@@ -71,7 +71,7 @@ def main():
         logdir = sys.argv[1]
     else:
         # no canonical default exists: the suite defaults to
-        # /tmp/onchip_<HHMM> and the watchdog to /tmp/onchip_watchdog
+        # /tmp/onchip_<HHMM>
         sys.exit(f"usage: {sys.argv[0]} <suite-logdir>\n"
                  "(the logdir bin/run_onchip_suite.sh printed at start)")
     if not os.path.isdir(logdir):

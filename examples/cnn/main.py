@@ -69,6 +69,10 @@ def main():
     parser.add_argument("--comm-mode", default=None,
                         help="None / AllReduce / PS / Hybrid")
     args = parser.parse_args()
+    # compiled programs persist between runs ($JAX_COMPILATION_CACHE_DIR,
+    # else <checkout>/.jax_cache)
+    from hetu_tpu.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     builder, kind = MODELS[args.model]
     tx, ty, vx, vy = load_dataset(kind, args.dataset)

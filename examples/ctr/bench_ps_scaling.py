@@ -270,56 +270,55 @@ def main():
             s.terminate()
 
     # ---- native C++ van tier (ps-lite zmq_van role) ----
-    from hetu_tpu.ps.van import van_available
-    van_iters = args.iters * 4     # 4x window: the van is ~7x faster,
-    if van_available():            # same wall time per cell (recorded)
-        port = _free_port()
-        ready = ctx.Event()
-        srv = ctx.Process(target=_van_serve,
-                          args=(port, args.rows, args.dim, ready),
-                          daemon=True)
-        srv.start()
-        if not ready.wait(60):
-            raise TimeoutError(
-                "van server did not come up (register/listen stalled)")
-        _wait(port)
-        for n in worker_counts:
-            rates = _fan_out(
-                ctx, _van_worker,
-                lambda r, q, b: (port, args.batch, args.dim, van_iters,
-                                 args.rows, 100 + r, q, b),
-                n)
-            agg = sum(rates)
-            results[f"van_{n}w"] = {
-                "aggregate_rows_per_sec": round(agg, 1),
-                "per_worker_rows_per_sec": [round(r, 1) for r in rates],
-            }
-            print(f"van workers={n}: {agg/1e6:.3f}M rows/s aggregate")
-        srv.terminate()
+    # 4x window: the van is ~7x faster, same wall time per cell (recorded)
+    van_iters = args.iters * 4
+    port = _free_port()
+    ready = ctx.Event()
+    srv = ctx.Process(target=_van_serve,
+                      args=(port, args.rows, args.dim, ready),
+                      daemon=True)
+    srv.start()
+    if not ready.wait(60):
+        raise TimeoutError(
+            "van server did not come up (register/listen stalled)")
+    _wait(port)
+    for n in worker_counts:
+        rates = _fan_out(
+            ctx, _van_worker,
+            lambda r, q, b: (port, args.batch, args.dim, van_iters,
+                             args.rows, 100 + r, q, b),
+            n)
+        agg = sum(rates)
+        results[f"van_{n}w"] = {
+            "aggregate_rows_per_sec": round(agg, 1),
+            "per_worker_rows_per_sec": [round(r, 1) for r in rates],
+        }
+        print(f"van workers={n}: {agg/1e6:.3f}M rows/s aggregate")
+    srv.terminate()
 
-        # in-process single stream: the van's service rate with no
-        # second python process competing for the core
-        from hetu_tpu.ps.van import NativeVan, VanClient
-        van = NativeVan()
-        vport = van.listen()
-        van.register_sgd_table(0, np.zeros((args.rows, args.dim),
-                                           np.float32), lr=0.01)
-        cli = VanClient("127.0.0.1", vport, dim=args.dim)
-        rng = np.random.RandomState(0)
-        vids = ((rng.zipf(1.05, args.batch) - 1) % args.rows)
-        vrows = rng.randn(args.batch, args.dim).astype(np.float32)
-        for _ in range(3):
-            cli.sd_pushpull(0, vids, vrows)
-        t0 = time.perf_counter()
-        vit = van_iters
-        for _ in range(vit):
-            cli.sd_pushpull(0, vids, vrows)
-        vr = args.batch * vit / (time.perf_counter() - t0)
-        results["van_inprocess_single_stream"] = {
-            "aggregate_rows_per_sec": round(vr, 1)}
-        print(f"van in-process single stream: {vr/1e6:.3f}M rows/s")
-        cli.close()
-        van.stop()
+    # in-process single stream: the van's service rate with no
+    # second python process competing for the core
+    from hetu_tpu.ps.van import NativeVan, VanClient
+    van = NativeVan()
+    vport = van.listen()
+    van.register_sgd_table(0, np.zeros((args.rows, args.dim),
+                                       np.float32), lr=0.01)
+    cli = VanClient("127.0.0.1", vport, dim=args.dim)
+    rng = np.random.RandomState(0)
+    vids = ((rng.zipf(1.05, args.batch) - 1) % args.rows)
+    vrows = rng.randn(args.batch, args.dim).astype(np.float32)
+    for _ in range(3):
+        cli.sd_pushpull(0, vids, vrows)
+    t0 = time.perf_counter()
+    vit = van_iters
+    for _ in range(vit):
+        cli.sd_pushpull(0, vids, vrows)
+    vr = args.batch * vit / (time.perf_counter() - t0)
+    results["van_inprocess_single_stream"] = {
+        "aggregate_rows_per_sec": round(vr, 1)}
+    print(f"van in-process single stream: {vr/1e6:.3f}M rows/s")
+    cli.close()
+    van.stop()
 
     base = results[f"{worker_counts[0]}w_{server_counts[0]}s"][
         "aggregate_rows_per_sec"]

@@ -77,6 +77,10 @@ def main():
     parser.add_argument("--all", action="store_true",
                         help="eval AUC each 10 steps")
     args = parser.parse_args()
+    # compiled programs persist between runs ($JAX_COMPILATION_CACHE_DIR,
+    # else <checkout>/.jax_cache)
+    from hetu_tpu.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.cache_bound is None:
         args.cache_bound = max(args.feature_dim // 10, 1024)
 

@@ -114,6 +114,10 @@ def main():
     ap.add_argument("--kill-ps", action="store_true",
                     help="kill the PS for the middle third of the trace")
     args = ap.parse_args()
+    # compiled programs persist between runs ($JAX_COMPILATION_CACHE_DIR,
+    # else <checkout>/.jax_cache)
+    from hetu_tpu.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     eng, table, comm = build_engine(args)
     reqs = zipf_trace(args)

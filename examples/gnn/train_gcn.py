@@ -44,6 +44,10 @@ def main():
     p.add_argument("--mesh", default=None,
                    help="e.g. dp4xtp2 — 1.5-D partition axes")
     args = p.parse_args()
+    # compiled programs persist between runs ($JAX_COMPILATION_CACHE_DIR,
+    # else <checkout>/.jax_cache)
+    from hetu_tpu.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     mesh = parse_mesh(args.mesh, logger)
     adj, feat, labels = sbm_graph(args.nodes, args.classes, 0.2, 0.01,

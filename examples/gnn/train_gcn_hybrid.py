@@ -50,6 +50,10 @@ def main():
                    help="HET embedding cache between worker and PS")
     p.add_argument("--cache-bound", type=int, default=64)
     args = p.parse_args()
+    # compiled programs persist between runs ($JAX_COMPILATION_CACHE_DIR,
+    # else <checkout>/.jax_cache)
+    from hetu_tpu.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     mesh = parse_mesh(args.mesh, logger)
     adj, _, labels = sbm_graph(args.nodes, args.classes, 0.2, 0.01)

@@ -42,6 +42,10 @@ def main():
     parser.add_argument("--bf16", action="store_true",
                         help="bf16 compute, fp32 masters")
     args = parser.parse_args()
+    # compiled programs persist between runs ($JAX_COMPILATION_CACHE_DIR,
+    # else <checkout>/.jax_cache)
+    from hetu_tpu.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     n_classes = args.model_dim
     # feed through the dataloader prefetch ring with sparse int labels:

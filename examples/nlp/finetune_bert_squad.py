@@ -66,6 +66,10 @@ def main():
     p.add_argument("--comm-mode", default=None,
                    choices=[None, "AllReduce"])
     args = p.parse_args()
+    # compiled programs persist between runs ($JAX_COMPILATION_CACHE_DIR,
+    # else <checkout>/.jax_cache)
+    from hetu_tpu.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     examples = read_squad_examples(args.data, is_training=True)
     tok = build_tokenizer(examples, args.vocab_path)

@@ -73,6 +73,10 @@ def main():
                          "wave (0 = off); outputs stay token-identical")
     ap.add_argument("--spec-draft-layers", type=int, default=1)
     args = ap.parse_args()
+    # compiled programs persist between runs ($JAX_COMPILATION_CACHE_DIR,
+    # else <checkout>/.jax_cache)
+    from hetu_tpu.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     cfg = GPTConfig(vocab_size=args.vocab_size, hidden_size=args.hidden,
                     num_hidden_layers=args.num_layers,

@@ -46,6 +46,10 @@ def main():
                              "corpus when absent")
     parser.add_argument("--dupe-factor", type=int, default=5)
     args = parser.parse_args()
+    # compiled programs persist between runs ($JAX_COMPILATION_CACHE_DIR,
+    # else <checkout>/.jax_cache)
+    from hetu_tpu.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     make = BertConfig.large if args.config == "large" else BertConfig.base
     kw = dict(batch_size=args.batch_size, seq_len=args.seq_len,

@@ -55,6 +55,10 @@ def main():
                              "batches when absent")
     parser.add_argument("--vocab-path", default=None)
     args = parser.parse_args()
+    # compiled programs persist between runs ($JAX_COMPILATION_CACHE_DIR,
+    # else <checkout>/.jax_cache)
+    from hetu_tpu.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     stream = None
     if args.data_path:

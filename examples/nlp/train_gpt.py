@@ -97,6 +97,10 @@ def main():
                         help="after training, greedy-decode this many "
                              "tokens from a short prompt")
     args = parser.parse_args()
+    # compiled programs persist between runs ($JAX_COMPILATION_CACHE_DIR,
+    # else <checkout>/.jax_cache)
+    from hetu_tpu.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     make = GPTConfig.medium if args.config == "medium" else GPTConfig.small
     kw = dict(batch_size=args.batch_size, seq_len=args.seq_len,

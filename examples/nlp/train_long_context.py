@@ -70,6 +70,10 @@ def main():
     p.add_argument("--tiny", action="store_true",
                    help="CPU-smoke scale")
     args = p.parse_args()
+    # compiled programs persist between runs ($JAX_COMPILATION_CACHE_DIR,
+    # else <checkout>/.jax_cache)
+    from hetu_tpu.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     if args.tiny:
         args.hidden, args.heads, args.layers, args.vocab = 64, 2, 2, 200

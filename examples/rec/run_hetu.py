@@ -36,6 +36,10 @@ def main():
                              "ratings.csv / ratings.dat; synthetic "
                              "interactions when unset")
     args = parser.parse_args()
+    # compiled programs persist between runs ($JAX_COMPILATION_CACHE_DIR,
+    # else <checkout>/.jax_cache)
+    from hetu_tpu.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     rng = np.random.RandomState(0)
     bs = args.batch_size

@@ -14,19 +14,6 @@ so code written against `import hetu as ht` works with
 
 __version__ = "0.1.0"
 
-import os as _os
-
-if _os.environ.get("JAX_PLATFORMS", "").strip().lower() == "cpu":
-    # The TPU plugin in some images auto-registers and ignores the
-    # JAX_PLATFORMS env var; honor the user's intent via jax.config (wins
-    # as long as no backend has initialized yet).
-    import jax as _jax
-    _jax.config.update("jax_platforms", "cpu")
-
-from ._compat import ensure_jax_compat as _ensure_jax_compat
-
-_ensure_jax_compat()
-
 from .context import (
     DLContext, DeviceGroup, DistConfig, context, get_current_context,
     cpu, gpu, tpu, rcpu, rgpu, rtpu, is_gpu_ctx, check_worker,

@@ -1,8 +1,9 @@
 """Embedding cache core: ctypes binding over the C++ library, with a
-pure-Python mirror used when no toolchain is available.
+pure-Python mirror (``prefer_native=False``) that the tests hold the
+native cache to.
 
-Both expose the same interface; `EmbeddingCache(...)` picks native when the
-.so builds.  Policies: 'LRU', 'LFU', 'LFUOpt' (reference lru_cache.h:17,
+Both expose the same interface; `EmbeddingCache(...)` is native unless
+told otherwise, and a native build that fails raises.  Policies: 'LRU', 'LFU', 'LFUOpt' (reference lru_cache.h:17,
 lfu_cache.h:17, lfuopt_cache.h:18).
 """
 
@@ -34,44 +35,42 @@ class NativeCache:
         if cls._lib is None:
             from ..native import build_and_load
             lib = build_and_load("cache.cpp", "libhetu_cache.so")
-            if lib is not None:
-                i64p = ctypes.POINTER(ctypes.c_int64)
-                f32p = ctypes.POINTER(ctypes.c_float)
-                u8p = ctypes.POINTER(ctypes.c_uint8)
-                lib.cache_create.restype = ctypes.c_void_p
-                lib.cache_create.argtypes = [ctypes.c_int, ctypes.c_int64,
-                                             ctypes.c_int64]
-                lib.cache_destroy.argtypes = [ctypes.c_void_p]
-                lib.cache_size.restype = ctypes.c_int64
-                lib.cache_size.argtypes = [ctypes.c_void_p]
-                lib.cache_counters.argtypes = [ctypes.c_void_p, i64p, i64p,
-                                               i64p]
-                lib.cache_lookup.argtypes = [ctypes.c_void_p, i64p,
-                                             ctypes.c_int64, f32p, u8p]
-                lib.cache_versions.argtypes = [ctypes.c_void_p, i64p,
-                                               ctypes.c_int64, i64p]
-                lib.cache_insert.restype = ctypes.c_int64
-                lib.cache_insert.argtypes = [ctypes.c_void_p, i64p,
-                                             ctypes.c_int64, f32p, i64p,
-                                             i64p, f32p, ctypes.c_int64]
-                lib.cache_update.restype = ctypes.c_int64
-                lib.cache_update.argtypes = [ctypes.c_void_p, i64p,
-                                             ctypes.c_int64, f32p]
-                lib.cache_max_updates.restype = ctypes.c_int64
-                lib.cache_max_updates.argtypes = [ctypes.c_void_p]
-                lib.cache_dirty.argtypes = [ctypes.c_void_p, i64p,
-                                            ctypes.c_int64, u8p]
-                lib.cache_collect_dirty.restype = ctypes.c_int64
-                lib.cache_collect_dirty.argtypes = [ctypes.c_void_p, i64p,
-                                                    f32p, ctypes.c_int64]
-                lib.cache_refresh.argtypes = [ctypes.c_void_p, i64p,
-                                              ctypes.c_int64, f32p, i64p]
-            cls._lib = lib if lib is not None else False
-        return cls._lib or None
+            i64p = ctypes.POINTER(ctypes.c_int64)
+            f32p = ctypes.POINTER(ctypes.c_float)
+            u8p = ctypes.POINTER(ctypes.c_uint8)
+            lib.cache_create.restype = ctypes.c_void_p
+            lib.cache_create.argtypes = [ctypes.c_int, ctypes.c_int64,
+                                         ctypes.c_int64]
+            lib.cache_destroy.argtypes = [ctypes.c_void_p]
+            lib.cache_size.restype = ctypes.c_int64
+            lib.cache_size.argtypes = [ctypes.c_void_p]
+            lib.cache_counters.argtypes = [ctypes.c_void_p, i64p, i64p,
+                                           i64p]
+            lib.cache_lookup.argtypes = [ctypes.c_void_p, i64p,
+                                         ctypes.c_int64, f32p, u8p]
+            lib.cache_versions.argtypes = [ctypes.c_void_p, i64p,
+                                           ctypes.c_int64, i64p]
+            lib.cache_insert.restype = ctypes.c_int64
+            lib.cache_insert.argtypes = [ctypes.c_void_p, i64p,
+                                         ctypes.c_int64, f32p, i64p,
+                                         i64p, f32p, ctypes.c_int64]
+            lib.cache_update.restype = ctypes.c_int64
+            lib.cache_update.argtypes = [ctypes.c_void_p, i64p,
+                                         ctypes.c_int64, f32p]
+            lib.cache_max_updates.restype = ctypes.c_int64
+            lib.cache_max_updates.argtypes = [ctypes.c_void_p]
+            lib.cache_dirty.argtypes = [ctypes.c_void_p, i64p,
+                                        ctypes.c_int64, u8p]
+            lib.cache_collect_dirty.restype = ctypes.c_int64
+            lib.cache_collect_dirty.argtypes = [ctypes.c_void_p, i64p,
+                                                f32p, ctypes.c_int64]
+            lib.cache_refresh.argtypes = [ctypes.c_void_p, i64p,
+                                          ctypes.c_int64, f32p, i64p]
+            cls._lib = lib
+        return cls._lib
 
     def __init__(self, limit, width, policy="LRU"):
         lib = self.load_lib()
-        assert lib is not None, "native cache library unavailable"
         self._l = lib
         self.limit = int(limit)
         self.width = int(width)
@@ -328,7 +327,7 @@ def merge_sparse(ids_a, rows_a, ids_b, rows_b):
 
 
 def EmbeddingCache(limit, width, policy="LRU", prefer_native=True):
-    """Factory: native C++ cache when buildable, Python mirror otherwise."""
-    if prefer_native and NativeCache.load_lib() is not None:
+    """Factory: the native C++ cache, or its Python mirror on request."""
+    if prefer_native:
         return NativeCache(limit, width, policy)
     return PythonCache(limit, width, policy)

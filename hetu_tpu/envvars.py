@@ -483,9 +483,6 @@ _reg("HETU_DATA_HOME", "path", "~/.hetu_data",
      "Dataset download/cache directory.", "data")
 _reg("HETU_CALIB_SMALL", "bool", False,
      "Chip-calibration: reduced ladder for smoke runs.", "planner")
-_reg("HETU_COMPILE_CACHE_DIR", "path", "/tmp/hetu_xla_cache",
-     "Persistent XLA compilation-cache directory for bench runs.",
-     "planner")
 
 # --------------------------------------------------------------------- #
 # bench.py
@@ -523,8 +520,6 @@ _reg("HETU_BENCH_MOE_TOKENS", "int", None,
      "Override the MoE bench tokens-per-sample.", "bench")
 _reg("HETU_BENCH_LC_BLOCKS", "str", None,
      "Long-context flash tile override, 'bq,bk'.", "bench")
-_reg("HETU_BENCH_NO_COMPILE_CACHE", "bool", False,
-     "Opt out of the persistent XLA compile cache.", "bench")
 
 
 # --------------------------------------------------------------------- #

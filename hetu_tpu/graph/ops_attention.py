@@ -8,7 +8,7 @@ generic VJPOp fallback.
 
 from __future__ import annotations
 
-from .node import Op, SimpleOp
+from .node import Op, SimpleOp, vjp_gradient
 
 
 class CausalMaskOp(Op):
@@ -46,6 +46,50 @@ def causal_mask_op(seq_len, neg=None, ctx=None):
     return CausalMaskOp(seq_len, neg, ctx=ctx)
 
 
+class FlashAttentionOp(Op):
+    """The Pallas flash kernel as a graph node.  Under a mesh the kernel
+    runs per shard inside ``shard_map`` — batch over the data axis, heads
+    over 'tp': GSPMD cannot partition a Mosaic kernel, and on the chip a
+    sharded step with a bare ``pallas_call`` in it does not even lower
+    ("Mosaic kernels cannot be automatically partitioned")."""
+
+    def __init__(self, q, k, v, kv_lens, causal, blocks, ctx=None):
+        inputs = (q, k, v) + ((kv_lens,) if kv_lens is not None else ())
+        super().__init__(*inputs, name="FlashAttention", ctx=ctx)
+        self.causal = causal
+        self.blocks = blocks
+
+    def _specs(self, mesh, shape):
+        from jax.sharding import PartitionSpec as P
+        from ..parallel.mesh import batch_axis
+        B, _, H, _ = shape
+        batch = batch_axis(mesh, B)
+        heads = "tp" if "tp" in mesh.axis_names \
+            and H % mesh.shape["tp"] == 0 else None
+        return P(batch, None, heads, None), P(batch)
+
+    def compute(self, input_vals, tc):
+        import jax
+        from ..kernels.flash_attention import flash_attention
+
+        def fn(q, k, v, lens=None):
+            return flash_attention(q, k, v, causal=self.causal,
+                                   kv_lens=lens, **self.blocks)
+
+        mesh = tc.mesh
+        # inside a manual trace (pipeline body) the values already are
+        # per-shard; a one-device mesh has nothing to partition
+        if mesh is None or mesh.size == 1 or tc.axis_env:
+            return fn(*input_vals)
+        qkv, lens = self._specs(mesh, input_vals[0].shape)
+        in_specs = (qkv, qkv, qkv) + ((lens,) * (len(input_vals) - 3))
+        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                             out_specs=qkv, check_vma=False)(*input_vals)
+
+    def gradient(self, output_grad):
+        return vjp_gradient(self, output_grad)
+
+
 def flash_attention_op(q, k, v, causal=False, kv_lens=None, block_q=None,
                        block_k=None, ctx=None):
     """Fused attention on [B, S, H, D] q/k/v nodes -> [B, S, H, D].
@@ -54,19 +98,12 @@ def flash_attention_op(q, k, v, causal=False, kv_lens=None, block_q=None,
     kv_lens[b] are masked (padding mask).  block_q/block_k default to
     the kernel's tuned values (single source of truth in
     kernels/flash_attention.py)."""
-    from ..kernels.flash_attention import flash_attention
-
-    kw = {}
+    blocks = {}
     if block_q is not None:
-        kw["block_q"] = block_q
+        blocks["block_q"] = block_q
     if block_k is not None:
-        kw["block_k"] = block_k
-
-    def fn(q, k, v, lens=None):
-        return flash_attention(q, k, v, causal=causal, kv_lens=lens, **kw)
-
-    inputs = (q, k, v) + ((kv_lens,) if kv_lens is not None else ())
-    return SimpleOp(fn, *inputs, name="FlashAttention", ctx=ctx)
+        blocks["block_k"] = block_k
+    return FlashAttentionOp(q, k, v, kv_lens, causal, blocks, ctx=ctx)
 
 
 def ring_attention_op(q, k, v, mesh, axis="cp", causal=False, impl=None,

@@ -1,27 +1,9 @@
-"""Pallas TPU kernels for the hot ops.
+"""Pallas TPU kernels for the hot ops: fused flash attention (training
+and prefill), the phase-split paged decode/verify kernels, and the
+mixed-mode ragged kernel the serving engine's TPU default runs."""
 
-Planned contents (SURVEY.md §2.1 'TPU equivalent'): fused flash attention,
-MoE capacity dispatch, top-k gating helpers.  Modules register themselves
-here as they land; import errors mean the kernel is not built yet — all
-call sites fall back to the jnp compositions in hetu_tpu.graph.
-"""
+from . import flash_attention  # noqa: F401
+from . import decode_attention  # noqa: F401
+from . import ragged_attention  # noqa: F401
 
-__all__ = []
-
-try:
-    from . import flash_attention  # noqa: F401
-    __all__.append("flash_attention")
-except ImportError:  # pallas unavailable: call sites fall back to jnp paths
-    pass
-
-try:
-    from . import decode_attention  # noqa: F401
-    __all__.append("decode_attention")
-except ImportError:  # pallas unavailable: serving falls back to masked
-    pass
-
-try:
-    from . import ragged_attention  # noqa: F401
-    __all__.append("ragged_attention")
-except ImportError:  # pallas unavailable: mixed mode falls back to masked
-    pass
+__all__ = ["flash_attention", "decode_attention", "ragged_attention"]

@@ -57,39 +57,6 @@ from .flash_attention import NEG_INF, _fit_block, _prec
 _LANES = 128
 
 
-def _online_softmax_update(q, k, v, filled, j, bk, scale, m_ref, l_ref,
-                           acc_ref):
-    """One KV block's contribution to a slot's online softmax: shared
-    verbatim by the f32/bf16 kernels and the int8 variants (which
-    dequantize k/v right before calling this — the dequant lives INSIDE
-    the online-softmax loop, no f32 pool is ever materialized)."""
-    H = q.shape[0]
-    # s[h, s] = q[h] . k[s, h] — per-head matvec, batched over heads
-    s = jax.lax.dot_general(
-        q, k, (((1,), (2,)), ((0,), (1,))),
-        precision=_prec(q.dtype),
-        preferred_element_type=jnp.float32) * scale   # [H, bk]
-    kv_pos = j * bk + jax.lax.broadcasted_iota(jnp.int32, (H, bk), 1)
-    s = jnp.where(kv_pos < filled, s, NEG_INF)
-    m_prev = m_ref[:, 0:1]
-    l_prev = l_ref[:, 0:1]
-    m_cur = jnp.max(s, axis=-1, keepdims=True)
-    m_new = jnp.maximum(m_prev, m_cur)
-    safe_m = jnp.where(m_new <= NEG_INF / 2, 0.0, m_new)
-    p = jnp.exp(s - safe_m)
-    p = jnp.where(s <= NEG_INF / 2, 0.0, p)
-    alpha = jnp.exp(jnp.clip(m_prev - m_new, max=0.0))
-    alpha = jnp.where(m_prev <= NEG_INF / 2, 0.0, alpha)
-    l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
-    pv = jax.lax.dot_general(
-        p.astype(v.dtype), v, (((1,), (0,)), ((0,), (1,))),
-        precision=_prec(v.dtype),
-        preferred_element_type=jnp.float32)           # [H, Dh]
-    acc_ref[:] = acc_ref[:] * alpha + pv
-    m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
-    l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
-
-
 def _decode_kernel(lens_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
                    acc_ref, *, scale, bk, n_kv):
     b = pl.program_id(0)
@@ -107,8 +74,12 @@ def _decode_kernel(lens_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
     # was already skipped by the revisit index map; skip the compute too
     @pl.when(j * bk < filled)
     def _compute():
-        _online_softmax_update(q_ref[0, 0], k_ref[0], v_ref[0], filled,
-                               j, bk, scale, m_ref, l_ref, acc_ref)
+        # a q-block of ONE query through the verify kernels' update (its
+        # mask degenerates to ``kv_pos < filled``).  A dedicated [H, Dh]
+        # matvec does not compile for the chip: Mosaic refuses a batched
+        # dot whose lhs has no non-contracting dim
+        _online_softmax_multi(q_ref[0], k_ref[0], v_ref[0], filled, 1,
+                              j, bk, scale, m_ref, l_ref, acc_ref)
 
     @pl.when(j == n_kv - 1)
     def _finalize():
@@ -141,9 +112,9 @@ def _decode_kernel_int8(lens_ref, q_ref, k_ref, ks_ref, v_ref, vs_ref,
     def _compute():
         k = k_ref[0].astype(jnp.float32) * ks_ref[0][..., None]
         v = v_ref[0].astype(jnp.float32) * vs_ref[0][..., None]
-        _online_softmax_update(q_ref[0, 0].astype(jnp.float32), k, v,
-                               filled, j, bk, scale, m_ref, l_ref,
-                               acc_ref)
+        _online_softmax_multi(q_ref[0].astype(jnp.float32), k, v,
+                              filled, 1, j, bk, scale, m_ref, l_ref,
+                              acc_ref)
 
     @pl.when(j == n_kv - 1)
     def _finalize():
@@ -362,19 +333,20 @@ def paged_block_decode_reference(q, pool_k, pool_v, lengths,
 # ------------------------------------------------------------------- #
 
 
-def _query_positions(filled, qlen, nq):
+def _query_positions(filled, qlen, nq, q0=0):
     """Absolute position of each query in a slot's verify q-block:
-    query ``jq`` sits at ``filled - qlen + jq``; dead queries
+    query ``jq`` sits at ``filled - qlen + jq`` (``q0`` is the index of
+    this tile's first query when the q-block is tiled); dead queries
     (``jq >= qlen``) clip to the last live position so their (discarded)
     softmax rows stay finite, and a fully-inert slot (filled 0) clips
     to 0 — the ``l == 0`` finalize guard zeroes its output anyway."""
     qidx = jax.lax.broadcasted_iota(jnp.int32, (1, nq, 1), 1)
-    return jnp.clip(filled - qlen + qidx, 0,
+    return jnp.clip(filled - qlen + q0 + qidx, 0,
                     jnp.maximum(filled - 1, 0))
 
 
 def _online_softmax_multi(q, k, v, filled, qlen, j, bk, scale, m_ref,
-                          l_ref, acc_ref):
+                          l_ref, acc_ref, q0=0):
     """One KV block's contribution to a VERIFY q-block's online softmax:
     ``q`` [Q, H, Dh] against ``k``/``v`` [bk, H, Dh], one accumulator
     row per (head, query).  The causal mask inside the q-block falls out
@@ -390,7 +362,7 @@ def _online_softmax_multi(q, k, v, filled, qlen, j, bk, scale, m_ref,
         precision=_prec(q.dtype),
         preferred_element_type=jnp.float32) * scale       # [H, Q, bk]
     kv_pos = j * bk + jax.lax.broadcasted_iota(jnp.int32, (H, Q, bk), 2)
-    posq = _query_positions(filled, qlen, Q)              # [1, Q, 1]
+    posq = _query_positions(filled, qlen, Q, q0)          # [1, Q, 1]
     s = jnp.where(kv_pos <= posq, s, NEG_INF)
     s = s.reshape(R, bk)
     m_prev = m_ref[:, 0:1]

@@ -10,16 +10,20 @@ spec-verify, a chunk of prompt for prefill/chunked-prefill), and one
 kernel call scores the whole mixed wave.  Mechanically it is the
 verify-kernel computation with nothing verify-specific left in it:
 
-  - grid (slot, kv-block), kv innermost, so the online-softmax
+  - grid (slot, q-tile, kv-block), kv innermost, so the online-softmax
     accumulators (one f32 (m, l, acc) row per (head, query)) persist in
-    VMEM scratch across a slot's kv steps;
+    VMEM scratch across a q-tile's kv steps; the q-block is tiled so
+    that VMEM is bounded by ``_MAX_ROWS`` (head, query) rows whatever
+    the prompt length — a 1024-token prompt in one q-block is eight
+    128-query tiles at 12 heads, not one 18 MB allocation;
   - per-slot ``q_len``/``kv_len``/block-table rows ride in as SCALAR
     PREFETCH so the kv block-index maps can see them;
   - blocks wholly past a slot's filled length REVISIT its last live
     block (a repeated index skips the DMA — flash_attention's
     ``_causal_kv_index`` trick) and their compute is skipped with
-    ``@pl.when``, so a wave's KV traffic is O(sum(kv_len)), not
-    O(B * S_max);
+    ``@pl.when``; q-tiles wholly past a slot's ``q_len`` stay on that
+    block too, so a wave's KV traffic is O(sum(kv_len)) per live
+    q-tile, not O(B * S_max);
   - scores and the output accumulate in f32 over bf16 pools;
   - the int8 twin takes per-(position, head) scale planes on the same
     revisit index maps and dequantizes INSIDE the online-softmax loop
@@ -56,7 +60,73 @@ from .decode_attention import (_LANES, _online_softmax_multi,
                                _use_interpret, _verify_finalize)
 
 
-def _ragged_kernel(*refs, scale, bk, n_kv, nq, quant, tabled):
+# (head, query) accumulator rows one q-tile may hold: the (m, l, acc)
+# scratch, the score block and the q/o tiles all scale with it.  A
+# q-block of up to 3200 rows (25 heads x 128 queries; 12 x 256 is 3072)
+# compiled for v5e's 16 MiB scoped VMEM as ONE tile before there were
+# tiles, and still is one — nothing that compiled changes its schedule.
+# Longer q-blocks, which the compiler refused, are cut into tiles of at
+# most 2048 rows (3072-row tiles of an f32 cache are refused once there
+# is more than one of them).
+_ONE_TILE_ROWS = 3200
+_MAX_ROWS = 2048
+
+
+def _q_tile(Q, H):
+    """Queries per q-tile: all of ``Q`` while its (head, query) rows fit
+    ``_ONE_TILE_ROWS``, else the largest divisor of ``Q`` whose rows fit
+    ``_MAX_ROWS``."""
+    if H * Q <= _ONE_TILE_ROWS:
+        return Q
+    return _fit_block(max(_MAX_ROWS // H, 1), Q)
+
+
+def _kv_block(block_k, S, H, Dh, dtype):
+    """Positions per contiguous kv block: ``block_k``, unless a
+    [bk, H, Dh] tile of ``dtype`` (H pads to the dtype's sublane tile,
+    Dh to 128 lanes) would pass 1 MiB of VMEM.  K and V are each
+    double-buffered; the one cache the compiler refused at 128
+    positions is f32 at 25 heads (2 MiB a tile, 8 MiB before a score is
+    computed), which drops to 64 — every other cache keeps 128."""
+    item = jnp.dtype(dtype).itemsize
+    sub = 32 // item
+    per_pos = -(-H // sub) * sub * -(-Dh // _LANES) * _LANES * item
+    return _fit_block(min(block_k, max((1 << 20) // per_pos, 8)), S)
+
+
+def _visible_end(lens_ref, qlens_ref, b, t, tq):
+    """One past the last kv position q-tile ``t`` of slot ``b`` can
+    see.  A tile that reaches the q-block's dead tail sees the whole
+    filled prefix (dead rows clip to the last live position); a wholly
+    live tile stops at its own last query (causality), so its
+    above-diagonal kv blocks are never fetched or scored."""
+    filled = lens_ref[b]
+    return jnp.minimum(filled, filled - qlens_ref[b] + (t + 1) * tq)
+
+
+def _live_tile(qlens_ref, b, t, tq):
+    """Whether q-tile ``t`` of slot ``b`` holds a live query.  Tile 0
+    always does (an empty slot still finalizes to zeros through it), so
+    a q-block of one tile keeps the verify kernel's arithmetic row for
+    row."""
+    return t * tq < jnp.maximum(qlens_ref[b], 1)
+
+
+def _kv_step_block(lens_ref, qlens_ref, b, t, j, tq, bk):
+    """The slot-local kv block that grid step (b, t, j) has resident.  A
+    live tile walks its visible blocks and then REVISITS the last one
+    (a repeated index skips the DMA).  A tile wholly in the q-block's
+    dead tail scores nothing, so it stays on the block the last live
+    tile ended on — the slot's final live block — for every ``j``: a
+    decode slot (q_len 1) riding in a 1024-wide wave fetches its KV
+    once, not once per q-tile."""
+    end = _visible_end(lens_ref, qlens_ref, b, t, tq)
+    last = jnp.maximum(end - 1, 0) // bk
+    return jnp.where(_live_tile(qlens_ref, b, t, tq),
+                     jnp.minimum(j, last), last)
+
+
+def _ragged_kernel(*refs, scale, bk, n_kv, tq, quant, tabled):
     """The single mixed-mode body.  ``refs`` is the Pallas positional
     layout — scalar-prefetch (lens, q_lens[, block_tables]) then
     operands (q, k[, k_scale], v[, v_scale]) then the output and the
@@ -78,7 +148,8 @@ def _ragged_kernel(*refs, scale, bk, n_kv, nq, quant, tabled):
     o_ref, m_ref, l_ref, acc_ref = refs[i:i + 4]
 
     b = pl.program_id(0)
-    j = pl.program_id(1)
+    t = pl.program_id(1)
+    j = pl.program_id(2)
 
     @pl.when(j == 0)
     def _init():
@@ -86,44 +157,47 @@ def _ragged_kernel(*refs, scale, bk, n_kv, nq, quant, tabled):
         l_ref[:] = jnp.zeros_like(l_ref)
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    filled = lens_ref[b]
+    qlen = qlens_ref[b]
+    # blocks past what this tile can see are dead: their DMA was
+    # already skipped by the revisit index map; skip the compute too.
+    # Tiles wholly inside the dead tail (past q_len) fetch and score
+    # nothing and finalize to zeros.
+    end = _visible_end(lens_ref, qlens_ref, b, t, tq)
 
-    # blocks wholly past this slot's filled prefix are dead: their DMA
-    # was already skipped by the revisit index map; skip the compute too
-    @pl.when(j * bk < filled)
+    @pl.when(_live_tile(qlens_ref, b, t, tq) & (j * bk < end))
     def _compute():
         q, k, v = q_ref[0], k_ref[0], v_ref[0]
         if quant:
             k = k.astype(jnp.float32) * ks_ref[0][..., None]
             v = v.astype(jnp.float32) * vs_ref[0][..., None]
             q = q.astype(jnp.float32)
-        _online_softmax_multi(q, k, v, filled, qlens_ref[b], j, bk,
-                              scale, m_ref, l_ref, acc_ref)
+        _online_softmax_multi(q, k, v, lens_ref[b], qlen, j, bk,
+                              scale, m_ref, l_ref, acc_ref, q0=t * tq)
 
     @pl.when(j == n_kv - 1)
     def _finalize():
-        _verify_finalize(o_ref, m_ref, l_ref, acc_ref, nq,
+        _verify_finalize(o_ref, m_ref, l_ref, acc_ref, tq,
                          q_ref.shape[2], q_ref.shape[3])
 
 
-def _call_ragged(q, lengths, q_lens, operands, *, bk, n_kv, quant,
-                 tabled, in_specs, scalars, interpret):
+def _call_ragged(q, operands, *, bk, n_kv, tq, quant, tabled, kv_specs,
+                 scalars, interpret):
     B, Q, H, Dh = q.shape
+    q_spec = pl.BlockSpec((1, tq, H, Dh), lambda b, t, j, *_: (b, t, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(scalars),
-        grid=(B, n_kv),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec(
-            (1, Q, H, Dh), lambda b, j, *_: (b, 0, 0, 0)),
+        grid=(B, Q // tq, n_kv),
+        in_specs=[q_spec, *kv_specs],
+        out_specs=q_spec,
         scratch_shapes=[
-            pltpu.VMEM((H * Q, _LANES), jnp.float32),   # running max
-            pltpu.VMEM((H * Q, _LANES), jnp.float32),   # running denom
-            pltpu.VMEM((H * Q, Dh), jnp.float32),       # output acc
+            pltpu.VMEM((H * tq, _LANES), jnp.float32),  # running max
+            pltpu.VMEM((H * tq, _LANES), jnp.float32),  # running denom
+            pltpu.VMEM((H * tq, Dh), jnp.float32),      # output acc
         ],
     )
     return pl.pallas_call(
         functools.partial(_ragged_kernel, scale=Dh ** -0.5, bk=bk,
-                          n_kv=n_kv, nq=Q, quant=quant, tabled=tabled),
+                          n_kv=n_kv, tq=tq, quant=quant, tabled=tabled),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Q, H, Dh), q.dtype),
         interpret=interpret,
@@ -145,38 +219,34 @@ def ragged_attention(q, k, v, lengths, q_lens, *, block_k=128,
     [B, S_max, H] f32."""
     B, Q, H, Dh = q.shape
     S = k.shape[1]
-    bk = _fit_block(block_k, S)
+    quant = k_scale is not None
+    bk = _kv_block(block_k, S, H, Dh, k.dtype)
+    tq = _q_tile(Q, H)
     if interpret is None:
         interpret = _use_interpret()
-    quant = k_scale is not None
 
-    def kv_idx(b, j, lens_ref, qlens_ref):
-        # dead blocks revisit the slot's last live block: the repeated
-        # index skips the DMA entirely
-        last = jnp.maximum(lens_ref[b] - 1, 0) // bk
-        return (b, jnp.minimum(j, last), 0, 0)
+    def block(b, t, j, lens_ref, qlens_ref):
+        return _kv_step_block(lens_ref, qlens_ref, b, t, j, tq, bk)
 
-    def sc_idx(b, j, lens_ref, qlens_ref):
-        last = jnp.maximum(lens_ref[b] - 1, 0) // bk
-        return (b, jnp.minimum(j, last), 0)
+    def kv_idx(b, t, j, *refs):
+        return (b, block(b, t, j, *refs), 0, 0)
 
-    q_spec = pl.BlockSpec((1, Q, H, Dh),
-                          lambda b, j, lens, qlens: (b, 0, 0, 0))
+    def sc_idx(b, t, j, *refs):
+        return (b, block(b, t, j, *refs), 0)
+
     if quant:
-        in_specs = [q_spec,
-                    pl.BlockSpec((1, bk, H, Dh), kv_idx),
+        kv_specs = [pl.BlockSpec((1, bk, H, Dh), kv_idx),
                     pl.BlockSpec((1, bk, H), sc_idx),
                     pl.BlockSpec((1, bk, H, Dh), kv_idx),
                     pl.BlockSpec((1, bk, H), sc_idx)]
         operands = (q, k, k_scale, v, v_scale)
     else:
-        in_specs = [q_spec,
-                    pl.BlockSpec((1, bk, H, Dh), kv_idx),
+        kv_specs = [pl.BlockSpec((1, bk, H, Dh), kv_idx),
                     pl.BlockSpec((1, bk, H, Dh), kv_idx)]
         operands = (q, k, v)
     return _call_ragged(
-        q, lengths, q_lens, operands, bk=bk, n_kv=S // bk, quant=quant,
-        tabled=False, in_specs=in_specs,
+        q, operands, bk=bk, n_kv=S // bk, tq=tq, quant=quant,
+        tabled=False, kv_specs=kv_specs,
         scalars=(lengths.astype(jnp.int32), q_lens.astype(jnp.int32)),
         interpret=interpret)
 
@@ -199,35 +269,34 @@ def ragged_paged_attention(q, pool_k, pool_v, lengths, q_lens,
     B, Q, H, Dh = q.shape
     bs = pool_k.shape[1]
     T = block_tables.shape[1]
+    tq = _q_tile(Q, H)
     if interpret is None:
         interpret = _use_interpret()
     quant = k_scale is not None
 
-    def kv_idx(b, j, lens_ref, qlens_ref, bt_ref):
-        last = jnp.maximum(lens_ref[b] - 1, 0) // bs
-        return (bt_ref[b, jnp.minimum(j, last)], 0, 0, 0)
+    def block(b, t, j, lens_ref, qlens_ref, bt_ref):
+        return bt_ref[b, _kv_step_block(lens_ref, qlens_ref, b, t, j,
+                                        tq, bs)]
 
-    def sc_idx(b, j, lens_ref, qlens_ref, bt_ref):
-        last = jnp.maximum(lens_ref[b] - 1, 0) // bs
-        return (bt_ref[b, jnp.minimum(j, last)], 0, 0)
+    def kv_idx(b, t, j, *refs):
+        return (block(b, t, j, *refs), 0, 0, 0)
 
-    q_spec = pl.BlockSpec((1, Q, H, Dh),
-                          lambda b, j, lens, qlens, bt: (b, 0, 0, 0))
+    def sc_idx(b, t, j, *refs):
+        return (block(b, t, j, *refs), 0, 0)
+
     if quant:
-        in_specs = [q_spec,
-                    pl.BlockSpec((1, bs, H, Dh), kv_idx),
+        kv_specs = [pl.BlockSpec((1, bs, H, Dh), kv_idx),
                     pl.BlockSpec((1, bs, H), sc_idx),
                     pl.BlockSpec((1, bs, H, Dh), kv_idx),
                     pl.BlockSpec((1, bs, H), sc_idx)]
         operands = (q, pool_k, k_scale, pool_v, v_scale)
     else:
-        in_specs = [q_spec,
-                    pl.BlockSpec((1, bs, H, Dh), kv_idx),
+        kv_specs = [pl.BlockSpec((1, bs, H, Dh), kv_idx),
                     pl.BlockSpec((1, bs, H, Dh), kv_idx)]
         operands = (q, pool_k, pool_v)
     return _call_ragged(
-        q, lengths, q_lens, operands, bk=bs, n_kv=T, quant=quant,
-        tabled=True, in_specs=in_specs,
+        q, operands, bk=bs, n_kv=T, tq=tq, quant=quant,
+        tabled=True, kv_specs=kv_specs,
         scalars=(lengths.astype(jnp.int32), q_lens.astype(jnp.int32),
                  block_tables.astype(jnp.int32)),
         interpret=interpret)

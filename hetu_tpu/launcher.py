@@ -124,13 +124,6 @@ def distributed_init():
     nrank = envvars.get_int("HETU_NUM_PROCESSES")
     if nrank <= 1:
         return
-    # pre-0.5 jax needs the gloo CPU-collectives implementation selected
-    # explicitly or multi-process CPU meshes abort with "Multiprocess
-    # computations aren't implemented".  Unconditional: the option only
-    # affects the CPU backend, and probing the backend here would
-    # initialize jax before distributed.initialize (which it forbids).
-    from ._compat import enable_cpu_collectives
-    enable_cpu_collectives()
     jax.distributed.initialize(
         coordinator_address=os.environ["JAX_COORDINATOR_ADDRESS"],
         num_processes=nrank,

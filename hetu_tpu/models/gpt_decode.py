@@ -962,20 +962,23 @@ def _spec_sample(logits, temperature, top_k, rng_keys, first_row=None,
     return a (discarded) sample so the wave stays one fused dispatch.
     None (the default) keeps the pure-verify behavior: split at every
     row."""
-    B, Q = logits.shape[:2]
-    toks, after = [], []
-    keys = rng_keys
-    for j in range(Q):
+    def row(keys, j):
         splits = jax.vmap(jax.random.split)(keys)          # [B,2,2]
         if first_row is None:
             keys = splits[:, 0]
         else:
             do = (j >= first_row) & (j < q_len)            # [B]
             keys = jnp.where(do[:, None], splits[:, 0], keys)
-        toks.append(jax.vmap(_sample_slot)(logits[:, j], temperature,
-                                           top_k, splits[:, 1]))
-        after.append(keys)
-    return jnp.stack(toks, 1), jnp.stack(after, 1)
+        tok = jax.vmap(_sample_slot)(
+            jax.lax.dynamic_index_in_dim(logits, j, 1, keepdims=False),
+            temperature, top_k, splits[:, 1])
+        return keys, (tok, keys)
+
+    # a scan, not a Python loop: a whole prompt in one q-block is 1024
+    # rows, and 1024 unrolled vocab sorts do not compile in useful time
+    _, (toks, after) = jax.lax.scan(row, rng_keys,
+                                    jnp.arange(logits.shape[1]))
+    return jnp.swapaxes(toks, 0, 1), jnp.swapaxes(after, 0, 1)
 
 
 def _serve_verify(params, cfg_tuple, cache_k, cache_v, pos, tokens,

@@ -307,7 +307,7 @@ def moe_ffn_ep_reference(params, us, x, spec, mesh, quant=None):
     x: [T, D] with T divisible by the axis size.  Returns y [T, D].
     """
     from jax.sharding import PartitionSpec as P
-    from jax import shard_map  # installed by hetu_tpu._compat
+    from jax import shard_map
     axis = spec.ep_axis or "ep"
     n = int(mesh.shape[axis])
     E = spec.num_experts

@@ -31,6 +31,8 @@ import jax.numpy as jnp
 from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from .mesh import batch_axis
+
 NEG_INF = -1e30
 
 
@@ -59,19 +61,6 @@ def _block_attn_update(q, k, v, bias, m, l, o):
 def _finalize(l, o):
     denom = jnp.where(l == 0.0, 1.0, l).transpose(0, 2, 1)[..., None]
     return o / denom
-
-
-def _batch_axis(mesh, cp_axis, batch):
-    """Shard the batch dim over 'dp' when the mesh has one and the batch
-    divides it: entering the shard_map with the batch replicated forces
-    GSPMD into a full rematerialization (unshard/reshard) around every
-    call — the body does no cross-batch communication, so slicing it per
-    dp device is free.  Indivisible batches (e.g. B=1 inference on a
-    training mesh) stay replicated."""
-    if "dp" in mesh.axis_names and cp_axis != "dp" \
-            and batch % mesh.shape["dp"] == 0:
-        return "dp"
-    return None
 
 
 def ring_attention(q, k, v, *, mesh, axis="cp", causal=False, impl=None,
@@ -112,7 +101,7 @@ def ring_attention(q, k, v, *, mesh, axis="cp", causal=False, impl=None,
     S = q.shape[1]
     assert S % cp == 0, f"seq {S} not divisible by cp={cp}"
     blk = S // cp
-    bax = _batch_axis(mesh, axis, q.shape[0])
+    bax = batch_axis(mesh, q.shape[0], exclude=axis)
 
     def per_device(q, k, v):
         # local blocks [B, blk, H, D]
@@ -182,7 +171,7 @@ def _ring_attention_flash(q, k, v, *, mesh, axis, causal, block_q,
     cp = mesh.shape[axis]
     S = q.shape[1]
     assert S % cp == 0, f"seq {S} not divisible by cp={cp}"
-    bax = _batch_axis(mesh, axis, q.shape[0])
+    bax = batch_axis(mesh, q.shape[0], exclude=axis)
 
     def per_device(q, k, v):
         my = jax.lax.axis_index(axis)
@@ -265,7 +254,7 @@ def ulysses_attention(q, k, v, *, mesh, axis="cp", causal=False,
         ol = attn_fn(ql, kl, vl, causal)
         return head_to_seq(ol)
 
-    spec = P(_batch_axis(mesh, axis, q.shape[0]), axis, None, None)
+    spec = P(batch_axis(mesh, q.shape[0], exclude=axis), axis, None, None)
     # check_vma off: attn_fn may be a pallas_call, whose out_shape carries
     # no varying-axes info under shard_map's vma tracking
     return shard_map(per_device, mesh=mesh, in_specs=(spec, spec, spec),

@@ -78,3 +78,21 @@ def default_mesh(dp=None):
     reference DataParallel strategy simple.py:6-39)."""
     n = dp or jax.device_count()
     return make_mesh({"dp": n})
+
+
+def batch_axis(mesh, batch, exclude=None):
+    """The mesh axis that carries the batch dim, or None: 'dp' when the
+    mesh has one — on a pure expert-parallel mesh tokens are
+    data-parallel over 'ep' (reference MoE: DP and EP share devices) —
+    and only if ``batch`` divides it (B=1 inference on a training mesh
+    stays replicated).  ``exclude``: an axis the caller uses otherwise.
+    Feeds, the flash op and ring attention all ask here, so a batch
+    enters every ``shard_map`` the way the executor laid it out —
+    entering one replicated forces GSPMD to unshard and reshard around
+    the call, while slicing a body with no cross-batch communication
+    per data device is free."""
+    axis = "dp" if "dp" in mesh.axis_names else "ep"
+    if axis in mesh.axis_names and axis != exclude \
+            and batch % mesh.shape[axis] == 0:
+        return axis
+    return None

@@ -38,16 +38,24 @@ from ..quant import QuantArray, maybe_decode, should_quantize, wire_chunk
 # ----------------------------------------------------------------- #
 # native core: fused C++ update loops (hetu_tpu/native/ps_core.cpp),
 # mirroring the reference's C++ server optimizers (server/optimizer.h).
-# Numpy paths below remain the fallback when no compiler exists.
+# The numpy paths below serve what the native loops do not cover
+# (broadcastable grads) and are the tests' reference implementation.
 # ----------------------------------------------------------------- #
 
-def _load_native():
+_NATIVE = None
+
+
+def _native():
+    """The fused C++ loops, built and loaded on the first PS update or
+    gather — not at import, so a host without g++ fails a PS run only
+    (``import hetu_tpu`` imports this module)."""
+    global _NATIVE
+    if _NATIVE is not None:
+        return _NATIVE
     from ..native import build_and_load
 
     lib = build_and_load("ps_core.cpp", "libps_core.so",
                          deps=("ps_kernels.h",))
-    if lib is None:
-        return None
     f32p = ctypes.POINTER(ctypes.c_float)
     i64p = ctypes.POINTER(ctypes.c_int64)
     i64 = ctypes.c_int64
@@ -68,10 +76,8 @@ def _load_native():
     lib.ps_sparse_accum.argtypes = [f32p, i64p, f32p, i64, i64]
     lib.ps_sparse_gather.argtypes = [f32p, i64p, f32p, i64, i64]
     lib.ps_bump_versions.argtypes = [i64p, i64p, i64]
+    _NATIVE = lib
     return lib
-
-
-_NATIVE = _load_native()
 
 
 def _fp(a):
@@ -84,9 +90,8 @@ def _ip(a):
 
 def _f32_ready(*arrays):
     """Arrays safe to hand to the float32 C loops (dtype + layout)."""
-    return _NATIVE is not None and all(
-        a.dtype == np.float32 and a.flags["C_CONTIGUOUS"]
-        for a in arrays)
+    return all(a.dtype == np.float32 and a.flags["C_CONTIGUOUS"]
+               for a in arrays)
 
 
 def _dense_ready(value, grad, *state):
@@ -146,15 +151,15 @@ class ServerOptimizer:
 class ServerSGD(ServerOptimizer):
     def apply_dense(self, value, grad, state):
         if _dense_ready(value, grad):
-            _NATIVE.ps_dense_sgd(_fp(value), _fp(grad), value.size,
-                                 self.lr)
+            _native().ps_dense_sgd(_fp(value), _fp(grad), value.size,
+                                   self.lr)
             return
         value -= self.lr * grad
 
     def apply_sparse(self, value, ids, rows, state):
         if _sparse_ready(value, ids, rows):
-            _NATIVE.ps_sparse_sgd(_fp(value), _ip(ids), _fp(rows),
-                                  len(ids), value.shape[-1], self.lr)
+            _native().ps_sparse_sgd(_fp(value), _ip(ids), _fp(rows),
+                                    len(ids), value.shape[-1], self.lr)
             return
         super().apply_sparse(value, ids, rows, state)
 
@@ -170,9 +175,9 @@ class ServerMomentum(ServerOptimizer):
 
     def apply_dense(self, value, grad, state):
         if _dense_ready(value, grad, state["v"]):
-            _NATIVE.ps_dense_momentum(_fp(value), _fp(state["v"]),
-                                      _fp(grad), value.size, self.lr,
-                                      self.momentum, int(self.nesterov))
+            _native().ps_dense_momentum(_fp(value), _fp(state["v"]),
+                                        _fp(grad), value.size, self.lr,
+                                        self.momentum, int(self.nesterov))
             return
         v = state["v"]
         v *= self.momentum
@@ -184,7 +189,7 @@ class ServerMomentum(ServerOptimizer):
 
     def apply_sparse(self, value, ids, rows, state):
         if _sparse_ready(value, ids, rows, state["v"]):
-            _NATIVE.ps_sparse_momentum(
+            _native().ps_sparse_momentum(
                 _fp(value), _fp(state["v"]), _ip(ids), _fp(rows),
                 len(ids), value.shape[-1], self.lr, self.momentum,
                 int(self.nesterov))
@@ -217,16 +222,16 @@ class ServerAdaGrad(ServerOptimizer):
 
     def apply_dense(self, value, grad, state):
         if _dense_ready(value, grad, state["acc"]):
-            _NATIVE.ps_dense_adagrad(_fp(value), _fp(state["acc"]),
-                                     _fp(grad), value.size, self.lr,
-                                     self.eps)
+            _native().ps_dense_adagrad(_fp(value), _fp(state["acc"]),
+                                       _fp(grad), value.size, self.lr,
+                                       self.eps)
             return
         state["acc"] += grad * grad
         value -= self.lr * grad / (np.sqrt(state["acc"]) + self.eps)
 
     def apply_sparse(self, value, ids, rows, state):
         if _sparse_ready(value, ids, rows, state["acc"]):
-            _NATIVE.ps_sparse_adagrad(
+            _native().ps_sparse_adagrad(
                 _fp(value), _fp(state["acc"]), _ip(ids), _fp(rows),
                 len(ids), value.shape[-1], self.lr, self.eps)
             return
@@ -253,9 +258,9 @@ class ServerAdam(ServerOptimizer):
         t = int(state["t"])
         m, v = state["m"], state["v"]
         if _dense_ready(value, grad, m, v):
-            _NATIVE.ps_dense_adam(_fp(value), _fp(m), _fp(v), _fp(grad),
-                                  value.size, self.lr, self.beta1,
-                                  self.beta2, self.eps, t)
+            _native().ps_dense_adam(_fp(value), _fp(m), _fp(v), _fp(grad),
+                                    value.size, self.lr, self.beta1,
+                                    self.beta2, self.eps, t)
             return
         m *= self.beta1
         m += (1 - self.beta1) * grad
@@ -268,7 +273,7 @@ class ServerAdam(ServerOptimizer):
     def apply_sparse(self, value, ids, rows, state):
         if _sparse_ready(value, ids, rows, state["m"], state["v"]):
             state["t"] += 1
-            _NATIVE.ps_sparse_adam(
+            _native().ps_sparse_adam(
                 _fp(value), _fp(state["m"]), _fp(state["v"]), _ip(ids),
                 _fp(rows), len(ids), value.shape[-1], self.lr,
                 self.beta1, self.beta2, self.eps, int(state["t"]))
@@ -687,8 +692,8 @@ class PSServer:
             if p.value.ndim == 2 and _f32_ready(p.value):
                 _check_ids(ids, p.value.shape[0])
                 out = np.empty((len(ids), p.value.shape[1]), np.float32)
-                _NATIVE.ps_sparse_gather(_fp(p.value), _ip(ids), _fp(out),
-                                         len(ids), p.value.shape[1])
+                _native().ps_sparse_gather(_fp(p.value), _ip(ids), _fp(out),
+                                           len(ids), p.value.shape[1])
                 return self._q_out(out, quant)
             return self._q_out(p.value[ids], quant)
 
@@ -702,16 +707,15 @@ class PSServer:
             if p.optimizer is not None:
                 p.optimizer.apply_sparse(p.value, ids, rows, p.state)
             elif _sparse_ready(p.value, ids, rows):
-                _NATIVE.ps_sparse_accum(_fp(p.value), _ip(ids), _fp(rows),
-                                        len(ids), p.value.shape[1])
+                _native().ps_sparse_accum(_fp(p.value), _ip(ids), _fp(rows),
+                                          len(ids), p.value.shape[1])
             else:
                 np.add.at(p.value, ids, rows)
             if p.versions is not None:
-                if _NATIVE is not None and \
-                        p.versions.flags["C_CONTIGUOUS"]:
+                if p.versions.flags["C_CONTIGUOUS"]:
                     _check_ids(ids, len(p.versions))
-                    _NATIVE.ps_bump_versions(_ip(p.versions), _ip(ids),
-                                             len(ids))
+                    _native().ps_bump_versions(_ip(p.versions), _ip(ids),
+                                               len(ids))
                 else:
                     p.versions[np.unique(ids)] += 1
 

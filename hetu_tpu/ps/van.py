@@ -44,32 +44,27 @@ def _load():
         lib = build_and_load("ps_van.cpp", "libps_van.so",
                              extra_flags=("-pthread",),
                              deps=("ps_kernels.h",))
-        if lib is not None:
-            lib.van_create.restype = ctypes.c_void_p
-            lib.van_listen.restype = ctypes.c_int
-            lib.van_listen.argtypes = [ctypes.c_void_p, ctypes.c_int,
-                                       ctypes.c_int]
-            f32p = ctypes.POINTER(ctypes.c_float)
-            i64p = ctypes.POINTER(ctypes.c_int64)
-            lib.van_register_sgd_table.argtypes = [
-                ctypes.c_void_p, ctypes.c_uint32, f32p, ctypes.c_int64,
-                ctypes.c_int64, ctypes.c_float, i64p]
-            lib.van_register_table.argtypes = [
-                ctypes.c_void_p, ctypes.c_uint32, f32p, ctypes.c_int64,
-                ctypes.c_int64, ctypes.c_int, ctypes.c_float,
-                ctypes.c_float, ctypes.c_float, ctypes.c_float,
-                ctypes.c_int, f32p, f32p, i64p, i64p]
-            for name in ("van_table_lock", "van_table_unlock",
-                         "van_stop", "van_destroy"):
-                getattr(lib, name).argtypes = [ctypes.c_void_p] \
-                    if name in ("van_stop", "van_destroy") else \
-                    [ctypes.c_void_p, ctypes.c_uint32]
-        _LIB = lib if lib is not None else False
-    return _LIB or None
-
-
-def van_available():
-    return _load() is not None
+        lib.van_create.restype = ctypes.c_void_p
+        lib.van_listen.restype = ctypes.c_int
+        lib.van_listen.argtypes = [ctypes.c_void_p, ctypes.c_int,
+                                   ctypes.c_int]
+        f32p = ctypes.POINTER(ctypes.c_float)
+        i64p = ctypes.POINTER(ctypes.c_int64)
+        lib.van_register_sgd_table.argtypes = [
+            ctypes.c_void_p, ctypes.c_uint32, f32p, ctypes.c_int64,
+            ctypes.c_int64, ctypes.c_float, i64p]
+        lib.van_register_table.argtypes = [
+            ctypes.c_void_p, ctypes.c_uint32, f32p, ctypes.c_int64,
+            ctypes.c_int64, ctypes.c_int, ctypes.c_float,
+            ctypes.c_float, ctypes.c_float, ctypes.c_float,
+            ctypes.c_int, f32p, f32p, i64p, i64p]
+        for name in ("van_table_lock", "van_table_unlock",
+                     "van_stop", "van_destroy"):
+            getattr(lib, name).argtypes = [ctypes.c_void_p] \
+                if name in ("van_stop", "van_destroy") else \
+                [ctypes.c_void_p, ctypes.c_uint32]
+        _LIB = lib
+    return _LIB
 
 
 class NativeVan:
@@ -77,8 +72,6 @@ class NativeVan:
 
     def __init__(self):
         lib = _load()
-        if lib is None:
-            raise RuntimeError("native van unavailable (no toolchain)")
         self._l = lib
         self._h = lib.van_create()
         self._tables = {}            # key -> value array (keepalive)

@@ -5,9 +5,8 @@ satellites; ADVICE findings).
   config row from the headline's nested matrix (the top-level headline
   value is the stale bert_base number on subset runs) and must not
   declare a winner on an all-equal group (string tie-break regression).
-- ``bin/tpu_watchdog.sh``: only the suite's distinctive flock-refusal
-  exit code (75) is exempt from the MAX_FIRES budget; a genuine exit-1
-  must count, or the watchdog re-fires the battery forever.
+- ``bin/run_onchip_suite.sh``: a second suite refuses to run while one
+  holds the lock, with the distinctive exit code 75.
 - ``bench.py``: the outlier re-probe records the DISCARDED reading
   (never a duplicate of the kept one), and HETU_BENCH_FORCE_FLASH
   stamps ``flash_forced`` provenance into the result row.
@@ -15,7 +14,6 @@ satellites; ADVICE findings).
 
 import json
 import os
-import stat
 import subprocess
 import sys
 
@@ -93,48 +91,7 @@ class TestSummarizeOnchip:
         assert "bert4l winner: flash=0 (1987.0)" in out
 
 
-class TestWatchdogExitCodes:
-    def _run_watchdog(self, tmp_path, suite_rc, timeout_s):
-        d = str(tmp_path)
-        counter = os.path.join(d, "fires")
-        stub = os.path.join(d, "suite_stub.sh")
-        with open(stub, "w") as f:
-            f.write("#!/bin/bash\n"
-                    f"echo x >> {counter}\n"
-                    f"exit {suite_rc}\n")
-        os.chmod(stub, os.stat(stub).st_mode | stat.S_IEXEC)
-        env = dict(os.environ,
-                   MAX_FIRES="2",
-                   PROBE_CMD="true",
-                   SUITE_CMD=f"bash {stub}",
-                   DONE_FILE=os.path.join(d, "done"))
-        r = subprocess.run(
-            ["timeout", str(timeout_s), "bash",
-             os.path.join(REPO, "bin", "tpu_watchdog.sh"), "0.1", d],
-            capture_output=True, text=True, env=env,
-            timeout=timeout_s + 30)
-        fires = 0
-        if os.path.exists(counter):
-            with open(counter) as f:
-                fires = len(f.readlines())
-        return r, fires
-
-    def test_lock_refusal_75_never_counts(self, tmp_path):
-        """rc=75 (flock refusal) keeps re-probing past MAX_FIRES — the
-        watchdog must still be alive (killed by our timeout, rc 124)
-        after more firings than the budget."""
-        r, fires = self._run_watchdog(tmp_path, suite_rc=75, timeout_s=5)
-        assert r.returncode == 124, (r.returncode, r.stdout, r.stderr)
-        assert fires > 2
-
-    def test_genuine_failure_counts_and_gives_up(self, tmp_path):
-        """rc=1 (a real early failure) must consume the budget: exactly
-        MAX_FIRES firings, then exit 2 (give up) — the regression was
-        rc=1 being treated as 'not an attempt' and re-firing forever."""
-        r, fires = self._run_watchdog(tmp_path, suite_rc=1, timeout_s=20)
-        assert r.returncode == 2, (r.returncode, r.stdout, r.stderr)
-        assert fires == 2
-
+class TestSuiteLock:
     def test_suite_flock_refusal_is_75(self, tmp_path):
         """bin/run_onchip_suite.sh itself exits 75 when the lock is
         held.  The holder script must NOT tail-exec the suite (bash
@@ -142,7 +99,7 @@ class TestWatchdogExitCodes:
         it), so the suite runs mid-script with commands after it."""
         script = (
             "cd %s || exit 98\n"
-            "exec 9>.tpu_watchdog.lock\n"
+            "exec 9>.onchip_suite.lock\n"
             "flock -n 9 || exit 99\n"
             "bash bin/run_onchip_suite.sh %s/log\n"
             "ec=$?\n"
@@ -176,7 +133,7 @@ class TestBenchProvenance:
         # failed/skipped retry records nothing
         probes, numeric = {48: 216.0}, {48: 216.0}
         bench._record_retry_probe(probes, numeric, 48, 216.0,
-                                  "probe timed out (tunnel degraded?)")
+                                  "RuntimeError: probe failed")
         assert set(probes) == {48}
 
     def test_bench_lm_records_flash_forced(self, monkeypatch):

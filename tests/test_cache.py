@@ -18,10 +18,7 @@ W = 4
 
 
 def impls():
-    out = [PythonCache]
-    if NativeCache.load_lib() is not None:
-        out.append(NativeCache)
-    return out
+    return [PythonCache, NativeCache]
 
 
 @pytest.mark.parametrize("Cache", impls())
@@ -73,8 +70,6 @@ def test_update_writeback_and_collect(Cache):
     assert len(ids2) == 0
 
 
-@pytest.mark.skipif(NativeCache.load_lib() is None,
-                    reason="no C++ toolchain")
 def test_native_python_equivalence_random_workload():
     rng = np.random.RandomState(0)
     nc = NativeCache(limit=8, width=W, policy="LRU")

@@ -1,0 +1,178 @@
+"""Ask the chip's compiler, without the chip (ISSUE 22).
+
+The TPU compiler is installed wherever the tests run, and it compiles
+for a chip that is described and not attached.  Every kernel of the two
+main paths is compiled here for a TPU v5e at the real GPT-2 widths with
+``interpret=False`` — what interpret mode cannot show (VMEM overruns,
+unaligned tiles, kernels GSPMD cannot partition) fails here, at no chip
+time.  Nothing runs, so nothing here is a result or a time.
+
+All of these live in this one file, and the topology is described
+inside a module-scoped fixture: only the worker that is handed this
+file loads libtpu.  Do not move the call to import time, to
+``conftest.py`` or into an ``autouse`` fixture.
+"""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+from hetu_tpu.kernels import decode_attention as da
+from hetu_tpu.kernels import flash_attention as fa
+from hetu_tpu.kernels.ragged_attention import (
+    ragged_attention, ragged_paged_attention)
+
+DH, S_MAX, BLOCK, SLOTS = 64, 1024, 16, 8
+
+
+@pytest.fixture(scope="module")
+def no_compile_cache():
+    """A compile for a described chip is written to the persistent cache
+    but cannot be read back without the chip; keep it out."""
+    from jax.experimental.compilation_cache import compilation_cache
+    old = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", old)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def topo(no_compile_cache):
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def sds(topo):
+    """Shape-with-sharding factory on the first described chip."""
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def make(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    return make
+
+
+def compiled_text(fn, *args):
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text, \
+        "the kernel was interpreted or replaced: nothing was proven"
+    return text
+
+
+# ------------------------------------------------------------------- #
+# training path: the flash kernel
+# ------------------------------------------------------------------- #
+
+@pytest.mark.parametrize("seq,masked", [(1024, False), (512, True)],
+                         ids=["gpt2-s1024-causal", "bert-s512-kv_lens"])
+def test_flash_fwd_bwd(sds, monkeypatch, seq, masked):
+    # flash_attention takes no interpret argument: it asks the backend,
+    # which is the CPU here — steer it in the test, not by a program option
+    monkeypatch.setattr(fa, "_use_interpret", lambda: False)
+    B, H = 8, 12
+    qkv = sds((B, seq, H, DH), jnp.bfloat16)
+
+    def loss(q, k, v, lens=None):
+        o = fa.flash_attention(q, k, v, causal=not masked, kv_lens=lens)
+        return (o.astype(jnp.float32) ** 2).sum()
+
+    args = (qkv, qkv, qkv) + ((sds((B,), jnp.int32),) if masked else ())
+    text = compiled_text(jax.value_and_grad(loss, argnums=(0, 1, 2)), *args)
+    assert text.count("tpu_custom_call") >= 3      # fwd, dkv, dq
+
+
+def test_flash_op_under_a_mesh(topo, monkeypatch):
+    """GSPMD cannot partition a Mosaic kernel ("Mosaic kernels cannot be
+    automatically partitioned"): the graph op runs it per shard inside
+    shard_map, or a dp x tp train step does not lower on the chip."""
+    import hetu_tpu as ht
+    from hetu_tpu.graph.node import TraceContext
+    monkeypatch.setattr(fa, "_use_interpret", lambda: False)
+    mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("dp", "tp"))
+    op = ht.flash_attention_op(ht.placeholder_op("q"), ht.placeholder_op("k"),
+                               ht.placeholder_op("v"), causal=True)
+    x = jax.ShapeDtypeStruct(
+        (8, 1024, 12, DH), jnp.bfloat16,
+        sharding=NamedSharding(mesh, P("dp", None, "tp", None)))
+    tc = TraceContext(mesh=mesh)
+    text = compiled_text(lambda q, k, v: op.compute([q, k, v], tc), x, x, x)
+    assert "all-gather" not in text     # batch and heads stay sharded
+
+
+# ------------------------------------------------------------------- #
+# serving path: the mixed-mode ragged kernel (the TPU default)
+# ------------------------------------------------------------------- #
+
+def _pool(sds, heads, quant, dtype):
+    T = S_MAX // BLOCK
+    N = SLOTS * T + 1
+    pdt = jnp.int8 if quant else dtype
+    pool = sds((N, BLOCK, heads, DH), pdt)
+    scales = (sds((N, BLOCK, heads), jnp.float32),) * 2 if quant else ()
+    return pool, sds((SLOTS, T), jnp.int32), scales
+
+
+@pytest.mark.parametrize("kv", ["bf16", "int8"])
+@pytest.mark.parametrize("heads,q_len", [
+    (12, 1), (12, 256), (12, 1024),      # GPT-2 small; 1024 = a whole prompt
+    (25, 1), (25, 512),                  # gpt2-xl width
+], ids=lambda v: str(v))
+def test_ragged_paged(sds, heads, q_len, kv):
+    """Q=1024 at H=12 and Q=512 at H=25 were refused before ISSUE 22
+    ("Scoped allocation with size 18.11M and limit 16.00M"): the q-block
+    was one VMEM tile that grew with the prompt."""
+    quant = kv == "int8"
+    pool, tables, scales = _pool(sds, heads, quant, jnp.bfloat16)
+    lens = sds((SLOTS,), jnp.int32)
+
+    def fn(q, pk, pv, lengths, q_lens, bt, *sc):
+        kw = dict(k_scale=sc[0], v_scale=sc[1]) if sc else {}
+        return ragged_paged_attention(q, pk, pv, lengths, q_lens, bt,
+                                      interpret=False, **kw)
+
+    compiled_text(fn, sds((SLOTS, q_len, heads, DH), jnp.bfloat16),
+                  pool, pool, lens, lens, tables, *scales)
+
+
+def test_ragged_contiguous_whole_prompt(sds):
+    kv = sds((SLOTS, S_MAX, 12, DH), jnp.bfloat16)
+    lens = sds((SLOTS,), jnp.int32)
+    compiled_text(
+        lambda q, k, v, n, ql: ragged_attention(q, k, v, n, ql,
+                                                interpret=False),
+        sds((SLOTS, 1024, 12, DH), jnp.bfloat16), kv, kv, lens, lens)
+
+
+# ------------------------------------------------------------------- #
+# serving path: the phase-split kernels ($HETU_SERVE_RAGGED=0)
+# ------------------------------------------------------------------- #
+
+@pytest.mark.parametrize("kernel", [
+    "paged_decode_attention", "paged_block_decode_attention",
+    "paged_verify_attention", "paged_block_verify_attention"])
+def test_phase_split(sds, kernel):
+    """At the widths the engine hands them: one query per slot for
+    decode, a k+1 = 8-position q-block for spec-verify."""
+    H = 12
+    lens = sds((SLOTS,), jnp.int32)
+    verify = "verify" in kernel
+    q = sds((SLOTS, 8, H, DH) if verify else (SLOTS, H, DH), jnp.bfloat16)
+    if "block" in kernel:
+        pool, tables, _ = _pool(sds, H, False, jnp.bfloat16)
+        kv, tail = (pool, pool), (tables,)
+    else:
+        c = sds((SLOTS, S_MAX, H, DH), jnp.bfloat16)
+        kv, tail = (c, c), ()
+    extra = (lens,) if verify else ()
+    fn = getattr(da, kernel)
+    compiled_text(lambda *a: fn(*a, interpret=False),
+                  q, *kv, lens, *extra, *tail)

@@ -31,6 +31,21 @@ nodes:
         assert c.enable_PS and c.num_servers == 1 and c.num_workers == 2
 
 
+def test_launcher_and_ps_roles_never_initialise_a_backend():
+    """One process per chip (ISSUE 22): ``heturun``'s parent and the PS /
+    scheduler children it spawns only IMPORT jax — a parent that had
+    initialised a backend would hold the chip its workers need."""
+    code = (
+        "import hetu_tpu.launcher, hetu_tpu.ps.server, hetu_tpu.ps.client\n"
+        "from jax._src import xla_bridge\n"
+        "assert not xla_bridge._backends, list(xla_bridge._backends)\n")
+    r = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                       text=True, timeout=120,
+                       cwd=os.path.dirname(os.path.dirname(
+                           os.path.abspath(__file__))))
+    assert r.returncode == 0, r.stderr[-2000:]
+
+
 class TestLaunch:
     def test_launch_runs_target_against_fresh_ps(self):
         def target():
@@ -108,9 +123,6 @@ class TestHeturnTrainEndToEnd:
                                          (0, True)],
                              ids=["bsp", "ssp1", "bsp-van"])
     def test_cluster_yaml_hybrid_training(self, bsp, van):
-        from hetu_tpu.ps.van import van_available
-        if van and not van_available():
-            pytest.skip("no C++ toolchain")
         from hetu_tpu.launcher import _free_port
         d = tempfile.mkdtemp()
         yml = os.path.join(d, "cluster.yml")

@@ -3,13 +3,12 @@ numpy fallback bit-for-bit-ish on every optimizer, dense and sparse
 (reference equivalent: server optimizers in ps-lite server/optimizer.h,
 exercised by tests/pstests)."""
 
+import os
+
 import numpy as np
 import pytest
 
 from hetu_tpu.ps import server as S
-
-pytestmark = pytest.mark.skipif(
-    S._NATIVE is None, reason="no C++ toolchain: native core not built")
 
 OPTS = [
     ("sgd", {"learning_rate": 0.1}),
@@ -36,7 +35,7 @@ def test_dense_native_matches_numpy(opt, kw, monkeypatch):
              for _ in range(5)]
     for g in grads:
         o.apply_dense(v_nat, g, s_nat)
-    monkeypatch.setattr(S, "_NATIVE", None)
+    monkeypatch.setattr(S, "_f32_ready", lambda *a: False)
     for g in grads:
         o.apply_dense(v_np, g, s_np)
     np.testing.assert_allclose(v_nat, v_np, rtol=1e-5, atol=1e-6)
@@ -57,10 +56,52 @@ def test_sparse_native_matches_numpy(opt, kw, monkeypatch):
         pushes.append((ids, rows))
     for ids, rows in pushes:
         o.apply_sparse(v_nat, ids, rows, s_nat)
-    monkeypatch.setattr(S, "_NATIVE", None)
+    monkeypatch.setattr(S, "_f32_ready", lambda *a: False)
     for ids, rows in pushes:
         o.apply_sparse(v_np, ids, rows, s_np)
     np.testing.assert_allclose(v_nat, v_np, rtol=1e-4, atol=1e-5)
+
+
+def test_native_build_is_keyed_by_the_sources(tmp_path, monkeypatch):
+    """What is loaded is decided by the committed sources alone (ISSUE
+    22): the library lands in ``_build/<hash of source+headers+flags>/``,
+    a changed source builds elsewhere, a stray ``.so`` next to the source
+    is never looked at, and a build that fails raises."""
+    import shutil
+    from hetu_tpu import native
+    for f in ("cache.cpp",):
+        shutil.copy(os.path.join(native._DIR, f), tmp_path / f)
+    monkeypatch.setattr(native, "_DIR", str(tmp_path))
+    monkeypatch.setattr(native, "_BUILD", str(tmp_path / "_build"))
+    (tmp_path / "libhetu_cache.so").write_bytes(b"not a library")
+    native.build_and_load("cache.cpp", "libhetu_cache.so")
+    first = native.loaded["libhetu_cache.so"]
+    assert os.path.dirname(os.path.dirname(first)) == str(tmp_path / "_build")
+    with open(tmp_path / "cache.cpp", "a") as f:
+        f.write("\n// changed\n")
+    native.build_and_load("cache.cpp", "libhetu_cache.so")
+    assert native.loaded["libhetu_cache.so"] != first
+    with open(tmp_path / "cache.cpp", "a") as f:
+        f.write("\nthis is not C++\n")
+    with pytest.raises(RuntimeError, match="g\\+\\+ failed building"):
+        native.build_and_load("cache.cpp", "libhetu_cache.so")
+
+
+def test_import_builds_no_native_library():
+    """The PS core is built on the first PS update or gather, not at
+    ``import hetu_tpu``: a host without g++ fails a PS run only, never a
+    GPT trainer or server that imports the package."""
+    import subprocess
+    import sys
+    code = ("import hetu_tpu, hetu_tpu.native as n; "
+            "assert not n.loaded, n.loaded; "
+            "import numpy as np; from hetu_tpu.ps import server as S; "
+            "v = np.ones((2, 2), np.float32); "
+            "S.ServerSGD(0.5).apply_dense(v, v.copy(), {}); "
+            "assert list(n.loaded) == ['libps_core.so'], n.loaded; "
+            "assert v[0, 0] == 0.5")
+    subprocess.run([sys.executable, "-c", code], check=True,
+                   env={**os.environ, "JAX_PLATFORMS": "cpu"})
 
 
 def test_duplicate_ids_update_stateful_row_once():
@@ -99,9 +140,7 @@ class TestNativeVan:
 
     @pytest.fixture()
     def van_pair(self):
-        from hetu_tpu.ps.van import NativeVan, VanClient, van_available
-        if not van_available():
-            pytest.skip("no C++ toolchain")
+        from hetu_tpu.ps.van import NativeVan, VanClient
         van = NativeVan()
         port = van.listen()
         value = van.register_sgd_table(
@@ -144,9 +183,7 @@ class TestNativeVan:
             cli.pull(99, np.array([0]))
 
     def test_version_counters_bump(self):
-        from hetu_tpu.ps.van import NativeVan, VanClient, van_available
-        if not van_available():
-            pytest.skip("no C++ toolchain")
+        from hetu_tpu.ps.van import NativeVan, VanClient
         van = NativeVan()
         port = van.listen()
         versions = np.zeros(16, np.int64)
@@ -161,9 +198,7 @@ class TestNativeVan:
         van.stop()
 
     def test_concurrent_clients_serialize_on_table_mutex(self):
-        from hetu_tpu.ps.van import NativeVan, VanClient, van_available
-        if not van_available():
-            pytest.skip("no C++ toolchain")
+        from hetu_tpu.ps.van import NativeVan, VanClient
         import threading
         van = NativeVan()
         port = van.listen()
@@ -196,9 +231,7 @@ class TestVanServerIntegration:
 
     def test_both_tiers_update_one_buffer(self):
         from hetu_tpu.ps.server import PSServer
-        from hetu_tpu.ps.van import VanClient, van_available
-        if not van_available():
-            pytest.skip("no C++ toolchain")
+        from hetu_tpu.ps.van import VanClient
         PSServer._instance = None
         srv = PSServer.get()
         srv.param_init("emb", (32, 4), "constant", 0.0, opt="sgd",
@@ -226,9 +259,7 @@ class TestVanServerIntegration:
 
     def test_concurrent_tiers_serialize(self):
         from hetu_tpu.ps.server import PSServer
-        from hetu_tpu.ps.van import VanClient, van_available
-        if not van_available():
-            pytest.skip("no C++ toolchain")
+        from hetu_tpu.ps.van import VanClient
         import threading
         PSServer._instance = None
         srv = PSServer.get()
@@ -268,9 +299,6 @@ class TestVanServerIntegration:
         r5 widened the family to include optimizer-less (accumulate)
         2-D tables, so the non-qualifying example is a 1-D vector."""
         from hetu_tpu.ps.server import PSServer
-        from hetu_tpu.ps.van import van_available
-        if not van_available():
-            pytest.skip("no C++ toolchain")
         PSServer._instance = None
         srv = PSServer.get()
         srv.param_init("vec", (8,), "constant", 0.0, opt="sgd",
@@ -290,9 +318,7 @@ class TestVanServerIntegration:
         (reference server/optimizer.h via zmq_van); an adam table's
         slot state and step counter are SHARED with the python tier."""
         from hetu_tpu.ps.server import PSServer
-        from hetu_tpu.ps.van import VanClient, van_available
-        if not van_available():
-            pytest.skip("no C++ toolchain")
+        from hetu_tpu.ps.van import VanClient
         PSServer._instance = None
         srv = PSServer.get()
         srv.param_init("ad", (8, 2), "constant", 0.0, opt="adam",
@@ -326,9 +352,7 @@ def test_van_optimizer_matches_python_tier(optname, kw):
     python tier's apply_sparse would: same value trajectory, same slot
     state, advanced in the registered (shared) buffers."""
     from hetu_tpu.ps.server import SERVER_OPTIMIZERS
-    from hetu_tpu.ps.van import NativeVan, VanClient, van_available
-    if not van_available():
-        pytest.skip("no C++ toolchain")
+    from hetu_tpu.ps.van import NativeVan, VanClient
     rng = np.random.RandomState(7)
     opt_py = SERVER_OPTIMIZERS[optname](**kw)
     opt_van = SERVER_OPTIMIZERS[optname](**kw)
@@ -359,9 +383,6 @@ def test_van_optimizer_matches_python_tier(optname, kw):
 
 def test_van_served_keys_refuse_buffer_replacement():
     from hetu_tpu.ps.server import PSServer
-    from hetu_tpu.ps.van import van_available
-    if not van_available():
-        pytest.skip("no C++ toolchain")
     PSServer._instance = None
     srv = PSServer.get()
     srv.param_init("k", (8, 2), "constant", 0.0, opt="sgd",
@@ -395,9 +416,7 @@ def test_van_version_dedup_matches_python_tier():
     """[5,5,5] in one push bumps versions[5] ONCE on both tiers (HET
     staleness counters must not diverge by tier)."""
     from hetu_tpu.ps.server import PSServer
-    from hetu_tpu.ps.van import VanClient, van_available
-    if not van_available():
-        pytest.skip("no C++ toolchain")
+    from hetu_tpu.ps.van import VanClient
     PSServer._instance = None
     srv = PSServer.get()
     srv.param_init("vd", (16, 2), "constant", 0.0, opt="sgd",
@@ -421,9 +440,6 @@ def test_shutdown_restores_python_locks():
     """PSFunc ops on a formerly-van-served key keep working after
     shutdown (the composite lock is unwound, no dead C++ handle)."""
     from hetu_tpu.ps.server import PSServer
-    from hetu_tpu.ps.van import van_available
-    if not van_available():
-        pytest.skip("no C++ toolchain")
     PSServer._instance = None
     srv = PSServer.get()
     srv.param_init("s", (8, 2), "constant", 1.0, opt="sgd",
@@ -447,9 +463,7 @@ def test_van_autoserve_and_discovery_over_tcp():
     push through it consistently with the python surface."""
     from hetu_tpu.ps.server import PSServer
     from hetu_tpu.ps.client import PSClient, _TCPTransport
-    from hetu_tpu.ps.van import VanClient, van_available
-    if not van_available():
-        pytest.skip("no C++ toolchain")
+    from hetu_tpu.ps.van import VanClient
     PSServer._instance = None
     PSClient._instance = None
     srv = PSServer.get()
@@ -495,9 +509,7 @@ class TestVanCacheSync:
         return srv
 
     def test_sync_embedding_parity_with_python_tier(self):
-        from hetu_tpu.ps.van import VanClient, van_available
-        if not van_available():
-            pytest.skip("no C++ toolchain")
+        from hetu_tpu.ps.van import VanClient
         srv = self._server()
         try:
             port, keymap = srv.serve_van(["ct"])
@@ -533,10 +545,7 @@ class TestVanCacheSync:
         """PSClient.sync_embedding/push_embedding reach the C++ tier
         when the table is van-served (cstable's hot verbs)."""
         from hetu_tpu.ps.server import PSServer
-        from hetu_tpu.ps.van import van_available
         import hetu_tpu.ps.client as psc
-        if not van_available():
-            pytest.skip("no C++ toolchain")
         srv = self._server()
         psc.PSClient._instance = None
         try:
@@ -563,10 +572,7 @@ class TestVanCacheSync:
         still equals the dense run."""
         import hetu_tpu as ht
         from hetu_tpu.ps.server import PSServer
-        from hetu_tpu.ps.van import van_available
         import hetu_tpu.ps.client as psc
-        if not van_available():
-            pytest.skip("no C++ toolchain")
 
         def build():
             ids = ht.placeholder_op("ids")
@@ -632,9 +638,7 @@ class TestVanFallbackContract:
         return srv, psc.PSClient()
 
     def test_send_side_failure_falls_back_without_double_apply(self):
-        from hetu_tpu.ps.van import VanTransportError, van_available
-        if not van_available():
-            pytest.skip("no C++ toolchain")
+        from hetu_tpu.ps.van import VanTransportError
         srv, c = self._pair()
         try:
             srv.serve_van(["fb"])
@@ -660,10 +664,8 @@ class TestVanFallbackContract:
             self._reset()
 
     def test_response_side_failure_raises_instead_of_double_apply(self):
-        from hetu_tpu.ps.van import VanTransportError, van_available
+        from hetu_tpu.ps.van import VanTransportError
         from hetu_tpu.ps.client import PSConnectionError
-        if not van_available():
-            pytest.skip("no C++ toolchain")
         srv, c = self._pair()
         try:
             srv.serve_van(["fb"])
@@ -686,9 +688,6 @@ class TestVanFallbackContract:
     def test_late_serve_van_discovered_after_refresh_window(self):
         """Traffic starts python-tier; serve_van afterwards is picked
         up once the per-thread refresh window elapses."""
-        from hetu_tpu.ps.van import van_available
-        if not van_available():
-            pytest.skip("no C++ toolchain")
         srv, c = self._pair()
         try:
             ids = np.array([0], np.int64)

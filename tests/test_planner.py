@@ -363,7 +363,7 @@ class TestChipCalibration:
         must produce a plan."""
         import os
         from hetu_tpu.planner.chip_calibration import (
-            CALIBRATION_FILE, load_calibration, SPEC_PEAKS)
+            CALIBRATION_FILE, load_calibration, spec_peak_tflops)
         from hetu_tpu.planner.search import PlannerSearch
         from hetu_tpu.planner.cost_model import LayerSpec
         if not os.path.exists(CALIBRATION_FILE):
@@ -375,12 +375,9 @@ class TestChipCalibration:
         if art.get("platform") == "cpu":
             import pytest
             pytest.skip("artifact is a CPU small-mode placeholder")
-        kind = art["device_kind"].lower()
-        spec_peak = next((p for sub, p in SPEC_PEAKS if sub in kind),
-                         None)
-        if spec_peak is not None:
-            for d, v in art["matmul_tflops_bf16"].items():
-                assert v is None or v <= spec_peak, (d, v)
+        spec_peak = spec_peak_tflops(art["device_kind"])
+        for d, v in art["matmul_tflops_bf16"].items():
+            assert v is None or v <= spec_peak, (d, v)
         spec = load_calibration(n_devices=8)
         assert spec.flops_per_sec > 1e12   # a real chip, not a CPU
         layers = [LayerSpec.transformer_encoder(768, 512)

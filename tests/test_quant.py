@@ -428,7 +428,7 @@ class TestCommQuantPair:
         assert quantized_collectives(seq), \
             "no int8 collective in the traced program"
         f = shard_map(body, mesh=mesh, in_specs=P(), out_specs=P(),
-                      check_rep=False)
+                      check_vma=False)
         x = np.random.RandomState(13).randn(8, 32).astype(np.float32)
         out = np.asarray(jax.jit(f)(x))
         ref = n * x

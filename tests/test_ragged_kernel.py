@@ -133,6 +133,67 @@ class TestRaggedKernel:
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    atol=2e-5, rtol=2e-5)
 
+    # a q-block longer than one tile (ISSUE 22: VMEM is bounded by
+    # _MAX_ROWS, so a long prompt is several q-tiles): every live row
+    # matches the reference exactly as a one-tile block does, wholly
+    # dead tiles are skipped and come back zero
+    @pytest.mark.parametrize("layout", ["contiguous", "paged",
+                                        "paged-int8"])
+    def test_tiled_q_block_matches_reference(self, layout, monkeypatch):
+        from hetu_tpu.kernels import ragged_attention as ra
+        monkeypatch.setattr(ra, "_ONE_TILE_ROWS", 8)
+        monkeypatch.setattr(ra, "_MAX_ROWS", 8)     # H=2 -> 4-query tiles
+        assert ra._q_tile(16, 2) == 4
+        q, k, v, lens, ql = _wave(Q=16, qlens=(16, 1, 6, 0),
+                                  lens=(40, 33, 6, 0))
+        if layout == "contiguous":
+            got = ragged_attention(q, k, v, lens, ql, block_k=16,
+                                   interpret=True)
+            want = ragged_masked_reference(q, k, v, lens, ql)
+        else:
+            pk, pv, tables = _to_pool(k, v)
+            kw = {}
+            if layout == "paged-int8":
+                pk, ks = _quantize(pk)
+                pv, vs = _quantize(pv)
+                kw = dict(k_scale=ks, v_scale=vs)
+            got = ragged_paged_attention(q, pk, pv, lens, ql, tables,
+                                         interpret=True, **kw)
+            want = ragged_paged_reference(q, pk, pv, lens, ql, tables,
+                                          **kw)
+        got, want = np.asarray(got), np.asarray(want)
+        for b, n in enumerate(ql):
+            live = -(-max(int(n), 1) // 4) * 4   # rows of live tiles
+            np.testing.assert_allclose(got[b, :live], want[b, :live],
+                                       atol=2e-5, rtol=2e-5)
+            assert not got[b, live:].any()
+
+    def test_dead_q_tiles_fetch_no_kv(self):
+        """The kv index map, walked in grid order (a changed block index
+        is one DMA): a decode slot (q_len 1) in a wave of eight q-tiles
+        fetches each of its live kv blocks ONCE — its seven dead tiles
+        stay on the block the live tile ended on — a verify slot
+        likewise, and a whole-prompt slot fetches one causal triangle."""
+        from hetu_tpu.kernels.ragged_attention import _kv_step_block
+        tq, bk, n_t, n_kv = 128, 16, 8, 64
+        lens = np.array([900, 1024, 300, 0], np.int32)
+        qlens = np.array([1, 1024, 5, 0], np.int32)
+
+        def fetches(b):
+            seen, n = None, 0
+            for t in range(n_t):
+                for j in range(n_kv):
+                    blk = int(_kv_step_block(lens, qlens, b, t, j, tq, bk))
+                    n += blk != seen
+                    seen = blk
+            return n
+
+        assert fetches(0) == -(-900 // bk)          # 57 live blocks, once
+        assert fetches(2) == -(-300 // bk)
+        assert fetches(3) == 1                      # empty slot: block 0
+        # tile t of the whole prompt sees (t + 1) * tq positions
+        assert fetches(1) == sum((t + 1) * tq // bk for t in range(n_t))
+
     def test_zero_length_slot_returns_zeros(self):
         q, k, v, lens, ql = _wave(qlens=(4, 1, 2, 0), lens=(17, 33, 5, 0))
         got = np.asarray(ragged_attention(q, k, v, lens, ql, block_k=16,

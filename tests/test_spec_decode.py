@@ -185,7 +185,11 @@ class TestVerifyKernel:
 class TestVerifyStep:
     def test_matches_sequential_decode_steps(self, model):
         """``_verify_step`` over a Q-block == Q sequential
-        ``_decode_step`` calls: logits bitwise, cache bitwise."""
+        ``_decode_step`` calls: the same argmax token at every live
+        position (the property the engine's acceptance rule relies
+        on), logits equal to f32 round-off (a batched [B, Q] matmul
+        and Q [B] matmuls may accumulate in a different order), and so
+        the cache rows they write."""
         p, cfg = model
         name, L, H = "sp", 2, 2
         Dh, S = 8, 32
@@ -215,14 +219,17 @@ class TestVerifyStep:
             l2 = np.asarray(l2)
             for b in range(B):
                 if j < qlen[b]:
-                    np.testing.assert_array_equal(lv[b, j], l2[b])
+                    np.testing.assert_allclose(lv[b, j], l2[b],
+                                               rtol=1e-5, atol=1e-6)
+                    assert lv[b, j].argmax() == l2[b].argmax()
             p2 = p2 + 1
-        # live cache region bitwise equal (dead verify positions land
-        # beyond each slot's live length)
+        # live cache region equal to the same round-off (dead verify
+        # positions land beyond each slot's live length)
         for b in range(B):
             n = 6 + int(qlen[b])
-            np.testing.assert_array_equal(
-                np.asarray(ckv)[:, b, :n], np.asarray(ck2)[:, b, :n])
+            np.testing.assert_allclose(
+                np.asarray(ckv)[:, b, :n], np.asarray(ck2)[:, b, :n],
+                rtol=1e-5, atol=1e-6)
 
 
 # ------------------------------------------------------------------- #
