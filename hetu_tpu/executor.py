@@ -21,6 +21,7 @@ from __future__ import annotations
 
 import os
 import pickle
+import re
 import threading
 
 import numpy as np
@@ -276,6 +277,20 @@ def gather_feeds(sub, feed_dict, peek=False):
     return feeds
 
 
+_SERIAL = re.compile(r"_(in)?\d+")
+
+
+def scope_name(node):
+    """The ``jax.named_scope`` a node's compute is traced under: its
+    name without the serial numbers (``Linear_45`` -> ``Linear``,
+    ``grad_Linear_45_in1_213`` -> ``grad_Linear``), so that the device
+    trace tells the flash op, the tied-head loss and the optimizer from
+    the block matmuls under names that survive a rebuild."""
+    if isinstance(node, OptimizerOp):
+        return "optimizer"
+    return _SERIAL.sub("", node.name)
+
+
 class SubExecutor:
     """One named subgraph compiled to a jitted step function, cached per
     feed-shape signature (reference SubExecutor at executor.py:570, but the
@@ -329,6 +344,7 @@ class SubExecutor:
         self._ps_lookup_ids = set(id(n) for n in self.ps_lookups)
         self._prefetched = {}    # lookup node name -> (ids, Future)
         self._compiled = {}
+        self._runs = 0           # run() calls: the spans' step= tag
         # async phase B: one worker drains the grad D2H + PS/cache push
         # off the critical path (reference overlaps push with the next
         # batch via CSEvent streams, stream.py:90-105); ordering with
@@ -394,15 +410,18 @@ class SubExecutor:
                                           vals[id(g.values_node)]))
                     else:
                         grad_vals.append(vals[id(g)])
-                new_opt_states[node.name] = node.apply(
-                    grad_vals, tc, opt_states[node.name],
-                    ps_vars=self.ps_var_names, side_outputs=side_outputs)
+                with jax.named_scope(scope_name(node)):
+                    new_opt_states[node.name] = node.apply(
+                        grad_vals, tc, opt_states[node.name],
+                        ps_vars=self.ps_var_names,
+                        side_outputs=side_outputs)
                 vals[id(node)] = None
             elif id(node) in self.skip_dense:
                 vals[id(node)] = None
             else:
-                vals[id(node)] = node.compute(
-                    [vals[id(i)] for i in node.inputs], tc)
+                with jax.named_scope(scope_name(node)):
+                    vals[id(node)] = node.compute(
+                        [vals[id(i)] for i in node.inputs], tc)
         # dedup the embedding grads on DEVICE: segment-sum per-position
         # rows into the unique-row slots so phase B ships U rows back,
         # mirroring the forward's unique-row feed.  The adjoint carries
@@ -477,13 +496,36 @@ class SubExecutor:
         return min(nums) if nums else None
 
     def run(self, feed_dict, convert_to_numpy_ret_vals=False):
+        """One step of this subgraph.
+
+        Spans, a fixed number a step, all tagged ``step=`` (this
+        subgraph's run count): ``exec.step`` (root, the whole of
+        ``run``) holding ``exec.feed`` (gathering the feeds and joining
+        the previous step's PS push; under a mesh a second one,
+        ``part="place"``, places them), ``exec.phase_a`` (PS lookups),
+        ``exec.compile`` (a new feed signature only), ``exec.dispatch``,
+        ``exec.phase_b`` (PS subgraphs only) and ``exec.fetch`` (only
+        with ``convert_to_numpy_ret_vals``: the host waits for the
+        device there).  ``exec.dispatch`` closes when the asynchronous call
+        returns: it is ENQUEUE time, not step time; the step's device
+        time is in the profiler's trace, under the same span names with
+        the ``hetu.`` prefix."""
+        from . import telemetry
+        self._runs += 1
+        with telemetry.span("exec.step", subgraph=self.name,
+                            step=self._runs):
+            return self._run(feed_dict, convert_to_numpy_ret_vals,
+                             self._runs)
+
+    def _run(self, feed_dict, convert_to_numpy_ret_vals, step):
         from . import telemetry
         ex = self.executor
-        feeds = gather_feeds(self, feed_dict)
-        # read-your-writes: the previous step's async push must land in
-        # the cache/PS before this step's lookups
-        ex.join_ps_push()
-        with telemetry.span("exec.phase_a", subgraph=self.name):
+        with telemetry.span("exec.feed", subgraph=self.name, step=step):
+            feeds = gather_feeds(self, feed_dict)
+            # read-your-writes: the previous step's async push must
+            # land in the cache/PS before this step's lookups
+            ex.join_ps_push()
+        with telemetry.span("exec.phase_a", subgraph=self.name, step=step):
             ps_ids = self._ps_phase_a(feeds)
         feed_sig = tuple(sorted(
             (k, tuple(v.shape), str(v.dtype)) for k, v in feeds.items()))
@@ -493,18 +535,24 @@ class SubExecutor:
             # miswired graph fails HERE with the node named, not as an
             # XLA stack dump out of the compile below (HETU_VALIDATE=1)
             telemetry.inc("exec.compile_cache_miss")
-            with telemetry.span("exec.compile", subgraph=self.name):
+            with telemetry.span("exec.compile", subgraph=self.name,
+                                step=step):
                 from .analysis import validate_subgraph_feeds
                 validate_subgraph_feeds(ex, self, feeds)
                 self._compiled[feed_sig] = self._compile(feed_sig)
         fn = self._compiled[feed_sig]
         if ex.mesh is not None:
-            feeds = {k: ex.device_put_feed(k, v) for k, v in feeds.items()}
+            # placement follows phase A (its rows are feeds too) and the
+            # signature (taken from the host shapes)
+            with telemetry.span("exec.feed", subgraph=self.name,
+                                step=step, part="place"):
+                feeds = {k: ex.device_put_feed(k, v)
+                         for k, v in feeds.items()}
         # dispatch covers trace+compile on a cache-miss step (jax.jit is
         # lazy — the first call lowers); `compiled` marks those spans so
         # the trace attributes the fat step correctly
         with telemetry.span("exec.dispatch", subgraph=self.name,
-                            compiled=compiled_now):
+                            step=step, compiled=compiled_now):
             ex.var_values, ex.opt_states, ex.step, ex.rng, outputs, side \
                 = fn(ex.var_values, ex.opt_states, ex.step, ex.rng, feeds)
         telemetry.inc("exec.steps")
@@ -514,27 +562,22 @@ class SubExecutor:
                 # prefetches (so the prefetched rows see the update);
                 # the main thread returns to the training loop
                 def _push():
-                    with telemetry.span("exec.phase_b",
+                    with telemetry.span("exec.phase_b", step=step,
                                         subgraph=self.name, mode="async"):
                         self._ps_phase_b(side, ps_ids)
                     self._ps_prefetch()
                 ex._ps_push_future = self._phase_b_pool.submit(_push)
             else:
                 with telemetry.span("exec.phase_b", subgraph=self.name,
-                                    mode="sync"):
+                                    step=step, mode="sync"):
                     self._ps_phase_b(side, ps_ids)
                 self._ps_prefetch()
         else:
             self._ps_prefetch()
-        results = []
-        for n, o in zip(self.eval_nodes, outputs):
-            if o is None:
-                results.append(None)
-            elif convert_to_numpy_ret_vals:
-                results.append(np.asarray(o))
-            else:
-                results.append(o)
-        return results
+        if not convert_to_numpy_ret_vals:
+            return list(outputs)
+        with telemetry.span("exec.fetch", subgraph=self.name, step=step):
+            return [None if o is None else np.asarray(o) for o in outputs]
 
     # ------------------------------------------------------------------ #
     # Hybrid/PS host phases (reference ParameterServerCommunicate.py:38-57

@@ -199,6 +199,7 @@ def paged_decode_attention(q, k, v, lengths, *, block_k=128,
         functools.partial(kernel, scale=scale, bk=bk, n_kv=n_kv),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, 1, H, Dh), q.dtype),
+        name="paged_decode",
         interpret=interpret,
     )(lengths.astype(jnp.int32), *operands)
     return out[:, 0]
@@ -308,6 +309,7 @@ def paged_block_decode_attention(q, pool_k, pool_v, lengths,
         functools.partial(kernel, scale=scale, bk=bs, n_kv=T),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, 1, H, Dh), q.dtype),
+        name="paged_block_decode",
         interpret=interpret,
     )(lengths.astype(jnp.int32), block_tables.astype(jnp.int32),
       *operands)
@@ -518,6 +520,7 @@ def paged_verify_attention(q, k, v, lengths, q_lens, *, block_k=128,
         functools.partial(kernel, scale=scale, bk=bk, n_kv=n_kv, nq=Q),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Q, H, Dh), q.dtype),
+        name="paged_verify",
         interpret=interpret,
     )(lengths.astype(jnp.int32), q_lens.astype(jnp.int32), *operands)
 
@@ -606,6 +609,7 @@ def paged_block_verify_attention(q, pool_k, pool_v, lengths, q_lens,
         functools.partial(kernel, scale=scale, bk=bs, n_kv=T, nq=Q),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Q, H, Dh), q.dtype),
+        name="paged_block_verify",
         interpret=interpret,
     )(lengths.astype(jnp.int32), q_lens.astype(jnp.int32),
       block_tables.astype(jnp.int32), *operands)

@@ -227,6 +227,7 @@ def _flash_fwd(q, k, v, kv_lens, *, causal, block_q, block_k, interpret,
             pltpu.VMEM((bq, _LANES), jnp.float32),   # running denom
             pltpu.VMEM((bq, D), jnp.float32),        # output accumulator
         ],
+        name="flash_fwd",
         interpret=interpret,
     )(*lens_arg, q, k, v, *carry_arg)
 
@@ -417,6 +418,7 @@ def _flash_bwd(q, k, v, kv_lens, o, lse, g, *, causal, block_q, block_k,
             pltpu.VMEM((bk, D), jnp.float32),
             pltpu.VMEM((bk, D), jnp.float32),
         ],
+        name="flash_bwd_dkv",
         interpret=interpret,
     )(*lens_arg, q, g, lse, delta, k, v)
 
@@ -440,6 +442,7 @@ def _flash_bwd(q, k, v, kv_lens, o, lse, g, *, causal, block_q, block_k,
         out_specs=pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((BH, S, D), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
+        name="flash_bwd_dq",
         interpret=interpret,
     )(*lens_arg, k, v, q, g, lse, delta)
     return dq, dk, dv

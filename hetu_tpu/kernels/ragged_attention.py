@@ -200,6 +200,7 @@ def _call_ragged(q, operands, *, bk, n_kv, tq, quant, tabled, kv_specs,
                           n_kv=n_kv, tq=tq, quant=quant, tabled=tabled),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Q, H, Dh), q.dtype),
+        name="ragged_paged_mixed" if tabled else "ragged_mixed",
         interpret=interpret,
     )(*scalars, *operands)
 

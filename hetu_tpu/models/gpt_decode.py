@@ -942,6 +942,7 @@ def _verify_step(params, cfg_tuple, cache_k, cache_v, pos, tokens,
     return logits, cache_k, cache_v
 
 
+@jax.named_scope("sample")
 def _spec_sample(logits, temperature, top_k, rng_keys, first_row=None,
                  q_len=None):
     """Sequential per-position sampling over a verify q-block: position
@@ -1191,7 +1192,12 @@ def _mixed_step(params, cfg_tuple, cache_k, cache_v, pos, tokens,
     is computed as well and selected for the slots ``self_fresh`` [B]
     marks.  The ragged path hands the whole wave to the mixed-mode
     kernel, which reads everything back from the pool (the fast path's
-    existing round-trip semantics)."""
+    existing round-trip semantics).
+
+    The device trace finds the wave's parts under a handful of
+    ``jax.named_scope`` names, the same for every layer: ``embed``,
+    ``attn_qkv``, ``kv_write``, ``attention``, ``attn_out``, ``mlp``,
+    ``lm_head`` (and ``sample``, ``_spec_sample``'s own)."""
     name, L, H, Dh, S_max = cfg_tuple[:5]
     moe = _moe_of(cfg_tuple)
     B, Q = tokens.shape
@@ -1201,9 +1207,10 @@ def _mixed_step(params, cfg_tuple, cache_k, cache_v, pos, tokens,
     posns = pos[:, None] + jnp.arange(Q)[None, :]          # [B, Q]
     valid = jnp.arange(Q)[None, :] < q_len[:, None]        # [B, Q]
     lens = (pos + q_len).astype(jnp.int32)   # filled after the writes
-    wpe = params[f"{name}_wpe"]
-    h = params[f"{name}_wte_table"][tokens] \
-        + wpe[jnp.clip(posns, 0, wpe.shape[0] - 1)]        # [B, Q, hd]
+    with jax.named_scope("embed"):
+        wpe = params[f"{name}_wpe"]
+        h = params[f"{name}_wte_table"][tokens] \
+            + wpe[jnp.clip(posns, 0, wpe.shape[0] - 1)]    # [B, Q, hd]
     if attn == "ragged":
         from ..kernels.ragged_attention import (
             ragged_attention, ragged_paged_attention,
@@ -1230,77 +1237,89 @@ def _mixed_step(params, cfg_tuple, cache_k, cache_v, pos, tokens,
     quant = _kv_q(cache_k)
     for i in range(L):
         us = f"{name}_h{i}"
-        x = _ln(h, params[f"{us}_ln1_scale"], params[f"{us}_ln1_bias"])
-        q = (x @ params[f"{us}_attn_q_weight"]
-             + params[f"{us}_attn_q_bias"]).reshape(B, Q, H, Dh)
-        k = (x @ params[f"{us}_attn_k_weight"]
-             + params[f"{us}_attn_k_bias"]).reshape(B, Q, H, Dh)
-        v = (x @ params[f"{us}_attn_v_weight"]
-             + params[f"{us}_attn_v_bias"]).reshape(B, Q, H, Dh)
-        if paged:
-            cache_k = _kv_scatter(cache_k, (i, wblk, woff), k)
-            cache_v = _kv_scatter(cache_v, (i, wblk, woff), v)
-        else:
-            # descending j: dead (clipped) tail first, live wins last
-            for jq in reversed(range(Q)):
-                pw = jnp.minimum(posns[:, jq], S_max - 1)
-                cache_k = _kv_scatter(cache_k, (i, bidx, pw), k[:, jq])
-                cache_v = _kv_scatter(cache_v, (i, bidx, pw), v[:, jq])
-        if quant:
-            ks, ksc = cache_k[0][i], cache_k[1][i]
-            vs, vsc = cache_v[0][i], cache_v[1][i]
-        else:
-            ks, vs = cache_k[i], cache_v[i]
-            ksc = vsc = None
-        if paged and attn == "ragged":
-            o = ragged_paged_attention(
-                q, ks, vs, lens, q_len, block_tables, k_scale=ksc,
-                v_scale=vsc).reshape(B, Q, hdim)
-        elif attn == "ragged":
-            o = ragged_attention(q, ks, vs, lens, q_len, k_scale=ksc,
-                                 v_scale=vsc).reshape(B, Q, hdim)
-        else:
+        with jax.named_scope("attn_qkv"):
+            x = _ln(h, params[f"{us}_ln1_scale"],
+                    params[f"{us}_ln1_bias"])
+            q = (x @ params[f"{us}_attn_q_weight"]
+                 + params[f"{us}_attn_q_bias"]).reshape(B, Q, H, Dh)
+            k = (x @ params[f"{us}_attn_k_weight"]
+                 + params[f"{us}_attn_k_bias"]).reshape(B, Q, H, Dh)
+            v = (x @ params[f"{us}_attn_v_weight"]
+                 + params[f"{us}_attn_v_bias"]).reshape(B, Q, H, Dh)
+        with jax.named_scope("kv_write"):
             if paged:
-                kg = ks[block_tables].reshape(B, span, H, Dh)
-                vg = vs[block_tables].reshape(B, span, H, Dh)
-                if ksc is not None:
-                    kg = kg.astype(jnp.float32) * ksc[
-                        block_tables].reshape(B, span, H)[..., None]
-                    vg = vg.astype(jnp.float32) * vsc[
-                        block_tables].reshape(B, span, H)[..., None]
+                cache_k = _kv_scatter(cache_k, (i, wblk, woff), k)
+                cache_v = _kv_scatter(cache_v, (i, wblk, woff), v)
             else:
-                kg, vg = ks, vs
-                if ksc is not None:
-                    kg = kv_decode(kg, ksc)
-                    vg = kv_decode(vg, vsc)
-            # default: _verify_step's full mask over the written cache
-            s_raw = jnp.einsum("bqhd,bshd->bqhs", q, kg) * scale
-            sw = jnp.where(live[:, :, None, :], s_raw, NEG_INF)
-            p = jax.nn.softmax(sw, axis=-1)
-            o = jnp.einsum("bqhs,bshd->bqhd", p, vg)
-            if has_fresh:
-                # _serve_prefill_chunk's arithmetic for chunk slots:
-                # read-back context + the chunk's own FRESH K/V
-                s1 = jnp.where(ctx_live[:, None, None, :], s_raw,
-                               NEG_INF)
-                s2 = jnp.einsum("bqhd,bjhd->bqhj", q, k) * scale
-                s2 = jnp.where(self_live[:, :, None, :], s2, NEG_INF)
-                pf = jax.nn.softmax(
-                    jnp.concatenate([s1, s2], axis=-1), axis=-1)
-                o_fresh = jnp.einsum("bqhs,bshd->bqhd",
-                                     pf[..., :span], vg) \
-                    + jnp.einsum("bqhj,bjhd->bqhd", pf[..., span:], v)
-                o = jnp.where(self_fresh[:, None, None, None],
-                              o_fresh, o)
-            o = o.reshape(B, Q, hdim)
-        o = o @ params[f"{us}_attn_proj_weight"] \
-            + params[f"{us}_attn_proj_bias"]
-        h = h + o
-        h = _ffn_block(params, us, h, i, moe=moe, valid=valid,
-                       stats=moe_stats)
-    h = _ln(h, params[f"{name}_ln_f_scale"], params[f"{name}_ln_f_bias"])
-    logits = (h @ params[f"{name}_wte_table"].T).astype(jnp.float32) \
-        + params.get(f"{name}_head_bias", 0.0)
+                # descending j: dead (clipped) tail first, live wins
+                # last
+                for jq in reversed(range(Q)):
+                    pw = jnp.minimum(posns[:, jq], S_max - 1)
+                    cache_k = _kv_scatter(cache_k, (i, bidx, pw),
+                                          k[:, jq])
+                    cache_v = _kv_scatter(cache_v, (i, bidx, pw),
+                                          v[:, jq])
+        with jax.named_scope("attention"):
+            if quant:
+                ks, ksc = cache_k[0][i], cache_k[1][i]
+                vs, vsc = cache_v[0][i], cache_v[1][i]
+            else:
+                ks, vs = cache_k[i], cache_v[i]
+                ksc = vsc = None
+            if paged and attn == "ragged":
+                o = ragged_paged_attention(
+                    q, ks, vs, lens, q_len, block_tables, k_scale=ksc,
+                    v_scale=vsc).reshape(B, Q, hdim)
+            elif attn == "ragged":
+                o = ragged_attention(q, ks, vs, lens, q_len, k_scale=ksc,
+                                     v_scale=vsc).reshape(B, Q, hdim)
+            else:
+                if paged:
+                    kg = ks[block_tables].reshape(B, span, H, Dh)
+                    vg = vs[block_tables].reshape(B, span, H, Dh)
+                    if ksc is not None:
+                        kg = kg.astype(jnp.float32) * ksc[
+                            block_tables].reshape(B, span, H)[..., None]
+                        vg = vg.astype(jnp.float32) * vsc[
+                            block_tables].reshape(B, span, H)[..., None]
+                else:
+                    kg, vg = ks, vs
+                    if ksc is not None:
+                        kg = kv_decode(kg, ksc)
+                        vg = kv_decode(vg, vsc)
+                # default: _verify_step's full mask over the written cache
+                s_raw = jnp.einsum("bqhd,bshd->bqhs", q, kg) * scale
+                sw = jnp.where(live[:, :, None, :], s_raw, NEG_INF)
+                p = jax.nn.softmax(sw, axis=-1)
+                o = jnp.einsum("bqhs,bshd->bqhd", p, vg)
+                if has_fresh:
+                    # _serve_prefill_chunk's arithmetic for chunk slots:
+                    # read-back context + the chunk's own FRESH K/V
+                    s1 = jnp.where(ctx_live[:, None, None, :], s_raw,
+                                   NEG_INF)
+                    s2 = jnp.einsum("bqhd,bjhd->bqhj", q, k) * scale
+                    s2 = jnp.where(self_live[:, :, None, :], s2, NEG_INF)
+                    pf = jax.nn.softmax(
+                        jnp.concatenate([s1, s2], axis=-1), axis=-1)
+                    o_fresh = jnp.einsum("bqhs,bshd->bqhd",
+                                         pf[..., :span], vg) \
+                        + jnp.einsum("bqhj,bjhd->bqhd", pf[..., span:], v)
+                    o = jnp.where(self_fresh[:, None, None, None],
+                                  o_fresh, o)
+                o = o.reshape(B, Q, hdim)
+        with jax.named_scope("attn_out"):
+            o = o @ params[f"{us}_attn_proj_weight"] \
+                + params[f"{us}_attn_proj_bias"]
+            h = h + o
+        with jax.named_scope("mlp"):
+            h = _ffn_block(params, us, h, i, moe=moe, valid=valid,
+                           stats=moe_stats)
+    with jax.named_scope("lm_head"):
+        h = _ln(h, params[f"{name}_ln_f_scale"],
+                params[f"{name}_ln_f_bias"])
+        logits = (h @ params[f"{name}_wte_table"].T
+                  ).astype(jnp.float32) \
+            + params.get(f"{name}_head_bias", 0.0)
     return logits, cache_k, cache_v
 
 

@@ -5,9 +5,9 @@ synthetic inputs with CUDA events; NCCLProfiler:389 measures allreduce
 bandwidth per group topology; TimerSubExecutor wraps each compute).
 
 TPU-native: the per-op wall-clock loop is meaningless under XLA fusion, so
-HetuProfiler reports (a) whole-step wall time with device sync, (b) XLA
-cost-analysis FLOPs/bytes per compiled step, and (c) optional xprof trace
-capture via jax.profiler.  NCCLProfiler becomes a collective probe over
+HetuProfiler reports (a) whole-step wall time with device sync and (b) XLA
+cost-analysis FLOPs/bytes per compiled step (a device trace is the
+benchmark's to take and reduce: benchmarks/xplane.py).  NCCLProfiler becomes a collective probe over
 mesh axes (ICI/DCN bandwidth), feeding the planner's cost model exactly as
 the reference's fed Galvatron.
 """
@@ -124,12 +124,6 @@ class HetuProfiler:
 
     def _synth_feeds(self):
         return {k: np.zeros(s, np.float32) for k, s in self.feed_shapes.items()}
-
-    def start_trace(self, logdir="/tmp/hetu_tpu_trace"):
-        jax.profiler.start_trace(logdir)
-
-    def stop_trace(self):
-        jax.profiler.stop_trace()
 
 
 class TPUProfiler(HetuProfiler):

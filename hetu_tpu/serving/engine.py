@@ -240,6 +240,11 @@ class ServingEngine:
         self._keys = np.zeros((B, 2), np.uint32)
         self._reqs = [None] * B
         self._gen = [None] * B               # generated ids per slot
+        # one host stamp per generated token (Result.token_times_s):
+        # the first at its first_token_at, the rest at the post-sync
+        # stamp of the wave that emitted them
+        self._tok_t = [None] * B
+        self._wave_end = 0.0
         # live weight sync (serving/weight_sync.py): the version the
         # current param dict is stamped with (None = unversioned) and
         # the per-slot ADMISSION version a retirement reports — the
@@ -307,7 +312,8 @@ class ServingEngine:
             self._mixed = (serve_mixed_paged_fn(donate, attn)
                            if self.paged else serve_mixed_fn(donate, attn))
             # tells the lifecycle accountant the wave IS the prefill:
-            # chunk_stall residue is asserted near-zero and folded
+            # a noise-scale chunk_stall residue is folded to 0, a
+            # larger one counted (serve.lifecycle_residue)
             self.metrics.mixed_mode = True
         if envvars.get_bool("HETU_VALIDATE"):
             # recompile sentinel: snapshot()/assert_no_recompile() can
@@ -519,9 +525,9 @@ class ServingEngine:
                 req = self._queue.popleft()
                 t_a = time.perf_counter()
                 slot = self.kv.alloc(req.request_id, len(req.prompt))
+                req.claimed_at = time.perf_counter()
                 self.metrics.lc_claimed(
-                    req.request_id,
-                    (time.perf_counter() - t_a) * 1e3)
+                    req.request_id, (req.claimed_at - t_a) * 1e3)
                 admits.append((req, slot))
             if not admits:
                 break
@@ -559,6 +565,7 @@ class ServingEngine:
                     self._reqs[slot] = req
                     self._slot_version[slot] = self.weight_version
                     self._gen[slot] = [tok0]
+                    self._tok_t[slot] = [now]
                     self.metrics.record_admit(
                         req.request_id, slot, now - req.submitted_at,
                         now - req.submitted_at)
@@ -591,6 +598,7 @@ class ServingEngine:
             # view, and admission writes per-slot rows into _keys
             self._keys = np.array(keys, np.uint32)
             dt = time.perf_counter() - t0
+            self._wave_end = t0 + dt
             for slot in live:
                 req = self._reqs[slot]
                 t = int(sampled[slot])
@@ -724,6 +732,7 @@ class ServingEngine:
             new_keys[~mask] = self._keys[~mask]
             self._keys = new_keys
             dt = time.perf_counter() - t0
+            self._wave_end = t0 + dt
             for slot in decoding:
                 req = self._reqs[slot]
                 t = int(sampled[slot])
@@ -773,8 +782,9 @@ class ServingEngine:
                     # capacity frees up (backpressure, not loss)
                     self.metrics.lc_blocked(req.request_id)
                     break
+                req.claimed_at = time.perf_counter()
                 self.metrics.lc_claimed(
-                    req.request_id, (time.perf_counter() - t_a) * 1e3)
+                    req.request_id, (req.claimed_at - t_a) * 1e3)
                 self._queue.popleft()
                 self._reqs[slot] = req
                 self._slot_version[slot] = self.weight_version
@@ -852,7 +862,6 @@ class ServingEngine:
             if self._gen[s] is None and self._prompt_arr[s] is not None \
                     and len(self._prompt_arr[s]) >= bs \
                     and [int(t) for t in self._prompt_arr[s][:bs]] == head:
-                telemetry.inc("serve.prefix_deferrals")
                 return True
         return False
 
@@ -916,6 +925,7 @@ class ServingEngine:
         self._tok[slot] = tok0
         self._keys[slot] = key
         self._gen[slot] = [tok0]
+        self._tok_t[slot] = [now]
         if self.paged:
             self.kv.register_prefix(self._prompt_arr[slot], slot)
         self.metrics.record_admit(
@@ -1025,8 +1035,9 @@ class ServingEngine:
             req = self._queue.popleft()
             t_a = time.perf_counter()
             slot = self.kv.alloc(req.request_id, len(req.prompt))
+            req.claimed_at = time.perf_counter()
             self.metrics.lc_claimed(
-                req.request_id, (time.perf_counter() - t_a) * 1e3)
+                req.request_id, (req.claimed_at - t_a) * 1e3)
             self._reqs[slot] = req
             self._slot_version[slot] = self.weight_version
             self._gen[slot] = None
@@ -1052,15 +1063,31 @@ class ServingEngine:
         request's prompt chunks.  Token-identical to the phase-split
         schedulers: every slot's write positions, attention masks, and
         rng splits reproduce exactly what its mode's dedicated step
-        would have done."""
+        would have done.
+
+        Spans, a fixed number a wave whatever is live, all tagged
+        ``wave=``: ``serve.wave`` (root) holding ``serve.admit``,
+        ``serve.wave.draft`` (speculative engines only),
+        ``serve.wave.assemble`` (the descriptor and the block-table
+        copy), ``serve.wave.dispatch`` (the call into the jitted step
+        until it returns: enqueue time; a MoE engine also fetches its
+        routing counts here), ``serve.wave.sync`` (the host waits for
+        the device) and ``serve.wave.unpack``.  An iteration with
+        nothing live ends after ``serve.admit``."""
+        wave = self.steps + 1
+        with telemetry.span("serve.wave", wave=wave) as root:
+            return self._mixed_wave(root, wave)
+
+    def _mixed_wave(self, root, wave_id):
         done = []
         # admission reuses the phase-split claim paths unchanged
         # (prefix sharing/COW, tier fetch, deferral, backpressure) —
         # minus the eager prefill: prompts join THIS step's wave
-        if self.paged:
-            self._admit_paged()
-        else:
-            self._admit_contiguous_mixed()
+        with telemetry.span("serve.admit", wave=wave_id):
+            if self.paged:
+                self._admit_paged()
+            else:
+                self._admit_contiguous_mixed()
         live = self.kv.live()
         if not live:
             return done
@@ -1075,180 +1102,189 @@ class ServingEngine:
         k_cur = 0
         draft = None
         if decoding and self.spec_k:
-            k_cur = self._spec_kcur
-            draft, dck, dcv = self._propose(
-                self.params, self.cfg_tuple_draft,
-                self._draft_ck, self._draft_cv,
-                self._pos.copy(), self._tok.copy(), k=k_cur)
-            self._draft_ck, self._draft_cv = dck, dcv
-            draft = np.asarray(draft)
-        entries = {}
-        chunk_take = {}   # slot -> (take, final) for prefill q-blocks
-        for s in pre:
-            prompt = self._prompt_arr[s]
-            P = len(prompt)
-            off = int(self._prefill_off[s])
-            if self.paged and self.chunk > 0:
-                C_b = min(_pow2(self.chunk, floor=8), self.kv.s_max)
-                take = min(self.chunk, C_b, P - off)
-            else:
-                take = P - off
-            final = off + take >= P
-            # only the final chunk samples (and splits the rng) — at
-            # its last row; mid-prompt chunks pass first_row == q_len
-            entries[s] = ([int(t) for t in prompt[off:off + take]],
-                          off, take - 1 if final else take, self.paged)
-            chunk_take[s] = (take, final)
-        qlen_v = {}
-        for s in decoding:
-            if k_cur:
-                rem = self._reqs[s].max_new_tokens - len(self._gen[s])
-                ql = min(k_cur + 1, rem,
-                         self.kv.s_max - int(self._pos[s]))
-                toks = ([int(self._tok[s])]
-                        + [int(t) for t in draft[s, :ql - 1]])
-                qlen_v[s] = ql
-            else:
-                toks = [int(self._tok[s])]
-            entries[s] = (toks, int(self._pos[s]), 0, False)
-        wave = assemble_mixed_wave(B, entries)
-        if self.paged:
-            sampled, ck, cv, after = self._moe_take(self._mixed(
-                self.params, self.cfg_tuple,
-                self.kv.cache_k, self.kv.cache_v,
-                self.kv.tables.copy(), wave["pos"], wave["tokens"],
-                wave["q_len"], wave["first_row"], wave["self_fresh"],
-                self._temp, self._topk, self._keys,
-                has_fresh=bool(pre)))
-        else:
-            sampled, ck, cv, after = self._moe_take(self._mixed(
-                self.params, self.cfg_tuple,
-                self.kv.cache_k, self.kv.cache_v,
-                wave["pos"], wave["tokens"], wave["q_len"],
-                wave["first_row"], wave["self_fresh"],
-                self._temp, self._topk, self._keys))
-        self.kv.cache_k, self.kv.cache_v = ck, cv
-        sampled = np.asarray(sampled)
-        after = np.array(after, np.uint32)
-        dt = time.perf_counter() - t0
-        # ---- per-mode unpack: prefill q-blocks ---- #
-        q_pre = 0
-        pre_credit = {}
-        if pre:
-            self.prefill_dispatches += 1
-        for s in pre:
-            req = self._reqs[s]
-            take, final = chunk_take[s]
-            q_pre += take
+            with telemetry.span("serve.wave.draft", wave=wave_id):
+                k_cur = self._spec_kcur
+                draft, dck, dcv = self._propose(
+                    self.params, self.cfg_tuple_draft,
+                    self._draft_ck, self._draft_cv,
+                    self._pos.copy(), self._tok.copy(), k=k_cur)
+                self._draft_ck, self._draft_cv = dck, dcv
+                draft = np.asarray(draft)
+        with telemetry.span("serve.wave.assemble", wave=wave_id):
+            entries = {}
+            chunk_take = {}   # slot -> (take, final) for prefill q-blocks
+            for s in pre:
+                prompt = self._prompt_arr[s]
+                P = len(prompt)
+                off = int(self._prefill_off[s])
+                if self.paged and self.chunk > 0:
+                    C_b = min(_pow2(self.chunk, floor=8), self.kv.s_max)
+                    take = min(self.chunk, C_b, P - off)
+                else:
+                    take = P - off
+                final = off + take >= P
+                # only the final chunk samples (and splits the rng) — at
+                # its last row; mid-prompt chunks pass first_row == q_len
+                entries[s] = ([int(t) for t in prompt[off:off + take]],
+                              off, take - 1 if final else take, self.paged)
+                chunk_take[s] = (take, final)
+            qlen_v = {}
+            for s in decoding:
+                if k_cur:
+                    rem = self._reqs[s].max_new_tokens - len(self._gen[s])
+                    ql = min(k_cur + 1, rem,
+                             self.kv.s_max - int(self._pos[s]))
+                    toks = ([int(self._tok[s])]
+                            + [int(t) for t in draft[s, :ql - 1]])
+                    qlen_v[s] = ql
+                else:
+                    toks = [int(self._tok[s])]
+                entries[s] = (toks, int(self._pos[s]), 0, False)
+            wave = assemble_mixed_wave(B, entries)
+            tables = self.kv.tables.copy() if self.paged else None
+        with telemetry.span("serve.wave.dispatch", wave=wave_id):
             if self.paged:
-                self.kv.advance(s, take)
-                self.prefill_chunks += 1
-                telemetry.inc("serve.prefill_chunks")
-            self._prefill_off[s] += take
-            # the whole fused wave IS this request's prefill compute —
-            # there is no separate decode phase to stall behind, so
-            # the lifecycle's chunk_stall residue collapses to ~0.
-            # Credit the elapsed wall since dispatch, not just dt:
-            # an earlier slot's _finish_prefill in this same loop can
-            # compile the draft prefill (~100s of ms once per process)
-            # and that wall sits inside THIS request's prefill span
-            # too; _retire clamps the credit to the observed wall, so
-            # over-crediting is safe and the stall residue stays ~0.
-            # (A LATER slot's compile is covered by the end-of-wave
-            # top-up below — this eager credit exists so a request
-            # that retires AT prefill still carries its share.)
-            e = time.perf_counter() - t0
-            self.metrics.lc_prefill(req.request_id, e)
-            pre_credit[req.request_id] = e
-            if final:
-                r = self._finish_prefill(
-                    s, int(sampled[s, take - 1]),
-                    np.asarray(after[s, take - 1], np.uint32))
+                sampled, ck, cv, after = self._moe_take(self._mixed(
+                    self.params, self.cfg_tuple,
+                    self.kv.cache_k, self.kv.cache_v,
+                    tables, wave["pos"], wave["tokens"],
+                    wave["q_len"], wave["first_row"], wave["self_fresh"],
+                    self._temp, self._topk, self._keys,
+                    has_fresh=bool(pre)))
+            else:
+                sampled, ck, cv, after = self._moe_take(self._mixed(
+                    self.params, self.cfg_tuple,
+                    self.kv.cache_k, self.kv.cache_v,
+                    wave["pos"], wave["tokens"], wave["q_len"],
+                    wave["first_row"], wave["self_fresh"],
+                    self._temp, self._topk, self._keys))
+            self.kv.cache_k, self.kv.cache_v = ck, cv
+        with telemetry.span("serve.wave.sync", wave=wave_id):
+            sampled = np.asarray(sampled)
+            after = np.array(after, np.uint32)
+        dt = time.perf_counter() - t0
+        self._wave_end = t0 + dt
+        with telemetry.span("serve.wave.unpack", wave=wave_id):
+            # ---- per-mode unpack: prefill q-blocks ---- #
+            q_pre = 0
+            pre_credit = {}
+            if pre:
+                self.prefill_dispatches += 1
+            for s in pre:
+                req = self._reqs[s]
+                take, final = chunk_take[s]
+                q_pre += take
+                if self.paged:
+                    self.kv.advance(s, take)
+                    self.prefill_chunks += 1
+                    telemetry.inc("serve.prefill_chunks")
+                self._prefill_off[s] += take
+                # the whole fused wave IS this request's prefill compute —
+                # there is no separate decode phase to stall behind, so
+                # the lifecycle's chunk_stall residue collapses to ~0.
+                # Credit the elapsed wall since dispatch, not just dt:
+                # an earlier slot's _finish_prefill in this same loop can
+                # compile the draft prefill (~100s of ms once per process)
+                # and that wall sits inside THIS request's prefill span
+                # too; _retire clamps the credit to the observed wall, so
+                # over-crediting is safe and the stall residue stays ~0.
+                # (A LATER slot's compile is covered by the end-of-wave
+                # top-up below — this eager credit exists so a request
+                # that retires AT prefill still carries its share.)
+                e = time.perf_counter() - t0
+                self.metrics.lc_prefill(req.request_id, e)
+                pre_credit[req.request_id] = e
+                if final:
+                    r = self._finish_prefill(
+                        s, int(sampled[s, take - 1]),
+                        np.asarray(after[s, take - 1], np.uint32))
+                    if r:
+                        done.append(r)
+            if pre:
+                self.metrics.record_prefill(len(pre), wave["q"], dt,
+                                            batched=True)
+            # ---- verify / decode q-blocks ---- #
+            n_dec = 0
+            wave_emit = wave_acc = wave_prop = 0
+            for s in decoding:
+                req = self._reqs[s]
+                if k_cur:
+                    ql = qlen_v[s]
+                    toks = entries[s][0]
+                    a = 0
+                    while a < ql - 1 and sampled[s, a] == toks[a + 1]:
+                        a += 1
+                    emit = [int(t) for t in sampled[s, :a + 1]]
+                    if req.eos_id is not None and req.eos_id in emit:
+                        emit = emit[:emit.index(req.eos_id) + 1]
+                    n_emit = len(emit)
+                    accepted = min(a, n_emit)
+                    wave_emit += n_emit
+                    wave_acc += accepted
+                    wave_prop += ql - 1
+                    self._spec_acc[s] += accepted
+                    self._spec_prop[s] += ql - 1
+                    self._spec_bonus[s] += n_emit - accepted
+                    base = int(self._pos[s])
+                    self.kv.advance(s, ql)
+                    self.kv.truncate(s, base + n_emit)
+                    self._pos[s] = base + n_emit
+                    self._tok[s] = emit[-1]
+                    self._keys[s] = after[s, n_emit - 1]
+                    self._gen[s].extend(emit)
+                    if req.stream_cb:
+                        for t in emit:
+                            req.stream_cb(req, t)
+                    r = self._maybe_finish(s, emit[-1])
+                else:
+                    t = int(sampled[s, 0])
+                    n_dec += 1
+                    self._pos[s] += 1
+                    self._tok[s] = t
+                    self._keys[s] = after[s, 0]
+                    self._gen[s].append(t)
+                    self.kv.advance(s)
+                    if req.stream_cb:
+                        req.stream_cb(req, t)
+                    r = self._maybe_finish(s, t)
                 if r:
                     done.append(r)
-        if pre:
-            self.metrics.record_prefill(len(pre), wave["q"], dt,
-                                        batched=True)
-        # ---- verify / decode q-blocks ---- #
-        n_dec = 0
-        wave_emit = wave_acc = wave_prop = 0
-        for s in decoding:
-            req = self._reqs[s]
+            if pre_credit:
+                # top every still-live prefill rider up to the FULL wave
+                # elapsed: a later slot's _finish_prefill (draft-prefill
+                # compile) or the verify/decode unpack runs after the
+                # rider's eager credit above but inside its prefill wall —
+                # without this the difference surfaces as a phantom
+                # chunk_stall residue (lc_prefill no-ops for requests that
+                # already retired; _retire clamps over-credit to the wall)
+                t_wave = time.perf_counter() - t0
+                for rid, e in pre_credit.items():
+                    if t_wave > e:
+                        self.metrics.lc_prefill(rid, t_wave - e,
+                                                count=False)
+            self.steps += 1
+            spec = None
             if k_cur:
-                ql = qlen_v[s]
-                toks = entries[s][0]
-                a = 0
-                while a < ql - 1 and sampled[s, a] == toks[a + 1]:
-                    a += 1
-                emit = [int(t) for t in sampled[s, :a + 1]]
-                if req.eos_id is not None and req.eos_id in emit:
-                    emit = emit[:emit.index(req.eos_id) + 1]
-                n_emit = len(emit)
-                accepted = min(a, n_emit)
-                wave_emit += n_emit
-                wave_acc += accepted
-                wave_prop += ql - 1
-                self._spec_acc[s] += accepted
-                self._spec_prop[s] += ql - 1
-                self._spec_bonus[s] += n_emit - accepted
-                base = int(self._pos[s])
-                self.kv.advance(s, ql)
-                self.kv.truncate(s, base + n_emit)
-                self._pos[s] = base + n_emit
-                self._tok[s] = emit[-1]
-                self._keys[s] = after[s, n_emit - 1]
-                self._gen[s].extend(emit)
-                if req.stream_cb:
-                    for t in emit:
-                        req.stream_cb(req, t)
-                r = self._maybe_finish(s, emit[-1])
-            else:
-                t = int(sampled[s, 0])
-                n_dec += 1
-                self._pos[s] += 1
-                self._tok[s] = t
-                self._keys[s] = after[s, 0]
-                self._gen[s].append(t)
-                self.kv.advance(s)
-                if req.stream_cb:
-                    req.stream_cb(req, t)
-                r = self._maybe_finish(s, t)
-            if r:
-                done.append(r)
-        if pre_credit:
-            # top every still-live prefill rider up to the FULL wave
-            # elapsed: a later slot's _finish_prefill (draft-prefill
-            # compile) or the verify/decode unpack runs after the
-            # rider's eager credit above but inside its prefill wall —
-            # without this the difference surfaces as a phantom
-            # chunk_stall residue (lc_prefill no-ops for requests that
-            # already retired; _retire clamps over-credit to the wall)
-            t_wave = time.perf_counter() - t0
-            for rid, e in pre_credit.items():
-                if t_wave > e:
-                    self.metrics.lc_prefill(rid, t_wave - e,
-                                            count=False)
-        self.steps += 1
-        spec = None
-        if k_cur:
-            self.spec_waves += 1
-            self.spec_k_sum += k_cur
-            self.spec_proposed += wave_prop
-            self.spec_accepted += wave_acc
-            self.spec_emitted += wave_emit
-            self._acc_window.append((wave_acc, wave_prop))
-            self._adapt_k()
-            spec = {"k": k_cur, "proposed": wave_prop,
-                    "accepted": wave_acc}
-        q_ver = sum(qlen_v.values())
-        q_tot = max(q_pre + q_ver + n_dec, 1)
-        self.metrics.record_step(
-            live=len(live), slots=B, queue_depth=len(self._queue),
-            dt_s=dt, new_tokens=wave_emit if k_cur else n_dec,
-            prefill_s=dt * q_pre / q_tot, step=self.steps,
-            requests=wave_reqs, end_perf=t0 + dt, spec=spec,
-            mix={"q_prefill": q_pre, "q_verify": q_ver,
-                 "q_decode": n_dec}, moe=self._moe_record())
+                self.spec_waves += 1
+                self.spec_k_sum += k_cur
+                self.spec_proposed += wave_prop
+                self.spec_accepted += wave_acc
+                self.spec_emitted += wave_emit
+                self._acc_window.append((wave_acc, wave_prop))
+                self._adapt_k()
+                spec = {"k": k_cur, "proposed": wave_prop,
+                        "accepted": wave_acc}
+            q_ver = sum(qlen_v.values())
+            q_tot = max(q_pre + q_ver + n_dec, 1)
+            self.metrics.record_step(
+                live=len(live), slots=B, queue_depth=len(self._queue),
+                dt_s=dt, new_tokens=wave_emit if k_cur else n_dec,
+                prefill_s=dt * q_pre / q_tot, step=self.steps,
+                requests=wave_reqs, end_perf=t0 + dt, spec=spec,
+                mix={"q_prefill": q_pre, "q_verify": q_ver,
+                     "q_decode": n_dec}, moe=self._moe_record())
+        root.set(live=len(live), q_prefill=q_pre, q_verify=q_ver,
+                 q_decode=n_dec)
         return done
 
     # ------------------------------------------------------------- #
@@ -1344,6 +1380,7 @@ class ServingEngine:
         sampled = np.asarray(sampled)
         after = np.array(after, np.uint32)
         dt = time.perf_counter() - t0
+        self._wave_end = t0 + dt
         done = []
         wave_emit = wave_acc = wave_prop = 0
         for s in decoding:
@@ -1426,6 +1463,8 @@ class ServingEngine:
     def _maybe_finish(self, slot, last_token):
         req = self._reqs[slot]
         n = len(self._gen[slot])
+        times = self._tok_t[slot]
+        times.extend([self._wave_end] * (n - len(times)))
         if req.eos_id is not None and last_token == req.eos_id:
             reason = "eos"
         elif n >= req.max_new_tokens:
@@ -1454,7 +1493,9 @@ class ServingEngine:
             latency_s=now - req.submitted_at, slot=slot,
             spec_accepted=spec["accepted"] if spec else 0,
             spec_proposed=spec["proposed"] if spec else 0,
-            weight_version=self._slot_version[slot])
+            weight_version=self._slot_version[slot],
+            queue_wait_s=req.claimed_at - req.submitted_at,
+            token_times_s=[t - req.submitted_at for t in times])
         self.metrics.record_finish(req.request_id, reason, n,
                                    res.latency_s, spec=spec)
         decode_s = now - req.first_token_at
@@ -1468,6 +1509,7 @@ class ServingEngine:
             self.retire_hook(req, slot)
         self._reqs[slot] = None
         self._gen[slot] = None
+        self._tok_t[slot] = None
         self._slot_version[slot] = None
         self.kv.release(slot)
         return res

@@ -30,10 +30,14 @@ full component breakdown:
     kv_alloc_ms     slot + block-table claim
     prefill_ms      prompt compute actually dispatched for this request
     chunk_stall_ms  prefill-phase wall not spent computing (chunked
-                    prefill interleaving with decode waves)
+                    prefill interleaving with decode waves; ~0 on a
+                    mixed-mode engine, where a residue over the
+                    threshold is a host pause or an accounting fault
+                    and is also counted, see ``_retire``)
     decode_ms       first token -> retirement
 
-``snapshot()`` aggregates each component at p50/p95/p99 and
+``snapshot()`` aggregates each component at p50/p95/p99 (over the
+engine's life, or with ``since=mark()`` over what came after the mark) and
 ``explain_tail()`` names the component that dominates the p99-TTFT
 tail — the "why was this request 40x the median" answer.
 
@@ -192,9 +196,9 @@ class ServingMetrics(MetricsCore):
         # mixed-mode ragged dispatch ($HETU_SERVE_RAGGED): the engine
         # sets this when every step is ONE unified wave — prefill
         # attribution then covers the whole ragged dispatch, so the
-        # chunk_stall component is asserted near-zero at retirement and
-        # folded to exactly 0 (kept in COMPONENTS for back-compat:
-        # dashboards and the tail report keep their schema)
+        # chunk_stall component is noise-scale and folded to exactly 0
+        # at retirement; a residue over the threshold is reported and
+        # counted instead (see _retire)
         self.mixed_mode = False
         # per-request breakdowns explain_tail() slices (ring: the tail
         # report is about RECENT behavior, same cap as the event ring)
@@ -376,14 +380,19 @@ class ServingMetrics(MetricsCore):
         if self.mixed_mode:
             # unified wave: the whole ragged dispatch IS this request's
             # prefill compute — any residue is host bookkeeping between
-            # claim and dispatch, noise-scale by construction.  Assert
-            # that (an accounting regression shows up HERE, not as a
-            # quietly wrong dashboard) and fold the component to 0.
-            assert chunk_stall_ms <= max(50.0, 0.5 * prefill_wall_ms), (
-                f"mixed-mode chunk_stall residue {chunk_stall_ms:.1f}ms "
-                f"of {prefill_wall_ms:.1f}ms prefill wall for "
-                f"{request_id}: wave attribution is broken")
-            chunk_stall_ms = 0.0
+            # claim and dispatch, noise-scale by construction, and is
+            # folded to 0.  A residue over the threshold is a host
+            # pause mid-prefill (a profiler starting, a long GC) or an
+            # accounting regression: the scheduler goes on, and the
+            # residue stays visible as chunk_stall_ms, one event and a
+            # counter that hetu_trace --check flags.
+            if chunk_stall_ms > max(50.0, 0.5 * prefill_wall_ms):
+                telemetry.inc("serve.lifecycle_residue")
+                self.event("serve_lifecycle_residue", request=request_id,
+                           residue_ms=round(chunk_stall_ms, 3),
+                           wall_ms=round(prefill_wall_ms, 3))
+            else:
+                chunk_stall_ms = 0.0
         decode_ms = max(now - lc.t_first, 0.0) * 1e3 \
             if n_generated > 1 else 0.0
         ttft_ms = max(lc.t_first - lc.t_submit, 0.0) * 1e3
@@ -442,14 +451,47 @@ class ServingMetrics(MetricsCore):
 
     # ------------------------------------------------------------- #
 
-    def snapshot(self):
+    # what mark() records: the lengths of the append-only sample lists
+    # and the values of the running counters
+    _MARK_LISTS = ("ttfts", "tpots", "step_live", "step_queue",
+                   "step_dt", "step_tokens", "prefill_dt")
+    _MARK_COUNTS = ("submitted", "rejected", "finished",
+                    "tokens_generated", "prefill_batched")
+
+    def mark(self):
+        """A position in this engine's history for ``snapshot(since=)``:
+        opaque to the caller, valid for this object only."""
+        m = {k: len(getattr(self, k)) for k in self._MARK_LISTS}
+        m.update({k: getattr(self, k) for k in self._MARK_COUNTS})
+        m["components"] = {c: len(xs) for c, xs in self.components.items()}
+        m["t"] = self._t_last
+        return m
+
+    def snapshot(self, since=None):
         """Aggregate view (JSON-able): throughput, TTFT/TPOT
         percentiles, mean batch occupancy over fused steps, queue
-        stats, and the per-component tail decomposition."""
-        wall = ((self._t_last - self._t0)
-                if self._t0 is not None and self._t_last > self._t0
+        stats, and the per-component tail decomposition — over the
+        engine's whole life, or with ``since`` (a ``mark()``) over the
+        steps and requests recorded after that mark."""
+        since = since or {}
+        at = since.get
+
+        def tail(name):
+            return getattr(self, name)[at(name, 0):]
+
+        def count(name):
+            return getattr(self, name) - at(name, 0)
+
+        ttfts, tpots = tail("ttfts"), tail("tpots")
+        step_live, step_queue = tail("step_live"), tail("step_queue")
+        step_dt, step_tokens = tail("step_dt"), tail("step_tokens")
+        prefill_dt = tail("prefill_dt")
+        tokens_generated = count("tokens_generated")
+        t_start = at("t") if at("t") is not None else self._t0
+        wall = ((self._t_last - t_start)
+                if t_start is not None and self._t_last > t_start
                 else None)
-        occ = ([l / self._slots for l in self.step_live]
+        occ = ([l / self._slots for l in step_live]
                if self._slots else [])
         # TPOT from REAL per-step emitted-token counts: a step emitting
         # n tokens contributes n samples of dt/n — correct with and
@@ -457,11 +499,12 @@ class ServingMetrics(MetricsCore):
         # assumed one token per wave and skewed the percentiles the
         # moment waves emitted more)
         tpot = []
-        for dt, n in zip(self.step_dt, self.step_tokens):
+        for dt, n in zip(step_dt, step_tokens):
             if n > 0:
                 tpot.extend([dt / n] * n)
         comps = {}
         for name, xs in self.components.items():
+            xs = xs[since.get("components", {}).get(name, 0):]
             if xs:
                 comps[name] = {
                     "p50_ms": round(_pct(xs, 50), 3),
@@ -470,39 +513,38 @@ class ServingMetrics(MetricsCore):
                     "mean_ms": round(float(np.mean(xs)), 3),
                 }
         return {
-            "requests_submitted": self.submitted,
-            "requests_rejected": self.rejected,
-            "requests_finished": self.finished,
-            "tokens_generated": self.tokens_generated,
+            "requests_submitted": count("submitted"),
+            "requests_rejected": count("rejected"),
+            "requests_finished": count("finished"),
+            "tokens_generated": tokens_generated,
             "wall_s": round(wall, 6) if wall else None,
-            "tokens_per_sec": (round(self.tokens_generated / wall, 2)
+            "tokens_per_sec": (round(tokens_generated / wall, 2)
                                if wall else None),
-            "ttft_p50_s": _pct(self.ttfts, 50),
-            "ttft_p95_s": _pct(self.ttfts, 95),
-            "ttft_p99_s": _pct(self.ttfts, 99),
-            "ttft_mean_s": (float(np.mean(self.ttfts))
-                            if self.ttfts else None),
+            "ttft_p50_s": _pct(ttfts, 50),
+            "ttft_p95_s": _pct(ttfts, 95),
+            "ttft_p99_s": _pct(ttfts, 99),
+            "ttft_mean_s": (float(np.mean(ttfts)) if ttfts else None),
             "tpot_p50_s": _pct(tpot, 50),
             "tpot_p99_s": _pct(tpot, 99),
-            "tpot_req_mean_p50_s": _pct(self.tpots, 50),
-            "tokens_per_step_mean": (float(np.mean(self.step_tokens))
-                                     if self.step_tokens else None),
-            "step_p50_s": _pct(self.step_dt, 50),
-            "step_p99_s": _pct(self.step_dt, 99),
-            "decode_ms_p50": (round(_pct(self.step_dt, 50) * 1e3, 3)
-                              if self.step_dt else None),
-            "prefill_ms_p50": (round(_pct(self.prefill_dt, 50) * 1e3, 3)
-                               if self.prefill_dt else None),
-            "prefill_total_s": (round(float(np.sum(self.prefill_dt)), 6)
-                                if self.prefill_dt else None),
-            "decode_total_s": (round(float(np.sum(self.step_dt)), 6)
-                               if self.step_dt else None),
-            "prefill_dispatches": len(self.prefill_dt),
-            "prefill_batched_dispatches": self.prefill_batched,
-            "steps": len(self.step_live),
+            "tpot_req_mean_p50_s": _pct(tpots, 50),
+            "tokens_per_step_mean": (float(np.mean(step_tokens))
+                                     if step_tokens else None),
+            "step_p50_s": _pct(step_dt, 50),
+            "step_p99_s": _pct(step_dt, 99),
+            "decode_ms_p50": (round(_pct(step_dt, 50) * 1e3, 3)
+                              if step_dt else None),
+            "prefill_ms_p50": (round(_pct(prefill_dt, 50) * 1e3, 3)
+                               if prefill_dt else None),
+            "prefill_total_s": (round(float(np.sum(prefill_dt)), 6)
+                                if prefill_dt else None),
+            "decode_total_s": (round(float(np.sum(step_dt)), 6)
+                               if step_dt else None),
+            "prefill_dispatches": len(prefill_dt),
+            "prefill_batched_dispatches": count("prefill_batched"),
+            "steps": len(step_live),
             "mean_batch_occupancy": (float(np.mean(occ)) if occ else None),
-            "mean_queue_depth": (float(np.mean(self.step_queue))
-                                 if self.step_queue else None),
+            "mean_queue_depth": (float(np.mean(step_queue))
+                                 if step_queue else None),
             "components": comps,
         }
 

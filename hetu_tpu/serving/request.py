@@ -92,6 +92,7 @@ class Request(RequestCore):
     deadline_s: Optional[float] = None
     # set by the engine
     submitted_at: Optional[float] = None
+    claimed_at: Optional[float] = None     # slot + KV claimed
     first_token_at: Optional[float] = None
 
     def __post_init__(self):
@@ -113,7 +114,11 @@ class Result:
     """A finished request: ``tokens`` is prompt + generated (numpy
     int32, EOS included when that's what stopped it — no padding, unlike
     the offline path's fixed span); ``finish_reason`` is "eos" or
-    "length"."""
+    "length".  ``queue_wait_s`` is submit -> slot claimed;
+    ``token_times_s`` holds one host stamp per generated token, in
+    seconds from submit: ``token_times_s[0] == ttft_s``, and the tokens
+    one wave emitted share the stamp taken when that wave's results
+    reached the host."""
 
     request_id: str
     tokens: np.ndarray
@@ -132,6 +137,8 @@ class Result:
     # unversioned engine) — a swap never lands mid-request, so every
     # generated token is this version's
     weight_version: Optional[int] = None
+    queue_wait_s: float = 0.0
+    token_times_s: List[float] = dataclasses.field(default_factory=list)
 
     @property
     def generated(self) -> List[int]:

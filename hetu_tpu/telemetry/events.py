@@ -20,11 +20,17 @@ take down a run that computed fine.
 Spans: ``with span("exec.phase_a", subgraph="train"):`` times a region,
 feeds a histogram (``span.exec.phase_a``) in the metrics registry, and
 — when a telemetry log is configured — emits a ``span`` record carrying
-the START time plus ``ms``/``pid``/``tid``, which the trace exporter
-turns into a Chrome ``"X"`` duration event.  With ``HETU_TELEMETRY=0``
-``span()`` returns a shared no-op and the instrumented call sites skip
-the registry: near-zero overhead is the contract (asserted as a <2%
-smoke-tier bound).
+the START time (``t``, and ``us`` to the microsecond) plus
+``ms``/``pid``/``tid``/``parent`` (the enclosing span's name on this
+thread, None at a root) and the caller's fields (``wave=``, ``step=``,
+``request=``: the identifier a root shares with its children), which the
+trace exporter turns into nested Chrome ``"X"`` duration events.  The
+same span is a ``jax.profiler.TraceAnnotation`` named ``hetu.<name>``:
+while a profiler session is open it is an event of the host plane of the
+profiler's own trace, on the device trace's clock; with none open the
+annotation is a flag test.  With ``HETU_TELEMETRY=0`` ``span()`` returns
+a shared no-op and the instrumented call sites skip the registry:
+near-zero overhead is the contract (asserted as a <2% smoke-tier bound).
 """
 
 from __future__ import annotations
@@ -34,6 +40,8 @@ import json
 import os
 import threading
 import time
+
+from jax.profiler import TraceAnnotation
 
 from .. import envvars, locks
 from . import flight
@@ -89,6 +97,10 @@ REQUIRED_FIELDS = {
     # request lifecycle (serve stream; ISSUE 7)
     "req_span": ("request", "phase", "ms"),
     "req_retire": ("request", "ttft_ms"),
+    # a mixed-mode request waited long between claim and first token
+    # OUTSIDE any wave (a paused host, or broken wave attribution);
+    # presence fails hetu_trace --check
+    "serve_lifecycle_residue": ("request", "residue_ms", "wall_ms"),
     # SLO monitor (telemetry/slo.py)
     "slo_violation": ("slo", "value", "target"),
     "slo_health": ("state",),
@@ -289,26 +301,46 @@ def emit(event, _stream="telemetry", _path=None, _t=None, **fields):
 # spans
 # ------------------------------------------------------------------- #
 
+# the one name prefix of the program's spans in the profiler's trace
+# (the benchmark's own are ``bench.``)
+TRACE_PREFIX = "hetu."
+_OPEN = threading.local()      # .stack: names of this thread's open spans
+
+
 class _Span:
-    __slots__ = ("name", "fields", "_t0", "_epoch")
+    __slots__ = ("name", "fields", "parent", "_t0", "_epoch", "_ann")
 
     def __init__(self, name, fields):
         self.name = name
         self.fields = fields
 
+    def set(self, **fields):
+        """Fields known only once the region has run (a wave's counts)."""
+        self.fields.update(fields)
+
     def __enter__(self):
+        stack = getattr(_OPEN, "stack", None)
+        if stack is None:
+            stack = _OPEN.stack = []
+        self.parent = stack[-1] if stack else None
+        stack.append(self.name)
+        self._ann = TraceAnnotation(TRACE_PREFIX + self.name)
+        self._ann.__enter__()
         self._epoch = time.time()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb):
         ms = (time.perf_counter() - self._t0) * 1e3
+        self._ann.__exit__(exc_type, exc, tb)
+        _OPEN.stack.pop()
         REGISTRY.histogram("span." + self.name).observe(ms)
         # JSONL only when a merged log is configured: per-step span
         # records are trace-export payload, not an always-on cost
         if envvars.is_set("HETU_TELEMETRY_LOG"):
             _SINK.emit("span", stream="telemetry", t=self._epoch,
                        name=self.name, ms=round(ms, 3),
+                       us=int(self._epoch * 1e6), parent=self.parent,
                        pid=os.getpid(),
                        tid=threading.current_thread().name,
                        **self.fields)
@@ -317,6 +349,9 @@ class _Span:
 
 class _NoopSpan:
     __slots__ = ()
+
+    def set(self, **fields):
+        pass
 
     def __enter__(self):
         return self

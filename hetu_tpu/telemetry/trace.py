@@ -30,6 +30,17 @@ and ``ms`` its length (events.py writes them that way); serving
 step/prefill records timestamp the END of the phase, so the exporter
 backdates their start by the duration field.
 
+Nesting: a ``span`` record carries ``parent`` (the enclosing span's
+name on its thread, None at a root), ``us`` (its start to the
+microsecond; ``t`` has three decimals) and the identifier its root
+shares with its children (``wave=``, ``step=``).  The exporter places a
+span by ``us`` and clips a child that the rounding of ``ms`` let run
+past its parent, so a wave draws as ``serve.wave`` holding
+``serve.admit``, ``serve.wave.assemble``, ``serve.wave.dispatch``,
+``serve.wave.sync`` and ``serve.wave.unpack``.  ``--check`` enforces the
+span-nesting rule: a span that names a parent must find a span of that
+name on its thread whose interval holds its start.
+
 ``--check`` validates every record against the event contract AND the
 span-balance rule: every ``serve_admit`` must have a matching
 ``serve_finish`` (a request admitted but never retired is a leaked
@@ -73,6 +84,12 @@ requeue (or a handoff pair) means a swap landed under a live request.
 drained must retire exactly once AFTER the drain, on a peer — never
 on the draining replica itself, never twice, never zero times
 (deadline-expired rids excepted).
+
+``--check`` also enforces the lifecycle-residue rule: any
+``serve_lifecycle_residue`` record fails the gate — a mixed-mode
+request waited long between its claim and its first token outside
+every wave (a paused host, or wave attribution that broke; the
+assertion this replaced raised out of the scheduler).
 
 ``--check`` also enforces the lockdep rule (ISSUE 19): any
 ``lockdep_violation`` record fails the gate outright — the sanitizer
@@ -178,8 +195,10 @@ def to_chrome_trace(events):
             track = rec.get("tid", rec.get("_src", "events"))
         tid = tid_for(pid, track)
         ts_us = float(rec.get("t", 0.0)) * 1e6
+        if kind == "span" and isinstance(rec.get("us"), (int, float)):
+            ts_us = float(rec["us"])
         args = {k: v for k, v in rec.items()
-                if k not in ("t", "event", "pid", "tid", "_src")
+                if k not in ("t", "us", "event", "pid", "tid", "_src")
                 and isinstance(v, (int, float, str, bool))}
         if kind == "gauge":
             out.append({"name": str(rec.get("name")), "cat": "gauge",
@@ -242,7 +261,27 @@ def to_chrome_trace(events):
         out.append({**flow, "ph": "f", "bp": "e", "ts": d1,
                     "pid": rpid, "tid": rtid})
         n_flows += 1
+    _clip_children(out)
     return {"traceEvents": out, "displayTimeUnit": "ms"}, n_spans
+
+
+def _clip_children(trace_events):
+    """A viewer nests complete events of one track by containment; a
+    child span's rounded length can overrun its parent's end by a
+    microsecond, which would draw it beside the parent.  Clip it."""
+    tracks = {}
+    for ev in trace_events:
+        if ev.get("ph") == "X" and ev.get("cat") == "span":
+            tracks.setdefault((ev["pid"], ev["tid"]), []).append(ev)
+    for spans in tracks.values():
+        open_ = []
+        for ev in sorted(spans, key=lambda e: (e["ts"], -e["dur"])):
+            while open_ and open_[-1]["ts"] + open_[-1]["dur"] <= ev["ts"]:
+                open_.pop()
+            if open_ and ev["args"].get("parent") == open_[-1]["name"]:
+                end = open_[-1]["ts"] + open_[-1]["dur"]
+                ev["dur"] = min(ev["dur"], end - ev["ts"])
+            open_.append(ev)
 
 
 def check_span_balance(events):
@@ -596,6 +635,55 @@ def check_moe_attribution(events):
     return problems
 
 
+def check_span_nesting(events):
+    """The span-nesting rule: a ``span`` record that names a ``parent``
+    must find a span of that name on its own pid/thread whose interval
+    holds its start (one millisecond of slack: ``ms`` is rounded).  A
+    stream cut short (``--last``, a flight dump) may have lost the
+    parent, which closes after its children: records newer than the
+    last root are exempt."""
+    if any(e.get("event") == "flight_dump" for e in events):
+        return []
+    spans = [e for e in events if e.get("event") == "span"
+             and isinstance(e.get("ms"), (int, float))]
+
+    def start_us(e):
+        return float(e.get("us", float(e.get("t", 0.0)) * 1e6))
+
+    def end_us(e):
+        return start_us(e) + e["ms"] * 1e3
+
+    by_thread = {}
+    for e in spans:
+        by_thread.setdefault((e.get("pid"), e.get("tid")), []).append(e)
+    problems = []
+    for thread in by_thread.values():
+        roots_end = max((end_us(e) for e in thread
+                         if e.get("parent") is None), default=None)
+        open_ = []
+        for e in sorted(thread, key=lambda e: (start_us(e), -e["ms"])):
+            at = start_us(e)
+            open_ = [p for p in open_ if end_us(p) + 1e3 >= at]
+            parent = e.get("parent")
+            if parent is not None and roots_end is not None \
+                    and at <= roots_end \
+                    and not any(p.get("name") == parent for p in open_):
+                problems.append(
+                    f"span-nesting: span {e.get('name')!r} names parent "
+                    f"{parent!r} but no such span holds it")
+            open_.append(e)
+    return problems
+
+
+def check_lifecycle_residue(events):
+    """The lifecycle-residue rule: presence is the finding (the engine
+    emits one only past the threshold the old assertion raised at)."""
+    return [f"lifecycle-residue: request {e.get('request')!r} waited "
+            f"{e.get('residue_ms')} ms of a {e.get('wall_ms')} ms "
+            f"prefill wall outside every wave"
+            for e in events if e.get("event") == "serve_lifecycle_residue"]
+
+
 def check_lockdep(events):
     """The lockdep rule (ISSUE 19): a ``lockdep_violation`` record in
     the stream IS a finding — the sanitizer only emits after it proved
@@ -701,8 +789,11 @@ def main(argv=None):
                          "long hold — fails the gate), and the MoE "
                          "routing-attribution rule (per serve_step, "
                          "routed + dropped == tokens x top_k x MoE "
-                         "layers; dense steps exempt); exit 1 on "
-                         "violations")
+                         "layers; dense steps exempt), and the "
+                         "span-nesting rule (a span naming a parent "
+                         "lies inside one) and the lifecycle-residue "
+                         "rule (any serve_lifecycle_residue record "
+                         "fails the gate); exit 1 on violations")
     args = ap.parse_args(argv)
 
     paths = args.paths or configured_logs()
@@ -741,6 +832,10 @@ def main(argv=None):
         problems.extend(lockdep)
         moe = check_moe_attribution(events)
         problems.extend(moe)
+        nesting = check_span_nesting(events)
+        problems.extend(nesting)
+        residue = check_lifecycle_residue(events)
+        problems.extend(residue)
         for p in problems:
             print(p)
         print(json.dumps({"records": len(events), "bad_lines": bad,
@@ -754,7 +849,10 @@ def main(argv=None):
                           "scale_balance_violations": len(scale),
                           "tier_balance_violations": len(tier),
                           "lockdep_violations": len(lockdep),
-                          "moe_attribution_violations": len(moe)}))
+                          "moe_attribution_violations": len(moe),
+                          "span_nesting_violations": len(nesting),
+                          "lifecycle_residue_violations":
+                              len(residue)}))
         return 1 if problems or bad else 0
 
     if args.export:

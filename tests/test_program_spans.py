@@ -1,0 +1,379 @@
+"""The program's spans, names and request timelines (ISSUE 25).
+
+One span primitive on the profiler's clock (``telemetry.span`` enters a
+``TraceAnnotation("hetu.<name>")``, records its parent and passes
+``wave=``/``step=`` through); a fixed number of spans a serving wave and
+a training step whatever is live; a ``Result``'s queue wait and
+per-token stamps; ``ServingMetrics.mark()``/``snapshot(since=)``; and a
+host pause mid-prefill that is counted instead of raising.
+"""
+
+import gc
+import json
+import time
+
+import numpy as np
+import pytest
+
+import hetu_tpu as ht
+from hetu_tpu import telemetry
+from hetu_tpu.serving import Request, ServingEngine
+from hetu_tpu.telemetry import events as tevents
+from hetu_tpu.telemetry.trace import main as trace_main
+from hetu_tpu.telemetry.trace import read_events, to_chrome_trace
+
+from test_serving import _rand_gpt
+
+pytestmark = pytest.mark.smoke
+
+
+@pytest.fixture(autouse=True)
+def _fresh(monkeypatch):
+    monkeypatch.setenv("HETU_TELEMETRY", "1")
+    telemetry.reset()
+    yield
+    telemetry.reset()
+
+
+@pytest.fixture()
+def merged_log(tmp_path, monkeypatch):
+    log = str(tmp_path / "telemetry.jsonl")
+    monkeypatch.setenv("HETU_TELEMETRY_LOG", log)
+    return log
+
+
+@pytest.fixture(scope="module")
+def model():
+    return _rand_gpt()
+
+
+def _spans(path):
+    with open(path) as f:
+        recs = [json.loads(ln) for ln in f if ln.strip()]
+    return [r for r in recs if r["event"] == "span"]
+
+
+class _Annotations:
+    """Stands in for ``jax.profiler.TraceAnnotation``: records what was
+    entered and left."""
+
+    def __init__(self):
+        self.entered, self.left = [], []
+
+    def __call__(self, name):
+        outer = self
+
+        class _One:
+            def __enter__(self):
+                outer.entered.append(name)
+
+            def __exit__(self, *exc):
+                outer.left.append(name)
+        return _One()
+
+
+# --------------------------------------------------------------------- #
+# the primitive
+# --------------------------------------------------------------------- #
+
+class TestSpanPrimitive:
+    def test_parent_and_identifiers_reach_the_record(self, merged_log):
+        with telemetry.span("serve.wave", wave=7) as root:
+            with telemetry.span("serve.admit", wave=7):
+                with telemetry.span("serve.kv_alloc", queue=2):
+                    pass
+            with telemetry.span("serve.wave.sync", wave=7):
+                pass
+            root.set(live=3)
+        with telemetry.span("exec.step", step=4):
+            pass
+        by = {r["name"]: r for r in _spans(merged_log)}
+        assert by["serve.wave"]["parent"] is None
+        assert by["serve.wave"]["wave"] == 7 and by["serve.wave"]["live"] == 3
+        assert by["serve.admit"]["parent"] == "serve.wave"
+        assert by["serve.kv_alloc"]["parent"] == "serve.admit"
+        assert by["serve.wave.sync"]["parent"] == "serve.wave"
+        assert by["serve.wave.sync"]["wave"] == 7
+        assert by["exec.step"]["parent"] is None and by["exec.step"]["step"] == 4
+        # the start to the microsecond, beside the contract's rounded t
+        assert all(abs(r["us"] / 1e6 - r["t"]) < 2e-3 for r in by.values())
+
+    def test_a_span_is_a_trace_annotation_named_hetu(self, monkeypatch):
+        ann = _Annotations()
+        monkeypatch.setattr(tevents, "TraceAnnotation", ann)
+        with telemetry.span("serve.wave", wave=1):
+            with telemetry.span("serve.admit", wave=1):
+                pass
+        assert ann.entered == ["hetu.serve.wave", "hetu.serve.admit"]
+        assert ann.left == ["hetu.serve.admit", "hetu.serve.wave"]
+
+    def test_disabled_enters_no_annotation(self, monkeypatch):
+        ann = _Annotations()
+        monkeypatch.setattr(tevents, "TraceAnnotation", ann)
+        monkeypatch.setenv("HETU_TELEMETRY", "0")
+        with telemetry.span("serve.wave", wave=1) as root:
+            root.set(live=1)
+        assert ann.entered == [] and ann.left == []
+
+    def test_parent_stack_survives_an_exception(self, merged_log):
+        with pytest.raises(ValueError):
+            with telemetry.span("outer"):
+                with telemetry.span("inner"):
+                    raise ValueError("boom")
+        with telemetry.span("after"):
+            pass
+        by = {r["name"]: r for r in _spans(merged_log)}
+        assert by["inner"]["parent"] == "outer"
+        assert by["after"]["parent"] is None
+
+    def test_export_draws_the_nesting_and_check_reads_it(self, merged_log,
+                                                         tmp_path, capsys):
+        with telemetry.span("serve.wave", wave=1):
+            with telemetry.span("serve.wave.assemble", wave=1):
+                time.sleep(0.001)
+            with telemetry.span("serve.wave.sync", wave=1):
+                time.sleep(0.002)
+        events, bad = read_events([merged_log])
+        trace, n = to_chrome_trace(events)
+        assert n == 3 and not bad
+        xs = {e["name"]: e for e in trace["traceEvents"] if e["ph"] == "X"}
+        root = xs["serve.wave"]
+        for child in ("serve.wave.assemble", "serve.wave.sync"):
+            c = xs[child]
+            assert c["args"]["parent"] == "serve.wave"
+            assert c["args"]["wave"] == 1
+            assert root["ts"] <= c["ts"]
+            assert c["ts"] + c["dur"] <= root["ts"] + root["dur"]
+        assert trace_main([merged_log, "--check"]) == 0
+        capsys.readouterr()
+        # a span whose parent never appears is a contract violation
+        telemetry.emit("span", name="orphan", ms=1.0, parent="nowhere",
+                       us=int(time.time() * 1e6), pid=1, tid="t")
+        telemetry.emit("span", name="late.root", ms=1.0, parent=None,
+                       us=int(time.time() * 1e6) + 5000, pid=1, tid="t")
+        assert trace_main([merged_log, "--check"]) == 1
+        out = capsys.readouterr().out
+        assert "span-nesting" in out and "'orphan'" in out
+
+
+# --------------------------------------------------------------------- #
+# the serving wave
+# --------------------------------------------------------------------- #
+
+WAVE_SPANS = ["serve.admit", "serve.kv_alloc", "serve.wave",
+              "serve.wave.assemble", "serve.wave.dispatch",
+              "serve.wave.sync", "serve.wave.unpack"]
+
+
+def _engine(model, **kw):
+    p, cfg = model
+    kw.setdefault("slots", 8)
+    return ServingEngine(p, cfg, ragged=True, paged=True, kv_block=8, **kw)
+
+
+class TestWaveSpans:
+    @pytest.mark.parametrize("n_requests", [1, 3, 8])
+    def test_one_wave_emits_each_span_once(self, model, merged_log,
+                                           n_requests):
+        eng = _engine(model)
+        for i in range(n_requests):
+            eng.submit(Request(prompt=[3 + i, 5, 7], max_new_tokens=6,
+                               seed=i))
+        for _ in range(3):
+            eng.step()
+        spans = [r for r in _spans(merged_log)
+                 if r["name"].startswith("serve.")]
+        for wave in (1, 2, 3):
+            mine = sorted(r["name"] for r in spans
+                          if r.get("wave") == wave
+                          or (r["name"] == "serve.kv_alloc"
+                              and r["parent"] == "serve.admit"))
+            # kv_alloc carries no wave tag: one per wave, three in all
+            assert [n for n in mine if n != "serve.kv_alloc"] == \
+                [n for n in WAVE_SPANS if n != "serve.kv_alloc"]
+        assert sorted(r["name"] for r in spans) == sorted(WAVE_SPANS * 3)
+        roots = [r for r in spans if r["name"] == "serve.wave"]
+        assert [r["wave"] for r in roots] == [1, 2, 3]
+        assert roots[0]["live"] == n_requests
+        assert roots[0]["q_prefill"] == 3 * n_requests
+        assert roots[1]["q_decode"] == n_requests
+        assert all(r["parent"] == "serve.wave" for r in spans
+                   if r["name"].startswith("serve.wave.")
+                   or r["name"] == "serve.admit")
+
+    def test_a_step_with_nothing_live_stops_after_admission(self, model,
+                                                            merged_log):
+        eng = _engine(model)
+        assert eng.step() == []
+        assert sorted(r["name"] for r in _spans(merged_log)) == \
+            ["serve.admit", "serve.kv_alloc", "serve.wave"]
+
+    def test_speculative_wave_adds_the_draft_span(self, model, merged_log):
+        eng = _engine(model, slots=4, spec=2)
+        eng.submit(Request(prompt=[3, 5, 7], max_new_tokens=6))
+        eng.step()
+        eng.step()
+        second = [r["name"] for r in _spans(merged_log)
+                  if r.get("wave") == 2]
+        assert sorted(second) == sorted(
+            [n for n in WAVE_SPANS if n != "serve.kv_alloc"]
+            + ["serve.wave.draft"])
+
+
+# --------------------------------------------------------------------- #
+# the request's timeline
+# --------------------------------------------------------------------- #
+
+class TestResultTimeline:
+    @pytest.mark.parametrize("kw", [
+        dict(ragged=True, paged=True, kv_block=8, prefill_chunk=4),
+        dict(ragged=True, paged=True, kv_block=8, spec=2),
+        dict(ragged=False, paged=True, kv_block=8),
+        dict(ragged=False, paged=False),
+        dict(ragged=False, paged=False, spec=2),
+    ], ids=["mixed-chunked", "mixed-spec", "paged", "contiguous",
+            "contiguous-spec"])
+    def test_token_times_and_queue_wait(self, model, kw):
+        p, cfg = model
+        eng = ServingEngine(p, cfg, slots=2, **kw)
+        reqs = [Request(prompt=list(range(2, 2 + n)), max_new_tokens=m,
+                        seed=i)
+                for i, (n, m) in enumerate([(9, 7), (3, 1), (5, 12),
+                                            (2, 4)])]
+        out = eng.run(reqs)
+        assert len(out) == 4
+        for r in out.values():
+            t = r.token_times_s
+            assert len(t) == r.n_generated
+            assert t[0] == pytest.approx(r.ttft_s, abs=1e-9)
+            assert all(b >= a for a, b in zip(t, t[1:]))
+            assert t[-1] <= r.latency_s + 1e-9
+            assert 0.0 <= r.queue_wait_s <= r.ttft_s
+        # two slots, four requests: the later two waited for a slot
+        waits = sorted(r.queue_wait_s for r in out.values())
+        assert waits[-1] > waits[0]
+
+    def test_a_speculative_wave_shares_one_stamp(self, model):
+        p, cfg = model
+        eng = ServingEngine(p, cfg, slots=2, ragged=True, paged=True,
+                            kv_block=8, spec=2)
+        [r] = eng.run([Request(prompt=[4, 5, 6], max_new_tokens=12)]
+                      ).values()
+        # 12 tokens in fewer waves than tokens: some stamps repeat
+        assert eng.spec_emitted > eng.spec_waves
+        assert len(set(r.token_times_s)) < r.n_generated
+
+
+class TestMetricsMark:
+    def test_since_a_mark_excludes_what_came_before(self, model):
+        eng = _engine(model, slots=2)
+        m0 = eng.metrics.mark()
+        assert eng.metrics.snapshot(since=m0) == eng.metrics.snapshot()
+        eng.run([Request(prompt=[7, 8], max_new_tokens=4),
+                 Request(prompt=[9], max_new_tokens=6)])
+        whole = eng.metrics.snapshot()
+        assert eng.metrics.snapshot(since=m0) == whole
+        m1 = eng.metrics.mark()
+        empty = eng.metrics.snapshot(since=m1)
+        assert empty["steps"] == 0 and empty["requests_finished"] == 0
+        assert empty["decode_ms_p50"] is None and empty["components"] == {}
+        eng.run([Request(prompt=[1, 2, 3], max_new_tokens=5)])
+        tail = eng.metrics.snapshot(since=m1)
+        after = eng.metrics.snapshot()
+        assert tail["requests_finished"] == 1
+        assert tail["tokens_generated"] == 5
+        assert tail["steps"] == after["steps"] - whole["steps"]
+        assert tail["steps"] == 5       # one prefill wave + four decode
+        assert tail["mean_batch_occupancy"] == pytest.approx(0.5)
+        assert after["requests_finished"] == 3
+        assert tail["components"]["decode_ms"]["p50_ms"] > 0
+        assert tail["wall_s"] < after["wall_s"]
+
+
+# --------------------------------------------------------------------- #
+# a paused host
+# --------------------------------------------------------------------- #
+
+class TestLifecycleResidue:
+    def test_a_pause_mid_prefill_is_counted_not_raised(self, model,
+                                                       tmp_path, capsys):
+        assert gc.isenabled()
+        log = str(tmp_path / "serve.jsonl")
+        eng = _engine(model, slots=2, log_path=log)
+        # warm the programs: the paused request's wave must be short
+        eng.run([Request(prompt=[5, 6, 7], max_new_tokens=2)])
+        admit = eng._admit_paged
+
+        def paused_admit():
+            claimed = admit()
+            if claimed:
+                time.sleep(0.2)         # between the claim and the wave
+            return claimed
+        eng._admit_paged = paused_admit
+        [res] = eng.run([Request(prompt=[8, 6, 7], max_new_tokens=2,
+                                 request_id="paused")]).values()
+        assert res.n_generated == 2
+        assert telemetry.snapshot()["counters"][
+            "serve.lifecycle_residue"] == 1
+        [ev] = [e for e in eng.metrics.events
+                if e["event"] == "serve_lifecycle_residue"]
+        assert ev["request"] == "paused" and ev["residue_ms"] >= 150
+        assert ev["wall_ms"] >= ev["residue_ms"]
+        # reported as chunk_stall_ms, not folded to 0
+        stalls = eng.metrics.components["chunk_stall_ms"]
+        assert stalls[0] == 0.0 and stalls[1] >= 150
+        assert trace_main([log, "--check"]) == 1
+        assert "lifecycle-residue" in capsys.readouterr().out
+
+
+# --------------------------------------------------------------------- #
+# the trainer's step
+# --------------------------------------------------------------------- #
+
+class TestExecutorSpans:
+    def test_step_span_holds_its_children(self, merged_log):
+        x = ht.placeholder_op("x")
+        w = ht.init.xavier_uniform((16, 16), name=f"ps_w_{time.time_ns()}")
+        loss = ht.reduce_mean_op(ht.reduce_mean_op(
+            ht.relu_op(ht.matmul_op(x, w)), axes=1), axes=0)
+        train = ht.optim.SGDOptimizer(learning_rate=0.1).minimize(loss)
+        ex = ht.Executor({"train": [loss, train]})
+        feed = {x: np.ones((4, 16), np.float32)}
+        ex.run("train", feed_dict=feed)
+        ex.run("train", feed_dict=feed)
+        out = ex.run("train", feed_dict=feed, convert_to_numpy_ret_vals=True)
+        assert isinstance(out[0], np.ndarray) and out[1] is None
+        spans = [r for r in _spans(merged_log)
+                 if r["name"].startswith("exec.")]
+        steps = {s: sorted(r["name"] for r in spans if r["step"] == s)
+                 for s in (1, 2, 3)}
+        base = ["exec.dispatch", "exec.feed", "exec.phase_a", "exec.step"]
+        assert steps[1] == sorted(base + ["exec.compile"])
+        assert steps[2] == base
+        assert steps[3] == sorted(base + ["exec.fetch"])
+        for r in spans:
+            assert r["parent"] == (None if r["name"] == "exec.step"
+                                   else "exec.step")
+            assert r["subgraph"] == "train"
+
+    def test_nodes_trace_under_their_own_scope(self):
+        from hetu_tpu.executor import scope_name
+        x = ht.placeholder_op("x")
+        w = ht.init.xavier_uniform((8, 8), name=f"ps_s_{time.time_ns()}")
+        y = ht.matmul_op(x, w)
+        loss = ht.reduce_mean_op(ht.reduce_mean_op(y, axes=1), axes=0)
+        opt = ht.optim.SGDOptimizer(learning_rate=0.1)
+        train = opt.minimize(loss)
+        ex = ht.Executor({"train": [loss, train]})
+        sub = ex.subexecutor["train"]
+        names = {scope_name(n) for n in sub.topo}
+        assert "optimizer" in names
+        assert any(n.startswith("grad_") for n in names)
+        assert not any(c.isdigit() for n in names for c in n
+                       if not n.startswith("ps_s_")), names
+        feeds = {"x": np.ones((4, 8), np.float32)}
+        import jax
+        text = jax.jit(lambda p, f: sub._trace(
+            p, ex.opt_states, 0, jax.random.PRNGKey(0), f)
+        ).lower(ex.var_values, feeds).as_text(debug_info=True)
+        assert "optimizer" in text and scope_name(y) in text
