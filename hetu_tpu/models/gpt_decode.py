@@ -469,8 +469,11 @@ def _sample_slot(logits, temperature, top_k, key):
     """Per-slot sampling with temperature AND top_k TRACED (unlike the
     offline ``_sample``, whose static top_k would force one compile per
     distinct request setting — a serving batch mixes settings freely).
-    The kth-largest threshold comes from a full sort: O(V log V), noise
-    next to the decode matmuls at serving batch sizes; top_k=0 disables
+    The kth-largest threshold comes from a full sort of the vocabulary,
+    O(V log V), taken whether or not the slot uses it (greedy, top_k=0):
+    about 1 ms for sixteen slots at V = 50257 on a v5e (PERF.md), so
+    callers sample only the rows a request reads (``_spec_sample``
+    takes a slot's window, never a padded q-block).  top_k=0 disables
     the mask."""
     greedy = jnp.argmax(logits).astype(jnp.int32)
     t_safe = jnp.maximum(temperature, 1e-6)
@@ -943,40 +946,36 @@ def _verify_step(params, cfg_tuple, cache_k, cache_v, pos, tokens,
 
 
 @jax.named_scope("sample")
-def _spec_sample(logits, temperature, top_k, rng_keys, first_row=None,
-                 q_len=None):
-    """Sequential per-position sampling over a verify q-block: position
-    j's token comes from the (j+1)-th split of each slot's rng stream —
-    EXACTLY the splits j+1 non-speculative steps would consume — and
-    ``keys_after[b, j]`` is the stream state after those splits, so the
-    host resumes at the accepted count and the stream stays aligned
-    with the non-speculative path token for token.
+def _spec_sample(logits, temperature, top_k, rng_keys, count):
+    """Sequential sampling over each slot's SAMPLING WINDOW: ``logits``
+    [B, W, V] holds the window's rows only (``_window_logits`` gathers
+    them; a verify q-block is its own window) and ``count`` [B] says how
+    many of them are live.  Row w's token comes from the (w+1)-th split
+    of the slot's rng stream — EXACTLY the splits w+1 non-speculative
+    steps would consume — and ``keys_after[b, w]`` is the stream after
+    those splits, so the host resumes at the accepted count and the
+    stream stays aligned with the non-speculative path token for token.
 
-    ``first_row``/``q_len`` [B] generalize this to a MIXED wave: slot b
-    splits its stream only at rows ``first_row[b] <= j < q_len[b]`` —
-    0/1 for a decode slot and 0/k+1 for spec-verify (both sequential
-    splits, as above), ``q_len-1``/``q_len`` for a prompt's FINAL
-    chunk (one split, matching the phase-split prefill paths' single
-    split per prompt), and ``q_len``/anything for a mid-prompt chunk
-    (no split; the returned keys equal the input and the host carries
-    the stream forward untouched).  Rows outside the window still
-    return a (discarded) sample so the wave stays one fused dispatch.
-    None (the default) keeps the pure-verify behavior: split at every
-    row."""
-    def row(keys, j):
+    Slot b splits its stream once for each row ``w < count[b]`` and at
+    no other: 1 for a decode slot and for a prompt's FINAL chunk (one
+    split, matching the phase-split prefill paths' single split per
+    prompt), up to k+1 for spec-verify, 0 for a mid-prompt chunk and a
+    dead slot (the returned keys equal the input and the host carries
+    the stream forward untouched).  Rows at or past ``count`` return a
+    sample nobody reads; there are at most W - 1 of them a slot, where W
+    is 1 on an engine that does not speculate.
+
+    Returns (sampled [B, W], keys_after [B, W, 2])."""
+    def row(keys, w):
         splits = jax.vmap(jax.random.split)(keys)          # [B,2,2]
-        if first_row is None:
-            keys = splits[:, 0]
-        else:
-            do = (j >= first_row) & (j < q_len)            # [B]
-            keys = jnp.where(do[:, None], splits[:, 0], keys)
+        keys = jnp.where((w < count)[:, None], splits[:, 0], keys)
         tok = jax.vmap(_sample_slot)(
-            jax.lax.dynamic_index_in_dim(logits, j, 1, keepdims=False),
+            jax.lax.dynamic_index_in_dim(logits, w, 1, keepdims=False),
             temperature, top_k, splits[:, 1])
         return keys, (tok, keys)
 
-    # a scan, not a Python loop: a whole prompt in one q-block is 1024
-    # rows, and 1024 unrolled vocab sorts do not compile in useful time
+    # a scan, not a Python loop: every row sorts the vocabulary once a
+    # slot, and unrolled sorts compile slowly
     _, (toks, after) = jax.lax.scan(row, rng_keys,
                                     jnp.arange(logits.shape[1]))
     return jnp.swapaxes(toks, 0, 1), jnp.swapaxes(after, 0, 1)
@@ -993,7 +992,8 @@ def _serve_verify(params, cfg_tuple, cache_k, cache_v, pos, tokens,
     logits, cache_k, cache_v = _verify_step(
         params, cfg_tuple, cache_k, cache_v, pos, tokens, q_len,
         attn=attn, moe_stats=sd)
-    sampled, after = _spec_sample(logits, temperature, top_k, rng_keys)
+    sampled, after = _spec_sample(logits, temperature, top_k, rng_keys,
+                                  q_len)
     out = (sampled, cache_k, cache_v, after)
     if moe_on:
         out = out + (_moe_stats_out(
@@ -1013,7 +1013,8 @@ def _serve_verify_paged(params, cfg_tuple, cache_k, cache_v, tables,
     logits, cache_k, cache_v = _verify_step(
         params, cfg_tuple, cache_k, cache_v, pos, tokens, q_len,
         attn=attn, block_tables=tables, moe_stats=sd)
-    sampled, after = _spec_sample(logits, temperature, top_k, rng_keys)
+    sampled, after = _spec_sample(logits, temperature, top_k, rng_keys,
+                                  q_len)
     out = (sampled, cache_k, cache_v, after)
     if moe_on:
         out = out + (_moe_stats_out(
@@ -1168,14 +1169,38 @@ def _serve_prefill_batch_paged(params, cfg_tuple, cache_k, cache_v,
 # across contiguous/paged/int8/spec/chunked configs.
 
 
+def _window_logits(params, name, h, first_row, window):
+    """The head over each slot's sampling window ALONE: rows
+    ``first_row[b] + w`` (``w < window``, clipped to the q-block) of the
+    last block's output ``h`` [B, Q, hd] are gathered FIRST, then the
+    final LN and the tied head run over ``[B, window, hd]``.  Returns
+    logits [B, window, V] f32.  The padded q-block never meets the
+    vocabulary: at 16 slots x 256 rows x 50257 that was 0.82 GB of f32
+    and 255 of every 256 rows were read by nobody."""
+    with jax.named_scope("lm_head"):
+        rows = jnp.clip(first_row[:, None] + jnp.arange(window)[None, :],
+                        0, h.shape[1] - 1)                 # [B, W]
+        hw = jnp.take_along_axis(h, rows[:, :, None], axis=1)
+        hw = _ln(hw, params[f"{name}_ln_f_scale"],
+                 params[f"{name}_ln_f_bias"])
+        return (hw @ params[f"{name}_wte_table"].T
+                ).astype(jnp.float32) \
+            + params.get(f"{name}_head_bias", 0.0)
+
+
 def _mixed_step(params, cfg_tuple, cache_k, cache_v, pos, tokens,
-                q_len, self_fresh, attn="masked", block_tables=None,
-                has_fresh=False, moe_stats=None):
+                q_len, first_row, self_fresh, window=1, attn="masked",
+                block_tables=None, has_fresh=False, moe_stats=None):
     """One MIXED wave: slot b consumes ``tokens[b, :q_len[b]]`` at
     positions ``pos[b] .. pos[b]+q_len[b]-1`` — whatever mode those
     tokens are (prompt chunk, draft+bonus verify block, single decode
-    token).  Returns (logits [B, Q, V] f32, cache_k, cache_v); row
-    ``logits[b, j]`` is the next-token distribution after input j.
+    token).  Returns (logits [B, W, V] f32, cache_k, cache_v) for the
+    slots' SAMPLING WINDOWS only (``_window_logits``): row
+    ``logits[b, w]`` is the next-token distribution after input
+    ``first_row[b] + w``, for ``w < window`` = W (static; 1, or
+    ``spec_k + 1`` on an engine that speculates — a constant of the
+    engine, so it adds no program).  Every block still runs over the
+    whole q-block; only the final LN and the head are narrowed.
     Dead positions and dead slots (``q_len`` 0) follow
     ``_verify_step``'s write/mask conventions exactly.
 
@@ -1197,7 +1222,8 @@ def _mixed_step(params, cfg_tuple, cache_k, cache_v, pos, tokens,
     The device trace finds the wave's parts under a handful of
     ``jax.named_scope`` names, the same for every layer: ``embed``,
     ``attn_qkv``, ``kv_write``, ``attention``, ``attn_out``, ``mlp``,
-    ``lm_head`` (and ``sample``, ``_spec_sample``'s own)."""
+    ``lm_head`` (the window's gather, final LN and head) and ``sample``,
+    ``_spec_sample``'s own."""
     name, L, H, Dh, S_max = cfg_tuple[:5]
     moe = _moe_of(cfg_tuple)
     B, Q = tokens.shape
@@ -1314,30 +1340,29 @@ def _mixed_step(params, cfg_tuple, cache_k, cache_v, pos, tokens,
         with jax.named_scope("mlp"):
             h = _ffn_block(params, us, h, i, moe=moe, valid=valid,
                            stats=moe_stats)
-    with jax.named_scope("lm_head"):
-        h = _ln(h, params[f"{name}_ln_f_scale"],
-                params[f"{name}_ln_f_bias"])
-        logits = (h @ params[f"{name}_wte_table"].T
-                  ).astype(jnp.float32) \
-            + params.get(f"{name}_head_bias", 0.0)
+    logits = _window_logits(params, name, h, first_row, window)
     return logits, cache_k, cache_v
 
 
 def _serve_mixed(params, cfg_tuple, cache_k, cache_v, pos, tokens,
                  q_len, first_row, self_fresh, temperature, top_k,
-                 rng_keys, attn="masked"):
+                 rng_keys, attn="masked", window=1):
     """One fused MIXED wave over all slots (contiguous layout): write +
-    score every slot's ragged q-block, then sample each slot's live
-    sampling window from its own rng stream (``first_row`` per
-    ``_spec_sample``).  Returns (sampled [B, Q], cache_k, cache_v,
-    keys_after [B, Q, 2][, moe stats])."""
+    score every slot's ragged q-block, then sample each slot's sampling
+    window — rows ``first_row[b] <= j < q_len[b]``, at most ``window``
+    of them — from its own rng stream (``_spec_sample``).  Returns
+    (sampled [B, W], cache_k, cache_v, keys_after [B, W, 2][, moe
+    stats]), both indexed FROM THE WINDOW'S FIRST ROW: ``[b, 0]`` is a
+    decode slot's token and a final chunk's first token, ``[b, :a + 1]``
+    a verify block's accepted run; a mid-prompt chunk and a dead slot
+    have an empty window and get their key back untouched."""
     moe_on = _moe_active(cfg_tuple)
     sd = {} if moe_on else None
     logits, cache_k, cache_v = _mixed_step(
         params, cfg_tuple, cache_k, cache_v, pos, tokens, q_len,
-        self_fresh, attn=attn, moe_stats=sd)
+        first_row, self_fresh, window=window, attn=attn, moe_stats=sd)
     sampled, after = _spec_sample(logits, temperature, top_k, rng_keys,
-                                  first_row, q_len)
+                                  q_len - first_row)
     out = (sampled, cache_k, cache_v, after)
     if moe_on:
         out = out + (_moe_stats_out(
@@ -1349,19 +1374,19 @@ def _serve_mixed(params, cfg_tuple, cache_k, cache_v, pos, tokens,
 def _serve_mixed_paged(params, cfg_tuple, cache_k, cache_v, tables,
                        pos, tokens, q_len, first_row, self_fresh,
                        temperature, top_k, rng_keys, attn="masked",
-                       has_fresh=False):
+                       has_fresh=False, window=1):
     """``_serve_mixed`` over the block-table paged pool (``q_len`` 0
     marks inert slots, whose writes route to scratch block 0 and whose
-    samples/keys the host discards).  ``has_fresh`` (static) marks
-    waves carrying prompt-chunk slots — see ``_mixed_step``."""
+    window is empty).  ``has_fresh`` (static) marks waves carrying
+    prompt-chunk slots — see ``_mixed_step``."""
     moe_on = _moe_active(cfg_tuple)
     sd = {} if moe_on else None
     logits, cache_k, cache_v = _mixed_step(
         params, cfg_tuple, cache_k, cache_v, pos, tokens, q_len,
-        self_fresh, attn=attn, block_tables=tables,
-        has_fresh=has_fresh, moe_stats=sd)
+        first_row, self_fresh, window=window, attn=attn,
+        block_tables=tables, has_fresh=has_fresh, moe_stats=sd)
     sampled, after = _spec_sample(logits, temperature, top_k, rng_keys,
-                                  first_row, q_len)
+                                  q_len - first_row)
     out = (sampled, cache_k, cache_v, after)
     if moe_on:
         out = out + (_moe_stats_out(
@@ -1371,29 +1396,31 @@ def _serve_mixed_paged(params, cfg_tuple, cache_k, cache_v, tables,
 
 
 @functools.lru_cache(maxsize=None)
-def serve_mixed_fn(donate=True, attn="masked"):
+def serve_mixed_fn(donate=True, attn="masked", window=1):
     """Jitted ``_serve_mixed`` — the contiguous mixed-mode wave (see
     ``serve_prefill_fn`` for the donation rationale).  Compiles per
     q-block bucket Q; the engine pow2-buckets the wave width, so the
-    ladder is log-bounded."""
-    kw = {"static_argnames": ("cfg_tuple", "attn")}
+    ladder is log-bounded.  ``window`` (the widest sampling window,
+    ``spec_k + 1`` or 1) is bound here, once an engine."""
+    kw = {"static_argnames": ("cfg_tuple", "attn", "window")}
     if donate:
         kw["donate_argnums"] = (2, 3)
     fn = jax.jit(_serve_mixed, **kw)
-    return functools.partial(fn, attn=attn)
+    return functools.partial(fn, attn=attn, window=window)
 
 
 @functools.lru_cache(maxsize=None)
-def serve_mixed_paged_fn(donate=True, attn="masked"):
+def serve_mixed_paged_fn(donate=True, attn="masked", window=1):
     """Jitted ``_serve_mixed_paged`` — the block-table mixed-mode wave,
     the production dispatch behind ``$HETU_SERVE_RAGGED``.  Compiles
     per (Q bucket, has_fresh): steady-state decode waves skip the
-    chunk-slot variant's extra softmax entirely."""
-    kw = {"static_argnames": ("cfg_tuple", "attn", "has_fresh")}
+    chunk-slot variant's extra softmax entirely.  ``window`` as in
+    ``serve_mixed_fn``: bound once an engine, never per wave."""
+    kw = {"static_argnames": ("cfg_tuple", "attn", "has_fresh", "window")}
     if donate:
         kw["donate_argnums"] = (2, 3)
     fn = jax.jit(_serve_mixed_paged, **kw)
-    return functools.partial(fn, attn=attn)
+    return functools.partial(fn, attn=attn, window=window)
 
 
 @functools.lru_cache(maxsize=None)

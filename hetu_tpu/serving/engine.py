@@ -309,8 +309,12 @@ class ServingEngine:
         self.ragged = resolve_serve_ragged(ragged)
         if self.ragged:
             attn = "ragged" if self.fast_path else "masked"
-            self._mixed = (serve_mixed_paged_fn(donate, attn)
-                           if self.paged else serve_mixed_fn(donate, attn))
+            # the widest sampling window a slot can have: a verify
+            # block's spec_k + 1 rows, else the one row a decode slot
+            # or a final chunk samples; the wave's head and sampling
+            # run over that many rows a slot, not the padded q-block
+            mixed_fn = serve_mixed_paged_fn if self.paged else serve_mixed_fn
+            self._mixed = mixed_fn(donate, attn, self.spec_k + 1)
             # tells the lifecycle accountant the wave IS the prefill:
             # a noise-scale chunk_stall residue is folded to 0, a
             # larger one counted (serve.lifecycle_residue)
@@ -1195,9 +1199,10 @@ class ServingEngine:
                 self.metrics.lc_prefill(req.request_id, e)
                 pre_credit[req.request_id] = e
                 if final:
+                    # a final chunk's window is its last row alone
                     r = self._finish_prefill(
-                        s, int(sampled[s, take - 1]),
-                        np.asarray(after[s, take - 1], np.uint32))
+                        s, int(sampled[s, 0]),
+                        np.asarray(after[s, 0], np.uint32))
                     if r:
                         done.append(r)
             if pre:

@@ -61,9 +61,11 @@ def assemble_mixed_wave(n_slots, entries, q_floor=1):
                      prompt chunk, ``[cur] + draft`` for spec-verify,
                      or ``[cur]`` for plain decode (len >= 1);
     * ``pos``        cache position of ``tokens[0]``;
-    * ``first_row``  index of the first row whose rng stream splits
-                     (== ``len(tokens)`` for mid-prompt chunks that
-                     sample nothing);
+    * ``first_row``  index of the first row whose rng stream splits:
+                     the start of the slot's sampling window, the only
+                     rows the wave's head and sampling run over
+                     (== ``len(tokens)``, an empty window, for
+                     mid-prompt chunks that sample nothing);
     * ``self_fresh`` True when the q-block's own K/V must be read
                      through the two-part fresh-self softmax (paged
                      prompt chunks) rather than the written cache.

@@ -24,6 +24,7 @@ from hetu_tpu.kernels import decode_attention as da
 from hetu_tpu.kernels import flash_attention as fa
 from hetu_tpu.kernels.ragged_attention import (
     ragged_attention, ragged_paged_attention)
+from hetu_tpu.models import gpt_decode as gd
 
 DH, S_MAX, BLOCK, SLOTS = 64, 1024, 16, 8
 
@@ -150,6 +151,32 @@ def test_ragged_contiguous_whole_prompt(sds):
         lambda q, k, v, n, ql: ragged_attention(q, k, v, n, ql,
                                                 interpret=False),
         sds((SLOTS, 1024, 12, DH), jnp.bfloat16), kv, kv, lens, lens)
+
+
+@pytest.mark.parametrize("window", [1, 5], ids=["W1", "W5-spec_k4"])
+def test_mixed_wave_tail(sds, window):
+    """What follows the last block of a chunk wave of the gpt2-xl cell
+    (16 slots, a 256-row q-block, 1600 wide, vocabulary 50257): the
+    window's gather, the final LN, the tied head and ``_spec_sample``,
+    alone.  The q-block never meets the vocabulary: no [16, 256, 50257]
+    tensor, and temporaries far under its 0.82 GB."""
+    B, Q, D, V = 16, 256, 1600, 50257
+
+    def tail(h, wte, scale, bias, first_row, q_len, temp, top_k, keys):
+        params = {"gpt_wte_table": wte, "gpt_ln_f_scale": scale,
+                  "gpt_ln_f_bias": bias}
+        logits = gd._window_logits(params, "gpt", h, first_row, window)
+        return gd._spec_sample(logits, temp, top_k, keys, q_len - first_row)
+
+    slot = sds((B,), jnp.int32)
+    compiled = jax.jit(tail).lower(
+        sds((B, Q, D), jnp.bfloat16), sds((V, D), jnp.bfloat16),
+        sds((D,), jnp.bfloat16), sds((D,), jnp.bfloat16), slot, slot,
+        sds((B,), jnp.float32), slot, sds((B, 2), jnp.uint32)).compile()
+    text = compiled.as_text()
+    assert f"[{B},{Q},{V}]" not in text
+    assert f"f32[{B},{window},{V}]" in text or f"f32[{B},{V}]" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < B * Q * V * 4 // 8
 
 
 # ------------------------------------------------------------------- #

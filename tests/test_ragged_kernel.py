@@ -377,3 +377,209 @@ class TestMixedModeEngine:
         p, cfg = model
         eng = ServingEngine(p, cfg, slots=4, paged=True, kv_block=8)
         assert eng.ragged and eng.metrics.mixed_mode
+
+
+# ------------------------------------------------------------------- #
+# the sampling window (ISSUE 26): the wave gathers each slot's window
+# before the head and samples only the window
+# ------------------------------------------------------------------- #
+
+
+def _all_rows_sample(logits, temperature, top_k, rng_keys, first_row,
+                     q_len):
+    """The plain reference: the scan the wave ran before ISSUE 26, over
+    EVERY row of the padded q-block's logits [B, Q, V], splitting slot
+    b's stream at rows ``first_row[b] <= j < q_len[b]``."""
+    import jax
+    from hetu_tpu.models.gpt_decode import _sample_slot
+
+    def row(keys, j):
+        splits = jax.vmap(jax.random.split)(keys)
+        do = (j >= first_row) & (j < q_len)
+        keys = jnp.where(do[:, None], splits[:, 0], keys)
+        tok = jax.vmap(_sample_slot)(logits[:, j], temperature, top_k,
+                                     splits[:, 1])
+        return keys, (tok, keys)
+
+    _, (toks, after) = jax.lax.scan(row, rng_keys,
+                                    jnp.arange(logits.shape[1]))
+    return np.asarray(toks).T, np.asarray(after).transpose(1, 0, 2)
+
+
+# slot: (tokens in the q-block, cache position, first_row); Q = 8, W = 3
+WINDOW_WAVE = {
+    0: ([5], 6, 0),                            # decode
+    1: ([3, 1, 4, 1, 5, 9], 12, 5),            # a prompt's FINAL chunk
+    2: ([2, 7, 1, 8, 2, 8, 1, 8], 8, 8),       # a mid-prompt chunk
+    3: ([6, 2, 8], 9, 0),                      # a verify block, k = 2
+}                                              # slot 4: dead
+SAMPLING = {
+    "greedy": ([0.0] * 5, [0] * 5),
+    "temperature": ([0.8, 1.1, 0.7, 0.9, 1.0], [0] * 5),
+    "top_k": ([0.8, 1.1, 0.7, 0.9, 1.0], [5, 3, 7, 4, 2]),
+    "mixed-settings": ([0.0, 0.9, 0.0, 1.2, 0.5], [0, 6, 3, 0, 0]),
+}
+
+
+@pytest.mark.smoke
+class TestSamplingWindow:
+    @pytest.mark.parametrize("sampling", list(SAMPLING))
+    @pytest.mark.parametrize("layout", ["contig", "paged"])
+    def test_window_equals_all_rows(self, model, layout, sampling):
+        """One wave holding a decode slot, a final chunk, a mid-prompt
+        chunk, a verify block and a dead slot: every row the engine
+        reads carries the token and the stream state that sampling ALL
+        rows of the q-block gave; empty windows return their key."""
+        import jax
+        from hetu_tpu.models import gpt_decode as gd
+        from hetu_tpu.serving.kv_manager import assemble_mixed_wave
+        p, cfg = model
+        params = {k: jnp.asarray(v, jnp.float32) for k, v in p.items()}
+        L, H, S = (cfg.num_hidden_layers, cfg.num_attention_heads,
+                   cfg.max_position_embeddings)
+        B, W, Dh = 5, 3, cfg.hidden_size // H
+        cfg_tuple = ("rg", L, H, Dh, S)
+        paged = layout == "paged"
+        wave = assemble_mixed_wave(
+            B, {s: (t, pos, fr, paged and len(t) > 3)
+                for s, (t, pos, fr) in WINDOW_WAVE.items()})
+        Q = wave["q"]
+        assert Q == 8
+        rng = np.random.RandomState(3)
+        if paged:
+            bs, T = 8, S // 8
+            shape = (L, B * T + 1, bs, H, Dh)
+            tables = (1 + np.arange(B * T, dtype=np.int32)).reshape(B, T)
+            layout_args = (tables,)
+        else:
+            shape = (L, B, S, H, Dh)
+            layout_args = ()
+        ck = jnp.asarray(rng.randn(*shape) * 0.3, jnp.float32)
+        cv = jnp.asarray(rng.randn(*shape) * 0.3, jnp.float32)
+        temp, topk = (np.asarray(SAMPLING[sampling][0], np.float32),
+                      np.asarray(SAMPLING[sampling][1], np.int32))
+        keys = np.asarray(jax.vmap(jax.random.PRNGKey)(
+            jnp.arange(B) + 11), np.uint32)
+        desc = (wave["pos"], wave["tokens"], wave["q_len"])
+        fn = (gd.serve_mixed_paged_fn if paged else gd.serve_mixed_fn)(
+            False, "masked", W)
+        kw = {"has_fresh": True} if paged else {}
+        sampled, _, _, after = fn(
+            params, cfg_tuple, ck, cv, *layout_args, *desc,
+            wave["first_row"], wave["self_fresh"], temp, topk, keys, **kw)
+        sampled, after = np.asarray(sampled), np.asarray(after)
+        assert sampled.shape == (B, W) and after.shape == (B, W, 2)
+
+        # the reference: the same step's logits at EVERY row (a window
+        # that starts at row 0 and is Q wide), then the old scan
+        full, _, _ = gd._mixed_step(
+            params, cfg_tuple, ck, cv, *desc, np.zeros(B, np.int32),
+            wave["self_fresh"], window=Q,
+            block_tables=tables if paged else None, has_fresh=paged)
+        assert full.shape == (B, Q, cfg.vocab_size)
+        want_tok, want_keys = _all_rows_sample(
+            full, temp, topk, keys, wave["first_row"], wave["q_len"])
+
+        read = 0
+        for b in range(B):
+            first, n = int(wave["first_row"][b]), int(wave["q_len"][b])
+            for w, j in enumerate(range(first, n)):
+                assert sampled[b, w] == want_tok[b, j], (b, j)
+                assert np.array_equal(after[b, w], want_keys[b, j]), (b, j)
+                read += 1
+            if n - first <= 0:        # mid-prompt chunk, dead slot
+                assert np.array_equal(after[b], np.tile(keys[b], (W, 1)))
+                assert np.array_equal(want_keys[b, Q - 1], keys[b])
+        assert read == 1 + 1 + 3      # decode, final chunk, verify block
+
+    @pytest.mark.parametrize("spec", [0, 2], ids=["W1", "W3"])
+    def test_chunk_wave_program_never_meets_the_vocabulary(self, model,
+                                                           spec):
+        """The chunk wave's program at bucket 128, lowered from an
+        engine's own state as ``chip_smoke.lowered_mixed_step`` lowers
+        the decode wave: no [B, Q, V] tensor, and the loop that sorts
+        the vocabulary runs W times, not Q."""
+        import jax
+        from hetu_tpu.serving.kv_manager import assemble_mixed_wave
+        p, cfg = _rand_gpt(S=256)
+        eng = ServingEngine(p, cfg, slots=4, ragged=True, paged=True,
+                            kv_block=8, spec=spec or None)
+        B, Q, V, W = 4, 128, cfg.vocab_size, spec + 1
+        wave = assemble_mixed_wave(B, {0: (list(range(1, 101)), 0, 99, True),
+                                       1: ([3], 7, 0, False)})
+        assert wave["q"] == Q
+        args = [eng.params, eng.cfg_tuple, eng.kv.cache_k, eng.kv.cache_v,
+                eng.kv.tables.copy(), wave["pos"], wave["tokens"],
+                wave["q_len"], wave["first_row"], wave["self_fresh"],
+                eng._temp, eng._topk, eng._keys]
+        kw = dict(eng._mixed.keywords, has_fresh=True)
+        assert kw["window"] == W
+        text = eng._mixed.func.lower(*args, **kw).as_text()
+        assert f"{B}x{Q}x{V}x" not in text
+        assert f"{B}x{W}x{V}xf32" in text
+
+        def inner_jaxprs(eqn):
+            for v in eqn.params.values():
+                for sub in (v if isinstance(v, (list, tuple)) else [v]):
+                    if hasattr(sub, "eqns") or hasattr(sub, "jaxpr"):
+                        yield getattr(sub, "jaxpr", sub)
+
+        def eqns_of(jaxpr):
+            for eqn in jaxpr.eqns:
+                yield eqn
+                for inner in inner_jaxprs(eqn):
+                    yield from eqns_of(inner)
+
+        def sorts(eqn):
+            return any(e.primitive.name == "sort"
+                       for inner in inner_jaxprs(eqn)
+                       for e in eqns_of(inner))
+
+        eqns = list(eqns_of(jax.make_jaxpr(
+            lambda *a: eng._mixed.func(*a, **kw),
+            static_argnums=(1,))(*args).jaxpr))
+        shapes = {getattr(v.aval, "shape", None)
+                  for e in eqns for v in e.outvars}
+        assert (B, W, V) in shapes and (B, Q, V) not in shapes
+        loops = [e.params["length"] for e in eqns
+                 if e.primitive.name == "scan" and sorts(e)]
+        assert loops == [W]
+
+    def test_same_programs_as_before(self):
+        """Warm-up as the benchmark's runner warms up (one request a
+        bucket, alone, two tokens), then a run that mixes final chunks,
+        mid-prompt chunks and decode slots: the engine holds one
+        program for each (Q bucket, has_fresh) it did before ISSUE 26
+        and the run builds nothing (``jax.monitoring``'s compile events,
+        as the runner counts them)."""
+        import jax
+        built = []
+
+        def on_duration(event, _secs, **_):
+            if event == "/jax/core/compile/backend_compile_duration":
+                built.append(event)
+
+        p, cfg = _rand_gpt(name="wnd", V=67)     # programs nobody built
+        eng = ServingEngine(p, cfg, slots=4, ragged=True, paged=True,
+                            kv_block=8, prefill_chunk=16)
+        programs = eng._mixed.func._cache_size   # one jit, every engine
+        before = programs()
+        for n in (8, 16):
+            eng.run([Request(prompt=((np.arange(n) + n) % 67).tolist(),
+                             max_new_tokens=2)])
+        # (Q = 1, decode), (Q = 8, chunk), (Q = 16, chunk)
+        assert programs() - before == 3
+        rng = np.random.RandomState(5)
+        reqs = [Request(prompt=rng.randint(1, 67, n).tolist(),
+                        max_new_tokens=m, temperature=t, seed=i)
+                for i, (n, m, t) in enumerate(
+                    [(8, 5, 0.0), (40, 3, 0.0), (16, 6, 0.9),
+                     (24, 2, 0.0), (8, 7, 0.0), (32, 4, 0.7)])]
+        jax.monitoring.register_event_duration_secs_listener(on_duration)
+        try:
+            res = eng.run(reqs)
+        finally:
+            jax.monitoring.unregister_event_duration_listener(on_duration)
+        assert len(res) == 6 and eng.prefill_chunks > 6
+        assert programs() - before == 3
+        assert not built
