@@ -150,7 +150,11 @@ def validate_serving(params, config, name, mesh=None):
     try:
         H = int(config.hidden_size)
         heads = int(config.num_attention_heads)
-        if H % heads != 0:
+        from ..models.gpt_decode import block_spec_of
+        # latent attention has its own head sizes: hidden / heads is
+        # not one of them
+        if H % heads != 0 \
+                and block_spec_of(config).attention != "latent":
             raise ShardCheckError(
                 f"serving config: hidden_size {H} is not divisible by "
                 f"num_attention_heads {heads}", kind="divisibility")

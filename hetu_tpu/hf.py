@@ -28,7 +28,8 @@ import numpy as np
 
 __all__ = ["convert_bert", "convert_bert_pretraining_heads",
            "convert_bert_classifier", "convert_bert_qa",
-           "convert_gpt2", "export_bert", "export_bert_classifier",
+           "convert_gpt2", "convert_glm4_moe_lite", "export_bert",
+           "export_bert_classifier",
            "export_bert_qa", "export_gpt2"]
 
 
@@ -167,6 +168,77 @@ def convert_gpt2(state_dict, name="gpt", prefix=""):
         out[f"{us}_ffn_wo_weight"] = _np(sd[f"{hf}.mlp.c_proj.weight"])
         out[f"{us}_ffn_wo_bias"] = _np(sd[f"{hf}.mlp.c_proj.bias"])
         i += 1
+    return out
+
+
+def convert_glm4_moe_lite(state_dict, config, name="glm", prefix="model."):
+    """HF ``glm4_moe_lite`` (the DeepSeek-V3 layout: MLA attention, a
+    sigmoid ``noaux_tc`` router with ``e_score_correction_bias``,
+    per-expert ``gate/up/down_proj``, ``shared_experts``) weights ->
+    the serving parameter dict of ``models.moe_decode.LatentMoEConfig``
+    (``config``; ``param_shapes`` lists the leaves).
+
+    torch ``Linear`` weights are [out, in] and are transposed; the
+    experts of a layer are stacked into [E, in, out] leaves;
+    ``kv_b_proj`` stays WHOLE (the serving step splits it into its key
+    and value halves when it absorbs them).  The checkpoints' RoPE pairs
+    neighbouring columns (2j, 2j+1); this repo rotates halves (j,
+    j + d/2), so the rope columns of ``q_b_proj`` (every head's) and of
+    ``kv_a_proj_with_mqa`` are permuted once, here: evens first, then
+    odds — the same permutation on both sides of the score, which it
+    leaves unchanged.  Layers past ``config.num_hidden_layers`` (the
+    multi-token-prediction layer) are dropped, as the checkpoints' own
+    loaders drop them at inference."""
+    c = config
+    sd = state_dict
+    H, dn, dr = (c.num_attention_heads, c.qk_nope_head_dim,
+                 c.qk_rope_head_dim)
+    dc = c.kv_lora_rank
+    halves = np.concatenate([np.arange(0, dr, 2), np.arange(1, dr, 2)])
+
+    def lin(key):
+        return _np(sd[key]).T.copy()
+
+    out = {f"{name}_wte_table": _np(sd[f"{prefix}embed_tokens.weight"]),
+           f"{name}_ln_f_scale": _np(sd[f"{prefix}norm.weight"])}
+    if not c.tie_word_embeddings:
+        out[f"{name}_lm_head_weight"] = lin("lm_head.weight")
+    for i in range(c.num_hidden_layers):
+        hf, us = f"{prefix}layers.{i}", f"{name}_h{i}"
+        at = f"{hf}.self_attn"
+        q_b = lin(f"{at}.q_b_proj.weight").reshape(-1, H, dn + dr)
+        q_b = np.concatenate([q_b[..., :dn], q_b[..., dn:][..., halves]], -1)
+        kv_a = lin(f"{at}.kv_a_proj_with_mqa.weight")
+        kv_a = np.concatenate([kv_a[:, :dc], kv_a[:, dc:][:, halves]], -1)
+        out.update({
+            f"{us}_ln1_scale": _np(sd[f"{hf}.input_layernorm.weight"]),
+            f"{us}_ln2_scale": _np(
+                sd[f"{hf}.post_attention_layernorm.weight"]),
+            f"{us}_attn_q_a_weight": lin(f"{at}.q_a_proj.weight"),
+            f"{us}_attn_q_a_norm_scale": _np(
+                sd[f"{at}.q_a_layernorm.weight"]),
+            f"{us}_attn_q_b_weight": q_b.reshape(q_b.shape[0], -1),
+            f"{us}_attn_kv_a_weight": kv_a,
+            f"{us}_attn_kv_a_norm_scale": _np(
+                sd[f"{at}.kv_a_layernorm.weight"]),
+            f"{us}_attn_kv_b_weight": lin(f"{at}.kv_b_proj.weight"),
+            f"{us}_attn_proj_weight": lin(f"{at}.o_proj.weight")})
+        mlp = f"{hf}.mlp"
+        if i < c.first_k_dense_replace:
+            for nm in ("gate", "up", "down"):
+                out[f"{us}_ffn_{nm}_weight"] = lin(f"{mlp}.{nm}_proj.weight")
+            continue
+        out[f"{us}_moe_router_weight"] = lin(f"{mlp}.gate.weight").astype(
+            np.float32)
+        out[f"{us}_moe_router_bias"] = _np(
+            sd[f"{mlp}.gate.e_score_correction_bias"]).astype(np.float32)
+        for nm in ("gate", "up", "down"):
+            out[f"{us}_moe_experts_{nm}"] = np.stack(
+                [lin(f"{mlp}.experts.{e}.{nm}_proj.weight")
+                 for e in range(c.n_routed_experts)])
+            if c.n_shared_experts:
+                out[f"{us}_moe_shared_{nm}_weight"] = lin(
+                    f"{mlp}.shared_experts.{nm}_proj.weight")
     return out
 
 

@@ -350,3 +350,186 @@ def ragged_paged_reference(q, pool_k, pool_v, lengths, q_lens,
         ks = k_scale[block_tables].reshape(B, T * bs, *k_scale.shape[2:])
         vs = v_scale[block_tables].reshape(B, T * bs, *v_scale.shape[2:])
     return ragged_masked_reference(q, k, v, lengths, q_lens, ks, vs)
+
+
+# --------------------- latent (MLA) mixed wave --------------------- #
+#
+# Multi-head latent attention in its absorbed form is multi-query
+# attention over ONE cached row a token: every head scores the same
+# ``[c_kv | k_r]`` row, and the value is the row's own first
+# ``value_width`` columns.  So a page is fetched once for all heads and
+# once for key and value, the (query, head) pairs of a q-tile are the
+# rows of one matmul, and the output stays in latent space.
+#
+# The pool stays in HBM (``memory_space=pl.ANY``) and the kernel copies
+# pages itself: the grid is (slot, q-tile) alone, and inside a step a
+# loop runs over as many groups of ``_MLA_GROUP`` pages as the tile can
+# SEE (scalar-prefetched lengths, q-lengths and block tables decide),
+# each group's copies started while the last group is scored (two VMEM
+# buffers).  A 16-position page is an 18 KB copy: handed to the grid as
+# a block it cost a step's latency each (1.42 ms a layer for 32 decode
+# slots at 1,500 positions); sixteen in flight at once cost 0.27 ms, a
+# 256-row chunk beside them 4.5 against 11.3 (my chip run, PR 28:
+# PERF.md section 6).  Dead slots and dead tiles copy and score nothing.
+
+_MLA_GROUP = 16
+# (query, head) rows one q-tile may hold.  Sized by the sandbox's AOT
+# compile for v5e (PR 28): at 20 heads x 640 a tile of 1280 rows (64
+# queries) with its f32 accumulator [1280, 512], score block, page
+# buffers and double-buffered q and o tiles compiles inside the 16 MiB
+# of scoped VMEM; 2560 does not.
+_MLA_TILE_ROWS = 1280
+
+
+def _mla_q_tile(Q, H):
+    if H * Q <= _MLA_TILE_ROWS:
+        return Q
+    return _fit_block(max(_MLA_TILE_ROWS // H, 1), Q)
+
+
+def _mla_kernel(lens_ref, qlens_ref, bt_ref, q_ref, pool_ref, o_ref,
+                kv_buf, sem, m_ref, l_ref, acc_ref, *, scale, bs, group,
+                tq, heads, dv, layer):
+    b = pl.program_id(0)
+    t = pl.program_id(1)
+    span = group * bs
+    end = _visible_end(lens_ref, qlens_ref, b, t, tq)
+    live = _live_tile(qlens_ref, b, t, tq) & (end > 0)
+    n_groups = jnp.where(live, (end + span - 1) // span, 0)
+    last = jnp.maximum(end - 1, 0) // bs        # the last page in sight
+    m_ref[:] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[:] = jnp.zeros_like(l_ref)
+    acc_ref[:] = jnp.zeros_like(acc_ref)
+
+    def copies(gi, buf):
+        """Group ``gi``'s page copies into buffer ``buf``; pages past
+        the last in sight copy that one again (their positions are
+        masked)."""
+        return [pltpu.make_async_copy(
+            pool_ref.at[layer, bt_ref[b, jnp.minimum(gi * group + g, last)]],
+            kv_buf.at[buf, pl.ds(g * bs, bs)], sem.at[buf])
+            for g in range(group)]
+
+    @pl.when(n_groups > 0)
+    def _first():
+        for c in copies(0, 0):
+            c.start()
+
+    def score(gi, carry):
+        buf = gi % 2
+
+        @pl.when(gi + 1 < n_groups)
+        def _next():
+            for c in copies(gi + 1, 1 - buf):
+                c.start()
+
+        for c in copies(gi, buf):
+            c.wait()
+        q = q_ref[0]                                      # [R, W]
+        kv = kv_buf[buf]                                  # [span, W]
+        R = q.shape[0]
+        s = jax.lax.dot_general(
+            q, kv, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale   # [R, span]
+        kv_pos = gi * span + jax.lax.broadcasted_iota(
+            jnp.int32, (R, span), 1)
+        # row r of the tile is query t*tq + r // heads; dead rows clip to
+        # the last live position (as ``_query_positions``)
+        qi = t * tq + jax.lax.broadcasted_iota(
+            jnp.int32, (R, span), 0) // heads
+        filled = lens_ref[b]
+        posq = jnp.minimum(filled - qlens_ref[b] + qi, filled - 1)
+        s = jnp.where(kv_pos <= posq, s, NEG_INF)
+        m_prev = m_ref[:, 0:1]
+        l_prev = l_ref[:, 0:1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        safe_m = jnp.where(m_new <= NEG_INF / 2, 0.0, m_new)
+        p = jnp.where(s <= NEG_INF / 2, 0.0, jnp.exp(s - safe_m))
+        alpha = jnp.exp(jnp.clip(m_prev - m_new, max=0.0))
+        alpha = jnp.where(m_prev <= NEG_INF / 2, 0.0, alpha)
+        l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        pv = jax.lax.dot_general(
+            p.astype(kv.dtype), kv[:, :dv], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)           # [R, dv]
+        acc_ref[:] = acc_ref[:] * alpha + pv
+        m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
+        l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
+        return carry
+
+    jax.lax.fori_loop(0, n_groups, score, 0)
+    l = l_ref[:, 0:1]
+    o_ref[0] = (acc_ref[:] / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
+
+
+def ragged_paged_mla(q, pool, lengths, q_lens, block_tables, *,
+                     value_width, scale, layer=0, interpret=None):
+    """The mixed wave over the paged LATENT pool.
+
+    q: [B, Q, H, W] (``[q_lat | q_rope | 0]``, already absorbed); pool:
+    [L, N_blocks, bs, W], every layer's rows ``[c_kv | k_r | 0]``, of
+    which the kernel takes layer ``layer`` (static) where it lies: a
+    ``pool[layer]`` outside the kernel is a copy of that layer's pool a
+    wave.  ``W`` is a multiple of the 128 lanes on the TPU (a page is
+    copied by hand: ``LatentSpec.row_width`` pads 576 to 640); the
+    q-block's own rows are already written; lengths / q_lens /
+    block_tables as in :func:`ragged_paged_attention`.  Scores run over
+    all ``W`` columns times ``scale``; the value is a row's first
+    ``value_width`` columns.
+    Returns o [B, Q, H, value_width] in q's dtype (f32 accumulators); a
+    slot with lengths 0 returns zeros."""
+    B, Q, H, W = q.shape
+    bs = pool.shape[2]
+    group = min(_MLA_GROUP, block_tables.shape[1])
+    tq = _mla_q_tile(Q, H)
+    if interpret is None:
+        interpret = _use_interpret()
+    if W % _LANES and not interpret:
+        raise ValueError(
+            f"ragged_paged_mla copies pages by hand: the row width {W} "
+            f"must be a multiple of {_LANES}")
+    rows = tq * H
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(B, Q // tq),
+        in_specs=[pl.BlockSpec((1, rows, W), lambda b, t, *_: (b, t, 0)),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((1, rows, value_width),
+                               lambda b, t, *_: (b, t, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((2, group * bs, W), pool.dtype),    # page buffers
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.VMEM((rows, _LANES), jnp.float32),       # running max
+            pltpu.VMEM((rows, _LANES), jnp.float32),       # running denom
+            pltpu.VMEM((rows, value_width), jnp.float32),  # output acc
+        ],
+    )
+    o = pl.pallas_call(
+        functools.partial(_mla_kernel, scale=scale, bs=bs, group=group,
+                          tq=tq, heads=H, dv=value_width, layer=layer),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, Q * H, value_width), q.dtype),
+        name="ragged_paged_mla",
+        interpret=interpret,
+    )(lengths.astype(jnp.int32), q_lens.astype(jnp.int32),
+      block_tables.astype(jnp.int32), q.reshape(B, Q * H, W), pool)
+    return o.reshape(B, Q, H, value_width)
+
+
+def ragged_paged_mla_reference(q, pool, lengths, q_lens, block_tables, *,
+                               value_width, scale, layer=0):
+    """Gather-then-mask oracle (f32) for :func:`ragged_paged_mla`, with
+    ``ragged_masked_reference``'s conventions (dead rows clip to the
+    last live position; a slot with lengths 0 returns zeros)."""
+    B, Q = q.shape[:2]
+    bs = pool.shape[2]
+    T = block_tables.shape[1]
+    kv = pool[layer][block_tables].reshape(B, T * bs, -1).astype(jnp.float32)
+    posq = jnp.clip(
+        (lengths - q_lens)[:, None] + jnp.arange(Q)[None, :], 0,
+        jnp.maximum(lengths - 1, 0)[:, None])              # [B, Q]
+    s = jnp.einsum("bqhc,bsc->bqhs", q.astype(jnp.float32), kv) * scale
+    live = jnp.arange(T * bs)[None, None, None, :] \
+        <= posq[:, :, None, None]
+    p = jax.nn.softmax(jnp.where(live, s, NEG_INF), axis=-1)
+    out = jnp.einsum("bqhs,bsc->bqhc", p, kv[..., :value_width])
+    return out * (lengths > 0)[:, None, None, None]

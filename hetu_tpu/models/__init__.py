@@ -38,4 +38,5 @@ from .moe_decode import (
     MoEDecodeConfig, MoESpec, moe_spec_of, moe_capacity, moe_ffn,
     moe_ffn_ep_reference, ep_shard_params, init_moe_params,
     convert_dense_to_moe, resolve_moe_capacity, resolve_moe_quant,
+    LatentMoEConfig, RoutedSpec, routed_ffn, init_latent_moe_params,
 )

@@ -33,6 +33,7 @@ from __future__ import annotations
 
 import functools
 import os
+from typing import NamedTuple, Optional
 
 import numpy as np
 import jax
@@ -238,10 +239,148 @@ def _gelu_tanh(x):
 # every emitted token, so acceptance semantics are untouched.
 
 
+# ------------------------- block spec ------------------------- #
+#
+# What kind of decoder block the mixed wave runs is DATA: a hashable,
+# jit-static ``BlockSpec`` that rides the cfg_tuple's sixth place (where
+# a ``MoESpec`` rides for the capacity-routed GPT).  A 5-tuple, and a
+# 6-tuple whose sixth element is a ``MoESpec``, mean GPT-2's block
+# (``GPT2_BLOCK``) and lower to exactly the program they lowered to
+# before there was a spec.  Only ``_mixed_step`` over the paged pool
+# reads the spec; the six other cores are GPT-2's alone (the engine
+# refuses any other spec on their paths; folding them is ROADMAP C3/C4).
+
+
+class LatentSpec(NamedTuple):
+    """Multi-head latent attention's five sizes (the source's own
+    ``config.json`` keys)."""
+
+    q_lora_rank: int
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+
+    @property
+    def row_width(self):
+        """One cached row a token a layer: ``[c_kv | k_r]``, padded with
+        zeros to the next multiple of the 128 lanes (576 -> 640).  The
+        device pads a row's last dimension to that anyway, so the pad
+        costs no memory; stated in the shape it keeps the pool's
+        default layout row-major (an unaligned last dimension makes the
+        compiler lay the pool out block-index-minor, and every wave
+        then copies the whole pool to and from the layout the kernel
+        reads: PERF.md section 6, PR 28) and lets the kernel copy a
+        page by hand."""
+        return -(-(self.kv_lora_rank + self.qk_rope_head_dim) // 128) * 128
+
+
+class BlockSpec(NamedTuple):
+    """norm: "layernorm" (scale and bias) | "rmsnorm"; positions:
+    "learned" (a table added to the embedding) | "rope" (rotate-half
+    over ``latent.qk_rope_head_dim`` with ``rope_theta``); attention:
+    "mha" | "latent"; ffn: the kind of every layer from
+    ``leading_dense`` on, "gelu" | "swiglu" | "routed" (a
+    ``moe_decode.RoutedSpec`` in ``routed``; the leading layers are
+    dense SwiGLU); head: "tied" (the embedding table) | "untied"
+    (``{name}_lm_head_weight`` [hidden, vocab])."""
+
+    norm: str = "layernorm"
+    norm_eps: float = 1e-5
+    positions: str = "learned"
+    rope_theta: float = 10000.0
+    attention: str = "mha"
+    latent: Optional[LatentSpec] = None
+    ffn: str = "gelu"
+    leading_dense: int = 0
+    routed: Optional[tuple] = None
+    head: str = "tied"
+
+    def ffn_kind(self, i):
+        """Layer ``i``'s FFN: the leading layers of a routed model are
+        dense SwiGLU."""
+        if self.ffn == "routed" and i < self.leading_dense:
+            return "swiglu"
+        return self.ffn
+
+    def routed_layers(self, L):
+        return sum(1 for i in range(L) if self.ffn_kind(i) == "routed")
+
+
+GPT2_BLOCK = BlockSpec()
+
+
+def _block_of(cfg_tuple):
+    """The cfg_tuple's ``BlockSpec`` (``GPT2_BLOCK`` when it carries
+    none: every 5-tuple and every ``MoESpec`` 6-tuple)."""
+    extra = cfg_tuple[5] if len(cfg_tuple) > 5 else None
+    return extra if isinstance(extra, BlockSpec) else GPT2_BLOCK
+
+
+def block_spec_of(config):
+    """The ``BlockSpec`` a configuration object carries
+    (``config.block_spec()``), ``GPT2_BLOCK`` for one that carries
+    none."""
+    make = getattr(config, "block_spec", None)
+    return make() if callable(make) else GPT2_BLOCK
+
+
+def check_block_spec(blk):
+    """Raise for a spec the mixed wave cannot run: besides GPT-2's
+    block it runs latent attention with RMSNorm and RoPE, over any of
+    the three FFN kinds and either head."""
+    if blk == GPT2_BLOCK:
+        return
+    if (blk.attention, blk.norm, blk.positions) != (
+            "latent", "rmsnorm", "rope") or blk.latent is None \
+            or (blk.ffn == "routed") != (blk.routed is not None) \
+            or blk.ffn not in ("gelu", "swiglu", "routed") \
+            or blk.head not in ("tied", "untied"):
+        raise ValueError(
+            f"the mixed wave runs GPT-2's block, or latent attention "
+            f"with rmsnorm and rope; it cannot run {blk}")
+
+
+def _rms(x, scale, eps):
+    """RMSNorm, statistics in f32 (as ``_ln``)."""
+    x32 = x.astype(jnp.float32)
+    y = x32 * jax.lax.rsqrt((x32 * x32).mean(-1, keepdims=True) + eps)
+    return (y * scale.astype(jnp.float32)).astype(x.dtype)
+
+
+def _norm(blk, params, prefix, x):
+    """The block's norm over ``x`` with the leaves ``{prefix}_scale``
+    (and ``_bias`` for LayerNorm)."""
+    if blk.norm == "rmsnorm":
+        return _rms(x, params[f"{prefix}_scale"], blk.norm_eps)
+    return _ln(x, params[f"{prefix}_scale"], params[f"{prefix}_bias"])
+
+
+def _rope(x, posns, theta):
+    """Rotate-half RoPE over the whole last axis of ``x`` [B, Q, ..., d]
+    at positions ``posns`` [B, Q]: pairs (j, j + d/2), frequency
+    ``theta ** (-2j/d)``, computed in f32."""
+    d = x.shape[-1]
+    inv = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    ang = posns.astype(jnp.float32)[..., None] * inv        # [B, Q, d/2]
+    ang = ang.reshape(ang.shape[:2] + (1,) * (x.ndim - 3) + (d // 2,))
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    x32 = x.astype(jnp.float32)
+    x1, x2 = x32[..., :d // 2], x32[..., d // 2:]
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                           axis=-1).astype(x.dtype)
+
+
+def swiglu(x, w_gate, w_up, w_down):
+    """``(silu(x W_gate) * x W_up) W_down``."""
+    return (jax.nn.silu(x @ w_gate) * (x @ w_up)) @ w_down
+
+
 def _moe_of(cfg_tuple):
     """The cfg_tuple's optional sixth element: a ``MoESpec`` routing
     descriptor, or None for a dense GPT (every pre-MoE tuple)."""
-    return cfg_tuple[5] if len(cfg_tuple) > 5 else None
+    extra = cfg_tuple[5] if len(cfg_tuple) > 5 else None
+    return None if isinstance(extra, BlockSpec) else extra
 
 
 def _moe_active(cfg_tuple):
@@ -482,6 +621,15 @@ def _sample_slot(logits, temperature, top_k, key):
     kth = desc[jnp.clip(top_k - 1, 0, logits.shape[-1] - 1)]
     masked = jnp.where((top_k > 0) & (scaled < kth), NEG_INF, scaled)
     sampled = jax.random.categorical(key, masked).astype(jnp.int32)
+    return jnp.where(temperature <= 0.0, greedy, sampled)
+
+
+def _sample_slot_unmasked(logits, temperature, key):
+    """``_sample_slot`` for a slot that asks for no top_k mask (greedy,
+    or top_k 0): the same token from the same key, without the sort."""
+    greedy = jnp.argmax(logits).astype(jnp.int32)
+    scaled = logits / jnp.maximum(temperature, 1e-6)
+    sampled = jax.random.categorical(key, scaled).astype(jnp.int32)
     return jnp.where(temperature <= 0.0, greedy, sampled)
 
 
@@ -969,9 +1117,19 @@ def _spec_sample(logits, temperature, top_k, rng_keys, count):
     def row(keys, w):
         splits = jax.vmap(jax.random.split)(keys)          # [B,2,2]
         keys = jnp.where((w < count)[:, None], splits[:, 0], keys)
-        tok = jax.vmap(_sample_slot)(
-            jax.lax.dynamic_index_in_dim(logits, w, 1, keepdims=False),
-            temperature, top_k, splits[:, 1])
+        row_logits = jax.lax.dynamic_index_in_dim(logits, w, 1,
+                                                  keepdims=False)
+        # the kth-largest threshold is a sort of the vocabulary a slot
+        # (7.3 of a 17 ms decode wave at 32 x 154,880: PERF.md section
+        # 6, PR 28); it is taken only when a live row of this step
+        # samples with a top_k.  One branch for the whole step, outside
+        # the vmap: a cond a slot would become a select that runs both
+        tok = jax.lax.cond(
+            jnp.any((top_k > 0) & (temperature > 0.0) & (w < count)),
+            lambda: jax.vmap(_sample_slot)(row_logits, temperature, top_k,
+                                           splits[:, 1]),
+            lambda: jax.vmap(_sample_slot_unmasked)(row_logits, temperature,
+                                                    splits[:, 1]))
         return keys, (tok, keys)
 
     # a scan, not a Python loop: every row sorts the vocabulary once a
@@ -1169,7 +1327,7 @@ def _serve_prefill_batch_paged(params, cfg_tuple, cache_k, cache_v,
 # across contiguous/paged/int8/spec/chunked configs.
 
 
-def _window_logits(params, name, h, first_row, window):
+def _window_logits(params, name, h, first_row, window, blk=GPT2_BLOCK):
     """The head over each slot's sampling window ALONE: rows
     ``first_row[b] + w`` (``w < window``, clipped to the q-block) of the
     last block's output ``h`` [B, Q, hd] are gathered FIRST, then the
@@ -1181,11 +1339,98 @@ def _window_logits(params, name, h, first_row, window):
         rows = jnp.clip(first_row[:, None] + jnp.arange(window)[None, :],
                         0, h.shape[1] - 1)                 # [B, W]
         hw = jnp.take_along_axis(h, rows[:, :, None], axis=1)
-        hw = _ln(hw, params[f"{name}_ln_f_scale"],
-                 params[f"{name}_ln_f_bias"])
+        hw = _norm(blk, params, f"{name}_ln_f", hw)
+        if blk.head == "untied":
+            return (hw @ params[f"{name}_lm_head_weight"]
+                    ).astype(jnp.float32)
         return (hw @ params[f"{name}_wte_table"].T
                 ).astype(jnp.float32) \
             + params.get(f"{name}_head_bias", 0.0)
+
+
+def _latent_attention(params, us, blk, H, h, pool, i, wblk, woff, posns,
+                      live, lens, q_len, block_tables, attn):
+    """One layer's multi-head latent attention over the paged LATENT
+    pool ``[L, N_blocks, block, LatentSpec.row_width]``, every row of
+    the wave in the ABSORBED form: ``q_nope`` is carried into latent space
+    through ``W_uk`` (``W_kvb``'s key half, split here in the step, not
+    at load), so a cached row ``[c_kv | k_r]`` is key and, in its first
+    ``kv_lora_rank`` columns, value, for all ``H`` heads at once; the
+    output leaves latent space through ``W_uv``.  The wave's own rows
+    are written first and read back from the pool (one path for chunk
+    and decode rows).  Returns (h + attention, pool)."""
+    la = blk.latent
+    B, Q, _ = h.shape
+    dn, dr, dv, dc = (la.qk_nope_head_dim, la.qk_rope_head_dim,
+                      la.v_head_dim, la.kv_lora_rank)
+    with jax.named_scope("mla_qkv"):
+        x = _norm(blk, params, f"{us}_ln1", h)
+        cq = _rms(x @ params[f"{us}_attn_q_a_weight"],
+                  params[f"{us}_attn_q_a_norm_scale"], blk.norm_eps)
+        q = (cq @ params[f"{us}_attn_q_b_weight"]).reshape(
+            B, Q, H, dn + dr)
+        kva = x @ params[f"{us}_attn_kv_a_weight"]          # [B, Q, dc+dr]
+        ckv = _rms(kva[..., :dc], params[f"{us}_attn_kv_a_norm_scale"],
+                   blk.norm_eps)
+        k_r = _rope(kva[..., dc:], posns, blk.rope_theta)
+        q_rope = _rope(q[..., dn:], posns, blk.rope_theta)
+        # the pool's rows are padded to the lane tile with zeros, and
+        # so is the query: the pad adds nothing to a score
+        pad = pool.shape[-1] - dc - dr
+        row = jnp.concatenate(
+            [ckv, k_r, jnp.zeros((B, Q, pad), ckv.dtype)], axis=-1)
+    w_kvb = params[f"{us}_attn_kv_b_weight"].reshape(dc, H, dn + dv)
+    with jax.named_scope("mla_absorb"):
+        q_lat = jnp.einsum("bqhn,chn->bqhc", q[..., :dn],
+                           w_kvb[:, :, :dn])
+        qf = jnp.concatenate(
+            [q_lat, q_rope, jnp.zeros((B, Q, H, pad), q_lat.dtype)],
+            axis=-1)                                        # [B, Q, H, W]
+    with jax.named_scope("kv_write"):
+        pool = pool.at[i, wblk, woff].set(row.astype(pool.dtype))
+    scale = (dn + dr) ** -0.5
+    with jax.named_scope("attention"):
+        if attn == "ragged":
+            from ..kernels.ragged_attention import ragged_paged_mla
+            o_lat = ragged_paged_mla(qf, pool, lens, q_len,
+                                     block_tables, value_width=dc,
+                                     scale=scale, layer=i)
+        else:
+            T, bs = block_tables.shape[1], pool.shape[2]
+            kg = pool[i][block_tables].reshape(B, T * bs, dc + dr + pad)
+            s = jnp.einsum("bqhc,bsc->bqhs", qf, kg) * scale
+            p = jax.nn.softmax(
+                jnp.where(live[:, :, None, :], s, NEG_INF), axis=-1)
+            o_lat = jnp.einsum("bqhs,bsc->bqhc", p, kg[..., :dc])
+    with jax.named_scope("mla_absorb"):
+        o = jnp.einsum("bqhc,chv->bqhv", o_lat.astype(h.dtype),
+                       w_kvb[:, :, dn:]).reshape(B, Q, H * dv)
+    with jax.named_scope("attn_out"):
+        h = h + o @ params[f"{us}_attn_proj_weight"]
+    return h, pool
+
+
+def _ffn_of_kind(params, us, blk, h, i, valid, stats):
+    """The FFN sublayer by ``blk.ffn_kind(i)``: GPT-2's GELU FFN or a
+    dense SwiGLU under the scope ``mlp``, or the dropless routed FFN
+    with its shared expert (``moe_decode.routed_ffn``, scopes
+    ``moe_route``, ``moe_experts``, ``moe_shared``)."""
+    kind = blk.ffn_kind(i)
+    if kind == "gelu":
+        with jax.named_scope("mlp"):
+            return _ffn_block(params, us, h, i)
+    x = _norm(blk, params, f"{us}_ln2", h)
+    if kind == "swiglu":
+        with jax.named_scope("mlp"):
+            return h + swiglu(x, params[f"{us}_ffn_gate_weight"],
+                              params[f"{us}_ffn_up_weight"],
+                              params[f"{us}_ffn_down_weight"])
+    from .moe_decode import routed_ffn
+    shp = x.shape
+    y = routed_ffn(params, us, x.reshape(-1, shp[-1]), blk.routed,
+                   valid=jnp.broadcast_to(valid, shp[:-1]).reshape(-1),
+                   stats=stats)
+    return h + y.reshape(shp)
 
 
 def _mixed_step(params, cfg_tuple, cache_k, cache_v, pos, tokens,
@@ -1223,9 +1468,17 @@ def _mixed_step(params, cfg_tuple, cache_k, cache_v, pos, tokens,
     ``jax.named_scope`` names, the same for every layer: ``embed``,
     ``attn_qkv``, ``kv_write``, ``attention``, ``attn_out``, ``mlp``,
     ``lm_head`` (the window's gather, final LN and head) and ``sample``,
-    ``_spec_sample``'s own."""
+    ``_spec_sample``'s own.
+
+    The block itself is the cfg_tuple's ``BlockSpec``: GPT-2's when it
+    carries none (everything above); with latent attention the layer is
+    ``_latent_attention`` (scopes ``mla_qkv``, ``mla_absorb``,
+    ``kv_write``, ``attention``, ``attn_out``) over ONE pool, ``cache_v``
+    None, then ``_ffn_of_kind`` (``mlp``, or ``moe_route``,
+    ``moe_experts``, ``moe_shared``), and the head follows the spec."""
     name, L, H, Dh, S_max = cfg_tuple[:5]
     moe = _moe_of(cfg_tuple)
+    blk = _block_of(cfg_tuple)
     B, Q = tokens.shape
     hdim = H * Dh
     paged = block_tables is not None
@@ -1234,9 +1487,10 @@ def _mixed_step(params, cfg_tuple, cache_k, cache_v, pos, tokens,
     valid = jnp.arange(Q)[None, :] < q_len[:, None]        # [B, Q]
     lens = (pos + q_len).astype(jnp.int32)   # filled after the writes
     with jax.named_scope("embed"):
-        wpe = params[f"{name}_wpe"]
-        h = params[f"{name}_wte_table"][tokens] \
-            + wpe[jnp.clip(posns, 0, wpe.shape[0] - 1)]    # [B, Q, hd]
+        h = params[f"{name}_wte_table"][tokens]            # [B, Q, hd]
+        if blk.positions == "learned":
+            wpe = params[f"{name}_wpe"]
+            h = h + wpe[jnp.clip(posns, 0, wpe.shape[0] - 1)]
     if attn == "ragged":
         from ..kernels.ragged_attention import (
             ragged_attention, ragged_paged_attention,
@@ -1263,6 +1517,15 @@ def _mixed_step(params, cfg_tuple, cache_k, cache_v, pos, tokens,
     quant = _kv_q(cache_k)
     for i in range(L):
         us = f"{name}_h{i}"
+        if blk.attention == "latent":
+            # latent attention over ONE pool (``cache_v`` is None), then
+            # the FFN by the layer's kind; ``check_block_spec`` keeps out
+            # the combinations this wave does not run
+            h, cache_k = _latent_attention(
+                params, us, blk, H, h, cache_k, i, wblk, woff, posns,
+                live, lens, q_len, block_tables, attn)
+            h = _ffn_of_kind(params, us, blk, h, i, valid, moe_stats)
+            continue
         with jax.named_scope("attn_qkv"):
             x = _ln(h, params[f"{us}_ln1_scale"],
                     params[f"{us}_ln1_bias"])
@@ -1340,7 +1603,7 @@ def _mixed_step(params, cfg_tuple, cache_k, cache_v, pos, tokens,
         with jax.named_scope("mlp"):
             h = _ffn_block(params, us, h, i, moe=moe, valid=valid,
                            stats=moe_stats)
-    logits = _window_logits(params, name, h, first_row, window)
+    logits = _window_logits(params, name, h, first_row, window, blk)
     return logits, cache_k, cache_v
 
 
@@ -1380,7 +1643,8 @@ def _serve_mixed_paged(params, cfg_tuple, cache_k, cache_v, tables,
     window is empty).  ``has_fresh`` (static) marks waves carrying
     prompt-chunk slots — see ``_mixed_step``."""
     moe_on = _moe_active(cfg_tuple)
-    sd = {} if moe_on else None
+    routed = _block_of(cfg_tuple).routed
+    sd = {} if moe_on or routed is not None else None
     logits, cache_k, cache_v = _mixed_step(
         params, cfg_tuple, cache_k, cache_v, pos, tokens, q_len,
         first_row, self_fresh, window=window, attn=attn,
@@ -1392,6 +1656,12 @@ def _serve_mixed_paged(params, cfg_tuple, cache_k, cache_v, tables,
         out = out + (_moe_stats_out(
             sd, _moe_of(cfg_tuple),
             jnp.sum(jnp.clip(q_len, 0, tokens.shape[1]))),)
+    if routed is not None:
+        # (load [E] summed over the routed layers, experts touched
+        # summed over them): dropless, so there is no drop element
+        z = jnp.zeros((routed.num_experts,), jnp.int32)
+        out = out + ((jnp.asarray(sd.get("load", z), jnp.int32),
+                      jnp.asarray(sd.get("touched", 0), jnp.int32)),)
     return out
 
 

@@ -230,6 +230,282 @@ def _gelu_tanh(x):
         0.7978845608028654 * (x + 0.044715 * x ** 3)))
 
 
+# ------------------- dropless routed FFN ------------------- #
+#
+# The other router there is (one for both is ROADMAP C8): no capacity,
+# no dropped token, no [T, E, cap] tensor.  Cost follows the rows routed
+# and the experts touched: the T x k assignments are sorted by expert and
+# run through grouped matmuls over the stacked expert leaves.
+
+
+class RoutedSpec(NamedTuple):
+    """The routed FFN of a ``gpt_decode.BlockSpec`` (``ffn="routed"``):
+    sigmoid scores, the ``top_k`` largest of ``score + bias`` chosen,
+    weights from the scores alone, normalised when ``norm_topk`` and
+    scaled by ``scale``; ``n_shared`` shared experts' width is
+    ``n_shared`` times an expert's."""
+
+    num_experts: int
+    top_k: int
+    scale: float = 1.0
+    norm_topk: bool = True
+    n_shared: int = 0
+
+
+def route(x, w_router, bias, spec):
+    """(chosen experts [T, k] int32, their weights [T, k] f32) for the
+    rows ``x`` [T, D]: ``s = sigmoid(float32(x) W_g)``; the ``k`` largest
+    of ``s + b`` are CHOSEN; the weights are ``s`` at the chosen,
+    normalised to sum 1 (``norm_topk``) and scaled."""
+    s = jax.nn.sigmoid(jnp.dot(
+        x.astype(jnp.float32), w_router.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST))
+    _, sel = jax.lax.top_k(s + bias.astype(jnp.float32), spec.top_k)
+    # s at the chosen, by comparison and not by gather (a gather of
+    # T x k scalars is 0.33 ms a layer at 8192 rows on a v5e)
+    hit = sel[:, :, None] == jnp.arange(s.shape[1])[None, None, :]
+    w = jnp.sum(jnp.where(hit, s[:, None, :], 0.0), axis=-1)
+    if spec.norm_topk:
+        w = w / (w.sum(-1, keepdims=True) + 1e-20)
+    return sel.astype(jnp.int32), w * spec.scale
+
+
+def grouped_matmul(lhs, rhs, group_sizes):
+    """``lhs`` [M, K] rows sorted by group times ``rhs`` [G, K, N], group
+    ``g`` owning the next ``group_sizes[g]`` rows; rows past the groups'
+    sum come out as anything.  ``jax.lax.ragged_dot``: the v5e compiler
+    takes it at [32768, 2048] x [64, 2048, 1536] and the chip runs it at
+    the cost of the rows and the experts touched (PERF.md section 6, PR
+    28, has the comparison with Pallas' megablox ``gmm``)."""
+    return jax.lax.ragged_dot(lhs, rhs, group_sizes)
+
+
+def routed_ffn(params, us, x, spec, valid=None, stats=None):
+    """Dropless routed FFN plus the shared expert over the flat rows
+    ``x`` [T, D] (the post-norm FFN input).  Rows with ``valid`` False
+    (padding, dead slots) are routed NOWHERE: they sort behind every
+    expert's group, no expert counts them and their output is 0 from
+    the routed part (batch company changes no live row's result: each
+    row's experts and weights depend on that row alone).  Leaves:
+    ``{us}_moe_router_weight`` [D, E], ``{us}_moe_router_bias`` [E] (the
+    selection bias), ``{us}_moe_experts_gate``/``_up`` [E, D, F],
+    ``{us}_moe_experts_down`` [E, F, D], ``{us}_moe_shared_gate_weight``
+    / ``_up_weight`` [D, n_shared F], ``_down_weight`` [n_shared F, D].
+
+    ``stats`` (dict, mutated at trace time) accumulates over the routed
+    layers ``load`` [E] int32 (assignments an expert, so that the sum
+    of ``load`` is valid rows x top_k x layers) and ``touched`` (experts
+    with load > 0, summed over layers)."""
+    E, k = spec.num_experts, spec.top_k
+    T, D = x.shape
+    vmask = (jnp.ones((T,), bool) if valid is None
+             else valid.reshape(T).astype(bool))
+    with jax.named_scope("moe_route"):
+        sel, w = route(x, params[f"{us}_moe_router_weight"],
+                       params[f"{us}_moe_router_bias"], spec)
+        # an invalid row's assignments go to group E, past the last
+        expert = jnp.where(vmask[:, None], sel, E).reshape(-1)  # [T k]
+        order = jnp.argsort(expert, stable=True)
+        load = jnp.sum(expert[:, None] == jnp.arange(E)[None, :], axis=0,
+                       dtype=jnp.int32)
+        xs = x[order // k]                                  # [T k, D]
+    with jax.named_scope("moe_experts"):
+        a = jax.nn.silu(grouped_matmul(
+            xs, params[f"{us}_moe_experts_gate"], load)) \
+            * grouped_matmul(xs, params[f"{us}_moe_experts_up"], load)
+        ys = grouped_matmul(a, params[f"{us}_moe_experts_down"], load)
+    with jax.named_scope("moe_route"):
+        # the inverse permutation by a second sort (a scatter of T k
+        # indices costs seven times as much on the chip); a row past
+        # the groups may hold anything, so it is zeroed, not weighted 0
+        back = jnp.argsort(order)
+        live = jnp.arange(T * k) < jnp.sum(load)
+        y = jnp.where(live[:, None], ys, 0)[back].reshape(T, k, D)
+        wv = jnp.where(vmask[:, None], w, 0.0)
+        # k weighted rows a token, summed in float32 (one fused pass)
+        y = sum(y[:, j].astype(jnp.float32) * wv[:, j, None]
+                for j in range(k)).astype(x.dtype)
+    if spec.n_shared:
+        from .gpt_decode import swiglu
+        with jax.named_scope("moe_shared"):
+            y = y + swiglu(x, params[f"{us}_moe_shared_gate_weight"],
+                           params[f"{us}_moe_shared_up_weight"],
+                           params[f"{us}_moe_shared_down_weight"])
+    if stats is not None:
+        stats["load"] = stats.get("load", 0) + load
+        stats["touched"] = stats.get("touched", 0) + jnp.sum(load > 0)
+    return y
+
+
+class LatentMoEConfig:
+    """A decoder of latent-attention (MLA) blocks with a dropless routed
+    FFN, built from the source's own ``config.json`` keys (the
+    ``glm4_moe_lite``/DeepSeek-V3 family's names).  It yields the
+    jit-static ``BlockSpec`` the mixed wave reads (``block_spec()``);
+    the engine takes the rest from the attributes a ``GPTConfig`` has
+    too (``num_hidden_layers``, ``num_attention_heads``,
+    ``hidden_size``, ``vocab_size``, ``max_position_embeddings``).
+    Unsupported values raise: ``n_group``/``topk_group`` other than 1
+    (group-limited selection), ``rope_scaling`` set,
+    ``partial_rotary_factor`` other than 1, a ``topk_method`` other than
+    ``noaux_tc``, ``attention_bias``, an activation other than SiLU.
+    ``num_nextn_predict_layers`` is accepted and not served: the MTP
+    layer takes no part in the next-token logits."""
+
+    def __init__(self, *, vocab_size, hidden_size, num_hidden_layers,
+                 num_attention_heads, q_lora_rank, kv_lora_rank,
+                 qk_nope_head_dim, qk_rope_head_dim, v_head_dim,
+                 intermediate_size, moe_intermediate_size,
+                 n_routed_experts, num_experts_per_tok,
+                 n_shared_experts=0, routed_scaling_factor=1.0,
+                 norm_topk_prob=True, first_k_dense_replace=0,
+                 rope_theta=10000.0, rms_norm_eps=1e-6,
+                 tie_word_embeddings=False,
+                 max_position_embeddings=4096, n_group=1, topk_group=1,
+                 rope_scaling=None, partial_rotary_factor=1,
+                 topk_method="noaux_tc", attention_bias=False,
+                 hidden_act="silu", **ignored):
+        for key, value, want in (
+                ("n_group", n_group, 1), ("topk_group", topk_group, 1),
+                ("rope_scaling", rope_scaling, None),
+                ("partial_rotary_factor", partial_rotary_factor, 1),
+                ("topk_method", topk_method, "noaux_tc"),
+                ("attention_bias", attention_bias, False),
+                ("hidden_act", hidden_act, "silu")):
+            if value != want:
+                raise ValueError(
+                    f"LatentMoEConfig: {key}={value!r} is not supported "
+                    f"(only {want!r})")
+        if not 1 <= num_experts_per_tok <= n_routed_experts:
+            raise ValueError(
+                f"num_experts_per_tok={num_experts_per_tok} outside "
+                f"[1, n_routed_experts={n_routed_experts}]")
+        if not 0 <= first_k_dense_replace <= num_hidden_layers:
+            raise ValueError(
+                f"first_k_dense_replace={first_k_dense_replace} outside "
+                f"[0, num_hidden_layers={num_hidden_layers}]")
+        if qk_rope_head_dim % 2:
+            raise ValueError("qk_rope_head_dim must be even")
+        self.vocab_size = int(vocab_size)
+        self.hidden_size = int(hidden_size)
+        self.num_hidden_layers = int(num_hidden_layers)
+        self.num_attention_heads = int(num_attention_heads)
+        self.max_position_embeddings = int(max_position_embeddings)
+        self.q_lora_rank = int(q_lora_rank)
+        self.kv_lora_rank = int(kv_lora_rank)
+        self.qk_nope_head_dim = int(qk_nope_head_dim)
+        self.qk_rope_head_dim = int(qk_rope_head_dim)
+        self.v_head_dim = int(v_head_dim)
+        self.intermediate_size = int(intermediate_size)
+        self.moe_intermediate_size = int(moe_intermediate_size)
+        self.n_routed_experts = int(n_routed_experts)
+        self.num_experts_per_tok = int(num_experts_per_tok)
+        self.n_shared_experts = int(n_shared_experts)
+        self.routed_scaling_factor = float(routed_scaling_factor)
+        self.norm_topk_prob = bool(norm_topk_prob)
+        self.first_k_dense_replace = int(first_k_dense_replace)
+        self.rope_theta = float(rope_theta)
+        self.rms_norm_eps = float(rms_norm_eps)
+        self.tie_word_embeddings = bool(tie_word_embeddings)
+
+    @classmethod
+    def from_hf(cls, config):
+        """From a ``config.json`` dict (keys it does not know are
+        ignored; the ones it cannot run raise)."""
+        return cls(**config)
+
+    def routed_spec(self):
+        return RoutedSpec(
+            num_experts=self.n_routed_experts,
+            top_k=self.num_experts_per_tok,
+            scale=self.routed_scaling_factor,
+            norm_topk=self.norm_topk_prob, n_shared=self.n_shared_experts)
+
+    def block_spec(self):
+        from .gpt_decode import BlockSpec, LatentSpec
+        all_dense = self.first_k_dense_replace >= self.num_hidden_layers
+        return BlockSpec(
+            norm="rmsnorm", norm_eps=self.rms_norm_eps, positions="rope",
+            rope_theta=self.rope_theta, attention="latent",
+            latent=LatentSpec(self.q_lora_rank, self.kv_lora_rank,
+                              self.qk_nope_head_dim, self.qk_rope_head_dim,
+                              self.v_head_dim),
+            ffn="swiglu" if all_dense else "routed",
+            leading_dense=0 if all_dense else self.first_k_dense_replace,
+            routed=None if all_dense else self.routed_spec(),
+            head="tied" if self.tie_word_embeddings else "untied")
+
+    def param_shapes(self, name="glm"):
+        """{leaf: shape} of the serving parameter dict: the one list
+        ``init_latent_moe_params`` and ``hf.convert_glm4_moe_lite`` agree
+        on."""
+        d, H = self.hidden_size, self.num_attention_heads
+        dn, dr, dv = (self.qk_nope_head_dim, self.qk_rope_head_dim,
+                      self.v_head_dim)
+        dc, dq = self.kv_lora_rank, self.q_lora_rank
+        f, fe, E = (self.intermediate_size, self.moe_intermediate_size,
+                    self.n_routed_experts)
+        shapes = {f"{name}_wte_table": (self.vocab_size, d),
+                  f"{name}_ln_f_scale": (d,)}
+        if not self.tie_word_embeddings:
+            shapes[f"{name}_lm_head_weight"] = (d, self.vocab_size)
+        for i in range(self.num_hidden_layers):
+            us = f"{name}_h{i}"
+            shapes.update({
+                f"{us}_ln1_scale": (d,), f"{us}_ln2_scale": (d,),
+                f"{us}_attn_q_a_weight": (d, dq),
+                f"{us}_attn_q_a_norm_scale": (dq,),
+                f"{us}_attn_q_b_weight": (dq, H * (dn + dr)),
+                f"{us}_attn_kv_a_weight": (d, dc + dr),
+                f"{us}_attn_kv_a_norm_scale": (dc,),
+                f"{us}_attn_kv_b_weight": (dc, H * (dn + dv)),
+                f"{us}_attn_proj_weight": (H * dv, d)})
+            if i < self.first_k_dense_replace:
+                shapes.update({f"{us}_ffn_gate_weight": (d, f),
+                               f"{us}_ffn_up_weight": (d, f),
+                               f"{us}_ffn_down_weight": (f, d)})
+                continue
+            fs = fe * self.n_shared_experts
+            shapes.update({f"{us}_moe_router_weight": (d, E),
+                           f"{us}_moe_router_bias": (E,),
+                           f"{us}_moe_experts_gate": (E, d, fe),
+                           f"{us}_moe_experts_up": (E, d, fe),
+                           f"{us}_moe_experts_down": (E, fe, d)})
+            if fs:
+                shapes.update({f"{us}_moe_shared_gate_weight": (d, fs),
+                               f"{us}_moe_shared_up_weight": (d, fs),
+                               f"{us}_moe_shared_down_weight": (fs, d)})
+        return shapes
+
+
+def init_latent_moe_params(config, name="glm", seed=0, scale=0.02,
+                           bias_scale=0.1, dtype=jnp.float32):
+    """Seeded random serving params for a ``LatentMoEConfig``, made on
+    the device in one jitted call: weights normal(``scale``), norm
+    scales 1, the selection bias normal(``bias_scale``) so that choosing
+    by ``s + b`` and weighting by ``s`` differ.  The router's weight and
+    bias stay float32 whatever ``dtype`` is."""
+    shapes = config.param_shapes(name)
+
+    def make(key):
+        out = {}
+        for k, (n, shape) in zip(jax.random.split(key, len(shapes)),
+                                 sorted(shapes.items())):
+            if n.endswith("_scale"):
+                out[n] = jnp.ones(shape, dtype)
+            elif n.endswith("_moe_router_bias"):
+                out[n] = bias_scale * jax.random.normal(k, shape,
+                                                        jnp.float32)
+            elif n.endswith("_moe_router_weight"):
+                out[n] = scale * jax.random.normal(k, shape, jnp.float32)
+            else:
+                out[n] = (scale * jax.random.normal(k, shape, jnp.float32)
+                          ).astype(dtype)
+        return out
+
+    return jax.jit(make)(jax.random.PRNGKey(int(seed) % (2 ** 31 - 1)))
+
+
 # ------------------- expert-parallel placement ------------------- #
 
 

@@ -34,6 +34,7 @@ from .. import envvars, telemetry
 from ..telemetry import flight
 from ..telemetry import slo as slo_mod
 from ..models.gpt_decode import (
+    GPT2_BLOCK, block_spec_of, check_block_spec,
     _infer_name, _prep_param, _pow2, _resolve_fast, resolve_draft_layers,
     resolve_serve_ragged, resolve_spec_k, serve_decode_fn,
     serve_decode_paged_fn, serve_mixed_fn, serve_mixed_paged_fn,
@@ -115,6 +116,23 @@ class ServingEngine:
     across every layout (contiguous/paged, int8, chunked, prefix
     sharing, speculation) — the parity suite pins it.
 
+    Block spec: a config that carries one (``config.block_spec()``, a
+    ``models.moe_decode.LatentMoEConfig``: RMSNorm, RoPE, latent (MLA)
+    attention over ONE paged pool of ``[c_kv | k_r]`` rows, dense
+    SwiGLU / dropless routed + shared FFN, untied head) runs on the
+    mixed ragged wave over the paged pool and NOWHERE ELSE: of the
+    seven serving cores only ``_mixed_step`` reads the spec (folding
+    the others is ROADMAP C3/C4).  Such an engine raises a
+    ``ValueError`` that names the path when built with ``paged=False``
+    (the contiguous ``KVCacheManager``), ``ragged=False`` (the
+    phase-split scheduler's prefill-chunk, batch-prefill and decode
+    cores), ``spec`` > 0 (the draft, its contiguous cache and
+    ``_verify_step``) or ``kv_quant="int8"``; its pool refuses
+    ``export_blocks``/``import_blocks`` and the KV tiers (latent rows
+    have no wire format).  Its waves count ``serve.moe.*`` and
+    ``serve.attn.*`` (``ServingMetrics.record_routed``).  A GPT-2
+    config carries no spec and runs exactly the programs it ran.
+
     Composes with ``tp_shard_params``: pass the placed dict and the
     fused step runs tensor-parallel (``_prep_param`` preserves the
     NamedShardings; GSPMD propagates them through prefill and decode).
@@ -150,6 +168,12 @@ class ServingEngine:
         Dh = c.hidden_size // c.num_attention_heads
         want = int(max_seq_len or c.max_position_embeddings)
         cdtype = self.params[f"{self._name}_wte_table"].dtype
+        # the block the mixed wave runs (GPT-2's unless the config
+        # carries another): see the class docstring for what a
+        # non-GPT-2 spec refuses
+        self.block_spec = block_spec_of(c)
+        check_block_spec(self.block_spec)
+        other = self.block_spec != GPT2_BLOCK
         # kv_quant="int8" (or $HETU_KV_QUANT) stores the cache as int8
         # payload + per-(position, head) f32 scales — ~3.7x more tokens
         # per HBM byte, dequantized inside the decode kernels
@@ -158,13 +182,34 @@ class ServingEngine:
         block = resolve_kv_block(paged, kv_block)
         self.paged = block > 0
         self.fast_path = _resolve_fast(fast_path)
+        self.ragged = resolve_serve_ragged(ragged)
+        self.spec_k = resolve_spec_k(spec)
+        if other:
+            for bad, path in (
+                    (not self.paged, "the contiguous KVCacheManager "
+                     "(paged=False): _serve_mixed, _serve_prefill, "
+                     "_serve_decode_step"),
+                    (not self.ragged, "the phase-split scheduler "
+                     "(ragged=False): _serve_prefill_chunk, "
+                     "_serve_prefill_batch_paged, _serve_decode_paged"),
+                    (self.spec_k, "speculation (spec_k > 0): "
+                     "_spec_propose, _verify_step and the draft's "
+                     "contiguous cache"),
+                    (self.kv_quant, "an int8 KV cache (kv_quant)")):
+                if bad:
+                    raise ValueError(
+                        f"ServingEngine: a non-GPT-2 block spec runs "
+                        f"only on the mixed ragged wave over the paged "
+                        f"pool; it cannot run on {path}")
+        latent = self.block_spec.latent
         if self.paged:
             self.kv = PagedKVManager(
                 layers=c.num_hidden_layers, heads=c.num_attention_heads,
                 head_dim=Dh, slots=slots, max_seq_len=want,
                 pos_cap=c.max_position_embeddings, dtype=kv_dtype,
                 block=block, pool_blocks=pool_blocks,
-                prefix_share=prefix_share)
+                prefix_share=prefix_share,
+                row_shape=(latent.row_width,) if latent else None)
             chunk = (prefill_chunk if prefill_chunk is not None
                      else envvars.get_int("HETU_KV_CHUNK"))
             self.chunk = max(int(chunk or 0), 0)
@@ -195,6 +240,14 @@ class ServingEngine:
         # self.moe None and nothing here changes. ---- #
         from ..models.moe_decode import moe_spec_of
         self.moe = moe_spec_of(c)
+        # the dropless router of a routed block spec: its wave hands
+        # back (load, touched) beside the sample, fetched with it in
+        # serve.wave.sync (no sync of its own)
+        self.routed = self.block_spec.routed
+        if other:
+            self.cfg_tuple = self.cfg_tuple + (self.block_spec,)
+            self._routed_layers = self.block_spec.routed_layers(
+                c.num_hidden_layers)
         if self.moe is not None:
             self.cfg_tuple = self.cfg_tuple + (self.moe,)
             E = self.moe.num_experts
@@ -258,7 +311,6 @@ class ServingEngine:
         self._prompt_arr = [None] * B              # position to prefill
         self.steps = 0
         # ---- speculative decoding (spec=/$HETU_SPEC_K) ---- #
-        self.spec_k = resolve_spec_k(spec)
         self.spec_adapt = False
         if self.spec_k:
             dl = resolve_draft_layers(spec_draft_layers,
@@ -306,7 +358,6 @@ class ServingEngine:
         # ---- mixed-mode ragged dispatch (ragged=/$HETU_SERVE_RAGGED):
         # arrivals, chunk continuations, spec-verify, and decode pack
         # into ONE ragged wave per step (see class docstring) ---- #
-        self.ragged = resolve_serve_ragged(ragged)
         if self.ragged:
             attn = "ragged" if self.fast_path else "masked"
             # the widest sampling window a slot can have: a verify
@@ -424,6 +475,29 @@ class ServingEngine:
                 "imb": imb, "drop_rate": rate,
                 "load": [int(x) for x in load],
                 "drop": [int(x) for x in drop]}
+
+    def _routed_record(self, wave, routed_out):
+        """A routed wave's counters (``serve.moe.*``, ``serve.attn.*``)
+        and its ``record_step`` payload.  Load and experts touched come
+        out of the compiled step; rows, context tokens and score pairs
+        are the wave descriptor's own arithmetic: slot b's ``q_len``
+        rows at positions ``pos .. pos + q_len - 1`` see
+        ``pos + j + 1`` positions each, and the slot holds
+        ``pos + q_len`` positions after the wave's writes."""
+        load = np.asarray(routed_out[0], np.int64)
+        touched = int(routed_out[1])
+        ql = wave["q_len"].astype(np.int64)
+        pos = wave["pos"].astype(np.int64)
+        rows = int(ql.sum())
+        ctx = int(np.where(ql > 0, pos + ql, 0).sum())
+        pairs = int((ql * pos + ql * (ql + 1) // 2).sum())
+        self.metrics.record_routed(load, touched, ctx, pairs)
+        assignments = int(load.sum())
+        mean = assignments / len(load)
+        return {"tokens": rows, "routed": assignments, "dropped": 0,
+                "k": self.routed.top_k, "layers": self._routed_layers,
+                "imb": float(load.max()) / mean if mean > 0 else 0.0,
+                "drop_rate": 0.0}
 
     @property
     def expert_imbalance(self):
@@ -1146,15 +1220,19 @@ class ServingEngine:
                 entries[s] = (toks, int(self._pos[s]), 0, False)
             wave = assemble_mixed_wave(B, entries)
             tables = self.kv.tables.copy() if self.paged else None
+        routed_out = None
         with telemetry.span("serve.wave.dispatch", wave=wave_id):
             if self.paged:
-                sampled, ck, cv, after = self._moe_take(self._mixed(
+                out = self._mixed(
                     self.params, self.cfg_tuple,
                     self.kv.cache_k, self.kv.cache_v,
                     tables, wave["pos"], wave["tokens"],
                     wave["q_len"], wave["first_row"], wave["self_fresh"],
                     self._temp, self._topk, self._keys,
-                    has_fresh=bool(pre)))
+                    has_fresh=bool(pre))
+                if self.routed is not None:
+                    out, routed_out = out[:-1], out[-1]
+                sampled, ck, cv, after = self._moe_take(out)
             else:
                 sampled, ck, cv, after = self._moe_take(self._mixed(
                     self.params, self.cfg_tuple,
@@ -1166,6 +1244,8 @@ class ServingEngine:
         with telemetry.span("serve.wave.sync", wave=wave_id):
             sampled = np.asarray(sampled)
             after = np.array(after, np.uint32)
+            moe_rec = (self._routed_record(wave, routed_out)
+                       if routed_out is not None else None)
         dt = time.perf_counter() - t0
         self._wave_end = t0 + dt
         with telemetry.span("serve.wave.unpack", wave=wave_id):
@@ -1287,7 +1367,8 @@ class ServingEngine:
                 prefill_s=dt * q_pre / q_tot, step=self.steps,
                 requests=wave_reqs, end_perf=t0 + dt, spec=spec,
                 mix={"q_prefill": q_pre, "q_verify": q_ver,
-                     "q_decode": n_dec}, moe=self._moe_record())
+                     "q_decode": n_dec},
+                moe=moe_rec or self._moe_record())
         root.set(live=len(live), q_prefill=q_pre, q_verify=q_ver,
                  q_decode=n_dec)
         return done

@@ -135,7 +135,10 @@ def _alloc_cache(shape, dtype, quantized):
 
 
 def cache_nbytes(cache):
-    """HBM bytes of a cache value (plain array or quantized pair)."""
+    """HBM bytes of a cache value (plain array, quantized pair, or the
+    None a latent pool has in place of a second pool)."""
+    if cache is None:
+        return 0
     if isinstance(cache, (tuple, list)):
         return sum(int(a.nbytes) for a in cache)
     return int(cache.nbytes)
@@ -437,7 +440,8 @@ class PagedKVManager:
 
     def __init__(self, *, layers, heads, head_dim, slots, max_seq_len,
                  pos_cap=None, dtype=jnp.float32, bucket=True,
-                 block=16, pool_blocks=None, prefix_share=None):
+                 block=16, pool_blocks=None, prefix_share=None,
+                 row_shape=None):
         if bucket:
             slots = round_up_pow2(slots)
             s = round_up_pow2(max_seq_len, floor=16)
@@ -468,9 +472,26 @@ class PagedKVManager:
             prefix_share = envvars.get_bool("HETU_KV_PREFIX_SHARE")
         self.prefix_share = bool(prefix_share)
         self.quant = "int8" if _is_int8(dtype) else None
-        shape = (layers, self.n_blocks, self.block, heads, head_dim)
-        self.cache_k = _alloc_cache(shape, dtype, self.quant)
-        self.cache_v = _alloc_cache(shape, dtype, self.quant)
+        # what one token keeps a layer: a K/V pair of [heads, head_dim]
+        # in two pools, or, with ``row_shape`` (a latent spec's
+        # ``(LatentSpec.row_width,)``), ONE row in ONE pool: ``cache_k``
+        # is that pool and ``cache_v`` is None.  Allocation, tables,
+        # prefix sharing, COW, truncate and release never look inside a
+        # row, so they hold for either
+        self.latent = row_shape is not None
+        if self.latent:
+            if self.quant:
+                raise ValueError(
+                    "an int8 pool of latent rows is not supported: the "
+                    "codec scales a (position, head) slab and a latent "
+                    "row has no heads (kv_quant with a latent spec)")
+            shape = (layers, self.n_blocks, self.block) + tuple(row_shape)
+            self.cache_k = _alloc_cache(shape, dtype, None)
+            self.cache_v = None
+        else:
+            shape = (layers, self.n_blocks, self.block, heads, head_dim)
+            self.cache_k = _alloc_cache(shape, dtype, self.quant)
+            self.cache_v = _alloc_cache(shape, dtype, self.quant)
         self._free = list(range(1, self.n_blocks))   # 0 = scratch
         self.ref = np.zeros(self.n_blocks, np.int32)
         self.tables = np.zeros((self.n_slots, self.table_width), np.int32)
@@ -712,7 +733,10 @@ class PagedKVManager:
         """Copy pool block ``src`` onto ``dst`` (plain array or the
         quantized (data, scale) pair — both leaves move together so a
         COW fork never mixes one block's payload with another's
-        scales)."""
+        scales; None, a latent pool's absent second pool, stays
+        None)."""
+        if cache is None:
+            return None
         if isinstance(cache, (tuple, list)):
             return tuple(a.at[:, dst].set(a[:, src]) for a in cache)
         return cache.at[:, dst].set(cache[:, src])
@@ -829,12 +853,20 @@ class PagedKVManager:
         return self._export_span(idx, int(e.length), quant_mode,
                                  count=count)
 
+    def _refuse_latent(self, what):
+        if self.latent:
+            raise ValueError(
+                f"{what}: latent rows have no wire format yet (the "
+                f"handoff payload is a K/V pair of heads); a latent "
+                f"pool neither exports nor imports blocks")
+
     def _export_span(self, idx, length, quant_mode, *, count=True):
         """Gather pool blocks ``idx`` into the wire payload (shared by
         the slot and prefix export paths).  ``count=False`` keeps the
         gather out of the handoff ledger — the tier-spill path uses it
         so spill bytes don't masquerade as replica-to-replica wire
         traffic (the tier store keeps its own byte counters)."""
+        self._refuse_latent("export_blocks/export_prefix")
         mode = resolve_handoff_quant(quant_mode)
 
         def gather(cache):
@@ -866,6 +898,7 @@ class PagedKVManager:
         prefill→decode handoff).  Returns the slot, or None when slots
         or blocks are short (backpressure, same contract as ``alloc``).
         Block size and layout must match; a mismatch raises."""
+        self._refuse_latent("import_blocks")
         if payload.get("layout") != "paged":
             raise ValueError(
                 f"cannot import a {payload.get('layout')!r} payload "
@@ -943,4 +976,5 @@ class PagedKVManager:
             "import_bytes": self.import_bytes,
             "quant": self.quant or "off",
             "cache_bytes": self.cache_bytes,
+            "latent": self.latent,
         }

@@ -148,6 +148,10 @@ class TieredKVStore:
         its engine's admission path fetches through ``kv.tier_store``.
         Called per incarnation, like the directory attach; a
         non-sharing or block-mismatched pool attaches as a no-op."""
+        if getattr(kv, "latent", False):
+            raise ValueError(
+                "kv_tiers: a latent pool cannot spill or fetch (its "
+                "blocks have no wire format: kv_manager.export_blocks)")
         if not self.enabled or not getattr(kv, "prefix_share", False):
             return
         block = getattr(kv, "block", None)

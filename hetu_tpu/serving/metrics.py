@@ -205,9 +205,41 @@ class ServingMetrics(MetricsCore):
         cap = max(1, envvars.get_int("HETU_TELEMETRY_BUFFER"))
         self.breakdowns = collections.deque(maxlen=cap)
         self._slots = None
+        # a dropless routed engine's running counts (``record_routed``);
+        # ``moe_load`` is None until the first routed wave
+        self.moe_assignments = 0
+        self.moe_experts_touched = 0
+        self.moe_load = None
+        self.attn_ctx_tokens = 0
+        self.attn_score_pairs = 0
 
     def _make_lc(self, t_submit):
         return _Lifecycle(t_submit)
+
+    def record_routed(self, load, touched, ctx_tokens, score_pairs):
+        """One wave of a dropless routed, latent engine: ``load`` [E]
+        (assignments an expert, summed over the routed layers),
+        ``touched`` (experts with load > 0, summed over them),
+        ``ctx_tokens`` (the live slots' filled lengths after the wave's
+        writes, once a wave) and ``score_pairs`` (the positions every
+        live row sees).  Running sums here (``snapshot(since=mark)``
+        windows them) and the counters ``serve.moe.assignments``,
+        ``serve.moe.experts_touched``, ``serve.attn.ctx_tokens``,
+        ``serve.attn.score_pairs`` and the gauge ``serve.moe.load_max``
+        (this wave's largest load) in ``telemetry``."""
+        load = np.asarray(load, np.int64)
+        assignments = int(load.sum())
+        self.moe_assignments += assignments
+        self.moe_experts_touched += int(touched)
+        self.moe_load = (load.copy() if self.moe_load is None
+                         else self.moe_load + load)
+        self.attn_ctx_tokens += int(ctx_tokens)
+        self.attn_score_pairs += int(score_pairs)
+        telemetry.inc("serve.moe.assignments", assignments)
+        telemetry.inc("serve.moe.experts_touched", int(touched))
+        telemetry.set_gauge("serve.moe.load_max", int(load.max()))
+        telemetry.inc("serve.attn.ctx_tokens", int(ctx_tokens))
+        telemetry.inc("serve.attn.score_pairs", int(score_pairs))
 
     # ------------------------------------------------------------- #
     # lifecycle marks (the engine calls these at phase boundaries)
@@ -456,7 +488,9 @@ class ServingMetrics(MetricsCore):
     _MARK_LISTS = ("ttfts", "tpots", "step_live", "step_queue",
                    "step_dt", "step_tokens", "prefill_dt")
     _MARK_COUNTS = ("submitted", "rejected", "finished",
-                    "tokens_generated", "prefill_batched")
+                    "tokens_generated", "prefill_batched",
+                    "moe_assignments", "moe_experts_touched",
+                    "attn_ctx_tokens", "attn_score_pairs")
 
     def mark(self):
         """A position in this engine's history for ``snapshot(since=)``:
@@ -465,6 +499,8 @@ class ServingMetrics(MetricsCore):
         m.update({k: getattr(self, k) for k in self._MARK_COUNTS})
         m["components"] = {c: len(xs) for c, xs in self.components.items()}
         m["t"] = self._t_last
+        m["moe_load"] = None if self.moe_load is None \
+            else self.moe_load.copy()
         return m
 
     def snapshot(self, since=None):
@@ -512,7 +548,23 @@ class ServingMetrics(MetricsCore):
                     "p99_ms": round(_pct(xs, 99), 3),
                     "mean_ms": round(float(np.mean(xs)), 3),
                 }
+        routed = {}
+        if self.moe_load is not None:
+            load = self.moe_load if at("moe_load") is None \
+                else self.moe_load - at("moe_load")
+            mean = float(load.mean())
+            routed = {
+                "moe_assignments": count("moe_assignments"),
+                "moe_experts_touched": count("moe_experts_touched"),
+                "moe_load": [int(x) for x in load],
+                "moe_load_max": int(load.max()),
+                "moe_load_imbalance": (float(load.max()) / mean
+                                       if mean > 0 else None),
+                "attn_ctx_tokens": count("attn_ctx_tokens"),
+                "attn_score_pairs": count("attn_score_pairs"),
+            }
         return {
+            **routed,
             "requests_submitted": count("submitted"),
             "requests_rejected": count("rejected"),
             "requests_finished": count("finished"),

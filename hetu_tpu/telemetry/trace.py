@@ -230,7 +230,8 @@ def to_chrome_trace(events):
                 reqs = rec.get("requests")
                 if isinstance(reqs, (list, tuple)):
                     waves.append((ts_us, ts_us + dur_us, pid, tid,
-                                  [str(r) for r in reqs]))
+                                  [str(r) for r in reqs],
+                                  rec.get("step")))
             elif kind == "req_span" and rec.get("phase") == "decode":
                 decode_spans[str(rec.get("request"))] = \
                     (ts_us, ts_us + dur_us, pid, tid)
@@ -243,21 +244,25 @@ def to_chrome_trace(events):
     # wave slice, f back on the request track at retire)
     n_flows = 0
     for rid, (d0, d1, rpid, rtid) in sorted(decode_spans.items()):
-        hits = [(w0, wpid, wtid) for w0, w1, wpid, wtid, reqs in waves
-                if rid in reqs]
+        hits = [(w0, wpid, wtid, step)
+                for w0, w1, wpid, wtid, reqs, step in waves if rid in reqs]
         if not hits:
             continue
         flow = {"name": "req_flow", "cat": "req", "id": rid}
         out.append({**flow, "ph": "s", "ts": d0, "pid": rpid,
                     "tid": rtid})
-        for w0, wpid, wtid in sorted(hits):
+        for w0, wpid, wtid, step in sorted(
+                hits, key=lambda hit: hit[:3]):
             # clamp into the decode span: the wave's backdated start
             # can drift past the request's retire stamp by scheduler-
             # loop overhead (the two are stamped at different points of
-            # the same iteration), and flow steps must stay s <= t <= f
+            # the same iteration), and flow steps must stay s <= t <= f.
+            # The clamp can move a step out of its wave's slice, so the
+            # step also NAMES its wave (the serve_step record's ``step``)
             out.append({**flow, "ph": "t",
                         "ts": min(max(w0, d0), d1),
-                        "pid": wpid, "tid": wtid})
+                        "pid": wpid, "tid": wtid,
+                        "args": {"wave": step}})
         out.append({**flow, "ph": "f", "bp": "e", "ts": d1,
                     "pid": rpid, "tid": rtid})
         n_flows += 1

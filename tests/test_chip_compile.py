@@ -23,7 +23,7 @@ from jax.sharding import SingleDeviceSharding
 from hetu_tpu.kernels import decode_attention as da
 from hetu_tpu.kernels import flash_attention as fa
 from hetu_tpu.kernels.ragged_attention import (
-    ragged_attention, ragged_paged_attention)
+    ragged_attention, ragged_paged_attention, ragged_paged_mla)
 from hetu_tpu.models import gpt_decode as gd
 
 DH, S_MAX, BLOCK, SLOTS = 64, 1024, 16, 8
@@ -203,3 +203,40 @@ def test_phase_split(sds, kernel):
     fn = getattr(da, kernel)
     compiled_text(lambda *a: fn(*a, interpret=False),
                   q, *kv, lens, *extra, *tail)
+
+
+
+@pytest.mark.parametrize("q_block", [1, 64, 256])
+def test_ragged_paged_mla_at_the_published_widths(sds, q_block):
+    """ISSUE 28: 20 heads x 640 (576 padded to the lanes) over a latent
+    pool of 16-position pages copied by hand, 32 slots, a table of 512
+    entries: wider than anything else compiled here.  A 256-row q-block
+    is four tiles of 1280 (query, head) rows."""
+    from hetu_tpu.kernels import ragged_attention as ra
+    B, H, W, T, pool_blocks = 32, 20, 640, 512, 10241
+    assert ra._mla_q_tile(256, H) * H <= ra._MLA_TILE_ROWS
+
+    def f(q, pool, lens, q_len, tables):
+        return ragged_paged_mla(q, pool, lens, q_len, tables,
+                                value_width=512, scale=1 / 16, layer=3,
+                                interpret=False)
+
+    compiled = jax.jit(f).lower(
+        sds((B, q_block, H, W), jnp.bfloat16),
+        sds((7, pool_blocks, BLOCK, W), jnp.bfloat16),
+        sds((B,), jnp.int32), sds((B,), jnp.int32),
+        sds((B, T), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "ragged_paged_mla" in text
+
+
+def test_ragged_dot_becomes_the_compilers_grouped_matmul(sds):
+    """What ``moe_decode.grouped_matmul`` rests on: on the TPU
+    ``jax.lax.ragged_dot`` is not expanded into a dense product an
+    expert; it becomes one kernel with group metadata."""
+    from hetu_tpu.models.moe_decode import grouped_matmul
+    compiled = jax.jit(grouped_matmul).lower(
+        sds((128, 2048), jnp.bfloat16), sds((64, 2048, 1536), jnp.bfloat16),
+        sds((64,), jnp.int32)).compile()
+    assert "ragged-dot" in compiled.as_text()
+    assert "tpu_custom_call" in compiled.as_text()

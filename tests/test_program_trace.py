@@ -300,7 +300,7 @@ def test_every_new_metric_is_left_out_on_the_parents_trace():
     log = _Log()
     data = {"trace": parents_trace(), "harness": log,
             "snapshot": {}, "samples": {}}
-    new = [m for m in bench["per_layer"][10:]]
+    new = bench["per_layer"][10:20]                    # PR 25's ten
     assert len(new) == 10
     assert bench_run.per_layer_metrics(new, data) == {}
     # each says what it missed, on a line of its own
@@ -315,6 +315,17 @@ def test_every_new_metric_is_left_out_on_the_parents_trace():
          and m["name"].endswith(".serve")], data)
     assert set(old) == {"pallas_kernel_share.serve",
                         "device_idle_share.serve"}
+    # PR 28's seven find nothing either: no such kernel, scope or counter
+    log28 = _Log()
+    seven = bench["per_layer"][20:27]
+    assert [m["name"] for m in seven][::3] == [
+        "mla_kernel_share.serve", "moe_experts_roofline.serve",
+        "expert_load_imbalance.serve"]
+    assert bench_run.per_layer_metrics(
+        seven, dict(data, harness=log28, counters={})) == {}
+    assert all(r["line"] == "metric_missing" for r in log28.lines)
+    assert {"op_share", "scope_share", "scope_or_op_share",
+            "kernel_roofline"} == {r["reader"] for r in log28.lines}
 
 
 def test_a_renamed_scope_or_span_is_a_missing_metric_not_a_zero():
