@@ -15,7 +15,9 @@ Without a TPU, with fewer chips than the cell asks for, or on a
 non-zero before it builds anything and prints no result.  The last line
 of standard output is the result object; everything else a reader may
 want (MFU, sample counts, cache hits, the engine's settings) is on
-earlier lines, one JSON object each.
+earlier lines, one JSON object each.  The last lines of standard error
+are the numbers ``correct`` compared, each beside its limit (a runner's
+``compared``).
 """
 
 from __future__ import annotations
@@ -229,6 +231,18 @@ def per_layer_metrics(entries, data, here=HERE):
     return out
 
 
+def report_compared(result, err=None):
+    """Each number ``correct`` compared, beside its limit, as the last
+    lines of standard error: what is left of a run that was not correct
+    is the end of that stream and the result line's keys."""
+    for c in result.get("compared", []):
+        print(f"compared: {c['name']} = {c['value']!r} limit {c['limit']!r} "
+              f"({'within' if c['within'] else 'OUTSIDE'})",
+              file=err or sys.stderr, flush=True)
+    print(f"correct: {bool(result['correct'])}", file=err or sys.stderr,
+          flush=True)
+
+
 def main(argv=None):
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--workload", required=True)
@@ -289,6 +303,7 @@ def main(argv=None):
                            for m in resolved["end_to_end"]}
     line["device"] = device
     print(json.dumps(line), file=h.out or sys.stdout, flush=True)
+    report_compared(result)
     return 0
 
 

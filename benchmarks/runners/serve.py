@@ -373,4 +373,9 @@ def run(h, cfg=None):
         "data": {"snapshot": view, "samples": out["untraced"]},
         "notes": {"slots": args["slots"], "buckets": buckets,
                   "widest_logit_gap": worst},
+        "compared": [
+            {"name": "widest_logit_gap", "value": worst,
+             "limit": float(args["logit_margin"]), "within": ok},
+            {"name": "exact_lengths", "value": out["exact_lengths"],
+             "limit": True, "within": out["exact_lengths"]}],
     }

@@ -234,4 +234,12 @@ def run(h, cfg=None):
         "data": {"snapshot": view, "samples": out["untraced"],
                  "counters": counters},
         "notes": {"slots": args["slots"], "buckets": buckets, **record},
+        "compared": [
+            {"name": key, "value": record[key], "limit": float(args[limit]),
+             "within": record[key] <= float(args[limit])}
+            for key, limit in (("widest_logit_gap", "logit_margin"),
+                               ("near_tie_share", "tie_share_max"))
+            if key in record] + [
+            {"name": "exact_lengths", "value": out["exact_lengths"],
+             "limit": True, "within": out["exact_lengths"]}],
     }

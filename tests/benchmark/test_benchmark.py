@@ -51,13 +51,32 @@ def harness(cell, seconds, config_over, traffic_over, trace=False, root=ROOT):
 # runners, end to end at a tiny width
 # ------------------------------------------------------------------ #
 
+# 32 channels round far more coarsely in bf16 than 1024 do, and the CPU's
+# bf16 is not the chip's: the tiny model gets limits of its own, the
+# cell's stay in its file
+TRAIN_ARGS = {"first_gradient_gap_max": 0.015, "first_loss_gap_max": 0.004,
+              "trained_loss_gap_max": 0.02, "loss_fall_min": 0.3}
+TRAIN_MIX = {"batch": 2, "seq": 32, "pool": 4, "data_ids": 16}
+CHECK_KEYS = {
+    "first_loss_system", "first_loss_reference", "first_loss_gap",
+    "first_loss_gap_max", "first_gradient_gap", "first_gradient_gap_max",
+    "first_gradient_worst_leaf", "first_step_agrees",
+    "trained_state_loss_system", "trained_state_loss_reference",
+    "trained_state_reading", "trained_state_limit",
+    "trained_state_agrees_share", "trained_state_agrees", "steps",
+    "loss_fall", "check_s"}
+COMPARED = ["first_gradient_gap", "first_loss_gap", "trained_state_loss_gap",
+            "loss_fall", "losses_finite"]
+
+
+def train_harness(seconds):
+    return harness("train-gpt2-medium-s1024", seconds,
+                   dict(TINY, runner_args=TRAIN_ARGS), TRAIN_MIX)
+
+
 def test_train_runner_end_to_end():
     from benchmarks.runners import train
-    # 32 channels round far more coarsely in bf16 than 1024 do: the tiny
-    # model gets a tolerance of its own, the cell's stays in its file
-    h = harness("train-gpt2-medium-s1024", 1.5,
-                dict(TINY, runner_args={"loss_tolerance": 0.02}),
-                {"batch": 2, "seq": 32, "pool": 4, "data_ids": 16})
+    h = train_harness(1.5)
     cfg = train.gpt_config(h.config, 2, 32)
     out = train.run(h, cfg)
     assert out["correct"], h.out.getvalue()
@@ -66,10 +85,122 @@ def test_train_runner_end_to_end():
     assert h.setup_s > 0
     assert len(out["data"]["samples"]["train_step_ms"]) == out["attempted"]
     assert "flash_call_cost" not in out["data"]
+
+
+def test_train_check_says_what_it_read():
+    """Every number compared, its limit and the trained-state reading as
+    a share of its limit are on the ``reference`` line, in the result's
+    notes and in what ``run.py`` prints last on standard error."""
+    from benchmarks.runners import train
+    h = train_harness(1.0)
+    out = train.run(h, train.gpt_config(h.config, 2, 32))
     lines = [json.loads(l) for l in h.out.getvalue().splitlines()]
     ref = next(l for l in lines if l["line"] == "reference")
-    assert abs(ref["loss_system"] - ref["loss_reference"]) < 0.02 * max(
-        ref["loss_reference"], 1)
+    assert CHECK_KEYS <= set(ref) and CHECK_KEYS <= set(out["notes"])
+    assert ref["steps"] == out["attempted"]
+    assert ref["first_loss_gap"] == pytest.approx(
+        abs(ref["first_loss_system"] - ref["first_loss_reference"]))
+    assert ref["trained_state_reading"] == pytest.approx(abs(
+        ref["trained_state_loss_system"]
+        - ref["trained_state_loss_reference"]))
+    assert ref["trained_state_limit"] == TRAIN_ARGS["trained_loss_gap_max"]
+    assert ref["trained_state_agrees_share"] == pytest.approx(
+        ref["trained_state_reading"] / ref["trained_state_limit"])
+    assert 0 < ref["first_gradient_gap"] <= ref["first_gradient_gap_max"] \
+        == TRAIN_ARGS["first_gradient_gap_max"]
+    assert out["notes"]["trained_state_agrees_share"] \
+        == ref["trained_state_agrees_share"]
+    assert [c["name"] for c in out["compared"]] == COMPARED
+    assert all(c["within"] for c in out["compared"]) == out["correct"]
+    by_name = {c["name"]: c for c in out["compared"]}
+    assert by_name["trained_state_loss_gap"]["value"] \
+        == ref["trained_state_reading"]
+    assert by_name["first_gradient_gap"]["value"] == ref["first_gradient_gap"]
+    err = io.StringIO()
+    bench_run.report_compared(out, err)
+    said = err.getvalue().splitlines()
+    assert len(said) == len(COMPARED) + 1
+    assert said[-1] == f"correct: {out['correct']}"
+    assert said[0].startswith("compared: first_gradient_gap = ") \
+        and f"limit {TRAIN_ARGS['first_gradient_gap_max']!r}" in said[0]
+
+
+@pytest.fixture(scope="module")
+def checked_tiny():
+    """The tiny trainer's two checks, sound and with each fault on the
+    reference's side: (first step, its faults, trained state after 60
+    steps, the reference's loss there with each fault)."""
+    from benchmarks import probe_train_check as probe
+    from benchmarks.runners import train
+    h = harness("train-gpt2-medium-s1024", 1, dict(TINY), TRAIN_MIX)
+    cfg = train.gpt_config(h.config, 2, 32)
+    ex, ids, labels = train.build_trainer(cfg, 11)
+    batches = loadgen.train_batches(h.traffic, 11, cfg.vocab_size)
+
+    def step(batch):
+        return train.one_step(h, ex, ids, labels, batch)
+    # the seeded weights stand until the first step donates them
+    want = train.reference_first_step(ex.var_values, h.config, batches[0])
+    first_faults = probe.first_step_controls(
+        ex.var_values, h.config, batches[0], want, TRAIN_ARGS)
+    first = train.judge_first_step(
+        (step(batches[0]), train.first_gradient_norms(
+            train.first_gradient_squares(ex))), want, TRAIN_ARGS)
+    for i in range(1, 60):
+        step(batches[i % len(batches)])
+    # before the check's own step, which moves the weights on
+    faults = probe.controls(ex.var_values, h.config, batches[-1])
+    trained = train.read_trained_state(ex, step, h.config, batches[-1])
+    return first, first_faults, trained, faults
+
+
+@pytest.mark.parametrize("fault", [
+    "mask_off_by_one", "block_skipped", "positions_shifted", "head_rolled",
+    "float8_products"])
+def test_train_check_catches_a_fault_on_the_references_side(checked_tiny,
+                                                            fault):
+    """The reference with the fault, put in the system's place, is
+    outside a limit by a factor of two or more; the system is inside
+    every limit, and a fault moves no limit."""
+    from benchmarks.runners import train
+    first, first_faults, trained, faults = checked_tiny
+    sound = train.judge_trained_state(trained, TRAIN_ARGS)
+    assert first["first_step_agrees"] and sound["agrees"], (first, sound)
+    assert first["first_gradient_gap"] \
+        < 0.75 * TRAIN_ARGS["first_gradient_gap_max"]
+    assert sound["agrees_share"] < 0.5
+    broken = train.judge_trained_state(
+        dict(trained, loss_system=faults[fault]), TRAIN_ARGS)
+    assert broken["limit"] == sound["limit"]
+    shares = {
+        "first_gradient_gap": first_faults[fault]["first_gradient_gap"]
+        / TRAIN_ARGS["first_gradient_gap_max"],
+        "first_loss_gap": first_faults[fault]["first_loss_gap"]
+        / TRAIN_ARGS["first_loss_gap_max"],
+        "trained_state_loss_gap": broken["agrees_share"]}
+    assert max(shares.values()) > 2, shares
+    if fault in ("mask_off_by_one", "block_skipped", "float8_products"):
+        # what a faster kernel or a cheaper product would break shows in
+        # the first gradient, wherever the window ends
+        assert shares["first_gradient_gap"] > 2, shares
+
+
+def test_a_step_that_leaves_the_state_unchanged_is_not_correct(monkeypatch):
+    """The rest of a run with the timed path broken underneath: the
+    optimizer hands every parameter and its own state back as it got
+    them.  The losses stay finite and agree with the reference; they do
+    not fall, and no gradient ever reached the optimizer's state."""
+    from benchmarks.runners import train
+    from hetu_tpu import optimizer
+    monkeypatch.setattr(optimizer.AdamWOptimizer, "update_one",
+                        lambda self, p, g, s, lr, step: (p, s))
+    h = train_harness(1.0)
+    out = train.run(h, train.gpt_config(h.config, 2, 32))
+    assert not out["correct"]
+    within = {c["name"]: c["within"] for c in out["compared"]}
+    assert within == {"first_gradient_gap": False, "first_loss_gap": True,
+                      "trained_state_loss_gap": True, "loss_fall": False,
+                      "losses_finite": True}
 
 
 SERVE_ARGS = {"slots": 4, "pool_blocks": 17, "prefill_chunk": 16,
