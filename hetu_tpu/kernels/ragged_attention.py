@@ -31,12 +31,19 @@ verify-kernel computation with nothing verify-specific left in it:
   - ``q_len = 1`` degenerates exactly to the decode kernel's mask, so
     a decode-only wave pays no mixed-mode tax.
 
-There is ONE parameterized kernel body (``_ragged_kernel``) behind all
-four layouts (contiguous/block-table x f32/int8) and ONE masked-gather
-reference (``ragged_masked_reference``) for off-TPU interpret-mode
-parity — kernels/decode_attention.py's four per-mode references now
-delegate here, and its per-mode kernels remain as parity oracles behind
-the existing ``$HETU_SERVE_FAST``/phase-split paths.
+The bullets above are the BLOCKED body (``_ragged_kernel``): the
+contiguous layout in either dtype, and the int8 block-table pool.  The
+float block-table pool — the engine's production layout, the one a
+benchmark cell serves from — is read in place by ``_kv_rows_kernel``
+under the same wrapper and the same HLO name: pool rows of whole lane
+tiles left in HBM, grid (slot, q-tile), pages copied by hand a group at
+a time with the layer in the copy, the same per-slot data (q_len,
+kv_len, tables), mask, dead-tile rule and f32 online softmax (see the
+comment block over it).  ONE masked-gather reference
+(``ragged_masked_reference``) serves them all for off-TPU
+interpret-mode parity — kernels/decode_attention.py's four per-mode
+references delegate here, and its per-mode kernels remain as parity
+oracles behind the existing ``$HETU_SERVE_FAST``/phase-split paths.
 
 The kernel reads the q-block's own K/V back from the pool (the
 engine's mixed step writes before it attends), so a lossy cache dtype
@@ -49,25 +56,34 @@ per-mode arithmetic instead.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .flash_attention import NEG_INF, _fit_block
+from ..kv_layout import kv_heads, kv_row_width, kv_rows
+from .flash_attention import NEG_INF, _fit_block, _prec
 from .decode_attention import (_LANES, _online_softmax_multi,
                                _use_interpret, _verify_finalize)
 
 
 # (head, query) accumulator rows one q-tile may hold: the (m, l, acc)
-# scratch, the score block and the q/o tiles all scale with it.  A
-# q-block of up to 3200 rows (25 heads x 128 queries; 12 x 256 is 3072)
-# compiled for v5e's 16 MiB scoped VMEM as ONE tile before there were
-# tiles, and still is one — nothing that compiled changes its schedule.
-# Longer q-blocks, which the compiler refused, are cut into tiles of at
-# most 2048 rows (3072-row tiles of an f32 cache are refused once there
-# is more than one of them).
+# scratch, the score block and the q/o tiles all scale with it.  The
+# BLOCKED body (contiguous caches, the int8 pool: K/V tiles of
+# ``[bk, H, Dh]``): a q-block of up to 3200 rows (25 heads x 128
+# queries; 12 x 256 is 3072) compiled for v5e's 16 MiB scoped VMEM as
+# ONE tile before there were tiles, and still is one; longer q-blocks,
+# which the compiler refused, are cut into tiles of at most 2048 rows
+# (3072-row tiles of an f32 cache are refused once there is more than
+# one of them).  The ROWS kernel over the paged float pool
+# (``[L, N, bs, W]``, below) always cuts at ``_MAX_ROWS``: its (m, l,
+# acc) rows are 128 lanes wide for two heads of 64, and beside them it
+# holds two buffers each of a group's K and V pages (16 pages x 16 x
+# 1664 x 2 B = 0.85 MB a buffer at 25 heads, 3.4 MB in all); 25 heads
+# give tiles of 64 queries, 12 and 16 heads of 128, every q-block from
+# 1 to 1024 compiled for v5e at those widths (tests/test_chip_compile).
 _ONE_TILE_ROWS = 3200
 _MAX_ROWS = 2048
 
@@ -252,28 +268,17 @@ def ragged_attention(q, k, v, lengths, q_lens, *, block_k=128,
         interpret=interpret)
 
 
-def ragged_paged_attention(q, pool_k, pool_v, lengths, q_lens,
-                           block_tables, *, k_scale=None, v_scale=None,
-                           interpret=None):
-    """The mixed wave over the BLOCK-TABLE paged pool — the serving
-    engine's production mixed-mode dispatch.
-
-    q: [B, Q, H, Dh]; pool_k, pool_v: [N_blocks, bs, H, Dh] (the shared
-    pool, one layer); block_tables: [B, T] int32 — entry (b, j) is the
-    pool block holding slot b's positions [j*bs, (j+1)*bs); lengths /
-    q_lens: [B] int32 as in :func:`ragged_attention` (dead table
-    entries may hold any valid pool index — the engine points them at
-    scratch block 0).  Each slot DMAs exactly ceil(lengths[b]/bs) live
-    pool blocks through its scalar-prefetched table row; shared prefix
-    blocks are fetched per-slot but stored once.  Int8 pools pass
-    ``k_scale``/``v_scale`` [N_blocks, bs, H] f32."""
+def _ragged_paged_blocked(q, pool_k, pool_v, lengths, q_lens,
+                          block_tables, k_scale, v_scale, interpret):
+    """The int8 pool's path (ONE layer, ``[N_blocks, bs, H, Dh]`` int8
+    with ``[N_blocks, bs, H]`` f32 scales): a page is a grid block,
+    grid (slot, q-tile, page), the shared ``_ragged_kernel`` body
+    dequantizing inside the online-softmax loop.  In no benchmark cell;
+    see :func:`ragged_paged_attention` for why it did not move."""
     B, Q, H, Dh = q.shape
     bs = pool_k.shape[1]
     T = block_tables.shape[1]
     tq = _q_tile(Q, H)
-    if interpret is None:
-        interpret = _use_interpret()
-    quant = k_scale is not None
 
     def block(b, t, j, lens_ref, qlens_ref, bt_ref):
         return bt_ref[b, _kv_step_block(lens_ref, qlens_ref, b, t, j,
@@ -285,22 +290,313 @@ def ragged_paged_attention(q, pool_k, pool_v, lengths, q_lens,
     def sc_idx(b, t, j, *refs):
         return (block(b, t, j, *refs), 0, 0)
 
-    if quant:
-        kv_specs = [pl.BlockSpec((1, bs, H, Dh), kv_idx),
-                    pl.BlockSpec((1, bs, H), sc_idx),
-                    pl.BlockSpec((1, bs, H, Dh), kv_idx),
-                    pl.BlockSpec((1, bs, H), sc_idx)]
-        operands = (q, pool_k, k_scale, pool_v, v_scale)
-    else:
-        kv_specs = [pl.BlockSpec((1, bs, H, Dh), kv_idx),
-                    pl.BlockSpec((1, bs, H, Dh), kv_idx)]
-        operands = (q, pool_k, pool_v)
+    kv_specs = [pl.BlockSpec((1, bs, H, Dh), kv_idx),
+                pl.BlockSpec((1, bs, H), sc_idx),
+                pl.BlockSpec((1, bs, H, Dh), kv_idx),
+                pl.BlockSpec((1, bs, H), sc_idx)]
     return _call_ragged(
-        q, operands, bk=bs, n_kv=T, tq=tq, quant=quant,
-        tabled=True, kv_specs=kv_specs,
+        q, (q, pool_k, k_scale, pool_v, v_scale), bk=bs, n_kv=T, tq=tq,
+        quant=True, tabled=True, kv_specs=kv_specs,
         scalars=(lengths.astype(jnp.int32), q_lens.astype(jnp.int32),
                  block_tables.astype(jnp.int32)),
         interpret=interpret)
+
+
+# ------------------- the paged K/V pool, read in place ------------------- #
+#
+# The pool pair is ``[L, N_blocks, bs, W]``: one ROW a position, head h in
+# lanes ``[h * Dh, (h + 1) * Dh)``, ``W = kv_row_width(H, Dh)`` the next
+# multiple of the 128 lanes (GPT-2 XL: 25 x 64 = 1600 -> 1664, zeros in
+# the pad; 768 and 1024 need none).  Three things follow from the row
+# (ledger, PR 30, ``serve-gpt2-xl-batch-closed``: of a 6 s trace 1.995 s
+# were ``copy``, 0.912 s ``slice_bitcast_fusion`` and 1.666 s the kernel):
+#
+#   - ``[.., 25, 64]`` in bf16 tiles to (32, 128), 2.56 x its bytes, so the
+#     compiler gave the old pool a block-index-minor default layout and
+#     every wave copied the donated pool to the kernel's layout and back.
+#     A row that is a whole number of lane tiles has the row-major default
+#     layout the kernel reads: the pool is aliased in place.
+#   - the pool stays in HBM (``memory_space=pl.ANY``) with the LAYER in the
+#     page copy (``pool.at[layer, page]``, the layer a prefetched scalar):
+#     no ``pool[layer]`` slice is materialised before each of a model's
+#     layers, and all of them share one trace and lowering of the kernel.
+#   - a 16-position page handed to the grid as a block cost a grid step
+#     each (1,024 steps a layer for a decode wave of 16 slots whatever they
+#     held).  The grid is (slot, q-tile); inside a step a loop runs over as
+#     many GROUPS of pages as the tile can see, the K and V pages of group
+#     g + 1 in flight while group g is scored (``_page_loop``, shared with
+#     the latent kernel below).
+#
+# Heads are split inside the kernel.  The row is scored a lane CHUNK at a
+# time (``_lane_chunk``: 128 lanes, two heads of 64): the chunk's heads
+# are stacked along the matmul's rows, each with the other heads' lanes
+# of the query zeroed, so ONE [G * tq, 128] x [128, span] product scores
+# them all with no lane shuffle and no contraction narrower than the MXU;
+# ``p @ v`` over the chunk's 128 value lanes gives every stacked row all
+# G heads' columns, of which the finalize keeps the row's own.
+
+_PAGE_GROUP = 16
+
+
+def _lane_chunk(W, Dh):
+    """Lanes scored by one matmul: the smallest whole number of lane
+    tiles that holds whole heads (128 for a head of 64), or the whole
+    row where that does not divide it (tiny test widths)."""
+    cw = math.lcm(_LANES, Dh)
+    return cw if W % cw == 0 else W
+
+
+def _page_group(T, bs, W, dtype):
+    """Pages copied and scored together: ``_PAGE_GROUP``, fewer where
+    the table is narrower or a group's K (or V) buffer would pass 1 MiB
+    (two kinds x two buffers are then at most 4 MiB of VMEM)."""
+    page = bs * W * jnp.dtype(dtype).itemsize
+    return max(1, min(_PAGE_GROUP, T, (1 << 20) // page))
+
+
+def _tile_in_sight(lens_ref, qlens_ref, tq, bs, group):
+    """This grid step's (slot, q-tile, groups of ``group`` pages the tile
+    can see, last page in sight).  A dead slot, and a tile wholly in the
+    q-block's dead tail, see no group."""
+    b = pl.program_id(0)
+    t = pl.program_id(1)
+    span = group * bs
+    end = _visible_end(lens_ref, qlens_ref, b, t, tq)
+    live = _live_tile(qlens_ref, b, t, tq) & (end > 0)
+    return (b, t, jnp.where(live, (end + span - 1) // span, 0),
+            jnp.maximum(end - 1, 0) // bs)
+
+
+def _reset(m_ref, l_ref, acc_ref):
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+
+
+def _page_loop(n_groups, copies, score):
+    """The two-buffer page pipeline both hand-paged kernels run:
+    ``copies(gi, buf)`` lists group ``gi``'s async copies into buffer
+    ``buf``; group ``gi + 1``'s are started before group ``gi``'s are
+    waited for and ``score(gi, buf)`` runs.  ``n_groups`` is traced: a
+    dead slot or tile (0) copies and scores nothing."""
+
+    @pl.when(n_groups > 0)
+    def _first():
+        for c in copies(0, 0):
+            c.start()
+
+    def step(gi, carry):
+        buf = gi % 2
+
+        @pl.when(gi + 1 < n_groups)
+        def _next():
+            for c in copies(gi + 1, 1 - buf):
+                c.start()
+
+        for c in copies(gi, buf):
+            c.wait()
+        score(gi, buf)
+        return carry
+
+    jax.lax.fori_loop(0, n_groups, step, 0)
+
+
+def _mask_scores(s, qi, gi, filled, qlen):
+    """Causal mask of a group's scores ``s`` [R, span]: row r is query
+    ``qi[r]`` of the q-block, at absolute position ``filled - qlen +
+    qi`` (dead rows clip to the last live position, as
+    ``_query_positions``), and admits kv positions up to itself."""
+    kv_pos = gi * s.shape[1] + jax.lax.broadcasted_iota(
+        jnp.int32, s.shape, 1)
+    posq = jnp.minimum(filled - qlen + qi, filled - 1)
+    return jnp.where(kv_pos <= posq, s, NEG_INF)
+
+
+def _softmax_step(s, v, m_ref, l_ref, acc_ref, at=(slice(None),),
+                  precision=None):
+    """One group's online-softmax update of the accumulator rows
+    ``at``: masked scores ``s`` [R, span] f32 over values ``v``
+    [span, dv]; (m, l) are lane-broadcast f32 rows, acc is [R, dv]."""
+    m_prev = m_ref[at][:, 0:1]
+    l_prev = l_ref[at][:, 0:1]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    safe_m = jnp.where(m_new <= NEG_INF / 2, 0.0, m_new)
+    p = jnp.where(s <= NEG_INF / 2, 0.0, jnp.exp(s - safe_m))
+    alpha = jnp.exp(jnp.clip(m_prev - m_new, max=0.0))
+    alpha = jnp.where(m_prev <= NEG_INF / 2, 0.0, alpha)
+    l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
+    pv = jax.lax.dot_general(
+        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+        precision=precision, preferred_element_type=jnp.float32)
+    acc_ref[at] = acc_ref[at] * alpha + pv
+    m_ref[at] = jnp.broadcast_to(m_new, m_ref[at].shape)
+    l_ref[at] = jnp.broadcast_to(l_new, l_ref[at].shape)
+
+
+def _kv_rows_kernel(lens_ref, qlens_ref, bt_ref, layer_ref, q_ref, k_pool,
+                    v_pool, o_ref, k_buf, v_buf, sem, m_ref, l_ref, acc_ref,
+                    *, scale, bs, group, tq, heads, dh, cw, precision):
+    b, t, n_groups, last = _tile_in_sight(lens_ref, qlens_ref, tq, bs, group)
+    layer = layer_ref[0]
+    _reset(m_ref, l_ref, acc_ref)
+    # (chunk, heads in it): the row's last chunk may hold fewer (GPT-2
+    # XL: head 24 alone beside 64 pad lanes, which are never scored)
+    per = cw // dh
+    chunks = [(c, min(per, heads - c * per))
+              for c in range(q_ref.shape[2] // cw)]
+
+    def copies(gi, buf):
+        """Group ``gi``'s K and V page copies into buffer ``buf``; pages
+        past the last in sight copy that one again (their positions are
+        masked)."""
+        out = []
+        for g in range(group):
+            page = bt_ref[b, jnp.minimum(gi * group + g, last)]
+            dst = pl.ds(g * bs, bs)
+            out.append(pltpu.make_async_copy(
+                k_pool.at[layer, page], k_buf.at[buf, dst], sem.at[0, buf]))
+            out.append(pltpu.make_async_copy(
+                v_pool.at[layer, page], v_buf.at[buf, dst], sem.at[1, buf]))
+        return out
+
+    def own_lanes(shape, g):
+        """Lanes of a chunk that are head ``g``'s (of the chunk)."""
+        return jax.lax.broadcasted_iota(jnp.int32, shape, 1) // dh == g
+
+    def score(gi, buf):
+        filled, qlen = lens_ref[b], qlens_ref[b]
+        for c, n in chunks:
+            lanes = slice(c * cw, (c + 1) * cw)
+            qc = q_ref[0, :, lanes]                        # [tq, cw]
+            # the chunk's heads stacked along the rows, each seeing its
+            # own lanes of the query alone
+            q2 = jnp.concatenate(
+                [jnp.where(own_lanes(qc.shape, g), qc, 0)
+                 for g in range(n)], axis=0)               # [n*tq, cw]
+            s = jax.lax.dot_general(
+                q2, k_buf[buf, :, lanes], (((1,), (1,)), ((), ())),
+                precision=precision,
+                preferred_element_type=jnp.float32) * scale
+            qi = t * tq + jax.lax.broadcasted_iota(
+                jnp.int32, s.shape, 0) % tq
+            _softmax_step(_mask_scores(s, qi, gi, filled, qlen),
+                          v_buf[buf, :, lanes], m_ref, l_ref, acc_ref,
+                          at=(c, slice(0, n * tq)), precision=precision)
+
+    _page_loop(n_groups, copies, score)
+    for c, n in chunks:
+        l = l_ref[c, 0:n * tq, 0:1]
+        o2 = acc_ref[c, 0:n * tq] / jnp.where(l == 0.0, 1.0, l)
+        oc = jnp.zeros((tq, cw), jnp.float32)
+        for g in range(n):
+            oc = jnp.where(own_lanes(oc.shape, g),
+                           o2[g * tq:(g + 1) * tq], oc)
+        o_ref[0, :, c * cw:(c + 1) * cw] = oc.astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("heads", "head_dim", "tq", "interpret"))
+def _paged_rows_call(lengths, q_lens, block_tables, layer, qr, pool_k,
+                     pool_v, *, heads, head_dim, tq, interpret):
+    """``_kv_rows_kernel`` over query rows ``qr`` [B, Q, W] (``Q`` whole
+    sublane tiles) and the pool pair.  Jitted, with the layer a traced
+    scalar: a model's layers then share ONE trace of the kernel a
+    q-block and ONE lowering a program (the calls of one jitted
+    function lower to calls of one function).  With a static layer each
+    of GPT-2 XL's 48 calls was traced and lowered to Mosaic anew, 28 s a
+    program against the blocked kernel's 3.4 (sandbox, PR 31), which the
+    warm set-up of a cell pays for each of its 19 programs before the
+    compile cache can be asked."""
+    B, Q, W = qr.shape
+    bs = pool_k.shape[2]
+    cw = _lane_chunk(W, head_dim)
+    group = _page_group(block_tables.shape[1], bs, W, pool_k.dtype)
+    rows = cw // head_dim * tq
+    tile = pl.BlockSpec((1, tq, W), lambda b, t, *_: (b, t, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,
+        grid=(B, Q // tq),
+        in_specs=[tile, pl.BlockSpec(memory_space=pl.ANY),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=tile,
+        scratch_shapes=[
+            pltpu.VMEM((2, group * bs, W), pool_k.dtype),   # K pages
+            pltpu.VMEM((2, group * bs, W), pool_v.dtype),   # V pages
+            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.VMEM((W // cw, rows, _LANES), jnp.float32),  # max
+            pltpu.VMEM((W // cw, rows, _LANES), jnp.float32),  # denom
+            pltpu.VMEM((W // cw, rows, cw), jnp.float32),      # acc
+        ],
+    )
+    return pl.pallas_call(
+        functools.partial(_kv_rows_kernel, scale=head_dim ** -0.5, bs=bs,
+                          group=group, tq=tq, heads=heads, dh=head_dim,
+                          cw=cw, precision=_prec(qr.dtype)),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, Q, W), qr.dtype),
+        name="ragged_paged_mixed",
+        interpret=interpret,
+    )(lengths, q_lens, block_tables, layer, qr, pool_k, pool_v)
+
+
+def ragged_paged_attention(q, pool_k, pool_v, lengths, q_lens,
+                           block_tables, *, layer=0, k_scale=None,
+                           v_scale=None, interpret=None):
+    """The mixed wave over the BLOCK-TABLE paged pool — the serving
+    engine's production mixed-mode dispatch.
+
+    q: [B, Q, H, Dh]; pool_k, pool_v: the WHOLE pool pair
+    ``[L, N_blocks, bs, W]``, one row a position with head h in lanes
+    ``[h * Dh, (h + 1) * Dh)`` and ``W = kv_row_width(H, Dh)`` (zeros,
+    or anything finite, in the pad), of which the kernel reads layer
+    ``layer`` where it lies: a ``pool[layer]`` outside the
+    kernel is a copy of that layer's pool a call, and a row that is not
+    a whole number of lane tiles makes the compiler relay the whole
+    donated pool a wave (the comment block above).  block_tables:
+    [B, T] int32 — entry (b, j) is the pool block holding slot b's
+    positions [j*bs, (j+1)*bs); lengths / q_lens: [B] int32 as in
+    :func:`ragged_attention` (dead table entries may hold any valid
+    pool index — the engine points them at scratch block 0).  The q-
+    block's own rows are already written.  A live q-tile copies the
+    pages it can see, ``_page_group`` at a time; dead slots and dead
+    tiles copy and score nothing and return zeros; shared prefix blocks
+    are fetched per slot but stored once.  Returns o [B, Q, H, Dh] in
+    q's dtype (f32 accumulators).
+
+    An int8 pool — ``(pool_k, k_scale)`` = int8 ``[L, N_blocks, bs, H,
+    Dh]`` with f32 scales ``[L, N_blocks, bs, H]`` — keeps its shape and
+    the blocked kernel (:func:`_ragged_paged_blocked` over
+    ``pool[layer]``), chosen by the scales being there.  The int8 page
+    itself copies by hand, but Mosaic refuses the scale page ("Slice
+    shape along dimension 3 must be aligned to tiling (128), but is 25",
+    sandbox AOT for v5e, PR 31): scale planes padded to 128 lanes would
+    cost the int8 pool a quarter more bytes a token, and the path is in
+    no benchmark cell."""
+    if interpret is None:
+        interpret = _use_interpret()
+    if k_scale is not None:
+        return _ragged_paged_blocked(
+            q, pool_k[layer], pool_v[layer], lengths, q_lens,
+            block_tables, k_scale[layer], v_scale[layer], interpret)
+    B, Q, H, Dh = q.shape
+    bs, W = pool_k.shape[2:]
+    if W != kv_row_width(H, Dh):
+        raise ValueError(
+            f"ragged_paged_attention reads rows of {kv_row_width(H, Dh)} "
+            f"lanes for {H} heads of {Dh}; the pool's are {W} wide")
+    # whole sublane tiles of query rows (8 of f32, 16 of bf16): a decode
+    # wave's single row rides in one, its dead rows scored by no one
+    sub = 32 // jnp.dtype(q.dtype).itemsize
+    qr = kv_rows(q, W)
+    if Q % sub:
+        qr = jnp.pad(qr, ((0, 0), (0, -Q % sub), (0, 0)))
+    o = _paged_rows_call(
+        lengths.astype(jnp.int32), q_lens.astype(jnp.int32),
+        block_tables.astype(jnp.int32),
+        jnp.asarray(layer, jnp.int32).reshape(1), qr, pool_k, pool_v,
+        heads=H, head_dim=Dh, interpret=interpret,
+        tq=_fit_block(max(_MAX_ROWS // H, 1), qr.shape[1]))
+    return kv_heads(o[:, :Q], H, Dh)
 
 
 def ragged_masked_reference(q, k, v, lengths, q_lens=None, k_scale=None,
@@ -363,16 +659,15 @@ def ragged_paged_reference(q, pool_k, pool_v, lengths, q_lens,
 #
 # The pool stays in HBM (``memory_space=pl.ANY``) and the kernel copies
 # pages itself: the grid is (slot, q-tile) alone, and inside a step a
-# loop runs over as many groups of ``_MLA_GROUP`` pages as the tile can
+# loop runs over as many groups of ``_PAGE_GROUP`` pages as the tile can
 # SEE (scalar-prefetched lengths, q-lengths and block tables decide),
 # each group's copies started while the last group is scored (two VMEM
-# buffers).  A 16-position page is an 18 KB copy: handed to the grid as
+# buffers: ``_page_loop``, the K/V kernel's own).  A 16-position page is an 18 KB copy: handed to the grid as
 # a block it cost a step's latency each (1.42 ms a layer for 32 decode
 # slots at 1,500 positions); sixteen in flight at once cost 0.27 ms, a
 # 256-row chunk beside them 4.5 against 11.3 (my chip run, PR 28:
 # PERF.md section 6).  Dead slots and dead tiles copy and score nothing.
 
-_MLA_GROUP = 16
 # (query, head) rows one q-tile may hold.  Sized by the sandbox's AOT
 # compile for v5e (PR 28): at 20 heads x 640 a tile of 1280 rows (64
 # queries) with its f32 accumulator [1280, 512], score block, page
@@ -390,16 +685,8 @@ def _mla_q_tile(Q, H):
 def _mla_kernel(lens_ref, qlens_ref, bt_ref, q_ref, pool_ref, o_ref,
                 kv_buf, sem, m_ref, l_ref, acc_ref, *, scale, bs, group,
                 tq, heads, dv, layer):
-    b = pl.program_id(0)
-    t = pl.program_id(1)
-    span = group * bs
-    end = _visible_end(lens_ref, qlens_ref, b, t, tq)
-    live = _live_tile(qlens_ref, b, t, tq) & (end > 0)
-    n_groups = jnp.where(live, (end + span - 1) // span, 0)
-    last = jnp.maximum(end - 1, 0) // bs        # the last page in sight
-    m_ref[:] = jnp.full_like(m_ref, NEG_INF)
-    l_ref[:] = jnp.zeros_like(l_ref)
-    acc_ref[:] = jnp.zeros_like(acc_ref)
+    b, t, n_groups, last = _tile_in_sight(lens_ref, qlens_ref, tq, bs, group)
+    _reset(m_ref, l_ref, acc_ref)
 
     def copies(gi, buf):
         """Group ``gi``'s page copies into buffer ``buf``; pages past
@@ -410,53 +697,19 @@ def _mla_kernel(lens_ref, qlens_ref, bt_ref, q_ref, pool_ref, o_ref,
             kv_buf.at[buf, pl.ds(g * bs, bs)], sem.at[buf])
             for g in range(group)]
 
-    @pl.when(n_groups > 0)
-    def _first():
-        for c in copies(0, 0):
-            c.start()
-
-    def score(gi, carry):
-        buf = gi % 2
-
-        @pl.when(gi + 1 < n_groups)
-        def _next():
-            for c in copies(gi + 1, 1 - buf):
-                c.start()
-
-        for c in copies(gi, buf):
-            c.wait()
+    def score(gi, buf):
         q = q_ref[0]                                      # [R, W]
         kv = kv_buf[buf]                                  # [span, W]
-        R = q.shape[0]
         s = jax.lax.dot_general(
             q, kv, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale   # [R, span]
-        kv_pos = gi * span + jax.lax.broadcasted_iota(
-            jnp.int32, (R, span), 1)
-        # row r of the tile is query t*tq + r // heads; dead rows clip to
-        # the last live position (as ``_query_positions``)
+        # row r of the tile is query t*tq + r // heads
         qi = t * tq + jax.lax.broadcasted_iota(
-            jnp.int32, (R, span), 0) // heads
-        filled = lens_ref[b]
-        posq = jnp.minimum(filled - qlens_ref[b] + qi, filled - 1)
-        s = jnp.where(kv_pos <= posq, s, NEG_INF)
-        m_prev = m_ref[:, 0:1]
-        l_prev = l_ref[:, 0:1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        safe_m = jnp.where(m_new <= NEG_INF / 2, 0.0, m_new)
-        p = jnp.where(s <= NEG_INF / 2, 0.0, jnp.exp(s - safe_m))
-        alpha = jnp.exp(jnp.clip(m_prev - m_new, max=0.0))
-        alpha = jnp.where(m_prev <= NEG_INF / 2, 0.0, alpha)
-        l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        pv = jax.lax.dot_general(
-            p.astype(kv.dtype), kv[:, :dv], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)           # [R, dv]
-        acc_ref[:] = acc_ref[:] * alpha + pv
-        m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
-        l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
-        return carry
+            jnp.int32, s.shape, 0) // heads
+        _softmax_step(_mask_scores(s, qi, gi, lens_ref[b], qlens_ref[b]),
+                      kv[:, :dv], m_ref, l_ref, acc_ref)
 
-    jax.lax.fori_loop(0, n_groups, score, 0)
+    _page_loop(n_groups, copies, score)
     l = l_ref[:, 0:1]
     o_ref[0] = (acc_ref[:] / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
 
@@ -479,7 +732,7 @@ def ragged_paged_mla(q, pool, lengths, q_lens, block_tables, *,
     slot with lengths 0 returns zeros."""
     B, Q, H, W = q.shape
     bs = pool.shape[2]
-    group = min(_MLA_GROUP, block_tables.shape[1])
+    group = min(_PAGE_GROUP, block_tables.shape[1])
     tq = _mla_q_tile(Q, H)
     if interpret is None:
         interpret = _use_interpret()

@@ -40,6 +40,7 @@ import jax
 import jax.numpy as jnp
 
 from .. import envvars
+from ..kv_layout import kv_heads, kv_rows
 from ..quant import kv_decode, kv_encode
 
 NEG_INF = -1e30
@@ -55,6 +56,14 @@ NEG_INF = -1e30
 # reads either dequantize (reference/masked paths) or hand the raw
 # payload + scales to the int8 decode kernels, which dequantize inside
 # the online-softmax loop.
+#
+# The PAGED pool of a float dtype holds ROWS, ``[L, N_blocks, block, W]``
+# (``kv_layout``: heads side by side, padded to the lane tile, the
+# layout the mixed ragged kernel reads in place): writes go through
+# ``_kv_scatter``, which lays a ``[.., H, Dh]`` slab out as rows, and
+# every reader but that kernel takes ``_kv_layer``'s ``[.., H, Dh]`` view.
+# The contiguous cache ``[L, B, S_max, H, Dh]`` and the int8 pool keep
+# their head axes.
 
 
 def _kv_q(cache):
@@ -81,7 +90,57 @@ def _kv_scatter(cache, idx, val):
         data, sc = cache
         q, s = kv_encode(val)
         return (data.at[idx].set(q), sc.at[idx].set(s))
+    if cache.ndim == 4:                # the paged pool's rows
+        val = kv_rows(val, cache.shape[-1])
     return cache.at[idx].set(val.astype(cache.dtype))
+
+
+@jax.jit
+def _kv_write_pages(cache, i, val, pos, q_len, block_tables):
+    """The paged float pool's write of a WIDE q-block, a page at a time:
+    slot b's ``val[b, :q_len[b]]`` ([B, Q, H, Dh]) lands at positions
+    ``pos[b] ..`` of layer ``i``, as ``_kv_scatter`` at ``(i, wblk,
+    woff)`` leaves it, but each of the ``ceil(Q / block) + 1`` pages a
+    q-block can touch is read, overlaid with the rows that fall in it
+    and written back WHOLE; pages with no live row are dropped (an
+    out-of-bounds index), so dead rows go nowhere instead of to scratch
+    block 0.  Why: a position is one row of a (16, 128) tile, and the
+    TPU runs a scatter an update at a time whatever it writes, dead
+    rows too: 16 x 256 rows cost 0.70 ms a pool a layer as rows, 0.12
+    as 272 pages (64 rows: 0.18 | 0.05; ONE row: 0.023 | 0.033, so a
+    narrow q-block keeps the row scatter; my chip run, PR 31).  Jitted
+    with ``i`` traced, so a model's layers share one trace and one
+    lowering a program (as ``ragged_attention._paged_rows_call``)."""
+    B, Q = val.shape[:2]
+    N, bs, W = cache.shape[1:]
+    K = -(-Q // bs) + 1
+    rows = kv_rows(val, W).astype(cache.dtype)
+    # shifted[b, m] = rows[b, m - pos[b] % bs]: the q-block laid over
+    # whole pages from the one its first row falls in
+    start = pos % bs
+    padded = jnp.pad(rows, ((0, 0), (bs, K * bs - Q), (0, 0)))
+    shifted = jax.vmap(
+        lambda x, at: jax.lax.dynamic_slice_in_dim(x, at, K * bs, 0))(
+            padded, bs - start)
+    j = jnp.arange(K * bs)[None, :] - start[:, None]       # q-block row
+    live = ((j >= 0) & (j < q_len[:, None])).reshape(B, K, bs)
+    page = pos[:, None] // bs + jnp.arange(K)[None, :]
+    blk = block_tables[jnp.arange(B)[:, None],
+                       jnp.clip(page, 0, block_tables.shape[1] - 1)]
+    new = jnp.where(live[..., None], shifted.reshape(B, K, bs, W),
+                    cache[i, blk])
+    return cache.at[i, jnp.where(live.any(-1), blk, N)].set(new)
+
+
+def _kv_layer(cache, i, H, Dh):
+    """Layer ``i`` of a cache as ``(payload [.., H, Dh], scales or
+    None)``: a contiguous cache's ``[B, S_max, H, Dh]``, or the paged
+    pool's ``[N_blocks, block, H, Dh]`` (the first ``H * Dh`` columns of
+    its rows), or the int8 pair's payload and ``[.., H]`` scales."""
+    if _kv_q(cache):
+        return cache[0][i], cache[1][i]
+    layer = cache[i]
+    return (kv_heads(layer, H, Dh) if layer.ndim == 3 else layer), None
 
 
 def _kv_dus(cache, val, i, pos):
@@ -108,7 +167,7 @@ def _kv_gather_row(cache, i, table_row, span, H, Dh):
         g = data[i][table_row].reshape(span, H, Dh)
         s = sc[i][table_row].reshape(span, H)
         return g.astype(jnp.float32) * s[..., None]
-    return cache[i][table_row].reshape(span, H, Dh)
+    return kv_heads(cache[i][table_row], H, Dh).reshape(span, H, Dh)
 
 
 def _kv_slot_slice(cache, slot, sizes):
@@ -455,8 +514,9 @@ def _decode_step(params, cfg_tuple, cache_k, cache_v, pos, token,
 
     ``block_tables`` (traced [B, T] int32, serving only) switches the
     CACHE LAYOUT to block-table paged: ``cache_k``/``cache_v`` are the
-    shared ``[L, N_blocks, bs, H, Dh]`` pool, this position's k/v
-    scatters into block ``block_tables[b, pos[b]//bs]`` at offset
+    shared pool (``[L, N_blocks, bs, W]`` rows, seen here as ``[..,
+    H, Dh]`` through ``_kv_layer``), this position's k/v scatters into
+    block ``block_tables[b, pos[b]//bs]`` at offset
     ``pos[b] % bs``, and attention reads each slot's blocks through its
     table ("masked" gathers + masks, "ragged" is the block-table
     kernel).  ``live_mask`` ([B] bool) redirects inert slots' ride-along
@@ -524,12 +584,8 @@ def _decode_step(params, cfg_tuple, cache_k, cache_v, pos, token,
         else:
             cache_k = _kv_dus(cache_k, k, i, pos)
             cache_v = _kv_dus(cache_v, v, i, pos)
-        if _kv_q(cache_k):                 # layer views: payload+scales
-            ks, ksc = cache_k[0][i], cache_k[1][i]
-            vs, vsc = cache_v[0][i], cache_v[1][i]
-        else:
-            ks, vs = cache_k[i], cache_v[i]   # [B,S,H,Dh] | [N,bs,H,Dh]
-            ksc = vsc = None
+        ks, ksc = _kv_layer(cache_k, i, H, Dh)  # [B,S,H,Dh] | [N,bs,H,Dh]
+        vs, vsc = _kv_layer(cache_v, i, H, Dh)
         if paged and attn == "ragged":
             o = paged_block_decode_attention(
                 q, ks, vs, lens, block_tables, k_scale=ksc,
@@ -1048,12 +1104,8 @@ def _verify_step(params, cfg_tuple, cache_k, cache_v, pos, tokens,
                 pw = jnp.minimum(posns[:, jq], S_max - 1)
                 cache_k = _kv_scatter(cache_k, (i, bidx, pw), k[:, jq])
                 cache_v = _kv_scatter(cache_v, (i, bidx, pw), v[:, jq])
-        if _kv_q(cache_k):
-            ks, ksc = cache_k[0][i], cache_k[1][i]
-            vs, vsc = cache_v[0][i], cache_v[1][i]
-        else:
-            ks, vs = cache_k[i], cache_v[i]
-            ksc = vsc = None
+        ks, ksc = _kv_layer(cache_k, i, H, Dh)
+        vs, vsc = _kv_layer(cache_v, i, H, Dh)
         if paged and attn == "ragged":
             o = paged_block_verify_attention(
                 q, ks, vs, lens, q_len, block_tables, k_scale=ksc,
@@ -1462,7 +1514,9 @@ def _mixed_step(params, cfg_tuple, cache_k, cache_v, pos, tokens,
     is computed as well and selected for the slots ``self_fresh`` [B]
     marks.  The ragged path hands the whole wave to the mixed-mode
     kernel, which reads everything back from the pool (the fast path's
-    existing round-trip semantics).
+    existing round-trip semantics): over the paged pool it takes the
+    pool pair WHOLE, rows ``[L, N_blocks, block, W]`` where they lie,
+    with ``layer=i`` an index in its page copies (no ``cache_k[i]``).
 
     The device trace finds the wave's parts under a handful of
     ``jax.named_scope`` names, the same for every layer: ``embed``,
@@ -1536,7 +1590,13 @@ def _mixed_step(params, cfg_tuple, cache_k, cache_v, pos, tokens,
             v = (x @ params[f"{us}_attn_v_weight"]
                  + params[f"{us}_attn_v_bias"]).reshape(B, Q, H, Dh)
         with jax.named_scope("kv_write"):
-            if paged:
+            if paged and not quant and Q >= bs_blk:
+                # a q-block a page or more wide: whole pages
+                cache_k = _kv_write_pages(cache_k, i, k, pos, q_len,
+                                          block_tables)
+                cache_v = _kv_write_pages(cache_v, i, v, pos, q_len,
+                                          block_tables)
+            elif paged:
                 cache_k = _kv_scatter(cache_k, (i, wblk, woff), k)
                 cache_v = _kv_scatter(cache_v, (i, wblk, woff), v)
             else:
@@ -1549,20 +1609,23 @@ def _mixed_step(params, cfg_tuple, cache_k, cache_v, pos, tokens,
                     cache_v = _kv_scatter(cache_v, (i, bidx, pw),
                                           v[:, jq])
         with jax.named_scope("attention"):
-            if quant:
-                ks, ksc = cache_k[0][i], cache_k[1][i]
-                vs, vsc = cache_v[0][i], cache_v[1][i]
-            else:
-                ks, vs = cache_k[i], cache_v[i]
-                ksc = vsc = None
             if paged and attn == "ragged":
+                # the pool pair whole, the layer an index in the page
+                # copy: no ``cache_k[i]`` is materialised (an int8 pair
+                # hands its scale planes over beside its payload)
+                pk, ksc = cache_k if quant else (cache_k, None)
+                pv, vsc = cache_v if quant else (cache_v, None)
                 o = ragged_paged_attention(
-                    q, ks, vs, lens, q_len, block_tables, k_scale=ksc,
-                    v_scale=vsc).reshape(B, Q, hdim)
+                    q, pk, pv, lens, q_len, block_tables, layer=i,
+                    k_scale=ksc, v_scale=vsc).reshape(B, Q, hdim)
             elif attn == "ragged":
+                ks, ksc = _kv_layer(cache_k, i, H, Dh)
+                vs, vsc = _kv_layer(cache_v, i, H, Dh)
                 o = ragged_attention(q, ks, vs, lens, q_len, k_scale=ksc,
                                      v_scale=vsc).reshape(B, Q, hdim)
             else:
+                ks, ksc = _kv_layer(cache_k, i, H, Dh)
+                vs, vsc = _kv_layer(cache_v, i, H, Dh)
                 if paged:
                     kg = ks[block_tables].reshape(B, span, H, Dh)
                     vg = vs[block_tables].reshape(B, span, H, Dh)

@@ -9,7 +9,8 @@ PER SLOT.  It remains the off-TPU reference (and the layout offline
 ``generate_fast`` uses).
 
 ``PagedKVManager`` is the production layout: a fixed pool of
-``[L, N_blocks, block, H, Dh]`` KV blocks with a free list, a
+``[L, N_blocks, block, W]`` KV blocks (one lane-dense row a position,
+``kv_layout``) with a free list, a
 per-request BLOCK TABLE mapping sequence positions to pool blocks, and
 refcounted copy-on-write prefix sharing keyed by a prompt-prefix hash —
 N requests with the same system prompt reference its KV blocks once.
@@ -30,6 +31,7 @@ import jax
 import jax.numpy as jnp
 
 from .. import envvars, quant, telemetry
+from ..kv_layout import kv_heads, kv_row_width, kv_rows
 
 
 def round_up_pow2(n, floor=1):
@@ -415,12 +417,26 @@ class _PrefixEntry:
 class PagedKVManager:
     """Block-pool allocator with per-request block tables.
 
-    The cache pair is ``[L, N_blocks, block, H, Dh]``; a request holds
-    ``ceil(tokens / block)`` blocks listed in its slot's block-table
-    row, so pool bytes bound the TOKENS held, not slots * S_max.  Block
-    id 0 is a permanent scratch block: dead table entries point at it
-    and inert slots' ride-along decode writes land in it, so nothing a
-    mask admits is ever clobbered.
+    The cache pair is ``[L, N_blocks, block, W]``: one ROW a position,
+    head h in lanes ``[h * Dh, (h + 1) * Dh)``, ``W = kv_row_width(H,
+    Dh)`` the next multiple of the 128 lanes (GPT-2 XL 1600 -> 1664,
+    zeros in the pad; 768 and 1024 need none).  That is the layout the
+    mixed ragged kernel reads in place: a row of whole lane tiles keeps
+    the pool's default layout row-major, so the donated pool is neither
+    copied to the kernel's layout and back every wave nor sliced a
+    layer at a time (``[.., 25, 64]`` was: ledger, PR 30, ``copy`` 1.995
+    s and ``slice_bitcast_fusion`` 0.912 s of a 6 s trace), and a page
+    is one contiguous copy.  ``kv_layout.kv_heads`` is the ``[.., H,
+    Dh]`` view every other reader takes, and the WIRE (export / import,
+    tiers) stays ``[L, n, block, H, Dh]`` with the pad stripped.  The
+    int8 pool keeps ``[L, N_blocks, block, H, Dh]`` int8 with ``[..,
+    H]`` f32 scales (its scale page cannot be copied by hand: see
+    ``ragged_paged_attention``).  A request holds ``ceil(tokens /
+    block)`` blocks listed in its slot's block-table row, so pool bytes
+    bound the TOKENS held, not slots * S_max.  Block id 0 is a
+    permanent scratch block: dead table entries point at it and inert
+    slots' ride-along decode writes land in it, so nothing a mask
+    admits is ever clobbered.
 
     Admission RESERVES the request's whole span (prompt +
     max_new_tokens, minus shared prefix blocks) up front, so decode
@@ -489,7 +505,10 @@ class PagedKVManager:
             self.cache_k = _alloc_cache(shape, dtype, None)
             self.cache_v = None
         else:
-            shape = (layers, self.n_blocks, self.block, heads, head_dim)
+            self.heads, self.head_dim = int(heads), int(head_dim)
+            row = ((heads, head_dim) if self.quant
+                   else (kv_row_width(heads, head_dim),))
+            shape = (layers, self.n_blocks, self.block) + row
             self.cache_k = _alloc_cache(shape, dtype, self.quant)
             self.cache_v = _alloc_cache(shape, dtype, self.quant)
         self._free = list(range(1, self.n_blocks))   # 0 = scratch
@@ -823,8 +842,9 @@ class PagedKVManager:
         """Serialize ``slot``'s FILLED blocks to a host-side payload a
         peer replica can :meth:`import_blocks`.  Ships exactly
         ``blocks_needed(length)`` blocks (the filled span, not the
-        whole reservation), as ``[L, n, block, H, Dh]`` host arrays —
-        or the (int8, scales) pair when the pool is quantized or the
+        whole reservation), as ``[L, n, block, H, Dh]`` host arrays
+        (the pool's rows seen through ``kv_heads``, pad stripped) — or
+        the (int8, scales) pair when the pool is quantized or the
         wire mode forces int8 (:func:`resolve_handoff_quant`), ~4x
         fewer bytes with scale planes moving in lockstep.  A pure
         read: refcounts, tables, and the prefix cache are untouched,
@@ -872,7 +892,9 @@ class PagedKVManager:
         def gather(cache):
             if isinstance(cache, (tuple, list)):
                 return tuple(np.asarray(a[:, idx]) for a in cache)
-            return np.asarray(cache[:, idx])
+            # the wire is [L, n, block, H, Dh]: the rows' pad stays here
+            return kv_heads(np.asarray(cache[:, idx]), self.heads,
+                            self.head_dim)
 
         k, kq = _wire_repr(gather(self.cache_k), self.quant, mode)
         v, _ = _wire_repr(gather(self.cache_v), self.quant, mode)
@@ -939,7 +961,8 @@ class PagedKVManager:
                 cache = (cache[0].at[:, dst].set(vals[0]),
                          cache[1].at[:, dst].set(vals[1]))
             else:
-                cache = cache.at[:, dst].set(vals)
+                cache = cache.at[:, dst].set(
+                    kv_rows(vals, cache.shape[-1]))
             setattr(self, name, cache)
         self.tables[slot, :] = 0
         self.tables[slot, :len(row)] = row

@@ -24,6 +24,7 @@ from hetu_tpu.kernels import decode_attention as da
 from hetu_tpu.kernels import flash_attention as fa
 from hetu_tpu.kernels.ragged_attention import (
     ragged_attention, ragged_paged_attention, ragged_paged_mla)
+from hetu_tpu.kv_layout import kv_row_width
 from hetu_tpu.models import gpt_decode as gd
 
 DH, S_MAX, BLOCK, SLOTS = 64, 1024, 16, 8
@@ -113,12 +114,27 @@ def test_flash_op_under_a_mesh(topo, monkeypatch):
 # serving path: the mixed-mode ragged kernel (the TPU default)
 # ------------------------------------------------------------------- #
 
-def _pool(sds, heads, quant, dtype):
+def _layer_pool(sds, heads):
+    """ONE layer of a pool with its head axes, ``[N, bs, H, Dh]`` bf16:
+    what the phase-split block kernels take."""
+    T = S_MAX // BLOCK
+    return (sds((SLOTS * T + 1, BLOCK, heads, DH), jnp.bfloat16),
+            sds((SLOTS, T), jnp.int32))
+
+
+def _pool(sds, heads, quant, layers=2):
+    """The whole pool as the engine holds it: bf16 rows ``[L, N, bs,
+    W]`` (``kv_layout``), or the int8 pair's ``[L, N, bs, H, Dh]`` with
+    its scale planes."""
     T = S_MAX // BLOCK
     N = SLOTS * T + 1
-    pdt = jnp.int8 if quant else dtype
-    pool = sds((N, BLOCK, heads, DH), pdt)
-    scales = (sds((N, BLOCK, heads), jnp.float32),) * 2 if quant else ()
+    if quant:
+        pool = sds((layers, N, BLOCK, heads, DH), jnp.int8)
+        scales = (sds((layers, N, BLOCK, heads), jnp.float32),) * 2
+    else:
+        pool = sds((layers, N, BLOCK, kv_row_width(heads, DH)),
+                   jnp.bfloat16)
+        scales = ()
     return pool, sds((SLOTS, T), jnp.int32), scales
 
 
@@ -130,18 +146,110 @@ def _pool(sds, heads, quant, dtype):
 def test_ragged_paged(sds, heads, q_len, kv):
     """Q=1024 at H=12 and Q=512 at H=25 were refused before ISSUE 22
     ("Scoped allocation with size 18.11M and limit 16.00M"): the q-block
-    was one VMEM tile that grew with the prompt."""
+    was one VMEM tile that grew with the prompt.  bf16: the pool's rows
+    read in place; int8: the blocked kernel over ``pool[layer]``."""
     quant = kv == "int8"
-    pool, tables, scales = _pool(sds, heads, quant, jnp.bfloat16)
+    pool, tables, scales = _pool(sds, heads, quant)
     lens = sds((SLOTS,), jnp.int32)
 
     def fn(q, pk, pv, lengths, q_lens, bt, *sc):
         kw = dict(k_scale=sc[0], v_scale=sc[1]) if sc else {}
         return ragged_paged_attention(q, pk, pv, lengths, q_lens, bt,
-                                      interpret=False, **kw)
+                                      layer=1, interpret=False, **kw)
 
-    compiled_text(fn, sds((SLOTS, q_len, heads, DH), jnp.bfloat16),
-                  pool, pool, lens, lens, tables, *scales)
+    text = compiled_text(fn, sds((SLOTS, q_len, heads, DH), jnp.bfloat16),
+                         pool, pool, lens, lens, tables, *scales)
+    assert "ragged_paged_mixed" in text
+
+
+# the gpt2-xl cell's own sizes (benchmarks/configs/gpt2-xl.json): 48
+# layers, 449 blocks of 16, rows of 1664 lanes, 16 slots, a table 64 wide
+CELL = dict(layers=48, blocks=449, slots=16, table=64)
+
+
+@pytest.mark.parametrize("heads,q_len", [
+    (25, 1), (25, 64), (25, 256), (25, 512),
+    (12, 1), (12, 256), (16, 1), (16, 256),
+], ids=lambda v: str(v))
+def test_ragged_paged_rows_at_the_cell_sizes(sds, heads, q_len):
+    """ISSUE 31: the pool pair left in HBM, pages copied by hand with
+    the layer in the copy.  At 25 heads a page is 16 x 1664 x 2 B = 53
+    KB each for K and V; 16 pages a group and two buffers are 3.4 MB of
+    VMEM beside a q-tile of at most 2048 (head, query) rows."""
+    from hetu_tpu.kernels import ragged_attention as ra
+    B, T = CELL["slots"], CELL["table"]
+    W = kv_row_width(heads, DH)
+    assert W % 128 == 0 and ra._lane_chunk(W, DH) == 128
+    assert ra._page_group(T, BLOCK, W, jnp.bfloat16) == 16
+    pool = sds((CELL["layers"], CELL["blocks"], BLOCK, W), jnp.bfloat16)
+    lens = sds((B,), jnp.int32)
+
+    def fn(q, pk, pv, lengths, q_lens, bt):
+        return ragged_paged_attention(q, pk, pv, lengths, q_lens, bt,
+                                      layer=47, interpret=False)
+
+    text = compiled_text(fn, sds((B, q_len, heads, DH), jnp.bfloat16),
+                         pool, pool, lens, lens, sds((B, T), jnp.int32))
+    assert "ragged_paged_mixed" in text
+
+
+def _gpt_shapes(sds, name, layers, hidden, vocab, positions):
+    """The parameter dict of a GPT-2 of these sizes, as bf16 shapes."""
+    def w(*shape):
+        return sds(shape, jnp.bfloat16)
+    p = {f"{name}_wte_table": w(vocab, hidden),
+         f"{name}_wpe": w(positions, hidden),
+         f"{name}_ln_f_scale": w(hidden), f"{name}_ln_f_bias": w(hidden)}
+    for i in range(layers):
+        us = f"{name}_h{i}"
+        for leaf, (a, b) in [("attn_q", (1, 1)), ("attn_k", (1, 1)),
+                             ("attn_v", (1, 1)), ("attn_proj", (1, 1)),
+                             ("ffn_wi", (1, 4)), ("ffn_wo", (4, 1))]:
+            p[f"{us}_{leaf}_weight"] = w(a * hidden, b * hidden)
+            p[f"{us}_{leaf}_bias"] = w(b * hidden)
+        for ln in ("ln1", "ln2"):
+            p[f"{us}_{ln}_scale"] = w(hidden)
+            p[f"{us}_{ln}_bias"] = w(hidden)
+    return p
+
+
+@pytest.mark.parametrize("q_len", [1, 256], ids=["Q1", "Q256"])
+def test_mixed_step_reads_the_donated_pool_in_place(sds, monkeypatch,
+                                                     q_len):
+    """The test that keeps the copy from coming back.  Eight layers of
+    the gpt2-xl cell's mixed step (25 heads, 16 slots, pool 449 x 16,
+    the pair donated) through ``serve_mixed_paged_fn``: with the pool
+    ``[.., 25, 64]`` the compiler's temporaries were 3.4 x the pool
+    whatever Q and the slots were, the donated pool relaid for the
+    kernel and back (ledger, PR 30: ``copy`` 1.995 s of a 6 s trace).
+    Rows of whole lane tiles are aliased in place: what is left does
+    not grow with the pool (a small vocabulary here, so that the head's
+    transposed table, 0.16 GB at 50257, is not what is measured)."""
+    from hetu_tpu.kernels import ragged_attention as ra
+    # the step asks the backend, which is the CPU here: steer it in the
+    # test, or the kernel is interpreted and nothing is proven
+    monkeypatch.setattr(ra, "_use_interpret", lambda: False)
+    L, H, B = 8, 25, CELL["slots"]
+    W = kv_row_width(H, DH)
+    pool = sds((L, CELL["blocks"], BLOCK, W), jnp.bfloat16)
+    i32 = lambda *shape: sds(shape, jnp.int32)  # noqa: E731
+    fn = gd.serve_mixed_paged_fn(donate=True, attn="ragged", window=1)
+    compiled = fn.func.lower(
+        _gpt_shapes(sds, "gpt", L, H * DH, 512, 1024),
+        ("gpt", L, H, DH, 1024), pool, pool, i32(B, CELL["table"]),
+        i32(B), i32(B, q_len), i32(B), i32(B), sds((B,), jnp.bool_),
+        sds((B,), jnp.float32), i32(B), sds((B, 2), jnp.uint32),
+        attn="ragged", window=1, has_fresh=False).compile()
+    calls = [line for line in compiled.as_text().splitlines()
+             if "custom-call(" in line and "ragged_paged_mixed" in line]
+    assert len(calls) == L and all("tpu_custom_call" in c for c in calls)
+    # each call takes the WHOLE pool pair, no layer sliced out of it
+    assert all(c.count(f"bf16[{L},{CELL['blocks']},{BLOCK},{W}]") >= 2
+               for c in calls)
+    mem = compiled.memory_analysis()
+    pool_bytes = 2 * L * CELL["blocks"] * BLOCK * W * 2
+    assert mem.alias_size_in_bytes >= pool_bytes      # donated, aliased
+    assert mem.temp_size_in_bytes < pool_bytes // 2
 
 
 def test_ragged_contiguous_whole_prompt(sds):
@@ -194,7 +302,7 @@ def test_phase_split(sds, kernel):
     verify = "verify" in kernel
     q = sds((SLOTS, 8, H, DH) if verify else (SLOTS, H, DH), jnp.bfloat16)
     if "block" in kernel:
-        pool, tables, _ = _pool(sds, H, False, jnp.bfloat16)
+        pool, tables = _layer_pool(sds, H)
         kv, tail = (pool, pool), (tables,)
     else:
         c = sds((SLOTS, S_MAX, H, DH), jnp.bfloat16)

@@ -29,6 +29,7 @@ import pytest
 import hetu_tpu as ht  # noqa: F401  (platform forcing + compat shims)
 import jax.numpy as jnp
 from hetu_tpu import quant, telemetry
+from hetu_tpu.kv_layout import kv_heads
 from hetu_tpu.models import GPTConfig
 from hetu_tpu.models.gpt_decode import generate_fast
 from hetu_tpu.ps import faults
@@ -128,7 +129,10 @@ def _fill(m, seed=0):
 
 
 def _span_f32(m, slot):
-    """The slot's filled span as dequantized f32 host arrays."""
+    """The slot's filled span as dequantized f32 host arrays
+    ``[L, n, block, H, Dh]``: a float pool's rows seen through
+    ``kv_heads`` (``_fill`` leaves garbage in the pad columns, which the
+    wire strips and an importing pool zeroes)."""
     n = m.blocks_needed(int(m.lengths[slot]))
     idx = [int(b) for b in m.tables[slot, :n]]
 
@@ -137,7 +141,7 @@ def _span_f32(m, slot):
             return np.asarray(quant.kv_decode(
                 jnp.asarray(np.asarray(cache[0])[:, idx]),
                 jnp.asarray(np.asarray(cache[1])[:, idx])))
-        return np.asarray(cache)[:, idx]
+        return kv_heads(np.asarray(cache)[:, idx], m.heads, m.head_dim)
 
     return one(m.cache_k), one(m.cache_v)
 

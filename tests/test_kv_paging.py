@@ -166,6 +166,125 @@ class TestPagedAllocator:
 
 
 @pytest.mark.smoke
+class TestPoolRows:
+    """ISSUE 31: the float pool pair is ``[L, N, block, W]`` rows of
+    whole lane tiles; COW forks, the wire and the tiers move a
+    request's K/V exactly, the pad columns carrying nothing."""
+
+    WIDTHS = [(12, 64, 768), (16, 64, 1024), (25, 64, 1664), (2, 8, 128)]
+
+    @staticmethod
+    def _filled(heads, head_dim, seed, **kw):
+        """A manager whose pool holds values EVERYWHERE, pad columns and
+        scratch block included."""
+        m = _mgr(layers=2, heads=heads, head_dim=head_dim,
+                 prefix_share=True, pool_blocks=9, **kw)
+        rng = np.random.RandomState(seed)
+        m.cache_k = jnp.asarray(
+            rng.randn(*m.cache_k.shape).astype(np.float32))
+        m.cache_v = jnp.asarray(
+            rng.randn(*m.cache_v.shape).astype(np.float32))
+        return m
+
+    @staticmethod
+    def _span(m, slot):
+        from hetu_tpu.kv_layout import kv_heads
+        n = m.blocks_needed(int(m.lengths[slot]))
+        idx = [int(b) for b in m.tables[slot, :n]]
+        return tuple(kv_heads(np.asarray(c)[:, idx], m.heads, m.head_dim)
+                     for c in (m.cache_k, m.cache_v))
+
+    @pytest.mark.parametrize("heads,head_dim,width", WIDTHS,
+                             ids=lambda v: str(v))
+    def test_pool_shape_follows_heads_and_head_width(self, heads,
+                                                     head_dim, width):
+        m = _mgr(layers=2, heads=heads, head_dim=head_dim)
+        assert m.cache_k.shape == m.cache_v.shape == (
+            2, m.n_blocks, m.block, width)
+        # the int8 pool keeps its head axes and per-(position, head) scales
+        q = _mgr(layers=2, heads=heads, head_dim=head_dim, dtype="int8")
+        assert q.cache_k[0].shape == (2, q.n_blocks, q.block, heads,
+                                      head_dim)
+        assert q.cache_k[1].shape == (2, q.n_blocks, q.block, heads)
+
+    @pytest.mark.parametrize("heads,head_dim,width", WIDTHS,
+                             ids=lambda v: str(v))
+    def test_export_import_round_trips_exactly(self, heads, head_dim,
+                                               width):
+        src = self._filled(heads, head_dim, 1)
+        dst = self._filled(heads, head_dim, 2)
+        prompt = list(range(1, 20))                       # 3 blocks of 8
+        slot, _ = src.alloc("r", prompt, len(prompt))
+        src.advance(slot, len(prompt))
+        pay = src.export_blocks(slot)
+        # the wire is heads, pad stripped
+        assert pay["k"].shape == (2, 3, 8, heads, head_dim)
+        slot2 = dst.import_blocks(pay, "r")
+        for a, b in zip(self._span(src, slot), self._span(dst, slot2)):
+            np.testing.assert_array_equal(a, b)
+        # what was imported has zeros in its pad columns
+        idx = [int(b) for b in dst.tables[slot2, :3]]
+        pad = np.asarray(dst.cache_k)[:, idx][..., heads * head_dim:]
+        assert not pad.any()
+
+    @pytest.mark.parametrize("heads,head_dim,width", WIDTHS,
+                             ids=lambda v: str(v))
+    def test_cow_fork_copies_the_rows_exactly(self, heads, head_dim,
+                                              width):
+        m = self._filled(heads, head_dim, 3)
+        s0, _ = m.alloc("a", list(range(1, 13)), 12)      # 8 + 4 of 8
+        m.advance(s0, 12)
+        m.register_prefix(list(range(1, 13)), s0)
+        s1, cached = m.alloc("b", list(range(1, 13)) + [50], 16)
+        assert cached == 12 and m.cow_copies == 1
+        assert m.tables[s1, 0] == m.tables[s0, 0]         # shared
+        src, dst = int(m.tables[s0, 1]), int(m.tables[s1, 1])
+        assert src != dst                                 # forked
+        for c in (m.cache_k, m.cache_v):
+            np.testing.assert_array_equal(np.asarray(c[:, dst]),
+                                          np.asarray(c[:, src]))
+
+
+@pytest.mark.smoke
+class TestPageWrite:
+    """``_kv_write_pages`` (a wide q-block written a page at a time)
+    leaves every block but scratch block 0 exactly as the row scatter
+    does."""
+
+    @pytest.mark.parametrize("Q,pos,q_len", [
+        (16, (0, 5, 16, 3), (16, 1, 7, 0)),      # aligned chunk, decode
+        (16, (13, 8, 31, 0), (16, 16, 1, 16)),   # straddling pages
+        (32, (0, 40, 7, 24), (32, 24, 32, 0)),   # two pages and more
+        (8, (0, 9, 16, 2), (8, 3, 0, 8)),        # a q-block of one page
+        (64, (0, 0, 0, 0), (64, 1, 64, 33)),     # to the table's width
+    ], ids=["aligned", "straddle", "wide", "one-page", "full"])
+    def test_same_pool_as_the_row_scatter(self, Q, pos, q_len):
+        from hetu_tpu.models.gpt_decode import (_kv_scatter,
+                                                _kv_write_pages)
+        B, T, bs, H, Dh, L = 4, 8, 8, 3, 8, 2
+        rng = np.random.RandomState(Q)
+        m = _mgr(layers=L, heads=H, head_dim=Dh, slots=B,
+                 max_seq_len=T * bs, block=bs)
+        pool = jnp.asarray(rng.randn(*m.cache_k.shape).astype(np.float32))
+        tables = jnp.asarray(
+            1 + rng.permutation(m.n_blocks - 1)[:B * T].reshape(B, T))
+        val = jnp.asarray(rng.randn(B, Q, H, Dh).astype(np.float32))
+        pos, q_len = jnp.asarray(pos), jnp.asarray(q_len)
+        posns = jnp.clip(pos[:, None] + jnp.arange(Q)[None, :], 0,
+                         T * bs - 1)
+        valid = jnp.arange(Q)[None, :] < q_len[:, None]
+        wblk = jnp.where(
+            valid, tables[jnp.arange(B)[:, None], posns // bs], 0)
+        want = _kv_scatter(pool, (1, wblk, posns % bs), val)
+        got = _kv_write_pages(pool, 1, val, pos, q_len, tables)
+        np.testing.assert_array_equal(np.asarray(got[:, 1:]),
+                                      np.asarray(want[:, 1:]))
+        # dead rows go nowhere: scratch block 0 is as it was
+        np.testing.assert_array_equal(np.asarray(got[:, 0]),
+                                      np.asarray(pool[:, 0]))
+
+
+@pytest.mark.smoke
 class TestBucketPromptPosCap:
     def test_bucket_clamped_to_pos_cap(self):
         """Regression: pow2 bucketing must never pad a prompt past the

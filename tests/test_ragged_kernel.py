@@ -35,6 +35,7 @@ from hetu_tpu.kernels.ragged_attention import (
     ragged_attention, ragged_masked_reference, ragged_paged_attention,
     ragged_paged_reference,
 )
+from hetu_tpu.kv_layout import kv_heads, kv_row_width, kv_rows
 from hetu_tpu.models import GPTConfig
 from hetu_tpu.models.gpt_decode import resolve_serve_ragged
 from hetu_tpu.serving import Request, ServingEngine
@@ -72,6 +73,21 @@ def _to_pool(k, v, bs=16, seed=1):
     return pk, pv, tables
 
 
+def _paged(q, pk, pv, lens, ql, tables, **kw):
+    """``ragged_paged_attention`` over ONE layer's ``[N, bs, H, Dh]``
+    pools as the engine holds them: a float pool as lane-dense rows
+    ``[1, N, bs, W]`` read in place, an int8 pool (``k_scale`` given)
+    with its head axes and scale planes, a layer axis in front."""
+    if "k_scale" in kw:
+        kw = {n: np.asarray(a)[None] for n, a in kw.items()}
+        pk, pv = np.asarray(pk)[None], np.asarray(pv)[None]
+    else:
+        W = kv_row_width(*pk.shape[2:])
+        pk, pv = (kv_rows(jnp.asarray(a), W)[None] for a in (pk, pv))
+    return ragged_paged_attention(q, pk, pv, lens, ql, tables,
+                                  interpret=True, **kw)
+
+
 def _quantize(x, axis=-1):
     """Int8 payload + per-(..., head) f32 scale planes."""
     amax = np.abs(x).max(axis=axis) + 1e-6
@@ -99,8 +115,7 @@ class TestRaggedKernel:
     def test_permuted_pool_matches_reference(self, qlens):
         q, k, v, lens, ql = _wave(qlens=qlens)
         pk, pv, tables = _to_pool(k, v)
-        got = ragged_paged_attention(q, pk, pv, lens, ql, tables,
-                                     interpret=True)
+        got = _paged(q, pk, pv, lens, ql, tables)
         want = ragged_paged_reference(q, pk, pv, lens, ql, tables)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    atol=2e-5, rtol=2e-5)
@@ -125,9 +140,8 @@ class TestRaggedKernel:
         pk, pv, tables = _to_pool(k, v)
         pk8, pks = _quantize(pk)
         pv8, pvs = _quantize(pv)
-        got = ragged_paged_attention(q, pk8, pv8, lens, ql, tables,
-                                     k_scale=pks, v_scale=pvs,
-                                     interpret=True)
+        got = _paged(q, pk8, pv8, lens, ql, tables, k_scale=pks,
+                     v_scale=pvs)
         want = ragged_paged_reference(q, pk8, pv8, lens, ql, tables,
                                       k_scale=pks, v_scale=pvs)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
@@ -157,8 +171,7 @@ class TestRaggedKernel:
                 pk, ks = _quantize(pk)
                 pv, vs = _quantize(pv)
                 kw = dict(k_scale=ks, v_scale=vs)
-            got = ragged_paged_attention(q, pk, pv, lens, ql, tables,
-                                         interpret=True, **kw)
+            got = _paged(q, pk, pv, lens, ql, tables, **kw)
             want = ragged_paged_reference(q, pk, pv, lens, ql, tables,
                                           **kw)
         got, want = np.asarray(got), np.asarray(want)
@@ -223,8 +236,7 @@ class TestRaggedKernel:
             q[:, 0], k, v, lens, block_k=16, interpret=True))
         np.testing.assert_allclose(got[:, 0], old, atol=2e-5, rtol=2e-5)
         pk, pv, tables = _to_pool(k, v)
-        gotp = np.asarray(ragged_paged_attention(
-            q[:, :1], pk, pv, lens, ones, tables, interpret=True))
+        gotp = np.asarray(_paged(q[:, :1], pk, pv, lens, ones, tables))
         oldp = np.asarray(paged_block_decode_attention(
             q[:, 0], pk, pv, lens, tables, interpret=True))
         np.testing.assert_allclose(gotp[:, 0], oldp, atol=2e-5,
@@ -239,8 +251,7 @@ class TestRaggedKernel:
                                                 interpret=True))
         np.testing.assert_allclose(got, old, atol=2e-5, rtol=2e-5)
         pk, pv, tables = _to_pool(k, v)
-        gotp = np.asarray(ragged_paged_attention(
-            q, pk, pv, lens, ql, tables, interpret=True))
+        gotp = np.asarray(_paged(q, pk, pv, lens, ql, tables))
         oldp = np.asarray(paged_block_verify_attention(
             q, pk, pv, lens, ql, tables, interpret=True))
         np.testing.assert_allclose(gotp, oldp, atol=2e-5, rtol=2e-5)
@@ -259,6 +270,124 @@ class TestRaggedKernel:
                 q[:, :1], k, v, lens,
                 np.ones_like(lens)))[:, 0],
             atol=0, rtol=0)
+
+
+# ------------------------------------------------------------------- #
+# the paged pool as lane-dense rows, read in place (ISSUE 31)
+# ------------------------------------------------------------------- #
+
+# one wave a case: (heads, Q, lens, q_lens, then what differs from the
+# defaults of ``_rows_wave``).  A page is 16 positions, the table 20
+# pages wide (320 positions), a group of pages 16 x 16 = 256 positions.
+ROW_CASES = {
+    # the three GPT-2 widths: 768, 1024, 1600 -> 1664 lanes; a chunk, a
+    # decode slot, a k+1 verify block and a dead slot in one wave
+    "w768-mixed": (12, 8, (300, 257, 5, 0), (8, 1, 5, 0), {}),
+    "w1024-mixed": (16, 8, (300, 257, 5, 0), (8, 1, 5, 0), {}),
+    "w1664-mixed": (25, 8, (300, 257, 5, 0), (8, 1, 5, 0), {}),
+    "w1664-bf16": (25, 8, (300, 257, 5, 0), (8, 1, 5, 0),
+                   dict(dtype=jnp.bfloat16, tol=3e-2)),
+    # q-blocks of 1, k+1 and a chunk alone
+    "decode-only-short": (12, 1, (1, 15, 16, 17), (1, 1, 1, 1), {}),
+    "decode-only-group-edge": (12, 1, (255, 256, 257, 320), (1, 1, 1, 1),
+                               {}),
+    "verify-k+1": (12, 8, (5, 20, 255, 260), (5, 5, 5, 5), {}),
+    "chunk": (12, 32, (32, 100, 256, 320), (32, 32, 32, 32), {}),
+    "chunk-dead-tail": (12, 32, (17, 272, 40, 9), (17, 23, 1, 9), {}),
+    # several q-tiles (8 queries each): a decode and a verify slot have
+    # dead tiles, which copy nothing and come back zero
+    "tiled-dead-tiles": (12, 32, (288, 256, 41, 0), (32, 1, 9, 0),
+                         dict(max_rows=96)),
+    "tiled-w1664": (25, 16, (270, 33, 16, 1), (16, 1, 4, 1),
+                    dict(max_rows=100)),
+    # the layer is an index in the page copy
+    "layer-0": (12, 8, (300, 257, 5, 0), (8, 1, 5, 0), dict(layer=0)),
+    "layer-last": (12, 8, (300, 257, 5, 0), (8, 1, 5, 0), dict(layer=2)),
+    # two slots whose first two pages are the same pool blocks
+    "shared-prefix": (12, 8, (40, 37, 300, 290), (8, 5, 1, 1),
+                      dict(share=((0, 1, 2), (2, 3, 16)))),
+    # large finite values in the pad columns and in scratch block 0
+    "pad-and-scratch-garbage": (25, 8, (300, 16, 5, 0), (8, 1, 5, 0),
+                                dict(garbage=1e3)),
+}
+
+
+def _rows_wave(H, Q, lens, q_lens, *, Dh=64, bs=16, T=20, L=3, layer=1,
+               share=(), garbage=None, dtype=np.float32, seed=0):
+    """A pool pair ``[L, N, bs, W]`` with values in EVERY layer, block
+    (scratch block 0 too) and pad column, tables whose dead entries
+    point at scratch block 0, and ``share`` = (slot a, slot b, pages)
+    triples making b's first pages a's."""
+    rng = np.random.RandomState(seed)
+    B, W = len(lens), kv_row_width(H, Dh)
+    N = B * T + 1
+    pk = rng.randn(L, N, bs, W).astype(np.float32)
+    pv = rng.randn(L, N, bs, W).astype(np.float32)
+    if garbage is not None:
+        for pool in (pk, pv):
+            pool[..., H * Dh:] = garbage
+            pool[:, 0] = garbage
+    tables = (1 + rng.permutation(N - 1)[:B * T]).reshape(B, T)
+    for a, b, pages in share:
+        tables[b, :pages] = tables[a, :pages]
+    for b, n in enumerate(lens):
+        tables[b, -(-n // bs):] = 0
+    q = rng.randn(B, Q, H, Dh).astype(np.float32)
+    return (jnp.asarray(q, dtype), jnp.asarray(pk, dtype),
+            jnp.asarray(pv, dtype), np.asarray(lens, np.int32),
+            np.asarray(q_lens, np.int32), tables.astype(np.int32), layer)
+
+
+@pytest.mark.smoke
+class TestPoolRowsKernel:
+    @pytest.mark.parametrize("case", list(ROW_CASES), ids=list(ROW_CASES))
+    def test_matches_reference_over_the_heads_view(self, case,
+                                                   monkeypatch):
+        from hetu_tpu.kernels import ragged_attention as ra
+        H, Q, lens, q_lens, kw = ROW_CASES[case]
+        kw = dict(kw)
+        tol = kw.pop("tol", 2e-5)
+        max_rows = kw.pop("max_rows", None)
+        if max_rows:
+            monkeypatch.setattr(ra, "_MAX_ROWS", max_rows)
+        q, pk, pv, lens, q_lens, tables, layer = _rows_wave(
+            H, Q, lens, q_lens, **kw)
+        got = np.asarray(ragged_paged_attention(
+            q, pk, pv, lens, q_lens, tables, layer=layer,
+            interpret=True), np.float32)
+        # the oracle sees the same layer as [N, bs, H, Dh], pad dropped
+        f32 = lambda a: np.asarray(a, np.float32)  # noqa: E731
+        want = np.asarray(ragged_paged_reference(
+            f32(q), kv_heads(f32(pk)[layer], H, 64),
+            kv_heads(f32(pv)[layer], H, 64), lens, q_lens, tables))
+        assert got.shape == want.shape == q.shape
+        tq = ra._fit_block(max(ra._MAX_ROWS // H, 1),
+                           -(-Q // 8) * 8 if q.dtype == np.float32
+                           else -(-Q // 16) * 16)
+        for b, n in enumerate(q_lens):
+            if lens[b] == 0:
+                assert not got[b].any()          # a dead slot: zeros
+                continue
+            live = min(-(-max(int(n), 1) // tq) * tq, Q)
+            np.testing.assert_allclose(got[b, :live], want[b, :live],
+                                       atol=tol, rtol=tol)
+            assert not got[b, live:].any()       # dead tiles: zeros
+
+    def test_a_row_that_is_not_the_heads_width_is_refused(self):
+        q, pk, pv, lens, q_lens, tables, _ = _rows_wave(
+            12, 1, (5, 5), (1, 1))
+        with pytest.raises(ValueError, match="rows of 768 lanes"):
+            ragged_paged_attention(q, pk[..., :640], pv[..., :640], lens,
+                                   q_lens, tables, interpret=True)
+
+    def test_row_width_and_views(self):
+        assert [kv_row_width(h, 64) for h in (12, 16, 25)] == [
+            768, 1024, 1664]
+        x = np.arange(2 * 3 * 25 * 64, dtype=np.float32).reshape(
+            2, 3, 25, 64)
+        rows = np.asarray(kv_rows(jnp.asarray(x), 1664))
+        assert rows.shape == (2, 3, 1664) and not rows[..., 1600:].any()
+        np.testing.assert_array_equal(kv_heads(rows, 25, 64), x)
 
 
 # ------------------------------------------------------------------- #
@@ -327,6 +456,50 @@ class TestMixedModeEngine:
         mix, eng = _run(p, cfg, ragged=True, **cfg_kw)
         assert eng.ragged
         assert base == mix
+
+    # greedy tokens of TRACE served by the mixed ragged wave over the
+    # paged pool (fast path, block 8, chunk 4, prefix sharing), in the
+    # order of sorted token lists: what the tree before ISSUE 31 served
+    # (pool ``[.., H, Dh]``, a page a grid step), f32 and int8 alike
+    SERVED = [[1, 2, 3, 4, 5, 32, 32, 32, 32],
+              [2, 3, 45, 59, 59, 59, 59, 59],
+              [3, 4, 16, 16, 16, 45, 45, 45, 45, 45],
+              [7, 8, 9, 9, 9, 9, 9, 9, 1],
+              [7, 8, 9, 10, 11, 12, 13, 14, 15, 16, 17, 1, 1, 1, 1, 1],
+              [9, 9, 9, 9, 1, 1, 1, 1], [11, 55, 1, 1, 1, 1, 1, 1]]
+
+    @pytest.mark.parametrize("kind", ["f32", "bf16", "int8"])
+    def test_rows_pool_serves_the_tokens_it_served_before(self, model,
+                                                          kind):
+        """The pool's rows read in place change no served token: the
+        ragged kernel path equals the tree before the change (bf16
+        parted from f32 in one token there, and still does) and, where
+        the arithmetic is exact enough to compare, the masked path."""
+        p, cfg = model
+        kw = dict(paged=True, kv_block=8, prefill_chunk=4,
+                  prefix_share=True, ragged=True,
+                  **{"f32": {}, "bf16": dict(dtype=jnp.bfloat16),
+                     "int8": dict(kv_quant="int8")}[kind])
+        reqs = [Request(prompt=pr, max_new_tokens=n, seed=i)
+                for i, (pr, n, _, _) in enumerate(TRACE)]
+
+        def served(fast):
+            eng = ServingEngine(p, cfg, slots=4, fast_path=fast, **kw)
+            assert eng.ragged and eng.paged
+            if kind != "int8":
+                assert eng.kv.cache_k.shape[-1] == 128    # rows, padded
+            out = eng.run([Request(prompt=r.prompt,
+                                   max_new_tokens=r.max_new_tokens,
+                                   seed=r.seed) for r in reqs])
+            return sorted(r.tokens.tolist() for r in out.values())
+
+        want = [list(t) for t in self.SERVED]
+        if kind == "bf16":
+            want[0] = [1, 2, 3, 4, 5, 50, 50, 50, 30]
+        got = served(True)
+        assert got == sorted(want)
+        if kind != "bf16":
+            assert served(False) == got                   # masked path
 
     def test_spec_decode_composes(self, model):
         p, cfg = model
@@ -448,7 +621,7 @@ class TestSamplingWindow:
         rng = np.random.RandomState(3)
         if paged:
             bs, T = 8, S // 8
-            shape = (L, B * T + 1, bs, H, Dh)
+            shape = (L, B * T + 1, bs, kv_row_width(H, Dh))   # pool rows
             tables = (1 + np.arange(B * T, dtype=np.int32)).reshape(B, T)
             layout_args = (tables,)
         else:

@@ -30,6 +30,7 @@ import hetu_tpu as ht  # noqa: F401  (platform forcing + compat shims)
 import jax
 import jax.numpy as jnp
 
+from hetu_tpu.kv_layout import kv_heads
 from hetu_tpu.models import GPTConfig
 from hetu_tpu.models.gpt_decode import (
     _decode_step, _kv_scatter, _verify_step, generate_fast,
@@ -458,6 +459,11 @@ def _live_bytes(m, slot, paged=True):
                         np.asarray(m.cache_k[1][idx]).tobytes(),
                         np.asarray(m.cache_v[0][idx]).tobytes(),
                         np.asarray(m.cache_v[1][idx]).tobytes()))
+        elif paged:                    # rows: the [L, H, Dh] view
+            out.append(tuple(
+                kv_heads(np.asarray(c[idx]), m.heads,
+                         m.head_dim).tobytes()
+                for c in (m.cache_k, m.cache_v)))
         else:
             out.append((np.asarray(m.cache_k[idx]).tobytes(),
                         np.asarray(m.cache_v[idx]).tobytes()))
