@@ -938,1774 +938,6 @@ def bench_decode(platform, reduced):
     return art
 
 
-_SERVE_FILE = os.path.join(_HERE, "BENCH_SERVE.json")
-
-
-def bench_serve(platform, reduced):
-    """Continuous-batching serving throughput (hetu_tpu/serving): replay
-    a seeded mixed-length request trace through the engine AND through
-    the static-batch baseline (offline ``generate_fast``: pad to the
-    longest request, no early exit) on the same weights, counting the
-    same USEFUL tokens for both — the artifact records both rates, the
-    engine's TTFT percentiles, and its mean batch occupancy."""
-    import jax.numpy as jnp
-    import hetu_tpu as ht
-    from hetu_tpu.models import GPTConfig, GPTForCausalLM
-    from hetu_tpu.models.gpt_decode import _prep_param, generate_fast
-    from hetu_tpu.serving import Request, ServingEngine
-
-    # GPT-2-small shape on chip; a 2-layer h128 model on the CPU harness
-    # (big enough that compute, not per-step dispatch, dominates)
-    vocab, hidden, layers_n, heads, s_max, slots, n_req = \
-        50257, 768, 12, 12, 1024, 8, 32
-    if reduced:
-        vocab, hidden, layers_n, heads, s_max, slots, n_req = \
-            256, 128, 2, 2, 256, 4, 16
-    cfg = GPTConfig(vocab_size=vocab, hidden_size=hidden,
-                    num_hidden_layers=layers_n,
-                    num_attention_heads=heads,
-                    max_position_embeddings=s_max, batch_size=slots,
-                    seq_len=s_max, dropout_rate=0.0)
-    model = GPTForCausalLM(cfg, name="srv")
-    ids = ht.placeholder_op("srv_ids")
-    logits = model(ids)
-    ex = ht.Executor({"gen": [logits]})     # materializes init params
-    del logits
-    dt_ = jnp.bfloat16 if platform == "tpu" else jnp.float32
-    params = {k: _prep_param(v, dt_) for k, v in ex.var_values.items()}
-
-    # seeded mixed-length trace: mostly short requests, a long straggler
-    # every 8th — the shape continuous batching exists for (static
-    # batching pads every batch member to the straggler)
-    rng = np.random.RandomState(1234)
-    straggle = s_max // 2
-    trace = []
-    for i in range(n_req):
-        P = int(rng.randint(4, 17))
-        gen = straggle if i % 8 == 7 else int(rng.randint(8, 33))
-        trace.append((rng.randint(0, vocab, P).astype(np.int32), gen))
-    useful = sum(g for _, g in trace)
-
-    def make_requests():
-        return [Request(prompt=p, max_new_tokens=g) for p, g in trace]
-
-    # ---- warm every compile outside the measured windows: the fused
-    # decode step plus ONE prefill per prompt-length bucket the trace
-    # hits (a cold bucket compile inside the window would be charged to
-    # the engine) ---- #
-    warm = ServingEngine(params, cfg, slots=slots, queue_limit=n_req,
-                         dtype=dt_)
-    buckets = sorted({warm.kv.bucket_prompt(len(p)) for p, _ in trace})
-    warm.run([Request(prompt=[1] * b, max_new_tokens=2)
-              for b in buckets])
-    generate_fast(params, cfg,
-                  np.zeros((slots, 8), np.int32), num_tokens=2,
-                  dtype=dt_)
-
-    # ---- continuous batching ---- #
-    eng = ServingEngine(params, cfg, slots=slots, queue_limit=n_req,
-                        dtype=dt_)
-    t0 = time.perf_counter()
-    res = eng.run(make_requests())
-    wall_c = time.perf_counter() - t0
-    assert len(res) == n_req
-    snap = eng.metrics.snapshot()
-    # request-lifecycle observability (ISSUE 7): the same trace-replay
-    # run now carries its tail decomposition — which component owns the
-    # p99 TTFT — plus the SLO state, into the artifact of record
-    tail = eng.metrics.explain_tail()
-    observability = {
-        "explain_tail": tail,
-        "components": snap["components"],
-        "ttft_p95_s": snap["ttft_p95_s"],
-        "tpot_p50_s": snap["tpot_p50_s"],
-        "slo": eng.slo.snapshot(),
-        "health": eng.health(),
-    }
-
-    # ---- static baseline: batches in arrival order, pad-to-longest,
-    # no early exit (the offline scan's whole-batch contract) ---- #
-    t0 = time.perf_counter()
-    for i in range(0, n_req, slots):
-        batch = trace[i:i + slots]
-        pmax = max(len(p) for p, _ in batch)
-        gmax = max(g for _, g in batch)
-        padded = np.zeros((len(batch), pmax), np.int32)
-        for j, (p, _) in enumerate(batch):
-            padded[j, :len(p)] = p
-        generate_fast(params, cfg, padded, num_tokens=gmax, dtype=dt_)
-    wall_s = time.perf_counter() - t0
-
-    tps_c = round(useful / wall_c, 1)
-    tps_s = round(useful / wall_s, 1)
-
-    def engine_trace(trace_, fast, useful_):
-        """Warm-run then measure one engine path over a trace; returns
-        the rate plus the per-phase attribution from the step events."""
-        reqs = [Request(prompt=p, max_new_tokens=g) for p, g in trace_]
-        warm_e = ServingEngine(params, cfg, slots=slots,
-                               queue_limit=len(trace_), dtype=dt_,
-                               fast_path=fast)
-        warm_e.run([Request(prompt=p, max_new_tokens=g)
-                    for p, g in trace_])   # full trace: every (group,
-        # bucket) compile the measured run will hit is now cached
-        e = ServingEngine(params, cfg, slots=slots,
-                          queue_limit=len(trace_), dtype=dt_,
-                          fast_path=fast)
-        t0 = time.perf_counter()
-        res = e.run(reqs)
-        wall = time.perf_counter() - t0
-        snap_ = e.metrics.snapshot()
-        return {
-            "tokens_per_sec": round(useful_ / wall, 1),
-            "wall_s": round(wall, 3),
-            "prefill_total_s": snap_["prefill_total_s"],
-            "decode_total_s": snap_["decode_total_s"],
-            "prefill_ms_p50": snap_["prefill_ms_p50"],
-            "decode_ms_p50": snap_["decode_ms_p50"],
-            "prefill_dispatches": snap_["prefill_dispatches"],
-        }, sorted(r.tokens.tolist() for r in res.values())
-
-    # ---- masked vs ragged fast-path A/B on the same mixed trace;
-    # greedy parity between the paths is the acceptance criterion ---- #
-    ab = {}
-    outs = {}
-    for label, fast in (("masked", False), ("ragged", True)):
-        ab[label], outs[label] = engine_trace(trace, fast, useful)
-    ab["greedy_identical"] = outs["masked"] == outs["ragged"]
-    ab["speedup"] = (round(ab["ragged"]["tokens_per_sec"]
-                           / ab["masked"]["tokens_per_sec"], 3)
-                     if ab["masked"]["tokens_per_sec"] else None)
-
-    # ---- prefill-heavy trace variant: long prompts, short tails —
-    # the phase mix where flash prefill carries the win ---- #
-    rng2 = np.random.RandomState(4321)
-    ptrace = []
-    for _ in range(n_req):
-        P = int(rng2.randint(s_max // 4, s_max // 2))
-        ptrace.append((rng2.randint(0, vocab, P).astype(np.int32),
-                       int(rng2.randint(4, 9))))
-    useful_p = sum(g for _, g in ptrace)
-    heavy = {"trace": {"seed": 4321, "n_requests": n_req,
-                       "prompt_len": f"{s_max // 4}..{s_max // 2 - 1}",
-                       "new_tokens": "4..8",
-                       "useful_tokens": useful_p}}
-    houts = {}
-    for label, fast in (("masked", False), ("ragged", True)):
-        heavy[label], houts[label] = engine_trace(ptrace, fast, useful_p)
-    heavy["greedy_identical"] = houts["masked"] == houts["ragged"]
-    heavy["speedup"] = (round(heavy["ragged"]["tokens_per_sec"]
-                              / heavy["masked"]["tokens_per_sec"], 3)
-                        if heavy["masked"]["tokens_per_sec"] else None)
-
-    phase_ab = _serve_phase_ab(params, cfg, dt_, reduced)
-    paged_ab = _serve_paged_ab(params, cfg, dt_, slots, s_max, vocab,
-                               n_req)
-    fleet_ab = _serve_fleet_ab(params, cfg, dt_, platform, slots,
-                               vocab, n_req)
-    swap_ab = _serve_swap_ab(params, cfg, dt_, platform, slots,
-                             vocab, n_req)
-    autoscale_ab = _serve_autoscale_ab(params, cfg, dt_, platform,
-                                       slots, vocab)
-    fleet_prefix_ab = _serve_fleet_prefix_ab(params, cfg, dt_, platform,
-                                             slots, s_max, vocab, n_req)
-    prefix_storm_ab = _serve_prefix_storm_ab(params, cfg, dt_, platform,
-                                             vocab)
-    quant_ab = _serve_quant_ab(params, cfg, dt_, slots, s_max, vocab,
-                               n_req)
-    spec_ab = _serve_spec_ab(params, cfg, dt_, platform, slots, s_max,
-                             vocab, n_req)
-    ragged_ab = _serve_ragged_ab(params, cfg, dt_, platform, slots,
-                                 s_max, vocab, n_req)
-    moe_ab = _serve_moe_ab(cfg, dt_, platform, slots, s_max, vocab,
-                           n_req)
-
-    art = {
-        "platform": platform,
-        "reduced_scale": reduced,
-        "measured_at": time.strftime("%Y-%m-%d %H:%M UTC", time.gmtime()),
-        "continuous": {
-            "tokens_per_sec": tps_c,
-            "wall_s": round(wall_c, 3),
-            "ttft_p50_s": snap["ttft_p50_s"],
-            "ttft_p99_s": snap["ttft_p99_s"],
-            "mean_batch_occupancy": (round(snap["mean_batch_occupancy"], 4)
-                                     if snap["mean_batch_occupancy"]
-                                     else None),
-            "steps": snap["steps"],
-        },
-        "static_baseline": {
-            "tokens_per_sec": tps_s,
-            "wall_s": round(wall_s, 3),
-            "batches": -(-n_req // slots),
-            "note": "generate_fast, pad-to-longest, no early exit",
-        },
-        "speedup": round(tps_c / tps_s, 3) if tps_s else None,
-        "observability": observability,
-        "fast_path_ab": ab,
-        "prefill_heavy": heavy,
-        "phase_ab": phase_ab,
-        "paged_ab": paged_ab,
-        "fleet_ab": fleet_ab,
-        "swap_ab": swap_ab,
-        "autoscale_ab": autoscale_ab,
-        "fleet_prefix_ab": fleet_prefix_ab,
-        "prefix_storm_ab": prefix_storm_ab,
-        "quant_ab": quant_ab,
-        "spec_ab": spec_ab,
-        "ragged_ab": ragged_ab,
-        "moe_ab": moe_ab,
-        "trace": {"seed": 1234, "n_requests": n_req,
-                  "prompt_len": "4..16", "short_new_tokens": "8..32",
-                  "straggler_every": 8, "straggler_new_tokens": straggle,
-                  "useful_tokens": useful},
-        "config": {"slots": slots, "s_max": s_max, "hidden": hidden,
-                   "layers": layers_n, "heads": heads, "vocab": vocab,
-                   "dtype": "bf16" if dt_ == jnp.bfloat16 else "f32",
-                   "kernel": "fused_slot_decode_step",
-                   "fast_path": "flash_prefill + ragged paged decode "
-                                "(kernels/decode_attention.py); "
-                                "interpret-mode emulation off-TPU — "
-                                "stage 4c is the A/B of record"},
-    }
-    _persist_artifact(_SERVE_FILE, art, reduced, has_data=True)
-    return art
-
-
-def _serve_paged_ab(params, cfg, dt_, slots, s_max, vocab, n_req):
-    """Paged-vs-contiguous KV at EQUAL cache bytes on a prefix-heavy
-    trace (every request shares one long system prompt, deliberately
-    NOT block-aligned so copy-on-write forks are exercised).  The
-    contiguous layout pays slots * S_max tokens no matter what; the
-    paged pool holds the same bytes as blocks, stores the shared prefix
-    ONCE, and reserves only each request's actual span — so it holds
-    more concurrent sequences per HBM byte, which is the occupancy
-    number that turns into tok/s on chip.  Records
-    peak_concurrent_slots and hbm_bytes_per_slot for both layouts plus
-    the pool's sharing/COW counters; greedy outputs must be identical
-    (this is suite stage 4c's A/B of record alongside masked-vs-ragged).
-    """
-    from hetu_tpu.serving import Request, ServingEngine
-
-    rng = np.random.RandomState(777)
-    block = 16
-    prefix = rng.randint(0, vocab, s_max // 4 + 1).astype(np.int32)
-    trace = []
-    for _ in range(n_req - max(2, n_req // 8)):
-        tail = rng.randint(0, vocab,
-                           int(rng.randint(4, 9))).astype(np.int32)
-        trace.append((np.concatenate([prefix, tail]),
-                      int(rng.randint(8, 17))))
-    # follow-up turns: extend an earlier request's FULL prompt verbatim
-    # (multi-turn shape) — these match a full-length prefix entry
-    # mid-block and exercise the copy-on-write fork
-    for i in range(max(2, n_req // 8)):
-        ext = rng.randint(0, vocab,
-                          int(rng.randint(4, 9))).astype(np.int32)
-        trace.append((np.concatenate([trace[i][0], ext]),
-                      int(rng.randint(8, 17))))
-    useful = sum(g for _, g in trace)
-    # equal bytes: the contiguous pair is slots * S_max tokens; the
-    # pool gets the same token count in blocks (+ the scratch block)
-    pool = slots * (s_max // block) + 1
-
-    def run(paged):
-        if paged:
-            kw = dict(paged=True, kv_block=block, pool_blocks=pool,
-                      slots=min(slots * 8, 64), prefix_share=True)
-        else:
-            kw = dict(paged=False, slots=slots)
-        mk = lambda: [Request(prompt=p, max_new_tokens=g)
-                      for p, g in trace]
-        warm = ServingEngine(params, cfg, queue_limit=n_req, dtype=dt_,
-                             **kw)
-        warm.run(mk())
-        e = ServingEngine(params, cfg, queue_limit=n_req, dtype=dt_,
-                          **kw)
-        t0 = time.perf_counter()
-        res = e.run(mk())
-        wall = time.perf_counter() - t0
-        bytes_ = int(e.kv.cache_bytes)
-        peak = max(e.peak_live, 1)
-        row = {
-            "tokens_per_sec": round(useful / wall, 1),
-            "wall_s": round(wall, 3),
-            "peak_concurrent_slots": e.peak_live,
-            "cache_bytes": bytes_,
-            "hbm_bytes_per_slot": int(bytes_ / peak),
-        }
-        if paged:
-            row["kv"] = e.kv.stats()
-            row["prefill_chunks"] = e.prefill_chunks
-        return row, sorted(r.tokens.tolist() for r in res.values())
-
-    cont, out_c = run(False)
-    pg, out_p = run(True)
-    return {
-        "trace": {"seed": 777, "n_requests": n_req,
-                  "shared_prefix_len": int(len(prefix)),
-                  "tail_len": "4..8", "new_tokens": "8..16",
-                  "followup_turns": max(2, n_req // 8),
-                  "useful_tokens": useful},
-        "block": block,
-        "pool_blocks": pool,
-        "contiguous": cont,
-        "paged": pg,
-        "greedy_identical": out_c == out_p,
-        "slot_capacity_ratio": round(
-            pg["peak_concurrent_slots"]
-            / max(cont["peak_concurrent_slots"], 1), 2),
-        "note": "equal cache bytes (+1 scratch block); paged stores "
-                "the shared prefix once and reserves actual spans",
-    }
-
-
-def _serve_quant_ab(params, cfg, dt_, slots, s_max, vocab, n_req):
-    """Int8 KV cache vs the exact cache at EQUAL HBM bytes (ISSUE 9
-    acceptance).  Both runs are paged; the exact pool's byte budget is
-    the denominator, and the int8 pool gets as many blocks as fit in
-    the SAME bytes (payload + per-(position, head) scale planes both
-    counted) — ~3.7x more tokens per byte at Dh=64.  The trace is
-    admission-saturating (every request reserves a long span against a
-    small pool, slots generous), so peak_concurrent_slots is bound by
-    POOL CAPACITY, which is exactly what int8 buys; the acceptance
-    floor is >= 1.9x peak slots with greedy outputs top-1-identical.
-    CPU tok/s is recorded honestly (dequant is emulated off-chip); the
-    on-chip suite stage is the throughput A/B of record."""
-    from hetu_tpu.serving import PagedKVManager, Request, ServingEngine
-
-    rng = np.random.RandomState(991)
-    block = 16
-    L = cfg.num_hidden_layers
-    H = cfg.num_attention_heads
-    Dh = cfg.hidden_size // H
-    # exact pool: enough blocks for slots//2 brim-full sequences — the
-    # trace below oversubscribes it several times over
-    import jax.numpy as jnp
-    reserve = s_max // 4
-    pool_exact = max(slots, 4) * (reserve // block) + 1
-    per_block_exact = 2 * L * block * H * Dh * jnp.dtype(dt_).itemsize
-    budget = pool_exact * per_block_exact
-    per_block_int8 = 2 * L * block * H * (Dh + 4)
-    pool_int8 = max(budget // per_block_int8, 2)
-    trace = []
-    for _ in range(n_req):
-        P = int(rng.randint(4, 13))
-        trace.append((rng.randint(0, vocab, P).astype(np.int32),
-                      reserve - 12))       # every request reserves ~the
-    useful = sum(g for _, g in trace)      # same long span
-
-    def run(kv_quant, dtype):
-        kw = dict(paged=True, kv_block=block, prefix_share=False,
-                  slots=max(slots * 16, 128), queue_limit=n_req,
-                  dtype=dtype, kv_quant=kv_quant,
-                  pool_blocks=(pool_int8 if kv_quant else pool_exact))
-        mk = lambda: [Request(prompt=p, max_new_tokens=g)
-                      for p, g in trace]
-        warm = ServingEngine(params, cfg, **kw)
-        warm.run(mk())
-        e = ServingEngine(params, cfg, **kw)
-        t0 = time.perf_counter()
-        res = e.run(mk())
-        wall = time.perf_counter() - t0
-        peak = max(e.peak_live, 1)
-        row = {
-            "kv_quant": kv_quant or "off",
-            "dtype": str(jnp.dtype(dtype).name),
-            "tokens_per_sec": round(useful / wall, 1),
-            "wall_s": round(wall, 3),
-            "peak_concurrent_slots": e.peak_live,
-            "pool_blocks": e.kv.n_blocks,
-            "cache_bytes": int(e.kv.cache_bytes),
-            "hbm_bytes_per_slot": int(e.kv.cache_bytes / peak),
-        }
-        return row, sorted(r.tokens.tolist() for r in res.values())
-
-    # the f32 pool is the capacity denominator of record (acceptance:
-    # >= 1.9x vs f32); greedy parity is judged at the SERVING dtype so
-    # bf16-vs-f32 compute noise never masquerades as quantization error
-    exact, out_e = run(None, jnp.float32)
-    if dt_ == jnp.float32:
-        out_ref = out_e
-    else:
-        _, out_ref = run(None, dt_)
-    int8, out_q = run("int8", dt_)
-    ratio = round(int8["peak_concurrent_slots"]
-                  / max(exact["peak_concurrent_slots"], 1), 2)
-
-    # ---- quality gate: greedy top-1-identical under the TOLERANCE-
-    # TESTED threshold.  Teacher-force every exact sequence through the
-    # fake-quant oracle (arithmetically = int8 store + in-kernel
-    # dequant), measure the worst logit perturbation delta, and require
-    # every position whose exact top-2 margin exceeds 2*delta to pick
-    # the SAME token — positions inside the threshold are genuine
-    # near-ties of the underlying model, counted, not hidden.  The
-    # free-running engine comparison is recorded alongside (a near-tie
-    # flip there changes the continuation, so it may legitimately
-    # differ on untrained bench weights). ---- #
-    from hetu_tpu.models.gpt_decode import teacher_forced_logits
-    import functools
-    import jax as _jax
-    delta = 0.0
-    checked = ties = mismatched = 0
-    tf = _jax.jit(functools.partial(
-        teacher_forced_logits, params, cfg),
-        static_argnames=("kv_fake_quant",))
-    for seq in out_ref:
-        le = np.asarray(tf(np.asarray(seq, np.int32),
-                           kv_fake_quant=False))
-        lq = np.asarray(tf(np.asarray(seq, np.int32),
-                           kv_fake_quant=True))
-        delta = max(delta, float(np.abs(lq - le).max()))
-    for seq in out_ref:
-        le = np.asarray(tf(np.asarray(seq, np.int32),
-                           kv_fake_quant=False))
-        lq = np.asarray(tf(np.asarray(seq, np.int32),
-                           kv_fake_quant=True))
-        top2 = np.sort(le, axis=-1)
-        margin = top2[:, -1] - top2[:, -2]
-        same = le.argmax(-1) == lq.argmax(-1)
-        confident = margin > 2 * delta
-        checked += int(confident.sum())
-        ties += int((~confident).sum())
-        mismatched += int((confident & ~same).sum())
-
-    result = {
-        "trace": {"seed": 991, "n_requests": n_req,
-                  "prompt_len": "4..12", "reserve_span": reserve,
-                  "useful_tokens": useful},
-        "block": block,
-        "byte_budget": int(budget),
-        "exact": exact,
-        "int8": int8,
-        "slot_capacity_ratio": ratio,
-        "greedy_gate": {
-            "logit_delta": round(delta, 6),
-            "threshold": round(2 * delta, 6),
-            "positions_checked": checked,
-            "near_ties_excluded": ties,
-            "top1_identical_above_threshold": mismatched == 0,
-        },
-        "greedy_identical_free_running": out_ref == out_q,
-        "note": "equal HBM bytes (scale planes counted against the "
-                "int8 pool); pool capacity bounds peak concurrency — "
-                "the int8 win composes multiplicatively with paged_ab's "
-                "prefix sharing; the greedy gate teacher-forces every "
-                "sequence through the fake-quant oracle "
-                "(gpt_decode.teacher_forced_logits) and requires top-1 "
-                "identity wherever the exact margin exceeds the "
-                "measured 2*delta tolerance; CPU dequant is "
-                "interpret-mode, the on-chip suite stage is the tok/s "
-                "A/B of record",
-    }
-    # the acceptance floors are asserted HERE so a regression in the
-    # quantized layout can never bank a quant_ab silently
-    assert ratio >= 1.9, (
-        f"int8 KV at equal bytes holds only {ratio}x peak slots "
-        f"(acceptance floor 1.9x): {exact} vs {int8}")
-    assert mismatched == 0 and checked > 0, (
-        f"int8 KV flipped {mismatched} greedy tokens whose exact "
-        f"margin exceeds the tolerance threshold 2*{delta}")
-    return result
-
-
-def _serve_fleet_ab(params, cfg, dt_, platform, slots, vocab, n_req):
-    """Single engine vs an N=2 ServingRouter fleet at EQUAL resources
-    (same total slots, so the same total KV cache bytes; the fleet
-    splits them across two supervised replicas) on one seeded
-    mixed-length trace: aggregate useful tok/s + fleet-clock TTFT p99,
-    greedy outputs identical.  A second, deliberately OVERLOADED fleet
-    run records the SLO-class shedding contract of record (ISSUE 8
-    acceptance): throughput-class traffic is shed first and every
-    admitted latency-class request retires with TTFT p95 inside the
-    configured SLO.  Both runs are stamped live — the in-process CPU
-    harness measures the scheduling/recovery contract; chip fleets are
-    per-host."""
-    from hetu_tpu.serving import (
-        QueueFull, Request, RouterShed, ServingEngine, ServingRouter,
-        SLO,
-    )
-
-    n_rep = 2
-    per = max(slots // n_rep, 1)
-    rng = np.random.RandomState(555)
-    trace = []
-    for _ in range(n_req):
-        P = int(rng.randint(4, 17))
-        trace.append((rng.randint(0, vocab, P).astype(np.int32),
-                      int(rng.randint(8, 25))))
-    useful = sum(g for _, g in trace)
-
-    def mk():
-        return [Request(prompt=p, max_new_tokens=g) for p, g in trace]
-
-    def run_single():
-        warm = ServingEngine(params, cfg, slots=slots,
-                             queue_limit=n_req, dtype=dt_)
-        warm.run(mk())
-        e = ServingEngine(params, cfg, slots=slots, queue_limit=n_req,
-                          dtype=dt_)
-        t0 = time.perf_counter()
-        res = e.run(mk())
-        wall = time.perf_counter() - t0
-        snap = e.metrics.snapshot()
-        return {
-            "tokens_per_sec": round(useful / wall, 1),
-            "wall_s": round(wall, 3),
-            "slots": slots,
-            "ttft_p99_s": (round(snap["ttft_p99_s"], 6)
-                           if snap["ttft_p99_s"] is not None else None),
-        }, sorted(r.tokens.tolist() for r in res.values())
-
-    def run_fleet():
-        factory = lambda i: ServingEngine(  # noqa: E731
-            params, cfg, slots=per, queue_limit=n_req, dtype=dt_)
-        warm = ServingRouter(factory, replicas=n_rep)
-        warm.run(mk())
-        r = ServingRouter(factory, replicas=n_rep)
-        t0 = time.perf_counter()
-        res = r.run(mk())
-        wall = time.perf_counter() - t0
-        snap = r.snapshot()
-        return {
-            "tokens_per_sec": round(useful / wall, 1),
-            "wall_s": round(wall, 3),
-            "replicas": n_rep,
-            "slots_per_replica": per,
-            # fleet clock: router submit -> first token, hops included
-            "ttft_p99_s": snap["ttft_p99_s"],
-            "routed_per_replica": [row["routed"]
-                                   for row in snap["replicas"]],
-            "health": snap["health"],
-        }, sorted(r_.tokens.tolist() for r_ in res.values())
-
-    single, out_s = run_single()
-    fleet, out_f = run_fleet()
-
-    # ---- synthetic overload: tiny queues force pressure past the shed
-    # threshold; the router must shed throughput-class traffic FIRST
-    # and keep every admitted latency-class request inside the SLO ---- #
-    slo_ms = 60000.0   # generous: the CPU harness proves ORDER and the
-    # within-budget bound, not chip-scale latency
-    factory = lambda i: ServingEngine(  # noqa: E731
-        params, cfg, slots=1, queue_limit=2, dtype=dt_,
-        slo=[SLO("ttft", "latency", slo_ms)])
-    router = ServingRouter(factory, replicas=n_rep, shed_queue=0.5)
-    for i in range(n_req):
-        cls = "latency" if i % 4 == 0 else "throughput"
-        p, g = trace[i]
-        try:
-            router.submit(Request(prompt=p, max_new_tokens=min(g, 8),
-                                  slo_class=cls))
-        except RouterShed:
-            pass
-        except QueueFull:
-            router.step()   # hard-full backpressure: drain and move on
-    router.run()
-    snap = router.snapshot()
-    lat = snap["classes"]["latency"]
-    overload = {
-        "slo_ttft_ms": slo_ms,
-        "shed": snap["shed"],
-        "shed_by_class": {c: snap["classes"][c]["shed"]
-                          for c in snap["classes"]},
-        "latency_finished": lat["finished"],
-        "latency_ttft_p95_s": lat["ttft_p95_s"],
-        "latency_within_slo": (lat["ttft_p95_s"] is not None
-                               and lat["ttft_p95_s"] * 1e3 <= slo_ms),
-        "queue_pressure": snap["queue_pressure"],
-    }
-
-    return {
-        "provenance": "live",
-        "platform": platform,
-        "measured_at": time.strftime("%Y-%m-%d %H:%M UTC",
-                                     time.gmtime()),
-        "trace": {"seed": 555, "n_requests": n_req,
-                  "prompt_len": "4..16", "new_tokens": "8..24",
-                  "useful_tokens": useful},
-        "single_engine": single,
-        "fleet": fleet,
-        "greedy_identical": out_s == out_f,
-        "overload_shed": overload,
-        "note": "equal total slots (same KV cache bytes) split across "
-                "2 supervised replicas; in-process CPU harness — the "
-                "contract is scheduling + recovery, per-host fleets "
-                "are the chip story",
-    }
-
-
-def _serve_swap_ab(params, cfg, dt_, platform, slots, vocab, n_req):
-    """Live weight sync A/B at EQUAL fleet slots (ISSUE 15): the same
-    seeded trace replayed through two N=2 fleets — ``steady`` (no
-    rollout) and ``rolling`` (a v1 -> v2 rollout begins with the trace
-    in flight: quiesce -> drain -> swap -> probe -> readmit, one
-    replica at a time).  The artifact records tok/s and TTFT p99 for
-    both arms plus the availability ratio; the floors asserted here are
-    the zero-downtime contract — zero request loss, the rollout lands
-    (fleet on v2), every result stamped with its admission version, and
-    the mid-swap throughput stays above the one-replica-out floor."""
-    from hetu_tpu.serving import (
-        Request, ServingEngine, ServingRouter, WeightSyncCoordinator,
-    )
-
-    n_rep = 2
-    per = max(slots // n_rep, 1)
-    rng = np.random.RandomState(1515)
-    trace = []
-    for _ in range(n_req):
-        P = int(rng.randint(4, 17))
-        trace.append((rng.randint(0, vocab, P).astype(np.int32),
-                      int(rng.randint(8, 25))))
-    useful = sum(g for _, g in trace)
-    # v2: same pytree shape, visibly different values — the probe
-    # decode and the per-result version stamps pin which weights served
-    rng2 = np.random.RandomState(1516)
-    params_v2 = {k: np.asarray(v, np.float32)
-                 + rng2.standard_normal(np.shape(v)).astype(np.float32)
-                 * 0.01
-                 for k, v in params.items()}
-
-    def mk():
-        return [Request(prompt=p, max_new_tokens=g) for p, g in trace]
-
-    def factory(i):
-        return ServingEngine(params, cfg, slots=per, queue_limit=n_req,
-                             dtype=dt_)
-
-    def run_arm(rolling):
-        warm = ServingRouter(factory, replicas=n_rep)
-        warm.run(mk())
-        r = ServingRouter(factory, replicas=n_rep)
-        coord = WeightSyncCoordinator(r, params, version=1)
-        t0 = time.perf_counter()
-        if rolling:
-            assert coord.begin(params_v2, 2)
-        res = r.run(mk())
-        if rolling:
-            coord.drain()
-        wall = time.perf_counter() - t0
-        snap = r.snapshot()
-        row = {
-            "tokens_per_sec": round(useful / wall, 1),
-            "wall_s": round(wall, 3),
-            "ttft_p99_s": snap["ttft_p99_s"],
-            "finished": snap["finished"],
-            "lost": snap["lost"],
-        }
-        if rolling:
-            row["rollout_state"] = coord.state
-            row["fleet_versions"] = coord.fleet_versions()
-            row["served_by_version"] = {
-                str(v): sum(1 for x in res.values()
-                            if x.weight_version == v)
-                for v in sorted({x.weight_version
-                                 for x in res.values()})}
-        return row, res
-
-    steady, _ = run_arm(rolling=False)
-    rolling, res_r = run_arm(rolling=True)
-    avail = (round(rolling["tokens_per_sec"]
-                   / steady["tokens_per_sec"], 3)
-             if steady["tokens_per_sec"] else None)
-
-    # the zero-downtime contract, asserted HERE so a regression can
-    # never bank a swap_ab silently
-    assert rolling["rollout_state"] == "done", rolling
-    assert rolling["fleet_versions"] == {i: 2 for i in range(n_rep)}, \
-        rolling
-    assert steady["lost"] == 0 and rolling["lost"] == 0
-    assert steady["finished"] == rolling["finished"] == n_req
-    assert all(x.weight_version in (1, 2) for x in res_r.values())
-    # one replica is quiesced at a time, so the fleet never drops below
-    # half capacity; 0.25 leaves headroom for drain stalls + probe cost
-    # on the CPU harness (chip fleets re-measure in the suite gate)
-    assert avail is not None and avail >= 0.25, (
-        f"rolling swap availability {avail} below floor: "
-        f"{rolling} vs {steady}")
-
-    return {
-        "provenance": "live",
-        "platform": platform,
-        "measured_at": time.strftime("%Y-%m-%d %H:%M UTC",
-                                     time.gmtime()),
-        "trace": {"seed": 1515, "n_requests": n_req,
-                  "prompt_len": "4..16", "new_tokens": "8..24",
-                  "useful_tokens": useful},
-        "steady": steady,
-        "rolling": rolling,
-        "availability": avail,
-        "note": "equal fleet slots, same seeded trace; the rolling arm "
-                "starts a v1 -> v2 rollout with the trace in flight — "
-                "quiesce/drain/swap/probe/readmit per replica, zero "
-                "request loss, every Result version-stamped; CPU "
-                "harness — suite stage 00g is the chaos-gated run",
-    }
-
-
-def _serve_autoscale_ab(params, cfg, dt_, platform, slots, vocab):
-    """Elastic fleet A/B at EQUAL PEAK CAPACITY (ISSUE 16): one seeded
-    diurnal trace (trough -> peak -> trough, zipf sessions, mixed SLO
-    classes) replayed against a virtual clock through two fleets —
-    ``static`` (pinned at the peak size all day: min = max = N, so the
-    autoscaler provably never acts and only integrates the cost) and
-    ``autoscaled`` (starts at 1 replica, grows on queue pressure,
-    shrinks on sustained idle).  The cost surface is REPLICA-SECONDS —
-    what the static fleet burns all day to cover its peak minute — and
-    the floors asserted here are the elasticity contract: zero request
-    loss in both arms, the autoscaled arm actually scales (>= 1 up and
-    >= 1 down), spends FEWER replica-seconds at equal-or-better SLO
-    attainment, and greedy outputs stay token-identical between arms
-    on every request both finished."""
-    from hetu_tpu.serving import (
-        SLO, FleetAutoscaler, ServingEngine, ServingRouter,
-        TrafficGenerator, replay,
-    )
-
-    n_peak = 2
-    per = max(slots // n_peak, 1)
-    # generous TTFT budget (30s, in ms): the A/B question is cost at
-    # EQUAL attainment, so the objective must be attainable by both
-    # arms on the CPU harness (tight-budget burn behavior is the chaos
-    # gate's subject, not this artifact's)
-    gen = TrafficGenerator(seed=2024, vocab=vocab, s_max=32,
-                           horizon_s=3.0, base_rps=2.0, peak_rps=80.0,
-                           cycle_s=3.0, n_sessions=8, zipf_a=1.4,
-                           prefix_len=8)
-    specs = gen.trace(dt=0.05)
-    step_s = 0.01
-
-    def run_arm(autoscaled):
-        mons = []
-
-        def factory(i):
-            eng = ServingEngine(params, cfg, slots=per, queue_limit=8,
-                                dtype=dt_, paged=True,
-                                prefix_share=True,
-                                slo=[SLO("ttft", "latency", 30_000.0)])
-            mons.append(eng.slo)
-            return eng
-
-        r = ServingRouter(factory,
-                          replicas=(1 if autoscaled else n_peak),
-                          directory=True, shed_on_slo=False)
-        auto = FleetAutoscaler(
-            r,
-            fleet_min=(1 if autoscaled else n_peak),
-            fleet_max=n_peak,
-            up_pressure=0.2, up_ticks=2, up_burn=10.0,
-            down_pressure=0.1, down_ticks=30, cooldown=10,
-            warm_prefixes=4)
-        t0 = time.perf_counter()
-        # one idle diurnal cycle of virtual tail gives the scale-down
-        # its sustained-idle window
-        res, rep = replay(r, specs, step_s=step_s, tail_s=3.0)
-        wall = time.perf_counter() - t0
-        snap = r.snapshot()
-        viol = sum(m.violations for m in mons)
-        obs = sum(m.observed for m in mons)
-        return {
-            "replicas": (f"1..{n_peak}" if autoscaled else str(n_peak)),
-            "wall_s": round(wall, 3),
-            "finished": snap["finished"],
-            "lost": snap["lost"],
-            "shed": len(rep["shed"]),
-            "rejected": len(rep["rejected"]),
-            "requeued": snap["requeued"],
-            # virtual-clock cost: one tick per router.step == step_s of
-            # trace time, so this is deterministic where wall-clock
-            # replica-seconds (reported too) absorb CPU compile noise
-            "replica_seconds": round(auto.replica_ticks * step_s, 4),
-            "replica_seconds_wall": auto.snapshot()["replica_seconds"],
-            "peak_replicas": auto.snapshot()["peak_replicas"],
-            "scale_ups": auto.scale_ups,
-            "scale_downs": auto.scale_downs,
-            "slo_attainment": round(1.0 - viol / max(obs, 1), 4),
-            "ttft_p99_s": snap["ttft_p99_s"],
-        }, res
-
-    # warm the jit caches once so neither arm banks compile time as
-    # replica-seconds (arm order must not decide the A/B)
-    warm = ServingRouter(
-        lambda i: ServingEngine(params, cfg, slots=per, queue_limit=8,
-                                dtype=dt_, paged=True,
-                                prefix_share=True),
-        replicas=1, shed_on_slo=False)
-    replay(warm, specs[:8], step_s=step_s)
-
-    static, res_s = run_arm(autoscaled=False)
-    auto, res_a = run_arm(autoscaled=True)
-
-    # the elasticity contract, asserted HERE so a regression can never
-    # bank an autoscale_ab silently
-    assert static["lost"] == 0 and auto["lost"] == 0, (static, auto)
-    assert static["scale_ups"] == static["scale_downs"] == 0, static
-    assert auto["scale_ups"] >= 1 and auto["scale_downs"] >= 1, auto
-    assert auto["replica_seconds"] < static["replica_seconds"], (
-        f"autoscaled fleet burned {auto['replica_seconds']} "
-        f"replica-seconds, static burned {static['replica_seconds']}")
-    assert auto["slo_attainment"] >= static["slo_attainment"], (
-        static, auto)
-    assert auto["slo_attainment"] >= 0.98, auto
-    common = set(res_s) & set(res_a)
-    assert common, "arms share no finished requests"
-    for rid in common:
-        assert list(res_s[rid].tokens) == list(res_a[rid].tokens), rid
-
-    return {
-        "provenance": "live",
-        "platform": platform,
-        "measured_at": time.strftime("%Y-%m-%d %H:%M UTC",
-                                     time.gmtime()),
-        "trace": dict(gen.describe(), n_requests=len(specs)),
-        "static": static,
-        "autoscaled": auto,
-        "replica_seconds_saved": round(
-            static["replica_seconds"] - auto["replica_seconds"], 4),
-        "token_identical_common": len(common),
-        "note": "equal peak capacity (static pinned at N, autoscaled "
-                "1..N), same seeded diurnal trace on a virtual clock; "
-                "scale-up on queue pressure, scale-down on sustained "
-                "idle; CPU harness — suite stage 00h is the "
-                "chaos-gated run",
-    }
-
-
-def _serve_fleet_prefix_ab(params, cfg, dt_, platform, slots, s_max,
-                           vocab, n_req):
-    """Fleet prefix intelligence at EQUAL fleet slots (ISSUE 12): a
-    prefix-storm trace (two long shared system prompts, every request
-    a DISTINCT session so PR 8 affinity hashing scatters them) replayed
-    through three N=2 fleets:
-
-    - ``affinity``  — PR 8 behavior (``directory=False``): each replica
-      prefills each system prompt for itself;
-    - ``directory`` — the PrefixDirectory routes matching prompts to
-      the replica already HOLDING the prefix, so the fleet prefills
-      each system prompt once;
-    - ``roles``     — directory + prefill/decode disaggregation
-      (``roles="prefill,decode"``): cold long prompts prefill on the
-      prefill-heavy replica and the KV span hands off to its decode
-      home over the int8-capable wire.
-
-    Requests are replayed in WAVES (the storm shape: tenants arriving
-    over time, not one atomic batch) so later waves can actually
-    consult what earlier waves registered.  Greedy outputs must be
-    token-identical across all three arms, and the acceptance floors
-    are asserted HERE so a regression can never bank the artifact
-    silently: directory tok/s >= affinity tok/s and directory TTFT p99
-    <= 1.25x affinity's."""
-    from hetu_tpu.serving import Request, ServingEngine, ServingRouter
-
-    n_rep = 2
-    per = max(slots // n_rep, 1)
-    sys_len = s_max // 2 - 8          # long, deliberately NOT aligned
-    rng = np.random.RandomState(777)
-    sys_a = rng.randint(0, vocab, sys_len).astype(np.int32)
-    sys_b = rng.randint(0, vocab, sys_len).astype(np.int32)
-    trace = []
-    for i in range(n_req):
-        base = sys_a if i % 2 == 0 else sys_b
-        tail = rng.randint(0, vocab, 2).astype(np.int32)
-        trace.append((np.concatenate([base, tail]),
-                      int(rng.randint(4, 9))))
-    useful = sum(g for _, g in trace)
-    wave = max(n_req // 4, 1)
-
-    def mk():
-        return [Request(prompt=p, max_new_tokens=g,
-                        session_id=f"tenant-{i}")
-                for i, (p, g) in enumerate(trace)]
-
-    def factory(**kw):
-        return lambda i: ServingEngine(
-            params, cfg, slots=per, queue_limit=n_req, dtype=dt_,
-            paged=True, prefix_share=True, **kw)
-
-    def run_arm(**router_kw):
-        warm = ServingRouter(factory(), replicas=n_rep, **router_kw)
-        warm.run(mk())
-        r = ServingRouter(factory(), replicas=n_rep, **router_kw)
-        reqs = mk()
-        out = {}
-        t0 = time.perf_counter()
-        for i in range(0, n_req, wave):
-            out.update(r.run(reqs[i:i + wave]))
-        wall = time.perf_counter() - t0
-        snap = r.snapshot()
-        row = {
-            "tokens_per_sec": round(useful / wall, 1),
-            "wall_s": round(wall, 3),
-            "ttft_p99_s": snap["ttft_p99_s"],
-            "directory": ({k: snap["directory"][k] for k in
-                           ("hits", "misses", "steals", "stale",
-                            "hit_rate")}
-                          if snap["directory"] else None),
-            "directory_hit_rate": snap["directory_hit_rate"],
-            "handoffs": snap["handoffs"],
-            "handoff_bytes": snap["handoff_bytes"],
-        }
-        return row, sorted(v.tokens.tolist() for v in out.values())
-
-    affinity, out_a = run_arm(directory=False)
-    directory, out_d = run_arm()
-    roles, out_r = run_arm(roles="prefill,decode")
-    if directory["tokens_per_sec"] < affinity["tokens_per_sec"] or \
-            (affinity["ttft_p99_s"] and directory["ttft_p99_s"]
-             and directory["ttft_p99_s"]
-             > affinity["ttft_p99_s"] * 1.25):
-        # the wave replay is a WALL-CLOCK measurement on a shared CPU:
-        # a load spike during one arm can invert a timing floor with
-        # no code regression behind it.  One full remeasure (all arms,
-        # same order) decides; a real regression fails both passes.
-        # Token identity is deterministic and is never retried.
-        affinity, out_a = run_arm(directory=False)
-        directory, out_d = run_arm()
-        roles, out_r = run_arm(roles="prefill,decode")
-
-    speedup = (round(directory["tokens_per_sec"]
-                     / affinity["tokens_per_sec"], 3)
-               if affinity["tokens_per_sec"] else None)
-    result = {
-        "provenance": "live",
-        "platform": platform,
-        "measured_at": time.strftime("%Y-%m-%d %H:%M UTC",
-                                     time.gmtime()),
-        "trace": {"seed": 777, "n_requests": n_req,
-                  "system_prompts": 2, "system_prompt_len": sys_len,
-                  "new_tokens": "4..8", "wave": wave,
-                  "useful_tokens": useful},
-        "affinity_only": affinity,
-        "directory": directory,
-        "directory_roles": roles,
-        "speedup_directory": speedup,
-        "speedup_roles": (round(roles["tokens_per_sec"]
-                                / affinity["tokens_per_sec"], 3)
-                          if affinity["tokens_per_sec"] else None),
-        "greedy_identical": out_a == out_d == out_r,
-        "note": "equal fleet slots across all arms; the affinity arm "
-                "still has PER-REPLICA prefix caching (PR 6) — the "
-                "directory's win is fleet-level placement, each "
-                "system prompt prefilled once per FLEET instead of "
-                "once per replica",
-    }
-    # acceptance floors (ISSUE 12): the directory must not lose to
-    # affinity-only on its home turf, and greedy outputs must match
-    assert result["greedy_identical"], (
-        "fleet_prefix_ab arms diverged: directory/role routing "
-        "changed greedy tokens")
-    assert directory["tokens_per_sec"] >= affinity["tokens_per_sec"], (
-        f"directory routing lost throughput on a prefix storm: "
-        f"{directory['tokens_per_sec']} vs {affinity['tokens_per_sec']}"
-        f" tok/s (floor: >= 1.0x affinity-only)")
-    if affinity["ttft_p99_s"] and directory["ttft_p99_s"]:
-        assert directory["ttft_p99_s"] <= affinity["ttft_p99_s"] * 1.25, (
-            f"directory routing degraded TTFT p99: "
-            f"{directory['ttft_p99_s']}s vs affinity "
-            f"{affinity['ttft_p99_s']}s (floor: <= 1.25x)")
-    assert (directory["directory"] or {}).get("hits", 0) > 0, (
-        "prefix storm produced zero directory hits — the directory "
-        "is not being consulted")
-    assert roles["handoffs"] > 0, (
-        "role-split arm produced zero KV handoffs")
-    return result
-
-
-def _serve_prefix_storm_ab(params, cfg, dt_, platform, vocab):
-    """Tiered-KV A/B at EQUAL POOL SIZE (ISSUE 17): a zipf-session
-    prefix storm whose warm working set (12 distinct 8-token session
-    heads plus bodies) deliberately exceeds a starved paged pool
-    (2 slots, 8 blocks), replayed on a virtual clock through three
-    single-replica fleets:
-
-    - ``drop``    — PR 6 behavior (no tiers): every refcount-zero
-      eviction discards the prefix KV, the next request of that
-      session re-prefills it;
-    - ``tiered``  — the full ladder (host-RAM ring sized to ~2 blocks
-      so demotion to the sharded-PS cold store is exercised too):
-      evictions spill, admission misses fetch back token-identically;
-    - ``tiered_ps_chaos`` — same ladder with ``HETU_CHAOS``
-      role=kvtier killing the PS mid-storm: the store must mark the
-      cold rung dead and degrade to drop-on-evict with ZERO loss.
-
-    The acceptance floors ride in-bench so a regression can never bank
-    silently: greedy outputs identical across all three arms, zero
-    request loss everywhere, tiered saves strictly more recompute
-    tokens than drop (``prefix_hit_tokens``) without degrading TTFT
-    p99 (<= 1.10x), the ladder actually cycles (spills AND fetches),
-    and the chaos arm ends with ``ps_dead`` set."""
-    from hetu_tpu.ps import faults
-    from hetu_tpu.ps.server import PSServer
-    from hetu_tpu.ps.sharded import ShardedPSClient
-    from hetu_tpu.serving import (
-        ServingEngine, ServingRouter, TieredKVStore, TrafficGenerator,
-        replay,
-    )
-
-    gen = TrafficGenerator(seed=909, vocab=vocab, s_max=32,
-                           horizon_s=2.0, base_rps=12.0, peak_rps=12.0,
-                           cycle_s=2.0, n_sessions=12, zipf_a=1.3,
-                           prefix_len=8)
-    specs = gen.trace(dt=0.05)
-    step_s = 0.01
-    # ~4 spilled prefixes of host ring (a full registered head+body
-    # span exports ~16KB here): small enough that the storm overflows
-    # the ring and demotes down to the PS rung, large enough that the
-    # ring serves fetches of its own
-    host_bytes = 65536
-
-    def factory(i):
-        return ServingEngine(params, cfg, slots=2, queue_limit=64,
-                             dtype=dt_, paged=True, kv_block=8,
-                             pool_blocks=8, prefix_share=True)
-
-    def run_arm(mode):
-        store = None
-        if mode != "drop":
-            store = TieredKVStore(
-                host_bytes=host_bytes, ps_tier=True,
-                ps=ShardedPSClient(servers=[PSServer(), PSServer()]))
-        if mode == "tiered_ps_chaos":
-            os.environ["HETU_CHAOS"] = "seed=5,kill=2,role=kvtier"
-            faults.reset_plans()
-        try:
-            # kv_tiers=None resolves from_env(), which is OFF here —
-            # both registry knobs were popped for the A/B sandbox
-            r = ServingRouter(factory, replicas=1, kv_tiers=store)
-            t0 = time.perf_counter()
-            res, rep = replay(r, specs, step_s=step_s)
-            wall = time.perf_counter() - t0
-            snap = r.snapshot()
-            kv = r.replicas[0].engine.kv
-            tiers = snap["kv_tiers"]
-            row = {
-                "wall_s": round(wall, 3),
-                "finished": snap["finished"],
-                "lost": snap["lost"],
-                "shed": len(rep["shed"]),
-                "rejected": len(rep["rejected"]),
-                "ttft_p99_s": snap["ttft_p99_s"],
-                "recompute_tokens_saved": kv.prefix_hit_tokens,
-                "pool_spills": kv.spills,
-                "replica_restarts": sum(x["restarts"]
-                                        for x in snap["replicas"]),
-                "tiers": tiers,
-            }
-            if store is not None:
-                store.close("bench_arm_done")
-            return row, sorted(v.tokens.tolist() for v in res.values())
-        finally:
-            if mode == "tiered_ps_chaos":
-                os.environ.pop("HETU_CHAOS", None)
-                faults.reset_plans()
-
-    saved_env = {k: os.environ.pop(k, None)
-                 for k in ("HETU_KV_HOST_BYTES", "HETU_KV_PS_TIER",
-                           "HETU_CHAOS")}
-    faults.reset_plans()
-    try:
-        # warm the jit caches once so arm order cannot decide the A/B.
-        # The warm fleet runs WITH tiers over the whole trace: the
-        # fetch-resume path prefills residual suffixes (prompt minus
-        # the re-admitted head), whose pow2 buckets a plain warm-up
-        # never compiles — unwarmed, the tiered arm banks compile
-        # pauses as TTFT
-        wstore = TieredKVStore(
-            host_bytes=host_bytes, ps_tier=True,
-            ps=ShardedPSClient(servers=[PSServer(), PSServer()]))
-        warm = ServingRouter(factory, replicas=1, kv_tiers=wstore)
-        replay(warm, specs, step_s=step_s)
-        wstore.close("bench_warmup_done")
-
-        drop, out_d = run_arm("drop")
-        tiered, out_t = run_arm("tiered")
-        chaos, out_c = run_arm("tiered_ps_chaos")
-        if drop["ttft_p99_s"] and tiered["ttft_p99_s"] and \
-                tiered["ttft_p99_s"] > drop["ttft_p99_s"] + 0.050:
-            # wall-clock TTFT on a shared CPU: one remeasure of the
-            # timed arms decides the cap (chaos arm re-runs too so the
-            # greedy-identity triple stays one coherent measurement);
-            # a real fetch-path stall fails both passes
-            drop, out_d = run_arm("drop")
-            tiered, out_t = run_arm("tiered")
-            chaos, out_c = run_arm("tiered_ps_chaos")
-    finally:
-        for k, v in saved_env.items():
-            if v is not None:
-                os.environ[k] = v
-        faults.reset_plans()
-
-    result = {
-        "provenance": "live",
-        "platform": platform,
-        "measured_at": time.strftime("%Y-%m-%d %H:%M UTC",
-                                     time.gmtime()),
-        "trace": dict(gen.describe(), n_requests=len(specs)),
-        "pool": {"slots": 2, "pool_blocks": 8, "kv_block": 8,
-                 "host_ring_bytes": host_bytes, "ps_shards": 2},
-        "drop_on_evict": drop,
-        "tiered": tiered,
-        "tiered_ps_chaos": chaos,
-        "recompute_tokens_saved_delta": (
-            tiered["recompute_tokens_saved"]
-            - drop["recompute_tokens_saved"]),
-        "greedy_identical": out_d == out_t == out_c,
-        "note": "equal pool size across all arms (2 slots x 8 blocks "
-                "of 8 tokens vs a 12-session zipf working set); the "
-                "drop arm still has in-pool prefix caching (PR 6) — "
-                "the ladder's win is capacity BEYOND the pool, "
-                "measured as recompute tokens saved (the TTFT win is "
-                "the on-chip claim; this harness's model re-prefills "
-                "a head faster than any fetch); suite stage 00i is "
-                "the chaos-gated contract run",
-    }
-    # acceptance floors (ISSUE 17)
-    assert result["greedy_identical"], (
-        "prefix_storm_ab arms diverged: tiering changed greedy tokens")
-    for name, row in (("drop", drop), ("tiered", tiered),
-                      ("chaos", chaos)):
-        assert row["lost"] == 0 and row["shed"] == 0 \
-            and row["rejected"] == 0, (name, row)
-    assert (tiered["recompute_tokens_saved"]
-            > drop["recompute_tokens_saved"]), (
-        f"tiering saved no recompute over drop-on-evict: "
-        f"{tiered['recompute_tokens_saved']} vs "
-        f"{drop['recompute_tokens_saved']} prefix-hit tokens")
-    if drop["ttft_p99_s"] and tiered["ttft_p99_s"]:
-        if platform == "tpu":
-            # the TTFT WIN is the on-chip claim: re-prefilling a real
-            # system prompt through a real model dwarfs a block fetch
-            assert tiered["ttft_p99_s"] <= drop["ttft_p99_s"] * 1.10, (
-                f"tiering degraded TTFT p99: {tiered['ttft_p99_s']}s "
-                f"vs drop {drop['ttft_p99_s']}s (floor: <= 1.10x)")
-        else:
-            # CPU harness: the 2-layer h128 model re-prefills an
-            # 8-token head in under a millisecond, so the fetch path's
-            # fixed cost (~3ms import_blocks) can only lose on wall
-            # TTFT here — cap the overhead absolutely instead (a
-            # compile pause or PS stall on the fetch path still fails)
-            assert (tiered["ttft_p99_s"]
-                    <= drop["ttft_p99_s"] + 0.050), (
-                f"tier fetch path stalled: TTFT p99 "
-                f"{tiered['ttft_p99_s']}s vs drop "
-                f"{drop['ttft_p99_s']}s (floor: <= drop + 50ms)")
-    t_stats = tiered["tiers"]
-    assert sum(t_stats["spills"].values()) > 0 \
-        and sum(t_stats["fetches"].values()) > 0, (
-        f"the ladder never cycled on the storm: {t_stats}")
-    assert t_stats["demotes"] > 0, (
-        "the host ring never overflowed into the PS rung — the storm "
-        "is not exercising the full ladder", t_stats)
-    assert chaos["tiers"]["ps_dead"] is True, (
-        "chaos arm never killed the PS rung — kill=2/role=kvtier "
-        "did not fire", chaos["tiers"])
-    assert chaos["replica_restarts"] == 0, (
-        "the PS kill took a REPLICA down with it — tier degradation "
-        "must never escape as an engine crash", chaos)
-    return result
-
-
-def _serve_spec_ab(params, cfg, dt_, platform, slots, s_max, vocab,
-                   n_req):
-    """Speculative vs plain decoding at EQUAL slots (ISSUE 10).
-
-    High-acceptance point: the measured model is the bench model with
-    every layer PAST the draft output-zeroed (attn_proj/ffn_wo weights
-    and biases set to 0; the reduced 2-layer CPU model is additionally
-    DEEPENED to 6 layers by replicating the zeroed block, so the
-    target:draft cost ratio resembles a real deployment instead of
-    2:1), so the truncated-layer draft's logits equal the target's
-    bitwise — greedy acceptance is 1.0 by construction while the
-    target still pays full-depth compute per verify, which is the
-    regime speculation exists for.  The temperature sweep then
-    degrades acceptance honestly: the target SAMPLES while the draft
-    proposes greedily, so hotter requests accept fewer drafts — a real
-    acceptance-rate sweep on one model.  Token identity spec-vs-plain
-    is asserted at EVERY sweep point (greedy and sampled alike: the
-    engine's accepted tokens are the target's own sequential samples),
-    the wall-clock tok/s floor is asserted at the high-acceptance
-    point, and TPOT percentiles come from real per-step token counts in
-    both modes.  CPU numbers are stamped live; the on-chip stage 4c
-    invocation records this section on chip — the A/B of record."""
-    from hetu_tpu.models import GPTConfig
-    from hetu_tpu.models.gpt_decode import _infer_name
-    from hetu_tpu.serving import Request, ServingEngine
-
-    name = _infer_name(params)
-    draft_layers = 1
-    spec_k = 4
-    L = max(cfg.num_hidden_layers, 6)
-    zeroed = ("attn_proj_weight", "attn_proj_bias",
-              "ffn_wo_weight", "ffn_wo_bias")
-    sp = dict(params)
-    for i in range(draft_layers, L):
-        src = min(i, cfg.num_hidden_layers - 1)
-        for suffix in ("ln1_scale", "ln1_bias", "ln2_scale", "ln2_bias",
-                       "attn_q_weight", "attn_q_bias", "attn_k_weight",
-                       "attn_k_bias", "attn_v_weight", "attn_v_bias",
-                       "ffn_wi_weight", "ffn_wi_bias", *zeroed):
-            v = np.asarray(params[f"{name}_h{src}_{suffix}"])
-            sp[f"{name}_h{i}_{suffix}"] = (np.zeros_like(v)
-                                           if suffix in zeroed else v)
-    if L != cfg.num_hidden_layers:
-        cfg = GPTConfig(
-            vocab_size=cfg.vocab_size, hidden_size=cfg.hidden_size,
-            num_hidden_layers=L,
-            num_attention_heads=cfg.num_attention_heads,
-            max_position_embeddings=cfg.max_position_embeddings,
-            batch_size=cfg.batch_size, seq_len=cfg.seq_len,
-            dropout_rate=0.0)
-
-    rng = np.random.RandomState(888)
-    trace = []
-    for _ in range(n_req):
-        P = int(rng.randint(4, 13))
-        trace.append((rng.randint(0, vocab, P).astype(np.int32),
-                      int(rng.randint(16, 33))))
-    useful = sum(g for _, g in trace)
-
-    def run(spec, temperature):
-        kw = dict(slots=slots, queue_limit=n_req, dtype=dt_,
-                  spec=(spec_k if spec else 0), spec_adapt=False,
-                  spec_draft_layers=draft_layers)
-        mk = lambda: [Request(prompt=p, max_new_tokens=g,  # noqa: E731
-                              temperature=temperature, seed=i)
-                      for i, (p, g) in enumerate(trace)]
-        warm = ServingEngine(sp, cfg, **kw)
-        warm.run(mk())
-        # best of two measured replays: the speedup floor below is
-        # ASSERTED, so a single background-load hiccup must not be
-        # able to fail the gate
-        best = None
-        for _ in range(2):
-            e_ = ServingEngine(sp, cfg, **kw)
-            t0 = time.perf_counter()
-            res_ = e_.run(mk())
-            w_ = time.perf_counter() - t0
-            if best is None or w_ < best[0]:
-                best = (w_, e_, res_)
-        wall, e, res = best
-        snap = e.metrics.snapshot()
-        row = {
-            "tokens_per_sec": round(useful / wall, 1),
-            "wall_s": round(wall, 3),
-            "steps": e.steps,
-            "tokens_per_step_mean": (round(snap["tokens_per_step_mean"],
-                                           3)
-                                     if snap["tokens_per_step_mean"]
-                                     else None),
-            # TPOT percentiles from REAL per-step emitted-token counts
-            # (serving/metrics.py step_tokens) in BOTH modes
-            "tpot_p50_s": snap["tpot_p50_s"],
-            "tpot_p99_s": snap["tpot_p99_s"],
-        }
-        if spec:
-            row.update({
-                "spec_k": spec_k,
-                "draft_layers": draft_layers,
-                "proposed": e.spec_proposed,
-                "accepted": e.spec_accepted,
-                "acceptance_rate": round(e.spec_acceptance or 0.0, 4),
-                "mean_k": round(e.spec_mean_k or 0.0, 2),
-                "waves": e.spec_waves,
-            })
-        return row, sorted(r.tokens.tolist() for r in res.values())
-
-    plain, out_p = run(False, 0.0)
-    spec_hi, out_s = run(True, 0.0)
-    speedup = (round(spec_hi["tokens_per_sec"]
-                     / plain["tokens_per_sec"], 3)
-               if plain["tokens_per_sec"] else None)
-
-    # acceptance-rate sweep via temperature: hotter target sampling
-    # accepts fewer greedy draft proposals; token identity must hold
-    # at every point (accepted tokens ARE the target's samples).  The
-    # greedy headline above is the acceptance-1.0 endpoint; one hot
-    # point bounds the other end (more temperatures on chip if wanted)
-    sweep = []
-    for t in (1.0,):
-        srow, souts = run(True, t)
-        _, pouts = run(False, t)
-        sweep.append({
-            "temperature": t,
-            "acceptance_rate": srow["acceptance_rate"],
-            "tokens_per_sec": srow["tokens_per_sec"],
-            "tokens_per_step_mean": srow["tokens_per_step_mean"],
-            "identical": souts == pouts,
-        })
-
-    result = {
-        "provenance": "live",
-        "platform": platform,
-        "measured_at": time.strftime("%Y-%m-%d %H:%M UTC",
-                                     time.gmtime()),
-        "trace": {"seed": 888, "n_requests": n_req,
-                  "prompt_len": "4..12", "new_tokens": "16..32",
-                  "useful_tokens": useful},
-        "spec_k": spec_k,
-        "draft_layers": draft_layers,
-        "target_layers": L,
-        "plain": plain,
-        "spec": spec_hi,
-        "speedup": speedup,
-        "greedy_identical": out_p == out_s,
-        "acceptance_sweep": sweep,
-        "note": "equal slots; layers past the draft output-zeroed (and "
-                "the reduced model deepened to 6 layers) so draft "
-                "logits == target logits (acceptance 1.0 at greedy) "
-                "while verify pays full depth — the high-acceptance "
-                "endpoint; sweep temperatures degrade acceptance "
-                "honestly (target samples vs greedy draft); CPU "
-                "harness runs the verify kernels in interpret mode — "
-                "stage 4c on chip is the A/B of record",
-    }
-    # acceptance floors asserted HERE so a speculative-path regression
-    # can never bank a spec_ab silently
-    assert result["greedy_identical"], (
-        "speculative greedy outputs diverged from the plain engine")
-    assert all(r["identical"] for r in sweep), (
-        f"speculative sampled outputs diverged in the sweep: {sweep}")
-    assert spec_hi["acceptance_rate"] >= 0.95, (
-        f"high-acceptance point accepted only "
-        f"{spec_hi['acceptance_rate']} of drafts: {spec_hi}")
-    assert speedup is not None and speedup > 0
-    if (os.cpu_count() or 1) >= 2:
-        # the wall-clock floor needs the draft scan and the batched
-        # verify to overlap with XLA's intra-op threads; on a 1-core
-        # host they serialize onto the same core and the win collapses
-        # to noise, so the floor only binds with >= 2 cores (the
-        # token-identity + acceptance + tokens/step floors above still
-        # bind everywhere)
-        assert speedup >= 1.05, (
-            f"speculation at acceptance "
-            f"{spec_hi['acceptance_rate']} shows no wall-clock win "
-            f"(speedup {speedup}): {plain} vs {spec_hi}")
-    return result
-
-
-def _serve_ragged_ab(params, cfg, dt_, platform, slots, s_max, vocab,
-                     n_req):
-    """Mixed-mode ragged dispatch vs the phase-split scheduler
-    (ISSUE 18) on a trace that exercises BOTH regimes at once: half
-    the requests are prefill-heavy (long chunked prompts, short
-    tails), half decode-heavy (short prompts, long tails), so every
-    engine step mixes chunk continuations with decode streams — the
-    wave shape the phase barrier penalizes.  Greedy token identity
-    between the modes is asserted at the end; the ragged arm's
-    chunk_stall tail component must be EXACTLY zero (mixed mode folds
-    it at retirement after asserting the residue is bounded), and
-    tok/s must be no worse than phase-split (strict speedup floor
-    gated to TPU — the CPU harness runs both arms through XLA-batched
-    attention, so only dispatch-count savings show here; suite stage
-    4c on chip is the A/B of record)."""
-    from hetu_tpu.serving import Request, ServingEngine
-
-    chunk = max(8, s_max // 16)
-    rng = np.random.RandomState(999)
-    trace = []
-    for i in range(n_req):
-        if i % 2 == 0:      # prefill-heavy: chunked prompt, short tail
-            P = int(rng.randint(s_max // 4, s_max // 2))
-            gen = int(rng.randint(4, 9))
-        else:               # decode-heavy: short prompt, long tail
-            P = int(rng.randint(4, 13))
-            gen = int(rng.randint(16, 33))
-        trace.append((rng.randint(0, vocab, P).astype(np.int32), gen))
-    useful = sum(g for _, g in trace)
-
-    def run(ragged):
-        kw = dict(slots=slots, queue_limit=n_req, dtype=dt_,
-                  paged=True, kv_block=8, prefill_chunk=chunk,
-                  ragged=ragged)
-        mk = lambda: [Request(prompt=p, max_new_tokens=g,  # noqa: E731
-                              seed=i)
-                      for i, (p, g) in enumerate(trace)]
-        warm = ServingEngine(params, cfg, **kw)
-        warm.run(mk())
-        # best of two measured replays — the no-worse floor below is
-        # ASSERTED, so a background-load hiccup must not fail the gate
-        best = None
-        for _ in range(2):
-            e_ = ServingEngine(params, cfg, **kw)
-            t0 = time.perf_counter()
-            res_ = e_.run(mk())
-            w_ = time.perf_counter() - t0
-            if best is None or w_ < best[0]:
-                best = (w_, e_, res_)
-        wall, e, res = best
-        snap = e.metrics.snapshot()
-        tail = e.metrics.explain_tail()
-        stall = snap["components"].get("chunk_stall_ms")
-        row = {
-            "tokens_per_sec": round(useful / wall, 1),
-            "wall_s": round(wall, 3),
-            "steps": e.steps,
-            "prefill_dispatches": snap["prefill_dispatches"],
-            "ttft_p50_s": snap["ttft_p50_s"],
-            "ttft_p99_s": snap["ttft_p99_s"],
-            "tpot_p50_s": snap["tpot_p50_s"],
-            "chunk_stall_p99_ms": (stall["p99_ms"] if stall else None),
-            "tail_dominant": (tail["dominant_component"]
-                              if tail else None),
-            "tail_components_ms": (tail["components_mean_ms"]
-                                   if tail else None),
-        }
-        return row, sorted(r.tokens.tolist() for r in res.values())
-
-    phase, out_p = run(False)
-    mixed, out_m = run(True)
-    speedup = (round(mixed["tokens_per_sec"] / phase["tokens_per_sec"],
-                     3)
-               if phase["tokens_per_sec"] else None)
-    result = {
-        "provenance": "live",
-        "platform": platform,
-        "measured_at": time.strftime("%Y-%m-%d %H:%M UTC",
-                                     time.gmtime()),
-        "trace": {"seed": 999, "n_requests": n_req,
-                  "prefill_heavy_prompt": f"{s_max // 4}..{s_max // 2 - 1}",
-                  "decode_heavy_prompt": "4..12",
-                  "useful_tokens": useful, "prefill_chunk": chunk},
-        "phase_split": phase,
-        "ragged": mixed,
-        "speedup": speedup,
-        "greedy_identical": out_p == out_m,
-        "note": "ONE ragged wave per step (arrivals + chunk "
-                "continuations + decode; kernels/ragged_attention.py) "
-                "vs the prefill-then-decode phase-split scheduler; "
-                "chunk_stall vanishes by construction in mixed mode; "
-                "CPU harness runs masked attention in both arms — "
-                "stage 4c on chip is the A/B of record",
-    }
-    # floors asserted HERE so a mixed-mode regression can never bank a
-    # ragged_ab silently
-    assert result["greedy_identical"], (
-        "mixed-mode greedy outputs diverged from the phase-split engine")
-    assert mixed["chunk_stall_p99_ms"] in (None, 0.0), (
-        f"ragged arm still shows chunk_stall: {mixed}")
-    assert phase["chunk_stall_p99_ms"], (
-        "phase-split arm shows NO chunk_stall — the trace no longer "
-        "exercises chunked prefill and this A/B is vacuous")
-    assert speedup is not None and speedup > 0
-    # the CPU masked path computes the UNION wave width for every slot
-    # (a 16-token chunk in the wave makes each decode slot pay 16 rows
-    # of forward compute), so "no worse" is an on-chip claim — there
-    # the ragged kernel skips dead q rows and the dispatch savings are
-    # the point.  The CPU floor below is a regression backstop only
-    # (catches a mixed-mode scheduler pathology, not a kernel claim)
-    assert speedup >= 0.5, (
-        f"mixed mode collapsed to {speedup}x phase-split on the mixed "
-        f"trace — scheduler regression, not padding overhead: "
-        f"{phase} vs {mixed}")
-    if platform == "tpu":
-        # the strict no-worse floor, gated to the platform the ragged
-        # kernel actually runs on (stage 4c banks this on chip)
-        assert speedup >= 1.0, (
-            f"mixed mode shows no on-chip win (speedup {speedup}): "
-            f"{phase} vs {mixed}")
-    return result
-
-
-def _serve_moe_ab(cfg, dt_, platform, slots, s_max, vocab, n_req):
-    """MoE vs dense serving at EQUAL ACTIVE PARAMS (ISSUE 20): the
-    flagship MoE GPT (top-2 of 4 experts, expert_size = ffn_size /
-    top_k, so each token's FFN FLOPs match the dense arm exactly)
-    against a dense GPT of the same hidden/layers/heads, replaying the
-    same seeded trace through the same engine configuration.  Records
-    tok/s + TTFT p99 per arm and the MoE arm's expert telemetry
-    (per-expert load, imbalance max/mean, drop rate).
-
-    Floors asserted HERE (and re-asserted on the banked artifact in
-    test_serving): the MoE arm's engine outputs are GREEDY-IDENTICAL
-    to offline ``generate_fast`` on the same weights; at the serving
-    capacity factor the drop rate is EXACTLY zero (capacity
-    un-binding — so identity is unconditional, not luck); the
-    capacity-binding probe run shows drops while load+drop still
-    accounts for every (token, rank); and the attribution invariant
-    holds on the measured run.  Throughput parity is an on-chip claim
-    (CPU pays the full E-expert einsum regardless of routing; suite
-    stage 4c banks ``moe_ab`` on chip) — the CPU floor is a loose
-    scheduler-regression backstop only."""
-    from hetu_tpu.models import GPTConfig
-    from hetu_tpu.models.moe_decode import (MoEDecodeConfig,
-                                            init_moe_params,
-                                            moe_spec_of)
-    from hetu_tpu.models.gpt_decode import generate_fast
-    from hetu_tpu.serving import Request, ServingEngine
-
-    hidden, layers_n, heads = (cfg.hidden_size, cfg.num_hidden_layers,
-                               cfg.num_attention_heads)
-    E, K = 4, 2
-    mcfg = MoEDecodeConfig(
-        vocab_size=vocab, hidden_size=hidden,
-        num_hidden_layers=layers_n, num_attention_heads=heads,
-        max_position_embeddings=s_max, batch_size=slots,
-        seq_len=s_max, dropout_rate=0.0,
-        num_experts=E, top_k=K, capacity_factor=2.0, moe_every=2,
-        expert_size=cfg.ffn_size // K)
-    mparams = init_moe_params(mcfg, name="moe", seed=7)
-    dcfg = GPTConfig(
-        vocab_size=vocab, hidden_size=hidden,
-        num_hidden_layers=layers_n, num_attention_heads=heads,
-        max_position_embeddings=s_max, batch_size=slots,
-        seq_len=s_max, dropout_rate=0.0)
-    # dense twin: same naming contract and trunk scale; every block
-    # carries the full-width dense FFN, so per-token FFN FLOPs match
-    # the MoE arm's K * expert_size exactly
-    dparams = _dense_twin_params(dcfg, vocab, hidden, layers_n, s_max,
-                                 seed=7)
-
-    rng = np.random.RandomState(555)
-    trace = []
-    for _ in range(n_req):
-        P = int(rng.randint(4, 17))
-        trace.append((rng.randint(0, vocab, P).astype(np.int32),
-                      int(rng.randint(8, 25))))
-    useful = sum(g for _, g in trace)
-
-    def run(p_, c_, name_):
-        kw = dict(slots=slots, queue_limit=n_req, dtype=dt_,
-                  fast_path=True, paged=True, kv_block=8, name=name_)
-        mk = lambda: [Request(request_id=str(i),  # noqa: E731
-                              prompt=p, max_new_tokens=g, seed=i)
-                      for i, (p, g) in enumerate(trace)]
-        warm = ServingEngine(p_, c_, **kw)
-        warm.run(mk())
-        e = ServingEngine(p_, c_, **kw)
-        t0 = time.perf_counter()
-        res = e.run(mk())
-        wall = time.perf_counter() - t0
-        snap = e.metrics.snapshot()
-        row = {
-            "tokens_per_sec": round(useful / wall, 1),
-            "wall_s": round(wall, 3),
-            "ttft_p99_s": snap["ttft_p99_s"],
-            "tpot_p50_s": snap["tpot_p50_s"],
-            "steps": e.steps,
-        }
-        return row, e, res
-
-    dense_row, _, _ = run(dparams, dcfg, "moe")
-    moe_row, meng, mres = run(mparams, mcfg, "moe")
-    spec = moe_spec_of(mcfg)
-    n_moe = spec.moe_layers(layers_n)
-    load = meng.expert_load
-    moe_row.update({
-        "expert_load": load.tolist(),
-        "expert_imbalance": (round(float(meng.expert_imbalance), 4)
-                             if meng.expert_imbalance is not None
-                             else None),
-        "drop_rate": (round(float(meng.expert_drop_rate), 6)
-                      if meng.expert_drop_rate is not None else None),
-    })
-
-    # greedy identity vs offline on a sub-trace (the full trace's
-    # offline replay would double the bench wall time for no extra
-    # signal — test_moe_serving.py pins the full matrix)
-    ident = True
-    for i, (p, g) in enumerate(trace[:4]):
-        off = generate_fast(mparams, mcfg, [list(map(int, p))], g,
-                            temperature=0.0, seed=0, dtype=dt_,
-                            name="moe")
-        eng_toks = [int(t) for t in
-                    np.asarray(mres[str(i)].tokens)[len(p):]]
-        if eng_toks != [int(t) for t in np.asarray(off)[0][len(p):]]:
-            ident = False
-            break
-
-    # capacity-binding probe: a tiny capacity factor MUST drop (the
-    # trace contract stage 00l asserts on chip) while the accounting
-    # invariant still closes
-    bcfg = MoEDecodeConfig(
-        vocab_size=vocab, hidden_size=hidden,
-        num_hidden_layers=layers_n, num_attention_heads=heads,
-        max_position_embeddings=s_max, batch_size=slots,
-        seq_len=s_max, dropout_rate=0.0,
-        num_experts=E, top_k=K, capacity_factor=0.25, moe_every=2,
-        expert_size=cfg.ffn_size // K)
-    _, beng, _ = run(mparams, bcfg, "moe")
-    binding = {
-        "capacity_factor": 0.25,
-        "drop_rate": (round(float(beng.expert_drop_rate), 6)
-                      if beng.expert_drop_rate is not None else None),
-        "invariant_ok": int(beng.expert_load.sum()
-                            + beng.expert_drops.sum())
-        == beng.moe_tokens * K * n_moe,
-    }
-
-    speedup = (round(moe_row["tokens_per_sec"]
-                     / dense_row["tokens_per_sec"], 3)
-               if dense_row["tokens_per_sec"] else None)
-    result = {
-        "provenance": "live",
-        "platform": platform,
-        "measured_at": time.strftime("%Y-%m-%d %H:%M UTC",
-                                     time.gmtime()),
-        "trace": {"seed": 555, "n_requests": n_req,
-                  "prompt_len": "4..16", "new_tokens": "8..24",
-                  "useful_tokens": useful},
-        "equal_active_params": {
-            "experts": E, "top_k": K, "moe_every": 2,
-            "expert_size": mcfg.expert_size,
-            "dense_ffn_size": dcfg.ffn_size,
-            "active_ffn_per_token": K * mcfg.expert_size,
-        },
-        "dense": dense_row,
-        "moe": moe_row,
-        "speedup_vs_dense": speedup,
-        "greedy_identical": ident,
-        "capacity_binding": binding,
-        "note": "equal active params: top_k * expert_size == dense "
-                "ffn_size; CPU pays the full E-expert einsum whatever "
-                "the routing, so tok/s parity is an on-chip claim — "
-                "suite stage 4c banks moe_ab on chip",
-    }
-    # floors asserted HERE so a routing regression can never bank a
-    # moe_ab silently (re-asserted on the artifact in test_serving)
-    assert ident, "MoE engine diverged from offline generate_fast"
-    assert moe_row["drop_rate"] == 0.0, (
-        f"serving capacity factor binds on the bench trace "
-        f"(drop_rate={moe_row['drop_rate']}) — identity is luck")
-    assert moe_row["expert_imbalance"] is not None \
-        and moe_row["expert_imbalance"] >= 1.0
-    assert sum(moe_row["expert_load"]) > 0
-    assert binding["drop_rate"] > 0, (
-        "cf=0.25 probe dropped nothing — capacity is not binding and "
-        "the drop path is untested")
-    assert binding["invariant_ok"], (
-        "load+drop no longer accounts for every (token, rank) under "
-        "binding capacity")
-    assert speedup is not None and speedup > 0.05, (
-        f"MoE arm collapsed to {speedup}x dense — scheduler/dispatch "
-        f"regression, not expert-compute cost: {dense_row} vs "
-        f"{moe_row}")
-    return result
-
-
-def _dense_twin_params(dcfg, vocab, hidden, layers_n, s_max, seed):
-    """Dense-GPT params in the serving naming contract, seeded like the
-    MoE arm's shared trunk (attention/embeddings match scale, FFN
-    carries the full dense width)."""
-    rng = np.random.default_rng(seed)
-    D, F = hidden, dcfg.ffn_size
-
-    def r(*shape):
-        return (rng.standard_normal(shape) * 0.02).astype(np.float32)
-
-    p = {"moe_wte_table": r(vocab, D),
-         "moe_wpe": r(s_max, D),
-         "moe_ln_f_scale": np.ones(D, np.float32),
-         "moe_ln_f_bias": np.zeros(D, np.float32)}
-    for i in range(layers_n):
-        us = f"moe_h{i}"
-        p.update({
-            f"{us}_ln1_scale": np.ones(D, np.float32),
-            f"{us}_ln1_bias": np.zeros(D, np.float32),
-            f"{us}_ln2_scale": np.ones(D, np.float32),
-            f"{us}_ln2_bias": np.zeros(D, np.float32),
-            f"{us}_attn_q_weight": r(D, D),
-            f"{us}_attn_q_bias": np.zeros(D, np.float32),
-            f"{us}_attn_k_weight": r(D, D),
-            f"{us}_attn_k_bias": np.zeros(D, np.float32),
-            f"{us}_attn_v_weight": r(D, D),
-            f"{us}_attn_v_bias": np.zeros(D, np.float32),
-            f"{us}_attn_proj_weight": r(D, D),
-            f"{us}_attn_proj_bias": np.zeros(D, np.float32),
-            f"{us}_ffn_wi_weight": r(D, F),
-            f"{us}_ffn_wi_bias": np.zeros(F, np.float32),
-            f"{us}_ffn_wo_weight": r(F, D),
-            f"{us}_ffn_wo_bias": np.zeros(D, np.float32),
-        })
-    return p
-
-
-def _serve_phase_ab(params, cfg, dt_, reduced):
-    """Per-phase micro A/B outside the scheduler: (a) the fused decode
-    step, masked vs ragged, at 25%/50% cache fill — the ragged kernel
-    fetches ceil(filled/block_k) KV blocks, so its step time scales
-    with fill while masked-S_max stays flat; (b) one-request prefill,
-    teacher-forced scan vs flash, at prompt length 128 (the acceptance
-    floor).  Engine-free: raw serve_*_fn calls on a standalone cache."""
-    import jax
-    from hetu_tpu.models.gpt_decode import (
-        serve_decode_fn, serve_prefill_batch_fn, serve_prefill_fn,
-    )
-    from hetu_tpu.serving import KVCacheManager
-
-    Dh = cfg.hidden_size // cfg.num_attention_heads
-    kv = KVCacheManager(
-        layers=cfg.num_hidden_layers, heads=cfg.num_attention_heads,
-        head_dim=Dh, slots=cfg.batch_size,
-        max_seq_len=cfg.max_position_embeddings, dtype=dt_)
-    cfg_tuple = ("srv", cfg.num_hidden_layers, cfg.num_attention_heads,
-                 Dh, kv.s_max)
-    B = kv.n_slots
-    iters = 5 if reduced else 30
-    tok = np.ones(B, np.int32)
-    temps = np.zeros(B, np.float32)
-    topks = np.zeros(B, np.int32)
-    keys = np.stack([np.asarray(jax.random.PRNGKey(i), np.uint32)
-                     for i in range(B)])
-
-    def time_decode(attn, filled):
-        fn = serve_decode_fn(donate=False, attn=attn)
-        pos = np.full(B, filled - 1, np.int32)
-        out = fn(params, cfg_tuple, kv.cache_k, kv.cache_v, pos, tok,
-                 temps, topks, keys)
-        jax.block_until_ready(out[0])              # warm the compile
-        t0 = time.perf_counter()
-        for _ in range(iters):
-            out = fn(params, cfg_tuple, kv.cache_k, kv.cache_v, pos,
-                     tok, temps, topks, keys)
-        jax.block_until_ready(out[0])
-        return round((time.perf_counter() - t0) / iters * 1e3, 3)
-
-    decode_rows = []
-    for frac in (0.25, 0.5):
-        filled = max(1, int(kv.s_max * frac))
-        masked_ms = time_decode("masked", filled)
-        ragged_ms = time_decode("ragged", filled)
-        decode_rows.append({
-            "fill": frac, "filled_len": filled, "s_max": kv.s_max,
-            "masked_ms": masked_ms, "ragged_ms": ragged_ms,
-            "ragged_speedup": (round(masked_ms / ragged_ms, 3)
-                               if ragged_ms else None)})
-
-    P = min(128, kv.s_max // 2)
-    prompt = np.arange(1, P + 1, dtype=np.int32) % cfg.vocab_size
-    key = np.asarray(jax.random.PRNGKey(0), np.uint32)
-
-    def time_prefill(flash):
-        if flash:
-            fn = serve_prefill_batch_fn(donate=False)
-            args = (params, cfg_tuple, kv.cache_k, kv.cache_v,
-                    np.zeros(1, np.int32), prompt[None],
-                    np.asarray([P], np.int32), np.zeros(1, np.float32),
-                    np.zeros(1, np.int32), key[None])
-        else:
-            fn = serve_prefill_fn(donate=False)
-            args = (params, cfg_tuple, kv.cache_k, kv.cache_v,
-                    np.int32(0), prompt, np.int32(P),
-                    np.float32(0.0), np.int32(0), key)
-        out = fn(*args)
-        jax.block_until_ready(out[0])
-        t0 = time.perf_counter()
-        for _ in range(iters):
-            out = fn(*args)
-        jax.block_until_ready(out[0])
-        return round((time.perf_counter() - t0) / iters * 1e3, 3)
-
-    scan_ms = time_prefill(False)
-    flash_ms = time_prefill(True)
-    return {
-        "decode": decode_rows,
-        "prefill": {"prompt_len": P, "scan_ms": scan_ms,
-                    "flash_ms": flash_ms,
-                    "flash_speedup": (round(scan_ms / flash_ms, 3)
-                                      if flash_ms else None)},
-    }
-
-
 _EMBED_SERVE_FILE = os.path.join(_HERE, "BENCH_EMBED_SERVE.json")
 
 
@@ -3039,26 +1271,6 @@ def main():
             **({"not_written": art["not_written"]}
                if "not_written" in art else
                {"decode_file": os.path.basename(_DECODE_FILE)})}))
-        return
-
-    if envvars.get_bool("HETU_BENCH_SERVE"):
-        art = bench_serve(platform, reduced)
-        cont = art["continuous"]
-        print(json.dumps({
-            "metric": "serve_continuous_tokens_per_sec",
-            "value": cont["tokens_per_sec"], "unit": "tokens/sec",
-            # vs_baseline here = speedup over static batching on the
-            # same trace (the serving acceptance ratio, not the north
-            # star target)
-            "vs_baseline": art["speedup"], "platform": platform,
-            "static_tokens_per_sec":
-                art["static_baseline"]["tokens_per_sec"],
-            "ttft_p50_s": cont["ttft_p50_s"],
-            "ttft_p99_s": cont["ttft_p99_s"],
-            "mean_batch_occupancy": cont["mean_batch_occupancy"],
-            **({"not_written": art["not_written"]}
-               if "not_written" in art else
-               {"serve_file": os.path.basename(_SERVE_FILE)})}))
         return
 
     if envvars.get_bool("HETU_BENCH_EMBED_SERVE"):

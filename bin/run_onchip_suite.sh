@@ -724,97 +724,6 @@ if ! grep -q 'kvtier_ps_killed' "$LOG/kvtier_failure.jsonl"; then
   exit 1
 fi
 
-# 00j. mixed-mode ragged-dispatch gate (ISSUE 18): one CPU process
-#      replays a chunked-prefill + decode trace through the engine
-#      twice — phase-split (ragged=False) and mixed-mode (ragged=True,
-#      arrivals + chunk continuations + decode packed into ONE ragged
-#      wave per step) — and requires greedy TOKEN-IDENTICAL outputs,
-#      zero chunk_stall in the mixed arm (folded by construction), and
-#      a serve stream that passes hetu_trace --check (incl. the
-#      spec-attribution rule: a third arm runs spec=2 THROUGH the
-#      mixed wave at acceptance 1.0).  The on-chip HETU_BENCH_SERVE
-#      run (stage 4c) banks ragged_ab with the native kernel — that
-#      run is the A/B of record; this gate proves the path first.
-run mixed_gate 900 env HETU_TELEMETRY=1 \
-    HETU_TELEMETRY_LOG="$LOG/mixed_trace.jsonl" JAX_PLATFORMS=cpu \
-    python - <<'PYEOF'
-import numpy as np
-import hetu_tpu as ht  # noqa: F401
-from hetu_tpu.models import GPTConfig
-from hetu_tpu.serving import Request, ServingEngine
-
-rng, hd, L = np.random.RandomState(0), 16, 2
-p = {"mxg_wte_table": rng.randn(61, hd) * 0.05,
-     "mxg_wpe": rng.randn(64, hd) * 0.05,
-     "mxg_ln_f_scale": np.ones(hd), "mxg_ln_f_bias": np.zeros(hd)}
-for i in range(L):
-    for w, shp in [("attn_q", (hd, hd)), ("attn_k", (hd, hd)),
-                   ("attn_v", (hd, hd)), ("attn_proj", (hd, hd)),
-                   ("ffn_wi", (hd, 4 * hd)), ("ffn_wo", (4 * hd, hd))]:
-        p[f"mxg_h{i}_{w}_weight"] = rng.randn(*shp) * 0.05
-        p[f"mxg_h{i}_{w}_bias"] = np.zeros(shp[1])
-    for ln in ("ln1", "ln2"):
-        p[f"mxg_h{i}_{ln}_scale"] = np.ones(hd)
-        p[f"mxg_h{i}_{ln}_bias"] = np.zeros(hd)
-cfg = GPTConfig(vocab_size=61, hidden_size=hd, num_hidden_layers=L,
-                num_attention_heads=2, max_position_embeddings=64,
-                batch_size=1, seq_len=64, dropout_rate=0.0)
-tr = np.random.RandomState(18)
-# long prompts (> chunk) riding next to short decode streams: every
-# step mixes a chunk continuation with decode rows — the wave shape
-# the phase barrier penalizes
-mk = lambda: [Request(prompt=[int(t) for t in
-                              tr.randint(0, 61, 4 + 3 * (s % 5))],
-                      max_new_tokens=6 + (s % 3) * 4, seed=s)
-              for s in range(8)]
-kw = dict(slots=3, paged=True, kv_block=8, prefill_chunk=4,
-          queue_limit=16)
-tr = np.random.RandomState(18)
-plain = ServingEngine(p, cfg, **kw, ragged=False).run(mk())
-tr = np.random.RandomState(18)
-eng = ServingEngine(p, cfg, **kw, ragged=True)
-res = eng.run(mk())
-assert eng.ragged and eng.metrics.mixed_mode
-a = sorted(r.tokens.tolist() for r in plain.values())
-b = sorted(r.tokens.tolist() for r in res.values())
-assert a == b, "mixed-mode greedy diverged from the phase-split engine"
-snap = eng.metrics.snapshot()
-stall = snap["components"].get("chunk_stall_ms")
-assert stall is None or stall["p99_ms"] == 0.0, stall
-assert eng.prefill_chunks > 0, "trace never exercised chunked prefill"
-# spec THROUGH the mixed wave at acceptance 1.0 (post-draft layer
-# output-zeroed): identity must hold and the serve stream must pass
-# the spec-attribution rule downstream
-sp = dict(p)
-for wn in ("attn_proj_weight", "attn_proj_bias",
-           "ffn_wo_weight", "ffn_wo_bias"):
-    sp[f"mxg_h1_{wn}"] = np.zeros_like(p[f"mxg_h1_{wn}"])
-tr = np.random.RandomState(18)
-sp_plain = ServingEngine(sp, cfg, **kw, ragged=False).run(mk())
-tr = np.random.RandomState(18)
-se = ServingEngine(sp, cfg, **kw, ragged=True, spec=2,
-                   spec_adapt=False, spec_draft_layers=1)
-sp_res = se.run(mk())
-sa = sorted(r.tokens.tolist() for r in sp_plain.values())
-sb = sorted(r.tokens.tolist() for r in sp_res.values())
-assert sa == sb, "mixed-mode spec greedy diverged"
-assert se.spec_accepted == se.spec_proposed > 0, \
-    (se.spec_accepted, se.spec_proposed)
-print("mixed gate OK: identity over", len(res), "requests,",
-      "chunks", eng.prefill_chunks, "spec accepted",
-      se.spec_accepted, "/", se.spec_proposed)
-PYEOF
-if ! grep -q 'mixed gate OK' "$LOG/mixed_gate.log"; then
-  echo "mixed-mode ragged gate FAILED — see $LOG/mixed_gate.log" >&2
-  exit 1
-fi
-python bin/hetu_trace.py "$LOG/mixed_trace.jsonl" --check \
-    > "$LOG/mixed_trace_contract.log" || {
-  echo "mixed-mode trace contract check FAILED — see" \
-       "$LOG/mixed_trace_contract.log" >&2
-  exit 1
-}
-
 # 00k. concurrency gate (ISSUE 19): the sanitizer itself, on CPU.
 #      Green half: the deterministic interleaving fuzzer must be a
 #      pure function of its seed (planted lost-update race reproduces
@@ -956,10 +865,7 @@ fi
 #      (routed + dropped == tokens x top_k x MoE layers).  A second,
 #      capacity-starved run (cf=0.25) must actually DROP and its serve
 #      stream must still pass hetu_trace --check — the MoE attribution
-#      rule is proven against overflow, not just the easy case.  The
-#      on-chip HETU_BENCH_SERVE run (stage 4c) banks moe_ab with
-#      native kernels — that run is the A/B of record; this gate
-#      proves the path before chip time is spent.
+#      rule is proven against overflow, not just the easy case.
 run moe_gate 900 env HETU_TELEMETRY=1 \
     HETU_TELEMETRY_LOG="$LOG/moe_trace.jsonl" JAX_PLATFORMS=cpu \
     python - <<'PYEOF'
@@ -1037,9 +943,7 @@ python bin/hetu_trace.py "$LOG/moe_trace.jsonl" --check \
 #     logits == target logits), retire every request in fewer waves
 #     than tokens, and leave a serve stream that passes the
 #     spec-attribution rule (hetu_trace --check: accepted + bonus + 1
-#     == n_generated per request).  The on-chip HETU_BENCH_SERVE run
-#     (stage 4c) banks spec_ab with native kernels — that run is the
-#     A/B of record; this gate proves the path before it is trusted.
+#     == n_generated per request).
 run spec_gate 900 env HETU_TELEMETRY=1 \
     HETU_TELEMETRY_LOG="$LOG/spec_trace.jsonl" JAX_PLATFORMS=cpu \
     python - <<'PYEOF'
@@ -1123,57 +1027,7 @@ run calibration 3600 python -m hetu_tpu.planner.chip_calibration
 # 4b. KV-cached serving throughput (BENCH_DECODE.json)
 HETU_BENCH_DECODE=1 run decode 3600 python bench.py
 
-# 4c. continuous-batching engine vs static batching on the seeded
-#     mixed-length trace, PLUS the serving fast-path A/B — masked
-#     reference vs ragged (flash prefill + paged decode kernel) on the
-#     mixed AND prefill-heavy traces with per-phase prefill/decode
-#     timings, and the phase micro A/B (decode step at 25%/50% fill,
-#     prefill scan-vs-flash at P=128) — all in one invocation
-#     (BENCH_SERVE.json fast_path_ab / prefill_heavy / phase_ab; this
-#     on-chip run is the A/B of record — the CPU harness emulates the
-#     kernels in interpret mode), PLUS the paged-vs-contiguous KV A/B
-#     of record (paged_ab: prefix-heavy trace at equal cache bytes —
-#     block-table pool + prefix sharing vs slot rows; on chip the
-#     block-table decode kernel runs native and HETU_KV_BLOCK=auto
-#     selects paged), PLUS the speculative-decoding A/B of record
-#     (spec_ab: draft-propose / batched-verify vs plain decoding at
-#     equal slots, acceptance-rate sweep via temperature, greedy
-#     token-identity and the tok/s floor asserted in-bench; the
-#     multi-token verify kernel runs native here — the CPU stage-4e
-#     gate only proves the path), PLUS the fleet prefix A/B
-#     (fleet_prefix_ab: affinity-only vs PrefixDirectory routing vs
-#     directory + prefill/decode roles with KV handoff on a
-#     prefix-storm trace at equal fleet slots — tok/s and TTFT p99
-#     floors and greedy token-identity asserted in-bench; the CPU
-#     stage-00e gate proves the chaos-kill degradation path), PLUS the
-#     mixed-mode ragged-dispatch A/B of record (ragged_ab: ONE ragged
-#     wave per step — arrivals + chunk continuations + spec-verify +
-#     decode through kernels/ragged_attention.py — vs the phase-split
-#     scheduler on a prefill-heavy + decode-heavy mixed trace; greedy
-#     token-identity and the chunk_stall==0 floor asserted in-bench
-#     everywhere, and the strict tok/s no-worse floor binds HERE
-#     because it is gated to TPU — the CPU harness pays union-width
-#     padding in the masked path and the stage-00j gate only proves
-#     the path), PLUS the MoE-vs-dense A/B of record (moe_ab: top-2 of
-#     4 experts at EQUAL ACTIVE PARAMS — expert_size = ffn_size /
-#     top_k — on the same trace/engine config; tok/s + TTFT p99 per
-#     arm, per-expert load, imbalance and drop rate in the artifact;
-#     greedy identity vs offline and the zero-drop-at-serving-cf floor
-#     asserted in-bench, capacity-binding probe must drop with the
-#     accounting invariant intact; the CPU harness pays the full
-#     E-expert einsum whatever the routing, so THIS on-chip row is the
-#     throughput number of record — the stage-00l gate only proves the
-#     path).  Runs after decode so the scan compile is already in
-#     the shared compilation cache.
-HETU_BENCH_SERVE=1 run serve 3600 python bench.py
-
-# 4d. quantized-bytes A/Bs of record (ISSUE 9).  The serving half rides
-#     stage 4c's invocation (BENCH_SERVE.json quant_ab: int8 KV vs f32
-#     at equal HBM bytes — peak concurrent slots + tok/s, the
-#     tolerance-gated greedy top-1 check, and the >=1.9x slot-capacity
-#     floor asserted in-bench; on chip the int8 decode kernels run
-#     native instead of interpret mode, making THIS the tok/s number of
-#     record).  This stage measures the training half: int8 PS
+# 4d. quantized-bytes A/B of record (ISSUE 9), the training half: int8 PS
 #     push/pull vs the exact f32 wire — bytes via the PR 5
 #     ps.rpc.bytes_* counters + step time, >=3.5x reduction asserted —
 #     merged into BENCH_PS_SCALING.json as its quant_ab section.
@@ -1209,4 +1063,4 @@ HETU_BENCH_FORCE_FLASH=1 HETU_BENCH_CONFIGS=bert4l \
 
 echo "done; artifacts: BENCH_MATRIX.json SWEEP_BERT_BASE.json \
 BENCH_CTR_ROWS.json CALIBRATION_TPU.json BENCH_DECODE.json \
-BENCH_SERVE.json (logs in $LOG)"
+(logs in $LOG)"

@@ -214,14 +214,14 @@ def serve_phase(params, cfg, prompt_lens, new_tokens, seed):
                 f"reference {want.tolist()}")
     reference_s = time.perf_counter() - t0
 
-    kernels = pallas_kernels(lowered_mixed_step(eng)) if eng.ragged else []
+    kernels = pallas_kernels(lowered_mixed_step(eng))
     snap = eng.metrics.snapshot()
     return {
         "phase": "serve", "requests": len(prompts),
         "prompt_lens": list(prompt_lens), "new_tokens": new_tokens,
         "tokens_out": sum(r.n_generated for r in done.values()),
         "engine": {"fast_path": bool(eng.fast_path),
-                   "ragged": bool(eng.ragged), "paged": bool(eng.paged),
+                   "paged": bool(eng.paged),
                    "kv_block": getattr(eng.kv, "block", 0),
                    "kv_dtype": str(eng.kv.quant or eng.params[
                        f"{eng._name}_wte_table"].dtype),
@@ -372,8 +372,7 @@ def main(argv=None):
         emit(rec)
         rec = serve_phase(ex.var_values, cfg, PROMPT_LENS, NEW_TOKENS,
                           args.seed)
-        want = {"fast_path": True, "ragged": True, "paged": True,
-                "kv_block": 16}
+        want = {"fast_path": True, "paged": True, "kv_block": 16}
         got = {k: rec["engine"][k] for k in want}
         if got != want:
             raise RuntimeError(f"serve: engine defaults on TPU are "

@@ -38,9 +38,7 @@ __all__ = ["JitAuditError", "register_engine", "registered",
            "compiles", "reset"]
 
 # the jitted-step attributes an engine may carry (absent/None skipped)
-_ENGINE_FNS = ("_prefill", "_prefill_chunk", "_prefill_batch",
-               "_decode", "_mixed", "_verify", "_propose",
-               "_draft_prefill")
+_ENGINE_FNS = ("_mixed", "_propose", "_draft_prefill")
 
 _ENGINES: list = []       # [(label, weakref-to-engine)]
 _N_REGISTERED = 0
@@ -69,8 +67,9 @@ def registered() -> list:
 
 
 def _cache_size(fn):
+    # the engine binds its wave's static arguments with functools.partial
     try:
-        return int(fn._cache_size())
+        return int(getattr(fn, "func", fn)._cache_size())
     except Exception:
         return None
 

@@ -242,24 +242,17 @@ _reg("HETU_CACHE_BACKLOG_ROWS", "int", 100000,
 # serving
 # --------------------------------------------------------------------- #
 _reg("HETU_SERVE_FAST", "str", "auto",
-     "Serving fast path: 1 forces flash-prefill + ragged decode "
-     "kernels, 0 the masked/scan reference, auto = fast on TPU.",
+     "Serving attention: 1 forces the Pallas kernels (flash prefill "
+     "offline, the ragged kernel in the mixed wave), 0 the masked/scan "
+     "reference, auto = kernels on TPU.",
      "serving")
-_reg("HETU_SERVE_RAGGED", "str", "auto",
-     "Mixed-mode ragged dispatch: 1 packs arrivals, chunk "
-     "continuations, spec-verify, and decode streams into ONE ragged "
-     "wave per engine step (per-slot q_len; no prefill/decode phase "
-     "barrier, chunk_stall ~ 0), 0 keeps the phase-split scheduler, "
-     "auto = mixed on TPU.  Greedy outputs are token-identical either "
-     "way.", "serving")
 _reg("HETU_SERVE_LOG", "path", None,
      "JSONL sink for serving engine events (same record shape as "
      "HETU_FAILURE_LOG).", "serving")
 _reg("HETU_KV_BLOCK", "str", "auto",
      "Paged KV cache: an integer enables the block-table paged "
      "allocator at that block size (tokens per block), 0 pins the "
-     "slot-contiguous layout, auto = paged with block 16 on TPU, "
-     "contiguous elsewhere.", "serving")
+     "slot-contiguous layout, auto = paged with block 16.", "serving")
 _reg("HETU_KV_PREFIX_SHARE", "bool", True,
      "Paged KV: refcounted copy-on-write sharing of common prompt "
      "prefixes — N requests with the same system prompt store its KV "
@@ -495,8 +488,6 @@ _reg("HETU_BENCH_SWEEP", "bool", False,
      "Run the (batch x attention x head) ablation sweep.", "bench")
 _reg("HETU_BENCH_DECODE", "bool", False,
      "Run the KV-cached decode benchmark.", "bench")
-_reg("HETU_BENCH_SERVE", "bool", False,
-     "Run the continuous-batching serving benchmark.", "bench")
 _reg("HETU_BENCH_EMBED_SERVE", "bool", False,
      "Run the embedding-cache recommendation-serving benchmark "
      "(zipf cache-limit ladder, int8-pull A/B, PS-kill chaos).",

@@ -1,6 +1,6 @@
 """Pallas TPU kernels for the hot ops: fused flash attention (training
-and prefill), the phase-split paged decode/verify kernels, and the
-mixed-mode ragged kernel the serving engine's TPU default runs."""
+and prefill), the contiguous-cache decode/verify kernels, and the ragged
+kernel that scores the serving engine's wave on a TPU."""
 
 from . import flash_attention  # noqa: F401
 from . import decode_attention  # noqa: F401

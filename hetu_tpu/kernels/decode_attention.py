@@ -1,4 +1,9 @@
-"""Ragged paged decode-attention Pallas kernel for the serving engine.
+"""Ragged decode- and verify-attention Pallas kernels over a
+slot-CONTIGUOUS cache ``[B, S_max, H, Dh]`` (``_decode_step`` and
+``_verify_step`` with ``attn="ragged"``).  The serving engine's wave,
+paged pool included, is ``kernels/ragged_attention.py``, which computes
+both of these at q_len 1 and k+1 and shares this file's online-softmax
+update.
 
 One fused decode step attends q_len=1 per cache slot over that slot's
 OWN filled prefix.  The masked reference path (``_decode_step``'s
@@ -35,7 +40,7 @@ what lets ~3.7x more tokens fit per HBM byte.
 MULTI-TOKEN VERIFY (speculative decoding, ISSUE 10): the ``*_verify_*``
 kernels generalize q_len=1 to a ``k+1``-position q-block per slot —
 the target model's batched verification of a draft's proposals.  Same
-grid, same scalar-prefetched lengths/tables, same revisit-index DMA
+grid, same scalar-prefetched lengths, same revisit-index DMA
 skipping; the q-block is causal INSIDE itself (query ``jq`` at absolute
 position ``lens - q_len + jq`` admits kv positions up to itself), so
 one kernel call scores all proposed positions exactly as ``k+1``
@@ -203,131 +208,6 @@ def paged_decode_attention(q, k, v, lengths, *, block_k=128,
         interpret=interpret,
     )(lengths.astype(jnp.int32), *operands)
     return out[:, 0]
-
-
-def _block_decode_kernel(lens_ref, bt_ref, q_ref, k_ref, v_ref, o_ref,
-                         m_ref, l_ref, acc_ref, *, scale, bk, n_kv):
-    """Block-table twin of ``_decode_kernel``: same online-softmax body
-    (the extra scalar-prefetch ref is the block table, consumed only by
-    the index maps — kv positions are still ``j * bk + iota`` because
-    table entry j holds the sequence's j-th block)."""
-    del bt_ref
-    _decode_kernel(lens_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
-                   acc_ref, scale=scale, bk=bk, n_kv=n_kv)
-
-
-def _block_decode_kernel_int8(lens_ref, bt_ref, q_ref, k_ref, ks_ref,
-                              v_ref, vs_ref, o_ref, m_ref, l_ref,
-                              acc_ref, *, scale, bk, n_kv):
-    """Block-table twin of ``_decode_kernel_int8``: int8 pool blocks +
-    per-(position, head) scale blocks, both routed through the table's
-    index maps, dequantized inside the online-softmax loop."""
-    del bt_ref
-    _decode_kernel_int8(lens_ref, q_ref, k_ref, ks_ref, v_ref, vs_ref,
-                        o_ref, m_ref, l_ref, acc_ref, scale=scale,
-                        bk=bk, n_kv=n_kv)
-
-
-def paged_block_decode_attention(q, pool_k, pool_v, lengths,
-                                 block_tables, *, k_scale=None,
-                                 v_scale=None, interpret=None):
-    """One decode position per slot over a BLOCK-TABLE paged KV pool.
-
-    q: [B, H, Dh]; pool_k, pool_v: [N_blocks, bs, H, Dh] — the SHARED
-    block pool (one layer's ``cache_k[i]``), where a sequence's KV
-    lives in the pool blocks its table names; block_tables: [B, T]
-    int32 — entry (b, j) is the pool block holding slot b's positions
-    [j*bs, (j+1)*bs); lengths: [B] int32 filled counts (0 = inert slot,
-    returns zeros).  Dead table entries may hold any valid pool index
-    (the engine points them at scratch block 0).
-
-    Grid (slots, table entries), both scalar-prefetched: the kv index
-    map reads ``block_tables[b, j]`` so each slot DMAs exactly its own
-    ``ceil(lengths[b]/bs)`` live blocks from the pool — entries past
-    the filled length revisit the last live block (repeated index =
-    DMA skipped) and their compute is skipped with ``@pl.when``.
-    Shared prefix blocks are fetched per-slot but STORED once in HBM,
-    which is the capacity win this kernel exists for.  f32
-    online-softmax over bf16 pools, matching ``paged_decode_attention``.
-
-    INT8 pools (``HETU_KV_QUANT``): pass the pools as int8 with
-    ``k_scale``/``v_scale`` [N_blocks, bs, H] f32 — the scale blocks
-    ride the same table index maps (dead entries skip their DMA too)
-    and dequantize inside the online-softmax loop, so the capacity win
-    compounds ~3.7x on top of prefix sharing.
-    """
-    B, H, Dh = q.shape
-    bs = pool_k.shape[1]
-    T = block_tables.shape[1]
-    scale = Dh ** -0.5
-    if interpret is None:
-        interpret = _use_interpret()
-    quantized = k_scale is not None
-
-    def kv_idx(b, j, lens_ref, bt_ref):
-        last = jnp.maximum(lens_ref[b] - 1, 0) // bs
-        return (bt_ref[b, jnp.minimum(j, last)], 0, 0, 0)
-
-    def sc_idx(b, j, lens_ref, bt_ref):
-        last = jnp.maximum(lens_ref[b] - 1, 0) // bs
-        return (bt_ref[b, jnp.minimum(j, last)], 0, 0)
-
-    if quantized:
-        kernel = _block_decode_kernel_int8
-        in_specs = [
-            pl.BlockSpec((1, 1, H, Dh),
-                         lambda b, j, lens, bt: (b, 0, 0, 0)),
-            pl.BlockSpec((1, bs, H, Dh), kv_idx),
-            pl.BlockSpec((1, bs, H), sc_idx),
-            pl.BlockSpec((1, bs, H, Dh), kv_idx),
-            pl.BlockSpec((1, bs, H), sc_idx),
-        ]
-        operands = (q[:, None], pool_k, k_scale, pool_v, v_scale)
-    else:
-        kernel = _block_decode_kernel
-        in_specs = [
-            pl.BlockSpec((1, 1, H, Dh),
-                         lambda b, j, lens, bt: (b, 0, 0, 0)),
-            pl.BlockSpec((1, bs, H, Dh), kv_idx),
-            pl.BlockSpec((1, bs, H, Dh), kv_idx),
-        ]
-        operands = (q[:, None], pool_k, pool_v)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B, T),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, H, Dh),
-                               lambda b, j, lens, bt: (b, 0, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((H, _LANES), jnp.float32),
-            pltpu.VMEM((H, _LANES), jnp.float32),
-            pltpu.VMEM((H, Dh), jnp.float32),
-        ],
-    )
-    out = pl.pallas_call(
-        functools.partial(kernel, scale=scale, bk=bs, n_kv=T),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, 1, H, Dh), q.dtype),
-        name="paged_block_decode",
-        interpret=interpret,
-    )(lengths.astype(jnp.int32), block_tables.astype(jnp.int32),
-      *operands)
-    return out[:, 0]
-
-
-def paged_block_decode_reference(q, pool_k, pool_v, lengths,
-                                 block_tables, k_scale=None,
-                                 v_scale=None):
-    """Gather-then-mask oracle for the block-table kernel: the
-    decode (q_len 1) degenerate of the unified ragged paged reference
-    (dequantizing int8 pools through their gathered scale planes — the
-    masked-gather reference path the engine runs off-TPU)."""
-    from .ragged_attention import ragged_paged_reference
-    ones = jnp.ones_like(lengths)
-    return ragged_paged_reference(q[:, None], pool_k, pool_v, lengths,
-                                  ones, block_tables, k_scale,
-                                  v_scale)[:, 0]
 
 
 # ------------------------------------------------------------------- #
@@ -525,96 +405,6 @@ def paged_verify_attention(q, k, v, lengths, q_lens, *, block_k=128,
     )(lengths.astype(jnp.int32), q_lens.astype(jnp.int32), *operands)
 
 
-def _block_verify_kernel(lens_ref, qlens_ref, bt_ref, q_ref, k_ref,
-                         v_ref, o_ref, m_ref, l_ref, acc_ref, *, scale,
-                         bk, n_kv, nq):
-    del bt_ref
-    _verify_kernel(lens_ref, qlens_ref, q_ref, k_ref, v_ref, o_ref,
-                   m_ref, l_ref, acc_ref, scale=scale, bk=bk,
-                   n_kv=n_kv, nq=nq)
-
-
-def _block_verify_kernel_int8(lens_ref, qlens_ref, bt_ref, q_ref,
-                              k_ref, ks_ref, v_ref, vs_ref, o_ref,
-                              m_ref, l_ref, acc_ref, *, scale, bk,
-                              n_kv, nq):
-    del bt_ref
-    _verify_kernel_int8(lens_ref, qlens_ref, q_ref, k_ref, ks_ref,
-                        v_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref,
-                        scale=scale, bk=bk, n_kv=n_kv, nq=nq)
-
-
-def paged_block_verify_attention(q, pool_k, pool_v, lengths, q_lens,
-                                 block_tables, *, k_scale=None,
-                                 v_scale=None, interpret=None):
-    """``paged_verify_attention`` over the BLOCK-TABLE paged pool: the
-    verify q-block reads each slot's live pool blocks through its
-    scalar-prefetched table row, exactly like
-    :func:`paged_block_decode_attention` (dead entries revisit = DMA
-    skipped; shared prefix blocks stored once), with the q-block causal
-    masks of the contiguous verify kernel.  q: [B, Q, H, Dh]; pools
-    [N_blocks, bs, H, Dh]; lengths/q_lens [B]; block_tables [B, T].
-    Int8 pools pass ``k_scale``/``v_scale`` [N_blocks, bs, H] f32."""
-    B, Q, H, Dh = q.shape
-    bs = pool_k.shape[1]
-    T = block_tables.shape[1]
-    scale = Dh ** -0.5
-    if interpret is None:
-        interpret = _use_interpret()
-    quantized = k_scale is not None
-
-    def kv_idx(b, j, lens_ref, qlens_ref, bt_ref):
-        last = jnp.maximum(lens_ref[b] - 1, 0) // bs
-        return (bt_ref[b, jnp.minimum(j, last)], 0, 0, 0)
-
-    def sc_idx(b, j, lens_ref, qlens_ref, bt_ref):
-        last = jnp.maximum(lens_ref[b] - 1, 0) // bs
-        return (bt_ref[b, jnp.minimum(j, last)], 0, 0)
-
-    if quantized:
-        kernel = _block_verify_kernel_int8
-        in_specs = [
-            pl.BlockSpec((1, Q, H, Dh),
-                         lambda b, j, lens, qlens, bt: (b, 0, 0, 0)),
-            pl.BlockSpec((1, bs, H, Dh), kv_idx),
-            pl.BlockSpec((1, bs, H), sc_idx),
-            pl.BlockSpec((1, bs, H, Dh), kv_idx),
-            pl.BlockSpec((1, bs, H), sc_idx),
-        ]
-        operands = (q, pool_k, k_scale, pool_v, v_scale)
-    else:
-        kernel = _block_verify_kernel
-        in_specs = [
-            pl.BlockSpec((1, Q, H, Dh),
-                         lambda b, j, lens, qlens, bt: (b, 0, 0, 0)),
-            pl.BlockSpec((1, bs, H, Dh), kv_idx),
-            pl.BlockSpec((1, bs, H, Dh), kv_idx),
-        ]
-        operands = (q, pool_k, pool_v)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(B, T),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, Q, H, Dh),
-                               lambda b, j, lens, qlens, bt:
-                               (b, 0, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((H * Q, _LANES), jnp.float32),
-            pltpu.VMEM((H * Q, _LANES), jnp.float32),
-            pltpu.VMEM((H * Q, Dh), jnp.float32),
-        ],
-    )
-    return pl.pallas_call(
-        functools.partial(kernel, scale=scale, bk=bs, n_kv=T, nq=Q),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, Q, H, Dh), q.dtype),
-        name="paged_block_verify",
-        interpret=interpret,
-    )(lengths.astype(jnp.int32), q_lens.astype(jnp.int32),
-      block_tables.astype(jnp.int32), *operands)
-
-
 def masked_verify_reference(q, k, v, lengths, q_lens, k_scale=None,
                             v_scale=None):
     """Exact masked oracle (f32) for the verify kernels: per-query
@@ -624,16 +414,6 @@ def masked_verify_reference(q, k, v, lengths, q_lens, k_scale=None,
     from .ragged_attention import ragged_masked_reference
     return ragged_masked_reference(q, k, v, lengths, q_lens, k_scale,
                                    v_scale)
-
-
-def paged_block_verify_reference(q, pool_k, pool_v, lengths, q_lens,
-                                 block_tables, k_scale=None,
-                                 v_scale=None):
-    """Gather-then-mask oracle for the block-table verify kernel — a
-    thin delegate of the unified ragged paged reference."""
-    from .ragged_attention import ragged_paged_reference
-    return ragged_paged_reference(q, pool_k, pool_v, lengths, q_lens,
-                                  block_tables, k_scale, v_scale)
 
 
 def masked_decode_reference(q, k, v, lengths, k_scale=None,

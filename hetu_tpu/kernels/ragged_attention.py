@@ -1,10 +1,10 @@
 """ONE ragged mixed-mode attention kernel for the whole serving hot
 loop (ISSUE 18, Ragged Paged Attention lineage).
 
-The phase-split engine runs three kernel families per scheduler
-iteration — flash prefill for admissions, the decode kernel for
-continuing streams, the verify kernel for speculative waves — with a
-scheduling barrier between the phases.  This module collapses them:
+A scheduler split by phase needs three kernel families an iteration —
+flash prefill for admissions, a decode kernel for continuing streams, a
+verify kernel for speculative waves — with a scheduling barrier between
+the phases.  This module is all three:
 every slot in a wave carries its OWN ``q_len`` (1 for decode, k+1 for
 spec-verify, a chunk of prompt for prefill/chunked-prefill), and one
 kernel call scores the whole mixed wave.  Mechanically it is the
@@ -41,16 +41,15 @@ a time with the layer in the copy, the same per-slot data (q_len,
 kv_len, tables), mask, dead-tile rule and f32 online softmax (see the
 comment block over it).  ONE masked-gather reference
 (``ragged_masked_reference``) serves them all for off-TPU
-interpret-mode parity — kernels/decode_attention.py's four per-mode
-references delegate here, and its per-mode kernels remain as parity
-oracles behind the existing ``$HETU_SERVE_FAST``/phase-split paths.
+interpret-mode parity — kernels/decode_attention.py's two per-mode
+references delegate here, and its contiguous decode and verify kernels
+remain for ``_decode_step``/``_verify_step`` (ROADMAP C5).
 
 The kernel reads the q-block's own K/V back from the pool (the
 engine's mixed step writes before it attends), so a lossy cache dtype
-(bf16/int8) round-trips prefill chunks exactly like the phase-split
-fast path round-trips decode/verify positions; the masked engine path
-(``_verify_step``'s mixed mode) keeps the phase-split engine's exact
-per-mode arithmetic instead.
+(bf16/int8) round-trips prefill chunks as it round-trips decode/verify
+positions; the masked engine path (``_mixed_step``'s ``has_fresh``)
+scores a chunk's own rows before their round-trip instead.
 """
 
 from __future__ import annotations

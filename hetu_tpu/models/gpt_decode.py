@@ -21,12 +21,13 @@ Sampling: greedy (temperature=0), temperature, and top-k; ``eos_id``
 stops a sequence at EOS (pad after, per-step compute short-circuits
 once the whole batch is done).
 
-``_decode_step`` is the SHARED decode core: the offline scan above and
-the continuous-batching serving engine (``hetu_tpu.serving``) both run
-it — the offline path with one scalar position for the whole batch, the
-server with a per-slot position vector (slots hold sequences of unequal
-filled lengths).  ``serve_prefill_fn``/``serve_decode_fn`` below are the
-server's two jitted entry points over the same arithmetic.
+``_decode_step`` is the contiguous-cache decode core: the offline scan
+above runs it with one scalar position for the whole batch, the
+speculative draft with a per-slot position vector.  The
+continuous-batching serving engine (``hetu_tpu.serving``) runs ONE
+core, ``_mixed_step``: a ragged wave in which a decode stream is a
+q-block of 1, a verify block k+1 and a prompt chunk its width
+(``serve_mixed_paged_fn``/``serve_mixed_fn``).
 """
 
 from __future__ import annotations
@@ -69,10 +70,6 @@ NEG_INF = -1e30
 def _kv_q(cache):
     """True when ``cache`` is the quantized (data, scales) pair."""
     return isinstance(cache, (tuple, list))
-
-
-def _kv_dtype(cache):
-    return cache[0].dtype if _kv_q(cache) else cache.dtype
 
 
 def _kv_shape(cache):
@@ -158,18 +155,6 @@ def _kv_dus(cache, val, i, pos):
         cache, val[None, :, None], (i, 0, pos, 0, 0))
 
 
-def _kv_gather_row(cache, i, table_row, span, H, Dh):
-    """One slot's logical [span, H, Dh] context gathered from a paged
-    pool through its block table row (the chunk-prefill read path);
-    quantized pools dequantize the gathered view."""
-    if _kv_q(cache):
-        data, sc = cache
-        g = data[i][table_row].reshape(span, H, Dh)
-        s = sc[i][table_row].reshape(span, H)
-        return g.astype(jnp.float32) * s[..., None]
-    return kv_heads(cache[i][table_row], H, Dh).reshape(span, H, Dh)
-
-
 def _kv_slot_slice(cache, slot, sizes):
     """One slot's [L, 1, S_max, H, Dh] view of a contiguous cache (the
     reference prefill works on this slice), both layouts."""
@@ -202,11 +187,12 @@ def _pow2(n, floor=1):
 def _resolve_fast(mode=None):
     """Serving fast-path selection, shared by ``generate_fast`` and the
     serving engine: an explicit argument wins; else ``$HETU_SERVE_FAST``
-    ("1" forces the flash-prefill + ragged-decode kernels, "0" forces
-    the masked/scan reference); else auto — fast on TPU, reference
-    elsewhere.  Off-TPU the fast kernels run in interpret mode: correct
-    (the parity suite pins it) but emulated, so the reference path
-    stays the off-TPU default."""
+    ("1" forces the Pallas kernels: flash prefill offline, the ragged
+    kernel in the engine's wave; "0" forces the masked/scan reference);
+    else auto — kernels on TPU, reference elsewhere.  This is the one
+    serving choice that follows the platform: off-TPU the kernels run
+    in interpret mode, correct (the parity suite pins it) but emulated,
+    so the reference stays the off-TPU default."""
     if mode is None:
         mode = envvars.get_str("HETU_SERVE_FAST")
     if isinstance(mode, bool):
@@ -215,27 +201,6 @@ def _resolve_fast(mode=None):
     if s in ("1", "on", "true", "fast", "ragged", "flash"):
         return True
     if s in ("0", "off", "false", "masked", "scan", "slow"):
-        return False
-    return jax.default_backend() == "tpu"
-
-
-def resolve_serve_ragged(mode=None):
-    """Mixed-mode ragged dispatch selection (ISSUE 18), shared by the
-    serving engine and its callers: an explicit argument wins; else
-    ``$HETU_SERVE_RAGGED`` ("1" packs arrivals, chunk continuations,
-    spec-verify, and decode streams into ONE ragged wave per step,
-    "0" keeps the phase-split prefill-then-decode scheduler); else
-    auto — mixed on TPU (where the one-dispatch wave erases the phase
-    barrier), phase-split elsewhere (off-TPU the two schedulers cost
-    the same and phase-split is the longer-soaked path)."""
-    if mode is None:
-        mode = envvars.get_str("HETU_SERVE_RAGGED")
-    if isinstance(mode, bool):
-        return mode
-    s = str(mode).strip().lower()
-    if s in ("1", "on", "true", "mixed", "ragged"):
-        return True
-    if s in ("0", "off", "false", "phase", "split", "phased"):
         return False
     return jax.default_backend() == "tpu"
 
@@ -496,70 +461,37 @@ def _ffn_block(params, us, h, i, moe=None, valid=None, stats=None):
 
 
 def _decode_step(params, cfg_tuple, cache_k, cache_v, pos, token,
-                 attn="masked", block_tables=None, live_mask=None,
-                 moe_stats=None, token_valid=None):
-    """One incremental position: token [B] int32 at position ``pos``.
-    Returns (logits [B, V], new cache_k, new cache_v).
+                 attn="masked", moe_stats=None):
+    """One incremental position over a CONTIGUOUS cache ``[L, B, S_max,
+    H, Dh]``: token [B] int32 at position ``pos``.  Returns (logits
+    [B, V], new cache_k, new cache_v).
 
     ``pos`` is a scalar (offline scan: the whole batch sits at one
-    position) OR an int32 [B] vector (serving: every slot decodes at its
-    own filled length).  Scalar positions keep the contiguous
+    position) OR an int32 [B] vector (the draft: every slot proposes at
+    its own filled length).  Scalar positions keep the contiguous
     dynamic_update_slice write; vector positions scatter one row per
     slot and mask attention per slot.
 
     ``attn`` (static) picks the attention implementation: "masked"
-    streams and masks (the reference), "ragged" runs the paged Pallas
-    decode kernel so each slot fetches only its live KV blocks
+    streams and masks (the reference), "ragged" runs the Pallas decode
+    kernel so each slot fetches only its live KV blocks
     (kernels/decode_attention.py).
 
-    ``block_tables`` (traced [B, T] int32, serving only) switches the
-    CACHE LAYOUT to block-table paged: ``cache_k``/``cache_v`` are the
-    shared pool (``[L, N_blocks, bs, W]`` rows, seen here as ``[..,
-    H, Dh]`` through ``_kv_layer``), this position's k/v scatters into
-    block ``block_tables[b, pos[b]//bs]`` at offset
-    ``pos[b] % bs``, and attention reads each slot's blocks through its
-    table ("masked" gathers + masks, "ragged" is the block-table
-    kernel).  ``live_mask`` ([B] bool) redirects inert slots' ride-along
-    writes to scratch block 0 and zeroes their attention span — a slot
-    mid-chunked-prefill must not have its freshly written prompt KV
-    clobbered by the frozen-position write the contiguous layout could
-    shrug off.  Offline ``generate_fast`` and the serving engine share
-    this one core; the layout is a parameter, not a fork.
-
-    ``token_valid`` ([B] bool) excludes ride-along rows from MoE
-    routing (falling back to ``live_mask`` when paged); ``moe_stats``
-    (dict) accumulates per-expert load/drop across the MoE layers.
-    Both are ignored by dense cfg_tuples."""
+    ``moe_stats`` (dict) accumulates per-expert load/drop across the
+    MoE layers; a dense cfg_tuple ignores it.  The serving engine's
+    wave, paged pool included, is ``_mixed_step``."""
     name, L, H, Dh, S_max = cfg_tuple[:5]
     moe = _moe_of(cfg_tuple)
-    if token_valid is None:
-        token_valid = live_mask
     B = token.shape[0]
     hdim = H * Dh
     per_slot = jnp.ndim(pos) > 0
-    paged = block_tables is not None
     h = params[f"{name}_wte_table"][token] + params[f"{name}_wpe"][pos]
 
-    if attn == "ragged" or paged:
-        from ..kernels.decode_attention import (
-            paged_block_decode_attention, paged_decode_attention,
-        )
+    if attn == "ragged":
+        from ..kernels.decode_attention import paged_decode_attention
         lens = ((pos + 1).astype(jnp.int32) if per_slot
                 else jnp.full((B,), pos + 1, jnp.int32))
-    if paged:
-        bs_blk = _kv_shape(cache_k)[2]
-        T = block_tables.shape[1]
-        bidx = jnp.arange(B)
-        wblk = block_tables[bidx, pos // bs_blk]
-        woff = pos % bs_blk
-        if live_mask is not None:
-            lens = jnp.where(live_mask, lens, 0)
-            wblk = jnp.where(live_mask, wblk, 0)
-        # masked gather path: a fully-dead slot still needs one live
-        # score to keep its (discarded) softmax row finite
-        live = (jnp.arange(T * bs_blk)[None, None, :]
-                < jnp.maximum(lens, 1)[:, None, None])
-    elif per_slot:
+    if per_slot:
         live = jnp.arange(S_max)[None, None, :] <= pos[:, None, None]
         bidx = jnp.arange(B)
     else:
@@ -575,35 +507,15 @@ def _decode_step(params, cfg_tuple, cache_k, cache_v, pos, token,
         v = v.reshape(B, H, Dh)
         # write this position's k/v into the cache (quantized caches
         # encode payload + per-(position, head) scales in one helper)
-        if paged:
-            cache_k = _kv_scatter(cache_k, (i, wblk, woff), k)
-            cache_v = _kv_scatter(cache_v, (i, wblk, woff), v)
-        elif per_slot:
+        if per_slot:
             cache_k = _kv_scatter(cache_k, (i, bidx, pos), k)
             cache_v = _kv_scatter(cache_v, (i, bidx, pos), v)
         else:
             cache_k = _kv_dus(cache_k, k, i, pos)
             cache_v = _kv_dus(cache_v, v, i, pos)
-        ks, ksc = _kv_layer(cache_k, i, H, Dh)  # [B,S,H,Dh] | [N,bs,H,Dh]
+        ks, ksc = _kv_layer(cache_k, i, H, Dh)              # [B,S,H,Dh]
         vs, vsc = _kv_layer(cache_v, i, H, Dh)
-        if paged and attn == "ragged":
-            o = paged_block_decode_attention(
-                q, ks, vs, lens, block_tables, k_scale=ksc,
-                v_scale=vsc).reshape(B, hdim)
-        elif paged:
-            kg = ks[block_tables].reshape(B, T * bs_blk, H, Dh)
-            vg = vs[block_tables].reshape(B, T * bs_blk, H, Dh)
-            if ksc is not None:
-                # masked-gather reference: dequantize the gathered view
-                kg = kg.astype(jnp.float32) * ksc[block_tables].reshape(
-                    B, T * bs_blk, H)[..., None]
-                vg = vg.astype(jnp.float32) * vsc[block_tables].reshape(
-                    B, T * bs_blk, H)[..., None]
-            s = jnp.einsum("bhd,bshd->bhs", q, kg) * (Dh ** -0.5)
-            s = jnp.where(live, s, NEG_INF)
-            p = jax.nn.softmax(s, axis=-1)
-            o = jnp.einsum("bhs,bshd->bhd", p, vg).reshape(B, hdim)
-        elif attn == "ragged":
+        if attn == "ragged":
             o = paged_decode_attention(
                 q, ks, vs, lens, k_scale=ksc,
                 v_scale=vsc).reshape(B, hdim)
@@ -618,8 +530,7 @@ def _decode_step(params, cfg_tuple, cache_k, cache_v, pos, token,
         o = o @ params[f"{us}_attn_proj_weight"] \
             + params[f"{us}_attn_proj_bias"]
         h = h + o
-        h = _ffn_block(params, us, h, i, moe=moe, valid=token_valid,
-                       stats=moe_stats)
+        h = _ffn_block(params, us, h, i, moe=moe, stats=moe_stats)
 
     h = _ln(h, params[f"{name}_ln_f_scale"], params[f"{name}_ln_f_bias"])
     # logits in f32 regardless of compute dtype: sampling compares and
@@ -865,13 +776,12 @@ def _generate_flash(params, cfg_tuple, prompt_bucket, prompt_len,
 
 # ------------------------- serving entry points ------------------------- #
 #
-# The continuous-batching server (hetu_tpu/serving/engine.py) drives the
-# SAME ``_decode_step`` core through two jitted functions: a teacher-
-# forced prefill of one new sequence into its cache slot, and one fused
-# decode step over every slot with per-slot positions.  Host code owns
-# the tiny scheduling state (positions, tokens, rng keys as numpy); the
-# device owns only the big [L, B_slots, S_max, H, Dh] cache pair, which
-# threads through each call.
+# What the serving engine and offline speculation run beside the mixed
+# wave further down: a teacher-forced prefill of one sequence into its
+# cache slot (the engine's draft), a batched flash prefill and a batched
+# verify (``_generate_spec``).  Host code owns the tiny scheduling state
+# (positions, tokens, rng keys as numpy); the device owns only the big
+# cache pair, which threads through each call.
 
 
 def _serve_prefill(params, cfg_tuple, cache_k, cache_v, slot, prompt,
@@ -965,60 +875,6 @@ def _serve_prefill_batch(params, cfg_tuple, cache_k, cache_v, slots,
     return out
 
 
-def _serve_decode_step(params, cfg_tuple, cache_k, cache_v, pos, token,
-                       temperature, top_k, rng_keys, attn="masked",
-                       live=None):
-    """One fused decode step over ALL slots: slot b consumes ``token[b]``
-    at its own position ``pos[b]`` (per-slot attention masking inside
-    ``_decode_step``) and samples its next token from its own rng
-    stream — outputs depend only on each request's (prompt, seed,
-    settings), never on slot assignment or batch company.  Free slots
-    ride along harmlessly: their frozen-position writes land in rows the
-    next prefill/decode overwrites before any mask admits them.
-    ``attn`` (static): "masked" reference or the "ragged" paged decode
-    kernel (per-slot filled lengths bound the KV blocks fetched).
-    ``live`` [B] bool (MoE configs) keeps ride-along free slots out of
-    expert routing; dense configs ignore it."""
-    moe_on = _moe_active(cfg_tuple)
-    sd = {} if moe_on else None
-    logits, cache_k, cache_v = _decode_step(
-        params, cfg_tuple, cache_k, cache_v, pos, token, attn=attn,
-        moe_stats=sd, token_valid=live)
-    splits = jax.vmap(jax.random.split)(rng_keys)          # [B,2,2]
-    new_keys, subs = splits[:, 0], splits[:, 1]
-    sampled = jax.vmap(_sample_slot)(logits, temperature, top_k, subs)
-    out = (sampled, cache_k, cache_v, new_keys)
-    if moe_on:
-        n = (token.shape[0] if live is None
-             else jnp.sum(live.astype(jnp.int32)))
-        out = out + (_moe_stats_out(sd, _moe_of(cfg_tuple), n),)
-    return out
-
-
-def _serve_decode_paged(params, cfg_tuple, cache_k, cache_v, tables,
-                        pos, live, token, temperature, top_k, rng_keys,
-                        attn="masked"):
-    """``_serve_decode_step`` over the block-table paged pool: same
-    fused step, but the cache pair is the shared block pool, ``tables``
-    [B, T] routes each slot's reads/writes, and ``live`` [B] bool marks
-    the slots actually decoding this wave (admitted, prompt fully
-    prefilled) — inert slots ride along with their writes pointed at
-    scratch block 0 and their sampled token discarded by the host."""
-    moe_on = _moe_active(cfg_tuple)
-    sd = {} if moe_on else None
-    logits, cache_k, cache_v = _decode_step(
-        params, cfg_tuple, cache_k, cache_v, pos, token, attn=attn,
-        block_tables=tables, live_mask=live, moe_stats=sd)
-    splits = jax.vmap(jax.random.split)(rng_keys)          # [B,2,2]
-    new_keys, subs = splits[:, 0], splits[:, 1]
-    sampled = jax.vmap(_sample_slot)(logits, temperature, top_k, subs)
-    out = (sampled, cache_k, cache_v, new_keys)
-    if moe_on:
-        out = out + (_moe_stats_out(
-            sd, _moe_of(cfg_tuple), jnp.sum(live.astype(jnp.int32))),)
-    return out
-
-
 # ---------------------- speculative decoding ---------------------- #
 #
 # Draft-propose / batched-verify (ISSUE 10): a truncated-layer DRAFT —
@@ -1039,8 +895,7 @@ def _serve_decode_paged(params, cfg_tuple, cache_k, cache_v, tables,
 
 
 def _verify_step(params, cfg_tuple, cache_k, cache_v, pos, tokens,
-                 q_len, attn="masked", block_tables=None,
-                 moe_stats=None):
+                 q_len, attn="masked", moe_stats=None):
     """Multi-position verify: slot b consumes ``tokens[b, :q_len[b]]``
     at positions ``pos[b] .. pos[b]+q_len[b]-1`` in ONE batched step.
     Returns (logits [B, Q, V] f32, new cache_k, new cache_v) — row
@@ -1049,19 +904,16 @@ def _verify_step(params, cfg_tuple, cache_k, cache_v, pos, tokens,
     (each query attends to the whole written prefix INCLUDING the
     q-block's own causal positions).
 
-    Dead positions (``j >= q_len[b]``): contiguous caches write them
-    at their natural ``pos+j`` slots — beyond the slot's live length,
-    never admitted by a mask, overwritten before use — with the writes
-    issued LAST-LIVE-WINS (descending j), so a dead tail clipped to
-    ``S_max-1`` can never clobber a live boundary write; paged caches
-    route them to scratch block 0 like every other inert write.
-    ``attn``/``block_tables`` select the implementation and layout as
-    in ``_decode_step``."""
+    Dead positions (``j >= q_len[b]``) are written at their natural
+    ``pos+j`` slots of the contiguous cache — beyond the slot's live
+    length, never admitted by a mask, overwritten before use — with the
+    writes issued LAST-LIVE-WINS (descending j), so a dead tail clipped
+    to ``S_max-1`` can never clobber a live boundary write.  ``attn``
+    selects the implementation as in ``_decode_step``."""
     name, L, H, Dh, S_max = cfg_tuple[:5]
     moe = _moe_of(cfg_tuple)
     B, Q = tokens.shape
     hdim = H * Dh
-    paged = block_tables is not None
     bidx = jnp.arange(B)
     posns = pos[:, None] + jnp.arange(Q)[None, :]          # [B, Q]
     valid = jnp.arange(Q)[None, :] < q_len[:, None]        # [B, Q]
@@ -1070,20 +922,8 @@ def _verify_step(params, cfg_tuple, cache_k, cache_v, pos, tokens,
     h = params[f"{name}_wte_table"][tokens] \
         + wpe[jnp.clip(posns, 0, wpe.shape[0] - 1)]        # [B, Q, hd]
     if attn == "ragged":
-        from ..kernels.decode_attention import (
-            paged_block_verify_attention, paged_verify_attention,
-        )
-    if paged:
-        bs_blk = _kv_shape(cache_k)[2]
-        T = block_tables.shape[1]
-        posc = jnp.clip(posns, 0, S_max - 1)
-        wblk = jnp.where(valid,
-                         block_tables[bidx[:, None], posc // bs_blk], 0)
-        woff = posc % bs_blk
-        span = T * bs_blk
-        ctx = jnp.arange(span)[None, None, :]
-    else:
-        ctx = jnp.arange(S_max)[None, None, :]
+        from ..kernels.decode_attention import paged_verify_attention
+    ctx = jnp.arange(S_max)[None, None, :]
     live = ctx <= posns[:, :, None]                        # [B, Q, S]
     for i in range(L):
         us = f"{name}_h{i}"
@@ -1094,35 +934,15 @@ def _verify_step(params, cfg_tuple, cache_k, cache_v, pos, tokens,
              + params[f"{us}_attn_k_bias"]).reshape(B, Q, H, Dh)
         v = (x @ params[f"{us}_attn_v_weight"]
              + params[f"{us}_attn_v_bias"]).reshape(B, Q, H, Dh)
-        if paged:
-            cache_k = _kv_scatter(cache_k, (i, wblk, woff), k)
-            cache_v = _kv_scatter(cache_v, (i, wblk, woff), v)
-        else:
-            # descending j so the (clipped) dead tail is written FIRST
-            # and any live boundary write lands last and wins
-            for jq in reversed(range(Q)):
-                pw = jnp.minimum(posns[:, jq], S_max - 1)
-                cache_k = _kv_scatter(cache_k, (i, bidx, pw), k[:, jq])
-                cache_v = _kv_scatter(cache_v, (i, bidx, pw), v[:, jq])
+        # descending j so the (clipped) dead tail is written FIRST and
+        # any live boundary write lands last and wins
+        for jq in reversed(range(Q)):
+            pw = jnp.minimum(posns[:, jq], S_max - 1)
+            cache_k = _kv_scatter(cache_k, (i, bidx, pw), k[:, jq])
+            cache_v = _kv_scatter(cache_v, (i, bidx, pw), v[:, jq])
         ks, ksc = _kv_layer(cache_k, i, H, Dh)
         vs, vsc = _kv_layer(cache_v, i, H, Dh)
-        if paged and attn == "ragged":
-            o = paged_block_verify_attention(
-                q, ks, vs, lens, q_len, block_tables, k_scale=ksc,
-                v_scale=vsc).reshape(B, Q, hdim)
-        elif paged:
-            kg = ks[block_tables].reshape(B, span, H, Dh)
-            vg = vs[block_tables].reshape(B, span, H, Dh)
-            if ksc is not None:
-                kg = kg.astype(jnp.float32) * ksc[block_tables].reshape(
-                    B, span, H)[..., None]
-                vg = vg.astype(jnp.float32) * vsc[block_tables].reshape(
-                    B, span, H)[..., None]
-            s = jnp.einsum("bqhd,bshd->bqhs", q, kg) * (Dh ** -0.5)
-            s = jnp.where(live[:, :, None, :], s, NEG_INF)
-            p = jax.nn.softmax(s, axis=-1)
-            o = jnp.einsum("bqhs,bshd->bqhd", p, vg).reshape(B, Q, hdim)
-        elif attn == "ragged":
+        if attn == "ragged":
             o = paged_verify_attention(
                 q, ks, vs, lens, q_len, k_scale=ksc,
                 v_scale=vsc).reshape(B, Q, hdim)
@@ -1158,8 +978,8 @@ def _spec_sample(logits, temperature, top_k, rng_keys, count):
 
     Slot b splits its stream once for each row ``w < count[b]`` and at
     no other: 1 for a decode slot and for a prompt's FINAL chunk (one
-    split, matching the phase-split prefill paths' single split per
-    prompt), up to k+1 for spec-verify, 0 for a mid-prompt chunk and a
+    split a prompt, as ``_serve_prefill`` and ``generate_fast`` make),
+    up to k+1 for spec-verify, 0 for a mid-prompt chunk and a
     dead slot (the returned keys equal the input and the host carries
     the stream forward untouched).  Rows at or past ``count`` return a
     sample nobody reads; there are at most W - 1 of them a slot, where W
@@ -1212,27 +1032,6 @@ def _serve_verify(params, cfg_tuple, cache_k, cache_v, pos, tokens,
     return out
 
 
-def _serve_verify_paged(params, cfg_tuple, cache_k, cache_v, tables,
-                        pos, tokens, q_len, temperature, top_k,
-                        rng_keys, attn="masked"):
-    """``_serve_verify`` over the block-table paged pool (``q_len`` 0
-    marks inert slots — mid-prefill or free — whose writes are routed
-    to scratch and whose samples/keys the host discards)."""
-    moe_on = _moe_active(cfg_tuple)
-    sd = {} if moe_on else None
-    logits, cache_k, cache_v = _verify_step(
-        params, cfg_tuple, cache_k, cache_v, pos, tokens, q_len,
-        attn=attn, block_tables=tables, moe_stats=sd)
-    sampled, after = _spec_sample(logits, temperature, top_k, rng_keys,
-                                  q_len)
-    out = (sampled, cache_k, cache_v, after)
-    if moe_on:
-        out = out + (_moe_stats_out(
-            sd, _moe_of(cfg_tuple),
-            jnp.sum(jnp.clip(q_len, 0, tokens.shape[1]))),)
-    return out
-
-
 def _spec_propose(params, cfg_tuple, cache_k, cache_v, pos, token, k):
     """``k`` greedy draft steps inside ONE dispatch: a lax.scan over
     the (truncated-layer) draft's ``_decode_step``, each step feeding
@@ -1261,122 +1060,16 @@ def _spec_propose(params, cfg_tuple, cache_k, cache_v, pos, token, k):
     return jnp.swapaxes(toks, 0, 1)[:, :k], cache_k, cache_v
 
 
-def _serve_prefill_chunk(params, cfg_tuple, cache_k, cache_v, table_row,
-                         tokens, pos_off, n_tok, temperature, top_k,
-                         rng_key, wblk, woff):
-    """One CHUNK of a prompt into one slot's blocks: forward ``tokens``
-    [C_b] (positions ``pos_off .. pos_off+n_tok-1``; the rest pad)
-    attending to the slot's already-written context (gathered from the
-    pool through ``table_row`` [T]) plus the chunk's own causal prefix,
-    then scatter the chunk's K/V into blocks ``wblk``/``woff`` [C_b]
-    (pad positions target scratch block 0).  This is both the chunked-
-    prefill engine (long prompts fill block by block between decode
-    waves) and the prefix-share tail pass (a prompt whose first
-    ``pos_off`` positions came from shared blocks forwards only the
-    remainder).  Returns (first_token, cache_k, cache_v, new_rng_key) —
-    the sample is meaningful only on the final chunk, and the HOST
-    applies new_rng_key only then, so the request's rng stream is
-    split exactly once, same as the unchunked paths."""
-    name, L, H, Dh, S_max = cfg_tuple[:5]
-    moe = _moe_of(cfg_tuple)
-    moe_on = _moe_active(cfg_tuple)
-    sd = {} if moe_on else None
-    C_b = tokens.shape[0]
-    T = table_row.shape[0]
-    bs_blk = _kv_shape(cache_k)[2]
-    hdim = H * Dh
-    wpe = params[f"{name}_wpe"]
-    posns = pos_off + jnp.arange(C_b)
-    h = params[f"{name}_wte_table"][tokens] \
-        + wpe[jnp.clip(posns, 0, wpe.shape[0] - 1)]        # [C_b, hd]
-    # context positions valid strictly below pos_off; chunk causal mask
-    ctx_live = (jnp.arange(T * bs_blk)[None, :] < pos_off)
-    ii = jnp.arange(C_b)
-    self_live = (ii[None, :] <= ii[:, None]) & (ii[None, :] < n_tok)
-    scale = Dh ** -0.5
-    for i in range(L):
-        us = f"{name}_h{i}"
-        x = _ln(h, params[f"{us}_ln1_scale"], params[f"{us}_ln1_bias"])
-        q = (x @ params[f"{us}_attn_q_weight"]
-             + params[f"{us}_attn_q_bias"]).reshape(C_b, H, Dh)
-        k = (x @ params[f"{us}_attn_k_weight"]
-             + params[f"{us}_attn_k_bias"]).reshape(C_b, H, Dh)
-        v = (x @ params[f"{us}_attn_v_weight"]
-             + params[f"{us}_attn_v_bias"]).reshape(C_b, H, Dh)
-        kc = _kv_gather_row(cache_k, i, table_row, T * bs_blk, H, Dh)
-        vc = _kv_gather_row(cache_v, i, table_row, T * bs_blk, H, Dh)
-        s1 = jnp.einsum("chd,shd->chs", q, kc) * scale
-        s1 = jnp.where(ctx_live[:, None, :], s1, NEG_INF)
-        s2 = jnp.einsum("chd,jhd->chj", q, k) * scale
-        s2 = jnp.where(self_live[:, None, :], s2, NEG_INF)
-        s = jnp.concatenate([s1, s2], axis=-1)
-        p = jax.nn.softmax(s, axis=-1)
-        o = (jnp.einsum("chs,shd->chd", p[..., :T * bs_blk], vc)
-             + jnp.einsum("chj,jhd->chd", p[..., T * bs_blk:], v))
-        o = o.reshape(C_b, hdim) @ params[f"{us}_attn_proj_weight"] \
-            + params[f"{us}_attn_proj_bias"]
-        h = h + o
-        h = _ffn_block(params, us, h, i, moe=moe, valid=ii < n_tok,
-                       stats=sd)
-        cache_k = _kv_scatter(cache_k, (i, wblk, woff), k)
-        cache_v = _kv_scatter(cache_v, (i, wblk, woff), v)
-    hf = _ln(h, params[f"{name}_ln_f_scale"], params[f"{name}_ln_f_bias"])
-    last = hf[jnp.maximum(n_tok - 1, 0)]
-    logits = (last @ params[f"{name}_wte_table"].T).astype(jnp.float32) \
-        + params.get(f"{name}_head_bias", 0.0)
-    rng_key, sub = jax.random.split(rng_key)
-    first = _sample_slot(logits, temperature, top_k, sub)
-    out = (first, cache_k, cache_v, rng_key)
-    if moe_on:
-        out = out + (_moe_stats_out(sd, moe,
-                                    jnp.clip(n_tok, 0, C_b)),)
-    return out
-
-
-def _serve_prefill_batch_paged(params, cfg_tuple, cache_k, cache_v,
-                               prompts, prompt_lens, temperature, top_k,
-                               rng_keys, wblk, woff, row_valid=None):
-    """Flash prefill of an admission group scattered into BLOCKS: the
-    same one-dispatch ``_prefill_forward`` as the contiguous fast path,
-    but every (request, position)'s K/V lands in the pool block the
-    host-built ``wblk``/``woff`` [N, P_b] maps name (pad positions and
-    replicated pad rows target scratch block 0 / duplicate identical
-    writes — order-safe).  ``row_valid`` [N] bool marks real rows (MoE
-    routing exclusion).  Returns (first_tokens [N], cache_k, cache_v,
-    new_rng_keys[, moe stats])."""
-    moe_on = _moe_active(cfg_tuple)
-    sd = {} if moe_on else None
-    logits, ks, vs = _prefill_forward(params, cfg_tuple, prompts,
-                                      prompt_lens, row_valid=row_valid,
-                                      moe_stats=sd)
-    cache_k = _kv_scatter(cache_k, (slice(None), wblk, woff), ks)
-    cache_v = _kv_scatter(cache_v, (slice(None), wblk, woff), vs)
-    splits = jax.vmap(jax.random.split)(rng_keys)          # [N,2,2]
-    new_keys, subs = splits[:, 0], splits[:, 1]
-    first = jax.vmap(_sample_slot)(logits, temperature, top_k, subs)
-    out = (first, cache_k, cache_v, new_keys)
-    if moe_on:
-        lens = jnp.clip(prompt_lens, 0, prompts.shape[1])
-        if row_valid is not None:
-            lens = jnp.where(row_valid, lens, 0)
-        out = out + (_moe_stats_out(sd, _moe_of(cfg_tuple),
-                                    jnp.sum(lens)),)
-    return out
-
-
-# --- mixed-mode ragged dispatch (ISSUE 18) ------------------------- #
-# ONE jitted core for the whole hot loop.  The phase-split engine runs
-# up to three kernel families per scheduler iteration (flash prefill,
-# decode, spec-verify) with a host barrier between the phases; the
-# mixed step consumes a RAGGED WAVE DESCRIPTOR — per-slot q_len + a
-# token block — in which a decode stream is a q-block of 1, a
-# spec-verify wave k+1, and a prompt (or prompt chunk) its chunk
-# width, all scored by one dispatch.  ``_verify_step`` was already
-# this computation for the uniform-mode case; ``_mixed_step``
-# generalizes its attention to per-slot SELF-FRESHNESS so every
-# phase-split path's exact arithmetic survives the merge (see below),
-# which is what keeps greedy outputs token-identical ragged-vs-phased
-# across contiguous/paged/int8/spec/chunked configs.
+# --- the mixed ragged wave (ISSUE 18) ------------------------------ #
+# ONE jitted core for the engine's whole hot loop.  The mixed step
+# consumes a RAGGED WAVE DESCRIPTOR — per-slot q_len + a token block —
+# in which a decode stream is a q-block of 1, a spec-verify wave k+1,
+# and a prompt (or prompt chunk) its chunk width, all scored by one
+# dispatch.  ``_verify_step`` is this computation for the uniform-mode
+# case over a contiguous cache; ``_mixed_step`` adds the paged pool,
+# the block spec and per-slot SELF-FRESHNESS (see below).  Greedy
+# outputs are token-identical to offline ``generate_fast`` across
+# contiguous/paged/int8/spec/chunked configs.
 
 
 def _window_logits(params, name, h, first_row, window, blk=GPT2_BLOCK):
@@ -1503,11 +1196,12 @@ def _mixed_step(params, cfg_tuple, cache_k, cache_v, pos, tokens,
 
     The masked path's DEFAULT attention is ``_verify_step``'s full
     causal mask over the just-written cache, bit for bit — so decode,
-    spec-verify, and contiguous-prefill slots produce exactly the
-    phase-split engine's logits (write-then-read self arithmetic,
+    spec-verify, and contiguous-prefill slots produce sequential
+    ``_decode_step``'s logits (write-then-read self arithmetic,
     including the int8 round-trip).  Paged PROMPT-CHUNK slots are the
-    one mode whose phase-split comparator (``_serve_prefill_chunk``)
-    keeps the chunk's own K/V FRESH; when a wave carries any
+    one mode that keeps the chunk's own K/V FRESH (an int8 pool scores
+    a chunk's own rows before their round-trip, as a one-pass prefill
+    does); when a wave carries any
     (``has_fresh``, static — steady-state decode waves skip the extra
     compute entirely), the fresh-self two-part variant (context masked
     strictly below ``pos`` + causal scores over the in-flight q-block)
@@ -1645,8 +1339,8 @@ def _mixed_step(params, cfg_tuple, cache_k, cache_v, pos, tokens,
                 p = jax.nn.softmax(sw, axis=-1)
                 o = jnp.einsum("bqhs,bshd->bqhd", p, vg)
                 if has_fresh:
-                    # _serve_prefill_chunk's arithmetic for chunk slots:
-                    # read-back context + the chunk's own FRESH K/V
+                    # chunk slots: read-back context + the chunk's own
+                    # FRESH K/V
                     s1 = jnp.where(ctx_live[:, None, None, :], s_raw,
                                    NEG_INF)
                     s2 = jnp.einsum("bqhd,bjhd->bqhj", q, k) * scale
@@ -1744,8 +1438,8 @@ def serve_mixed_fn(donate=True, attn="masked", window=1):
 
 @functools.lru_cache(maxsize=None)
 def serve_mixed_paged_fn(donate=True, attn="masked", window=1):
-    """Jitted ``_serve_mixed_paged`` — the block-table mixed-mode wave,
-    the production dispatch behind ``$HETU_SERVE_RAGGED``.  Compiles
+    """Jitted ``_serve_mixed_paged`` — the block-table mixed wave, the
+    engine's dispatch in every benchmark cell.  Compiles
     per (Q bucket, has_fresh): steady-state decode waves skip the
     chunk-slot variant's extra softmax entirely.  ``window`` as in
     ``serve_mixed_fn``: bound once an engine, never per wave."""
@@ -1781,29 +1475,6 @@ def serve_prefill_batch_fn(donate=True):
 
 
 @functools.lru_cache(maxsize=None)
-def serve_decode_fn(donate=True, attn="masked"):
-    """Jitted ``_serve_decode_step`` (see ``serve_prefill_fn``)."""
-    kw = {"static_argnames": ("cfg_tuple", "attn")}
-    if donate:
-        kw["donate_argnums"] = (2, 3)
-    fn = jax.jit(_serve_decode_step, **kw)
-    return functools.partial(fn, attn=attn)
-
-
-@functools.lru_cache(maxsize=None)
-def serve_decode_paged_fn(donate=True, attn="masked"):
-    """Jitted ``_serve_decode_paged`` — the block-table fused step (see
-    ``serve_prefill_fn`` for the donation rationale; donating the POOL
-    pair matters even more here, since it is the engine's entire KV
-    memory)."""
-    kw = {"static_argnames": ("cfg_tuple", "attn")}
-    if donate:
-        kw["donate_argnums"] = (2, 3)
-    fn = jax.jit(_serve_decode_paged, **kw)
-    return functools.partial(fn, attn=attn)
-
-
-@functools.lru_cache(maxsize=None)
 def serve_verify_fn(donate=True, attn="masked"):
     """Jitted ``_serve_verify`` — the speculative wave's batched
     verification step over the contiguous cache (see
@@ -1818,16 +1489,6 @@ def serve_verify_fn(donate=True, attn="masked"):
 
 
 @functools.lru_cache(maxsize=None)
-def serve_verify_paged_fn(donate=True, attn="masked"):
-    """Jitted ``_serve_verify_paged`` — the block-table verify wave."""
-    kw = {"static_argnames": ("cfg_tuple", "attn")}
-    if donate:
-        kw["donate_argnums"] = (2, 3)
-    fn = jax.jit(_serve_verify_paged, **kw)
-    return functools.partial(fn, attn=attn)
-
-
-@functools.lru_cache(maxsize=None)
 def spec_propose_fn(donate=True):
     """Jitted ``_spec_propose`` (draft cache pair donated).  Compiles
     per draft length k — the adaptive controller moves k through a
@@ -1836,27 +1497,6 @@ def spec_propose_fn(donate=True):
     if donate:
         kw["donate_argnums"] = (2, 3)
     return jax.jit(_spec_propose, **kw)
-
-
-@functools.lru_cache(maxsize=None)
-def serve_prefill_chunk_fn(donate=True):
-    """Jitted ``_serve_prefill_chunk``; compiles per (chunk bucket,
-    table width) — the engine pads chunks to one fixed pow2 bucket, so
-    the ladder stays bounded."""
-    kw = {"static_argnames": ("cfg_tuple",)}
-    if donate:
-        kw["donate_argnums"] = (2, 3)
-    return jax.jit(_serve_prefill_chunk, **kw)
-
-
-@functools.lru_cache(maxsize=None)
-def serve_prefill_batch_paged_fn(donate=True):
-    """Jitted ``_serve_prefill_batch_paged`` — the paged engine's
-    batched-admission flash dispatch."""
-    kw = {"static_argnames": ("cfg_tuple",)}
-    if donate:
-        kw["donate_argnums"] = (2, 3)
-    return jax.jit(_serve_prefill_batch_paged, **kw)
 
 
 def teacher_forced_logits(params, config, seq, kv_fake_quant=False,

@@ -5,12 +5,15 @@ The offline path (``models/gpt_decode.generate_fast``) compiles one
 whole-generation scan per (batch, S_max) — every request in the batch
 enters and leaves together, padded to the longest.  This package is the
 online counterpart: an iteration-level scheduler (Orca-style continuous
-batching) that admits and retires sequences BETWEEN fused decode steps,
-over a slot-structured KV cache, sharing ``_decode_step`` — the same
-compiled arithmetic — with the offline path.
+batching) that admits and retires sequences BETWEEN fused waves, over a
+slot-structured KV cache, with the offline path's arithmetic (greedy
+tokens are ``generate_fast``'s).
 
-    engine.py     ServingEngine: admission queue with backpressure, the
-                  per-step admit -> prefill -> fused-decode -> retire loop
+    engine.py     ServingEngine: admission queue with backpressure and
+                  the scheduler, one on every backend: each step admits,
+                  packs every live slot's q-block (a prompt or its next
+                  chunk, a spec-verify block, a decode token) into ONE
+                  ragged wave, dispatches it once, retires
     embed_engine.py
                   EmbedServingEngine: the recommendation workload —
                   waves of (user_ids, item_ids, dense_features)
@@ -36,7 +39,8 @@ compiled arithmetic — with the offline path.
                   cache pair, pow2-bucketed shapes; PagedKVManager: the
                   block-table paged pool (free-list block allocator,
                   refcounted copy-on-write prefix sharing, chunked
-                  prefill support) — paged=/$HETU_KV_BLOCK selects it
+                  prefill support), the default (block 16,
+                  $HETU_KV_BLOCK); paged=False selects the former
     kv_tiers.py   TieredKVStore: fleet-global prefix capacity — the
                   eviction-to-tier ladder behind every paged pool
                   (HBM pool -> host-RAM LRU ring sized by
@@ -96,20 +100,18 @@ into an engine exception or QueueFull storm to ``$HETU_FLIGHT_LOG``.
 Speculative decoding (``spec=``/``$HETU_SPEC_K``): a truncated-layer
 draft — the target's own first blocks, no separate weights — proposes
 up to k tokens per slot in one scanned dispatch, the target verifies
-all k+1 positions in ONE batched step (the multi-token verify kernels
-in kernels/decode_attention.py), longest-prefix acceptance + a bonus
-token emit 1..k+1 tokens per wave, and rejected positions roll back via
-``kv.truncate`` — outputs stay token-identical to plain decoding
+all k+1 positions as a q-block of the wave, longest-prefix acceptance +
+a bonus token emit 1..k+1 tokens per wave, and rejected positions roll
+back via ``kv.truncate`` — outputs stay token-identical to plain decoding
 (greedy AND sampled), with an adaptive-k controller riding a sliding
 acceptance-rate window (``$HETU_SPEC_ADAPT``).
 
-Both phases have a ragged fast path (``fast_path=``/``$HETU_SERVE_FAST``,
-auto-on on TPU): admission prefills whole same-bucket GROUPS in one
-batched flash-attention pass, and the fused decode step runs the paged
-decode-attention kernel (kernels/decode_attention.py) so each slot
-fetches only ceil(filled/block_k) KV blocks instead of streaming all of
-S_max.  The masked/scan path remains the reference — greedy outputs are
-token-identical between the two.
+What scores the wave follows the platform (``fast_path=``/
+``$HETU_SERVE_FAST``): the Pallas ragged kernel
+(kernels/ragged_attention.py) on a TPU, so each slot fetches only its
+live KV pages instead of streaming all of S_max; the masked
+``jax.numpy`` reference elsewhere — greedy outputs are token-identical
+between the two.
 
 Quickstart (greedy results are token-identical to ``generate_fast``):
 

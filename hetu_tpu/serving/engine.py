@@ -1,12 +1,13 @@
 """The continuous-batching serving engine.
 
-Iteration-level scheduling (Orca-style): between fused decode steps the
+Iteration-level scheduling (Orca-style): between fused waves the
 engine retires finished sequences, frees their slots, and admits queued
 requests into the holes — a short request leaves the batch the moment
 it finishes instead of padding along until the longest one is done, and
-a new one takes its slot on the very next step.  Prefill interleaves
-with decode: each admission runs one teacher-forced prefill scan into
-its slot (bucketed prompt lengths), then joins the shared fused step.
+a new one takes its slot on the very next step.  There is ONE scheduler
+on every backend, the mixed ragged wave: a prompt (or its next chunk),
+a spec-verify block and a decode token are q-blocks of one fused
+dispatch (``_step_mixed``).
 
 Division of labor: the DEVICE holds only the big cache pair and the
 model weights; the HOST owns every piece of scheduling state (queue,
@@ -36,11 +37,8 @@ from ..telemetry import slo as slo_mod
 from ..models.gpt_decode import (
     GPT2_BLOCK, block_spec_of, check_block_spec,
     _infer_name, _prep_param, _pow2, _resolve_fast, resolve_draft_layers,
-    resolve_serve_ragged, resolve_spec_k, serve_decode_fn,
-    serve_decode_paged_fn, serve_mixed_fn, serve_mixed_paged_fn,
-    serve_prefill_batch_fn, serve_prefill_batch_paged_fn,
-    serve_prefill_chunk_fn, serve_prefill_fn, serve_verify_fn,
-    serve_verify_paged_fn, spec_propose_fn,
+    resolve_spec_k, serve_mixed_fn, serve_mixed_paged_fn,
+    serve_prefill_fn, spec_propose_fn,
 )
 from .kv_manager import (KVCacheManager, PagedKVManager,
                          assemble_mixed_wave, resolve_kv_block,
@@ -81,19 +79,22 @@ class ServingEngine:
     cache pair to the jitted steps so XLA updates it in place (default
     True — without it every step copies the whole cache, ~3ms per 100MB;
     measured 320x on the scatter alone on the CPU harness); fast_path:
-    True runs the ragged serving fast path — flash prefill (one batched
-    full-prompt pass per admission group) + the paged decode-attention
-    kernel (each slot fetches only ceil(filled/block_k) KV blocks
-    instead of streaming all of S_max) — False the masked/scan
-    reference, default consults ``$HETU_SERVE_FAST`` then auto-selects
-    fast on TPU (greedy outputs are identical either way; the parity
-    suite pins it in interpret mode); spec: > 0 enables SPECULATIVE
+    True scores the wave with the Pallas ragged kernel (each slot
+    fetches only its live KV pages instead of streaming all of S_max),
+    False with the masked ``jax.numpy`` reference; default consults
+    ``$HETU_SERVE_FAST`` then takes the kernel on a TPU and the
+    reference elsewhere — the one thing here that follows the platform
+    (greedy outputs are identical either way; ``fast_path=True`` is how
+    a test runs the kernel in interpret mode); paged: the KV layout,
+    default the block-table pool (``PagedKVManager``, block 16,
+    ``kv_block``/``$HETU_KV_BLOCK``) on every backend, ``paged=False``
+    the slot-contiguous ``KVCacheManager``; spec: > 0 enables SPECULATIVE
     DECODING (default ``$HETU_SPEC_K``) — a truncated-layer draft
     (``spec_draft_layers`` of the target's own blocks + the shared
     final LN/tied head; default ``$HETU_SPEC_DRAFT_LAYERS`` or
     max(1, L // 4)) proposes up to ``spec`` tokens per slot per wave
     in ONE scanned dispatch, the target verifies all proposals plus
-    the carried token in ONE batched step, and longest-prefix
+    the carried token as a k+1 q-block of the wave, and longest-prefix
     acceptance + the bonus token emit 1..spec+1 tokens per wave —
     outputs stay TOKEN-IDENTICAL to the non-speculative engine (greedy
     and sampled alike: accepted tokens are the target's own sequential
@@ -104,30 +105,26 @@ class ServingEngine:
     with paged/prefix-shared/chunked/int8 KV, the fast path, TP, and
     the fleet router; the draft keeps its own small contiguous cache.
 
-    ragged (``$HETU_SERVE_RAGGED``, auto = mixed on TPU): MIXED-MODE
-    RAGGED DISPATCH — every scheduler iteration packs fresh-prompt
-    prefills, chunk continuations, spec-verify blocks, and plain
-    decode into ONE ragged wave (per-slot ``q_len``) and launches ONE
-    fused step, instead of the phase-split prefill-then-decode
-    cadence.  Decode slots no longer stall behind another request's
-    prompt chunks (the ``chunk_stall`` lifecycle component collapses
-    to ~0) and a step costs one dispatch regardless of the mode mix.
-    Greedy outputs stay token-identical to the phase-split scheduler
-    across every layout (contiguous/paged, int8, chunked, prefix
-    sharing, speculation) — the parity suite pins it.
+    The scheduler: every iteration packs fresh-prompt prefills, chunk
+    continuations, spec-verify blocks, and plain decode into ONE ragged
+    wave (per-slot ``q_len``) and launches ONE fused step.  A decode
+    slot never stalls behind another request's prompt chunks (the
+    ``chunk_stall`` lifecycle component is ~0) and a step costs one
+    dispatch regardless of the mode mix.  Greedy outputs are
+    token-identical to offline ``generate_fast`` across every layout
+    (contiguous/paged, int8, chunked, prefix sharing, speculation) —
+    the parity suite pins it.
 
     Block spec: a config that carries one (``config.block_spec()``, a
     ``models.moe_decode.LatentMoEConfig``: RMSNorm, RoPE, latent (MLA)
     attention over ONE paged pool of ``[c_kv | k_r]`` rows, dense
     SwiGLU / dropless routed + shared FFN, untied head) runs on the
-    mixed ragged wave over the paged pool and NOWHERE ELSE: of the
-    seven serving cores only ``_mixed_step`` reads the spec (folding
-    the others is ROADMAP C3/C4).  Such an engine raises a
+    wave over the paged pool: ``_mixed_step`` reads the spec, the
+    draft's cores do not (ROADMAP C3).  Such an engine raises a
     ``ValueError`` that names the path when built with ``paged=False``
-    (the contiguous ``KVCacheManager``), ``ragged=False`` (the
-    phase-split scheduler's prefill-chunk, batch-prefill and decode
-    cores), ``spec`` > 0 (the draft, its contiguous cache and
-    ``_verify_step``) or ``kv_quant="int8"``; its pool refuses
+    (the contiguous ``KVCacheManager``), ``spec`` > 0 (the draft, its
+    contiguous cache and ``_decode_step``) or ``kv_quant="int8"``; its
+    pool refuses
     ``export_blocks``/``import_blocks`` and the KV tiers (latent rows
     have no wire format).  Its waves count ``serve.moe.*`` and
     ``serve.attn.*`` (``ServingMetrics.record_routed``).  A GPT-2
@@ -147,12 +144,16 @@ class ServingEngine:
     QueueFull storms dump the flight recorder to ``$HETU_FLIGHT_LOG``.
     """
 
+    # read by benchmarks/runners/serve.py, serve_latent_moe.py and
+    # tests/benchmark/test_benchmark.py; nothing branches on it
+    ragged = True
+
     def __init__(self, params, config, *, slots=8, queue_limit=64,
                  max_seq_len=None, name=None, dtype=None, log_path=None,
                  donate=True, fast_path=None, paged=None, kv_block=None,
                  pool_blocks=None, prefix_share=None, prefill_chunk=None,
                  kv_quant=None, slo=None, tags=None, spec=None,
-                 spec_adapt=None, spec_draft_layers=None, ragged=None):
+                 spec_adapt=None, spec_draft_layers=None):
         c = config
         self._name = _infer_name(params, name)
         # dtype=None FOLLOWS the params: bf16 weights stay bf16 and the
@@ -182,18 +183,13 @@ class ServingEngine:
         block = resolve_kv_block(paged, kv_block)
         self.paged = block > 0
         self.fast_path = _resolve_fast(fast_path)
-        self.ragged = resolve_serve_ragged(ragged)
         self.spec_k = resolve_spec_k(spec)
         if other:
             for bad, path in (
                     (not self.paged, "the contiguous KVCacheManager "
-                     "(paged=False): _serve_mixed, _serve_prefill, "
-                     "_serve_decode_step"),
-                    (not self.ragged, "the phase-split scheduler "
-                     "(ragged=False): _serve_prefill_chunk, "
-                     "_serve_prefill_batch_paged, _serve_decode_paged"),
+                     "(paged=False): _serve_mixed"),
                     (self.spec_k, "speculation (spec_k > 0): "
-                     "_spec_propose, _verify_step and the draft's "
+                     "_spec_propose, _serve_prefill and the draft's "
                      "contiguous cache"),
                     (self.kv_quant, "an int8 KV cache (kv_quant)")):
                 if bad:
@@ -213,23 +209,12 @@ class ServingEngine:
             chunk = (prefill_chunk if prefill_chunk is not None
                      else envvars.get_int("HETU_KV_CHUNK"))
             self.chunk = max(int(chunk or 0), 0)
-            self._prefill = None
-            self._prefill_chunk = serve_prefill_chunk_fn(donate)
-            self._prefill_batch = (serve_prefill_batch_paged_fn(donate)
-                                   if self.fast_path else None)
-            self._decode = serve_decode_paged_fn(
-                donate, "ragged" if self.fast_path else "masked")
         else:
             self.kv = KVCacheManager(
                 layers=c.num_hidden_layers, heads=c.num_attention_heads,
                 head_dim=Dh, slots=slots, max_seq_len=want,
                 pos_cap=c.max_position_embeddings, dtype=kv_dtype)
             self.chunk = 0
-            self._prefill = serve_prefill_fn(donate)
-            self._prefill_batch = (serve_prefill_batch_fn(donate)
-                                   if self.fast_path else None)
-            self._decode = serve_decode_fn(
-                donate, "ragged" if self.fast_path else "masked")
         self.cfg_tuple = (self._name, c.num_hidden_layers,
                           c.num_attention_heads, Dh, self.kv.s_max)
         # ---- MoE serving (models/moe_decode.py): a MoEDecodeConfig
@@ -258,10 +243,9 @@ class ServingEngine:
             self.moe_tokens = 0
             self._moe_layers = self.moe.moe_layers(c.num_hidden_layers)
             self._moe_step = None   # per-step [load, drop, tokens]
-        self.prefill_dispatches = 0   # jitted prefill calls (the
-        # batched-admission win: a burst of k same-bucket arrivals on
-        # the fast path costs ONE dispatch, not k)
-        self.prefill_chunks = 0       # chunked-prefill dispatches (paged)
+        self.prefill_dispatches = 0   # waves that carried a prompt
+        # q-block (a burst of k arrivals is ONE wave, not k dispatches)
+        self.prefill_chunks = 0       # prompt q-blocks written (paged)
         self.peak_live = 0            # max concurrent admitted slots
         self.queue_limit = int(queue_limit)
         self._queue = collections.deque()
@@ -341,10 +325,6 @@ class ServingEngine:
             self._draft_cv = jnp.zeros(dshape, cdtype)
             self._propose = spec_propose_fn(donate)
             self._draft_prefill = serve_prefill_fn(donate)
-            attn = "ragged" if self.fast_path else "masked"
-            self._verify = (serve_verify_paged_fn(donate, attn)
-                            if self.paged else
-                            serve_verify_fn(donate, attn))
             self._acc_window = collections.deque(maxlen=32)
             self.spec_proposed = 0    # draft tokens scored
             self.spec_accepted = 0    # draft tokens emitted
@@ -355,21 +335,17 @@ class ServingEngine:
             self._spec_acc = np.zeros(B, np.int64)
             self._spec_prop = np.zeros(B, np.int64)
             self._spec_bonus = np.zeros(B, np.int64)
-        # ---- mixed-mode ragged dispatch (ragged=/$HETU_SERVE_RAGGED):
-        # arrivals, chunk continuations, spec-verify, and decode pack
-        # into ONE ragged wave per step (see class docstring) ---- #
-        if self.ragged:
-            attn = "ragged" if self.fast_path else "masked"
-            # the widest sampling window a slot can have: a verify
-            # block's spec_k + 1 rows, else the one row a decode slot
-            # or a final chunk samples; the wave's head and sampling
-            # run over that many rows a slot, not the padded q-block
-            mixed_fn = serve_mixed_paged_fn if self.paged else serve_mixed_fn
-            self._mixed = mixed_fn(donate, attn, self.spec_k + 1)
-            # tells the lifecycle accountant the wave IS the prefill:
-            # a noise-scale chunk_stall residue is folded to 0, a
-            # larger one counted (serve.lifecycle_residue)
-            self.metrics.mixed_mode = True
+        # ---- the wave: arrivals, chunk continuations, spec-verify and
+        # decode pack into ONE ragged dispatch per step (see class
+        # docstring).  ``window`` is the widest sampling window a slot
+        # can have: a verify block's spec_k + 1 rows, else the one row a
+        # decode slot or a final chunk samples; the wave's head and
+        # sampling run over that many rows a slot, not the padded
+        # q-block ---- #
+        mixed_fn = serve_mixed_paged_fn if self.paged else serve_mixed_fn
+        self._mixed = mixed_fn(
+            donate, "ragged" if self.fast_path else "masked",
+            self.spec_k + 1)
         if envvars.get_bool("HETU_VALIDATE"):
             # recompile sentinel: snapshot()/assert_no_recompile() can
             # now prove the steady state stays ONE compiled core
@@ -564,26 +540,16 @@ class ServingEngine:
     # ------------------------------------------------------------- #
 
     def step(self):
-        """One scheduler iteration: admit+prefill into free slots, then
-        one fused decode step over every live slot, retiring finished
-        sequences as their tokens land.  Returns the Results that
-        completed this iteration.
-
-        Admission runs in WAVES: each wave claims every free slot,
-        groups its admissions by prompt-length bucket, and prefills one
-        group per jitted dispatch (fast path — the masked reference
-        keeps its per-request scan); a request that finishes AT prefill
-        frees its slot for the next wave of the same step.
+        """One scheduler iteration: admit into free slots, then ONE
+        mixed wave over every live slot (``_step_mixed``), retiring
+        finished sequences as their tokens land.  Returns the Results
+        that completed this iteration.
 
         An exception escaping the scheduler dumps the flight recorder
         (``$HETU_FLIGHT_LOG``) before propagating — the black box holds
         the records leading into the fault."""
         try:
-            if self.ragged:
-                return self._step_mixed()
-            if self.paged:
-                return self._step_paged()
-            return self._step_contiguous()
+            return self._step_mixed()
         except QueueFull:
             raise
         except Exception as e:   # noqa: BLE001 — dump-and-reraise
@@ -594,243 +560,9 @@ class ServingEngine:
                 queue_depth=len(self._queue))
             raise
 
-    def _step_contiguous(self):
-        done = []
-        prefill_s = 0.0
-        while True:
-            admits = []
-            while self._queue and self.kv.free_slots:
-                req = self._queue.popleft()
-                t_a = time.perf_counter()
-                slot = self.kv.alloc(req.request_id, len(req.prompt))
-                req.claimed_at = time.perf_counter()
-                self.metrics.lc_claimed(
-                    req.request_id, (req.claimed_at - t_a) * 1e3)
-                admits.append((req, slot))
-            if not admits:
-                break
-            telemetry.inc("serve.admission_waves")
-            groups = {}
-            for req, slot in admits:
-                pb = self.kv.bucket_prompt(len(req.prompt))
-                groups.setdefault(pb, []).append((req, slot))
-            for pb, group in sorted(groups.items()):
-                t0 = time.perf_counter()
-                if self.fast_path:
-                    firsts, keys = self._prefill_group_flash(pb, group)
-                else:
-                    firsts, keys = self._prefill_group_ref(pb, group)
-                dt = time.perf_counter() - t0
-                prefill_s += dt
-                self.metrics.record_prefill(
-                    len(group), pb, dt, batched=self.fast_path)
-                for req, _slot in group:
-                    self.metrics.lc_prefill(req.request_id, dt)
-                for (req, slot), tok0, key in zip(group, firsts, keys):
-                    if self.spec_k:
-                        t_d = time.perf_counter()
-                        self._draft_prefill_slot(slot, req.prompt)
-                        d_dt = time.perf_counter() - t_d
-                        prefill_s += d_dt
-                        self.metrics.lc_prefill(req.request_id, d_dt)
-                    now = time.perf_counter()
-                    req.first_token_at = now
-                    self._pos[slot] = len(req.prompt)
-                    self._tok[slot] = tok0
-                    self._temp[slot] = req.temperature
-                    self._topk[slot] = req.top_k
-                    self._keys[slot] = key
-                    self._reqs[slot] = req
-                    self._slot_version[slot] = self.weight_version
-                    self._gen[slot] = [tok0]
-                    self._tok_t[slot] = [now]
-                    self.metrics.record_admit(
-                        req.request_id, slot, now - req.submitted_at,
-                        now - req.submitted_at)
-                    if req.stream_cb:
-                        req.stream_cb(req, tok0)
-                    r = self._maybe_finish(slot, tok0)
-                    if r:
-                        done.append(r)   # frees the slot: next wave
-        # ---- one fused decode step over all live slots ---- #
-        live = self.kv.live()
-        self.peak_live = max(self.peak_live, len(live))
-        if live and self.spec_k:
-            done.extend(self._spec_wave(live, prefill_s))
-        elif live:
-            wave_reqs = [self._reqs[s].request_id for s in live]
-            # MoE: free (dead) slots ride the fused step but must not
-            # compete for expert capacity — the live mask gates them
-            # out of routing (dense engines ignore it)
-            mask = np.zeros(self.kv.n_slots, bool)
-            mask[live] = True
-            t0 = time.perf_counter()
-            sampled, ck, cv, keys = self._moe_take(self._decode(
-                self.params, self.cfg_tuple,
-                self.kv.cache_k, self.kv.cache_v,
-                self._pos, self._tok, self._temp, self._topk, self._keys,
-                live=mask))
-            self.kv.cache_k, self.kv.cache_v = ck, cv
-            sampled = np.asarray(sampled)
-            # np.array copies: np.asarray on a jax array is a read-only
-            # view, and admission writes per-slot rows into _keys
-            self._keys = np.array(keys, np.uint32)
-            dt = time.perf_counter() - t0
-            self._wave_end = t0 + dt
-            for slot in live:
-                req = self._reqs[slot]
-                t = int(sampled[slot])
-                self._pos[slot] += 1
-                self._tok[slot] = t
-                self._gen[slot].append(t)
-                self.kv.advance(slot)
-                if req.stream_cb:
-                    req.stream_cb(req, t)
-                r = self._maybe_finish(slot, t)
-                if r:
-                    done.append(r)
-            self.steps += 1
-            self.metrics.record_step(
-                live=len(live), slots=self.kv.n_slots,
-                queue_depth=len(self._queue), dt_s=dt,
-                new_tokens=len(live), prefill_s=prefill_s,
-                step=self.steps, requests=wave_reqs,
-                end_perf=t0 + dt, moe=self._moe_record())
-        return done
-
     # ------------------------------------------------------------- #
-
-    def _prefill_group_ref(self, pb, group):
-        """Reference admission: one teacher-forced prefill scan per
-        request (the pre-fast-path behavior, kept bit-identical)."""
-        firsts, keys = [], []
-        for req, slot in group:
-            P = len(req.prompt)
-            prompt = np.zeros(pb, np.int32)
-            prompt[:P] = req.prompt
-            key = np.asarray(jax.random.PRNGKey(req.seed), np.uint32)
-            first, ck, cv, key = self._moe_take(self._prefill(
-                self.params, self.cfg_tuple,
-                self.kv.cache_k, self.kv.cache_v,
-                np.int32(slot), prompt, np.int32(P),
-                np.float32(req.temperature), np.int32(req.top_k), key))
-            self.kv.cache_k, self.kv.cache_v = ck, cv
-            self.prefill_dispatches += 1
-            firsts.append(int(first))
-            keys.append(np.asarray(key))
-        return firsts, keys
-
-    def _prefill_group_flash(self, pb, group):
-        """Fast-path admission: the whole same-bucket group in ONE
-        batched flash-prefill dispatch.  The group size is pow2-bucketed
-        (bounding the compile ladder) by REPLICATING entry 0 into the
-        pad rows — duplicate cache-scatter indices then write identical
-        values, so padding is order-safe and its outputs are simply
-        dropped."""
-        n = len(group)
-        nb = min(_pow2(n), self.kv.n_slots)
-        rows = list(range(n)) + [0] * (nb - n)
-        prompts = np.zeros((nb, pb), np.int32)
-        lens = np.zeros(nb, np.int32)
-        slots = np.zeros(nb, np.int32)
-        temps = np.zeros(nb, np.float32)
-        topks = np.zeros(nb, np.int32)
-        keys = np.zeros((nb, 2), np.uint32)
-        for row, i in enumerate(rows):
-            req, slot = group[i]
-            P = len(req.prompt)
-            prompts[row, :P] = req.prompt
-            lens[row] = P
-            slots[row] = slot
-            temps[row] = req.temperature
-            topks[row] = req.top_k
-            keys[row] = np.asarray(jax.random.PRNGKey(req.seed),
-                                   np.uint32)
-        first, ck, cv, new_keys = self._moe_take(self._prefill_batch(
-            self.params, self.cfg_tuple,
-            self.kv.cache_k, self.kv.cache_v,
-            slots, prompts, lens, temps, topks, keys,
-            row_valid=(np.arange(nb) < n)))
-        self.kv.cache_k, self.kv.cache_v = ck, cv
-        self.prefill_dispatches += 1
-        first = np.asarray(first)
-        new_keys = np.array(new_keys, np.uint32)
-        return ([int(first[i]) for i in range(n)],
-                [new_keys[i] for i in range(n)])
-
+    # admission
     # ------------------------------------------------------------- #
-    # paged scheduler
-    # ------------------------------------------------------------- #
-
-    def _step_paged(self):
-        """One paged scheduler iteration: admit into block tables,
-        advance every mid-prefill slot by one chunk (long prompts fill
-        their blocks INTERLEAVED with decode waves instead of stalling
-        them), then one fused block-table decode step over the slots
-        whose prompts are fully written.  A request finishing at
-        prefill frees capacity for another admission wave within the
-        same step."""
-        done = []
-        prefill_s = 0.0
-        while True:
-            self._admit_paged()
-            fin, dt = self._prefill_wave_paged()
-            prefill_s += dt
-            done.extend(fin)
-            if not fin:
-                break   # nothing retired at prefill -> no freed
-                # capacity -> no further admissions this step: decode
-        # a request deferred for a prefix that REGISTERED this step can
-        # claim its (shared) blocks now and prefill next step
-        self._admit_paged()
-        # ---- fused decode over fully-prefilled slots; mid-prefill
-        # slots ride along pointed at the scratch block ---- #
-        live = self.kv.live()
-        decoding = [s for s in live if self._gen[s] is not None]
-        self.peak_live = max(self.peak_live, len(live))
-        if decoding and self.spec_k:
-            done.extend(self._spec_wave(decoding, prefill_s))
-        elif decoding:
-            wave_reqs = [self._reqs[s].request_id for s in decoding]
-            B = self.kv.n_slots
-            mask = np.zeros(B, bool)
-            mask[decoding] = True
-            t0 = time.perf_counter()
-            sampled, ck, cv, keys = self._moe_take(self._decode(
-                self.params, self.cfg_tuple,
-                self.kv.cache_k, self.kv.cache_v,
-                self.kv.tables.copy(), self._pos, mask, self._tok,
-                self._temp, self._topk, self._keys))
-            self.kv.cache_k, self.kv.cache_v = ck, cv
-            sampled = np.asarray(sampled)
-            new_keys = np.array(keys, np.uint32)
-            # ONLY decoding slots consumed their rng stream: a slot
-            # mid-prefill splits its key exactly once, at its final
-            # prefill chunk — restore the ride-along splits
-            new_keys[~mask] = self._keys[~mask]
-            self._keys = new_keys
-            dt = time.perf_counter() - t0
-            self._wave_end = t0 + dt
-            for slot in decoding:
-                req = self._reqs[slot]
-                t = int(sampled[slot])
-                self._pos[slot] += 1
-                self._tok[slot] = t
-                self._gen[slot].append(t)
-                self.kv.advance(slot)
-                if req.stream_cb:
-                    req.stream_cb(req, t)
-                r = self._maybe_finish(slot, t)
-                if r:
-                    done.append(r)
-            self.steps += 1
-            self.metrics.record_step(
-                live=len(decoding), slots=self.kv.n_slots,
-                queue_depth=len(self._queue), dt_s=dt,
-                new_tokens=len(decoding), prefill_s=prefill_s,
-                step=self.steps, requests=wave_reqs,
-                end_perf=t0 + dt, moe=self._moe_record())
-        return done
 
     def _admit_paged(self):
         """Claim slots + block tables for queued requests, FIFO, until
@@ -943,49 +675,6 @@ class ServingEngine:
                 return True
         return False
 
-    def _prefill_wave_paged(self):
-        """Advance every mid-prefill slot: fresh whole-prompt slots go
-        through the batched flash dispatch on the fast path (grouped by
-        prompt bucket, K/V scattered straight into their blocks); slots
-        with a shared-prefix tail or a chunked long prompt advance one
-        chunk through the chunk kernel.  Returns (finished Results,
-        prefill seconds)."""
-        t_all = time.perf_counter()
-        fin = []
-        pre = [s for s in self.kv.live() if self._gen[s] is None]
-        if not pre:
-            return fin, 0.0
-        flash, chunked = [], []
-        for s in pre:
-            P = len(self._prompt_arr[s])
-            whole = self.chunk == 0 or P <= self.chunk
-            if (self.fast_path and self._prefill_off[s] == 0 and whole):
-                flash.append(s)
-            else:
-                chunked.append(s)
-        groups = {}
-        for s in flash:
-            pb = self.kv.bucket_prompt(len(self._prompt_arr[s]))
-            groups.setdefault(pb, []).append(s)
-        for pb, group in sorted(groups.items()):
-            t0 = time.perf_counter()
-            firsts, keys = self._flash_group_paged(pb, group)
-            dt = time.perf_counter() - t0
-            self.metrics.record_prefill(len(group), pb, dt, batched=True)
-            for s in group:
-                self.metrics.lc_prefill(self._reqs[s].request_id, dt)
-            for s, tok0, key in zip(group, firsts, keys):
-                r = self._finish_prefill(s, tok0, key)
-                if r:
-                    fin.append(r)
-        for s in chunked:
-            out = self._chunk_advance(s)
-            if out is not None:
-                r = self._finish_prefill(s, out[0], out[1])
-                if r:
-                    fin.append(r)
-        return fin, time.perf_counter() - t_all
-
     def _finish_prefill(self, slot, tok0, key):
         """Prompt fully written: the slot joins the decode wave (or
         retires right here on max_new_tokens=1/instant EOS).  Registers
@@ -1013,101 +702,10 @@ class ServingEngine:
             req.stream_cb(req, tok0)
         return self._maybe_finish(slot, tok0)
 
-    def _chunk_advance(self, slot):
-        """One prefill chunk for one slot; returns (first_token,
-        new_key) when this chunk completed the prompt, else None."""
-        req = self._reqs[slot]
-        prompt = self._prompt_arr[slot]
-        P = len(prompt)
-        off = int(self._prefill_off[slot])
-        if self.chunk > 0:
-            C_b = min(_pow2(self.chunk, floor=8), self.kv.s_max)
-            take = min(self.chunk, C_b, P - off)
-        else:
-            C_b = self.kv.bucket_prompt(P - off)
-            take = P - off
-        tokens = np.zeros(C_b, np.int32)
-        tokens[:take] = prompt[off:off + take]
-        bs = self.kv.block
-        wblk = np.zeros(C_b, np.int32)
-        woff = np.zeros(C_b, np.int32)
-        for j in range(take):
-            p = off + j
-            wblk[j] = self.kv.tables[slot, p // bs]
-            woff[j] = p % bs
-        t0 = time.perf_counter()
-        first, ck, cv, nk = self._moe_take(self._prefill_chunk(
-            self.params, self.cfg_tuple,
-            self.kv.cache_k, self.kv.cache_v,
-            self.kv.tables[slot].copy(), tokens, np.int32(off),
-            np.int32(take), np.float32(req.temperature),
-            np.int32(req.top_k), self._keys[slot].copy(), wblk, woff))
-        self.kv.cache_k, self.kv.cache_v = ck, cv
-        self.prefill_dispatches += 1
-        self.prefill_chunks += 1
-        telemetry.inc("serve.prefill_chunks")
-        self.kv.advance(slot, take)
-        self._prefill_off[slot] = off + take
-        dt = time.perf_counter() - t0
-        self.metrics.record_prefill(1, C_b, dt, batched=False)
-        self.metrics.lc_prefill(req.request_id, dt)
-        if off + take >= P:
-            return int(first), np.asarray(nk, np.uint32)
-        return None
-
-    def _flash_group_paged(self, pb, group):
-        """Batched flash prefill into BLOCKS: one dispatch for the
-        whole same-bucket group, pow2-padded by replicating entry 0
-        (identical duplicate block writes — order-safe), with host-built
-        (block, offset) scatter maps routing each position's K/V into
-        its slot's table (pad tails hit scratch block 0)."""
-        n = len(group)
-        nb = min(_pow2(n), self.kv.n_slots)
-        rows = list(range(n)) + [0] * (nb - n)
-        prompts = np.zeros((nb, pb), np.int32)
-        lens = np.zeros(nb, np.int32)
-        temps = np.zeros(nb, np.float32)
-        topks = np.zeros(nb, np.int32)
-        keys = np.zeros((nb, 2), np.uint32)
-        wblk = np.zeros((nb, pb), np.int32)
-        woff = np.zeros((nb, pb), np.int32)
-        bs = self.kv.block
-        for row, i in enumerate(rows):
-            slot = group[i]
-            req = self._reqs[slot]
-            P = len(self._prompt_arr[slot])
-            prompts[row, :P] = self._prompt_arr[slot]
-            lens[row] = P
-            temps[row] = req.temperature
-            topks[row] = req.top_k
-            keys[row] = self._keys[slot]
-            for j in range(P):
-                wblk[row, j] = self.kv.tables[slot, j // bs]
-                woff[row, j] = j % bs
-        first, ck, cv, new_keys = self._moe_take(self._prefill_batch(
-            self.params, self.cfg_tuple,
-            self.kv.cache_k, self.kv.cache_v,
-            prompts, lens, temps, topks, keys, wblk, woff,
-            row_valid=(np.arange(nb) < n)))
-        self.kv.cache_k, self.kv.cache_v = ck, cv
-        self.prefill_dispatches += 1
-        first = np.asarray(first)
-        new_keys = np.array(new_keys, np.uint32)
-        for slot in group:
-            self.kv.advance(slot, len(self._prompt_arr[slot]))
-            self._prefill_off[slot] = len(self._prompt_arr[slot])
-        return ([int(first[i]) for i in range(n)],
-                [new_keys[i] for i in range(n)])
-
-    # ------------------------------------------------------------- #
-    # mixed-mode ragged dispatch (ragged=/$HETU_SERVE_RAGGED)
-    # ------------------------------------------------------------- #
-
     def _admit_contiguous_mixed(self):
-        """Contiguous admission WITHOUT the eager prefill: the claimed
-        slot's prompt joins this step's mixed wave as one ragged
-        q-block (``_gen = None`` marks it mid-prefill, exactly like the
-        paged scheduler's chunk slots)."""
+        """Contiguous admission: the claimed slot's prompt joins this
+        step's wave as one ragged q-block (``_gen = None`` marks it
+        mid-prefill, as ``_admit_paged`` does)."""
         admitted = []
         while self._queue and self.kv.free_slots:
             req = self._queue.popleft()
@@ -1133,15 +731,13 @@ class ServingEngine:
         return admitted
 
     def _step_mixed(self):
-        """One MIXED-MODE scheduler iteration: admissions, chunk
-        continuations, spec-verify blocks, and plain decode pack into
-        ONE ragged wave descriptor (per-slot ``q_len``/``first_row``)
-        and launch as ONE fused dispatch — no prefill/decode phase
-        barrier, so a decode slot never stalls behind another
-        request's prompt chunks.  Token-identical to the phase-split
-        schedulers: every slot's write positions, attention masks, and
-        rng splits reproduce exactly what its mode's dedicated step
-        would have done.
+        """The scheduler iteration: admissions, chunk continuations,
+        spec-verify blocks, and plain decode pack into ONE ragged wave
+        descriptor (per-slot ``q_len``/``first_row``) and launch as ONE
+        fused dispatch — no prefill/decode phase barrier, so a decode
+        slot never stalls behind another request's prompt chunks.
+        Every slot's write positions, attention masks, and rng splits
+        are those of a sequential decode of its request alone.
 
         Spans, a fixed number a wave whatever is live, all tagged
         ``wave=``: ``serve.wave`` (root) holding ``serve.admit``,
@@ -1158,9 +754,8 @@ class ServingEngine:
 
     def _mixed_wave(self, root, wave_id):
         done = []
-        # admission reuses the phase-split claim paths unchanged
-        # (prefix sharing/COW, tier fetch, deferral, backpressure) —
-        # minus the eager prefill: prompts join THIS step's wave
+        # admission claims slots and blocks (prefix sharing/COW, tier
+        # fetch, deferral, backpressure); prompts join THIS step's wave
         with telemetry.span("serve.admit", wave=wave_id):
             if self.paged:
                 self._admit_paged()
@@ -1175,8 +770,8 @@ class ServingEngine:
         decoding = [s for s in live if self._gen[s] is not None]
         wave_reqs = [self._reqs[s].request_id for s in live]
         t0 = time.perf_counter()
-        # speculative draft rides AHEAD of the wave exactly as in the
-        # phase-split spec scheduler (mid-prefill slots' rows are dead)
+        # speculative draft rides AHEAD of the wave (mid-prefill slots'
+        # rows are dead)
         k_cur = 0
         draft = None
         if decoding and self.spec_k:
@@ -1382,9 +977,8 @@ class ServingEngine:
         a newly admitted slot (one teacher-forced scan over the prompt
         bucket; the sampled token and rng split are discarded — the
         draft only ever proposes greedily from its own cache).  Also
-        zeroes the slot's per-request speculation attribution: this is
-        the one point both schedulers pass through exactly once per
-        admission."""
+        zeroes the slot's per-request speculation attribution: an
+        admission passes through here exactly once."""
         P = len(prompt)
         pb = self.kv.bucket_prompt(P)
         arr = np.zeros(pb, np.int32)
@@ -1419,105 +1013,6 @@ class ServingEngine:
         elif rate <= 0.35 and self._spec_kcur > 1:
             self._spec_kcur = max(self._spec_kcur // 2, 1)
             self._acc_window.clear()
-
-    def _spec_wave(self, decoding, prefill_s):
-        """One speculative wave over the decoding slots: draft-propose
-        (k_cur greedy steps in ONE scanned dispatch), batched verify
-        (ONE target step over all k_cur+1 positions), longest-prefix
-        acceptance + bonus token, KV rollback of rejected positions.
-        Emits 1..k_cur+1 tokens per slot; outputs are token-identical
-        to the non-speculative wave (greedy AND sampled — accepted
-        tokens are the target's own sequential samples, and the slot's
-        rng stream resumes at exactly the accepted count via the
-        per-position keys the verify returns).  Returns the Results
-        finished this wave."""
-        B = self.kv.n_slots
-        Q = self.spec_k + 1
-        k_cur = self._spec_kcur
-        wave_reqs = [self._reqs[s].request_id for s in decoding]
-        t0 = time.perf_counter()
-        draft, dck, dcv = self._propose(
-            self.params, self.cfg_tuple_draft,
-            self._draft_ck, self._draft_cv,
-            self._pos.copy(), self._tok.copy(), k=k_cur)
-        self._draft_ck, self._draft_cv = dck, dcv
-        draft = np.asarray(draft)
-        tokens = np.zeros((B, Q), np.int32)
-        tokens[:, 0] = self._tok
-        tokens[:, 1:1 + k_cur] = draft
-        qlen = np.zeros(B, np.int32)
-        for s in decoding:
-            rem = self._reqs[s].max_new_tokens - len(self._gen[s])
-            qlen[s] = min(k_cur + 1, rem,
-                          self.kv.s_max - int(self._pos[s]))
-        if self.paged:
-            sampled, ck, cv, after = self._moe_take(self._verify(
-                self.params, self.cfg_tuple,
-                self.kv.cache_k, self.kv.cache_v,
-                self.kv.tables.copy(), self._pos, tokens, qlen,
-                self._temp, self._topk, self._keys))
-        else:
-            sampled, ck, cv, after = self._moe_take(self._verify(
-                self.params, self.cfg_tuple,
-                self.kv.cache_k, self.kv.cache_v,
-                self._pos, tokens, qlen, self._temp, self._topk,
-                self._keys))
-        self.kv.cache_k, self.kv.cache_v = ck, cv
-        sampled = np.asarray(sampled)
-        after = np.array(after, np.uint32)
-        dt = time.perf_counter() - t0
-        self._wave_end = t0 + dt
-        done = []
-        wave_emit = wave_acc = wave_prop = 0
-        for s in decoding:
-            req = self._reqs[s]
-            ql = int(qlen[s])
-            a = 0
-            while a < ql - 1 and sampled[s, a] == tokens[s, a + 1]:
-                a += 1
-            emit = [int(t) for t in sampled[s, :a + 1]]
-            if req.eos_id is not None and req.eos_id in emit:
-                emit = emit[:emit.index(req.eos_id) + 1]
-            n_emit = len(emit)
-            accepted = min(a, n_emit)   # emitted tokens that WERE the
-            # draft's (the rest — at most one — is the bonus sample)
-            wave_emit += n_emit
-            wave_acc += accepted
-            wave_prop += ql - 1
-            self._spec_acc[s] += accepted
-            self._spec_prop[s] += ql - 1
-            self._spec_bonus[s] += n_emit - accepted
-            base = int(self._pos[s])
-            # the verify wrote all ql positions; keep the accepted
-            # prefix + bonus, roll the rejected tail back
-            self.kv.advance(s, ql)
-            self.kv.truncate(s, base + n_emit)
-            self._pos[s] = base + n_emit
-            self._tok[s] = emit[-1]
-            self._keys[s] = after[s, n_emit - 1]
-            self._gen[s].extend(emit)
-            if req.stream_cb:
-                for t in emit:
-                    req.stream_cb(req, t)
-            r = self._maybe_finish(s, emit[-1])
-            if r:
-                done.append(r)
-        self.steps += 1
-        self.spec_waves += 1
-        self.spec_k_sum += k_cur
-        self.spec_proposed += wave_prop
-        self.spec_accepted += wave_acc
-        self.spec_emitted += wave_emit
-        self._acc_window.append((wave_acc, wave_prop))
-        self._adapt_k()
-        self.metrics.record_step(
-            live=len(decoding), slots=B,
-            queue_depth=len(self._queue), dt_s=dt,
-            new_tokens=wave_emit, prefill_s=prefill_s,
-            step=self.steps, requests=wave_reqs, end_perf=t0 + dt,
-            spec={"k": k_cur, "proposed": wave_prop,
-                  "accepted": wave_acc}, moe=self._moe_record())
-        return done
 
     @property
     def spec_acceptance(self):

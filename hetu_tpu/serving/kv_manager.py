@@ -27,7 +27,6 @@ not by the number of distinct deployment configs.
 from __future__ import annotations
 
 import numpy as np
-import jax
 import jax.numpy as jnp
 
 from .. import envvars, quant, telemetry
@@ -55,7 +54,7 @@ def _bucket_prompt(p, s_max, pos_cap):
 
 def assemble_mixed_wave(n_slots, entries, q_floor=1):
     """Pack per-slot ragged q-blocks into ONE padded mixed-wave
-    descriptor (the `$HETU_SERVE_RAGGED` hot loop).
+    descriptor (the engine's hot loop).
 
     ``entries`` maps slot -> ``(tokens, pos, first_row, self_fresh)``:
 
@@ -75,8 +74,7 @@ def assemble_mixed_wave(n_slots, entries, q_floor=1):
     Width is bucketed to a power of two so waves with nearby shapes
     land on the same jit entry.  Slots absent from ``entries`` ride
     along inactive (``q_len = 0``): the kernel masks their attention
-    and their clipped writes land on dead positions, same as free
-    slots in the phase-split decode wave.
+    and their clipped writes land on dead positions.
     """
     width = max((len(t) for t, *_ in entries.values()), default=1)
     q = round_up_pow2(width, floor=q_floor)
@@ -200,21 +198,17 @@ def _wire_to_pool(wire, wire_quant, pool_cache):
 
 
 def resolve_kv_block(paged=None, block=None):
-    """Paged-layout selection shared by the engine and bench: returns
-    the block size in tokens (0 = slot-contiguous layout).  An explicit
-    ``block`` wins; else ``$HETU_KV_BLOCK`` ("0" pins contiguous, an
-    integer enables paging at that block size, "auto" = paged with
-    block 16 on TPU, contiguous elsewhere — mirroring the
-    ``$HETU_SERVE_FAST`` convention).  ``paged=True`` forces paging
-    (default block 16), ``paged=False`` forces contiguous."""
+    """KV-layout selection: returns the block size in tokens (0 =
+    slot-contiguous layout).  An explicit ``block`` wins; else
+    ``$HETU_KV_BLOCK`` ("0" pins contiguous, an integer enables paging
+    at that block size, "auto" = paged with block 16, on every
+    backend).  ``paged=True`` forces paging (default block 16),
+    ``paged=False`` forces contiguous."""
     if paged is False:
         return 0
     if block is None:
         raw = str(envvars.get_str("HETU_KV_BLOCK") or "auto").strip().lower()
-        if raw in ("auto", ""):
-            block = 16 if (paged or jax.default_backend() == "tpu") else 0
-        else:
-            block = int(raw)
+        block = 16 if raw in ("auto", "") else int(raw)
     block = int(block)
     if paged and block <= 0:
         block = 16
@@ -778,10 +772,11 @@ class PagedKVManager:
         preserved), wholly-dead trailing blocks are swapped for fresh
         blocks with no copy.  A shared block is NEVER freed here — its
         refcount drops by one and every other holder keeps it.  In the
-        engine's speculative path this loop is a no-op (generation
-        never writes into a shared block: ``match_prefix`` caps sharing
-        below the last prompt position, so every writable block is
-        already private), but the discipline holds for any caller.
+        engine's speculative path the one shared block a slot rewrites
+        is its prompt's partial tail block, which ``register_prefix``
+        took a refcount on (``match_prefix`` caps what OTHER requests
+        attach below the last prompt position); the discipline holds
+        for any caller.
         Quantized pools move payload and scale planes together
         (``_block_copy``)."""
         if self.owner[slot] is None:
@@ -799,6 +794,10 @@ class PagedKVManager:
             partial = j == first_w and n % self.block != 0
             if not self._free:
                 self._evict_for(1)
+                if self.ref[b] <= 1:
+                    # the evicted prefix entry was the other holder:
+                    # the block is private now and needs no fork
+                    continue
             if not self._free:
                 raise RuntimeError(
                     f"pool exhausted un-COWing rollback of slot {slot} "

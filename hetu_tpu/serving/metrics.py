@@ -193,13 +193,6 @@ class ServingMetrics(MetricsCore):
         self.prefill_reqs = 0      # requests prefilled
         self.prefill_batched = 0   # batched (fast-path) dispatches
         self.components = {c: [] for c in COMPONENTS}
-        # mixed-mode ragged dispatch ($HETU_SERVE_RAGGED): the engine
-        # sets this when every step is ONE unified wave — prefill
-        # attribution then covers the whole ragged dispatch, so the
-        # chunk_stall component is noise-scale and folded to exactly 0
-        # at retirement; a residue over the threshold is reported and
-        # counted instead (see _retire)
-        self.mixed_mode = False
         # per-request breakdowns explain_tail() slices (ring: the tail
         # report is about RECENT behavior, same cap as the event ring)
         cap = max(1, envvars.get_int("HETU_TELEMETRY_BUFFER"))
@@ -409,22 +402,21 @@ class ServingMetrics(MetricsCore):
         prefill_wall_ms = max(lc.t_first - claim_end, 0.0) * 1e3
         prefill_ms = min(lc.prefill_ms, prefill_wall_ms)
         chunk_stall_ms = max(prefill_wall_ms - prefill_ms, 0.0)
-        if self.mixed_mode:
-            # unified wave: the whole ragged dispatch IS this request's
-            # prefill compute — any residue is host bookkeeping between
-            # claim and dispatch, noise-scale by construction, and is
-            # folded to 0.  A residue over the threshold is a host
-            # pause mid-prefill (a profiler starting, a long GC) or an
-            # accounting regression: the scheduler goes on, and the
-            # residue stays visible as chunk_stall_ms, one event and a
-            # counter that hetu_trace --check flags.
-            if chunk_stall_ms > max(50.0, 0.5 * prefill_wall_ms):
-                telemetry.inc("serve.lifecycle_residue")
-                self.event("serve_lifecycle_residue", request=request_id,
-                           residue_ms=round(chunk_stall_ms, 3),
-                           wall_ms=round(prefill_wall_ms, 3))
-            else:
-                chunk_stall_ms = 0.0
+        # every step is ONE unified wave, and the whole ragged dispatch
+        # IS this request's prefill compute — any residue is host
+        # bookkeeping between claim and dispatch, noise-scale by
+        # construction, and is folded to 0.  A residue over the
+        # threshold is a host pause mid-prefill (a profiler starting, a
+        # long GC) or an accounting regression: the scheduler goes on,
+        # and the residue stays visible as chunk_stall_ms, one event and
+        # a counter that hetu_trace --check flags.
+        if chunk_stall_ms > max(50.0, 0.5 * prefill_wall_ms):
+            telemetry.inc("serve.lifecycle_residue")
+            self.event("serve_lifecycle_residue", request=request_id,
+                       residue_ms=round(chunk_stall_ms, 3),
+                       wall_ms=round(prefill_wall_ms, 3))
+        else:
+            chunk_stall_ms = 0.0
         decode_ms = max(now - lc.t_first, 0.0) * 1e3 \
             if n_generated > 1 else 0.0
         ttft_ms = max(lc.t_first - lc.t_submit, 0.0) * 1e3
@@ -633,20 +625,17 @@ class ServingMetrics(MetricsCore):
             "components_mean_ms": {c: round(v, 3)
                                    for c, v in means.items()},
             "tail_requests": [b["request"] for b in tail[:8]],
-            "mixed_mode": self.mixed_mode,
+            "mixed_mode": True,
         }
+        # the unified wave carries all modes: prefill_ms here means
+        # "ragged dispatches this prompt rode in" and chunk_stall is 0
+        # by construction (folded at retirement)
         report["summary"] = (
             f"p{q} TTFT {cut:.1f}ms ({len(tail)}/{len(rows)} requests): "
             f"dominated by {dominant.replace('_ms', '')} "
             f"({ttft_parts[dominant]:.1f}ms, {share:.0%} of the "
-            f"pre-token wall)")
-        if self.mixed_mode:
-            # the unified wave carries all modes: prefill_ms here means
-            # "ragged dispatches this prompt rode in" and chunk_stall
-            # is 0 by construction (folded at retirement)
-            report["summary"] += (
-                " [mixed-mode: prefill attributed to unified ragged "
-                "waves; chunk_stall folded to 0]")
+            f"pre-token wall) [mixed-mode: prefill attributed to unified "
+            f"ragged waves; chunk_stall folded to 0]")
         return report
 
 

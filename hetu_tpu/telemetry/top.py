@@ -153,9 +153,9 @@ def summarize(events, window=512):
         tpot_ms = step_tpot
     drafted = accepted = 0
     spec_ks = []
-    # mixed-mode ragged dispatch ($HETU_SERVE_RAGGED): serve_step
-    # events carry the wave's per-mode q-token split — how many query
-    # rows were prompt prefill vs spec-verify vs plain decode
+    # every wave's serve_step event carries its per-mode q-token split:
+    # how many query rows were prompt prefill, spec-verify, plain decode
+    # (an embed engine's step has none)
     mix_tot = {"q_prefill": 0, "q_verify": 0, "q_decode": 0}
     mix_steps = 0
     for s in steps:
@@ -287,7 +287,7 @@ def summarize_fleet(events, window=4096):
                 r["drafted"] += e["spec_proposed"]
                 r["accepted"] += e.get("spec_accepted", 0)
             if isinstance(e.get("q_prefill"), int):
-                # mixed-mode wave: per-replica mode split
+                # per-replica mode split of the wave
                 r["q_prefill"] += e["q_prefill"]
                 r["q_verify"] += e.get("q_verify", 0) or 0
                 r["q_decode"] += e.get("q_decode", 0) or 0
@@ -445,10 +445,6 @@ def render_fleet(stats, clock=None):
     ]
     for r in stats["replicas"]:
         ver = r.get("version")
-        # mixed-mode columns stay "-" for phase-split replicas (their
-        # serve_step events carry no per-mode q split)
-        mixed = (r.get("q_prefill", 0) or r.get("q_verify", 0)
-                 or r.get("q_decode", 0))
         lines.append(
             f"{r['replica']:>3} {r['state']:<7} "
             f"{str(r.get('life') or '-'):<8} "
@@ -462,9 +458,8 @@ def render_fleet(stats, clock=None):
             f"{r['deaths']:>6} {r['drafted']:>7} "
             f"{_fmt(r['acceptance'], nd=2):>5} "
             f"{_fmt(r.get('dir_hit_rate'), nd=2):>5} "
-            f"{_fmt(r['q_prefill'] if mixed else None):>6} "
-            f"{_fmt(r['q_verify'] if mixed else None):>6} "
-            f"{_fmt(r['q_decode'] if mixed else None):>6} "
+            f"{_fmt(r['q_prefill']):>6} {_fmt(r['q_verify']):>6} "
+            f"{_fmt(r['q_decode']):>6} "
             # MoE columns stay "-" for dense replicas (their
             # serve_step events carry no moe_* fields)
             f"{_fmt(r.get('moe_imb'), nd=2):>5} "
@@ -568,8 +563,7 @@ def render(stats, clock=None):
             f"  mean_k {_fmt(sp['mean_k'], nd=1)}"))
     mx = s.get("mix")
     if mx:
-        # mixed-mode ragged dispatch: the per-step prefill/verify/
-        # decode q-token split of the unified waves
+        # the per-step prefill/verify/decode q-token split of the waves
         lines.insert(-1, (
             f"mixed     q_prefill {mx['q_prefill']}"
             f"  q_verify {mx['q_verify']}"

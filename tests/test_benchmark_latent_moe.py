@@ -63,11 +63,10 @@ def harness(seconds=2.0, trace=False, **args_over):
 
 
 @pytest.fixture
-def tpu_default_paths(monkeypatch):
-    """The engine's TPU defaults on the CPU (``tests/benchmark``'s own
-    fixture): mixed wave, paged pool of block 16, masked attention."""
-    monkeypatch.setenv("HETU_SERVE_RAGGED", "1")
-    monkeypatch.setenv("HETU_KV_BLOCK", "16")
+def tpu_default_paths():
+    """The engine's defaults as the runner takes them (mixed wave over
+    the paged pool of block 16, on the CPU with masked attention), with
+    the collector held off as ``tests/benchmark``'s own fixture does."""
     gc.collect()
     gc.disable()
     yield

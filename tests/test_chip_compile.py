@@ -111,16 +111,8 @@ def test_flash_op_under_a_mesh(topo, monkeypatch):
 
 
 # ------------------------------------------------------------------- #
-# serving path: the mixed-mode ragged kernel (the TPU default)
+# serving path: the mixed wave's ragged kernel (scores the wave on a TPU)
 # ------------------------------------------------------------------- #
-
-def _layer_pool(sds, heads):
-    """ONE layer of a pool with its head axes, ``[N, bs, H, Dh]`` bf16:
-    what the phase-split block kernels take."""
-    T = S_MAX // BLOCK
-    return (sds((SLOTS * T + 1, BLOCK, heads, DH), jnp.bfloat16),
-            sds((SLOTS, T), jnp.int32))
-
 
 def _pool(sds, heads, quant, layers=2):
     """The whole pool as the engine holds it: bf16 rows ``[L, N, bs,
@@ -288,29 +280,24 @@ def test_mixed_wave_tail(sds, window):
 
 
 # ------------------------------------------------------------------- #
-# serving path: the phase-split kernels ($HETU_SERVE_RAGGED=0)
+# the contiguous decode and verify kernels (``_decode_step`` and
+# ``_verify_step`` with attn="ragged"; no cell runs them)
 # ------------------------------------------------------------------- #
 
 @pytest.mark.parametrize("kernel", [
-    "paged_decode_attention", "paged_block_decode_attention",
-    "paged_verify_attention", "paged_block_verify_attention"])
+    "paged_decode_attention", "paged_verify_attention"])
 def test_phase_split(sds, kernel):
-    """At the widths the engine hands them: one query per slot for
-    decode, a k+1 = 8-position q-block for spec-verify."""
+    """One query per slot for decode, a k+1 = 8-position q-block for
+    spec-verify, over a contiguous cache."""
     H = 12
     lens = sds((SLOTS,), jnp.int32)
     verify = "verify" in kernel
     q = sds((SLOTS, 8, H, DH) if verify else (SLOTS, H, DH), jnp.bfloat16)
-    if "block" in kernel:
-        pool, tables = _layer_pool(sds, H)
-        kv, tail = (pool, pool), (tables,)
-    else:
-        c = sds((SLOTS, S_MAX, H, DH), jnp.bfloat16)
-        kv, tail = (c, c), ()
+    c = sds((SLOTS, S_MAX, H, DH), jnp.bfloat16)
     extra = (lens,) if verify else ()
     fn = getattr(da, kernel)
     compiled_text(lambda *a: fn(*a, interpret=False),
-                  q, *kv, lens, *extra, *tail)
+                  q, c, c, lens, *extra)
 
 
 

@@ -44,18 +44,18 @@ def test_train_phase(trained):
                          ids=["cpu-defaults", "tpu-defaults-interpreted"])
 def test_serve_phase(trained, monkeypatch, tpu_defaults):
     """Every request finishes with ``generate_fast``'s greedy tokens —
-    also through the settings the engine picks by itself on a TPU (fast
-    path, mixed ragged wave, paged KV block 16), here interpreted."""
+    also with the one setting the engine picks differently on a TPU
+    (the Pallas kernel in place of the masked reference), here
+    interpreted.  The mixed wave over paged KV block 16 is the default
+    on both."""
     cfg, ex, _ = trained
     if tpu_defaults:
         monkeypatch.setenv("HETU_SERVE_FAST", "1")
-        monkeypatch.setenv("HETU_SERVE_RAGGED", "1")
-        monkeypatch.setenv("HETU_KV_BLOCK", "16")
     rec = cs.serve_phase(ex.var_values, cfg, (3, 9, 20, 40), 8, seed=0)
     assert rec["requests"] == 4 and rec["tokens_out"] == 4 * 8
     assert rec["matches_generate_fast"] is True
-    assert rec["engine"]["ragged"] is tpu_defaults
-    assert rec["engine"]["kv_block"] == (16 if tpu_defaults else 0)
+    assert rec["engine"]["fast_path"] is tpu_defaults
+    assert rec["engine"]["paged"] and rec["engine"]["kv_block"] == 16
     json.dumps(rec)
 
 

@@ -472,8 +472,12 @@ class TestHandoffRouting:
         """The full disaggregated path: long prompts prefill on the
         prefill-heavy replica, the KV span hands off to a decode-heavy
         home, outputs stay token-identical to offline, events pair,
-        and the destination engine carries handoff_ms attribution."""
-        router = ServingRouter(_factory(model), replicas=2,
+        and the destination engine carries handoff_ms attribution.
+        Eight slots a replica: an import needs a free slot as its write
+        vehicle, and a two-slot decode replica is full of the earlier
+        arrivals' waves when the later ones land (those then admit
+        cold: ``handoff_failed``, never an error)."""
+        router = ServingRouter(_factory(model, slots=8), replicas=2,
                                roles="prefill,decode")
         assert router.roles == ["prefill", "decode"]
         assert router.replicas[0].kind == "prefill"
@@ -629,7 +633,8 @@ class TestFleetTopKV:
                                                  monkeypatch, capsys):
         slog = str(tmp_path / "serve.jsonl")
         monkeypatch.setenv("HETU_SERVE_LOG", slog)
-        router = ServingRouter(_factory(model), replicas=2,
+        # a free slot for every import, as in TestHandoffRouting
+        router = ServingRouter(_factory(model, slots=8), replicas=2,
                                roles="prefill,decode")
         sys_p = list(range(1, 18))
         router.run([Request(prompt=sys_p + [20 + i], max_new_tokens=3,

@@ -62,17 +62,17 @@ def test_fake_engine_cache_growth_raises():
 
     e = _Eng()
     e._name = "fake"
-    e._decode = jax.jit(lambda x: x + 1)
+    e._mixed = jax.jit(lambda x: x + 1)
     label = jit_audit.register_engine(e)
     assert label.startswith("fake#")
-    e._decode(np.ones(3, np.float32))
+    e._mixed(np.ones(3, np.float32))
     before = jit_audit.snapshot()
-    e._decode(np.ones(3, np.float32))          # same shape: cached
+    e._mixed(np.ones(3, np.float32))          # same shape: cached
     jit_audit.assert_no_recompile(before, context="steady wave")
-    e._decode(np.ones(5, np.float32))          # new shape: re-trace
+    e._mixed(np.ones(5, np.float32))          # new shape: re-trace
     with pytest.raises(jit_audit.JitAuditError) as ei:
         jit_audit.assert_no_recompile(before, context="shape leak")
-    assert "_decode" in str(ei.value) and "shape leak" in str(ei.value)
+    assert "_mixed" in str(ei.value) and "shape leak" in str(ei.value)
 
 
 def test_dead_engine_drops_out():
@@ -83,7 +83,7 @@ def test_dead_engine_drops_out():
 
     e = _Eng()
     e._name = "mortal"
-    e._decode = jax.jit(lambda x: x)
+    e._mixed = jax.jit(lambda x: x)
     jit_audit.register_engine(e)
     assert any(lbl.startswith("mortal#")
                for lbl in jit_audit.registered())

@@ -48,7 +48,7 @@ def params(cfg):
 
 def engine(params, cfg, **kw):
     kw = dict(dict(slots=4, max_seq_len=64, paged=True, kv_block=4,
-                   prefill_chunk=8, ragged=True, fast_path=False,
+                   prefill_chunk=8, fast_path=False,
                    prefix_share=False), **kw)
     return ServingEngine(params, cfg, **kw)
 
@@ -294,10 +294,9 @@ def test_prefix_sharing_and_cow_on_latent_blocks(params, cfg):
 
 @pytest.mark.parametrize("kw,names", [
     (dict(paged=False), "KVCacheManager"),
-    (dict(ragged=False), "phase-split"),
     (dict(spec=2), "speculation"),
     (dict(kv_quant="int8"), "int8"),
-], ids=["contiguous", "phase-split", "speculation", "int8-kv"])
+], ids=["contiguous", "speculation", "int8-kv"])
 def test_engine_refuses_other_paths(params, cfg, kw, names):
     with pytest.raises(ValueError, match=names):
         engine(params, cfg, **kw)

@@ -112,15 +112,13 @@ def _run_engine(params, cfg, **kw):
 
 
 ENGINE_MATRIX = [
-    ("contiguous_ref", dict(fast_path=False, paged=False, ragged=False)),
-    ("contiguous_fast", dict(fast_path=True, paged=False, ragged=False)),
-    ("paged", dict(fast_path=True, paged=16, ragged=False)),
-    ("paged_int8", dict(fast_path=True, paged=16, kv_quant="int8",
-                        ragged=False)),
-    ("spec", dict(fast_path=True, paged=False, spec=2, ragged=False)),
-    ("ragged", dict(fast_path=True, paged=16, ragged=True)),
-    ("ragged_chunked", dict(fast_path=True, paged=16, prefill_chunk=2,
-                            ragged=True)),
+    ("contiguous_ref", dict(fast_path=False, paged=False)),
+    ("contiguous_fast", dict(fast_path=True, paged=False)),
+    ("paged", dict(fast_path=True, paged=16)),
+    ("paged_int8", dict(fast_path=True, paged=16, kv_quant="int8")),
+    ("spec", dict(fast_path=True, paged=False, spec=2)),
+    ("paged_ref", dict(fast_path=False, paged=16)),
+    ("paged_chunked", dict(fast_path=True, paged=16, prefill_chunk=2)),
 ]
 
 

@@ -168,7 +168,7 @@ WAVE_SPANS = ["serve.admit", "serve.kv_alloc", "serve.wave",
 def _engine(model, **kw):
     p, cfg = model
     kw.setdefault("slots", 8)
-    return ServingEngine(p, cfg, ragged=True, paged=True, kv_block=8, **kw)
+    return ServingEngine(p, cfg, paged=True, kv_block=8, **kw)
 
 
 class TestWaveSpans:
@@ -226,12 +226,12 @@ class TestWaveSpans:
 
 class TestResultTimeline:
     @pytest.mark.parametrize("kw", [
-        dict(ragged=True, paged=True, kv_block=8, prefill_chunk=4),
-        dict(ragged=True, paged=True, kv_block=8, spec=2),
-        dict(ragged=False, paged=True, kv_block=8),
-        dict(ragged=False, paged=False),
-        dict(ragged=False, paged=False, spec=2),
-    ], ids=["mixed-chunked", "mixed-spec", "paged", "contiguous",
+        dict(paged=True, kv_block=8, prefill_chunk=4),
+        dict(paged=True, kv_block=8, spec=2),
+        dict(paged=True, kv_block=8),
+        dict(paged=False),
+        dict(paged=False, spec=2),
+    ], ids=["paged-chunked", "paged-spec", "paged", "contiguous",
             "contiguous-spec"])
     def test_token_times_and_queue_wait(self, model, kw):
         p, cfg = model
@@ -255,8 +255,8 @@ class TestResultTimeline:
 
     def test_a_speculative_wave_shares_one_stamp(self, model):
         p, cfg = model
-        eng = ServingEngine(p, cfg, slots=2, ragged=True, paged=True,
-                            kv_block=8, spec=2)
+        eng = ServingEngine(p, cfg, slots=2, paged=True, kv_block=8,
+                            spec=2)
         [r] = eng.run([Request(prompt=[4, 5, 6], max_new_tokens=12)]
                       ).values()
         # 12 tokens in fewer waves than tokens: some stamps repeat

@@ -582,24 +582,6 @@ class TestKVQuantKernels:
         exact = masked_decode_reference(q, k, v, lens)
         assert float(jnp.abs(ref - exact).max()) < 0.05
 
-    def test_block_table_kernel_matches_oracle(self):
-        rng = np.random.RandomState(21)
-        B, H, Dh, N, bs, T = 4, 2, 8, 20, 8, 8
-        from hetu_tpu.kernels.decode_attention import (
-            paged_block_decode_attention, paged_block_decode_reference)
-        q = jnp.asarray(rng.randn(B, H, Dh).astype(np.float32))
-        pk = jnp.asarray(rng.randn(N, bs, H, Dh).astype(np.float32))
-        pv = jnp.asarray(rng.randn(N, bs, H, Dh).astype(np.float32))
-        bt = jnp.asarray(rng.randint(1, N, (B, T)).astype(np.int32))
-        lens = jnp.asarray(np.array([3, 17, 0, 61], np.int32))
-        qk, sk = quant.kv_encode(pk)
-        qv, sv = quant.kv_encode(pv)
-        out = paged_block_decode_attention(q, qk, qv, lens, bt,
-                                           k_scale=sk, v_scale=sv)
-        ref = paged_block_decode_reference(q, qk, qv, lens, bt,
-                                           k_scale=sk, v_scale=sv)
-        assert float(jnp.abs(out - ref).max()) < 2e-5
-
 
 class TestKVQuantEngine:
     def _offline(self, model, prompts, n=6):

@@ -1,21 +1,22 @@
-"""Mixed-mode ragged dispatch (ISSUE 18): ONE kernel and ONE engine
-wave for the whole serving hot loop.
+"""The mixed ragged wave (ISSUE 18): ONE kernel and ONE engine wave
+for the whole serving hot loop.
 
 Kernel tier: ``ragged_attention`` / ``ragged_paged_attention`` (one
 parameterized Pallas body across contiguous/block-table x f32/int8)
 must match the ONE masked-gather oracle (``ragged_masked_reference``)
 on decode-only, verify-only, prefill-only, and freely mixed ``q_len``
-waves — including arbitrarily permuted pools and int8 scale planes —
-and must degenerate exactly to the per-mode kernels the phase-split
-engine still runs (those stay behind as parity oracles).
+waves — including arbitrarily permuted pools and int8 scale planes,
+bf16 pools, dead slots and a call under ``jit`` — and must degenerate
+exactly to the contiguous decode and verify kernels that
+``_decode_step`` and ``_verify_step`` still call.
 
-Engine tier: the load-bearing contract is TOKEN IDENTITY — a
-``ragged=True`` engine (``$HETU_SERVE_RAGGED``) that packs admissions,
-chunk continuations, spec-verify, and decode into one wave per step
-must emit exactly the tokens the phase-split scheduler emits, greedy
-AND sampled, across contiguous/paged/int8/chunked/prefix-shared/
-speculative configurations, while the ``chunk_stall`` lifecycle
-component collapses to exactly 0.
+Engine tier: the load-bearing contract is TOKEN IDENTITY — the engine,
+which packs admissions, chunk continuations, spec-verify, and decode
+into one wave per step, must emit exactly the tokens offline
+``generate_fast`` emits, greedy AND sampled, across
+contiguous/paged/int8/chunked/prefix-shared/speculative
+configurations, while the ``chunk_stall`` lifecycle component
+collapses to exactly 0.
 
 Everything runs on CPU via interpret mode; ``smoke``-tier.
 """
@@ -24,11 +25,11 @@ import numpy as np
 import pytest
 
 import hetu_tpu as ht  # noqa: F401  (platform forcing + compat shims)
+import jax
 import jax.numpy as jnp
 
 from hetu_tpu.kernels.decode_attention import (
     masked_decode_reference, masked_verify_reference,
-    paged_block_decode_attention, paged_block_verify_attention,
     paged_decode_attention, paged_verify_attention,
 )
 from hetu_tpu.kernels.ragged_attention import (
@@ -37,7 +38,7 @@ from hetu_tpu.kernels.ragged_attention import (
 )
 from hetu_tpu.kv_layout import kv_heads, kv_row_width, kv_rows
 from hetu_tpu.models import GPTConfig
-from hetu_tpu.models.gpt_decode import resolve_serve_ragged
+from hetu_tpu.models.gpt_decode import generate_fast
 from hetu_tpu.serving import Request, ServingEngine
 
 
@@ -110,12 +111,17 @@ class TestRaggedKernel:
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    atol=2e-5, rtol=2e-5)
 
+    # q_len 1 (a decode wave) and k+1 (a verify wave) are what the
+    # block-table decode and verify kernels computed; ``jit`` as the
+    # engine's step calls it
+    @pytest.mark.parametrize("jit", [False, True], ids=["eager", "jit"])
     @pytest.mark.parametrize("qlens", [
-        (1, 1, 1, 1), (4, 1, 2, 0)])
-    def test_permuted_pool_matches_reference(self, qlens):
+        (1, 1, 1, 1), (4, 4, 4, 4), (4, 1, 2, 0), (2, 0, 4, 1)])
+    def test_permuted_pool_matches_reference(self, qlens, jit):
         q, k, v, lens, ql = _wave(qlens=qlens)
         pk, pv, tables = _to_pool(k, v)
-        got = _paged(q, pk, pv, lens, ql, tables)
+        got = (jax.jit(_paged) if jit else _paged)(
+            q, pk, pv, lens, ql, tables)
         want = ragged_paged_reference(q, pk, pv, lens, ql, tables)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    atol=2e-5, rtol=2e-5)
@@ -135,8 +141,10 @@ class TestRaggedKernel:
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    atol=2e-5, rtol=2e-5)
 
-    def test_int8_twin_paged(self):
-        q, k, v, lens, ql = _wave()
+    @pytest.mark.parametrize("qlens", [
+        (4, 1, 2, 0), (1, 1, 1, 1), (4, 4, 4, 4)])
+    def test_int8_twin_paged(self, qlens):
+        q, k, v, lens, ql = _wave(qlens=qlens)
         pk, pv, tables = _to_pool(k, v)
         pk8, pks = _quantize(pk)
         pv8, pvs = _quantize(pv)
@@ -207,26 +215,35 @@ class TestRaggedKernel:
         # tile t of the whole prompt sees (t + 1) * tq positions
         assert fetches(1) == sum((t + 1) * tq // bk for t in range(n_t))
 
-    def test_zero_length_slot_returns_zeros(self):
+    @pytest.mark.parametrize("layout", ["contiguous", "paged"])
+    def test_zero_length_slot_returns_zeros(self, layout):
         q, k, v, lens, ql = _wave(qlens=(4, 1, 2, 0), lens=(17, 33, 5, 0))
-        got = np.asarray(ragged_attention(q, k, v, lens, ql, block_k=16,
-                                          interpret=True))
+        if layout == "paged":
+            pk, pv, tables = _to_pool(k, v)
+            got = np.asarray(_paged(q, pk, pv, lens, ql, tables))
+        else:
+            got = np.asarray(ragged_attention(q, k, v, lens, ql,
+                                              block_k=16, interpret=True))
         assert np.all(got[3] == 0.0)
 
-    def test_bf16_accumulates_f32(self):
+    @pytest.mark.parametrize("layout", ["contiguous", "paged"])
+    def test_bf16_accumulates_f32(self, layout):
         q, k, v, lens, ql = _wave()
-        got = ragged_attention(q.astype(jnp.bfloat16),
-                               k.astype(jnp.bfloat16),
-                               v.astype(jnp.bfloat16), lens, ql,
-                               block_k=16, interpret=True)
+        qb, kb, vb = (jnp.asarray(a, jnp.bfloat16) for a in (q, k, v))
+        if layout == "paged":
+            pk, pv, tables = _to_pool(np.asarray(kb), np.asarray(vb))
+            got = _paged(qb, pk, pv, lens, ql, tables)
+        else:
+            got = ragged_attention(qb, kb, vb, lens, ql, block_k=16,
+                                   interpret=True)
         assert got.dtype == jnp.bfloat16
         want = ragged_masked_reference(q, k, v, lens, ql)
         np.testing.assert_allclose(
             np.asarray(got, np.float32), np.asarray(want),
             atol=3e-2, rtol=3e-2)
 
-    # q_len = 1 IS the decode kernel; q_lens = spec widths IS the
-    # verify kernel — the phase-split kernels stay as parity oracles
+    # q_len = 1 IS the contiguous decode kernel; q_lens = spec widths IS
+    # the contiguous verify kernel
     def test_degenerates_to_decode_kernel(self):
         q, k, v, lens, _ = _wave()
         ones = np.ones_like(lens)
@@ -235,12 +252,6 @@ class TestRaggedKernel:
         old = np.asarray(paged_decode_attention(
             q[:, 0], k, v, lens, block_k=16, interpret=True))
         np.testing.assert_allclose(got[:, 0], old, atol=2e-5, rtol=2e-5)
-        pk, pv, tables = _to_pool(k, v)
-        gotp = np.asarray(_paged(q[:, :1], pk, pv, lens, ones, tables))
-        oldp = np.asarray(paged_block_decode_attention(
-            q[:, 0], pk, pv, lens, tables, interpret=True))
-        np.testing.assert_allclose(gotp[:, 0], oldp, atol=2e-5,
-                                   rtol=2e-5)
 
     def test_degenerates_to_verify_kernel(self):
         q, k, v, lens, ql = _wave()
@@ -250,11 +261,6 @@ class TestRaggedKernel:
                                                 block_k=16,
                                                 interpret=True))
         np.testing.assert_allclose(got, old, atol=2e-5, rtol=2e-5)
-        pk, pv, tables = _to_pool(k, v)
-        gotp = np.asarray(_paged(q, pk, pv, lens, ql, tables))
-        oldp = np.asarray(paged_block_verify_attention(
-            q, pk, pv, lens, ql, tables, interpret=True))
-        np.testing.assert_allclose(gotp, oldp, atol=2e-5, rtol=2e-5)
 
     # the four old per-mode references are now delegates of the ONE
     # parameterized oracle — pin the degenerate-mode equivalences
@@ -440,6 +446,21 @@ def _run(params, cfg, **kw):
     return sorted(r.tokens.tolist() for r in res.values()), eng
 
 
+@pytest.fixture(scope="module")
+def offline(model):
+    """TRACE through offline ``generate_fast``, a request at a time:
+    greedy requests through the teacher-forced scan; sampled requests
+    through offline speculation, which draws the engine's per-request
+    stream (``PRNGKey(seed)``, one split a generated token) and emits
+    the target's own sequential samples."""
+    p, cfg = model
+    return sorted(
+        (generate_fast(p, cfg, [pr], n, prefill="scan") if t == 0.0 else
+         generate_fast(p, cfg, [pr], n, temperature=t, top_k=k, seed=i,
+                       spec=1))[0].tolist()
+        for i, (pr, n, t, k) in enumerate(TRACE))
+
+
 @pytest.mark.smoke
 class TestMixedModeEngine:
     @pytest.mark.parametrize("cfg_kw", [
@@ -450,12 +471,11 @@ class TestMixedModeEngine:
         dict(paged=True, kv_block=8, prefix_share=True, prefill_chunk=4),
     ], ids=["contig", "contig-int8", "paged", "paged-chunk-int8",
             "paged-prefix-chunk"])
-    def test_token_identity_vs_phase_split(self, model, cfg_kw):
+    def test_token_identity_vs_offline(self, model, offline, cfg_kw):
         p, cfg = model
-        base, _ = _run(p, cfg, ragged=False, **cfg_kw)
-        mix, eng = _run(p, cfg, ragged=True, **cfg_kw)
-        assert eng.ragged
-        assert base == mix
+        mix, eng = _run(p, cfg, **cfg_kw)
+        assert eng.steps > 0 and eng.paged is cfg_kw["paged"]
+        assert mix == offline
 
     # greedy tokens of TRACE served by the mixed ragged wave over the
     # paged pool (fast path, block 8, chunk 4, prefix sharing), in the
@@ -477,7 +497,7 @@ class TestMixedModeEngine:
         the arithmetic is exact enough to compare, the masked path."""
         p, cfg = model
         kw = dict(paged=True, kv_block=8, prefill_chunk=4,
-                  prefix_share=True, ragged=True,
+                  prefix_share=True,
                   **{"f32": {}, "bf16": dict(dtype=jnp.bfloat16),
                      "int8": dict(kv_quant="int8")}[kind])
         reqs = [Request(prompt=pr, max_new_tokens=n, seed=i)
@@ -485,7 +505,7 @@ class TestMixedModeEngine:
 
         def served(fast):
             eng = ServingEngine(p, cfg, slots=4, fast_path=fast, **kw)
-            assert eng.ragged and eng.paged
+            assert eng.paged
             if kind != "int8":
                 assert eng.kv.cache_k.shape[-1] == 128    # rows, padded
             out = eng.run([Request(prompt=r.prompt,
@@ -501,19 +521,16 @@ class TestMixedModeEngine:
         if kind != "bf16":
             assert served(False) == got                   # masked path
 
-    def test_spec_decode_composes(self, model):
+    def test_spec_decode_composes(self, model, offline):
         p, cfg = model
-        kw = dict(paged=True, kv_block=8, kv_quant="int8",
-                  prefill_chunk=4)
-        plain, _ = _run(p, cfg, ragged=False, **kw)
-        mix, eng = _run(p, cfg, ragged=True, spec=2, **kw)
+        mix, eng = _run(p, cfg, spec=2, paged=True, kv_block=8,
+                        kv_quant="int8", prefill_chunk=4)
         assert eng.spec_k == 2 and eng.spec_waves > 0
-        assert plain == mix
+        assert mix == offline
 
     def test_chunk_stall_folds_to_zero(self, model):
         p, cfg = model
-        _, eng = _run(p, cfg, ragged=True, paged=True, kv_block=8,
-                      prefill_chunk=4)
+        _, eng = _run(p, cfg, paged=True, kv_block=8, prefill_chunk=4)
         cs = eng.metrics.components["chunk_stall_ms"]
         assert cs and all(v == 0.0 for v in cs)
         # kept in the schema for back-compat dashboards
@@ -527,8 +544,7 @@ class TestMixedModeEngine:
         # prefix_share off: every prompt token is then COMPUTED in some
         # wave, so the q_prefill ledger must sum to the trace exactly
         # (shared prefixes would legitimately skip their cached tokens)
-        _, eng = _run(p, cfg, ragged=True, paged=True, kv_block=8,
-                      prefix_share=False)
+        _, eng = _run(p, cfg, paged=True, kv_block=8, prefix_share=False)
         steps = [e for e in eng.metrics.events
                  if e["event"] == "serve_step"]
         assert steps
@@ -537,19 +553,6 @@ class TestMixedModeEngine:
         assert sum(e["q_prefill"] for e in steps) == \
             sum(len(pr) for pr, *_ in TRACE)
         assert sum(e["q_decode"] for e in steps) > 0
-
-    def test_env_resolution(self, monkeypatch, model):
-        for val, want in [("1", True), ("mixed", True), ("ragged", True),
-                          ("0", False), ("phase", False), ("off", False)]:
-            monkeypatch.setenv("HETU_SERVE_RAGGED", val)
-            assert resolve_serve_ragged() is want, val
-        monkeypatch.setenv("HETU_SERVE_RAGGED", "auto")
-        assert resolve_serve_ragged() is False   # CPU backend
-        assert resolve_serve_ragged(True) is True
-        monkeypatch.setenv("HETU_SERVE_RAGGED", "1")
-        p, cfg = model
-        eng = ServingEngine(p, cfg, slots=4, paged=True, kv_block=8)
-        assert eng.ragged and eng.metrics.mixed_mode
 
 
 # ------------------------------------------------------------------- #
@@ -675,7 +678,7 @@ class TestSamplingWindow:
         import jax
         from hetu_tpu.serving.kv_manager import assemble_mixed_wave
         p, cfg = _rand_gpt(S=256)
-        eng = ServingEngine(p, cfg, slots=4, ragged=True, paged=True,
+        eng = ServingEngine(p, cfg, slots=4, paged=True,
                             kv_block=8, spec=spec or None)
         B, Q, V, W = 4, 128, cfg.vocab_size, spec + 1
         wave = assemble_mixed_wave(B, {0: (list(range(1, 101)), 0, 99, True),
@@ -733,7 +736,7 @@ class TestSamplingWindow:
                 built.append(event)
 
         p, cfg = _rand_gpt(name="wnd", V=67)     # programs nobody built
-        eng = ServingEngine(p, cfg, slots=4, ragged=True, paged=True,
+        eng = ServingEngine(p, cfg, slots=4, paged=True,
                             kv_block=8, prefill_chunk=16)
         programs = eng._mixed.func._cache_size   # one jit, every engine
         before = programs()

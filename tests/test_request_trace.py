@@ -1,9 +1,8 @@
 """Request-lifecycle observability (ISSUE 7 tentpole).
 
-The acceptance spine: a trace-replay run (the ``HETU_BENCH_SERVE``
-harness shape — seeded mixed-length requests through the continuous-
-batching engine) exports a Perfetto trace where each request has its
-OWN track showing its queue/kv_alloc/prefill/decode lifecycle with
+The acceptance spine: a trace-replay run (seeded mixed-length requests
+through the continuous-batching engine) exports a Perfetto trace where
+each request has its OWN track showing its queue/kv_alloc/prefill/decode lifecycle with
 flow arrows into the engine's fused-step wave spans;
 ``explain_tail()`` names the component that dominates p99 TTFT; a
 deliberately-undersized SLO flips ``engine.health()`` to "breach" and
@@ -82,8 +81,8 @@ def _fresh(monkeypatch):
 
 
 def _mixed_trace(n_req=10, seed=1234, vocab=61):
-    """Seeded mixed-length trace, the HETU_BENCH_SERVE harness shape:
-    mostly short requests, a longer straggler every 5th."""
+    """Seeded mixed-length trace: mostly short requests, a longer
+    straggler every 5th."""
     rng = np.random.RandomState(seed)
     trace = []
     for i in range(n_req):
@@ -373,7 +372,7 @@ class TestFlightRecorder:
 
         def boom(*a, **k):
             raise RuntimeError("injected decode fault")
-        monkeypatch.setattr(eng, "_decode", boom)
+        monkeypatch.setattr(eng, "_mixed_wave", boom)
         with pytest.raises(RuntimeError, match="injected"):
             eng.step()
         recs = [json.loads(ln) for ln in open(flog) if ln.strip()]
@@ -448,7 +447,7 @@ class TestCounterExport:
         cs = [e for e in trace["traceEvents"] if e.get("ph") == "C"]
         names = {e["name"] for e in cs}
         assert {"serve.queue_depth", "serve.live",
-                "serve.occupancy", "serve.slots_free"} <= names
+                "serve.occupancy", "serve.blocks_free"} <= names
         for e in cs:
             assert isinstance(e["args"]["value"], (int, float))
 
@@ -530,7 +529,9 @@ class TestHetuTop:
             assert needle in frame, needle
 
     def test_cli_once(self, replay, capsys):
-        assert top.main([replay["log"], "--once"]) == 0
+        # the whole replay: a wave's seven spans push the ten submits
+        # out of the default window of 512 records
+        assert top.main([replay["log"], "--once", "--window", "0"]) == 0
         out = capsys.readouterr().out
         assert "hetu_top" in out and "submitted 10" in out
 

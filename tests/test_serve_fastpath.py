@@ -297,9 +297,8 @@ class TestEngineFastPathParity:
 @pytest.mark.smoke
 class TestBatchedAdmission:
     def test_burst_costs_one_dispatch(self, model):
-        """A burst of k same-bucket arrivals: ONE batched prefill
-        dispatch on the fast path, k on the reference — with identical
-        outputs."""
+        """A burst of k arrivals is ONE wave — one dispatch for the k
+        prompts, kernel and reference alike — with identical outputs."""
         p, cfg = model
         burst = [Request(prompt=[i + 1, i + 2, i + 3], max_new_tokens=4)
                  for i in range(4)]
@@ -315,13 +314,15 @@ class TestBatchedAdmission:
         ref_eng, ref = run(False)
         fast_eng, fast = run(True)
         assert fast == ref
-        assert ref_eng.prefill_dispatches == 4
-        assert fast_eng.prefill_dispatches == 1
-        assert fast_eng.metrics.prefill_batched == 1
+        for eng in (ref_eng, fast_eng):
+            assert eng.prefill_dispatches == 1
+            assert eng.metrics.prefill_batched == 1
+            assert eng.steps == 1 + 3      # the prompts' wave, 3 decode
 
-    def test_mixed_buckets_group_per_bucket(self, model):
-        """Arrivals spanning two prompt buckets: one dispatch per
-        bucket, not per request; non-pow2 group sizes pad safely."""
+    def test_mixed_buckets_share_the_wave(self, model):
+        """Arrivals spanning two prompt buckets are still one wave, as
+        wide as the longest (bucket 16); non-pow2 group sizes pad
+        safely."""
         p, cfg = model
         reqs = [Request(prompt=[1, 2], max_new_tokens=3),          # b8
                 Request(prompt=[3, 4, 5], max_new_tokens=3),       # b8
@@ -331,7 +332,10 @@ class TestBatchedAdmission:
                             fast_path=True)
         res = eng.run(reqs)
         assert len(res) == 4
-        assert eng.prefill_dispatches == 2     # bucket 8 + bucket 16
+        assert eng.prefill_dispatches == 1
+        pre = [e for e in eng.metrics.events
+               if e["event"] == "serve_prefill"]
+        assert [(e["n"], e["bucket"]) for e in pre] == [(4, 16)]
         ref = ServingEngine(p, cfg, slots=4, queue_limit=8,
                             fast_path=False)
         res_ref = ref.run([Request(prompt=r.prompt, max_new_tokens=3)
@@ -340,15 +344,20 @@ class TestBatchedAdmission:
             sorted(r.tokens.tolist() for r in res_ref.values())
 
     def test_finish_at_prefill_frees_slot_same_step(self, model):
-        """The admission-wave loop preserves the reference semantics:
-        max_new_tokens=1 retires at admission and the freed slot admits
-        the next queued request within the same step()."""
+        """max_new_tokens=1 retires in the wave that wrote the prompt:
+        the slot is free when step() returns and the next step admits
+        the next queued request."""
         p, cfg = model
         eng = ServingEngine(p, cfg, slots=1, fast_path=True)
-        res = eng.run([Request(prompt=[7, 8, 9], max_new_tokens=1),
-                       Request(prompt=[3, 4], max_new_tokens=1)])
-        assert all(r.n_generated == 1 for r in res.values())
-        assert eng.steps == 0
+        for r in (Request(prompt=[7, 8, 9], max_new_tokens=1),
+                  Request(prompt=[3, 4], max_new_tokens=1)):
+            eng.submit(r)
+        first = eng.step()
+        assert len(first) == 1 and not eng.kv.live()
+        second = eng.step()
+        assert len(second) == 1 and not eng.pending
+        assert all(r.n_generated == 1 for r in first + second)
+        assert eng.steps == 2 and eng.prefill_dispatches == 2
 
 
 @pytest.mark.smoke

@@ -6,12 +6,11 @@ The load-bearing contract: engine outputs are a pure function of each
 Request (prompt, seed, settings) — token-identical to offline
 ``generate_fast`` for greedy, identical across arrival orders and slot
 assignments for sampling — while short requests leave the batch early
-and new ones take their slots between fused decode steps.
+and new ones take their slots between waves.
 
 Weights are a deterministic random GPT parameter dict (the engine's
 contract is numeric parity, not model quality), so the whole file runs
-in seconds; it is part of the ``smoke`` battery except the bench
-speedup measurement.
+in seconds; it is part of the ``smoke`` battery.
 """
 
 import json
@@ -223,8 +222,8 @@ class TestSchedulerEdgeCases:
 
     def test_same_length_degenerates_to_static_batching(self, model):
         """All requests the same shape, submitted together: one
-        admission wave, full batch every step, one retirement wave —
-        exactly static batching."""
+        admission, ONE wave for the four prompts, full batch every
+        step, one retirement wave — exactly static batching."""
         p, cfg = model
         eng = ServingEngine(p, cfg, slots=4, queue_limit=8)
         reqs = [Request(prompt=[i + 1, i + 2, i + 3], max_new_tokens=6)
@@ -233,8 +232,13 @@ class TestSchedulerEdgeCases:
         assert len(res) == 4
         snap = eng.metrics.snapshot()
         assert snap["mean_batch_occupancy"] == 1.0
-        # prefill emits token 1; the remaining 5 come from 5 fused steps
-        assert eng.steps == 5
+        # the burst's prompts are one wave, which emits token 1; the
+        # remaining 5 come from 5 decode waves
+        assert eng.steps == 6 and eng.prefill_dispatches == 1
+        steps = [e for e in eng.metrics.events
+                 if e["event"] == "serve_step"]
+        assert [e["q_prefill"] for e in steps] == [12, 0, 0, 0, 0, 0]
+        assert [e["q_decode"] for e in steps] == [0, 4, 4, 4, 4, 4]
         assert eng.kv.total_allocs == 4   # no slot ever recycled
 
     def test_long_straggler_slots_cycle(self, model):
@@ -258,14 +262,22 @@ class TestSchedulerEdgeCases:
         assert res[straggler.request_id].tokens.tolist() == want.tolist()
 
     def test_short_circuit_finish_at_prefill(self, model):
-        """max_new_tokens=1 (or instant EOS) retires at admission — the
-        slot frees before the fused step even runs."""
+        """max_new_tokens=1 (or instant EOS) retires in the wave that
+        wrote its prompt — the slot is free when the step returns, and
+        the next step admits the request behind it."""
         p, cfg = model
         eng = ServingEngine(p, cfg, slots=1)
-        res = eng.run([Request(prompt=[7, 8, 9], max_new_tokens=1),
-                       Request(prompt=[3, 4], max_new_tokens=1)])
-        assert all(r.n_generated == 1 for r in res.values())
-        assert eng.steps == 0             # never needed a decode step
+        a = eng.submit(Request(prompt=[7, 8, 9], max_new_tokens=1))
+        b = eng.submit(Request(prompt=[3, 4], max_new_tokens=1))
+        first = eng.step()
+        assert [r.request_id for r in first] == [a.request_id]
+        assert not eng.kv.live() and eng.queue_depth == 1
+        assert [r.request_id for r in eng.step()] == [b.request_id]
+        assert all(r.n_generated == 1 for r in first)
+        # two prompt waves and never a decode row
+        assert eng.steps == 2 and eng.prefill_dispatches == 2
+        assert not any(e["q_decode"] for e in eng.metrics.events
+                       if e["event"] == "serve_step")
         assert eng.kv.total_allocs == 2
 
 
@@ -312,194 +324,34 @@ class TestServingMetrics:
         assert os.path.exists(log)
 
 
-def test_bench_serve_continuous_beats_static(tmp_path, monkeypatch):
-    """Acceptance: under the seeded mixed-length trace, continuous
-    batching measures higher useful-token throughput than the static
-    pad-to-longest baseline on the same harness, the masked-vs-ragged
-    fast-path A/B records per-phase timings with GREEDY-IDENTICAL
-    outputs, and flash prefill beats the scan prefill at prompt length
-    128 — all recorded in the artifact."""
-    import bench
-    monkeypatch.setattr(bench, "_SERVE_FILE",
-                        str(tmp_path / "BENCH_SERVE.json"))
-    art = bench.bench_serve("cpu", reduced=True)
-    cont = art["continuous"]["tokens_per_sec"]
-    stat = art["static_baseline"]["tokens_per_sec"]
-    assert cont > stat, (cont, stat)
-    assert art["speedup"] > 1.0
-    assert art["continuous"]["ttft_p50_s"] is not None
-    assert art["continuous"]["mean_batch_occupancy"] > 0
-    # request-lifecycle observability rides the same replay (ISSUE 7):
-    # the artifact records the tail decomposition + SLO state
-    obs = art["observability"]
-    assert obs["explain_tail"]["dominant_component"] in obs["components"]
-    assert obs["health"] in ("ok", "degraded", "breach")
-    assert obs["slo"]["health"] == obs["health"]
-    # fast-path A/B: acceptance is greedy parity + per-phase numbers
-    # (the ragged-vs-masked WIN is an on-chip claim — interpret-mode
-    # emulation pays per-block overhead on CPU; suite stage 4c measures)
-    for section in ("fast_path_ab", "prefill_heavy"):
-        ab = art[section]
-        assert ab["greedy_identical"] is True
-        for path in ("masked", "ragged"):
-            assert ab[path]["tokens_per_sec"] > 0
-            assert ab[path]["prefill_total_s"] is not None
-            assert ab[path]["decode_total_s"] is not None
-    # flash prefill beats the teacher-forced scan at P=128 even on the
-    # CPU harness (the scan pays P sequential [1, D] dispatch rounds)
-    pf = art["phase_ab"]["prefill"]
-    assert pf["prompt_len"] >= 128
-    assert pf["flash_ms"] < pf["scan_ms"], pf
-    assert len(art["phase_ab"]["decode"]) == 2
-    for row in art["phase_ab"]["decode"]:
-        assert row["masked_ms"] > 0 and row["ragged_ms"] > 0
-    # paged-vs-contiguous at equal cache bytes on the prefix-heavy
-    # trace: identical greedy outputs, and the paged pool holds >= 2x
-    # the concurrent slots (the shared system prompt is stored once and
-    # requests reserve actual spans, not S_max)
-    pg = art["paged_ab"]
-    assert pg["greedy_identical"] is True
-    assert pg["slot_capacity_ratio"] >= 2.0, pg
-    assert pg["paged"]["hbm_bytes_per_slot"] * 2 <= \
-        pg["contiguous"]["hbm_bytes_per_slot"], pg
-    assert pg["paged"]["kv"]["prefix_hits"] > 0
-    assert pg["paged"]["kv"]["cow_copies"] > 0
-    # fleet A/B at equal resources (ISSUE 8): greedy parity single
-    # engine vs the 2-replica router, both rates + fleet TTFT p99
-    # recorded live, and the overload run proves the shedding contract
-    # — throughput-class shed first, admitted latency-class TTFT p95
-    # inside the configured SLO
-    fl = art["fleet_ab"]
-    assert fl["provenance"] == "live" and fl["platform"] == "cpu"
-    assert fl["greedy_identical"] is True
-    assert fl["single_engine"]["tokens_per_sec"] > 0
-    assert fl["fleet"]["tokens_per_sec"] > 0
-    assert fl["fleet"]["ttft_p99_s"] is not None
-    assert all(n > 0 for n in fl["fleet"]["routed_per_replica"])
-    # rolling-swap A/B (ISSUE 15): the v1 -> v2 rollout lands mid-trace
-    # with zero loss, every result version-stamped, and the mid-swap
-    # throughput above the availability floor (also asserted in-bench)
-    sw = art["swap_ab"]
-    assert sw["provenance"] == "live" and sw["platform"] == "cpu"
-    assert sw["rolling"]["rollout_state"] == "done"
-    assert sw["rolling"]["lost"] == 0 and sw["steady"]["lost"] == 0
-    assert sw["rolling"]["fleet_versions"] == {0: 2, 1: 2}
-    assert sum(sw["rolling"]["served_by_version"].values()) == \
-        sw["rolling"]["finished"]
-    assert sw["availability"] is not None and sw["availability"] >= 0.25
-    # elastic-fleet A/B (ISSUE 16): at equal peak capacity over the
-    # same diurnal trace, the autoscaled arm actually scales (>= 1 up
-    # and >= 1 down), loses nothing, spends fewer virtual
-    # replica-seconds at equal-or-better SLO attainment, and stays
-    # token-identical to the static arm (floors also asserted in-bench)
-    asc = art["autoscale_ab"]
-    assert asc["provenance"] == "live" and asc["platform"] == "cpu"
-    assert asc["static"]["lost"] == 0 and asc["autoscaled"]["lost"] == 0
-    assert asc["static"]["scale_ups"] == 0 \
-        and asc["static"]["scale_downs"] == 0
-    assert asc["autoscaled"]["scale_ups"] >= 1
-    assert asc["autoscaled"]["scale_downs"] >= 1
-    assert asc["autoscaled"]["replica_seconds"] < \
-        asc["static"]["replica_seconds"]
-    assert asc["replica_seconds_saved"] > 0
-    assert asc["autoscaled"]["slo_attainment"] >= \
-        asc["static"]["slo_attainment"] >= 0.98
-    assert asc["autoscaled"]["peak_replicas"] == 2
-    assert asc["token_identical_common"] > 0
-    ov = fl["overload_shed"]
-    assert ov["shed"] > 0
-    assert ov["shed_by_class"]["latency"] == 0
-    assert ov["shed_by_class"]["throughput"] == ov["shed"]
-    assert ov["latency_within_slo"] is True
-    # speculative A/B (ISSUE 10): greedy token-identity spec-vs-plain,
-    # a wall-clock tok/s win at the acceptance-1.0 endpoint (floor also
-    # asserted in-bench), acceptance + mean-k stamped on the row, the
-    # temperature sweep degrading acceptance with identity intact, and
-    # TPOT percentiles from real per-step token counts in both modes
-    sa = art["spec_ab"]
-    assert sa["provenance"] == "live" and sa["platform"] == "cpu"
-    assert sa["greedy_identical"] is True
-    assert sa["speedup"] > 0
-    if (os.cpu_count() or 1) >= 2:
-        # 1-core hosts serialize draft + batched verify onto the same
-        # core, so the wall-clock floor only binds with >= 2 cores
-        # (mirrors the in-bench gate; identity/acceptance floors below
-        # bind everywhere)
-        assert sa["speedup"] >= 1.05
-    assert sa["spec"]["acceptance_rate"] >= 0.95
-    assert sa["spec"]["mean_k"] > 0
-    assert sa["spec"]["tokens_per_step_mean"] > \
-        sa["plain"]["tokens_per_step_mean"]
-    for row in (sa["plain"], sa["spec"]):
-        assert row["tpot_p50_s"] is not None
-        assert row["tpot_p99_s"] >= row["tpot_p50_s"]
-    for srow in sa["acceptance_sweep"]:
-        assert srow["identical"] is True
-        assert srow["acceptance_rate"] <= sa["spec"]["acceptance_rate"]
-    # tiered-KV prefix storm (ISSUE 17): at equal pool size, the
-    # ladder saves strictly more recompute tokens than drop-on-evict
-    # with zero loss and greedy identity everywhere, the full ladder
-    # cycles (spills, fetches, ring -> PS demotions), and the
-    # PS-chaos arm degrades (ps_dead) without taking a replica down
-    # (floors also asserted in-bench)
-    storm = art["prefix_storm_ab"]
-    assert storm["provenance"] == "live" and storm["platform"] == "cpu"
-    assert storm["greedy_identical"] is True
-    for arm in ("drop_on_evict", "tiered", "tiered_ps_chaos"):
-        row = storm[arm]
-        assert row["lost"] == 0 and row["shed"] == 0 \
-            and row["rejected"] == 0, (arm, row)
-        assert row["replica_restarts"] == 0, (arm, row)
-    assert storm["recompute_tokens_saved_delta"] > 0, storm
-    assert storm["tiered"]["recompute_tokens_saved"] > \
-        storm["drop_on_evict"]["recompute_tokens_saved"]
-    tst = storm["tiered"]["tiers"]
-    assert sum(tst["spills"].values()) > 0
-    assert sum(tst["fetches"].values()) > 0
-    assert tst["demotes"] > 0
-    cst = storm["tiered_ps_chaos"]["tiers"]
-    assert cst["ps_dead"] is True and cst["ps_entries"] == 0
-    assert storm["drop_on_evict"]["tiers"] is None
-    # mixed-mode ragged dispatch (ISSUE 18): greedy token-identity
-    # ragged-vs-phase-split on the mixed trace, chunk_stall EXACTLY
-    # zero in the ragged arm while the phase-split arm still pays it,
-    # and tok/s no worse (strict speedup is an on-chip claim — stage
-    # 4c; floors also asserted in-bench)
-    ra = art["ragged_ab"]
-    assert ra["provenance"] == "live" and ra["platform"] == "cpu"
-    assert ra["greedy_identical"] is True
-    assert ra["ragged"]["chunk_stall_p99_ms"] in (None, 0.0), ra
-    assert ra["phase_split"]["chunk_stall_p99_ms"] > 0, ra
-    assert ra["speedup"] > 0
-    assert ra["ragged"]["tail_dominant"] != "chunk_stall_ms"
-    for arm in ("phase_split", "ragged"):
-        assert ra[arm]["tokens_per_sec"] > 0
-        assert ra[arm]["ttft_p99_s"] is not None
-    # MoE vs dense at equal active params (ISSUE 20): greedy identity
-    # vs offline at un-binding capacity (drop rate exactly zero), the
-    # binding probe drops while load+drop still accounts for every
-    # (token, rank), and expert telemetry rides the artifact (floors
-    # also asserted in-bench; stage 4c banks moe_ab on chip)
-    ma = art["moe_ab"]
-    assert ma["provenance"] == "live" and ma["platform"] == "cpu"
-    assert ma["greedy_identical"] is True
-    assert ma["moe"]["drop_rate"] == 0.0
-    assert ma["moe"]["expert_imbalance"] >= 1.0
-    assert len(ma["moe"]["expert_load"]) == \
-        ma["equal_active_params"]["experts"]
-    assert sum(ma["moe"]["expert_load"]) > 0
-    assert ma["equal_active_params"]["active_ffn_per_token"] == \
-        ma["equal_active_params"]["dense_ffn_size"]
-    assert ma["capacity_binding"]["drop_rate"] > 0
-    assert ma["capacity_binding"]["invariant_ok"] is True
-    for arm in ("dense", "moe"):
-        assert ma[arm]["tokens_per_sec"] > 0
-        assert ma[arm]["ttft_p99_s"] is not None
-    assert ma["speedup_vs_dense"] > 0
-    with open(tmp_path / "BENCH_SERVE.json") as f:
-        on_disk = json.load(f)
-    assert on_disk["continuous"]["tokens_per_sec"] == cont
-    assert on_disk["static_baseline"]["tokens_per_sec"] == stat
-    assert on_disk["fast_path_ab"]["greedy_identical"] is True
-    assert on_disk["fleet_ab"]["greedy_identical"] is True
-    assert on_disk["prefix_storm_ab"]["greedy_identical"] is True
+@pytest.mark.parametrize("backend", ["cpu", "tpu"])
+def test_default_is_one_path(model, monkeypatch, backend):
+    """``ServingEngine(params, cfg)`` is the same scheduler over the same
+    manager on every backend: the mixed wave over the paged pool of
+    block 16, chosen by no argument.  The one thing that follows the
+    platform is which code scores the wave: the Pallas kernel on a TPU,
+    the masked reference elsewhere."""
+    import inspect
+
+    import jax
+    from hetu_tpu.models.gpt_decode import _resolve_fast
+    from hetu_tpu.serving.kv_manager import (PagedKVManager,
+                                             resolve_kv_block)
+    for knob in ("HETU_KV_BLOCK", "HETU_SERVE_FAST"):
+        monkeypatch.delenv(knob, raising=False)
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    assert resolve_kv_block() == 16
+    assert _resolve_fast() is (backend == "tpu")
+    assert "ragged" not in inspect.signature(
+        ServingEngine.__init__).parameters
+    p, cfg = model
+    eng = ServingEngine(p, cfg)
+    assert isinstance(eng.kv, PagedKVManager) and eng.kv.block == 16
+    assert eng.paged and eng.fast_path is (backend == "tpu")
+    assert eng._mixed.keywords["attn"] == (
+        "ragged" if backend == "tpu" else "masked")
+    waves = []
+    monkeypatch.setattr(
+        ServingEngine, "_mixed_wave",
+        lambda self, root, wave_id: waves.append(wave_id) or [])
+    assert eng.step() == [] and waves == [1]

@@ -136,27 +136,6 @@ class TestVerifyKernel:
                                    np.asarray(want), rtol=1e-5,
                                    atol=1e-5)
 
-    def test_block_table_variant_permuted_pool(self):
-        from hetu_tpu.kernels.decode_attention import (
-            paged_block_verify_attention, paged_block_verify_reference,
-        )
-        rng = np.random.RandomState(1)
-        B, Q, H, Dh, bs, T = 3, 3, 2, 8, 8, 6
-        N = B * T + 1
-        pool_k = rng.randn(N, bs, H, Dh).astype(np.float32)
-        pool_v = rng.randn(N, bs, H, Dh).astype(np.float32)
-        q = rng.randn(B, Q, H, Dh).astype(np.float32)
-        perm = rng.permutation(np.arange(1, N))[:B * T]
-        tables = perm.reshape(B, T).astype(np.int32)
-        lens = np.array([bs * 2 + 3, bs * T, 2], np.int32)
-        qlens = np.array([Q, Q - 1, 1], np.int32)
-        got = paged_block_verify_attention(q, pool_k, pool_v, lens,
-                                           qlens, tables)
-        want = paged_block_verify_reference(q, pool_k, pool_v, lens,
-                                            qlens, tables)
-        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                                   rtol=1e-5, atol=1e-5)
-
     def test_int8_variants(self):
         from hetu_tpu.kernels.decode_attention import (
             masked_verify_reference, paged_verify_attention,
@@ -576,6 +555,29 @@ class TestKVRollback:
                 for t in prompt[:6]]
         assert [g[0] for g in got] == want
 
+    def test_truncate_on_a_full_pool_evicts_the_prefix_it_shares(self):
+        """A slot's own registered prompt is the other holder of its
+        partial tail block: on a pool with nothing free, rollback evicts
+        that entry and goes on with the block, private again, in place
+        (the default engine with ``spec`` on a just-large-enough pool)."""
+        m = PagedKVManager(layers=1, heads=1, head_dim=4, slots=1,
+                           max_seq_len=16, block=4, pool_blocks=5,
+                           prefix_share=True)
+        prompt = [1, 2, 3, 4, 5, 6]                 # tail block: 2 of 4
+        s0, _ = m.alloc("a", prompt, 16)
+        assert m.free_blocks == 0
+        m.advance(s0, 6)
+        m.register_prefix(np.asarray(prompt), s0)
+        tail = int(m.tables[s0, 1])
+        assert m.ref[tail] == 2
+        m.advance(s0, 2)                            # a verify block
+        cow0 = m.cow_copies
+        m.truncate(s0, 7)                           # one token rejected
+        assert int(m.tables[s0, 1]) == tail and m.ref[tail] == 1
+        assert m.cow_copies == cow0 and int(m.lengths[s0]) == 7
+        m.release(s0)
+        assert m.free_blocks == m.n_blocks - 1
+
     def test_engine_rollback_leaves_pool_consistent(self, model):
         """End to end: a paged speculative run releases every block it
         reserved — refcounts return to zero, the free list to full."""
@@ -626,10 +628,16 @@ class TestTpotAccounting:
         with open(log) as f:
             recs = [json.loads(ln) for ln in f]
         steps = [r for r in recs if r["event"] == "serve_step"]
-        assert steps and all("spec_k" in r and "spec_proposed" in r
-                             and "spec_accepted" in r and
-                             "new_tokens" in r for r in steps)
-        assert sum(r["spec_accepted"] for r in steps) == \
+        # a wave with a verify block carries the draft's fields; a wave
+        # of prompts alone proposed nothing and carries none
+        verify = [r for r in steps if r["q_verify"]]
+        assert verify and len(verify) == eng.spec_waves
+        assert all("spec_k" in r and "spec_proposed" in r
+                   and "spec_accepted" in r and "new_tokens" in r
+                   for r in verify)
+        assert not any("spec_k" in r or r["new_tokens"]
+                       for r in steps if not r["q_verify"])
+        assert sum(r["spec_accepted"] for r in verify) == \
             eng.spec_accepted
         retires = [r for r in recs if r["event"] == "req_retire"]
         for r in retires:
