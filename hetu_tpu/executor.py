@@ -31,7 +31,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .graph.node import Op, TraceContext
 from .graph.autodiff import find_topo_sort
-from .graph.ops_misc import PlaceholderOp
+from .graph.ops_misc import Backward, PlaceholderOp
 from .graph.ops_embed import IndexedSlicesOp
 from .optimizer import OptimizerOp
 
@@ -367,6 +367,9 @@ class SubExecutor:
                           config=self.executor.config, step=step)
         tc.rng_ids = self._stable_rng_ids()
         tc.extra_outputs = _ExtraOutputs()
+        # the whole subgraph is ONE jax trace: its gradient nodes share
+        # each forward's pullback and each backward's call
+        tc.backward = Backward(self.topo)
         vals = {}
         new_opt_states = dict(opt_states)
         side_outputs = {}
@@ -419,9 +422,11 @@ class SubExecutor:
             elif id(node) in self.skip_dense:
                 vals[id(node)] = None
             else:
+                ins = [vals[id(i)] for i in node.inputs]
                 with jax.named_scope(scope_name(node)):
-                    vals[id(node)] = node.compute(
-                        [vals[id(i)] for i in node.inputs], tc)
+                    # node.compute, under jax.vjp where a VJPOp of this
+                    # subgraph will want the pullback
+                    vals[id(node)] = tc.backward.compute(node, ins, tc)
         # dedup the embedding grads on DEVICE: segment-sum per-position
         # rows into the unique-row slots so phase B ships U rows back,
         # mirroring the forward's unique-row feed.  The adjoint carries

@@ -56,6 +56,12 @@ class TraceContext:
         # executors install topo positions here to keep dropout/rand
         # streams — and therefore resumed trajectories — build-invariant.
         self.rng_ids = {}
+        # What this trace's gradient nodes share (``ops_misc.Backward``).
+        # An executor that evaluates a whole subgraph inside ONE jax trace
+        # installs one; a node evaluated alone (``infer_shape``, the
+        # graph verifier's per-node ``eval_shape``) finds None and traces
+        # what it needs itself: a value cannot cross jax traces.
+        self.backward = None
 
     def rng_for(self, node) -> jax.Array:
         assert self._rng is not None, (
@@ -216,10 +222,18 @@ class SimpleOp(Op):
 
 
 def vjp_gradient(node: Op, output_grad: Op):
-    """Fallback gradient: one VJPOp per differentiable input, each computing
-    the cotangent via ``jax.vjp`` of the node's own compute at trace time.
-    XLA CSE merges the duplicated forward computations, so this costs
-    nothing extra in the compiled program — this replaces dozens of
-    hand-written backward kernels in the reference (src/ops/*.cu)."""
+    """Fallback gradient: one VJPOp per input, each picking its cotangent
+    out of ``jax.vjp`` of the node's own compute — this replaces dozens of
+    hand-written backward kernels in the reference (src/ops/*.cu).
+
+    The forward runs once and the backward once a cotangent BY
+    CONSTRUCTION: the executor computes a node that has VJPOp consumers
+    under ``jax.vjp`` and keeps the pullback for the trace, and the VJPOps
+    of one ``(node, output_grad)`` share one call of it
+    (``ops_misc.Backward``).  Leaving the repeats to XLA's CSE held for a
+    matmul and failed where the forward is opaque to the compiler: a
+    Mosaic call that also returns its ``lse`` is another call than the
+    node's own, so the flash forward ran twice a layer (ledger, PR 32:
+    ``flash_fwd`` 0.2500 s beside ``jvp_flash_fwd`` 0.2501 s)."""
     from .ops_misc import VJPOp
     return [VJPOp(node, output_grad, i) for i in range(len(node.inputs))]

@@ -13,6 +13,7 @@ import jax
 import jax.numpy as jnp
 
 from .ops_math import _simple
+from .ops_misc import SharedBackwardOp
 
 
 def softmaxcrossentropy_op(a, labels, ctx=None):
@@ -200,24 +201,26 @@ def tied_lm_head_xent_op(h, table, bias, labels, ignored_index=-1,
     gigabytes per step, pure memory-bandwidth cost the reference pays
     with a dedicated CUDA kernel pair instead
     (src/ops/SoftmaxCrossEntropySparse.cu).  The three gradient nodes
-    share one recompute scan (XLA CSE merges their identical bodies, the
-    same mechanism VJPOp relies on — ops_misc.py:92).
+    share ONE ``_chunked_xent_bwd`` call a trace (one scan, three results:
+    ``ops_misc.SharedBackwardOp``), traced under whichever of them the
+    executor reaches first.  Three calls each keeping one result were NOT
+    merged by XLA's CSE: the compiler prunes each scan to the carry its
+    node keeps and is left with three different loops, each rebuilding the
+    same ``[chunk, V]`` logits and softmax (ledger, PR 32: 11.1 % of the
+    GPT-2 medium step's busy time under ``TiedXentGradH/W/B``).
     """
     def f(hh, W, b, yy):
         return _chunked_xent_fwd(hh, W, b, yy, ignored_index, n_chunks)
 
+    def bwd(gv, hv, Wv, bv, yv):
+        return _chunked_xent_bwd(gv, hv, Wv, bv, yv, ignored_index, n_chunks)
+
     def grad_rule(n, g):
         hh, W, b, yy = n.inputs
-
-        def mk(idx, name):
-            return _simple(
-                name,
-                lambda gv, hv, Wv, bv, yv:
-                _chunked_xent_bwd(gv, hv, Wv, bv, yv,
-                                  ignored_index, n_chunks)[idx],
-                g, hh, W, b, yy)
-        return [mk(0, "TiedXentGradH"), mk(1, "TiedXentGradW"),
-                mk(2, "TiedXentGradB"), None]
+        return [SharedBackwardOp(name, bwd, idx, g, hh, W, b, yy)
+                for idx, name in enumerate(
+                    ("TiedXentGradH", "TiedXentGradW", "TiedXentGradB"))] \
+            + [None]
 
     return _simple("TiedXentChunked", f, h, table, bias, labels,
                    grad_rule=grad_rule, ctx=ctx)

@@ -89,13 +89,99 @@ def placeholder_op(name, value=None, initializer=None, trainable=True,
     return PlaceholderOp(name, value, initializer, trainable, dtype, ctx)
 
 
+def _forward_vjp(node: Op, xs, tc: TraceContext):
+    """``node``'s compute under ``jax.vjp`` -> (output, pullback, side
+    writes).  Stateful ops (BatchNorm's running statistics) write to
+    ``tc.extra_outputs``; here they write to a context of the inner trace
+    and leave it as the vjp's ``has_aux`` outputs, so no inner tracer
+    leaks into the outer jit trace and no write is lost."""
+    inner = TraceContext(
+        params=tc.params, rng=tc._rng, training=tc.training,
+        mesh=tc.mesh, axis_env=tc.axis_env, config=tc.config,
+        step=tc.step)
+    # the outer trace's RNG stream ids: a forward traced here sees the
+    # dropout mask every other trace of the node sees
+    inner.rng_ids = tc.rng_ids
+    written = []        # the keys are nodes, not values: safe to close over
+
+    def primal(*a):
+        out = node.compute(list(a), inner)
+        written[:] = inner.extra_outputs
+        return out, [inner.extra_outputs[k] for k in written]
+
+    out, pullback, side = jax.vjp(primal, *xs, has_aux=True)
+    return out, pullback, dict(zip(written, side))
+
+
+class Backward:
+    """What the gradient nodes of ONE trace share, kept in
+    ``TraceContext.backward`` by an executor that evaluates the whole
+    subgraph inside one jax trace.
+
+    - The pullback of every forward node that a ``VJPOp`` of the subgraph
+      differentiates: ``compute`` evaluates such a node under ``jax.vjp``
+      where the trace reaches it, so the node's output and its VJP's
+      forward are one computation.
+    - The result of every backward called through ``once``: the gradient
+      nodes of one ``(node, output_grad)`` call it one time and each keeps
+      its own input's part.
+    """
+
+    def __init__(self, topo):
+        self._wanted = {id(n._orig) for n in topo if isinstance(n, VJPOp)}
+        self._pullbacks = {}    # id(forward node) -> (pullback, out dtype)
+        self._results = {}      # backward's key -> what it returned
+
+    def compute(self, node, input_vals, tc: TraceContext):
+        """``node``'s output, leaving its pullback where a VJPOp of the
+        subgraph will ask for it."""
+        if id(node) not in self._wanted:
+            return node.compute(input_vals, tc)
+        out, pullback, side = _forward_vjp(node, input_vals, tc)
+        for var, value in side.items():
+            tc.extra_outputs[var] = value
+        self._pullbacks[id(node)] = (pullback, out.dtype)
+        return out
+
+    def cotangents(self, node, grad_node, g):
+        """Every input's cotangent of ``node`` for the output gradient
+        ``grad_node`` (its value ``g``), or None where this trace holds
+        no pullback of ``node``."""
+        saved = self._pullbacks.get(id(node))
+        if saved is None:
+            return None
+        pullback, dtype = saved
+        return self.once((id(node), id(grad_node)),
+                         lambda: pullback(jnp.asarray(g, dtype=dtype)))
+
+    def once(self, key, backward):
+        if key not in self._results:
+            self._results[key] = backward()
+        return self._results[key]
+
+
+def _count(shared: bool):
+    """Trace-time counters beside ``exec.compile_cache_miss``: gradient
+    nodes served from what the trace saved, and those that traced their
+    forward (or ran a backward of their own) again."""
+    from .. import telemetry
+    telemetry.inc("exec.grad.shared" if shared else "exec.grad.retraced")
+
+
 class VJPOp(Op):
     """Generic cotangent node: grad of ``orig``'s ``input_index``-th input.
+    This one node replaces the majority of hand-written backward kernels in
+    the reference (src/ops/*.cu).
 
-    The forward is recomputed inside ``jax.vjp`` at trace time; XLA CSE
-    merges it with the original forward computation, so the compiled program
-    contains each forward op once.  This one node replaces the majority of
-    hand-written backward kernels in the reference (src/ops/*.cu)."""
+    Where the trace holds ``orig``'s pullback (``Backward``: the executor
+    computed ``orig`` under ``jax.vjp``), the VJPOps of one ``(orig,
+    output_grad)`` share one call of it and each picks its cotangent: one
+    forward and one backward in the compiled program by construction.
+    Where it does not (the node evaluated alone by ``infer_shape`` or the
+    graph verifier; a subgraph that does not hold ``orig``), the forward is
+    traced again here.  XLA's CSE merged that copy with the node's own for
+    a matmul, but not a Pallas call whose VJP forward returns one more
+    output, nor loops pruned to different carries (ledger, PR 32)."""
 
     def __init__(self, orig: Op, output_grad: Op, input_index: int):
         super().__init__(*orig.inputs, output_grad,
@@ -105,23 +191,41 @@ class VJPOp(Op):
 
     def compute(self, input_vals, tc: TraceContext):
         *xs, g = input_vals
-        # sandbox the recomputed forward: stateful ops (e.g. BatchNorm
-        # running stats) write to tc.extra_outputs, and writes from inside
-        # the vjp trace would leak inner tracers into the outer jit trace.
-        inner_tc = TraceContext(
-            params=tc.params, rng=tc._rng, training=tc.training,
-            mesh=tc.mesh, axis_env=tc.axis_env, config=tc.config,
-            step=tc.step)
-        # same RNG stream ids as the outer trace — the recomputed forward
-        # must see the identical dropout mask the primal forward used
-        inner_tc.rng_ids = tc.rng_ids
-
-        def primal(*a):
-            return self._orig.compute(list(a), inner_tc)
-
-        primal_out, vjp = jax.vjp(primal, *xs)
-        cot = vjp(jnp.asarray(g, dtype=primal_out.dtype))
+        cot = None if tc.backward is None else tc.backward.cotangents(
+            self._orig, self.inputs[-1], g)
+        _count(shared=cot is not None)
+        if cot is None:
+            # the side writes are the forward node's to make, not this
+            # copy's: dropped
+            primal_out, pullback, _ = _forward_vjp(self._orig, xs, tc)
+            cot = pullback(jnp.asarray(g, dtype=primal_out.dtype))
         return cot[self._idx]
+
+    def gradient(self, output_grad):
+        raise NotImplementedError("second-order autodiff not supported")
+
+
+class SharedBackwardOp(Op):
+    """One part of a hand-written backward that returns several inputs'
+    gradients at once: ``fn(*input_vals)`` gives a tuple and this node
+    keeps ``[index]``.  The nodes built over one ``fn`` and the same
+    inputs call it once a trace and share what it returned; a node
+    evaluated alone calls it for itself."""
+
+    def __init__(self, name, fn, index, *inputs, ctx=None):
+        super().__init__(*inputs, name=name, ctx=ctx)
+        self._fn = fn
+        self._key = (fn,) + tuple(id(i) for i in inputs)
+        self._idx = index
+
+    def compute(self, input_vals, tc: TraceContext):
+        def call():
+            return self._fn(*input_vals)
+
+        _count(shared=tc.backward is not None)
+        parts = call() if tc.backward is None \
+            else tc.backward.once(self._key, call)
+        return parts[self._idx]
 
     def gradient(self, output_grad):
         raise NotImplementedError("second-order autodiff not supported")
