@@ -242,6 +242,69 @@ def convert_glm4_moe_lite(state_dict, config, name="glm", prefix="model."):
     return out
 
 
+def convert_lfm2_moe(state_dict, config, name="lfm", prefix="model."):
+    """HF ``lfm2_moe`` weights (gated short convolutions ``conv.in_proj``
+    / ``conv.conv`` / ``conv.out_proj``, grouped-query ``self_attn`` with
+    ``q_layernorm`` / ``k_layernorm``, a dense ``feed_forward.w1/w3/w2``
+    in the leading layers and then ``feed_forward.gate``, ``expert_bias``
+    and per-expert ``w1/w3/w2``) -> the serving parameter dict of
+    ``models.moe_decode.HybridMoEConfig`` (``config``; ``param_shapes``
+    lists the leaves).
+
+    torch ``Linear`` weights are [out, in] and are transposed; the
+    depthwise ``Conv1d`` weight ``[D, 1, K]`` becomes ``[K, D]`` (tap
+    ``j`` weighs the input ``K - 1 - j`` positions back, as torch's
+    left-padded cross-correlation has it); the experts of a layer are
+    stacked into [E, in, out] leaves (w1 the gate, w3 the up, w2 the
+    down projection).  The family rotates halves, as this repo does, so
+    no column is permuted.  The head is the embedding table.  A
+    checkpoint without ``expert_bias`` (``use_expert_bias`` false) gets
+    a zero one."""
+    c = config
+    sd = state_dict
+
+    def lin(key):
+        return _np(sd[key]).T.copy()
+
+    out = {f"{name}_wte_table": _np(sd[f"{prefix}embed_tokens.weight"]),
+           f"{name}_ln_f_scale": _np(sd[f"{prefix}embedding_norm.weight"])}
+    ffn_names = (("gate", "w1"), ("up", "w3"), ("down", "w2"))
+    for i, op in enumerate(c.operators()):
+        hf, us = f"{prefix}layers.{i}", f"{name}_h{i}"
+        out[f"{us}_ln1_scale"] = _np(sd[f"{hf}.operator_norm.weight"])
+        out[f"{us}_ln2_scale"] = _np(sd[f"{hf}.ffn_norm.weight"])
+        if op == "conv":
+            out[f"{us}_conv_in_weight"] = lin(f"{hf}.conv.in_proj.weight")
+            out[f"{us}_conv_weight"] = _np(
+                sd[f"{hf}.conv.conv.weight"])[:, 0, :].T.copy()
+            out[f"{us}_conv_out_weight"] = lin(f"{hf}.conv.out_proj.weight")
+        else:
+            at = f"{hf}.self_attn"
+            for nm in ("q", "k", "v"):
+                out[f"{us}_attn_{nm}_weight"] = lin(f"{at}.{nm}_proj.weight")
+            out[f"{us}_attn_q_norm_scale"] = _np(
+                sd[f"{at}.q_layernorm.weight"])
+            out[f"{us}_attn_k_norm_scale"] = _np(
+                sd[f"{at}.k_layernorm.weight"])
+            out[f"{us}_attn_proj_weight"] = lin(f"{at}.out_proj.weight")
+        ff = f"{hf}.feed_forward"
+        if i < c.num_dense_layers:
+            for ours, theirs in ffn_names:
+                out[f"{us}_ffn_{ours}_weight"] = lin(f"{ff}.{theirs}.weight")
+            continue
+        out[f"{us}_moe_router_weight"] = lin(f"{ff}.gate.weight").astype(
+            np.float32)
+        bias = sd.get(f"{ff}.expert_bias")
+        out[f"{us}_moe_router_bias"] = (
+            np.zeros(c.n_routed_experts, np.float32) if bias is None
+            else _np(bias).astype(np.float32))
+        for ours, theirs in ffn_names:
+            out[f"{us}_moe_experts_{ours}"] = np.stack(
+                [lin(f"{ff}.experts.{e}.{theirs}.weight")
+                 for e in range(c.n_routed_experts)])
+    return out
+
+
 # ------------------------------------------------------------------ #
 # the REVERSE direction: our trained parameters -> HF state_dicts, so
 # models trained here load into transformers (torch) for serving /

@@ -434,15 +434,23 @@ def _softmax_step(s, v, m_ref, l_ref, acc_ref, at=(slice(None),),
 
 def _kv_rows_kernel(lens_ref, qlens_ref, bt_ref, layer_ref, q_ref, k_pool,
                     v_pool, o_ref, k_buf, v_buf, sem, m_ref, l_ref, acc_ref,
-                    *, scale, bs, group, tq, heads, dh, cw, precision):
+                    *, scale, bs, group, tq, heads, dh, cw, precision,
+                    qpk=1):
     b, t, n_groups, last = _tile_in_sight(lens_ref, qlens_ref, tq, bs, group)
     layer = layer_ref[0]
     _reset(m_ref, l_ref, acc_ref)
-    # (chunk, heads in it): the row's last chunk may hold fewer (GPT-2
-    # XL: head 24 alone beside 64 pad lanes, which are never scored)
+    # (chunk, K/V heads in it): the row's last chunk may hold fewer
+    # (GPT-2 XL: head 24 alone beside 64 pad lanes, which are never
+    # scored)
     per = cw // dh
     chunks = [(c, min(per, heads - c * per))
               for c in range(q_ref.shape[2] // cw)]
+
+    def member(r):
+        """Rows of the q (or o) tile that hold member ``r`` of every K/V
+        head's ``qpk`` query heads (all of the tile where ``qpk`` is 1:
+        a query head then IS its K/V head)."""
+        return slice(None) if qpk == 1 else slice(r * tq, (r + 1) * tq)
 
     def copies(gi, buf):
         """Group ``gi``'s K and V page copies into buffer ``buf``; pages
@@ -466,12 +474,13 @@ def _kv_rows_kernel(lens_ref, qlens_ref, bt_ref, layer_ref, q_ref, k_pool,
         filled, qlen = lens_ref[b], qlens_ref[b]
         for c, n in chunks:
             lanes = slice(c * cw, (c + 1) * cw)
-            qc = q_ref[0, :, lanes]                        # [tq, cw]
-            # the chunk's heads stacked along the rows, each seeing its
-            # own lanes of the query alone
+            # the chunk's query heads stacked along the rows (K/V head
+            # g's ``qpk`` members one after another), each seeing its
+            # own K/V head's lanes of the query alone
+            qcs = [q_ref[0, member(r), lanes] for r in range(qpk)]
             q2 = jnp.concatenate(
                 [jnp.where(own_lanes(qc.shape, g), qc, 0)
-                 for g in range(n)], axis=0)               # [n*tq, cw]
+                 for g in range(n) for qc in qcs], axis=0)
             s = jax.lax.dot_general(
                 q2, k_buf[buf, :, lanes], (((1,), (1,)), ((), ())),
                 precision=precision,
@@ -480,25 +489,31 @@ def _kv_rows_kernel(lens_ref, qlens_ref, bt_ref, layer_ref, q_ref, k_pool,
                 jnp.int32, s.shape, 0) % tq
             _softmax_step(_mask_scores(s, qi, gi, filled, qlen),
                           v_buf[buf, :, lanes], m_ref, l_ref, acc_ref,
-                          at=(c, slice(0, n * tq)), precision=precision)
+                          at=(c, slice(0, n * qpk * tq)),
+                          precision=precision)
 
     _page_loop(n_groups, copies, score)
     for c, n in chunks:
-        l = l_ref[c, 0:n * tq, 0:1]
-        o2 = acc_ref[c, 0:n * tq] / jnp.where(l == 0.0, 1.0, l)
-        oc = jnp.zeros((tq, cw), jnp.float32)
-        for g in range(n):
-            oc = jnp.where(own_lanes(oc.shape, g),
-                           o2[g * tq:(g + 1) * tq], oc)
-        o_ref[0, :, c * cw:(c + 1) * cw] = oc.astype(o_ref.dtype)
+        l = l_ref[c, 0:n * qpk * tq, 0:1]
+        o2 = acc_ref[c, 0:n * qpk * tq] / jnp.where(l == 0.0, 1.0, l)
+        for r in range(qpk):
+            oc = jnp.zeros((tq, cw), jnp.float32)
+            for g in range(n):
+                at = (g * qpk + r) * tq
+                oc = jnp.where(own_lanes(oc.shape, g), o2[at:at + tq], oc)
+            o_ref[0, member(r), c * cw:(c + 1) * cw] = \
+                oc.astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("heads", "head_dim", "tq", "interpret"))
+                   static_argnames=("heads", "head_dim", "tq", "interpret",
+                                    "qpk"))
 def _paged_rows_call(lengths, q_lens, block_tables, layer, qr, pool_k,
-                     pool_v, *, heads, head_dim, tq, interpret):
+                     pool_v, *, heads, head_dim, tq, interpret, qpk=1):
     """``_kv_rows_kernel`` over query rows ``qr`` [B, Q, W] (``Q`` whole
-    sublane tiles) and the pool pair.  Jitted, with the layer a traced
+    sublane tiles) and the pool pair; with ``qpk`` query heads a K/V
+    head, ``qr`` is [B, qpk * Q, W], tile by tile the ``qpk`` members'
+    ``tq`` rows one after another (``_grouped_rows``).  Jitted, with the layer a traced
     scalar: a model's layers then share ONE trace of the kernel a
     q-block and ONE lowering a program (the calls of one jitted
     function lower to calls of one function).  With a static layer each
@@ -510,11 +525,11 @@ def _paged_rows_call(lengths, q_lens, block_tables, layer, qr, pool_k,
     bs = pool_k.shape[2]
     cw = _lane_chunk(W, head_dim)
     group = _page_group(block_tables.shape[1], bs, W, pool_k.dtype)
-    rows = cw // head_dim * tq
-    tile = pl.BlockSpec((1, tq, W), lambda b, t, *_: (b, t, 0))
+    rows = cw // head_dim * qpk * tq
+    tile = pl.BlockSpec((1, qpk * tq, W), lambda b, t, *_: (b, t, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
-        grid=(B, Q // tq),
+        grid=(B, Q // (qpk * tq)),
         in_specs=[tile, pl.BlockSpec(memory_space=pl.ANY),
                   pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=tile,
@@ -530,7 +545,7 @@ def _paged_rows_call(lengths, q_lens, block_tables, layer, qr, pool_k,
     return pl.pallas_call(
         functools.partial(_kv_rows_kernel, scale=head_dim ** -0.5, bs=bs,
                           group=group, tq=tq, heads=heads, dh=head_dim,
-                          cw=cw, precision=_prec(qr.dtype)),
+                          cw=cw, precision=_prec(qr.dtype), qpk=qpk),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Q, W), qr.dtype),
         name="ragged_paged_mixed",
@@ -538,20 +553,47 @@ def _paged_rows_call(lengths, q_lens, block_tables, layer, qr, pool_k,
     )(lengths, q_lens, block_tables, layer, qr, pool_k, pool_v)
 
 
+def _grouped_rows(q, W, tq, groups):
+    """Query heads ``[B, Q, Hkv * groups, Dh]`` (``Q`` a multiple of
+    ``tq``) as the rows the grouped kernel reads, ``[B, groups * Q, W]``:
+    member ``r`` of every K/V head's ``groups`` query heads laid out as
+    one pool-shaped row (query head ``kvh * groups + r`` in K/V head
+    ``kvh``'s lanes), and tile by tile the members' ``tq`` rows one
+    after another.  :func:`_ungrouped_rows` is the way back."""
+    B, Q, H, Dh = q.shape
+    m = q.reshape(B, Q // tq, tq, H // groups, groups, Dh)
+    m = m.transpose(0, 1, 4, 2, 3, 5)            # [B, tiles, G, tq, Hkv, Dh]
+    return kv_rows(m, W).reshape(B, groups * Q, W)
+
+
+def _ungrouped_rows(o, heads, head_dim, tq, groups):
+    """``[B, groups * Q, W]`` kernel rows back to ``[B, Q, H, Dh]``."""
+    B, GQ, _ = o.shape
+    Q = GQ // groups
+    m = kv_heads(o.reshape(B, Q // tq, groups, tq, -1),
+                 heads // groups, head_dim)
+    return m.transpose(0, 1, 3, 4, 2, 5).reshape(B, Q, heads, head_dim)
+
+
 def ragged_paged_attention(q, pool_k, pool_v, lengths, q_lens,
                            block_tables, *, layer=0, k_scale=None,
-                           v_scale=None, interpret=None):
+                           v_scale=None, interpret=None, groups=1):
     """The mixed wave over the BLOCK-TABLE paged pool — the serving
     engine's production mixed-mode dispatch.
 
     q: [B, Q, H, Dh]; pool_k, pool_v: the WHOLE pool pair
-    ``[L, N_blocks, bs, W]``, one row a position with head h in lanes
-    ``[h * Dh, (h + 1) * Dh)`` and ``W = kv_row_width(H, Dh)`` (zeros,
-    or anything finite, in the pad), of which the kernel reads layer
-    ``layer`` where it lies: a ``pool[layer]`` outside the
+    ``[L, N_blocks, bs, W]``, one row a position with K/V head h in
+    lanes ``[h * Dh, (h + 1) * Dh)`` and ``W = kv_row_width(H // groups,
+    Dh)`` (zeros, or anything finite, in the pad), of which the kernel
+    reads layer ``layer`` where it lies: a ``pool[layer]`` outside the
     kernel is a copy of that layer's pool a call, and a row that is not
     a whole number of lane tiles makes the compiler relay the whole
-    donated pool a wave (the comment block above).  block_tables:
+    donated pool a wave (the comment block above).  ``groups`` query
+    heads read one K/V head (query head ``n`` reads K/V head ``n //
+    groups``; 1: as many K/V heads as query heads, the code path there
+    was before there were groups, operation for operation): a page is
+    copied once for all of them, and a K/V head's ``groups`` members
+    are more rows of the same product against its lanes.  block_tables:
     [B, T] int32 — entry (b, j) is the pool block holding slot b's
     positions [j*bs, (j+1)*bs); lengths / q_lens: [B] int32 as in
     :func:`ragged_attention` (dead table entries may hold any valid
@@ -570,32 +612,46 @@ def ragged_paged_attention(q, pool_k, pool_v, lengths, q_lens,
     shape along dimension 3 must be aligned to tiling (128), but is 25",
     sandbox AOT for v5e, PR 31): scale planes padded to 128 lanes would
     cost the int8 pool a quarter more bytes a token, and the path is in
-    no benchmark cell."""
+    no benchmark cell (nor has it groups)."""
     if interpret is None:
         interpret = _use_interpret()
+    B, Q, H, Dh = q.shape
+    if H % groups:
+        raise ValueError(f"{H} query heads are not {groups} a K/V head")
     if k_scale is not None:
+        if groups != 1:
+            raise ValueError("the int8 pool's kernel has no groups")
         return _ragged_paged_blocked(
             q, pool_k[layer], pool_v[layer], lengths, q_lens,
             block_tables, k_scale[layer], v_scale[layer], interpret)
-    B, Q, H, Dh = q.shape
     bs, W = pool_k.shape[2:]
-    if W != kv_row_width(H, Dh):
+    if W != kv_row_width(H // groups, Dh):
         raise ValueError(
-            f"ragged_paged_attention reads rows of {kv_row_width(H, Dh)} "
-            f"lanes for {H} heads of {Dh}; the pool's are {W} wide")
+            f"ragged_paged_attention reads rows of "
+            f"{kv_row_width(H // groups, Dh)} lanes for {H // groups} K/V "
+            f"heads of {Dh}; the pool's are {W} wide")
     # whole sublane tiles of query rows (8 of f32, 16 of bf16): a decode
     # wave's single row rides in one, its dead rows scored by no one
     sub = 32 // jnp.dtype(q.dtype).itemsize
-    qr = kv_rows(q, W)
-    if Q % sub:
-        qr = jnp.pad(qr, ((0, 0), (0, -Q % sub), (0, 0)))
+    if groups == 1:
+        qr = kv_rows(q, W)
+        if Q % sub:
+            qr = jnp.pad(qr, ((0, 0), (0, -Q % sub), (0, 0)))
+        tq = _fit_block(max(_MAX_ROWS // H, 1), qr.shape[1])
+    else:
+        if Q % sub:
+            q = jnp.pad(q, ((0, 0), (0, -Q % sub), (0, 0), (0, 0)))
+        tq = _fit_block(max(_MAX_ROWS // H, 1), q.shape[1])
+        qr = _grouped_rows(q, W, tq, groups)
     o = _paged_rows_call(
         lengths.astype(jnp.int32), q_lens.astype(jnp.int32),
         block_tables.astype(jnp.int32),
         jnp.asarray(layer, jnp.int32).reshape(1), qr, pool_k, pool_v,
-        heads=H, head_dim=Dh, interpret=interpret,
-        tq=_fit_block(max(_MAX_ROWS // H, 1), qr.shape[1]))
-    return kv_heads(o[:, :Q], H, Dh)
+        heads=H // groups, head_dim=Dh, interpret=interpret, tq=tq,
+        qpk=groups)
+    if groups == 1:
+        return kv_heads(o[:, :Q], H, Dh)
+    return _ungrouped_rows(o, H, Dh, tq, groups)[:, :Q]
 
 
 def ragged_masked_reference(q, k, v, lengths, q_lens=None, k_scale=None,

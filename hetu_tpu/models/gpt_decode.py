@@ -302,8 +302,16 @@ class LatentSpec(NamedTuple):
 class BlockSpec(NamedTuple):
     """norm: "layernorm" (scale and bias) | "rmsnorm"; positions:
     "learned" (a table added to the embedding) | "rope" (rotate-half
-    over ``latent.qk_rope_head_dim`` with ``rope_theta``); attention:
-    "mha" | "latent"; ffn: the kind of every layer from
+    with ``rope_theta``: over ``latent.qk_rope_head_dim``, or over the
+    whole head of a K/V attention); attention: "mha" (as many K/V heads
+    as query heads) | "gqa" (``kv_heads`` K/V heads, query head ``n``
+    reading K/V head ``n // (H / kv_heads)``) | "latent"; bias: the
+    K/V attention's projections carry biases (GPT-2's do); qk_norm:
+    every head's q and k RMS-normalised over its columns with a learned
+    scale before the rotation; ops: the OPERATOR of every layer, a tuple
+    of "attention" | "conv" (None: attention everywhere), a "conv" layer
+    being the gated short convolution of ``conv_kernel`` taps whose
+    state lives beside the pool; ffn: the kind of every layer from
     ``leading_dense`` on, "gelu" | "swiglu" | "routed" (a
     ``moe_decode.RoutedSpec`` in ``routed``; the leading layers are
     dense SwiGLU); head: "tied" (the embedding table) | "untied"
@@ -319,6 +327,11 @@ class BlockSpec(NamedTuple):
     leading_dense: int = 0
     routed: Optional[tuple] = None
     head: str = "tied"
+    bias: bool = True
+    kv_heads: int = 0
+    qk_norm: bool = False
+    ops: Optional[tuple] = None
+    conv_kernel: int = 0
 
     def ffn_kind(self, i):
         """Layer ``i``'s FFN: the leading layers of a routed model are
@@ -329,6 +342,19 @@ class BlockSpec(NamedTuple):
 
     def routed_layers(self, L):
         return sum(1 for i in range(L) if self.ffn_kind(i) == "routed")
+
+    def op_kind(self, i):
+        """Layer ``i``'s operator: "attention" | "conv"."""
+        return self.ops[i] if self.ops else "attention"
+
+    def op_index(self, i):
+        """Layer ``i``'s place among the layers of its own operator: an
+        attention layer's index into the K/V pool, a conv layer's into
+        the state."""
+        return sum(1 for j in range(i) if self.op_kind(j) == self.op_kind(i))
+
+    def op_layers(self, L, kind):
+        return sum(1 for i in range(L) if self.op_kind(i) == kind)
 
 
 GPT2_BLOCK = BlockSpec()
@@ -349,20 +375,35 @@ def block_spec_of(config):
     return make() if callable(make) else GPT2_BLOCK
 
 
-def check_block_spec(blk):
-    """Raise for a spec the mixed wave cannot run: besides GPT-2's
-    block it runs latent attention with RMSNorm and RoPE, over any of
-    the three FFN kinds and either head."""
+def check_block_spec(blk, layers=None):
+    """Raise for a spec the mixed wave cannot run.  It runs GPT-2's
+    block; latent attention with RMSNorm and RoPE; and grouped-query
+    K/V attention with RMSNorm, RoPE over the whole head, no biases and
+    an optional per-head q/k norm, every layer's operator either that
+    attention or the gated short convolution (``ops``; a conv layer
+    needs ``conv_kernel`` >= 2 taps, and ``ops`` names ``layers`` of
+    them): each over any of the three FFN kinds and either head."""
     if blk == GPT2_BLOCK:
         return
-    if (blk.attention, blk.norm, blk.positions) != (
-            "latent", "rmsnorm", "rope") or blk.latent is None \
-            or (blk.ffn == "routed") != (blk.routed is not None) \
-            or blk.ffn not in ("gelu", "swiglu", "routed") \
-            or blk.head not in ("tied", "untied"):
+    common = (blk.norm, blk.positions) == ("rmsnorm", "rope") \
+        and (blk.ffn == "routed") == (blk.routed is not None) \
+        and blk.ffn in ("gelu", "swiglu", "routed") \
+        and blk.head in ("tied", "untied")
+    if blk.attention == "latent":
+        ok = common and blk.latent is not None and blk.ops is None
+    else:
+        ops = blk.ops or ()
+        ok = common and blk.attention == "gqa" and blk.latent is None \
+            and not blk.bias and blk.kv_heads >= 1 \
+            and all(o in ("attention", "conv") for o in ops) \
+            and ("conv" not in ops or blk.conv_kernel >= 2) \
+            and (layers is None or not ops or len(ops) == layers)
+    if not ok:
         raise ValueError(
-            f"the mixed wave runs GPT-2's block, or latent attention "
-            f"with rmsnorm and rope; it cannot run {blk}")
+            f"the mixed wave runs GPT-2's block, latent attention with "
+            f"rmsnorm and rope, or grouped-query attention with rmsnorm "
+            f"and rope beside gated short convolutions; it cannot run "
+            f"{blk}")
 
 
 def _rms(x, scale, eps):
@@ -1155,15 +1196,17 @@ def _latent_attention(params, us, blk, H, h, pool, i, wblk, woff, posns,
     return h, pool
 
 
-def _ffn_of_kind(params, us, blk, h, i, valid, stats):
-    """The FFN sublayer by ``blk.ffn_kind(i)``: GPT-2's GELU FFN or a
-    dense SwiGLU under the scope ``mlp``, or the dropless routed FFN
-    with its shared expert (``moe_decode.routed_ffn``, scopes
-    ``moe_route``, ``moe_experts``, ``moe_shared``)."""
+def _ffn_of_kind(params, us, blk, h, i, valid, stats, moe=None):
+    """The FFN sublayer by ``blk.ffn_kind(i)``: GPT-2's GELU FFN (or,
+    under a ``MoESpec``, its capacity-routed experts) or a dense SwiGLU
+    under the scope ``mlp``, or the dropless routed FFN with its shared
+    expert (``moe_decode.routed_ffn``, scopes ``moe_route``,
+    ``moe_experts``, ``moe_shared``)."""
     kind = blk.ffn_kind(i)
     if kind == "gelu":
         with jax.named_scope("mlp"):
-            return _ffn_block(params, us, h, i)
+            return _ffn_block(params, us, h, i, moe=moe, valid=valid,
+                              stats=stats)
     x = _norm(blk, params, f"{us}_ln2", h)
     if kind == "swiglu":
         with jax.named_scope("mlp"):
@@ -1178,14 +1221,54 @@ def _ffn_of_kind(params, us, blk, h, i, valid, stats):
     return h + y.reshape(shp)
 
 
+def _conv_operator(params, us, blk, h, state, si, q_len):
+    """One layer's gated short convolution over the wave's ``[B, Q]``
+    rows: ``[b | c | x] = u W_in`` (``u`` the norm of ``h``), ``z = b *
+    x``, ``y_t = sum_j w[j] * z_{t - (K - 1) + j}`` (depthwise, causal,
+    ``K = blk.conv_kernel`` taps), ``h + (c * y) W_out``.  What a
+    sequence carries from one q-block to its next is ``z`` at its last
+    ``K - 1`` positions: ``state`` ``[conv layers, slots, K - 1,
+    hidden]``, of which this layer's rows ``si`` are READ as the
+    history before the q-block's first row and WRITTEN with the ``z`` of
+    rows ``q_len - (K - 1) .. q_len - 1`` (reaching back into the old
+    history where the q-block is shorter).  A dead row lies past
+    ``q_len`` and a dead slot has ``q_len`` 0, so neither moves the
+    state; a slot's rows are zero when its sequence starts (the manager
+    zeroes them on admission).  Returns (h + operator, state)."""
+    K = blk.conv_kernel
+    Q = h.shape[1]
+    with jax.named_scope("conv_in"):
+        u = _norm(blk, params, f"{us}_ln1", h)
+        bg, cg, x = jnp.split(u @ params[f"{us}_conv_in_weight"], 3, axis=-1)
+        z = bg * x                                          # [B, Q, d]
+    with jax.named_scope("conv_mix"):
+        zz = jnp.concatenate([state[si].astype(z.dtype), z], axis=1)
+        w = params[f"{us}_conv_weight"]                     # [K, d]
+        y = sum(w[j] * zz[:, j:j + Q] for j in range(K))
+    with jax.named_scope("state_write"):
+        last = jax.vmap(lambda rows, n: jax.lax.dynamic_slice_in_dim(
+            rows, n, K - 1, 0))(zz, q_len)                  # [B, K-1, d]
+        state = state.at[si].set(last.astype(state.dtype))
+    with jax.named_scope("conv_out"):
+        h = h + (cg * y) @ params[f"{us}_conv_out_weight"]
+    return h, state
+
+
+def _proj(params, prefix, x, bias):
+    """``x W`` (``+ b`` where the block's projections carry biases)."""
+    y = x @ params[f"{prefix}_weight"]
+    return y + params[f"{prefix}_bias"] if bias else y
+
+
 def _mixed_step(params, cfg_tuple, cache_k, cache_v, pos, tokens,
                 q_len, first_row, self_fresh, window=1, attn="masked",
-                block_tables=None, has_fresh=False, moe_stats=None):
+                block_tables=None, has_fresh=False, moe_stats=None,
+                state=None):
     """One MIXED wave: slot b consumes ``tokens[b, :q_len[b]]`` at
     positions ``pos[b] .. pos[b]+q_len[b]-1`` — whatever mode those
     tokens are (prompt chunk, draft+bonus verify block, single decode
-    token).  Returns (logits [B, W, V] f32, cache_k, cache_v) for the
-    slots' SAMPLING WINDOWS only (``_window_logits``): row
+    token).  Returns (logits [B, W, V] f32, cache_k, cache_v, state) for
+    the slots' SAMPLING WINDOWS only (``_window_logits``): row
     ``logits[b, w]`` is the next-token distribution after input
     ``first_row[b] + w``, for ``w < window`` = W (static; 1, or
     ``spec_k + 1`` on an engine that speculates — a constant of the
@@ -1218,17 +1301,27 @@ def _mixed_step(params, cfg_tuple, cache_k, cache_v, pos, tokens,
     ``lm_head`` (the window's gather, final LN and head) and ``sample``,
     ``_spec_sample``'s own.
 
-    The block itself is the cfg_tuple's ``BlockSpec``: GPT-2's when it
-    carries none (everything above); with latent attention the layer is
-    ``_latent_attention`` (scopes ``mla_qkv``, ``mla_absorb``,
-    ``kv_write``, ``attention``, ``attn_out``) over ONE pool, ``cache_v``
-    None, then ``_ffn_of_kind`` (``mlp``, or ``moe_route``,
-    ``moe_experts``, ``moe_shared``), and the head follows the spec."""
+    The block itself is the cfg_tuple's ``BlockSpec``, GPT-2's when it
+    carries none.  Each layer is its OPERATOR, then its FFN
+    (``_ffn_of_kind``: ``mlp``, or ``moe_route``, ``moe_experts``,
+    ``moe_shared``), and the head follows the spec.  The operator is
+    the K/V attention written here, which reads the spec for its norm,
+    biases, positions, per-head q/k norm and group (``kv_heads`` K/V
+    heads under ``H`` query heads; GPT-2's is LayerNorm, biases, learned
+    positions, group 1); or ``_latent_attention`` (scopes ``mla_qkv``,
+    ``mla_absorb``, ``kv_write``, ``attention``, ``attn_out``) over ONE
+    pool, ``cache_v`` None; or, where ``blk.ops`` says "conv",
+    ``_conv_operator`` (``conv_in``, ``conv_mix``, ``state_write``,
+    ``conv_out``) over ``state``.  With ``ops`` the pool holds the
+    attention layers alone and the state the conv layers alone, each
+    layer finding its own by ``blk.op_index``."""
     name, L, H, Dh, S_max = cfg_tuple[:5]
     moe = _moe_of(cfg_tuple)
     blk = _block_of(cfg_tuple)
     B, Q = tokens.shape
     hdim = H * Dh
+    Hkv = blk.kv_heads or H
+    group = H // Hkv
     paged = block_tables is not None
     bidx = jnp.arange(B)
     posns = pos[:, None] + jnp.arange(Q)[None, :]          # [B, Q]
@@ -1263,44 +1356,62 @@ def _mixed_step(params, cfg_tuple, cache_k, cache_v, pos, tokens,
         & valid[:, None, :]                                # [B, Q, Q]
     scale = Dh ** -0.5
     quant = _kv_q(cache_k)
+
+    def per_query_head(kv):
+        """K/V heads ``[.., Hkv, Dh]`` as the query heads read them."""
+        return kv if group == 1 else jnp.repeat(kv, group, axis=-2)
+
     for i in range(L):
         us = f"{name}_h{i}"
+        if blk.op_kind(i) == "conv":
+            h, state = _conv_operator(params, us, blk, h, state,
+                                      blk.op_index(i), q_len)
+            h = _ffn_of_kind(params, us, blk, h, i, valid, moe_stats)
+            continue
         if blk.attention == "latent":
-            # latent attention over ONE pool (``cache_v`` is None), then
-            # the FFN by the layer's kind; ``check_block_spec`` keeps out
-            # the combinations this wave does not run
+            # latent attention over ONE pool (``cache_v`` is None);
+            # ``check_block_spec`` keeps out the combinations this wave
+            # does not run
             h, cache_k = _latent_attention(
                 params, us, blk, H, h, cache_k, i, wblk, woff, posns,
                 live, lens, q_len, block_tables, attn)
             h = _ffn_of_kind(params, us, blk, h, i, valid, moe_stats)
             continue
+        # the pool holds the attention layers alone (all of them, in
+        # their order, where the spec names no operators)
+        pi = blk.op_index(i)
         with jax.named_scope("attn_qkv"):
-            x = _ln(h, params[f"{us}_ln1_scale"],
-                    params[f"{us}_ln1_bias"])
-            q = (x @ params[f"{us}_attn_q_weight"]
-                 + params[f"{us}_attn_q_bias"]).reshape(B, Q, H, Dh)
-            k = (x @ params[f"{us}_attn_k_weight"]
-                 + params[f"{us}_attn_k_bias"]).reshape(B, Q, H, Dh)
-            v = (x @ params[f"{us}_attn_v_weight"]
-                 + params[f"{us}_attn_v_bias"]).reshape(B, Q, H, Dh)
+            x = _norm(blk, params, f"{us}_ln1", h)
+            q = _proj(params, f"{us}_attn_q", x, blk.bias).reshape(
+                B, Q, H, Dh)
+            k = _proj(params, f"{us}_attn_k", x, blk.bias).reshape(
+                B, Q, Hkv, Dh)
+            v = _proj(params, f"{us}_attn_v", x, blk.bias).reshape(
+                B, Q, Hkv, Dh)
+            if blk.qk_norm:
+                q = _rms(q, params[f"{us}_attn_q_norm_scale"], blk.norm_eps)
+                k = _rms(k, params[f"{us}_attn_k_norm_scale"], blk.norm_eps)
+            if blk.positions == "rope":
+                q = _rope(q, posns, blk.rope_theta)
+                k = _rope(k, posns, blk.rope_theta)
         with jax.named_scope("kv_write"):
             if paged and not quant and Q >= bs_blk:
                 # a q-block a page or more wide: whole pages
-                cache_k = _kv_write_pages(cache_k, i, k, pos, q_len,
+                cache_k = _kv_write_pages(cache_k, pi, k, pos, q_len,
                                           block_tables)
-                cache_v = _kv_write_pages(cache_v, i, v, pos, q_len,
+                cache_v = _kv_write_pages(cache_v, pi, v, pos, q_len,
                                           block_tables)
             elif paged:
-                cache_k = _kv_scatter(cache_k, (i, wblk, woff), k)
-                cache_v = _kv_scatter(cache_v, (i, wblk, woff), v)
+                cache_k = _kv_scatter(cache_k, (pi, wblk, woff), k)
+                cache_v = _kv_scatter(cache_v, (pi, wblk, woff), v)
             else:
                 # descending j: dead (clipped) tail first, live wins
                 # last
                 for jq in reversed(range(Q)):
                     pw = jnp.minimum(posns[:, jq], S_max - 1)
-                    cache_k = _kv_scatter(cache_k, (i, bidx, pw),
+                    cache_k = _kv_scatter(cache_k, (pi, bidx, pw),
                                           k[:, jq])
-                    cache_v = _kv_scatter(cache_v, (i, bidx, pw),
+                    cache_v = _kv_scatter(cache_v, (pi, bidx, pw),
                                           v[:, jq])
         with jax.named_scope("attention"):
             if paged and attn == "ragged":
@@ -1310,29 +1421,31 @@ def _mixed_step(params, cfg_tuple, cache_k, cache_v, pos, tokens,
                 pk, ksc = cache_k if quant else (cache_k, None)
                 pv, vsc = cache_v if quant else (cache_v, None)
                 o = ragged_paged_attention(
-                    q, pk, pv, lens, q_len, block_tables, layer=i,
-                    k_scale=ksc, v_scale=vsc).reshape(B, Q, hdim)
+                    q, pk, pv, lens, q_len, block_tables, layer=pi,
+                    k_scale=ksc, v_scale=vsc,
+                    groups=group).reshape(B, Q, hdim)
             elif attn == "ragged":
-                ks, ksc = _kv_layer(cache_k, i, H, Dh)
-                vs, vsc = _kv_layer(cache_v, i, H, Dh)
+                ks, ksc = _kv_layer(cache_k, pi, Hkv, Dh)
+                vs, vsc = _kv_layer(cache_v, pi, Hkv, Dh)
                 o = ragged_attention(q, ks, vs, lens, q_len, k_scale=ksc,
                                      v_scale=vsc).reshape(B, Q, hdim)
             else:
-                ks, ksc = _kv_layer(cache_k, i, H, Dh)
-                vs, vsc = _kv_layer(cache_v, i, H, Dh)
+                ks, ksc = _kv_layer(cache_k, pi, Hkv, Dh)
+                vs, vsc = _kv_layer(cache_v, pi, Hkv, Dh)
                 if paged:
-                    kg = ks[block_tables].reshape(B, span, H, Dh)
-                    vg = vs[block_tables].reshape(B, span, H, Dh)
+                    kg = ks[block_tables].reshape(B, span, Hkv, Dh)
+                    vg = vs[block_tables].reshape(B, span, Hkv, Dh)
                     if ksc is not None:
                         kg = kg.astype(jnp.float32) * ksc[
-                            block_tables].reshape(B, span, H)[..., None]
+                            block_tables].reshape(B, span, Hkv)[..., None]
                         vg = vg.astype(jnp.float32) * vsc[
-                            block_tables].reshape(B, span, H)[..., None]
+                            block_tables].reshape(B, span, Hkv)[..., None]
                 else:
                     kg, vg = ks, vs
                     if ksc is not None:
                         kg = kv_decode(kg, ksc)
                         vg = kv_decode(vg, vsc)
+                kg, vg = per_query_head(kg), per_query_head(vg)
                 # default: _verify_step's full mask over the written cache
                 s_raw = jnp.einsum("bqhd,bshd->bqhs", q, kg) * scale
                 sw = jnp.where(live[:, :, None, :], s_raw, NEG_INF)
@@ -1341,27 +1454,24 @@ def _mixed_step(params, cfg_tuple, cache_k, cache_v, pos, tokens,
                 if has_fresh:
                     # chunk slots: read-back context + the chunk's own
                     # FRESH K/V
+                    kf, vf = per_query_head(k), per_query_head(v)
                     s1 = jnp.where(ctx_live[:, None, None, :], s_raw,
                                    NEG_INF)
-                    s2 = jnp.einsum("bqhd,bjhd->bqhj", q, k) * scale
+                    s2 = jnp.einsum("bqhd,bjhd->bqhj", q, kf) * scale
                     s2 = jnp.where(self_live[:, :, None, :], s2, NEG_INF)
                     pf = jax.nn.softmax(
                         jnp.concatenate([s1, s2], axis=-1), axis=-1)
                     o_fresh = jnp.einsum("bqhs,bshd->bqhd",
                                          pf[..., :span], vg) \
-                        + jnp.einsum("bqhj,bjhd->bqhd", pf[..., span:], v)
+                        + jnp.einsum("bqhj,bjhd->bqhd", pf[..., span:], vf)
                     o = jnp.where(self_fresh[:, None, None, None],
                                   o_fresh, o)
                 o = o.reshape(B, Q, hdim)
         with jax.named_scope("attn_out"):
-            o = o @ params[f"{us}_attn_proj_weight"] \
-                + params[f"{us}_attn_proj_bias"]
-            h = h + o
-        with jax.named_scope("mlp"):
-            h = _ffn_block(params, us, h, i, moe=moe, valid=valid,
-                           stats=moe_stats)
+            h = h + _proj(params, f"{us}_attn_proj", o, blk.bias)
+        h = _ffn_of_kind(params, us, blk, h, i, valid, moe_stats, moe)
     logits = _window_logits(params, name, h, first_row, window, blk)
-    return logits, cache_k, cache_v
+    return logits, cache_k, cache_v, state
 
 
 def _serve_mixed(params, cfg_tuple, cache_k, cache_v, pos, tokens,
@@ -1378,7 +1488,7 @@ def _serve_mixed(params, cfg_tuple, cache_k, cache_v, pos, tokens,
     have an empty window and get their key back untouched."""
     moe_on = _moe_active(cfg_tuple)
     sd = {} if moe_on else None
-    logits, cache_k, cache_v = _mixed_step(
+    logits, cache_k, cache_v, _ = _mixed_step(
         params, cfg_tuple, cache_k, cache_v, pos, tokens, q_len,
         first_row, self_fresh, window=window, attn=attn, moe_stats=sd)
     sampled, after = _spec_sample(logits, temperature, top_k, rng_keys,
@@ -1394,18 +1504,21 @@ def _serve_mixed(params, cfg_tuple, cache_k, cache_v, pos, tokens,
 def _serve_mixed_paged(params, cfg_tuple, cache_k, cache_v, tables,
                        pos, tokens, q_len, first_row, self_fresh,
                        temperature, top_k, rng_keys, attn="masked",
-                       has_fresh=False, window=1):
+                       has_fresh=False, window=1, state=None):
     """``_serve_mixed`` over the block-table paged pool (``q_len`` 0
     marks inert slots, whose writes route to scratch block 0 and whose
     window is empty).  ``has_fresh`` (static) marks waves carrying
-    prompt-chunk slots — see ``_mixed_step``."""
+    prompt-chunk slots — see ``_mixed_step``.  ``state`` is the conv
+    layers' state of a block spec that has any (donated like the pool,
+    returned LAST); every other spec passes none and gets none back."""
     moe_on = _moe_active(cfg_tuple)
     routed = _block_of(cfg_tuple).routed
     sd = {} if moe_on or routed is not None else None
-    logits, cache_k, cache_v = _mixed_step(
+    logits, cache_k, cache_v, state = _mixed_step(
         params, cfg_tuple, cache_k, cache_v, pos, tokens, q_len,
         first_row, self_fresh, window=window, attn=attn,
-        block_tables=tables, has_fresh=has_fresh, moe_stats=sd)
+        block_tables=tables, has_fresh=has_fresh, moe_stats=sd,
+        state=state)
     sampled, after = _spec_sample(logits, temperature, top_k, rng_keys,
                                   q_len - first_row)
     out = (sampled, cache_k, cache_v, after)
@@ -1419,6 +1532,8 @@ def _serve_mixed_paged(params, cfg_tuple, cache_k, cache_v, tables,
         z = jnp.zeros((routed.num_experts,), jnp.int32)
         out = out + ((jnp.asarray(sd.get("load", z), jnp.int32),
                       jnp.asarray(sd.get("touched", 0), jnp.int32)),)
+    if state is not None:
+        out = out + (state,)
     return out
 
 
@@ -1446,6 +1561,7 @@ def serve_mixed_paged_fn(donate=True, attn="masked", window=1):
     kw = {"static_argnames": ("cfg_tuple", "attn", "has_fresh", "window")}
     if donate:
         kw["donate_argnums"] = (2, 3)
+        kw["donate_argnames"] = ("state",)
     fn = jax.jit(_serve_mixed_paged, **kw)
     return functools.partial(fn, attn=attn, window=window)
 

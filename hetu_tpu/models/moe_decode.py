@@ -485,8 +485,11 @@ def init_latent_moe_params(config, name="glm", seed=0, scale=0.02,
     scales 1, the selection bias normal(``bias_scale``) so that choosing
     by ``s + b`` and weighting by ``s`` differ.  The router's weight and
     bias stay float32 whatever ``dtype`` is."""
-    shapes = config.param_shapes(name)
+    return _init_routed_params(config.param_shapes(name), seed, scale,
+                               bias_scale, dtype)
 
+
+def _init_routed_params(shapes, seed, scale, bias_scale, dtype):
     def make(key):
         out = {}
         for k, (n, shape) in zip(jax.random.split(key, len(shapes)),
@@ -504,6 +507,175 @@ def init_latent_moe_params(config, name="glm", seed=0, scale=0.02,
         return out
 
     return jax.jit(make)(jax.random.PRNGKey(int(seed) % (2 ** 31 - 1)))
+
+
+# ------------- gated short convolutions beside attention ------------- #
+
+
+class HybridMoEConfig:
+    """A decoder whose layers are EITHER a gated short convolution OR
+    grouped-query attention (RMSNorm on every head's q and k, RoPE over
+    the whole head), each over a dense SwiGLU (the leading
+    ``num_dense_layers``) or a dropless routed FFN with no shared
+    expert: built from the source's own ``config.json`` keys (the
+    ``lfm2_moe`` family's names).  It yields the jit-static ``BlockSpec``
+    the mixed wave reads (``block_spec()``: the operator of every layer
+    is in it); the engine takes the rest from the attributes a
+    ``GPTConfig`` has too.  The head is the embedding table.  Values it
+    cannot run raise: ``conv_bias`` true, a ``layer_types`` entry other
+    than "conv" / "full_attention", a list that is not
+    ``num_hidden_layers`` long, query heads that are not a whole number
+    a K/V head.  ``use_expert_bias`` false is run with a zero selection
+    bias (``init_hybrid_moe_params`` and the converter make it)."""
+
+    OPERATORS = {"conv": "conv", "full_attention": "attention"}
+
+    def __init__(self, *, vocab_size, hidden_size, num_hidden_layers,
+                 num_attention_heads, num_key_value_heads, layer_types,
+                 conv_L_cache, intermediate_size, moe_intermediate_size,
+                 num_experts, num_experts_per_tok, num_dense_layers=0,
+                 norm_topk_prob=True, use_expert_bias=True,
+                 routed_scaling_factor=1.0, rope_theta=1000000.0,
+                 norm_eps=1e-5, conv_bias=False,
+                 max_position_embeddings=128000, **ignored):
+        unknown = sorted(set(layer_types) - set(self.OPERATORS))
+        if unknown:
+            raise ValueError(
+                f"HybridMoEConfig: layer_types holds {unknown}; it runs "
+                f"{sorted(self.OPERATORS)}")
+        if len(layer_types) != num_hidden_layers:
+            raise ValueError(
+                f"HybridMoEConfig: {len(layer_types)} layer_types for "
+                f"num_hidden_layers={num_hidden_layers}")
+        if conv_bias:
+            raise ValueError("HybridMoEConfig: conv_bias=True is not "
+                             "supported (only False)")
+        if conv_L_cache < 2:
+            raise ValueError(f"conv_L_cache={conv_L_cache}: a short "
+                             f"convolution has at least 2 taps")
+        if num_attention_heads % num_key_value_heads \
+                or hidden_size % num_attention_heads:
+            raise ValueError(
+                f"hidden_size={hidden_size}, num_attention_heads="
+                f"{num_attention_heads} and num_key_value_heads="
+                f"{num_key_value_heads} do not divide")
+        if (hidden_size // num_attention_heads) % 2:
+            raise ValueError("the head size must be even (RoPE)")
+        if not 1 <= num_experts_per_tok <= num_experts:
+            raise ValueError(
+                f"num_experts_per_tok={num_experts_per_tok} outside "
+                f"[1, num_experts={num_experts}]")
+        if not 0 <= num_dense_layers <= num_hidden_layers:
+            raise ValueError(
+                f"num_dense_layers={num_dense_layers} outside "
+                f"[0, num_hidden_layers={num_hidden_layers}]")
+        self.vocab_size = int(vocab_size)
+        self.hidden_size = int(hidden_size)
+        self.num_hidden_layers = int(num_hidden_layers)
+        self.num_attention_heads = int(num_attention_heads)
+        self.num_key_value_heads = int(num_key_value_heads)
+        self.max_position_embeddings = int(max_position_embeddings)
+        self.layer_types = tuple(layer_types)
+        self.conv_L_cache = int(conv_L_cache)
+        self.intermediate_size = int(intermediate_size)
+        self.moe_intermediate_size = int(moe_intermediate_size)
+        # not ``num_experts``: ``moe_spec_of`` reads that attribute as
+        # the capacity router's
+        self.n_routed_experts = int(num_experts)
+        self.num_experts_per_tok = int(num_experts_per_tok)
+        self.num_dense_layers = int(num_dense_layers)
+        self.norm_topk_prob = bool(norm_topk_prob)
+        self.use_expert_bias = bool(use_expert_bias)
+        self.routed_scaling_factor = float(routed_scaling_factor)
+        self.rope_theta = float(rope_theta)
+        self.norm_eps = float(norm_eps)
+
+    @classmethod
+    def from_hf(cls, config):
+        """From a ``config.json`` dict (keys it does not know are
+        ignored; the ones it cannot run raise).  Newer exports keep
+        ``rope_theta`` inside ``rope_parameters``."""
+        config = dict(config)
+        rope = config.pop("rope_parameters", None) or {}
+        config.setdefault("rope_theta", rope.get("rope_theta", 1000000.0))
+        return cls(**config)
+
+    @property
+    def head_dim(self):
+        return self.hidden_size // self.num_attention_heads
+
+    def operators(self):
+        """("conv" | "attention") for every layer."""
+        return tuple(self.OPERATORS[t] for t in self.layer_types)
+
+    def routed_spec(self):
+        return RoutedSpec(
+            num_experts=self.n_routed_experts,
+            top_k=self.num_experts_per_tok,
+            scale=self.routed_scaling_factor,
+            norm_topk=self.norm_topk_prob, n_shared=0)
+
+    def block_spec(self):
+        from .gpt_decode import BlockSpec
+        all_dense = self.num_dense_layers >= self.num_hidden_layers
+        return BlockSpec(
+            norm="rmsnorm", norm_eps=self.norm_eps, positions="rope",
+            rope_theta=self.rope_theta, attention="gqa", bias=False,
+            kv_heads=self.num_key_value_heads, qk_norm=True,
+            ops=self.operators(), conv_kernel=self.conv_L_cache,
+            ffn="swiglu" if all_dense else "routed",
+            leading_dense=0 if all_dense else self.num_dense_layers,
+            routed=None if all_dense else self.routed_spec(), head="tied")
+
+    def param_shapes(self, name="lfm"):
+        """{leaf: shape} of the serving parameter dict: the one list
+        ``init_hybrid_moe_params`` and ``hf.convert_lfm2_moe`` agree
+        on."""
+        d, dh = self.hidden_size, self.head_dim
+        hq, hkv = self.num_attention_heads, self.num_key_value_heads
+        f, fe, E = (self.intermediate_size, self.moe_intermediate_size,
+                    self.n_routed_experts)
+        shapes = {f"{name}_wte_table": (self.vocab_size, d),
+                  f"{name}_ln_f_scale": (d,)}
+        for i, op in enumerate(self.operators()):
+            us = f"{name}_h{i}"
+            shapes.update({f"{us}_ln1_scale": (d,), f"{us}_ln2_scale": (d,)})
+            if op == "conv":
+                shapes.update({
+                    f"{us}_conv_in_weight": (d, 3 * d),
+                    f"{us}_conv_weight": (self.conv_L_cache, d),
+                    f"{us}_conv_out_weight": (d, d)})
+            else:
+                shapes.update({
+                    f"{us}_attn_q_weight": (d, hq * dh),
+                    f"{us}_attn_k_weight": (d, hkv * dh),
+                    f"{us}_attn_v_weight": (d, hkv * dh),
+                    f"{us}_attn_q_norm_scale": (dh,),
+                    f"{us}_attn_k_norm_scale": (dh,),
+                    f"{us}_attn_proj_weight": (hq * dh, d)})
+            if i < self.num_dense_layers:
+                shapes.update({f"{us}_ffn_gate_weight": (d, f),
+                               f"{us}_ffn_up_weight": (d, f),
+                               f"{us}_ffn_down_weight": (f, d)})
+            else:
+                shapes.update({f"{us}_moe_router_weight": (d, E),
+                               f"{us}_moe_router_bias": (E,),
+                               f"{us}_moe_experts_gate": (E, d, fe),
+                               f"{us}_moe_experts_up": (E, d, fe),
+                               f"{us}_moe_experts_down": (E, fe, d)})
+        return shapes
+
+
+def init_hybrid_moe_params(config, name="lfm", seed=0, scale=0.02,
+                           bias_scale=0.1, dtype=jnp.float32):
+    """Seeded random serving params for a ``HybridMoEConfig``, as
+    ``init_latent_moe_params`` makes them (one jitted call on the
+    device; norm scales 1; the router's weight and selection bias
+    float32, the bias zero where the config has ``use_expert_bias``
+    false); the conv taps are weights like any other."""
+    return _init_routed_params(
+        config.param_shapes(name), seed, scale,
+        bias_scale if config.use_expert_bias else 0.0, dtype)
 
 
 # ------------------- expert-parallel placement ------------------- #

@@ -115,20 +115,33 @@ class ServingEngine:
     (contiguous/paged, int8, chunked, prefix sharing, speculation) —
     the parity suite pins it.
 
-    Block spec: a config that carries one (``config.block_spec()``, a
+    Block spec: a config that carries one (``config.block_spec()``)
+    runs on the wave over the paged pool: ``_mixed_step`` reads the
+    spec, the draft's cores do not (ROADMAP C3).  Two kinds do.  A
     ``models.moe_decode.LatentMoEConfig``: RMSNorm, RoPE, latent (MLA)
     attention over ONE paged pool of ``[c_kv | k_r]`` rows, dense
-    SwiGLU / dropless routed + shared FFN, untied head) runs on the
-    wave over the paged pool: ``_mixed_step`` reads the spec, the
-    draft's cores do not (ROADMAP C3).  Such an engine raises a
-    ``ValueError`` that names the path when built with ``paged=False``
-    (the contiguous ``KVCacheManager``), ``spec`` > 0 (the draft, its
-    contiguous cache and ``_decode_step``) or ``kv_quant="int8"``; its
-    pool refuses
+    SwiGLU / dropless routed + shared FFN, untied head.  A
+    ``models.moe_decode.HybridMoEConfig``: RMSNorm, every layer's
+    operator either grouped-query attention (RoPE over the whole head, a
+    per-head q/k norm, ``num_key_value_heads`` K/V heads in a pool that
+    holds the ATTENTION layers alone) or a gated short convolution whose
+    state (``z`` at a sequence's last ``conv_L_cache - 1`` positions, a
+    slot) the same ``PagedKVManager`` owns beside the pool, zeroes on
+    admission and hands through the donated step; dense SwiGLU / dropless
+    routed FFN, tied head.  Such an engine raises a ``ValueError`` that
+    names the path when built with ``paged=False`` (the contiguous
+    ``KVCacheManager``), ``spec`` > 0 (the draft, its contiguous cache
+    and ``_decode_step``; and state has no rollback) or
+    ``kv_quant="int8"``; a latent pool refuses
     ``export_blocks``/``import_blocks`` and the KV tiers (latent rows
-    have no wire format).  Its waves count ``serve.moe.*`` and
-    ``serve.attn.*`` (``ServingMetrics.record_routed``).  A GPT-2
-    config carries no spec and runs exactly the programs it ran.
+    have no wire format); a manager with state refuses those too, and
+    ``truncate`` and ``prefix_share=True`` (a prefix-cache hit would
+    start a sequence past position 0, and the state at a block boundary
+    is not in the pool: no snapshots yet; the default resolves to off).
+    Their waves count ``serve.moe.*`` and ``serve.attn.*``
+    (``ServingMetrics.record_routed``), the state ``serve.state.resets``
+    and ``serve.state.bytes``.  A GPT-2 config carries no spec and runs
+    exactly the programs it ran.
 
     Composes with ``tp_shard_params``: pass the placed dict and the
     fused step runs tensor-parallel (``_prep_param`` preserves the
@@ -173,7 +186,7 @@ class ServingEngine:
         # carries another): see the class docstring for what a
         # non-GPT-2 spec refuses
         self.block_spec = block_spec_of(c)
-        check_block_spec(self.block_spec)
+        check_block_spec(self.block_spec, c.num_hidden_layers)
         other = self.block_spec != GPT2_BLOCK
         # kv_quant="int8" (or $HETU_KV_QUANT) stores the cache as int8
         # payload + per-(position, head) f32 scales — ~3.7x more tokens
@@ -197,15 +210,22 @@ class ServingEngine:
                         f"ServingEngine: a non-GPT-2 block spec runs "
                         f"only on the mixed ragged wave over the paged "
                         f"pool; it cannot run on {path}")
-        latent = self.block_spec.latent
+        blk = self.block_spec
+        latent = blk.latent
+        L = c.num_hidden_layers
+        # layers that carry slot-indexed state beside the pool
+        n_state = blk.op_layers(L, "conv")
         if self.paged:
             self.kv = PagedKVManager(
-                layers=c.num_hidden_layers, heads=c.num_attention_heads,
+                layers=L - n_state,
+                heads=blk.kv_heads or c.num_attention_heads,
                 head_dim=Dh, slots=slots, max_seq_len=want,
                 pos_cap=c.max_position_embeddings, dtype=kv_dtype,
                 block=block, pool_blocks=pool_blocks,
                 prefix_share=prefix_share,
-                row_shape=(latent.row_width,) if latent else None)
+                row_shape=(latent.row_width,) if latent else None,
+                state_shape=(n_state, blk.conv_kernel - 1, c.hidden_size)
+                if n_state else None)
             chunk = (prefill_chunk if prefill_chunk is not None
                      else envvars.get_int("HETU_KV_CHUNK"))
             self.chunk = max(int(chunk or 0), 0)
@@ -467,7 +487,8 @@ class ServingEngine:
         rows = int(ql.sum())
         ctx = int(np.where(ql > 0, pos + ql, 0).sum())
         pairs = int((ql * pos + ql * (ql + 1) // 2).sum())
-        self.metrics.record_routed(load, touched, ctx, pairs)
+        self.metrics.record_routed(load, touched, ctx, pairs, rows,
+                                   len(ql) * int(wave["q"]))
         assignments = int(load.sum())
         mean = assignments / len(load)
         return {"tokens": rows, "routed": assignments, "dropped": 0,
@@ -818,13 +839,19 @@ class ServingEngine:
         routed_out = None
         with telemetry.span("serve.wave.dispatch", wave=wave_id):
             if self.paged:
+                # a manager with state hands it through beside the pool
+                # and gets it back last
+                stateful = {"state": self.kv.state} \
+                    if self.kv.stateful else {}
                 out = self._mixed(
                     self.params, self.cfg_tuple,
                     self.kv.cache_k, self.kv.cache_v,
                     tables, wave["pos"], wave["tokens"],
                     wave["q_len"], wave["first_row"], wave["self_fresh"],
                     self._temp, self._topk, self._keys,
-                    has_fresh=bool(pre))
+                    has_fresh=bool(pre), **stateful)
+                if stateful:
+                    out, self.kv.state = out[:-1], out[-1]
                 if self.routed is not None:
                     out, routed_out = out[:-1], out[-1]
                 sampled, ck, cv, after = self._moe_take(out)

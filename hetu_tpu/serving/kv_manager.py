@@ -26,7 +26,10 @@ not by the number of distinct deployment configs.
 
 from __future__ import annotations
 
+import functools
+
 import numpy as np
+import jax
 import jax.numpy as jnp
 
 from .. import envvars, quant, telemetry
@@ -132,6 +135,13 @@ def _alloc_cache(shape, dtype, quantized):
         return (jnp.zeros(shape, jnp.int8),
                 jnp.zeros(shape[:-1], jnp.float32))
     return jnp.zeros(shape, dtype)
+
+
+@functools.partial(jax.jit, donate_argnums=(0,))
+def _zero_slot(state, slot):
+    """``state`` ``[layers, slots, rows, width]`` with slot ``slot``'s
+    rows zeroed, in place (donated; ``slot`` traced: one program)."""
+    return state.at[:, slot].set(0)
 
 
 def cache_nbytes(cache):
@@ -451,7 +461,7 @@ class PagedKVManager:
     def __init__(self, *, layers, heads, head_dim, slots, max_seq_len,
                  pos_cap=None, dtype=jnp.float32, bucket=True,
                  block=16, pool_blocks=None, prefix_share=None,
-                 row_shape=None):
+                 row_shape=None, state_shape=None):
         if bucket:
             slots = round_up_pow2(slots)
             s = round_up_pow2(max_seq_len, floor=16)
@@ -478,10 +488,31 @@ class PagedKVManager:
         if self.n_blocks < 2:
             raise ValueError("pool needs at least 2 blocks "
                              "(scratch + one allocatable)")
+        # state beside the pool: ``state_shape`` = (layers that carry
+        # state, rows a slot, width) makes ``self.state`` ``[layers,
+        # slots, rows, width]``, indexed by SLOT, not by position (a
+        # short convolution's last inputs).  The same manager owns it:
+        # ``alloc`` zeroes a claimed slot's rows, the engine threads it
+        # through the donated step beside the pool.  It has no copy at a
+        # block boundary, so what would need one is refused by name: a
+        # shared prefix (explicit ``prefix_share=True`` raises; the
+        # default resolves to off), ``truncate`` and the wire.
+        self.stateful = state_shape is not None
+        if self.stateful and prefix_share:
+            raise ValueError(
+                "PagedKVManager: prefix_share with slot-indexed state: a "
+                "prefix-cache hit would start a sequence past position 0 "
+                "and the state at that block boundary is not in the pool "
+                "(no snapshots yet)")
         if prefix_share is None:
-            prefix_share = envvars.get_bool("HETU_KV_PREFIX_SHARE")
+            prefix_share = not self.stateful \
+                and envvars.get_bool("HETU_KV_PREFIX_SHARE")
         self.prefix_share = bool(prefix_share)
         self.quant = "int8" if _is_int8(dtype) else None
+        if self.stateful and self.quant:
+            raise ValueError(
+                "PagedKVManager: an int8 pool beside slot-indexed state: "
+                "the state has no codec (kv_quant with a conv operator)")
         # what one token keeps a layer: a K/V pair of [heads, head_dim]
         # in two pools, or, with ``row_shape`` (a latent spec's
         # ``(LatentSpec.row_width,)``), ONE row in ONE pool: ``cache_k``
@@ -505,6 +536,13 @@ class PagedKVManager:
             shape = (layers, self.n_blocks, self.block) + row
             self.cache_k = _alloc_cache(shape, dtype, self.quant)
             self.cache_v = _alloc_cache(shape, dtype, self.quant)
+        self.state = None
+        self.state_resets = 0
+        if self.stateful:
+            n_state, rows, width = (int(v) for v in state_shape)
+            self.state = jnp.zeros((n_state, self.n_slots, rows, width),
+                                   dtype)
+            telemetry.set_gauge("serve.state.bytes", int(self.state.nbytes))
         self._free = list(range(1, self.n_blocks))   # 0 = scratch
         self.ref = np.zeros(self.n_blocks, np.int32)
         self.tables = np.zeros((self.n_slots, self.table_width), np.int32)
@@ -734,6 +772,11 @@ class PagedKVManager:
         self.owner[slot] = owner
         self.lengths[slot] = cached
         self.total_allocs += 1
+        if self.stateful:
+            # a sequence starts with no history
+            self.state = _zero_slot(self.state, np.int32(slot))
+            self.state_resets += 1
+            telemetry.inc("serve.state.resets")
         if cached:
             self.prefix_hits += 1
             self.prefix_hit_tokens += cached
@@ -781,6 +824,7 @@ class PagedKVManager:
         (``_block_copy``)."""
         if self.owner[slot] is None:
             raise ValueError(f"slot {slot} is free")
+        self._refuse_state("truncate")
         old = int(self.lengths[slot])
         n = int(n)
         if not 0 <= n <= old:
@@ -879,6 +923,14 @@ class PagedKVManager:
                 f"handoff payload is a K/V pair of heads); a latent "
                 f"pool neither exports nor imports blocks")
 
+    def _refuse_state(self, what):
+        if self.stateful:
+            raise ValueError(
+                f"{what}: this manager holds slot-indexed state beside "
+                f"the pool, of which there is one copy a slot and none a "
+                f"position: it can neither be rolled back nor shipped "
+                f"with a span of blocks (no snapshots yet)")
+
     def _export_span(self, idx, length, quant_mode, *, count=True):
         """Gather pool blocks ``idx`` into the wire payload (shared by
         the slot and prefix export paths).  ``count=False`` keeps the
@@ -886,6 +938,7 @@ class PagedKVManager:
         so spill bytes don't masquerade as replica-to-replica wire
         traffic (the tier store keeps its own byte counters)."""
         self._refuse_latent("export_blocks/export_prefix")
+        self._refuse_state("export_blocks/export_prefix")
         mode = resolve_handoff_quant(quant_mode)
 
         def gather(cache):
@@ -920,6 +973,7 @@ class PagedKVManager:
         or blocks are short (backpressure, same contract as ``alloc``).
         Block size and layout must match; a mismatch raises."""
         self._refuse_latent("import_blocks")
+        self._refuse_state("import_blocks")
         if payload.get("layout") != "paged":
             raise ValueError(
                 f"cannot import a {payload.get('layout')!r} payload "
@@ -999,4 +1053,6 @@ class PagedKVManager:
             "quant": self.quant or "off",
             "cache_bytes": self.cache_bytes,
             "latent": self.latent,
+            "state_bytes": int(self.state.nbytes) if self.stateful else 0,
+            "state_resets": self.state_resets,
         }

@@ -152,6 +152,11 @@ class TieredKVStore:
             raise ValueError(
                 "kv_tiers: a latent pool cannot spill or fetch (its "
                 "blocks have no wire format: kv_manager.export_blocks)")
+        if getattr(kv, "stateful", False):
+            raise ValueError(
+                "kv_tiers: a pool with slot-indexed state beside it cannot "
+                "spill or fetch (a fetched prefix would start a sequence "
+                "without its state: kv_manager._refuse_state)")
         if not self.enabled or not getattr(kv, "prefix_share", False):
             return
         block = getattr(kv, "block", None)

@@ -205,20 +205,26 @@ class ServingMetrics(MetricsCore):
         self.moe_load = None
         self.attn_ctx_tokens = 0
         self.attn_score_pairs = 0
+        self.wave_rows_live = 0
+        self.wave_rows_computed = 0
 
     def _make_lc(self, t_submit):
         return _Lifecycle(t_submit)
 
-    def record_routed(self, load, touched, ctx_tokens, score_pairs):
+    def record_routed(self, load, touched, ctx_tokens, score_pairs,
+                      rows_live=0, rows_computed=0):
         """One wave of a dropless routed, latent engine: ``load`` [E]
         (assignments an expert, summed over the routed layers),
         ``touched`` (experts with load > 0, summed over them),
         ``ctx_tokens`` (the live slots' filled lengths after the wave's
-        writes, once a wave) and ``score_pairs`` (the positions every
-        live row sees).  Running sums here (``snapshot(since=mark)``
+        writes, once a wave), ``score_pairs`` (the positions every
+        live row sees), ``rows_live`` (the q-blocks' live rows) and
+        ``rows_computed`` (slots x the padded q-block: what every
+        operator but the routed experts runs over).  Running sums here (``snapshot(since=mark)``
         windows them) and the counters ``serve.moe.assignments``,
         ``serve.moe.experts_touched``, ``serve.attn.ctx_tokens``,
-        ``serve.attn.score_pairs`` and the gauge ``serve.moe.load_max``
+        ``serve.attn.score_pairs``, ``serve.wave.rows_live``,
+        ``serve.wave.rows_computed`` and the gauge ``serve.moe.load_max``
         (this wave's largest load) in ``telemetry``."""
         load = np.asarray(load, np.int64)
         assignments = int(load.sum())
@@ -228,6 +234,10 @@ class ServingMetrics(MetricsCore):
                          else self.moe_load + load)
         self.attn_ctx_tokens += int(ctx_tokens)
         self.attn_score_pairs += int(score_pairs)
+        self.wave_rows_live += int(rows_live)
+        self.wave_rows_computed += int(rows_computed)
+        telemetry.inc("serve.wave.rows_live", int(rows_live))
+        telemetry.inc("serve.wave.rows_computed", int(rows_computed))
         telemetry.inc("serve.moe.assignments", assignments)
         telemetry.inc("serve.moe.experts_touched", int(touched))
         telemetry.set_gauge("serve.moe.load_max", int(load.max()))
@@ -482,7 +492,8 @@ class ServingMetrics(MetricsCore):
     _MARK_COUNTS = ("submitted", "rejected", "finished",
                     "tokens_generated", "prefill_batched",
                     "moe_assignments", "moe_experts_touched",
-                    "attn_ctx_tokens", "attn_score_pairs")
+                    "attn_ctx_tokens", "attn_score_pairs",
+                    "wave_rows_live", "wave_rows_computed")
 
     def mark(self):
         """A position in this engine's history for ``snapshot(since=)``:
@@ -554,6 +565,8 @@ class ServingMetrics(MetricsCore):
                                        if mean > 0 else None),
                 "attn_ctx_tokens": count("attn_ctx_tokens"),
                 "attn_score_pairs": count("attn_score_pairs"),
+                "wave_rows_live": count("wave_rows_live"),
+                "wave_rows_computed": count("wave_rows_computed"),
             }
         return {
             **routed,

@@ -335,3 +335,130 @@ def test_ragged_dot_becomes_the_compilers_grouped_matmul(sds):
         sds((64,), jnp.int32)).compile()
     assert "ragged-dot" in compiled.as_text()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+# ------------------------------------------------------------------- #
+# ISSUE 34: grouped query heads over the K/V pool, conv state beside it
+# ------------------------------------------------------------------- #
+
+# the lfm2-8b-a1b cell's own sizes (benchmarks/configs/lfm2-8b-a1b.json):
+# 32 query heads over 8 K/V heads of 64, rows of 512 lanes, 32 slots, a
+# table of 1024 pages (s_max 16,384), 25,601 blocks
+LFM = dict(heads=32, kv_heads=8, slots=32, table=1024, blocks=25601)
+
+
+@pytest.mark.parametrize("q_len", [1, 128, 256])
+def test_grouped_rows_at_the_lfm2_cell_sizes(sds, q_len):
+    """Four query heads read one K/V head's lanes: a q-tile of 64
+    queries stacks 2 K/V heads x 4 members x 64 = 512 rows a lane chunk;
+    a page is 16 x 512 x 2 B = 16 KB, four whole lane tiles with no
+    padding."""
+    from hetu_tpu.kernels import ragged_attention as ra
+    B, T, G = LFM["slots"], LFM["table"], LFM["heads"] // LFM["kv_heads"]
+    W = kv_row_width(LFM["kv_heads"], DH)
+    assert W == 512 and ra._lane_chunk(W, DH) == 128
+    pool = sds((3, LFM["blocks"], BLOCK, W), jnp.bfloat16)
+    lens = sds((B,), jnp.int32)
+
+    def fn(q, pk, pv, lengths, q_lens, bt):
+        return ragged_paged_attention(q, pk, pv, lengths, q_lens, bt,
+                                      layer=2, interpret=False, groups=G)
+
+    text = compiled_text(fn, sds((B, q_len, LFM["heads"], DH), jnp.bfloat16),
+                         pool, pool, lens, lens, sds((B, T), jnp.int32))
+    assert "ragged_paged_mixed" in text
+
+
+@pytest.mark.parametrize("q_len", [1, 256], ids=["Q1", "Q256"])
+def test_hybrid_mixed_step_updates_pool_and_state_in_place(sds, monkeypatch,
+                                                           q_len):
+    """The first four layers of the cell (c c A c: both dense FFNs, two
+    routed ones, one attention layer) at the published widths through
+    ``serve_mixed_paged_fn``: ONE kernel call (the pool holds the
+    attention layer alone), the pool pair and the conv state donated and
+    aliased, temporaries that do not grow with the pool."""
+    import json
+    import os
+    from hetu_tpu.kernels import ragged_attention as ra
+    from hetu_tpu.models.moe_decode import HybridMoEConfig
+    monkeypatch.setattr(ra, "_use_interpret", lambda: False)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "configs",
+                           "lfm2-8b-a1b.json")) as f:
+        conf = json.load(f)
+    L = 4
+    cfg = HybridMoEConfig.from_hf(dict(
+        conf, num_hidden_layers=L, layer_types=conf["layer_types"][:L]))
+    blk = cfg.block_spec()
+    B, T = LFM["slots"], LFM["table"]
+    params = {k: sds(s, jnp.float32 if "_moe_router_" in k else jnp.bfloat16)
+              for k, s in cfg.param_shapes("lfm").items()}
+    pool = sds((1, LFM["blocks"], BLOCK, 512), jnp.bfloat16)
+    state = sds((3, B, 2, cfg.hidden_size), jnp.bfloat16)
+    i32 = lambda *shape: sds(shape, jnp.int32)  # noqa: E731
+    fn = gd.serve_mixed_paged_fn(donate=True, attn="ragged", window=1)
+    compiled = fn.func.lower(
+        params, ("lfm", L, 32, DH, T * BLOCK, blk), pool, pool, i32(B, T),
+        i32(B), i32(B, q_len), i32(B), i32(B), sds((B,), jnp.bool_),
+        sds((B,), jnp.float32), i32(B), sds((B, 2), jnp.uint32),
+        attn="ragged", window=1, has_fresh=q_len > 1, state=state).compile()
+    calls = [line for line in compiled.as_text().splitlines()
+             if "custom-call(" in line and "ragged_paged_mixed" in line]
+    assert len(calls) == 1 and "tpu_custom_call" in calls[0]
+    assert calls[0].count(f"bf16[1,{LFM['blocks']},{BLOCK},512]") >= 2
+    mem = compiled.memory_analysis()
+    pool_bytes = 2 * LFM["blocks"] * BLOCK * 512 * 2
+    state_bytes = 3 * B * 2 * cfg.hidden_size * 2
+    assert mem.alias_size_in_bytes >= pool_bytes + state_bytes
+    assert mem.temp_size_in_bytes < pool_bytes
+
+
+# sha256[:16] of the same programs as tests/test_hybrid_moe.py's
+# PARENT_MASKED, lowered for the described chip with the Pallas kernels
+# (``attn="ragged"``, not interpreted), as the PARENT of PR 34 lowered
+# them, each Mosaic kernel's serialized body replaced by its assembly
+# WITHOUT locations (the bytecode carries file names and line numbers,
+# which move whenever a line is added above a kernel).
+PARENT_RAGGED = {
+    "gpt2.Q1.fresh0": "5919310cf2517705", "gpt2.Q1.fresh1": "5919310cf2517705",
+    "gpt2.Q32.fresh0": "a7c64ccb01632823",
+    "gpt2.Q32.fresh1": "a7c64ccb01632823",
+    "latent.Q1.fresh0": "3e2ddf60b91b860d",
+    "latent.Q1.fresh1": "3e2ddf60b91b860d",
+    "latent.Q32.fresh0": "9be25ac3fb1f17f1",
+    "latent.Q32.fresh1": "9be25ac3fb1f17f1"}
+
+
+def strip_kernel_locations(text):
+    """StableHLO text with every Mosaic kernel's base64 bytecode replaced
+    by its location-free assembly."""
+    import base64
+    import re
+    from jax._src.interpreters import mlir as jax_mlir
+    from jax._src.lib import tpu
+    from jax._src.lib.mlir import ir
+    ctx = jax_mlir.make_ir_context()
+    tpu.register_dialect(ctx)
+    ctx.allow_unregistered_dialects = True
+
+    def body(m):
+        with ctx:
+            module = ir.Module.parse(base64.b64decode(m.group(1)))
+            return '\\22body\\22: \\22' + module.operation.get_asm(
+                enable_debug_info=False) + '\\22'
+    return re.sub(r'\\22body\\22: \\22([A-Za-z0-9+/=]+)\\22', body, text)
+
+
+def test_gpt2_and_latent_kernel_waves_lower_to_the_parents(sds, monkeypatch):
+    """The two accepted serving cells' programs, kernels included, are
+    the parent's operation for operation (ISSUE 34: ``groups`` 1 is the
+    code path there was)."""
+    from test_hybrid_moe import digest, wave_programs
+    from hetu_tpu.kernels import ragged_attention as ra
+    monkeypatch.setattr(ra, "_use_interpret", lambda: False)
+    got = {}
+    for name, lowered in wave_programs(sds, "ragged").items():
+        text = lowered.as_text()
+        assert "tpu_custom_call" in text
+        got[name] = digest(strip_kernel_locations(text))
+    assert got == PARENT_RAGGED

@@ -648,7 +648,7 @@ class TestSamplingWindow:
 
         # the reference: the same step's logits at EVERY row (a window
         # that starts at row 0 and is Q wide), then the old scan
-        full, _, _ = gd._mixed_step(
+        full, _, _, _ = gd._mixed_step(
             params, cfg_tuple, ck, cv, *desc, np.zeros(B, np.int32),
             wave["self_fresh"], window=Q,
             block_tables=tables if paged else None, has_fresh=paged)
