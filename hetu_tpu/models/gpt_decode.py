@@ -184,6 +184,74 @@ def _pow2(n, floor=1):
     return 1 << (n - 1).bit_length()
 
 
+# Under about 240 rows a wave's weight products are bound by the
+# weights' bytes and not by its rows (a v5e's 197 TFLOP/s over 819 GB/s,
+# at 2 B and 2 operations a weight a row): a wave packed tighter than
+# that computes no faster and only makes chunks wait.
+_PACKED_ROWS_FLOOR = 256
+
+
+def wave_rows(cfg_tuple, slots, window, q, paged=True, has_fresh=True):
+    """How many rows the row-wise operators of a mixed wave run over:
+    the one place that says it, read by ``_mixed_step`` (the program's
+    static row count) and by the engine's scheduler (the capacity it
+    keeps a wave's live rows within).  A wave over the paged pool that
+    carries a prompt chunk (``has_fresh``) is PACKED into ``min(slots *
+    q, max(256, pow2(slots * window + 2 * q)))`` rows: every slot's
+    sampling window and two chunks of the bucket always fit (1,024 rows
+    at 32 slots and q 256, where the padded block is 8,192), and the
+    count is a function of the bucket, so the program set stays one a
+    (q bucket, ``has_fresh``).  Every other wave runs over ``slots *
+    q``: decode and verify blocks have most of their rows live; the
+    contiguous layout is in no cell; and the capacity router of a
+    ``MoESpec`` sizes each expert's slots from the rows it is handed
+    (``moe_capacity``), so its waves stay padded and drop what they
+    dropped."""
+    dense = slots * q
+    if not (paged and has_fresh) or _moe_of(cfg_tuple) is not None:
+        return dense
+    return min(dense, max(_PACKED_ROWS_FLOOR,
+                          _pow2(slots * window + 2 * q)))
+
+
+class _Rows(NamedTuple):
+    """A packed wave's row layout, built on the device from ``q_len``
+    alone: slot-major, each slot's ``q_len`` live rows in sequence
+    order, dead rows at the tail.  ``slot``/``off`` [R]: the slot and
+    the place in its q-block of every packed row (a dead row's are
+    clipped into range and mean nothing); ``live`` [R]; ``start`` [B]:
+    the packed row a slot's q-block starts at; ``q``: the q-block's
+    padded width."""
+
+    slot: jax.Array
+    off: jax.Array
+    live: jax.Array
+    start: jax.Array
+    q: int
+
+    @classmethod
+    def of(cls, q_len, q, rows):
+        ends = jnp.cumsum(q_len)
+        start = ends - q_len
+        r = jnp.arange(rows)
+        slot = jnp.minimum(
+            jnp.sum(r[:, None] >= ends[None, :], axis=1), len(q_len) - 1)
+        off = jnp.clip(r - start[slot], 0, q - 1)
+        return cls(slot, off, r < ends[-1], start, q)
+
+    def pack(self, x):
+        """``x`` [B, Q, ...] -> [1, R, ...]: the live rows, packed."""
+        flat = x.reshape((-1,) + x.shape[2:])
+        return flat[self.slot * self.q + self.off][None]
+
+    def unpack(self, x):
+        """``x`` [1, R, ...] -> [B, Q, ...]: slot b's rows back at
+        ``[b, :q_len[b]]``; what lies past them belongs to a neighbour
+        and is read by nobody (dead rows are masked by every reader)."""
+        at = self.start[:, None] + jnp.arange(self.q)[None, :]
+        return x[0][jnp.minimum(at, x.shape[1] - 1)]
+
+
 def _resolve_fast(mode=None):
     """Serving fast-path selection, shared by ``generate_fast`` and the
     serving engine: an explicit argument wins; else ``$HETU_SERVE_FAST``
@@ -1113,18 +1181,26 @@ def _spec_propose(params, cfg_tuple, cache_k, cache_v, pos, token, k):
 # contiguous/paged/int8/spec/chunked configs.
 
 
-def _window_logits(params, name, h, first_row, window, blk=GPT2_BLOCK):
+def _window_logits(params, name, h, first_row, window, blk=GPT2_BLOCK,
+                   rows=None):
     """The head over each slot's sampling window ALONE: rows
     ``first_row[b] + w`` (``w < window``, clipped to the q-block) of the
     last block's output ``h`` [B, Q, hd] are gathered FIRST, then the
     final LN and the tied head run over ``[B, window, hd]``.  Returns
     logits [B, window, V] f32.  The padded q-block never meets the
     vocabulary: at 16 slots x 256 rows x 50257 that was 0.82 GB of f32
-    and 255 of every 256 rows were read by nobody."""
+    and 255 of every 256 rows were read by nobody.  Of a packed wave
+    (``rows``, ``h`` [1, R, hd]) the window is gathered straight from
+    the packed rows, ``rows.start[b]`` further on."""
     with jax.named_scope("lm_head"):
-        rows = jnp.clip(first_row[:, None] + jnp.arange(window)[None, :],
-                        0, h.shape[1] - 1)                 # [B, W]
-        hw = jnp.take_along_axis(h, rows[:, :, None], axis=1)
+        Q = h.shape[1] if rows is None else rows.q
+        at = jnp.clip(first_row[:, None] + jnp.arange(window)[None, :],
+                      0, Q - 1)                            # [B, W]
+        if rows is None:
+            hw = jnp.take_along_axis(h, at[:, :, None], axis=1)
+        else:
+            hw = h[0][jnp.minimum(rows.start[:, None] + at,
+                                  h.shape[1] - 1)]
         hw = _norm(blk, params, f"{name}_ln_f", hw)
         if blk.head == "untied":
             return (hw @ params[f"{name}_lm_head_weight"]
@@ -1135,7 +1211,7 @@ def _window_logits(params, name, h, first_row, window, blk=GPT2_BLOCK):
 
 
 def _latent_attention(params, us, blk, H, h, pool, i, wblk, woff, posns,
-                      live, lens, q_len, block_tables, attn):
+                      live, lens, q_len, block_tables, attn, rows=None):
     """One layer's multi-head latent attention over the paged LATENT
     pool ``[L, N_blocks, block, LatentSpec.row_width]``, every row of
     the wave in the ABSORBED form: ``q_nope`` is carried into latent space
@@ -1144,7 +1220,10 @@ def _latent_attention(params, us, blk, H, h, pool, i, wblk, woff, posns,
     ``kv_lora_rank`` columns, value, for all ``H`` heads at once; the
     output leaves latent space through ``W_uv``.  The wave's own rows
     are written first and read back from the pool (one path for chunk
-    and decode rows).  Returns (h + attention, pool)."""
+    and decode rows).  Of a packed wave (``rows``; ``h``, ``wblk``,
+    ``woff`` and ``posns`` [1, R, ..]) the rows are written as they
+    lie, and the query alone is unpacked for the scoring and its result
+    packed back.  Returns (h + attention, pool)."""
     la = blk.latent
     B, Q, _ = h.shape
     dn, dr, dv, dc = (la.qk_nope_head_dim, la.qk_rope_head_dim,
@@ -1176,6 +1255,8 @@ def _latent_attention(params, us, blk, H, h, pool, i, wblk, woff, posns,
         pool = pool.at[i, wblk, woff].set(row.astype(pool.dtype))
     scale = (dn + dr) ** -0.5
     with jax.named_scope("attention"):
+        if rows is not None:
+            qf = rows.unpack(qf)
         if attn == "ragged":
             from ..kernels.ragged_attention import ragged_paged_mla
             o_lat = ragged_paged_mla(qf, pool, lens, q_len,
@@ -1183,11 +1264,13 @@ def _latent_attention(params, us, blk, H, h, pool, i, wblk, woff, posns,
                                      scale=scale, layer=i)
         else:
             T, bs = block_tables.shape[1], pool.shape[2]
-            kg = pool[i][block_tables].reshape(B, T * bs, dc + dr + pad)
+            kg = pool[i][block_tables].reshape(-1, T * bs, dc + dr + pad)
             s = jnp.einsum("bqhc,bsc->bqhs", qf, kg) * scale
             p = jax.nn.softmax(
                 jnp.where(live[:, :, None, :], s, NEG_INF), axis=-1)
             o_lat = jnp.einsum("bqhs,bsc->bqhc", p, kg[..., :dc])
+        if rows is not None:
+            o_lat = rows.pack(o_lat)
     with jax.named_scope("mla_absorb"):
         o = jnp.einsum("bqhc,chv->bqhv", o_lat.astype(h.dtype),
                        w_kvb[:, :, dn:]).reshape(B, Q, H * dv)
@@ -1221,7 +1304,7 @@ def _ffn_of_kind(params, us, blk, h, i, valid, stats, moe=None):
     return h + y.reshape(shp)
 
 
-def _conv_operator(params, us, blk, h, state, si, q_len):
+def _conv_operator(params, us, blk, h, state, si, q_len, rows=None):
     """One layer's gated short convolution over the wave's ``[B, Q]``
     rows: ``[b | c | x] = u W_in`` (``u`` the norm of ``h``), ``z = b *
     x``, ``y_t = sum_j w[j] * z_{t - (K - 1) + j}`` (depthwise, causal,
@@ -1234,20 +1317,46 @@ def _conv_operator(params, us, blk, h, state, si, q_len):
     history where the q-block is shorter).  A dead row lies past
     ``q_len`` and a dead slot has ``q_len`` 0, so neither moves the
     state; a slot's rows are zero when its sequence starts (the manager
-    zeroes them on admission).  Returns (h + operator, state)."""
+    zeroes them on admission).  A packed wave (``rows``, ``h`` [1, R,
+    d]) needs no unpacking: slot-major rows keep ``z_{t-1}``,
+    ``z_{t-2}`` next door, and a row nearer than a tap's reach to its
+    slot's first takes the slot's history instead.  Returns (h +
+    operator, state)."""
     K = blk.conv_kernel
     Q = h.shape[1]
     with jax.named_scope("conv_in"):
         u = _norm(blk, params, f"{us}_ln1", h)
         bg, cg, x = jnp.split(u @ params[f"{us}_conv_in_weight"], 3, axis=-1)
         z = bg * x                                          # [B, Q, d]
-    with jax.named_scope("conv_mix"):
-        zz = jnp.concatenate([state[si].astype(z.dtype), z], axis=1)
-        w = params[f"{us}_conv_weight"]                     # [K, d]
-        y = sum(w[j] * zz[:, j:j + Q] for j in range(K))
+    w = params[f"{us}_conv_weight"]                         # [K, d]
+    if rows is None:
+        with jax.named_scope("conv_mix"):
+            zz = jnp.concatenate([state[si].astype(z.dtype), z], axis=1)
+            y = sum(w[j] * zz[:, j:j + Q] for j in range(K))
+        with jax.named_scope("state_write"):
+            last = jax.vmap(lambda rows, n: jax.lax.dynamic_slice_in_dim(
+                rows, n, K - 1, 0))(zz, q_len)              # [B, K-1, d]
+    else:
+        # ``zz`` above, a slot: index m is the history's row m under
+        # K - 1 and the slot's own row m - (K - 1) from there on
+        hist, zp = state[si].astype(z.dtype), z[0]          # [B, K-1, d]
+        with jax.named_scope("conv_mix"):
+            taps = []
+            for j in range(K - 1):
+                back = K - 1 - j
+                own = jnp.pad(zp, ((back, 0), (0, 0)))[:Q]  # z_{t - back}
+                old = hist[rows.slot, jnp.minimum(rows.off + j, K - 2)]
+                taps.append(jnp.where((rows.off >= back)[:, None], own,
+                                      old))
+            taps.append(zp)
+            y = sum(w[j] * taps[j] for j in range(K))[None]
+        with jax.named_scope("state_write"):
+            m = q_len[:, None] + jnp.arange(K - 1)[None, :]  # [B, K-1]
+            own = zp[jnp.clip(rows.start[:, None] + m - (K - 1), 0, Q - 1)]
+            old = jnp.take_along_axis(
+                hist, jnp.minimum(m, K - 2)[:, :, None], axis=1)
+            last = jnp.where((m >= K - 1)[:, :, None], own, old)
     with jax.named_scope("state_write"):
-        last = jax.vmap(lambda rows, n: jax.lax.dynamic_slice_in_dim(
-            rows, n, K - 1, 0))(zz, q_len)                  # [B, K-1, d]
         state = state.at[si].set(last.astype(state.dtype))
     with jax.named_scope("conv_out"):
         h = h + (cg * y) @ params[f"{us}_conv_out_weight"]
@@ -1272,10 +1381,28 @@ def _mixed_step(params, cfg_tuple, cache_k, cache_v, pos, tokens,
     ``logits[b, w]`` is the next-token distribution after input
     ``first_row[b] + w``, for ``w < window`` = W (static; 1, or
     ``spec_k + 1`` on an engine that speculates — a constant of the
-    engine, so it adds no program).  Every block still runs over the
-    whole q-block; only the final LN and the head are narrowed.
-    Dead positions and dead slots (``q_len`` 0) follow
-    ``_verify_step``'s write/mask conventions exactly.
+    engine, so it adds no program).  Dead positions and dead slots
+    (``q_len`` 0) follow ``_verify_step``'s write/mask conventions
+    exactly.
+
+    What the blocks run over.  A decode or verify wave (``has_fresh``
+    False), and every wave of the contiguous layout, runs every block
+    over the whole padded q-block ``[B, Q]``; only the final LN and the
+    head are narrowed to the windows.  A wave over the paged pool that
+    carries a prompt chunk is PACKED once, before the block stack, into
+    ``wave_rows(...)`` = R rows (``_Rows``: slot-major, live rows first;
+    static, a function of B, ``window`` and Q, so it adds no program;
+    the SCHEDULER keeps a wave's live rows within it, and rows past R
+    would be lost).  Everything row-wise then runs over ``[1, R]``:
+    embedding, norms, projections, per-head norm and RoPE, the latent
+    projections and absorbs, the conv operator, every FFN and the
+    router (``T x k`` assignments of R rows, not of B x Q).  Only what
+    needs a slot's rows as a block unpacks to ``[B, Q]``: the page
+    write, the attention of every kind (kernels and masked paths see
+    what they saw) and the fresh-self softmax; the attention's result
+    is packed back, and ``_window_logits`` gathers the windows straight
+    from the packed rows.  Where R is ``B x Q`` packing is the identity
+    and is skipped.
 
     The masked path's DEFAULT attention is ``_verify_step``'s full
     causal mask over the just-written cache, bit for bit — so decode,
@@ -1323,15 +1450,25 @@ def _mixed_step(params, cfg_tuple, cache_k, cache_v, pos, tokens,
     Hkv = blk.kv_heads or H
     group = H // Hkv
     paged = block_tables is not None
+    R = wave_rows(cfg_tuple, B, window, Q, paged, has_fresh)
     bidx = jnp.arange(B)
     posns = pos[:, None] + jnp.arange(Q)[None, :]          # [B, Q]
     valid = jnp.arange(Q)[None, :] < q_len[:, None]        # [B, Q]
     lens = (pos + q_len).astype(jnp.int32)   # filled after the writes
+    # the rows every row-wise operator runs over: the padded q-blocks
+    # [B, Q], or a chunk wave's live rows packed into [1, R]
+    rows = _Rows.of(q_len, Q, R) if R < B * Q else None
+    if rows is None:
+        tokens_r, posns_r, valid_r = tokens, posns, valid
+    else:
+        tokens_r, posns_r = rows.pack(tokens), rows.pack(posns)
+        valid_r = rows.live[None]
+    Br, Qr = tokens_r.shape
     with jax.named_scope("embed"):
-        h = params[f"{name}_wte_table"][tokens]            # [B, Q, hd]
+        h = params[f"{name}_wte_table"][tokens_r]          # [Br, Qr, hd]
         if blk.positions == "learned":
             wpe = params[f"{name}_wpe"]
-            h = h + wpe[jnp.clip(posns, 0, wpe.shape[0] - 1)]
+            h = h + wpe[jnp.clip(posns_r, 0, wpe.shape[0] - 1)]
     if attn == "ragged":
         from ..kernels.ragged_attention import (
             ragged_attention, ragged_paged_attention,
@@ -1344,6 +1481,12 @@ def _mixed_step(params, cfg_tuple, cache_k, cache_v, pos, tokens,
                          block_tables[bidx[:, None], posc // bs_blk], 0)
         woff = posc % bs_blk
         span = T * bs_blk
+        # a row scatter writes the rows where they lie (a packed wave's
+        # dead tail to scratch block 0, as a padded wave's dead rows)
+        wblk_r, woff_r = wblk, woff
+        if rows is not None:
+            wblk_r = jnp.where(valid_r, rows.pack(wblk), 0)
+            woff_r = rows.pack(woff)
     else:
         span = S_max
     ctx = jnp.arange(span)[None, None, :]
@@ -1365,17 +1508,17 @@ def _mixed_step(params, cfg_tuple, cache_k, cache_v, pos, tokens,
         us = f"{name}_h{i}"
         if blk.op_kind(i) == "conv":
             h, state = _conv_operator(params, us, blk, h, state,
-                                      blk.op_index(i), q_len)
-            h = _ffn_of_kind(params, us, blk, h, i, valid, moe_stats)
+                                      blk.op_index(i), q_len, rows)
+            h = _ffn_of_kind(params, us, blk, h, i, valid_r, moe_stats)
             continue
         if blk.attention == "latent":
             # latent attention over ONE pool (``cache_v`` is None);
             # ``check_block_spec`` keeps out the combinations this wave
             # does not run
             h, cache_k = _latent_attention(
-                params, us, blk, H, h, cache_k, i, wblk, woff, posns,
-                live, lens, q_len, block_tables, attn)
-            h = _ffn_of_kind(params, us, blk, h, i, valid, moe_stats)
+                params, us, blk, H, h, cache_k, i, wblk_r, woff_r,
+                posns_r, live, lens, q_len, block_tables, attn, rows)
+            h = _ffn_of_kind(params, us, blk, h, i, valid_r, moe_stats)
             continue
         # the pool holds the attention layers alone (all of them, in
         # their order, where the spec names no operators)
@@ -1383,17 +1526,25 @@ def _mixed_step(params, cfg_tuple, cache_k, cache_v, pos, tokens,
         with jax.named_scope("attn_qkv"):
             x = _norm(blk, params, f"{us}_ln1", h)
             q = _proj(params, f"{us}_attn_q", x, blk.bias).reshape(
-                B, Q, H, Dh)
+                Br, Qr, H, Dh)
             k = _proj(params, f"{us}_attn_k", x, blk.bias).reshape(
-                B, Q, Hkv, Dh)
+                Br, Qr, Hkv, Dh)
             v = _proj(params, f"{us}_attn_v", x, blk.bias).reshape(
-                B, Q, Hkv, Dh)
+                Br, Qr, Hkv, Dh)
             if blk.qk_norm:
                 q = _rms(q, params[f"{us}_attn_q_norm_scale"], blk.norm_eps)
                 k = _rms(k, params[f"{us}_attn_k_norm_scale"], blk.norm_eps)
             if blk.positions == "rope":
-                q = _rope(q, posns, blk.rope_theta)
-                k = _rope(k, posns, blk.rope_theta)
+                q = _rope(q, posns_r, blk.rope_theta)
+                k = _rope(k, posns_r, blk.rope_theta)
+        k_r, v_r = k, v
+        if rows is not None:
+            # the page write, the scoring and the fresh-self softmax
+            # take a slot's rows as a block
+            with jax.named_scope("attention"):
+                q = rows.unpack(q)
+            with jax.named_scope("kv_write"):
+                k, v = rows.unpack(k), rows.unpack(v)
         with jax.named_scope("kv_write"):
             if paged and not quant and Q >= bs_blk:
                 # a q-block a page or more wide: whole pages
@@ -1402,8 +1553,8 @@ def _mixed_step(params, cfg_tuple, cache_k, cache_v, pos, tokens,
                 cache_v = _kv_write_pages(cache_v, pi, v, pos, q_len,
                                           block_tables)
             elif paged:
-                cache_k = _kv_scatter(cache_k, (pi, wblk, woff), k)
-                cache_v = _kv_scatter(cache_v, (pi, wblk, woff), v)
+                cache_k = _kv_scatter(cache_k, (pi, wblk_r, woff_r), k_r)
+                cache_v = _kv_scatter(cache_v, (pi, wblk_r, woff_r), v_r)
             else:
                 # descending j: dead (clipped) tail first, live wins
                 # last
@@ -1467,10 +1618,12 @@ def _mixed_step(params, cfg_tuple, cache_k, cache_v, pos, tokens,
                     o = jnp.where(self_fresh[:, None, None, None],
                                   o_fresh, o)
                 o = o.reshape(B, Q, hdim)
+            if rows is not None:
+                o = rows.pack(o)
         with jax.named_scope("attn_out"):
             h = h + _proj(params, f"{us}_attn_proj", o, blk.bias)
-        h = _ffn_of_kind(params, us, blk, h, i, valid, moe_stats, moe)
-    logits = _window_logits(params, name, h, first_row, window, blk)
+        h = _ffn_of_kind(params, us, blk, h, i, valid_r, moe_stats, moe)
+    logits = _window_logits(params, name, h, first_row, window, blk, rows)
     return logits, cache_k, cache_v, state
 
 

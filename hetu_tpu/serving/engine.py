@@ -38,7 +38,7 @@ from ..models.gpt_decode import (
     GPT2_BLOCK, block_spec_of, check_block_spec,
     _infer_name, _prep_param, _pow2, _resolve_fast, resolve_draft_layers,
     resolve_spec_k, serve_mixed_fn, serve_mixed_paged_fn,
-    serve_prefill_fn, spec_propose_fn,
+    serve_prefill_fn, spec_propose_fn, wave_rows,
 )
 from .kv_manager import (KVCacheManager, PagedKVManager,
                          assemble_mixed_wave, resolve_kv_block,
@@ -313,6 +313,10 @@ class ServingEngine:
         self._slot_version = [None] * B
         self._prefill_off = np.zeros(B, np.int32)  # paged: next prompt
         self._prompt_arr = [None] * B              # position to prefill
+        # admission order: a chunk wave takes its prompt chunks oldest
+        # admission first (see ``_mixed_wave``)
+        self._admit_no = np.zeros(B, np.int64)
+        self._admitted = 0
         self.steps = 0
         # ---- speculative decoding (spec=/$HETU_SPEC_K) ---- #
         self.spec_adapt = False
@@ -487,8 +491,7 @@ class ServingEngine:
         rows = int(ql.sum())
         ctx = int(np.where(ql > 0, pos + ql, 0).sum())
         pairs = int((ql * pos + ql * (ql + 1) // 2).sum())
-        self.metrics.record_routed(load, touched, ctx, pairs, rows,
-                                   len(ql) * int(wave["q"]))
+        self.metrics.record_routed(load, touched, ctx, pairs)
         assignments = int(load.sum())
         mean = assignments / len(load)
         return {"tokens": rows, "routed": assignments, "dropped": 0,
@@ -622,6 +625,8 @@ class ServingEngine:
                 self._gen[slot] = None
                 self._prompt_arr[slot] = np.asarray(req.prompt, np.int32)
                 self._prefill_off[slot] = cached
+                self._admit_no[slot] = self._admitted
+                self._admitted += 1
                 self._pos[slot] = 0
                 self._tok[slot] = 0
                 self._temp[slot] = req.temperature
@@ -740,6 +745,8 @@ class ServingEngine:
             self._gen[slot] = None
             self._prompt_arr[slot] = np.asarray(req.prompt, np.int32)
             self._prefill_off[slot] = 0
+            self._admit_no[slot] = self._admitted
+            self._admitted += 1
             self._pos[slot] = 0
             self._tok[slot] = 0
             self._temp[slot] = req.temperature
@@ -806,22 +813,6 @@ class ServingEngine:
                 draft = np.asarray(draft)
         with telemetry.span("serve.wave.assemble", wave=wave_id):
             entries = {}
-            chunk_take = {}   # slot -> (take, final) for prefill q-blocks
-            for s in pre:
-                prompt = self._prompt_arr[s]
-                P = len(prompt)
-                off = int(self._prefill_off[s])
-                if self.paged and self.chunk > 0:
-                    C_b = min(_pow2(self.chunk, floor=8), self.kv.s_max)
-                    take = min(self.chunk, C_b, P - off)
-                else:
-                    take = P - off
-                final = off + take >= P
-                # only the final chunk samples (and splits the rng) — at
-                # its last row; mid-prompt chunks pass first_row == q_len
-                entries[s] = ([int(t) for t in prompt[off:off + take]],
-                              off, take - 1 if final else take, self.paged)
-                chunk_take[s] = (take, final)
             qlen_v = {}
             for s in decoding:
                 if k_cur:
@@ -834,6 +825,41 @@ class ServingEngine:
                 else:
                     toks = [int(self._tok[s])]
                 entries[s] = (toks, int(self._pos[s]), 0, False)
+            # every decoding slot's rows ride every wave; prompt chunks
+            # enter WHOLE, oldest admission first, while the wave's live
+            # rows stay within the rows of the program the wave then has
+            # (``wave_rows``: a chunk wave is computed over that many
+            # packed rows, not over slots x the widest q-block).  A
+            # chunk that does not fit waits this wave out as a dead slot
+            # (``q_len`` 0: its pool and state do not move); the oldest
+            # always fits, so none waits longer than the chunks of older
+            # admissions last.
+            rows_live = sum(len(e[0]) for e in entries.values())
+            width = max((len(e[0]) for e in entries.values()), default=1)
+            chunk_take = {}   # slot -> (take, final) for prefill q-blocks
+            for s in sorted(pre, key=self._admit_no.__getitem__):
+                prompt = self._prompt_arr[s]
+                P = len(prompt)
+                off = int(self._prefill_off[s])
+                if self.paged and self.chunk > 0:
+                    C_b = min(_pow2(self.chunk, floor=8), self.kv.s_max)
+                    take = min(self.chunk, C_b, P - off)
+                else:
+                    take = P - off
+                if rows_live + take > wave_rows(
+                        self.cfg_tuple, B, self.spec_k + 1,
+                        _pow2(max(width, take)), self.paged):
+                    continue
+                rows_live += take
+                width = max(width, take)
+                final = off + take >= P
+                # only the final chunk samples (and splits the rng) — at
+                # its last row; mid-prompt chunks pass first_row == q_len
+                entries[s] = ([int(t) for t in prompt[off:off + take]],
+                              off, take - 1 if final else take, self.paged)
+                chunk_take[s] = (take, final)
+            waiting = [s for s in pre if s not in chunk_take]
+            pre = [s for s in pre if s in chunk_take]
             wave = assemble_mixed_wave(B, entries)
             tables = self.kv.tables.copy() if self.paged else None
         routed_out = None
@@ -868,6 +894,10 @@ class ServingEngine:
             after = np.array(after, np.uint32)
             moe_rec = (self._routed_record(wave, routed_out)
                        if routed_out is not None else None)
+            self.metrics.record_wave(
+                rows_live, wave_rows(self.cfg_tuple, B, self.spec_k + 1,
+                                     wave["q"], self.paged, bool(pre)),
+                len(waiting))
         dt = time.perf_counter() - t0
         self._wave_end = t0 + dt
         with telemetry.span("serve.wave.unpack", wave=wave_id):
@@ -969,6 +999,9 @@ class ServingEngine:
                     if t_wave > e:
                         self.metrics.lc_prefill(rid, t_wave - e,
                                                 count=False)
+                # a chunk that waited this wave out stalled that long
+                for s in waiting:
+                    self.metrics.lc_stall(self._reqs[s].request_id, t_wave)
             self.steps += 1
             spec = None
             if k_cur:

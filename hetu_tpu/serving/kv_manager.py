@@ -56,8 +56,13 @@ def _bucket_prompt(p, s_max, pos_cap):
 
 
 def assemble_mixed_wave(n_slots, entries, q_floor=1):
-    """Pack per-slot ragged q-blocks into ONE padded mixed-wave
-    descriptor (the engine's hot loop).
+    """Lay per-slot ragged q-blocks out as ONE mixed-wave descriptor,
+    every slot's q-block padded to the widest (the engine's hot loop).
+    The descriptor is what the HOST hands over; what the device computes
+    over is the step's own business: a wave that carries a prompt chunk
+    packs the live rows on the device (``gpt_decode._mixed_step``,
+    ``wave_rows``), and the engine lets into ``entries`` only as many
+    chunks as that row count holds.
 
     ``entries`` maps slot -> ``(tokens, pos, first_row, self_fresh)``:
 
@@ -77,7 +82,8 @@ def assemble_mixed_wave(n_slots, entries, q_floor=1):
     Width is bucketed to a power of two so waves with nearby shapes
     land on the same jit entry.  Slots absent from ``entries`` ride
     along inactive (``q_len = 0``): the kernel masks their attention
-    and their clipped writes land on dead positions.
+    and their clipped writes land on dead positions.  A prompt chunk
+    the wave had no rows for is such a slot for one wave.
     """
     width = max((len(t) for t, *_ in entries.values()), default=1)
     q = round_up_pow2(width, floor=q_floor)

@@ -29,11 +29,12 @@ full component breakdown:
                     0 without a handoff
     kv_alloc_ms     slot + block-table claim
     prefill_ms      prompt compute actually dispatched for this request
-    chunk_stall_ms  prefill-phase wall not spent computing (chunked
-                    prefill interleaving with decode waves; ~0 on a
-                    mixed-mode engine, where a residue over the
-                    threshold is a host pause or an accounting fault
-                    and is also counted, see ``_retire``)
+    chunk_stall_ms  prefill-phase wall not spent computing: the waves a
+                    prompt chunk waited out because the wave's rows
+                    were taken (``lc_stall``); else ~0 on a mixed-mode
+                    engine, where a residue over the threshold is a
+                    host pause or an accounting fault and is also
+                    counted, see ``_retire``
     decode_ms       first token -> retirement
 
 ``snapshot()`` aggregates each component at p50/p95/p99 (over the
@@ -74,7 +75,7 @@ class _Lifecycle:
 
     __slots__ = ("t_submit", "t_blocked", "t_claim", "kv_alloc_ms",
                  "prefill_ms", "t_first", "n_prefills", "hop_ms",
-                 "handoff_ms")
+                 "handoff_ms", "stall_ms")
 
     def __init__(self, t_submit):
         self.t_submit = t_submit
@@ -86,6 +87,7 @@ class _Lifecycle:
         self.t_first = None       # first token landed
         self.hop_ms = 0.0         # router requeue hops before us
         self.handoff_ms = 0.0     # prefill->decode handoff detour
+        self.stall_ms = 0.0       # waves its chunk waited out
 
 
 class MetricsCore:
@@ -207,24 +209,39 @@ class ServingMetrics(MetricsCore):
         self.attn_score_pairs = 0
         self.wave_rows_live = 0
         self.wave_rows_computed = 0
+        self.chunks_deferred = 0
 
     def _make_lc(self, t_submit):
         return _Lifecycle(t_submit)
 
-    def record_routed(self, load, touched, ctx_tokens, score_pairs,
-                      rows_live=0, rows_computed=0):
+    def record_wave(self, rows_live, rows_computed, deferred):
+        """One wave of any engine: ``rows_live`` (the q-blocks' live
+        rows), ``rows_computed`` (the rows the wave's row-wise operators
+        ran over, ``gpt_decode.wave_rows``: a chunk wave's packed rows,
+        else slots x the padded q-block; live over computed is the
+        packing's hit share) and ``deferred`` (prompt chunks that waited
+        this wave out because the wave's rows were taken).  Running sums
+        here (``snapshot(since=mark)`` windows them) and the counters
+        ``serve.wave.rows_live``, ``serve.wave.rows_computed``,
+        ``serve.wave.chunks_deferred`` in ``telemetry``."""
+        self.wave_rows_live += int(rows_live)
+        self.wave_rows_computed += int(rows_computed)
+        self.chunks_deferred += int(deferred)
+        telemetry.inc("serve.wave.rows_live", int(rows_live))
+        telemetry.inc("serve.wave.rows_computed", int(rows_computed))
+        if deferred:
+            telemetry.inc("serve.wave.chunks_deferred", int(deferred))
+
+    def record_routed(self, load, touched, ctx_tokens, score_pairs):
         """One wave of a dropless routed, latent engine: ``load`` [E]
         (assignments an expert, summed over the routed layers),
         ``touched`` (experts with load > 0, summed over them),
         ``ctx_tokens`` (the live slots' filled lengths after the wave's
-        writes, once a wave), ``score_pairs`` (the positions every
-        live row sees), ``rows_live`` (the q-blocks' live rows) and
-        ``rows_computed`` (slots x the padded q-block: what every
-        operator but the routed experts runs over).  Running sums here (``snapshot(since=mark)``
+        writes, once a wave) and ``score_pairs`` (the positions every
+        live row sees).  Running sums here (``snapshot(since=mark)``
         windows them) and the counters ``serve.moe.assignments``,
         ``serve.moe.experts_touched``, ``serve.attn.ctx_tokens``,
-        ``serve.attn.score_pairs``, ``serve.wave.rows_live``,
-        ``serve.wave.rows_computed`` and the gauge ``serve.moe.load_max``
+        ``serve.attn.score_pairs`` and the gauge ``serve.moe.load_max``
         (this wave's largest load) in ``telemetry``."""
         load = np.asarray(load, np.int64)
         assignments = int(load.sum())
@@ -234,10 +251,6 @@ class ServingMetrics(MetricsCore):
                          else self.moe_load + load)
         self.attn_ctx_tokens += int(ctx_tokens)
         self.attn_score_pairs += int(score_pairs)
-        self.wave_rows_live += int(rows_live)
-        self.wave_rows_computed += int(rows_computed)
-        telemetry.inc("serve.wave.rows_live", int(rows_live))
-        telemetry.inc("serve.wave.rows_computed", int(rows_computed))
         telemetry.inc("serve.moe.assignments", assignments)
         telemetry.inc("serve.moe.experts_touched", int(touched))
         telemetry.set_gauge("serve.moe.load_max", int(load.max()))
@@ -274,6 +287,14 @@ class ServingMetrics(MetricsCore):
             lc.prefill_ms += dt_s * 1e3
             if count:
                 lc.n_prefills += 1
+
+    def lc_stall(self, request_id, dt_s):
+        """A wave this request's prompt chunk waited out (the wave's
+        rows were taken by older chunks): wall that is the scheduler's
+        doing, reported as ``chunk_stall_ms`` and not as a residue."""
+        lc = self._lc.get(request_id)
+        if lc is not None:
+            lc.stall_ms += dt_s * 1e3
 
     def lc_handoff(self, request_id, handoff_ms):
         """Credit the prefill->decode disaggregation detour: wall time
@@ -413,20 +434,24 @@ class ServingMetrics(MetricsCore):
         prefill_ms = min(lc.prefill_ms, prefill_wall_ms)
         chunk_stall_ms = max(prefill_wall_ms - prefill_ms, 0.0)
         # every step is ONE unified wave, and the whole ragged dispatch
-        # IS this request's prefill compute — any residue is host
-        # bookkeeping between claim and dispatch, noise-scale by
-        # construction, and is folded to 0.  A residue over the
-        # threshold is a host pause mid-prefill (a profiler starting, a
-        # long GC) or an accounting regression: the scheduler goes on,
-        # and the residue stays visible as chunk_stall_ms, one event and
-        # a counter that hetu_trace --check flags.
-        if chunk_stall_ms > max(50.0, 0.5 * prefill_wall_ms):
+        # IS this request's prefill compute — what is left is the waves
+        # its chunk waited out for the wave's rows (``lc_stall``) and a
+        # residue: host bookkeeping between claim and dispatch,
+        # noise-scale by construction, and folded to 0.  A residue over
+        # the threshold is a host pause mid-prefill (a profiler
+        # starting, a long GC) or an accounting regression: the
+        # scheduler goes on, and the residue stays visible in
+        # chunk_stall_ms, one event and a counter that hetu_trace
+        # --check flags.
+        waited_ms = min(lc.stall_ms, chunk_stall_ms)
+        residue_ms = chunk_stall_ms - waited_ms
+        if residue_ms > max(50.0, 0.5 * prefill_wall_ms):
             telemetry.inc("serve.lifecycle_residue")
             self.event("serve_lifecycle_residue", request=request_id,
-                       residue_ms=round(chunk_stall_ms, 3),
+                       residue_ms=round(residue_ms, 3),
                        wall_ms=round(prefill_wall_ms, 3))
         else:
-            chunk_stall_ms = 0.0
+            chunk_stall_ms = waited_ms
         decode_ms = max(now - lc.t_first, 0.0) * 1e3 \
             if n_generated > 1 else 0.0
         ttft_ms = max(lc.t_first - lc.t_submit, 0.0) * 1e3
@@ -493,7 +518,8 @@ class ServingMetrics(MetricsCore):
                     "tokens_generated", "prefill_batched",
                     "moe_assignments", "moe_experts_touched",
                     "attn_ctx_tokens", "attn_score_pairs",
-                    "wave_rows_live", "wave_rows_computed")
+                    "wave_rows_live", "wave_rows_computed",
+                    "chunks_deferred")
 
     def mark(self):
         """A position in this engine's history for ``snapshot(since=)``:
@@ -565,11 +591,12 @@ class ServingMetrics(MetricsCore):
                                        if mean > 0 else None),
                 "attn_ctx_tokens": count("attn_ctx_tokens"),
                 "attn_score_pairs": count("attn_score_pairs"),
-                "wave_rows_live": count("wave_rows_live"),
-                "wave_rows_computed": count("wave_rows_computed"),
             }
         return {
             **routed,
+            "wave_rows_live": count("wave_rows_live"),
+            "wave_rows_computed": count("wave_rows_computed"),
+            "chunks_deferred": count("chunks_deferred"),
             "requests_submitted": count("submitted"),
             "requests_rejected": count("rejected"),
             "requests_finished": count("finished"),
@@ -641,14 +668,15 @@ class ServingMetrics(MetricsCore):
             "mixed_mode": True,
         }
         # the unified wave carries all modes: prefill_ms here means
-        # "ragged dispatches this prompt rode in" and chunk_stall is 0
-        # by construction (folded at retirement)
+        # "ragged dispatches this prompt rode in" and chunk_stall the
+        # waves a chunk waited out for the wave's rows (the rest is
+        # folded to 0 at retirement)
         report["summary"] = (
             f"p{q} TTFT {cut:.1f}ms ({len(tail)}/{len(rows)} requests): "
             f"dominated by {dominant.replace('_ms', '')} "
             f"({ttft_parts[dominant]:.1f}ms, {share:.0%} of the "
             f"pre-token wall) [mixed-mode: prefill attributed to unified "
-            f"ragged waves; chunk_stall folded to 0]")
+            f"ragged waves; chunk_stall = waves waited out for row capacity]")
         return report
 
 

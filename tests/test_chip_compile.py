@@ -413,6 +413,121 @@ def test_hybrid_mixed_step_updates_pool_and_state_in_place(sds, monkeypatch,
     assert mem.temp_size_in_bytes < pool_bytes
 
 
+# ------------------------------------------------------------------- #
+# ISSUE 35: the packed chunk wave at the three serving cells' sizes
+# ------------------------------------------------------------------- #
+
+def _cell_config(name):
+    import json
+    import os
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "configs", name)) as f:
+        return json.load(f)
+
+
+def _chunk_wave_case(sds, cell):
+    """(params, cfg_tuple, pool_k, pool_v, state, slots, table, kernel
+    name, kernel calls) of a few layers of a serving cell at its
+    published widths, slots, table and pool."""
+    from hetu_tpu.models.moe_decode import HybridMoEConfig, LatentMoEConfig
+    if cell == "gpt2-xl":
+        L, H = 2, 25
+        pool = sds((L, CELL["blocks"], BLOCK, kv_row_width(H, DH)),
+                   jnp.bfloat16)
+        return (_gpt_shapes(sds, "gpt", L, H * DH, 512, 1024),
+                ("gpt", L, H, DH, 1024), pool, pool, None, CELL["slots"],
+                CELL["table"], "ragged_paged_mixed", L)
+    if cell == "glm-4.7-flash":
+        L, B, T = 2, 32, 320                  # the dense layer + a routed
+        cfg = LatentMoEConfig.from_hf(dict(
+            _cell_config("glm-4.7-flash.json"), num_hidden_layers=L,
+            vocab_size=512))
+        blk = cfg.block_spec()
+        params = {k: sds(v, jnp.float32 if "_moe_router_" in k
+                         else jnp.bfloat16)
+                  for k, v in cfg.param_shapes("glm").items()}
+        pool = sds((L, 10241, BLOCK, blk.latent.row_width), jnp.bfloat16)
+        return (params, ("glm", L, 20, 2048 // 20, T * BLOCK, blk), pool,
+                None, None, B, T, "ragged_paged_mla", L)
+    L, B, T = 3, LFM["slots"], LFM["table"]   # c c A: dense, dense, routed
+    conf = _cell_config("lfm2-8b-a1b.json")
+    cfg = HybridMoEConfig.from_hf(dict(
+        conf, num_hidden_layers=L, layer_types=conf["layer_types"][:L]))
+    params = {k: sds(v, jnp.float32 if "_moe_router_" in k else jnp.bfloat16)
+              for k, v in cfg.param_shapes("lfm").items()}
+    pool = sds((1, LFM["blocks"], BLOCK, 512), jnp.bfloat16)
+    return (params, ("lfm", L, 32, DH, T * BLOCK, cfg.block_spec()), pool,
+            pool, sds((2, B, 2, cfg.hidden_size), jnp.bfloat16), B, T,
+            "ragged_paged_mixed", 1)
+
+
+@pytest.mark.parametrize("cell", ["gpt2-xl", "glm-4.7-flash", "lfm2-8b-a1b"])
+def test_packed_chunk_wave_at_the_cells_sizes(sds, monkeypatch, cell):
+    """The chunk program of each serving cell (a 256-row bucket on 16 or
+    32 slots) runs its row-wise operators over 1,024 packed rows: the
+    kernels are the ones the padded program calls and see the padded
+    q-block, the pool (and the conv state) are still updated in place,
+    and the compiler's peak is no higher than the padded program's."""
+    from hetu_tpu.kernels import ragged_attention as ra
+    monkeypatch.setattr(ra, "_use_interpret", lambda: False)
+    params, cfg_tuple, pk, pv, state, B, T, kernel, n_calls = \
+        _chunk_wave_case(sds, cell)
+    Q = 256
+    assert gd.wave_rows(cfg_tuple, B, 1, Q) == 1024 < B * Q
+    i32 = lambda *shape: sds(shape, jnp.int32)  # noqa: E731
+
+    def compile_wave():
+        # a function of its own: the row count is read while tracing,
+        # and a trace is cached by the function traced
+        def wave(*args, **kw):
+            return gd._serve_mixed_paged(*args, **kw)
+        fn = jax.jit(wave, static_argnums=(1,),
+                     static_argnames=("attn", "has_fresh", "window"),
+                     donate_argnums=(2, 3), donate_argnames=("state",))
+        return fn.lower(
+            params, cfg_tuple, pk, pv, i32(B, T), i32(B), i32(B, Q), i32(B),
+            i32(B), sds((B,), jnp.bool_), sds((B,), jnp.float32), i32(B),
+            sds((B, 2), jnp.uint32), attn="ragged", window=1,
+            has_fresh=True, state=state).compile()
+
+    packed = compile_wave()
+    with monkeypatch.context() as m:
+        m.setattr(gd, "wave_rows",
+                  lambda cfg, slots, window, q, *a, **k: slots * q)
+        padded = compile_wave()
+    text = packed.as_text()
+    calls = [line for line in text.splitlines()
+             if "custom-call(" in line and kernel in line]
+    assert len(calls) == n_calls
+    assert all("tpu_custom_call" in c for c in calls)
+    # the kernel is handed the padded q-block, as in the padded program
+    # (the wrapper lays it out for the kernel: the same call line)
+    padded_calls = [line for line in padded.as_text().splitlines()
+                    if "custom-call(" in line and kernel in line]
+    shape_of = lambda line: line.split(  # noqa: E731
+        " custom-call(")[0].split("= ")[-1].split("{")[0]
+    assert [shape_of(c) for c in calls] == [shape_of(c)
+                                            for c in padded_calls]
+    assert ("ragged-dot" in text) == (cell != "gpt2-xl")
+    # the weight products run over the packed rows, not over B x Q
+    products = [line for line in text.splitlines()
+                if " dot(" in line or " convolution(" in line]
+    assert products and not any(f"[{B * Q}," in line or f"[{B},{Q},"
+                                in line.split(" = ")[0]
+                                for line in products)
+    pools = [a for a in (pk, pv) if a is not None]
+    donated = sum(int(np.prod(a.shape)) * 2 for a in pools + (
+        [state] if state is not None else []))
+    mem, mem0 = packed.memory_analysis(), padded.memory_analysis()
+    assert mem.alias_size_in_bytes >= donated
+    # no higher than the padded program's: the latent cell's reads 0.4 %
+    # over it (the padded query block is now gathered from the packed
+    # one and both are live at the kernel's call: 1.8 MB of 11 GB), the
+    # other two under it
+    assert mem.temp_size_in_bytes <= mem0.temp_size_in_bytes * (
+        1.01 if cell == "glm-4.7-flash" else 1.0)
+
+
 # sha256[:16] of the same programs as tests/test_hybrid_moe.py's
 # PARENT_MASKED, lowered for the described chip with the Pallas kernels
 # (``attn="ragged"``, not interpreted), as the PARENT of PR 34 lowered

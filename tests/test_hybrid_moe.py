@@ -165,7 +165,12 @@ def test_engine_through_pool_and_state_matches_reference(params, cfg, fast):
                                       (21, 7)])
     assert snap["moe_assignments"] == rows * 2 * 4 == sum(snap["moe_load"])
     assert snap["wave_rows_live"] == rows
-    assert snap["wave_rows_computed"] > 2 * rows   # padded to the widest
+    # rows computed: a chunk wave's ``wave_rows`` (at 4 slots x q 8 the
+    # padded block's 32: tests/test_packed_wave.py has the engines whose
+    # chunk waves pack), a decode wave's 4
+    assert rows <= snap["wave_rows_computed"] <= 32 * eng.steps
+    assert snap["wave_rows_computed"] < 2.5 * rows
+    assert snap["chunks_deferred"] == 0
     assert snap["attn_ctx_tokens"] > 0 and snap["attn_score_pairs"] > 0
     assert eng.kv.free_blocks == eng.kv.capacity_blocks   # all released
     assert eng.kv.stats()["state_bytes"] == 4 * 4 * 2 * 64 * 4
