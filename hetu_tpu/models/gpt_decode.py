@@ -367,6 +367,29 @@ class LatentSpec(NamedTuple):
         return -(-(self.kv_lora_rank + self.qk_rope_head_dim) // 128) * 128
 
 
+class MuP(NamedTuple):
+    """The forward multipliers of a block trained under maximal-update
+    parametrisation (the ``falcon_h1`` family's ``config.json`` keys):
+    constants the forward multiplies by, not weights.  ``embedding`` on
+    the embedded tokens, ``attention_in`` / ``ssm_in`` on the normed
+    input of each mixer, ``key`` on the projected keys, ``ssm`` on the
+    five slices ``z | x | B | C | dt`` of the state-space projection,
+    ``attention_out`` / ``ssm_out`` on each mixer's output, ``mlp_gate``
+    inside the SiLU, ``mlp_down`` on the FFN's output and ``lm_head`` on
+    the logits."""
+
+    embedding: float = 1.0
+    attention_in: float = 1.0
+    attention_out: float = 1.0
+    key: float = 1.0
+    ssm_in: float = 1.0
+    ssm_out: float = 1.0
+    ssm: tuple = (1.0, 1.0, 1.0, 1.0, 1.0)
+    mlp_gate: float = 1.0
+    mlp_down: float = 1.0
+    lm_head: float = 1.0
+
+
 class BlockSpec(NamedTuple):
     """norm: "layernorm" (scale and bias) | "rmsnorm"; positions:
     "learned" (a table added to the embedding) | "rope" (rotate-half
@@ -377,9 +400,16 @@ class BlockSpec(NamedTuple):
     K/V attention's projections carry biases (GPT-2's do); qk_norm:
     every head's q and k RMS-normalised over its columns with a learned
     scale before the rotation; ops: the OPERATOR of every layer, a tuple
-    of "attention" | "conv" (None: attention everywhere), a "conv" layer
-    being the gated short convolution of ``conv_kernel`` taps whose
-    state lives beside the pool; ffn: the kind of every layer from
+    of "attention" | "conv" | "attention+ssm" (None: attention
+    everywhere), a "conv" layer being the gated short convolution of
+    ``conv_kernel`` taps whose state lives beside the pool, an
+    "attention+ssm" layer running that K/V attention AND the state-space
+    mixer ``ssm`` (a ``ssm_decode.SSMSpec``) side by side on one norm,
+    their outputs summed into the residual, so that it holds K/V pages
+    and slot state both; head_dim: the head size where it is the
+    configuration's own key and not ``hidden / heads`` (0: that
+    quotient); mup: the block's forward multipliers (``MuP``; None: no
+    multiplication anywhere); ffn: the kind of every layer from
     ``leading_dense`` on, "gelu" | "swiglu" | "routed" (a
     ``moe_decode.RoutedSpec`` in ``routed``; the leading layers are
     dense SwiGLU); head: "tied" (the embedding table) | "untied"
@@ -400,6 +430,9 @@ class BlockSpec(NamedTuple):
     qk_norm: bool = False
     ops: Optional[tuple] = None
     conv_kernel: int = 0
+    head_dim: int = 0
+    ssm: Optional[tuple] = None
+    mup: Optional[MuP] = None
 
     def ffn_kind(self, i):
         """Layer ``i``'s FFN: the leading layers of a routed model are
@@ -412,17 +445,42 @@ class BlockSpec(NamedTuple):
         return sum(1 for i in range(L) if self.ffn_kind(i) == "routed")
 
     def op_kind(self, i):
-        """Layer ``i``'s operator: "attention" | "conv"."""
+        """Layer ``i``'s operator: "attention" | "conv" |
+        "attention+ssm"."""
         return self.ops[i] if self.ops else "attention"
 
-    def op_index(self, i):
-        """Layer ``i``'s place among the layers of its own operator: an
-        attention layer's index into the K/V pool, a conv layer's into
-        the state."""
-        return sum(1 for j in range(i) if self.op_kind(j) == self.op_kind(i))
+    def holds(self, i, what):
+        """Whether layer ``i`` keeps ``what``: "pool" (K/V pages: every
+        layer with an attention) or "state" (slot state beside the
+        pool: a conv or a state-space mixer)."""
+        kind = self.op_kind(i)
+        return kind != "conv" if what == "pool" else kind != "attention"
 
-    def op_layers(self, L, kind):
-        return sum(1 for i in range(L) if self.op_kind(i) == kind)
+    def op_index(self, i, what=None):
+        """Layer ``i``'s place among the layers that keep what it keeps:
+        an attention layer's index into the K/V pool, a conv layer's
+        into the state; a layer that keeps both says ``what``."""
+        what = what or ("state" if self.op_kind(i) == "conv" else "pool")
+        return sum(1 for j in range(i) if self.holds(j, what))
+
+    def state_shapes(self, L, hidden):
+        """The set of slot states ``L`` layers of this spec keep beside
+        the pool, as ``PagedKVManager(state_shapes=)`` takes it (None:
+        none): a conv layer's last ``conv_kernel - 1`` inputs, or a
+        state-space mixer's set (``SSMSpec.state_shapes``)."""
+        n = self.op_layers(L, "state")
+        if not n:
+            return None
+        if self.ssm is not None:
+            return self.ssm.state_shapes(n)
+        return (((n, self.conv_kernel - 1, hidden), None),)
+
+    def op_layers(self, L, what):
+        """How many of ``L`` layers keep ``what`` ("pool" | "state"; or
+        an operator's name: the layers of that operator)."""
+        if what in ("pool", "state"):
+            return sum(1 for i in range(L) if self.holds(i, what))
+        return sum(1 for i in range(L) if self.op_kind(i) == what)
 
 
 GPT2_BLOCK = BlockSpec()
@@ -450,7 +508,12 @@ def check_block_spec(blk, layers=None):
     an optional per-head q/k norm, every layer's operator either that
     attention or the gated short convolution (``ops``; a conv layer
     needs ``conv_kernel`` >= 2 taps, and ``ops`` names ``layers`` of
-    them): each over any of the three FFN kinds and either head."""
+    them) or both that attention and a state-space mixer on one norm
+    ("attention+ssm", which needs an ``ssm`` spec and is not mixed with
+    "conv" layers: the two keep different state): each over any of the
+    three FFN kinds and either head.  Multipliers (``mup``) and a head
+    size of the configuration's own go with the grouped-query block
+    alone."""
     if blk == GPT2_BLOCK:
         return
     common = (blk.norm, blk.positions) == ("rmsnorm", "rope") \
@@ -458,20 +521,31 @@ def check_block_spec(blk, layers=None):
         and blk.ffn in ("gelu", "swiglu", "routed") \
         and blk.head in ("tied", "untied")
     if blk.attention == "latent":
-        ok = common and blk.latent is not None and blk.ops is None
+        ok = common and blk.latent is not None and blk.ops is None \
+            and blk.ssm is None and blk.mup is None and not blk.head_dim
     else:
         ops = blk.ops or ()
         ok = common and blk.attention == "gqa" and blk.latent is None \
             and not blk.bias and blk.kv_heads >= 1 \
-            and all(o in ("attention", "conv") for o in ops) \
+            and all(o in ("attention", "conv", "attention+ssm")
+                    for o in ops) \
             and ("conv" not in ops or blk.conv_kernel >= 2) \
+            and ("attention+ssm" in ops) == (blk.ssm is not None) \
+            and not ("attention+ssm" in ops and "conv" in ops) \
             and (layers is None or not ops or len(ops) == layers)
     if not ok:
         raise ValueError(
             f"the mixed wave runs GPT-2's block, latent attention with "
             f"rmsnorm and rope, or grouped-query attention with rmsnorm "
-            f"and rope beside gated short convolutions; it cannot run "
-            f"{blk}")
+            f"and rope beside gated short convolutions or a state-space "
+            f"mixer; it cannot run {blk}")
+
+
+def head_dim_of(config):
+    """A configuration's head size: its block spec's own key where it
+    states one, else ``hidden_size / num_attention_heads``."""
+    return block_spec_of(config).head_dim \
+        or config.hidden_size // config.num_attention_heads
 
 
 def _rms(x, scale, eps):
@@ -504,9 +578,13 @@ def _rope(x, posns, theta):
                            axis=-1).astype(x.dtype)
 
 
-def swiglu(x, w_gate, w_up, w_down):
-    """``(silu(x W_gate) * x W_up) W_down``."""
-    return (jax.nn.silu(x @ w_gate) * (x @ w_up)) @ w_down
+def swiglu(x, w_gate, w_up, w_down, mup=None):
+    """``(silu(x W_gate) * x W_up) W_down``; under multipliers
+    ``(silu(mlp_gate * x W_gate) * x W_up) W_down * mlp_down``."""
+    if mup is None:
+        return (jax.nn.silu(x @ w_gate) * (x @ w_up)) @ w_down
+    gate = jax.nn.silu((x @ w_gate) * mup.mlp_gate)
+    return ((gate * (x @ w_up)) @ w_down) * mup.mlp_down
 
 
 def _moe_of(cfg_tuple):
@@ -1203,8 +1281,10 @@ def _window_logits(params, name, h, first_row, window, blk=GPT2_BLOCK,
                                   h.shape[1] - 1)]
         hw = _norm(blk, params, f"{name}_ln_f", hw)
         if blk.head == "untied":
-            return (hw @ params[f"{name}_lm_head_weight"]
-                    ).astype(jnp.float32)
+            logits = (hw @ params[f"{name}_lm_head_weight"]
+                      ).astype(jnp.float32)
+            return logits if blk.mup is None \
+                else logits * blk.mup.lm_head
         return (hw @ params[f"{name}_wte_table"].T
                 ).astype(jnp.float32) \
             + params.get(f"{name}_head_bias", 0.0)
@@ -1295,7 +1375,7 @@ def _ffn_of_kind(params, us, blk, h, i, valid, stats, moe=None):
         with jax.named_scope("mlp"):
             return h + swiglu(x, params[f"{us}_ffn_gate_weight"],
                               params[f"{us}_ffn_up_weight"],
-                              params[f"{us}_ffn_down_weight"])
+                              params[f"{us}_ffn_down_weight"], blk.mup)
     from .moe_decode import routed_ffn
     shp = x.shape
     y = routed_ffn(params, us, x.reshape(-1, shp[-1]), blk.routed,
@@ -1304,58 +1384,71 @@ def _ffn_of_kind(params, us, blk, h, i, valid, stats, moe=None):
     return h + y.reshape(shp)
 
 
+def _causal_conv(z, hist, w, q_len, rows=None, mix="conv_mix",
+                 write="state_write"):
+    """A depthwise causal convolution of ``K = w.shape[0]`` taps over
+    the wave's rows ``z`` ([B, Q, d], or a packed wave's [1, R, d] with
+    ``rows``): ``y_t = sum_j w[j] * z_{t - (K - 1) + j}``, a slot's
+    history before its q-block's first row being ``hist`` [B, K - 1, d].
+    Returns (y, the slot's last ``K - 1`` LIVE rows after the wave:
+    ``z`` at rows ``q_len - (K - 1) .. q_len - 1``, reaching back into
+    the old history where the q-block is shorter, so that a dead row
+    and a dead slot leave it where it was).  A packed wave needs no
+    unpacking: slot-major rows keep ``z_{t-1}``, ``z_{t-2}`` next door,
+    and a row nearer than a tap's reach to its slot's first takes the
+    slot's history instead.  ``mix`` / ``write`` name the two scopes."""
+    K = w.shape[0]
+    Q = z.shape[1]
+    hist = hist.astype(z.dtype)
+    if rows is None:
+        with jax.named_scope(mix):
+            zz = jnp.concatenate([hist, z], axis=1)
+            y = sum(w[j] * zz[:, j:j + Q] for j in range(K))
+        with jax.named_scope(write):
+            last = jax.vmap(lambda rows, n: jax.lax.dynamic_slice_in_dim(
+                rows, n, K - 1, 0))(zz, q_len)              # [B, K-1, d]
+        return y, last
+    # ``zz`` above, a slot: index m is the history's row m under
+    # K - 1 and the slot's own row m - (K - 1) from there on
+    zp = z[0]                                               # [R, d]
+    with jax.named_scope(mix):
+        taps = []
+        for j in range(K - 1):
+            back = K - 1 - j
+            own = jnp.pad(zp, ((back, 0), (0, 0)))[:Q]      # z_{t - back}
+            old = hist[rows.slot, jnp.minimum(rows.off + j, K - 2)]
+            taps.append(jnp.where((rows.off >= back)[:, None], own,
+                                  old))
+        taps.append(zp)
+        y = sum(w[j] * taps[j] for j in range(K))[None]
+    with jax.named_scope(write):
+        m = q_len[:, None] + jnp.arange(K - 1)[None, :]     # [B, K-1]
+        own = zp[jnp.clip(rows.start[:, None] + m - (K - 1), 0, Q - 1)]
+        old = jnp.take_along_axis(
+            hist, jnp.minimum(m, K - 2)[:, :, None], axis=1)
+        last = jnp.where((m >= K - 1)[:, :, None], own, old)
+    return y, last
+
+
 def _conv_operator(params, us, blk, h, state, si, q_len, rows=None):
     """One layer's gated short convolution over the wave's ``[B, Q]``
     rows: ``[b | c | x] = u W_in`` (``u`` the norm of ``h``), ``z = b *
     x``, ``y_t = sum_j w[j] * z_{t - (K - 1) + j}`` (depthwise, causal,
-    ``K = blk.conv_kernel`` taps), ``h + (c * y) W_out``.  What a
-    sequence carries from one q-block to its next is ``z`` at its last
-    ``K - 1`` positions: ``state`` ``[conv layers, slots, K - 1,
-    hidden]``, of which this layer's rows ``si`` are READ as the
-    history before the q-block's first row and WRITTEN with the ``z`` of
-    rows ``q_len - (K - 1) .. q_len - 1`` (reaching back into the old
-    history where the q-block is shorter).  A dead row lies past
-    ``q_len`` and a dead slot has ``q_len`` 0, so neither moves the
-    state; a slot's rows are zero when its sequence starts (the manager
-    zeroes them on admission).  A packed wave (``rows``, ``h`` [1, R,
-    d]) needs no unpacking: slot-major rows keep ``z_{t-1}``,
-    ``z_{t-2}`` next door, and a row nearer than a tap's reach to its
-    slot's first takes the slot's history instead.  Returns (h +
-    operator, state)."""
-    K = blk.conv_kernel
-    Q = h.shape[1]
+    ``K = blk.conv_kernel`` taps: ``_causal_conv``), ``h + (c * y)
+    W_out``.  What a sequence carries from one q-block to its next is
+    ``z`` at its last ``K - 1`` positions: ``state`` ``[conv layers,
+    slots, K - 1, hidden]``, of which this layer's rows ``si`` are READ
+    as the history before the q-block's first row and WRITTEN with the
+    ``z`` of the slot's last live rows.  A dead row lies past ``q_len``
+    and a dead slot has ``q_len`` 0, so neither moves the state; a
+    slot's rows are zero when its sequence starts (the manager zeroes
+    them on admission).  Returns (h + operator, state)."""
     with jax.named_scope("conv_in"):
         u = _norm(blk, params, f"{us}_ln1", h)
         bg, cg, x = jnp.split(u @ params[f"{us}_conv_in_weight"], 3, axis=-1)
         z = bg * x                                          # [B, Q, d]
-    w = params[f"{us}_conv_weight"]                         # [K, d]
-    if rows is None:
-        with jax.named_scope("conv_mix"):
-            zz = jnp.concatenate([state[si].astype(z.dtype), z], axis=1)
-            y = sum(w[j] * zz[:, j:j + Q] for j in range(K))
-        with jax.named_scope("state_write"):
-            last = jax.vmap(lambda rows, n: jax.lax.dynamic_slice_in_dim(
-                rows, n, K - 1, 0))(zz, q_len)              # [B, K-1, d]
-    else:
-        # ``zz`` above, a slot: index m is the history's row m under
-        # K - 1 and the slot's own row m - (K - 1) from there on
-        hist, zp = state[si].astype(z.dtype), z[0]          # [B, K-1, d]
-        with jax.named_scope("conv_mix"):
-            taps = []
-            for j in range(K - 1):
-                back = K - 1 - j
-                own = jnp.pad(zp, ((back, 0), (0, 0)))[:Q]  # z_{t - back}
-                old = hist[rows.slot, jnp.minimum(rows.off + j, K - 2)]
-                taps.append(jnp.where((rows.off >= back)[:, None], own,
-                                      old))
-            taps.append(zp)
-            y = sum(w[j] * taps[j] for j in range(K))[None]
-        with jax.named_scope("state_write"):
-            m = q_len[:, None] + jnp.arange(K - 1)[None, :]  # [B, K-1]
-            own = zp[jnp.clip(rows.start[:, None] + m - (K - 1), 0, Q - 1)]
-            old = jnp.take_along_axis(
-                hist, jnp.minimum(m, K - 2)[:, :, None], axis=1)
-            last = jnp.where((m >= K - 1)[:, :, None], own, old)
+    y, last = _causal_conv(z, state[si], params[f"{us}_conv_weight"],
+                           q_len, rows)
     with jax.named_scope("state_write"):
         state = state.at[si].set(last.astype(state.dtype))
     with jax.named_scope("conv_out"):
@@ -1439,12 +1532,21 @@ def _mixed_step(params, cfg_tuple, cache_k, cache_v, pos, tokens,
     ``mla_absorb``, ``kv_write``, ``attention``, ``attn_out``) over ONE
     pool, ``cache_v`` None; or, where ``blk.ops`` says "conv",
     ``_conv_operator`` (``conv_in``, ``conv_mix``, ``state_write``,
-    ``conv_out``) over ``state``.  With ``ops`` the pool holds the
-    attention layers alone and the state the conv layers alone, each
-    layer finding its own by ``blk.op_index``."""
+    ``conv_out``) over ``state``; or, where it says "attention+ssm",
+    that K/V attention AND ``ssm_decode.ssm_mixer`` (``ssm_in``,
+    ``ssm_conv``, ``ssm_scan``, ``state_write``, ``ssm_out``) on ONE
+    norm, ``state`` then being the mixer's set (a conv tail and a
+    float32 matrix state a layer) and the two outputs summed into the
+    residual.  With
+    ``ops`` the pool holds the layers with an attention alone and the
+    state the layers with a conv or a mixer alone, each layer finding
+    its own by ``blk.op_index``.  A spec's multipliers (``blk.mup``)
+    scale the embedding, each mixer's input and output, the keys, the
+    FFN and the logits; a spec without them multiplies nowhere."""
     name, L, H, Dh, S_max = cfg_tuple[:5]
     moe = _moe_of(cfg_tuple)
     blk = _block_of(cfg_tuple)
+    mup = blk.mup
     B, Q = tokens.shape
     hdim = H * Dh
     Hkv = blk.kv_heads or H
@@ -1466,6 +1568,8 @@ def _mixed_step(params, cfg_tuple, cache_k, cache_v, pos, tokens,
     Br, Qr = tokens_r.shape
     with jax.named_scope("embed"):
         h = params[f"{name}_wte_table"][tokens_r]          # [Br, Qr, hd]
+        if mup is not None:
+            h = h * mup.embedding
         if blk.positions == "learned":
             wpe = params[f"{name}_wpe"]
             h = h + wpe[jnp.clip(posns_r, 0, wpe.shape[0] - 1)]
@@ -1520,17 +1624,29 @@ def _mixed_step(params, cfg_tuple, cache_k, cache_v, pos, tokens,
                 posns_r, live, lens, q_len, block_tables, attn, rows)
             h = _ffn_of_kind(params, us, blk, h, i, valid_r, moe_stats)
             continue
-        # the pool holds the attention layers alone (all of them, in
-        # their order, where the spec names no operators)
-        pi = blk.op_index(i)
+        # the pool holds the layers with an attention alone (all of
+        # them, in their order, where the spec names no operators)
+        pi = blk.op_index(i, "pool")
         with jax.named_scope("attn_qkv"):
             x = _norm(blk, params, f"{us}_ln1", h)
+        y_ssm = None
+        if blk.op_kind(i) == "attention+ssm":
+            # the state-space mixer reads the same normed rows; its
+            # output joins the attention's in the residual below
+            from .ssm_decode import ssm_mixer
+            y_ssm, state = ssm_mixer(params, us, blk, x, state,
+                                     blk.op_index(i, "state"), q_len, rows)
+        with jax.named_scope("attn_qkv"):
+            if mup is not None:
+                x = x * mup.attention_in
             q = _proj(params, f"{us}_attn_q", x, blk.bias).reshape(
                 Br, Qr, H, Dh)
             k = _proj(params, f"{us}_attn_k", x, blk.bias).reshape(
                 Br, Qr, Hkv, Dh)
             v = _proj(params, f"{us}_attn_v", x, blk.bias).reshape(
                 Br, Qr, Hkv, Dh)
+            if mup is not None:
+                k = k * mup.key
             if blk.qk_norm:
                 q = _rms(q, params[f"{us}_attn_q_norm_scale"], blk.norm_eps)
                 k = _rms(k, params[f"{us}_attn_k_norm_scale"], blk.norm_eps)
@@ -1621,7 +1737,10 @@ def _mixed_step(params, cfg_tuple, cache_k, cache_v, pos, tokens,
             if rows is not None:
                 o = rows.pack(o)
         with jax.named_scope("attn_out"):
-            h = h + _proj(params, f"{us}_attn_proj", o, blk.bias)
+            o = _proj(params, f"{us}_attn_proj", o, blk.bias)
+            h = h + (o if mup is None else o * mup.attention_out)
+        if y_ssm is not None:
+            h = h + y_ssm
         h = _ffn_of_kind(params, us, blk, h, i, valid_r, moe_stats, moe)
     logits = _window_logits(params, name, h, first_row, window, blk, rows)
     return logits, cache_k, cache_v, state
@@ -1661,9 +1780,10 @@ def _serve_mixed_paged(params, cfg_tuple, cache_k, cache_v, tables,
     """``_serve_mixed`` over the block-table paged pool (``q_len`` 0
     marks inert slots, whose writes route to scratch block 0 and whose
     window is empty).  ``has_fresh`` (static) marks waves carrying
-    prompt-chunk slots — see ``_mixed_step``.  ``state`` is the conv
-    layers' state of a block spec that has any (donated like the pool,
-    returned LAST); every other spec passes none and gets none back."""
+    prompt-chunk slots — see ``_mixed_step``.  ``state`` is the slot
+    state of a block spec that keeps any (an array, or a tuple of them:
+    the manager's set, donated like the pool, returned LAST); every
+    other spec passes none and gets none back."""
     moe_on = _moe_active(cfg_tuple)
     routed = _block_of(cfg_tuple).routed
     sd = {} if moe_on or routed is not None else None
@@ -1790,7 +1910,7 @@ def teacher_forced_logits(params, config, seq, kv_fake_quant=False,
     params = {k: _prep_param(v) for k, v in params.items()
               if k.startswith(name + "_")}
     L, H = c.num_hidden_layers, c.num_attention_heads
-    Dh = c.hidden_size // H
+    Dh = head_dim_of(c)
     seq = jnp.asarray(seq, jnp.int32)
     P = seq.shape[0]
     hdim = H * Dh
@@ -2016,7 +2136,7 @@ def generate_fast(params, config, prompts, num_tokens, temperature=0.0,
     if total > S_max:
         raise ValueError(f"prompt + num_tokens = {total} exceeds "
                          f"max_position_embeddings {S_max}")
-    Dh = c.hidden_size // c.num_attention_heads
+    Dh = head_dim_of(c)
     cfg_tuple = (name, c.num_hidden_layers, c.num_attention_heads,
                  Dh, S_max)
     from .moe_decode import moe_spec_of

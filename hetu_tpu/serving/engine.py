@@ -35,7 +35,7 @@ from .. import envvars, telemetry
 from ..telemetry import flight
 from ..telemetry import slo as slo_mod
 from ..models.gpt_decode import (
-    GPT2_BLOCK, block_spec_of, check_block_spec,
+    GPT2_BLOCK, block_spec_of, check_block_spec, head_dim_of,
     _infer_name, _prep_param, _pow2, _resolve_fast, resolve_draft_layers,
     resolve_spec_k, serve_mixed_fn, serve_mixed_paged_fn,
     serve_prefill_fn, spec_propose_fn, wave_rows,
@@ -179,7 +179,7 @@ class ServingEngine:
         # (analysis/integration.py; no-op when validation is off)
         from ..analysis import validate_serving
         validate_serving(self.params, c, self._name)
-        Dh = c.hidden_size // c.num_attention_heads
+        Dh = head_dim_of(c)
         want = int(max_seq_len or c.max_position_embeddings)
         cdtype = self.params[f"{self._name}_wte_table"].dtype
         # the block the mixed wave runs (GPT-2's unless the config
@@ -213,19 +213,19 @@ class ServingEngine:
         blk = self.block_spec
         latent = blk.latent
         L = c.num_hidden_layers
-        # layers that carry slot-indexed state beside the pool
-        n_state = blk.op_layers(L, "conv")
         if self.paged:
             self.kv = PagedKVManager(
-                layers=L - n_state,
+                # the pool holds the layers with an attention; the
+                # layers with a conv or a state-space mixer keep slot
+                # state beside it (a layer may do both)
+                layers=blk.op_layers(L, "pool"),
                 heads=blk.kv_heads or c.num_attention_heads,
                 head_dim=Dh, slots=slots, max_seq_len=want,
                 pos_cap=c.max_position_embeddings, dtype=kv_dtype,
                 block=block, pool_blocks=pool_blocks,
                 prefix_share=prefix_share,
                 row_shape=(latent.row_width,) if latent else None,
-                state_shape=(n_state, blk.conv_kernel - 1, c.hidden_size)
-                if n_state else None)
+                state_shapes=blk.state_shapes(L, c.hidden_size))
             chunk = (prefill_chunk if prefill_chunk is not None
                      else envvars.get_int("HETU_KV_CHUNK"))
             self.chunk = max(int(chunk or 0), 0)
@@ -253,6 +253,10 @@ class ServingEngine:
             self.cfg_tuple = self.cfg_tuple + (self.block_spec,)
             self._routed_layers = self.block_spec.routed_layers(
                 c.num_hidden_layers)
+        # layers with a state-space mixer: their waves count
+        # ``serve.ssm.*`` (``ServingMetrics.record_ssm``)
+        self._ssm_layers = self.block_spec.op_layers(
+            c.num_hidden_layers, "attention+ssm")
         if self.moe is not None:
             self.cfg_tuple = self.cfg_tuple + (self.moe,)
             E = self.moe.num_experts
@@ -476,22 +480,39 @@ class ServingEngine:
                 "load": [int(x) for x in load],
                 "drop": [int(x) for x in drop]}
 
-    def _routed_record(self, wave, routed_out):
-        """A routed wave's counters (``serve.moe.*``, ``serve.attn.*``)
-        and its ``record_step`` payload.  Load and experts touched come
-        out of the compiled step; rows, context tokens and score pairs
-        are the wave descriptor's own arithmetic: slot b's ``q_len``
-        rows at positions ``pos .. pos + q_len - 1`` see
-        ``pos + j + 1`` positions each, and the slot holds
-        ``pos + q_len`` positions after the wave's writes."""
-        load = np.asarray(routed_out[0], np.int64)
-        touched = int(routed_out[1])
+    def _wave_record(self, wave):
+        """Every wave's attention counters (``serve.attn.*``) and, on an
+        engine with state-space layers, its ``serve.ssm.*`` ones and
+        their ``record_step`` payload: the wave descriptor's own
+        arithmetic.  Slot b's ``q_len`` rows at positions ``pos .. pos
+        + q_len - 1`` see ``pos + j + 1`` positions each, and the slot
+        holds ``pos + q_len`` positions after the wave's writes; a live
+        slot's state moves once a state-space layer."""
         ql = wave["q_len"].astype(np.int64)
         pos = wave["pos"].astype(np.int64)
-        rows = int(ql.sum())
         ctx = int(np.where(ql > 0, pos + ql, 0).sum())
         pairs = int((ql * pos + ql * (ql + 1) // 2).sum())
-        self.metrics.record_routed(load, touched, ctx, pairs)
+        self.metrics.record_attention(ctx, pairs)
+        if not self._ssm_layers:
+            return None
+        # row pairs (i, j <= i) inside the chunks of the chunked form: a
+        # q-block of one row takes the plain step and has none
+        c = self.block_spec.ssm.chunk
+        wide = np.where(ql > 1, ql, 0)
+        full, rest = wide // c, wide % c
+        chunk_pairs = int((full * (c * (c + 1) // 2)
+                           + rest * (rest + 1) // 2).sum())
+        return self.metrics.record_ssm(int((ql > 0).sum()), int(ql.sum()),
+                                       chunk_pairs, self._ssm_layers)
+
+    def _routed_record(self, wave, routed_out):
+        """A routed wave's counters (``serve.moe.*``) and its
+        ``record_step`` payload.  Load and experts touched come out of
+        the compiled step; the rows are the wave descriptor's."""
+        load = np.asarray(routed_out[0], np.int64)
+        touched = int(routed_out[1])
+        rows = int(wave["q_len"].astype(np.int64).sum())
+        self.metrics.record_routed(load, touched)
         assignments = int(load.sum())
         mean = assignments / len(load)
         return {"tokens": rows, "routed": assignments, "dropped": 0,
@@ -894,6 +915,7 @@ class ServingEngine:
             after = np.array(after, np.uint32)
             moe_rec = (self._routed_record(wave, routed_out)
                        if routed_out is not None else None)
+            ssm_rec = self._wave_record(wave)
             self.metrics.record_wave(
                 rows_live, wave_rows(self.cfg_tuple, B, self.spec_k + 1,
                                      wave["q"], self.paged, bool(pre)),
@@ -1023,7 +1045,7 @@ class ServingEngine:
                 requests=wave_reqs, end_perf=t0 + dt, spec=spec,
                 mix={"q_prefill": q_pre, "q_verify": q_ver,
                      "q_decode": n_dec},
-                moe=moe_rec or self._moe_record())
+                moe=moe_rec or self._moe_record(), ssm=ssm_rec)
         root.set(live=len(live), q_prefill=q_pre, q_verify=q_ver,
                  q_decode=n_dec)
         return done

@@ -144,10 +144,11 @@ def _alloc_cache(shape, dtype, quantized):
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))
-def _zero_slot(state, slot):
-    """``state`` ``[layers, slots, rows, width]`` with slot ``slot``'s
-    rows zeroed, in place (donated; ``slot`` traced: one program)."""
-    return state.at[:, slot].set(0)
+def _zero_slot(states, slot):
+    """Every member of ``states`` (each ``[layers, slots, ...]``) with
+    slot ``slot``'s part zeroed, in place (donated; ``slot`` traced: one
+    program a set)."""
+    return tuple(s.at[:, slot].set(0) for s in states)
 
 
 def cache_nbytes(cache):
@@ -467,7 +468,7 @@ class PagedKVManager:
     def __init__(self, *, layers, heads, head_dim, slots, max_seq_len,
                  pos_cap=None, dtype=jnp.float32, bucket=True,
                  block=16, pool_blocks=None, prefix_share=None,
-                 row_shape=None, state_shape=None):
+                 row_shape=None, state_shape=None, state_shapes=None):
         if bucket:
             slots = round_up_pow2(slots)
             s = round_up_pow2(max_seq_len, floor=16)
@@ -494,16 +495,23 @@ class PagedKVManager:
         if self.n_blocks < 2:
             raise ValueError("pool needs at least 2 blocks "
                              "(scratch + one allocatable)")
-        # state beside the pool: ``state_shape`` = (layers that carry
-        # state, rows a slot, width) makes ``self.state`` ``[layers,
-        # slots, rows, width]``, indexed by SLOT, not by position (a
-        # short convolution's last inputs).  The same manager owns it:
-        # ``alloc`` zeroes a claimed slot's rows, the engine threads it
-        # through the donated step beside the pool.  It has no copy at a
-        # block boundary, so what would need one is refused by name: a
-        # shared prefix (explicit ``prefix_share=True`` raises; the
-        # default resolves to off), ``truncate`` and the wire.
-        self.stateful = state_shape is not None
+        # state beside the pool: a SET of arrays indexed by SLOT, not by
+        # position, each ``[layers that carry it, slots, ...]`` of any
+        # rank and dtype.  ``state_shapes`` names the members as
+        # ((layers, ...a slot's shape), dtype or None for the pool's);
+        # ``state_shape`` = (layers, rows a slot, width) is the
+        # one-member set in the pool's dtype (a short convolution's
+        # last inputs).  The same manager owns all of it: ``alloc``
+        # zeroes every member of a claimed slot, the engine threads the
+        # set through the donated step beside the pool.  It has no copy
+        # at a block boundary, so what would need one is refused by
+        # name: a shared prefix (explicit ``prefix_share=True`` raises;
+        # the default resolves to off), ``truncate`` and the wire.
+        if state_shape is not None and state_shapes is not None:
+            raise ValueError("PagedKVManager: state_shape OR state_shapes")
+        if state_shape is not None:
+            state_shapes = ((tuple(state_shape), None),)
+        self.stateful = bool(state_shapes)
         if self.stateful and prefix_share:
             raise ValueError(
                 "PagedKVManager: prefix_share with slot-indexed state: a "
@@ -542,13 +550,15 @@ class PagedKVManager:
             shape = (layers, self.n_blocks, self.block) + row
             self.cache_k = _alloc_cache(shape, dtype, self.quant)
             self.cache_v = _alloc_cache(shape, dtype, self.quant)
-        self.state = None
+        self.states = ()
         self.state_resets = 0
         if self.stateful:
-            n_state, rows, width = (int(v) for v in state_shape)
-            self.state = jnp.zeros((n_state, self.n_slots, rows, width),
-                                   dtype)
-            telemetry.set_gauge("serve.state.bytes", int(self.state.nbytes))
+            self.states = tuple(
+                jnp.zeros((int(shape[0]), self.n_slots)
+                          + tuple(int(v) for v in shape[1:]),
+                          dtype if member_dtype is None else member_dtype)
+                for shape, member_dtype in state_shapes)
+            telemetry.set_gauge("serve.state.bytes", self.state_bytes)
         self._free = list(range(1, self.n_blocks))   # 0 = scratch
         self.ref = np.zeros(self.n_blocks, np.int32)
         self.tables = np.zeros((self.n_slots, self.table_width), np.int32)
@@ -780,7 +790,7 @@ class PagedKVManager:
         self.total_allocs += 1
         if self.stateful:
             # a sequence starts with no history
-            self.state = _zero_slot(self.state, np.int32(slot))
+            self.states = _zero_slot(self.states, np.int32(slot))
             self.state_resets += 1
             telemetry.inc("serve.state.resets")
         if cached:
@@ -929,6 +939,23 @@ class PagedKVManager:
                 f"handoff payload is a K/V pair of heads); a latent "
                 f"pool neither exports nor imports blocks")
 
+    @property
+    def state(self):
+        """What the step is handed and hands back: None, the set's one
+        member, or the tuple of its members."""
+        if len(self.states) <= 1:
+            return self.states[0] if self.states else None
+        return self.states
+
+    @state.setter
+    def state(self, value):
+        self.states = (tuple(value) if isinstance(value, (tuple, list))
+                       else (value,))
+
+    @property
+    def state_bytes(self):
+        return int(sum(s.nbytes for s in self.states))
+
     def _refuse_state(self, what):
         if self.stateful:
             raise ValueError(
@@ -1059,6 +1086,6 @@ class PagedKVManager:
             "quant": self.quant or "off",
             "cache_bytes": self.cache_bytes,
             "latent": self.latent,
-            "state_bytes": int(self.state.nbytes) if self.stateful else 0,
+            "state_bytes": self.state_bytes,
             "state_resets": self.state_resets,
         }

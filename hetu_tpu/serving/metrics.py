@@ -207,6 +207,9 @@ class ServingMetrics(MetricsCore):
         self.moe_load = None
         self.attn_ctx_tokens = 0
         self.attn_score_pairs = 0
+        self.ssm_slot_steps = 0
+        self.ssm_rows = 0
+        self.ssm_chunk_pairs = 0
         self.wave_rows_live = 0
         self.wave_rows_computed = 0
         self.chunks_deferred = 0
@@ -232,30 +235,60 @@ class ServingMetrics(MetricsCore):
         if deferred:
             telemetry.inc("serve.wave.chunks_deferred", int(deferred))
 
-    def record_routed(self, load, touched, ctx_tokens, score_pairs):
-        """One wave of a dropless routed, latent engine: ``load`` [E]
-        (assignments an expert, summed over the routed layers),
-        ``touched`` (experts with load > 0, summed over them),
-        ``ctx_tokens`` (the live slots' filled lengths after the wave's
-        writes, once a wave) and ``score_pairs`` (the positions every
-        live row sees).  Running sums here (``snapshot(since=mark)``
-        windows them) and the counters ``serve.moe.assignments``,
-        ``serve.moe.experts_touched``, ``serve.attn.ctx_tokens``,
-        ``serve.attn.score_pairs`` and the gauge ``serve.moe.load_max``
-        (this wave's largest load) in ``telemetry``."""
+    def record_attention(self, ctx_tokens, score_pairs):
+        """One wave of any engine: ``ctx_tokens`` (the live slots'
+        filled lengths after the wave's writes, once a wave) and
+        ``score_pairs`` (the positions every live row sees).  Running
+        sums here (``snapshot(since=mark)`` windows them) and the
+        counters ``serve.attn.ctx_tokens`` and
+        ``serve.attn.score_pairs`` in ``telemetry``."""
+        self.attn_ctx_tokens += int(ctx_tokens)
+        self.attn_score_pairs += int(score_pairs)
+        telemetry.inc("serve.attn.ctx_tokens", int(ctx_tokens))
+        telemetry.inc("serve.attn.score_pairs", int(score_pairs))
+
+    def record_ssm(self, live_slots, rows, chunk_pairs, layers):
+        """One wave of an engine with ``layers`` state-space layers:
+        ``live_slots`` (slots with a row in the wave: each one's matrix
+        state is read and written once a layer), ``rows`` (the wave's
+        live rows) and ``chunk_pairs`` (the row pairs ``j <= i`` inside
+        the chunks of the q-blocks wider than one row: what the chunked
+        form multiplies out besides).  Running sums ``ssm_slot_steps``
+        (live slots x layers), ``ssm_rows`` and ``ssm_chunk_pairs``
+        (each x layers) here, the counters ``serve.ssm.slot_steps``,
+        ``serve.ssm.rows`` and ``serve.ssm.chunk_pairs`` in
+        ``telemetry``; returns the ``record_step`` payload whose
+        ``slot_steps == live_slots * layers`` ``hetu_trace --check``
+        holds a ``serve_step`` to."""
+        steps, rows = int(live_slots) * int(layers), int(rows) * int(layers)
+        pairs = int(chunk_pairs) * int(layers)
+        self.ssm_slot_steps += steps
+        self.ssm_rows += rows
+        self.ssm_chunk_pairs += pairs
+        telemetry.inc("serve.ssm.slot_steps", steps)
+        telemetry.inc("serve.ssm.rows", rows)
+        telemetry.inc("serve.ssm.chunk_pairs", pairs)
+        return {"slot_steps": steps, "rows": rows,
+                "live_slots": int(live_slots), "layers": int(layers)}
+
+    def record_routed(self, load, touched):
+        """One wave of a dropless routed engine: ``load`` [E]
+        (assignments an expert, summed over the routed layers) and
+        ``touched`` (experts with load > 0, summed over them).  Running
+        sums here (``snapshot(since=mark)`` windows them) and the
+        counters ``serve.moe.assignments``,
+        ``serve.moe.experts_touched`` and the gauge
+        ``serve.moe.load_max`` (this wave's largest load) in
+        ``telemetry``."""
         load = np.asarray(load, np.int64)
         assignments = int(load.sum())
         self.moe_assignments += assignments
         self.moe_experts_touched += int(touched)
         self.moe_load = (load.copy() if self.moe_load is None
                          else self.moe_load + load)
-        self.attn_ctx_tokens += int(ctx_tokens)
-        self.attn_score_pairs += int(score_pairs)
         telemetry.inc("serve.moe.assignments", assignments)
         telemetry.inc("serve.moe.experts_touched", int(touched))
         telemetry.set_gauge("serve.moe.load_max", int(load.max()))
-        telemetry.inc("serve.attn.ctx_tokens", int(ctx_tokens))
-        telemetry.inc("serve.attn.score_pairs", int(score_pairs))
 
     # ------------------------------------------------------------- #
     # lifecycle marks (the engine calls these at phase boundaries)
@@ -332,7 +365,8 @@ class ServingMetrics(MetricsCore):
 
     def record_step(self, live, slots, queue_depth, dt_s, new_tokens,
                     prefill_s=0.0, step=None, requests=None,
-                    end_perf=None, spec=None, mix=None, moe=None):
+                    end_perf=None, spec=None, mix=None, moe=None,
+                    ssm=None):
         """One fused decode step; ``prefill_s`` is the prefill wall time
         this scheduler iteration paid before decoding, so the per-step
         JSONL event attributes the phases separately (the masked vs
@@ -363,7 +397,12 @@ class ServingMetrics(MetricsCore):
         routing outcome — ``routed + dropped == tokens * k * layers``
         is the invariant hetu_trace --check enforces, ``imb`` and
         ``drop_rate`` feed hetu_top's expert columns.  Dense steps
-        carry no moe_* fields and the checker exempts them."""
+        carry no moe_* fields and the checker exempts them.
+
+        ``ssm`` (``record_ssm``'s {slot_steps, rows, live_slots, layers}
+        dict, engines with state-space layers only) stamps the wave's
+        state traffic: ``slot_steps == live_slots * layers`` is the
+        invariant hetu_trace --check enforces."""
         self._mark()
         self._slots = slots
         self.step_live.append(live)
@@ -397,6 +436,9 @@ class ServingMetrics(MetricsCore):
             fields["moe_imb"] = round(float(moe.get("imb", 0.0)), 4)
             fields["moe_drop_rate"] = round(
                 float(moe.get("drop_rate", 0.0)), 6)
+        if ssm is not None:
+            for k in ("slot_steps", "rows", "live_slots", "layers"):
+                fields[f"ssm_{k}"] = int(ssm.get(k, 0))
         self.event("serve_step", live=live, queue_depth=queue_depth,
                    slots=slots, new_tokens=int(new_tokens),
                    prefill_ms=round(prefill_s * 1e3, 3),
@@ -518,6 +560,7 @@ class ServingMetrics(MetricsCore):
                     "tokens_generated", "prefill_batched",
                     "moe_assignments", "moe_experts_touched",
                     "attn_ctx_tokens", "attn_score_pairs",
+                    "ssm_slot_steps", "ssm_rows", "ssm_chunk_pairs",
                     "wave_rows_live", "wave_rows_computed",
                     "chunks_deferred")
 
@@ -589,11 +632,14 @@ class ServingMetrics(MetricsCore):
                 "moe_load_max": int(load.max()),
                 "moe_load_imbalance": (float(load.max()) / mean
                                        if mean > 0 else None),
-                "attn_ctx_tokens": count("attn_ctx_tokens"),
-                "attn_score_pairs": count("attn_score_pairs"),
             }
         return {
             **routed,
+            "attn_ctx_tokens": count("attn_ctx_tokens"),
+            "attn_score_pairs": count("attn_score_pairs"),
+            "ssm_slot_steps": count("ssm_slot_steps"),
+            "ssm_rows": count("ssm_rows"),
+            "ssm_chunk_pairs": count("ssm_chunk_pairs"),
             "wave_rows_live": count("wave_rows_live"),
             "wave_rows_computed": count("wave_rows_computed"),
             "chunks_deferred": count("chunks_deferred"),

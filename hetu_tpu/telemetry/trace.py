@@ -640,6 +640,33 @@ def check_moe_attribution(events):
     return problems
 
 
+def check_ssm_attribution(events):
+    """The state-traffic rule (ISSUE 37): a ``serve_step`` record of an
+    engine with state-space layers carries ``ssm_slot_steps``, and it
+    must equal the wave's live slots times the state-space layers
+    (``ssm_live_slots`` x ``ssm_layers``): every live slot's matrix
+    state moves once a layer a wave, a dead slot's never.  Records
+    without ``ssm_slot_steps`` are exempt; one missing a companion
+    field is itself a violation.  Returns problem strings."""
+    problems = []
+    for e in events:
+        if e.get("event") != "serve_step" or "ssm_slot_steps" not in e:
+            continue
+        steps, slots, layers = (e.get(f"ssm_{k}") for k in (
+            "slot_steps", "live_slots", "layers"))
+        if not all(isinstance(v, int) for v in (steps, slots, layers)):
+            problems.append(
+                f"ssm-attribution: step {e.get('step')!r} carries "
+                f"ssm_slot_steps without integer ssm_live_slots and "
+                f"ssm_layers")
+        elif steps != slots * layers:
+            problems.append(
+                f"ssm-attribution: step {e.get('step')!r} counts {steps} "
+                f"slot steps but {slots} live slot(s) x {layers} "
+                f"state-space layer(s) = {slots * layers}")
+    return problems
+
+
 def check_span_nesting(events):
     """The span-nesting rule: a ``span`` record that names a ``parent``
     must find a span of that name on its own pid/thread whose interval
@@ -837,6 +864,8 @@ def main(argv=None):
         problems.extend(lockdep)
         moe = check_moe_attribution(events)
         problems.extend(moe)
+        ssm = check_ssm_attribution(events)
+        problems.extend(ssm)
         nesting = check_span_nesting(events)
         problems.extend(nesting)
         residue = check_lifecycle_residue(events)
@@ -855,6 +884,7 @@ def main(argv=None):
                           "tier_balance_violations": len(tier),
                           "lockdep_violations": len(lockdep),
                           "moe_attribution_violations": len(moe),
+                          "ssm_attribution_violations": len(ssm),
                           "span_nesting_violations": len(nesting),
                           "lifecycle_residue_violations":
                               len(residue)}))

@@ -300,7 +300,8 @@ def test_the_cell_reports_the_metric_and_its_files_are_there(name):
     if name in NEW_METRICS:
         assert entry["workloads"] == [CELL]
     else:
-        assert entry["workloads"][-1] == CELL       # appended
+        # appended; later cells are appended after it
+        assert CELL in entry["workloads"][1:]
     spec = bench_run.load_json(os.path.join(
         ROOT, "benchmarks", "metrics", name + ".json"))
     assert os.path.isfile(os.path.join(
@@ -312,10 +313,11 @@ def test_the_cell_is_one_chip_and_the_old_entries_stand():
     assert cell == dict(cell, config="lfm2-8b-a1b", traffic="rag-closed",
                         chips=1)
     assert len(cell["why"]) <= 200
-    assert [w["name"] for w in BENCH["workloads"]] == [
+    # later cells and configurations are appended after these
+    assert [w["name"] for w in BENCH["workloads"]][:4] == [
         "train-gpt2-medium-s1024", "serve-gpt2-xl-batch-closed",
         "serve-glm47flash-reason-closed", CELL]
-    assert [c["name"] for c in BENCH["configs"]][-1] == "lfm2-8b-a1b"
+    assert [c["name"] for c in BENCH["configs"]][3] == "lfm2-8b-a1b"
     assert BENCH["run_seconds"] == 51
     resolved = bench_run.resolve_cell(BENCH, CELL)
     assert {m["name"] for m in resolved["end_to_end"]} == {
