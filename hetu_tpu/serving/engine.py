@@ -16,6 +16,13 @@ small numpy arrays passed into each jitted call — admission and
 retirement are plain python between steps, no recompilation, no
 device<->host cache traffic.
 
+One wave ahead: a wave is LAUNCHED (admit, assemble, dispatch) by one
+``step()`` and LANDED (its tokens fetched and unpacked) by the next, and
+a full engine that nobody is about to leave launches wave t+1 before it
+lands wave t, so the device has its next program queued while the host
+unpacks (``ServingEngine.step``).  The sampled tokens and rng keys a
+wave run ahead needs stay on the device (``_hand_over``).
+
 Determinism: each request samples from its own seed-derived rng stream
 with its own traced temperature/top_k, so outputs are a pure function
 of the request — identical across arrival orders and slot assignments;
@@ -26,6 +33,7 @@ from __future__ import annotations
 
 import collections
 import time
+import types
 
 import numpy as np
 import jax
@@ -56,6 +64,20 @@ class QueueFull(RuntimeError):
 # recorder dumps once per storm so the black box captures the records
 # leading into sustained overload, not just the steady-state spam
 _STORM_REJECTS = 8
+
+
+@jax.jit
+def _hand_over(sampled, after, from_device, tokens, keys):
+    """Every launch's input tokens and rng keys, as the device holds
+    them.  A slot ``from_device`` marks decodes on the token the wave
+    still in flight samples for it (``sampled[s, 0]``, with the key
+    after it, ``after[s, 0]``): neither has reached the host.  Every
+    other slot takes the host's ``tokens`` row and ``keys`` row.  One
+    program a q-block bucket, the same whether a wave runs ahead or in
+    order, so that the wave programs take what they always took."""
+    tok0 = jnp.where(from_device, sampled[:, 0], tokens[:, 0])
+    return (tokens.at[:, 0].set(tok0),
+            jnp.where(from_device[:, None], after[:, 0], keys))
 
 
 class ServingEngine:
@@ -241,8 +263,9 @@ class ServingEngine:
         # rides the SAME compiled cores — the hashable MoESpec joins
         # the static cfg_tuple and every serve wrapper appends one
         # trailing (load, drop, tokens) stats element the scheduler
-        # strips + accounts below (_moe_take).  Dense configs leave
-        # self.moe None and nothing here changes. ---- #
+        # strips at launch and accounts at landing (_moe_record).
+        # Dense configs leave self.moe None and nothing here changes.
+        # ---- #
         from ..models.moe_decode import moe_spec_of
         self.moe = moe_spec_of(c)
         # the dropless router of a routed block spec: its wave hands
@@ -266,7 +289,6 @@ class ServingEngine:
             self.expert_drops = np.zeros(E, np.int64)
             self.moe_tokens = 0
             self._moe_layers = self.moe.moe_layers(c.num_hidden_layers)
-            self._moe_step = None   # per-step [load, drop, tokens]
         self.prefill_dispatches = 0   # waves that carried a prompt
         # q-block (a burst of k arrivals is ONE wave, not k dispatches)
         self.prefill_chunks = 0       # prompt q-blocks written (paged)
@@ -305,7 +327,22 @@ class ServingEngine:
         # the first at its first_token_at, the rest at the post-sync
         # stamp of the wave that emitted them
         self._tok_t = [None] * B
-        self._wave_end = 0.0
+        self._wave_end = 0.0     # the last landed wave's post-sync stamp
+        self._land_end = 0.0     # ... and when its unpack ended
+        # ---- one wave ahead (``step``) ---- #
+        # the wave launched and not landed yet; the Results of a wave
+        # that something other than ``step`` had to land
+        self._flying = None
+        self._held = []
+        self._launched = 0       # waves launched (``steps``: landed)
+        # tokens a slot's LAUNCHED waves emit (0: mid-prefill), so that
+        # the host knows by count who decodes next and who is about to
+        # leave; a speculative wave's count is known at landing
+        self._emitted = np.zeros(B, np.int64)
+        # the last launched wave's samples and keys, as ``_hand_over``
+        # takes them (host zeros until the first wave leaves some)
+        self._dev_sampled = np.zeros((B, self.spec_k + 1), np.int32)
+        self._dev_after = np.zeros((B, self.spec_k + 1, 2), np.uint32)
         # live weight sync (serving/weight_sync.py): the version the
         # current param dict is stamped with (None = unversioned) and
         # the per-slot ADMISSION version a retirement reports — the
@@ -318,7 +355,7 @@ class ServingEngine:
         self._prefill_off = np.zeros(B, np.int32)  # paged: next prompt
         self._prompt_arr = [None] * B              # position to prefill
         # admission order: a chunk wave takes its prompt chunks oldest
-        # admission first (see ``_mixed_wave``)
+        # admission first (see ``_launch``)
         self._admit_no = np.zeros(B, np.int64)
         self._admitted = 0
         self.steps = 0
@@ -401,7 +438,10 @@ class ServingEngine:
         before any buffer moves); dtypes follow the resident params so
         the KV cache dtype contract survives the swap.  Call only on a
         drained engine (the coordinator's job) — live slots would mix
-        versions mid-request."""
+        versions mid-request.  A wave in flight is landed first (a token
+        is stamped with the weights that made it); what it retires
+        comes out of the next ``step()``."""
+        self._settle()
         name = self._name
         new = {}
         for k, v in params.items():
@@ -430,44 +470,26 @@ class ServingEngine:
     # MoE accounting (models/moe_decode.py)
     # ------------------------------------------------------------- #
 
-    def _moe_take(self, out):
-        """Strip + account the trailing ``(load, drop, tokens)`` stats
-        element the serve wrappers append under a MoE ``cfg_tuple``.
-        Identity on dense engines, so every TARGET-cfg dispatch site
-        wraps its call unconditionally; draft dispatches stay unwrapped
-        (the draft spec appends nothing — it skips routing)."""
-        if self.moe is None:
-            return out
-        load, drop, tokens = out[-1]
-        load = np.asarray(load, np.int64)
-        drop = np.asarray(drop, np.int64)
-        tokens = int(tokens)
+    def _moe_record(self, stats):
+        """Account a capacity-routed wave's ``(load, drop, tokens)``, the
+        trailing element the serve wrappers append under a MoE
+        ``cfg_tuple`` (the launch strips it; the draft appends nothing,
+        it skips routing), fetched here at landing.  Returns the
+        ``record_step`` payload, None for a wave that routed nothing.
+        ``routed + dropped == tokens * k * layers`` is the hetu_trace
+        attribution invariant; ``imb`` (max/mean expert load) and
+        ``drop_rate`` are THE MoE health observables and land as gauges
+        for hetu_top."""
+        load = np.asarray(stats[0], np.int64)
+        drop = np.asarray(stats[1], np.int64)
+        tokens = int(stats[2])
         self.expert_load += load
         self.expert_drops += drop
         self.moe_tokens += tokens
-        if self._moe_step is None:
-            self._moe_step = [load.copy(), drop.copy(), tokens]
-        else:
-            self._moe_step[0] += load
-            self._moe_step[1] += drop
-            self._moe_step[2] += tokens
-        telemetry.inc("serve.expert_load", int(load.sum()))
-        telemetry.inc("serve.expert_drops", int(drop.sum()))
-        return out[:-1]
-
-    def _moe_record(self):
-        """Drain the per-step accumulator into a ``record_step``
-        payload (None on dense engines or MoE steps that routed
-        nothing).  ``routed + dropped == tokens * k * layers`` is the
-        hetu_trace attribution invariant; ``imb`` (max/mean expert
-        load) and ``drop_rate`` are THE MoE health observables and land
-        as gauges for hetu_top."""
-        if self.moe is None or self._moe_step is None:
-            return None
-        load, drop, tokens = self._moe_step
-        self._moe_step = None
         routed = int(load.sum())
         dropped = int(drop.sum())
+        telemetry.inc("serve.expert_load", routed)
+        telemetry.inc("serve.expert_drops", dropped)
         mean = float(load.mean())
         imb = float(load.max()) / mean if mean > 0 else 0.0
         total = routed + dropped
@@ -573,8 +595,10 @@ class ServingEngine:
 
     @property
     def pending(self):
-        """Requests not yet finished (queued + in slots)."""
-        return len(self._queue) + len(self.kv.live())
+        """Requests not yet handed back: queued, in slots (a slot is
+        live until its last wave has landed), or retired by a landing
+        outside ``step`` and held for the next one."""
+        return len(self._queue) + len(self.kv.live()) + len(self._held)
 
     @property
     def queue_depth(self):
@@ -585,10 +609,33 @@ class ServingEngine:
     # ------------------------------------------------------------- #
 
     def step(self):
-        """One scheduler iteration: admit into free slots, then ONE
-        mixed wave over every live slot (``_step_mixed``), retiring
-        finished sequences as their tokens land.  Returns the Results
-        that completed this iteration.
+        """One scheduler iteration (``_step_mixed``).  Returns the
+        Results that completed in it.
+
+        A wave is IN FLIGHT from the ``step()`` that launches it (admit
+        into free slots, assemble ONE mixed wave over every live slot,
+        dispatch) to the ``step()`` that lands it (fetches its tokens,
+        runs the stream callbacks, retires who is done).  So a Result
+        arrives one ``step()`` after its last wave was launched, and
+        between two calls the device works while the caller does.  At
+        most one wave is in flight when ``step()`` returns, and every
+        slot riding it still counts in ``pending``.
+
+        The rule.  With a wave in flight, ``step()`` launches the next
+        one BEFORE it lands that one (one wave ahead: the device never
+        waits for the host's unpack) only where that can delay nobody's
+        admission: every slot is occupied as ``step()`` is entered, no
+        request reaches its ``max_new_tokens`` in the wave in flight
+        (the host knows by count), and the engine does not speculate.
+        Otherwise it lands first; if that retired a request it returns
+        the Result at once, so that the caller can fill the slot before
+        the next wave is composed, and if it retired none it launches
+        the next wave and returns with it in flight.  An admission so
+        happens in exactly the wave an engine that never ran ahead
+        would have given it.  An ``eos_id`` ending cannot be foreseen:
+        the wave run ahead then carries one dead row.  Counted:
+        ``serve.wave.ahead`` and ``serve.wave.rows_dead_ahead``
+        (``snapshot()``: ``waves_ahead``, ``rows_dead_ahead``).
 
         An exception escaping the scheduler dumps the flight recorder
         (``$HETU_FLIGHT_LOG``) before propagating — the black box holds
@@ -649,6 +696,7 @@ class ServingEngine:
                 self._admit_no[slot] = self._admitted
                 self._admitted += 1
                 self._pos[slot] = 0
+                self._emitted[slot] = 0
                 self._tok[slot] = 0
                 self._temp[slot] = req.temperature
                 self._topk[slot] = req.top_k
@@ -723,9 +771,10 @@ class ServingEngine:
         return False
 
     def _finish_prefill(self, slot, tok0, key):
-        """Prompt fully written: the slot joins the decode wave (or
-        retires right here on max_new_tokens=1/instant EOS).  Registers
-        the prompt's blocks for prefix sharing."""
+        """The final chunk landed: the slot's first token is here (the
+        launch already put the slot among the decoding ones), or the
+        request retires right here on max_new_tokens=1/instant EOS.
+        Registers the prompt's blocks for prefix sharing."""
         req = self._reqs[slot]
         if self.spec_k:
             t_d = time.perf_counter()
@@ -734,8 +783,6 @@ class ServingEngine:
                                     time.perf_counter() - t_d)
         now = time.perf_counter()
         req.first_token_at = now
-        P = len(self._prompt_arr[slot])
-        self._pos[slot] = P
         self._tok[slot] = tok0
         self._keys[slot] = key
         self._gen[slot] = [tok0]
@@ -769,6 +816,7 @@ class ServingEngine:
             self._admit_no[slot] = self._admitted
             self._admitted += 1
             self._pos[slot] = 0
+            self._emitted[slot] = 0
             self._tok[slot] = 0
             self._temp[slot] = req.temperature
             self._topk[slot] = req.top_k
@@ -788,23 +836,77 @@ class ServingEngine:
         Every slot's write positions, attention masks, and rng splits
         are those of a sequential decode of its request alone.
 
-        Spans, a fixed number a wave whatever is live, all tagged
-        ``wave=``: ``serve.wave`` (root) holding ``serve.admit``,
-        ``serve.wave.draft`` (speculative engines only),
-        ``serve.wave.assemble`` (the descriptor and the block-table
-        copy), ``serve.wave.dispatch`` (the call into the jitted step
-        until it returns: enqueue time; a MoE engine also fetches its
-        routing counts here), ``serve.wave.sync`` (the host waits for
-        the device) and ``serve.wave.unpack``.  An iteration with
-        nothing live ends after ``serve.admit``."""
-        wave = self.steps + 1
-        with telemetry.span("serve.wave", wave=wave) as root:
-            return self._mixed_wave(root, wave)
+        A wave has two halves, in two iterations.  LAUNCH (``_launch``):
+        admit, (draft), assemble, dispatch, and the bookkeeping that
+        needs no token VALUE (positions, ``kv.advance``, the prompt
+        offsets, who decodes next).  LAND (``_land``): fetch the samples,
+        then everything that needs them (``_gen``, the stream callbacks,
+        ``register_prefix``, retirement, ``record_step``).  With wave t
+        in flight this iteration launches t+1 and then lands t where the
+        rule allows (``_may_run_ahead``); else it lands t first, returns
+        at once what that retired, and launches t+1 only if it retired
+        nobody.  With nothing in flight it launches and returns.
 
-    def _mixed_wave(self, root, wave_id):
-        done = []
+        Spans, a fixed number a WAVE whatever is live, each tagged with
+        the ``wave=`` it belongs to: ``serve.admit`` (holding
+        ``serve.kv_alloc``), ``serve.wave.draft`` (speculative engines
+        only), ``serve.wave.assemble`` (the descriptor and the
+        block-table copy) and ``serve.wave.dispatch`` (``_hand_over``
+        and the call into the jitted step until it returns: enqueue
+        time) at its launch; ``serve.wave.sync`` (the host waits for the
+        device and fetches samples, keys and routing counts) and
+        ``serve.wave.unpack`` at its landing.  One root ``serve.wave``
+        an iteration holds what it ran: the launch of one wave
+        (``launched=``) and the landing of the one before it
+        (``landed=``, with that wave's ``live=`` and ``q_*=``).  An
+        iteration with nothing live ends after ``serve.admit``."""
+        with telemetry.span("serve.wave") as root:
+            done, self._held = self._held, []
+            flying = self._flying
+            ahead = flying is not None and self._may_run_ahead(flying)
+            if flying is not None and not ahead:
+                done += self._land(flying, root)
+                if done:
+                    # the caller fills the slot before the next wave is
+                    # composed
+                    return done
+            self._launch(root, ahead)
+            if ahead:
+                done += self._land(flying, root)
+                if self._flying is not None and not self.kv.live():
+                    # everybody ended on ``eos_id``: the wave run ahead
+                    # carries dead rows alone
+                    done += self._land(self._flying, root)
+            return done
+
+    def _may_run_ahead(self, flying):
+        """THE rule: launch the next wave before landing ``flying`` only
+        where that can cost nobody a wave.  Every slot is occupied (so
+        admission has nothing to do: it never claims a slot with a wave
+        in flight, and sees every ``register_prefix`` and release the
+        in-order engine would have seen), no request reaches its
+        ``max_new_tokens`` in ``flying`` (known by count; its successor
+        would wait out the whole wave run ahead), and the engine does
+        not speculate (the next descriptor depends on how many drafts
+        were accepted).  A request that ends on ``eos_id`` cannot be
+        foreseen: it leaves one dead row in the wave run ahead
+        (``serve.wave.rows_dead_ahead``)."""
+        return not (self.spec_k or self.kv.free_slots or flying.ends)
+
+    def _settle(self):
+        """Land the wave in flight outside ``step`` (a weight swap); the
+        next ``step()`` hands out what it retired."""
+        if self._flying is not None:
+            with telemetry.span("serve.wave") as root:
+                self._held += self._land(self._flying, root)
+
+    def _launch(self, root, ahead):
+        """A wave's first half.  Leaves it in ``_flying`` (None where
+        nothing is live).  ``ahead``: another wave is in flight, and the
+        decoding slots' tokens and keys are still on the device."""
+        wave_id = self._launched + 1
         # admission claims slots and blocks (prefix sharing/COW, tier
-        # fetch, deferral, backpressure); prompts join THIS step's wave
+        # fetch, deferral, backpressure); prompts join THIS wave
         with telemetry.span("serve.admit", wave=wave_id):
             if self.paged:
                 self._admit_paged()
@@ -812,12 +914,11 @@ class ServingEngine:
                 self._admit_contiguous_mixed()
         live = self.kv.live()
         if not live:
-            return done
+            return
         self.peak_live = max(self.peak_live, len(live))
         B = self.kv.n_slots
-        pre = [s for s in live if self._gen[s] is None]
-        decoding = [s for s in live if self._gen[s] is not None]
-        wave_reqs = [self._reqs[s].request_id for s in live]
+        pre = [s for s in live if not self._emitted[s]]
+        decoding = [s for s in live if self._emitted[s]]
         t0 = time.perf_counter()
         # speculative draft rides AHEAD of the wave (mid-prefill slots'
         # rows are dead)
@@ -836,15 +937,15 @@ class ServingEngine:
             entries = {}
             qlen_v = {}
             for s in decoding:
+                # a wave run ahead overwrites the host's stale token
+                # with the device's (``_hand_over``)
+                toks = [int(self._tok[s])]
                 if k_cur:
                     rem = self._reqs[s].max_new_tokens - len(self._gen[s])
                     ql = min(k_cur + 1, rem,
                              self.kv.s_max - int(self._pos[s]))
-                    toks = ([int(self._tok[s])]
-                            + [int(t) for t in draft[s, :ql - 1]])
+                    toks += [int(t) for t in draft[s, :ql - 1]]
                     qlen_v[s] = ql
-                else:
-                    toks = [int(self._tok[s])]
                 entries[s] = (toks, int(self._pos[s]), 0, False)
             # every decoding slot's rows ride every wave; prompt chunks
             # enter WHOLE, oldest admission first, while the wave's live
@@ -883,8 +984,14 @@ class ServingEngine:
             pre = [s for s in pre if s in chunk_take]
             wave = assemble_mixed_wave(B, entries)
             tables = self.kv.tables.copy() if self.paged else None
-        routed_out = None
+            from_device = np.zeros(B, bool)
+            if ahead:
+                from_device[decoding] = True
+        routed_out = moe_stats = None
         with telemetry.span("serve.wave.dispatch", wave=wave_id):
+            tokens, keys = _hand_over(self._dev_sampled, self._dev_after,
+                                      from_device, wave["tokens"],
+                                      self._keys)
             if self.paged:
                 # a manager with state hands it through beside the pool
                 # and gets it back last
@@ -893,54 +1000,110 @@ class ServingEngine:
                 out = self._mixed(
                     self.params, self.cfg_tuple,
                     self.kv.cache_k, self.kv.cache_v,
-                    tables, wave["pos"], wave["tokens"],
+                    tables, wave["pos"], tokens,
                     wave["q_len"], wave["first_row"], wave["self_fresh"],
-                    self._temp, self._topk, self._keys,
+                    self._temp, self._topk, keys,
                     has_fresh=bool(pre), **stateful)
                 if stateful:
                     out, self.kv.state = out[:-1], out[-1]
                 if self.routed is not None:
                     out, routed_out = out[:-1], out[-1]
-                sampled, ck, cv, after = self._moe_take(out)
             else:
-                sampled, ck, cv, after = self._moe_take(self._mixed(
+                out = self._mixed(
                     self.params, self.cfg_tuple,
                     self.kv.cache_k, self.kv.cache_v,
-                    wave["pos"], wave["tokens"], wave["q_len"],
+                    wave["pos"], tokens, wave["q_len"],
                     wave["first_row"], wave["self_fresh"],
-                    self._temp, self._topk, self._keys))
+                    self._temp, self._topk, keys)
+            if self.moe is not None:
+                out, moe_stats = out[:-1], out[-1]
+            sampled, ck, cv, after = out
             self.kv.cache_k, self.kv.cache_v = ck, cv
+            self._dev_sampled, self._dev_after = sampled, after
+        # ---- what the host knows without a token's value ---- #
+        if pre:
+            self.prefill_dispatches += 1
+        for s in pre:
+            take, final = chunk_take[s]
+            if self.paged:
+                self.kv.advance(s, take)
+                self.prefill_chunks += 1
+                telemetry.inc("serve.prefill_chunks")
+            self._prefill_off[s] += take
+            if final:
+                self._pos[s] = len(self._prompt_arr[s])
+                self._emitted[s] = 1
+        if not k_cur:
+            # (a verify block's length is known when it lands)
+            for s in decoding:
+                self._pos[s] += 1
+                self._emitted[s] += 1
+                self.kv.advance(s)
+        reqs = {s: self._reqs[s] for s in live}
+        self._launched = wave_id
+        root.set(launched=wave_id)
+        self._flying = types.SimpleNamespace(
+            id=wave_id, t0=t0, ahead=ahead, reqs=reqs, live=live, pre=pre,
+            decoding=decoding, waiting=waiting, chunk_take=chunk_take,
+            entries=entries, qlen_v=qlen_v, k_cur=k_cur, wave=wave,
+            rows_live=rows_live,
+            rows_computed=wave_rows(self.cfg_tuple, B, self.spec_k + 1,
+                                    wave["q"], self.paged, bool(pre)),
+            sampled=sampled, after=after, routed_out=routed_out,
+            moe_stats=moe_stats,
+            ends=any(self._emitted[s] >= r.max_new_tokens
+                     for s, r in reqs.items()))
+
+    def _land(self, w, root):
+        """A wave's second half: fetch what ``w`` sampled, unpack it,
+        retire who is done.  Returns the Results.  ``_flying`` is then
+        the wave launched ahead of this landing, if any: a request that
+        retires here has a dead row in it."""
+        if self._flying is w:
+            self._flying = None
+        done = []
+        wave, wave_id = w.wave, w.id
+        # a request that ended while this wave was in flight (``eos_id``
+        # in the wave before it): its rows here are dead, dropped
+        dead = [s for s in w.live if self._reqs[s] is not w.reqs[s]]
+        pre, decoding = w.pre, w.decoding
+        if dead:
+            pre = [s for s in pre if s not in dead]
+            decoding = [s for s in decoding if s not in dead]
         with telemetry.span("serve.wave.sync", wave=wave_id):
-            sampled = np.asarray(sampled)
-            after = np.array(after, np.uint32)
-            moe_rec = (self._routed_record(wave, routed_out)
-                       if routed_out is not None else None)
+            sampled = np.asarray(w.sampled)
+            after = np.array(w.after, np.uint32)
+            moe_rec = None
+            if w.routed_out is not None:
+                moe_rec = self._routed_record(wave, w.routed_out)
+            elif w.moe_stats is not None:
+                moe_rec = self._moe_record(w.moe_stats)
             ssm_rec = self._wave_record(wave)
             self.metrics.record_wave(
-                rows_live, wave_rows(self.cfg_tuple, B, self.spec_k + 1,
-                                     wave["q"], self.paged, bool(pre)),
-                len(waiting))
-        dt = time.perf_counter() - t0
-        self._wave_end = t0 + dt
+                w.rows_live, w.rows_computed, len(w.waiting),
+                ahead=w.ahead,
+                rows_dead=int(wave["q_len"][dead].sum()) if dead else 0)
+        # what the wave ADDED: its landing less the later of its own
+        # launch's start and the landing before it.  In order that is
+        # launch to landing; run ahead it is the wave's period.
+        now = time.perf_counter()
+        dt = now - max(w.t0, self._wave_end)
+        self._wave_end = now
+        # the lifecycle's wall, so that two waves never share any
+        t_from = max(w.t0, self._land_end)
+        k_cur = w.k_cur
         with telemetry.span("serve.wave.unpack", wave=wave_id):
             # ---- per-mode unpack: prefill q-blocks ---- #
             q_pre = 0
             pre_credit = {}
-            if pre:
-                self.prefill_dispatches += 1
             for s in pre:
                 req = self._reqs[s]
-                take, final = chunk_take[s]
+                take, final = w.chunk_take[s]
                 q_pre += take
-                if self.paged:
-                    self.kv.advance(s, take)
-                    self.prefill_chunks += 1
-                    telemetry.inc("serve.prefill_chunks")
-                self._prefill_off[s] += take
                 # the whole fused wave IS this request's prefill compute —
                 # there is no separate decode phase to stall behind, so
                 # the lifecycle's chunk_stall residue collapses to ~0.
-                # Credit the elapsed wall since dispatch, not just dt:
+                # Credit the elapsed wall since ``t_from``, not just dt:
                 # an earlier slot's _finish_prefill in this same loop can
                 # compile the draft prefill (~100s of ms once per process)
                 # and that wall sits inside THIS request's prefill span
@@ -949,7 +1112,7 @@ class ServingEngine:
                 # (A LATER slot's compile is covered by the end-of-wave
                 # top-up below — this eager credit exists so a request
                 # that retires AT prefill still carries its share.)
-                e = time.perf_counter() - t0
+                e = time.perf_counter() - t_from
                 self.metrics.lc_prefill(req.request_id, e)
                 pre_credit[req.request_id] = e
                 if final:
@@ -968,8 +1131,8 @@ class ServingEngine:
             for s in decoding:
                 req = self._reqs[s]
                 if k_cur:
-                    ql = qlen_v[s]
-                    toks = entries[s][0]
+                    ql = w.qlen_v[s]
+                    toks = w.entries[s][0]
                     a = 0
                     while a < ql - 1 and sampled[s, a] == toks[a + 1]:
                         a += 1
@@ -988,6 +1151,7 @@ class ServingEngine:
                     self.kv.advance(s, ql)
                     self.kv.truncate(s, base + n_emit)
                     self._pos[s] = base + n_emit
+                    self._emitted[s] += n_emit
                     self._tok[s] = emit[-1]
                     self._keys[s] = after[s, n_emit - 1]
                     self._gen[s].extend(emit)
@@ -998,11 +1162,9 @@ class ServingEngine:
                 else:
                     t = int(sampled[s, 0])
                     n_dec += 1
-                    self._pos[s] += 1
                     self._tok[s] = t
                     self._keys[s] = after[s, 0]
                     self._gen[s].append(t)
-                    self.kv.advance(s)
                     if req.stream_cb:
                         req.stream_cb(req, t)
                     r = self._maybe_finish(s, t)
@@ -1016,13 +1178,13 @@ class ServingEngine:
                 # without this the difference surfaces as a phantom
                 # chunk_stall residue (lc_prefill no-ops for requests that
                 # already retired; _retire clamps over-credit to the wall)
-                t_wave = time.perf_counter() - t0
+                t_wave = time.perf_counter() - t_from
                 for rid, e in pre_credit.items():
                     if t_wave > e:
                         self.metrics.lc_prefill(rid, t_wave - e,
                                                 count=False)
                 # a chunk that waited this wave out stalled that long
-                for s in waiting:
+                for s in w.waiting:
                     self.metrics.lc_stall(self._reqs[s].request_id, t_wave)
             self.steps += 1
             spec = None
@@ -1036,18 +1198,23 @@ class ServingEngine:
                 self._adapt_k()
                 spec = {"k": k_cur, "proposed": wave_prop,
                         "accepted": wave_acc}
-            q_ver = sum(qlen_v.values())
+            q_ver = sum(w.qlen_v.values())
             q_tot = max(q_pre + q_ver + n_dec, 1)
+            n_live = len(w.live) - len(dead)
             self.metrics.record_step(
-                live=len(live), slots=B, queue_depth=len(self._queue),
+                live=n_live, slots=self.kv.n_slots,
+                queue_depth=len(self._queue),
                 dt_s=dt, new_tokens=wave_emit if k_cur else n_dec,
                 prefill_s=dt * q_pre / q_tot, step=self.steps,
-                requests=wave_reqs, end_perf=t0 + dt, spec=spec,
+                requests=[w.reqs[s].request_id for s in w.live
+                          if s not in dead],
+                end_perf=now, spec=spec,
                 mix={"q_prefill": q_pre, "q_verify": q_ver,
                      "q_decode": n_dec},
-                moe=moe_rec or self._moe_record(), ssm=ssm_rec)
-        root.set(live=len(live), q_prefill=q_pre, q_verify=q_ver,
-                 q_decode=n_dec)
+                moe=moe_rec, ssm=ssm_rec)
+        self._land_end = time.perf_counter()
+        root.set(landed=wave_id, live=n_live, q_prefill=q_pre,
+                 q_verify=q_ver, q_decode=n_dec)
         return done
 
     # ------------------------------------------------------------- #
@@ -1112,7 +1279,10 @@ class ServingEngine:
 
     def run(self, requests=()):
         """Submit ``requests`` then step until everything (including
-        already-pending work) drains; returns {request_id: Result}."""
+        already-pending work) drains; returns {request_id: Result}.  A
+        slot stays live, and counts in ``pending``, until the wave that
+        carries its last token has LANDED, so the loop ends with
+        nothing in flight."""
         for r in requests:
             self.submit(r)
         out = {}
@@ -1166,6 +1336,13 @@ class ServingEngine:
             request_id=req.request_id, ttft_ms=res.ttft_s * 1e3,
             tok_s=((n - 1) / decode_s
                    if n > 1 and decode_s > 0 else None))
+        if self._flying is not None and slot in self._flying.reqs:
+            # ended on ``eos_id`` with the next wave already launched:
+            # its row there is dead (written past the request's end,
+            # inside the span reserved for it), and the slot's length is
+            # again what has landed
+            self.kv.advance(
+                slot, -int(self._flying.wave["q_len"][slot]))
         if self.retire_hook is not None:
             # last look at the LIVE slot (the router's KV-handoff
             # export rides this) — release frees the blocks next

@@ -26,6 +26,7 @@ not by the number of distinct deployment configs.
 
 from __future__ import annotations
 
+import collections
 import functools
 
 import numpy as np
@@ -462,7 +463,11 @@ class PagedKVManager:
     request must overwrite (the prefix ends mid-block) is COPY-ON-WRITE
     forked at admission.  Retirement decrements refcounts and returns a
     block to the free list only at zero; registered prefixes are
-    LRU-evicted when the pool runs short.
+    LRU-evicted when the pool runs short.  Both sides of that cost what
+    they touch, not the size of the cache: a lookup reads the entries
+    under the prompt's first block (``_by_head``), an eviction pops the
+    least recently used end of ``_prefix`` (an admission into a full
+    pool of ten thousand entries was 150 ms of scans, the device idle).
     """
 
     def __init__(self, *, layers, heads, head_dim, slots, max_seq_len,
@@ -566,7 +571,15 @@ class PagedKVManager:
         self.lengths = np.zeros(self.n_slots, np.int32)
         self.owner = [None] * self.n_slots
         self._free_slots = list(range(self.n_slots))
-        self._prefix = {}                            # tokens -> entry
+        # tokens -> entry, least recently used FIRST (a use moves an
+        # entry to the end: ``_touch``), so eviction pops from the front
+        # and costs what it frees, not a scan of the cache a victim
+        self._prefix = collections.OrderedDict()
+        # head -> {tokens: entry}: the entries under their first block
+        # of tokens (an entry shorter than a block under all of its
+        # tokens), so a lookup reads the prompts that start like this
+        # one, not every registered prefix
+        self._by_head = {}
         self._clock = 0
         self.total_allocs = 0
         self.cow_copies = 0
@@ -655,14 +668,20 @@ class PagedKVManager:
             return None, 0
         p = tuple(int(t) for t in prompt)
         best, best_len = None, 0
-        for key, e in self._prefix.items():
-            if e.length <= len(p) - 1 and e.length > best_len \
-                    and key == p[:e.length]:
-                best, best_len = e, e.length
+        for n in range(1, min(self.block, len(p)) + 1):
+            for key, e in self._by_head.get(p[:n], {}).items():
+                if e.length <= len(p) - 1 and e.length > best_len \
+                        and key == p[:e.length]:
+                    best, best_len = e, e.length
         if best is not None:
-            self._clock += 1
-            best.used = self._clock
+            self._touch(best)
         return best, best_len
+
+    def _touch(self, entry):
+        """``entry`` was just used: the last the eviction reaches."""
+        self._clock += 1
+        entry.used = self._clock
+        self._prefix.move_to_end(entry.tokens)
 
     def register_prefix(self, prompt, slot):
         """Register ``slot``'s prompt blocks for future sharing (called
@@ -682,8 +701,7 @@ class PagedKVManager:
         for n in sorted(cuts):
             key = p[:n]
             if key in self._prefix:
-                self._clock += 1
-                self._prefix[key].used = self._clock
+                self._touch(self._prefix[key])
                 if self.on_prefix_register is not None:
                     # re-registration refreshes the directory's
                     # last-use stamp (TTL staleness tracks real use)
@@ -696,19 +714,21 @@ class PagedKVManager:
             self._clock += 1
             e = _PrefixEntry(key, blocks, n, self._clock)
             self._prefix[key] = e
+            self._by_head.setdefault(key[:self.block], {})[key] = e
             if self.on_prefix_register is not None:
                 self.on_prefix_register(key, e)
         self._gauges()
 
     def _evict_for(self, need, keep=None):
         """LRU-drop registered prefixes until ``need`` blocks are free
-        (blocks still referenced by live requests stay allocated)."""
-        while len(self._free) < need and self._prefix:
-            candidates = [(e.used, k) for k, e in self._prefix.items()
-                          if e is not keep]
-            if not candidates:
+        (blocks still referenced by live requests stay allocated):
+        ``_prefix`` from its front, ``keep`` passed over."""
+        while len(self._free) < need:
+            # the least recently used entry but ``keep``
+            key = next((k for k, e in self._prefix.items()
+                        if e is not keep), None)
+            if key is None:
                 break
-            _, key = min(candidates)
             if self.on_prefix_spill is not None:
                 # eviction-to-tier: serialize the doomed prefix while
                 # its blocks are still resident (export_prefix is a
@@ -723,6 +743,10 @@ class PagedKVManager:
                     self.spills += 1
                     telemetry.inc("serve.prefix_spills")
             e = self._prefix.pop(key)
+            head = self._by_head[key[:self.block]]
+            del head[key]
+            if not head:
+                del self._by_head[key[:self.block]]
             for b in e.blocks:
                 self.ref[b] -= 1
                 if self.ref[b] == 0:

@@ -213,27 +213,43 @@ class ServingMetrics(MetricsCore):
         self.wave_rows_live = 0
         self.wave_rows_computed = 0
         self.chunks_deferred = 0
+        self.waves_ahead = 0
+        self.rows_dead_ahead = 0
 
     def _make_lc(self, t_submit):
         return _Lifecycle(t_submit)
 
-    def record_wave(self, rows_live, rows_computed, deferred):
-        """One wave of any engine: ``rows_live`` (the q-blocks' live
-        rows), ``rows_computed`` (the rows the wave's row-wise operators
-        ran over, ``gpt_decode.wave_rows``: a chunk wave's packed rows,
-        else slots x the padded q-block; live over computed is the
-        packing's hit share) and ``deferred`` (prompt chunks that waited
-        this wave out because the wave's rows were taken).  Running sums
-        here (``snapshot(since=mark)`` windows them) and the counters
+    def record_wave(self, rows_live, rows_computed, deferred,
+                    ahead=False, rows_dead=0):
+        """One landed wave of any engine: ``rows_live`` (the q-blocks'
+        live rows), ``rows_computed`` (the rows the wave's row-wise
+        operators ran over, ``gpt_decode.wave_rows``: a chunk wave's
+        packed rows, else slots x the padded q-block; live over computed
+        is the packing's hit share), ``deferred`` (prompt chunks that
+        waited this wave out because the wave's rows were taken),
+        ``ahead`` (the wave was launched while the one before it was
+        still in flight; over ``steps`` that is how often the engine
+        runs one wave ahead) and ``rows_dead`` (of ``rows_live``, the
+        rows computed for a request that had ended by the time they
+        landed: an ``eos_id`` the wave before this one emitted).
+        Running sums here (``snapshot(since=mark)`` windows them:
+        ``waves_ahead``, ``rows_dead_ahead``) and the counters
         ``serve.wave.rows_live``, ``serve.wave.rows_computed``,
-        ``serve.wave.chunks_deferred`` in ``telemetry``."""
+        ``serve.wave.chunks_deferred``, ``serve.wave.ahead``,
+        ``serve.wave.rows_dead_ahead`` in ``telemetry``."""
         self.wave_rows_live += int(rows_live)
         self.wave_rows_computed += int(rows_computed)
         self.chunks_deferred += int(deferred)
+        self.waves_ahead += bool(ahead)
+        self.rows_dead_ahead += int(rows_dead)
         telemetry.inc("serve.wave.rows_live", int(rows_live))
         telemetry.inc("serve.wave.rows_computed", int(rows_computed))
         if deferred:
             telemetry.inc("serve.wave.chunks_deferred", int(deferred))
+        if ahead:
+            telemetry.inc("serve.wave.ahead")
+        if rows_dead:
+            telemetry.inc("serve.wave.rows_dead_ahead", int(rows_dead))
 
     def record_attention(self, ctx_tokens, score_pairs):
         """One wave of any engine: ``ctx_tokens`` (the live slots'
@@ -562,7 +578,7 @@ class ServingMetrics(MetricsCore):
                     "attn_ctx_tokens", "attn_score_pairs",
                     "ssm_slot_steps", "ssm_rows", "ssm_chunk_pairs",
                     "wave_rows_live", "wave_rows_computed",
-                    "chunks_deferred")
+                    "chunks_deferred", "waves_ahead", "rows_dead_ahead")
 
     def mark(self):
         """A position in this engine's history for ``snapshot(since=)``:
@@ -643,6 +659,8 @@ class ServingMetrics(MetricsCore):
             "wave_rows_live": count("wave_rows_live"),
             "wave_rows_computed": count("wave_rows_computed"),
             "chunks_deferred": count("chunks_deferred"),
+            "waves_ahead": count("waves_ahead"),
+            "rows_dead_ahead": count("rows_dead_ahead"),
             "requests_submitted": count("submitted"),
             "requests_rejected": count("rejected"),
             "requests_finished": count("finished"),

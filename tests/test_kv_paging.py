@@ -159,6 +159,60 @@ class TestPagedAllocator:
 
 
 @pytest.mark.smoke
+class TestPrefixIndex:
+    """The prefix cache reads the prompts that start like this one and
+    evicts from the least recently used end (ISSUE 38): what it finds
+    and what it evicts are what a scan of every entry finds and evicts."""
+
+    @staticmethod
+    def _scan(kv, prompt):
+        p = tuple(int(t) for t in prompt)
+        best = None
+        for key, e in kv._prefix.items():
+            if e.length <= len(p) - 1 and key == p[:e.length] \
+                    and (best is None or e.length > best.length):
+                best = e
+        return best
+
+    @pytest.mark.parametrize("seed", [0, 1, 2])
+    def test_lookup_and_eviction_equal_a_scan_of_every_entry(self, seed):
+        kv = _mgr(slots=4, max_seq_len=64, block=4, pool_blocks=40,
+                  prefix_share=True)
+        rng = np.random.default_rng(seed)
+        # a few system prompts, some shorter than a block, extended,
+        # cut and repeated: buckets of one entry and of many
+        heads = [rng.integers(1, 9, n).tolist() for n in (2, 3, 9, 14, 23)]
+        evicted = []
+        kv.on_prefix_evict = evicted.append
+        live = []
+        for i in range(120):
+            head = heads[int(rng.integers(len(heads)))]
+            prompt = (head[:int(rng.integers(1, len(head) + 1))]
+                      + rng.integers(1, 9, int(rng.integers(0, 12))).tolist())
+            want = self._scan(kv, prompt)
+            got, n = kv.match_prefix(prompt)
+            assert got is want and n == (want.length if want else 0)
+            if len(live) == 4 or (live and rng.random() < 0.3):
+                kv.release(live.pop(int(rng.integers(len(live)))))
+            order = [e.used for e in kv._prefix.values()]
+            assert order == sorted(order)            # least recent first
+            before = [k for k in kv._prefix]
+            slot, cached = kv.alloc(f"r{i}", prompt, len(prompt) + 4)
+            if slot is None:
+                continue
+            # what an admission evicted is the front of the order, less
+            # the entry it attached
+            gone = [k for k in before if k not in kv._prefix]
+            assert gone == evicted[len(evicted) - len(gone):]
+            kept = [k for k in before[:len(gone) + 1] if k in kv._prefix]
+            assert len(kept) <= 1
+            kv.register_prefix(prompt, slot)
+            live.append(slot)
+            assert sorted(k for b in kv._by_head.values() for k in b) == \
+                sorted(kv._prefix)
+        assert evicted and kv.prefix_hits > 10
+
+
 class TestPoolRows:
     """ISSUE 31: the float pool pair is ``[L, N, block, W]`` rows of
     whole lane tiles; COW forks, the wire and the tiers move a
@@ -383,10 +437,11 @@ class TestPagedEngineParity:
                             prefix_share=False)
         short = Request(prompt=[7, 8], max_new_tokens=8)
         eng.submit(short)
-        eng.step()                                # short is decoding
+        eng.step()                                # short's prompt flies
         lng = Request(prompt=long_prompt, max_new_tokens=3)
         eng.submit(lng)
-        eng.step()                                # one chunk + decode
+        eng.step()                  # short decodes; one chunk + decode
+        eng.step()                  # ... has landed, the next flies
         slot = [s for s in eng.kv.live()
                 if eng._reqs[s] is lng][0]
         assert eng._gen[slot] is None             # still prefilling...

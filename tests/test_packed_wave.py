@@ -443,10 +443,15 @@ def test_chunks_over_the_capacity_wait_oldest_first(gpt):
     pool0 = None
     while eng.pending:
         off0 = eng._prefill_off.copy()
+        launched = eng._launched
         eng.step()
+        if eng._launched == launched:
+            # the step that hands back a retirement launches no wave
+            continue
         if not slot_of:
             slot_of = {eng._reqs[s].request_id: s for s in eng.kv.live()}
             order = [slot_of[f"b{i}"] for i in range(8)]
+        # the prompt offsets move when a wave is LAUNCHED
         trail.append([int(eng._prefill_off[s] - off0[s]) for s in order])
         if len(trail) == 1:
             # a deferred slot's pool is untouched: its first block still
@@ -501,6 +506,9 @@ def test_a_deferred_slots_state_is_untouched(hybrid):
     state = np.asarray(eng.kv.state)
     assert not state[:, waited].any()
     assert all(state[:, s].any() for s in went)
+    # counted when the wave lands: the next step
+    assert eng.metrics.snapshot()["chunks_deferred"] == 0
+    eng.step()
     assert eng.metrics.snapshot()["chunks_deferred"] == 4
     out = eng.run()
     assert len(out) == 8
@@ -532,8 +540,10 @@ def test_counters_count_what_happened(gpt):
     while eng.pending:
         eng.step()
         now = rows_of_waves(eng)
-        waves.append((now[0] - before[0], now[1] - before[1]))
+        if now != before:             # a wave landed in this step
+            waves.append((now[0] - before[0], now[1] - before[1]))
         before = now
+    assert len(waves) == eng.steps
     assert all(live <= computed for live, computed in waves)
     # chunk waves are 256 rows (q 64), decode waves 8 (q 1)
     assert {c for _, c in waves} == {256, 8}

@@ -160,9 +160,11 @@ class TestSpanPrimitive:
 # the serving wave
 # --------------------------------------------------------------------- #
 
-WAVE_SPANS = ["serve.admit", "serve.kv_alloc", "serve.wave",
-              "serve.wave.assemble", "serve.wave.dispatch",
-              "serve.wave.sync", "serve.wave.unpack"]
+# a wave's spans, tagged ``wave=``: its launch in one iteration, its
+# landing in the next (``serve.kv_alloc`` carries no tag and lies in
+# ``serve.admit``); and one root ``serve.wave`` an iteration
+LAUNCH_SPANS = ["serve.admit", "serve.wave.assemble", "serve.wave.dispatch"]
+LAND_SPANS = ["serve.wave.sync", "serve.wave.unpack"]
 
 
 def _engine(model, **kw):
@@ -175,31 +177,45 @@ class TestWaveSpans:
     @pytest.mark.parametrize("n_requests", [1, 3, 8])
     def test_one_wave_emits_each_span_once(self, model, merged_log,
                                            n_requests):
+        """A fixed number of spans a WAVE whatever is live, in order
+        (1 and 3 requests on 8 slots) and one wave ahead (8 on 8): three
+        at its launch, two at its landing one iteration later."""
         eng = _engine(model)
         for i in range(n_requests):
             eng.submit(Request(prompt=[3 + i, 5, 7], max_new_tokens=6,
                                seed=i))
-        for _ in range(3):
+        for _ in range(4):
             eng.step()
         spans = [r for r in _spans(merged_log)
                  if r["name"].startswith("serve.")]
         for wave in (1, 2, 3):
-            mine = sorted(r["name"] for r in spans
-                          if r.get("wave") == wave
-                          or (r["name"] == "serve.kv_alloc"
-                              and r["parent"] == "serve.admit"))
-            # kv_alloc carries no wave tag: one per wave, three in all
-            assert [n for n in mine if n != "serve.kv_alloc"] == \
-                [n for n in WAVE_SPANS if n != "serve.kv_alloc"]
-        assert sorted(r["name"] for r in spans) == sorted(WAVE_SPANS * 3)
+            assert sorted(r["name"] for r in spans
+                          if r.get("wave") == wave) == \
+                sorted(LAUNCH_SPANS + LAND_SPANS)
+        # the fourth is in flight: launched, not landed
+        assert sorted(r["name"] for r in spans if r.get("wave") == 4) == \
+            sorted(LAUNCH_SPANS)
+        assert sorted(r["name"] for r in spans) == sorted(
+            LAUNCH_SPANS * 4 + LAND_SPANS * 3
+            + ["serve.kv_alloc", "serve.wave"] * 4)
         roots = [r for r in spans if r["name"] == "serve.wave"]
-        assert [r["wave"] for r in roots] == [1, 2, 3]
-        assert roots[0]["live"] == n_requests
-        assert roots[0]["q_prefill"] == 3 * n_requests
-        assert roots[1]["q_decode"] == n_requests
+        assert [(r.get("launched"), r.get("landed")) for r in roots] == \
+            [(1, None), (2, 1), (3, 2), (4, 3)]
+        assert all("wave" not in r for r in roots)
+        assert roots[1]["live"] == n_requests
+        assert roots[1]["q_prefill"] == 3 * n_requests
+        assert roots[2]["q_decode"] == n_requests
         assert all(r["parent"] == "serve.wave" for r in spans
                    if r["name"].startswith("serve.wave.")
                    or r["name"] == "serve.admit")
+        assert all(r["parent"] == "serve.admit" for r in spans
+                   if r["name"] == "serve.kv_alloc")
+        # which half of the second iteration came first
+        at = {(r["name"], r.get("wave")): r["us"] for r in spans}
+        ahead = n_requests == 8
+        assert (at["serve.wave.dispatch", 2]
+                < at["serve.wave.sync", 1]) is ahead
+        assert eng.metrics.snapshot()["waves_ahead"] == (2 if ahead else 0)
 
     def test_a_step_with_nothing_live_stops_after_admission(self, model,
                                                             merged_log):
@@ -211,13 +227,12 @@ class TestWaveSpans:
     def test_speculative_wave_adds_the_draft_span(self, model, merged_log):
         eng = _engine(model, slots=4, spec=2)
         eng.submit(Request(prompt=[3, 5, 7], max_new_tokens=6))
-        eng.step()
-        eng.step()
+        for _ in range(3):
+            eng.step()
         second = [r["name"] for r in _spans(merged_log)
                   if r.get("wave") == 2]
         assert sorted(second) == sorted(
-            [n for n in WAVE_SPANS if n != "serve.kv_alloc"]
-            + ["serve.wave.draft"])
+            LAUNCH_SPANS + LAND_SPANS + ["serve.wave.draft"])
 
 
 # --------------------------------------------------------------------- #

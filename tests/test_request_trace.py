@@ -372,7 +372,7 @@ class TestFlightRecorder:
 
         def boom(*a, **k):
             raise RuntimeError("injected decode fault")
-        monkeypatch.setattr(eng, "_mixed_wave", boom)
+        monkeypatch.setattr(eng, "_launch", boom)
         with pytest.raises(RuntimeError, match="injected"):
             eng.step()
         recs = [json.loads(ln) for ln in open(flog) if ln.strip()]

@@ -345,15 +345,17 @@ class TestBatchedAdmission:
 
     def test_finish_at_prefill_frees_slot_same_step(self, model):
         """max_new_tokens=1 retires in the wave that wrote the prompt:
-        the slot is free when step() returns and the next step admits
-        the next queued request."""
+        the slot is free when the step() that lands that wave returns
+        and the next step admits the next queued request."""
         p, cfg = model
         eng = ServingEngine(p, cfg, slots=1, fast_path=True)
         for r in (Request(prompt=[7, 8, 9], max_new_tokens=1),
                   Request(prompt=[3, 4], max_new_tokens=1)):
             eng.submit(r)
+        assert eng.step() == []                   # launched
         first = eng.step()
         assert len(first) == 1 and not eng.kv.live()
+        assert eng.step() == [] and eng.kv.live()
         second = eng.step()
         assert len(second) == 1 and not eng.pending
         assert all(r.n_generated == 1 for r in first + second)

@@ -263,15 +263,18 @@ class TestSchedulerEdgeCases:
 
     def test_short_circuit_finish_at_prefill(self, model):
         """max_new_tokens=1 (or instant EOS) retires in the wave that
-        wrote its prompt — the slot is free when the step returns, and
-        the next step admits the request behind it."""
+        wrote its prompt — the slot is free when the step that lands
+        that wave returns (it launches nothing: the caller fills the
+        slot first), and the next step admits the request behind it."""
         p, cfg = model
         eng = ServingEngine(p, cfg, slots=1)
         a = eng.submit(Request(prompt=[7, 8, 9], max_new_tokens=1))
         b = eng.submit(Request(prompt=[3, 4], max_new_tokens=1))
+        assert eng.step() == [] and eng.kv.live()    # a's wave flies
         first = eng.step()
         assert [r.request_id for r in first] == [a.request_id]
         assert not eng.kv.live() and eng.queue_depth == 1
+        assert eng.step() == [] and eng.queue_depth == 0
         assert [r.request_id for r in eng.step()] == [b.request_id]
         assert all(r.n_generated == 1 for r in first)
         # two prompt waves and never a decode row
@@ -352,6 +355,6 @@ def test_default_is_one_path(model, monkeypatch, backend):
         "ragged" if backend == "tpu" else "masked")
     waves = []
     monkeypatch.setattr(
-        ServingEngine, "_mixed_wave",
-        lambda self, root, wave_id: waves.append(wave_id) or [])
-    assert eng.step() == [] and waves == [1]
+        ServingEngine, "_launch",
+        lambda self, root, ahead: waves.append(ahead))
+    assert eng.step() == [] and waves == [False]
