@@ -1516,7 +1516,8 @@ def _mixed_step(params, cfg_tuple, cache_k, cache_v, pos, tokens,
     with ``layer=i`` an index in its page copies (no ``cache_k[i]``).
 
     The device trace finds the wave's parts under a handful of
-    ``jax.named_scope`` names, the same for every layer: ``embed``,
+    ``jax.named_scope`` names, the same for every layer and every kind
+    of wave (each under the wave's own scope, below): ``embed``,
     ``attn_qkv``, ``kv_write``, ``attention``, ``attn_out``, ``mlp``,
     ``lm_head`` (the window's gather, final LN and head) and ``sample``,
     ``_spec_sample``'s own.
@@ -1542,7 +1543,28 @@ def _mixed_step(params, cfg_tuple, cache_k, cache_v, pos, tokens,
     state the layers with a conv or a mixer alone, each layer finding
     its own by ``blk.op_index``.  A spec's multipliers (``blk.mup``)
     scale the embedding, each mixer's input and output, the keys, the
-    FFN and the logits; a spec without them multiplies nowhere."""
+    FFN and the logits; a spec without them multiplies nowhere.
+
+    ONE outer scope names the wave's PROGRAM in the device trace, by the
+    static facts the body branches on: ``wave_chunk`` (``has_fresh``),
+    ``wave_verify`` (``window > 1``: every wave of an engine that
+    speculates that carries no chunk), ``wave_decode``.  It is the first
+    component of every operation's name stack below ``jit(...)``, so a
+    trace's reader tells a chunk wave's program from a decode wave's
+    (both are ``jit__serve_mixed_paged`` on the ``XLA Modules`` line).
+    Metadata alone: the lowered text does not change."""
+    scope = "wave_chunk" if has_fresh else \
+        "wave_verify" if window > 1 else "wave_decode"
+    with jax.named_scope(scope):
+        return _mixed_wave(params, cfg_tuple, cache_k, cache_v, pos, tokens,
+                           q_len, first_row, self_fresh, window, attn,
+                           block_tables, has_fresh, moe_stats, state)
+
+
+def _mixed_wave(params, cfg_tuple, cache_k, cache_v, pos, tokens, q_len,
+                first_row, self_fresh, window, attn, block_tables,
+                has_fresh, moe_stats, state):
+    """``_mixed_step``'s body, traced under the wave's own scope."""
     name, L, H, Dh, S_max = cfg_tuple[:5]
     moe = _moe_of(cfg_tuple)
     blk = _block_of(cfg_tuple)

@@ -478,8 +478,9 @@ class ServingEngine:
         ``record_step`` payload, None for a wave that routed nothing.
         ``routed + dropped == tokens * k * layers`` is the hetu_trace
         attribution invariant; ``imb`` (max/mean expert load) and
-        ``drop_rate`` are THE MoE health observables and land as gauges
-        for hetu_top."""
+        ``drop_rate`` are THE MoE health observables: ``imb`` lands as a
+        gauge for hetu_top, ``drop_rate`` rides the ``serve_step`` event
+        (``moe_drop_rate``), which is what hetu_top reads."""
         load = np.asarray(stats[0], np.int64)
         drop = np.asarray(stats[1], np.int64)
         tokens = int(stats[2])
@@ -495,7 +496,6 @@ class ServingEngine:
         total = routed + dropped
         rate = dropped / total if total else 0.0
         telemetry.set_gauge("serve.expert_imbalance", imb)
-        telemetry.set_gauge("serve.expert_drop_rate", rate)
         return {"tokens": tokens, "routed": routed, "dropped": dropped,
                 "k": self.moe.top_k, "layers": self._moe_layers,
                 "imb": imb, "drop_rate": rate,
@@ -663,7 +663,8 @@ class ServingEngine:
         starting with a registered prefix attaches those blocks
         refcounted and only prefills the tail."""
         admitted = []
-        with telemetry.span("serve.kv_alloc", queue=len(self._queue)):
+        with telemetry.span("serve.kv_alloc", wave=self._launched + 1,
+                            queue=len(self._queue)):
             while self._queue:
                 req = self._queue[0]
                 if self._defer_for_prefix(req):
@@ -847,23 +848,38 @@ class ServingEngine:
         at once what that retired, and launches t+1 only if it retired
         nobody.  With nothing in flight it launches and returns.
 
-        Spans, a fixed number a WAVE whatever is live, each tagged with
-        the ``wave=`` it belongs to: ``serve.admit`` (holding
-        ``serve.kv_alloc``), ``serve.wave.draft`` (speculative engines
-        only), ``serve.wave.assemble`` (the descriptor and the
-        block-table copy) and ``serve.wave.dispatch`` (``_hand_over``
-        and the call into the jitted step until it returns: enqueue
-        time) at its launch; ``serve.wave.sync`` (the host waits for the
-        device and fetches samples, keys and routing counts) and
-        ``serve.wave.unpack`` at its landing.  One root ``serve.wave``
-        an iteration holds what it ran: the launch of one wave
-        (``launched=``) and the landing of the one before it
-        (``landed=``, with that wave's ``live=`` and ``q_*=``).  An
-        iteration with nothing live ends after ``serve.admit``."""
-        with telemetry.span("serve.wave") as root:
+        Spans, a fixed number a WAVE whatever is live, each OPENED with
+        the ``wave=`` it belongs to (entry fields reach the profiler's
+        trace as the host event's stats, so a wave's launch and its
+        landing, in different roots, join by it): ``serve.admit(wave=,
+        queue=)`` (holding ``serve.kv_alloc(wave=, queue=)``),
+        ``serve.wave.draft`` (speculative engines only),
+        ``serve.wave.assemble(wave=, kind=)`` (the descriptor and the
+        block-table copy) and ``serve.wave.dispatch(wave=, kind=, q=,
+        ahead=)`` (``_hand_over`` and the call into the jitted step
+        until it returns: enqueue time) at its launch;
+        ``serve.wave.sync(wave=, kind=, ahead=)`` (the host waits for
+        the device and fetches samples, keys and routing counts) and
+        ``serve.wave.unpack(wave=, kind=)`` at its landing.  ``kind`` is
+        what the wave holds: ``chunk`` (a prompt chunk: the
+        ``has_fresh`` program over the paged pool), ``verify`` (a
+        speculating engine's draft blocks), else ``decode``; ``q`` its
+        q-block bucket; ``ahead`` whether another wave was in flight at
+        its launch.  One root ``serve.wave(order=)`` an iteration holds
+        what it ran: the launch of one wave (``launched=``) and the
+        landing of the one before it (``landed=``, with that wave's
+        ``live=`` and ``q_*=``; set when known, so JSONL-only).
+        ``order`` is the iteration's shape, known at entry: ``ahead``
+        (launches t+1, then lands t), ``inorder`` (lands t first, then
+        launches t+1 unless t retired somebody) or ``first`` (nothing
+        in flight: launches).  An iteration with nothing live ends
+        after ``serve.admit``."""
+        flying = self._flying
+        ahead = flying is not None and self._may_run_ahead(flying)
+        order = "first" if flying is None else \
+            "ahead" if ahead else "inorder"
+        with telemetry.span("serve.wave", order=order) as root:
             done, self._held = self._held, []
-            flying = self._flying
-            ahead = flying is not None and self._may_run_ahead(flying)
             if flying is not None and not ahead:
                 done += self._land(flying, root)
                 if done:
@@ -897,7 +913,7 @@ class ServingEngine:
         """Land the wave in flight outside ``step`` (a weight swap); the
         next ``step()`` hands out what it retired."""
         if self._flying is not None:
-            with telemetry.span("serve.wave") as root:
+            with telemetry.span("serve.wave", order="inorder") as root:
                 self._held += self._land(self._flying, root)
 
     def _launch(self, root, ahead):
@@ -907,7 +923,8 @@ class ServingEngine:
         wave_id = self._launched + 1
         # admission claims slots and blocks (prefix sharing/COW, tier
         # fetch, deferral, backpressure); prompts join THIS wave
-        with telemetry.span("serve.admit", wave=wave_id):
+        with telemetry.span("serve.admit", wave=wave_id,
+                            queue=len(self._queue)):
             if self.paged:
                 self._admit_paged()
             else:
@@ -922,18 +939,22 @@ class ServingEngine:
         t0 = time.perf_counter()
         # speculative draft rides AHEAD of the wave (mid-prefill slots'
         # rows are dead)
-        k_cur = 0
+        k_cur = self._spec_kcur if decoding and self.spec_k else 0
         draft = None
-        if decoding and self.spec_k:
+        # what the wave is, known before it is composed: a wave with a
+        # prompt to prefill carries a chunk (the oldest always fits:
+        # the ``has_fresh`` program), else a speculating engine's is a
+        # verify block, else plain decode
+        kind = "chunk" if pre else "verify" if k_cur else "decode"
+        if k_cur:
             with telemetry.span("serve.wave.draft", wave=wave_id):
-                k_cur = self._spec_kcur
                 draft, dck, dcv = self._propose(
                     self.params, self.cfg_tuple_draft,
                     self._draft_ck, self._draft_cv,
                     self._pos.copy(), self._tok.copy(), k=k_cur)
                 self._draft_ck, self._draft_cv = dck, dcv
                 draft = np.asarray(draft)
-        with telemetry.span("serve.wave.assemble", wave=wave_id):
+        with telemetry.span("serve.wave.assemble", wave=wave_id, kind=kind):
             entries = {}
             qlen_v = {}
             for s in decoding:
@@ -988,7 +1009,8 @@ class ServingEngine:
             if ahead:
                 from_device[decoding] = True
         routed_out = moe_stats = None
-        with telemetry.span("serve.wave.dispatch", wave=wave_id):
+        with telemetry.span("serve.wave.dispatch", wave=wave_id, kind=kind,
+                            q=int(wave["q"]), ahead=ahead):
             tokens, keys = _hand_over(self._dev_sampled, self._dev_after,
                                       from_device, wave["tokens"],
                                       self._keys)
@@ -1043,10 +1065,10 @@ class ServingEngine:
         self._launched = wave_id
         root.set(launched=wave_id)
         self._flying = types.SimpleNamespace(
-            id=wave_id, t0=t0, ahead=ahead, reqs=reqs, live=live, pre=pre,
-            decoding=decoding, waiting=waiting, chunk_take=chunk_take,
-            entries=entries, qlen_v=qlen_v, k_cur=k_cur, wave=wave,
-            rows_live=rows_live,
+            id=wave_id, kind=kind, t0=t0, ahead=ahead, reqs=reqs, live=live,
+            pre=pre, decoding=decoding, waiting=waiting,
+            chunk_take=chunk_take, entries=entries, qlen_v=qlen_v,
+            k_cur=k_cur, wave=wave, rows_live=rows_live,
             rows_computed=wave_rows(self.cfg_tuple, B, self.spec_k + 1,
                                     wave["q"], self.paged, bool(pre)),
             sampled=sampled, after=after, routed_out=routed_out,
@@ -1070,7 +1092,8 @@ class ServingEngine:
         if dead:
             pre = [s for s in pre if s not in dead]
             decoding = [s for s in decoding if s not in dead]
-        with telemetry.span("serve.wave.sync", wave=wave_id):
+        with telemetry.span("serve.wave.sync", wave=wave_id, kind=w.kind,
+                            ahead=w.ahead):
             sampled = np.asarray(w.sampled)
             after = np.array(w.after, np.uint32)
             moe_rec = None
@@ -1092,7 +1115,7 @@ class ServingEngine:
         # the lifecycle's wall, so that two waves never share any
         t_from = max(w.t0, self._land_end)
         k_cur = w.k_cur
-        with telemetry.span("serve.wave.unpack", wave=wave_id):
+        with telemetry.span("serve.wave.unpack", wave=wave_id, kind=w.kind):
             # ---- per-mode unpack: prefill q-blocks ---- #
             q_pre = 0
             pre_credit = {}

@@ -294,7 +294,6 @@ class KVCacheManager:
 
     def _gauges(self):
         telemetry.set_gauge("serve.occupancy", round(self.occupancy, 4))
-        telemetry.set_gauge("serve.slots_free", self.free_slots)
 
     def bucket_prompt(self, p):
         """Prompt-length bucket for the prefill scan: pow2, floor 8,
@@ -752,7 +751,6 @@ class PagedKVManager:
                 if self.ref[b] == 0:
                     self._free.append(b)
             self.evictions += 1
-            telemetry.inc("serve.prefix_evictions")
             if self.on_prefix_evict is not None:
                 self.on_prefix_evict(key)
 
@@ -1013,7 +1011,6 @@ class PagedKVManager:
         if count:
             self.exports += 1
             self.export_bytes += nbytes
-            telemetry.inc("serve.kv_export_bytes", nbytes)
         return {"layout": "paged", "block": self.block, "length": length,
                 "quant": kq, "k": k, "v": v,
                 "nbytes": nbytes, "raw_nbytes": raw}
@@ -1082,7 +1079,6 @@ class PagedKVManager:
         self.total_allocs += 1
         self.imports += 1
         self.import_bytes += int(payload["nbytes"])
-        telemetry.inc("serve.kv_import_bytes", int(payload["nbytes"]))
         if prompt is not None and len(prompt) <= length:
             self.register_prefix(prompt, slot)
         self._gauges()

@@ -221,7 +221,6 @@ class TieredKVStore:
     def _note_spill(self, h, payload, tier, replica):
         self.spills[tier] += 1
         self.spill_bytes += int(payload["nbytes"])
-        telemetry.inc(f"kvtier.spill_{tier}")
         self._event("kv_spill", prefix=h, tier=tier,
                     length=int(payload["length"]),
                     bytes=int(payload["nbytes"]),
@@ -238,7 +237,6 @@ class TieredKVStore:
             self._ring_bytes -= e.nbytes
             if self._ps_put(h, e.tokens, e.payload):
                 self.demotes += 1
-                telemetry.inc("kvtier.demotes")
                 if self.directory is not None:
                     self.directory.set_tier(e.tokens, "ps")
             else:
@@ -248,7 +246,6 @@ class TieredKVStore:
         """Terminal drop: the residency ends without a fetch (ring
         overflow past a dead/absent PS rung, corruption, close)."""
         self.drops[tier] += 1
-        telemetry.inc(f"kvtier.drop_{tier}")
         self._event("kv_tier_drop", prefix=h, tier=tier, reason=reason)
         if self.directory is not None:
             self.directory.clear_tier(tokens)
@@ -305,7 +302,6 @@ class TieredKVStore:
                     del self._ring[h]
                     self._ring_bytes -= e.nbytes
                     self.corruptions += 1
-                    telemetry.inc("kvtier.corruptions")
                     self._drop(h, toks, "host", "corrupt")
                     return None
                 del self._ring[h]
@@ -349,7 +345,6 @@ class TieredKVStore:
     def _note_fetch(self, h, payload, tier, replica):
         self.fetches[tier] += 1
         self.fetch_bytes += int(payload["nbytes"])
-        telemetry.inc(f"kvtier.fetch_{tier}")
         self._event("kv_fetch", prefix=h, tier=tier,
                     length=int(payload["length"]),
                     bytes=int(payload["nbytes"]),
@@ -361,7 +356,6 @@ class TieredKVStore:
         the residency already ended (honest — the warmth is gone), this
         only counts the degradation."""
         self.import_failed += 1
-        telemetry.inc("kvtier.import_failed")
 
     # ------------------------------------------------------------- #
     # PS rung

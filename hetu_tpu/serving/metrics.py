@@ -843,7 +843,6 @@ class EmbedServingMetrics(MetricsCore):
         self.step_dt.append(dt_s)
         self.step_rows.append(int(rows))
         self.pairs_scored += int(rows)
-        telemetry.observe("serve.pairs_per_wave", int(rows))
         fields = {}
         if step is not None:
             fields["step"] = step

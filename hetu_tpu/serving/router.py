@@ -466,7 +466,6 @@ class ServingRouter:
                           if routed.t_phase is not None
                           else routed.t_submit)) * 1e3
         rep.engine.metrics.lc_handoff(rid, hand_ms)
-        telemetry.inc("router.handoffs")
         self._event("kv_handoff_out", request=rid, replica=src,
                     to_replica=rep.index, bytes=nbytes, blocks=blocks,
                     quant=payload["quant"] or "off")
@@ -665,7 +664,6 @@ class ServingRouter:
                     # this placement pays the cold prefill (a handoff
                     # payload is exempt — it ships the warmth along)
                     self.affinity_prefix_misses += 1
-                    telemetry.inc("router.prefix_miss")
                 self._session_last[sid] = r.index
             if hint is not None:
                 if r.index == hint[0]:
@@ -960,7 +958,6 @@ class ServingRouter:
             routed.replica = None
             routed.next_at = 0.0
             self.requeued += 1
-            telemetry.inc("router.requeues")
             self._pending.append(routed)
         if self.directory is not None:
             self.directory.drop_replica(idx)
@@ -1195,7 +1192,6 @@ class ServingRouter:
             routed.replica = None
             routed.next_at = 0.0
             self.requeued += 1
-            telemetry.inc("router.requeues")
             self._pending.append(routed)
         r.drained = True
         self._fail_event("replica_drain", replica=r.index,

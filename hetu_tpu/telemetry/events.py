@@ -27,8 +27,12 @@ thread, None at a root) and the caller's fields (``wave=``, ``step=``,
 trace exporter turns into nested Chrome ``"X"`` duration events.  The
 same span is a ``jax.profiler.TraceAnnotation`` named ``hetu.<name>``:
 while a profiler session is open it is an event of the host plane of the
-profiler's own trace, on the device trace's clock; with none open the
-annotation is a flag test.  With ``HETU_TELEMETRY=0`` ``span()`` returns
+profiler's own trace, on the device trace's clock, and the fields the
+span was OPENED with are that event's stats (``wave=``, ``kind=``,
+``order=``, ``step=``: what joins a wave's spans across roots in the
+one sink the benchmark reads; fields ``set()`` later are known too late
+and stay in the JSONL record alone); with none open the annotation is a
+flag test.  With ``HETU_TELEMETRY=0`` ``span()`` returns
 a shared no-op and the instrumented call sites skip the registry:
 near-zero overhead is the contract (asserted as a <2% smoke-tier bound).
 """
@@ -324,7 +328,9 @@ class _Span:
             stack = _OPEN.stack = []
         self.parent = stack[-1] if stack else None
         stack.append(self.name)
-        self._ann = TraceAnnotation(TRACE_PREFIX + self.name)
+        # the entry fields ride the annotation as the host event's stats;
+        # the event's NAME stays ``hetu.<name>``
+        self._ann = TraceAnnotation(TRACE_PREFIX + self.name, **self.fields)
         self._ann.__enter__()
         self._epoch = time.time()
         self._t0 = time.perf_counter()

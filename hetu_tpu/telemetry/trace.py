@@ -91,6 +91,16 @@ request waited long between its claim and its first token outside
 every wave (a paused host, or wave attribution that broke; the
 assertion this replaced raised out of the scheduler).
 
+``--check`` also enforces the wave-pairing rule (ISSUE 40): on every
+thread, each ``serve.wave.dispatch`` span has exactly one
+``serve.wave.sync`` span after it with the same ``wave=`` and
+``kind=`` (the engine's invariant since it runs a wave ahead: what was
+launched is landed once, as what it was launched as); the newest
+dispatch of a thread may still be in flight where the stream ends.  The
+exporter draws the same pair as a flow arrow (``wave_flow``) from the
+dispatch slice to the sync slice, which sit in different ``serve.wave``
+roots whenever the engine ran ahead.
+
 ``--check`` also enforces the lockdep rule (ISSUE 19): any
 ``lockdep_violation`` record fails the gate outright — the sanitizer
 (``hetu_tpu/locks.py`` under ``HETU_LOCKDEP=1``) only emits one after
@@ -266,8 +276,90 @@ def to_chrome_trace(events):
         out.append({**flow, "ph": "f", "bp": "e", "ts": d1,
                     "pid": rpid, "tid": rtid})
         n_flows += 1
+    # a wave's launch and its landing, joined by their ``wave=``: the
+    # two sit in different roots whenever the engine ran a wave ahead
+    for i, pair in enumerate(wave_pairs(events)[0]):
+        flow = {"name": "wave_flow", "cat": "wave", "id": f"wave:{i}",
+                "args": {"wave": pair[0].get("wave"),
+                         "kind": pair[0].get("kind")}}
+        for rec, end in zip(pair, ({"ph": "s"}, {"ph": "f", "bp": "e"})):
+            pid = int(rec.get("pid", 0))
+            out.append({**flow, **end, "ts": _span_start_us(rec),
+                        "pid": pid, "tid": tid_for(pid, rec.get(
+                            "tid", rec.get("_src", "events")))})
     _clip_children(out)
     return {"traceEvents": out, "displayTimeUnit": "ms"}, n_spans
+
+
+def _span_start_us(rec):
+    """A ``span`` record's start to the microsecond (``t`` has three
+    decimals)."""
+    return float(rec.get("us", float(rec.get("t", 0.0)) * 1e6))
+
+
+def wave_pairs(events):
+    """A serving wave's ``serve.wave.dispatch`` span joined to its
+    ``serve.wave.sync`` span by ``wave=``, thread by thread in time
+    order (two engines that ran one after the other on a thread both
+    count from 1).  Returns ``(pairs, problems)``: ``pairs`` the
+    (dispatch, sync) records, ``problems`` what ``--check`` reports (a
+    dispatch never landed or landed twice, a sync of nothing, a ``kind``
+    that changed in flight).  Exempt: the newest dispatch of a thread
+    (in flight where the stream ends) and a sync that precedes every
+    dispatch of its thread (a stream cut at its head)."""
+    by_thread = {}
+    for e in events:
+        if e.get("event") == "span" and e.get("name") in (
+                "serve.wave.dispatch", "serve.wave.sync"):
+            by_thread.setdefault((e.get("pid"), e.get("tid")), []).append(e)
+    pairs, problems = [], []
+    for thread in by_thread.values():
+        thread.sort(key=_span_start_us)
+        flying = {}              # wave -> its dispatch record
+        landed = set()
+        seen_dispatch = False
+        for e in thread:
+            wave = e.get("wave")
+            if e["name"] == "serve.wave.dispatch":
+                if wave in flying:
+                    problems.append(
+                        f"wave-pairing: wave {wave!r} was dispatched "
+                        f"twice with no sync between")
+                flying[wave] = e
+                landed.discard(wave)
+                seen_dispatch = True
+                continue
+            d = flying.pop(wave, None)
+            if d is None:
+                if wave in landed:
+                    problems.append(
+                        f"wave-pairing: wave {wave!r} was synced twice")
+                elif seen_dispatch:
+                    problems.append(
+                        f"wave-pairing: sync of wave {wave!r} has no "
+                        f"dispatch")
+                continue
+            landed.add(wave)
+            if d.get("kind") != e.get("kind"):
+                problems.append(
+                    f"wave-pairing: wave {wave!r} was dispatched as "
+                    f"{d.get('kind')!r} and synced as {e.get('kind')!r}")
+            pairs.append((d, e))
+        newest = max(flying.values(), key=_span_start_us, default=None)
+        for wave, d in flying.items():
+            if d is not newest:
+                problems.append(
+                    f"wave-pairing: wave {wave!r} ({d.get('kind')!r}) was "
+                    f"dispatched and never synced")
+    return pairs, problems
+
+
+def check_wave_pairing(events):
+    """The wave-pairing rule (``wave_pairs``); a flight recording is a
+    mid-flight snapshot and is exempt."""
+    if any(e.get("event") == "flight_dump" for e in events):
+        return []
+    return wave_pairs(events)[1]
 
 
 def _clip_children(trace_events):
@@ -679,8 +771,7 @@ def check_span_nesting(events):
     spans = [e for e in events if e.get("event") == "span"
              and isinstance(e.get("ms"), (int, float))]
 
-    def start_us(e):
-        return float(e.get("us", float(e.get("t", 0.0)) * 1e6))
+    start_us = _span_start_us
 
     def end_us(e):
         return start_us(e) + e["ms"] * 1e3
@@ -825,7 +916,10 @@ def main(argv=None):
                          "span-nesting rule (a span naming a parent "
                          "lies inside one) and the lifecycle-residue "
                          "rule (any serve_lifecycle_residue record "
-                         "fails the gate); exit 1 on violations")
+                         "fails the gate), and the wave-pairing rule "
+                         "(every serve.wave.dispatch span has exactly "
+                         "one serve.wave.sync of its wave= and kind=); "
+                         "exit 1 on violations")
     args = ap.parse_args(argv)
 
     paths = args.paths or configured_logs()
@@ -870,6 +964,8 @@ def main(argv=None):
         problems.extend(nesting)
         residue = check_lifecycle_residue(events)
         problems.extend(residue)
+        pairing = check_wave_pairing(events)
+        problems.extend(pairing)
         for p in problems:
             print(p)
         print(json.dumps({"records": len(events), "bad_lines": bad,
@@ -887,7 +983,8 @@ def main(argv=None):
                           "ssm_attribution_violations": len(ssm),
                           "span_nesting_violations": len(nesting),
                           "lifecycle_residue_violations":
-                              len(residue)}))
+                              len(residue),
+                          "wave_pairing_violations": len(pairing)}))
         return 1 if problems or bad else 0
 
     if args.export:

@@ -406,10 +406,11 @@ PARENT_MASKED = {
     "latent.Q32.fresh1": "6d630c5c240e04a8"}
 
 
-def wave_programs(sds, attn):
+def wave_programs(sds, attn, window=1):
     """{name: lowered mixed step} of a small GPT-2 and a small latent
     configuration at two q-block buckets x has_fresh; ``sds(shape,
-    dtype)`` makes the abstract arguments."""
+    dtype)`` makes the abstract arguments.  ``window`` is the engine's
+    sampling window (over 1: an engine that speculates)."""
     def w(*s):
         return sds(s, jnp.bfloat16)
 
@@ -446,7 +447,7 @@ def wave_programs(sds, attn):
     cases["latent"] = (lp, ("glm", 3, 4, 64, 128, blk),
                        sds((3, N, BS, blk.latent.row_width), jnp.bfloat16),
                        None)
-    fn = gd.serve_mixed_paged_fn(True, attn, 1)
+    fn = gd.serve_mixed_paged_fn(True, attn, window)
     out = {}
     for name, (params, cfg_tuple, ck, cv) in cases.items():
         for Q in (1, 32):
@@ -455,7 +456,7 @@ def wave_programs(sds, attn):
                     params, cfg_tuple, ck, cv, i32(B, T), i32(B), i32(B, Q),
                     i32(B), i32(B), sds((B,), jnp.bool_),
                     sds((B,), jnp.float32), i32(B), sds((B, 2), jnp.uint32),
-                    attn=attn, window=1, has_fresh=fresh)
+                    attn=attn, window=window, has_fresh=fresh)
     return out
 
 
@@ -467,6 +468,37 @@ def test_gpt2_and_latent_waves_lower_to_the_parents_stablehlo():
     got = {k: digest(low.as_text()) for k, low in wave_programs(
         jax.ShapeDtypeStruct, "masked").items()}
     assert got == PARENT_MASKED
+
+
+@pytest.mark.parametrize("program,scope", [
+    ("gpt2.Q1.fresh0", "wave_decode"), ("gpt2.Q32.fresh1", "wave_chunk"),
+    ("latent.Q1.fresh0", "wave_decode"), ("latent.Q32.fresh1", "wave_chunk"),
+    ("gpt2.Q32.verify", "wave_verify")])
+def test_a_waves_program_is_traced_under_its_kind(program, scope):
+    """ISSUE 40: ONE outer scope names the wave's program in the device
+    trace (both are ``jit__serve_mixed_paged`` on the modules line).
+    Every name stack of the lowered wave that passes through one of the
+    wave's parts starts under it; sampling stays outside; and it is
+    metadata alone: without debug info the text is the parent's."""
+    import re
+    if program.endswith(".verify"):
+        lowered = wave_programs(jax.ShapeDtypeStruct, "masked",
+                                window=3)["gpt2.Q32.fresh0"]
+    else:
+        lowered = wave_programs(jax.ShapeDtypeStruct, "masked")[program]
+        assert digest(lowered.as_text()) == PARENT_MASKED[program]
+    stacks = [s for s in re.findall(r'loc\("([^"]*)"',
+                                    lowered.as_text(debug_info=True))
+              if s.startswith("jit(_serve_mixed_paged)/")]
+    parts = ("embed", "attn_qkv", "mla_qkv", "kv_write", "attention",
+             "attn_out", "mlp", "moe_route", "moe_experts", "lm_head")
+    inside = [s for s in stacks if any(f"/{p}/" in s + "/" for p in parts)]
+    assert len(inside) > 50
+    assert all(s.split("/")[1] == scope for s in inside)
+    waves = {c for s in stacks for c in s.split("/") if c.startswith("wave_")}
+    assert waves == {scope}
+    sampled = [s for s in stacks if "/sample/" in s + "/"]
+    assert sampled and all(s.split("/")[1] == "sample" for s in sampled)
 
 
 def test_hybrid_wave_carries_its_scopes(params, cfg):

@@ -470,9 +470,11 @@ class TestTelemetryAndTop:
         snap = telemetry.snapshot()
         assert snap["counters"].get("serve.expert_load", 0) > 0
         assert "serve.expert_imbalance" in snap["gauges"]
-        assert "serve.expert_drop_rate" in snap["gauges"]
         events, bad = read_events([log])
         assert bad == 0
+        # the drop rate rides the wave's event (what hetu_top reads)
+        steps = [e for e in events if e.get("event") == "serve_step"]
+        assert steps and all("moe_drop_rate" in e for e in steps)
         s = summarize(events)
         assert s["moe"] is not None
         assert s["moe"]["routed"] == int(eng.expert_load.sum())

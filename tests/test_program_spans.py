@@ -1,8 +1,10 @@
 """The program's spans, names and request timelines (ISSUE 25).
 
 One span primitive on the profiler's clock (``telemetry.span`` enters a
-``TraceAnnotation("hetu.<name>")``, records its parent and passes
-``wave=``/``step=`` through); a fixed number of spans a serving wave and
+``TraceAnnotation("hetu.<name>", **entry fields)``, records its parent
+and passes ``wave=``/``step=`` through; since ISSUE 40 a wave's spans
+say its ``kind=`` and a root its ``order=``); a fixed number of spans a
+serving wave and
 a training step whatever is live; a ``Result``'s queue wait and
 per-token stamps; ``ServingMetrics.mark()``/``snapshot(since=)``; and a
 host pause mid-prefill that is counted instead of raising.
@@ -55,13 +57,17 @@ def _spans(path):
 
 class _Annotations:
     """Stands in for ``jax.profiler.TraceAnnotation``: records what was
-    entered and left."""
+    entered (``fields``: the keyword arguments each was built with) and
+    left."""
 
     def __init__(self):
-        self.entered, self.left = [], []
+        self.entered, self.left, self.fields = [], [], []
+        self.built = 0
 
-    def __call__(self, name):
+    def __call__(self, name, **fields):
         outer = self
+        self.built += 1
+        self.fields.append(fields)
 
         class _One:
             def __enter__(self):
@@ -107,12 +113,38 @@ class TestSpanPrimitive:
         assert ann.entered == ["hetu.serve.wave", "hetu.serve.admit"]
         assert ann.left == ["hetu.serve.admit", "hetu.serve.wave"]
 
+    def test_entry_fields_reach_the_annotation_and_set_fields_do_not(
+            self, monkeypatch, merged_log):
+        """What a span is OPENED with rides the annotation (the host
+        event's stats in the profiler's trace); what ``set()`` adds is
+        known too late and stays in the JSONL record, which keeps both."""
+        ann = _Annotations()
+        monkeypatch.setattr(tevents, "TraceAnnotation", ann)
+        with telemetry.span("serve.wave", order="ahead") as root:
+            with telemetry.span("serve.wave.dispatch", wave=7, kind="chunk",
+                                q=128, ahead=True):
+                pass
+            root.set(launched=7, landed=6)
+        assert ann.entered == ["hetu.serve.wave", "hetu.serve.wave.dispatch"]
+        assert ann.fields == [
+            {"order": "ahead"},
+            {"wave": 7, "kind": "chunk", "q": 128, "ahead": True}]
+        by = {r["name"]: r for r in _spans(merged_log)}
+        assert by["serve.wave"]["order"] == "ahead"
+        assert (by["serve.wave"]["launched"], by["serve.wave"]["landed"]) \
+            == (7, 6)
+        assert by["serve.wave.dispatch"]["kind"] == "chunk"
+
     def test_disabled_enters_no_annotation(self, monkeypatch):
+        """A disabled span builds nothing: no annotation object, no
+        record, the shared no-op."""
         ann = _Annotations()
         monkeypatch.setattr(tevents, "TraceAnnotation", ann)
         monkeypatch.setenv("HETU_TELEMETRY", "0")
-        with telemetry.span("serve.wave", wave=1) as root:
+        with telemetry.span("serve.wave", order="first") as root:
             root.set(live=1)
+        assert telemetry.span("a", wave=1) is telemetry.span("b", kind="x")
+        assert ann.built == 0
         assert ann.entered == [] and ann.left == []
 
     def test_parent_stack_survives_an_exception(self, merged_log):
@@ -163,7 +195,8 @@ class TestSpanPrimitive:
 # a wave's spans, tagged ``wave=``: its launch in one iteration, its
 # landing in the next (``serve.kv_alloc`` carries no tag and lies in
 # ``serve.admit``); and one root ``serve.wave`` an iteration
-LAUNCH_SPANS = ["serve.admit", "serve.wave.assemble", "serve.wave.dispatch"]
+LAUNCH_SPANS = ["serve.admit", "serve.kv_alloc", "serve.wave.assemble",
+                "serve.wave.dispatch"]
 LAND_SPANS = ["serve.wave.sync", "serve.wave.unpack"]
 
 
@@ -178,8 +211,9 @@ class TestWaveSpans:
     def test_one_wave_emits_each_span_once(self, model, merged_log,
                                            n_requests):
         """A fixed number of spans a WAVE whatever is live, in order
-        (1 and 3 requests on 8 slots) and one wave ahead (8 on 8): three
-        at its launch, two at its landing one iteration later."""
+        (1 and 3 requests on 8 slots) and one wave ahead (8 on 8): four
+        at its launch, two at its landing one iteration later, six in
+        all under the iterations' roots."""
         eng = _engine(model)
         for i in range(n_requests):
             eng.submit(Request(prompt=[3 + i, 5, 7], max_new_tokens=6,
@@ -196,8 +230,7 @@ class TestWaveSpans:
         assert sorted(r["name"] for r in spans if r.get("wave") == 4) == \
             sorted(LAUNCH_SPANS)
         assert sorted(r["name"] for r in spans) == sorted(
-            LAUNCH_SPANS * 4 + LAND_SPANS * 3
-            + ["serve.kv_alloc", "serve.wave"] * 4)
+            LAUNCH_SPANS * 4 + LAND_SPANS * 3 + ["serve.wave"] * 4)
         roots = [r for r in spans if r["name"] == "serve.wave"]
         assert [(r.get("launched"), r.get("landed")) for r in roots] == \
             [(1, None), (2, 1), (3, 2), (4, 3)]
@@ -216,6 +249,60 @@ class TestWaveSpans:
         assert (at["serve.wave.dispatch", 2]
                 < at["serve.wave.sync", 1]) is ahead
         assert eng.metrics.snapshot()["waves_ahead"] == (2 if ahead else 0)
+
+    @pytest.mark.parametrize("arrangement", ["inorder", "ahead", "spec"])
+    def test_a_wave_says_its_kind_number_and_order_on_the_annotation(
+            self, model, monkeypatch, arrangement):
+        """What the profiler's trace gets (the annotations' arguments):
+        every dispatch has one sync of the same ``wave=`` and ``kind=``
+        (in different roots once the engine runs ahead), a kind is what
+        the wave held, a root says its ``order=`` at entry, and a wave
+        is six spans as before."""
+        ann = _Annotations()
+        monkeypatch.setattr(tevents, "TraceAnnotation", ann)
+        kw = {"inorder": dict(slots=8, prefill_chunk=4),
+              "ahead": dict(slots=4, prefill_chunk=4),
+              "spec": dict(slots=4, spec=2)}[arrangement]
+        eng = _engine(model, **kw)
+        eng.run([Request(prompt=[3 + i, 5, 7, 9, 11, 13], max_new_tokens=5,
+                         seed=i) for i in range(4)])
+        seen = [(n[len("hetu."):], f) for n, f in zip(ann.entered, ann.fields)]
+        by_wave = {}
+        for name, f in seen:
+            if "wave" in f:
+                by_wave.setdefault(f["wave"], []).append((name, f))
+        assert sorted(by_wave) == list(range(1, len(by_wave) + 1))
+        kinds = {}
+        for wave, spans in by_wave.items():
+            names = sorted(n for n, _ in spans)
+            assert names == sorted(
+                LAUNCH_SPANS + LAND_SPANS
+                + ["serve.wave.draft"] * names.count("serve.wave.draft"))
+            fields = dict(spans)
+            d, sy = fields["serve.wave.dispatch"], fields["serve.wave.sync"]
+            assert set(d) == {"wave", "kind", "q", "ahead"}
+            assert set(sy) == {"wave", "kind", "ahead"}
+            assert (d["kind"], d["ahead"]) == (sy["kind"], sy["ahead"])
+            assert fields["serve.wave.assemble"] == \
+                fields["serve.wave.unpack"] == {"wave": wave,
+                                                "kind": d["kind"]}
+            assert set(fields["serve.admit"]) == \
+                set(fields["serve.kv_alloc"]) == {"wave", "queue"}
+            assert ("serve.wave.draft" in fields) == (d["kind"] == "verify")
+            kinds[wave] = d["kind"]
+        # the prompts' chunk waves first (two where six tokens go in
+        # chunks of four), then the answers' decode (or verify) waves
+        assert kinds[1] == "chunk"
+        assert arrangement == "spec" or kinds[2] == "chunk"
+        assert set(kinds.values()) == {
+            "chunk", "verify" if arrangement == "spec" else "decode"}
+        orders = [f["order"] for n, f in seen if n == "serve.wave"]
+        assert orders[0] == "first"
+        assert set(orders[1:]) == (
+            {"ahead", "inorder"} if arrangement == "ahead" else {"inorder"})
+        ahead = [f["ahead"] for n, f in seen if n == "serve.wave.dispatch"]
+        assert sum(ahead) == eng.metrics.snapshot()["waves_ahead"] \
+            == orders.count("ahead")
 
     def test_a_step_with_nothing_live_stops_after_admission(self, model,
                                                             merged_log):

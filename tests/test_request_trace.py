@@ -37,7 +37,7 @@ from hetu_tpu.telemetry import top
 from hetu_tpu.telemetry.flight import RECORDER
 from hetu_tpu.telemetry.metrics import Histogram, percentile
 from hetu_tpu.telemetry.trace import (
-    check_span_balance, main as trace_main, read_events,
+    check_span_balance, check_wave_pairing, main as trace_main, read_events,
 )
 
 pytestmark = pytest.mark.smoke
@@ -501,6 +501,98 @@ class TestSpanBalance:
                                   ttft_s=0.01),
         ]
         assert check_span_balance(evs) == []
+
+
+def _wave_span(name, wave, kind, us, tid="MainThread"):
+    return dict(telemetry.make_record("span", t=us / 1e6, name=name,
+                                      ms=0.5), us=us, pid=1, tid=tid,
+                parent="serve.wave", wave=wave, kind=kind)
+
+
+class TestWavePairing:
+    """ISSUE 40: a wave's dispatch and its sync share ``wave=`` and
+    ``kind=``, in different roots once the engine runs a wave ahead;
+    ``--check`` holds the engine to one sync a dispatch and ``--export``
+    draws the pair as a flow arrow."""
+
+    def test_the_replay_pairs_every_wave_and_draws_its_arrow(
+            self, replay, tmp_path, capsys):
+        assert trace_main([replay["log"], "--check"]) == 0
+        out = capsys.readouterr().out.strip().splitlines()[-1]
+        assert json.loads(out)["wave_pairing_violations"] == 0
+        evs = _export(replay["log"], tmp_path / "t.json")["traceEvents"]
+        spans = {n: sorted((e for e in evs if e.get("ph") == "X"
+                            and e["name"] == n), key=lambda e: e["ts"])
+                 for n in ("serve.wave.dispatch", "serve.wave.sync")}
+        n_waves = replay["eng"].steps
+        assert len(spans["serve.wave.dispatch"]) \
+            == len(spans["serve.wave.sync"]) == n_waves
+        flows = [e for e in evs if e.get("cat") == "wave"]
+        starts = {e["id"]: e for e in flows if e["ph"] == "s"}
+        ends = {e["id"]: e for e in flows if e["ph"] == "f"}
+        assert len(starts) == len(ends) == n_waves
+        by_wave = {n: {e["args"]["wave"]: e for e in spans[n]}
+                   for n in spans}
+        for fid, s in starts.items():
+            f, wave = ends[fid], s["args"]["wave"]
+            d = by_wave["serve.wave.dispatch"][wave]
+            sy = by_wave["serve.wave.sync"][wave]
+            # each end binds inside its own slice, on that slice's track
+            assert (s["pid"], s["tid"], s["ts"]) == (d["pid"], d["tid"],
+                                                     d["ts"])
+            assert (f["pid"], f["tid"], f["ts"]) == (sy["pid"], sy["tid"],
+                                                     sy["ts"])
+            assert s["ts"] < f["ts"] and f["bp"] == "e"
+            assert s["args"]["kind"] == d["args"]["kind"] \
+                == sy["args"]["kind"]
+
+    @pytest.mark.parametrize("fault,finding", [
+        (None, None),
+        ("never_synced", "dispatched and never synced"),
+        ("synced_twice", "synced twice"),
+        ("kind_changed", "dispatched as 'chunk' and synced as 'decode'"),
+        ("no_dispatch", "has no dispatch"),
+        ("dispatched_twice", "dispatched twice"),
+    ])
+    def test_check_holds_a_dispatch_to_exactly_one_sync(self, fault,
+                                                        finding):
+        # one wave ahead: dispatch t+1, then sync t; the last in flight
+        evs = [_wave_span("serve.wave.dispatch", 1, "chunk", 100),
+               _wave_span("serve.wave.dispatch", 2, "decode", 200),
+               _wave_span("serve.wave.sync", 1, "chunk", 300),
+               _wave_span("serve.wave.dispatch", 3, "decode", 400),
+               _wave_span("serve.wave.sync", 2, "decode", 500)]
+        if fault == "never_synced":
+            del evs[2]
+        elif fault == "synced_twice":
+            evs.append(_wave_span("serve.wave.sync", 2, "decode", 600))
+        elif fault == "kind_changed":
+            evs[2]["kind"] = "decode"
+        elif fault == "no_dispatch":
+            evs.append(_wave_span("serve.wave.sync", 9, "decode", 600))
+        elif fault == "dispatched_twice":
+            evs.insert(2, _wave_span("serve.wave.dispatch", 1, "chunk", 250))
+        problems = check_wave_pairing(evs)
+        if finding is None:
+            assert problems == []
+        else:
+            assert len(problems) == 1 and finding in problems[0]
+
+    def test_a_cut_stream_and_a_second_engine_are_no_findings(self):
+        # the head lost wave 4's dispatch; another thread's engine and a
+        # later engine on this thread both count from 1
+        evs = [_wave_span("serve.wave.sync", 4, "decode", 50),
+               _wave_span("serve.wave.dispatch", 1, "chunk", 100),
+               _wave_span("serve.wave.dispatch", 1, "chunk", 110, tid="r1"),
+               _wave_span("serve.wave.sync", 1, "chunk", 200),
+               _wave_span("serve.wave.sync", 1, "chunk", 210, tid="r1"),
+               _wave_span("serve.wave.dispatch", 1, "decode", 300),
+               _wave_span("serve.wave.sync", 1, "decode", 400)]
+        assert check_wave_pairing(evs) == []
+        dump = [telemetry.make_record("flight_dump", reason="chaos_kill"),
+                _wave_span("serve.wave.sync", 7, "decode", 10),
+                _wave_span("serve.wave.dispatch", 8, "decode", 5)]
+        assert check_wave_pairing(dump) == []
 
 
 # --------------------------------------------------------------------- #

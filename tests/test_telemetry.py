@@ -488,6 +488,73 @@ class TestMergedStream:
 
 
 # --------------------------------------------------------------------- #
+# every name has a reader to point at (ISSUE 40)
+# --------------------------------------------------------------------- #
+
+def _instrumented_names():
+    """(call, name, "file:line") for every name handed to
+    ``telemetry.span/inc/observe/set_gauge`` under the serving stack, the
+    executor and the gradient nodes; ``name`` is None where it is not a
+    literal (or a choice between literals)."""
+    import ast
+    import glob
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    files = sorted(glob.glob(os.path.join(root, "hetu_tpu", "serving",
+                                          "*.py")))
+    files += [os.path.join(root, "hetu_tpu", "executor.py"),
+              os.path.join(root, "hetu_tpu", "graph", "ops_misc.py")]
+    assert len(files) > 10
+    found = []
+    for path in files:
+        with open(path) as f:
+            tree = ast.parse(f.read())
+        for node in ast.walk(tree):
+            if not (isinstance(node, ast.Call)
+                    and isinstance(node.func, ast.Attribute)
+                    and node.func.attr in ("span", "inc", "observe",
+                                           "set_gauge")
+                    and isinstance(node.func.value, ast.Name)
+                    and node.func.value.id == "telemetry" and node.args):
+                continue
+            first = node.args[0]
+            where = f"{os.path.relpath(path, root)}:{node.lineno}"
+            for n in ([first.body, first.orelse]
+                      if isinstance(first, ast.IfExp) else [first]):
+                literal = isinstance(n, ast.Constant) \
+                    and isinstance(n.value, str)
+                found.append((node.func.attr, n.value if literal else None,
+                              where))
+    return root, found
+
+
+def test_every_serving_and_executor_name_is_documented():
+    """A span, counter, histogram or gauge that no document names has no
+    reader anybody can find: it is deleted or written down, in README's
+    telemetry section (operators, ``hetu_top``, ``hetu_trace``) or in
+    ``benchmarks/PROGRAM_SPANS*.md`` (the benchmark's readers)."""
+    import glob
+    root, found = _instrumented_names()
+    with open(os.path.join(root, "README.md")) as f:
+        readme = f.read()
+    start = readme.index("\n## Telemetry (")
+    docs = readme[start:readme.index("\n## ", start + 1)]
+    for path in glob.glob(os.path.join(root, "benchmarks",
+                                       "PROGRAM_SPANS*.md")):
+        with open(path) as f:
+            docs += f.read()
+    assert {"serve.wave", "serve.wave.dispatch", "exec.step",
+            "serve.wave.ahead", "exec.grad.retraced",
+            "serve.tokens_per_step", "serve.occupancy"} \
+        <= {name for _, name, _ in found}
+    computed = sorted(where for _, name, where in found if name is None)
+    assert not computed, f"names built at run time, unreadable: {computed}"
+    unread = sorted({f"{name} ({call}, {where})"
+                     for call, name, where in found
+                     if f"`{name}`" not in docs and f"`{name}(" not in docs})
+    assert not unread, f"documented nowhere: {unread}"
+
+
+# --------------------------------------------------------------------- #
 # trace merge/export CLI
 # --------------------------------------------------------------------- #
 
