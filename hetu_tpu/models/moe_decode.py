@@ -270,14 +270,61 @@ def route(x, w_router, bias, spec):
     return sel.astype(jnp.int32), w * spec.scale
 
 
-def grouped_matmul(lhs, rhs, group_sizes):
+# Expected rows a group (static ``M // G``) from which the Pallas kernel
+# takes a routed layer's products.  A decode wave of the routed cells is
+# 128 assignment rows over 32 or 64 experts (4 or 2 a group) and keeps
+# the compiler's kernel; their chunk waves are 1,024 to 4,096 rows (16
+# to 128 a group), where the compiler's kernel takes 2.3 to 3.6 times
+# its weights' time and this one 1.2 to 1.3.  8 is the fewest rows a
+# group the chip sweep read above a decode wave's (256 rows over 32
+# experts: 0.35 ms against 0.65; PERF.md section 6, PR 41).
+KERNEL_ROWS_A_GROUP = 8
+
+
+def takes_kernel(rows, groups):
+    """The shape rule: whether a grouped matmul of ``rows`` sorted rows
+    over ``groups`` groups runs through ``kernels/grouped_matmul`` (else
+    through ``jax.lax.ragged_dot``).  Static shapes alone decide, so a
+    program is one or the other, and the engine can ask the same
+    question of a wave's row count (``serve.moe.kernel_waves``)."""
+    from ..kernels.grouped_matmul import TILE_M
+    return rows % TILE_M == 0 and rows >= KERNEL_ROWS_A_GROUP * groups
+
+
+def kernel_tiles(group_sizes, rows):
+    """The Pallas kernel's (group, row tile) steps for ``rows`` sorted
+    rows in groups of ``group_sizes``, or None where the shape rule
+    leaves the product with ``jax.lax.ragged_dot``.  A caller with
+    several products over the same groups makes them once."""
+    if not takes_kernel(rows, group_sizes.shape[0]):
+        return None
+    from ..kernels.grouped_matmul import group_tiles
+    return group_tiles(group_sizes, rows)
+
+
+def grouped_matmul(lhs, rhs, group_sizes, up=None, tiles=None):
     """``lhs`` [M, K] rows sorted by group times ``rhs`` [G, K, N], group
     ``g`` owning the next ``group_sizes[g]`` rows; rows past the groups'
-    sum come out as anything.  ``jax.lax.ragged_dot``: the v5e compiler
-    takes it at [32768, 2048] x [64, 2048, 1536] and the chip runs it at
-    the cost of the rows and the experts touched (PERF.md section 6, PR
-    28, has the comparison with Pallas' megablox ``gmm``)."""
-    return jax.lax.ragged_dot(lhs, rhs, group_sizes)
+    sum come out as anything.  With ``up`` [G, K, N] the gated pair
+    ``silu(lhs rhs) * (lhs up)``.  One algorithm, two tilings, chosen by
+    :func:`takes_kernel` from ``M`` and ``G``: with few rows a group
+    ``jax.lax.ragged_dot``, which the v5e compiler turns into its own
+    kernel and the chip runs at the cost of the experts touched (a
+    decode wave: PERF.md section 6, PR 28); with tens to hundreds the
+    Pallas kernel of ``kernels/grouped_matmul``, 128-row tiles against a
+    whole-K block of the expert's matrix read once a call, the gated
+    pair in one call (PR 41: the compiler's kernel took three times its
+    bytes' time there).  ``tiles``: ``kernel_tiles(group_sizes, M)`` if
+    the caller has made them."""
+    if tiles is None:
+        tiles = kernel_tiles(group_sizes, lhs.shape[0])
+    if tiles is None:
+        y = jax.lax.ragged_dot(lhs, rhs, group_sizes)
+        if up is None:
+            return y
+        return jax.nn.silu(y) * jax.lax.ragged_dot(lhs, up, group_sizes)
+    from ..kernels.grouped_matmul import grouped_matmul_tiled
+    return grouped_matmul_tiled(lhs, rhs, tiles, up=up)
 
 
 def routed_ffn(params, us, x, spec, valid=None, stats=None):
@@ -310,10 +357,11 @@ def routed_ffn(params, us, x, spec, valid=None, stats=None):
                        dtype=jnp.int32)
         xs = x[order // k]                                  # [T k, D]
     with jax.named_scope("moe_experts"):
-        a = jax.nn.silu(grouped_matmul(
-            xs, params[f"{us}_moe_experts_gate"], load)) \
-            * grouped_matmul(xs, params[f"{us}_moe_experts_up"], load)
-        ys = grouped_matmul(a, params[f"{us}_moe_experts_down"], load)
+        tiles = kernel_tiles(load, T * k)           # once for the layer
+        a = grouped_matmul(xs, params[f"{us}_moe_experts_gate"], load,
+                           up=params[f"{us}_moe_experts_up"], tiles=tiles)
+        ys = grouped_matmul(a, params[f"{us}_moe_experts_down"], load,
+                            tiles=tiles)
     with jax.named_scope("moe_route"):
         # the inverse permutation by a second sort (a scatter of T k
         # indices costs seven times as much on the chip); a row past

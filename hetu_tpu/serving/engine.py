@@ -48,6 +48,7 @@ from ..models.gpt_decode import (
     resolve_spec_k, serve_mixed_fn, serve_mixed_paged_fn,
     serve_prefill_fn, spec_propose_fn, wave_rows,
 )
+from ..models.moe_decode import takes_kernel
 from .kv_manager import (KVCacheManager, PagedKVManager,
                          assemble_mixed_wave, resolve_kv_block,
                          resolve_kv_quant)
@@ -527,14 +528,18 @@ class ServingEngine:
         return self.metrics.record_ssm(int((ql > 0).sum()), int(ql.sum()),
                                        chunk_pairs, self._ssm_layers)
 
-    def _routed_record(self, wave, routed_out):
+    def _routed_record(self, wave, routed_out, rows_computed):
         """A routed wave's counters (``serve.moe.*``) and its
         ``record_step`` payload.  Load and experts touched come out of
-        the compiled step; the rows are the wave descriptor's."""
+        the compiled step; the rows are the wave descriptor's; whether
+        the program took the grouped-matmul kernel is the shape rule's
+        answer for the rows it ran over."""
         load = np.asarray(routed_out[0], np.int64)
         touched = int(routed_out[1])
         rows = int(wave["q_len"].astype(np.int64).sum())
-        self.metrics.record_routed(load, touched)
+        self.metrics.record_routed(
+            load, touched, kernel=takes_kernel(
+                rows_computed * self.routed.top_k, self.routed.num_experts))
         assignments = int(load.sum())
         mean = assignments / len(load)
         return {"tokens": rows, "routed": assignments, "dropped": 0,
@@ -1098,7 +1103,8 @@ class ServingEngine:
             after = np.array(w.after, np.uint32)
             moe_rec = None
             if w.routed_out is not None:
-                moe_rec = self._routed_record(wave, w.routed_out)
+                moe_rec = self._routed_record(wave, w.routed_out,
+                                              w.rows_computed)
             elif w.moe_stats is not None:
                 moe_rec = self._moe_record(w.moe_stats)
             ssm_rec = self._wave_record(wave)

@@ -204,6 +204,7 @@ class ServingMetrics(MetricsCore):
         # ``moe_load`` is None until the first routed wave
         self.moe_assignments = 0
         self.moe_experts_touched = 0
+        self.moe_kernel_waves = 0
         self.moe_load = None
         self.attn_ctx_tokens = 0
         self.attn_score_pairs = 0
@@ -287,14 +288,16 @@ class ServingMetrics(MetricsCore):
         return {"slot_steps": steps, "rows": rows,
                 "live_slots": int(live_slots), "layers": int(layers)}
 
-    def record_routed(self, load, touched):
+    def record_routed(self, load, touched, kernel=False):
         """One wave of a dropless routed engine: ``load`` [E]
-        (assignments an expert, summed over the routed layers) and
-        ``touched`` (experts with load > 0, summed over them).  Running
-        sums here (``snapshot(since=mark)`` windows them) and the
-        counters ``serve.moe.assignments``,
-        ``serve.moe.experts_touched`` and the gauge
-        ``serve.moe.load_max`` (this wave's largest load) in
+        (assignments an expert, summed over the routed layers),
+        ``touched`` (experts with load > 0, summed over them) and
+        ``kernel`` (the wave's program ran its experts' products through
+        ``kernels/grouped_matmul``: ``moe_decode.takes_kernel`` of its
+        row count).  Running sums here (``snapshot(since=mark)`` windows
+        them) and the counters ``serve.moe.assignments``,
+        ``serve.moe.experts_touched``, ``serve.moe.kernel_waves`` and
+        the gauge ``serve.moe.load_max`` (this wave's largest load) in
         ``telemetry``."""
         load = np.asarray(load, np.int64)
         assignments = int(load.sum())
@@ -304,6 +307,9 @@ class ServingMetrics(MetricsCore):
                          else self.moe_load + load)
         telemetry.inc("serve.moe.assignments", assignments)
         telemetry.inc("serve.moe.experts_touched", int(touched))
+        if kernel:
+            self.moe_kernel_waves += 1
+            telemetry.inc("serve.moe.kernel_waves")
         telemetry.set_gauge("serve.moe.load_max", int(load.max()))
 
     # ------------------------------------------------------------- #
@@ -575,7 +581,7 @@ class ServingMetrics(MetricsCore):
     _MARK_COUNTS = ("submitted", "rejected", "finished",
                     "tokens_generated", "prefill_batched",
                     "moe_assignments", "moe_experts_touched",
-                    "attn_ctx_tokens", "attn_score_pairs",
+                    "moe_kernel_waves", "attn_ctx_tokens", "attn_score_pairs",
                     "ssm_slot_steps", "ssm_rows", "ssm_chunk_pairs",
                     "wave_rows_live", "wave_rows_computed",
                     "chunks_deferred", "waves_ahead", "rows_dead_ahead")
@@ -644,6 +650,7 @@ class ServingMetrics(MetricsCore):
             routed = {
                 "moe_assignments": count("moe_assignments"),
                 "moe_experts_touched": count("moe_experts_touched"),
+                "moe_kernel_waves": count("moe_kernel_waves"),
                 "moe_load": [int(x) for x in load],
                 "moe_load_max": int(load.max()),
                 "moe_load_imbalance": (float(load.max()) / mean

@@ -338,6 +338,71 @@ def test_ragged_dot_becomes_the_compilers_grouped_matmul(sds):
 
 
 # ------------------------------------------------------------------- #
+# ISSUE 41: the chunk wave's grouped matmul
+# ------------------------------------------------------------------- #
+
+# (M, K, G, N) of the routed experts' products in a Q 256 chunk wave of
+# the two routed cells: 1,024 packed rows x top-4 over 32 experts of 1792
+# (lfm2-8b-a1b) and 64 of 1536 (glm-4.7-flash), hidden 2048
+GMM_CELL_SHAPES = {
+    "lfm2-gate-up": (4096, 2048, 32, 1792),
+    "lfm2-down": (4096, 1792, 32, 2048),
+    "glm-gate-up": (4096, 2048, 64, 1536),
+    "glm-down": (4096, 1536, 64, 2048)}
+
+
+@pytest.mark.parametrize("gated", [False, True], ids=["plain", "gated-pair"])
+@pytest.mark.parametrize("cell", GMM_CELL_SHAPES)
+def test_grouped_matmul_at_the_cells_shapes(sds, cell, gated):
+    """A whole ``[K, N]`` matrix a buffer, two buffers an operand (29 MB
+    for the gated pair at [2048, 1792]) under the kernel's own
+    ``vmem_limit_bytes``; and a function that calls it three times has
+    ONE Mosaic lowering (the inner jit: the calls of one jitted function
+    lower to calls of one function)."""
+    from hetu_tpu.kernels import grouped_matmul as gm
+    M, K, G, N = GMM_CELL_SHAPES[cell]
+    assert gm.col_tile(K, N) == N
+
+    def three(xs, w0, w1, w2, load):
+        tiles = gm.group_tiles(load, M)
+        return [gm.grouped_matmul_tiled(xs, w, tiles, interpret=False,
+                                        up=w0 if gated else None)
+                for w in (w0, w1, w2)]
+
+    w = sds((G, K, N), jnp.bfloat16)
+    lowered = jax.jit(three).lower(sds((M, K), jnp.bfloat16), w, w, w,
+                                   sds((G,), jnp.int32))
+    text = lowered.as_text()
+    assert text.count("call @_gmm_call") == 3
+    assert text.count("stablehlo.custom_call @tpu_custom_call") == 1
+    compiled = lowered.compile().as_text()
+    assert "tpu_custom_call" in compiled and "moe_grouped_matmul" in compiled
+
+
+@pytest.mark.parametrize("rows,kernel", [(128, False), (4096, True)],
+                         ids=["decode-wave", "chunk-wave"])
+@pytest.mark.parametrize("experts,width", [(32, 1792), (64, 1536)],
+                         ids=["lfm2", "glm"])
+def test_the_shape_rule_picks_the_product(sds, monkeypatch, rows, kernel,
+                                          experts, width):
+    """``moe_decode.grouped_matmul`` at a decode wave's 128 assignment
+    rows stays the compiler's ``ragged-dot`` (the parent's program) and
+    names no ``moe_grouped_matmul``; at a chunk wave's 4,096 it is the
+    reverse.  Nothing but the shapes differs between the two."""
+    from hetu_tpu.kernels import grouped_matmul as gm
+    from hetu_tpu.models.moe_decode import grouped_matmul, takes_kernel
+    monkeypatch.setattr(gm, "_use_interpret", lambda: False)
+    assert takes_kernel(rows, experts) == kernel
+    w = sds((experts, 2048, width), jnp.bfloat16)
+    text = jax.jit(lambda x, g, u, n: grouped_matmul(x, g, n, up=u)).lower(
+        sds((rows, 2048), jnp.bfloat16), w, w,
+        sds((experts,), jnp.int32)).compile().as_text()
+    assert "tpu_custom_call" in text
+    assert ("moe_grouped_matmul" in text) == kernel
+    assert ("ragged-dot" in text) != kernel
+
+
+# ------------------------------------------------------------------- #
 # ISSUE 34: grouped query heads over the K/V pool, conv state beside it
 # ------------------------------------------------------------------- #
 
@@ -468,8 +533,10 @@ def test_packed_chunk_wave_at_the_cells_sizes(sds, monkeypatch, cell):
     kernels are the ones the padded program calls and see the padded
     q-block, the pool (and the conv state) are still updated in place,
     and the compiler's peak is no higher than the padded program's."""
+    from hetu_tpu.kernels import grouped_matmul as gm
     from hetu_tpu.kernels import ragged_attention as ra
     monkeypatch.setattr(ra, "_use_interpret", lambda: False)
+    monkeypatch.setattr(gm, "_use_interpret", lambda: False)
     params, cfg_tuple, pk, pv, state, B, T, kernel, n_calls = \
         _chunk_wave_case(sds, cell)
     Q = 256
@@ -508,7 +575,10 @@ def test_packed_chunk_wave_at_the_cells_sizes(sds, monkeypatch, cell):
         " custom-call(")[0].split("= ")[-1].split("{")[0]
     assert [shape_of(c) for c in calls] == [shape_of(c)
                                             for c in padded_calls]
-    assert ("ragged-dot" in text) == (cell != "gpt2-xl")
+    # 4,096 assignment rows over 64 or 32 experts: the routed products
+    # are the chunk wave's own kernel (ISSUE 41), not the compiler's
+    assert ("moe_grouped_matmul" in text) == (cell != "gpt2-xl")
+    assert "ragged-dot" not in text
     # the weight products run over the packed rows, not over B x Q
     products = [line for line in text.splitlines()
                 if " dot(" in line or " convolution(" in line]
@@ -540,8 +610,11 @@ PARENT_RAGGED = {
     "gpt2.Q32.fresh1": "a7c64ccb01632823",
     "latent.Q1.fresh0": "3e2ddf60b91b860d",
     "latent.Q1.fresh1": "3e2ddf60b91b860d",
-    "latent.Q32.fresh0": "9be25ac3fb1f17f1",
-    "latent.Q32.fresh1": "9be25ac3fb1f17f1"}
+    "latent.Q32.fresh0": "c873bfc54690610f",
+    "latent.Q32.fresh1": "c873bfc54690610f"}
+# (the latent Q 32 pair is PR 41's: its routed products are the
+# ``moe_grouped_matmul`` kernel there, tests/test_hybrid_moe.py says why;
+# the parent of PR 41 lowered them to 9be25ac3fb1f17f1)
 
 
 def strip_kernel_locations(text):
@@ -569,8 +642,10 @@ def test_gpt2_and_latent_kernel_waves_lower_to_the_parents(sds, monkeypatch):
     the parent's operation for operation (ISSUE 34: ``groups`` 1 is the
     code path there was)."""
     from test_hybrid_moe import digest, wave_programs
+    from hetu_tpu.kernels import grouped_matmul as gm
     from hetu_tpu.kernels import ragged_attention as ra
     monkeypatch.setattr(ra, "_use_interpret", lambda: False)
+    monkeypatch.setattr(gm, "_use_interpret", lambda: False)
     got = {}
     for name, lowered in wave_programs(sds, "ragged").items():
         text = lowered.as_text()

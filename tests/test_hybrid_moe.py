@@ -402,8 +402,13 @@ PARENT_MASKED = {
     "gpt2.Q32.fresh1": "910e84f83fe7e6d4",
     "latent.Q1.fresh0": "7af25cbea0694a84",
     "latent.Q1.fresh1": "7af25cbea0694a84",
-    "latent.Q32.fresh0": "6d630c5c240e04a8",
-    "latent.Q32.fresh1": "6d630c5c240e04a8"}
+    "latent.Q32.fresh0": "57bb0151f876695a",
+    "latent.Q32.fresh1": "57bb0151f876695a"}
+# PR 41 changed the latent Q 32 pair on purpose: 128 rows x top-2 over 8
+# experts are 32 expected rows a group, where ``moe_decode.takes_kernel``
+# hands the routed experts' products to ``kernels/grouped_matmul`` (here
+# its interpreted body; parent of PR 41: 6d630c5c240e04a8).  The Q 1
+# pair, 8 assignment rows, keeps ``ragged_dot`` and the parent's text.
 
 
 def wave_programs(sds, attn, window=1):

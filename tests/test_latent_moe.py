@@ -223,9 +223,17 @@ def test_route_chooses_by_biased_score_and_weighs_by_score(params, cfg):
     np.testing.assert_allclose(np.asarray(w).sum(1), 1.8, rtol=1e-5)
 
 
-def test_invalid_rows_are_routed_nowhere(params, cfg):
-    x, _, _ = _route_inputs(cfg, params)
-    valid = jnp.asarray([True] * 5 + [False] * 7)
+@pytest.mark.parametrize("T,kernel", [(12, False), (64, True)],
+                         ids=["compilers-kernel", "grouped-kernel"])
+def test_invalid_rows_are_routed_nowhere(params, cfg, T, kernel):
+    """12 rows x top-2 over 8 experts stay with ``ragged_dot``; 64 rows
+    are 16 expected rows a group, where the shape rule hands the products
+    to ``kernels/grouped_matmul`` (ISSUE 41), most of whose row tiles
+    then lie past the groups' sum."""
+    from hetu_tpu.models.moe_decode import takes_kernel
+    assert takes_kernel(T * 2, 8) == kernel
+    x, _, _ = _route_inputs(cfg, params, T=T)
+    valid = jnp.asarray([True] * 5 + [False] * (T - 5))
     stats = {}
     y = routed_ffn(params, "glm_h1", x, cfg.routed_spec(), valid=valid,
                    stats=stats)
@@ -239,15 +247,30 @@ def test_invalid_rows_are_routed_nowhere(params, cfg):
     assert np.abs(np.asarray(y[:5] - shared[:5])).max() > 1e-3
     # and changes no valid row's result
     alone = routed_ffn(params, "glm_h1", x[:5], cfg.routed_spec())
-    np.testing.assert_allclose(y[:5], alone, atol=1e-6)
+    # (the five alone are ten assignment rows: ``ragged_dot``, another
+    # order of the float32 sums where the crowd took the kernel)
+    np.testing.assert_allclose(y[:5], alone, atol=2e-5 if kernel else 1e-6)
 
 
-def test_batch_company_changes_no_requests_logits(params, cfg):
-    alone = serve(engine(params, cfg), [(19, 6)])["r0"]
-    crowd = serve(engine(params, cfg), [(19, 6), (7, 9), (30, 4), (5, 12)])
+@pytest.mark.parametrize("kw,kernel", [
+    (dict(), False), (dict(slots=8, prefill_chunk=16), True)],
+    ids=["compilers-kernel", "grouped-kernel"])
+def test_batch_company_changes_no_requests_logits(params, cfg, kw, kernel):
+    """4 slots x chunks of 8 are 64 assignment rows a chunk wave (no
+    whole row tile: ``ragged_dot``); 8 slots x 16 are 256 over 8 experts,
+    which the shape rule gives the grouped kernel, and the engine counts
+    those waves (``serve.moe.kernel_waves``)."""
+    alone_eng = engine(params, cfg, **kw)
+    alone = serve(alone_eng, [(19, 6)])["r0"]
+    eng = engine(params, cfg, **kw)
+    crowd = serve(eng, [(19, 6), (7, 9), (30, 4), (5, 12)])
     assert list(crowd["r0"].tokens) == list(alone.tokens)
     for r in crowd.values():
         assert gaps(params, cfg, r)[0] <= TOL
+    for e in (alone_eng, eng):
+        waves = e.metrics.snapshot()["moe_kernel_waves"]
+        # chunk waves take the kernel, decode waves never
+        assert (0 < waves <= e.prefill_chunks) if kernel else waves == 0
 
 
 # ------------------------------------------------------------------ #
