@@ -366,6 +366,15 @@ def _tile_in_sight(lens_ref, qlens_ref, tq, bs, group):
             jnp.maximum(end - 1, 0) // bs)
 
 
+def _first_group(lens_ref, qlens_ref, b, t, tq, span, window):
+    """The first group of ``span`` positions q-tile ``t`` of slot ``b``
+    can see under a window: the one that holds the oldest position its
+    EARLIEST query admits (a tile's first query is live wherever the
+    tile is).  Groups before it are neither copied nor scored."""
+    first_q = lens_ref[b] - qlens_ref[b] + t * tq
+    return jnp.maximum(first_q - window + 1, 0) // span
+
+
 def _reset(m_ref, l_ref, acc_ref):
     m_ref[...] = jnp.full_like(m_ref, NEG_INF)
     l_ref[...] = jnp.zeros_like(l_ref)
@@ -400,15 +409,19 @@ def _page_loop(n_groups, copies, score):
     jax.lax.fori_loop(0, n_groups, step, 0)
 
 
-def _mask_scores(s, qi, gi, filled, qlen):
+def _mask_scores(s, qi, gi, filled, qlen, window=0):
     """Causal mask of a group's scores ``s`` [R, span]: row r is query
     ``qi[r]`` of the q-block, at absolute position ``filled - qlen +
     qi`` (dead rows clip to the last live position, as
-    ``_query_positions``), and admits kv positions up to itself."""
+    ``_query_positions``), and admits kv positions up to itself; under
+    a ``window`` the band ``posq - window < kv_pos <= posq``."""
     kv_pos = gi * s.shape[1] + jax.lax.broadcasted_iota(
         jnp.int32, s.shape, 1)
     posq = jnp.minimum(filled - qlen + qi, filled - 1)
-    return jnp.where(kv_pos <= posq, s, NEG_INF)
+    seen = kv_pos <= posq
+    if window:
+        seen &= kv_pos > posq - window
+    return jnp.where(seen, s, NEG_INF)
 
 
 def _softmax_step(s, v, m_ref, l_ref, acc_ref, at=(slice(None),),
@@ -435,8 +448,16 @@ def _softmax_step(s, v, m_ref, l_ref, acc_ref, at=(slice(None),),
 def _kv_rows_kernel(lens_ref, qlens_ref, bt_ref, layer_ref, q_ref, k_pool,
                     v_pool, o_ref, k_buf, v_buf, sem, m_ref, l_ref, acc_ref,
                     *, scale, bs, group, tq, heads, dh, cw, precision,
-                    qpk=1):
+                    qpk=1, window=0):
     b, t, n_groups, last = _tile_in_sight(lens_ref, qlens_ref, tq, bs, group)
+    # under a window the page loop starts at the first group in sight:
+    # ``g0`` groups are passed over, and ``at(gi)`` is the group the
+    # loop's step ``gi`` copies and scores
+    at = lambda gi: gi                                     # noqa: E731
+    if window:
+        g0 = _first_group(lens_ref, qlens_ref, b, t, tq, group * bs, window)
+        n_groups = jnp.maximum(n_groups - g0, 0)
+        at = lambda gi: g0 + gi                            # noqa: E731
     layer = layer_ref[0]
     _reset(m_ref, l_ref, acc_ref)
     # (chunk, K/V heads in it): the row's last chunk may hold fewer
@@ -458,7 +479,7 @@ def _kv_rows_kernel(lens_ref, qlens_ref, bt_ref, layer_ref, q_ref, k_pool,
         masked)."""
         out = []
         for g in range(group):
-            page = bt_ref[b, jnp.minimum(gi * group + g, last)]
+            page = bt_ref[b, jnp.minimum(at(gi) * group + g, last)]
             dst = pl.ds(g * bs, bs)
             out.append(pltpu.make_async_copy(
                 k_pool.at[layer, page], k_buf.at[buf, dst], sem.at[0, buf]))
@@ -487,7 +508,7 @@ def _kv_rows_kernel(lens_ref, qlens_ref, bt_ref, layer_ref, q_ref, k_pool,
                 preferred_element_type=jnp.float32) * scale
             qi = t * tq + jax.lax.broadcasted_iota(
                 jnp.int32, s.shape, 0) % tq
-            _softmax_step(_mask_scores(s, qi, gi, filled, qlen),
+            _softmax_step(_mask_scores(s, qi, at(gi), filled, qlen, window),
                           v_buf[buf, :, lanes], m_ref, l_ref, acc_ref,
                           at=(c, slice(0, n * qpk * tq)),
                           precision=precision)
@@ -507,9 +528,10 @@ def _kv_rows_kernel(lens_ref, qlens_ref, bt_ref, layer_ref, q_ref, k_pool,
 
 @functools.partial(jax.jit,
                    static_argnames=("heads", "head_dim", "tq", "interpret",
-                                    "qpk"))
+                                    "qpk", "window"))
 def _paged_rows_call(lengths, q_lens, block_tables, layer, qr, pool_k,
-                     pool_v, *, heads, head_dim, tq, interpret, qpk=1):
+                     pool_v, *, heads, head_dim, tq, interpret, qpk=1,
+                     window=0):
     """``_kv_rows_kernel`` over query rows ``qr`` [B, Q, W] (``Q`` whole
     sublane tiles) and the pool pair; with ``qpk`` query heads a K/V
     head, ``qr`` is [B, qpk * Q, W], tile by tile the ``qpk`` members'
@@ -520,7 +542,8 @@ def _paged_rows_call(lengths, q_lens, block_tables, layer, qr, pool_k,
     of GPT-2 XL's 48 calls was traced and lowered to Mosaic anew, 28 s a
     program against the blocked kernel's 3.4 (sandbox, PR 31), which the
     warm set-up of a cell pays for each of its 19 programs before the
-    compile cache can be asked."""
+    compile cache can be asked.  ``window`` > 0 is the banded kernel,
+    named ``ragged_paged_window`` in the trace."""
     B, Q, W = qr.shape
     bs = pool_k.shape[2]
     cw = _lane_chunk(W, head_dim)
@@ -545,10 +568,11 @@ def _paged_rows_call(lengths, q_lens, block_tables, layer, qr, pool_k,
     return pl.pallas_call(
         functools.partial(_kv_rows_kernel, scale=head_dim ** -0.5, bs=bs,
                           group=group, tq=tq, heads=heads, dh=head_dim,
-                          cw=cw, precision=_prec(qr.dtype), qpk=qpk),
+                          cw=cw, precision=_prec(qr.dtype), qpk=qpk,
+                          window=window),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Q, W), qr.dtype),
-        name="ragged_paged_mixed",
+        name="ragged_paged_window" if window else "ragged_paged_mixed",
         interpret=interpret,
     )(lengths, q_lens, block_tables, layer, qr, pool_k, pool_v)
 
@@ -577,7 +601,8 @@ def _ungrouped_rows(o, heads, head_dim, tq, groups):
 
 def ragged_paged_attention(q, pool_k, pool_v, lengths, q_lens,
                            block_tables, *, layer=0, k_scale=None,
-                           v_scale=None, interpret=None, groups=1):
+                           v_scale=None, interpret=None, groups=1,
+                           window=0):
     """The mixed wave over the BLOCK-TABLE paged pool — the serving
     engine's production mixed-mode dispatch.
 
@@ -604,6 +629,17 @@ def ragged_paged_attention(q, pool_k, pool_v, lengths, q_lens,
     are fetched per slot but stored once.  Returns o [B, Q, H, Dh] in
     q's dtype (f32 accumulators).
 
+    ``window`` > 0 (static) scores a SLIDING WINDOW: a query at position
+    ``p`` admits ``p - window < kv <= p``, itself and the ``window - 1``
+    before it.  A (slot, q-tile) step then starts its page loop at the
+    first group its earliest live query can see (pages before it are
+    neither copied nor scored) and masks the band; the table is over
+    logical pages as ever, and may list one pool block under several of
+    them (a window layer's ring repeated: whatever a later page has
+    overwritten lies before every band).  The float pool's kernel
+    alone; it is named ``ragged_paged_window``.  0 is the kernel there
+    was, operation for operation.
+
     An int8 pool — ``(pool_k, k_scale)`` = int8 ``[L, N_blocks, bs, H,
     Dh]`` with f32 scales ``[L, N_blocks, bs, H]`` — keeps its shape and
     the blocked kernel (:func:`_ragged_paged_blocked` over
@@ -619,8 +655,9 @@ def ragged_paged_attention(q, pool_k, pool_v, lengths, q_lens,
     if H % groups:
         raise ValueError(f"{H} query heads are not {groups} a K/V head")
     if k_scale is not None:
-        if groups != 1:
-            raise ValueError("the int8 pool's kernel has no groups")
+        if groups != 1 or window:
+            raise ValueError("the int8 pool's kernel has no groups and "
+                             "no window")
         return _ragged_paged_blocked(
             q, pool_k[layer], pool_v[layer], lengths, q_lens,
             block_tables, k_scale[layer], v_scale[layer], interpret)
@@ -648,7 +685,7 @@ def ragged_paged_attention(q, pool_k, pool_v, lengths, q_lens,
         block_tables.astype(jnp.int32),
         jnp.asarray(layer, jnp.int32).reshape(1), qr, pool_k, pool_v,
         heads=H // groups, head_dim=Dh, interpret=interpret, tq=tq,
-        qpk=groups)
+        qpk=groups, window=int(window))
     if groups == 1:
         return kv_heads(o[:, :Q], H, Dh)
     return _ungrouped_rows(o, H, Dh, tq, groups)[:, :Q]

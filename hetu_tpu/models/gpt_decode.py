@@ -33,6 +33,7 @@ q-block of 1, a verify block k+1 and a prompt chunk its width
 from __future__ import annotations
 
 import functools
+import math
 import os
 from typing import NamedTuple, Optional
 
@@ -394,14 +395,22 @@ class BlockSpec(NamedTuple):
     """norm: "layernorm" (scale and bias) | "rmsnorm"; positions:
     "learned" (a table added to the embedding) | "rope" (rotate-half
     with ``rope_theta``: over ``latent.qk_rope_head_dim``, or over the
-    whole head of a K/V attention); attention: "mha" (as many K/V heads
+    whole head of a K/V attention; ``rope_by_op`` gives the rotary
+    parameters BY OPERATOR instead, ``((operator, inv_freq, factor),
+    ...)`` as ``rope_frequencies`` makes them on the host: the kinds
+    "default" and "yarn" exist, and a layer whose operator is not named
+    rotates with ``rope_theta``); attention: "mha" (as many K/V heads
     as query heads) | "gqa" (``kv_heads`` K/V heads, query head ``n``
     reading K/V head ``n // (H / kv_heads)``) | "latent"; bias: the
     K/V attention's projections carry biases (GPT-2's do); qk_norm:
     every head's q and k RMS-normalised over its columns with a learned
     scale before the rotation; ops: the OPERATOR of every layer, a tuple
-    of "attention" | "conv" | "attention+ssm" (None: attention
-    everywhere), a "conv" layer being the gated short convolution of
+    of "attention" | "window_attention" | "conv" | "attention+ssm"
+    (None: attention everywhere), a "window_attention" layer being that
+    K/V attention over the last ``window`` positions alone (a query sees
+    itself and the ``window - 1`` before it), its K/V pages in the
+    WINDOW pool, a ring a slot; a "conv" layer being the gated short
+    convolution of
     ``conv_kernel`` taps whose state lives beside the pool, an
     "attention+ssm" layer running that K/V attention AND the state-space
     mixer ``ssm`` (a ``ssm_decode.SSMSpec``) side by side on one norm,
@@ -433,6 +442,8 @@ class BlockSpec(NamedTuple):
     head_dim: int = 0
     ssm: Optional[tuple] = None
     mup: Optional[MuP] = None
+    window: int = 0
+    rope_by_op: Optional[tuple] = None
 
     def ffn_kind(self, i):
         """Layer ``i``'s FFN: the leading layers of a routed model are
@@ -445,23 +456,38 @@ class BlockSpec(NamedTuple):
         return sum(1 for i in range(L) if self.ffn_kind(i) == "routed")
 
     def op_kind(self, i):
-        """Layer ``i``'s operator: "attention" | "conv" |
-        "attention+ssm"."""
+        """Layer ``i``'s operator: "attention" | "window_attention" |
+        "conv" | "attention+ssm"."""
         return self.ops[i] if self.ops else "attention"
 
     def holds(self, i, what):
-        """Whether layer ``i`` keeps ``what``: "pool" (K/V pages: every
-        layer with an attention) or "state" (slot state beside the
-        pool: a conv or a state-space mixer)."""
+        """Whether layer ``i`` keeps ``what``: "pool" (K/V pages of every
+        position: a layer with an attention over everything), "window"
+        (K/V pages in the window pool: a window layer) or "state" (slot
+        state beside the pool: a conv or a state-space mixer)."""
         kind = self.op_kind(i)
-        return kind != "conv" if what == "pool" else kind != "attention"
+        if what == "window":
+            return kind == "window_attention"
+        if what == "pool":
+            return kind in ("attention", "attention+ssm")
+        return kind in ("conv", "attention+ssm")
 
     def op_index(self, i, what=None):
         """Layer ``i``'s place among the layers that keep what it keeps:
-        an attention layer's index into the K/V pool, a conv layer's
-        into the state; a layer that keeps both says ``what``."""
-        what = what or ("state" if self.op_kind(i) == "conv" else "pool")
+        an attention layer's index into the K/V pool, a window layer's
+        into the window pool, a conv layer's into the state; a layer
+        that keeps two says ``what``."""
+        what = what or {"conv": "state", "window_attention": "window"}.get(
+            self.op_kind(i), "pool")
         return sum(1 for j in range(i) if self.holds(j, what))
+
+    def rope_of(self, i):
+        """Layer ``i``'s (inv_freq or None, factor): its operator's entry
+        of ``rope_by_op``, else ``rope_theta``'s own frequencies."""
+        for op, inv, factor in self.rope_by_op or ():
+            if op == self.op_kind(i):
+                return inv, factor
+        return None, 1.0
 
     def state_shapes(self, L, hidden):
         """The set of slot states ``L`` layers of this spec keep beside
@@ -476,14 +502,55 @@ class BlockSpec(NamedTuple):
         return (((n, self.conv_kernel - 1, hidden), None),)
 
     def op_layers(self, L, what):
-        """How many of ``L`` layers keep ``what`` ("pool" | "state"; or
-        an operator's name: the layers of that operator)."""
-        if what in ("pool", "state"):
+        """How many of ``L`` layers keep ``what`` ("pool" | "window" |
+        "state"; or an operator's name: the layers of that operator)."""
+        if what in ("pool", "window", "state"):
             return sum(1 for i in range(L) if self.holds(i, what))
         return sum(1 for i in range(L) if self.op_kind(i) == what)
 
 
 GPT2_BLOCK = BlockSpec()
+
+# every operator a layer of ``BlockSpec.ops`` may name, and every kind of
+# rotary frequencies ``rope_frequencies`` makes
+OPERATORS = ("attention", "window_attention", "conv", "attention+ssm")
+ROPE_KINDS = ("default", "yarn")
+
+
+def rope_frequencies(head_dim, rope_type="default", rope_theta=10000.0,
+                     factor=1.0, original_max_position_embeddings=0,
+                     beta_fast=32.0, beta_slow=1.0, attention_factor=None,
+                     **ignored):
+    """(inv_freq: ``head_dim / 2`` floats, the factor ``cos`` and ``sin``
+    are both multiplied by) of one ``rope_parameters`` section, on the
+    host, once: no table over positions.  "default": ``theta ** (-2i /
+    d)`` and 1.  "yarn": frequency ``i`` is the default one below
+    ``low``, the default over ``factor`` above ``high`` and a linear
+    ramp between, ``low`` / ``high`` the indices whose wavelength fits
+    ``beta_fast`` / ``beta_slow`` times into
+    ``original_max_position_embeddings``, clamped to the head; the
+    factor is ``attention_factor``, else ``0.1 ln(factor) + 1``.  The
+    frequencies do not depend on a sequence's length."""
+    if rope_type not in ROPE_KINDS:
+        raise ValueError(f"rope_type={rope_type!r}; there are "
+                         f"{', '.join(ROPE_KINDS)}")
+    d = int(head_dim)
+    base = float(rope_theta) ** (-np.arange(0, d, 2, dtype=np.float64) / d)
+    if rope_type == "default":
+        return tuple(float(v) for v in base), 1.0
+
+    def index(rotations):
+        return d * math.log(original_max_position_embeddings
+                            / (rotations * 2 * math.pi)) \
+            / (2 * math.log(rope_theta))
+
+    low = max(math.floor(index(beta_fast)), 0)
+    high = min(math.ceil(index(beta_slow)), d - 1)
+    ramp = np.clip((np.arange(d // 2) - low) / max(high - low, 1e-3), 0, 1)
+    inv = (1 - ramp) * base + ramp * base / float(factor)
+    if attention_factor is None:
+        attention_factor = 0.1 * math.log(float(factor)) + 1.0
+    return tuple(float(v) for v in inv), float(attention_factor)
 
 
 def _block_of(cfg_tuple):
@@ -508,37 +575,51 @@ def check_block_spec(blk, layers=None):
     an optional per-head q/k norm, every layer's operator either that
     attention or the gated short convolution (``ops``; a conv layer
     needs ``conv_kernel`` >= 2 taps, and ``ops`` names ``layers`` of
-    them) or both that attention and a state-space mixer on one norm
+    them) or that attention over a sliding window ("window_attention",
+    which needs ``window`` >= 1 and is mixed with plain "attention"
+    layers alone: the window pool has no state beside it) or both that
+    attention and a state-space mixer on one norm
     ("attention+ssm", which needs an ``ssm`` spec and is not mixed with
     "conv" layers: the two keep different state): each over any of the
-    three FFN kinds and either head.  Multipliers (``mup``) and a head
-    size of the configuration's own go with the grouped-query block
-    alone."""
+    three FFN kinds and either head.  Multipliers (``mup``), a head
+    size of the configuration's own and rotary parameters by operator
+    (``rope_by_op``: "default" or "yarn" frequencies, made by
+    ``rope_frequencies``) go with the grouped-query block alone; a
+    routed FFN scores by "sigmoid" or "softmax"."""
     if blk == GPT2_BLOCK:
         return
     common = (blk.norm, blk.positions) == ("rmsnorm", "rope") \
         and (blk.ffn == "routed") == (blk.routed is not None) \
         and blk.ffn in ("gelu", "swiglu", "routed") \
-        and blk.head in ("tied", "untied")
+        and blk.head in ("tied", "untied") \
+        and (blk.routed is None
+             or blk.routed.scoring in ("sigmoid", "softmax"))
     if blk.attention == "latent":
         ok = common and blk.latent is not None and blk.ops is None \
-            and blk.ssm is None and blk.mup is None and not blk.head_dim
+            and blk.ssm is None and blk.mup is None and not blk.head_dim \
+            and not blk.window and blk.rope_by_op is None
     else:
         ops = blk.ops or ()
         ok = common and blk.attention == "gqa" and blk.latent is None \
             and not blk.bias and blk.kv_heads >= 1 \
-            and all(o in ("attention", "conv", "attention+ssm")
-                    for o in ops) \
+            and all(o in OPERATORS for o in ops) \
             and ("conv" not in ops or blk.conv_kernel >= 2) \
             and ("attention+ssm" in ops) == (blk.ssm is not None) \
             and not ("attention+ssm" in ops and "conv" in ops) \
+            and ("window_attention" in ops) == (blk.window >= 1) \
+            and ("window_attention" not in ops
+                 or set(ops) <= {"attention", "window_attention"}) \
+            and all(op in ops and factor > 0
+                    for op, _, factor in blk.rope_by_op or ()) \
             and (layers is None or not ops or len(ops) == layers)
     if not ok:
         raise ValueError(
             f"the mixed wave runs GPT-2's block, latent attention with "
             f"rmsnorm and rope, or grouped-query attention with rmsnorm "
-            f"and rope beside gated short convolutions or a state-space "
-            f"mixer; it cannot run {blk}")
+            f"and rope (operators {', '.join(OPERATORS)}; rotary kinds "
+            f"{', '.join(ROPE_KINDS)}; routers sigmoid, softmax): beside "
+            f"gated short convolutions or a state-space mixer, or over a "
+            f"sliding window beside full layers; it cannot run {blk}")
 
 
 def head_dim_of(config):
@@ -563,15 +644,22 @@ def _norm(blk, params, prefix, x):
     return _ln(x, params[f"{prefix}_scale"], params[f"{prefix}_bias"])
 
 
-def _rope(x, posns, theta):
+def _rope(x, posns, theta, inv=None, factor=1.0):
     """Rotate-half RoPE over the whole last axis of ``x`` [B, Q, ..., d]
     at positions ``posns`` [B, Q]: pairs (j, j + d/2), frequency
-    ``theta ** (-2j/d)``, computed in f32."""
+    ``theta ** (-2j/d)``, computed in f32.  With ``inv`` (``d / 2``
+    floats: ``BlockSpec.rope_of``) those are the frequencies, and
+    ``cos`` and ``sin`` are both multiplied by ``factor``."""
     d = x.shape[-1]
-    inv = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    if inv is None:
+        inv = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    else:
+        inv = jnp.asarray(inv, jnp.float32)
     ang = posns.astype(jnp.float32)[..., None] * inv        # [B, Q, d/2]
     ang = ang.reshape(ang.shape[:2] + (1,) * (x.ndim - 3) + (d // 2,))
     cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if factor != 1.0:
+        cos, sin = cos * factor, sin * factor
     x32 = x.astype(jnp.float32)
     x1, x2 = x32[..., :d // 2], x32[..., d // 2:]
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
@@ -1465,7 +1553,7 @@ def _proj(params, prefix, x, bias):
 def _mixed_step(params, cfg_tuple, cache_k, cache_v, pos, tokens,
                 q_len, first_row, self_fresh, window=1, attn="masked",
                 block_tables=None, has_fresh=False, moe_stats=None,
-                state=None):
+                state=None, win=None, ring=None):
     """One MIXED wave: slot b consumes ``tokens[b, :q_len[b]]`` at
     positions ``pos[b] .. pos[b]+q_len[b]-1`` — whatever mode those
     tokens are (prompt chunk, draft+bonus verify block, single decode
@@ -1545,6 +1633,18 @@ def _mixed_step(params, cfg_tuple, cache_k, cache_v, pos, tokens,
     scale the embedding, each mixer's input and output, the keys, the
     FFN and the logits; a spec without them multiplies nowhere.
 
+    A "window_attention" layer is that K/V attention over the WINDOW
+    pool: ``win`` is its pool pair ``[window layers, N, block, W]``,
+    handed back as a fifth element, ``ring`` its table ``[B, ring
+    entries]``.  A slot's ring
+    entry ``j mod ring`` holds logical page ``j``, so the table over
+    logical pages the write and the scoring take is the ring repeated
+    (a gather of ``[B, T]`` indices a wave); a query at ``p`` admits
+    ``p - window < kv <= p`` (the masked path's band, the kernel's
+    ``window``), so a page that a later one has overwritten is never in
+    sight.  Rotary frequencies follow the layer's operator
+    (``BlockSpec.rope_of``).  Without ``win`` nothing here changes.
+
     ONE outer scope names the wave's PROGRAM in the device trace, by the
     static facts the body branches on: ``wave_chunk`` (``has_fresh``),
     ``wave_verify`` (``window > 1``: every wave of an engine that
@@ -1558,12 +1658,13 @@ def _mixed_step(params, cfg_tuple, cache_k, cache_v, pos, tokens,
     with jax.named_scope(scope):
         return _mixed_wave(params, cfg_tuple, cache_k, cache_v, pos, tokens,
                            q_len, first_row, self_fresh, window, attn,
-                           block_tables, has_fresh, moe_stats, state)
+                           block_tables, has_fresh, moe_stats, state, win,
+                           ring)
 
 
 def _mixed_wave(params, cfg_tuple, cache_k, cache_v, pos, tokens, q_len,
                 first_row, self_fresh, window, attn, block_tables,
-                has_fresh, moe_stats, state):
+                has_fresh, moe_stats, state, win=None, ring=None):
     """``_mixed_step``'s body, traced under the wave's own scope."""
     name, L, H, Dh, S_max = cfg_tuple[:5]
     moe = _moe_of(cfg_tuple)
@@ -1613,6 +1714,15 @@ def _mixed_wave(params, cfg_tuple, cache_k, cache_v, pos, tokens, q_len,
         if rows is not None:
             wblk_r = jnp.where(valid_r, rows.pack(wblk), 0)
             woff_r = rows.pack(woff)
+        if win is not None:
+            # the window layers' table over logical pages: the ring
+            # repeated, and the blocks a row scatter writes through it
+            win_k, win_v = win
+            win_tables = ring[:, jnp.arange(T) % ring.shape[1]]
+            wblk_w = jnp.where(valid,
+                               win_tables[bidx[:, None], posc // bs_blk], 0)
+            if rows is not None:
+                wblk_w = jnp.where(valid_r, rows.pack(wblk_w), 0)
     else:
         span = S_max
     ctx = jnp.arange(span)[None, None, :]
@@ -1623,6 +1733,11 @@ def _mixed_wave(params, cfg_tuple, cache_k, cache_v, pos, tokens, q_len,
     jj = jnp.arange(Q)
     self_live = (jj[None, None, :] <= jj[None, :, None]) \
         & valid[:, None, :]                                # [B, Q, Q]
+    if win is not None:
+        # the masked path's band: a query admits the ``blk.window``
+        # positions that end at its own
+        near = ctx > posns[:, :, None] - blk.window        # [B, Q, S]
+        self_near = jj[None, :] > jj[:, None] - blk.window  # [Q, Q]
     scale = Dh ** -0.5
     quant = _kv_q(cache_k)
 
@@ -1647,8 +1762,18 @@ def _mixed_wave(params, cfg_tuple, cache_k, cache_v, pos, tokens, q_len,
             h = _ffn_of_kind(params, us, blk, h, i, valid_r, moe_stats)
             continue
         # the pool holds the layers with an attention alone (all of
-        # them, in their order, where the spec names no operators)
-        pi = blk.op_index(i, "pool")
+        # them, in their order, where the spec names no operators); a
+        # window layer's pages are in the window pool, under its table
+        windowed = blk.op_kind(i) == "window_attention"
+        if windowed:
+            pi = blk.op_index(i, "window")
+            ck, cv, tables, wb = win_k, win_v, win_tables, wblk_w
+            live_i = live & near
+        else:
+            pi = blk.op_index(i, "pool")
+            ck, cv, tables = cache_k, cache_v, block_tables
+            wb = wblk_r if paged else None
+            live_i = live
         with jax.named_scope("attn_qkv"):
             x = _norm(blk, params, f"{us}_ln1", h)
         y_ssm = None
@@ -1673,8 +1798,9 @@ def _mixed_wave(params, cfg_tuple, cache_k, cache_v, pos, tokens, q_len,
                 q = _rms(q, params[f"{us}_attn_q_norm_scale"], blk.norm_eps)
                 k = _rms(k, params[f"{us}_attn_k_norm_scale"], blk.norm_eps)
             if blk.positions == "rope":
-                q = _rope(q, posns_r, blk.rope_theta)
-                k = _rope(k, posns_r, blk.rope_theta)
+                inv, factor = blk.rope_of(i)
+                q = _rope(q, posns_r, blk.rope_theta, inv, factor)
+                k = _rope(k, posns_r, blk.rope_theta, inv, factor)
         k_r, v_r = k, v
         if rows is not None:
             # the page write, the scoring and the fresh-self softmax
@@ -1686,49 +1812,47 @@ def _mixed_wave(params, cfg_tuple, cache_k, cache_v, pos, tokens, q_len,
         with jax.named_scope("kv_write"):
             if paged and not quant and Q >= bs_blk:
                 # a q-block a page or more wide: whole pages
-                cache_k = _kv_write_pages(cache_k, pi, k, pos, q_len,
-                                          block_tables)
-                cache_v = _kv_write_pages(cache_v, pi, v, pos, q_len,
-                                          block_tables)
+                ck = _kv_write_pages(ck, pi, k, pos, q_len, tables)
+                cv = _kv_write_pages(cv, pi, v, pos, q_len, tables)
             elif paged:
-                cache_k = _kv_scatter(cache_k, (pi, wblk_r, woff_r), k_r)
-                cache_v = _kv_scatter(cache_v, (pi, wblk_r, woff_r), v_r)
+                ck = _kv_scatter(ck, (pi, wb, woff_r), k_r)
+                cv = _kv_scatter(cv, (pi, wb, woff_r), v_r)
             else:
                 # descending j: dead (clipped) tail first, live wins
                 # last
                 for jq in reversed(range(Q)):
                     pw = jnp.minimum(posns[:, jq], S_max - 1)
-                    cache_k = _kv_scatter(cache_k, (pi, bidx, pw),
-                                          k[:, jq])
-                    cache_v = _kv_scatter(cache_v, (pi, bidx, pw),
-                                          v[:, jq])
+                    ck = _kv_scatter(ck, (pi, bidx, pw), k[:, jq])
+                    cv = _kv_scatter(cv, (pi, bidx, pw), v[:, jq])
         with jax.named_scope("attention"):
             if paged and attn == "ragged":
                 # the pool pair whole, the layer an index in the page
                 # copy: no ``cache_k[i]`` is materialised (an int8 pair
                 # hands its scale planes over beside its payload)
-                pk, ksc = cache_k if quant else (cache_k, None)
-                pv, vsc = cache_v if quant else (cache_v, None)
+                pk, ksc = ck if quant else (ck, None)
+                pv, vsc = cv if quant else (cv, None)
                 o = ragged_paged_attention(
-                    q, pk, pv, lens, q_len, block_tables, layer=pi,
+                    q, pk, pv, lens, q_len, tables, layer=pi,
                     k_scale=ksc, v_scale=vsc,
-                    groups=group).reshape(B, Q, hdim)
+                    groups=group,
+                    window=blk.window if windowed else 0).reshape(
+                        B, Q, hdim)
             elif attn == "ragged":
-                ks, ksc = _kv_layer(cache_k, pi, Hkv, Dh)
-                vs, vsc = _kv_layer(cache_v, pi, Hkv, Dh)
+                ks, ksc = _kv_layer(ck, pi, Hkv, Dh)
+                vs, vsc = _kv_layer(cv, pi, Hkv, Dh)
                 o = ragged_attention(q, ks, vs, lens, q_len, k_scale=ksc,
                                      v_scale=vsc).reshape(B, Q, hdim)
             else:
-                ks, ksc = _kv_layer(cache_k, pi, Hkv, Dh)
-                vs, vsc = _kv_layer(cache_v, pi, Hkv, Dh)
+                ks, ksc = _kv_layer(ck, pi, Hkv, Dh)
+                vs, vsc = _kv_layer(cv, pi, Hkv, Dh)
                 if paged:
-                    kg = ks[block_tables].reshape(B, span, Hkv, Dh)
-                    vg = vs[block_tables].reshape(B, span, Hkv, Dh)
+                    kg = ks[tables].reshape(B, span, Hkv, Dh)
+                    vg = vs[tables].reshape(B, span, Hkv, Dh)
                     if ksc is not None:
                         kg = kg.astype(jnp.float32) * ksc[
-                            block_tables].reshape(B, span, Hkv)[..., None]
+                            tables].reshape(B, span, Hkv)[..., None]
                         vg = vg.astype(jnp.float32) * vsc[
-                            block_tables].reshape(B, span, Hkv)[..., None]
+                            tables].reshape(B, span, Hkv)[..., None]
                 else:
                     kg, vg = ks, vs
                     if ksc is not None:
@@ -1737,17 +1861,22 @@ def _mixed_wave(params, cfg_tuple, cache_k, cache_v, pos, tokens, q_len,
                 kg, vg = per_query_head(kg), per_query_head(vg)
                 # default: _verify_step's full mask over the written cache
                 s_raw = jnp.einsum("bqhd,bshd->bqhs", q, kg) * scale
-                sw = jnp.where(live[:, :, None, :], s_raw, NEG_INF)
+                sw = jnp.where(live_i[:, :, None, :], s_raw, NEG_INF)
                 p = jax.nn.softmax(sw, axis=-1)
                 o = jnp.einsum("bqhs,bshd->bqhd", p, vg)
                 if has_fresh:
                     # chunk slots: read-back context + the chunk's own
                     # FRESH K/V
                     kf, vf = per_query_head(k), per_query_head(v)
-                    s1 = jnp.where(ctx_live[:, None, None, :], s_raw,
-                                   NEG_INF)
+                    if windowed:
+                        ctx_i = (ctx_live[:, None, :] & near)[:, :, None, :]
+                        self_i = self_live & self_near[None]
+                    else:
+                        ctx_i = ctx_live[:, None, None, :]
+                        self_i = self_live
+                    s1 = jnp.where(ctx_i, s_raw, NEG_INF)
                     s2 = jnp.einsum("bqhd,bjhd->bqhj", q, kf) * scale
-                    s2 = jnp.where(self_live[:, :, None, :], s2, NEG_INF)
+                    s2 = jnp.where(self_i[:, :, None, :], s2, NEG_INF)
                     pf = jax.nn.softmax(
                         jnp.concatenate([s1, s2], axis=-1), axis=-1)
                     o_fresh = jnp.einsum("bqhs,bshd->bqhd",
@@ -1758,6 +1887,10 @@ def _mixed_wave(params, cfg_tuple, cache_k, cache_v, pos, tokens, q_len,
                 o = o.reshape(B, Q, hdim)
             if rows is not None:
                 o = rows.pack(o)
+        if windowed:
+            win_k, win_v = ck, cv
+        else:
+            cache_k, cache_v = ck, cv
         with jax.named_scope("attn_out"):
             o = _proj(params, f"{us}_attn_proj", o, blk.bias)
             h = h + (o if mup is None else o * mup.attention_out)
@@ -1765,6 +1898,8 @@ def _mixed_wave(params, cfg_tuple, cache_k, cache_v, pos, tokens, q_len,
             h = h + y_ssm
         h = _ffn_of_kind(params, us, blk, h, i, valid_r, moe_stats, moe)
     logits = _window_logits(params, name, h, first_row, window, blk, rows)
+    if win is not None:
+        return logits, cache_k, cache_v, state, (win_k, win_v)
     return logits, cache_k, cache_v, state
 
 
@@ -1798,22 +1933,26 @@ def _serve_mixed(params, cfg_tuple, cache_k, cache_v, pos, tokens,
 def _serve_mixed_paged(params, cfg_tuple, cache_k, cache_v, tables,
                        pos, tokens, q_len, first_row, self_fresh,
                        temperature, top_k, rng_keys, attn="masked",
-                       has_fresh=False, window=1, state=None):
+                       has_fresh=False, window=1, state=None, win=None,
+                       ring=None):
     """``_serve_mixed`` over the block-table paged pool (``q_len`` 0
     marks inert slots, whose writes route to scratch block 0 and whose
     window is empty).  ``has_fresh`` (static) marks waves carrying
     prompt-chunk slots — see ``_mixed_step``.  ``state`` is the slot
     state of a block spec that keeps any (an array, or a tuple of them:
     the manager's set, donated like the pool, returned LAST); every
-    other spec passes none and gets none back."""
+    other spec passes none and gets none back.  ``win`` / ``ring`` are
+    the window layers' pool pair and ring table of a spec that has any
+    (the pair donated, returned after everything else)."""
     moe_on = _moe_active(cfg_tuple)
     routed = _block_of(cfg_tuple).routed
     sd = {} if moe_on or routed is not None else None
-    logits, cache_k, cache_v, state = _mixed_step(
+    band = {} if win is None else {"win": win, "ring": ring}
+    logits, cache_k, cache_v, state, *win_out = _mixed_step(
         params, cfg_tuple, cache_k, cache_v, pos, tokens, q_len,
         first_row, self_fresh, window=window, attn=attn,
         block_tables=tables, has_fresh=has_fresh, moe_stats=sd,
-        state=state)
+        state=state, **band)
     sampled, after = _spec_sample(logits, temperature, top_k, rng_keys,
                                   q_len - first_row)
     out = (sampled, cache_k, cache_v, after)
@@ -1829,7 +1968,7 @@ def _serve_mixed_paged(params, cfg_tuple, cache_k, cache_v, tables,
                       jnp.asarray(sd.get("touched", 0), jnp.int32)),)
     if state is not None:
         out = out + (state,)
-    return out
+    return out + tuple(win_out)
 
 
 @functools.lru_cache(maxsize=None)
@@ -1856,7 +1995,7 @@ def serve_mixed_paged_fn(donate=True, attn="masked", window=1):
     kw = {"static_argnames": ("cfg_tuple", "attn", "has_fresh", "window")}
     if donate:
         kw["donate_argnums"] = (2, 3)
-        kw["donate_argnames"] = ("state",)
+        kw["donate_argnames"] = ("state", "win")
     fn = jax.jit(_serve_mixed_paged, **kw)
     return functools.partial(fn, attn=attn, window=window)
 

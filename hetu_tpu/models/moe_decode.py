@@ -239,10 +239,12 @@ def _gelu_tanh(x):
 
 
 class RoutedSpec(NamedTuple):
-    """The routed FFN of a ``gpt_decode.BlockSpec`` (``ffn="routed"``):
-    sigmoid scores, the ``top_k`` largest of ``score + bias`` chosen,
-    weights from the scores alone, normalised when ``norm_topk`` and
-    scaled by ``scale``; ``n_shared`` shared experts' width is
+    """The routed FFN of a ``gpt_decode.BlockSpec`` (``ffn="routed"``).
+    ``scoring`` "sigmoid": sigmoid scores, the ``top_k`` largest of
+    ``score + bias`` chosen; "softmax": a softmax over all the experts,
+    the ``top_k`` largest chosen, no selection bias.  Either way the
+    weights are the scores at the chosen, normalised when ``norm_topk``
+    and scaled by ``scale``; ``n_shared`` shared experts' width is
     ``n_shared`` times an expert's."""
 
     num_experts: int
@@ -250,17 +252,25 @@ class RoutedSpec(NamedTuple):
     scale: float = 1.0
     norm_topk: bool = True
     n_shared: int = 0
+    scoring: str = "sigmoid"
 
 
 def route(x, w_router, bias, spec):
     """(chosen experts [T, k] int32, their weights [T, k] f32) for the
     rows ``x`` [T, D]: ``s = sigmoid(float32(x) W_g)``; the ``k`` largest
     of ``s + b`` are CHOSEN; the weights are ``s`` at the chosen,
-    normalised to sum 1 (``norm_topk``) and scaled."""
-    s = jax.nn.sigmoid(jnp.dot(
+    normalised to sum 1 (``norm_topk``) and scaled.  With
+    ``spec.scoring`` "softmax" ``s`` is the softmax over all the experts
+    and its ``k`` largest are chosen (``bias`` is not read: None)."""
+    logits = jnp.dot(
         x.astype(jnp.float32), w_router.astype(jnp.float32),
-        precision=jax.lax.Precision.HIGHEST))
-    _, sel = jax.lax.top_k(s + bias.astype(jnp.float32), spec.top_k)
+        precision=jax.lax.Precision.HIGHEST)
+    if spec.scoring == "softmax":
+        s = jax.nn.softmax(logits, axis=-1)
+        _, sel = jax.lax.top_k(s, spec.top_k)
+    else:
+        s = jax.nn.sigmoid(logits)
+        _, sel = jax.lax.top_k(s + bias.astype(jnp.float32), spec.top_k)
     # s at the chosen, by comparison and not by gather (a gather of
     # T x k scalars is 0.33 ms a layer at 8192 rows on a v5e)
     hit = sel[:, :, None] == jnp.arange(s.shape[1])[None, None, :]
@@ -335,7 +345,8 @@ def routed_ffn(params, us, x, spec, valid=None, stats=None):
     the routed part (batch company changes no live row's result: each
     row's experts and weights depend on that row alone).  Leaves:
     ``{us}_moe_router_weight`` [D, E], ``{us}_moe_router_bias`` [E] (the
-    selection bias), ``{us}_moe_experts_gate``/``_up`` [E, D, F],
+    selection bias; a softmax router has none),
+    ``{us}_moe_experts_gate``/``_up`` [E, D, F],
     ``{us}_moe_experts_down`` [E, F, D], ``{us}_moe_shared_gate_weight``
     / ``_up_weight`` [D, n_shared F], ``_down_weight`` [n_shared F, D].
 
@@ -349,7 +360,7 @@ def routed_ffn(params, us, x, spec, valid=None, stats=None):
              else valid.reshape(T).astype(bool))
     with jax.named_scope("moe_route"):
         sel, w = route(x, params[f"{us}_moe_router_weight"],
-                       params[f"{us}_moe_router_bias"], spec)
+                       params.get(f"{us}_moe_router_bias"), spec)
         # an invalid row's assignments go to group E, past the last
         expert = jnp.where(vmask[:, None], sel, E).reshape(-1)  # [T k]
         order = jnp.argsort(expert, stable=True)
@@ -561,31 +572,57 @@ def _init_routed_params(shapes, seed, scale, bias_scale, dtype):
 
 
 class HybridMoEConfig:
-    """A decoder whose layers are EITHER a gated short convolution OR
-    grouped-query attention (RMSNorm on every head's q and k, RoPE over
-    the whole head), each over a dense SwiGLU (the leading
-    ``num_dense_layers``) or a dropless routed FFN with no shared
-    expert: built from the source's own ``config.json`` keys (the
-    ``lfm2_moe`` family's names).  It yields the jit-static ``BlockSpec``
-    the mixed wave reads (``block_spec()``: the operator of every layer
-    is in it); the engine takes the rest from the attributes a
-    ``GPTConfig`` has too.  The head is the embedding table.  Values it
-    cannot run raise: ``conv_bias`` true, a ``layer_types`` entry other
-    than "conv" / "full_attention", a list that is not
-    ``num_hidden_layers`` long, query heads that are not a whole number
-    a K/V head.  ``use_expert_bias`` false is run with a zero selection
+    """A decoder whose layers are EACH a gated short convolution, a
+    grouped-query attention over everything before it, or the same
+    attention over a sliding window (RoPE over the whole head), each
+    over a dense SwiGLU (the leading ``num_dense_layers``) or a dropless
+    routed FFN with no shared expert: built from the source's own
+    ``config.json`` keys.  It yields the jit-static ``BlockSpec`` the
+    mixed wave reads (``block_spec()``: the operator of every layer is
+    in it); the engine takes the rest from the attributes a ``GPTConfig``
+    has too.  Two families write such a file, told apart by
+    ``model_type`` (``FAMILIES``: what a family's files do not say):
+    ``lfm2_moe`` (the default: conv and full-attention layers, RMSNorm on
+    every head's q and k, sigmoid scores with a selection bias, the
+    embedding table as the head, ``norm_eps``) and ``mellum``
+    ("sliding_attention" layers of ``sliding_window`` positions beside
+    "full_attention" ones, rotary parameters BY LAYER KIND in
+    ``rope_parameters``, YaRN among them, no q/k norm, a softmax router
+    with no bias, ``rms_norm_eps``, ``head_dim`` and
+    ``tie_word_embeddings`` its own keys).  Values it cannot run raise:
+    ``conv_bias`` or ``attention_bias`` true, a ``layer_types`` entry it
+    has no operator for, a list that is not ``num_hidden_layers`` long,
+    query heads that are not a whole number a K/V head, a "conv" layer
+    without ``conv_L_cache``, a sliding layer without ``sliding_window``,
+    a ``rope_type`` other than "default" / "yarn", an ``mlp_layer_types``
+    entry other than "sparse" past the dense layers, an activation other
+    than SiLU.  ``use_expert_bias`` false is run with a zero selection
     bias (``init_hybrid_moe_params`` and the converter make it)."""
 
-    OPERATORS = {"conv": "conv", "full_attention": "attention"}
+    OPERATORS = {"conv": "conv", "full_attention": "attention",
+                 "sliding_attention": "window_attention"}
+    FAMILIES = {
+        "lfm2_moe": {"qk_norm": True, "scoring": "sigmoid", "tied": True},
+        "mellum": {"qk_norm": False, "scoring": "softmax", "tied": False},
+    }
 
     def __init__(self, *, vocab_size, hidden_size, num_hidden_layers,
                  num_attention_heads, num_key_value_heads, layer_types,
-                 conv_L_cache, intermediate_size, moe_intermediate_size,
-                 num_experts, num_experts_per_tok, num_dense_layers=0,
+                 intermediate_size, moe_intermediate_size,
+                 num_experts, num_experts_per_tok, conv_L_cache=0,
+                 num_dense_layers=0,
                  norm_topk_prob=True, use_expert_bias=True,
                  routed_scaling_factor=1.0, rope_theta=1000000.0,
-                 norm_eps=1e-5, conv_bias=False,
-                 max_position_embeddings=128000, **ignored):
+                 norm_eps=None, rms_norm_eps=None, conv_bias=False,
+                 max_position_embeddings=128000, model_type="lfm2_moe",
+                 head_dim=None, sliding_window=0, rope_parameters=None,
+                 tie_word_embeddings=None, mlp_layer_types=None,
+                 attention_bias=False, hidden_act="silu", **ignored):
+        if model_type not in self.FAMILIES:
+            raise ValueError(
+                f"HybridMoEConfig: model_type={model_type!r}; it runs "
+                f"{sorted(self.FAMILIES)}")
+        family = self.FAMILIES[model_type]
         unknown = sorted(set(layer_types) - set(self.OPERATORS))
         if unknown:
             raise ValueError(
@@ -595,19 +632,29 @@ class HybridMoEConfig:
             raise ValueError(
                 f"HybridMoEConfig: {len(layer_types)} layer_types for "
                 f"num_hidden_layers={num_hidden_layers}")
-        if conv_bias:
-            raise ValueError("HybridMoEConfig: conv_bias=True is not "
-                             "supported (only False)")
-        if conv_L_cache < 2:
+        for key, value in (("conv_bias", conv_bias),
+                           ("attention_bias", attention_bias)):
+            if value:
+                raise ValueError(f"HybridMoEConfig: {key}=True is not "
+                                 f"supported (only False)")
+        if hidden_act != "silu":
+            raise ValueError(f"HybridMoEConfig: hidden_act={hidden_act!r} "
+                             f"is not supported (only 'silu')")
+        if "conv" in layer_types and conv_L_cache < 2:
             raise ValueError(f"conv_L_cache={conv_L_cache}: a short "
                              f"convolution has at least 2 taps")
+        if "sliding_attention" in layer_types and sliding_window < 1:
+            raise ValueError(
+                f"sliding_window={sliding_window}: a sliding_attention "
+                f"layer sees at least itself")
         if num_attention_heads % num_key_value_heads \
-                or hidden_size % num_attention_heads:
+                or (head_dim is None and hidden_size % num_attention_heads):
             raise ValueError(
                 f"hidden_size={hidden_size}, num_attention_heads="
                 f"{num_attention_heads} and num_key_value_heads="
                 f"{num_key_value_heads} do not divide")
-        if (hidden_size // num_attention_heads) % 2:
+        head_dim = int(head_dim or hidden_size // num_attention_heads)
+        if head_dim % 2:
             raise ValueError("the head size must be even (RoPE)")
         if not 1 <= num_experts_per_tok <= num_experts:
             raise ValueError(
@@ -617,6 +664,14 @@ class HybridMoEConfig:
             raise ValueError(
                 f"num_dense_layers={num_dense_layers} outside "
                 f"[0, num_hidden_layers={num_hidden_layers}]")
+        if mlp_layer_types is not None and (
+                len(mlp_layer_types) != num_hidden_layers
+                or set(mlp_layer_types[num_dense_layers:]) - {"sparse"}):
+            raise ValueError(
+                f"HybridMoEConfig: mlp_layer_types={list(mlp_layer_types)}"
+                f": every layer past the {num_dense_layers} dense ones is "
+                f"'sparse', one entry a layer")
+        self.model_type = model_type
         self.vocab_size = int(vocab_size)
         self.hidden_size = int(hidden_size)
         self.num_hidden_layers = int(num_hidden_layers)
@@ -633,47 +688,84 @@ class HybridMoEConfig:
         self.num_experts_per_tok = int(num_experts_per_tok)
         self.num_dense_layers = int(num_dense_layers)
         self.norm_topk_prob = bool(norm_topk_prob)
-        self.use_expert_bias = bool(use_expert_bias)
+        self.scoring = family["scoring"]
+        # a softmax router chooses by its scores alone
+        self.use_expert_bias = bool(use_expert_bias) \
+            and self.scoring == "sigmoid"
         self.routed_scaling_factor = float(routed_scaling_factor)
         self.rope_theta = float(rope_theta)
-        self.norm_eps = float(norm_eps)
+        eps = norm_eps if norm_eps is not None else rms_norm_eps
+        self.norm_eps = float(1e-5 if eps is None else eps)
+        self.head_dim = head_dim
+        self.qk_norm = family["qk_norm"]
+        self.tie_word_embeddings = family["tied"] \
+            if tie_word_embeddings is None else bool(tie_word_embeddings)
+        self.sliding_window = int(sliding_window) \
+            if "sliding_attention" in layer_types else 0
+        # rotary parameters BY LAYER KIND (the source's nested
+        # ``rope_parameters``), None where one ``rope_theta`` serves all
+        self.rope_parameters = rope_parameters
 
     @classmethod
     def from_hf(cls, config):
         """From a ``config.json`` dict (keys it does not know are
         ignored; the ones it cannot run raise).  Newer exports keep
-        ``rope_theta`` inside ``rope_parameters``."""
+        ``rope_theta`` inside ``rope_parameters``; a file whose
+        ``rope_parameters`` has a section a ``layer_types`` name keeps
+        them by layer kind."""
         config = dict(config)
         rope = config.pop("rope_parameters", None) or {}
-        config.setdefault("rope_theta", rope.get("rope_theta", 1000000.0))
+        if any(k in rope for k in cls.OPERATORS):
+            config["rope_parameters"] = rope
+            first = next(iter(rope.values()))
+            config.setdefault("rope_theta", first.get("rope_theta",
+                                                      1000000.0))
+        else:
+            config.setdefault("rope_theta",
+                              rope.get("rope_theta", 1000000.0))
         return cls(**config)
 
-    @property
-    def head_dim(self):
-        return self.hidden_size // self.num_attention_heads
-
     def operators(self):
-        """("conv" | "attention") for every layer."""
+        """("conv" | "attention" | "window_attention") for every
+        layer."""
         return tuple(self.OPERATORS[t] for t in self.layer_types)
+
+    def rope_by_op(self):
+        """((operator, inv_freq, factor), ...) for a file with rotary
+        parameters by layer kind (``gpt_decode.rope_frequencies``,
+        computed once, on the host), else None."""
+        if not self.rope_parameters:
+            return None
+        from .gpt_decode import rope_frequencies
+        return tuple(
+            (self.OPERATORS[kind],) + rope_frequencies(self.head_dim, **p)
+            for kind, p in sorted(self.rope_parameters.items())
+            if kind in self.layer_types)
 
     def routed_spec(self):
         return RoutedSpec(
             num_experts=self.n_routed_experts,
             top_k=self.num_experts_per_tok,
             scale=self.routed_scaling_factor,
-            norm_topk=self.norm_topk_prob, n_shared=0)
+            norm_topk=self.norm_topk_prob, n_shared=0,
+            scoring=self.scoring)
 
     def block_spec(self):
         from .gpt_decode import BlockSpec
         all_dense = self.num_dense_layers >= self.num_hidden_layers
+        own_head = self.head_dim * self.num_attention_heads \
+            != self.hidden_size
         return BlockSpec(
             norm="rmsnorm", norm_eps=self.norm_eps, positions="rope",
             rope_theta=self.rope_theta, attention="gqa", bias=False,
-            kv_heads=self.num_key_value_heads, qk_norm=True,
+            kv_heads=self.num_key_value_heads, qk_norm=self.qk_norm,
             ops=self.operators(), conv_kernel=self.conv_L_cache,
             ffn="swiglu" if all_dense else "routed",
             leading_dense=0 if all_dense else self.num_dense_layers,
-            routed=None if all_dense else self.routed_spec(), head="tied")
+            routed=None if all_dense else self.routed_spec(),
+            head="tied" if self.tie_word_embeddings else "untied",
+            head_dim=self.head_dim if own_head else 0,
+            window=self.sliding_window, rope_by_op=self.rope_by_op())
 
     def param_shapes(self, name="lfm"):
         """{leaf: shape} of the serving parameter dict: the one list
@@ -685,6 +777,8 @@ class HybridMoEConfig:
                     self.n_routed_experts)
         shapes = {f"{name}_wte_table": (self.vocab_size, d),
                   f"{name}_ln_f_scale": (d,)}
+        if not self.tie_word_embeddings:
+            shapes[f"{name}_lm_head_weight"] = (d, self.vocab_size)
         for i, op in enumerate(self.operators()):
             us = f"{name}_h{i}"
             shapes.update({f"{us}_ln1_scale": (d,), f"{us}_ln2_scale": (d,)})
@@ -698,19 +792,22 @@ class HybridMoEConfig:
                     f"{us}_attn_q_weight": (d, hq * dh),
                     f"{us}_attn_k_weight": (d, hkv * dh),
                     f"{us}_attn_v_weight": (d, hkv * dh),
-                    f"{us}_attn_q_norm_scale": (dh,),
-                    f"{us}_attn_k_norm_scale": (dh,),
                     f"{us}_attn_proj_weight": (hq * dh, d)})
+                if self.qk_norm:
+                    shapes.update({
+                        f"{us}_attn_q_norm_scale": (dh,),
+                        f"{us}_attn_k_norm_scale": (dh,)})
             if i < self.num_dense_layers:
                 shapes.update({f"{us}_ffn_gate_weight": (d, f),
                                f"{us}_ffn_up_weight": (d, f),
                                f"{us}_ffn_down_weight": (f, d)})
             else:
                 shapes.update({f"{us}_moe_router_weight": (d, E),
-                               f"{us}_moe_router_bias": (E,),
                                f"{us}_moe_experts_gate": (E, d, fe),
                                f"{us}_moe_experts_up": (E, d, fe),
                                f"{us}_moe_experts_down": (E, fe, d)})
+                if self.scoring == "sigmoid":
+                    shapes[f"{us}_moe_router_bias"] = (E,)
         return shapes
 
 

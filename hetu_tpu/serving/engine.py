@@ -237,6 +237,9 @@ class ServingEngine:
         latent = blk.latent
         L = c.num_hidden_layers
         if self.paged:
+            chunk = (prefill_chunk if prefill_chunk is not None
+                     else envvars.get_int("HETU_KV_CHUNK"))
+            self.chunk = max(int(chunk or 0), 0)
             self.kv = PagedKVManager(
                 # the pool holds the layers with an attention; the
                 # layers with a conv or a state-space mixer keep slot
@@ -248,10 +251,8 @@ class ServingEngine:
                 block=block, pool_blocks=pool_blocks,
                 prefix_share=prefix_share,
                 row_shape=(latent.row_width,) if latent else None,
-                state_shapes=blk.state_shapes(L, c.hidden_size))
-            chunk = (prefill_chunk if prefill_chunk is not None
-                     else envvars.get_int("HETU_KV_CHUNK"))
-            self.chunk = max(int(chunk or 0), 0)
+                state_shapes=blk.state_shapes(L, c.hidden_size),
+                **self._window_pool(blk, L, want, self.chunk))
         else:
             self.kv = KVCacheManager(
                 layers=c.num_hidden_layers, heads=c.num_attention_heads,
@@ -281,6 +282,7 @@ class ServingEngine:
         # ``serve.ssm.*`` (``ServingMetrics.record_ssm``)
         self._ssm_layers = self.block_spec.op_layers(
             c.num_hidden_layers, "attention+ssm")
+        self._window_recycled_seen = 0
         if self.moe is not None:
             self.cfg_tuple = self.cfg_tuple + (self.moe,)
             E = self.moe.num_experts
@@ -418,6 +420,19 @@ class ServingEngine:
             from ..analysis import jit_audit
             jit_audit.register_engine(self)
 
+    @staticmethod
+    def _window_pool(blk, layers, max_seq_len, chunk):
+        """The manager's window-pool arguments for a block spec with
+        window layers (none otherwise: such a manager has no window
+        pool and no option for one).  The ring is sized for the widest
+        q-block a wave writes: the prefill chunk, or with no chunking
+        (``chunk`` 0) a whole prompt."""
+        n = blk.op_layers(layers, "window")
+        if not n:
+            return {}
+        return {"window_layers": n, "window": blk.window,
+                "window_chunk": chunk or int(max_seq_len)}
+
     # ------------------------------------------------------------- #
     # live weight sync (serving/weight_sync.py)
     # ------------------------------------------------------------- #
@@ -510,12 +525,30 @@ class ServingEngine:
         arithmetic.  Slot b's ``q_len`` rows at positions ``pos .. pos
         + q_len - 1`` see ``pos + j + 1`` positions each, and the slot
         holds ``pos + q_len`` positions after the wave's writes; a live
-        slot's state moves once a state-space layer."""
+        slot's state moves once a state-space layer.  An engine with
+        window layers counts what those really read beside it
+        (``record_attention``'s ``window``)."""
         ql = wave["q_len"].astype(np.int64)
         pos = wave["pos"].astype(np.int64)
         ctx = int(np.where(ql > 0, pos + ql, 0).sum())
         pairs = int((ql * pos + ql * (ql + 1) // 2).sum())
-        self.metrics.record_attention(ctx, pairs)
+        window = None
+        if self.paged and self.kv.window_layers:
+            # a window layer's rows see ``min(pos + j + 1, W)`` positions
+            # each: the first ``W - pos`` rows (if any) as a full
+            # layer's, the rest ``W``; a slot's q-block has ``min(pos +
+            # q_len, W + q_len - 1)`` positions in sight
+            W = self.block_spec.window
+            grow = np.clip(W - pos, 0, ql)       # rows still under W
+            recycled = self.kv.window_blocks_recycled
+            window = (
+                int(np.where(ql > 0, np.minimum(pos + ql, W + ql - 1),
+                             0).sum()),
+                int((grow * pos + grow * (grow + 1) // 2
+                     + (ql - grow) * W).sum()),
+                recycled - self._window_recycled_seen)
+            self._window_recycled_seen = recycled
+        self.metrics.record_attention(ctx, pairs, window)
         if not self._ssm_layers:
             return None
         # row pairs (i, j <= i) inside the chunks of the chunked form: a
@@ -1024,6 +1057,12 @@ class ServingEngine:
                 # and gets it back last
                 stateful = {"state": self.kv.state} \
                     if self.kv.stateful else {}
+                if self.kv.window_layers:
+                    # the window layers' pool pair rides beside the
+                    # pool and comes back after everything else
+                    stateful.update(
+                        win=(self.kv.win_k, self.kv.win_v),
+                        ring=self.kv.win_tables.copy())
                 out = self._mixed(
                     self.params, self.cfg_tuple,
                     self.kv.cache_k, self.kv.cache_v,
@@ -1031,7 +1070,9 @@ class ServingEngine:
                     wave["q_len"], wave["first_row"], wave["self_fresh"],
                     self._temp, self._topk, keys,
                     has_fresh=bool(pre), **stateful)
-                if stateful:
+                if self.kv.window_layers:
+                    out, (self.kv.win_k, self.kv.win_v) = out[:-1], out[-1]
+                if self.kv.stateful:
                     out, self.kv.state = out[:-1], out[-1]
                 if self.routed is not None:
                     out, routed_out = out[:-1], out[-1]
@@ -1240,7 +1281,11 @@ class ServingEngine:
                 end_perf=now, spec=spec,
                 mix={"q_prefill": q_pre, "q_verify": q_ver,
                      "q_decode": n_dec},
-                moe=moe_rec, ssm=ssm_rec)
+                moe=moe_rec, ssm=ssm_rec,
+                # the window pool's ring and the most blocks a slot holds
+                window={"ring": self.kv.ring, "held_max": int(
+                    np.count_nonzero(self.kv.win_tables, axis=1).max())}
+                if self.paged and self.kv.window_layers else None)
         self._land_end = time.perf_counter()
         root.set(landed=wave_id, live=n_live, q_prefill=q_pre,
                  q_verify=q_ver, q_decode=n_dec)

@@ -467,12 +467,33 @@ class PagedKVManager:
     under the prompt's first block (``_by_head``), an eviction pops the
     least recently used end of ``_prefix`` (an admission into a full
     pool of ten thousand entries was 150 ms of scans, the device idle).
+
+    Blocks by LAYER KIND (``window_layers`` > 0): the layers that attend
+    over a sliding ``window`` keep their K/V in a pool pair of their own,
+    ``win_k`` / ``win_v`` ``[window_layers, N_win, block, W]``, with its
+    own free list and its own table a slot, ``win_tables`` ``[slots,
+    ring]``: a RING.  Position ``p`` of a slot lives in ring entry ``(p
+    // block) mod ring``, ``ring = ceil((window + window_chunk) / block)
+    + 1`` blocks (never more than a full table), where ``window_chunk``
+    is the widest q-block a wave writes: a q-block's first row still
+    sees ``window - 1`` positions before it, so the page a write
+    overwrites holds nothing that this or a later wave can see.  The
+    ring is claimed whole at ``alloc`` and returned at ``release``: a
+    window layer's reservation is a constant, whatever the prompt's
+    length, while ``cache_k`` / ``cache_v`` and ``tables`` hold the
+    full-attention layers alone, every position, as ever.  What would
+    need the ring's past is refused by name (``_refuse_window``):
+    ``prefix_share=True``, ``truncate`` below the ring's oldest
+    position, the wire, an int8 pool.  A manager without window layers
+    has none of this: its arrays, tables and programs are what they
+    were.
     """
 
     def __init__(self, *, layers, heads, head_dim, slots, max_seq_len,
                  pos_cap=None, dtype=jnp.float32, bucket=True,
                  block=16, pool_blocks=None, prefix_share=None,
-                 row_shape=None, state_shape=None, state_shapes=None):
+                 row_shape=None, state_shape=None, state_shapes=None,
+                 window_layers=0, window=0, window_chunk=0):
         if bucket:
             slots = round_up_pow2(slots)
             s = round_up_pow2(max_seq_len, floor=16)
@@ -522,11 +543,23 @@ class PagedKVManager:
                 "prefix-cache hit would start a sequence past position 0 "
                 "and the state at that block boundary is not in the pool "
                 "(no snapshots yet)")
+        self.window_layers = int(window_layers)
+        if self.window_layers and prefix_share:
+            raise ValueError(
+                "PagedKVManager: prefix_share with window layers: a "
+                "prefix-cache hit would need the last window of the "
+                "prefix in the window layers' ring, which holds the "
+                "slot's own last positions alone")
         if prefix_share is None:
-            prefix_share = not self.stateful \
+            prefix_share = not self.stateful and not self.window_layers \
                 and envvars.get_bool("HETU_KV_PREFIX_SHARE")
         self.prefix_share = bool(prefix_share)
         self.quant = "int8" if _is_int8(dtype) else None
+        if self.window_layers and (self.quant or row_shape is not None):
+            raise ValueError(
+                "PagedKVManager: window layers beside an int8 pool or "
+                "latent rows: the window kernel reads float K/V rows "
+                "(kv_quant or a latent spec with window layers)")
         if self.stateful and self.quant:
             raise ValueError(
                 "PagedKVManager: an int8 pool beside slot-indexed state: "
@@ -563,6 +596,26 @@ class PagedKVManager:
                           dtype if member_dtype is None else member_dtype)
                 for shape, member_dtype in state_shapes)
             telemetry.set_gauge("serve.state.bytes", self.state_bytes)
+        self.window = self.ring = 0
+        self.win_k = self.win_v = self.win_tables = None
+        self.window_blocks_recycled = 0
+        if self.window_layers:
+            self.window = int(window)
+            if self.window < 1:
+                raise ValueError(f"window={window}: a window layer sees "
+                                 f"at least itself")
+            self.ring = min(
+                -(-(self.window + int(window_chunk)) // self.block) + 1,
+                self.table_width)
+            # every slot's ring + the scratch block 0
+            n_win = self.n_slots * self.ring + 1
+            shape = (self.window_layers, n_win, self.block,
+                     kv_row_width(heads, head_dim))
+            self.win_k = _alloc_cache(shape, dtype, None)
+            self.win_v = _alloc_cache(shape, dtype, None)
+            self._win_free = list(range(1, n_win))
+            self.win_tables = np.zeros((self.n_slots, self.ring), np.int32)
+            telemetry.set_gauge("serve.kv.window_bytes", self.window_bytes)
         self._free = list(range(1, self.n_blocks))   # 0 = scratch
         self.ref = np.zeros(self.n_blocks, np.int32)
         self.tables = np.zeros((self.n_slots, self.table_width), np.int32)
@@ -626,10 +679,24 @@ class PagedKVManager:
         return int(np.sum(self.ref > 1))
 
     @property
-    def cache_bytes(self):
-        """Total HBM bytes of the pool pair (scales included when
-        quantized)."""
+    def full_bytes(self):
+        """HBM bytes of the pool pair that holds every position (scales
+        included when quantized): all there is without window layers."""
         return cache_nbytes(self.cache_k) + cache_nbytes(self.cache_v)
+
+    @property
+    def window_bytes(self):
+        """HBM bytes of the window layers' pool pair (0 without any)."""
+        return cache_nbytes(self.win_k) + cache_nbytes(self.win_v)
+
+    @property
+    def cache_bytes(self):
+        """Total HBM bytes of K/V: ``full_bytes + window_bytes``."""
+        return self.full_bytes + self.window_bytes
+
+    @property
+    def free_window_blocks(self):
+        return len(self._win_free) if self.window_layers else 0
 
     @property
     def occupancy(self):
@@ -637,6 +704,11 @@ class PagedKVManager:
 
     def live(self):
         return [i for i in range(self.n_slots) if self.owner[i] is not None]
+
+    def window_blocks_held(self, slot):
+        """Window-pool blocks ``slot`` holds: its ring, or 0."""
+        return int(np.count_nonzero(self.win_tables[slot])) \
+            if self.window_layers else 0
 
     def blocks_needed(self, tokens):
         return -(-int(tokens) // self.block)
@@ -651,6 +723,9 @@ class PagedKVManager:
         telemetry.set_gauge("serve.blocks_free", self.free_blocks)
         telemetry.set_gauge("serve.blocks_shared", self.blocks_shared)
         telemetry.set_gauge("serve.prefix_entries", len(self._prefix))
+        if self.window_layers:
+            telemetry.set_gauge("serve.blocks_free.window",
+                                self.free_window_blocks)
 
     # ------------------------------------------------------------- #
     # prefix cache
@@ -782,7 +857,13 @@ class PagedKVManager:
             # ref 0 only if it was not kept — `keep` pins it
             if len(self._free) < need:
                 return None, 0
+        if self.window_layers and len(self._win_free) < self.ring:
+            return None, 0
         slot = self._free_slots.pop()
+        if self.window_layers:
+            # the ring whole: a constant a slot, whatever the prompt
+            self.win_tables[slot] = [self._win_free.pop()
+                                     for _ in range(self.ring)]
         row = []
         for j in range(n_shared):
             b = entry.blocks[j]
@@ -837,7 +918,16 @@ class PagedKVManager:
 
     def advance(self, slot, n=1):
         """Record ``n`` more filled positions (blocks were reserved at
-        admission — nothing to allocate)."""
+        admission — nothing to allocate).  With window layers the pages
+        past the ring's first turn each overwrote an older one of the
+        slot's own: counted (``serve.kv.window_blocks_recycled``)."""
+        if self.window_layers:
+            old = self.blocks_needed(self.lengths[slot])
+            new = self.blocks_needed(self.lengths[slot] + n)
+            turned = max(new - self.ring, 0) - max(old - self.ring, 0)
+            if turned:
+                self.window_blocks_recycled += turned
+                telemetry.inc("serve.kv.window_blocks_recycled", turned)
         self.lengths[slot] += n
 
     def truncate(self, slot, n):
@@ -865,6 +955,13 @@ class PagedKVManager:
         self._refuse_state("truncate")
         old = int(self.lengths[slot])
         n = int(n)
+        if self.window_layers and n < old \
+                and self.blocks_needed(old) > self.ring:
+            # the ring has turned: the pages a rollback would uncover
+            # were overwritten by the positions it takes back
+            self._refuse_window(
+                f"truncate to {n} of {old} positions, below the ring's "
+                f"oldest")
         if not 0 <= n <= old:
             raise ValueError(
                 f"cannot truncate slot {slot} to {n} (filled {old})")
@@ -908,6 +1005,9 @@ class PagedKVManager:
             self.ref[b] -= 1
             if self.ref[b] == 0:
                 self._free.append(b)
+        if self.window_layers:
+            self._win_free.extend(int(b) for b in self.win_tables[slot])
+            self.win_tables[slot] = 0
         self.tables[slot, :] = 0
         self.n_table[slot] = 0
         self.owner[slot] = None
@@ -986,6 +1086,15 @@ class PagedKVManager:
                 f"position: it can neither be rolled back nor shipped "
                 f"with a span of blocks (no snapshots yet)")
 
+    def _refuse_window(self, what):
+        if self.window_layers:
+            raise ValueError(
+                f"{what}: this manager's window layers keep a ring of "
+                f"{self.ring} blocks a slot, the last {self.window} "
+                f"positions and a q-block: older positions are "
+                f"overwritten, so they can be neither rolled back to nor "
+                f"shipped with a span of blocks")
+
     def _export_span(self, idx, length, quant_mode, *, count=True):
         """Gather pool blocks ``idx`` into the wire payload (shared by
         the slot and prefix export paths).  ``count=False`` keeps the
@@ -994,6 +1103,7 @@ class PagedKVManager:
         traffic (the tier store keeps its own byte counters)."""
         self._refuse_latent("export_blocks/export_prefix")
         self._refuse_state("export_blocks/export_prefix")
+        self._refuse_window("export_blocks/export_prefix")
         mode = resolve_handoff_quant(quant_mode)
 
         def gather(cache):
@@ -1028,6 +1138,7 @@ class PagedKVManager:
         Block size and layout must match; a mismatch raises."""
         self._refuse_latent("import_blocks")
         self._refuse_state("import_blocks")
+        self._refuse_window("import_blocks")
         if payload.get("layout") != "paged":
             raise ValueError(
                 f"cannot import a {payload.get('layout')!r} payload "
@@ -1105,6 +1216,12 @@ class PagedKVManager:
             "import_bytes": self.import_bytes,
             "quant": self.quant or "off",
             "cache_bytes": self.cache_bytes,
+            "full_bytes": self.full_bytes,
+            "window_bytes": self.window_bytes,
+            "window_layers": self.window_layers,
+            "window_ring": self.ring,
+            "window_blocks_free": self.free_window_blocks,
+            "window_blocks_recycled": self.window_blocks_recycled,
             "latent": self.latent,
             "state_bytes": self.state_bytes,
             "state_resets": self.state_resets,

@@ -157,6 +157,11 @@ class TieredKVStore:
                 "kv_tiers: a pool with slot-indexed state beside it cannot "
                 "spill or fetch (a fetched prefix would start a sequence "
                 "without its state: kv_manager._refuse_state)")
+        if getattr(kv, "window_layers", 0):
+            raise ValueError(
+                "kv_tiers: a pool with window layers cannot spill or fetch "
+                "(a span of blocks would travel without the window "
+                "layers' ring: kv_manager._refuse_window)")
         if not self.enabled or not getattr(kv, "prefix_share", False):
             return
         block = getattr(kv, "block", None)

@@ -208,6 +208,10 @@ class ServingMetrics(MetricsCore):
         self.moe_load = None
         self.attn_ctx_tokens = 0
         self.attn_score_pairs = 0
+        # an engine with window layers (``record_attention``)
+        self.attn_window_ctx_tokens = 0
+        self.attn_window_score_pairs = 0
+        self.window_blocks_recycled = 0
         self.ssm_slot_steps = 0
         self.ssm_rows = 0
         self.ssm_chunk_pairs = 0
@@ -252,17 +256,32 @@ class ServingMetrics(MetricsCore):
         if rows_dead:
             telemetry.inc("serve.wave.rows_dead_ahead", int(rows_dead))
 
-    def record_attention(self, ctx_tokens, score_pairs):
+    def record_attention(self, ctx_tokens, score_pairs, window=None):
         """One wave of any engine: ``ctx_tokens`` (the live slots'
         filled lengths after the wave's writes, once a wave) and
-        ``score_pairs`` (the positions every live row sees).  Running
+        ``score_pairs`` (the positions every live row sees): what a
+        layer that attends over everything reads.  Running
         sums here (``snapshot(since=mark)`` windows them) and the
         counters ``serve.attn.ctx_tokens`` and
-        ``serve.attn.score_pairs`` in ``telemetry``."""
+        ``serve.attn.score_pairs`` in ``telemetry``.  An engine with
+        window layers adds ``window`` = (the positions a WINDOW layer
+        had in sight, the pairs it scored, the window-pool blocks the
+        wave's writes recycled): ``attn_window_ctx_tokens``,
+        ``attn_window_score_pairs``, ``window_blocks_recycled`` and the
+        counters ``serve.attn.window_ctx_tokens``,
+        ``serve.attn.window_score_pairs`` (``serve.kv.
+        window_blocks_recycled`` is the manager's own)."""
         self.attn_ctx_tokens += int(ctx_tokens)
         self.attn_score_pairs += int(score_pairs)
         telemetry.inc("serve.attn.ctx_tokens", int(ctx_tokens))
         telemetry.inc("serve.attn.score_pairs", int(score_pairs))
+        if window is not None:
+            ctx, pairs, recycled = (int(v) for v in window)
+            self.attn_window_ctx_tokens += ctx
+            self.attn_window_score_pairs += pairs
+            self.window_blocks_recycled += recycled
+            telemetry.inc("serve.attn.window_ctx_tokens", ctx)
+            telemetry.inc("serve.attn.window_score_pairs", pairs)
 
     def record_ssm(self, live_slots, rows, chunk_pairs, layers):
         """One wave of an engine with ``layers`` state-space layers:
@@ -388,7 +407,7 @@ class ServingMetrics(MetricsCore):
     def record_step(self, live, slots, queue_depth, dt_s, new_tokens,
                     prefill_s=0.0, step=None, requests=None,
                     end_perf=None, spec=None, mix=None, moe=None,
-                    ssm=None):
+                    ssm=None, window=None):
         """One fused decode step; ``prefill_s`` is the prefill wall time
         this scheduler iteration paid before decoding, so the per-step
         JSONL event attributes the phases separately (the masked vs
@@ -424,7 +443,12 @@ class ServingMetrics(MetricsCore):
         ``ssm`` (``record_ssm``'s {slot_steps, rows, live_slots, layers}
         dict, engines with state-space layers only) stamps the wave's
         state traffic: ``slot_steps == live_slots * layers`` is the
-        invariant hetu_trace --check enforces."""
+        invariant hetu_trace --check enforces.
+
+        ``window`` ({ring, held_max}, engines with window layers only)
+        stamps the window pool's ring and the most window blocks any
+        slot holds: ``held_max <= ring`` is the invariant hetu_trace
+        --check enforces."""
         self._mark()
         self._slots = slots
         self.step_live.append(live)
@@ -461,6 +485,9 @@ class ServingMetrics(MetricsCore):
         if ssm is not None:
             for k in ("slot_steps", "rows", "live_slots", "layers"):
                 fields[f"ssm_{k}"] = int(ssm.get(k, 0))
+        if window is not None:
+            fields["window_ring"] = int(window["ring"])
+            fields["window_held_max"] = int(window["held_max"])
         self.event("serve_step", live=live, queue_depth=queue_depth,
                    slots=slots, new_tokens=int(new_tokens),
                    prefill_ms=round(prefill_s * 1e3, 3),
@@ -582,6 +609,8 @@ class ServingMetrics(MetricsCore):
                     "tokens_generated", "prefill_batched",
                     "moe_assignments", "moe_experts_touched",
                     "moe_kernel_waves", "attn_ctx_tokens", "attn_score_pairs",
+                    "attn_window_ctx_tokens", "attn_window_score_pairs",
+                    "window_blocks_recycled",
                     "ssm_slot_steps", "ssm_rows", "ssm_chunk_pairs",
                     "wave_rows_live", "wave_rows_computed",
                     "chunks_deferred", "waves_ahead", "rows_dead_ahead")
@@ -660,6 +689,9 @@ class ServingMetrics(MetricsCore):
             **routed,
             "attn_ctx_tokens": count("attn_ctx_tokens"),
             "attn_score_pairs": count("attn_score_pairs"),
+            "attn_window_ctx_tokens": count("attn_window_ctx_tokens"),
+            "attn_window_score_pairs": count("attn_window_score_pairs"),
+            "window_blocks_recycled": count("window_blocks_recycled"),
             "ssm_slot_steps": count("ssm_slot_steps"),
             "ssm_rows": count("ssm_rows"),
             "ssm_chunk_pairs": count("ssm_chunk_pairs"),

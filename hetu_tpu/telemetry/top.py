@@ -52,6 +52,14 @@ the ``serve.expert_load``/``serve.expert_drops`` counters' step-level
 twins) and ``--fleet`` grows per-replica ``imb``/``drop%`` columns;
 dense replicas render "-".
 
+Window layers (ISSUE 42): an engine whose block spec has sliding-window
+layers keeps their K/V in a pool of its own, a ring of blocks a slot;
+the single-engine view grows a ``kv window`` line (the window pool's
+free blocks and bytes from the ``serve.blocks_free.window`` /
+``serve.kv.window_bytes`` gauges, the ring and the most blocks a slot
+holds from the last ``serve_step``'s ``window_ring`` /
+``window_held_max``).  Other engines render no such line.
+
 Elastic fleet (ISSUE 16): ``--fleet`` grows a per-replica ``life``
 column (warming/serving/draining/retired, from the router's
 ``replica_warming``/``replica_ready``/``replica_draining``/
@@ -190,6 +198,17 @@ def summarize(events, window=512):
         moe = {"routed": moe_routed, "dropped": moe_dropped,
                "imbalance": moe_imb,
                "drop_rate": round(moe_dropped / tot, 4) if tot else 0.0}
+    # window layers (ISSUE 42): the window pool's gauges and the ring
+    # the newest wave stamped
+    ringed = [s for s in steps if isinstance(s.get("window_ring"), int)]
+    kv_window = None
+    if ringed or gauges.get("serve.kv.window_bytes") is not None:
+        newest = ringed[-1] if ringed else {}
+        kv_window = {
+            "blocks_free": gauges.get("serve.blocks_free.window"),
+            "bytes": gauges.get("serve.kv.window_bytes"),
+            "ring": newest.get("window_ring"),
+            "held_max": newest.get("window_held_max")}
     spec = {
         "drafted": drafted,
         "accepted": accepted,
@@ -223,6 +242,7 @@ def summarize(events, window=512):
         "spec": spec,
         "mix": mix,
         "moe": moe,
+        "kv_window": kv_window,
         "slo": slo,
         "flight_dumps": flight_dumps,
         "weight_version": weight_version,
@@ -578,6 +598,13 @@ def render(stats, clock=None):
             f"  dropped {me['dropped']}"
             f"  imbalance {_fmt(me['imbalance'], nd=2)}"
             f"  drop_rate {_fmt(me['drop_rate'], nd=4)}"))
+    kw = s.get("kv_window")
+    if kw:
+        lines.insert(-1, (
+            f"kv window blocks_free {_fmt(kw['blocks_free'])}"
+            f"  ring {_fmt(kw['ring'])}"
+            f"  held_max {_fmt(kw['held_max'])}"
+            f"  bytes {_fmt(kw['bytes'])}"))
     return "\n".join(lines)
 
 

@@ -91,6 +91,11 @@ request waited long between its claim and its first token outside
 every wave (a paused host, or wave attribution that broke; the
 assertion this replaced raised out of the scheduler).
 
+``--check`` also enforces the window-ring rule (ISSUE 42): a
+``serve_step`` record of an engine with window layers carries
+``window_ring`` and ``window_held_max``, and no slot holds more
+window-pool blocks than its ring.
+
 ``--check`` also enforces the wave-pairing rule (ISSUE 40): on every
 thread, each ``serve.wave.dispatch`` span has exactly one
 ``serve.wave.sync`` span after it with the same ``wave=`` and
@@ -759,6 +764,30 @@ def check_ssm_attribution(events):
     return problems
 
 
+def check_window_ring(events):
+    """The window-pool rule (ISSUE 42): a ``serve_step`` record of an
+    engine with window layers carries ``window_ring`` (the blocks of a
+    slot's ring) and ``window_held_max`` (the most window-pool blocks
+    any slot holds): a slot never holds more than its ring, whatever
+    its sequence's length.  Records without ``window_ring`` are exempt;
+    one missing the companion field is itself a violation.  Returns
+    problem strings."""
+    problems = []
+    for e in events:
+        if e.get("event") != "serve_step" or "window_ring" not in e:
+            continue
+        ring, held = e.get("window_ring"), e.get("window_held_max")
+        if not isinstance(ring, int) or not isinstance(held, int):
+            problems.append(
+                f"window-ring: step {e.get('step')!r} carries window_ring "
+                f"without an integer window_held_max")
+        elif held > ring:
+            problems.append(
+                f"window-ring: step {e.get('step')!r}: a slot holds {held} "
+                f"window blocks, over its ring of {ring}")
+    return problems
+
+
 def check_span_nesting(events):
     """The span-nesting rule: a ``span`` record that names a ``parent``
     must find a span of that name on its own pid/thread whose interval
@@ -960,6 +989,8 @@ def main(argv=None):
         problems.extend(moe)
         ssm = check_ssm_attribution(events)
         problems.extend(ssm)
+        ring = check_window_ring(events)
+        problems.extend(ring)
         nesting = check_span_nesting(events)
         problems.extend(nesting)
         residue = check_lifecycle_residue(events)
@@ -981,6 +1012,7 @@ def main(argv=None):
                           "lockdep_violations": len(lockdep),
                           "moe_attribution_violations": len(moe),
                           "ssm_attribution_violations": len(ssm),
+                          "window_ring_violations": len(ring),
                           "span_nesting_violations": len(nesting),
                           "lifecycle_residue_violations":
                               len(residue),
