@@ -39,7 +39,10 @@ under the same wrapper and the same HLO name: pool rows of whole lane
 tiles left in HBM, grid (slot, q-tile), pages copied by hand a group at
 a time with the layer in the copy, the same per-slot data (q_len,
 kv_len, tables), mask, dead-tile rule and f32 online softmax (see the
-comment block over it).  ONE masked-gather reference
+comment block over it), and a (slot, q-tile) step scored at the height
+its live rows need (``tile_heights``: a decoding slot's one row beside
+a prompt chunk costs one sublane tile of queries, not the tile's 64).
+ONE masked-gather reference
 (``ragged_masked_reference``) serves them all for off-TPU
 interpret-mode parity — kernels/decode_attention.py's two per-mode
 references delegate here, and its contiguous decode and verify kernels
@@ -353,17 +356,90 @@ def _page_group(T, bs, W, dtype):
     return max(1, min(_PAGE_GROUP, T, (1 << 20) // page))
 
 
-def _tile_in_sight(lens_ref, qlens_ref, tq, bs, group):
+def tile_heights(q_len, t, tq, short):
+    """THE rule of a q-tile's height in the hand-paged kernels: of q-tile
+    ``t`` (``tq`` queries) of a q-block with ``q_len`` live rows, whether
+    it is LIVE (holds a live query) and whether it is scored at its FULL
+    height, all ``tq`` queries; a live tile whose live rows fit the
+    program's ``short`` height (one sublane tile of queries, ``< tq``)
+    is scored at that height: a decoding slot's one row beside a prompt
+    chunk's 256 costs 16 queries of scores, not 64.  Plain operators, so
+    the kernels ask it of a prefetched scalar and the engine's counters
+    (``serve.attn.tiles_live``, ``serve.attn.tiles_short``) of a wave's
+    ``q_len`` array.  A program with one height (``short`` 0) never asks:
+    :func:`_tile_in_sight`."""
+    rows = q_len - t * tq
+    return rows > 0, rows > short
+
+
+def _short_height(tq, sub):
+    """The short height of a program whose q-tiles are ``tq`` queries:
+    ``sub`` where that is less than a tile, else 0 (one height: the Q 1
+    decode programs and the verify programs, whose lowered text is the
+    one-height kernel's)."""
+    return sub if sub < tq else 0
+
+
+# Rows a lane chunk's product must have at the SHORT height for a second
+# height to pay.  At GPT-2 XL's width (two heads of 64 a lane chunk, one
+# query head a K/V head) the short product is 32 rows against the full
+# one's 128, and the MXU's weight loads bound both: a page group cost
+# 3.7 us at the one height and 4.4 under two (my chip runs, PR 43; the
+# cell lost 4 % of its rate and `attention_chunk_wave_ms` rose 11.6 ->
+# 14.3).  With 4 to 8 query heads a K/V head it is 80-128 rows against
+# 320-512, 2.5 us a group against 6.1.
+_SHORT_MIN_ROWS = 64
+
+
+def rows_tiling(Q, H, head_dim, groups, dtype):
+    """(padded Q, queries a q-tile, short height) of the K/V rows
+    kernel's program for a q-block of ``Q`` queries of ``H`` query heads
+    of ``head_dim``, ``groups`` a K/V head, in ``dtype``: whole sublane
+    tiles of query rows (8 of f32, 16 of bf16), tiles of at most
+    ``_MAX_ROWS`` (head, query) rows, and a second height of one sublane
+    tile of queries where a tile is taller than that and a lane chunk's
+    product at it still has ``_SHORT_MIN_ROWS`` rows."""
+    sub = 32 // jnp.dtype(dtype).itemsize
+    Qp = -(-Q // sub) * sub
+    tq = _fit_block(max(_MAX_ROWS // H, 1), Qp)
+    cw = _lane_chunk(kv_row_width(H // groups, head_dim), head_dim)
+    if min(cw // head_dim, H // groups) * groups * sub < _SHORT_MIN_ROWS:
+        return Qp, tq, 0
+    return Qp, tq, _short_height(tq, sub)
+
+
+def _tile_in_sight(lens_ref, qlens_ref, tq, bs, group, short=0):
     """This grid step's (slot, q-tile, groups of ``group`` pages the tile
-    can see, last page in sight).  A dead slot, and a tile wholly in the
-    q-block's dead tail, see no group."""
+    can see, last page in sight, whether it is scored at the full
+    height).  A dead slot, and a tile wholly in the q-block's dead tail,
+    see no group.  In a program with two heights (``short`` > 0) the
+    tile's live rows decide the height (:func:`tile_heights`; a dead
+    tile takes the short one, to finalize its zeros), and a slot with
+    ``q_len`` 0 is dead whatever it has filled; a program with one keeps
+    ``_live_tile`` (and ``full`` is None)."""
     b = pl.program_id(0)
     t = pl.program_id(1)
     span = group * bs
     end = _visible_end(lens_ref, qlens_ref, b, t, tq)
-    live = _live_tile(qlens_ref, b, t, tq) & (end > 0)
+    if short:
+        live, full = tile_heights(qlens_ref[b], t, tq, short)
+    else:
+        live, full = _live_tile(qlens_ref, b, t, tq), None
+    live &= end > 0
     return (b, t, jnp.where(live, (end + span - 1) // span, 0),
-            jnp.maximum(end - 1, 0) // bs)
+            jnp.maximum(end - 1, 0) // bs, full)
+
+
+def _at_heights(heights, part, *args):
+    """Run ``part(height, *args)`` at the height this grid step's tile
+    takes: ``heights`` = (tile, short height, ``full``): the one there
+    is, or of two the one ``full`` (traced) picks."""
+    tq, short, full = heights
+    if not short:
+        part(tq, *args)
+        return
+    pl.when(full)(lambda: part(tq, *args))
+    pl.when(jnp.logical_not(full))(lambda: part(short, *args))
 
 
 def _first_group(lens_ref, qlens_ref, b, t, tq, span, window):
@@ -375,10 +451,10 @@ def _first_group(lens_ref, qlens_ref, b, t, tq, span, window):
     return jnp.maximum(first_q - window + 1, 0) // span
 
 
-def _reset(m_ref, l_ref, acc_ref):
-    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-    l_ref[...] = jnp.zeros_like(l_ref)
-    acc_ref[...] = jnp.zeros_like(acc_ref)
+def _reset(m_ref, l_ref, acc_ref, at=(Ellipsis,)):
+    """Empty accumulators in the rows ``at`` (all of them by default)."""
+    for ref, fill in ((m_ref, NEG_INF), (l_ref, 0.0), (acc_ref, 0.0)):
+        ref[at] = jnp.full(ref.at[at].shape, fill, ref.dtype)
 
 
 def _page_loop(n_groups, copies, score):
@@ -413,8 +489,10 @@ def _mask_scores(s, qi, gi, filled, qlen, window=0):
     """Causal mask of a group's scores ``s`` [R, span]: row r is query
     ``qi[r]`` of the q-block, at absolute position ``filled - qlen +
     qi`` (dead rows clip to the last live position, as
-    ``_query_positions``), and admits kv positions up to itself; under
-    a ``window`` the band ``posq - window < kv_pos <= posq``."""
+    ``_query_positions``: the dead rows INSIDE a tile's scored height,
+    that is; the rows past a short tile's height are scored by nobody
+    and come back zero), and admits kv positions up to itself; under a
+    ``window`` the band ``posq - window < kv_pos <= posq``."""
     kv_pos = gi * s.shape[1] + jax.lax.broadcasted_iota(
         jnp.int32, s.shape, 1)
     posq = jnp.minimum(filled - qlen + qi, filled - 1)
@@ -448,8 +526,9 @@ def _softmax_step(s, v, m_ref, l_ref, acc_ref, at=(slice(None),),
 def _kv_rows_kernel(lens_ref, qlens_ref, bt_ref, layer_ref, q_ref, k_pool,
                     v_pool, o_ref, k_buf, v_buf, sem, m_ref, l_ref, acc_ref,
                     *, scale, bs, group, tq, heads, dh, cw, precision,
-                    qpk=1, window=0):
-    b, t, n_groups, last = _tile_in_sight(lens_ref, qlens_ref, tq, bs, group)
+                    qpk=1, window=0, short=0):
+    b, t, n_groups, last, full = _tile_in_sight(lens_ref, qlens_ref, tq, bs,
+                                                group, short)
     # under a window the page loop starts at the first group in sight:
     # ``g0`` groups are passed over, and ``at(gi)`` is the group the
     # loop's step ``gi`` copies and scores
@@ -459,19 +538,12 @@ def _kv_rows_kernel(lens_ref, qlens_ref, bt_ref, layer_ref, q_ref, k_pool,
         n_groups = jnp.maximum(n_groups - g0, 0)
         at = lambda gi: g0 + gi                            # noqa: E731
     layer = layer_ref[0]
-    _reset(m_ref, l_ref, acc_ref)
     # (chunk, K/V heads in it): the row's last chunk may hold fewer
     # (GPT-2 XL: head 24 alone beside 64 pad lanes, which are never
     # scored)
     per = cw // dh
     chunks = [(c, min(per, heads - c * per))
               for c in range(q_ref.shape[2] // cw)]
-
-    def member(r):
-        """Rows of the q (or o) tile that hold member ``r`` of every K/V
-        head's ``qpk`` query heads (all of the tile where ``qpk`` is 1:
-        a query head then IS its K/V head)."""
-        return slice(None) if qpk == 1 else slice(r * tq, (r + 1) * tq)
 
     def copies(gi, buf):
         """Group ``gi``'s K and V page copies into buffer ``buf``; pages
@@ -491,47 +563,96 @@ def _kv_rows_kernel(lens_ref, qlens_ref, bt_ref, layer_ref, q_ref, k_pool,
         """Lanes of a chunk that are head ``g``'s (of the chunk)."""
         return jax.lax.broadcasted_iota(jnp.int32, shape, 1) // dh == g
 
-    def score(gi, buf):
-        filled, qlen = lens_ref[b], qlens_ref[b]
-        for c, n in chunks:
-            lanes = slice(c * cw, (c + 1) * cw)
-            # the chunk's query heads stacked along the rows (K/V head
-            # g's ``qpk`` members one after another), each seeing its
-            # own K/V head's lanes of the query alone
-            qcs = [q_ref[0, member(r), lanes] for r in range(qpk)]
-            q2 = jnp.concatenate(
-                [jnp.where(own_lanes(qc.shape, g), qc, 0)
-                 for g in range(n) for qc in qcs], axis=0)
-            s = jax.lax.dot_general(
-                q2, k_buf[buf, :, lanes], (((1,), (1,)), ((), ())),
-                precision=precision,
-                preferred_element_type=jnp.float32) * scale
-            qi = t * tq + jax.lax.broadcasted_iota(
-                jnp.int32, s.shape, 0) % tq
-            _softmax_step(_mask_scores(s, qi, at(gi), filled, qlen, window),
-                          v_buf[buf, :, lanes], m_ref, l_ref, acc_ref,
-                          at=(c, slice(0, n * qpk * tq)),
-                          precision=precision)
+    def member(hq, r):
+        """Rows of the q (or o) tile that hold the first ``hq`` queries
+        of member ``r`` of every K/V head's ``qpk`` query heads (all of
+        the tile where ``qpk`` is 1 at the full height: a query head
+        then IS its K/V head)."""
+        if qpk == 1 and hq == tq:
+            return slice(None)
+        return slice(r * tq, r * tq + hq)
 
-    _page_loop(n_groups, copies, score)
-    for c, n in chunks:
-        l = l_ref[c, 0:n * qpk * tq, 0:1]
-        o2 = acc_ref[c, 0:n * qpk * tq] / jnp.where(l == 0.0, 1.0, l)
+    def over_chunks(hq, part, *args):
+        """``part(hq, c, n, *args)`` for every lane chunk ``c`` of ``n``
+        K/V heads: one after another at the full height; at the short
+        one a LOOP over the chunks of ``per`` heads, so that a chunk
+        program lowers the short height's body once and not once a lane
+        chunk (a program is traced and lowered at every warm set-up:
+        1.43 s at GPT-2 XL's 13 chunks with both heights unrolled where
+        the one height took 0.71, sandbox, PR 43)."""
+        looped = sum(n == per for _, n in chunks) if hq < tq else 0
+        if looped > 1:
+            def body(c, carry):
+                part(hq, c, per, *args)
+                return carry
+            jax.lax.fori_loop(0, looped, body, 0)
+        else:
+            looped = 0
+        for c, n in chunks[looped:]:
+            part(hq, c, n, *args)
+
+    def lanes_of(c):
+        """Lane chunk ``c``'s lanes (``c`` the loop's index, or static)."""
+        if isinstance(c, int):
+            return slice(c * cw, (c + 1) * cw)
+        return pl.ds(pl.multiple_of(c * cw, cw), cw)
+
+    def reset(hq):
+        """Empty accumulators for ``hq`` queries; a short tile's rows
+        past them come back zero."""
+        _reset(m_ref, l_ref, acc_ref, (Ellipsis,) if hq == tq else
+               (slice(None), slice(0, per * qpk * hq)))
+        if hq < tq:
+            o_ref[...] = jnp.zeros_like(o_ref)
+
+    def score(hq, c, n, gi, buf, filled, qlen):
+        """Group ``gi`` scored by the tile's first ``hq`` queries of
+        lane chunk ``c``."""
+        lanes = lanes_of(c)
+        # the chunk's query heads stacked along the rows (K/V head g's
+        # ``qpk`` members one after another), each seeing its own K/V
+        # head's lanes of the query alone
+        qcs = [q_ref[0, member(hq, r), lanes] for r in range(qpk)]
+        q2 = jnp.concatenate(
+            [jnp.where(own_lanes(qc.shape, g), qc, 0)
+             for g in range(n) for qc in qcs], axis=0)
+        s = jax.lax.dot_general(
+            q2, k_buf[buf, :, lanes], (((1,), (1,)), ((), ())),
+            precision=precision,
+            preferred_element_type=jnp.float32) * scale
+        qi = t * tq + jax.lax.broadcasted_iota(
+            jnp.int32, s.shape, 0) % hq
+        _softmax_step(
+            _mask_scores(s, qi, at(gi), filled, qlen, window),
+            v_buf[buf, :, lanes], m_ref, l_ref, acc_ref,
+            at=(c, slice(0, n * qpk * hq)), precision=precision)
+
+    def finalize(hq, c, n):
+        rows = slice(0, n * qpk * hq)
+        l = l_ref[c, rows, 0:1]
+        o2 = acc_ref[c, rows] / jnp.where(l == 0.0, 1.0, l)
         for r in range(qpk):
-            oc = jnp.zeros((tq, cw), jnp.float32)
+            oc = jnp.zeros((hq, cw), jnp.float32)
             for g in range(n):
-                at = (g * qpk + r) * tq
-                oc = jnp.where(own_lanes(oc.shape, g), o2[at:at + tq], oc)
-            o_ref[0, member(r), c * cw:(c + 1) * cw] = \
-                oc.astype(o_ref.dtype)
+                row = (g * qpk + r) * hq
+                oc = jnp.where(own_lanes(oc.shape, g), o2[row:row + hq], oc)
+            o_ref[0, member(hq, r), lanes_of(c)] = oc.astype(o_ref.dtype)
+
+    # the page copies are one text for both heights; what is scored of a
+    # group follows the tile's height
+    heights = (tq, short, full)
+    _at_heights(heights, reset)
+    _page_loop(n_groups, copies, lambda gi, buf: _at_heights(
+        heights, over_chunks, score, gi, buf, lens_ref[b], qlens_ref[b]))
+    _at_heights(heights, over_chunks, finalize)
 
 
 @functools.partial(jax.jit,
                    static_argnames=("heads", "head_dim", "tq", "interpret",
-                                    "qpk", "window"))
+                                    "qpk", "window", "short"))
 def _paged_rows_call(lengths, q_lens, block_tables, layer, qr, pool_k,
                      pool_v, *, heads, head_dim, tq, interpret, qpk=1,
-                     window=0):
+                     window=0, short=0):
     """``_kv_rows_kernel`` over query rows ``qr`` [B, Q, W] (``Q`` whole
     sublane tiles) and the pool pair; with ``qpk`` query heads a K/V
     head, ``qr`` is [B, qpk * Q, W], tile by tile the ``qpk`` members'
@@ -543,7 +664,8 @@ def _paged_rows_call(lengths, q_lens, block_tables, layer, qr, pool_k,
     program against the blocked kernel's 3.4 (sandbox, PR 31), which the
     warm set-up of a cell pays for each of its 19 programs before the
     compile cache can be asked.  ``window`` > 0 is the banded kernel,
-    named ``ragged_paged_window`` in the trace."""
+    named ``ragged_paged_window`` in the trace; ``short`` > 0 is the
+    program's second height (:func:`rows_tiling`)."""
     B, Q, W = qr.shape
     bs = pool_k.shape[2]
     cw = _lane_chunk(W, head_dim)
@@ -569,7 +691,7 @@ def _paged_rows_call(lengths, q_lens, block_tables, layer, qr, pool_k,
         functools.partial(_kv_rows_kernel, scale=head_dim ** -0.5, bs=bs,
                           group=group, tq=tq, heads=heads, dh=head_dim,
                           cw=cw, precision=_prec(qr.dtype), qpk=qpk,
-                          window=window),
+                          window=window, short=short),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Q, W), qr.dtype),
         name="ragged_paged_window" if window else "ragged_paged_mixed",
@@ -629,6 +751,21 @@ def ragged_paged_attention(q, pool_k, pool_v, lengths, q_lens,
     are fetched per slot but stored once.  Returns o [B, Q, H, Dh] in
     q's dtype (f32 accumulators).
 
+    A q-tile is scored at the HEIGHT its live rows need
+    (:func:`tile_heights`, asked of the prefetched ``q_len``): where a
+    q-block is cut into tiles taller than one sublane tile of queries
+    (:func:`rows_tiling`: 16 of bf16, 8 of f32), a live tile whose live
+    rows fit that many copies the same pages and scores, masks,
+    accumulates and finalizes those queries alone, and its rows past
+    them come back ZERO (no reader takes a dead row: ``_Rows.pack``
+    gathers live rows and every reader masks by ``q_len``); a slot with
+    ``q_len`` 0 is dead there whatever it has filled.  A taller tile is
+    scored whole, its dead rows clipped to the last live position.  Live
+    rows are the same to the bit at either height (the same products
+    row by row, the same order of page groups).  A q-block of one short
+    tile (a decode wave, a verify wave) has ONE height and the kernel
+    there was, operation for operation.
+
     ``window`` > 0 (static) scores a SLIDING WINDOW: a query at position
     ``p`` admits ``p - window < kv <= p``, itself and the ``window - 1``
     before it.  A (slot, q-tile) step then starts its page loop at the
@@ -669,23 +806,21 @@ def ragged_paged_attention(q, pool_k, pool_v, lengths, q_lens,
             f"heads of {Dh}; the pool's are {W} wide")
     # whole sublane tiles of query rows (8 of f32, 16 of bf16): a decode
     # wave's single row rides in one, its dead rows scored by no one
-    sub = 32 // jnp.dtype(q.dtype).itemsize
+    Qp, tq, short = rows_tiling(Q, H, Dh, groups, q.dtype)
     if groups == 1:
         qr = kv_rows(q, W)
-        if Q % sub:
-            qr = jnp.pad(qr, ((0, 0), (0, -Q % sub), (0, 0)))
-        tq = _fit_block(max(_MAX_ROWS // H, 1), qr.shape[1])
+        if Qp > Q:
+            qr = jnp.pad(qr, ((0, 0), (0, Qp - Q), (0, 0)))
     else:
-        if Q % sub:
-            q = jnp.pad(q, ((0, 0), (0, -Q % sub), (0, 0), (0, 0)))
-        tq = _fit_block(max(_MAX_ROWS // H, 1), q.shape[1])
+        if Qp > Q:
+            q = jnp.pad(q, ((0, 0), (0, Qp - Q), (0, 0), (0, 0)))
         qr = _grouped_rows(q, W, tq, groups)
     o = _paged_rows_call(
         lengths.astype(jnp.int32), q_lens.astype(jnp.int32),
         block_tables.astype(jnp.int32),
         jnp.asarray(layer, jnp.int32).reshape(1), qr, pool_k, pool_v,
         heads=H // groups, head_dim=Dh, interpret=interpret, tq=tq,
-        qpk=groups, window=int(window))
+        qpk=groups, window=int(window), short=short)
     if groups == 1:
         return kv_heads(o[:, :Q], H, Dh)
     return _ungrouped_rows(o, H, Dh, tq, groups)[:, :Q]
@@ -774,11 +909,29 @@ def _mla_q_tile(Q, H):
     return _fit_block(max(_MLA_TILE_ROWS // H, 1), Q)
 
 
+# Queries of the latent kernel's short height.  Its rows are query-major
+# ((query, head) pairs, all heads of a query together), so the height
+# need not be the query dtype's sublane tile.  One call of a chunk wave
+# at the long-answer cell's shapes (2 chunks of 256 beside 30 decoding
+# slots at 800-2,200 positions; my chip run, PR 43): 4.37 ms at the one
+# height of 64 queries, 3.60 with a short height of 16, 3.43 of 8, 3.39
+# of 4.  8: the step is by then bound by what does not scale with its
+# rows, and 160 rows of 20 heads are whole sublane tiles of bf16.
+_MLA_SHORT_QUERIES = 8
+
+
+def mla_tiling(Q, H):
+    """(queries a q-tile, short height) of the latent kernel's program
+    for a q-block of ``Q`` queries of ``H`` heads."""
+    tq = _mla_q_tile(Q, H)
+    return tq, _short_height(tq, _MLA_SHORT_QUERIES)
+
+
 def _mla_kernel(lens_ref, qlens_ref, bt_ref, q_ref, pool_ref, o_ref,
                 kv_buf, sem, m_ref, l_ref, acc_ref, *, scale, bs, group,
-                tq, heads, dv, layer):
-    b, t, n_groups, last = _tile_in_sight(lens_ref, qlens_ref, tq, bs, group)
-    _reset(m_ref, l_ref, acc_ref)
+                tq, heads, dv, layer, short=0):
+    b, t, n_groups, last, full = _tile_in_sight(lens_ref, qlens_ref, tq, bs,
+                                                group, short)
 
     def copies(gi, buf):
         """Group ``gi``'s page copies into buffer ``buf``; pages past
@@ -789,8 +942,20 @@ def _mla_kernel(lens_ref, qlens_ref, bt_ref, q_ref, pool_ref, o_ref,
             kv_buf.at[buf, pl.ds(g * bs, bs)], sem.at[buf])
             for g in range(group)]
 
-    def score(gi, buf):
-        q = q_ref[0]                                      # [R, W]
+    def rows(hq):
+        """The tile's first ``hq`` queries: its first ``hq * heads``
+        rows."""
+        return (slice(None) if hq == tq else slice(0, hq * heads),)
+
+    def reset(hq):
+        """Empty accumulators for ``hq`` queries; a short tile's rows
+        past them come back zero."""
+        _reset(m_ref, l_ref, acc_ref, rows(hq))
+        if hq < tq:
+            o_ref[...] = jnp.zeros_like(o_ref)
+
+    def score(hq, gi, buf):
+        q = q_ref[(0, *rows(hq))]                         # [R, W]
         kv = kv_buf[buf]                                  # [span, W]
         s = jax.lax.dot_general(
             q, kv, (((1,), (1,)), ((), ())),
@@ -799,11 +964,18 @@ def _mla_kernel(lens_ref, qlens_ref, bt_ref, q_ref, pool_ref, o_ref,
         qi = t * tq + jax.lax.broadcasted_iota(
             jnp.int32, s.shape, 0) // heads
         _softmax_step(_mask_scores(s, qi, gi, lens_ref[b], qlens_ref[b]),
-                      kv[:, :dv], m_ref, l_ref, acc_ref)
+                      kv[:, :dv], m_ref, l_ref, acc_ref, at=rows(hq))
 
-    _page_loop(n_groups, copies, score)
-    l = l_ref[:, 0:1]
-    o_ref[0] = (acc_ref[:] / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
+    def finalize(hq):
+        l = l_ref[(*rows(hq), slice(0, 1))]
+        o_ref[(0, *rows(hq))] = (acc_ref[rows(hq)] / jnp.where(
+            l == 0.0, 1.0, l)).astype(o_ref.dtype)
+
+    heights = (tq, short, full)
+    _at_heights(heights, reset)
+    _page_loop(n_groups, copies,
+               lambda gi, buf: _at_heights(heights, score, gi, buf))
+    _at_heights(heights, finalize)
 
 
 def ragged_paged_mla(q, pool, lengths, q_lens, block_tables, *,
@@ -819,13 +991,16 @@ def ragged_paged_mla(q, pool, lengths, q_lens, block_tables, *,
     q-block's own rows are already written; lengths / q_lens /
     block_tables as in :func:`ragged_paged_attention`.  Scores run over
     all ``W`` columns times ``scale``; the value is a row's first
-    ``value_width`` columns.
+    ``value_width`` columns.  A q-tile is scored at the height its live
+    rows need, as in :func:`ragged_paged_attention` (:func:`mla_tiling`
+    has the short height: ``_MLA_SHORT_QUERIES`` queries, all their
+    heads): a short tile's rows past it come back zero.
     Returns o [B, Q, H, value_width] in q's dtype (f32 accumulators); a
     slot with lengths 0 returns zeros."""
     B, Q, H, W = q.shape
     bs = pool.shape[2]
     group = min(_PAGE_GROUP, block_tables.shape[1])
-    tq = _mla_q_tile(Q, H)
+    tq, short = mla_tiling(Q, H)
     if interpret is None:
         interpret = _use_interpret()
     if W % _LANES and not interpret:
@@ -850,7 +1025,8 @@ def ragged_paged_mla(q, pool, lengths, q_lens, block_tables, *,
     )
     o = pl.pallas_call(
         functools.partial(_mla_kernel, scale=scale, bs=bs, group=group,
-                          tq=tq, heads=H, dv=value_width, layer=layer),
+                          tq=tq, heads=H, dv=value_width, layer=layer,
+                          short=short),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Q * H, value_width), q.dtype),
         name="ragged_paged_mla",
@@ -864,7 +1040,8 @@ def ragged_paged_mla_reference(q, pool, lengths, q_lens, block_tables, *,
                                value_width, scale, layer=0):
     """Gather-then-mask oracle (f32) for :func:`ragged_paged_mla`, with
     ``ragged_masked_reference``'s conventions (dead rows clip to the
-    last live position; a slot with lengths 0 returns zeros)."""
+    last live position, where the kernel has zeros past a short tile's
+    height; a slot with lengths 0 returns zeros)."""
     B, Q = q.shape[:2]
     bs = pool.shape[2]
     T = block_tables.shape[1]

@@ -48,6 +48,8 @@ from ..models.gpt_decode import (
     resolve_spec_k, serve_mixed_fn, serve_mixed_paged_fn,
     serve_prefill_fn, spec_propose_fn, wave_rows,
 )
+from ..kernels.ragged_attention import (
+    mla_tiling, rows_tiling, tile_heights)
 from ..models.moe_decode import takes_kernel
 from .kv_manager import (KVCacheManager, PagedKVManager,
                          assemble_mixed_wave, resolve_kv_block,
@@ -204,7 +206,7 @@ class ServingEngine:
         validate_serving(self.params, c, self._name)
         Dh = head_dim_of(c)
         want = int(max_seq_len or c.max_position_embeddings)
-        cdtype = self.params[f"{self._name}_wte_table"].dtype
+        cdtype = self._cdtype = self.params[f"{self._name}_wte_table"].dtype
         # the block the mixed wave runs (GPT-2's unless the config
         # carries another): see the class docstring for what a
         # non-GPT-2 spec refuses
@@ -548,7 +550,8 @@ class ServingEngine:
                      + (ql - grow) * W).sum()),
                 recycled - self._window_recycled_seen)
             self._window_recycled_seen = recycled
-        self.metrics.record_attention(ctx, pairs, window)
+        self.metrics.record_attention(ctx, pairs, window,
+                                      self._attn_tiles(ql, int(wave["q"])))
         if not self._ssm_layers:
             return None
         # row pairs (i, j <= i) inside the chunks of the chunked form: a
@@ -560,6 +563,25 @@ class ServingEngine:
                            + rest * (rest + 1) // 2).sum())
         return self.metrics.record_ssm(int((ql > 0).sum()), int(ql.sum()),
                                        chunk_pairs, self._ssm_layers)
+
+    def _attn_tiles(self, q_len, Q):
+        """(live (slot, q-tile) steps, those scored at the short height)
+        of one call of the hand-paged attention kernel in a wave of
+        q-blocks ``Q`` wide: the kernel's own rule asked of the wave's
+        ``q_len`` and the tile the program for ``Q`` has.  None where
+        the engine's waves run no such kernel (the masked path, the
+        contiguous cache, the int8 pool)."""
+        if not (self.fast_path and self.paged) or self.kv_quant:
+            return None
+        H, Dh = self.cfg_tuple[2:4]
+        if self.block_spec.latent:
+            tq, short = mla_tiling(Q, H)
+        else:
+            _, tq, short = rows_tiling(
+                Q, H, Dh, H // (self.block_spec.kv_heads or H), self._cdtype)
+        live, full = tile_heights(q_len[:, None],
+                                  np.arange(-(-Q // tq))[None, :], tq, short)
+        return int(live.sum()), int((live & ~full).sum())
 
     def _routed_record(self, wave, routed_out, rows_computed):
         """A routed wave's counters (``serve.moe.*``) and its

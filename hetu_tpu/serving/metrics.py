@@ -208,6 +208,8 @@ class ServingMetrics(MetricsCore):
         self.moe_load = None
         self.attn_ctx_tokens = 0
         self.attn_score_pairs = 0
+        self.attn_tiles_live = 0
+        self.attn_tiles_short = 0
         # an engine with window layers (``record_attention``)
         self.attn_window_ctx_tokens = 0
         self.attn_window_score_pairs = 0
@@ -256,7 +258,8 @@ class ServingMetrics(MetricsCore):
         if rows_dead:
             telemetry.inc("serve.wave.rows_dead_ahead", int(rows_dead))
 
-    def record_attention(self, ctx_tokens, score_pairs, window=None):
+    def record_attention(self, ctx_tokens, score_pairs, window=None,
+                         tiles=None):
         """One wave of any engine: ``ctx_tokens`` (the live slots'
         filled lengths after the wave's writes, once a wave) and
         ``score_pairs`` (the positions every live row sees): what a
@@ -270,11 +273,23 @@ class ServingMetrics(MetricsCore):
         ``attn_window_score_pairs``, ``window_blocks_recycled`` and the
         counters ``serve.attn.window_ctx_tokens``,
         ``serve.attn.window_score_pairs`` (``serve.kv.
-        window_blocks_recycled`` is the manager's own)."""
+        window_blocks_recycled`` is the manager's own).  An engine whose
+        waves run a hand-paged attention kernel adds ``tiles`` = (the
+        wave's live (slot, q-tile) steps of one call of the kernel,
+        those of them it scored at the short height:
+        ``ragged_attention.tile_heights`` of the wave's ``q_len``):
+        ``attn_tiles_live``, ``attn_tiles_short`` and the counters
+        ``serve.attn.tiles_live``, ``serve.attn.tiles_short``."""
         self.attn_ctx_tokens += int(ctx_tokens)
         self.attn_score_pairs += int(score_pairs)
         telemetry.inc("serve.attn.ctx_tokens", int(ctx_tokens))
         telemetry.inc("serve.attn.score_pairs", int(score_pairs))
+        if tiles is not None:
+            live, short = (int(v) for v in tiles)
+            self.attn_tiles_live += live
+            self.attn_tiles_short += short
+            telemetry.inc("serve.attn.tiles_live", live)
+            telemetry.inc("serve.attn.tiles_short", short)
         if window is not None:
             ctx, pairs, recycled = (int(v) for v in window)
             self.attn_window_ctx_tokens += ctx
@@ -609,6 +624,7 @@ class ServingMetrics(MetricsCore):
                     "tokens_generated", "prefill_batched",
                     "moe_assignments", "moe_experts_touched",
                     "moe_kernel_waves", "attn_ctx_tokens", "attn_score_pairs",
+                    "attn_tiles_live", "attn_tiles_short",
                     "attn_window_ctx_tokens", "attn_window_score_pairs",
                     "window_blocks_recycled",
                     "ssm_slot_steps", "ssm_rows", "ssm_chunk_pairs",
@@ -689,6 +705,8 @@ class ServingMetrics(MetricsCore):
             **routed,
             "attn_ctx_tokens": count("attn_ctx_tokens"),
             "attn_score_pairs": count("attn_score_pairs"),
+            "attn_tiles_live": count("attn_tiles_live"),
+            "attn_tiles_short": count("attn_tiles_short"),
             "attn_window_ctx_tokens": count("attn_window_ctx_tokens"),
             "attn_window_score_pairs": count("attn_window_score_pairs"),
             "window_blocks_recycled": count("window_blocks_recycled"),

@@ -610,9 +610,16 @@ PARENT_RAGGED = {
     "gpt2.Q32.fresh1": "a7c64ccb01632823",
     "latent.Q1.fresh0": "3e2ddf60b91b860d",
     "latent.Q1.fresh1": "3e2ddf60b91b860d",
-    "latent.Q32.fresh0": "c873bfc54690610f",
-    "latent.Q32.fresh1": "c873bfc54690610f"}
-# (the latent Q 32 pair is PR 41's: its routed products are the
+    "latent.Q32.fresh0": "eef22714386f596c",
+    "latent.Q32.fresh1": "eef22714386f596c"}
+# (the latent Q 32 pair is PR 43's: a q-tile of 32 queries is taller than
+# the latent kernel's short height (8 queries), so the kernel of a chunk
+# program holds its step at two heights, ``ragged_attention.
+# tile_heights``; the parent of PR 43 lowered it to c873bfc54690610f.
+# The GPT-2 programs are the parent's at every Q: one query head a K/V
+# head stacks too few rows for a second height, ``_SHORT_MIN_ROWS``.
+# The Q 1 programs have one height and are the parent's text.  Before
+# that the latent Q 32 pair was PR 41's: its routed products are the
 # ``moe_grouped_matmul`` kernel there, tests/test_hybrid_moe.py says why;
 # the parent of PR 41 lowered them to 9be25ac3fb1f17f1)
 
@@ -652,3 +659,82 @@ def test_gpt2_and_latent_kernel_waves_lower_to_the_parents(sds, monkeypatch):
         assert "tpu_custom_call" in text
         got[name] = digest(strip_kernel_locations(text))
     assert got == PARENT_RAGGED
+
+
+# ------------------------------------------------------------------- #
+# ISSUE 43: a chunk program's hand-paged kernel has two heights
+# ------------------------------------------------------------------- #
+
+def matmul_rows(lowered):
+    """The row counts of the left operands of the Mosaic kernels'
+    products in a lowered program."""
+    import re
+    return {int(n) for n in re.findall(
+        r'tpu\.matmul"[^\n]*? : \(vector<(\d+)x',
+        strip_kernel_locations(lowered.as_text()))}
+
+
+# name: (slots, query heads, K/V heads (None: the latent kernel), head,
+# table, pool blocks, window, rows of a product at the full and at the
+# short height).  The three cells' chunk shapes, and GPT-2 XL's, whose
+# short product would have 32 rows: ONE height (128 rows, and 64 in the
+# last lane chunk's one head), ``_SHORT_MIN_ROWS`` says why.
+CHUNK_KERNELS = {
+    "lfm2-groups4-head64": (32, 32, 8, 64, 1024, 25601, 0, {512, 128}),
+    "mellum2-groups8-head128-window1024": (
+        32, 32, 4, 128, 1024, 32 * 81 + 1, 1024, {512, 128}),
+    "mellum2-groups8-head128-full": (32, 32, 4, 128, 1024, 16385, 0,
+                                     {512, 128}),
+    "glm-latent-20x640": (32, 20, None, 640, 512, 10241, 0, {1280, 160}),
+    "gpt2-xl-25x64": (16, 25, 25, 64, 64, 449, 0, {128, 64}),
+}
+
+
+@pytest.mark.parametrize("name", list(CHUNK_KERNELS))
+def test_a_chunk_programs_kernel_compiles_with_its_heights(sds, name):
+    """Q 256 at the cells' widths: a q-tile is 64 queries, the short
+    height 16 (the latent kernel's 8), and the kernel's products are
+    there at both; the Q 1 program of the same widths has one height,
+    one sublane tile of queries, and no product of the tall one's rows.
+    Compiled for the described v5e: two bodies' worth of scratch and
+    code fit the scoped VMEM."""
+    from hetu_tpu.kernels import ragged_attention as ra
+    B, H, Hkv, Dh, T, blocks, window, rows = CHUNK_KERNELS[name]
+    lens = sds((B,), jnp.int32)
+    if Hkv is None:
+        assert ra.mla_tiling(256, H) == (64, 8)
+        assert ra.mla_tiling(1, H) == (1, 0)
+        pool = (sds((7, blocks, BLOCK, Dh), jnp.bfloat16),)
+
+        def fn(q, pool, lengths, q_lens, bt):
+            return ragged_paged_mla(q, pool, lengths, q_lens, bt,
+                                    value_width=512, scale=1 / 16, layer=3,
+                                    interpret=False)
+    else:
+        assert ra.rows_tiling(256, H, Dh, H // Hkv, jnp.bfloat16) == (
+            256, 64, 16 if H > Hkv else 0)
+        assert ra.rows_tiling(1, H, Dh, H // Hkv, jnp.bfloat16) == (16, 16, 0)
+        pool = (sds((3, blocks, BLOCK, kv_row_width(Hkv, Dh)),
+                    jnp.bfloat16),) * 2
+
+        def fn(q, pk, pv, lengths, q_lens, bt):
+            return ragged_paged_attention(
+                q, pk, pv, lengths, q_lens, bt, layer=2, interpret=False,
+                groups=H // Hkv, window=window)
+    lowered = jax.jit(fn).lower(sds((B, 256, H, Dh), jnp.bfloat16), *pool,
+                                lens, lens, sds((B, T), jnp.int32))
+    assert rows <= matmul_rows(lowered) and (
+        H > (Hkv or 0) or rows == matmul_rows(lowered))
+    assert "tpu_custom_call" in lowered.compile().as_text()
+    q1 = jax.jit(fn).lower(sds((B, 1, H, Dh), jnp.bfloat16), *pool,
+                           lens, lens, sds((B, T), jnp.int32))
+    assert matmul_rows(q1) and max(rows) not in matmul_rows(q1)
+
+
+def test_gpt2_xl_chunk_program_keeps_one_height():
+    """GPT-2 XL's chunk programs have the one height: with two, the
+    short products' 32 rows cost the cell 4 % of its rate (PERF.md,
+    PR 43)."""
+    from hetu_tpu.kernels import ragged_attention as ra
+    for q in (32, 64, 128, 256):
+        assert ra.rows_tiling(q, 25, DH, 1, jnp.bfloat16)[2] == 0

@@ -157,32 +157,60 @@ def test_absorbed_equals_expanded_attention(params, cfg):
 # the kernel in interpret mode against its masked reference
 # ------------------------------------------------------------------ #
 
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
 @pytest.mark.parametrize("Q,lens,qlens,tile_rows", [
     (1, [37, 8, 0, 80], [1, 1, 0, 1], None),          # decode, a dead slot
     (8, [37, 8, 0, 80], [8, 1, 0, 5], None),          # mixed, ragged q_len
     (8, [37, 9, 16, 80], [8, 3, 8, 5], 8),            # q-block of 4 tiles
     (8, [5, 1, 0, 33], [5, 1, 0, 8], 16),             # chunk from empty
-], ids=["decode", "mixed", "tiled", "from-empty"])
+    # chunks with DECODING slots beside them, tiles of 32 queries over a
+    # short height of 8 (ISSUE 43): one row, exactly 8 and 9, a short
+    # tail in the second tile (33, 40; 41 is a full one), a dead slot,
+    # and a slot with q_len 0 whose pages are filled
+    (64, [70, 37, 16, 80, 0, 40], [64, 1, 8, 9, 0, 0], 128),
+    (64, [64, 33, 80, 61, 79, 40], [64, 33, 1, 40, 41, 0], 128),
+], ids=["decode", "mixed", "tiled", "from-empty", "decoding-beside",
+        "decoding-beside-tails"])
 def test_ragged_paged_mla_matches_masked_reference(monkeypatch, Q, lens,
-                                                   qlens, tile_rows):
+                                                   qlens, tile_rows, dtype):
     if tile_rows:
         monkeypatch.setattr(ra, "_MLA_TILE_ROWS", tile_rows)
         assert ra._mla_q_tile(Q, 4) < Q
     rng = np.random.default_rng(0)
-    B, H, W, dv, bs, T, N = 4, 4, 48, 32, 4, 20, 64
-    q = jnp.asarray(rng.standard_normal((B, Q, H, W)), jnp.float32)
-    pool = jnp.asarray(rng.standard_normal((3, N, bs, W)), jnp.float32)
+    B, H, W, dv, bs, T, N = len(lens), 4, 48, 32, 4, 20, 64
+    q = jnp.asarray(rng.standard_normal((B, Q, H, W)), dtype)
+    pool = jnp.asarray(rng.standard_normal((3, N, bs, W)), dtype)
     tables = jnp.asarray(rng.integers(1, N, (B, T)), jnp.int32)
     lens, qlens = jnp.asarray(lens, jnp.int32), jnp.asarray(qlens, jnp.int32)
     kw = dict(value_width=dv, scale=0.2, layer=1)
-    got = ra.ragged_paged_mla(q, pool, lens, qlens, tables, interpret=True,
-                              **kw)
-    want = ra.ragged_paged_mla_reference(q, pool, lens, qlens, tables, **kw)
+    got = np.asarray(ra.ragged_paged_mla(
+        q, pool, lens, qlens, tables, interpret=True, **kw), np.float32)
+    want = np.asarray(ra.ragged_paged_mla_reference(
+        q, pool, lens, qlens, tables, **kw))
     assert got.shape == (B, Q, H, dv)
-    live = np.arange(Q)[None, :] < np.asarray(qlens)[:, None]
-    np.testing.assert_allclose(np.asarray(got)[live], np.asarray(want)[live],
-                               atol=2e-5)
-    assert not np.asarray(got)[np.asarray(lens) == 0].any()
+    tol = 2e-5 if dtype == jnp.float32 else 3e-2
+    tq, short = ra.mla_tiling(Q, H)
+    assert short == (8 if tq > 8 else 0)
+    for b, n in enumerate(np.asarray(qlens)):
+        if lens[b] == 0:
+            assert not got[b].any()
+            continue
+        for t in range(Q // tq):
+            # live rows, and a scored tile's dead rows (clipped to the
+            # last live position), are the reference's; rows past the
+            # scored height, and dead tiles, are zeros
+            rows = min(max(int(n) - t * tq, 0), tq)
+            if short:
+                h = 0 if rows == 0 else short if rows <= short else tq
+            else:
+                h = tq if rows or t == 0 else 0
+            at = t * tq
+            np.testing.assert_allclose(got[b, at:at + h], want[b, at:at + h],
+                                       atol=tol, rtol=tol)
+            assert not got[b, at + h:at + tq].any()
+            live, full = ra.tile_heights(int(n), t, tq, short)
+            assert not short or (bool(live), bool(full)) == (h > 0, h == tq)
 
 
 def test_kernel_refuses_an_unaligned_row_on_the_chip():

@@ -321,6 +321,83 @@ def rows_of_waves(eng):
     return snap["wave_rows_live"], snap["wave_rows_computed"]
 
 
+def watch_waves(eng):
+    """[(q_len, Q)] of the waves ``eng`` lands from now on."""
+    waves, record = [], eng._wave_record
+
+    def recording(wave):
+        waves.append((wave["q_len"].copy(), int(wave["q"])))
+        return record(wave)
+    eng._wave_record = recording
+    return waves
+
+
+def f32_rows_tile(Q):
+    """Queries a q-tile of the rows kernel at these engines' widths:
+    the q-block padded to 8 float32 rows, whole up to a chunk's 64."""
+    return min(-(-Q // 8) * 8, 64)
+
+
+def tiles_of(waves, tile, short_queries):
+    """(live (slot, q-tile) steps, those at the short height) that the
+    waves' ``q_len`` give, written out from the kernels' contract:
+    ``tile(Q)`` queries a q-tile, and a live tile whose live rows fit
+    ``short_queries`` scored short wherever the tile is taller."""
+    live = short = 0
+    for q_lens, Q in waves:
+        tq = tile(Q)
+        for n in q_lens:
+            for t in range(-(-Q // tq)):
+                rows = min(max(int(n) - t * tq, 0), tq)
+                live += rows > 0
+                short += 0 < rows <= short_queries < tq
+    return live, short
+
+
+def test_attention_tile_counters_follow_the_waves_q_lens(gpt, monkeypatch):
+    """``serve.attn.tiles_live`` / ``tiles_short`` of an engine on the
+    kernel path: a chunk of 64 float32 queries of 2 heads is one tile of
+    64, its short height 8, so a decoding slot beside a chunk is a short
+    tile and the chunk a full one; a decode wave's program (Q 1) has one
+    height and nothing in it is short.  An engine on the masked path
+    runs no kernel and counts none."""
+    from hetu_tpu import telemetry
+    from hetu_tpu.kernels import ragged_attention as ra
+    params, cfg = gpt
+    telemetry.reset()
+    # two heads of 8 are 16 rows a short product, under the row count
+    # at which the rule gives a program two heights: lowered here
+    monkeypatch.setattr(ra, "_SHORT_MIN_ROWS", 1)
+    eng = ServingEngine(params, cfg, slots=8, paged=True, kv_block=16,
+                        prefill_chunk=64, fast_path=True)
+    waves = watch_waves(eng)
+    eng.run(requests(61, SMALL_SIZES))
+    mark, first = eng.metrics.mark(), len(waves)
+    whole = eng.metrics.snapshot()
+    assert ra.rows_tiling(64, 2, 8, 1, jnp.float32) == (64, 64, 8)
+    want = tiles_of(waves, f32_rows_tile, 8)
+    assert (whole["attn_tiles_live"], whole["attn_tiles_short"]) == want
+    # decoding slots rode beside chunks, chunks were scored whole, and
+    # the decode waves added live tiles alone
+    chunk = [(ql, Q) for ql, Q in waves if Q == 64]
+    assert any((ql == 1).any() and (ql > 8).any() for ql, _ in chunk)
+    assert 0 < want[1] == sum(int((0 < ql).sum() - (ql > 8).sum())
+                              for ql, _ in chunk) < want[0]
+    counters = telemetry.snapshot()["counters"]
+    assert counters["serve.attn.tiles_live"] == want[0]
+    assert counters["serve.attn.tiles_short"] == want[1]
+    eng.run(requests(61, SMALL_SIZES[:3], seed=5))
+    tail = eng.metrics.snapshot(since=mark)
+    assert (tail["attn_tiles_live"], tail["attn_tiles_short"]) == tiles_of(
+        waves[first:], f32_rows_tile, 8)
+    masked = ServingEngine(params, cfg, slots=8, paged=True, kv_block=16,
+                           prefill_chunk=64, fast_path=False)
+    masked.run(requests(61, SMALL_SIZES[:3]))
+    snap = masked.metrics.snapshot()
+    assert snap["attn_tiles_live"] == snap["attn_tiles_short"] == 0
+    telemetry.reset()
+
+
 @pytest.mark.parametrize("fast,spec", [
     (False, None), (True, None), (False, 2), (True, 2)],
     ids=["masked", "kernel", "masked-spec2", "kernel-spec2"])
@@ -377,6 +454,7 @@ def test_hybrid_engine_matches_reference_with_packing_engaged(hybrid, fast):
                         prefill_chunk=64, fast_path=fast)
     assert gd.wave_rows(eng.cfg_tuple, 8, 1, 64) == 256 < 512
     sizes = SMALL_SIZES
+    waves = watch_waves(eng)
     out = eng.run(requests(257, sizes))
     for r in out.values():
         seq = np.asarray(r.tokens, np.int32)
@@ -395,6 +473,11 @@ def test_hybrid_engine_matches_reference_with_packing_engaged(hybrid, fast):
     assert snap["wave_rows_live"] > snap["wave_rows_computed"] / 2, snap
     assert eng.kv.state_resets == len(sizes)
     assert eng.kv.free_blocks == eng.kv.capacity_blocks
+    # the grouped rows kernel's tiles (float32: a short height of 8
+    # queries under tiles of 64); the masked path runs no kernel
+    tiles = (snap["attn_tiles_live"], snap["attn_tiles_short"])
+    assert tiles == (tiles_of(waves, f32_rows_tile, 8) if fast else (0, 0))
+    assert not fast or 0 < tiles[1] < tiles[0]
 
 
 @pytest.mark.parametrize("fast", [False, True], ids=["masked", "kernel"])
@@ -404,6 +487,7 @@ def test_latent_engine_matches_reference_with_packing_engaged(latent, fast):
                         kv_block=4, prefill_chunk=64, fast_path=fast,
                         prefix_share=False)
     sizes = SMALL_SIZES
+    waves = watch_waves(eng)
     out = eng.run(requests(257, sizes))
     for r in out.values():
         seq = np.asarray(r.tokens, np.int32)
@@ -416,6 +500,11 @@ def test_latent_engine_matches_reference_with_packing_engaged(latent, fast):
     assert snap["wave_rows_live"] == rows
     assert snap["moe_assignments"] == rows * 2 * 2
     assert snap["chunks_deferred"] > 0
+    # the latent kernel's tiles: all of a q-block of up to 64 queries in
+    # one, its short height 8 queries whatever the dtype
+    tiles = (snap["attn_tiles_live"], snap["attn_tiles_short"])
+    assert tiles == (tiles_of(waves, lambda Q: Q, 8) if fast else (0, 0))
+    assert not fast or 0 < tiles[1] < tiles[0]
 
 
 # ------------------------------------------------------------------ #

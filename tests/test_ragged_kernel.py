@@ -317,15 +317,64 @@ ROW_CASES = {
                                 dict(garbage=1e3)),
 }
 
+# chunk waves with DECODING slots beside the chunks (ISSUE 43): tiles of
+# 32 queries, so a program with two heights; a slot's one row, a tile
+# whose live rows are exactly one sublane tile (8 of f32, 16 of bf16)
+# and one more, a later tile with a short tail (17 of 16 + 1, 33 of 32 +
+# 1), a dead slot, and a slot with ``q_len`` 0 whose pages are filled (a
+# chunk deferred for a wave).  2 K/V heads of 64 (rows of 128 lanes)
+# under ``groups`` query heads each, with and without a window.
+_F32 = dict(q_lens=((64, 1, 1, 17, 0, 0), (128, 8, 9, 1, 33, 0)), tol=2e-5)
+_BF16 = dict(q_lens=((64, 1, 16, 17, 0, 0), (128, 16, 17, 1, 33, 0)),
+             tol=3e-2, dtype=jnp.bfloat16)
+for _k, (_g, _win) in enumerate((g, w) for g in (1, 4, 8) for w in (0, 40)):
+    # each (groups, window) with both dtypes, one at Q 64 and one at Q 128
+    for _i, (_name, _d) in enumerate((("f32", _F32), ("bf16", _BF16))):
+        _ql = _d["q_lens"][(_k + _i) % 2]
+        # (one query head a K/V head is 16 or 32 rows a short product,
+        # under the row count at which the rule gives a program two
+        # heights: the count is lowered, the code path is the same)
+        _kw = dict(groups=_g, window=_win, max_rows=2 * _g * 32,
+                   tol=_d["tol"], min_short_rows=1)
+        if "dtype" in _d:
+            _kw["dtype"] = _d["dtype"]
+        # every slot decodes from (or prefills onto) a prefix; the last
+        # has q_len 0 over 40 filled positions, the one before it (where
+        # its q_len is 0) is dead
+        _lens = tuple(40 if i == len(_ql) - 1 else
+                      0 if n == 0 else (300, 257, 100, 290, 310)[i]
+                      for i, n in enumerate(_ql))
+        ROW_CASES[f"decoding-beside-Q{_ql[0]}-g{_g}-w{_win}-{_name}"] = (
+            2 * _g, _ql[0], _lens, _ql, _kw)
+
+# rows of SEVERAL lane chunks under two heights (the short height loops
+# over the chunks of whole heads, the full one takes them one by one):
+# GPT-2 XL's 13 chunks, the last with one head beside the pad, and 2
+# chunks of 2 K/V heads x 4 query heads under a window
+_LENS = (300, 257, 100, 290, 310, 40)
+ROW_CASES.update({
+    "decoding-beside-w1664-f32": (
+        25, 64, _LENS, (64, 1, 8, 9, 33, 0),
+        dict(max_rows=25 * 32, garbage=1e3, min_short_rows=1)),
+    "decoding-beside-w1664-bf16": (
+        25, 64, _LENS, (64, 1, 16, 17, 33, 0),
+        dict(max_rows=25 * 32, dtype=jnp.bfloat16, tol=3e-2,
+             min_short_rows=1)),
+    "decoding-beside-w256-g4-w40-f32": (
+        16, 64, _LENS, (64, 1, 8, 9, 33, 0),
+        dict(max_rows=16 * 32, groups=4, window=40)),
+})
+
 
 def _rows_wave(H, Q, lens, q_lens, *, Dh=64, bs=16, T=20, L=3, layer=1,
-               share=(), garbage=None, dtype=np.float32, seed=0):
+               share=(), garbage=None, dtype=np.float32, seed=0, groups=1):
     """A pool pair ``[L, N, bs, W]`` with values in EVERY layer, block
     (scratch block 0 too) and pad column, tables whose dead entries
     point at scratch block 0, and ``share`` = (slot a, slot b, pages)
-    triples making b's first pages a's."""
+    triples making b's first pages a's.  ``H`` query heads read ``H //
+    groups`` K/V heads."""
     rng = np.random.RandomState(seed)
-    B, W = len(lens), kv_row_width(H, Dh)
+    B, W = len(lens), kv_row_width(H // groups, Dh)
     N = B * T + 1
     pk = rng.randn(L, N, bs, W).astype(np.float32)
     pv = rng.randn(L, N, bs, W).astype(np.float32)
@@ -344,6 +393,24 @@ def _rows_wave(H, Q, lens, q_lens, *, Dh=64, bs=16, T=20, L=3, layer=1,
             np.asarray(q_lens, np.int32), tables.astype(np.int32), layer)
 
 
+def _scored_rows(n, Q, tq, short):
+    """[(first row, rows scored)] of a q-block's tiles with ``n`` live
+    rows, written out from the kernel's contract and not through its
+    rule: a program with two heights (``short`` > 0) scores a live tile
+    whose live rows fit ``short`` at ``short`` and a dead one not at
+    all; a program with one scores every live tile whole, and tile 0
+    always."""
+    out = []
+    for t in range(-(-Q // tq)):
+        live = min(max(n - t * tq, 0), tq)
+        if short:
+            h = 0 if live == 0 else short if live <= short else tq
+        else:
+            h = tq if live or t == 0 else 0
+        out.append((t * tq, h))
+    return out
+
+
 @pytest.mark.smoke
 class TestPoolRowsKernel:
     @pytest.mark.parametrize("case", list(ROW_CASES), ids=list(ROW_CASES))
@@ -354,30 +421,97 @@ class TestPoolRowsKernel:
         kw = dict(kw)
         tol = kw.pop("tol", 2e-5)
         max_rows = kw.pop("max_rows", None)
+        min_short_rows = kw.pop("min_short_rows", ra._SHORT_MIN_ROWS)
+        window = kw.pop("window", 0)
+        groups = kw.get("groups", 1)
         if max_rows:
             monkeypatch.setattr(ra, "_MAX_ROWS", max_rows)
+        monkeypatch.setattr(ra, "_SHORT_MIN_ROWS", min_short_rows)
         q, pk, pv, lens, q_lens, tables, layer = _rows_wave(
             H, Q, lens, q_lens, **kw)
         got = np.asarray(ragged_paged_attention(
-            q, pk, pv, lens, q_lens, tables, layer=layer,
-            interpret=True), np.float32)
-        # the oracle sees the same layer as [N, bs, H, Dh], pad dropped
+            q, pk, pv, lens, q_lens, tables, layer=layer, groups=groups,
+            window=window, interpret=True), np.float32)
         f32 = lambda a: np.asarray(a, np.float32)  # noqa: E731
-        want = np.asarray(ragged_paged_reference(
-            f32(q), kv_heads(f32(pk)[layer], H, 64),
-            kv_heads(f32(pv)[layer], H, 64), lens, q_lens, tables))
+        if groups > 1 or window:
+            from test_window_moe import banded_reference
+            want = np.asarray(banded_reference(
+                jnp.asarray(f32(q)), jnp.asarray(f32(pk)),
+                jnp.asarray(f32(pv)), lens, q_lens, tables, layer, groups,
+                window))
+        else:
+            # the oracle sees the same layer as [N, bs, H, Dh], pad dropped
+            want = np.asarray(ragged_paged_reference(
+                f32(q), kv_heads(f32(pk)[layer], H, 64),
+                kv_heads(f32(pv)[layer], H, 64), lens, q_lens, tables))
         assert got.shape == want.shape == q.shape
-        tq = ra._fit_block(max(ra._MAX_ROWS // H, 1),
-                           -(-Q // 8) * 8 if q.dtype == np.float32
-                           else -(-Q // 16) * 16)
+        sub = 8 if q.dtype == np.float32 else 16
+        tq = ra._fit_block(max(ra._MAX_ROWS // H, 1), -(-Q // sub) * sub)
+        # two K/V heads of 64 a lane chunk, ``groups`` query heads each
+        short = sub if sub < tq and 2 * groups * sub >= min_short_rows else 0
+        assert ra.rows_tiling(Q, H, 64, groups, q.dtype) == (
+            -(-Q // sub) * sub, tq, short)
         for b, n in enumerate(q_lens):
             if lens[b] == 0:
                 assert not got[b].any()          # a dead slot: zeros
                 continue
-            live = min(-(-max(int(n), 1) // tq) * tq, Q)
-            np.testing.assert_allclose(got[b, :live], want[b, :live],
-                                       atol=tol, rtol=tol)
-            assert not got[b, live:].any()       # dead tiles: zeros
+            # live rows, and a scored tile's dead rows (clipped to the
+            # last live position), are the reference's; the rows past a
+            # tile's scored height, and dead tiles, are zeros
+            for at, h in _scored_rows(int(n), Q, tq, short):
+                np.testing.assert_allclose(got[b, at:at + h],
+                                           want[b, at:at + h],
+                                           atol=tol, rtol=tol)
+                assert not got[b, at + h:at + tq].any()
+
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                             ids=["f32", "bf16"])
+    def test_the_rule_says_what_the_kernel_did(self, dtype, monkeypatch):
+        """``tile_heights`` (what the engine's counters ask) against
+        the kernel's output: a live tile's rows past the short height
+        are zero exactly where the rule says it was not scored at the
+        full one."""
+        from hetu_tpu.kernels import ragged_attention as ra
+        monkeypatch.setattr(ra, "_MAX_ROWS", 4 * 32)
+        sub = 8 if dtype == jnp.float32 else 16
+        q_lens = (64, 1, sub, sub + 1, 33, 32 + sub, 0)
+        q, pk, pv, lens, q_lens, tables, layer = _rows_wave(
+            4, 64, (300, 257, 100, 290, 310, 200, 40), q_lens, groups=2,
+            dtype=dtype)
+        monkeypatch.setattr(ra, "_SHORT_MIN_ROWS", 32)
+        Qp, tq, short = ra.rows_tiling(64, 4, 64, 2, dtype)
+        assert (Qp, tq, short) == (64, 32, sub)
+        got = np.asarray(ragged_paged_attention(
+            q, pk, pv, lens, q_lens, tables, layer=layer, groups=2,
+            interpret=True), np.float32)
+        live, full = ra.tile_heights(q_lens[:, None], np.arange(2)[None, :],
+                                     tq, short)
+        assert live.tolist() == [[1, 1], [1, 0], [1, 0], [1, 0], [1, 1],
+                                 [1, 1], [0, 0]]
+        assert full.tolist() == [[1, 1], [0, 0], [0, 0], [1, 0], [1, 0],
+                                 [1, 0], [0, 0]]
+        for b in range(len(q_lens)):
+            for t in range(2):
+                tile = got[b, t * tq:(t + 1) * tq]
+                assert tile[:short].any() == bool(live[b, t])
+                assert tile[short:].any() == bool(full[b, t])
+        # a program with one height asks no rule: nothing is short
+        assert ra.rows_tiling(1, 4, 64, 2, dtype) == (sub, sub, 0)
+        assert ra.rows_tiling(5, 4, 64, 2, dtype) == (sub, sub, 0)
+
+    def test_two_heights_where_the_short_product_has_rows_enough(self):
+        """The cells' chunk programs (Q 256, bf16): 4 to 8 query heads a
+        K/V head stack 80-128 rows a short product and have two
+        heights; GPT-2's one query head a K/V head stacks 32, which the
+        MXU's weight loads bound as they bound the full height's 128:
+        one height, the kernel there was."""
+        from hetu_tpu.kernels import ragged_attention as ra
+        bf16 = jnp.bfloat16
+        assert ra.rows_tiling(256, 32, 64, 4, bf16) == (256, 64, 16)   # lfm2
+        assert ra.rows_tiling(256, 32, 128, 8, bf16) == (256, 64, 16)  # mellum
+        assert ra.rows_tiling(256, 20, 128, 5, bf16) == (256, 64, 16)  # falcon
+        assert ra.rows_tiling(256, 25, 64, 1, bf16) == (256, 64, 0)    # XL
+        assert ra.rows_tiling(256, 12, 64, 1, bf16) == (256, 128, 0)
 
     def test_a_row_that_is_not_the_heads_width_is_refused(self):
         q, pk, pv, lens, q_lens, tables, _ = _rows_wave(
