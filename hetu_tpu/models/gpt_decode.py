@@ -405,7 +405,8 @@ class BlockSpec(NamedTuple):
     K/V attention's projections carry biases (GPT-2's do); qk_norm:
     every head's q and k RMS-normalised over its columns with a learned
     scale before the rotation; ops: the OPERATOR of every layer, a tuple
-    of "attention" | "window_attention" | "conv" | "attention+ssm"
+    of "attention" | "window_attention" | "conv" | "attention+ssm" |
+    "retention"
     (None: attention everywhere), a "window_attention" layer being that
     K/V attention over the last ``window`` positions alone (a query sees
     itself and the ``window - 1`` before it), its K/V pages in the
@@ -415,7 +416,11 @@ class BlockSpec(NamedTuple):
     "attention+ssm" layer running that K/V attention AND the state-space
     mixer ``ssm`` (a ``ssm_decode.SSMSpec``) side by side on one norm,
     their outputs summed into the residual, so that it holds K/V pages
-    and slot state both; head_dim: the head size where it is the
+    and slot state both, a "retention" layer running that attention's
+    front end (projections, q/k norm, rotation) into power retention
+    (``retention``, a ``retention_decode.RetentionSpec``) in place of
+    the softmax over pages, so that it holds slot state and NO page (a
+    spec of such layers alone has no pool); head_dim: the head size where it is the
     configuration's own key and not ``hidden / heads`` (0: that
     quotient); mup: the block's forward multipliers (``MuP``; None: no
     multiplication anywhere); ffn: the kind of every layer from
@@ -444,6 +449,7 @@ class BlockSpec(NamedTuple):
     mup: Optional[MuP] = None
     window: int = 0
     rope_by_op: Optional[tuple] = None
+    retention: Optional[tuple] = None
 
     def ffn_kind(self, i):
         """Layer ``i``'s FFN: the leading layers of a routed model are
@@ -457,28 +463,29 @@ class BlockSpec(NamedTuple):
 
     def op_kind(self, i):
         """Layer ``i``'s operator: "attention" | "window_attention" |
-        "conv" | "attention+ssm"."""
+        "conv" | "attention+ssm" | "retention"."""
         return self.ops[i] if self.ops else "attention"
 
     def holds(self, i, what):
         """Whether layer ``i`` keeps ``what``: "pool" (K/V pages of every
         position: a layer with an attention over everything), "window"
         (K/V pages in the window pool: a window layer) or "state" (slot
-        state beside the pool: a conv or a state-space mixer)."""
+        state beside the pool: a conv, a state-space mixer or a
+        retention layer)."""
         kind = self.op_kind(i)
         if what == "window":
             return kind == "window_attention"
         if what == "pool":
             return kind in ("attention", "attention+ssm")
-        return kind in ("conv", "attention+ssm")
+        return kind in ("conv", "attention+ssm", "retention")
 
     def op_index(self, i, what=None):
         """Layer ``i``'s place among the layers that keep what it keeps:
         an attention layer's index into the K/V pool, a window layer's
-        into the window pool, a conv layer's into the state; a layer
-        that keeps two says ``what``."""
-        what = what or {"conv": "state", "window_attention": "window"}.get(
-            self.op_kind(i), "pool")
+        into the window pool, a conv or a retention layer's into the
+        state; a layer that keeps two says ``what``."""
+        what = what or {"conv": "state", "window_attention": "window",
+                        "retention": "state"}.get(self.op_kind(i), "pool")
         return sum(1 for j in range(i) if self.holds(j, what))
 
     def rope_of(self, i):
@@ -493,12 +500,15 @@ class BlockSpec(NamedTuple):
         """The set of slot states ``L`` layers of this spec keep beside
         the pool, as ``PagedKVManager(state_shapes=)`` takes it (None:
         none): a conv layer's last ``conv_kernel - 1`` inputs, or a
-        state-space mixer's set (``SSMSpec.state_shapes``)."""
+        state-space mixer's set (``SSMSpec.state_shapes``), or the
+        retention layers' (``RetentionSpec.state_shapes``)."""
         n = self.op_layers(L, "state")
         if not n:
             return None
         if self.ssm is not None:
             return self.ssm.state_shapes(n)
+        if self.retention is not None:
+            return self.retention.state_shapes(n)
         return (((n, self.conv_kernel - 1, hidden), None),)
 
     def op_layers(self, L, what):
@@ -513,7 +523,8 @@ GPT2_BLOCK = BlockSpec()
 
 # every operator a layer of ``BlockSpec.ops`` may name, and every kind of
 # rotary frequencies ``rope_frequencies`` makes
-OPERATORS = ("attention", "window_attention", "conv", "attention+ssm")
+OPERATORS = ("attention", "window_attention", "conv", "attention+ssm",
+             "retention")
 ROPE_KINDS = ("default", "yarn")
 
 
@@ -580,8 +591,12 @@ def check_block_spec(blk, layers=None):
     layers alone: the window pool has no state beside it) or both that
     attention and a state-space mixer on one norm
     ("attention+ssm", which needs an ``ssm`` spec and is not mixed with
-    "conv" layers: the two keep different state): each over any of the
-    three FFN kinds and either head.  Multipliers (``mup``), a head
+    "conv" layers: the two keep different state) or that attention's
+    front end into power retention ("retention", which needs a
+    ``retention`` spec of degree 2 over the block's own K/V heads and
+    head size, and is alone or mixed with plain "attention" layers: its
+    layers hold state and no page, so a spec of them alone has no pool):
+    each over any of the three FFN kinds and either head.  Multipliers (``mup``), a head
     size of the configuration's own and rotary parameters by operator
     (``rope_by_op``: "default" or "yarn" frequencies, made by
     ``rope_frequencies``) go with the grouped-query block alone; a
@@ -597,7 +612,8 @@ def check_block_spec(blk, layers=None):
     if blk.attention == "latent":
         ok = common and blk.latent is not None and blk.ops is None \
             and blk.ssm is None and blk.mup is None and not blk.head_dim \
-            and not blk.window and blk.rope_by_op is None
+            and not blk.window and blk.rope_by_op is None \
+            and blk.retention is None
     else:
         ops = blk.ops or ()
         ok = common and blk.attention == "gqa" and blk.latent is None \
@@ -609,6 +625,13 @@ def check_block_spec(blk, layers=None):
             and ("window_attention" in ops) == (blk.window >= 1) \
             and ("window_attention" not in ops
                  or set(ops) <= {"attention", "window_attention"}) \
+            and ("retention" in ops) == (blk.retention is not None) \
+            and ("retention" not in ops or (
+                set(ops) <= {"attention", "retention"}
+                and blk.retention.degree == 2
+                and blk.retention.kv_heads == blk.kv_heads
+                and blk.retention.head_dim == blk.head_dim
+                and blk.head_dim % 2 == 0 and blk.mup is None)) \
             and all(op in ops and factor > 0
                     for op, _, factor in blk.rope_by_op or ()) \
             and (layers is None or not ops or len(ops) == layers)
@@ -619,7 +642,8 @@ def check_block_spec(blk, layers=None):
             f"and rope (operators {', '.join(OPERATORS)}; rotary kinds "
             f"{', '.join(ROPE_KINDS)}; routers sigmoid, softmax): beside "
             f"gated short convolutions or a state-space mixer, or over a "
-            f"sliding window beside full layers; it cannot run {blk}")
+            f"sliding window beside full layers, or with power retention "
+            f"of degree 2 in the softmax's place; it cannot run {blk}")
 
 
 def head_dim_of(config):
@@ -1550,6 +1574,53 @@ def _proj(params, prefix, x, bias):
     return y + params[f"{prefix}_bias"] if bias else y
 
 
+def _qkv_heads(params, us, blk, i, x, H, Hkv, Dh, posns):
+    """The K/V attention's front end over the normed rows ``x`` [Br, Qr,
+    hidden]: q [.., H, Dh], k and v [.., Hkv, Dh] by the spec's
+    projections, multipliers, per-head q/k norm and rotation (layer
+    ``i``'s frequencies) at ``posns`` [Br, Qr].  A retention layer's
+    front end is this one."""
+    Br, Qr = x.shape[:2]
+    mup = blk.mup
+    if mup is not None:
+        x = x * mup.attention_in
+    q = _proj(params, f"{us}_attn_q", x, blk.bias).reshape(Br, Qr, H, Dh)
+    k = _proj(params, f"{us}_attn_k", x, blk.bias).reshape(Br, Qr, Hkv, Dh)
+    v = _proj(params, f"{us}_attn_v", x, blk.bias).reshape(Br, Qr, Hkv, Dh)
+    if mup is not None:
+        k = k * mup.key
+    if blk.qk_norm:
+        q = _rms(q, params[f"{us}_attn_q_norm_scale"], blk.norm_eps)
+        k = _rms(k, params[f"{us}_attn_k_norm_scale"], blk.norm_eps)
+    if blk.positions == "rope":
+        inv, factor = blk.rope_of(i)
+        q = _rope(q, posns, blk.rope_theta, inv, factor)
+        k = _rope(k, posns, blk.rope_theta, inv, factor)
+    return q, k, v
+
+
+def _retention_operator(params, us, blk, i, h, H, Hkv, Dh, posns, state,
+                        si, q_len, rows=None):
+    """One layer's power retention over the wave's rows ``h``: the K/V
+    attention's front end and the gate ``log sigmoid(x W_g + b_g)``
+    (float32, one a K/V head a row) under ``ret_qkvg``,
+    ``retention_decode.retention_mixer`` over ``state`` (``ret_expand``,
+    ``ret_scan``, ``state_write``), ``W_o`` under ``ret_out``.  No page
+    is written or read.  Returns (h + operator, state)."""
+    from .retention_decode import retention_mixer
+    with jax.named_scope("ret_qkvg"):
+        x = _norm(blk, params, f"{us}_ln1", h)
+        q, k, v = _qkv_heads(params, us, blk, i, x, H, Hkv, Dh, posns)
+        lg = jax.nn.log_sigmoid(
+            (x @ params[f"{us}_ret_gate_weight"]).astype(jnp.float32)
+            + params[f"{us}_ret_gate_bias"].astype(jnp.float32))
+    y, state = retention_mixer(blk.retention, q, k, v, lg, state, si,
+                               q_len, rows)
+    with jax.named_scope("ret_out"):
+        h = h + _proj(params, f"{us}_attn_proj", y, blk.bias)
+    return h, state
+
+
 def _mixed_step(params, cfg_tuple, cache_k, cache_v, pos, tokens,
                 q_len, first_row, self_fresh, window=1, attn="masked",
                 block_tables=None, has_fresh=False, moe_stats=None,
@@ -1626,7 +1697,13 @@ def _mixed_step(params, cfg_tuple, cache_k, cache_v, pos, tokens,
     ``ssm_conv``, ``ssm_scan``, ``state_write``, ``ssm_out``) on ONE
     norm, ``state`` then being the mixer's set (a conv tail and a
     float32 matrix state a layer) and the two outputs summed into the
-    residual.  With
+    residual; or, where it says "retention", ``_retention_operator``
+    (``ret_qkvg``, ``ret_expand``, ``ret_scan``, ``state_write``,
+    ``ret_out``): the K/V attention's front end (``_qkv_heads``, shared
+    with it) into ``retention_decode.retention_mixer`` over ``state``,
+    which writes and reads no page, so that a spec of such layers alone
+    is handed NO pool (``cache_k`` and ``cache_v`` None, the tables
+    unread) and builds no mask over positions.  With
     ``ops`` the pool holds the layers with an attention alone and the
     state the layers with a conv or a mixer alone, each layer finding
     its own by ``blk.op_index``.  A spec's multipliers (``blk.mup``)
@@ -1700,7 +1777,10 @@ def _mixed_wave(params, cfg_tuple, cache_k, cache_v, pos, tokens, q_len,
         from ..kernels.ragged_attention import (
             ragged_attention, ragged_paged_attention,
         )
-    if paged:
+    # a spec none of whose layers holds a page has no pool, and its wave
+    # none of what follows up to the layers
+    pooled = blk.op_layers(L, "pool") + blk.op_layers(L, "window") > 0
+    if paged and pooled:
         bs_blk = _kv_shape(cache_k)[2]
         T = block_tables.shape[1]
         posc = jnp.clip(posns, 0, S_max - 1)
@@ -1725,21 +1805,22 @@ def _mixed_wave(params, cfg_tuple, cache_k, cache_v, pos, tokens, q_len,
                 wblk_w = jnp.where(valid_r, rows.pack(wblk_w), 0)
     else:
         span = S_max
-    ctx = jnp.arange(span)[None, None, :]
-    live = ctx <= posns[:, :, None]                        # [B, Q, S]
-    # fresh-self variant: context strictly below the write window plus
-    # a causal mask over the in-flight q-block
-    ctx_live = (jnp.arange(span)[None, :] < pos[:, None])  # [B, S]
-    jj = jnp.arange(Q)
-    self_live = (jj[None, None, :] <= jj[None, :, None]) \
-        & valid[:, None, :]                                # [B, Q, Q]
+    if pooled:
+        ctx = jnp.arange(span)[None, None, :]
+        live = ctx <= posns[:, :, None]                    # [B, Q, S]
+        # fresh-self variant: context strictly below the write window
+        # plus a causal mask over the in-flight q-block
+        ctx_live = (jnp.arange(span)[None, :] < pos[:, None])  # [B, S]
+        jj = jnp.arange(Q)
+        self_live = (jj[None, None, :] <= jj[None, :, None]) \
+            & valid[:, None, :]                            # [B, Q, Q]
     if win is not None:
         # the masked path's band: a query admits the ``blk.window``
         # positions that end at its own
         near = ctx > posns[:, :, None] - blk.window        # [B, Q, S]
         self_near = jj[None, :] > jj[:, None] - blk.window  # [Q, Q]
     scale = Dh ** -0.5
-    quant = _kv_q(cache_k)
+    quant = pooled and _kv_q(cache_k)
 
     def per_query_head(kv):
         """K/V heads ``[.., Hkv, Dh]`` as the query heads read them."""
@@ -1750,6 +1831,12 @@ def _mixed_wave(params, cfg_tuple, cache_k, cache_v, pos, tokens, q_len,
         if blk.op_kind(i) == "conv":
             h, state = _conv_operator(params, us, blk, h, state,
                                       blk.op_index(i), q_len, rows)
+            h = _ffn_of_kind(params, us, blk, h, i, valid_r, moe_stats)
+            continue
+        if blk.op_kind(i) == "retention":
+            h, state = _retention_operator(
+                params, us, blk, i, h, H, Hkv, Dh, posns_r, state,
+                blk.op_index(i), q_len, rows)
             h = _ffn_of_kind(params, us, blk, h, i, valid_r, moe_stats)
             continue
         if blk.attention == "latent":
@@ -1784,23 +1871,7 @@ def _mixed_wave(params, cfg_tuple, cache_k, cache_v, pos, tokens, q_len,
             y_ssm, state = ssm_mixer(params, us, blk, x, state,
                                      blk.op_index(i, "state"), q_len, rows)
         with jax.named_scope("attn_qkv"):
-            if mup is not None:
-                x = x * mup.attention_in
-            q = _proj(params, f"{us}_attn_q", x, blk.bias).reshape(
-                Br, Qr, H, Dh)
-            k = _proj(params, f"{us}_attn_k", x, blk.bias).reshape(
-                Br, Qr, Hkv, Dh)
-            v = _proj(params, f"{us}_attn_v", x, blk.bias).reshape(
-                Br, Qr, Hkv, Dh)
-            if mup is not None:
-                k = k * mup.key
-            if blk.qk_norm:
-                q = _rms(q, params[f"{us}_attn_q_norm_scale"], blk.norm_eps)
-                k = _rms(k, params[f"{us}_attn_k_norm_scale"], blk.norm_eps)
-            if blk.positions == "rope":
-                inv, factor = blk.rope_of(i)
-                q = _rope(q, posns_r, blk.rope_theta, inv, factor)
-                k = _rope(k, posns_r, blk.rope_theta, inv, factor)
+            q, k, v = _qkv_heads(params, us, blk, i, x, H, Hkv, Dh, posns_r)
         k_r, v_r = k, v
         if rows is not None:
             # the page write, the scoring and the fresh-self softmax

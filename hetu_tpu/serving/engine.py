@@ -244,8 +244,10 @@ class ServingEngine:
             self.chunk = max(int(chunk or 0), 0)
             self.kv = PagedKVManager(
                 # the pool holds the layers with an attention; the
-                # layers with a conv or a state-space mixer keep slot
-                # state beside it (a layer may do both)
+                # layers with a conv, a state-space mixer or retention
+                # keep slot state beside it (a layer may do both; where
+                # none holds a page there is no pool, and the manager
+                # takes no ``pool_blocks``)
                 layers=blk.op_layers(L, "pool"),
                 heads=blk.kv_heads or c.num_attention_heads,
                 head_dim=Dh, slots=slots, max_seq_len=want,
@@ -281,9 +283,17 @@ class ServingEngine:
             self._routed_layers = self.block_spec.routed_layers(
                 c.num_hidden_layers)
         # layers with a state-space mixer: their waves count
-        # ``serve.ssm.*`` (``ServingMetrics.record_ssm``)
+        # ``serve.ssm.*`` (``ServingMetrics.record_state_scan``)
         self._ssm_layers = self.block_spec.op_layers(
             c.num_hidden_layers, "attention+ssm")
+        # ... and with power retention: ``serve.ret.*``
+        # (the same); layers with an attention of any kind (none:
+        # the engine's ``serve.attn.*`` stay 0 and no kernel runs)
+        self._ret_layers = self.block_spec.op_layers(
+            c.num_hidden_layers, "retention")
+        self._attn_layers = sum(
+            self.block_spec.op_layers(c.num_hidden_layers, pages)
+            for pages in ("pool", "window"))
         self._window_recycled_seen = 0
         if self.moe is not None:
             self.cfg_tuple = self.cfg_tuple + (self.moe,)
@@ -521,10 +531,12 @@ class ServingEngine:
                 "drop": [int(x) for x in drop]}
 
     def _wave_record(self, wave):
-        """Every wave's attention counters (``serve.attn.*``) and, on an
-        engine with state-space layers, its ``serve.ssm.*`` ones and
-        their ``record_step`` payload: the wave descriptor's own
-        arithmetic.  Slot b's ``q_len`` rows at positions ``pos .. pos
+        """Every wave's attention counters (``serve.attn.*``; 0 on an
+        engine none of whose layers attends) and, on an engine with
+        state-space or retention layers, its ``serve.ssm.*`` /
+        ``serve.ret.*`` ones and their ``record_step`` payloads
+        ({"ssm": .., "ret": ..}, those it has): the wave descriptor's
+        own arithmetic.  Slot b's ``q_len`` rows at positions ``pos .. pos
         + q_len - 1`` see ``pos + j + 1`` positions each, and the slot
         holds ``pos + q_len`` positions after the wave's writes; a live
         slot's state moves once a state-space layer.  An engine with
@@ -532,8 +544,9 @@ class ServingEngine:
         (``record_attention``'s ``window``)."""
         ql = wave["q_len"].astype(np.int64)
         pos = wave["pos"].astype(np.int64)
-        ctx = int(np.where(ql > 0, pos + ql, 0).sum())
-        pairs = int((ql * pos + ql * (ql + 1) // 2).sum())
+        attends = self._attn_layers > 0
+        ctx = int(np.where(ql > 0, pos + ql, 0).sum()) if attends else 0
+        pairs = int((ql * pos + ql * (ql + 1) // 2).sum()) if attends else 0
         window = None
         if self.paged and self.kv.window_layers:
             # a window layer's rows see ``min(pos + j + 1, W)`` positions
@@ -550,19 +563,27 @@ class ServingEngine:
                      + (ql - grow) * W).sum()),
                 recycled - self._window_recycled_seen)
             self._window_recycled_seen = recycled
-        self.metrics.record_attention(ctx, pairs, window,
-                                      self._attn_tiles(ql, int(wave["q"])))
-        if not self._ssm_layers:
-            return None
-        # row pairs (i, j <= i) inside the chunks of the chunked form: a
-        # q-block of one row takes the plain step and has none
-        c = self.block_spec.ssm.chunk
+        self.metrics.record_attention(
+            ctx, pairs, window,
+            self._attn_tiles(ql, int(wave["q"])) if attends else None)
+        out = {}
         wide = np.where(ql > 1, ql, 0)
-        full, rest = wide // c, wide % c
-        chunk_pairs = int((full * (c * (c + 1) // 2)
-                           + rest * (rest + 1) // 2).sum())
-        return self.metrics.record_ssm(int((ql > 0).sum()), int(ql.sum()),
-                                       chunk_pairs, self._ssm_layers)
+        for kind, layers, spec in (
+                ("ssm", self._ssm_layers, self.block_spec.ssm),
+                ("ret", self._ret_layers, self.block_spec.retention)):
+            if not layers:
+                continue
+            # row pairs (i, j <= i) inside the chunks of the chunked
+            # form: a q-block of one row takes the plain step and has
+            # none
+            c = spec.chunk
+            full, rest = wide // c, wide % c
+            chunk_pairs = int((full * (c * (c + 1) // 2)
+                               + rest * (rest + 1) // 2).sum())
+            out[kind] = self.metrics.record_state_scan(
+                kind, int((ql > 0).sum()), int(ql.sum()), chunk_pairs,
+                layers)
+        return out
 
     def _attn_tiles(self, q_len, Q):
         """(live (slot, q-tile) steps, those scored at the short height)
@@ -1170,7 +1191,7 @@ class ServingEngine:
                                               w.rows_computed)
             elif w.moe_stats is not None:
                 moe_rec = self._moe_record(w.moe_stats)
-            ssm_rec = self._wave_record(wave)
+            scan_rec = self._wave_record(wave)
             self.metrics.record_wave(
                 w.rows_live, w.rows_computed, len(w.waiting),
                 ahead=w.ahead,
@@ -1303,7 +1324,7 @@ class ServingEngine:
                 end_perf=now, spec=spec,
                 mix={"q_prefill": q_pre, "q_verify": q_ver,
                      "q_decode": n_dec},
-                moe=moe_rec, ssm=ssm_rec,
+                moe=moe_rec, **scan_rec,
                 # the window pool's ring and the most blocks a slot holds
                 window={"ring": self.kv.ring, "held_max": int(
                     np.count_nonzero(self.kv.win_tables, axis=1).max())}

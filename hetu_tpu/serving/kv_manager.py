@@ -487,6 +487,17 @@ class PagedKVManager:
     position, the wire, an int8 pool.  A manager without window layers
     has none of this: its arrays, tables and programs are what they
     were.
+
+    NO pool layer (``layers=0``: a model whose every layer keeps slot
+    state and no page, ``state_shapes`` then being all it holds):
+    ``cache_k`` and ``cache_v`` are None, a token takes no block
+    (``blocks_needed`` is 0, the tables one zero column wide), admission
+    is by SLOT alone and ``max_seq_len`` is bounded by ``pos_cap`` only.
+    The slot count is then EXACT, not rounded up to a power of two: a
+    slot of such a manager costs its whole state whether or not a
+    request is in it (34 MB a layer for the retention state), where a
+    pooled manager's idle slot costs a table row.  Everything the state
+    refuses stays refused (``_refuse_state``).
     """
 
     def __init__(self, *, layers, heads, head_dim, slots, max_seq_len,
@@ -494,8 +505,20 @@ class PagedKVManager:
                  block=16, pool_blocks=None, prefix_share=None,
                  row_shape=None, state_shape=None, state_shapes=None,
                  window_layers=0, window=0, window_chunk=0):
+        self.pool_layers = int(layers)
+        if self.pool_layers < 0 or not (self.pool_layers or state_shapes
+                                        or state_shape):
+            raise ValueError(
+                f"PagedKVManager: layers={layers} and no slot state: a "
+                f"manager holds pool layers, slot state, or both")
+        if not self.pool_layers and (pool_blocks is not None
+                                     or window_layers):
+            raise ValueError(
+                "PagedKVManager: layers=0 holds no block: pool_blocks and "
+                "window_layers go with pool layers")
         if bucket:
-            slots = round_up_pow2(slots)
+            if self.pool_layers:
+                slots = round_up_pow2(slots)
             s = round_up_pow2(max_seq_len, floor=16)
         else:
             s = int(max_seq_len)
@@ -511,13 +534,17 @@ class PagedKVManager:
         self.block = int(block)
         if self.block < 1:
             raise ValueError(f"block size must be >= 1, got {block}")
-        # table width: blocks needed for a brim-full sequence
-        self.table_width = -(-self.s_max // self.block)
+        # table width: blocks needed for a brim-full sequence (without
+        # pool layers: one column that stays zero, and the scratch block
+        # alone)
+        self.table_width = -(-self.s_max // self.block) \
+            if self.pool_layers else 1
         if pool_blocks is None:
             # contiguous-equivalent capacity (+1 for the scratch block)
-            pool_blocks = self.n_slots * self.table_width + 1
+            pool_blocks = self.n_slots * self.table_width + 1 \
+                if self.pool_layers else 1
         self.n_blocks = int(pool_blocks)
-        if self.n_blocks < 2:
+        if self.n_blocks < 2 and self.pool_layers:
             raise ValueError("pool needs at least 2 blocks "
                              "(scratch + one allocatable)")
         # state beside the pool: a SET of arrays indexed by SLOT, not by
@@ -571,7 +598,9 @@ class PagedKVManager:
         # prefix sharing, COW, truncate and release never look inside a
         # row, so they hold for either
         self.latent = row_shape is not None
-        if self.latent:
+        if not self.pool_layers:
+            self.cache_k = self.cache_v = None
+        elif self.latent:
             if self.quant:
                 raise ValueError(
                     "an int8 pool of latent rows is not supported: the "
@@ -711,7 +740,8 @@ class PagedKVManager:
             if self.window_layers else 0
 
     def blocks_needed(self, tokens):
-        return -(-int(tokens) // self.block)
+        """Blocks ``tokens`` positions take: none without pool layers."""
+        return -(-int(tokens) // self.block) if self.pool_layers else 0
 
     def bucket_prompt(self, p):
         """Same contract as ``KVCacheManager.bucket_prompt`` (pos_cap

@@ -217,6 +217,9 @@ class ServingMetrics(MetricsCore):
         self.ssm_slot_steps = 0
         self.ssm_rows = 0
         self.ssm_chunk_pairs = 0
+        self.ret_slot_steps = 0
+        self.ret_rows = 0
+        self.ret_chunk_pairs = 0
         self.wave_rows_live = 0
         self.wave_rows_computed = 0
         self.chunks_deferred = 0
@@ -298,27 +301,34 @@ class ServingMetrics(MetricsCore):
             telemetry.inc("serve.attn.window_ctx_tokens", ctx)
             telemetry.inc("serve.attn.window_score_pairs", pairs)
 
-    def record_ssm(self, live_slots, rows, chunk_pairs, layers):
-        """One wave of an engine with ``layers`` state-space layers:
-        ``live_slots`` (slots with a row in the wave: each one's matrix
-        state is read and written once a layer), ``rows`` (the wave's
-        live rows) and ``chunk_pairs`` (the row pairs ``j <= i`` inside
-        the chunks of the q-blocks wider than one row: what the chunked
-        form multiplies out besides).  Running sums ``ssm_slot_steps``
-        (live slots x layers), ``ssm_rows`` and ``ssm_chunk_pairs``
-        (each x layers) here, the counters ``serve.ssm.slot_steps``,
-        ``serve.ssm.rows`` and ``serve.ssm.chunk_pairs`` in
-        ``telemetry``; returns the ``record_step`` payload whose
-        ``slot_steps == live_slots * layers`` ``hetu_trace --check``
-        holds a ``serve_step`` to."""
+    def record_state_scan(self, kind, live_slots, rows, chunk_pairs,
+                          layers):
+        """One wave of an engine with ``layers`` layers that scan a slot
+        state, ``kind`` "ssm" (state-space mixers) or "ret" (power
+        retention): ``live_slots`` (slots with a row in the wave: each
+        one's state is read and written once a layer), ``rows`` (the
+        wave's live rows) and ``chunk_pairs`` (the row pairs ``j <= i``
+        inside the chunks of the q-blocks wider than one row: what the
+        chunked form multiplies out besides).  Running sums
+        ``<kind>_slot_steps`` (live slots x layers), ``<kind>_rows`` and
+        ``<kind>_chunk_pairs`` (each x layers) here, the counters
+        ``serve.<kind>.slot_steps``, ``serve.<kind>.rows`` and
+        ``serve.<kind>.chunk_pairs`` in ``telemetry``; returns the
+        ``record_step`` payload whose ``slot_steps == live_slots *
+        layers`` ``hetu_trace --check`` holds a ``serve_step`` to."""
         steps, rows = int(live_slots) * int(layers), int(rows) * int(layers)
         pairs = int(chunk_pairs) * int(layers)
-        self.ssm_slot_steps += steps
-        self.ssm_rows += rows
-        self.ssm_chunk_pairs += pairs
-        telemetry.inc("serve.ssm.slot_steps", steps)
-        telemetry.inc("serve.ssm.rows", rows)
-        telemetry.inc("serve.ssm.chunk_pairs", pairs)
+        for key, n in (("slot_steps", steps), ("rows", rows),
+                       ("chunk_pairs", pairs)):
+            setattr(self, f"{kind}_{key}",
+                    getattr(self, f"{kind}_{key}") + n)
+        # (literal names: tests/test_telemetry.py reads them from here)
+        ret = kind == "ret"
+        telemetry.inc("serve.ret.slot_steps" if ret
+                      else "serve.ssm.slot_steps", steps)
+        telemetry.inc("serve.ret.rows" if ret else "serve.ssm.rows", rows)
+        telemetry.inc("serve.ret.chunk_pairs" if ret
+                      else "serve.ssm.chunk_pairs", pairs)
         return {"slot_steps": steps, "rows": rows,
                 "live_slots": int(live_slots), "layers": int(layers)}
 
@@ -422,7 +432,7 @@ class ServingMetrics(MetricsCore):
     def record_step(self, live, slots, queue_depth, dt_s, new_tokens,
                     prefill_s=0.0, step=None, requests=None,
                     end_perf=None, spec=None, mix=None, moe=None,
-                    ssm=None, window=None):
+                    ssm=None, window=None, ret=None):
         """One fused decode step; ``prefill_s`` is the prefill wall time
         this scheduler iteration paid before decoding, so the per-step
         JSONL event attributes the phases separately (the masked vs
@@ -455,10 +465,11 @@ class ServingMetrics(MetricsCore):
         ``drop_rate`` feed hetu_top's expert columns.  Dense steps
         carry no moe_* fields and the checker exempts them.
 
-        ``ssm`` (``record_ssm``'s {slot_steps, rows, live_slots, layers}
+        ``ssm`` (``record_state_scan``'s {slot_steps, rows, live_slots, layers}
         dict, engines with state-space layers only) stamps the wave's
         state traffic: ``slot_steps == live_slots * layers`` is the
-        invariant hetu_trace --check enforces.
+        invariant hetu_trace --check enforces; ``ret`` is the same
+        payload for an engine's retention layers, under ``ret_*``.
 
         ``window`` ({ring, held_max}, engines with window layers only)
         stamps the window pool's ring and the most window blocks any
@@ -497,9 +508,10 @@ class ServingMetrics(MetricsCore):
             fields["moe_imb"] = round(float(moe.get("imb", 0.0)), 4)
             fields["moe_drop_rate"] = round(
                 float(moe.get("drop_rate", 0.0)), 6)
-        if ssm is not None:
-            for k in ("slot_steps", "rows", "live_slots", "layers"):
-                fields[f"ssm_{k}"] = int(ssm.get(k, 0))
+        for kind, scan in (("ssm", ssm), ("ret", ret)):
+            if scan is not None:
+                for k in ("slot_steps", "rows", "live_slots", "layers"):
+                    fields[f"{kind}_{k}"] = int(scan.get(k, 0))
         if window is not None:
             fields["window_ring"] = int(window["ring"])
             fields["window_held_max"] = int(window["held_max"])
@@ -628,6 +640,7 @@ class ServingMetrics(MetricsCore):
                     "attn_window_ctx_tokens", "attn_window_score_pairs",
                     "window_blocks_recycled",
                     "ssm_slot_steps", "ssm_rows", "ssm_chunk_pairs",
+                    "ret_slot_steps", "ret_rows", "ret_chunk_pairs",
                     "wave_rows_live", "wave_rows_computed",
                     "chunks_deferred", "waves_ahead", "rows_dead_ahead")
 
@@ -713,6 +726,9 @@ class ServingMetrics(MetricsCore):
             "ssm_slot_steps": count("ssm_slot_steps"),
             "ssm_rows": count("ssm_rows"),
             "ssm_chunk_pairs": count("ssm_chunk_pairs"),
+            "ret_slot_steps": count("ret_slot_steps"),
+            "ret_rows": count("ret_rows"),
+            "ret_chunk_pairs": count("ret_chunk_pairs"),
             "wave_rows_live": count("wave_rows_live"),
             "wave_rows_computed": count("wave_rows_computed"),
             "chunks_deferred": count("chunks_deferred"),

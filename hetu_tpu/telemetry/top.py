@@ -60,6 +60,14 @@ free blocks and bytes from the ``serve.blocks_free.window`` /
 holds from the last ``serve_step``'s ``window_ring`` /
 ``window_held_max``).  Other engines render no such line.
 
+Slot state (ISSUE 44): an engine whose layers keep state beside (or in
+place of) the K/V pool (a short convolution, a state-space mixer,
+power retention) renders a ``state`` line: the bytes of all its slot
+states (the ``serve.state.bytes`` gauge) and, where its ``serve_step``
+records count them (``ssm_slot_steps`` / ``ret_slot_steps``), the slot
+states read and written a second over the visible window.  Other
+engines render no such line.
+
 Elastic fleet (ISSUE 16): ``--fleet`` grows a per-replica ``life``
 column (warming/serving/draining/retired, from the router's
 ``replica_warming``/``replica_ready``/``replica_draining``/
@@ -209,6 +217,19 @@ def summarize(events, window=512):
             "bytes": gauges.get("serve.kv.window_bytes"),
             "ring": newest.get("window_ring"),
             "held_max": newest.get("window_held_max")}
+    # slot state (ISSUE 44): the gauge, and the slot steps a second the
+    # visible steps counted (state-space and retention layers alike)
+    state = None
+    scanned = [s for s in steps if any(
+        isinstance(s.get(f"{k}_slot_steps"), int) for k in ("ssm", "ret"))]
+    if scanned or gauges.get("serve.state.bytes") is not None:
+        span = steps[-1].get("t", 0) - steps[0].get("t", 0) \
+            if len(steps) >= 2 else 0
+        total = sum(s.get(f"{k}_slot_steps") or 0
+                    for s in scanned for k in ("ssm", "ret"))
+        state = {"bytes": gauges.get("serve.state.bytes"),
+                 "slot_steps_per_sec": round(total / span, 1)
+                 if scanned and span > 0 else None}
     spec = {
         "drafted": drafted,
         "accepted": accepted,
@@ -243,6 +264,7 @@ def summarize(events, window=512):
         "mix": mix,
         "moe": moe,
         "kv_window": kv_window,
+        "state": state,
         "slo": slo,
         "flight_dumps": flight_dumps,
         "weight_version": weight_version,
@@ -605,6 +627,11 @@ def render(stats, clock=None):
             f"  ring {_fmt(kw['ring'])}"
             f"  held_max {_fmt(kw['held_max'])}"
             f"  bytes {_fmt(kw['bytes'])}"))
+    st = s.get("state")
+    if st:
+        lines.insert(-1, (
+            f"state     bytes {_fmt(st['bytes'])}"
+            f"  slot_steps/s {_fmt(st['slot_steps_per_sec'])}"))
     return "\n".join(lines)
 
 

@@ -737,31 +737,45 @@ def check_moe_attribution(events):
     return problems
 
 
+def _check_state_scan(events, kind, what):
+    """``slot_steps = live slots x layers`` on every ``serve_step`` that
+    carries ``<kind>_slot_steps`` (``ServingMetrics.record_state_scan``'s
+    payload): every live slot's state moves once a layer a wave, a dead
+    slot's never.  Records without the field are exempt; one missing a
+    companion field is itself a violation."""
+    problems = []
+    for e in events:
+        if e.get("event") != "serve_step" or f"{kind}_slot_steps" not in e:
+            continue
+        steps, slots, layers = (e.get(f"{kind}_{k}") for k in (
+            "slot_steps", "live_slots", "layers"))
+        if not all(isinstance(v, int) for v in (steps, slots, layers)):
+            problems.append(
+                f"{kind}-attribution: step {e.get('step')!r} carries "
+                f"{kind}_slot_steps without integer {kind}_live_slots and "
+                f"{kind}_layers")
+        elif steps != slots * layers:
+            problems.append(
+                f"{kind}-attribution: step {e.get('step')!r} counts {steps} "
+                f"slot steps but {slots} live slot(s) x {layers} "
+                f"{what} layer(s) = {slots * layers}")
+    return problems
+
+
 def check_ssm_attribution(events):
     """The state-traffic rule (ISSUE 37): a ``serve_step`` record of an
     engine with state-space layers carries ``ssm_slot_steps``, and it
     must equal the wave's live slots times the state-space layers
-    (``ssm_live_slots`` x ``ssm_layers``): every live slot's matrix
-    state moves once a layer a wave, a dead slot's never.  Records
-    without ``ssm_slot_steps`` are exempt; one missing a companion
-    field is itself a violation.  Returns problem strings."""
-    problems = []
-    for e in events:
-        if e.get("event") != "serve_step" or "ssm_slot_steps" not in e:
-            continue
-        steps, slots, layers = (e.get(f"ssm_{k}") for k in (
-            "slot_steps", "live_slots", "layers"))
-        if not all(isinstance(v, int) for v in (steps, slots, layers)):
-            problems.append(
-                f"ssm-attribution: step {e.get('step')!r} carries "
-                f"ssm_slot_steps without integer ssm_live_slots and "
-                f"ssm_layers")
-        elif steps != slots * layers:
-            problems.append(
-                f"ssm-attribution: step {e.get('step')!r} counts {steps} "
-                f"slot steps but {slots} live slot(s) x {layers} "
-                f"state-space layer(s) = {slots * layers}")
-    return problems
+    (``ssm_live_slots`` x ``ssm_layers``).  Returns problem strings
+    (``_check_state_scan``)."""
+    return _check_state_scan(events, "ssm", "state-space")
+
+
+def check_ret_attribution(events):
+    """The same rule for an engine with power-retention layers (ISSUE
+    44): ``ret_slot_steps = ret_live_slots x ret_layers`` on every
+    ``serve_step`` that carries it.  Returns problem strings."""
+    return _check_state_scan(events, "ret", "retention")
 
 
 def check_window_ring(events):
@@ -989,6 +1003,8 @@ def main(argv=None):
         problems.extend(moe)
         ssm = check_ssm_attribution(events)
         problems.extend(ssm)
+        ret = check_ret_attribution(events)
+        problems.extend(ret)
         ring = check_window_ring(events)
         problems.extend(ring)
         nesting = check_span_nesting(events)
@@ -1012,6 +1028,7 @@ def main(argv=None):
                           "lockdep_violations": len(lockdep),
                           "moe_attribution_violations": len(moe),
                           "ssm_attribution_violations": len(ssm),
+                          "ret_attribution_violations": len(ret),
                           "window_ring_violations": len(ring),
                           "span_nesting_violations": len(nesting),
                           "lifecycle_residue_violations":

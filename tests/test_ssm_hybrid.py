@@ -513,7 +513,8 @@ def test_record_ssm_counts_what_the_check_reads():
     from hetu_tpu.serving.metrics import ServingMetrics
     m = ServingMetrics()
     mark = m.mark()
-    rec = m.record_ssm(live_slots=3, rows=19, chunk_pairs=40, layers=4)
+    rec = m.record_state_scan("ssm", live_slots=3, rows=19,
+                              chunk_pairs=40, layers=4)
     assert rec == {"slot_steps": 12, "rows": 76, "live_slots": 3,
                    "layers": 4}
     snap = m.snapshot(since=mark)
