@@ -40,17 +40,30 @@ q-block takes the CHUNKED form over chunks of ``RetentionSpec.chunk``
 rows: inside a chunk the scores ``(q_t . k_j)^2`` directly (products
 ``d`` wide, never through ``phi``) under the gates' decay, masked before
 the exponential; from the carry ``G_t phi(q_t)^T S``; then ONE state
-update a chunk.  A wave's few such slots are gathered ``WIDE_LANES`` at a
-time, so ``phi(q)`` exists for those lanes' rows alone.  A dead row and a
-dead slot have ``lg`` 0 and ``k`` 0: decay 1, increment 0, the state
-stays where it was, bit for bit.  The matrix products take their
-operands in the activations' dtype and accumulate in float32; the state
-is read, decayed, added to and stored in float32.
+update a chunk.  A wave's few such slots are taken ``WIDE_LANES`` at a
+time.  A dead row and a dead slot have ``lg`` 0 and ``k`` 0: decay 1,
+increment 0, the state stays where it was, bit for bit.  The matrix
+products take their operands in the activations' dtype and accumulate in
+float32; the state is read, decayed, added to and stored in float32.
+
+What goes through ``phi`` in the chunked form (the carry's read, the
+normaliser's read, the state's update) has two bodies, chosen by static
+shape alone (``takes_kernel``): where the head is a whole number of lane
+tiles (the published 128) and the q-block wider than one row,
+``kernels/retention_scan`` builds ``phi(q)`` and ``phi(k)`` a stripe at
+a time in VMEM and never writes them, and reads and rewrites a lane's
+state once, where it lies in the manager's arrays
+(``retention_chunked_inplace``); anywhere else ``retention_chunked``, in
+XLA's own operations, on states sliced out and written back, which is
+also the form the kernel is held to.
 
 Scopes: ``ret_qkvg`` (``gpt_decode``: projections, q/k norm, rotation,
-gate), ``ret_expand`` (``phi`` of q and k), ``ret_scan`` (step and
-chunked forms, numerator and denominator), ``state_write`` (the shared
-name), ``ret_out`` (``gpt_decode``: ``W_o``).
+gate), ``ret_expand`` (``phi`` of q and k where XLA forms them),
+``ret_scan`` (step and chunked forms, numerator and denominator, the
+kernel ``retention_chunk_scan`` and with it the wide slots' state
+store), ``state_write`` (the shared name: the one-step pass's store, and
+the slices' where the chunked form runs in XLA), ``ret_out``
+(``gpt_decode``: ``W_o``).
 """
 
 from __future__ import annotations
@@ -133,9 +146,60 @@ def retention_step(q, k, v, lg, S, z):
     num = jnp.einsum("bgmD,bgDd->bgmd", pq, S,
                      preferred_element_type=f32)
     den = jnp.sum(pq * z[:, :, None], axis=-1)             # [B, g, m]
-    # (a slot that does not move and holds nothing yet reads 0 / 0: its
-    # row is read by nobody, and must not be a NaN beside the others)
-    return num / jnp.where(den == 0, 1.0, den)[..., None], S, z
+    return _read_out(num, den), S, z
+
+
+def _chunk_rows(q, k, v, lg, chunk):
+    """The rows of ``retention_chunked`` cut for chunks of ``chunk``:
+    (q, k, v, lg padded to whole chunks with rows of lg 0 and k 0, which
+    move nothing; the chunk's rows ``c``; the starts of the chunks)."""
+    Q = q.shape[1]
+    c = min(int(chunk), Q)
+    pad = -Q % c
+    if pad:
+        q, k, v, lg = (jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),)
+                               * (a.ndim - 2)) for a in (q, k, v, lg))
+    return q, k, v, lg, c, range(0, Q + pad, c)
+
+
+def _inside_chunk(qz, kz, vz, lgz):
+    """What a chunk's rows give each other, and the chunk's decays:
+    ``qz`` [B, c, g, m, d], ``kz`` / ``vz`` [B, c, g, d], ``lgz`` [B, c,
+    g].  Returns (num [B, g, m, c, d], den [B, g, m, c] float32 from
+    inside the chunk; since [B, g, 1, c]: the decay from the chunk's
+    start to each row; left [B, c, g]: from each row to the chunk's end;
+    end [B, g]: the whole chunk's)."""
+    f32 = jnp.float32
+    c = qz.shape[1]
+    tri = jnp.tril(jnp.ones((c, c), bool))
+    cum = jnp.cumsum(lgz.transpose(0, 2, 1), axis=-1)      # [B, g, c]
+    # row i weighs row j <= i by (q_i . k_j)^2 under the gates between
+    # them (masked BEFORE the exponential: above the diagonal the
+    # difference is positive and may overflow)
+    s = jnp.einsum("bigmd,bjgd->bgmij", qz, kz,
+                   preferred_element_type=f32)
+    decay = jnp.exp(jnp.where(
+        tri, cum[:, :, :, None] - cum[:, :, None, :], -jnp.inf))
+    a = s * s * decay[:, :, None]                          # [B, g, m, c, c]
+    num = jnp.einsum("bgmij,bjgd->bgmid", a.astype(qz.dtype), vz,
+                     preferred_element_type=f32)
+    den = jnp.sum(a, axis=-1)                              # [B, g, m, c]
+    since = jnp.exp(cum)[:, :, None]                       # [B, g, 1, c]
+    left = jnp.exp(cum[:, :, -1:] - cum).transpose(0, 2, 1)  # [B, c, g]
+    return num, den, since, left, jnp.exp(cum[:, :, -1])
+
+
+def _read_out(num, den):
+    """``num / den``; a row nobody reads (a dead one, or a slot that does
+    not move and holds nothing yet) has 0 / 0 and must not be a NaN
+    beside the others."""
+    return num / jnp.where(den == 0, 1.0, den)[..., None]
+
+
+def _rows_first(ys):
+    """The chunks' y [B, g, m, c, d] as one q-block [B, Q, g, m, d]."""
+    y = ys[0] if len(ys) == 1 else jnp.concatenate(ys, axis=3)
+    return y.transpose(0, 3, 1, 2, 4)
 
 
 def retention_chunked(q, k, v, lg, S, z, chunk):
@@ -144,63 +208,84 @@ def retention_chunked(q, k, v, lg, S, z, chunk):
     ``k`` 0 on dead rows), ``S`` [B, g, D, d] and ``z`` [B, g, D]
     float32 (the lane's carry).  Equal to ``retention_step`` row after
     row.  Returns (y [B, Q, g, n/g, d] float32, S, z after the
-    q-block)."""
+    q-block).  This is the form in XLA's own operations: what small
+    heads run, and what ``retention_chunked_inplace`` is held to."""
     Q = q.shape[1]
     f32 = jnp.float32
     cd = q.dtype                       # the products' operand dtype
-    c = min(int(chunk), Q)
-    pad = -Q % c
-    if pad:
-        # rows of lg 0 and k 0 past the q-block: they move nothing
-        q, k, v, lg = (jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),)
-                               * (a.ndim - 2)) for a in (q, k, v, lg))
-    tri = jnp.tril(jnp.ones((c, c), bool))
+    q, k, v, lg, c, starts = _chunk_rows(q, k, v, lg, chunk)
     ys = []
-    for z0 in range(0, Q + pad, c):
+    for z0 in starts:
         qz, kz, vz = q[:, z0:z0 + c], k[:, z0:z0 + c], v[:, z0:z0 + c]
-        cum = jnp.cumsum(lg[:, z0:z0 + c].transpose(0, 2, 1),
-                         axis=-1)                          # [B, g, c]
-        # inside the chunk: row i weighs row j <= i by (q_i . k_j)^2
-        # under the gates between them (masked BEFORE the exponential:
-        # above the diagonal the difference is positive and may
-        # overflow)
-        s = jnp.einsum("bigmd,bjgd->bgmij", qz, kz,
-                       preferred_element_type=f32)
-        decay = jnp.exp(jnp.where(
-            tri, cum[:, :, :, None] - cum[:, :, None, :], -jnp.inf))
-        a = s * s * decay[:, :, None]                      # [B, g, m, c, c]
-        num = jnp.einsum("bgmij,bjgd->bigmd", a.astype(cd), vz,
-                         preferred_element_type=f32)
-        den = jnp.sum(a, axis=-1).transpose(0, 3, 1, 2)    # [B, c, g, m]
+        num, den, since, left, end = _inside_chunk(qz, kz, vz,
+                                                   lg[:, z0:z0 + c])
         # from the carry: phi(q_i) reads S and z under the decay since
         # the chunk began
         with jax.named_scope("ret_expand"):
             pq = sympow2(qz).astype(cd)                    # [B, c, g, m, D]
-        since = jnp.exp(cum).transpose(0, 2, 1)[..., None]  # [B, c, g, 1]
-        num = num + jnp.einsum("bigmD,bgDd->bigmd", pq, S.astype(cd),
+        num = num + jnp.einsum("bigmD,bgDd->bgmid", pq, S.astype(cd),
                                preferred_element_type=f32) * since[..., None]
         den = den + jnp.sum(pq.astype(f32) * z[:, None, :, None],
-                            axis=-1) * since
-        ys.append(num / jnp.where(den == 0, 1.0, den)[..., None])
+                            axis=-1).transpose(0, 2, 3, 1) * since
+        ys.append(_read_out(num, den))
         # ONE state update a chunk: every row's increment under the
         # decay that is left to the chunk's end
-        left = jnp.exp(cum[:, :, -1:] - cum).transpose(0, 2, 1)  # [B, c, g]
         with jax.named_scope("ret_expand"):
             pk = sympow2(kz) * left[..., None]             # [B, c, g, D]
-        end = jnp.exp(cum[:, :, -1])                       # [B, g]
         S = S * end[:, :, None, None] \
             + jnp.einsum("bjgD,bjgd->bgDd", pk.astype(cd), vz,
                          preferred_element_type=f32)
         z = z * end[:, :, None] + jnp.sum(pk, axis=1)
-    y = ys[0] if len(ys) == 1 else jnp.concatenate(ys, axis=1)
-    return y[:, :Q], S, z
+    return _rows_first(ys)[:, :Q], S, z
+
+
+def takes_kernel(head_dim, q_block):
+    """The shape rule: whether a program whose q-blocks are ``q_block``
+    rows wide runs the wide slots' chunked form through
+    ``kernels/retention_scan`` (else through ``retention_chunked``).  A
+    head of whole lane tiles (the kernel's stripes are the head's width:
+    the published model's 128) and a q-block wider than one row.  Static
+    shapes alone decide, so a program is one or the other, and the
+    engine can ask the same question of a wave
+    (``serve.ret.kernel_slot_steps``)."""
+    from ..kernels.decode_attention import _LANES
+    return head_dim % _LANES == 0 and q_block > 1
+
+
+def retention_chunked_inplace(q, k, v, lg, mats, norms, slot, q_len,
+                              chunk):
+    """``retention_chunked`` for lanes whose carry is slot ``slot[b]`` of
+    the manager's ``mats`` [1, slots, g, D, d] / ``norms`` [1, slots, g,
+    D] (no two lanes the same slot; ``q_len`` [B]: a lane's live rows, 0
+    an idle lane): the scores inside a chunk and the division as there,
+    everything that goes through ``phi`` in
+    ``kernels.retention_scan.retention_chunk_scan``, which reads and
+    rewrites the lanes' states where they lie.  Returns (y [B, Q, g,
+    n/g, d] float32, mats, norms)."""
+    from ..kernels.retention_scan import retention_chunk_scan
+    Q = q.shape[1]
+    q, k, v, lg, c, starts = _chunk_rows(q, k, v, lg, chunk)
+    ys = []
+    for z0 in starts:
+        qz, kz, vz = q[:, z0:z0 + c], k[:, z0:z0 + c], v[:, z0:z0 + c]
+        num, den, since, left, end = _inside_chunk(qz, kz, vz,
+                                                   lg[:, z0:z0 + c])
+        carry, norm, mats, norms = retention_chunk_scan(
+            slot, jnp.maximum(q_len - z0, 0), qz, kz, vz, left, end,
+            mats, norms)
+        ys.append(_read_out(num + carry * since[..., None],
+                            den + norm * since))
+    return _rows_first(ys)[:, :Q], mats, norms
 
 
 # how many slots with a q-block wider than one row the chunked form
 # takes at a time: a packed wave of 1,024 rows holds three whole chunks
 # of 256 beside its decoding rows, so three lanes are one pass with no
-# idle lane (``phi(q)`` of a lane's 256 rows is 256 x 40 x 8,256 values,
-# 169 MB in bfloat16: fewer lanes than ``ssm_decode.WIDE_LANES``)
+# idle lane.  What bounds the count is the scores inside a chunk, [lanes,
+# g, m, c, c] float32 (31 MB at three lanes of 256 rows), and the idle
+# lanes of a pass that is not full; ``phi(q)`` no longer does where the
+# kernel runs (it was 169 MB a lane in bfloat16, written and read back),
+# and where XLA forms it the heads are small
 WIDE_LANES = 3
 
 
@@ -253,11 +338,41 @@ def retention_mixer(sp, q, k, v, lg, state, si, q_len, rows=None):
         y = y1.reshape(Br, Qr, n * d)
     else:
         with jax.named_scope("ret_scan"):
-            y_f = jnp.zeros((R, n * d), f32).at[
-                jnp.where(q_len == 1, first, R)].set(y1, mode="drop")
+            # (Q rows more than the wave's: a wide slot's q-block is
+            # written back as ONE slice of Q rows from its start, which
+            # may run past the last row)
+            y_f = jnp.zeros((R + Q, n * d), f32).at[
+                jnp.where(q_len == 1, first, R + Q)].set(y1, mode="drop")
             lanes = math.gcd(WIDE_LANES, B_)     # divides the slots
+            kernel = takes_kernel(d, Q)
             order = jnp.argsort(-q_len)                    # widest first
             n_wide = jnp.sum(q_len > 1)
+
+            def through_slices(qc, kc, vc, lgc, mats, norms, slot):
+                # a lane's state by a slice of its own: a gather over
+                # the slots makes the compiler copy the whole state
+                S0 = jnp.concatenate([jax.lax.dynamic_slice(
+                    mats, (0, slot[j], 0, 0, 0), (1, 1, g, D, d))[0]
+                    for j in range(lanes)]).astype(f32)
+                z0 = jnp.concatenate([jax.lax.dynamic_slice(
+                    norms, (0, slot[j], 0, 0), (1, 1, g, D))[0]
+                    for j in range(lanes)]).astype(f32)
+                yc, Sc, zc = retention_chunked(qc, kc, vc, lgc, S0, z0,
+                                               sp.chunk)
+                # every read of the lanes' old states ends here, before
+                # the writes below overwrite them in place
+                # (``ssm_mixer``: a slice read again after its write is
+                # the new state)
+                yc, Sc, zc = jax.lax.optimization_barrier((yc, Sc, zc))
+                with jax.named_scope("state_write"):
+                    for j in range(lanes):
+                        mats = jax.lax.dynamic_update_slice(
+                            mats, Sc[j].astype(kept)[None, None],
+                            (0, slot[j], 0, 0, 0))
+                        norms = jax.lax.dynamic_update_slice(
+                            norms, zc[j].astype(kept)[None, None],
+                            (0, slot[j], 0, 0))
+                return yc, mats, norms
 
             def wide(carry):
                 j0, mats, norms, y_f = carry
@@ -271,37 +386,30 @@ def retention_mixer(sp, q, k, v, lg, state, si, q_len, rows=None):
                 got = jnp.minimum(at, R - 1)
                 kc = jnp.where(live[..., None, None], k_f[got], 0)
                 lgc = jnp.where(live[..., None], lg_f[got], 0.0)
-                # a lane's state by a slice of its own: a gather over
-                # the slots makes the compiler copy the whole state
-                S0 = jnp.concatenate([jax.lax.dynamic_slice(
-                    mats, (0, slot[j], 0, 0, 0), (1, 1, g, D, d))[0]
-                    for j in range(lanes)]).astype(f32)
-                z0 = jnp.concatenate([jax.lax.dynamic_slice(
-                    norms, (0, slot[j], 0, 0), (1, 1, g, D))[0]
-                    for j in range(lanes)]).astype(f32)
-                yc, Sc, zc = retention_chunked(q_f[got], kc, v_f[got], lgc,
-                                               S0, z0, sp.chunk)
-                # every read of the lanes' old states ends here, before
-                # the writes below overwrite them in place
-                # (``ssm_mixer``: a slice read again after its write is
-                # the new state)
-                yc, Sc, zc = jax.lax.optimization_barrier((yc, Sc, zc))
-                y_f = y_f.at[jnp.where(live, at, R).reshape(-1)].set(
-                    yc.reshape(lanes * Q, n * d), mode="drop")
-                with jax.named_scope("state_write"):
-                    for j in range(lanes):
-                        mats = jax.lax.dynamic_update_slice(
-                            mats, Sc[j].astype(kept)[None, None],
-                            (0, slot[j], 0, 0, 0))
-                        norms = jax.lax.dynamic_update_slice(
-                            norms, zc[j].astype(kept)[None, None],
-                            (0, slot[j], 0, 0))
+                if kernel:
+                    # the lanes' states read and rewritten where they
+                    # lie, once (``kernels/retention_scan``)
+                    yc, mats, norms = retention_chunked_inplace(
+                        q_f[got], kc, v_f[got], lgc, mats, norms, slot, ql,
+                        sp.chunk)
+                else:
+                    yc, mats, norms = through_slices(
+                        q_f[got], kc, v_f[got], lgc, mats, norms, slot)
+                # a lane's live rows into the wave's, by a slice of its
+                # own (a scatter of 768 rows took 0.42 ms a layer on the
+                # chip, ten times its bytes: PERF.md section 6, PR 45)
+                for j in range(lanes):
+                    at0 = start[slot[j]]
+                    old = jax.lax.dynamic_slice_in_dim(y_f, at0, Q)
+                    y_f = jax.lax.dynamic_update_slice_in_dim(
+                        y_f, jnp.where(live[j][:, None],
+                                       yc[j].reshape(Q, n * d), old), at0, 0)
                 return j0 + 1, mats, norms, y_f
 
             _, mats, norms, y_f = jax.lax.while_loop(
                 lambda c: c[0] * lanes < n_wide, wide,
                 (jnp.int32(0), mats, norms, y_f))
-            y = y_f.reshape(Br, Qr, n * d)
+            y = y_f[:R].reshape(Br, Qr, n * d)
     return y.astype(q.dtype), (
         state[:si] + (mats,) + state[si + 1:n_state + si]
         + (norms,) + state[n_state + si + 1:])

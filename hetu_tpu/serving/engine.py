@@ -51,6 +51,7 @@ from ..models.gpt_decode import (
 from ..kernels.ragged_attention import (
     mla_tiling, rows_tiling, tile_heights)
 from ..models.moe_decode import takes_kernel
+from ..models.retention_decode import takes_kernel as retention_takes_kernel
 from .kv_manager import (KVCacheManager, PagedKVManager,
                          assemble_mixed_wave, resolve_kv_block,
                          resolve_kv_quant)
@@ -580,9 +581,13 @@ class ServingEngine:
             full, rest = wide // c, wide % c
             chunk_pairs = int((full * (c * (c + 1) // 2)
                                + rest * (rest + 1) // 2).sum())
+            # the wide slots whose chunked form the wave's program ran
+            # through ``kernels/retention_scan``: the program's own rule
+            by_kernel = kind == "ret" and retention_takes_kernel(
+                spec.head_dim, int(wave["q"]))
             out[kind] = self.metrics.record_state_scan(
                 kind, int((ql > 0).sum()), int(ql.sum()), chunk_pairs,
-                layers)
+                layers, int((ql > 1).sum()) if by_kernel else 0)
         return out
 
     def _attn_tiles(self, q_len, Q):

@@ -220,6 +220,7 @@ class ServingMetrics(MetricsCore):
         self.ret_slot_steps = 0
         self.ret_rows = 0
         self.ret_chunk_pairs = 0
+        self.ret_kernel_slot_steps = 0
         self.wave_rows_live = 0
         self.wave_rows_computed = 0
         self.chunks_deferred = 0
@@ -302,14 +303,19 @@ class ServingMetrics(MetricsCore):
             telemetry.inc("serve.attn.window_score_pairs", pairs)
 
     def record_state_scan(self, kind, live_slots, rows, chunk_pairs,
-                          layers):
+                          layers, kernel_slots=0):
         """One wave of an engine with ``layers`` layers that scan a slot
         state, ``kind`` "ssm" (state-space mixers) or "ret" (power
         retention): ``live_slots`` (slots with a row in the wave: each
         one's state is read and written once a layer), ``rows`` (the
         wave's live rows) and ``chunk_pairs`` (the row pairs ``j <= i``
         inside the chunks of the q-blocks wider than one row: what the
-        chunked form multiplies out besides).  Running sums
+        chunked form multiplies out besides); ``kernel_slots`` (of a
+        retention wave: the slots with a q-block wider than one row
+        whose chunked form the wave's program ran through
+        ``kernels/retention_scan``, ``retention_decode.takes_kernel`` of
+        its head and q-block; x layers the sum ``ret_kernel_slot_steps``
+        and the counter ``serve.ret.kernel_slot_steps``).  Running sums
         ``<kind>_slot_steps`` (live slots x layers), ``<kind>_rows`` and
         ``<kind>_chunk_pairs`` (each x layers) here, the counters
         ``serve.<kind>.slot_steps``, ``serve.<kind>.rows`` and
@@ -329,6 +335,10 @@ class ServingMetrics(MetricsCore):
         telemetry.inc("serve.ret.rows" if ret else "serve.ssm.rows", rows)
         telemetry.inc("serve.ret.chunk_pairs" if ret
                       else "serve.ssm.chunk_pairs", pairs)
+        if ret and kernel_slots:
+            by_kernel = int(kernel_slots) * int(layers)
+            self.ret_kernel_slot_steps += by_kernel
+            telemetry.inc("serve.ret.kernel_slot_steps", by_kernel)
         return {"slot_steps": steps, "rows": rows,
                 "live_slots": int(live_slots), "layers": int(layers)}
 
@@ -641,6 +651,7 @@ class ServingMetrics(MetricsCore):
                     "window_blocks_recycled",
                     "ssm_slot_steps", "ssm_rows", "ssm_chunk_pairs",
                     "ret_slot_steps", "ret_rows", "ret_chunk_pairs",
+                    "ret_kernel_slot_steps",
                     "wave_rows_live", "wave_rows_computed",
                     "chunks_deferred", "waves_ahead", "rows_dead_ahead")
 
@@ -729,6 +740,7 @@ class ServingMetrics(MetricsCore):
             "ret_slot_steps": count("ret_slot_steps"),
             "ret_rows": count("ret_rows"),
             "ret_chunk_pairs": count("ret_chunk_pairs"),
+            "ret_kernel_slot_steps": count("ret_kernel_slot_steps"),
             "wave_rows_live": count("wave_rows_live"),
             "wave_rows_computed": count("wave_rows_computed"),
             "chunks_deferred": count("chunks_deferred"),

@@ -738,3 +738,74 @@ def test_gpt2_xl_chunk_program_keeps_one_height():
     from hetu_tpu.kernels import ragged_attention as ra
     for q in (32, 64, 128, 256):
         assert ra.rows_tiling(q, 25, DH, 1, jnp.bfloat16)[2] == 0
+
+
+# ------------------------------------------------------------------- #
+# ISSUE 45: the retention layer's chunked form as one kernel
+# ------------------------------------------------------------------- #
+
+BRUMBY = {"slots": 24, "lanes": 3, "chunk": 256}
+
+
+@pytest.mark.parametrize("kept", ["float32", "bfloat16"])
+def test_retention_chunk_scan_at_the_cells_sizes(sds, kept):
+    """Three lanes of 256 rows x 5 query heads over 8 K/V heads of 128,
+    the state of 24 slots (float32 as the configuration keeps it,
+    bfloat16 as the control the comparison refuses does): the kernel
+    compiles for the chip, both states aliased, no temporary."""
+    from hetu_tpu.kernels import retention_scan as rs
+    lanes, c, slots = BRUMBY["lanes"], BRUMBY["chunk"], BRUMBY["slots"]
+    g, m, d = 8, 5, 128
+    D = d * (d + 1) // 2
+    bf, f32, kept = jnp.bfloat16, jnp.float32, jnp.dtype(kept)
+    args = (sds((lanes,), jnp.int32), sds((lanes,), jnp.int32),
+            sds((lanes, g, c * m, d), bf), sds((lanes, g, c, d), bf),
+            sds((lanes, g, c, d), bf), sds((lanes, g, c, 1), f32),
+            sds((lanes, g, 1, d), f32), sds((1, slots, g, D, d), kept),
+            sds((1, slots, g, D), kept))
+    compiled = jax.jit(
+        lambda *a: rs._chunk_scan_call(*a, interpret=False),
+        donate_argnums=(7, 8)).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    # (the normaliser's rows are padded to whole lane tiles)
+    assert mem.alias_size_in_bytes >= slots * g * D * (d + 1) * kept.itemsize
+    assert mem.temp_size_in_bytes == 0
+
+
+def test_retention_mixed_step_rewrites_the_states_where_they_lie(
+        sds, monkeypatch):
+    """Two layers of the documents cell at its published widths and 24
+    slots, the Q 256 program: ONE lowering of the kernel for both layers,
+    a call a layer, the four states aliased and no temporary of a
+    state's size (the un-aliased copy PR 44's first build paid for)."""
+    from hetu_tpu.kernels import retention_scan as rs
+    from hetu_tpu.models import retention_decode as rd
+    monkeypatch.setattr(rs, "_use_interpret", lambda: False)
+    L, B, Q = 2, BRUMBY["slots"], BRUMBY["chunk"]
+    cfg = rd.RetentionConfig.from_hf(dict(
+        _cell_config("brumby-14b.json"), num_hidden_layers=L,
+        vocab_size=512))
+    blk = cfg.block_spec()
+    params = {k: sds(s, jnp.float32 if k.endswith(rd.F32_LEAVES)
+                     else jnp.bfloat16)
+              for k, s in cfg.param_shapes("bru").items()}
+    state = tuple(sds((sh[0], B) + tuple(sh[1:]), dt)
+                  for sh, dt in blk.state_shapes(L, cfg.hidden_size))
+    i32 = lambda *shape: sds(shape, jnp.int32)  # noqa: E731
+    fn = gd.serve_mixed_paged_fn(donate=True, attn="ragged", window=1)
+    lowered = fn.func.lower(
+        params, ("bru", L, cfg.num_attention_heads, cfg.head_dim, 16896,
+                 blk), None, None, i32(B, 1), i32(B), i32(B, Q), i32(B),
+        i32(B), sds((B,), jnp.bool_), sds((B,), jnp.float32), i32(B),
+        sds((B, 2), jnp.uint32), attn="ragged", window=1, has_fresh=True,
+        state=state)
+    assert lowered.as_text().count("tpu_custom_call") == 1
+    compiled = lowered.compile()
+    calls = [line for line in compiled.as_text().splitlines()
+             if "custom-call(" in line and "retention_chunk_scan" in line]
+    assert len(calls) == L and all("tpu_custom_call" in c for c in calls)
+    one_state = B * 8 * 8256 * 128 * 4
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= L * B * 8 * 8256 * 129 * 4
+    assert mem.temp_size_in_bytes < one_state / 2

@@ -428,6 +428,8 @@ def test_engine_serves_through_the_states_and_no_pool(params, cfg, served):
     assert snap["ret_rows"] == rows * 3
     assert snap["ret_slot_steps"] % 3 == 0 and snap["ret_slot_steps"] > 0
     assert snap["ret_chunk_pairs"] > 0 and snap["ssm_slot_steps"] == 0
+    # heads of 16: the wide slots' chunked form stays in XLA's operations
+    assert snap["ret_kernel_slot_steps"] == 0
     # an engine without attention counts none, and runs no kernel
     assert snap["attn_ctx_tokens"] == snap["attn_score_pairs"] == 0
     assert snap["attn_tiles_live"] == snap["attn_tiles_short"] == 0
@@ -635,6 +637,30 @@ def test_record_state_scan_counts_what_the_check_reads():
     assert (snap["ret_slot_steps"], snap["ret_rows"],
             snap["ret_chunk_pairs"]) == (30, 1560, 1800)
     assert snap["ssm_slot_steps"] == 0
+
+
+@pytest.mark.parametrize("kind,kernel_slots,want", [
+    ("ret", 3, 18), ("ret", 0, 0), ("ssm", 3, 0)],
+    ids=["wide_slots_x_layers", "one_row_slots", "ssm_kind"])
+def test_record_state_scan_counts_the_slots_the_kernel_took(kind,
+                                                            kernel_slots,
+                                                            want):
+    """``serve.ret.kernel_slot_steps`` / ``ret_kernel_slot_steps`` (ISSUE
+    45): wide slots x layers of a retention wave whose program takes
+    ``kernels/retention_scan``; 0 for a wave of one-row slots and for
+    the state-space kind, whose waves run no such kernel."""
+    from hetu_tpu import telemetry
+    m = ServingMetrics()
+    mark = m.mark()
+    before = telemetry.snapshot()["counters"].get(
+        "serve.ret.kernel_slot_steps", 0)
+    rec = m.record_state_scan(kind, live_slots=24, rows=789, chunk_pairs=0,
+                              layers=6, kernel_slots=kernel_slots)
+    assert rec["slot_steps"] == 144
+    assert m.snapshot(since=mark)["ret_kernel_slot_steps"] == want
+    assert m.snapshot()["ret_kernel_slot_steps"] == want
+    assert telemetry.snapshot()["counters"].get(
+        "serve.ret.kernel_slot_steps", 0) - before == want
 
 
 # ------------------------------------------------------------------ #
