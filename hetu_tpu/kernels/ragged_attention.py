@@ -372,6 +372,31 @@ def tile_heights(q_len, t, tq, short):
     return rows > 0, rows > short
 
 
+def row_tile_visits(start, q_len, t, tq, short):
+    """THE rule of a VISIT in the packed latent kernel, whose q-tiles are
+    tiles of ``tq`` PACKED query rows and not of one slot's q-block: row
+    tile ``t`` visits every slot whose rows cross it.  Of a slot whose
+    q-block is the packed rows ``[start, start + q_len)``: (``lo``,
+    ``hi``) its rows inside the tile, counted from the tile's first;
+    whether there are any (LIVE); and whether the visit is scored at the
+    FULL tile.  A live visit whose rows lie inside one aligned window of
+    ``short`` queries (a tile is a whole number of them, so the windows
+    are the packed rows' own) is scored at that window: a decoding
+    slot's one row costs ``short`` queries of scores wherever it lies.
+    Plain operators, as :func:`tile_heights`: the kernel asks it of
+    prefetched scalars, its wrapper of the wave's arrays (which slots a
+    tile's loop runs over) and the engine's counters of a wave's
+    ``q_len`` (``serve.attn.tiles_live``, ``tiles_short``)."""
+    lo = start - t * tq
+    hi = lo + q_len
+    lo = lo * (lo > 0)                      # max(lo, 0)
+    hi = hi - (hi - tq) * (hi > tq)         # min(hi, tq)
+    live = hi > lo
+    if not short:
+        return lo, hi, live, live
+    return lo, hi, live, live & (lo // short != (hi - 1) // short)
+
+
 def _short_height(tq, sub):
     """The short height of a program whose q-tiles are ``tq`` queries:
     ``sub`` where that is less than a tile, else 0 (one height: the Q 1
@@ -894,6 +919,19 @@ def ragged_paged_reference(q, pool_k, pool_v, lengths, q_lens,
 # slots at 1,500 positions); sixteen in flight at once cost 0.27 ms, a
 # 256-row chunk beside them 4.5 against 11.3 (my chip run, PR 28:
 # PERF.md section 6).  Dead slots and dead tiles copy and score nothing.
+#
+# Two entries, chosen by how the wave's rows lie (static in the caller).
+# DENSE, ``ragged_paged_mla``: q-blocks ``[B, Q]``, grid (slot, q-tile):
+# every decode and verify wave.  PACKED, ``ragged_paged_mla_rows``
+# (ISSUE 46): a chunk wave's packed rows ``[R]``, grid (row tile) alone:
+# the q and o tiles that move are the 1,024 packed rows' and not the
+# padded block's 8,192, and a tile loops over the slots whose rows cross
+# it (``row_tile_visits``), each visit the same page pipeline, mask and
+# softmax step with every row that is not the slot's masked.  At the
+# long-answer cell's shapes the attention of a chunk wave's seven layers
+# took 19.8 ms in the dense form (the query's unpack, two relayouts of
+# 210 MB, 128 q and o tiles a call, the pack) and 3.2 packed (my chip
+# run, PR 46: PERF.md section 6).
 
 # (query, head) rows one q-tile may hold.  Sized by the sandbox's AOT
 # compile for v5e (PR 28): at 20 heads x 640 a tile of 1280 rows (64
@@ -927,20 +965,26 @@ def mla_tiling(Q, H):
     return tq, _short_height(tq, _MLA_SHORT_QUERIES)
 
 
+def _mla_copies(pool_ref, bt_ref, kv_buf, sem, layer, b, last, group, bs):
+    """``copies(gi, buf)`` of :func:`_page_loop` for slot ``b`` of the
+    latent pool: group ``gi``'s page copies into buffer ``buf``; pages
+    past the ``last`` in sight copy that one again (their positions are
+    masked)."""
+    def copies(gi, buf):
+        return [pltpu.make_async_copy(
+            pool_ref.at[layer, bt_ref[b, jnp.minimum(gi * group + g, last)]],
+            kv_buf.at[buf, pl.ds(g * bs, bs)], sem.at[buf])
+            for g in range(group)]
+    return copies
+
+
 def _mla_kernel(lens_ref, qlens_ref, bt_ref, q_ref, pool_ref, o_ref,
                 kv_buf, sem, m_ref, l_ref, acc_ref, *, scale, bs, group,
                 tq, heads, dv, layer, short=0):
     b, t, n_groups, last, full = _tile_in_sight(lens_ref, qlens_ref, tq, bs,
                                                 group, short)
-
-    def copies(gi, buf):
-        """Group ``gi``'s page copies into buffer ``buf``; pages past
-        the last in sight copy that one again (their positions are
-        masked)."""
-        return [pltpu.make_async_copy(
-            pool_ref.at[layer, bt_ref[b, jnp.minimum(gi * group + g, last)]],
-            kv_buf.at[buf, pl.ds(g * bs, bs)], sem.at[buf])
-            for g in range(group)]
+    copies = _mla_copies(pool_ref, bt_ref, kv_buf, sem, layer, b, last,
+                         group, bs)
 
     def rows(hq):
         """The tile's first ``hq`` queries: its first ``hq * heads``
@@ -978,6 +1022,19 @@ def _mla_kernel(lens_ref, qlens_ref, bt_ref, q_ref, pool_ref, o_ref,
     _at_heights(heights, finalize)
 
 
+def _mla_interpret(W, interpret):
+    """Whether a latent kernel's call is interpreted (``interpret``, or
+    the platform's answer); a row ``W`` wide must be whole lane tiles
+    wherever it is not: pages are copied by hand."""
+    if interpret is None:
+        interpret = _use_interpret()
+    if W % _LANES and not interpret:
+        raise ValueError(
+            f"ragged_paged_mla copies pages by hand: the row width {W} "
+            f"must be a multiple of {_LANES}")
+    return bool(interpret)
+
+
 def ragged_paged_mla(q, pool, lengths, q_lens, block_tables, *,
                      value_width, scale, layer=0, interpret=None):
     """The mixed wave over the paged LATENT pool.
@@ -996,17 +1053,17 @@ def ragged_paged_mla(q, pool, lengths, q_lens, block_tables, *,
     has the short height: ``_MLA_SHORT_QUERIES`` queries, all their
     heads): a short tile's rows past it come back zero.
     Returns o [B, Q, H, value_width] in q's dtype (f32 accumulators); a
-    slot with lengths 0 returns zeros."""
+    slot with lengths 0 returns zeros.
+
+    This is the DENSE entry, for a wave whose rows lie as ``[B, Q]``
+    (every decode and verify wave, and a chunk wave too small to pack);
+    a packed chunk wave's rows go to :func:`ragged_paged_mla_rows` as
+    they lie.  Both run under this name in a trace."""
     B, Q, H, W = q.shape
     bs = pool.shape[2]
     group = min(_PAGE_GROUP, block_tables.shape[1])
     tq, short = mla_tiling(Q, H)
-    if interpret is None:
-        interpret = _use_interpret()
-    if W % _LANES and not interpret:
-        raise ValueError(
-            f"ragged_paged_mla copies pages by hand: the row width {W} "
-            f"must be a multiple of {_LANES}")
+    interpret = _mla_interpret(W, interpret)
     rows = tq * H
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
@@ -1034,6 +1091,155 @@ def ragged_paged_mla(q, pool, lengths, q_lens, block_tables, *,
     )(lengths.astype(jnp.int32), q_lens.astype(jnp.int32),
       block_tables.astype(jnp.int32), q.reshape(B, Q * H, W), pool)
     return o.reshape(B, Q, H, value_width)
+
+
+def mla_rows_tiling(R, H, dtype):
+    """(packed queries a row tile, short window) of the packed latent
+    kernel's program for ``R`` packed rows of ``H`` heads in ``dtype``:
+    the dense entry's tile, and a short window of ``_MLA_SHORT_QUERIES``
+    queries where a tile is a whole number of them, more than one, and a
+    window's ``H`` x queries rows are whole sublane tiles (it starts
+    where a slot's rows lie: a traced, aligned start)."""
+    tq = _mla_q_tile(R, H)
+    short = _MLA_SHORT_QUERIES
+    sub = 32 // jnp.dtype(dtype).itemsize
+    if tq <= short or tq % short or (short * H) % sub:
+        short = 0
+    return tq, short
+
+
+def _mla_rows_kernel(lens_ref, qlens_ref, bt_ref, layer_ref, start_ref,
+                     first_ref, last_ref, q_ref, pool_ref, o_ref, kv_buf,
+                     sem, m_ref, l_ref, acc_ref, *, scale, bs, group, tq,
+                     heads, dv, short):
+    """One row tile of the PACKED wave: ``tq`` packed queries x ``heads``
+    rows, whichever slots they belong to.  The slots ``first_ref[t] ..
+    last_ref[t]`` are visited one after another (the layout is
+    slot-major, so they are a range); a visit runs the slot's page
+    pipeline and scores the tile, or the one short window that holds the
+    slot's rows (:func:`row_tile_visits`), with every row that is not the
+    slot's masked: the online softmax leaves such a row as it was.  A
+    row nobody owns comes back zero."""
+    t = pl.program_id(0)
+    span = group * bs
+    layer = layer_ref[0]
+    _reset(m_ref, l_ref, acc_ref)
+
+    def visit(b, carry):
+        filled, qlen, start = lens_ref[b], qlens_ref[b], start_ref[b]
+        lo, hi, live, full = row_tile_visits(start, qlen, t, tq, short)
+        # one past the last position the slot's last row in this tile
+        # sees (causality: what lies above it is neither copied nor
+        # scored)
+        end = filled - qlen + (t * tq + hi - start)
+        live &= end > 0
+        n_groups = jnp.where(live, (end + span - 1) // span, 0)
+        last = jnp.maximum(end - 1, 0) // bs
+        # the window's first query, counted from the tile's first
+        w = lo // short * short if short else 0
+
+        def rows(hq):
+            if hq == tq:
+                return (slice(None),)
+            return (pl.ds(pl.multiple_of(w * heads, hq * heads),
+                          hq * heads),)
+
+        def score(hq, gi, buf):
+            at = rows(hq)
+            kv = kv_buf[buf]                                  # [span, W]
+            s = jax.lax.dot_general(
+                q_ref[at], kv, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale   # [R, span]
+            # row r of what is scored is packed query ``first + r //
+            # heads``: query ``qi`` of the slot's q-block, or another
+            # slot's row, which sees nothing
+            first = t * tq + (0 if hq == tq else w)
+            qi = first - start + jax.lax.broadcasted_iota(
+                jnp.int32, (s.shape[0], 1), 0) // heads
+            qi = jnp.where((qi >= 0) & (qi < qlen), qi, -(1 << 30))
+            _softmax_step(_mask_scores(s, qi, gi, filled, qlen),
+                          kv[:, :dv], m_ref, l_ref, acc_ref, at=at)
+
+        _page_loop(n_groups,
+                   _mla_copies(pool_ref, bt_ref, kv_buf, sem, layer, b, last,
+                               group, bs),
+                   lambda gi, buf: _at_heights((tq, short, full), score,
+                                               gi, buf))
+        return carry
+
+    jax.lax.fori_loop(first_ref[t], last_ref[t] + 1, visit, 0)
+    l = l_ref[:, 0:1]
+    o_ref[...] = (acc_ref[...] / jnp.where(l == 0.0, 1.0, l)).astype(
+        o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("value_width", "scale", "tq",
+                                             "short", "interpret"))
+def _mla_rows_call(lengths, q_lens, block_tables, layer, start, qr, pool, *,
+                   value_width, scale, tq, short, interpret):
+    """``_mla_rows_kernel`` over the packed query rows ``qr`` [R, H, W].
+    Jitted, with the layer a traced scalar, as :func:`_paged_rows_call`:
+    a model's layers share one trace and one lowering a program."""
+    R, H, W = qr.shape
+    bs = pool.shape[2]
+    group = min(_PAGE_GROUP, block_tables.shape[1])
+    rows = tq * H
+    # the slots whose rows cross each tile: slot-major, so a range
+    live = row_tile_visits(start[:, None], q_lens[:, None],
+                           jnp.arange(R // tq)[None, :], tq, short)[2]
+    slot = jnp.arange(len(start))[:, None]
+    first = jnp.min(jnp.where(live, slot, len(start)), axis=0)
+    last = jnp.max(jnp.where(live, slot, -1), axis=0)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=7,
+        grid=(R // tq,),
+        in_specs=[pl.BlockSpec((rows, W), lambda t, *_: (t, 0)),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((rows, value_width), lambda t, *_: (t, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((2, group * bs, W), pool.dtype),    # page buffers
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.VMEM((rows, _LANES), jnp.float32),       # running max
+            pltpu.VMEM((rows, _LANES), jnp.float32),       # running denom
+            pltpu.VMEM((rows, value_width), jnp.float32),  # output acc
+        ],
+    )
+    o = pl.pallas_call(
+        functools.partial(_mla_rows_kernel, scale=scale, bs=bs, group=group,
+                          tq=tq, heads=H, dv=value_width, short=short),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((R * H, value_width), qr.dtype),
+        name="ragged_paged_mla",
+        interpret=interpret,
+    )(lengths, q_lens, block_tables, layer, start, first.astype(jnp.int32),
+      last.astype(jnp.int32), qr.reshape(R * H, W), pool)
+    return o.reshape(R, H, value_width)
+
+
+def ragged_paged_mla_rows(q, pool, lengths, q_lens, start, block_tables, *,
+                          value_width, scale, layer=0, interpret=None):
+    """:func:`ragged_paged_mla` over a PACKED wave's query rows as they
+    lie (``gpt_decode._Rows``: slot-major, slot ``b``'s ``q_lens[b]``
+    live rows at ``start[b]``, dead rows at the tail), under the same
+    name in a trace.
+
+    q: [R, H, W] (``R`` packed rows, a whole number of row tiles:
+    :func:`mla_rows_tiling`); pool, lengths, q_lens, block_tables, the
+    scores, the value and the arithmetic as there; ``layer`` may be
+    traced.  Its q-tiles are tiles of packed rows: the q and o tiles
+    that move are the packed rows', ``R / tq`` a call whatever the slots,
+    and a tile visits the slots whose rows cross it
+    (:func:`row_tile_visits`: each at the full tile, or at the one short
+    window that holds its rows).  Returns o [R, H, value_width]; a row
+    no slot owns, and a slot with lengths 0, return zeros."""
+    R, H, W = q.shape
+    interpret = _mla_interpret(W, interpret)
+    tq, short = mla_rows_tiling(R, H, q.dtype)
+    i32 = lambda x: jnp.asarray(x, jnp.int32)              # noqa: E731
+    return _mla_rows_call(
+        i32(lengths), i32(q_lens), i32(block_tables), i32(layer).reshape(1),
+        i32(start), q, pool, value_width=value_width, scale=float(scale),
+        tq=tq, short=short, interpret=interpret)
 
 
 def ragged_paged_mla_reference(q, pool, lengths, q_lens, block_tables, *,

@@ -1414,8 +1414,11 @@ def _latent_attention(params, us, blk, H, h, pool, i, wblk, woff, posns,
     are written first and read back from the pool (one path for chunk
     and decode rows).  Of a packed wave (``rows``; ``h``, ``wblk``,
     ``woff`` and ``posns`` [1, R, ..]) the rows are written as they
-    lie, and the query alone is unpacked for the scoring and its result
-    packed back.  Returns (h + attention, pool)."""
+    lie and the kernel scores them as they lie
+    (``ragged_paged_mla_rows``, handed the layout's slot starts: no
+    ``[B, Q]`` block of the query or of the result exists); the masked
+    path alone unpacks the query for its scores and packs the result
+    back.  Returns (h + attention, pool)."""
     la = blk.latent
     B, Q, _ = h.shape
     dn, dr, dv, dc = (la.qk_nope_head_dim, la.qk_rope_head_dim,
@@ -1447,22 +1450,27 @@ def _latent_attention(params, us, blk, H, h, pool, i, wblk, woff, posns,
         pool = pool.at[i, wblk, woff].set(row.astype(pool.dtype))
     scale = (dn + dr) ** -0.5
     with jax.named_scope("attention"):
-        if rows is not None:
-            qf = rows.unpack(qf)
-        if attn == "ragged":
+        if attn == "ragged" and rows is not None:
+            from ..kernels.ragged_attention import ragged_paged_mla_rows
+            o_lat = ragged_paged_mla_rows(
+                qf[0], pool, lens, q_len, rows.start, block_tables,
+                value_width=dc, scale=scale, layer=i)[None]
+        elif attn == "ragged":
             from ..kernels.ragged_attention import ragged_paged_mla
             o_lat = ragged_paged_mla(qf, pool, lens, q_len,
                                      block_tables, value_width=dc,
                                      scale=scale, layer=i)
         else:
+            if rows is not None:
+                qf = rows.unpack(qf)
             T, bs = block_tables.shape[1], pool.shape[2]
             kg = pool[i][block_tables].reshape(-1, T * bs, dc + dr + pad)
             s = jnp.einsum("bqhc,bsc->bqhs", qf, kg) * scale
             p = jax.nn.softmax(
                 jnp.where(live[:, :, None, :], s, NEG_INF), axis=-1)
             o_lat = jnp.einsum("bqhs,bsc->bqhc", p, kg[..., :dc])
-        if rows is not None:
-            o_lat = rows.pack(o_lat)
+            if rows is not None:
+                o_lat = rows.pack(o_lat)
     with jax.named_scope("mla_absorb"):
         o = jnp.einsum("bqhc,chv->bqhv", o_lat.astype(h.dtype),
                        w_kvb[:, :, dn:]).reshape(B, Q, H * dv)

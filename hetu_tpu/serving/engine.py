@@ -49,7 +49,7 @@ from ..models.gpt_decode import (
     serve_prefill_fn, spec_propose_fn, wave_rows,
 )
 from ..kernels.ragged_attention import (
-    mla_tiling, rows_tiling, tile_heights)
+    mla_rows_tiling, mla_tiling, row_tile_visits, rows_tiling, tile_heights)
 from ..models.moe_decode import takes_kernel
 from ..models.retention_decode import takes_kernel as retention_takes_kernel
 from .kv_manager import (KVCacheManager, PagedKVManager,
@@ -531,13 +531,15 @@ class ServingEngine:
                 "load": [int(x) for x in load],
                 "drop": [int(x) for x in drop]}
 
-    def _wave_record(self, wave):
+    def _wave_record(self, wave, rows_computed):
         """Every wave's attention counters (``serve.attn.*``; 0 on an
         engine none of whose layers attends) and, on an engine with
         state-space or retention layers, its ``serve.ssm.*`` /
         ``serve.ret.*`` ones and their ``record_step`` payloads
         ({"ssm": .., "ret": ..}, those it has): the wave descriptor's
-        own arithmetic.  Slot b's ``q_len`` rows at positions ``pos .. pos
+        own arithmetic (and ``rows_computed``, the rows the wave's
+        program ran over: whether it was packed).  Slot b's ``q_len``
+        rows at positions ``pos .. pos
         + q_len - 1`` see ``pos + j + 1`` positions each, and the slot
         holds ``pos + q_len`` positions after the wave's writes; a live
         slot's state moves once a state-space layer.  An engine with
@@ -566,7 +568,8 @@ class ServingEngine:
             self._window_recycled_seen = recycled
         self.metrics.record_attention(
             ctx, pairs, window,
-            self._attn_tiles(ql, int(wave["q"])) if attends else None)
+            self._attn_tiles(ql, int(wave["q"]), rows_computed)
+            if attends else None)
         out = {}
         wide = np.where(ql > 1, ql, 0)
         for kind, layers, spec in (
@@ -590,16 +593,26 @@ class ServingEngine:
                 layers, int((ql > 1).sum()) if by_kernel else 0)
         return out
 
-    def _attn_tiles(self, q_len, Q):
-        """(live (slot, q-tile) steps, those scored at the short height)
-        of one call of the hand-paged attention kernel in a wave of
-        q-blocks ``Q`` wide: the kernel's own rule asked of the wave's
-        ``q_len`` and the tile the program for ``Q`` has.  None where
-        the engine's waves run no such kernel (the masked path, the
-        contiguous cache, the int8 pool)."""
+    def _attn_tiles(self, q_len, Q, rows):
+        """(live (slot, q-tile) steps, those scored at the short height,
+        q-tiles moved) of one call of the hand-paged attention kernel in
+        a wave of q-blocks ``Q`` wide computed over ``rows`` rows: the
+        kernel's own rule asked of the wave's ``q_len`` and the tile the
+        program for ``Q`` has.  A packed wave of the latent kernel
+        (``rows`` under slots x ``Q``) moves the packed rows' tiles and
+        counts a tile's VISITS to the slots whose rows cross it.  None
+        where the engine's waves run no such kernel (the masked path,
+        the contiguous cache, the int8 pool)."""
         if not (self.fast_path and self.paged) or self.kv_quant:
             return None
         H, Dh = self.cfg_tuple[2:4]
+        if self.block_spec.latent and rows < len(q_len) * Q:
+            tq, short = mla_rows_tiling(rows, H, self._cdtype)
+            start = np.cumsum(q_len) - q_len
+            _, _, live, full = row_tile_visits(
+                start[:, None], q_len[:, None],
+                np.arange(rows // tq)[None, :], tq, short)
+            return int(live.sum()), int((live & ~full).sum()), rows // tq
         if self.block_spec.latent:
             tq, short = mla_tiling(Q, H)
         else:
@@ -607,7 +620,7 @@ class ServingEngine:
                 Q, H, Dh, H // (self.block_spec.kv_heads or H), self._cdtype)
         live, full = tile_heights(q_len[:, None],
                                   np.arange(-(-Q // tq))[None, :], tq, short)
-        return int(live.sum()), int((live & ~full).sum())
+        return int(live.sum()), int((live & ~full).sum()), live.size
 
     def _routed_record(self, wave, routed_out, rows_computed):
         """A routed wave's counters (``serve.moe.*``) and its
@@ -1196,7 +1209,7 @@ class ServingEngine:
                                               w.rows_computed)
             elif w.moe_stats is not None:
                 moe_rec = self._moe_record(w.moe_stats)
-            scan_rec = self._wave_record(wave)
+            scan_rec = self._wave_record(wave, w.rows_computed)
             self.metrics.record_wave(
                 w.rows_live, w.rows_computed, len(w.waiting),
                 ahead=w.ahead,

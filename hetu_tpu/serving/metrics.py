@@ -210,6 +210,7 @@ class ServingMetrics(MetricsCore):
         self.attn_score_pairs = 0
         self.attn_tiles_live = 0
         self.attn_tiles_short = 0
+        self.attn_q_tiles_moved = 0
         # an engine with window layers (``record_attention``)
         self.attn_window_ctx_tokens = 0
         self.attn_window_score_pairs = 0
@@ -280,20 +281,27 @@ class ServingMetrics(MetricsCore):
         window_blocks_recycled`` is the manager's own).  An engine whose
         waves run a hand-paged attention kernel adds ``tiles`` = (the
         wave's live (slot, q-tile) steps of one call of the kernel,
-        those of them it scored at the short height:
-        ``ragged_attention.tile_heights`` of the wave's ``q_len``):
-        ``attn_tiles_live``, ``attn_tiles_short`` and the counters
-        ``serve.attn.tiles_live``, ``serve.attn.tiles_short``."""
+        those of them it scored at the short height, the q-tiles (and o
+        tiles) the call moved, live or dead:
+        ``ragged_attention.tile_heights`` of the wave's ``q_len``; of a
+        packed wave of the latent kernel a row tile's visits to the
+        slots whose rows cross it, ``row_tile_visits``, and the packed
+        rows' tiles): ``attn_tiles_live``, ``attn_tiles_short``,
+        ``attn_q_tiles_moved`` and the counters
+        ``serve.attn.tiles_live``, ``serve.attn.tiles_short``,
+        ``serve.attn.q_tiles_moved``."""
         self.attn_ctx_tokens += int(ctx_tokens)
         self.attn_score_pairs += int(score_pairs)
         telemetry.inc("serve.attn.ctx_tokens", int(ctx_tokens))
         telemetry.inc("serve.attn.score_pairs", int(score_pairs))
         if tiles is not None:
-            live, short = (int(v) for v in tiles)
+            live, short, moved = (int(v) for v in tiles)
             self.attn_tiles_live += live
             self.attn_tiles_short += short
+            self.attn_q_tiles_moved += moved
             telemetry.inc("serve.attn.tiles_live", live)
             telemetry.inc("serve.attn.tiles_short", short)
+            telemetry.inc("serve.attn.q_tiles_moved", moved)
         if window is not None:
             ctx, pairs, recycled = (int(v) for v in window)
             self.attn_window_ctx_tokens += ctx
@@ -647,6 +655,7 @@ class ServingMetrics(MetricsCore):
                     "moe_assignments", "moe_experts_touched",
                     "moe_kernel_waves", "attn_ctx_tokens", "attn_score_pairs",
                     "attn_tiles_live", "attn_tiles_short",
+                    "attn_q_tiles_moved",
                     "attn_window_ctx_tokens", "attn_window_score_pairs",
                     "window_blocks_recycled",
                     "ssm_slot_steps", "ssm_rows", "ssm_chunk_pairs",
@@ -731,6 +740,7 @@ class ServingMetrics(MetricsCore):
             "attn_score_pairs": count("attn_score_pairs"),
             "attn_tiles_live": count("attn_tiles_live"),
             "attn_tiles_short": count("attn_tiles_short"),
+            "attn_q_tiles_moved": count("attn_q_tiles_moved"),
             "attn_window_ctx_tokens": count("attn_window_ctx_tokens"),
             "attn_window_score_pairs": count("attn_window_score_pairs"),
             "window_blocks_recycled": count("window_blocks_recycled"),

@@ -6,6 +6,8 @@ the plain reference's full forward (``models/reference_latent_moe.py``),
 never of tokens alone.
 """
 
+import functools
+
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -211,6 +213,113 @@ def test_ragged_paged_mla_matches_masked_reference(monkeypatch, Q, lens,
             assert not got[b, at + h:at + tq].any()
             live, full = ra.tile_heights(int(n), t, tq, short)
             assert not short or (bool(live), bool(full)) == (h > 0, h == tq)
+
+
+# The PACKED entry (ISSUE 46): row tiles of 16 packed queries x 4 heads,
+# a short window of 8.  Q is the q-block's padded width (what the dense
+# entry and the reference are asked), R the packed rows.
+PACKED_WAVES = {
+    # a chunk that starts at packed row 3 and crosses the tile edges at
+    # 16 and 32, one-row slots before and after it
+    "chunk-across-two-edges": (
+        [9, 30, 7, 55, 12, 21], [1, 1, 1, 40, 1, 1], jnp.float32),
+    # tile 0 is sixteen one-row slots; a chunk fills the next tile and a half
+    "tile-of-one-row-slots": (
+        list(range(5, 21)) + [40, 9], [1] * 16 + [24, 1], jnp.bfloat16),
+    # dead slots between live ones (one of them with pages filled), and
+    # three tiles nobody owns
+    "dead-slots-and-a-dead-tail": (
+        [8, 0, 11, 30, 0, 5, 0], [1, 0, 0, 9, 0, 1, 0], jnp.float32),
+    # chunk pieces of 2 to 7 rows: 6 rows inside window 0, 5 that
+    # straddle the windows' edge at 8, 7 that straddle the TILE's edge at
+    # 16 (two visits, each inside one window), 2 inside a window
+    "short-pieces-that-straddle": (
+        [6, 25, 17, 2, 9], [6, 5, 7, 2, 1], jnp.float32),
+}
+# one shape for all of them (18 slots, dead past a case's own; q-blocks
+# 64 wide, what the dense entry and the reference are asked; 64 packed
+# rows), so that the interpreted kernels are built once a dtype
+PACKED_SHAPE = dict(B=18, Q=64, R=64)
+
+
+def brute_force_visits(q_lens, tq, short):
+    """{(tile, slot): short?} row by row: a tile visits every slot with
+    a packed row in it, at the short window iff those rows share one."""
+    windows = {}
+    for row, slot in enumerate(np.repeat(np.arange(len(q_lens)), q_lens)):
+        windows.setdefault((row // tq, int(slot)), set()).add(row // short)
+    return {k: len(w) == 1 for k, w in windows.items()}
+
+
+@functools.partial(jax.jit, static_argnames=("value_width", "scale", "layer"))
+def _dense_mla(q, pool, lens, qlens, tables, **kw):
+    return ra.ragged_paged_mla(q, pool, lens, qlens, tables, interpret=True,
+                               **kw)
+
+
+@pytest.mark.parametrize("name", list(PACKED_WAVES))
+def test_packed_mla_rows_match_the_reference_and_the_dense_entry(
+        monkeypatch, name):
+    lens, qlens, dtype = PACKED_WAVES[name]
+    B, Q, R = (PACKED_SHAPE[k] for k in "BQR")
+    lens, qlens = (v + [0] * (B - len(v)) for v in (lens, qlens))
+    monkeypatch.setattr(ra, "_MLA_TILE_ROWS", 64)
+    rng = np.random.default_rng(0)
+    H, W, dv, bs, T, N = 4, 48, 32, 4, 16, 64
+    tq, short = ra.mla_rows_tiling(R, H, dtype)
+    assert (tq, short) == (16, 8)
+    q = jnp.asarray(rng.standard_normal((B, Q, H, W)), dtype)
+    pool = jnp.asarray(rng.standard_normal((3, N, bs, W)), dtype)
+    tables = jnp.asarray(rng.integers(1, N, (B, T)), jnp.int32)
+    lens, qlens = jnp.asarray(lens, jnp.int32), jnp.asarray(qlens, jnp.int32)
+    kw = dict(value_width=dv, scale=0.2, layer=1)
+    rows = gd._Rows.of(qlens, Q, R)
+    got = np.asarray(ra.ragged_paged_mla_rows(
+        rows.pack(q)[0], pool, lens, qlens, rows.start, tables,
+        interpret=True, **kw), np.float32)
+    assert got.shape == (R, H, dv)
+    live = np.asarray(rows.live)
+    assert live.sum() == int(qlens.sum()) and got[live].any(-1).all()
+    # dead packed rows (the last live tile's tail, tiles nobody owns)
+    # come back zero
+    assert not got[~live].any()
+    tol = 2e-5 if dtype == jnp.float32 else 3e-2
+    want = rows.pack(ra.ragged_paged_mla_reference(
+        q, pool, lens, qlens, tables, **kw))[0]
+    np.testing.assert_allclose(got[live], np.asarray(want)[live],
+                               atol=tol, rtol=tol)
+    # ... and equal ``rows.pack`` of the dense entry's output on the
+    # same wave
+    dense = rows.pack(_dense_mla(q, pool, lens, qlens, tables, **kw))[0]
+    np.testing.assert_allclose(
+        got[live], np.asarray(dense, np.float32)[live], atol=tol, rtol=tol)
+    # the visit rule (what the wrapper's slot ranges and the engine's
+    # counters ask) against a count row by row
+    ql = np.asarray(qlens)
+    lo, hi, visit, full = ra.row_tile_visits(
+        (np.cumsum(ql) - ql)[:, None], ql[:, None],
+        np.arange(R // tq)[None, :], tq, short)
+    want_visits = brute_force_visits(ql, tq, short)
+    assert {(t, b) for b, t in zip(*np.nonzero(visit))} == set(want_visits)
+    assert all(bool(full[b, t]) != is_short
+               for (t, b), is_short in want_visits.items())
+    assert (hi - lo)[visit].sum() == ql.sum()
+    if name == "short-pieces-that-straddle":
+        # slot 1's five rows straddle the windows' edge: the full tile;
+        # slot 2's seven straddle the tile's edge: two short visits
+        assert want_visits == {(0, 0): True, (0, 1): False, (0, 2): True,
+                               (1, 2): True, (1, 3): True, (1, 4): True}
+
+
+def test_mla_rows_tiling_takes_a_short_window_where_it_can_start_aligned():
+    """The packed program of the long-answer cell: 64 packed queries x 20
+    heads a row tile, a window of 8 queries = 160 rows, whole sublane
+    tiles of bfloat16; a window whose rows are no whole sublane tiles,
+    or a tile no taller than it, has the one height."""
+    assert ra.mla_rows_tiling(1024, 20, jnp.bfloat16) == (64, 8)
+    assert ra.mla_rows_tiling(1024, 3, jnp.bfloat16) == (256, 0)
+    assert ra.mla_rows_tiling(1024, 3, jnp.float32) == (256, 8)
+    assert ra.mla_rows_tiling(256, 160, jnp.bfloat16) == (8, 0)
 
 
 def test_kernel_refuses_an_unaligned_row_on_the_chip():

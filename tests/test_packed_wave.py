@@ -322,12 +322,13 @@ def rows_of_waves(eng):
 
 
 def watch_waves(eng):
-    """[(q_len, Q)] of the waves ``eng`` lands from now on."""
+    """[(q_len, Q, rows computed)] of the waves ``eng`` lands from now
+    on."""
     waves, record = [], eng._wave_record
 
-    def recording(wave):
-        waves.append((wave["q_len"].copy(), int(wave["q"])))
-        return record(wave)
+    def recording(wave, rows_computed):
+        waves.append((wave["q_len"].copy(), int(wave["q"]), rows_computed))
+        return record(wave, rows_computed)
     eng._wave_record = recording
     return waves
 
@@ -344,7 +345,7 @@ def tiles_of(waves, tile, short_queries):
     ``tile(Q)`` queries a q-tile, and a live tile whose live rows fit
     ``short_queries`` scored short wherever the tile is taller."""
     live = short = 0
-    for q_lens, Q in waves:
+    for q_lens, Q, _ in waves:
         tq = tile(Q)
         for n in q_lens:
             for t in range(-(-Q // tq)):
@@ -352,6 +353,28 @@ def tiles_of(waves, tile, short_queries):
                 live += rows > 0
                 short += 0 < rows <= short_queries < tq
     return live, short
+
+
+def visits_of(waves, tq, short_queries):
+    """(visits, those at the short window, q-tiles moved) of the latent
+    kernel over the waves, row by row from the packed kernel's contract:
+    a PACKED wave (computed over fewer rows than slots x Q) is cut into
+    row tiles of ``tq`` packed queries, a tile visits every slot that
+    has a row in it, and a visit all of whose rows lie in one aligned
+    window of ``short_queries`` is short; any other wave is the dense
+    entry's, one tile a slot (``tiles_of``)."""
+    from test_latent_moe import brute_force_visits
+    live = short = moved = 0
+    for q_lens, Q, rows in waves:
+        if rows == len(q_lens) * Q:
+            a, b = tiles_of([(q_lens, Q, rows)], lambda Q: Q, short_queries)
+            live, short, moved = live + a, short + b, moved + len(q_lens)
+            continue
+        visits = brute_force_visits(q_lens, tq, short_queries)
+        live += len(visits)
+        short += sum(visits.values())
+        moved += rows // tq
+    return live, short, moved
 
 
 def test_attention_tile_counters_follow_the_waves_q_lens(gpt, monkeypatch):
@@ -379,7 +402,7 @@ def test_attention_tile_counters_follow_the_waves_q_lens(gpt, monkeypatch):
     assert (whole["attn_tiles_live"], whole["attn_tiles_short"]) == want
     # decoding slots rode beside chunks, chunks were scored whole, and
     # the decode waves added live tiles alone
-    chunk = [(ql, Q) for ql, Q in waves if Q == 64]
+    chunk = [(ql, Q) for ql, Q, _ in waves if Q == 64]
     assert any((ql == 1).any() and (ql > 8).any() for ql, _ in chunk)
     assert 0 < want[1] == sum(int((0 < ql).sum() - (ql > 8).sum())
                               for ql, _ in chunk) < want[0]
@@ -481,7 +504,14 @@ def test_hybrid_engine_matches_reference_with_packing_engaged(hybrid, fast):
 
 
 @pytest.mark.parametrize("fast", [False, True], ids=["masked", "kernel"])
-def test_latent_engine_matches_reference_with_packing_engaged(latent, fast):
+def test_latent_engine_matches_reference_with_packing_engaged(
+        latent, fast, monkeypatch):
+    from hetu_tpu.kernels import ragged_attention as ra
+    # row tiles of 16 packed queries x 4 heads: a chunk of 64 crosses
+    # tile edges wherever it starts
+    monkeypatch.setattr(ra, "_MLA_TILE_ROWS", 64)
+    assert ra.mla_rows_tiling(256, 4, jnp.float32) == (16, 8)
+    telemetry.reset()
     params, cfg = latent
     eng = ServingEngine(params, cfg, slots=8, max_seq_len=256, paged=True,
                         kv_block=4, prefill_chunk=64, fast_path=fast,
@@ -500,11 +530,24 @@ def test_latent_engine_matches_reference_with_packing_engaged(latent, fast):
     assert snap["wave_rows_live"] == rows
     assert snap["moe_assignments"] == rows * 2 * 2
     assert snap["chunks_deferred"] > 0
-    # the latent kernel's tiles: all of a q-block of up to 64 queries in
-    # one, its short height 8 queries whatever the dtype
-    tiles = (snap["attn_tiles_live"], snap["attn_tiles_short"])
-    assert tiles == (tiles_of(waves, lambda Q: Q, 8) if fast else (0, 0))
-    assert not fast or 0 < tiles[1] < tiles[0]
+    # the latent kernel's visits: a chunk wave is packed (256 rows where
+    # the block is 512) and its kernel runs over 16 row tiles, a decode
+    # wave is the dense entry's (8 tiles of one query); the short window
+    # is 8 queries whatever the dtype
+    tiles = (snap["attn_tiles_live"], snap["attn_tiles_short"],
+             snap["attn_q_tiles_moved"])
+    assert tiles == (visits_of(waves, 16, 8) if fast else (0, 0, 0))
+    if fast:
+        chunk = [w for w in waves if w[2] < 8 * w[1]]
+        assert chunk and all(w[2] == 256 for w in chunk)
+        assert 0 < tiles[1] < tiles[0]
+        # a chunk that crosses a tile's edge is visited from both sides
+        assert tiles[0] > sum(int((w[0] > 0).sum()) for w in waves)
+        assert tiles[2] == 16 * len(chunk) + 8 * (len(waves) - len(chunk))
+        counters = telemetry.snapshot()["counters"]
+        assert tiles == tuple(counters[f"serve.attn.{name}"] for name in (
+            "tiles_live", "tiles_short", "q_tiles_moved"))
+    telemetry.reset()
 
 
 # ------------------------------------------------------------------ #
