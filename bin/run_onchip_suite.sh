@@ -251,7 +251,7 @@ cfg = GPTConfig(vocab_size=61, hidden_size=hd, num_hidden_layers=1,
                 batch_size=1, seq_len=32, dropout_rate=0.0)
 router = ServingRouter(
     lambda i: ServingEngine(p, cfg, slots=2, fast_path=False,
-                            paged=True, kv_block=8, prefix_share=True),
+                            kv_block=8, prefix_share=True),
     replicas=2, roles="prefill,decode")
 sys_p = list(range(1, 18))          # shared long prompt (> one block)
 reqs = [Request(prompt=sys_p + [20 + i], max_new_tokens=4,
@@ -513,7 +513,7 @@ def mk_router(replicas, slo_ms=None):
     def factory(i):
         slo = [SLO("ttft", "latency", slo_ms)] if slo_ms else None
         return ServingEngine(p, cfg, slots=4, queue_limit=8,
-                             max_seq_len=32, paged=True, kv_block=4,
+                             max_seq_len=32, kv_block=4,
                              prefix_share=True, slo=slo)
     return ServingRouter(factory, replicas=replicas, directory=True,
                          shed_on_slo=False, restart_backoff=0.01)
@@ -660,7 +660,7 @@ def mk_store():
 def mk_router(store):
     def factory(i):
         return ServingEngine(p, cfg, slots=2, queue_limit=64,
-                             max_seq_len=32, paged=True, kv_block=8,
+                             max_seq_len=32, kv_block=8,
                              pool_blocks=8, prefix_share=True)
     return ServingRouter(factory, replicas=1, kv_tiers=store)
 
@@ -858,8 +858,8 @@ fi
 
 # 00l. MoE serving gate (ISSUE 20): one CPU process decodes the MoE
 #      GPT (top-2 of 4 experts, alternating blocks) through the engine
-#      across THREE cache configurations — contiguous fast path,
-#      block-table paged, paged + int8 KV — and requires greedy
+#      across TWO cache configurations — the block-table pool and
+#      the pool with int8 KV — and requires greedy
 #      TOKEN-IDENTICAL outputs vs offline generate_fast in every one,
 #      plus the routing-attribution invariant on the engine counters
 #      (routed + dropped == tokens x top_k x MoE layers).  A second,
@@ -892,10 +892,8 @@ n_moe = moe_spec_of(cfg).moe_layers(cfg.num_hidden_layers)
 mk = lambda: [Request(request_id=str(i), prompt=pr, max_new_tokens=NEW,
                       temperature=0.0, seed=0)
               for i, pr in enumerate(prompts)]
-configs = [("contiguous", dict(fast_path=True)),
-           ("paged", dict(fast_path=True, paged=16)),
-           ("paged_int8", dict(fast_path=True, paged=16,
-                               kv_quant="int8"))]
+configs = [("paged", dict(fast_path=True)),
+           ("paged_int8", dict(fast_path=True, kv_quant="int8"))]
 for label, kw in configs:
     eng = ServingEngine(p, cfg, slots=4, name="moe", **kw)
     out = eng.run(mk())
@@ -912,8 +910,7 @@ scfg = MoEDecodeConfig(
     num_attention_heads=2, ffn_mult=2, seq_len=48, dropout_rate=0.0,
     max_position_embeddings=48, num_experts=4, top_k=2,
     capacity_factor=0.25, moe_every=2)
-seng = ServingEngine(p, scfg, slots=4, name="moe", fast_path=True,
-                     paged=16)
+seng = ServingEngine(p, scfg, slots=4, name="moe", fast_path=True)
 seng.run(mk())
 assert int(seng.expert_drops.sum()) > 0, \
     "cf=0.25 dropped nothing — the overflow path went untested"

@@ -175,11 +175,10 @@ def lowered_mixed_step(eng):
     lowered from the engine's own state (nothing is executed)."""
     from hetu_tpu.serving.kv_manager import assemble_mixed_wave
     wave = assemble_mixed_wave(eng.kv.n_slots, {})
-    args = [eng.params, eng.cfg_tuple, eng.kv.cache_k, eng.kv.cache_v]
-    if eng.paged:
-        args.append(eng.kv.tables.copy())
-    args += [wave["pos"], wave["tokens"], wave["q_len"], wave["first_row"],
-             wave["self_fresh"], eng._temp, eng._topk, eng._keys]
+    args = [eng.params, eng.cfg_tuple, eng.kv.cache_k, eng.kv.cache_v,
+            eng.kv.tables.copy(), wave["pos"], wave["tokens"],
+            wave["q_len"], wave["first_row"], wave["self_fresh"],
+            eng._temp, eng._topk, eng._keys]
     return eng._mixed.func.lower(*args, **eng._mixed.keywords).as_text()
 
 

@@ -250,9 +250,9 @@ _reg("HETU_SERVE_LOG", "path", None,
      "JSONL sink for serving engine events (same record shape as "
      "HETU_FAILURE_LOG).", "serving")
 _reg("HETU_KV_BLOCK", "str", "auto",
-     "Paged KV cache: an integer enables the block-table paged "
-     "allocator at that block size (tokens per block), 0 pins the "
-     "slot-contiguous layout, auto = paged with block 16.", "serving")
+     "Paged KV cache: the block-table pool's block size (tokens per "
+     "block), a positive integer; auto = 16.  0 or less is refused "
+     "(the slot-contiguous layout it selected is gone).", "serving")
 _reg("HETU_KV_PREFIX_SHARE", "bool", True,
      "Paged KV: refcounted copy-on-write sharing of common prompt "
      "prefixes — N requests with the same system prompt store its KV "

@@ -1,12 +1,9 @@
 """Pallas TPU kernels for the hot ops: fused flash attention (training
-and prefill), the contiguous-cache decode/verify kernels, the ragged
-kernel that scores the serving engine's wave on a TPU, and the grouped
-matmul of a chunk wave's routed experts."""
+and prefill), the ragged kernel that scores the serving engine's wave
+on a TPU, and the grouped matmul of a chunk wave's routed experts."""
 
 from . import flash_attention  # noqa: F401
-from . import decode_attention  # noqa: F401
 from . import ragged_attention  # noqa: F401
 from . import grouped_matmul  # noqa: F401
 
-__all__ = ["flash_attention", "decode_attention", "ragged_attention",
-           "grouped_matmul"]
+__all__ = ["flash_attention", "ragged_attention", "grouped_matmul"]

@@ -42,7 +42,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .decode_attention import _LANES, _use_interpret
+from ._shared import _LANES, _use_interpret
 
 # rows a tile (swept 64 / 128 / 256 on the chip: PERF.md section 6, PR 41)
 TILE_M = 128

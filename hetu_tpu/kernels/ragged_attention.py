@@ -7,8 +7,7 @@ verify kernel for speculative waves — with a scheduling barrier between
 the phases.  This module is all three:
 every slot in a wave carries its OWN ``q_len`` (1 for decode, k+1 for
 spec-verify, a chunk of prompt for prefill/chunked-prefill), and one
-kernel call scores the whole mixed wave.  Mechanically it is the
-verify-kernel computation with nothing verify-specific left in it:
+kernel call scores the whole mixed wave.  Mechanically:
 
   - grid (slot, q-tile, kv-block), kv innermost, so the online-softmax
     accumulators (one f32 (m, l, acc) row per (head, query)) persist in
@@ -28,13 +27,13 @@ verify-kernel computation with nothing verify-specific left in it:
   - the int8 twin takes per-(position, head) scale planes on the same
     revisit index maps and dequantizes INSIDE the online-softmax loop
     (no f32 pool is ever materialized);
-  - ``q_len = 1`` degenerates exactly to the decode kernel's mask, so
+  - ``q_len = 1`` degenerates exactly to a one-query decode mask, so
     a decode-only wave pays no mixed-mode tax.
 
-The bullets above are the BLOCKED body (``_ragged_kernel``): the
-contiguous layout in either dtype, and the int8 block-table pool.  The
-float block-table pool — the engine's production layout, the one a
-benchmark cell serves from — is read in place by ``_kv_rows_kernel``
+The bullets above are the BLOCKED body (``_ragged_kernel``), the int8
+block-table pool's.  The float block-table pool — the engine's
+production layout, the one a benchmark cell serves from — is read in
+place by ``_kv_rows_kernel``
 under the same wrapper and the same HLO name: pool rows of whole lane
 tiles left in HBM, grid (slot, q-tile), pages copied by hand a group at
 a time with the layer in the copy, the same per-slot data (q_len,
@@ -42,11 +41,8 @@ kv_len, tables), mask, dead-tile rule and f32 online softmax (see the
 comment block over it), and a (slot, q-tile) step scored at the height
 its live rows need (``tile_heights``: a decoding slot's one row beside
 a prompt chunk costs one sublane tile of queries, not the tile's 64).
-ONE masked-gather reference
-(``ragged_masked_reference``) serves them all for off-TPU
-interpret-mode parity — kernels/decode_attention.py's two per-mode
-references delegate here, and its contiguous decode and verify kernels
-remain for ``_decode_step``/``_verify_step`` (ROADMAP C5).
+ONE masked-gather reference (``ragged_masked_reference``) serves them
+all for off-TPU interpret-mode parity.
 
 The kernel reads the q-block's own K/V back from the pool (the
 engine's mixed step writes before it attends), so a lossy cache dtype
@@ -67,15 +63,14 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ..kv_layout import kv_heads, kv_row_width, kv_rows
 from .flash_attention import NEG_INF, _fit_block, _prec
-from .decode_attention import (_LANES, _online_softmax_multi,
-                               _use_interpret, _verify_finalize)
+from ._shared import _LANES, _use_interpret
 
 
 # (head, query) accumulator rows one q-tile may hold: the (m, l, acc)
 # scratch, the score block and the q/o tiles all scale with it.  The
-# BLOCKED body (contiguous caches, the int8 pool: K/V tiles of
-# ``[bk, H, Dh]``): a q-block of up to 3200 rows (25 heads x 128
-# queries; 12 x 256 is 3072) compiled for v5e's 16 MiB scoped VMEM as
+# BLOCKED body (the int8 pool: K/V tiles of ``[bs, H, Dh]``): a
+# q-block of up to 3200 rows (25 heads x 128 queries; 12 x 256 is
+# 3072) compiled for v5e's 16 MiB scoped VMEM as
 # ONE tile before there were tiles, and still is one; longer q-blocks,
 # which the compiler refused, are cut into tiles of at most 2048 rows
 # (3072-row tiles of an f32 cache are refused once there is more than
@@ -99,19 +94,6 @@ def _q_tile(Q, H):
     return _fit_block(max(_MAX_ROWS // H, 1), Q)
 
 
-def _kv_block(block_k, S, H, Dh, dtype):
-    """Positions per contiguous kv block: ``block_k``, unless a
-    [bk, H, Dh] tile of ``dtype`` (H pads to the dtype's sublane tile,
-    Dh to 128 lanes) would pass 1 MiB of VMEM.  K and V are each
-    double-buffered; the one cache the compiler refused at 128
-    positions is f32 at 25 heads (2 MiB a tile, 8 MiB before a score is
-    computed), which drops to 64 — every other cache keeps 128."""
-    item = jnp.dtype(dtype).itemsize
-    sub = 32 // item
-    per_pos = -(-H // sub) * sub * -(-Dh // _LANES) * _LANES * item
-    return _fit_block(min(block_k, max((1 << 20) // per_pos, 8)), S)
-
-
 def _visible_end(lens_ref, qlens_ref, b, t, tq):
     """One past the last kv position q-tile ``t`` of slot ``b`` can
     see.  A tile that reaches the q-block's dead tail sees the whole
@@ -124,9 +106,7 @@ def _visible_end(lens_ref, qlens_ref, b, t, tq):
 
 def _live_tile(qlens_ref, b, t, tq):
     """Whether q-tile ``t`` of slot ``b`` holds a live query.  Tile 0
-    always does (an empty slot still finalizes to zeros through it), so
-    a q-block of one tile keeps the verify kernel's arithmetic row for
-    row."""
+    always does (an empty slot still finalizes to zeros through it)."""
     return t * tq < jnp.maximum(qlens_ref[b], 1)
 
 
@@ -144,27 +124,73 @@ def _kv_step_block(lens_ref, qlens_ref, b, t, j, tq, bk):
                      jnp.minimum(j, last), last)
 
 
-def _ragged_kernel(*refs, scale, bk, n_kv, tq, quant, tabled):
-    """The single mixed-mode body.  ``refs`` is the Pallas positional
-    layout — scalar-prefetch (lens, q_lens[, block_tables]) then
-    operands (q, k[, k_scale], v[, v_scale]) then the output and the
-    (m, l, acc) scratch — sliced by the two static flags: ``quant``
-    adds the int8 scale planes, ``tabled`` the block-table ref (consumed
-    only by the index maps).  Everything mode-specific is per-slot DATA
-    (q_len, kv_len), never a code path: a decode slot is q_len=1, a
-    spec-verify slot k+1, a prefill chunk its chunk width, all in the
-    same wave."""
-    i = 2 + (1 if tabled else 0)     # skip lens/qlens[/tables] refs
-    lens_ref, qlens_ref = refs[0], refs[1]
-    q_ref = refs[i]
-    if quant:
-        k_ref, ks_ref, v_ref, vs_ref = refs[i + 1:i + 5]
-        i += 5
-    else:
-        k_ref, v_ref = refs[i + 1:i + 3]
-        i += 3
-    o_ref, m_ref, l_ref, acc_ref = refs[i:i + 4]
+def _query_positions(filled, qlen, nq, q0=0):
+    """Absolute position of each query in a slot's q-block:
+    query ``jq`` sits at ``filled - qlen + jq`` (``q0`` is the index of
+    this tile's first query when the q-block is tiled); dead queries
+    (``jq >= qlen``) clip to the last live position so their (discarded)
+    softmax rows stay finite, and a fully-inert slot (filled 0) clips
+    to 0 — the ``l == 0`` finalize guard zeroes its output anyway."""
+    qidx = jax.lax.broadcasted_iota(jnp.int32, (1, nq, 1), 1)
+    return jnp.clip(filled - qlen + q0 + qidx, 0,
+                    jnp.maximum(filled - 1, 0))
 
+
+def _online_softmax_multi(q, k, v, filled, qlen, j, bk, scale, m_ref,
+                          l_ref, acc_ref, q0=0):
+    """One KV block's contribution to a q-block's online softmax:
+    ``q`` [Q, H, Dh] against ``k``/``v`` [bk, H, Dh], one accumulator
+    row per (head, query).  The causal mask inside the q-block falls out
+    of the per-query absolute positions — query jq admits kv positions
+    up to ``filled - qlen + jq``, which for qlen=1 degenerates to the
+    single-query kernel's ``< filled`` mask."""
+    Q, H, Dh = q.shape
+    R = H * Q
+    # s[h, qj, s] = q[qj, h] . k[s, h] — batched over heads
+    qt = jnp.swapaxes(q, 0, 1)                            # [H, Q, Dh]
+    s = jax.lax.dot_general(
+        qt, k, (((2,), (2,)), ((0,), (1,))),
+        precision=_prec(q.dtype),
+        preferred_element_type=jnp.float32) * scale       # [H, Q, bk]
+    kv_pos = j * bk + jax.lax.broadcasted_iota(jnp.int32, (H, Q, bk), 2)
+    posq = _query_positions(filled, qlen, Q, q0)          # [1, Q, 1]
+    s = jnp.where(kv_pos <= posq, s, NEG_INF)
+    s = s.reshape(R, bk)
+    m_prev = m_ref[:, 0:1]
+    l_prev = l_ref[:, 0:1]
+    m_cur = jnp.max(s, axis=-1, keepdims=True)
+    m_new = jnp.maximum(m_prev, m_cur)
+    safe_m = jnp.where(m_new <= NEG_INF / 2, 0.0, m_new)
+    p = jnp.exp(s - safe_m)
+    p = jnp.where(s <= NEG_INF / 2, 0.0, p)
+    alpha = jnp.exp(jnp.clip(m_prev - m_new, max=0.0))
+    alpha = jnp.where(m_prev <= NEG_INF / 2, 0.0, alpha)
+    l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
+    pv = jax.lax.dot_general(
+        p.reshape(H, Q, bk).astype(v.dtype), v,
+        (((2,), (0,)), ((0,), (1,))),
+        precision=_prec(v.dtype),
+        preferred_element_type=jnp.float32)               # [H, Q, Dh]
+    acc_ref[:] = acc_ref[:] * alpha + pv.reshape(R, Dh)
+    m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
+    l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
+
+
+def _verify_finalize(o_ref, m_ref, l_ref, acc_ref, nq, heads, dh):
+    l = l_ref[:, 0:1]
+    denom = jnp.where(l == 0.0, 1.0, l)
+    o = (acc_ref[:] / denom).reshape(heads, nq, dh)
+    o_ref[0] = jnp.swapaxes(o, 0, 1).astype(o_ref.dtype)
+
+
+def _ragged_kernel(lens_ref, qlens_ref, bt_ref, q_ref, k_ref, ks_ref,
+                   v_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref, *, scale,
+                   bk, n_kv, tq):
+    """The blocked mixed-mode body, over int8 pages with their scale
+    planes (``bt_ref``, the block tables, is read by the index maps
+    alone).  Everything mode-specific is per-slot DATA (q_len, kv_len),
+    never a code path: a decode slot is q_len=1, a spec-verify slot
+    k+1, a prefill chunk its chunk width, all in the same wave."""
     b = pl.program_id(0)
     t = pl.program_id(1)
     j = pl.program_id(2)
@@ -184,13 +210,11 @@ def _ragged_kernel(*refs, scale, bk, n_kv, tq, quant, tabled):
 
     @pl.when(_live_tile(qlens_ref, b, t, tq) & (j * bk < end))
     def _compute():
-        q, k, v = q_ref[0], k_ref[0], v_ref[0]
-        if quant:
-            k = k.astype(jnp.float32) * ks_ref[0][..., None]
-            v = v.astype(jnp.float32) * vs_ref[0][..., None]
-            q = q.astype(jnp.float32)
-        _online_softmax_multi(q, k, v, lens_ref[b], qlen, j, bk,
-                              scale, m_ref, l_ref, acc_ref, q0=t * tq)
+        k = k_ref[0].astype(jnp.float32) * ks_ref[0][..., None]
+        v = v_ref[0].astype(jnp.float32) * vs_ref[0][..., None]
+        _online_softmax_multi(q_ref[0].astype(jnp.float32), k, v,
+                              lens_ref[b], qlen, j, bk, scale, m_ref,
+                              l_ref, acc_ref, q0=t * tq)
 
     @pl.when(j == n_kv - 1)
     def _finalize():
@@ -198,85 +222,13 @@ def _ragged_kernel(*refs, scale, bk, n_kv, tq, quant, tabled):
                          q_ref.shape[2], q_ref.shape[3])
 
 
-def _call_ragged(q, operands, *, bk, n_kv, tq, quant, tabled, kv_specs,
-                 scalars, interpret):
-    B, Q, H, Dh = q.shape
-    q_spec = pl.BlockSpec((1, tq, H, Dh), lambda b, t, j, *_: (b, t, 0, 0))
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=len(scalars),
-        grid=(B, Q // tq, n_kv),
-        in_specs=[q_spec, *kv_specs],
-        out_specs=q_spec,
-        scratch_shapes=[
-            pltpu.VMEM((H * tq, _LANES), jnp.float32),  # running max
-            pltpu.VMEM((H * tq, _LANES), jnp.float32),  # running denom
-            pltpu.VMEM((H * tq, Dh), jnp.float32),      # output acc
-        ],
-    )
-    return pl.pallas_call(
-        functools.partial(_ragged_kernel, scale=Dh ** -0.5, bk=bk,
-                          n_kv=n_kv, tq=tq, quant=quant, tabled=tabled),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, Q, H, Dh), q.dtype),
-        name="ragged_paged_mixed" if tabled else "ragged_mixed",
-        interpret=interpret,
-    )(*scalars, *operands)
-
-
-def ragged_attention(q, k, v, lengths, q_lens, *, block_k=128,
-                     k_scale=None, v_scale=None, interpret=None):
-    """The mixed wave over the slot-contiguous cache layout.
-
-    q: [B, Q, H, Dh] — one q-block per slot, already written to the
-    cache (rows past ``q_lens[b]`` are inert pad whose output the host
-    discards); k, v: [B, S_max, H, Dh] (one layer's ``cache_k[i]``);
-    lengths: [B] int32 filled counts INCLUDING the q-block's live
-    rows; q_lens: [B] int32 live queries per slot — 1 decodes, k+1
-    verifies, a chunk width prefills, mixed freely in one call.
-    Returns o [B, Q, H, Dh] in q's dtype; a slot with lengths 0
-    returns zeros.  Int8 caches pass ``k_scale``/``v_scale``
-    [B, S_max, H] f32."""
-    B, Q, H, Dh = q.shape
-    S = k.shape[1]
-    quant = k_scale is not None
-    bk = _kv_block(block_k, S, H, Dh, k.dtype)
-    tq = _q_tile(Q, H)
-    if interpret is None:
-        interpret = _use_interpret()
-
-    def block(b, t, j, lens_ref, qlens_ref):
-        return _kv_step_block(lens_ref, qlens_ref, b, t, j, tq, bk)
-
-    def kv_idx(b, t, j, *refs):
-        return (b, block(b, t, j, *refs), 0, 0)
-
-    def sc_idx(b, t, j, *refs):
-        return (b, block(b, t, j, *refs), 0)
-
-    if quant:
-        kv_specs = [pl.BlockSpec((1, bk, H, Dh), kv_idx),
-                    pl.BlockSpec((1, bk, H), sc_idx),
-                    pl.BlockSpec((1, bk, H, Dh), kv_idx),
-                    pl.BlockSpec((1, bk, H), sc_idx)]
-        operands = (q, k, k_scale, v, v_scale)
-    else:
-        kv_specs = [pl.BlockSpec((1, bk, H, Dh), kv_idx),
-                    pl.BlockSpec((1, bk, H, Dh), kv_idx)]
-        operands = (q, k, v)
-    return _call_ragged(
-        q, operands, bk=bk, n_kv=S // bk, tq=tq, quant=quant,
-        tabled=False, kv_specs=kv_specs,
-        scalars=(lengths.astype(jnp.int32), q_lens.astype(jnp.int32)),
-        interpret=interpret)
-
-
 def _ragged_paged_blocked(q, pool_k, pool_v, lengths, q_lens,
                           block_tables, k_scale, v_scale, interpret):
     """The int8 pool's path (ONE layer, ``[N_blocks, bs, H, Dh]`` int8
     with ``[N_blocks, bs, H]`` f32 scales): a page is a grid block,
-    grid (slot, q-tile, page), the shared ``_ragged_kernel`` body
-    dequantizing inside the online-softmax loop.  In no benchmark cell;
-    see :func:`ragged_paged_attention` for why it did not move."""
+    grid (slot, q-tile, page), ``_ragged_kernel`` dequantizing inside
+    the online-softmax loop.  In no benchmark cell; see
+    :func:`ragged_paged_attention` for why it did not move."""
     B, Q, H, Dh = q.shape
     bs = pool_k.shape[1]
     T = block_tables.shape[1]
@@ -292,16 +244,31 @@ def _ragged_paged_blocked(q, pool_k, pool_v, lengths, q_lens,
     def sc_idx(b, t, j, *refs):
         return (block(b, t, j, *refs), 0, 0)
 
-    kv_specs = [pl.BlockSpec((1, bs, H, Dh), kv_idx),
-                pl.BlockSpec((1, bs, H), sc_idx),
-                pl.BlockSpec((1, bs, H, Dh), kv_idx),
-                pl.BlockSpec((1, bs, H), sc_idx)]
-    return _call_ragged(
-        q, (q, pool_k, k_scale, pool_v, v_scale), bk=bs, n_kv=T, tq=tq,
-        quant=True, tabled=True, kv_specs=kv_specs,
-        scalars=(lengths.astype(jnp.int32), q_lens.astype(jnp.int32),
-                 block_tables.astype(jnp.int32)),
-        interpret=interpret)
+    q_spec = pl.BlockSpec((1, tq, H, Dh), lambda b, t, j, *_: (b, t, 0, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(B, Q // tq, T),
+        in_specs=[q_spec,
+                  pl.BlockSpec((1, bs, H, Dh), kv_idx),
+                  pl.BlockSpec((1, bs, H), sc_idx),
+                  pl.BlockSpec((1, bs, H, Dh), kv_idx),
+                  pl.BlockSpec((1, bs, H), sc_idx)],
+        out_specs=q_spec,
+        scratch_shapes=[
+            pltpu.VMEM((H * tq, _LANES), jnp.float32),  # running max
+            pltpu.VMEM((H * tq, _LANES), jnp.float32),  # running denom
+            pltpu.VMEM((H * tq, Dh), jnp.float32),      # output acc
+        ],
+    )
+    return pl.pallas_call(
+        functools.partial(_ragged_kernel, scale=Dh ** -0.5, bk=bs,
+                          n_kv=T, tq=tq),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, Q, H, Dh), q.dtype),
+        name="ragged_paged_mixed",
+        interpret=interpret,
+    )(lengths.astype(jnp.int32), q_lens.astype(jnp.int32),
+      block_tables.astype(jnp.int32), q, pool_k, k_scale, pool_v, v_scale)
 
 
 # ------------------- the paged K/V pool, read in place ------------------- #
@@ -862,8 +829,7 @@ def ragged_masked_reference(q, k, v, lengths, q_lens=None, k_scale=None,
     ``lengths[b] - q_lens[b] + jq`` and admits kv positions up to
     itself; rows past ``q_lens[b]`` clip to the last live position so
     their (discarded) softmax stays finite; a slot with lengths 0
-    returns zeros.  kernels/decode_attention.py's four per-mode
-    references are thin delegates of this function."""
+    returns zeros."""
     if k_scale is not None:
         k = k.astype(jnp.float32) * k_scale[..., None]
         v = v.astype(jnp.float32) * v_scale[..., None]

@@ -58,7 +58,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .decode_attention import _use_interpret
+from ._shared import _use_interpret
 
 SQRT2 = math.sqrt(2.0)
 # a head's S twice in and twice out (17 MB of float32 at d 128), the

@@ -26,8 +26,8 @@ above runs it with one scalar position for the whole batch, the
 speculative draft with a per-slot position vector.  The
 continuous-batching serving engine (``hetu_tpu.serving``) runs ONE
 core, ``_mixed_step``: a ragged wave in which a decode stream is a
-q-block of 1, a verify block k+1 and a prompt chunk its width
-(``serve_mixed_paged_fn``/``serve_mixed_fn``).
+q-block of 1, a verify block k+1 and a prompt chunk its width, over
+the block-table paged pool (``serve_mixed_paged_fn``).
 """
 
 from __future__ import annotations
@@ -56,7 +56,7 @@ NEG_INF = -1e30
 # opaquely.  These helpers are the ONLY places the layout forks: writes
 # encode through ``quant.kv_encode`` (one scale per position per head),
 # reads either dequantize (reference/masked paths) or hand the raw
-# payload + scales to the int8 decode kernels, which dequantize inside
+# payload + scales to the int8 pool's kernel, which dequantizes inside
 # the online-softmax loop.
 #
 # The PAGED pool of a float dtype holds ROWS, ``[L, N_blocks, block, W]``
@@ -192,24 +192,24 @@ def _pow2(n, floor=1):
 _PACKED_ROWS_FLOOR = 256
 
 
-def wave_rows(cfg_tuple, slots, window, q, paged=True, has_fresh=True):
+def wave_rows(cfg_tuple, slots, window, q, has_fresh=True):
     """How many rows the row-wise operators of a mixed wave run over:
     the one place that says it, read by ``_mixed_step`` (the program's
     static row count) and by the engine's scheduler (the capacity it
-    keeps a wave's live rows within).  A wave over the paged pool that
-    carries a prompt chunk (``has_fresh``) is PACKED into ``min(slots *
+    keeps a wave's live rows within).  A wave that carries a prompt
+    chunk (``has_fresh``) is PACKED into ``min(slots *
     q, max(256, pow2(slots * window + 2 * q)))`` rows: every slot's
     sampling window and two chunks of the bucket always fit (1,024 rows
     at 32 slots and q 256, where the padded block is 8,192), and the
     count is a function of the bucket, so the program set stays one a
     (q bucket, ``has_fresh``).  Every other wave runs over ``slots *
-    q``: decode and verify blocks have most of their rows live; the
-    contiguous layout is in no cell; and the capacity router of a
+    q``: decode and verify blocks have most of their rows live; and
+    the capacity router of a
     ``MoESpec`` sizes each expert's slots from the rows it is handed
     (``moe_capacity``), so its waves stay padded and drop what they
     dropped."""
     dense = slots * q
-    if not (paged and has_fresh) or _moe_of(cfg_tuple) is not None:
+    if not has_fresh or _moe_of(cfg_tuple) is not None:
         return dense
     return min(dense, max(_PACKED_ROWS_FLOOR,
                           _pow2(slots * window + 2 * q)))
@@ -760,7 +760,7 @@ def _ffn_block(params, us, h, i, moe=None, valid=None, stats=None):
 
 
 def _decode_step(params, cfg_tuple, cache_k, cache_v, pos, token,
-                 attn="masked", moe_stats=None):
+                 moe_stats=None):
     """One incremental position over a CONTIGUOUS cache ``[L, B, S_max,
     H, Dh]``: token [B] int32 at position ``pos``.  Returns (logits
     [B, V], new cache_k, new cache_v).
@@ -770,11 +770,6 @@ def _decode_step(params, cfg_tuple, cache_k, cache_v, pos, token,
     its own filled length).  Scalar positions keep the contiguous
     dynamic_update_slice write; vector positions scatter one row per
     slot and mask attention per slot.
-
-    ``attn`` (static) picks the attention implementation: "masked"
-    streams and masks (the reference), "ragged" runs the Pallas decode
-    kernel so each slot fetches only its live KV blocks
-    (kernels/decode_attention.py).
 
     ``moe_stats`` (dict) accumulates per-expert load/drop across the
     MoE layers; a dense cfg_tuple ignores it.  The serving engine's
@@ -786,10 +781,6 @@ def _decode_step(params, cfg_tuple, cache_k, cache_v, pos, token,
     per_slot = jnp.ndim(pos) > 0
     h = params[f"{name}_wte_table"][token] + params[f"{name}_wpe"][pos]
 
-    if attn == "ragged":
-        from ..kernels.decode_attention import paged_decode_attention
-        lens = ((pos + 1).astype(jnp.int32) if per_slot
-                else jnp.full((B,), pos + 1, jnp.int32))
     if per_slot:
         live = jnp.arange(S_max)[None, None, :] <= pos[:, None, None]
         bidx = jnp.arange(B)
@@ -814,18 +805,13 @@ def _decode_step(params, cfg_tuple, cache_k, cache_v, pos, token,
             cache_v = _kv_dus(cache_v, v, i, pos)
         ks, ksc = _kv_layer(cache_k, i, H, Dh)              # [B,S,H,Dh]
         vs, vsc = _kv_layer(cache_v, i, H, Dh)
-        if attn == "ragged":
-            o = paged_decode_attention(
-                q, ks, vs, lens, k_scale=ksc,
-                v_scale=vsc).reshape(B, hdim)
-        else:
-            if ksc is not None:
-                ks = kv_decode(ks, ksc)
-                vs = kv_decode(vs, vsc)
-            s = jnp.einsum("bhd,bshd->bhs", q, ks) * (Dh ** -0.5)
-            s = jnp.where(live, s, NEG_INF)
-            p = jax.nn.softmax(s, axis=-1)
-            o = jnp.einsum("bhs,bshd->bhd", p, vs).reshape(B, hdim)
+        if ksc is not None:
+            ks = kv_decode(ks, ksc)
+            vs = kv_decode(vs, vsc)
+        s = jnp.einsum("bhd,bshd->bhs", q, ks) * (Dh ** -0.5)
+        s = jnp.where(live, s, NEG_INF)
+        p = jax.nn.softmax(s, axis=-1)
+        o = jnp.einsum("bhs,bshd->bhd", p, vs).reshape(B, hdim)
         o = o @ params[f"{us}_attn_proj_weight"] \
             + params[f"{us}_attn_proj_bias"]
         h = h + o
@@ -1194,7 +1180,7 @@ def _serve_prefill_batch(params, cfg_tuple, cache_k, cache_v, slots,
 
 
 def _verify_step(params, cfg_tuple, cache_k, cache_v, pos, tokens,
-                 q_len, attn="masked", moe_stats=None):
+                 q_len, moe_stats=None):
     """Multi-position verify: slot b consumes ``tokens[b, :q_len[b]]``
     at positions ``pos[b] .. pos[b]+q_len[b]-1`` in ONE batched step.
     Returns (logits [B, Q, V] f32, new cache_k, new cache_v) — row
@@ -1207,8 +1193,7 @@ def _verify_step(params, cfg_tuple, cache_k, cache_v, pos, tokens,
     ``pos+j`` slots of the contiguous cache — beyond the slot's live
     length, never admitted by a mask, overwritten before use — with the
     writes issued LAST-LIVE-WINS (descending j), so a dead tail clipped
-    to ``S_max-1`` can never clobber a live boundary write.  ``attn``
-    selects the implementation as in ``_decode_step``."""
+    to ``S_max-1`` can never clobber a live boundary write."""
     name, L, H, Dh, S_max = cfg_tuple[:5]
     moe = _moe_of(cfg_tuple)
     B, Q = tokens.shape
@@ -1216,12 +1201,9 @@ def _verify_step(params, cfg_tuple, cache_k, cache_v, pos, tokens,
     bidx = jnp.arange(B)
     posns = pos[:, None] + jnp.arange(Q)[None, :]          # [B, Q]
     valid = jnp.arange(Q)[None, :] < q_len[:, None]        # [B, Q]
-    lens = (pos + q_len).astype(jnp.int32)   # filled after the writes
     wpe = params[f"{name}_wpe"]
     h = params[f"{name}_wte_table"][tokens] \
         + wpe[jnp.clip(posns, 0, wpe.shape[0] - 1)]        # [B, Q, hd]
-    if attn == "ragged":
-        from ..kernels.decode_attention import paged_verify_attention
     ctx = jnp.arange(S_max)[None, None, :]
     live = ctx <= posns[:, :, None]                        # [B, Q, S]
     for i in range(L):
@@ -1241,18 +1223,13 @@ def _verify_step(params, cfg_tuple, cache_k, cache_v, pos, tokens,
             cache_v = _kv_scatter(cache_v, (i, bidx, pw), v[:, jq])
         ks, ksc = _kv_layer(cache_k, i, H, Dh)
         vs, vsc = _kv_layer(cache_v, i, H, Dh)
-        if attn == "ragged":
-            o = paged_verify_attention(
-                q, ks, vs, lens, q_len, k_scale=ksc,
-                v_scale=vsc).reshape(B, Q, hdim)
-        else:
-            if ksc is not None:
-                ks = kv_decode(ks, ksc)
-                vs = kv_decode(vs, vsc)
-            s = jnp.einsum("bqhd,bshd->bqhs", q, ks) * (Dh ** -0.5)
-            s = jnp.where(live[:, :, None, :], s, NEG_INF)
-            p = jax.nn.softmax(s, axis=-1)
-            o = jnp.einsum("bqhs,bshd->bqhd", p, vs).reshape(B, Q, hdim)
+        if ksc is not None:
+            ks = kv_decode(ks, ksc)
+            vs = kv_decode(vs, vsc)
+        s = jnp.einsum("bqhd,bshd->bqhs", q, ks) * (Dh ** -0.5)
+        s = jnp.where(live[:, :, None, :], s, NEG_INF)
+        p = jax.nn.softmax(s, axis=-1)
+        o = jnp.einsum("bqhs,bshd->bqhd", p, vs).reshape(B, Q, hdim)
         o = o @ params[f"{us}_attn_proj_weight"] \
             + params[f"{us}_attn_proj_bias"]
         h = h + o
@@ -1311,7 +1288,7 @@ def _spec_sample(logits, temperature, top_k, rng_keys, count):
 
 
 def _serve_verify(params, cfg_tuple, cache_k, cache_v, pos, tokens,
-                  q_len, temperature, top_k, rng_keys, attn="masked"):
+                  q_len, temperature, top_k, rng_keys):
     """One fused VERIFY wave over all slots (contiguous layout): write
     + score the q-block, then sample every position from each slot's
     own rng stream.  Returns (sampled [B, Q], cache_k, cache_v,
@@ -1320,7 +1297,7 @@ def _serve_verify(params, cfg_tuple, cache_k, cache_v, pos, tokens,
     sd = {} if moe_on else None
     logits, cache_k, cache_v = _verify_step(
         params, cfg_tuple, cache_k, cache_v, pos, tokens, q_len,
-        attn=attn, moe_stats=sd)
+        moe_stats=sd)
     sampled, after = _spec_sample(logits, temperature, top_k, rng_keys,
                                   q_len)
     out = (sampled, cache_k, cache_v, after)
@@ -1365,10 +1342,10 @@ def _spec_propose(params, cfg_tuple, cache_k, cache_v, pos, token, k):
 # in which a decode stream is a q-block of 1, a spec-verify wave k+1,
 # and a prompt (or prompt chunk) its chunk width, all scored by one
 # dispatch.  ``_verify_step`` is this computation for the uniform-mode
-# case over a contiguous cache; ``_mixed_step`` adds the paged pool,
-# the block spec and per-slot SELF-FRESHNESS (see below).  Greedy
-# outputs are token-identical to offline ``generate_fast`` across
-# contiguous/paged/int8/spec/chunked configs.
+# case over a contiguous cache; ``_mixed_step`` runs over the paged
+# pool and adds the block spec and per-slot SELF-FRESHNESS (see below).
+# Greedy outputs are token-identical to offline ``generate_fast``
+# across float/int8/spec/chunked configs.
 
 
 def _window_logits(params, name, h, first_row, window, blk=GPT2_BLOCK,
@@ -1646,11 +1623,10 @@ def _mixed_step(params, cfg_tuple, cache_k, cache_v, pos, tokens,
     exactly.
 
     What the blocks run over.  A decode or verify wave (``has_fresh``
-    False), and every wave of the contiguous layout, runs every block
-    over the whole padded q-block ``[B, Q]``; only the final LN and the
-    head are narrowed to the windows.  A wave over the paged pool that
-    carries a prompt chunk is PACKED once, before the block stack, into
-    ``wave_rows(...)`` = R rows (``_Rows``: slot-major, live rows first;
+    False) runs every block over the whole padded q-block ``[B, Q]``;
+    only the final LN and the head are narrowed to the windows.  A wave
+    that carries a prompt chunk is PACKED once, before the block stack,
+    into ``wave_rows(...)`` = R rows (``_Rows``: slot-major, live rows first;
     static, a function of B, ``window`` and Q, so it adds no program;
     the SCHEDULER keeps a wave's live rows within it, and rows past R
     would be lost).  Everything row-wise then runs over ``[1, R]``:
@@ -1665,10 +1641,10 @@ def _mixed_step(params, cfg_tuple, cache_k, cache_v, pos, tokens,
     and is skipped.
 
     The masked path's DEFAULT attention is ``_verify_step``'s full
-    causal mask over the just-written cache, bit for bit — so decode,
-    spec-verify, and contiguous-prefill slots produce sequential
-    ``_decode_step``'s logits (write-then-read self arithmetic,
-    including the int8 round-trip).  Paged PROMPT-CHUNK slots are the
+    causal mask over the just-written cache, bit for bit — so decode
+    and spec-verify slots produce sequential ``_decode_step``'s logits
+    (write-then-read self arithmetic, including the int8 round-trip).
+    PROMPT-CHUNK slots are the
     one mode that keeps the chunk's own K/V FRESH (an int8 pool scores
     a chunk's own rows before their round-trip, as a one-pass prefill
     does); when a wave carries any
@@ -1678,9 +1654,9 @@ def _mixed_step(params, cfg_tuple, cache_k, cache_v, pos, tokens,
     is computed as well and selected for the slots ``self_fresh`` [B]
     marks.  The ragged path hands the whole wave to the mixed-mode
     kernel, which reads everything back from the pool (the fast path's
-    existing round-trip semantics): over the paged pool it takes the
-    pool pair WHOLE, rows ``[L, N_blocks, block, W]`` where they lie,
-    with ``layer=i`` an index in its page copies (no ``cache_k[i]``).
+    existing round-trip semantics): it takes the pool pair WHOLE, rows
+    ``[L, N_blocks, block, W]`` where they lie, with ``layer=i`` an
+    index in its page copies (no ``cache_k[i]``).
 
     The device trace finds the wave's parts under a handful of
     ``jax.named_scope`` names, the same for every layer and every kind
@@ -1759,8 +1735,7 @@ def _mixed_wave(params, cfg_tuple, cache_k, cache_v, pos, tokens, q_len,
     hdim = H * Dh
     Hkv = blk.kv_heads or H
     group = H // Hkv
-    paged = block_tables is not None
-    R = wave_rows(cfg_tuple, B, window, Q, paged, has_fresh)
+    R = wave_rows(cfg_tuple, B, window, Q, has_fresh)
     bidx = jnp.arange(B)
     posns = pos[:, None] + jnp.arange(Q)[None, :]          # [B, Q]
     valid = jnp.arange(Q)[None, :] < q_len[:, None]        # [B, Q]
@@ -1782,13 +1757,11 @@ def _mixed_wave(params, cfg_tuple, cache_k, cache_v, pos, tokens, q_len,
             wpe = params[f"{name}_wpe"]
             h = h + wpe[jnp.clip(posns_r, 0, wpe.shape[0] - 1)]
     if attn == "ragged":
-        from ..kernels.ragged_attention import (
-            ragged_attention, ragged_paged_attention,
-        )
+        from ..kernels.ragged_attention import ragged_paged_attention
     # a spec none of whose layers holds a page has no pool, and its wave
     # none of what follows up to the layers
     pooled = blk.op_layers(L, "pool") + blk.op_layers(L, "window") > 0
-    if paged and pooled:
+    if pooled:
         bs_blk = _kv_shape(cache_k)[2]
         T = block_tables.shape[1]
         posc = jnp.clip(posns, 0, S_max - 1)
@@ -1811,9 +1784,6 @@ def _mixed_wave(params, cfg_tuple, cache_k, cache_v, pos, tokens, q_len,
                                win_tables[bidx[:, None], posc // bs_blk], 0)
             if rows is not None:
                 wblk_w = jnp.where(valid_r, rows.pack(wblk_w), 0)
-    else:
-        span = S_max
-    if pooled:
         ctx = jnp.arange(span)[None, None, :]
         live = ctx <= posns[:, :, None]                    # [B, Q, S]
         # fresh-self variant: context strictly below the write window
@@ -1866,8 +1836,7 @@ def _mixed_wave(params, cfg_tuple, cache_k, cache_v, pos, tokens, q_len,
             live_i = live & near
         else:
             pi = blk.op_index(i, "pool")
-            ck, cv, tables = cache_k, cache_v, block_tables
-            wb = wblk_r if paged else None
+            ck, cv, tables, wb = cache_k, cache_v, block_tables, wblk_r
             live_i = live
         with jax.named_scope("attn_qkv"):
             x = _norm(blk, params, f"{us}_ln1", h)
@@ -1889,22 +1858,15 @@ def _mixed_wave(params, cfg_tuple, cache_k, cache_v, pos, tokens, q_len,
             with jax.named_scope("kv_write"):
                 k, v = rows.unpack(k), rows.unpack(v)
         with jax.named_scope("kv_write"):
-            if paged and not quant and Q >= bs_blk:
+            if not quant and Q >= bs_blk:
                 # a q-block a page or more wide: whole pages
                 ck = _kv_write_pages(ck, pi, k, pos, q_len, tables)
                 cv = _kv_write_pages(cv, pi, v, pos, q_len, tables)
-            elif paged:
+            else:
                 ck = _kv_scatter(ck, (pi, wb, woff_r), k_r)
                 cv = _kv_scatter(cv, (pi, wb, woff_r), v_r)
-            else:
-                # descending j: dead (clipped) tail first, live wins
-                # last
-                for jq in reversed(range(Q)):
-                    pw = jnp.minimum(posns[:, jq], S_max - 1)
-                    ck = _kv_scatter(ck, (pi, bidx, pw), k[:, jq])
-                    cv = _kv_scatter(cv, (pi, bidx, pw), v[:, jq])
         with jax.named_scope("attention"):
-            if paged and attn == "ragged":
+            if attn == "ragged":
                 # the pool pair whole, the layer an index in the page
                 # copy: no ``cache_k[i]`` is materialised (an int8 pair
                 # hands its scale planes over beside its payload)
@@ -1916,27 +1878,16 @@ def _mixed_wave(params, cfg_tuple, cache_k, cache_v, pos, tokens, q_len,
                     groups=group,
                     window=blk.window if windowed else 0).reshape(
                         B, Q, hdim)
-            elif attn == "ragged":
-                ks, ksc = _kv_layer(ck, pi, Hkv, Dh)
-                vs, vsc = _kv_layer(cv, pi, Hkv, Dh)
-                o = ragged_attention(q, ks, vs, lens, q_len, k_scale=ksc,
-                                     v_scale=vsc).reshape(B, Q, hdim)
             else:
                 ks, ksc = _kv_layer(ck, pi, Hkv, Dh)
                 vs, vsc = _kv_layer(cv, pi, Hkv, Dh)
-                if paged:
-                    kg = ks[tables].reshape(B, span, Hkv, Dh)
-                    vg = vs[tables].reshape(B, span, Hkv, Dh)
-                    if ksc is not None:
-                        kg = kg.astype(jnp.float32) * ksc[
-                            tables].reshape(B, span, Hkv)[..., None]
-                        vg = vg.astype(jnp.float32) * vsc[
-                            tables].reshape(B, span, Hkv)[..., None]
-                else:
-                    kg, vg = ks, vs
-                    if ksc is not None:
-                        kg = kv_decode(kg, ksc)
-                        vg = kv_decode(vg, vsc)
+                kg = ks[tables].reshape(B, span, Hkv, Dh)
+                vg = vs[tables].reshape(B, span, Hkv, Dh)
+                if ksc is not None:
+                    kg = kg.astype(jnp.float32) * ksc[
+                        tables].reshape(B, span, Hkv)[..., None]
+                    vg = vg.astype(jnp.float32) * vsc[
+                        tables].reshape(B, span, Hkv)[..., None]
                 kg, vg = per_query_head(kg), per_query_head(vg)
                 # default: _verify_step's full mask over the written cache
                 s_raw = jnp.einsum("bqhd,bshd->bqhs", q, kg) * scale
@@ -1982,41 +1933,23 @@ def _mixed_wave(params, cfg_tuple, cache_k, cache_v, pos, tokens, q_len,
     return logits, cache_k, cache_v, state
 
 
-def _serve_mixed(params, cfg_tuple, cache_k, cache_v, pos, tokens,
-                 q_len, first_row, self_fresh, temperature, top_k,
-                 rng_keys, attn="masked", window=1):
-    """One fused MIXED wave over all slots (contiguous layout): write +
-    score every slot's ragged q-block, then sample each slot's sampling
-    window — rows ``first_row[b] <= j < q_len[b]``, at most ``window``
-    of them — from its own rng stream (``_spec_sample``).  Returns
-    (sampled [B, W], cache_k, cache_v, keys_after [B, W, 2][, moe
-    stats]), both indexed FROM THE WINDOW'S FIRST ROW: ``[b, 0]`` is a
-    decode slot's token and a final chunk's first token, ``[b, :a + 1]``
-    a verify block's accepted run; a mid-prompt chunk and a dead slot
-    have an empty window and get their key back untouched."""
-    moe_on = _moe_active(cfg_tuple)
-    sd = {} if moe_on else None
-    logits, cache_k, cache_v, _ = _mixed_step(
-        params, cfg_tuple, cache_k, cache_v, pos, tokens, q_len,
-        first_row, self_fresh, window=window, attn=attn, moe_stats=sd)
-    sampled, after = _spec_sample(logits, temperature, top_k, rng_keys,
-                                  q_len - first_row)
-    out = (sampled, cache_k, cache_v, after)
-    if moe_on:
-        out = out + (_moe_stats_out(
-            sd, _moe_of(cfg_tuple),
-            jnp.sum(jnp.clip(q_len, 0, tokens.shape[1]))),)
-    return out
-
-
 def _serve_mixed_paged(params, cfg_tuple, cache_k, cache_v, tables,
                        pos, tokens, q_len, first_row, self_fresh,
                        temperature, top_k, rng_keys, attn="masked",
                        has_fresh=False, window=1, state=None, win=None,
                        ring=None):
-    """``_serve_mixed`` over the block-table paged pool (``q_len`` 0
-    marks inert slots, whose writes route to scratch block 0 and whose
-    window is empty).  ``has_fresh`` (static) marks waves carrying
+    """One fused MIXED wave over all slots of the block-table paged
+    pool: write + score every slot's ragged q-block, then sample each
+    slot's sampling window — rows ``first_row[b] <= j < q_len[b]``, at
+    most ``window`` of them — from its own rng stream
+    (``_spec_sample``).  Returns (sampled [B, W], cache_k, cache_v,
+    keys_after [B, W, 2][, moe stats][, state][, window pool pair]),
+    the first and the keys indexed FROM THE WINDOW'S FIRST ROW: ``[b,
+    0]`` is a decode slot's token and a final chunk's first token,
+    ``[b, :a + 1]`` a verify block's accepted run; a mid-prompt chunk
+    and a dead slot (``q_len`` 0: its writes route to scratch block 0)
+    have an empty window and get their key back untouched.
+    ``has_fresh`` (static) marks waves carrying
     prompt-chunk slots — see ``_mixed_step``.  ``state`` is the slot
     state of a block spec that keeps any (an array, or a tuple of them:
     the manager's set, donated like the pool, returned LAST); every
@@ -2051,26 +1984,15 @@ def _serve_mixed_paged(params, cfg_tuple, cache_k, cache_v, tables,
 
 
 @functools.lru_cache(maxsize=None)
-def serve_mixed_fn(donate=True, attn="masked", window=1):
-    """Jitted ``_serve_mixed`` — the contiguous mixed-mode wave (see
-    ``serve_prefill_fn`` for the donation rationale).  Compiles per
-    q-block bucket Q; the engine pow2-buckets the wave width, so the
-    ladder is log-bounded.  ``window`` (the widest sampling window,
-    ``spec_k + 1`` or 1) is bound here, once an engine."""
-    kw = {"static_argnames": ("cfg_tuple", "attn", "window")}
-    if donate:
-        kw["donate_argnums"] = (2, 3)
-    fn = jax.jit(_serve_mixed, **kw)
-    return functools.partial(fn, attn=attn, window=window)
-
-
-@functools.lru_cache(maxsize=None)
 def serve_mixed_paged_fn(donate=True, attn="masked", window=1):
     """Jitted ``_serve_mixed_paged`` — the block-table mixed wave, the
-    engine's dispatch in every benchmark cell.  Compiles
-    per (Q bucket, has_fresh): steady-state decode waves skip the
-    chunk-slot variant's extra softmax entirely.  ``window`` as in
-    ``serve_mixed_fn``: bound once an engine, never per wave."""
+    engine's one dispatch (see ``serve_prefill_fn`` for the donation
+    rationale).  Compiles per (Q bucket, has_fresh): the engine
+    pow2-buckets the wave width, so the ladder is log-bounded, and
+    steady-state decode waves skip the chunk-slot variant's extra
+    softmax entirely.  ``window`` (the widest sampling window,
+    ``spec_k + 1`` or 1) is bound here, once an engine, never per
+    wave."""
     kw = {"static_argnames": ("cfg_tuple", "attn", "has_fresh", "window")}
     if donate:
         kw["donate_argnums"] = (2, 3)
@@ -2104,17 +2026,16 @@ def serve_prefill_batch_fn(donate=True):
 
 
 @functools.lru_cache(maxsize=None)
-def serve_verify_fn(donate=True, attn="masked"):
+def serve_verify_fn(donate=True):
     """Jitted ``_serve_verify`` — the speculative wave's batched
     verification step over the contiguous cache (see
     ``serve_prefill_fn`` for the donation rationale).  Compiles per
     q-block width Q = spec_k + 1; adaptive k varies per-slot ``q_len``
     INSIDE one compile, so the ladder is one entry per engine."""
-    kw = {"static_argnames": ("cfg_tuple", "attn")}
+    kw = {"static_argnames": ("cfg_tuple",)}
     if donate:
         kw["donate_argnums"] = (2, 3)
-    fn = jax.jit(_serve_verify, **kw)
-    return functools.partial(fn, attn=attn)
+    return jax.jit(_serve_verify, **kw)
 
 
 @functools.lru_cache(maxsize=None)

@@ -248,7 +248,7 @@ def takes_kernel(head_dim, q_block):
     shapes alone decide, so a program is one or the other, and the
     engine can ask the same question of a wave
     (``serve.ret.kernel_slot_steps``)."""
-    from ..kernels.decode_attention import _LANES
+    from ..kernels._shared import _LANES
     return head_dim % _LANES == 0 and q_block > 1
 
 

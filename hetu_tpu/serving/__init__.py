@@ -34,13 +34,11 @@ tokens are ``generate_fast``'s).
                   the launcher's HETU_RESTART_LIMIT/BACKOFF budget,
                   chaos kill/wedge at the step seam (HETU_CHAOS
                   role=replica<k>), heartbeat for wedge detection
-    kv_manager.py KVCacheManager: free-slot allocation + per-slot filled
-                  lengths over one preallocated [L, B_slots, S_max, H, Dh]
-                  cache pair, pow2-bucketed shapes; PagedKVManager: the
-                  block-table paged pool (free-list block allocator,
+    kv_manager.py PagedKVManager: the block-table paged pool, the
+                  engine's one KV layout (free-list block allocator,
                   refcounted copy-on-write prefix sharing, chunked
-                  prefill support), the default (block 16,
-                  $HETU_KV_BLOCK); paged=False selects the former
+                  prefill support, pow2-bucketed shapes; block 16,
+                  $HETU_KV_BLOCK)
     kv_tiers.py   TieredKVStore: fleet-global prefix capacity — the
                   eviction-to-tier ladder behind every paged pool
                   (HBM pool -> host-RAM LRU ring sized by
@@ -125,8 +123,8 @@ from ..telemetry.slo import SLO, SLOMonitor
 from .autoscaler import FleetAutoscaler
 from .request import EmbedRequest, EmbedResult, Request, RequestCore, Result
 from .kv_manager import (
-    KVCacheManager, PagedKVManager, resolve_handoff_quant,
-    resolve_kv_block, resolve_kv_quant, round_up_pow2,
+    PagedKVManager, resolve_handoff_quant, resolve_kv_block,
+    resolve_kv_quant, round_up_pow2,
 )
 from .metrics import (
     COMPONENTS, EMBED_COMPONENTS, EmbedServingMetrics, ServingMetrics,
@@ -146,7 +144,7 @@ __all__ = [
     "TrafficGenerator", "TrafficSpec", "replay",
     "QueueFull", "RouterShed", "Request", "RequestCore", "Result",
     "EmbedRequest", "EmbedResult",
-    "KVCacheManager", "PagedKVManager", "ServingMetrics",
+    "PagedKVManager", "ServingMetrics",
     "EmbedServingMetrics", "COMPONENTS", "EMBED_COMPONENTS",
     "SLO", "SLOMonitor", "PrefixDirectory", "TieredKVStore",
     "prefix_hash", "resolve_handoff_quant",

@@ -45,16 +45,15 @@ from ..telemetry import slo as slo_mod
 from ..models.gpt_decode import (
     GPT2_BLOCK, block_spec_of, check_block_spec, head_dim_of,
     _infer_name, _prep_param, _pow2, _resolve_fast, resolve_draft_layers,
-    resolve_spec_k, serve_mixed_fn, serve_mixed_paged_fn,
-    serve_prefill_fn, spec_propose_fn, wave_rows,
+    resolve_spec_k, serve_mixed_paged_fn, serve_prefill_fn,
+    spec_propose_fn, wave_rows,
 )
 from ..kernels.ragged_attention import (
     mla_rows_tiling, mla_tiling, row_tile_visits, rows_tiling, tile_heights)
 from ..models.moe_decode import takes_kernel
 from ..models.retention_decode import takes_kernel as retention_takes_kernel
-from .kv_manager import (KVCacheManager, PagedKVManager,
-                         assemble_mixed_wave, resolve_kv_block,
-                         resolve_kv_quant)
+from .kv_manager import (PagedKVManager, assemble_mixed_wave,
+                         resolve_kv_block, resolve_kv_quant)
 from .metrics import ServingMetrics
 from .request import Request, Result
 
@@ -111,14 +110,13 @@ class ServingEngine:
     ``$HETU_SERVE_FAST`` then takes the kernel on a TPU and the
     reference elsewhere — the one thing here that follows the platform
     (greedy outputs are identical either way; ``fast_path=True`` is how
-    a test runs the kernel in interpret mode); paged: the KV layout,
-    default the block-table pool (``PagedKVManager``, block 16,
-    ``kv_block``/``$HETU_KV_BLOCK``) on every backend, ``paged=False``
-    the slot-contiguous ``KVCacheManager``; spec: > 0 enables SPECULATIVE
-    DECODING (default ``$HETU_SPEC_K``) — a truncated-layer draft
-    (``spec_draft_layers`` of the target's own blocks + the shared
-    final LN/tied head; default ``$HETU_SPEC_DRAFT_LAYERS`` or
-    max(1, L // 4)) proposes up to ``spec`` tokens per slot per wave
+    a test runs the kernel in interpret mode); kv_block: the block
+    of the ONE KV layout, the block-table pool (``PagedKVManager``,
+    default 16 or ``$HETU_KV_BLOCK``, on every backend); spec: > 0
+    enables SPECULATIVE DECODING (default ``$HETU_SPEC_K``) — a
+    truncated-layer draft (``spec_draft_layers`` of the target's own
+    blocks + the shared final LN/tied head; default
+    ``$HETU_SPEC_DRAFT_LAYERS`` or max(1, L // 4)) proposes up to ``spec`` tokens per slot per wave
     in ONE scanned dispatch, the target verifies all proposals plus
     the carried token as a k+1 q-block of the wave, and longest-prefix
     acceptance + the bonus token emit 1..spec+1 tokens per wave —
@@ -137,9 +135,9 @@ class ServingEngine:
     slot never stalls behind another request's prompt chunks (the
     ``chunk_stall`` lifecycle component is ~0) and a step costs one
     dispatch regardless of the mode mix.  Greedy outputs are
-    token-identical to offline ``generate_fast`` across every layout
-    (contiguous/paged, int8, chunked, prefix sharing, speculation) —
-    the parity suite pins it.
+    token-identical to offline ``generate_fast`` across every
+    configuration (int8, chunked, prefix sharing, speculation) — the
+    parity suite pins it.
 
     Block spec: a config that carries one (``config.block_spec()``)
     runs on the wave over the paged pool: ``_mixed_step`` reads the
@@ -155,9 +153,8 @@ class ServingEngine:
     slot) the same ``PagedKVManager`` owns beside the pool, zeroes on
     admission and hands through the donated step; dense SwiGLU / dropless
     routed FFN, tied head.  Such an engine raises a ``ValueError`` that
-    names the path when built with ``paged=False`` (the contiguous
-    ``KVCacheManager``), ``spec`` > 0 (the draft, its contiguous cache
-    and ``_decode_step``; and state has no rollback) or
+    names the path when built with ``spec`` > 0 (the draft, its
+    contiguous cache and ``_decode_step``; and state has no rollback) or
     ``kv_quant="int8"``; a latent pool refuses
     ``export_blocks``/``import_blocks`` and the KV tiers (latent rows
     have no wire format); a manager with state refuses those too, and
@@ -183,13 +180,15 @@ class ServingEngine:
     QueueFull storms dump the flight recorder to ``$HETU_FLIGHT_LOG``.
     """
 
-    # read by benchmarks/runners/serve.py, serve_latent_moe.py and
-    # tests/benchmark/test_benchmark.py; nothing branches on it
+    # read by benchmarks/runners/serve*.py and
+    # tests/benchmark/test_benchmark.py; nothing branches on them: the
+    # mixed ragged wave over the paged pool is the one path there is
     ragged = True
+    paged = True
 
     def __init__(self, params, config, *, slots=8, queue_limit=64,
                  max_seq_len=None, name=None, dtype=None, log_path=None,
-                 donate=True, fast_path=None, paged=None, kv_block=None,
+                 donate=True, fast_path=None, kv_block=None,
                  pool_blocks=None, prefix_share=None, prefill_chunk=None,
                  kv_quant=None, slo=None, tags=None, spec=None,
                  spec_adapt=None, spec_draft_layers=None):
@@ -219,14 +218,11 @@ class ServingEngine:
         # per HBM byte, dequantized inside the decode kernels
         self.kv_quant = resolve_kv_quant(kv_quant)
         kv_dtype = self.kv_quant or cdtype
-        block = resolve_kv_block(paged, kv_block)
-        self.paged = block > 0
+        block = resolve_kv_block(kv_block)
         self.fast_path = _resolve_fast(fast_path)
         self.spec_k = resolve_spec_k(spec)
         if other:
             for bad, path in (
-                    (not self.paged, "the contiguous KVCacheManager "
-                     "(paged=False): _serve_mixed"),
                     (self.spec_k, "speculation (spec_k > 0): "
                      "_spec_propose, _serve_prefill and the draft's "
                      "contiguous cache"),
@@ -239,31 +235,24 @@ class ServingEngine:
         blk = self.block_spec
         latent = blk.latent
         L = c.num_hidden_layers
-        if self.paged:
-            chunk = (prefill_chunk if prefill_chunk is not None
-                     else envvars.get_int("HETU_KV_CHUNK"))
-            self.chunk = max(int(chunk or 0), 0)
-            self.kv = PagedKVManager(
-                # the pool holds the layers with an attention; the
-                # layers with a conv, a state-space mixer or retention
-                # keep slot state beside it (a layer may do both; where
-                # none holds a page there is no pool, and the manager
-                # takes no ``pool_blocks``)
-                layers=blk.op_layers(L, "pool"),
-                heads=blk.kv_heads or c.num_attention_heads,
-                head_dim=Dh, slots=slots, max_seq_len=want,
-                pos_cap=c.max_position_embeddings, dtype=kv_dtype,
-                block=block, pool_blocks=pool_blocks,
-                prefix_share=prefix_share,
-                row_shape=(latent.row_width,) if latent else None,
-                state_shapes=blk.state_shapes(L, c.hidden_size),
-                **self._window_pool(blk, L, want, self.chunk))
-        else:
-            self.kv = KVCacheManager(
-                layers=c.num_hidden_layers, heads=c.num_attention_heads,
-                head_dim=Dh, slots=slots, max_seq_len=want,
-                pos_cap=c.max_position_embeddings, dtype=kv_dtype)
-            self.chunk = 0
+        chunk = (prefill_chunk if prefill_chunk is not None
+                 else envvars.get_int("HETU_KV_CHUNK"))
+        self.chunk = max(int(chunk or 0), 0)
+        self.kv = PagedKVManager(
+            # the pool holds the layers with an attention; the layers
+            # with a conv, a state-space mixer or retention keep slot
+            # state beside it (a layer may do both; where none holds a
+            # page there is no pool, and the manager takes no
+            # ``pool_blocks``)
+            layers=blk.op_layers(L, "pool"),
+            heads=blk.kv_heads or c.num_attention_heads,
+            head_dim=Dh, slots=slots, max_seq_len=want,
+            pos_cap=c.max_position_embeddings, dtype=kv_dtype,
+            block=block, pool_blocks=pool_blocks,
+            prefix_share=prefix_share,
+            row_shape=(latent.row_width,) if latent else None,
+            state_shapes=blk.state_shapes(L, c.hidden_size),
+            **self._window_pool(blk, L, want, self.chunk))
         self.cfg_tuple = (self._name, c.num_hidden_layers,
                           c.num_attention_heads, Dh, self.kv.s_max)
         # ---- MoE serving (models/moe_decode.py): a MoEDecodeConfig
@@ -307,7 +296,7 @@ class ServingEngine:
             self._moe_layers = self.moe.moe_layers(c.num_hidden_layers)
         self.prefill_dispatches = 0   # waves that carried a prompt
         # q-block (a burst of k arrivals is ONE wave, not k dispatches)
-        self.prefill_chunks = 0       # prompt q-blocks written (paged)
+        self.prefill_chunks = 0       # prompt q-blocks written
         self.peak_live = 0            # max concurrent admitted slots
         self.queue_limit = int(queue_limit)
         self._queue = collections.deque()
@@ -368,8 +357,8 @@ class ServingEngine:
         self.weight_version = None
         self.last_swap_at = None
         self._slot_version = [None] * B
-        self._prefill_off = np.zeros(B, np.int32)  # paged: next prompt
-        self._prompt_arr = [None] * B              # position to prefill
+        self._prefill_off = np.zeros(B, np.int32)  # next prompt position
+        self._prompt_arr = [None] * B              # to prefill
         # admission order: a chunk wave takes its prompt chunks oldest
         # admission first (see ``_launch``)
         self._admit_no = np.zeros(B, np.int64)
@@ -423,8 +412,7 @@ class ServingEngine:
         # decode slot or a final chunk samples; the wave's head and
         # sampling run over that many rows a slot, not the padded
         # q-block ---- #
-        mixed_fn = serve_mixed_paged_fn if self.paged else serve_mixed_fn
-        self._mixed = mixed_fn(
+        self._mixed = serve_mixed_paged_fn(
             donate, "ragged" if self.fast_path else "masked",
             self.spec_k + 1)
         if envvars.get_bool("HETU_VALIDATE"):
@@ -551,7 +539,7 @@ class ServingEngine:
         ctx = int(np.where(ql > 0, pos + ql, 0).sum()) if attends else 0
         pairs = int((ql * pos + ql * (ql + 1) // 2).sum()) if attends else 0
         window = None
-        if self.paged and self.kv.window_layers:
+        if self.kv.window_layers:
             # a window layer's rows see ``min(pos + j + 1, W)`` positions
             # each: the first ``W - pos`` rows (if any) as a full
             # layer's, the rest ``W``; a slot's q-block has ``min(pos +
@@ -602,8 +590,8 @@ class ServingEngine:
         (``rows`` under slots x ``Q``) moves the packed rows' tiles and
         counts a tile's VISITS to the slots whose rows cross it.  None
         where the engine's waves run no such kernel (the masked path,
-        the contiguous cache, the int8 pool)."""
-        if not (self.fast_path and self.paged) or self.kv_quant:
+        the int8 pool)."""
+        if not self.fast_path or self.kv_quant:
             return None
         H, Dh = self.cfg_tuple[2:4]
         if self.block_spec.latent and rows < len(q_len) * Q:
@@ -670,8 +658,7 @@ class ServingEngine:
             raise ValueError(
                 f"prompt + max_new_tokens = {total} exceeds the "
                 f"engine's S_max {self.kv.s_max}")
-        if self.paged and \
-                self.kv.blocks_needed(total) > self.kv.capacity_blocks:
+        if self.kv.blocks_needed(total) > self.kv.capacity_blocks:
             raise ValueError(
                 f"request needs {self.kv.blocks_needed(total)} KV "
                 f"blocks; the pool holds {self.kv.capacity_blocks}")
@@ -887,45 +874,13 @@ class ServingEngine:
         self._keys[slot] = key
         self._gen[slot] = [tok0]
         self._tok_t[slot] = [now]
-        if self.paged:
-            self.kv.register_prefix(self._prompt_arr[slot], slot)
+        self.kv.register_prefix(self._prompt_arr[slot], slot)
         self.metrics.record_admit(
             req.request_id, slot, now - req.submitted_at,
             now - req.submitted_at)
         if req.stream_cb:
             req.stream_cb(req, tok0)
         return self._maybe_finish(slot, tok0)
-
-    def _admit_contiguous_mixed(self):
-        """Contiguous admission: the claimed slot's prompt joins this
-        step's wave as one ragged q-block (``_gen = None`` marks it
-        mid-prefill, as ``_admit_paged`` does)."""
-        admitted = []
-        while self._queue and self.kv.free_slots:
-            req = self._queue.popleft()
-            t_a = time.perf_counter()
-            slot = self.kv.alloc(req.request_id, len(req.prompt))
-            req.claimed_at = time.perf_counter()
-            self.metrics.lc_claimed(
-                req.request_id, (req.claimed_at - t_a) * 1e3)
-            self._reqs[slot] = req
-            self._slot_version[slot] = self.weight_version
-            self._gen[slot] = None
-            self._prompt_arr[slot] = np.asarray(req.prompt, np.int32)
-            self._prefill_off[slot] = 0
-            self._admit_no[slot] = self._admitted
-            self._admitted += 1
-            self._pos[slot] = 0
-            self._emitted[slot] = 0
-            self._tok[slot] = 0
-            self._temp[slot] = req.temperature
-            self._topk[slot] = req.top_k
-            self._keys[slot] = np.asarray(
-                jax.random.PRNGKey(req.seed), np.uint32)
-            admitted.append(slot)
-        if admitted:
-            telemetry.inc("serve.admission_waves")
-        return admitted
 
     def _step_mixed(self):
         """The scheduler iteration: admissions, chunk continuations,
@@ -1024,10 +979,7 @@ class ServingEngine:
         # fetch, deferral, backpressure); prompts join THIS wave
         with telemetry.span("serve.admit", wave=wave_id,
                             queue=len(self._queue)):
-            if self.paged:
-                self._admit_paged()
-            else:
-                self._admit_contiguous_mixed()
+            self._admit_paged()
         live = self.kv.live()
         if not live:
             return
@@ -1083,14 +1035,14 @@ class ServingEngine:
                 prompt = self._prompt_arr[s]
                 P = len(prompt)
                 off = int(self._prefill_off[s])
-                if self.paged and self.chunk > 0:
+                if self.chunk > 0:
                     C_b = min(_pow2(self.chunk, floor=8), self.kv.s_max)
                     take = min(self.chunk, C_b, P - off)
                 else:
                     take = P - off
                 if rows_live + take > wave_rows(
                         self.cfg_tuple, B, self.spec_k + 1,
-                        _pow2(max(width, take)), self.paged):
+                        _pow2(max(width, take))):
                     continue
                 rows_live += take
                 width = max(width, take)
@@ -1098,12 +1050,12 @@ class ServingEngine:
                 # only the final chunk samples (and splits the rng) — at
                 # its last row; mid-prompt chunks pass first_row == q_len
                 entries[s] = ([int(t) for t in prompt[off:off + take]],
-                              off, take - 1 if final else take, self.paged)
+                              off, take - 1 if final else take, True)
                 chunk_take[s] = (take, final)
             waiting = [s for s in pre if s not in chunk_take]
             pre = [s for s in pre if s in chunk_take]
             wave = assemble_mixed_wave(B, entries)
-            tables = self.kv.tables.copy() if self.paged else None
+            tables = self.kv.tables.copy()
             from_device = np.zeros(B, bool)
             if ahead:
                 from_device[decoding] = True
@@ -1113,37 +1065,28 @@ class ServingEngine:
             tokens, keys = _hand_over(self._dev_sampled, self._dev_after,
                                       from_device, wave["tokens"],
                                       self._keys)
-            if self.paged:
-                # a manager with state hands it through beside the pool
-                # and gets it back last
-                stateful = {"state": self.kv.state} \
-                    if self.kv.stateful else {}
-                if self.kv.window_layers:
-                    # the window layers' pool pair rides beside the
-                    # pool and comes back after everything else
-                    stateful.update(
-                        win=(self.kv.win_k, self.kv.win_v),
-                        ring=self.kv.win_tables.copy())
-                out = self._mixed(
-                    self.params, self.cfg_tuple,
-                    self.kv.cache_k, self.kv.cache_v,
-                    tables, wave["pos"], tokens,
-                    wave["q_len"], wave["first_row"], wave["self_fresh"],
-                    self._temp, self._topk, keys,
-                    has_fresh=bool(pre), **stateful)
-                if self.kv.window_layers:
-                    out, (self.kv.win_k, self.kv.win_v) = out[:-1], out[-1]
-                if self.kv.stateful:
-                    out, self.kv.state = out[:-1], out[-1]
-                if self.routed is not None:
-                    out, routed_out = out[:-1], out[-1]
-            else:
-                out = self._mixed(
-                    self.params, self.cfg_tuple,
-                    self.kv.cache_k, self.kv.cache_v,
-                    wave["pos"], tokens, wave["q_len"],
-                    wave["first_row"], wave["self_fresh"],
-                    self._temp, self._topk, keys)
+            # a manager with state hands it through beside the pool and
+            # gets it back last
+            stateful = {"state": self.kv.state} if self.kv.stateful else {}
+            if self.kv.window_layers:
+                # the window layers' pool pair rides beside the pool
+                # and comes back after everything else
+                stateful.update(
+                    win=(self.kv.win_k, self.kv.win_v),
+                    ring=self.kv.win_tables.copy())
+            out = self._mixed(
+                self.params, self.cfg_tuple,
+                self.kv.cache_k, self.kv.cache_v,
+                tables, wave["pos"], tokens,
+                wave["q_len"], wave["first_row"], wave["self_fresh"],
+                self._temp, self._topk, keys,
+                has_fresh=bool(pre), **stateful)
+            if self.kv.window_layers:
+                out, (self.kv.win_k, self.kv.win_v) = out[:-1], out[-1]
+            if self.kv.stateful:
+                out, self.kv.state = out[:-1], out[-1]
+            if self.routed is not None:
+                out, routed_out = out[:-1], out[-1]
             if self.moe is not None:
                 out, moe_stats = out[:-1], out[-1]
             sampled, ck, cv, after = out
@@ -1154,10 +1097,9 @@ class ServingEngine:
             self.prefill_dispatches += 1
         for s in pre:
             take, final = chunk_take[s]
-            if self.paged:
-                self.kv.advance(s, take)
-                self.prefill_chunks += 1
-                telemetry.inc("serve.prefill_chunks")
+            self.kv.advance(s, take)
+            self.prefill_chunks += 1
+            telemetry.inc("serve.prefill_chunks")
             self._prefill_off[s] += take
             if final:
                 self._pos[s] = len(self._prompt_arr[s])
@@ -1177,7 +1119,7 @@ class ServingEngine:
             chunk_take=chunk_take, entries=entries, qlen_v=qlen_v,
             k_cur=k_cur, wave=wave, rows_live=rows_live,
             rows_computed=wave_rows(self.cfg_tuple, B, self.spec_k + 1,
-                                    wave["q"], self.paged, bool(pre)),
+                                    wave["q"], bool(pre)),
             sampled=sampled, after=after, routed_out=routed_out,
             moe_stats=moe_stats,
             ends=any(self._emitted[s] >= r.max_new_tokens
@@ -1346,7 +1288,7 @@ class ServingEngine:
                 # the window pool's ring and the most blocks a slot holds
                 window={"ring": self.kv.ring, "held_max": int(
                     np.count_nonzero(self.kv.win_tables, axis=1).max())}
-                if self.paged and self.kv.window_layers else None)
+                if self.kv.window_layers else None)
         self._land_end = time.perf_counter()
         root.set(landed=wave_id, live=n_live, q_prefill=q_pre,
                  q_verify=q_ver, q_decode=n_dec)

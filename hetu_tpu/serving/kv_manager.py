@@ -1,22 +1,17 @@
-"""KV-cache management for continuous batching: the slot-contiguous
-reference layout and the block-table paged layout.
+"""KV-cache management for continuous batching: the block-table paged
+layout.
 
-``KVCacheManager`` is the original design: one preallocated
-``[L, B_slots, S_max, H, Dh]`` cache pair, one contiguous row per
-admitted sequence — every sequence pays for ``S_max`` positions no
-matter how short it is, and identical system prompts are stored once
-PER SLOT.  It remains the off-TPU reference (and the layout offline
-``generate_fast`` uses).
-
-``PagedKVManager`` is the production layout: a fixed pool of
+``PagedKVManager`` is the engine's one layout: a fixed pool of
 ``[L, N_blocks, block, W]`` KV blocks (one lane-dense row a position,
 ``kv_layout``) with a free list, a
 per-request BLOCK TABLE mapping sequence positions to pool blocks, and
 refcounted copy-on-write prefix sharing keyed by a prompt-prefix hash —
 N requests with the same system prompt reference its KV blocks once.
-Concurrent sequences per HBM byte become a function of *actual* tokens
+Concurrent sequences per HBM byte are a function of *actual* tokens
 held (prompt + generation, shared prefixes amortized) instead of the
-worst-case ``S_max``, which is the number that caps serving occupancy.
+worst-case ``S_max`` a slot-contiguous cache pays (offline
+``generate_fast``'s layout), which is the number that caps serving
+occupancy.
 
 Shapes are BUCKETED to powers of two (``B_slots`` and ``S_max``
 independently) so engines configured for nearby workloads land on the
@@ -215,199 +210,22 @@ def _wire_to_pool(wire, wire_quant, pool_cache):
     return vals.astype(pool_cache.dtype)
 
 
-def resolve_kv_block(paged=None, block=None):
-    """KV-layout selection: returns the block size in tokens (0 =
-    slot-contiguous layout).  An explicit ``block`` wins; else
-    ``$HETU_KV_BLOCK`` ("0" pins contiguous, an integer enables paging
-    at that block size, "auto" = paged with block 16, on every
-    backend).  ``paged=True`` forces paging (default block 16),
-    ``paged=False`` forces contiguous."""
-    if paged is False:
-        return 0
+def resolve_kv_block(block=None):
+    """The paged pool's block size in tokens.  An explicit ``block``
+    wins; else ``$HETU_KV_BLOCK`` (an integer, or "auto" = 16, on every
+    backend).  A block of 0 or less once selected the slot-contiguous
+    layout, which is gone: it is refused."""
     if block is None:
         raw = str(envvars.get_str("HETU_KV_BLOCK") or "auto").strip().lower()
         block = 16 if raw in ("auto", "") else int(raw)
     block = int(block)
-    if paged and block <= 0:
-        block = 16
-    return max(block, 0)
-
-
-class KVCacheManager:
-    """Free-slot allocator over one preallocated cache pair.
-
-    layers/heads/head_dim: model shape; slots: requested concurrent
-    sequences (bucketed up to a power of two); max_seq_len: longest
-    prompt+generation to admit (bucketed, then capped at ``pos_cap`` —
-    the model's max_position_embeddings, since the position table can't
-    index past it); dtype: cache dtype — follow the weights (the engine
-    passes its param dtype, so bf16 params mean a bf16 cache), or
-    "int8"/jnp.int8 for the QUANTIZED layout: an int8 payload with one
-    f32 scale per (layer, slot, position, head), ~3.7x more tokens per
-    HBM byte, dequantized inside the decode kernels.  Memory:
-    L*B*S*H*Dh * itemsize * 2 (+ the scale planes when quantized).
-    """
-
-    def __init__(self, *, layers, heads, head_dim, slots, max_seq_len,
-                 pos_cap=None, dtype=jnp.float32, bucket=True):
-        if bucket:
-            slots = round_up_pow2(slots)
-            s = round_up_pow2(max_seq_len, floor=16)
-        else:
-            s = int(max_seq_len)
-        if pos_cap is not None:
-            s = min(s, int(pos_cap))
-        if s < max_seq_len:
-            raise ValueError(
-                f"max_seq_len={max_seq_len} exceeds the position-table "
-                f"cap {pos_cap}")
-        self.n_slots = int(slots)
-        self.s_max = int(s)
-        self.pos_cap = int(pos_cap) if pos_cap is not None else self.s_max
-        self.quant = "int8" if _is_int8(dtype) else None
-        shape = (layers, self.n_slots, self.s_max, heads, head_dim)
-        self.cache_k = _alloc_cache(shape, dtype, self.quant)
-        self.cache_v = _alloc_cache(shape, dtype, self.quant)
-        self._free = list(range(self.n_slots))
-        self.lengths = np.zeros(self.n_slots, np.int32)
-        self.owner = [None] * self.n_slots
-        self.total_allocs = 0
-
-    @property
-    def free_slots(self):
-        return len(self._free)
-
-    @property
-    def occupancy(self):
-        return 1.0 - len(self._free) / self.n_slots
-
-    @property
-    def cache_bytes(self):
-        """Total HBM bytes of the cache pair (scales included when
-        quantized) — the equal-bytes denominator every capacity A/B
-        uses."""
-        return cache_nbytes(self.cache_k) + cache_nbytes(self.cache_v)
-
-    def live(self):
-        """Slot indices currently holding a sequence (ascending)."""
-        return [i for i in range(self.n_slots) if self.owner[i] is not None]
-
-    def _gauges(self):
-        telemetry.set_gauge("serve.occupancy", round(self.occupancy, 4))
-
-    def bucket_prompt(self, p):
-        """Prompt-length bucket for the prefill scan: pow2, floor 8,
-        capped at S_max AND the position-table cap — a handful of
-        prefill compiles serves every prompt length, and the bucket can
-        never index past the wpe table (regression: the pow2 round-up
-        used to consult only s_max, which is safe solely because s_max
-        itself is capped — the explicit clamp pins the contract)."""
-        return _bucket_prompt(p, self.s_max, self.pos_cap)
-
-    def alloc(self, owner, length):
-        """Claim a free slot for ``owner`` whose prompt fills ``length``
-        positions; returns the slot index or None when full."""
-        if length > self.s_max:
-            raise ValueError(
-                f"sequence length {length} exceeds S_max {self.s_max}")
-        if not self._free:
-            return None
-        slot = self._free.pop()
-        self.owner[slot] = owner
-        self.lengths[slot] = length
-        self.total_allocs += 1
-        self._gauges()
-        return slot
-
-    def advance(self, slot, n=1):
-        """Record ``n`` more filled positions in ``slot``."""
-        self.lengths[slot] += n
-
-    def truncate(self, slot, n):
-        """Roll ``slot`` back to ``n`` filled positions (speculative-
-        decode rejection rollback).  Contiguous rows need only the
-        length decrement: positions at or past ``n`` are never admitted
-        by the per-slot attention masks and are overwritten in place by
-        the next writes at those positions — and a quantized cache's
-        scale planes share the position axis, so they truncate in
-        lockstep by the same argument."""
-        n = int(n)
-        if self.owner[slot] is None:
-            raise ValueError(f"slot {slot} is free")
-        if not 0 <= n <= int(self.lengths[slot]):
-            raise ValueError(
-                f"cannot truncate slot {slot} to {n} "
-                f"(filled {int(self.lengths[slot])})")
-        self.lengths[slot] = n
-
-    def release(self, slot):
-        """Return a retired sequence's slot to the free list (its cache
-        rows are left as-is — recycled content is masked/overwritten)."""
-        if self.owner[slot] is None:
-            raise ValueError(f"slot {slot} is already free")
-        self.owner[slot] = None
-        self.lengths[slot] = 0
-        self._free.append(slot)
-        self._gauges()
-
-    # ------------------------------------------------------------- #
-    # replica-to-replica handoff (span export — paged parity)
-    # ------------------------------------------------------------- #
-
-    def export_blocks(self, slot, quant_mode=None):
-        """Serialize ``slot``'s filled KV span to a host-side payload
-        (the contiguous parity of ``PagedKVManager.export_blocks``:
-        one dense ``[L, length, H, Dh]`` span per cache instead of a
-        block list).  Refcounts don't exist in this layout, so export
-        is a pure read.  See the paged docstring for the wire grammar."""
-        if self.owner[slot] is None:
-            raise ValueError(f"slot {slot} is free")
-        length = int(self.lengths[slot])
-        mode = resolve_handoff_quant(quant_mode)
-
-        def gather(cache):
-            if isinstance(cache, (tuple, list)):
-                return tuple(np.asarray(a[:, slot, :length]) for a in cache)
-            return np.asarray(cache[:, slot, :length])
-
-        k, kq = _wire_repr(gather(self.cache_k), self.quant, mode)
-        v, _ = _wire_repr(gather(self.cache_v), self.quant, mode)
-        nbytes = cache_nbytes(k) + cache_nbytes(v)
-        shape = (k[0] if isinstance(k, tuple) else k).shape
-        raw = 2 * 4 * int(np.prod(shape))        # f32-equivalent bytes
-        return {"layout": "contiguous", "length": length,
-                "quant": kq, "k": k, "v": v,
-                "nbytes": nbytes, "raw_nbytes": raw}
-
-    def import_blocks(self, payload, owner, *, reserve=None):
-        """Materialize an exported contiguous span into a fresh slot
-        (dequantizing/requantizing the wire into this pool's layout as
-        needed).  Returns the slot, or None when slots are short."""
-        if payload.get("layout") != "contiguous":
-            raise ValueError(
-                f"cannot import a {payload.get('layout')!r} payload "
-                "into a contiguous manager")
-        length = int(payload["length"])
-        reserve = length if reserve is None else int(reserve)
-        if reserve < length:
-            raise ValueError(
-                f"reserve {reserve} below payload length {length}")
-        slot = self.alloc(owner, reserve)
-        if slot is None:
-            return None
-        self.lengths[slot] = length
-        wq = payload["quant"]
-        for name in ("cache_k", "cache_v"):
-            cache = getattr(self, name)
-            vals = _wire_to_pool(payload["k" if name == "cache_k" else "v"],
-                                 wq, cache)
-            if isinstance(cache, (tuple, list)):
-                cache = (cache[0].at[:, slot, :length].set(vals[0]),
-                         cache[1].at[:, slot, :length].set(vals[1]))
-            else:
-                cache = cache.at[:, slot, :length].set(vals)
-            setattr(self, name, cache)
-        return slot
+    if block <= 0:
+        raise ValueError(
+            f"kv_block / $HETU_KV_BLOCK must be a positive block size in "
+            f"tokens (or \"auto\"), got {block}: 0 selected the "
+            f"slot-contiguous layout, and the contiguous layout is gone "
+            f"(the paged pool is the one KV layout)")
+    return block
 
 
 class _PrefixEntry:
@@ -744,8 +562,8 @@ class PagedKVManager:
         return -(-int(tokens) // self.block) if self.pool_layers else 0
 
     def bucket_prompt(self, p):
-        """Same contract as ``KVCacheManager.bucket_prompt`` (pos_cap
-        clamp included)."""
+        """The prompt-length bucket (``_bucket_prompt``: pos_cap clamp
+        included)."""
         return _bucket_prompt(p, self.s_max, self.pos_cap)
 
     def _gauges(self):

@@ -19,9 +19,28 @@ if "xla_force_host_platform_device_count" not in flags:
 # instead of an XLA stack dump.  Explicit HETU_VALIDATE=0 still wins.
 os.environ.setdefault("HETU_VALIDATE", "1")
 
+# the persistent compilation cache stays OFF for the life of every test
+# process: a worker that an example's ``main()`` had switched it on for
+# died in ``compilation_cache.put_executable_and_time`` several compiles
+# later (ROADMAP C8 (a); the log does not say why the call faults)
+os.environ.pop("JAX_COMPILATION_CACHE_DIR", None)
+
 import jax  # noqa: E402
+import pytest  # noqa: E402
 
 jax.config.update("jax_platforms", "cpu")
+
+
+@pytest.fixture(autouse=True)
+def no_persistent_compile_cache(monkeypatch):
+    """``compile_cache.enable_compile_cache`` does nothing under pytest,
+    whoever calls it (every ``examples/*`` ``main()`` does).  The value
+    is the real function, for the test of where it puts the cache."""
+    from hetu_tpu import compile_cache
+    real = compile_cache.enable_compile_cache
+    monkeypatch.setattr(compile_cache, "enable_compile_cache",
+                        lambda: None)
+    return real
 
 
 def pytest_configure(config):

@@ -86,7 +86,7 @@ def _mk_router(p, cfg, *, replicas=2, slo_ms=None, **rkw):
         slo = ([SLO("ttft", "latency", slo_ms)]
                if slo_ms is not None else None)
         return ServingEngine(p, cfg, slots=4, queue_limit=8,
-                             max_seq_len=32, paged=True, kv_block=4,
+                             max_seq_len=32, kv_block=4,
                              prefix_share=True, slo=slo)
 
     rkw.setdefault("shed_on_slo", False)

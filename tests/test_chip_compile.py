@@ -20,10 +20,9 @@ import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
-from hetu_tpu.kernels import decode_attention as da
 from hetu_tpu.kernels import flash_attention as fa
 from hetu_tpu.kernels.ragged_attention import (
-    ragged_attention, ragged_paged_attention, ragged_paged_mla)
+    ragged_paged_attention, ragged_paged_mla)
 from hetu_tpu.kv_layout import kv_row_width
 from hetu_tpu.models import gpt_decode as gd
 
@@ -244,15 +243,6 @@ def test_mixed_step_reads_the_donated_pool_in_place(sds, monkeypatch,
     assert mem.temp_size_in_bytes < pool_bytes // 2
 
 
-def test_ragged_contiguous_whole_prompt(sds):
-    kv = sds((SLOTS, S_MAX, 12, DH), jnp.bfloat16)
-    lens = sds((SLOTS,), jnp.int32)
-    compiled_text(
-        lambda q, k, v, n, ql: ragged_attention(q, k, v, n, ql,
-                                                interpret=False),
-        sds((SLOTS, 1024, 12, DH), jnp.bfloat16), kv, kv, lens, lens)
-
-
 @pytest.mark.parametrize("window", [1, 5], ids=["W1", "W5-spec_k4"])
 def test_mixed_wave_tail(sds, window):
     """What follows the last block of a chunk wave of the gpt2-xl cell
@@ -277,28 +267,6 @@ def test_mixed_wave_tail(sds, window):
     assert f"[{B},{Q},{V}]" not in text
     assert f"f32[{B},{window},{V}]" in text or f"f32[{B},{V}]" in text
     assert compiled.memory_analysis().temp_size_in_bytes < B * Q * V * 4 // 8
-
-
-# ------------------------------------------------------------------- #
-# the contiguous decode and verify kernels (``_decode_step`` and
-# ``_verify_step`` with attn="ragged"; no cell runs them)
-# ------------------------------------------------------------------- #
-
-@pytest.mark.parametrize("kernel", [
-    "paged_decode_attention", "paged_verify_attention"])
-def test_phase_split(sds, kernel):
-    """One query per slot for decode, a k+1 = 8-position q-block for
-    spec-verify, over a contiguous cache."""
-    H = 12
-    lens = sds((SLOTS,), jnp.int32)
-    verify = "verify" in kernel
-    q = sds((SLOTS, 8, H, DH) if verify else (SLOTS, H, DH), jnp.bfloat16)
-    c = sds((SLOTS, S_MAX, H, DH), jnp.bfloat16)
-    extra = (lens,) if verify else ()
-    fn = getattr(da, kernel)
-    compiled_text(lambda *a: fn(*a, interpret=False),
-                  q, c, c, lens, *extra)
-
 
 
 @pytest.mark.parametrize("q_block", [1, 64, 256])
@@ -610,32 +578,6 @@ def test_packed_chunk_wave_at_the_cells_sizes(sds, monkeypatch, cell):
     assert mem.temp_size_in_bytes <= mem0.temp_size_in_bytes
 
 
-# sha256[:16] of the same programs as tests/test_hybrid_moe.py's
-# PARENT_MASKED, lowered for the described chip with the Pallas kernels
-# (``attn="ragged"``, not interpreted), as the PARENT of PR 34 lowered
-# them, each Mosaic kernel's serialized body replaced by its assembly
-# WITHOUT locations (the bytecode carries file names and line numbers,
-# which move whenever a line is added above a kernel).
-PARENT_RAGGED = {
-    "gpt2.Q1.fresh0": "5919310cf2517705", "gpt2.Q1.fresh1": "5919310cf2517705",
-    "gpt2.Q32.fresh0": "a7c64ccb01632823",
-    "gpt2.Q32.fresh1": "a7c64ccb01632823",
-    "latent.Q1.fresh0": "3e2ddf60b91b860d",
-    "latent.Q1.fresh1": "3e2ddf60b91b860d",
-    "latent.Q32.fresh0": "eef22714386f596c",
-    "latent.Q32.fresh1": "eef22714386f596c"}
-# (the latent Q 32 pair is PR 43's: a q-tile of 32 queries is taller than
-# the latent kernel's short height (8 queries), so the kernel of a chunk
-# program holds its step at two heights, ``ragged_attention.
-# tile_heights``; the parent of PR 43 lowered it to c873bfc54690610f.
-# The GPT-2 programs are the parent's at every Q: one query head a K/V
-# head stacks too few rows for a second height, ``_SHORT_MIN_ROWS``.
-# The Q 1 programs have one height and are the parent's text.  Before
-# that the latent Q 32 pair was PR 41's: its routed products are the
-# ``moe_grouped_matmul`` kernel there, tests/test_hybrid_moe.py says why;
-# the parent of PR 41 lowered them to 9be25ac3fb1f17f1)
-
-
 def strip_kernel_locations(text):
     """StableHLO text with every Mosaic kernel's base64 bytecode replaced
     by its location-free assembly."""
@@ -654,23 +596,6 @@ def strip_kernel_locations(text):
             return '\\22body\\22: \\22' + module.operation.get_asm(
                 enable_debug_info=False) + '\\22'
     return re.sub(r'\\22body\\22: \\22([A-Za-z0-9+/=]+)\\22', body, text)
-
-
-def test_gpt2_and_latent_kernel_waves_lower_to_the_parents(sds, monkeypatch):
-    """The two accepted serving cells' programs, kernels included, are
-    the parent's operation for operation (ISSUE 34: ``groups`` 1 is the
-    code path there was)."""
-    from test_hybrid_moe import digest, wave_programs
-    from hetu_tpu.kernels import grouped_matmul as gm
-    from hetu_tpu.kernels import ragged_attention as ra
-    monkeypatch.setattr(ra, "_use_interpret", lambda: False)
-    monkeypatch.setattr(gm, "_use_interpret", lambda: False)
-    got = {}
-    for name, lowered in wave_programs(sds, "ragged").items():
-        text = lowered.as_text()
-        assert "tpu_custom_call" in text
-        got[name] = digest(strip_kernel_locations(text))
-    assert got == PARENT_RAGGED
 
 
 # ------------------------------------------------------------------- #

@@ -113,20 +113,22 @@ def test_pallas_kernels_reads_the_lowered_text():
 
 
 def test_compile_cache_is_placed_from_outside_or_in_the_checkout(
-        monkeypatch, tmp_path):
+        monkeypatch, tmp_path, no_persistent_compile_cache):
     """``JAX_COMPILATION_CACHE_DIR`` set: nothing is set in code.  Unset:
-    ``<checkout>/.jax_cache`` — never /tmp, a pid or a timestamp."""
+    ``<checkout>/.jax_cache`` — never /tmp, a pid or a timestamp.  (The
+    real function: under pytest the module's name is a no-op,
+    tests/conftest.py.)"""
     import os
-    from hetu_tpu import compile_cache
+    enable_compile_cache = no_persistent_compile_cache
     old = jax.config.jax_compilation_cache_dir
     try:
         monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
-        assert compile_cache.enable_compile_cache() == str(tmp_path)
+        assert enable_compile_cache() == str(tmp_path)
         assert jax.config.jax_compilation_cache_dir == old   # untouched
         monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
         want = os.path.join(os.path.dirname(os.path.abspath(cs.__file__)),
                             ".jax_cache")
-        assert compile_cache.enable_compile_cache() == want
+        assert enable_compile_cache() == want
         assert jax.config.jax_compilation_cache_dir == want
     finally:
         jax.config.update("jax_compilation_cache_dir", old)

@@ -493,10 +493,10 @@ def gpt_model():
 class TestGPTByteIdentity:
     @pytest.mark.parametrize("kw", [
         dict(),
-        dict(paged=True, kv_block=4),
+        dict(kv_block=4),
         dict(kv_quant="int8"),
         dict(spec=3, spec_adapt=False, spec_draft_layers=1),
-    ], ids=["contiguous", "paged", "int8", "spec"])
+    ], ids=["default", "paged", "int8", "spec"])
     def test_router_matches_offline(self, gpt_model, kw):
         """Every token the refactored substrate serves equals offline
         ``generate_fast`` — per config, through the fleet router."""

@@ -44,6 +44,18 @@ def test_gcn_example_generalizes_through_graph():
     assert acc > 0.9         # held-out nodes classified via propagation
 
 
+def test_an_examples_main_leaves_the_persistent_cache_off():
+    """Every example's ``main()`` asks for the persistent compilation
+    cache; under pytest it stays off for the worker's whole life
+    (tests/conftest.py, ROADMAP C8 (a))."""
+    import jax
+    assert not jax.config.jax_compilation_cache_dir
+    mod = _load("gnn/train_gcn.py", "ex_gcn_cache")
+    _run_main(mod, ["--nodes", "32", "--epochs", "1"])
+    assert not jax.config.jax_compilation_cache_dir
+    assert "JAX_COMPILATION_CACHE_DIR" not in os.environ
+
+
 def test_gcn_hybrid_example_learns_embeddings_on_ps():
     """run_dist_hybrid.py role: PS-served node embeddings + 1.5-D mesh
     compute; structure is the only signal, so held-out accuracy above

@@ -34,8 +34,8 @@ from hetu_tpu.models import GPTConfig
 from hetu_tpu.models.gpt_decode import generate_fast
 from hetu_tpu.ps import faults
 from hetu_tpu.serving import (
-    KVCacheManager, PagedKVManager, PrefixDirectory, Request,
-    ServingEngine, ServingRouter, prefix_hash, resolve_handoff_quant,
+    PagedKVManager, PrefixDirectory, Request, ServingEngine, ServingRouter,
+    prefix_hash, resolve_handoff_quant,
 )
 from hetu_tpu.telemetry import top
 from hetu_tpu.telemetry.trace import (
@@ -93,7 +93,6 @@ def _factory(model, **kw):
     kw.setdefault("slots", 2)
     kw.setdefault("queue_limit", 16)
     kw.setdefault("fast_path", False)
-    kw.setdefault("paged", True)
     kw.setdefault("kv_block", 8)
     kw.setdefault("prefix_share", True)
     return lambda i: ServingEngine(p, cfg, **kw)
@@ -284,31 +283,6 @@ class TestHandoffWire:
             _mgr().import_blocks(pay, "r", reserve=4)     # below length
         with pytest.raises(ValueError):
             _mgr().import_blocks(dict(pay, layout="contiguous"), "r")
-
-    def test_contiguous_manager_parity(self):
-        """The slot-contiguous manager has span export parity: the
-        same payload contract, both wire modes."""
-        src = KVCacheManager(layers=2, heads=2, head_dim=8, slots=2,
-                             max_seq_len=32)
-        dst = KVCacheManager(layers=2, heads=2, head_dim=8, slots=2,
-                             max_seq_len=32)
-        rng = np.random.RandomState(7)
-        src.cache_k = jnp.asarray(
-            rng.randn(*src.cache_k.shape).astype(np.float32))
-        src.cache_v = jnp.asarray(
-            rng.randn(*src.cache_v.shape).astype(np.float32))
-        slot = src.alloc("r", 11)
-        src.lengths[slot] = 11
-        pay = src.export_blocks(slot)
-        assert pay["layout"] == "contiguous" and pay["length"] == 11
-        slot2 = dst.import_blocks(pay, "r")
-        assert np.array_equal(np.asarray(src.cache_k)[:, slot, :11],
-                              np.asarray(dst.cache_k)[:, slot2, :11])
-        pay8 = src.export_blocks(slot, quant_mode="int8")
-        assert pay8["quant"] == "int8"
-        assert pay8["nbytes"] < pay["nbytes"]
-        with pytest.raises(ValueError):
-            _mgr().import_blocks(pay, "r")                # layout mismatch
 
 
 # --------------------------------------------------------------------- #

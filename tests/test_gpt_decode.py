@@ -19,6 +19,26 @@ def trained():
     return _trained_model()
 
 
+# ``python -c _HF_HALF out.npz <prompt tokens>``: a random GPT-2's
+# ``state_dict`` and its greedy continuation of the prompt, as numpy
+_HF_HALF = """
+import sys
+import numpy as np
+import torch
+from transformers import GPT2Config, GPT2LMHeadModel
+torch.manual_seed(3)
+hf = GPT2LMHeadModel(GPT2Config(
+    vocab_size=97, n_embd=32, n_layer=2, n_head=2, n_positions=24,
+    resid_pdrop=0.0, embd_pdrop=0.0, attn_pdrop=0.0)).eval()
+with torch.no_grad():
+    generated = hf.generate(
+        torch.tensor([[int(t) for t in sys.argv[2:]]]), max_new_tokens=10,
+        do_sample=False, pad_token_id=0)
+np.savez(sys.argv[1], generated=generated.numpy(),
+         **{k: v.detach().numpy() for k, v in hf.state_dict().items()})
+"""
+
+
 def _trained_model():
     cfg = GPTConfig(vocab_size=61, hidden_size=32, num_hidden_layers=2,
                     num_attention_heads=2, max_position_embeddings=16,
@@ -55,29 +75,30 @@ class TestFastDecode:
         # the trained arithmetic chain actually decoded
         assert slow == list(range(7, 18))
 
-    def test_matches_hf_generate_on_imported_weights(self):
-        torch = pytest.importorskip("torch")
-        transformers = pytest.importorskip("transformers")
-        from transformers import GPT2Config as HFC
-        from transformers import GPT2LMHeadModel
-        hf_cfg = HFC(vocab_size=97, n_embd=32, n_layer=2, n_head=2,
-                     n_positions=24, resid_pdrop=0.0, embd_pdrop=0.0,
-                     attn_pdrop=0.0)
-        torch.manual_seed(3)
-        hf = GPT2LMHeadModel(hf_cfg).eval()
+    def test_matches_hf_generate_on_imported_weights(self, tmp_path):
+        """torch's half (the HF model, its weights, ``hf.generate``'s
+        tokens) runs in a CHILD process: a worker that had imported
+        torch died at its next JAX compile (ROADMAP C8 (a))."""
+        import importlib.util
+        import subprocess
+        import sys
+        for mod in ("torch", "transformers"):
+            if importlib.util.find_spec(mod) is None:
+                pytest.skip(f"could not import {mod!r}")
+        prompt = [5, 11, 17]
+        out = tmp_path / "hf.npz"
+        subprocess.run([sys.executable, "-c", _HF_HALF, str(out),
+                        *map(str, prompt)], check=True, timeout=300)
+        hf = np.load(out)
         cfg = GPTConfig(vocab_size=97, hidden_size=32,
                         num_hidden_layers=2, num_attention_heads=2,
                         max_position_embeddings=24, batch_size=1,
                         seq_len=24, dropout_rate=0.0)
-        params = ht.hf.convert_gpt2(hf.state_dict(),
-                                    prefix="transformer.")
-        prompt = [5, 11, 17]
+        params = ht.hf.convert_gpt2(
+            {k: hf[k] for k in hf.files if k != "generated"},
+            prefix="transformer.")
         ours = generate_fast(params, cfg, prompt, num_tokens=10)
-        with torch.no_grad():
-            want = hf.generate(torch.tensor([prompt]),
-                               max_new_tokens=10, do_sample=False,
-                               pad_token_id=0)
-        assert ours[0].tolist() == want[0].tolist()
+        assert ours[0].tolist() == hf["generated"][0].tolist()
 
     def test_sampling_contract(self, trained):
         cfg, ex, _ = trained

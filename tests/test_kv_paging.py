@@ -1,14 +1,14 @@
 """Block-table paged KV cache: the block-pool allocator (free list,
 refcounts, copy-on-write prefix sharing, LRU eviction), chunked
-prefill, and the paged engine's end-to-end greedy parity with the
-contiguous reference — each contract pinned separately (the kernel over
+prefill, and the paged engine's end-to-end greedy parity with offline
+``generate_fast`` — each contract pinned separately (the kernel over
 a permuted pool is ``tests/test_ragged_kernel.py``'s).
 
 The load-bearing claims:
 - allocator: blocks free only at refcount zero; a shared prefix is
   stored ONCE; a mid-block shared tail is COW-forked; exhaustion is
   backpressure (requeue/QueueFull), never corruption;
-- engine: greedy outputs are token-identical across contiguous vs paged,
+- engine: greedy outputs are token-identical across block sizes,
   shared vs unshared prefix, chunked vs whole prefill, fast vs masked —
   and to offline ``generate_fast``.
 
@@ -26,8 +26,7 @@ from hetu_tpu import telemetry
 from hetu_tpu.models import GPTConfig
 from hetu_tpu.models.gpt_decode import generate_fast
 from hetu_tpu.serving import (
-    KVCacheManager, PagedKVManager, QueueFull, Request, ServingEngine,
-    resolve_kv_block,
+    PagedKVManager, QueueFull, Request, ServingEngine, resolve_kv_block,
 )
 
 
@@ -336,31 +335,52 @@ class TestBucketPromptPosCap:
     def test_bucket_clamped_to_pos_cap(self):
         """Regression: pow2 bucketing must never pad a prompt past the
         position-table cap when s_max was capped to a non-pow2 size."""
-        m = KVCacheManager(layers=1, heads=1, head_dim=4, slots=2,
-                           max_seq_len=20, pos_cap=24)
-        assert m.s_max == 24                  # capped, non-pow2
-        assert m.bucket_prompt(17) <= 24      # pow2 round-up alone -> 32
-        assert m.bucket_prompt(3) == 8
         pm = PagedKVManager(layers=1, heads=1, head_dim=4, slots=2,
                             max_seq_len=20, pos_cap=24, block=8)
-        assert pm.bucket_prompt(17) <= 24
+        assert pm.s_max == 24                 # capped, non-pow2
+        assert pm.bucket_prompt(17) <= 24     # pow2 round-up alone -> 32
         assert pm.bucket_prompt(23) <= 24
+        assert pm.bucket_prompt(3) == 8
 
-    def test_resolve_kv_block(self, monkeypatch):
-        assert resolve_kv_block(False) == 0
-        assert resolve_kv_block(True) > 0
-        assert resolve_kv_block(None, 8) == 8
-        monkeypatch.setenv("HETU_KV_BLOCK", "32")
-        assert resolve_kv_block(None) == 32
-        monkeypatch.setenv("HETU_KV_BLOCK", "0")
-        assert resolve_kv_block(None) == 0
-        assert resolve_kv_block(True) == 16   # paged forced: 0 invalid
-        for auto in ("auto", ""):
-            monkeypatch.setenv("HETU_KV_BLOCK", auto)
-            assert resolve_kv_block(None) == 16   # on every backend
-        monkeypatch.delenv("HETU_KV_BLOCK")
-        assert resolve_kv_block() == 16
-        assert resolve_kv_block(False) == 0
+
+# what replaced the option that selected the KV layout (ISSUE 47): the
+# paged pool is the one layout, stated by the class and chosen by
+# nothing.  name: ($HETU_KV_BLOCK, the engine's arguments, the block it
+# then has or the error and what its message names)
+_GONE = (ValueError, "HETU_KV_BLOCK.*contiguous layout is gone")
+LAYOUT_CASES = {
+    "the-old-argument": (None, {"paged": False}, (TypeError, "paged")),
+    "block=0": (None, dict(kv_block=0), _GONE),
+    "block=-4": (None, dict(kv_block=-4), _GONE),
+    "env=0": ("0", {}, _GONE),
+    "env=-16": ("-16", {}, _GONE),
+    "env-unset": (None, {}, 16),
+    "env=auto": ("auto", {}, 16),
+    "env-empty": ("", {}, 16),
+    "env=32": ("32", {}, 32),
+    "block-wins": ("32", dict(kv_block=8), 8),
+}
+
+
+@pytest.mark.smoke
+@pytest.mark.parametrize("case", list(LAYOUT_CASES))
+def test_the_kv_layout_is_not_an_option(model, monkeypatch, case):
+    env, kw, want = LAYOUT_CASES[case]
+    monkeypatch.delenv("HETU_KV_BLOCK", raising=False)
+    if env is not None:
+        monkeypatch.setenv("HETU_KV_BLOCK", env)
+    # what the benchmark's runners print and its test asserts
+    assert ServingEngine.paged is True and ServingEngine.ragged is True
+    if isinstance(want, int):
+        assert resolve_kv_block(kw.get("kv_block")) == want
+        assert ServingEngine(*model, **kw).kv.block == want
+        return
+    error, names = want
+    with pytest.raises(error, match=names):
+        ServingEngine(*model, **kw)
+    if error is ValueError:
+        with pytest.raises(error, match=names):
+            resolve_kv_block(kw.get("kv_block"))
 
 
 TRACE = [([7, 8, 9], 6), ([3, 4], 11), ([1, 2, 3, 4, 5], 4),
@@ -378,22 +398,20 @@ def _run(p, cfg, trace, **kw):
 @pytest.mark.smoke
 class TestPagedEngineParity:
     def test_greedy_identical_to_contiguous_and_offline(self, model):
-        """Acceptance: mixed-length greedy trace, paged == contiguous ==
-        offline, token for token — across block sizes, slot counts, and
-        both attention paths."""
+        """Acceptance: mixed-length greedy trace, paged == offline
+        (``generate_fast``'s contiguous cache), token for token — across
+        block sizes, slot counts, and both attention paths."""
         p, cfg = model
-        _, ref = _run(p, cfg, TRACE, slots=4, paged=False)
+        ref = {tuple(pr): generate_fast(p, cfg, [pr], num_tokens=n,
+                                        prefill="scan")[0].tolist()
+               for pr, n in TRACE}
         for kw in (dict(kv_block=16), dict(kv_block=8),
                    dict(kv_block=8, slots=2),
                    dict(kv_block=8, fast_path=True),
                    dict(kv_block=8, fast_path=False)):
             eng, got = _run(p, cfg, TRACE, slots=kw.pop("slots", 4),
-                            paged=True, **kw)
-            assert eng.paged and got == ref, kw
-        for pr, n in TRACE:
-            want = generate_fast(p, cfg, [pr], num_tokens=n,
-                                 prefill="scan")[0]
-            assert ref[tuple(pr)] == want.tolist()
+                            **kw)
+            assert got == ref, kw
 
     def test_shared_vs_unshared_prefix_identical(self, model):
         """Prefix sharing is a MEMORY optimization: greedy outputs are
@@ -403,10 +421,10 @@ class TestPagedEngineParity:
         sysp = list(np.arange(1, 18) % 60)        # 17 tokens: straddle
         trace = [(sysp + [30 + i], 6) for i in range(4)]
         trace.append((sysp + [30, 31, 32], 5))    # extends a full prompt
-        eng_s, shared = _run(p, cfg, trace, slots=4, paged=True,
-                             kv_block=8, prefix_share=True)
-        eng_u, unshared = _run(p, cfg, trace, slots=4, paged=True,
-                               kv_block=8, prefix_share=False)
+        eng_s, shared = _run(p, cfg, trace, slots=4, kv_block=8,
+                             prefix_share=True)
+        eng_u, unshared = _run(p, cfg, trace, slots=4, kv_block=8,
+                               prefix_share=False)
         assert shared == unshared
         st = eng_s.kv.stats()
         assert st["prefix_hits"] >= 3, st
@@ -417,11 +435,11 @@ class TestPagedEngineParity:
         p, cfg = model
         trace = [(list(range(1, 20)), 5), ([3, 4], 6),
                  (list(range(5, 29)), 4)]
-        _, whole = _run(p, cfg, trace, slots=4, paged=True, kv_block=8,
+        _, whole = _run(p, cfg, trace, slots=4, kv_block=8,
                         prefill_chunk=0)
         for chunk in (4, 8, 16):
-            eng, got = _run(p, cfg, trace, slots=4, paged=True,
-                            kv_block=8, prefill_chunk=chunk)
+            eng, got = _run(p, cfg, trace, slots=4, kv_block=8,
+                            prefill_chunk=chunk)
             assert got == whole, chunk
             assert eng.prefill_chunks >= sum(
                 -(-len(pr) // chunk) for pr, _ in trace) - 1
@@ -432,9 +450,8 @@ class TestPagedEngineParity:
         straggler's prompt is still being written."""
         p, cfg = model
         long_prompt = list(range(1, 25))          # 24 tokens, chunk 4
-        eng = ServingEngine(p, cfg, slots=4, queue_limit=16, paged=True,
-                            kv_block=8, prefill_chunk=4,
-                            prefix_share=False)
+        eng = ServingEngine(p, cfg, slots=4, queue_limit=16, kv_block=8,
+                            prefill_chunk=4, prefix_share=False)
         short = Request(prompt=[7, 8], max_new_tokens=8)
         eng.submit(short)
         eng.step()                                # short's prompt flies
@@ -454,31 +471,32 @@ class TestPagedEngineParity:
 
     def test_bf16_and_sampling_compose(self, model):
         p, cfg = model
-        _, ref = _run(p, cfg, TRACE, slots=4, paged=False,
-                      dtype=jnp.bfloat16)
-        _, got = _run(p, cfg, TRACE, slots=4, paged=True, kv_block=8,
+        ref = {tuple(pr): generate_fast(
+            p, cfg, [pr], num_tokens=n, dtype=jnp.bfloat16)[0].tolist()
+            for pr, n in TRACE}
+        _, got = _run(p, cfg, TRACE, slots=4, kv_block=8,
                       dtype=jnp.bfloat16)
         assert got == ref
         # per-request rng streams survive the paged scheduler: sampled
-        # outputs identical across layouts
-        reqs = lambda: [Request(prompt=[3, 4], max_new_tokens=6,
-                                temperature=0.9, top_k=5, seed=11),
-                        Request(prompt=[7, 8, 9], max_new_tokens=5,
-                                temperature=0.7, top_k=3, seed=22)]
-        a = ServingEngine(p, cfg, slots=2, paged=False).run(reqs())
-        b = ServingEngine(p, cfg, slots=2, paged=True,
-                          kv_block=8).run(reqs())
-        assert sorted(r.tokens.tolist() for r in a.values()) == \
-            sorted(r.tokens.tolist() for r in b.values())
+        # outputs are offline speculation's, which draws the request's
+        # own stream (``PRNGKey(seed)``, one split a generated token)
+        sampled = [([3, 4], 6, 0.9, 5, 11), ([7, 8, 9], 5, 0.7, 3, 22)]
+        b = ServingEngine(p, cfg, slots=2, kv_block=8).run(
+            [Request(prompt=pr, max_new_tokens=n, temperature=t, top_k=k,
+                     seed=seed) for pr, n, t, k, seed in sampled])
+        assert sorted(r.tokens.tolist() for r in b.values()) == sorted(
+            generate_fast(p, cfg, [pr], n, temperature=t, top_k=k,
+                          seed=seed, spec=1)[0].tolist()
+            for pr, n, t, k, seed in sampled)
 
 
 @pytest.mark.smoke
 class TestPoolBackpressure:
     def test_exhaustion_queuefull_then_drain(self, model):
         p, cfg = model
-        eng = ServingEngine(p, cfg, slots=4, queue_limit=2, paged=True,
-                            kv_block=8, pool_blocks=4,
-                            prefix_share=False)       # 3 usable blocks
+        eng = ServingEngine(p, cfg, slots=4, queue_limit=2, kv_block=8,
+                            pool_blocks=4,            # 3 usable blocks
+                            prefix_share=False)
         eng.submit(Request(prompt=list(range(1, 11)), max_new_tokens=12))
         eng.submit(Request(prompt=[5] * 9, max_new_tokens=10))
         with pytest.raises(QueueFull):
@@ -493,20 +511,19 @@ class TestPoolBackpressure:
 
     def test_more_slots_than_contiguous_at_equal_bytes(self, model):
         """The capacity claim, engine-level: at a pool sized to the
-        CONTIGUOUS layout's bytes, the paged engine holds every short
-        request concurrently while contiguous is capped at its slot
-        count."""
+        bytes of a slot-CONTIGUOUS cache of 2 slots, the paged engine
+        holds twice as many short requests concurrently."""
         p, cfg = model
         sysp = list(np.arange(1, 10) % 60)        # 9 shared tokens
         trace = [(sysp + [20 + i], 4) for i in range(8)]
-        # contiguous: 2 slots x S_max=32 tokens = 64 token-slots
-        eng_c, ref = _run(p, cfg, trace, slots=2, paged=False)
+        ref = {tuple(pr): generate_fast(p, cfg, [pr], num_tokens=n)[
+            0].tolist() for pr, n in trace}
+        # contiguous: 2 slots x S_max=32 tokens = 64 token-slots;
         # paged, same bytes: 64 tokens / block 8 = 8 blocks (+ scratch)
-        eng_p, got = _run(p, cfg, trace, slots=16, paged=True,
-                          kv_block=8, pool_blocks=9)
+        eng_p, got = _run(p, cfg, trace, slots=16, kv_block=8,
+                          pool_blocks=9)
         assert got == ref
-        assert eng_c.peak_live <= 2
-        assert eng_p.peak_live >= 2 * eng_c.peak_live
+        assert eng_p.peak_live >= 2 * 2
 
 
 @pytest.mark.smoke
@@ -520,8 +537,8 @@ class TestPagedTelemetry:
         telemetry.get_sink()  # sink re-reads env per emit; just ensure up
         sysp = list(np.arange(1, 18) % 60)
         log = str(tmp_path / "serve.jsonl")
-        eng = ServingEngine(p, cfg, slots=4, queue_limit=16, paged=True,
-                            kv_block=8, prefill_chunk=4, log_path=log)
+        eng = ServingEngine(p, cfg, slots=4, queue_limit=16, kv_block=8,
+                            prefill_chunk=4, log_path=log)
         eng.run([Request(prompt=sysp + [30 + i], max_new_tokens=4)
                  for i in range(3)])
         snap = telemetry.snapshot()

@@ -88,7 +88,6 @@ def _factory(model, **kw):
     kw.setdefault("slots", 2)
     kw.setdefault("queue_limit", 16)
     kw.setdefault("fast_path", False)
-    kw.setdefault("paged", True)
     kw.setdefault("kv_block", 8)
     kw.setdefault("prefix_share", True)
     return lambda i: ServingEngine(p, cfg, **kw)

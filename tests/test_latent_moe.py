@@ -21,7 +21,7 @@ from hetu_tpu.models.gpt import GPTConfig
 from hetu_tpu.models.moe_decode import (
     LatentMoEConfig, RoutedSpec, init_latent_moe_params, route, routed_ffn)
 from hetu_tpu.serving import Request, ServingEngine
-from hetu_tpu.serving.kv_manager import KVCacheManager, PagedKVManager
+from hetu_tpu.serving.kv_manager import PagedKVManager
 
 SMALL = dict(
     vocab_size=257, hidden_size=64, num_hidden_layers=3,
@@ -49,7 +49,7 @@ def params(cfg):
 
 
 def engine(params, cfg, **kw):
-    kw = dict(dict(slots=4, max_seq_len=64, paged=True, kv_block=4,
+    kw = dict(dict(slots=4, max_seq_len=64, kv_block=4,
                    prefill_chunk=8, fast_path=False,
                    prefix_share=False), **kw)
     return ServingEngine(params, cfg, **kw)
@@ -453,10 +453,9 @@ def test_prefix_sharing_and_cow_on_latent_blocks(params, cfg):
 # ------------------------------------------------------------------ #
 
 @pytest.mark.parametrize("kw,names", [
-    (dict(paged=False), "KVCacheManager"),
     (dict(spec=2), "speculation"),
     (dict(kv_quant="int8"), "int8"),
-], ids=["contiguous", "speculation", "int8-kv"])
+], ids=["speculation", "int8-kv"])
 def test_engine_refuses_other_paths(params, cfg, kw, names):
     with pytest.raises(ValueError, match=names):
         engine(params, cfg, **kw)
@@ -479,7 +478,6 @@ def test_latent_pool_refuses_the_wire_and_the_tiers(params, cfg):
                  if isinstance(v, type) and hasattr(v, "attach"))
     with pytest.raises(ValueError, match="latent"):
         store.attach(object.__new__(store), 0, eng.kv)
-    assert not hasattr(KVCacheManager, "latent")   # contiguous: no latent
 
 
 @pytest.mark.parametrize("bad", [

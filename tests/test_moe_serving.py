@@ -5,7 +5,7 @@ The load-bearing contracts:
 
 - **Identity**: an ``MoEDecodeConfig`` model decodes TOKEN-IDENTICALLY
   through ServingEngine and offline ``generate_fast`` across every
-  cache configuration — contiguous (ref + fast), block-table paged,
+  cache configuration — the block-table pool (ref + fast),
   int8-quantized KV, speculative (draft skips routing), ragged mixed
   wave, and chunked prefill.
 - **Dense oracle**: ``top_k == num_experts`` at non-binding capacity
@@ -112,13 +112,11 @@ def _run_engine(params, cfg, **kw):
 
 
 ENGINE_MATRIX = [
-    ("contiguous_ref", dict(fast_path=False, paged=False)),
-    ("contiguous_fast", dict(fast_path=True, paged=False)),
-    ("paged", dict(fast_path=True, paged=16)),
-    ("paged_int8", dict(fast_path=True, paged=16, kv_quant="int8")),
-    ("spec", dict(fast_path=True, paged=False, spec=2)),
-    ("paged_ref", dict(fast_path=False, paged=16)),
-    ("paged_chunked", dict(fast_path=True, paged=16, prefill_chunk=2)),
+    ("paged", dict(fast_path=True)),
+    ("paged_int8", dict(fast_path=True, kv_quant="int8")),
+    ("spec", dict(fast_path=True, spec=2)),
+    ("paged_ref", dict(fast_path=False)),
+    ("paged_chunked", dict(fast_path=True, prefill_chunk=2)),
 ]
 
 
@@ -285,8 +283,7 @@ class TestTraceAttribution:
 
     def test_green_stream_passes_check(self, model, tmp_path):
         from hetu_tpu.telemetry import trace as trace_mod
-        log, recs = self._trace(model, tmp_path, fast_path=True,
-                                paged=16)
+        log, recs = self._trace(model, tmp_path, fast_path=True)
         steps = [r for r in recs if r.get("event") == "serve_step"
                  and "moe_routed" in r]
         assert steps, "serve_step records must carry MoE attribution"
@@ -464,7 +461,7 @@ class TestTelemetryAndTop:
         telemetry.reset()
         log = str(tmp_path / "top.jsonl")
         eng = ServingEngine(params, cfg, slots=4, name="moe",
-                            fast_path=True, paged=16, log_path=log,
+                            fast_path=True, log_path=log,
                             tags={"replica": 0})
         eng.run(_mk())
         snap = telemetry.snapshot()
@@ -590,7 +587,7 @@ class TestSwapAndSpec:
         validates per-expert leaf shapes."""
         params, cfg = model
         eng = ServingEngine(params, cfg, slots=4, name="moe",
-                            fast_path=True, paged=16)
+                            fast_path=True)
         if not hasattr(eng, "swap_params"):
             pytest.skip("engine has no swap_params")
         eng.swap_params({k: np.asarray(v) for k, v in params.items()})

@@ -108,10 +108,10 @@ def test_wave_rows_of_a_chunk_wave(slots, window, q, rows):
 
 
 @pytest.mark.parametrize("kw,cfg_tuple", [
-    ({"has_fresh": False}, GPT), ({"paged": False}, GPT),
+    ({"has_fresh": False}, GPT),
     ({}, GPT + (MoESpec(num_experts=4, top_k=1, capacity_factor=1.0,
                         moe_every=1, draft=False, ep_axis=None),))],
-    ids=["decode-or-verify", "contiguous", "capacity-router"])
+    ids=["decode-or-verify", "capacity-router"])
 def test_wave_rows_of_every_other_wave_is_the_padded_block(kw, cfg_tuple):
     assert gd.wave_rows(cfg_tuple, 32, 1, 256, **kw) == 32 * 256
 
@@ -391,7 +391,7 @@ def test_attention_tile_counters_follow_the_waves_q_lens(gpt, monkeypatch):
     # two heads of 8 are 16 rows a short product, under the row count
     # at which the rule gives a program two heights: lowered here
     monkeypatch.setattr(ra, "_SHORT_MIN_ROWS", 1)
-    eng = ServingEngine(params, cfg, slots=8, paged=True, kv_block=16,
+    eng = ServingEngine(params, cfg, slots=8, kv_block=16,
                         prefill_chunk=64, fast_path=True)
     waves = watch_waves(eng)
     eng.run(requests(61, SMALL_SIZES))
@@ -413,7 +413,7 @@ def test_attention_tile_counters_follow_the_waves_q_lens(gpt, monkeypatch):
     tail = eng.metrics.snapshot(since=mark)
     assert (tail["attn_tiles_live"], tail["attn_tiles_short"]) == tiles_of(
         waves[first:], f32_rows_tile, 8)
-    masked = ServingEngine(params, cfg, slots=8, paged=True, kv_block=16,
+    masked = ServingEngine(params, cfg, slots=8, kv_block=16,
                            prefill_chunk=64, fast_path=False)
     masked.run(requests(61, SMALL_SIZES[:3]))
     snap = masked.metrics.snapshot()
@@ -430,7 +430,7 @@ def test_gpt2_engine_tokens_equal_a_sequential_decode(gpt, fast, spec):
     its verify-only waves not); every request's tokens are what
     ``generate_fast`` gives it alone."""
     params, cfg = gpt
-    eng = ServingEngine(params, cfg, slots=8, paged=True, kv_block=16,
+    eng = ServingEngine(params, cfg, slots=8, kv_block=16,
                         prefill_chunk=64, fast_path=fast, spec=spec)
     assert gd.wave_rows(eng.cfg_tuple, 8, eng.spec_k + 1, 64) == 256
     out = eng.run(requests(61))
@@ -452,7 +452,7 @@ def test_int8_engine_tokens_equal_the_padded_engines(gpt, monkeypatch):
     params, cfg = gpt
 
     def served():
-        eng = ServingEngine(params, cfg, slots=8, paged=True, kv_block=16,
+        eng = ServingEngine(params, cfg, slots=8, kv_block=16,
                             prefill_chunk=64, fast_path=False,
                             kv_quant="int8", prefix_share=False)
         return {k: list(r.tokens) for k, r in eng.run(requests(61)).items()}
@@ -513,8 +513,8 @@ def test_latent_engine_matches_reference_with_packing_engaged(
     assert ra.mla_rows_tiling(256, 4, jnp.float32) == (16, 8)
     telemetry.reset()
     params, cfg = latent
-    eng = ServingEngine(params, cfg, slots=8, max_seq_len=256, paged=True,
-                        kv_block=4, prefill_chunk=64, fast_path=fast,
+    eng = ServingEngine(params, cfg, slots=8, max_seq_len=256, kv_block=4,
+                        prefill_chunk=64, fast_path=fast,
                         prefix_share=False)
     sizes = SMALL_SIZES
     waves = watch_waves(eng)
@@ -557,7 +557,7 @@ def test_latent_engine_matches_reference_with_packing_engaged(
 def burst_engine(gpt, **kw):
     params, cfg = gpt
     return ServingEngine(params, cfg, **dict(dict(
-        slots=8, paged=True, kv_block=16, prefill_chunk=64,
+        slots=8, kv_block=16, prefill_chunk=64,
         fast_path=False, prefix_share=False, queue_limit=64), **kw))
 
 
@@ -703,7 +703,7 @@ def test_capacity_router_waves_stay_padded_and_defer_nothing():
                           batch_size=1, seq_len=256, dropout_rate=0.0,
                           num_experts=4, top_k=2, capacity_factor=4.0)
     params = init_moe_params(cfg, name="moe", seed=0)
-    eng = ServingEngine(params, cfg, slots=8, paged=True, kv_block=16,
+    eng = ServingEngine(params, cfg, slots=8, kv_block=16,
                         prefill_chunk=64, fast_path=False)
     assert gd.wave_rows(eng.cfg_tuple, 8, 1, 64) == 512
     out = eng.run(requests(61, [(192, 3)] * 8))
@@ -718,7 +718,7 @@ def test_one_program_a_bucket_after_a_burst_of_32():
     bucket, two tokens), then 32 prompts at once on 32 slots: the ramp
     of the RAG cell.  The jitted step holds what the warm-up built."""
     params, cfg = rand_gpt(name="pb", V=67, S=128)
-    eng = ServingEngine(params, cfg, slots=32, paged=True, kv_block=4,
+    eng = ServingEngine(params, cfg, slots=32, kv_block=4,
                         prefill_chunk=16, fast_path=False,
                         prefix_share=False, queue_limit=64)
     assert gd.wave_rows(eng.cfg_tuple, 32, 1, 16) == 256 < 32 * 16

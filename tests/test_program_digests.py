@@ -1,0 +1,175 @@
+"""Every accepted serving program's lowered text, a case a program.
+
+A PR that is meant to change no behaviour is judged here: each entry of
+``PARENT`` is sha256[:16] of ``serve_mixed_paged_fn(...).lower(...)
+.as_text()`` for one small configuration of an accepted cell's block
+spec, one q-block bucket and one ``has_fresh``.  A family is lowered
+once (module-scoped ``digests``), by the builder beside the spec's own
+tests; a program that moved fails under its own name.  A PR that
+changes what a wave computes changes its entries on purpose and says
+which operation differs.
+
+``masked`` families are the ``jax.numpy`` path, lowered for the CPU.
+``kernels`` families are lowered for the described v5e chip with the
+Pallas kernels not interpreted (``attn="ragged"``), each Mosaic
+kernel's serialized body replaced by its assembly WITHOUT locations
+(``strip_kernel_locations``: the bytecode carries file names and line
+numbers, which move whenever a line is added above a kernel).
+
+``wave_programs``' latent Q 32 pair has 4 slots x 32 = 128 rows, under
+the packed wave's floor of 256 (``gd.wave_rows``), so it stays padded
+and takes ``ragged_paged_mla``: it does NOT cover PR 46's packed chunk
+program.  That one is the ``PACKED`` families' (16 slots x 32 packed
+into 256 rows), whose latent kernel call is ``ragged_paged_mla_rows``
+(named ``ragged_paged_mla`` in the text too; its result is
+``[256 * heads, kv_lora_rank]``).
+"""
+
+import jax
+import pytest
+
+# the described v5e chip and its shape-with-sharding factory
+from test_chip_compile import (  # noqa: E402,F401
+    no_compile_cache, sds, strip_kernel_locations, topo)
+from test_hybrid_moe import digest, wave_programs
+from test_retention import retention_programs, window_programs
+from test_window_moe import hybrid_programs
+
+
+def packed_programs(sds, attn):
+    """The GPT-2 and latent chunk programs of 16 slots: packed rows."""
+    return {k: low for k, low in wave_programs(sds, attn, slots=16).items()
+            if k.endswith("Q32.fresh1")}
+
+
+# family: (builder, lowered with the kernels for the described chip)
+FAMILIES = {
+    "PARENT_MASKED": (lambda s: wave_programs(s, "masked"), False),
+    "PARENT_RAGGED": (lambda s: wave_programs(s, "ragged"), True),
+    "PARENT_HYBRID_MASKED": (lambda s: hybrid_programs(s, "masked"), False),
+    "PARENT_HYBRID_RAGGED": (lambda s: hybrid_programs(s, "ragged"), True),
+    "PARENT_WINDOW_MASKED": (window_programs, False),
+    "PARENT_WINDOW_RAGGED": (
+        lambda s: window_programs(s, "ragged", qs=(1, 32)), True),
+    "PARENT_RETENTION_MASKED": (retention_programs, False),
+    "PARENT_PACKED_MASKED": (lambda s: packed_programs(s, "masked"), False),
+    "PARENT_PACKED_RAGGED": (lambda s: packed_programs(s, "ragged"), True),
+}
+
+PARENT = {
+    # GPT-2 (2 layers, 4 heads of 64, bf16) and the latent block, as the
+    # PARENT of PR 34 lowered them (commit cdadf90).  PR 41 changed the
+    # latent Q 32 pair on purpose: 128 rows x top-2 over 8 experts are 32
+    # expected rows a group, where ``moe_decode.takes_kernel`` hands the
+    # routed experts' products to ``kernels/grouped_matmul`` (here its
+    # interpreted body; parent of PR 41: 6d630c5c240e04a8).  The Q 1
+    # pair, 8 assignment rows, keeps ``ragged_dot`` and the parent's text.
+    "PARENT_MASKED": {
+        "gpt2.Q1.fresh0": "9691db83be028caf",
+        "gpt2.Q1.fresh1": "7beca803d1ca3f4f",
+        "gpt2.Q32.fresh0": "fe44f8933c85a0a3",
+        "gpt2.Q32.fresh1": "910e84f83fe7e6d4",
+        "latent.Q1.fresh0": "7af25cbea0694a84",
+        "latent.Q1.fresh1": "7af25cbea0694a84",
+        "latent.Q32.fresh0": "57bb0151f876695a",
+        "latent.Q32.fresh1": "57bb0151f876695a"},
+    # The latent Q 32 pair is PR 43's: a q-tile of 32 queries is taller
+    # than the latent kernel's short height (8 queries), so the kernel of
+    # a chunk program holds its step at two heights, ``ragged_attention.
+    # tile_heights``; the parent of PR 43 lowered it to c873bfc54690610f,
+    # and before PR 41's grouped kernel to 9be25ac3fb1f17f1.  The GPT-2
+    # programs are the parent's at every Q: one query head a K/V head
+    # stacks too few rows for a second height, ``_SHORT_MIN_ROWS``.
+    "PARENT_RAGGED": {
+        "gpt2.Q1.fresh0": "5919310cf2517705",
+        "gpt2.Q1.fresh1": "5919310cf2517705",
+        "gpt2.Q32.fresh0": "a7c64ccb01632823",
+        "gpt2.Q32.fresh1": "a7c64ccb01632823",
+        "latent.Q1.fresh0": "3e2ddf60b91b860d",
+        "latent.Q1.fresh1": "3e2ddf60b91b860d",
+        "latent.Q32.fresh0": "eef22714386f596c",
+        "latent.Q32.fresh1": "eef22714386f596c"},
+    # A small lfm2_moe and a small falcon_h1 configuration, as the PARENT
+    # of PR 42 lowered them (commit c2d3500).
+    "PARENT_HYBRID_MASKED": {
+        "lfm2.Q1.fresh0": "d5335fb3237bcd18",
+        "lfm2.Q1.fresh1": "43b61d3f0c614ec1",
+        "lfm2.Q32.fresh0": "34501c1582f67ac1",
+        "lfm2.Q32.fresh1": "90e43d41275f0717",
+        "falcon.Q1.fresh0": "5ed3ea5756c9fb13",
+        "falcon.Q1.fresh1": "471086ba72e5dab2",
+        "falcon.Q32.fresh0": "7a593dcee24143b8",
+        "falcon.Q32.fresh1": "e1c7e7369a2d3dbb"},
+    # The four Q 32 entries are PR 43's: with 4 and 2 query heads a K/V
+    # head the rows kernel of a chunk program holds its step at two
+    # heights, ``ragged_attention.tile_heights`` and ``rows_tiling``; the
+    # parent of PR 43 lowered them to 4305fadbb584f1c8 and
+    # c49aeded6dc2f4d8.  No ``ragged_paged_window`` in any of them.
+    "PARENT_HYBRID_RAGGED": {
+        "lfm2.Q1.fresh0": "d8e968f0ef99f889",
+        "lfm2.Q1.fresh1": "d8e968f0ef99f889",
+        "lfm2.Q32.fresh0": "1c3dd14502830c9d",
+        "lfm2.Q32.fresh1": "1c3dd14502830c9d",
+        "falcon.Q1.fresh0": "b452b65dabd73846",
+        "falcon.Q1.fresh1": "b452b65dabd73846",
+        "falcon.Q32.fresh0": "0aee2c839734b68c",
+        "falcon.Q32.fresh1": "0aee2c839734b68c"},
+    # tests/test_window_moe.py's small sliding-window / full model, as
+    # the PARENT of PR 44 lowered it (commit 56a5ee3).
+    "PARENT_WINDOW_MASKED": {
+        "mellum2.Q1.fresh0": "353f217c54a48780",
+        "mellum2.Q1.fresh1": "6c5a3286d660bb18",
+        "mellum2.Q8.fresh0": "4e448bc7c3ca2927",
+        "mellum2.Q8.fresh1": "293ab0d1cdcca346"},
+    # The families below were taken on the PARENT of PR 47 (commit
+    # 2accd4a, in a scratch checkout, before that PR's first deletion).
+    "PARENT_WINDOW_RAGGED": {
+        "mellum2.Q1.fresh0": "071a841212bd9ec1",
+        "mellum2.Q1.fresh1": "071a841212bd9ec1",
+        "mellum2.Q32.fresh0": "598db0ab0ecb210c",
+        "mellum2.Q32.fresh1": "598db0ab0ecb210c"},
+    "PARENT_RETENTION_MASKED": {
+        "brumby.Q1.fresh0": "e33a3369dfd0dd1b",
+        "brumby.Q1.fresh1": "e33a3369dfd0dd1b",
+        "brumby.Q32.fresh0": "addbf0ad5c1461c1",
+        "brumby.Q32.fresh1": "addbf0ad5c1461c1"},
+    "PARENT_PACKED_MASKED": {
+        "gpt2.Q32.fresh1": "6fd67af84c39d578",
+        "latent.Q32.fresh1": "9b275df0e7c91882"},
+    "PARENT_PACKED_RAGGED": {
+        "gpt2.Q32.fresh1": "ee10736bb0265878",
+        "latent.Q32.fresh1": "89ef03c3aad79ffc"},
+}
+
+
+@pytest.fixture(scope="module")
+def digests(request):
+    """``family -> {program: digest}``, each family lowered once."""
+    done = {}
+
+    def of(family):
+        if family not in done:
+            build, kernels = FAMILIES[family]
+            if not kernels:
+                done[family] = {k: digest(low.as_text()) for k, low in
+                                build(jax.ShapeDtypeStruct).items()}
+                return done[family]
+            from hetu_tpu.kernels import grouped_matmul as gm
+            from hetu_tpu.kernels import ragged_attention as ra
+            on_chip = request.getfixturevalue("sds")
+            with pytest.MonkeyPatch.context() as m:
+                m.setattr(ra, "_use_interpret", lambda: False)
+                m.setattr(gm, "_use_interpret", lambda: False)
+                texts = {k: low.as_text()
+                         for k, low in build(on_chip).items()}
+            assert all("tpu_custom_call" in t for t in texts.values())
+            done[family] = {k: digest(strip_kernel_locations(t))
+                            for k, t in texts.items()}
+        return done[family]
+    return of
+
+
+@pytest.mark.parametrize("family,program", [
+    (f, p) for f, table in PARENT.items() for p in table])
+def test_a_program_lowers_to_the_parents_text(digests, family, program):
+    assert digests(family)[program] == PARENT[family][program]

@@ -203,7 +203,7 @@ LAND_SPANS = ["serve.wave.sync", "serve.wave.unpack"]
 def _engine(model, **kw):
     p, cfg = model
     kw.setdefault("slots", 8)
-    return ServingEngine(p, cfg, paged=True, kv_block=8, **kw)
+    return ServingEngine(p, cfg, kv_block=8, **kw)
 
 
 class TestWaveSpans:
@@ -328,13 +328,10 @@ class TestWaveSpans:
 
 class TestResultTimeline:
     @pytest.mark.parametrize("kw", [
-        dict(paged=True, kv_block=8, prefill_chunk=4),
-        dict(paged=True, kv_block=8, spec=2),
-        dict(paged=True, kv_block=8),
-        dict(paged=False),
-        dict(paged=False, spec=2),
-    ], ids=["paged-chunked", "paged-spec", "paged", "contiguous",
-            "contiguous-spec"])
+        dict(kv_block=8, prefill_chunk=4),
+        dict(kv_block=8, spec=2),
+        dict(kv_block=8),
+    ], ids=["paged-chunked", "paged-spec", "paged"])
     def test_token_times_and_queue_wait(self, model, kw):
         p, cfg = model
         eng = ServingEngine(p, cfg, slots=2, **kw)
@@ -357,7 +354,7 @@ class TestResultTimeline:
 
     def test_a_speculative_wave_shares_one_stamp(self, model):
         p, cfg = model
-        eng = ServingEngine(p, cfg, slots=2, paged=True, kv_block=8,
+        eng = ServingEngine(p, cfg, slots=2, kv_block=8,
                             spec=2)
         [r] = eng.run([Request(prompt=[4, 5, 6], max_new_tokens=12)]
                       ).values()

@@ -561,28 +561,6 @@ def model():
     return _rand_gpt()
 
 
-class TestKVQuantKernels:
-    def test_contiguous_kernel_matches_oracle(self):
-        rng = np.random.RandomState(20)
-        B, S, H, Dh = 4, 64, 2, 8
-        from hetu_tpu.kernels.decode_attention import (
-            masked_decode_reference, paged_decode_attention)
-        q = jnp.asarray(rng.randn(B, H, Dh).astype(np.float32))
-        k = jnp.asarray(rng.randn(B, S, H, Dh).astype(np.float32))
-        v = jnp.asarray(rng.randn(B, S, H, Dh).astype(np.float32))
-        lens = jnp.asarray(np.array([5, 0, 33, 64], np.int32))
-        qk, sk = quant.kv_encode(k)
-        qv, sv = quant.kv_encode(v)
-        out = paged_decode_attention(q, qk, qv, lens, block_k=16,
-                                     k_scale=sk, v_scale=sv)
-        ref = masked_decode_reference(q, qk, qv, lens, k_scale=sk,
-                                      v_scale=sv)
-        assert float(jnp.abs(out - ref).max()) < 2e-5
-        # and the quantization error itself is bounded vs exact f32
-        exact = masked_decode_reference(q, k, v, lens)
-        assert float(jnp.abs(ref - exact).max()) < 0.05
-
-
 class TestKVQuantEngine:
     def _offline(self, model, prompts, n=6):
         from hetu_tpu.models.gpt_decode import generate_fast
@@ -604,10 +582,8 @@ class TestKVQuantEngine:
 
     def test_engine_int8_greedy_identical_to_offline(self, model):
         ref = self._offline(model, self.PROMPTS)
-        for kw in [dict(paged=False, fast_path=False),
-                   dict(paged=True, kv_block=8, fast_path=False),
-                   dict(paged=True, kv_block=8, fast_path=True),
-                   dict(paged=False, fast_path=True)]:
+        for kw in [dict(kv_block=8, fast_path=False),
+                   dict(kv_block=8, fast_path=True)]:
             eng, out = self._engine(model, self.PROMPTS,
                                     kv_quant="int8", **kw)
             assert out == ref, kw
@@ -617,8 +593,8 @@ class TestKVQuantEngine:
 
     def test_env_knob_and_stats(self, model, monkeypatch):
         monkeypatch.setenv("HETU_KV_QUANT", "int8")
-        eng, out = self._engine(model, self.PROMPTS, paged=True,
-                                kv_block=8, fast_path=False)
+        eng, out = self._engine(model, self.PROMPTS, kv_block=8,
+                                fast_path=False)
         assert eng.kv.quant == "int8"
         assert eng.kv.stats()["quant"] == "int8"
         assert out == self._offline(model, self.PROMPTS)
@@ -627,9 +603,9 @@ class TestKVQuantEngine:
         pre = [5, 6, 7, 8, 9, 10, 11, 12, 13]   # straddles block 4
         prompts = [pre + [20 + i] for i in range(3)]
         _, a = self._engine(model, prompts, kv_quant="int8",
-                            paged=True, kv_block=4, fast_path=False,
+                            kv_block=4, fast_path=False,
                             prefix_share=True, prefill_chunk=4)
-        eng_b, b = self._engine(model, prompts, paged=True, kv_block=4,
+        eng_b, b = self._engine(model, prompts, kv_block=4,
                                 fast_path=False, prefix_share=False)
         assert a == b
 
@@ -643,14 +619,12 @@ class TestKVQuantEngine:
         assert int8 < exact / 2
 
     def test_manager_accepts_dtype_int8(self):
-        from hetu_tpu.serving import KVCacheManager, PagedKVManager
-        m = KVCacheManager(layers=1, heads=2, head_dim=8, slots=2,
-                           max_seq_len=32, dtype="int8")
-        assert m.quant == "int8" and isinstance(m.cache_k, tuple)
-        pm = PagedKVManager(layers=1, heads=2, head_dim=8, slots=2,
-                            max_seq_len=32, block=8, dtype=jnp.int8)
-        assert pm.quant == "int8"
-        assert pm.cache_k[1].dtype == jnp.float32
+        from hetu_tpu.serving import PagedKVManager
+        for dtype in ("int8", jnp.int8):
+            pm = PagedKVManager(layers=1, heads=2, head_dim=8, slots=2,
+                                max_seq_len=32, block=8, dtype=dtype)
+            assert pm.quant == "int8" and isinstance(pm.cache_k, tuple)
+            assert pm.cache_k[1].dtype == jnp.float32
 
     def test_teacher_forced_margin_gate(self, model):
         from hetu_tpu.models.gpt_decode import teacher_forced_logits
@@ -675,8 +649,7 @@ class TestKVQuantEngine:
         p, cfg = model
         pbf = {k: jnp.asarray(np.asarray(v), jnp.bfloat16)
                for k, v in p.items()}
-        eng = ServingEngine(pbf, cfg, slots=2, fast_path=False,
-                            paged=False)
+        eng = ServingEngine(pbf, cfg, slots=2, fast_path=False)
         assert eng.kv.cache_k.dtype == jnp.bfloat16
         assert eng.params[f"qg_wte_table"].dtype == jnp.bfloat16
 

@@ -1,20 +1,18 @@
 """The mixed ragged wave (ISSUE 18): ONE kernel and ONE engine wave
 for the whole serving hot loop.
 
-Kernel tier: ``ragged_attention`` / ``ragged_paged_attention`` (one
-parameterized Pallas body across contiguous/block-table x f32/int8)
-must match the ONE masked-gather oracle (``ragged_masked_reference``)
-on decode-only, verify-only, prefill-only, and freely mixed ``q_len``
+Kernel tier: ``ragged_paged_attention`` (the block-table pool, float
+rows and int8) must match the ONE masked-gather oracle
+(``ragged_masked_reference`` behind ``ragged_paged_reference``) on
+decode-only, verify-only, prefill-only, and freely mixed ``q_len``
 waves — including arbitrarily permuted pools and int8 scale planes,
-bf16 pools, dead slots and a call under ``jit`` — and must degenerate
-exactly to the contiguous decode and verify kernels that
-``_decode_step`` and ``_verify_step`` still call.
+bf16 pools, dead slots and a call under ``jit`` and under ``scan``.
 
 Engine tier: the load-bearing contract is TOKEN IDENTITY — the engine,
 which packs admissions, chunk continuations, spec-verify, and decode
 into one wave per step, must emit exactly the tokens offline
 ``generate_fast`` emits, greedy AND sampled, across
-contiguous/paged/int8/chunked/prefix-shared/speculative
+float/int8/chunked/prefix-shared/speculative
 configurations, while the ``chunk_stall`` lifecycle component
 collapses to exactly 0.
 
@@ -28,12 +26,8 @@ import hetu_tpu as ht  # noqa: F401  (platform forcing + compat shims)
 import jax
 import jax.numpy as jnp
 
-from hetu_tpu.kernels.decode_attention import (
-    masked_decode_reference, masked_verify_reference,
-    paged_decode_attention, paged_verify_attention,
-)
 from hetu_tpu.kernels.ragged_attention import (
-    ragged_attention, ragged_masked_reference, ragged_paged_attention,
+    ragged_masked_reference, ragged_paged_attention,
     ragged_paged_reference,
 )
 from hetu_tpu.kv_layout import kv_heads, kv_row_width, kv_rows
@@ -100,28 +94,22 @@ def _quantize(x, axis=-1):
 @pytest.mark.smoke
 class TestRaggedKernel:
     # decode-only, spec-verify-only, full-prompt prefill, and freely
-    # mixed waves — all one kernel, selected purely by per-slot data
+    # mixed waves — all one kernel, selected purely by per-slot data;
+    # under ``jit`` as the engine's step calls it, and inside a
+    # ``lax.scan`` as an offline loop would
+    @pytest.mark.parametrize("how", ["eager", "jit", "scan"])
     @pytest.mark.parametrize("qlens", [
         (1, 1, 1, 1), (4, 4, 4, 4), (4, 1, 2, 0), (2, 0, 4, 1)])
-    def test_contiguous_matches_reference(self, qlens):
-        q, k, v, lens, ql = _wave(qlens=qlens)
-        got = ragged_attention(q, k, v, lens, ql, block_k=16,
-                               interpret=True)
-        want = ragged_masked_reference(q, k, v, lens, ql)
-        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                                   atol=2e-5, rtol=2e-5)
-
-    # q_len 1 (a decode wave) and k+1 (a verify wave) are what the
-    # block-table decode and verify kernels computed; ``jit`` as the
-    # engine's step calls it
-    @pytest.mark.parametrize("jit", [False, True], ids=["eager", "jit"])
-    @pytest.mark.parametrize("qlens", [
-        (1, 1, 1, 1), (4, 4, 4, 4), (4, 1, 2, 0), (2, 0, 4, 1)])
-    def test_permuted_pool_matches_reference(self, qlens, jit):
+    def test_permuted_pool_matches_reference(self, qlens, how):
         q, k, v, lens, ql = _wave(qlens=qlens)
         pk, pv, tables = _to_pool(k, v)
-        got = (jax.jit(_paged) if jit else _paged)(
-            q, pk, pv, lens, ql, tables)
+        if how == "scan":
+            got = jax.lax.scan(
+                lambda c, _: (c, _paged(q, pk, pv, lens, ql, tables)),
+                0, None, length=2)[1][1]
+        else:
+            got = (jax.jit(_paged) if how == "jit" else _paged)(
+                q, pk, pv, lens, ql, tables)
         want = ragged_paged_reference(q, pk, pv, lens, ql, tables)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    atol=2e-5, rtol=2e-5)
@@ -130,21 +118,16 @@ class TestRaggedKernel:
         np.testing.assert_allclose(np.asarray(want), np.asarray(contig),
                                    atol=1e-6, rtol=1e-6)
 
-    def test_int8_twin_contiguous(self):
-        q, k, v, lens, ql = _wave()
-        k8, ks = _quantize(k)
-        v8, vs = _quantize(v)
-        got = ragged_attention(q, k8, v8, lens, ql, block_k=16,
-                               k_scale=ks, v_scale=vs, interpret=True)
-        want = ragged_masked_reference(q, k8, v8, lens, ql,
-                                       k_scale=ks, v_scale=vs)
-        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                                   atol=2e-5, rtol=2e-5)
-
+    # (the last: a q-block ONE query wide, a decode-only program)
     @pytest.mark.parametrize("qlens", [
-        (4, 1, 2, 0), (1, 1, 1, 1), (4, 4, 4, 4)])
+        (4, 1, 2, 0), (1, 1, 1, 1), (4, 4, 4, 4), (1,)],
+        ids=["qlens0", "qlens1", "qlens2", "one-query-block"])
     def test_int8_twin_paged(self, qlens):
-        q, k, v, lens, ql = _wave(qlens=qlens)
+        if len(qlens) == 1:
+            q, k, v, lens, ql = _wave(Q=1, qlens=(1, 1, 1, 0),
+                                      lens=(17, 64, 5, 0))
+        else:
+            q, k, v, lens, ql = _wave(qlens=qlens)
         pk, pv, tables = _to_pool(k, v)
         pk8, pks = _quantize(pk)
         pv8, pvs = _quantize(pv)
@@ -159,8 +142,7 @@ class TestRaggedKernel:
     # _MAX_ROWS, so a long prompt is several q-tiles): every live row
     # matches the reference exactly as a one-tile block does, wholly
     # dead tiles are skipped and come back zero
-    @pytest.mark.parametrize("layout", ["contiguous", "paged",
-                                        "paged-int8"])
+    @pytest.mark.parametrize("layout", ["paged", "paged-int8"])
     def test_tiled_q_block_matches_reference(self, layout, monkeypatch):
         from hetu_tpu.kernels import ragged_attention as ra
         monkeypatch.setattr(ra, "_ONE_TILE_ROWS", 8)
@@ -168,20 +150,14 @@ class TestRaggedKernel:
         assert ra._q_tile(16, 2) == 4
         q, k, v, lens, ql = _wave(Q=16, qlens=(16, 1, 6, 0),
                                   lens=(40, 33, 6, 0))
-        if layout == "contiguous":
-            got = ragged_attention(q, k, v, lens, ql, block_k=16,
-                                   interpret=True)
-            want = ragged_masked_reference(q, k, v, lens, ql)
-        else:
-            pk, pv, tables = _to_pool(k, v)
-            kw = {}
-            if layout == "paged-int8":
-                pk, ks = _quantize(pk)
-                pv, vs = _quantize(pv)
-                kw = dict(k_scale=ks, v_scale=vs)
-            got = _paged(q, pk, pv, lens, ql, tables, **kw)
-            want = ragged_paged_reference(q, pk, pv, lens, ql, tables,
-                                          **kw)
+        pk, pv, tables = _to_pool(k, v)
+        kw = {}
+        if layout == "paged-int8":
+            pk, ks = _quantize(pk)
+            pv, vs = _quantize(pv)
+            kw = dict(k_scale=ks, v_scale=vs)
+        got = _paged(q, pk, pv, lens, ql, tables, **kw)
+        want = ragged_paged_reference(q, pk, pv, lens, ql, tables, **kw)
         got, want = np.asarray(got), np.asarray(want)
         for b, n in enumerate(ql):
             live = -(-max(int(n), 1) // 4) * 4   # rows of live tiles
@@ -215,67 +191,22 @@ class TestRaggedKernel:
         # tile t of the whole prompt sees (t + 1) * tq positions
         assert fetches(1) == sum((t + 1) * tq // bk for t in range(n_t))
 
-    @pytest.mark.parametrize("layout", ["contiguous", "paged"])
-    def test_zero_length_slot_returns_zeros(self, layout):
+    def test_zero_length_slot_returns_zeros(self):
         q, k, v, lens, ql = _wave(qlens=(4, 1, 2, 0), lens=(17, 33, 5, 0))
-        if layout == "paged":
-            pk, pv, tables = _to_pool(k, v)
-            got = np.asarray(_paged(q, pk, pv, lens, ql, tables))
-        else:
-            got = np.asarray(ragged_attention(q, k, v, lens, ql,
-                                              block_k=16, interpret=True))
+        pk, pv, tables = _to_pool(k, v)
+        got = np.asarray(_paged(q, pk, pv, lens, ql, tables))
         assert np.all(got[3] == 0.0)
 
-    @pytest.mark.parametrize("layout", ["contiguous", "paged"])
-    def test_bf16_accumulates_f32(self, layout):
+    def test_bf16_accumulates_f32(self):
         q, k, v, lens, ql = _wave()
         qb, kb, vb = (jnp.asarray(a, jnp.bfloat16) for a in (q, k, v))
-        if layout == "paged":
-            pk, pv, tables = _to_pool(np.asarray(kb), np.asarray(vb))
-            got = _paged(qb, pk, pv, lens, ql, tables)
-        else:
-            got = ragged_attention(qb, kb, vb, lens, ql, block_k=16,
-                                   interpret=True)
+        pk, pv, tables = _to_pool(np.asarray(kb), np.asarray(vb))
+        got = _paged(qb, pk, pv, lens, ql, tables)
         assert got.dtype == jnp.bfloat16
         want = ragged_masked_reference(q, k, v, lens, ql)
         np.testing.assert_allclose(
             np.asarray(got, np.float32), np.asarray(want),
             atol=3e-2, rtol=3e-2)
-
-    # q_len = 1 IS the contiguous decode kernel; q_lens = spec widths IS
-    # the contiguous verify kernel
-    def test_degenerates_to_decode_kernel(self):
-        q, k, v, lens, _ = _wave()
-        ones = np.ones_like(lens)
-        got = np.asarray(ragged_attention(
-            q[:, :1], k, v, lens, ones, block_k=16, interpret=True))
-        old = np.asarray(paged_decode_attention(
-            q[:, 0], k, v, lens, block_k=16, interpret=True))
-        np.testing.assert_allclose(got[:, 0], old, atol=2e-5, rtol=2e-5)
-
-    def test_degenerates_to_verify_kernel(self):
-        q, k, v, lens, ql = _wave()
-        got = np.asarray(ragged_attention(q, k, v, lens, ql, block_k=16,
-                                          interpret=True))
-        old = np.asarray(paged_verify_attention(q, k, v, lens, ql,
-                                                block_k=16,
-                                                interpret=True))
-        np.testing.assert_allclose(got, old, atol=2e-5, rtol=2e-5)
-
-    # the four old per-mode references are now delegates of the ONE
-    # parameterized oracle — pin the degenerate-mode equivalences
-    def test_unified_reference_subsumes_old(self):
-        q, k, v, lens, ql = _wave()
-        np.testing.assert_allclose(
-            np.asarray(masked_verify_reference(q, k, v, lens, ql)),
-            np.asarray(ragged_masked_reference(q, k, v, lens, ql)),
-            atol=0, rtol=0)
-        np.testing.assert_allclose(
-            np.asarray(masked_decode_reference(q[:, 0], k, v, lens)),
-            np.asarray(ragged_masked_reference(
-                q[:, :1], k, v, lens,
-                np.ones_like(lens)))[:, 0],
-            atol=0, rtol=0)
 
 
 # ------------------------------------------------------------------- #
@@ -598,17 +529,14 @@ def offline(model):
 @pytest.mark.smoke
 class TestMixedModeEngine:
     @pytest.mark.parametrize("cfg_kw", [
-        dict(paged=False),
-        dict(paged=False, kv_quant="int8"),
-        dict(paged=True, kv_block=8),
-        dict(paged=True, kv_block=8, prefill_chunk=4, kv_quant="int8"),
-        dict(paged=True, kv_block=8, prefix_share=True, prefill_chunk=4),
-    ], ids=["contig", "contig-int8", "paged", "paged-chunk-int8",
-            "paged-prefix-chunk"])
+        dict(kv_block=8),
+        dict(kv_block=8, prefill_chunk=4, kv_quant="int8"),
+        dict(kv_block=8, prefix_share=True, prefill_chunk=4),
+    ], ids=["paged", "paged-chunk-int8", "paged-prefix-chunk"])
     def test_token_identity_vs_offline(self, model, offline, cfg_kw):
         p, cfg = model
         mix, eng = _run(p, cfg, **cfg_kw)
-        assert eng.steps > 0 and eng.paged is cfg_kw["paged"]
+        assert eng.steps > 0
         assert mix == offline
 
     # greedy tokens of TRACE served by the mixed ragged wave over the
@@ -630,7 +558,7 @@ class TestMixedModeEngine:
         parted from f32 in one token there, and still does) and, where
         the arithmetic is exact enough to compare, the masked path."""
         p, cfg = model
-        kw = dict(paged=True, kv_block=8, prefill_chunk=4,
+        kw = dict(kv_block=8, prefill_chunk=4,
                   prefix_share=True,
                   **{"f32": {}, "bf16": dict(dtype=jnp.bfloat16),
                      "int8": dict(kv_quant="int8")}[kind])
@@ -657,14 +585,14 @@ class TestMixedModeEngine:
 
     def test_spec_decode_composes(self, model, offline):
         p, cfg = model
-        mix, eng = _run(p, cfg, spec=2, paged=True, kv_block=8,
+        mix, eng = _run(p, cfg, spec=2, kv_block=8,
                         kv_quant="int8", prefill_chunk=4)
         assert eng.spec_k == 2 and eng.spec_waves > 0
         assert mix == offline
 
     def test_chunk_stall_folds_to_zero(self, model):
         p, cfg = model
-        _, eng = _run(p, cfg, paged=True, kv_block=8, prefill_chunk=4)
+        _, eng = _run(p, cfg, kv_block=8, prefill_chunk=4)
         cs = eng.metrics.components["chunk_stall_ms"]
         assert cs and all(v == 0.0 for v in cs)
         # kept in the schema for back-compat dashboards
@@ -678,7 +606,7 @@ class TestMixedModeEngine:
         # prefix_share off: every prompt token is then COMPUTED in some
         # wave, so the q_prefill ledger must sum to the trace exactly
         # (shared prefixes would legitimately skip their cached tokens)
-        _, eng = _run(p, cfg, paged=True, kv_block=8, prefix_share=False)
+        _, eng = _run(p, cfg, kv_block=8, prefix_share=False)
         steps = [e for e in eng.metrics.events
                  if e["event"] == "serve_step"]
         assert steps
@@ -734,8 +662,7 @@ SAMPLING = {
 @pytest.mark.smoke
 class TestSamplingWindow:
     @pytest.mark.parametrize("sampling", list(SAMPLING))
-    @pytest.mark.parametrize("layout", ["contig", "paged"])
-    def test_window_equals_all_rows(self, model, layout, sampling):
+    def test_window_equals_all_rows(self, model, sampling):
         """One wave holding a decode slot, a final chunk, a mid-prompt
         chunk, a verify block and a dead slot: every row the engine
         reads carries the token and the stream state that sampling ALL
@@ -749,21 +676,15 @@ class TestSamplingWindow:
                    cfg.max_position_embeddings)
         B, W, Dh = 5, 3, cfg.hidden_size // H
         cfg_tuple = ("rg", L, H, Dh, S)
-        paged = layout == "paged"
         wave = assemble_mixed_wave(
-            B, {s: (t, pos, fr, paged and len(t) > 3)
+            B, {s: (t, pos, fr, len(t) > 3)
                 for s, (t, pos, fr) in WINDOW_WAVE.items()})
         Q = wave["q"]
         assert Q == 8
         rng = np.random.RandomState(3)
-        if paged:
-            bs, T = 8, S // 8
-            shape = (L, B * T + 1, bs, kv_row_width(H, Dh))   # pool rows
-            tables = (1 + np.arange(B * T, dtype=np.int32)).reshape(B, T)
-            layout_args = (tables,)
-        else:
-            shape = (L, B, S, H, Dh)
-            layout_args = ()
+        bs, T = 8, S // 8
+        shape = (L, B * T + 1, bs, kv_row_width(H, Dh))   # pool rows
+        tables = (1 + np.arange(B * T, dtype=np.int32)).reshape(B, T)
         ck = jnp.asarray(rng.randn(*shape) * 0.3, jnp.float32)
         cv = jnp.asarray(rng.randn(*shape) * 0.3, jnp.float32)
         temp, topk = (np.asarray(SAMPLING[sampling][0], np.float32),
@@ -771,12 +692,11 @@ class TestSamplingWindow:
         keys = np.asarray(jax.vmap(jax.random.PRNGKey)(
             jnp.arange(B) + 11), np.uint32)
         desc = (wave["pos"], wave["tokens"], wave["q_len"])
-        fn = (gd.serve_mixed_paged_fn if paged else gd.serve_mixed_fn)(
-            False, "masked", W)
-        kw = {"has_fresh": True} if paged else {}
+        fn = gd.serve_mixed_paged_fn(False, "masked", W)
         sampled, _, _, after = fn(
-            params, cfg_tuple, ck, cv, *layout_args, *desc,
-            wave["first_row"], wave["self_fresh"], temp, topk, keys, **kw)
+            params, cfg_tuple, ck, cv, tables, *desc,
+            wave["first_row"], wave["self_fresh"], temp, topk, keys,
+            has_fresh=True)
         sampled, after = np.asarray(sampled), np.asarray(after)
         assert sampled.shape == (B, W) and after.shape == (B, W, 2)
 
@@ -784,8 +704,8 @@ class TestSamplingWindow:
         # that starts at row 0 and is Q wide), then the old scan
         full, _, _, _ = gd._mixed_step(
             params, cfg_tuple, ck, cv, *desc, np.zeros(B, np.int32),
-            wave["self_fresh"], window=Q,
-            block_tables=tables if paged else None, has_fresh=paged)
+            wave["self_fresh"], window=Q, block_tables=tables,
+            has_fresh=True)
         assert full.shape == (B, Q, cfg.vocab_size)
         want_tok, want_keys = _all_rows_sample(
             full, temp, topk, keys, wave["first_row"], wave["q_len"])
@@ -812,8 +732,7 @@ class TestSamplingWindow:
         import jax
         from hetu_tpu.serving.kv_manager import assemble_mixed_wave
         p, cfg = _rand_gpt(S=256)
-        eng = ServingEngine(p, cfg, slots=4, paged=True,
-                            kv_block=8, spec=spec or None)
+        eng = ServingEngine(p, cfg, slots=4, kv_block=8, spec=spec or None)
         B, Q, V, W = 4, 128, cfg.vocab_size, spec + 1
         wave = assemble_mixed_wave(B, {0: (list(range(1, 101)), 0, 99, True),
                                        1: ([3], 7, 0, False)})
@@ -870,8 +789,7 @@ class TestSamplingWindow:
                 built.append(event)
 
         p, cfg = _rand_gpt(name="wnd", V=67)     # programs nobody built
-        eng = ServingEngine(p, cfg, slots=4, paged=True,
-                            kv_block=8, prefill_chunk=16)
+        eng = ServingEngine(p, cfg, slots=4, kv_block=8, prefill_chunk=16)
         programs = eng._mixed.func._cache_size   # one jit, every engine
         before = programs()
         for n in (8, 16):

@@ -249,7 +249,7 @@ class TestTailDecomposition:
         """A paged pool that fits ONE request at a time: the second
         request's wait shows up as requeue_ms, not queue_ms."""
         p, cfg = model
-        eng = ServingEngine(p, cfg, slots=2, paged=True, kv_block=4,
+        eng = ServingEngine(p, cfg, slots=2, kv_block=4,
                             pool_blocks=4, prefix_share=False,
                             fast_path=False)
         reqs = [Request(prompt=[1, 2, 3], max_new_tokens=8),
@@ -264,7 +264,7 @@ class TestTailDecomposition:
         """Chunked prefill interleaves with decode waves: the prefill
         phase records >1 dispatch and a non-negative stall share."""
         p, cfg = model
-        eng = ServingEngine(p, cfg, slots=2, paged=True, kv_block=4,
+        eng = ServingEngine(p, cfg, slots=2, kv_block=4,
                             prefill_chunk=4, fast_path=False)
         long_req = Request(prompt=list(range(1, 13)), max_new_tokens=3)
         res = eng.run([Request(prompt=[7, 8], max_new_tokens=10),
@@ -456,7 +456,7 @@ class TestCounterExport:
         log = str(tmp_path / "paged.jsonl")
         monkeypatch.setenv("HETU_TELEMETRY_LOG", log)
         p, cfg = model
-        eng = ServingEngine(p, cfg, slots=2, paged=True, kv_block=4,
+        eng = ServingEngine(p, cfg, slots=2, kv_block=4,
                             fast_path=False)
         eng.run([Request(prompt=[1, 2, 3], max_new_tokens=3)])
         trace = _export(log, tmp_path / "t.json")

@@ -361,8 +361,7 @@ def test_config_refuses_what_it_cannot_run(key, value):
 
 
 def test_engine_refuses_other_paths_by_name(params, cfg):
-    for kw, what in ((dict(paged=False), "contiguous"),
-                     (dict(spec=2), "speculation"),
+    for kw, what in ((dict(spec=2), "speculation"),
                      (dict(kv_quant="int8"), "int8"),
                      (dict(pool_blocks=64), "pool_blocks")):
         with pytest.raises(ValueError, match=what):
@@ -664,27 +663,10 @@ def test_record_state_scan_counts_the_slots_the_kernel_took(kind,
 
 
 # ------------------------------------------------------------------ #
-# the accepted cells' programs: the parent's text
+# the accepted cells' programs (tests/test_program_digests.py pins them)
 # ------------------------------------------------------------------ #
 
-# sha256[:16] of the lowered mixed step of tests/test_window_moe.py's
-# small sliding-window / full model at two q-block buckets x has_fresh,
-# as the PARENT of PR 44 lowered them (commit 56a5ee3).  GPT-2's and the
-# latent block's are tests/test_hybrid_moe.py's PARENT_MASKED, the
-# lfm2_moe and falcon_h1 ones tests/test_window_moe.py's
-# PARENT_HYBRID_MASKED / _RAGGED, all of which this PR leaves as they were
-# and runs again below: PR 44 moved the grouped-query front end into
-# ``gpt_decode._qkv_heads`` and put guards for a wave without a pool
-# around the masks, and every accepted configuration's wave lowers to the
-# text it had.
-PARENT_WINDOW_MASKED = {
-    "mellum2.Q1.fresh0": "353f217c54a48780",
-    "mellum2.Q1.fresh1": "6c5a3286d660bb18",
-    "mellum2.Q8.fresh0": "4e448bc7c3ca2927",
-    "mellum2.Q8.fresh1": "293ab0d1cdcca346"}
-
-
-def window_programs(sds):
+def window_programs(sds, attn="masked", qs=(1, 8)):
     from hetu_tpu.models.moe_decode import HybridMoEConfig
     from test_window_moe import NAME as MEL, SMALL as MELLUM
     i32 = lambda *s: sds(s, jnp.int32)                     # noqa: E731
@@ -693,54 +675,46 @@ def window_programs(sds):
     p = {k: sds(s, jnp.float32) for k, s in c.param_shapes(MEL).items()}
     pool = sds((1, 33, BS, 128), jnp.float32)
     win = sds((3, 25, BS, 128), jnp.float32)
-    fn = gd.serve_mixed_paged_fn(True, "masked", 1)
+    fn = gd.serve_mixed_paged_fn(True, attn, 1)
     out = {}
-    for Q in (1, 8):
+    for Q in qs:
         for fresh in (False, True):
             out[f"mellum2.Q{Q}.fresh{int(fresh)}"] = fn.func.lower(
                 p, (MEL, 4, 8, 16, 64, c.block_spec()), pool, pool,
                 i32(B, T), i32(B), i32(B, Q), i32(B), i32(B),
                 sds((B,), jnp.bool_), sds((B,), jnp.float32), i32(B),
-                sds((B, 2), jnp.uint32), attn="masked", window=1,
+                sds((B, 2), jnp.uint32), attn=attn, window=1,
                 has_fresh=fresh, win=(win, win), ring=i32(B, 6))
     return out
 
 
-def test_the_window_cells_waves_lower_to_the_parents_stablehlo():
-    from test_hybrid_moe import digest
-    got = {k: digest(low.as_text())
-           for k, low in window_programs(jax.ShapeDtypeStruct).items()}
-    assert got == PARENT_WINDOW_MASKED
+def retention_programs(sds, qs=(1, 32)):
+    """{name: lowered mixed step} of this file's small power-retention
+    model: no pool, six states a slot."""
+    i32 = lambda *s: sds(s, jnp.int32)                     # noqa: E731
+    c = rd.RetentionConfig.from_hf(SMALL)
+    blk = c.block_spec()
+    B = 4
+    p = {k: sds(s, jnp.float32) for k, s in c.param_shapes(NAME).items()}
+    state = tuple(sds((sh[0], B) + tuple(sh[1:]), dt)
+                  for sh, dt in blk.state_shapes(3, 64))
+    fn = gd.serve_mixed_paged_fn(True, "masked", 1)
+    out = {}
+    for Q in qs:
+        for fresh in (False, True):
+            out[f"brumby.Q{Q}.fresh{int(fresh)}"] = fn.func.lower(
+                p, (NAME, 3, 4, 16, 512, blk), None, None, i32(B, 1),
+                i32(B), i32(B, Q), i32(B), i32(B), sds((B,), jnp.bool_),
+                sds((B,), jnp.float32), i32(B), sds((B, 2), jnp.uint32),
+                attn="masked", window=1, has_fresh=fresh, state=state)
+    return out
 
 
-@pytest.mark.parametrize("family", ["gpt2_latent", "lfm2_falcon"])
-def test_every_older_cells_waves_lower_to_the_parents_stablehlo(family):
-    from test_hybrid_moe import digest
-    if family == "gpt2_latent":
-        from test_hybrid_moe import PARENT_MASKED as want, wave_programs
-        programs = wave_programs(jax.ShapeDtypeStruct, "masked")
-    else:
-        from test_window_moe import (
-            PARENT_HYBRID_MASKED as want, hybrid_programs)
-        programs = hybrid_programs(jax.ShapeDtypeStruct, "masked")
-    assert {k: digest(low.as_text()) for k, low in programs.items()} == want
-
-
-def test_a_retention_wave_builds_no_mask_and_takes_no_pool(cfg):
+def test_a_retention_wave_builds_no_mask_and_takes_no_pool():
     """The program of a spec without a pool layer: no array over
     positions, the states donated and handed back, the pool pair None."""
-    sds = jax.ShapeDtypeStruct
-    i32 = lambda *s: sds(s, jnp.int32)                     # noqa: E731
-    B = 4
-    p = {k: sds(s, jnp.float32) for k, s in cfg.param_shapes(NAME).items()}
-    state = tuple(sds((sh[0], B) + tuple(sh[1:]), dt)
-                  for sh, dt in cfg.block_spec().state_shapes(3, 64))
-    fn = gd.serve_mixed_paged_fn(True, "masked", 1)
-    lowered = fn.func.lower(
-        p, (NAME, 3, 4, 16, 512, cfg.block_spec()), None, None, i32(B, 1),
-        i32(B), i32(B, 8), i32(B), i32(B), sds((B,), jnp.bool_),
-        sds((B,), jnp.float32), i32(B), sds((B, 2), jnp.uint32),
-        attn="masked", window=1, has_fresh=True, state=state)
+    lowered = retention_programs(jax.ShapeDtypeStruct,
+                                 qs=(8,))["brumby.Q8.fresh1"]
     leaves = jax.tree_util.tree_leaves(lowered.out_info)
     assert len(leaves) == 2 + 6            # sampled, keys, the six states
     assert "x512x" not in lowered.as_text()      # nothing spans S_max

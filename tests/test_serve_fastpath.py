@@ -1,11 +1,8 @@
-"""Ragged KV serving fast path: flash prefill + the paged
-decode-attention kernel (kernels/decode_attention.py), pinned against
+"""Ragged KV serving fast path: flash prefill + the ragged kernel
+(its own parity suite is tests/test_ragged_kernel.py), pinned against
 the masked/scan reference in interpret mode.
 
 The load-bearing contracts, each tested separately:
-- kernel parity: ``paged_decode_attention`` equals the masked-S_max
-  oracle to tolerance across fill fractions, pow2 buckets, bf16, and
-  ragged per-slot lengths (every slot a different filled length);
 - prefill parity: one batched flash-prefill dispatch writes the same
   cache rows and samples the same first token as the teacher-forced
   per-request scan;
@@ -27,9 +24,6 @@ import jax
 import jax.numpy as jnp
 
 import hetu_tpu as ht  # noqa: F401  (platform forcing + compat shims)
-from hetu_tpu.kernels.decode_attention import (
-    masked_decode_reference, paged_decode_attention,
-)
 from hetu_tpu.models import GPTConfig
 from hetu_tpu.models.gpt_decode import (
     _resolve_fast, generate_fast, tp_shard_params,
@@ -69,76 +63,6 @@ def model():
 
 
 @pytest.mark.smoke
-class TestPagedDecodeKernel:
-    """The kernel against the masked-S_max oracle."""
-
-    def _rand_qkv(self, B, S, H, Dh, dtype=jnp.float32, seed=0):
-        rng = np.random.RandomState(seed)
-        q = jnp.asarray(rng.randn(B, H, Dh), dtype)
-        k = jnp.asarray(rng.randn(B, S, H, Dh), dtype)
-        v = jnp.asarray(rng.randn(B, S, H, Dh), dtype)
-        return q, k, v
-
-    @pytest.mark.parametrize("S", [16, 64, 256])
-    def test_fill_fraction_sweep_f32(self, S):
-        """Every fill fraction from one token to brim-full, including
-        block-boundary straddles."""
-        B, H, Dh = 4, 2, 8
-        q, k, v = self._rand_qkv(B, S, H, Dh)
-        for fill in (1, 2, S // 4, S // 2, S // 2 + 1, S - 1, S):
-            lens = jnp.full((B,), fill, jnp.int32)
-            got = paged_decode_attention(q, k, v, lens)
-            want = masked_decode_reference(q, k, v, lens)
-            np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                                       rtol=1e-5, atol=1e-5)
-
-    def test_ragged_per_slot_lengths(self):
-        """Each slot a different filled length — the serving shape."""
-        B, S, H, Dh = 8, 128, 2, 8
-        q, k, v = self._rand_qkv(B, S, H, Dh, seed=3)
-        lens = jnp.asarray([1, 7, 16, 17, 63, 64, 100, 128], jnp.int32)
-        got = paged_decode_attention(q, k, v, lens)
-        want = masked_decode_reference(q, k, v, lens)
-        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                                   rtol=1e-5, atol=1e-5)
-
-    def test_bf16_accumulates_f32(self):
-        """bf16 caches: scores/output accumulate f32 in the kernel, so
-        the kernel tracks the f32 oracle to bf16 resolution."""
-        B, S, H, Dh = 4, 64, 2, 8
-        q, k, v = self._rand_qkv(B, S, H, Dh, jnp.bfloat16, seed=5)
-        lens = jnp.asarray([3, 17, 40, 64], jnp.int32)
-        got = paged_decode_attention(q, k, v, lens)
-        assert got.dtype == jnp.bfloat16
-        want = masked_decode_reference(q, k, v, lens)
-        np.testing.assert_allclose(np.asarray(got, np.float32),
-                                   np.asarray(want), rtol=0.05, atol=0.05)
-
-    def test_zero_length_slot_returns_zeros(self):
-        """lengths 0 (no live positions) matches the oracle's dead-row
-        convention: exact zeros, no NaN from the empty softmax."""
-        B, S, H, Dh = 2, 32, 2, 8
-        q, k, v = self._rand_qkv(B, S, H, Dh, seed=7)
-        lens = jnp.asarray([0, 9], jnp.int32)
-        got = np.asarray(paged_decode_attention(q, k, v, lens))
-        assert np.all(got[0] == 0.0) and np.all(np.isfinite(got))
-        want = masked_decode_reference(q, k, v, lens)
-        np.testing.assert_allclose(got, np.asarray(want),
-                                   rtol=1e-5, atol=1e-5)
-
-    def test_under_jit_and_under_scan(self):
-        """The serving engine calls the kernel from inside jit; the
-        offline path could call it from inside lax.scan — both trace."""
-        B, S, H, Dh = 2, 32, 2, 8
-        q, k, v = self._rand_qkv(B, S, H, Dh, seed=9)
-        lens = jnp.asarray([5, 30], jnp.int32)
-        jitted = jax.jit(paged_decode_attention)(q, k, v, lens)
-        want = masked_decode_reference(q, k, v, lens)
-        np.testing.assert_allclose(np.asarray(jitted), np.asarray(want),
-                                   rtol=1e-5, atol=1e-5)
-
-
-@pytest.mark.smoke
 class TestFlashPrefillParity:
     """Batched flash prefill vs the teacher-forced scan prefill."""
 
@@ -149,7 +73,7 @@ class TestFlashPrefillParity:
         from hetu_tpu.models.gpt_decode import (
             _prep_param, serve_prefill_batch_fn, serve_prefill_fn,
         )
-        from hetu_tpu.serving import KVCacheManager
+        from hetu_tpu.serving.kv_manager import _bucket_prompt
         p, cfg = model
         params = {k: _prep_param(v) for k, v in p.items()}
         Dh = cfg.hidden_size // cfg.num_attention_heads
@@ -158,22 +82,22 @@ class TestFlashPrefillParity:
                      cfg.max_position_embeddings)
         scan = serve_prefill_fn(donate=False)
         flash = serve_prefill_batch_fn(donate=False)
+        S = cfg.max_position_embeddings
+        # the offline cores' contiguous cache pair, two slot rows
+        cache = jnp.zeros((cfg.num_hidden_layers, 2, S,
+                           cfg.num_attention_heads, Dh), jnp.float32)
         for P in (1, 3, 7, 8, 9, 16):
-            kv = KVCacheManager(
-                layers=cfg.num_hidden_layers,
-                heads=cfg.num_attention_heads, head_dim=Dh, slots=2,
-                max_seq_len=cfg.max_position_embeddings)
-            pb = kv.bucket_prompt(P)
+            pb = _bucket_prompt(P, S, S)
             prompt = np.arange(1, P + 1, dtype=np.int32) % 60
             padded = np.zeros(pb, np.int32)
             padded[:P] = prompt
             key = np.asarray(jax.random.PRNGKey(0), np.uint32)
             f_scan, ck_s, cv_s, _ = scan(
-                params, cfg_tuple, kv.cache_k, kv.cache_v,
+                params, cfg_tuple, cache, cache,
                 np.int32(1), padded, np.int32(P),
                 np.float32(0.0), np.int32(0), key)
             f_flash, ck_f, cv_f, _ = flash(
-                params, cfg_tuple, kv.cache_k, kv.cache_v,
+                params, cfg_tuple, cache, cache,
                 np.asarray([1], np.int32), padded[None],
                 np.asarray([P], np.int32),
                 np.zeros(1, np.float32), np.zeros(1, np.int32),

@@ -23,9 +23,9 @@ import hetu_tpu as ht  # noqa: F401  (platform forcing + compat shims)
 from hetu_tpu.models import GPTConfig
 from hetu_tpu.models.gpt_decode import generate_fast, tp_shard_params
 from hetu_tpu.serving import (
-    KVCacheManager, QueueFull, Request, ServingEngine, ServingMetrics,
-    round_up_pow2,
+    QueueFull, Request, ServingEngine, ServingMetrics,
 )
+
 
 def _rand_gpt(name="sv", L=2, H=2, Dh=8, V=61, S=32, seed=0):
     """Deterministic random params in generate_fast's naming contract."""
@@ -54,45 +54,6 @@ def _rand_gpt(name="sv", L=2, H=2, Dh=8, V=61, S=32, seed=0):
 @pytest.fixture(scope="module")
 def model():
     return _rand_gpt()
-
-
-@pytest.mark.smoke
-class TestKVCacheManager:
-    def test_pow2_bucketing(self):
-        assert round_up_pow2(5) == 8
-        assert round_up_pow2(8) == 8
-        assert round_up_pow2(3, floor=8) == 8
-        m = KVCacheManager(layers=1, heads=1, head_dim=4, slots=3,
-                           max_seq_len=20)
-        assert m.n_slots == 4 and m.s_max == 32
-        assert m.cache_k.shape == (1, 4, 32, 1, 4)
-        assert m.bucket_prompt(3) == 8 and m.bucket_prompt(9) == 16
-
-    def test_pos_cap_bounds_bucket(self):
-        m = KVCacheManager(layers=1, heads=1, head_dim=4, slots=2,
-                           max_seq_len=16, pos_cap=16)
-        assert m.s_max == 16          # bucket never exceeds the wpe table
-        with pytest.raises(ValueError):
-            KVCacheManager(layers=1, heads=1, head_dim=4, slots=2,
-                           max_seq_len=24, pos_cap=16)
-
-    def test_alloc_release_cycle(self):
-        m = KVCacheManager(layers=1, heads=1, head_dim=4, slots=2,
-                           max_seq_len=16)
-        a = m.alloc("r0", 3)
-        b = m.alloc("r1", 5)
-        assert {a, b} == {0, 1} and m.alloc("r2", 1) is None
-        assert m.occupancy == 1.0 and m.live() == [0, 1]
-        m.advance(a, 2)
-        assert m.lengths[a] == 5
-        m.release(a)
-        assert m.free_slots == 1 and m.owner[a] is None
-        with pytest.raises(ValueError):
-            m.release(a)              # double free
-        assert m.alloc("r3", 4) == a  # recycled
-        assert m.total_allocs == 3
-        with pytest.raises(ValueError):
-            m.alloc("r4", 99)         # longer than S_max
 
 
 @pytest.mark.smoke

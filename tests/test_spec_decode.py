@@ -7,13 +7,13 @@ the non-speculative engine and to offline ``generate_fast`` — greedy
 trivially, and SAMPLED too, because every emitted token is the target's
 own sequential sample from the request's rng stream (the verify returns
 the stream state after every split, so the host resumes at exactly the
-accepted count).  Identity must hold across every cache configuration:
-contiguous, block-table paged (with prefix sharing and chunked
-prefill), int8-quantized, and the ragged fast path.
+accepted count).  Identity must hold across every cache
+configuration: the block-table pool with prefix sharing and chunked
+prefill, int8-quantized, and the ragged fast path.
 
 Rollback property tests (the ISSUE's satellite): randomized
 propose/accept/reject sequences must leave the cache's live bytes equal
-to a never-speculated replay on contiguous, paged (including COW-shared
+to a never-speculated replay on the paged pool (including COW-shared
 prefixes — rollback must never free a block another holder still
 references), and int8 variants (scale planes truncated in lockstep).
 
@@ -37,8 +37,7 @@ from hetu_tpu.models.gpt_decode import (
     resolve_draft_layers, resolve_spec_k,
 )
 from hetu_tpu.serving import (
-    KVCacheManager, PagedKVManager, Request, ServingEngine,
-    ServingMetrics,
+    PagedKVManager, Request, ServingEngine, ServingMetrics,
 )
 
 
@@ -93,72 +92,6 @@ def _mk(trace=TRACE, **kw):
 
 def _outs(res):
     return sorted(r.tokens.tolist() for r in res.values())
-
-
-# ------------------------------------------------------------------- #
-# verify kernels
-# ------------------------------------------------------------------- #
-
-
-@pytest.mark.smoke
-class TestVerifyKernel:
-    def _data(self, B=4, Q=4, H=2, Dh=8, S=64, seed=0):
-        rng = np.random.RandomState(seed)
-        q = rng.randn(B, Q, H, Dh).astype(np.float32)
-        k = rng.randn(B, S, H, Dh).astype(np.float32)
-        v = rng.randn(B, S, H, Dh).astype(np.float32)
-        qlens = np.array([Q, Q - 1, 1, 0], np.int32)[:B]
-        lens = np.array([17, 33, 5, 0], np.int32)[:B]
-        return q, k, v, lens, qlens
-
-    def test_matches_masked_reference(self):
-        from hetu_tpu.kernels.decode_attention import (
-            masked_verify_reference, paged_verify_attention,
-        )
-        q, k, v, lens, qlens = self._data()
-        got = paged_verify_attention(q, k, v, lens, qlens, block_k=16)
-        want = masked_verify_reference(q, k, v, lens, qlens)
-        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                                   rtol=1e-5, atol=1e-5)
-
-    def test_q1_degenerates_to_decode_kernel(self):
-        """A q_len=1 verify block scores exactly what the single-query
-        decode kernel scores."""
-        from hetu_tpu.kernels.decode_attention import (
-            paged_decode_attention, paged_verify_attention,
-        )
-        q, k, v, lens, _ = self._data()
-        lens = np.maximum(lens, 1)
-        got = paged_verify_attention(q[:, :1], k, v, lens,
-                                     np.ones_like(lens), block_k=16)
-        want = paged_decode_attention(q[:, 0], k, v, lens, block_k=16)
-        np.testing.assert_allclose(np.asarray(got[:, 0]),
-                                   np.asarray(want), rtol=1e-5,
-                                   atol=1e-5)
-
-    def test_int8_variants(self):
-        from hetu_tpu.kernels.decode_attention import (
-            masked_verify_reference, paged_verify_attention,
-        )
-        from hetu_tpu.quant import kv_encode
-        q, k, v, lens, qlens = self._data()
-        kq, ks = kv_encode(jnp.asarray(k))
-        vq, vs = kv_encode(jnp.asarray(v))
-        got = paged_verify_attention(q, kq, vq, lens, qlens,
-                                     block_k=16, k_scale=ks, v_scale=vs)
-        want = masked_verify_reference(q, kq, vq, lens, qlens,
-                                       k_scale=ks, v_scale=vs)
-        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                                   rtol=1e-5, atol=1e-5)
-
-    def test_zero_length_slot_outputs_zero(self):
-        from hetu_tpu.kernels.decode_attention import (
-            paged_verify_attention,
-        )
-        q, k, v, lens, qlens = self._data()
-        got = np.asarray(paged_verify_attention(q, k, v, lens, qlens,
-                                                block_k=16))
-        assert np.all(got[3] == 0.0)       # lens[3] == 0
 
 
 @pytest.mark.smoke
@@ -219,18 +152,15 @@ class TestVerifyStep:
 
 @pytest.mark.smoke
 class TestEngineIdentity:
-    # contiguous spec-vs-plain is covered by test_sampled_identity and
-    # spec-vs-offline below; these pin the non-trivial cache layouts
-    # (the ISSUE's contiguous/paged/int8/chunked/shared-prefix matrix,
-    # fast_path exercising the verify KERNELS in interpret mode)
+    # the default engine's spec-vs-plain is covered by
+    # test_sampled_identity and spec-vs-offline below; these pin the
+    # other pool configurations (int8/chunked/shared-prefix,
+    # fast_path exercising the ragged KERNEL in interpret mode)
     CONFIGS = [
-        ("paged_shared", {"paged": True, "kv_block": 4,
-                          "prefix_share": True}),
-        ("paged_chunked", {"paged": True, "kv_block": 4,
-                           "prefill_chunk": 3}),
+        ("paged_shared", {"kv_block": 4, "prefix_share": True}),
+        ("paged_chunked", {"kv_block": 4, "prefill_chunk": 3}),
         ("int8", {"kv_quant": "int8"}),
-        ("paged_fast", {"paged": True, "kv_block": 4,
-                        "fast_path": True}),
+        ("paged_fast", {"kv_block": 4, "fast_path": True}),
     ]
 
     @pytest.mark.parametrize("label,kw",
@@ -397,86 +327,64 @@ class TestOfflineSpec:
 # ------------------------------------------------------------------- #
 
 
-def _write_positions(m, slot, positions, values, L=1, H=1, Dh=4,
-                     paged=True):
+def _write_positions(m, slot, positions, values, L=1, H=1, Dh=4):
     """Write one [H, Dh] slab per position through the manager's
-    layout (block tables or slot rows), mirroring the verify write."""
+    block tables, mirroring the verify write."""
     for pos, val in zip(positions, values):
         v = jnp.asarray(np.full((1, H, Dh), val, np.float32))
         for i in range(L):
-            if paged:
-                b = int(m.tables[slot, pos // m.block])
-                off = pos % m.block
-                m.cache_k = _kv_scatter(m.cache_k,
-                                        (i, np.array([b]),
-                                         np.array([off])), v)
-                m.cache_v = _kv_scatter(m.cache_v,
-                                        (i, np.array([b]),
-                                         np.array([off])), v)
-            else:
-                m.cache_k = _kv_scatter(
-                    m.cache_k, (i, np.array([slot]), np.array([pos])), v)
-                m.cache_v = _kv_scatter(
-                    m.cache_v, (i, np.array([slot]), np.array([pos])), v)
+            b = int(m.tables[slot, pos // m.block])
+            off = pos % m.block
+            m.cache_k = _kv_scatter(m.cache_k,
+                                    (i, np.array([b]),
+                                     np.array([off])), v)
+            m.cache_v = _kv_scatter(m.cache_v,
+                                    (i, np.array([b]),
+                                     np.array([off])), v)
 
 
-def _live_bytes(m, slot, paged=True):
+def _live_bytes(m, slot):
     """The slot's live-region cache content (payload + scale planes for
     quantized layouts), gathered position by position."""
     out = []
     n = int(m.lengths[slot])
     quant = isinstance(m.cache_k, tuple)
     for pos in range(n):
-        if paged:
-            b = int(m.tables[slot, pos // m.block])
-            off = pos % m.block
-            idx = (slice(None), b, off)
-        else:
-            idx = (slice(None), slot, pos)
+        b = int(m.tables[slot, pos // m.block])
+        off = pos % m.block
+        idx = (slice(None), b, off)
         if quant:
             out.append((np.asarray(m.cache_k[0][idx]).tobytes(),
                         np.asarray(m.cache_k[1][idx]).tobytes(),
                         np.asarray(m.cache_v[0][idx]).tobytes(),
                         np.asarray(m.cache_v[1][idx]).tobytes()))
-        elif paged:                    # rows: the [L, H, Dh] view
+        else:                          # rows: the [L, H, Dh] view
             out.append(tuple(
                 kv_heads(np.asarray(c[idx]), m.heads,
                          m.head_dim).tobytes()
                 for c in (m.cache_k, m.cache_v)))
-        else:
-            out.append((np.asarray(m.cache_k[idx]).tobytes(),
-                        np.asarray(m.cache_v[idx]).tobytes()))
     return out
 
 
 @pytest.mark.smoke
 class TestKVRollback:
-    def _mgr(self, paged, dtype=jnp.float32):
-        if paged:
-            return PagedKVManager(layers=1, heads=1, head_dim=4,
-                                  slots=2, max_seq_len=64, block=4,
-                                  dtype=dtype, prefix_share=False)
-        return KVCacheManager(layers=1, heads=1, head_dim=4, slots=2,
-                              max_seq_len=64, dtype=dtype)
+    def _mgr(self, dtype=jnp.float32):
+        return PagedKVManager(layers=1, heads=1, head_dim=4,
+                              slots=2, max_seq_len=64, block=4,
+                              dtype=dtype, prefix_share=False)
 
-    @pytest.mark.parametrize("paged", [False, True],
-                             ids=["contiguous", "paged"])
     @pytest.mark.parametrize("dtype", [jnp.float32, "int8"],
-                             ids=["f32", "int8"])
-    def test_speculate_rollback_equals_replay(self, paged, dtype):
+                             ids=["f32-paged", "int8-paged"])
+    def test_speculate_rollback_equals_replay(self, dtype):
         """Property: after randomized propose/accept/reject rounds,
         the live cache region equals a never-speculated replay byte
-        for byte — on both layouts and the int8 variant (whose scale
+        for byte — the float pool and the int8 variant (whose scale
         planes must truncate in lockstep)."""
         rng = np.random.RandomState(7)
-        spec = self._mgr(paged, dtype)
-        replay = self._mgr(paged, dtype)
-        if paged:
-            slot_s, _ = spec.alloc("r", [1, 2, 3], 40)
-            slot_r, _ = replay.alloc("r", [1, 2, 3], 40)
-        else:
-            slot_s = spec.alloc("r", 0)
-            slot_r = replay.alloc("r", 0)
+        spec = self._mgr(dtype)
+        replay = self._mgr(dtype)
+        slot_s, _ = spec.alloc("r", [1, 2, 3], 40)
+        slot_r, _ = replay.alloc("r", [1, 2, 3], 40)
         canonical = lambda pos: float(np.sin(pos + 1))  # noqa: E731
         n = 0
         for rnd in range(10):
@@ -487,24 +395,21 @@ class TestKVRollback:
             vals = [canonical(n + j) if j < keep
                     else 1e3 + rnd * 10 + j          # rejected garbage
                     for j in range(q)]
-            _write_positions(spec, slot_s, range(n, n + q), vals,
-                             paged=paged)
+            _write_positions(spec, slot_s, range(n, n + q), vals)
             spec.advance(slot_s, q)
             spec.truncate(slot_s, n + keep)
             _write_positions(replay, slot_r, range(n, n + keep),
-                             [canonical(n + j) for j in range(keep)],
-                             paged=paged)
+                             [canonical(n + j) for j in range(keep)])
             replay.advance(slot_r, keep)
             n += keep
         assert int(spec.lengths[slot_s]) == n
-        assert _live_bytes(spec, slot_s, paged) == \
-            _live_bytes(replay, slot_r, paged)
-        if paged:
-            assert spec.free_blocks == replay.free_blocks
+        assert _live_bytes(spec, slot_s) == _live_bytes(replay, slot_r)
+        assert spec.free_blocks == replay.free_blocks
 
     def test_truncate_errors(self):
-        m = self._mgr(False)
-        slot = m.alloc("r", 5)
+        m = self._mgr()
+        slot, _ = m.alloc("r", [1, 2, 3, 4, 5], 10)
+        m.advance(slot, 5)
         with pytest.raises(ValueError):
             m.truncate(slot, 6)        # beyond filled
         with pytest.raises(ValueError):
@@ -526,7 +431,7 @@ class TestKVRollback:
         s0, cached = m.alloc("a", prompt, 16)
         assert cached == 0
         _write_positions(m, s0, range(10),
-                         [float(t) for t in prompt], paged=True)
+                         [float(t) for t in prompt])
         m.advance(s0, 10)
         m.register_prefix(np.asarray(prompt), s0)
         # a second request attaches the shared prefix
@@ -535,7 +440,7 @@ class TestKVRollback:
         shared = [int(b) for b in m.tables[s1, :cached // m.block]]
         assert all(m.ref[b] >= 2 for b in shared)
         m.advance(s1, 12 - cached)   # pretend the tail got written
-        before = _live_bytes(m, s0, True)
+        before = _live_bytes(m, s0)
         cow0 = m.cow_copies
         # roll s1 back INTO the shared region (mid-block: position 6)
         m.truncate(s1, 6)
@@ -548,9 +453,9 @@ class TestKVRollback:
         for b in shared:
             assert m.ref[b] >= 1
             assert b not in m._free
-        assert _live_bytes(m, s0, True) == before
+        assert _live_bytes(m, s0) == before
         # s1's live content below the cut is intact too
-        got = _live_bytes(m, s1, True)
+        got = _live_bytes(m, s1)
         want = [np.full((1, 1, 4), float(t), np.float32).tobytes()
                 for t in prompt[:6]]
         assert [g[0] for g in got] == want
@@ -584,7 +489,7 @@ class TestKVRollback:
         p, cfg = model
         eng = ServingEngine(p, cfg, slots=2, queue_limit=16, spec=3,
                             spec_adapt=False, spec_draft_layers=1,
-                            paged=True, kv_block=4, prefix_share=False)
+                            kv_block=4, prefix_share=False)
         eng.run(_mk())
         assert eng.kv.free_blocks == eng.kv.n_blocks - 1
         assert int(np.sum(eng.kv.ref[1:])) == 0

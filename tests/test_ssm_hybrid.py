@@ -207,8 +207,7 @@ def test_config_refuses_what_it_cannot_run(key, value):
 
 
 def test_engine_refuses_other_paths_by_name(params, cfg):
-    for kw, what in ((dict(paged=False), "contiguous"),
-                     (dict(spec=2), "speculation"),
+    for kw, what in ((dict(spec=2), "speculation"),
                      (dict(kv_quant="int8"), "int8")):
         with pytest.raises(ValueError, match=what):
             engine(params, cfg, **kw)

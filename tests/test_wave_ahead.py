@@ -62,13 +62,11 @@ def _capacity_moe():
     return init_moe_params(cfg, name="moe", seed=0), cfg
 
 
-PAGED = dict(paged=True, kv_block=4, prefill_chunk=8, max_seq_len=64,
+PAGED = dict(kv_block=4, prefill_chunk=8, max_seq_len=64,
              prefix_share=False)
 KINDS = {
     "gpt2-paged": (_gpt, dict(PAGED, fast_path=False)),
     "gpt2-paged-kernel": (_gpt, dict(PAGED, fast_path=True)),
-    "gpt2-contiguous": (_gpt, dict(paged=False, fast_path=False)),
-    "gpt2-contiguous-kernel": (_gpt, dict(paged=False, fast_path=True)),
     "gpt2-int8": (_gpt, dict(PAGED, fast_path=False, kv_quant="int8")),
     "gpt2-shared-prefix": (_gpt, dict(PAGED, fast_path=False,
                                       prefix_share=True)),
@@ -231,8 +229,7 @@ def test_capacity_routed_engine_accounts_at_landing():
 # the rule
 # --------------------------------------------------------------------- #
 
-@pytest.mark.parametrize("kind", ["gpt2-paged", "gpt2-contiguous",
-                                  "conv-gqa-routed"])
+@pytest.mark.parametrize("kind", ["gpt2-paged", "conv-gqa-routed"])
 def test_every_first_chunk_rides_the_parents_wave(kind):
     """A closed loop that answers every completion at once: each
     request's first chunk is in the wave an engine that never runs
@@ -291,8 +288,7 @@ def test_a_speculative_engine_never_runs_ahead():
 # an ending nobody could foresee
 # --------------------------------------------------------------------- #
 
-@pytest.mark.parametrize("kind", ["gpt2-shared-prefix", "gpt2-contiguous",
-                                  "attention-ssm"])
+@pytest.mark.parametrize("kind", ["gpt2-shared-prefix", "attention-ssm"])
 def test_an_eos_ending_leaves_one_dead_row_and_harms_nobody(kind):
     """A request that ends on ``eos_id`` has a row in the wave already
     launched: the row is dropped and counted, its write touches no other

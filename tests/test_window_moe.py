@@ -724,40 +724,8 @@ def test_the_engines_steps_pass_the_ring_check_and_top_shows_the_pool(
 
 
 # ------------------------------------------------------------------ #
-# the accepted cells' programs: the parent's text
+# the accepted cells' programs (tests/test_program_digests.py pins them)
 # ------------------------------------------------------------------ #
-
-# sha256[:16] of the lowered mixed step of a small lfm2_moe and a small
-# falcon_h1 configuration (the two accepted cells whose block spec shares
-# ``HybridMoEConfig`` / the grouped-query branch with this PR) at two
-# q-block buckets x has_fresh, as the PARENT of PR 42 lowered them
-# (commit c2d3500); GPT-2's and the latent block's are
-# tests/test_hybrid_moe.py's PARENT_MASKED and tests/test_chip_compile.py's
-# PARENT_RAGGED, which this PR leaves as they were.
-PARENT_HYBRID_MASKED = {
-    "lfm2.Q1.fresh0": "d5335fb3237bcd18", "lfm2.Q1.fresh1": "43b61d3f0c614ec1",
-    "lfm2.Q32.fresh0": "34501c1582f67ac1",
-    "lfm2.Q32.fresh1": "90e43d41275f0717",
-    "falcon.Q1.fresh0": "5ed3ea5756c9fb13",
-    "falcon.Q1.fresh1": "471086ba72e5dab2",
-    "falcon.Q32.fresh0": "7a593dcee24143b8",
-    "falcon.Q32.fresh1": "e1c7e7369a2d3dbb"}
-# ... and for the described chip with the Pallas kernels, each Mosaic
-# kernel's body as location-free assembly (test_chip_compile's rule)
-PARENT_HYBRID_RAGGED = {
-    "lfm2.Q1.fresh0": "d8e968f0ef99f889", "lfm2.Q1.fresh1": "d8e968f0ef99f889",
-    "lfm2.Q32.fresh0": "1c3dd14502830c9d",
-    "lfm2.Q32.fresh1": "1c3dd14502830c9d",
-    "falcon.Q1.fresh0": "b452b65dabd73846",
-    "falcon.Q1.fresh1": "b452b65dabd73846",
-    "falcon.Q32.fresh0": "0aee2c839734b68c",
-    "falcon.Q32.fresh1": "0aee2c839734b68c"}
-# (the four Q 32 entries are PR 43's: with 4 and 2 query heads a K/V
-# head the rows kernel of a chunk program holds its step at two heights,
-# ``ragged_attention.tile_heights`` and ``rows_tiling``; the parent of
-# PR 43 lowered them to 4305fadbb584f1c8 and c49aeded6dc2f4d8.  The Q 1
-# programs have one height and are the parent's text.)
-
 
 def hybrid_programs(sds, attn):
     """{name: lowered mixed step} of a small ``lfm2_moe`` and a small
@@ -814,32 +782,6 @@ def hybrid_programs(sds, attn):
                     sds((B,), jnp.float32), i32(B), sds((B, 2), jnp.uint32),
                     attn=attn, window=1, has_fresh=fresh, state=state)
     return out
-
-
-def test_the_hybrid_cells_waves_lower_to_the_parents_stablehlo():
-    from test_hybrid_moe import digest
-    got = {k: digest(low.as_text()) for k, low in hybrid_programs(
-        jax.ShapeDtypeStruct, "masked").items()}
-    assert got == PARENT_HYBRID_MASKED
-
-
-def test_the_hybrid_cells_kernel_waves_lower_to_the_parents(sds,
-                                                            monkeypatch):
-    """The same programs with the Pallas kernels, for the described
-    chip: ``window`` 0 is the kernel there was, operation for
-    operation."""
-    from test_chip_compile import strip_kernel_locations
-    from test_hybrid_moe import digest
-    from hetu_tpu.kernels import grouped_matmul as gm
-    monkeypatch.setattr(ra, "_use_interpret", lambda: False)
-    monkeypatch.setattr(gm, "_use_interpret", lambda: False)
-    got = {}
-    for name, lowered in hybrid_programs(sds, "ragged").items():
-        text = lowered.as_text()
-        assert "tpu_custom_call" in text
-        assert "ragged_paged_window" not in text
-        got[name] = digest(strip_kernel_locations(text))
-    assert got == PARENT_HYBRID_RAGGED
 
 
 def test_a_window_spec_lowers_its_own_program(cfg):
