@@ -133,7 +133,7 @@ def col_tile(K, N, itemsize=2, budget=_RHS_BLOCK_BYTES):
 
 
 def _gmm_kernel(offs_ref, gid_ref, tid_ref, steps_ref, first_ref, ord_ref,
-                next_ref, lhs_ref, *refs, tm, tn, n_rhs):
+                next_ref, lhs_ref, *refs, tm, tn, n_rhs, act=None):
     """Grid (column tile ``n``, step ``s``).  The expert's ``[K, tn]``
     blocks are copied by hand a GROUP ahead: at a group's first step its
     own copy is awaited and the next group's started into the other
@@ -178,6 +178,8 @@ def _gmm_kernel(offs_ref, gid_ref, tid_ref, steps_ref, first_ref, ord_ref,
         if n_rhs == 2:
             acc = jax.nn.silu(acc) * jnp.dot(
                 x, w_buf[1][slot], preferred_element_type=jnp.float32)
+        elif act == "relu2":
+            acc = jnp.square(jnp.maximum(acc, 0.0))
         row = tid_ref[s] * tm + jax.lax.broadcasted_iota(
             jnp.int32, acc.shape, 0)
         mine = (row >= offs_ref[g]) & (row < offs_ref[g + 1])
@@ -186,12 +188,14 @@ def _gmm_kernel(offs_ref, gid_ref, tid_ref, steps_ref, first_ref, ord_ref,
                                  out_ref[...])
 
 
-@functools.partial(jax.jit, static_argnames=("tm", "tn", "interpret"))
-def _gmm_call(tiles, lhs, *rhs, tm, tn, interpret):
+@functools.partial(jax.jit,
+                   static_argnames=("tm", "tn", "interpret", "act"))
+def _gmm_call(tiles, lhs, *rhs, tm, tn, interpret, act=None):
     """The kernel over ``lhs`` [M, K] and one ``rhs`` [G, K, N], or two
     (gate, up) for the gated product.  Steps innermost: a group's steps
     are consecutive, so its ``[K, tn]`` block is copied once a column
-    tile."""
+    tile.  ``act`` "relu2": the one product's squared ReLU, on the
+    float32 accumulator."""
     M, K = lhs.shape
     G, _, N = rhs[0].shape
 
@@ -211,7 +215,8 @@ def _gmm_call(tiles, lhs, *rhs, tm, tn, interpret):
         + [pltpu.SemaphoreType.DMA((len(rhs), 2))],
     )
     return pl.pallas_call(
-        functools.partial(_gmm_kernel, tm=tm, tn=tn, n_rhs=len(rhs)),
+        functools.partial(_gmm_kernel, tm=tm, tn=tn, n_rhs=len(rhs),
+                          act=act),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((M, N), lhs.dtype),
         compiler_params=pltpu.CompilerParams(
@@ -223,12 +228,16 @@ def _gmm_call(tiles, lhs, *rhs, tm, tn, interpret):
 
 
 def grouped_matmul_tiled(lhs, rhs, tiles, *, up=None, tm=TILE_M, tn=None,
-                         interpret=None):
+                         interpret=None, act=None):
     """``lhs`` [M, K] (rows sorted by group, ``M`` a multiple of ``tm``)
     times ``rhs`` [G, K, N] under ``tiles = group_tiles(sizes, M, tm)``;
     rows past the groups' sum come out as anything.  With ``up`` [G, K,
-    N] the result is ``silu(lhs rhs) * (lhs up)``."""
+    N] the result is ``silu(lhs rhs) * (lhs up)``; with ``act`` "relu2"
+    (and no ``up``) it is ``relu(lhs rhs) ** 2``."""
     M, K = lhs.shape
+    if act is not None and (act != "relu2" or up is not None):
+        raise ValueError(f"act={act!r} with up={up is not None}: the "
+                         f"kernel has relu2 on one product alone")
     if M % tm:
         raise ValueError(f"{M} rows are no whole number of {tm}-row tiles")
     if tiles.group_ids.shape[0] != grid_steps(M, rhs.shape[0], tm):
@@ -238,4 +247,5 @@ def grouped_matmul_tiled(lhs, rhs, tiles, *, up=None, tm=TILE_M, tn=None,
     both = (rhs,) if up is None else (rhs, up)
     if tn is None:
         tn = col_tile(K, rhs.shape[2], rhs.dtype.itemsize)
-    return _gmm_call(tiles, lhs, *both, tm=tm, tn=tn, interpret=interpret)
+    return _gmm_call(tiles, lhs, *both, tm=tm, tn=tn, interpret=interpret,
+                     act=act)

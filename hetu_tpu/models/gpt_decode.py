@@ -391,9 +391,34 @@ class MuP(NamedTuple):
     lm_head: float = 1.0
 
 
+# Every operator a layer of ``BlockSpec.ops`` may name, with what such a
+# layer KEEPS from one wave to the next: "pool" (K/V pages of every
+# position), "window" (K/V pages in the window pool), "state" (slot state
+# beside the pool); "none" is a layer with no operator at all (an FFN
+# alone on its one norm), which keeps nothing.  ``BlockSpec.holds`` and
+# ``check_block_spec`` read this table; so does the latter's error text.
+OPERATORS = {
+    "attention": ("pool",),
+    "window_attention": ("window",),
+    "conv": ("state",),
+    "attention+ssm": ("pool", "state"),
+    "retention": ("state",),
+    "ssm": ("state",),
+    "none": (),
+}
+# every kind of FFN a layer may have; "none": a layer that is its
+# operator alone on its one norm
+FFN_KINDS = ("gelu", "swiglu", "routed", "none")
+# the operators that keep slot state, by the kind of state: one manager
+# holds ONE set, so a spec names operators of one kind alone
+STATE_KINDS = {"conv": "conv", "attention+ssm": "ssm", "ssm": "ssm",
+               "retention": "retention"}
+
+
 class BlockSpec(NamedTuple):
     """norm: "layernorm" (scale and bias) | "rmsnorm"; positions:
-    "learned" (a table added to the embedding) | "rope" (rotate-half
+    "learned" (a table added to the embedding) | "none" (nothing added
+    and nothing rotated: the ``nemotron_h`` family's attention) | "rope" (rotate-half
     with ``rope_theta``: over ``latent.qk_rope_head_dim``, or over the
     whole head of a K/V attention; ``rope_by_op`` gives the rotary
     parameters BY OPERATOR instead, ``((operator, inv_freq, factor),
@@ -405,8 +430,7 @@ class BlockSpec(NamedTuple):
     K/V attention's projections carry biases (GPT-2's do); qk_norm:
     every head's q and k RMS-normalised over its columns with a learned
     scale before the rotation; ops: the OPERATOR of every layer, a tuple
-    of "attention" | "window_attention" | "conv" | "attention+ssm" |
-    "retention"
+    of ``OPERATORS``' names
     (None: attention everywhere), a "window_attention" layer being that
     K/V attention over the last ``window`` positions alone (a query sees
     itself and the ``window - 1`` before it), its K/V pages in the
@@ -420,14 +444,21 @@ class BlockSpec(NamedTuple):
     front end (projections, q/k norm, rotation) into power retention
     (``retention``, a ``retention_decode.RetentionSpec``) in place of
     the softmax over pages, so that it holds slot state and NO page (a
-    spec of such layers alone has no pool); head_dim: the head size where it is the
+    spec of such layers alone has no pool), an "ssm" layer running the
+    state-space mixer ALONE on the layer's one norm (state, no page) and
+    a "none" layer running no operator (it keeps nothing); head_dim: the head size where it is the
     configuration's own key and not ``hidden / heads`` (0: that
     quotient); mup: the block's forward multipliers (``MuP``; None: no
     multiplication anywhere); ffn: the kind of every layer from
     ``leading_dense`` on, "gelu" | "swiglu" | "routed" (a
     ``moe_decode.RoutedSpec`` in ``routed``; the leading layers are
-    dense SwiGLU); head: "tied" (the embedding table) | "untied"
-    (``{name}_lm_head_weight`` [hidden, vocab])."""
+    dense SwiGLU); ffns: the FFN kind BY LAYER instead, a tuple of
+    ``FFN_KINDS``' names, "none" being a layer that is its operator
+    alone (None: ``ffn`` as above; with it ``ffn`` says whether any
+    layer routes); a layer has ONE norm for each part it has: ``ln1``
+    before its operator, ``ln2`` before its FFN; head: "tied" (the
+    embedding table) | "untied" (``{name}_lm_head_weight`` [hidden,
+    vocab])."""
 
     norm: str = "layernorm"
     norm_eps: float = 1e-5
@@ -450,10 +481,13 @@ class BlockSpec(NamedTuple):
     window: int = 0
     rope_by_op: Optional[tuple] = None
     retention: Optional[tuple] = None
+    ffns: Optional[tuple] = None
 
     def ffn_kind(self, i):
-        """Layer ``i``'s FFN: the leading layers of a routed model are
-        dense SwiGLU."""
+        """Layer ``i``'s FFN: its entry of ``ffns``; else ``ffn``, the
+        leading layers of a routed model being dense SwiGLU."""
+        if self.ffns:
+            return self.ffns[i]
         if self.ffn == "routed" and i < self.leading_dense:
             return "swiglu"
         return self.ffn
@@ -462,8 +496,7 @@ class BlockSpec(NamedTuple):
         return sum(1 for i in range(L) if self.ffn_kind(i) == "routed")
 
     def op_kind(self, i):
-        """Layer ``i``'s operator: "attention" | "window_attention" |
-        "conv" | "attention+ssm" | "retention"."""
+        """Layer ``i``'s operator, one of ``OPERATORS``' names."""
         return self.ops[i] if self.ops else "attention"
 
     def holds(self, i, what):
@@ -471,21 +504,19 @@ class BlockSpec(NamedTuple):
         position: a layer with an attention over everything), "window"
         (K/V pages in the window pool: a window layer) or "state" (slot
         state beside the pool: a conv, a state-space mixer or a
-        retention layer)."""
-        kind = self.op_kind(i)
-        if what == "window":
-            return kind == "window_attention"
-        if what == "pool":
-            return kind in ("attention", "attention+ssm")
-        return kind in ("conv", "attention+ssm", "retention")
+        retention layer).  ``OPERATORS`` says which; a layer with no
+        operator keeps none of them."""
+        return what in OPERATORS[self.op_kind(i)]
 
     def op_index(self, i, what=None):
         """Layer ``i``'s place among the layers that keep what it keeps:
         an attention layer's index into the K/V pool, a window layer's
-        into the window pool, a conv or a retention layer's into the
-        state; a layer that keeps two says ``what``."""
-        what = what or {"conv": "state", "window_attention": "window",
-                        "retention": "state"}.get(self.op_kind(i), "pool")
+        into the window pool, a conv, mixer or retention layer's into
+        the state; a layer that keeps two says ``what`` (else its
+        pages'); None for a layer that keeps nothing."""
+        what = what or next(iter(OPERATORS[self.op_kind(i)]), None)
+        if what is None:
+            return None
         return sum(1 for j in range(i) if self.holds(j, what))
 
     def rope_of(self, i):
@@ -521,10 +552,7 @@ class BlockSpec(NamedTuple):
 
 GPT2_BLOCK = BlockSpec()
 
-# every operator a layer of ``BlockSpec.ops`` may name, and every kind of
-# rotary frequencies ``rope_frequencies`` makes
-OPERATORS = ("attention", "window_attention", "conv", "attention+ssm",
-             "retention")
+# every kind of rotary frequencies ``rope_frequencies`` makes
 ROPE_KINDS = ("default", "yarn")
 
 
@@ -581,69 +609,95 @@ def block_spec_of(config):
 
 def check_block_spec(blk, layers=None):
     """Raise for a spec the mixed wave cannot run.  It runs GPT-2's
-    block; latent attention with RMSNorm and RoPE; and grouped-query
-    K/V attention with RMSNorm, RoPE over the whole head, no biases and
-    an optional per-head q/k norm, every layer's operator either that
-    attention or the gated short convolution (``ops``; a conv layer
-    needs ``conv_kernel`` >= 2 taps, and ``ops`` names ``layers`` of
-    them) or that attention over a sliding window ("window_attention",
-    which needs ``window`` >= 1 and is mixed with plain "attention"
-    layers alone: the window pool has no state beside it) or both that
-    attention and a state-space mixer on one norm
-    ("attention+ssm", which needs an ``ssm`` spec and is not mixed with
-    "conv" layers: the two keep different state) or that attention's
-    front end into power retention ("retention", which needs a
-    ``retention`` spec of degree 2 over the block's own K/V heads and
-    head size, and is alone or mixed with plain "attention" layers: its
-    layers hold state and no page, so a spec of them alone has no pool):
-    each over any of the three FFN kinds and either head.  Multipliers (``mup``), a head
-    size of the configuration's own and rotary parameters by operator
-    (``rope_by_op``: "default" or "yarn" frequencies, made by
-    ``rope_frequencies``) go with the grouped-query block alone; a
-    routed FFN scores by "sigmoid" or "softmax"."""
+    block; latent attention with RMSNorm and RoPE over any FFN kind of
+    ``ffn``; and the grouped-query block: RMSNorm, no biases, an
+    optional per-head q/k norm, positions "rope" (over the whole head)
+    or "none", every layer ONE of ``OPERATORS`` and one of
+    ``FFN_KINDS``, at least one of the two not "none".  What each
+    operator needs is ``_OPERATOR_NEEDS``' entry; operators that keep
+    slot state are of ONE kind a spec (``STATE_KINDS``: the manager
+    holds one set), and a window or a retention layer is mixed with
+    plain "attention" layers alone.  Multipliers (``mup``), a head size
+    of the configuration's own and rotary parameters by operator
+    (``rope_by_op``, of ``ROPE_KINDS``) go with the grouped-query block
+    alone; a routed FFN scores by one of ``moe_decode.SCORINGS``, has
+    experts of one of ``moe_decode.EXPERT_FORMS`` and holds all its
+    experts or a contiguous share of them.  ``layers``: the model's
+    depth, which ``ops`` and ``ffns`` then name layer by layer."""
     if blk == GPT2_BLOCK:
         return
-    common = (blk.norm, blk.positions) == ("rmsnorm", "rope") \
-        and (blk.ffn == "routed") == (blk.routed is not None) \
-        and blk.ffn in ("gelu", "swiglu", "routed") \
+    from .moe_decode import EXPERT_FORMS, SCORINGS
+    rt = blk.routed
+    kinds = set(blk.ffns or (blk.ffn,))
+    common = blk.norm == "rmsnorm" \
+        and (blk.ffn == "routed") == (rt is not None) \
+        and kinds <= set(FFN_KINDS) \
+        and (blk.ffns is None or (
+            ("routed" in kinds) == (rt is not None)
+            and not blk.leading_dense
+            and (layers is None or len(blk.ffns) == layers))) \
         and blk.head in ("tied", "untied") \
-        and (blk.routed is None
-             or blk.routed.scoring in ("sigmoid", "softmax"))
+        and (rt is None or (
+            rt.scoring in SCORINGS and rt.expert in EXPERT_FORMS
+            and rt.latent >= 0 and 0 <= rt.held_first
+            and 0 <= rt.held
+            and rt.held_first + rt.held <= rt.num_experts))
     if blk.attention == "latent":
-        ok = common and blk.latent is not None and blk.ops is None \
+        ok = common and blk.positions == "rope" \
+            and blk.latent is not None and blk.ops is None \
+            and blk.ffns is None \
             and blk.ssm is None and blk.mup is None and not blk.head_dim \
             and not blk.window and blk.rope_by_op is None \
             and blk.retention is None
     else:
         ops = blk.ops or ()
+        n = max(len(ops), len(blk.ffns or ()))
         ok = common and blk.attention == "gqa" and blk.latent is None \
+            and blk.positions in ("rope", "none") \
             and not blk.bias and blk.kv_heads >= 1 \
             and all(o in OPERATORS for o in ops) \
-            and ("conv" not in ops or blk.conv_kernel >= 2) \
-            and ("attention+ssm" in ops) == (blk.ssm is not None) \
-            and not ("attention+ssm" in ops and "conv" in ops) \
-            and ("window_attention" in ops) == (blk.window >= 1) \
-            and ("window_attention" not in ops
-                 or set(ops) <= {"attention", "window_attention"}) \
-            and ("retention" in ops) == (blk.retention is not None) \
-            and ("retention" not in ops or (
-                set(ops) <= {"attention", "retention"}
-                and blk.retention.degree == 2
-                and blk.retention.kv_heads == blk.kv_heads
-                and blk.retention.head_dim == blk.head_dim
-                and blk.head_dim % 2 == 0 and blk.mup is None)) \
+            and len({STATE_KINDS[o] for o in ops if o in STATE_KINDS}) <= 1 \
+            and all(need(blk, ops) for need in _OPERATOR_NEEDS.values()) \
+            and all(blk.op_kind(i) != "none" or blk.ffn_kind(i) != "none"
+                    for i in range(n)) \
             and all(op in ops and factor > 0
                     for op, _, factor in blk.rope_by_op or ()) \
+            and (blk.positions == "rope" or blk.rope_by_op is None) \
             and (layers is None or not ops or len(ops) == layers)
     if not ok:
         raise ValueError(
             f"the mixed wave runs GPT-2's block, latent attention with "
-            f"rmsnorm and rope, or grouped-query attention with rmsnorm "
-            f"and rope (operators {', '.join(OPERATORS)}; rotary kinds "
-            f"{', '.join(ROPE_KINDS)}; routers sigmoid, softmax): beside "
-            f"gated short convolutions or a state-space mixer, or over a "
-            f"sliding window beside full layers, or with power retention "
-            f"of degree 2 in the softmax's place; it cannot run {blk}")
+            f"rmsnorm and rope, or the grouped-query block with rmsnorm "
+            f"and positions rope or none, a layer one operator of "
+            f"{', '.join(OPERATORS)} (state of one kind a spec; window "
+            f"and retention layers beside plain attention alone) and one "
+            f"FFN of {', '.join(FFN_KINDS)}, not both none; rotary kinds "
+            f"{', '.join(ROPE_KINDS)}; routers {', '.join(SCORINGS)}; "
+            f"experts {', '.join(EXPERT_FORMS)}: it cannot run {blk}")
+
+
+def _retention_fits(blk, ops):
+    return set(ops) <= {"attention", "retention"} \
+        and blk.retention.degree == 2 \
+        and blk.retention.kv_heads == blk.kv_heads \
+        and blk.retention.head_dim == blk.head_dim \
+        and blk.head_dim % 2 == 0 and blk.mup is None
+
+
+# What a spec that names an operator must carry (and one that does not
+# must not), asked of (spec, its ops): ``check_block_spec``'s one table.
+_OPERATOR_NEEDS = {
+    "conv": lambda blk, ops: "conv" not in ops or blk.conv_kernel >= 2,
+    "ssm": lambda blk, ops: (blk.ssm is not None) == bool(
+        {"attention+ssm", "ssm"} & set(ops)),
+    "window_attention": lambda blk, ops:
+        ("window_attention" in ops) == (blk.window >= 1)
+        and ("window_attention" not in ops
+             or set(ops) <= {"attention", "window_attention"}),
+    "retention": lambda blk, ops:
+        ("retention" in ops) == (blk.retention is not None)
+        and ("retention" not in ops or _retention_fits(blk, ops)),
+}
 
 
 def head_dim_of(config):
@@ -1461,8 +1515,13 @@ def _ffn_of_kind(params, us, blk, h, i, valid, stats, moe=None):
     under a ``MoESpec``, its capacity-routed experts) or a dense SwiGLU
     under the scope ``mlp``, or the dropless routed FFN with its shared
     expert (``moe_decode.routed_ffn``, scopes ``moe_route``,
-    ``moe_experts``, ``moe_shared``)."""
+    ``moe_experts``, ``moe_shared``, and ``moe_latent_in`` /
+    ``moe_latent_out`` where the experts work at a latent width), or, of
+    a layer that is its operator alone ("none"), nothing: ``h`` as it
+    came."""
     kind = blk.ffn_kind(i)
+    if kind == "none":
+        return h
     if kind == "gelu":
         with jax.named_scope("mlp"):
             return _ffn_block(params, us, h, i, moe=moe, valid=valid,
@@ -1681,7 +1740,10 @@ def _mixed_step(params, cfg_tuple, cache_k, cache_v, pos, tokens,
     ``ssm_conv``, ``ssm_scan``, ``state_write``, ``ssm_out``) on ONE
     norm, ``state`` then being the mixer's set (a conv tail and a
     float32 matrix state a layer) and the two outputs summed into the
-    residual; or, where it says "retention", ``_retention_operator``
+    residual; or, where it says "ssm", that mixer ALONE on the layer's
+    one norm; or, where it says "none", no operator at all (the layer is
+    its FFN alone, on ``ln2``; a layer whose FFN kind is "none" is its
+    operator alone, on ``ln1``); or, where it says "retention", ``_retention_operator``
     (``ret_qkvg``, ``ret_expand``, ``ret_scan``, ``state_write``,
     ``ret_out``): the K/V attention's front end (``_qkv_heads``, shared
     with it) into ``retention_decode.retention_mixer`` over ``state``,
@@ -1809,6 +1871,19 @@ def _mixed_wave(params, cfg_tuple, cache_k, cache_v, pos, tokens, q_len,
         if blk.op_kind(i) == "conv":
             h, state = _conv_operator(params, us, blk, h, state,
                                       blk.op_index(i), q_len, rows)
+            h = _ffn_of_kind(params, us, blk, h, i, valid_r, moe_stats)
+            continue
+        if blk.op_kind(i) == "ssm":
+            # the state-space mixer alone, on the layer's one norm
+            from .ssm_decode import ssm_mixer
+            with jax.named_scope("ssm_in"):
+                x = _norm(blk, params, f"{us}_ln1", h)
+            y_ssm, state = ssm_mixer(params, us, blk, x, state,
+                                     blk.op_index(i), q_len, rows)
+            h = _ffn_of_kind(params, us, blk, h + y_ssm, i, valid_r,
+                             moe_stats)
+            continue
+        if blk.op_kind(i) == "none":
             h = _ffn_of_kind(params, us, blk, h, i, valid_r, moe_stats)
             continue
         if blk.op_kind(i) == "retention":
@@ -1973,11 +2048,17 @@ def _serve_mixed_paged(params, cfg_tuple, cache_k, cache_v, tables,
             sd, _moe_of(cfg_tuple),
             jnp.sum(jnp.clip(q_len, 0, tokens.shape[1]))),)
     if routed is not None:
-        # (load [E] summed over the routed layers, experts touched
-        # summed over them): dropless, so there is no drop element
-        z = jnp.zeros((routed.num_experts,), jnp.int32)
-        out = out + ((jnp.asarray(sd.get("load", z), jnp.int32),
-                      jnp.asarray(sd.get("touched", 0), jnp.int32)),)
+        # (load [held] summed over the routed layers, experts touched
+        # summed over them): dropless, so there is no drop element; a
+        # spec that holds a share of its experts adds ALL the
+        # assignments routed, of which the load's are the ones that
+        # landed here
+        z = jnp.zeros((routed.held_experts,), jnp.int32)
+        rs = (jnp.asarray(sd.get("load", z), jnp.int32),
+              jnp.asarray(sd.get("touched", 0), jnp.int32))
+        if routed.holds_a_share:
+            rs = rs + (jnp.asarray(sd.get("routed", 0), jnp.int32),)
+        out = out + (rs,)
     if state is not None:
         out = out + (state,)
     return out + tuple(win_out)

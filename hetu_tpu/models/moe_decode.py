@@ -31,6 +31,16 @@ compiled step and surfaced by the serving wrappers, so expert
 imbalance — THE MoE production failure mode — is a first-class
 observable in telemetry, ``hetu_top``, and the bench artifact.
 
+Two routers live here.  The CAPACITY router above (``MoESpec``,
+:func:`moe_ffn`: GPT-2's block, static capacity, dropped tokens) always
+holds every expert.  The DROPLESS router (``RoutedSpec``,
+:func:`routed_ffn`: the block-spec families' FFN, no capacity, grouped
+matmuls over sorted assignments) is the one that can be told it holds a
+SHARE of its experts (``RoutedSpec.held``): it still scores and chooses
+over all of them and computes the part its own give, as one chip of an
+expert-parallel deployment does; it can also put its experts at a latent
+width and give them a squared-ReLU form.
+
 Speculative decoding: the truncated-layer draft SKIPS ROUTING ENTIRELY
 (``MoESpec.draft``) — its MoE layers contribute zero FFN (attention +
 residual only), so drafting needs no dispatch, no capacity, and no
@@ -235,17 +245,40 @@ def _gelu_tanh(x):
 # The other router there is (one for both is ROADMAP C8): no capacity,
 # no dropped token, no [T, E, cap] tensor.  Cost follows the rows routed
 # and the experts touched: the T x k assignments are sorted by expert and
-# run through grouped matmuls over the stacked expert leaves.
+# run through grouped matmuls over the stacked expert leaves.  It is THIS
+# router, and not the capacity router above, that can be told it holds a
+# SHARE of the experts (``RoutedSpec.held``): one chip's part of an
+# expert-parallel deployment, computed as that chip computes it.
+
+# how a router scores, and what an expert is
+SCORINGS = ("sigmoid", "softmax")
+EXPERT_FORMS = ("gated_silu", "relu2")
 
 
 class RoutedSpec(NamedTuple):
-    """The routed FFN of a ``gpt_decode.BlockSpec`` (``ffn="routed"``).
+    """The dropless routed FFN of a ``gpt_decode.BlockSpec`` (a layer
+    whose FFN kind is "routed"; the capacity router's spec is
+    ``MoESpec``, which holds every expert always).
     ``scoring`` "sigmoid": sigmoid scores, the ``top_k`` largest of
     ``score + bias`` chosen; "softmax": a softmax over all the experts,
     the ``top_k`` largest chosen, no selection bias.  Either way the
     weights are the scores at the chosen, normalised when ``norm_topk``
     and scaled by ``scale``; ``n_shared`` shared experts' width is
-    ``n_shared`` times an expert's."""
+    ``n_shared`` times an expert's (the leaves carry the width, so a
+    configuration whose shared width is its own key says 1).
+
+    What the layer is told beside that.  ``held`` / ``held_first``: the
+    experts ``[held_first, held_first + held)`` are the ones this layer
+    HOLDS (0: all ``num_experts``).  The router still scores all
+    ``num_experts`` and chooses ``top_k`` of them, the weights are
+    normalised over all the chosen, and the layer computes the part its
+    held experts give: the expert leaves are ``[held, ...]``.
+    ``latent``: the experts work at this width, between a projection
+    ``hidden -> latent`` before them and one ``latent -> hidden`` behind
+    them (0: at the hidden width, no projection).  ``expert``:
+    "gated_silu" (three matrices, ``silu(x W_gate) * (x W_up)`` then
+    ``W_down``) | "relu2" (two matrices, ``relu(x W_up) ** 2`` then
+    ``W_down``); the shared expert has the same form."""
 
     num_experts: int
     top_k: int
@@ -253,6 +286,19 @@ class RoutedSpec(NamedTuple):
     norm_topk: bool = True
     n_shared: int = 0
     scoring: str = "sigmoid"
+    held_first: int = 0
+    held: int = 0
+    latent: int = 0
+    expert: str = "gated_silu"
+
+    @property
+    def held_experts(self):
+        """How many experts the layer holds."""
+        return self.held or self.num_experts
+
+    @property
+    def holds_a_share(self):
+        return self.held_experts < self.num_experts
 
 
 def route(x, w_router, bias, spec):
@@ -291,32 +337,50 @@ def route(x, w_router, bias, spec):
 KERNEL_ROWS_A_GROUP = 8
 
 
-def takes_kernel(rows, groups):
+def takes_kernel(rows, groups, landing=None):
     """The shape rule: whether a grouped matmul of ``rows`` sorted rows
     over ``groups`` groups runs through ``kernels/grouped_matmul`` (else
-    through ``jax.lax.ragged_dot``).  Static shapes alone decide, so a
-    program is one or the other, and the engine can ask the same
-    question of a wave's row count (``serve.moe.kernel_waves``)."""
+    through ``jax.lax.ragged_dot``).  ``landing``: how many of the rows
+    can be expected to land on the groups, where the layer holds a share
+    of the experts and the others' rows sort behind every group (None:
+    all of them).  Static shapes alone decide, so a program is one or
+    the other, and the engine can ask the same question of a wave's row
+    count (``serve.moe.kernel_waves``)."""
     from ..kernels.grouped_matmul import TILE_M
-    return rows % TILE_M == 0 and rows >= KERNEL_ROWS_A_GROUP * groups
+    landing = rows if landing is None else landing
+    return rows % TILE_M == 0 and landing >= KERNEL_ROWS_A_GROUP * groups
 
 
-def kernel_tiles(group_sizes, rows):
+def landing_rows(rows, spec):
+    """Of ``rows`` assignment rows, those a layer of ``spec`` can expect
+    on the experts it holds (uniform routing): all of them where it
+    holds all."""
+    return rows * spec.held_experts // spec.num_experts
+
+
+def kernel_tiles(group_sizes, rows, landing=None):
     """The Pallas kernel's (group, row tile) steps for ``rows`` sorted
     rows in groups of ``group_sizes``, or None where the shape rule
     leaves the product with ``jax.lax.ragged_dot``.  A caller with
     several products over the same groups makes them once."""
-    if not takes_kernel(rows, group_sizes.shape[0]):
+    if not takes_kernel(rows, group_sizes.shape[0], landing):
         return None
     from ..kernels.grouped_matmul import group_tiles
     return group_tiles(group_sizes, rows)
 
 
-def grouped_matmul(lhs, rhs, group_sizes, up=None, tiles=None):
+def _relu2(x):
+    return jnp.square(jax.nn.relu(x))
+
+
+def grouped_matmul(lhs, rhs, group_sizes, up=None, tiles=None, act=None):
     """``lhs`` [M, K] rows sorted by group times ``rhs`` [G, K, N], group
     ``g`` owning the next ``group_sizes[g]`` rows; rows past the groups'
     sum come out as anything.  With ``up`` [G, K, N] the gated pair
-    ``silu(lhs rhs) * (lhs up)``.  One algorithm, two tilings, chosen by
+    ``silu(lhs rhs) * (lhs up)``; with ``act`` "relu2" the product's
+    squared ReLU (in the Pallas kernel an epilogue on the float32
+    accumulator before its one rounding; behind ``ragged_dot`` an
+    elementwise pass of the compiler's).  One algorithm, two tilings, chosen by
     :func:`takes_kernel` from ``M`` and ``G``: with few rows a group
     ``jax.lax.ragged_dot``, which the v5e compiler turns into its own
     kernel and the chip runs at the cost of the experts touched (a
@@ -325,16 +389,20 @@ def grouped_matmul(lhs, rhs, group_sizes, up=None, tiles=None):
     whole-K block of the expert's matrix read once a call, the gated
     pair in one call (PR 41: the compiler's kernel took three times its
     bytes' time there).  ``tiles``: ``kernel_tiles(group_sizes, M)`` if
-    the caller has made them."""
+    the caller has made them, False if it asked and the shape rule said
+    no (a layer that holds a share of its experts asks with the rows
+    that can land on them)."""
     if tiles is None:
         tiles = kernel_tiles(group_sizes, lhs.shape[0])
-    if tiles is None:
+    if not tiles:
         y = jax.lax.ragged_dot(lhs, rhs, group_sizes)
+        if act == "relu2":
+            return _relu2(y)
         if up is None:
             return y
         return jax.nn.silu(y) * jax.lax.ragged_dot(lhs, up, group_sizes)
     from ..kernels.grouped_matmul import grouped_matmul_tiled
-    return grouped_matmul_tiled(lhs, rhs, tiles, up=up)
+    return grouped_matmul_tiled(lhs, rhs, tiles, up=up, act=act)
 
 
 def routed_ffn(params, us, x, spec, valid=None, stats=None):
@@ -343,34 +411,62 @@ def routed_ffn(params, us, x, spec, valid=None, stats=None):
     (padding, dead slots) are routed NOWHERE: they sort behind every
     expert's group, no expert counts them and their output is 0 from
     the routed part (batch company changes no live row's result: each
-    row's experts and weights depend on that row alone).  Leaves:
+    row's experts and weights depend on that row alone).  Where the
+    layer holds a share of the experts (``spec.held``) an assignment to
+    an expert NOT held goes where an invalid row's goes: the router has
+    scored all ``num_experts`` and normalised over all the chosen, and
+    the layer adds what its own experts give (a row none of whose
+    chosen experts is held gets the shared expert alone).  Nothing here
+    stands in for the chips that hold the others.  Leaves:
     ``{us}_moe_router_weight`` [D, E], ``{us}_moe_router_bias`` [E] (the
     selection bias; a softmax router has none),
-    ``{us}_moe_experts_gate``/``_up`` [E, D, F],
-    ``{us}_moe_experts_down`` [E, F, D], ``{us}_moe_shared_gate_weight``
-    / ``_up_weight`` [D, n_shared F], ``_down_weight`` [n_shared F, D].
+    ``{us}_moe_experts_gate``/``_up`` [held, W, F],
+    ``{us}_moe_experts_down`` [held, F, W] (``W`` the latent width, else
+    D; a "relu2" expert has no gate), ``{us}_moe_latent_in_weight`` [D,
+    W] and ``_latent_out_weight`` [W, D] where there is a latent width,
+    ``{us}_moe_shared_gate_weight`` / ``_up_weight`` [D, Fs],
+    ``_down_weight`` [Fs, D].
 
     ``stats`` (dict, mutated at trace time) accumulates over the routed
-    layers ``load`` [E] int32 (assignments an expert, so that the sum
-    of ``load`` is valid rows x top_k x layers) and ``touched`` (experts
-    with load > 0, summed over layers)."""
-    E, k = spec.num_experts, spec.top_k
+    layers ``load`` [held] int32 (assignments a held expert, so that
+    where all are held the sum of ``load`` is valid rows x top_k x
+    layers), ``touched`` (held experts with load > 0, summed over
+    layers) and, of a layer that holds a share, ``routed`` (ALL the
+    valid rows' assignments, wherever they went)."""
+    E, k = spec.held_experts, spec.top_k
     T, D = x.shape
     vmask = (jnp.ones((T,), bool) if valid is None
              else valid.reshape(T).astype(bool))
+    relu2 = spec.expert == "relu2"
+    xe = x
+    if spec.latent:
+        with jax.named_scope("moe_latent_in"):
+            xe = x @ params[f"{us}_moe_latent_in_weight"]
     with jax.named_scope("moe_route"):
         sel, w = route(x, params[f"{us}_moe_router_weight"],
                        params.get(f"{us}_moe_router_bias"), spec)
-        # an invalid row's assignments go to group E, past the last
-        expert = jnp.where(vmask[:, None], sel, E).reshape(-1)  # [T k]
+        # an invalid row's assignments go to group E, past the last; so
+        # do the assignments to experts this layer does not hold
+        here = vmask[:, None]
+        if spec.holds_a_share:
+            sel = sel - spec.held_first
+            here = here & (sel >= 0) & (sel < E)
+        expert = jnp.where(here, sel, E).reshape(-1)        # [T k]
         order = jnp.argsort(expert, stable=True)
         load = jnp.sum(expert[:, None] == jnp.arange(E)[None, :], axis=0,
                        dtype=jnp.int32)
-        xs = x[order // k]                                  # [T k, D]
+        xs = xe[order // k]                                 # [T k, W]
     with jax.named_scope("moe_experts"):
-        tiles = kernel_tiles(load, T * k)           # once for the layer
-        a = grouped_matmul(xs, params[f"{us}_moe_experts_gate"], load,
-                           up=params[f"{us}_moe_experts_up"], tiles=tiles)
+        # once for the layer
+        tiles = kernel_tiles(load, T * k, landing_rows(T * k, spec)) \
+            or False
+        if relu2:
+            a = grouped_matmul(xs, params[f"{us}_moe_experts_up"], load,
+                               tiles=tiles, act="relu2")
+        else:
+            a = grouped_matmul(xs, params[f"{us}_moe_experts_gate"], load,
+                               up=params[f"{us}_moe_experts_up"],
+                               tiles=tiles)
         ys = grouped_matmul(a, params[f"{us}_moe_experts_down"], load,
                             tiles=tiles)
     with jax.named_scope("moe_route"):
@@ -379,20 +475,31 @@ def routed_ffn(params, us, x, spec, valid=None, stats=None):
         # the groups may hold anything, so it is zeroed, not weighted 0
         back = jnp.argsort(order)
         live = jnp.arange(T * k) < jnp.sum(load)
-        y = jnp.where(live[:, None], ys, 0)[back].reshape(T, k, D)
+        y = jnp.where(live[:, None], ys, 0)[back].reshape(
+            T, k, ys.shape[-1])
         wv = jnp.where(vmask[:, None], w, 0.0)
         # k weighted rows a token, summed in float32 (one fused pass)
         y = sum(y[:, j].astype(jnp.float32) * wv[:, j, None]
                 for j in range(k)).astype(x.dtype)
+    if spec.latent:
+        with jax.named_scope("moe_latent_out"):
+            y = y @ params[f"{us}_moe_latent_out_weight"]
     if spec.n_shared:
-        from .gpt_decode import swiglu
         with jax.named_scope("moe_shared"):
-            y = y + swiglu(x, params[f"{us}_moe_shared_gate_weight"],
-                           params[f"{us}_moe_shared_up_weight"],
-                           params[f"{us}_moe_shared_down_weight"])
+            if relu2:
+                y = y + _relu2(x @ params[f"{us}_moe_shared_up_weight"]) \
+                    @ params[f"{us}_moe_shared_down_weight"]
+            else:
+                from .gpt_decode import swiglu
+                y = y + swiglu(x, params[f"{us}_moe_shared_gate_weight"],
+                               params[f"{us}_moe_shared_up_weight"],
+                               params[f"{us}_moe_shared_down_weight"])
     if stats is not None:
         stats["load"] = stats.get("load", 0) + load
         stats["touched"] = stats.get("touched", 0) + jnp.sum(load > 0)
+        if spec.holds_a_share:
+            stats["routed"] = stats.get("routed", 0) \
+                + k * jnp.sum(vmask, dtype=jnp.int32)
     return y
 
 

@@ -454,6 +454,24 @@ class SSMHybridConfig:
 F32_LEAVES = ("_ssm_dt_bias", "_ssm_A_log", "_ssm_D")
 
 
+def init_recurrence_constant(name, key, shape, dt_range, a_range):
+    """The seeded value of one of ``F32_LEAVES`` by the family's
+    initialisation (``D`` 1, ``A_log`` the logarithm of ``A`` uniform in
+    ``a_range``, ``dt_bias`` the inverse softplus of ``dt`` log-uniform
+    in ``dt_range``), float32; None for any other leaf."""
+    if name.endswith("_ssm_D"):
+        return jnp.ones(shape, jnp.float32)
+    if name.endswith("_ssm_A_log"):
+        return jnp.log(jax.random.uniform(
+            key, shape, jnp.float32, a_range[0], a_range[1]))
+    if name.endswith("_ssm_dt_bias"):
+        dt = jnp.exp(jax.random.uniform(
+            key, shape, jnp.float32, math.log(dt_range[0]),
+            math.log(dt_range[1])))
+        return dt + jnp.log(-jnp.expm1(-dt))
+    return None
+
+
 def init_ssm_hybrid_params(config, name="fh1", seed=0, gains=None,
                            dtype=jnp.float32, dt_range=(0.001, 0.1),
                            a_range=(1.0, 16.0)):
@@ -497,18 +515,12 @@ def init_ssm_hybrid_params(config, name="fh1", seed=0, gains=None,
                                    for w, s in zip(widths, slices)])
         for k, (n, shape) in zip(jax.random.split(key, len(shapes)),
                                  sorted(shapes.items())):
+            constant = init_recurrence_constant(n, k, shape, dt_range,
+                                                a_range)
             if n.endswith("_scale"):
                 out[n] = jnp.ones(shape, dtype)
-            elif n.endswith("_ssm_D"):
-                out[n] = jnp.ones(shape, jnp.float32)
-            elif n.endswith("_ssm_A_log"):
-                out[n] = jnp.log(jax.random.uniform(
-                    k, shape, jnp.float32, a_range[0], a_range[1]))
-            elif n.endswith("_ssm_dt_bias"):
-                dt = jnp.exp(jax.random.uniform(
-                    k, shape, jnp.float32, math.log(dt_range[0]),
-                    math.log(dt_range[1])))
-                out[n] = dt + jnp.log(-jnp.expm1(-dt))
+            elif constant is not None:
+                out[n] = constant
             elif n.endswith("_ssm_in_weight"):
                 out[n] = (jax.random.normal(k, shape, jnp.float32)
                           * in_cols).astype(dtype)

@@ -50,7 +50,7 @@ from ..models.gpt_decode import (
 )
 from ..kernels.ragged_attention import (
     mla_rows_tiling, mla_tiling, row_tile_visits, rows_tiling, tile_heights)
-from ..models.moe_decode import takes_kernel
+from ..models.moe_decode import landing_rows, takes_kernel
 from ..models.retention_decode import takes_kernel as retention_takes_kernel
 from .kv_manager import (PagedKVManager, assemble_mixed_wave,
                          resolve_kv_block, resolve_kv_quant)
@@ -274,8 +274,9 @@ class ServingEngine:
                 c.num_hidden_layers)
         # layers with a state-space mixer: their waves count
         # ``serve.ssm.*`` (``ServingMetrics.record_state_scan``)
-        self._ssm_layers = self.block_spec.op_layers(
-            c.num_hidden_layers, "attention+ssm")
+        self._ssm_layers = sum(
+            self.block_spec.op_layers(c.num_hidden_layers, op)
+            for op in ("attention+ssm", "ssm"))
         # ... and with power retention: ``serve.ret.*``
         # (the same); layers with an attention of any kind (none:
         # the engine's ``serve.attn.*`` stay 0 and no kernel runs)
@@ -615,16 +616,23 @@ class ServingEngine:
         ``record_step`` payload.  Load and experts touched come out of
         the compiled step; the rows are the wave descriptor's; whether
         the program took the grouped-matmul kernel is the shape rule's
-        answer for the rows it ran over."""
+        answer for the rows it ran over.  Of a layer that holds a share
+        of its experts the load is over the held ones: ``held`` of the
+        ``routed`` assignments landed here (all of them where every
+        expert is held)."""
         load = np.asarray(routed_out[0], np.int64)
         touched = int(routed_out[1])
         rows = int(wave["q_len"].astype(np.int64).sum())
-        self.metrics.record_routed(
-            load, touched, kernel=takes_kernel(
-                rows_computed * self.routed.top_k, self.routed.num_experts))
         assignments = int(load.sum())
+        routed = int(routed_out[2]) if len(routed_out) > 2 else assignments
+        sorted_rows = rows_computed * self.routed.top_k
+        self.metrics.record_routed(
+            load, touched, routed=routed, kernel=takes_kernel(
+                sorted_rows, len(load),
+                landing_rows(sorted_rows, self.routed)))
         mean = assignments / len(load)
-        return {"tokens": rows, "routed": assignments, "dropped": 0,
+        share = {"held": assignments} if self.routed.holds_a_share else {}
+        return {"tokens": rows, "routed": routed, "dropped": 0, **share,
                 "k": self.routed.top_k, "layers": self._routed_layers,
                 "imb": float(load.max()) / mean if mean > 0 else 0.0,
                 "drop_rate": 0.0}

@@ -203,6 +203,7 @@ class ServingMetrics(MetricsCore):
         # a dropless routed engine's running counts (``record_routed``);
         # ``moe_load`` is None until the first routed wave
         self.moe_assignments = 0
+        self.moe_assignments_routed = 0
         self.moe_experts_touched = 0
         self.moe_kernel_waves = 0
         self.moe_load = None
@@ -350,10 +351,13 @@ class ServingMetrics(MetricsCore):
         return {"slot_steps": steps, "rows": rows,
                 "live_slots": int(live_slots), "layers": int(layers)}
 
-    def record_routed(self, load, touched, kernel=False):
-        """One wave of a dropless routed engine: ``load`` [E]
-        (assignments an expert, summed over the routed layers),
-        ``touched`` (experts with load > 0, summed over them) and
+    def record_routed(self, load, touched, kernel=False, routed=None):
+        """One wave of a dropless routed engine: ``load`` [held experts]
+        (assignments an expert the layers hold, summed over the routed
+        layers), ``touched`` (held experts with load > 0, summed over
+        them), ``routed`` (ALL the assignments the router made, of which
+        the load's landed on held experts; None: every expert is held
+        and it is the load's sum: ``serve.moe.assignments_routed``) and
         ``kernel`` (the wave's program ran its experts' products through
         ``kernels/grouped_matmul``: ``moe_decode.takes_kernel`` of its
         row count).  Running sums here (``snapshot(since=mark)`` windows
@@ -363,11 +367,14 @@ class ServingMetrics(MetricsCore):
         ``telemetry``."""
         load = np.asarray(load, np.int64)
         assignments = int(load.sum())
+        routed = assignments if routed is None else int(routed)
         self.moe_assignments += assignments
+        self.moe_assignments_routed += routed
         self.moe_experts_touched += int(touched)
         self.moe_load = (load.copy() if self.moe_load is None
                          else self.moe_load + load)
         telemetry.inc("serve.moe.assignments", assignments)
+        telemetry.inc("serve.moe.assignments_routed", routed)
         telemetry.inc("serve.moe.experts_touched", int(touched))
         if kernel:
             self.moe_kernel_waves += 1
@@ -521,6 +528,9 @@ class ServingMetrics(MetricsCore):
             fields["moe_tokens"] = int(moe.get("tokens", 0))
             fields["moe_routed"] = int(moe.get("routed", 0))
             fields["moe_dropped"] = int(moe.get("dropped", 0))
+            if "held" in moe:
+                # of the routed, those that landed on experts held here
+                fields["moe_held"] = int(moe["held"])
             fields["moe_k"] = int(moe.get("k", 0))
             fields["moe_layers"] = int(moe.get("layers", 0))
             fields["moe_imb"] = round(float(moe.get("imb", 0.0)), 4)
@@ -652,7 +662,8 @@ class ServingMetrics(MetricsCore):
                    "step_dt", "step_tokens", "prefill_dt")
     _MARK_COUNTS = ("submitted", "rejected", "finished",
                     "tokens_generated", "prefill_batched",
-                    "moe_assignments", "moe_experts_touched",
+                    "moe_assignments", "moe_assignments_routed",
+                    "moe_experts_touched",
                     "moe_kernel_waves", "attn_ctx_tokens", "attn_score_pairs",
                     "attn_tiles_live", "attn_tiles_short",
                     "attn_q_tiles_moved",
@@ -727,6 +738,7 @@ class ServingMetrics(MetricsCore):
             mean = float(load.mean())
             routed = {
                 "moe_assignments": count("moe_assignments"),
+                "moe_assignments_routed": count("moe_assignments_routed"),
                 "moe_experts_touched": count("moe_experts_touched"),
                 "moe_kernel_waves": count("moe_kernel_waves"),
                 "moe_load": [int(x) for x in load],

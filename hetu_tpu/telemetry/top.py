@@ -188,13 +188,16 @@ def summarize(events, window=512):
     # MoE serving (ISSUE 20): serve_step events from MoE engines carry
     # the wave's routing outcome — expert-load imbalance (max/mean) is
     # THE MoE production failure mode, so it gets a panel
-    moe_routed = moe_dropped = 0
+    moe_routed = moe_dropped = moe_held = 0
     moe_imb = None
     moe_steps = 0
     for s in steps:
         if isinstance(s.get("moe_routed"), int):
             moe_steps += 1
             moe_routed += s["moe_routed"]
+            # a layer that holds a share of its experts stamps how many
+            # of the routed landed on them; else all did
+            moe_held += s.get("moe_held", s["moe_routed"])
             moe_dropped += s.get("moe_dropped", 0) or 0
             if isinstance(s.get("moe_imb"), (int, float)):
                 moe_imb = s["moe_imb"]
@@ -205,6 +208,8 @@ def summarize(events, window=512):
         tot = moe_routed + moe_dropped
         moe = {"routed": moe_routed, "dropped": moe_dropped,
                "imbalance": moe_imb,
+               "held_share": round(moe_held / moe_routed, 4)
+               if moe_routed else None,
                "drop_rate": round(moe_dropped / tot, 4) if tot else 0.0}
     # window layers (ISSUE 42): the window pool's gauges and the ring
     # the newest wave stamped
@@ -619,6 +624,7 @@ def render(stats, clock=None):
             f"experts   routed {me['routed']}"
             f"  dropped {me['dropped']}"
             f"  imbalance {_fmt(me['imbalance'], nd=2)}"
+            f"  held {_fmt(me.get('held_share'), nd=4)}"
             f"  drop_rate {_fmt(me['drop_rate'], nd=4)}"))
     kw = s.get("kv_window")
     if kw:

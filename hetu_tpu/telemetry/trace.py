@@ -707,8 +707,11 @@ def check_moe_attribution(events):
     a token to the residual path (``moe_dropped``), it NEVER vanishes
     from the ledger, so the two sides always balance.  Records without
     ``moe_routed`` (dense engines) are exempt; a MoE record missing any
-    of its companion fields is itself a violation.  Returns problem
-    strings."""
+    of its companion fields is itself a violation.  A record of an
+    engine whose expert layers hold a SHARE of their experts (ISSUE 48)
+    carries ``moe_held`` beside it, the routed assignments that landed
+    on held experts: an integer within ``[0, moe_routed]``.  Returns
+    problem strings."""
     problems = []
     for e in events:
         if e.get("event") != "serve_step":
@@ -734,6 +737,14 @@ def check_moe_attribution(events):
                 f"{fields['tokens']} tokens x top_k {fields['k']} x "
                 f"{fields['layers']} MoE layer(s) = {want} — a token "
                 f"left the routing ledger")
+        held = e.get("moe_held")
+        if held is not None and not (isinstance(held, int)
+                                     and 0 <= held <= routed):
+            problems.append(
+                f"moe-attribution: step {e.get('step')!r} has "
+                f"{held!r} assignments on held experts of {routed} "
+                f"routed — a layer that holds a share of its experts "
+                f"computes at most what was routed")
     return problems
 
 

@@ -802,3 +802,82 @@ def test_retention_mixed_step_rewrites_the_states_where_they_lie(
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= L * B * 8 * 8256 * 129 * 4
     assert mem.temp_size_in_bytes < one_state / 2
+
+
+# ------------------------------------------------------------------- #
+# ISSUE 48: the one-part layers and the held experts at the cell's sizes
+# ------------------------------------------------------------------- #
+
+@pytest.mark.parametrize("q_len,pattern", [
+    (1, "M*E"), (64, "M*E"), (128, "M*E"), (256, None)],
+    ids=["Q1-one_of_each", "Q64-one_of_each", "Q128-one_of_each", "Q256"])
+def test_nemotron_wave_programs_at_the_published_widths(sds, monkeypatch,
+                                                        q_len, pattern):
+    """The agent cell's four wave programs at the published widths, 64
+    slots and the cell's pool through ``serve_mixed_paged_fn``.  The
+    widest chunk program holds all eleven layers (5 mixers, 1 attention,
+    5 expert layers of 128 held experts of 512); the other three one
+    layer of each kind (the same kernels at their own tiles; the whole
+    depth of all four is in the configuration's ``memory_analysis``: a
+    whole-depth compile is 40-70 s here, and the suite has a limit).  The
+    pool pair and every state array updated in place, no temporary of a
+    state array's size (the compiler, short of memory, once recomputed a
+    recurrence: PR 37), ONE attention kernel call, the held experts'
+    products by the rule: the compiler's ``ragged-dot`` in the decode
+    wave (352 landing rows on 128 experts), ``moe_grouped_matmul`` twice
+    a layer in the chunk waves."""
+    import json
+    import os
+    from hetu_tpu.kernels import grouped_matmul as gm
+    from hetu_tpu.kernels import ragged_attention as ra
+    from hetu_tpu.models import nemotron_h as nh
+    monkeypatch.setattr(ra, "_use_interpret", lambda: False)
+    monkeypatch.setattr(gm, "_use_interpret", lambda: False)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "configs",
+                           "nemotron-3-super-120b-a12b.json")) as f:
+        conf = json.load(f)
+    args = conf["runner_args"]
+    source = dict(conf, n_routed_experts=conf["published"][
+        "n_routed_experts"])
+    if pattern:
+        source.update(hybrid_override_pattern=pattern,
+                      num_hidden_layers=len(pattern))
+    cfg = nh.NemotronHConfig.from_hf(
+        source, held_experts=tuple(conf["deployment"]["experts_held"]))
+    blk = cfg.block_spec()
+    L, B, S = cfg.num_hidden_layers, args["slots"], args["max_seq_len"]
+    mixers = cfg.pattern.count("M")
+    T, N = S // BLOCK, args["pool_blocks"]
+    params = {k: sds(s, jnp.float32 if "_moe_router_" in k
+                     or k.endswith(nh.F32_LEAVES) else jnp.bfloat16)
+              for k, s in cfg.param_shapes("nmh").items()}
+    pool = sds((1, N, BLOCK, kv_row_width(2, 128)), jnp.bfloat16)
+    state = tuple(
+        sds((sh[0], B) + tuple(sh[1:]), jnp.bfloat16 if dt is None else dt)
+        for sh, dt in blk.state_shapes(L, cfg.hidden_size))
+    i32 = lambda *shape: sds(shape, jnp.int32)  # noqa: E731
+    fn = gd.serve_mixed_paged_fn(donate=True, attn="ragged", window=1)
+    compiled = fn.func.lower(
+        params, ("nmh", L, 32, 128, S, blk), pool, pool, i32(B, T), i32(B),
+        i32(B, q_len), i32(B), i32(B), sds((B,), jnp.bool_),
+        sds((B,), jnp.float32), i32(B), sds((B, 2), jnp.uint32),
+        attn="ragged", window=1, has_fresh=q_len > 1, state=state).compile()
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines() if "custom-call(" in line]
+    assert sum("ragged_paged_mixed" in c for c in calls) == 1
+    experts = sum("moe_grouped_matmul" in c for c in calls)
+    assert experts == (0 if q_len == 1 else 2 * cfg.pattern.count("E"))
+    assert ("ragged-dot" in text) == (q_len == 1)
+    mem = compiled.memory_analysis()
+    pool_bytes = 2 * N * BLOCK * kv_row_width(2, 128) * 2
+    one_state = B * 128 * 64 * 128 * 4
+    tails = mixers * B * 3 * 10240 * 2
+    assert mem.alias_size_in_bytes >= pool_bytes + mixers * one_state + tails
+    # the widest program's temporaries are the packed rows' (1,024 x
+    # 18,560 projections, 22,528 sorted rows x 2,688): 0.4 GB, growing
+    # with neither the pool nor the states
+    assert mem.temp_size_in_bytes < 2 * one_state
+    peak = mem.argument_size_in_bytes + mem.output_size_in_bytes \
+        - mem.alias_size_in_bytes + mem.temp_size_in_bytes
+    assert peak < 12e9

@@ -86,7 +86,8 @@ def test_config_reads_the_sources_keys(cfg):
     assert (blk.attention, blk.kv_heads, blk.qk_norm, blk.bias) == (
         "gqa", 2, True, False)
     assert (blk.conv_kernel, blk.leading_dense, blk.head) == (3, 2, "tied")
-    assert blk.routed == (8, 2, 1.0, True, 0, "sigmoid")
+    assert blk.routed[:6] == (8, 2, 1.0, True, 0, "sigmoid")
+    assert not blk.routed.holds_a_share and blk.routed.latent == 0
     assert [blk.op_index(i) for i in range(6)] == [0, 1, 0, 2, 1, 3]
     assert blk.op_layers(6, "conv") == 4 and blk.routed_layers(6) == 4
     gd.check_block_spec(blk, 6)

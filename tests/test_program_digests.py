@@ -32,6 +32,7 @@ import pytest
 from test_chip_compile import (  # noqa: E402,F401
     no_compile_cache, sds, strip_kernel_locations, topo)
 from test_hybrid_moe import digest, wave_programs
+from test_nemotron_h import nemotron_programs
 from test_retention import retention_programs, window_programs
 from test_window_moe import hybrid_programs
 
@@ -54,6 +55,8 @@ FAMILIES = {
     "PARENT_RETENTION_MASKED": (retention_programs, False),
     "PARENT_PACKED_MASKED": (lambda s: packed_programs(s, "masked"), False),
     "PARENT_PACKED_RAGGED": (lambda s: packed_programs(s, "ragged"), True),
+    "NEMOTRON_MASKED": (lambda s: nemotron_programs(s, "masked"), False),
+    "NEMOTRON_RAGGED": (lambda s: nemotron_programs(s, "ragged"), True),
 }
 
 PARENT = {
@@ -139,6 +142,23 @@ PARENT = {
     "PARENT_PACKED_RAGGED": {
         "gpt2.Q32.fresh1": "ee10736bb0265878",
         "latent.Q32.fresh1": "89ef03c3aad79ffc"},
+    # PR 48's own, no parent's: tests/test_nemotron_h.py's small
+    # ``nemotron_h`` model (eleven one-part layers, positions "none",
+    # expert layers that hold experts [4, 8) of 16 at a latent width, the
+    # squared-ReLU experts).  4 slots x 32 rows x top-4 are 512 sorted
+    # rows of which 128 can land on 4 held experts, 32 a group: the Q 32
+    # pair takes ``kernels/grouped_matmul`` with the ``relu2`` epilogue;
+    # the Q 1 pair, 16 sorted rows, keeps ``ragged_dot``.
+    "NEMOTRON_MASKED": {
+        "nemotron.Q1.fresh0": "c1af25b0c15abce7",
+        "nemotron.Q1.fresh1": "d3a20ecdcb55212b",
+        "nemotron.Q32.fresh0": "7a29ba66835c6b6a",
+        "nemotron.Q32.fresh1": "01313047839bb51b"},
+    "NEMOTRON_RAGGED": {
+        "nemotron.Q1.fresh0": "acb5db193cd96995",
+        "nemotron.Q1.fresh1": "acb5db193cd96995",
+        "nemotron.Q32.fresh0": "7f0e176ec1f6c772",
+        "nemotron.Q32.fresh1": "7f0e176ec1f6c772"},
 }
 
 
