@@ -1,11 +1,14 @@
-"""A grouped matmul sized for the chunk wave (ISSUE 41).
+"""A grouped matmul whose time follows the touched groups' bytes
+(ISSUE 41: the chunk wave; ISSUE 49: the decode wave too).
 
 ``lhs`` [M, K] rows sorted by group times ``rhs`` [G, K, N], group ``g``
 owning the next ``group_sizes[g]`` rows: the routed experts' three
 products (``models/moe_decode.routed_ffn``).  The compiler's own kernel
-for ``jax.lax.ragged_dot`` is at its bytes where a group holds a few
-rows (a decode wave: 2-4 rows an expert) and at a third of them where it
-holds tens to hundreds (a chunk wave); this one is for the second case:
+for ``jax.lax.ragged_dot`` takes 1.4 to 5.3 times the touched experts'
+bytes where a group holds a few rows (a decode wave: 2-4 rows an
+expert) and about three times where it holds tens to hundreds (a chunk
+wave); this one takes 1.2 times in both (PERF.md section 6, PR 41 and
+PR 49):
 
   - the rows are cut into tiles of ``tm`` and a grid step is one
     (group, row tile) pair in the order of the rows, so that a tile two
@@ -44,7 +47,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ._shared import _LANES, _use_interpret
 
-# rows a tile (swept 64 / 128 / 256 on the chip: PERF.md section 6, PR 41)
+# rows a tile (swept 64 / 128 / 256 at a chunk wave's shapes, PR 41, and 16
+# to 128 at a decode wave's, all within 1 %, PR 49: PERF.md section 6)
 TILE_M = 128
 # bytes one ``[K, tn]`` block of an expert's matrix may take: a whole
 # [2048, 2048] bf16 matrix, so that at the cells' widths ``tn`` is N (the

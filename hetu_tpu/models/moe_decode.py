@@ -326,44 +326,31 @@ def route(x, w_router, bias, spec):
     return sel.astype(jnp.int32), w * spec.scale
 
 
-# Expected rows a group (static ``M // G``) from which the Pallas kernel
-# takes a routed layer's products.  A decode wave of the routed cells is
-# 128 assignment rows over 32 or 64 experts (4 or 2 a group) and keeps
-# the compiler's kernel; their chunk waves are 1,024 to 4,096 rows (16
-# to 128 a group), where the compiler's kernel takes 2.3 to 3.6 times
-# its weights' time and this one 1.2 to 1.3.  8 is the fewest rows a
-# group the chip sweep read above a decode wave's (256 rows over 32
-# experts: 0.35 ms against 0.65; PERF.md section 6, PR 41).
-KERNEL_ROWS_A_GROUP = 8
-
-
-def takes_kernel(rows, groups, landing=None):
+def takes_kernel(rows):
     """The shape rule: whether a grouped matmul of ``rows`` sorted rows
-    over ``groups`` groups runs through ``kernels/grouped_matmul`` (else
-    through ``jax.lax.ragged_dot``).  ``landing``: how many of the rows
-    can be expected to land on the groups, where the layer holds a share
-    of the experts and the others' rows sort behind every group (None:
-    all of them).  Static shapes alone decide, so a program is one or
-    the other, and the engine can ask the same question of a wave's row
-    count (``serve.moe.kernel_waves``)."""
+    runs through ``kernels/grouped_matmul`` (else through
+    ``jax.lax.ragged_dot``): wherever the rows are a whole number of the
+    kernel's row tiles, however few of them a group holds.  The kernel's
+    time follows the bytes of the groups that have rows; on the chip, at
+    loads taken from served decode waves (ms a layer's pair of products,
+    ``ragged_dot`` | the kernel | the touched experts' bytes at 819
+    GB/s; PERF.md section 6, PR 49): 1,408 rows over 128 groups of
+    [1024, 2688], 64 touched, 2.78 | 1.04 | 0.87; 256 rows over 64 of
+    [2304, 896] gated, 52 touched, 4.19 | 0.93 | 0.79; 128 rows over 64
+    of [2048, 1536] gated, 32 touched, 1.04 | 0.87 | 0.74; with 4 to 10
+    touched both take 0.2 ms.  A static shape alone decides, so a
+    program is one or the other, and the engine can ask the same
+    question of a wave's row count (``serve.moe.kernel_waves``)."""
     from ..kernels.grouped_matmul import TILE_M
-    landing = rows if landing is None else landing
-    return rows % TILE_M == 0 and landing >= KERNEL_ROWS_A_GROUP * groups
+    return rows % TILE_M == 0
 
 
-def landing_rows(rows, spec):
-    """Of ``rows`` assignment rows, those a layer of ``spec`` can expect
-    on the experts it holds (uniform routing): all of them where it
-    holds all."""
-    return rows * spec.held_experts // spec.num_experts
-
-
-def kernel_tiles(group_sizes, rows, landing=None):
+def kernel_tiles(group_sizes, rows):
     """The Pallas kernel's (group, row tile) steps for ``rows`` sorted
     rows in groups of ``group_sizes``, or None where the shape rule
     leaves the product with ``jax.lax.ragged_dot``.  A caller with
     several products over the same groups makes them once."""
-    if not takes_kernel(rows, group_sizes.shape[0], landing):
+    if not takes_kernel(rows):
         return None
     from ..kernels.grouped_matmul import group_tiles
     return group_tiles(group_sizes, rows)
@@ -380,18 +367,17 @@ def grouped_matmul(lhs, rhs, group_sizes, up=None, tiles=None, act=None):
     ``silu(lhs rhs) * (lhs up)``; with ``act`` "relu2" the product's
     squared ReLU (in the Pallas kernel an epilogue on the float32
     accumulator before its one rounding; behind ``ragged_dot`` an
-    elementwise pass of the compiler's).  One algorithm, two tilings, chosen by
-    :func:`takes_kernel` from ``M`` and ``G``: with few rows a group
-    ``jax.lax.ragged_dot``, which the v5e compiler turns into its own
-    kernel and the chip runs at the cost of the experts touched (a
-    decode wave: PERF.md section 6, PR 28); with tens to hundreds the
-    Pallas kernel of ``kernels/grouped_matmul``, 128-row tiles against a
-    whole-K block of the expert's matrix read once a call, the gated
-    pair in one call (PR 41: the compiler's kernel took three times its
-    bytes' time there).  ``tiles``: ``kernel_tiles(group_sizes, M)`` if
-    the caller has made them, False if it asked and the shape rule said
-    no (a layer that holds a share of its experts asks with the rows
-    that can land on them)."""
+    elementwise pass of the compiler's).  One algorithm, two tilings,
+    chosen by :func:`takes_kernel` from ``M``: rows that are whole row
+    tiles run through the Pallas kernel of ``kernels/grouped_matmul``,
+    128-row tiles against a whole-K block of the expert's matrix read
+    once a call, the gated pair in one call, at 1.2 times the touched
+    experts' bytes from a decode wave's 2 rows a group to a chunk
+    wave's hundreds (PR 41, PR 49: the compiler's kernel took 1.4 to
+    5.3 times them); other row counts (the small shapes of the tests)
+    through ``jax.lax.ragged_dot``.  ``tiles``: ``kernel_tiles(
+    group_sizes, M)`` if the caller has made them, False if it asked
+    and the shape rule said no."""
     if tiles is None:
         tiles = kernel_tiles(group_sizes, lhs.shape[0])
     if not tiles:
@@ -458,8 +444,7 @@ def routed_ffn(params, us, x, spec, valid=None, stats=None):
         xs = xe[order // k]                                 # [T k, W]
     with jax.named_scope("moe_experts"):
         # once for the layer
-        tiles = kernel_tiles(load, T * k, landing_rows(T * k, spec)) \
-            or False
+        tiles = kernel_tiles(load, T * k) or False
         if relu2:
             a = grouped_matmul(xs, params[f"{us}_moe_experts_up"], load,
                                tiles=tiles, act="relu2")

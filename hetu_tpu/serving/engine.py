@@ -50,7 +50,7 @@ from ..models.gpt_decode import (
 )
 from ..kernels.ragged_attention import (
     mla_rows_tiling, mla_tiling, row_tile_visits, rows_tiling, tile_heights)
-from ..models.moe_decode import landing_rows, takes_kernel
+from ..models.moe_decode import takes_kernel
 from ..models.retention_decode import takes_kernel as retention_takes_kernel
 from .kv_manager import (PagedKVManager, assemble_mixed_wave,
                          resolve_kv_block, resolve_kv_quant)
@@ -625,11 +625,9 @@ class ServingEngine:
         rows = int(wave["q_len"].astype(np.int64).sum())
         assignments = int(load.sum())
         routed = int(routed_out[2]) if len(routed_out) > 2 else assignments
-        sorted_rows = rows_computed * self.routed.top_k
         self.metrics.record_routed(
-            load, touched, routed=routed, kernel=takes_kernel(
-                sorted_rows, len(load),
-                landing_rows(sorted_rows, self.routed)))
+            load, touched, routed=routed,
+            kernel=takes_kernel(rows_computed * self.routed.top_k))
         mean = assignments / len(load)
         share = {"held": assignments} if self.routed.holds_a_share else {}
         return {"tokens": rows, "routed": routed, "dropped": 0, **share,

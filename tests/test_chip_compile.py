@@ -294,12 +294,13 @@ def test_ragged_paged_mla_at_the_published_widths(sds, q_block):
 
 
 def test_ragged_dot_becomes_the_compilers_grouped_matmul(sds):
-    """What ``moe_decode.grouped_matmul`` rests on: on the TPU
-    ``jax.lax.ragged_dot`` is not expanded into a dense product an
-    expert; it becomes one kernel with group metadata."""
+    """What ``moe_decode.grouped_matmul`` rests on where the rows are no
+    whole row tiles (120 here): on the TPU ``jax.lax.ragged_dot`` is not
+    expanded into a dense product an expert; it becomes one kernel with
+    group metadata."""
     from hetu_tpu.models.moe_decode import grouped_matmul
     compiled = jax.jit(grouped_matmul).lower(
-        sds((128, 2048), jnp.bfloat16), sds((64, 2048, 1536), jnp.bfloat16),
+        sds((120, 2048), jnp.bfloat16), sds((64, 2048, 1536), jnp.bfloat16),
         sds((64,), jnp.int32)).compile()
     assert "ragged-dot" in compiled.as_text()
     assert "tpu_custom_call" in compiled.as_text()
@@ -347,20 +348,21 @@ def test_grouped_matmul_at_the_cells_shapes(sds, cell, gated):
     assert "tpu_custom_call" in compiled and "moe_grouped_matmul" in compiled
 
 
-@pytest.mark.parametrize("rows,kernel", [(128, False), (4096, True)],
-                         ids=["decode-wave", "chunk-wave"])
+@pytest.mark.parametrize("rows,kernel",
+                         [(128, True), (4096, True), (8, False)],
+                         ids=["decode-wave", "chunk-wave", "no-whole-tile"])
 @pytest.mark.parametrize("experts,width", [(32, 1792), (64, 1536)],
                          ids=["lfm2", "glm"])
 def test_the_shape_rule_picks_the_product(sds, monkeypatch, rows, kernel,
                                           experts, width):
     """``moe_decode.grouped_matmul`` at a decode wave's 128 assignment
-    rows stays the compiler's ``ragged-dot`` (the parent's program) and
-    names no ``moe_grouped_matmul``; at a chunk wave's 4,096 it is the
-    reverse.  Nothing but the shapes differs between the two."""
+    rows (since PR 49) and at a chunk wave's 4,096 names
+    ``moe_grouped_matmul`` and no ``ragged-dot``; at 8 rows, no whole
+    row tile, it is the reverse.  Nothing but the row count differs."""
     from hetu_tpu.kernels import grouped_matmul as gm
     from hetu_tpu.models.moe_decode import grouped_matmul, takes_kernel
     monkeypatch.setattr(gm, "_use_interpret", lambda: False)
-    assert takes_kernel(rows, experts) == kernel
+    assert takes_kernel(rows) == kernel
     w = sds((experts, 2048, width), jnp.bfloat16)
     text = jax.jit(lambda x, g, u, n: grouped_matmul(x, g, n, up=u)).lower(
         sds((rows, 2048), jnp.bfloat16), w, w,
@@ -823,9 +825,10 @@ def test_nemotron_wave_programs_at_the_published_widths(sds, monkeypatch,
     pool pair and every state array updated in place, no temporary of a
     state array's size (the compiler, short of memory, once recomputed a
     recurrence: PR 37), ONE attention kernel call, the held experts'
-    products by the rule: the compiler's ``ragged-dot`` in the decode
-    wave (352 landing rows on 128 experts), ``moe_grouped_matmul`` twice
-    a layer in the chunk waves."""
+    products by the rule: ``moe_grouped_matmul`` twice a
+    layer in every wave, the decode wave's 1,408 sorted rows (352
+    landing on 128 experts) among them since PR 49, and no
+    ``ragged-dot`` anywhere."""
     import json
     import os
     from hetu_tpu.kernels import grouped_matmul as gm
@@ -867,8 +870,8 @@ def test_nemotron_wave_programs_at_the_published_widths(sds, monkeypatch,
     calls = [line for line in text.splitlines() if "custom-call(" in line]
     assert sum("ragged_paged_mixed" in c for c in calls) == 1
     experts = sum("moe_grouped_matmul" in c for c in calls)
-    assert experts == (0 if q_len == 1 else 2 * cfg.pattern.count("E"))
-    assert ("ragged-dot" in text) == (q_len == 1)
+    assert experts == 2 * cfg.pattern.count("E")
+    assert "ragged-dot" not in text
     mem = compiled.memory_analysis()
     pool_bytes = 2 * N * BLOCK * kv_row_width(2, 128) * 2
     one_state = B * 128 * 64 * 128 * 4

@@ -114,16 +114,53 @@ def test_kernel_equals_ragged_dot(case, orientation, G):
         rtol=BF16_ULP, atol=1e-6)
 
 
-def test_the_shape_rule_reads_rows_and_groups_alone():
-    # decode waves: 128 rows over 32 and 64 experts (4 and 2 a group)
-    assert not takes_kernel(128, 32) and not takes_kernel(128, 64)
+@pytest.mark.parametrize("form", ["plain", "gated", "relu2"])
+def test_a_decode_waves_shape_in_small_equals_ragged_dot(form):
+    """ISSUE 49: what a share-holding layer's decode wave hands the
+    kernel: eleven row tiles of which three are live, 160 groups of 1-4
+    rows, a third of them EMPTY (an expert without rows has no step; a
+    tile past the groups' sum has none)."""
+    rng = np.random.default_rng(49)
+    M, K, N, G = 11 * gm.TILE_M, 64, 128, 160
+    sizes = rng.integers(1, 5, G)
+    sizes[rng.permutation(G)[:G // 3]] = 0
+    n = int(sizes.sum())
+    assert 2 * gm.TILE_M < n <= 3 * gm.TILE_M
+    lhs = jnp.asarray(rng.standard_normal((M, K)), jnp.bfloat16)
+    rhs, up = (jnp.asarray(rng.standard_normal((G, K, N)) / 8, jnp.bfloat16)
+               for _ in range(2))
+    gs = jnp.asarray(sizes, jnp.int32)
+    tiles = moe_decode.kernel_tiles(gs, M)
+    assert [int(v) for v in tiles.steps][1] == int((sizes > 0).sum())
+    dot = lambda w: jax.lax.ragged_dot(                     # noqa: E731
+        lhs, w, gs, preferred_element_type=jnp.float32)
+    if form == "gated":
+        got = gm.grouped_matmul_tiled(lhs, rhs, tiles, up=up)
+        want = jax.nn.silu(dot(rhs)) * dot(up)
+    elif form == "relu2":
+        got = gm.grouped_matmul_tiled(lhs, rhs, tiles, act="relu2")
+        want = jnp.square(jax.nn.relu(dot(rhs)))
+    else:
+        got = gm.grouped_matmul_tiled(lhs, rhs, tiles)
+        want = dot(rhs)
+    np.testing.assert_allclose(
+        np.asarray(got[:n], np.float32),
+        np.asarray(want.astype(jnp.bfloat16)[:n], np.float32),
+        rtol=BF16_ULP, atol=1e-6)
+
+
+def test_the_shape_rule_reads_the_rows_alone():
+    # the routed cells' decode waves: 32 slots x top-4 and top-8, 64 x 22
+    # sorted rows (2 to 4 rows a group, 2.75 landing of 11): the kernel
+    # since PR 49, whose sweep read it ahead at every one of them
+    for M in (128, 256, 1408):
+        assert takes_kernel(M)
     # the chunk buckets Q 64 / 128 / 256: M = 1,024 / 2,048 / 4,096
     for M in (1024, 2048, 4096):
-        assert takes_kernel(M, 32) and takes_kernel(M, 64)
+        assert takes_kernel(M)
     # rows that are no whole tiles stay with the compiler
-    assert not takes_kernel(4096 + 8, 32)
-    k = moe_decode.KERNEL_ROWS_A_GROUP
-    assert takes_kernel(k * 64, 64) and not takes_kernel(k * 64 - 128, 64)
+    for M in (8, 64, 4096 + 8):
+        assert not takes_kernel(M)
 
 
 def test_grouped_matmul_takes_the_kernel_by_the_rule(monkeypatch):
@@ -138,7 +175,7 @@ def test_grouped_matmul_takes_the_kernel_by_the_rule(monkeypatch):
         gs = jnp.asarray([M // 8] * 8, jnp.int32)
         del calls[:]
         got = grouped_matmul(lhs, rhs, gs)
-        assert bool(calls) == kernel == takes_kernel(M, 8)
+        assert bool(calls) == kernel == takes_kernel(M)
         np.testing.assert_allclose(
             np.asarray(got, np.float32),
             np.asarray(jax.lax.ragged_dot(lhs, rhs, gs), np.float32),
@@ -164,9 +201,10 @@ def routed():
 
 
 def _with_compilers_kernel(monkeypatch):
-    """What the rule gives below its threshold, asked for here above it
-    (the test's steering: the program has no such switch)."""
-    monkeypatch.setattr(moe_decode, "KERNEL_ROWS_A_GROUP", 1 << 30)
+    """What the rule gives rows that are no whole tiles, asked for here
+    at whole tiles (the test's steering: the program has no such
+    switch)."""
+    monkeypatch.setattr(moe_decode, "takes_kernel", lambda rows: False)
 
 
 @pytest.mark.parametrize("T", [64, 192])
@@ -177,7 +215,7 @@ def test_routed_ffn_through_the_kernel_equals_ragged_dots(routed,
     three row tiles."""
     cfg, params = routed
     spec = cfg.routed_spec()
-    assert takes_kernel(T * spec.top_k, spec.num_experts)
+    assert takes_kernel(T * spec.top_k)
     rng = np.random.default_rng(T)
     x = jnp.asarray(rng.standard_normal((T, cfg.hidden_size)), jnp.bfloat16)
     valid = jnp.asarray(rng.random(T) < 0.7)

@@ -363,12 +363,12 @@ def test_route_chooses_by_biased_score_and_weighs_by_score(params, cfg):
 @pytest.mark.parametrize("T,kernel", [(12, False), (64, True)],
                          ids=["compilers-kernel", "grouped-kernel"])
 def test_invalid_rows_are_routed_nowhere(params, cfg, T, kernel):
-    """12 rows x top-2 over 8 experts stay with ``ragged_dot``; 64 rows
-    are 16 expected rows a group, where the shape rule hands the products
-    to ``kernels/grouped_matmul`` (ISSUE 41), most of whose row tiles
-    then lie past the groups' sum."""
+    """12 rows x top-2 are no whole row tile and stay with
+    ``ragged_dot``; 64 rows x top-2 are one, which the shape rule hands
+    to ``kernels/grouped_matmul`` (ISSUE 41, ISSUE 49), most of whose
+    rows then lie past the groups' sum."""
     from hetu_tpu.models.moe_decode import takes_kernel
-    assert takes_kernel(T * 2, 8) == kernel
+    assert takes_kernel(T * 2) == kernel
     x, _, _ = _route_inputs(cfg, params, T=T)
     valid = jnp.asarray([True] * 5 + [False] * (T - 5))
     stats = {}
@@ -406,8 +406,24 @@ def test_batch_company_changes_no_requests_logits(params, cfg, kw, kernel):
         assert gaps(params, cfg, r)[0] <= TOL
     for e in (alone_eng, eng):
         waves = e.metrics.snapshot()["moe_kernel_waves"]
-        # chunk waves take the kernel, decode waves never
+        # chunk waves take the kernel; a decode wave's 8 slots x top-2
+        # are no whole row tile
         assert (0 < waves <= e.prefill_chunks) if kernel else waves == 0
+
+
+def test_a_decode_wave_of_whole_row_tiles_counts_as_a_kernel_wave(params,
+                                                                   cfg):
+    """ISSUE 49: 64 slots x top-2 are 128 sorted rows a DECODE wave, one
+    whole row tile, so its experts' products run through
+    ``kernels/grouped_matmul`` like a chunk wave's (64 x 8 x 2 = 1,024
+    rows) and ``serve.moe.kernel_waves`` counts every wave served."""
+    eng = engine(params, cfg, slots=64)
+    out = serve(eng, [(19, 6), (7, 9)])
+    for r in out.values():
+        assert gaps(params, cfg, r)[0] <= TOL
+    snap = eng.metrics.snapshot()
+    assert snap["steps"] > eng.prefill_chunks       # decode waves among them
+    assert snap["moe_kernel_waves"] == snap["steps"]
 
 
 # ------------------------------------------------------------------ #

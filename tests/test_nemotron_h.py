@@ -340,6 +340,30 @@ def test_four_shares_add_up_to_the_uncut_layer(whole):
                                    want["out"], atol=5e-5)
 
 
+def test_a_share_at_a_decode_waves_rows_runs_the_kernel_like_the_reference(
+        whole):
+    """ISSUE 49: 32 rows x top-4 are 128 sorted rows, ONE whole row tile,
+    of which a quarter can land on the 4 experts held (8 a group, most of
+    the tile past the groups' sum): the squared-ReLU layer's two products
+    run through ``kernels/grouped_matmul`` (interpreted) and give the
+    reference's share."""
+    c, p = whole
+    us = f"{NAME}_h1"
+    u = layer_inputs(c, rows=32, seed=9)
+    rt = c.routed_spec()._replace(held_first=8, held=4)
+    cut = share_of(p, us, 8, 4)
+    assert md.takes_kernel(32 * rt.top_k)
+    text = str(jax.make_jaxpr(lambda x: md.routed_ffn(cut, us, x, rt))(u))
+    assert text.count("pallas_call") == 2 and "ragged_dot" not in text
+    stats = {}
+    with jax.default_matmul_precision("highest"):
+        want = ref.expert_layer(p, us, c, u, held=(8, 4))
+        y = md.routed_ffn(cut, us, u, rt, stats=stats)
+    assert 0 < int(stats["load"].sum()) < int(stats["routed"]) == 128
+    np.testing.assert_allclose(y, want["out"], atol=2e-5)
+    assert float(jnp.abs(want["routed"]).max()) > 0
+
+
 def test_every_expert_held_is_todays_routed_ffn_bit_for_bit():
     """``held == E`` on a gated spec: the same lowered text and the same
     bits as a spec that says nothing of a share."""
@@ -412,33 +436,38 @@ def test_weights_are_normalised_over_all_the_chosen(whole):
 
 @pytest.mark.parametrize("rows,spec,kernel", [
     # a decode wave of 64 slots x 22: 1,408 sorted rows, 352 landing on
-    # 128 experts, 2.75 a group
-    (1408, (512, 128), False),
+    # 128 experts, 2.75 a group: the kernel since PR 49 (its sweep read
+    # 1.04 ms a layer there against ``ragged_dot``'s 2.78)
+    (1408, (512, 128), True),
     # a packed chunk wave of 1,024 rows x 22: 5,632 landing, 44 a group
     (22528, (512, 128), True),
-    # every expert held: the rule of today
-    (1024, (64, 64), True), (128, (64, 64), False), (1000, (8, 8), False),
+    # every expert held
+    (1024, (64, 64), True), (128, (64, 64), True), (1000, (8, 8), False),
 ], ids=["share_decode", "share_chunk", "whole_chunk", "whole_decode",
         "no_whole_tiles"])
 def test_takes_kernel_is_asked_with_the_rows_that_land_here(rows, spec,
                                                             kernel):
-    E, held = spec
-    rt = md.RoutedSpec(num_experts=E, top_k=2, held=0 if held == E else held)
-    landing = md.landing_rows(rows, rt)
-    assert landing == rows * held // E
-    assert md.takes_kernel(rows, held, landing) is kernel
-    if held == E:
-        assert md.takes_kernel(rows, E) is kernel
+    """Since PR 49 the rule reads the sorted rows alone, whatever share
+    of them can land here: the kernel's steps follow the groups that
+    have rows, and a row tile past the groups' sum has none."""
+    _, held = spec
+    assert md.takes_kernel(rows) is kernel
+    load = jnp.zeros((held,), jnp.int32).at[held // 2].set(3)
+    tiles = md.kernel_tiles(load, rows)
+    assert (tiles is not None) is kernel
+    if kernel:
+        # one group has rows: one live step of the static grid
+        assert [int(n) for n in tiles.steps] == [1, 1]
 
 
-@pytest.mark.parametrize("T,kernel", [(64, False), (1024, True)],
-                         ids=["decode_wave", "chunk_wave"])
+@pytest.mark.parametrize("T,kernel", [(64, True), (1024, True), (5, False)],
+                         ids=["decode_wave", "chunk_wave", "no_whole_tiles"])
 def test_a_layer_that_holds_a_share_traces_the_product_the_rule_names(
         T, kernel):
-    """Both products of a layer follow the rule's ONE answer for the rows
-    that can land here (a product that asked again, with all the sorted
-    rows, took the kernel in a decode wave: found by the chip's compiler,
-    PR 48): 64 slots x 22 are 1,408 sorted rows, 352 landing on 128."""
+    """Both products of a layer follow the rule's ONE answer for its
+    sorted rows: 64 slots x 22 are 1,408, eleven row tiles of which
+    three can be expected live (352 landing on 128), and since PR 49
+    the kernel's; 5 rows x 22 are no whole tiles."""
     spec = md.RoutedSpec(num_experts=512, top_k=22, scale=5.0, held=128,
                          latent=64, expert="relu2", n_shared=1)
     us, D = "m", 32
@@ -532,16 +561,17 @@ def test_the_latent_projections_have_their_own_scopes():
 # the programs ``test_program_digests`` pins
 # ------------------------------------------------------------------ #
 
-def nemotron_programs(sds, attn, qs=(1, 32)):
+def nemotron_programs(sds, attn, qs=(1, 32), slots=4):
     """{name: lowered mixed step} of a small ``nemotron_h`` configuration
     whose expert layers hold a quarter of 16 experts; ``sds(shape,
-    dtype)`` makes the abstract arguments."""
+    dtype)`` makes the abstract arguments.  At 32 ``slots`` a decode
+    wave's 32 x top-4 sorted rows are one whole row tile."""
     from hetu_tpu.kv_layout import kv_row_width
 
     def i32(*s):
         return sds(s, jnp.int32)
 
-    B, T, N, BS = 4, 8, 33, 16
+    B, T, N, BS = slots, 8, 33, 16
     c = nh.NemotronHConfig.from_hf(dict(
         SMALL, hidden_size=256, num_attention_heads=4, head_dim=64,
         mamba_num_heads=4, mamba_head_dim=32, moe_latent_size=128,

@@ -57,6 +57,8 @@ FAMILIES = {
     "PARENT_PACKED_RAGGED": (lambda s: packed_programs(s, "ragged"), True),
     "NEMOTRON_MASKED": (lambda s: nemotron_programs(s, "masked"), False),
     "NEMOTRON_RAGGED": (lambda s: nemotron_programs(s, "ragged"), True),
+    "NEMOTRON_DECODE_TILES_RAGGED": (
+        lambda s: nemotron_programs(s, "ragged", qs=(1,), slots=32), True),
 }
 
 PARENT = {
@@ -159,6 +161,16 @@ PARENT = {
         "nemotron.Q1.fresh1": "acb5db193cd96995",
         "nemotron.Q32.fresh0": "7f0e176ec1f6c772",
         "nemotron.Q32.fresh1": "7f0e176ec1f6c772"},
+    # PR 49's own, no parent's: the same model at 32 slots, whose DECODE
+    # wave's 32 x top-4 sorted rows are one whole row tile (32 can land
+    # on the 4 held experts): since PR 49 the rule hands such a wave's
+    # products to ``kernels/grouped_matmul`` too (two lowerings, ``relu2``
+    # up and down, for the five expert layers) and the text holds no
+    # ``ragged_dot``.  Every Q 1 program above has 8 or 16 sorted rows,
+    # no whole tile, and keeps ``ragged_dot`` and its parent's text.
+    "NEMOTRON_DECODE_TILES_RAGGED": {
+        "nemotron.Q1.fresh0": "3153082ce74992be",
+        "nemotron.Q1.fresh1": "3153082ce74992be"},
 }
 
 

@@ -201,6 +201,25 @@ def test_waves_are_labelled_by_scope_and_joined_to_their_dispatch():
     assert wave_trace.waves(data) is w and len(log.of("wave_kinds")) == 1
 
 
+def test_the_decode_waves_experts_on_the_hand_made_trace():
+    """PR 49's ``moe_experts_decode_wave_ms``, the decode twin of
+    ``moe_experts_chunk_wave_ms`` (same reader, same scopes and names,
+    ``kind`` "decode"): wave 2's ``ragged-dot-none`` [60, 64) over the
+    two decode waves; wave 3 has no expert operation."""
+    data, _ = fresh(wave_trace_by_hand())
+    assert metric("moe_experts_decode_wave_ms", data) == pytest.approx(4 / 2)
+    twin, mine = (bench_run.load_json(os.path.join(
+        ROOT, "benchmarks", "metrics", f"moe_experts_{kind}_wave_ms.json"))
+        for kind in ("chunk", "decode"))
+    assert mine == dict(twin, args=dict(twin["args"], kind="decode"))
+    [entry] = [m for m in BENCH["per_layer"]
+               if m["name"] == "moe_experts_decode_wave_ms"]
+    assert entry["moves"] == "serve_tokens_per_s"
+    # the cells whose traced windows always hold decode waves
+    assert entry["workloads"] == ["serve-glm47flash-reason-closed",
+                                  "serve-nemotron3-super-agent-closed"]
+
+
 def test_every_wave_metric_on_the_hand_made_trace():
     """Busy inside [0, 90): 40 + 10 + 10 + 11 = 71; idle 19 in the gaps
     [0, 5), [45, 55), [65, 65.5), [75.5, 79)."""
