@@ -30,9 +30,12 @@ handed through the donated step.
 One program a bucket serves every kind of row.  A slot with ONE live
 row (all of a decode wave's, and the decoding slots of a chunk wave)
 takes one step of the recurrence, elementwise in float32, the whole
-batch at once.  A slot with a wider q-block takes the CHUNKED form over
-chunks of ``SSMSpec.chunk`` rows: inside a chunk the products ``C_i
-B_j^T`` under the decay ``exp(cum_i - cum_j)``, from chunk to chunk ONE
+batch at once: where ``takes_kernel`` says so through the Pallas kernel
+``kernels/ssm_step`` (the state read once and written once where it
+lies), else through ``ssd_step``, the same step in XLA's operations.  A
+slot with a wider q-block takes the CHUNKED form over chunks of
+``SSMSpec.chunk`` rows: inside a chunk the products ``C_i B_j^T`` under
+the decay ``exp(cum_i - cum_j)``, from chunk to chunk ONE
 state update, the first chunk's carry the slot's state and the last
 chunk's result written back to it; a wave's few such slots are gathered
 ``WIDE_LANES`` at a time (``ssm_mixer``), so nothing is computed over
@@ -121,6 +124,18 @@ def ssd_step(x, dt, A, Bm, Cm, S):
     return jnp.sum(S * Ch[:, :, None, :], axis=-1), S
 
 
+def takes_kernel(spec):
+    """The shape rule: whether a program of ``spec``'s mixers runs the
+    one-row slots' step through ``kernels/ssm_step`` (else through
+    ``ssd_step``).  A state whose columns are whole lane tiles and whose
+    rows are whole sublane tiles (both published models'; the state is
+    float32 whatever the model).  Static shapes alone decide, so a
+    program is one or the other, and the engine can ask the same
+    question of a wave (``serve.ssm.kernel_slot_steps``)."""
+    from ..kernels._shared import _LANES
+    return spec.state % _LANES == 0 and spec.head_dim % 8 == 0
+
+
 def ssd_chunked(x, dt, A, Bm, Cm, S, chunk):
     """The chunked form over every slot's q-block: ``x`` [B, Q, H, P],
     ``dt`` [B, Q, H] (0 on dead rows), ``A`` [H], ``Bm`` / ``Cm`` [B, Q,
@@ -199,8 +214,9 @@ def ssm_mixer(params, us, blk, u, state, si, q_len, rows=None):
     layer ``si``'s two are read and rewritten whole.
 
     The scan never unpacks the wave.  Every slot with ONE live row (all
-    of a decode wave's) takes ``ssd_step`` at its row, the whole batch at
-    once.  The slots with a wider q-block (a chunk wave's few) are taken
+    of a decode wave's) takes one step at its row, the whole batch at
+    once (``kernels/ssm_step`` by ``takes_kernel``, else ``ssd_step``).
+    The slots with a wider q-block (a chunk wave's few) are taken
     ``WIDE_LANES`` at a time, widest first, by a ``while_loop`` that
     gathers their rows and states, runs ``ssd_chunked`` and scatters both
     back: one pass for up to ``WIDE_LANES`` chunks, as many as it takes
@@ -250,10 +266,18 @@ def ssm_mixer(params, us, blk, u, state, si, q_len, rows=None):
         first = jnp.minimum(start, R - 1)
         x1, B1, C1 = split(xbc_f[first])
         dt1 = jnp.where((q_len == 1)[:, None], dt_f[first], 0.0)
-        y1, S = ssd_step(x1, dt1, A, B1, C1, mats[0])
+        kernel = takes_kernel(sp)
+        if kernel:
+            # the state decayed, added to, read out and stored in ONE
+            # pass where it lies
+            from ..kernels.ssm_step import ssm_step
+            y1, mats = ssm_step(x1, dt1, A, B1, C1, mats)
+        else:
+            y1, S = ssd_step(x1, dt1, A, B1, C1, mats[0])
         y1 = (y1 + D[:, None] * x1.astype(f32)).reshape(B_, d_ssm)
-    with jax.named_scope("state_write"):
-        mats = S[None]
+    if not kernel:
+        with jax.named_scope("state_write"):
+            mats = S[None]
     if Q == 1:
         y = y1.reshape(Br, Qr, d_ssm)
     else:

@@ -52,6 +52,7 @@ from ..kernels.ragged_attention import (
     mla_rows_tiling, mla_tiling, row_tile_visits, rows_tiling, tile_heights)
 from ..models.moe_decode import takes_kernel
 from ..models.retention_decode import takes_kernel as retention_takes_kernel
+from ..models.ssm_decode import takes_kernel as ssm_takes_kernel
 from .kv_manager import (PagedKVManager, assemble_mixed_wave,
                          resolve_kv_block, resolve_kv_quant)
 from .metrics import ServingMetrics
@@ -573,13 +574,18 @@ class ServingEngine:
             full, rest = wide // c, wide % c
             chunk_pairs = int((full * (c * (c + 1) // 2)
                                + rest * (rest + 1) // 2).sum())
-            # the wide slots whose chunked form the wave's program ran
-            # through ``kernels/retention_scan``: the program's own rule
-            by_kernel = kind == "ret" and retention_takes_kernel(
-                spec.head_dim, int(wave["q"]))
+            # the slots the wave's program took through a kernel, by
+            # the program's own rule: the wide slots' chunked form
+            # through ``kernels/retention_scan``, the one-row slots'
+            # step through ``kernels/ssm_step``
+            if kind == "ret":
+                by_kernel = (ql > 1) & retention_takes_kernel(
+                    spec.head_dim, int(wave["q"]))
+            else:
+                by_kernel = (ql == 1) & ssm_takes_kernel(spec)
             out[kind] = self.metrics.record_state_scan(
                 kind, int((ql > 0).sum()), int(ql.sum()), chunk_pairs,
-                layers, int((ql > 1).sum()) if by_kernel else 0)
+                layers, int(by_kernel.sum()))
         return out
 
     def _attn_tiles(self, q_len, Q, rows):

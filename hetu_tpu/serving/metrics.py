@@ -219,6 +219,7 @@ class ServingMetrics(MetricsCore):
         self.ssm_slot_steps = 0
         self.ssm_rows = 0
         self.ssm_chunk_pairs = 0
+        self.ssm_kernel_slot_steps = 0
         self.ret_slot_steps = 0
         self.ret_rows = 0
         self.ret_chunk_pairs = 0
@@ -319,12 +320,16 @@ class ServingMetrics(MetricsCore):
         one's state is read and written once a layer), ``rows`` (the
         wave's live rows) and ``chunk_pairs`` (the row pairs ``j <= i``
         inside the chunks of the q-blocks wider than one row: what the
-        chunked form multiplies out besides); ``kernel_slots`` (of a
-        retention wave: the slots with a q-block wider than one row
-        whose chunked form the wave's program ran through
+        chunked form multiplies out besides); ``kernel_slots`` (the
+        slots the wave's program took through a Pallas kernel by its own
+        shape rule: of a retention wave those with a q-block wider than
+        one row, whose chunked form ran through
         ``kernels/retention_scan``, ``retention_decode.takes_kernel`` of
-        its head and q-block; x layers the sum ``ret_kernel_slot_steps``
-        and the counter ``serve.ret.kernel_slot_steps``).  Running sums
+        its head and q-block; of a state-space wave those with ONE row,
+        whose step ran through ``kernels/ssm_step``,
+        ``ssm_decode.takes_kernel`` of its mixer's sizes; x layers the
+        sum ``<kind>_kernel_slot_steps`` and the counter
+        ``serve.<kind>.kernel_slot_steps``).  Running sums
         ``<kind>_slot_steps`` (live slots x layers), ``<kind>_rows`` and
         ``<kind>_chunk_pairs`` (each x layers) here, the counters
         ``serve.<kind>.slot_steps``, ``serve.<kind>.rows`` and
@@ -344,10 +349,12 @@ class ServingMetrics(MetricsCore):
         telemetry.inc("serve.ret.rows" if ret else "serve.ssm.rows", rows)
         telemetry.inc("serve.ret.chunk_pairs" if ret
                       else "serve.ssm.chunk_pairs", pairs)
-        if ret and kernel_slots:
+        if kernel_slots:
             by_kernel = int(kernel_slots) * int(layers)
-            self.ret_kernel_slot_steps += by_kernel
-            telemetry.inc("serve.ret.kernel_slot_steps", by_kernel)
+            setattr(self, f"{kind}_kernel_slot_steps",
+                    getattr(self, f"{kind}_kernel_slot_steps") + by_kernel)
+            telemetry.inc("serve.ret.kernel_slot_steps" if ret
+                          else "serve.ssm.kernel_slot_steps", by_kernel)
         return {"slot_steps": steps, "rows": rows,
                 "live_slots": int(live_slots), "layers": int(layers)}
 
@@ -670,6 +677,7 @@ class ServingMetrics(MetricsCore):
                     "attn_window_ctx_tokens", "attn_window_score_pairs",
                     "window_blocks_recycled",
                     "ssm_slot_steps", "ssm_rows", "ssm_chunk_pairs",
+                    "ssm_kernel_slot_steps",
                     "ret_slot_steps", "ret_rows", "ret_chunk_pairs",
                     "ret_kernel_slot_steps",
                     "wave_rows_live", "wave_rows_computed",
@@ -759,6 +767,7 @@ class ServingMetrics(MetricsCore):
             "ssm_slot_steps": count("ssm_slot_steps"),
             "ssm_rows": count("ssm_rows"),
             "ssm_chunk_pairs": count("ssm_chunk_pairs"),
+            "ssm_kernel_slot_steps": count("ssm_kernel_slot_steps"),
             "ret_slot_steps": count("ret_slot_steps"),
             "ret_rows": count("ret_rows"),
             "ret_chunk_pairs": count("ret_chunk_pairs"),

@@ -828,14 +828,16 @@ def test_nemotron_wave_programs_at_the_published_widths(sds, monkeypatch,
     products by the rule: ``moe_grouped_matmul`` twice a
     layer in every wave, the decode wave's 1,408 sorted rows (352
     landing on 128 experts) among them since PR 49, and no
-    ``ragged-dot`` anywhere."""
+    ``ragged-dot`` anywhere; every mixer's one-step form ONE
+    ``ssm_step`` call on its state where it lies (PR 50)."""
     import json
     import os
     from hetu_tpu.kernels import grouped_matmul as gm
     from hetu_tpu.kernels import ragged_attention as ra
+    from hetu_tpu.kernels import ssm_step as ss
     from hetu_tpu.models import nemotron_h as nh
-    monkeypatch.setattr(ra, "_use_interpret", lambda: False)
-    monkeypatch.setattr(gm, "_use_interpret", lambda: False)
+    for module in (ra, gm, ss):
+        monkeypatch.setattr(module, "_use_interpret", lambda: False)
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     with open(os.path.join(root, "benchmarks", "configs",
                            "nemotron-3-super-120b-a12b.json")) as f:
@@ -872,6 +874,9 @@ def test_nemotron_wave_programs_at_the_published_widths(sds, monkeypatch,
     experts = sum("moe_grouped_matmul" in c for c in calls)
     assert experts == 2 * cfg.pattern.count("E")
     assert "ragged-dot" not in text
+    # every mixer's one-row slots through ``ssm_step`` (since PR 50), in
+    # the decode program and in every chunk bucket's
+    assert sum("ssm_step" in c for c in calls) == mixers
     mem = compiled.memory_analysis()
     pool_bytes = 2 * N * BLOCK * kv_row_width(2, 128) * 2
     one_state = B * 128 * 64 * 128 * 4
@@ -884,3 +889,33 @@ def test_nemotron_wave_programs_at_the_published_widths(sds, monkeypatch,
     peak = mem.argument_size_in_bytes + mem.output_size_in_bytes \
         - mem.alias_size_in_bytes + mem.temp_size_in_bytes
     assert peak < 12e9
+
+
+# ------------------------------------------------------------------- #
+# ISSUE 50: the state-space mixer's one-step form as one kernel
+# ------------------------------------------------------------------- #
+
+@pytest.mark.parametrize("H,P,N,G", [(128, 64, 128, 8), (32, 128, 256, 2)],
+                         ids=["nemotron-3-super", "falcon-h1"])
+def test_ssm_step_at_the_cells_sizes(sds, H, P, N, G):
+    """64 slots of both cells' mixers, a group's 16 heads a grid step
+    (0.5 and 2 MB of a slot's 4.19 MB state): the kernel compiles for
+    the chip, the state aliased, no temporary of its size (the
+    transposed ``dt x`` and the row of results alone)."""
+    from hetu_tpu.kernels import ssm_step as ss
+    from hetu_tpu.models import ssm_decode as sd
+    assert sd.takes_kernel(sd.SSMSpec(H, P, N, G, 4, 128))
+    assert ss.head_block(H, G, P, N) == 16
+    B, bf, f32 = 64, jnp.bfloat16, jnp.float32
+    compiled = jax.jit(
+        lambda *a: ss.ssm_step(*a, interpret=False),
+        donate_argnums=(5,)).lower(
+            sds((B, H, P), bf), sds((B, H), f32), sds((H,), f32),
+            sds((B, G, N), bf), sds((B, G, N), bf),
+            sds((1, B, H, P, N), f32)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "ssm_step" in text
+    mem = compiled.memory_analysis()
+    state = B * H * P * N * 4
+    assert mem.alias_size_in_bytes >= state
+    assert mem.temp_size_in_bytes < state // 16

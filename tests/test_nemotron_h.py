@@ -561,11 +561,13 @@ def test_the_latent_projections_have_their_own_scopes():
 # the programs ``test_program_digests`` pins
 # ------------------------------------------------------------------ #
 
-def nemotron_programs(sds, attn, qs=(1, 32), slots=4):
+def nemotron_programs(sds, attn, qs=(1, 32), slots=4, ssm_state=16):
     """{name: lowered mixed step} of a small ``nemotron_h`` configuration
     whose expert layers hold a quarter of 16 experts; ``sds(shape,
     dtype)`` makes the abstract arguments.  At 32 ``slots`` a decode
-    wave's 32 x top-4 sorted rows are one whole row tile."""
+    wave's 32 x top-4 sorted rows are one whole row tile; at an
+    ``ssm_state`` of 128 columns the mixers' one-row slots take
+    ``kernels/ssm_step``."""
     from hetu_tpu.kv_layout import kv_row_width
 
     def i32(*s):
@@ -576,7 +578,8 @@ def nemotron_programs(sds, attn, qs=(1, 32), slots=4):
         SMALL, hidden_size=256, num_attention_heads=4, head_dim=64,
         mamba_num_heads=4, mamba_head_dim=32, moe_latent_size=128,
         moe_intermediate_size=128, moe_shared_expert_intermediate_size=256,
-        vocab_size=512, max_position_embeddings=128), held_experts=HELD)
+        vocab_size=512, max_position_embeddings=128,
+        ssm_state_size=ssm_state), held_experts=HELD)
     blk = c.block_spec()
     p = {k: sds(s, jnp.float32 if "router" in k or k.endswith(
         ("_ssm_dt_bias", "_ssm_A_log", "_ssm_D")) else jnp.bfloat16)

@@ -34,13 +34,22 @@ from test_chip_compile import (  # noqa: E402,F401
 from test_hybrid_moe import digest, wave_programs
 from test_nemotron_h import nemotron_programs
 from test_retention import retention_programs, window_programs
-from test_window_moe import hybrid_programs
+from test_window_moe import falcon_case, hybrid_programs, lower_cases
 
 
 def packed_programs(sds, attn):
     """The GPT-2 and latent chunk programs of 16 slots: packed rows."""
     return {k: low for k, low in wave_programs(sds, attn, slots=16).items()
             if k.endswith("Q32.fresh1")}
+
+
+def ssm_kernel_programs(sds):
+    """The small ``nemotron_h`` and ``falcon_h1`` models with a state of
+    128 columns: every program's one-row slots through ``ssm_step``."""
+    out = nemotron_programs(sds, "ragged", ssm_state=128)
+    out.update(lower_cases(sds, "ragged", {"falcon": falcon_case(
+        sds, 4, 33, 16, ssm_state=128)}, 4, 8))
+    return out
 
 
 # family: (builder, lowered with the kernels for the described chip)
@@ -59,6 +68,7 @@ FAMILIES = {
     "NEMOTRON_RAGGED": (lambda s: nemotron_programs(s, "ragged"), True),
     "NEMOTRON_DECODE_TILES_RAGGED": (
         lambda s: nemotron_programs(s, "ragged", qs=(1,), slots=32), True),
+    "SSM_STEP_RAGGED": (ssm_kernel_programs, True),
 }
 
 PARENT = {
@@ -171,6 +181,22 @@ PARENT = {
     "NEMOTRON_DECODE_TILES_RAGGED": {
         "nemotron.Q1.fresh0": "3153082ce74992be",
         "nemotron.Q1.fresh1": "3153082ce74992be"},
+    # PR 50's own, no parent's: the small ``nemotron_h`` and ``falcon_h1``
+    # models above with a state of 128 columns (every entry above has
+    # 16), where ``ssm_decode.takes_kernel`` hands the one-row slots'
+    # step to ``kernels/ssm_step``: ONE lowering a program for all of a
+    # model's mixers (five and two), in the decode program and in the
+    # chunk bucket's, whose wide slots keep the chunked form's
+    # ``while``; no ``multiply_add`` over the whole state is left.
+    "SSM_STEP_RAGGED": {
+        "nemotron.Q1.fresh0": "c3a7775fd1b7f6c8",
+        "nemotron.Q1.fresh1": "c3a7775fd1b7f6c8",
+        "nemotron.Q32.fresh0": "69fe6c6b2ab88112",
+        "nemotron.Q32.fresh1": "69fe6c6b2ab88112",
+        "falcon.Q1.fresh0": "737fe178722df78a",
+        "falcon.Q1.fresh1": "737fe178722df78a",
+        "falcon.Q32.fresh0": "7209be57d34aca4a",
+        "falcon.Q32.fresh1": "7209be57d34aca4a"},
 }
 
 
@@ -188,10 +214,11 @@ def digests(request):
                 return done[family]
             from hetu_tpu.kernels import grouped_matmul as gm
             from hetu_tpu.kernels import ragged_attention as ra
+            from hetu_tpu.kernels import ssm_step as ss
             on_chip = request.getfixturevalue("sds")
             with pytest.MonkeyPatch.context() as m:
-                m.setattr(ra, "_use_interpret", lambda: False)
-                m.setattr(gm, "_use_interpret", lambda: False)
+                for module in (ra, gm, ss):
+                    m.setattr(module, "_use_interpret", lambda: False)
                 texts = {k: low.as_text()
                          for k, low in build(on_chip).items()}
             assert all("tpu_custom_call" in t for t in texts.values())

@@ -639,15 +639,17 @@ def test_record_state_scan_counts_what_the_check_reads():
 
 
 @pytest.mark.parametrize("kind,kernel_slots,want", [
-    ("ret", 3, 18), ("ret", 0, 0), ("ssm", 3, 0)],
-    ids=["wide_slots_x_layers", "one_row_slots", "ssm_kind"])
+    ("ret", 3, 18), ("ret", 0, 0), ("ssm", 3, 0), ("ssm", 0, 0)],
+    ids=["wide_slots_x_layers", "one_row_slots", "ssm_kind",
+         "ssm_kind_none"])
 def test_record_state_scan_counts_the_slots_the_kernel_took(kind,
                                                             kernel_slots,
                                                             want):
     """``serve.ret.kernel_slot_steps`` / ``ret_kernel_slot_steps`` (ISSUE
     45): wide slots x layers of a retention wave whose program takes
     ``kernels/retention_scan``; 0 for a wave of one-row slots and for
-    the state-space kind, whose waves run no such kernel."""
+    the state-space kind, whose kernel's slots are a counter of their
+    own (``serve.ssm.kernel_slot_steps``, ``tests/test_ssm_hybrid.py``)."""
     from hetu_tpu import telemetry
     m = ServingMetrics()
     mark = m.mark()

@@ -519,3 +519,29 @@ def test_record_ssm_counts_what_the_check_reads():
     snap = m.snapshot(since=mark)
     assert (snap["ssm_slot_steps"], snap["ssm_rows"],
             snap["ssm_chunk_pairs"]) == (12, 76, 160)
+    assert snap["ssm_kernel_slot_steps"] == 0
+
+
+@pytest.mark.parametrize("d_state,want", [(128, 6), (16, 0)],
+                         ids=["whole_lane_tiles", "narrow_state"])
+def test_a_wave_counts_the_slots_the_step_kernel_took(d_state, want):
+    """``serve.ssm.kernel_slot_steps`` / ``ssm_kernel_slot_steps`` (ISSUE
+    50): a wave of 3 one-row slots, 1 wide and 1 dead over 2 layers, as
+    the engine reads its own wave: the one-row slots x layers where the
+    mixer's shape rule hands their step to ``kernels/ssm_step``, 0 where
+    it keeps ``ssd_step``; the live slots' steps either way."""
+    from hetu_tpu import telemetry
+    c = sd.SSMHybridConfig.from_hf(dict(
+        SMALL, num_hidden_layers=2, mamba_d_state=d_state))
+    eng = engine(sd.init_ssm_hybrid_params(c, NAME, seed=1), c, slots=5)
+    before = telemetry.snapshot()["counters"].get(
+        "serve.ssm.kernel_slot_steps", 0)
+    mark = eng.metrics.mark()
+    rec = eng._wave_record({"q_len": np.array([1, 7, 1, 0, 1]),
+                            "pos": np.array([9, 0, 3, 0, 5]), "q": 8}, 40)
+    assert rec["ssm"]["slot_steps"] == 4 * 2
+    for snap in (eng.metrics.snapshot(since=mark), eng.metrics.snapshot()):
+        assert snap["ssm_kernel_slot_steps"] == want
+        assert snap["ssm_slot_steps"] == 8
+    assert telemetry.snapshot()["counters"].get(
+        "serve.ssm.kernel_slot_steps", 0) - before == want

@@ -731,11 +731,6 @@ def hybrid_programs(sds, attn):
     """{name: lowered mixed step} of a small ``lfm2_moe`` and a small
     ``falcon_h1`` configuration; ``sds(shape, dtype)`` makes the abstract
     arguments."""
-    from hetu_tpu.models import ssm_decode as sd
-
-    def i32(*s):
-        return sds(s, jnp.int32)
-
     B, T, N, BS = 4, 8, 33, 16
     c = HybridMoEConfig.from_hf(dict(
         vocab_size=512, hidden_size=256, num_hidden_layers=4,
@@ -751,12 +746,21 @@ def hybrid_programs(sds, attn):
     cases = {"lfm2": (lp, ("lfm", 4, 8, 32, 128, c.block_spec()),
                       sds((2, N, BS, kv_row_width(2, 32)), jnp.bfloat16),
                       sds((2, B, 2, 256), jnp.bfloat16))}
+    cases["falcon"] = falcon_case(sds, B, N, BS)
+    return lower_cases(sds, attn, cases, B, T)
+
+
+def falcon_case(sds, B, N, BS, ssm_state=16):
+    """(params, cfg tuple, pool, state) of a small ``falcon_h1``
+    configuration; at an ``ssm_state`` of 128 columns the mixers'
+    one-row slots take ``kernels/ssm_step``."""
+    from hetu_tpu.models import ssm_decode as sd
     f = sd.SSMHybridConfig.from_hf(dict(
         vocab_size=512, hidden_size=256, num_hidden_layers=2,
         num_attention_heads=4, num_key_value_heads=2, head_dim=64,
         intermediate_size=256, mamba_d_ssm=128, mamba_n_heads=4,
-        mamba_d_head=32, mamba_d_state=16, mamba_n_groups=2, mamba_d_conv=4,
-        mamba_chunk_size=8, rope_theta=1e11, rms_norm_eps=1e-5,
+        mamba_d_head=32, mamba_d_state=ssm_state, mamba_n_groups=2,
+        mamba_d_conv=4, mamba_chunk_size=8, rope_theta=1e11, rms_norm_eps=1e-5,
         max_position_embeddings=128, embedding_multiplier=5.5,
         attention_in_multiplier=0.9, attention_out_multiplier=0.04,
         key_multiplier=0.3, ssm_in_multiplier=0.25, ssm_out_multiplier=0.09,
@@ -768,13 +772,20 @@ def hybrid_programs(sds, attn):
     fstate = tuple(
         sds((sh[0], B) + tuple(sh[1:]), jnp.bfloat16 if dt is None else dt)
         for sh, dt in fb.state_shapes(2, 256))
-    cases["falcon"] = (fp, ("fh1", 2, 4, 64, 128, fb),
-                       sds((2, N, BS, kv_row_width(2, 64)), jnp.bfloat16),
-                       fstate)
+    return (fp, ("fh1", 2, 4, 64, 128, fb),
+            sds((2, N, BS, kv_row_width(2, 64)), jnp.bfloat16), fstate)
+
+
+def lower_cases(sds, attn, cases, B, T, qs=(1, 32)):
+    """{name.Q.fresh: lowered mixed step} of ``cases`` {name: (params,
+    cfg tuple, pool, state)}."""
+    def i32(*s):
+        return sds(s, jnp.int32)
+
     fn = gd.serve_mixed_paged_fn(True, attn, 1)
     out = {}
     for name, (p, cfg_tuple, pool, state) in cases.items():
-        for Q in (1, 32):
+        for Q in qs:
             for fresh in (False, True):
                 out[f"{name}.Q{Q}.fresh{int(fresh)}"] = fn.func.lower(
                     p, cfg_tuple, pool, pool, i32(B, T), i32(B), i32(B, Q),
