@@ -905,12 +905,20 @@ def ragged_paged_reference(q, pool_k, pool_v, lengths, q_lens,
 # buffers and double-buffered q and o tiles compiles inside the 16 MiB
 # of scoped VMEM; 2560 does not.
 _MLA_TILE_ROWS = 1280
+# the widest row that tile was sized at
+_MLA_TILE_WIDTH = 640
 
 
-def _mla_q_tile(Q, H):
-    if H * Q <= _MLA_TILE_ROWS:
+def _mla_q_tile(Q, H, W=0):
+    """Queries a q-tile of ``H`` heads: ``_MLA_TILE_ROWS`` rows of up to
+    ``_MLA_TILE_WIDTH`` columns, fewer in proportion of a wider row (a
+    window layer's 1,152 beside 1,024 value columns: the q and o tiles
+    and the accumulator all scale with the width)."""
+    cap = _MLA_TILE_ROWS if W <= _MLA_TILE_WIDTH \
+        else _MLA_TILE_ROWS * _MLA_TILE_WIDTH // W
+    if H * Q <= cap:
         return Q
-    return _fit_block(max(_MLA_TILE_ROWS // H, 1), Q)
+    return _fit_block(max(cap // H, 1), Q)
 
 
 # Queries of the latent kernel's short height.  Its rows are query-major
@@ -924,19 +932,21 @@ def _mla_q_tile(Q, H):
 _MLA_SHORT_QUERIES = 8
 
 
-def mla_tiling(Q, H):
+def mla_tiling(Q, H, W=0):
     """(queries a q-tile, short height) of the latent kernel's program
-    for a q-block of ``Q`` queries of ``H`` heads."""
-    tq = _mla_q_tile(Q, H)
+    for a q-block of ``Q`` queries of ``H`` heads (rows ``W`` wide)."""
+    tq = _mla_q_tile(Q, H, W)
     return tq, _short_height(tq, _MLA_SHORT_QUERIES)
 
 
-def _mla_copies(pool_ref, bt_ref, kv_buf, sem, layer, b, last, group, bs):
+def _mla_copies(pool_ref, bt_ref, kv_buf, sem, layer, b, last, group, bs,
+                at=lambda gi: gi):
     """``copies(gi, buf)`` of :func:`_page_loop` for slot ``b`` of the
-    latent pool: group ``gi``'s page copies into buffer ``buf``; pages
-    past the ``last`` in sight copy that one again (their positions are
-    masked)."""
+    latent pool: group ``at(gi)``'s page copies into buffer ``buf``
+    (``at``: past the groups a window passes over); pages past the
+    ``last`` in sight copy that one again (their positions are masked)."""
     def copies(gi, buf):
+        gi = at(gi)
         return [pltpu.make_async_copy(
             pool_ref.at[layer, bt_ref[b, jnp.minimum(gi * group + g, last)]],
             kv_buf.at[buf, pl.ds(g * bs, bs)], sem.at[buf])
@@ -946,11 +956,18 @@ def _mla_copies(pool_ref, bt_ref, kv_buf, sem, layer, b, last, group, bs):
 
 def _mla_kernel(lens_ref, qlens_ref, bt_ref, q_ref, pool_ref, o_ref,
                 kv_buf, sem, m_ref, l_ref, acc_ref, *, scale, bs, group,
-                tq, heads, dv, layer, short=0):
+                tq, heads, dv, layer, short=0, window=0):
     b, t, n_groups, last, full = _tile_in_sight(lens_ref, qlens_ref, tq, bs,
                                                 group, short)
+    # under a window the page loop starts at the first group in sight
+    # (as ``_kv_rows_kernel``'s)
+    at = lambda gi: gi                                     # noqa: E731
+    if window:
+        g0 = _first_group(lens_ref, qlens_ref, b, t, tq, group * bs, window)
+        n_groups = jnp.maximum(n_groups - g0, 0)
+        at = lambda gi: g0 + gi                            # noqa: E731
     copies = _mla_copies(pool_ref, bt_ref, kv_buf, sem, layer, b, last,
-                         group, bs)
+                         group, bs, at)
 
     def rows(hq):
         """The tile's first ``hq`` queries: its first ``hq * heads``
@@ -973,7 +990,8 @@ def _mla_kernel(lens_ref, qlens_ref, bt_ref, q_ref, pool_ref, o_ref,
         # row r of the tile is query t*tq + r // heads
         qi = t * tq + jax.lax.broadcasted_iota(
             jnp.int32, s.shape, 0) // heads
-        _softmax_step(_mask_scores(s, qi, gi, lens_ref[b], qlens_ref[b]),
+        _softmax_step(_mask_scores(s, qi, at(gi), lens_ref[b],
+                                   qlens_ref[b], window),
                       kv[:, :dv], m_ref, l_ref, acc_ref, at=rows(hq))
 
     def finalize(hq):
@@ -1002,7 +1020,7 @@ def _mla_interpret(W, interpret):
 
 
 def ragged_paged_mla(q, pool, lengths, q_lens, block_tables, *,
-                     value_width, scale, layer=0, interpret=None):
+                     value_width, scale, layer=0, interpret=None, window=0):
     """The mixed wave over the paged LATENT pool.
 
     q: [B, Q, H, W] (``[q_lat | q_rope | 0]``, already absorbed); pool:
@@ -1024,11 +1042,15 @@ def ragged_paged_mla(q, pool, lengths, q_lens, block_tables, *,
     This is the DENSE entry, for a wave whose rows lie as ``[B, Q]``
     (every decode and verify wave, and a chunk wave too small to pack);
     a packed chunk wave's rows go to :func:`ragged_paged_mla_rows` as
-    they lie.  Both run under this name in a trace."""
+    they lie.  Both run under this name in a trace.  ``window`` > 0
+    (static) scores a SLIDING WINDOW as :func:`ragged_paged_attention`
+    does (the band ``p - window < kv <= p``, the page loop starting at
+    the first group in sight, ``block_tables`` the ring repeated), under
+    the name ``ragged_paged_mla_window``; 0 is the kernel there was."""
     B, Q, H, W = q.shape
     bs = pool.shape[2]
     group = min(_PAGE_GROUP, block_tables.shape[1])
-    tq, short = mla_tiling(Q, H)
+    tq, short = mla_tiling(Q, H, W)
     interpret = _mla_interpret(W, interpret)
     rows = tq * H
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -1049,24 +1071,24 @@ def ragged_paged_mla(q, pool, lengths, q_lens, block_tables, *,
     o = pl.pallas_call(
         functools.partial(_mla_kernel, scale=scale, bs=bs, group=group,
                           tq=tq, heads=H, dv=value_width, layer=layer,
-                          short=short),
+                          short=short, window=int(window)),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Q * H, value_width), q.dtype),
-        name="ragged_paged_mla",
+        name="ragged_paged_mla_window" if window else "ragged_paged_mla",
         interpret=interpret,
     )(lengths.astype(jnp.int32), q_lens.astype(jnp.int32),
       block_tables.astype(jnp.int32), q.reshape(B, Q * H, W), pool)
     return o.reshape(B, Q, H, value_width)
 
 
-def mla_rows_tiling(R, H, dtype):
+def mla_rows_tiling(R, H, dtype, W=0):
     """(packed queries a row tile, short window) of the packed latent
     kernel's program for ``R`` packed rows of ``H`` heads in ``dtype``:
     the dense entry's tile, and a short window of ``_MLA_SHORT_QUERIES``
     queries where a tile is a whole number of them, more than one, and a
     window's ``H`` x queries rows are whole sublane tiles (it starts
     where a slot's rows lie: a traced, aligned start)."""
-    tq = _mla_q_tile(R, H)
+    tq = _mla_q_tile(R, H, W)
     short = _MLA_SHORT_QUERIES
     sub = 32 // jnp.dtype(dtype).itemsize
     if tq <= short or tq % short or (short * H) % sub:
@@ -1077,7 +1099,7 @@ def mla_rows_tiling(R, H, dtype):
 def _mla_rows_kernel(lens_ref, qlens_ref, bt_ref, layer_ref, start_ref,
                      first_ref, last_ref, q_ref, pool_ref, o_ref, kv_buf,
                      sem, m_ref, l_ref, acc_ref, *, scale, bs, group, tq,
-                     heads, dv, short):
+                     heads, dv, short, window=0, allowed=None):
     """One row tile of the PACKED wave: ``tq`` packed queries x ``heads``
     rows, whichever slots they belong to.  The slots ``first_ref[t] ..
     last_ref[t]`` are visited one after another (the layout is
@@ -1085,7 +1107,10 @@ def _mla_rows_kernel(lens_ref, qlens_ref, bt_ref, layer_ref, start_ref,
     pipeline and scores the tile, or the one short window that holds the
     slot's rows (:func:`row_tile_visits`), with every row that is not the
     slot's masked: the online softmax leaves such a row as it was.  A
-    row nobody owns comes back zero."""
+    row nobody owns comes back zero.  ``allowed`` (a float32 ``[R, S]``
+    in HBM and a two-buffer scratch for a tile of it: the SELECTED-rows
+    form) admits, a packed query, the positions marked 1 alone: a
+    group's tile of it is copied beside the group's pages."""
     t = pl.program_id(0)
     span = group * bs
     layer = layer_ref[0]
@@ -1101,6 +1126,14 @@ def _mla_rows_kernel(lens_ref, qlens_ref, bt_ref, layer_ref, start_ref,
         live &= end > 0
         n_groups = jnp.where(live, (end + span - 1) // span, 0)
         last = jnp.maximum(end - 1, 0) // bs
+        grp = lambda gi: gi                                # noqa: E731
+        if window:
+            # the first group the slot's EARLIEST row in this tile
+            # admits: the groups before it are neither copied nor scored
+            first_q = filled - qlen + jnp.maximum(t * tq + lo - start, 0)
+            g0 = jnp.maximum(first_q - window + 1, 0) // span
+            n_groups = jnp.maximum(n_groups - g0, 0)
+            grp = lambda gi: g0 + gi                       # noqa: E731
         # the window's first query, counted from the tile's first
         w = lo // short * short if short else 0
 
@@ -1123,12 +1156,31 @@ def _mla_rows_kernel(lens_ref, qlens_ref, bt_ref, layer_ref, start_ref,
             qi = first - start + jax.lax.broadcasted_iota(
                 jnp.int32, (s.shape[0], 1), 0) // heads
             qi = jnp.where((qi >= 0) & (qi < qlen), qi, -(1 << 30))
-            _softmax_step(_mask_scores(s, qi, gi, filled, qlen),
-                          kv[:, :dv], m_ref, l_ref, acc_ref, at=at)
+            s = _mask_scores(s, qi, grp(gi), filled, qlen, window)
+            if allowed is not None:
+                # a query's row of the tile for all its heads: [hq, span]
+                # spread over the (query, head) rows by a 0/1 product
+                al = allowed[1][buf] if hq == tq \
+                    else allowed[1][buf, pl.ds(w, hq)]
+                spread = (jax.lax.broadcasted_iota(
+                    jnp.int32, (hq * heads, hq), 0) // heads
+                    == jax.lax.broadcasted_iota(
+                        jnp.int32, (hq * heads, hq), 1)).astype(al.dtype)
+                s = jnp.where(jnp.dot(
+                    spread, al, preferred_element_type=jnp.float32) > 0.5,
+                    s, NEG_INF)
+            _softmax_step(s, kv[:, :dv], m_ref, l_ref, acc_ref, at=at)
 
-        _page_loop(n_groups,
-                   _mla_copies(pool_ref, bt_ref, kv_buf, sem, layer, b, last,
-                               group, bs),
+        pages = _mla_copies(pool_ref, bt_ref, kv_buf, sem, layer, b, last,
+                            group, bs, grp)
+        copies = pages
+        if allowed is not None:
+            copies = lambda gi, buf: pages(gi, buf) + [   # noqa: E731
+                pltpu.make_async_copy(
+                    allowed[0].at[pl.ds(t * tq, tq),
+                                  pl.ds(grp(gi) * span, span)],
+                    allowed[1].at[buf], sem.at[buf])]
+        _page_loop(n_groups, copies,
                    lambda gi, buf: _at_heights((tq, short, full), score,
                                                gi, buf))
         return carry
@@ -1139,10 +1191,25 @@ def _mla_rows_kernel(lens_ref, qlens_ref, bt_ref, layer_ref, start_ref,
         o_ref.dtype)
 
 
+def _mla_rows_selected_kernel(lens_ref, qlens_ref, bt_ref, layer_ref,
+                              start_ref, first_ref, last_ref, q_ref,
+                              pool_ref, allowed_ref, o_ref, kv_buf, sem,
+                              m_ref, l_ref, acc_ref, allowed_buf, **sizes):
+    """``_mla_rows_kernel`` handed the positions every packed query may
+    read (the argument order of a call with one more input and one more
+    scratch)."""
+    _mla_rows_kernel(lens_ref, qlens_ref, bt_ref, layer_ref, start_ref,
+                     first_ref, last_ref, q_ref, pool_ref, o_ref, kv_buf,
+                     sem, m_ref, l_ref, acc_ref,
+                     allowed=(allowed_ref, allowed_buf), **sizes)
+
+
 @functools.partial(jax.jit, static_argnames=("value_width", "scale", "tq",
-                                             "short", "interpret"))
+                                             "short", "interpret",
+                                             "window"))
 def _mla_rows_call(lengths, q_lens, block_tables, layer, start, qr, pool, *,
-                   value_width, scale, tq, short, interpret):
+                   value_width, scale, tq, short, interpret, window=0,
+                   allowed=None):
     """``_mla_rows_kernel`` over the packed query rows ``qr`` [R, H, W].
     Jitted, with the layer a traced scalar, as :func:`_paged_rows_call`:
     a model's layers share one trace and one lowering a program."""
@@ -1156,11 +1223,14 @@ def _mla_rows_call(lengths, q_lens, block_tables, layer, start, qr, pool, *,
     slot = jnp.arange(len(start))[:, None]
     first = jnp.min(jnp.where(live, slot, len(start)), axis=0)
     last = jnp.max(jnp.where(live, slot, -1), axis=0)
+    selected = allowed is not None
+    span = group * bs
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=7,
         grid=(R // tq,),
         in_specs=[pl.BlockSpec((rows, W), lambda t, *_: (t, 0)),
-                  pl.BlockSpec(memory_space=pl.ANY)],
+                  pl.BlockSpec(memory_space=pl.ANY)]
+        + [pl.BlockSpec(memory_space=pl.ANY)] * selected,
         out_specs=pl.BlockSpec((rows, value_width), lambda t, *_: (t, 0)),
         scratch_shapes=[
             pltpu.VMEM((2, group * bs, W), pool.dtype),    # page buffers
@@ -1168,22 +1238,32 @@ def _mla_rows_call(lengths, q_lens, block_tables, layer, start, qr, pool, *,
             pltpu.VMEM((rows, _LANES), jnp.float32),       # running max
             pltpu.VMEM((rows, _LANES), jnp.float32),       # running denom
             pltpu.VMEM((rows, value_width), jnp.float32),  # output acc
-        ],
+        ] + [pltpu.VMEM((2, tq, span), jnp.float32)] * selected,
     )
+    more = ()
+    if selected:
+        # whole groups of positions: a tile of it is copied a group
+        S = allowed.shape[1]
+        more = (jnp.pad(allowed.astype(jnp.float32),
+                        ((0, 0), (0, -S % span))),)
     o = pl.pallas_call(
-        functools.partial(_mla_rows_kernel, scale=scale, bs=bs, group=group,
-                          tq=tq, heads=H, dv=value_width, short=short),
+        functools.partial(
+            _mla_rows_selected_kernel if selected else _mla_rows_kernel,
+            scale=scale, bs=bs, group=group,
+            tq=tq, heads=H, dv=value_width, short=short, window=window),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((R * H, value_width), qr.dtype),
-        name="ragged_paged_mla",
+        name="ragged_paged_mla_sparse" if selected
+        else "ragged_paged_mla_window" if window else "ragged_paged_mla",
         interpret=interpret,
     )(lengths, q_lens, block_tables, layer, start, first.astype(jnp.int32),
-      last.astype(jnp.int32), qr.reshape(R * H, W), pool)
+      last.astype(jnp.int32), qr.reshape(R * H, W), pool, *more)
     return o.reshape(R, H, value_width)
 
 
 def ragged_paged_mla_rows(q, pool, lengths, q_lens, start, block_tables, *,
-                          value_width, scale, layer=0, interpret=None):
+                          value_width, scale, layer=0, interpret=None,
+                          window=0, allowed=None):
     """:func:`ragged_paged_mla` over a PACKED wave's query rows as they
     lie (``gpt_decode._Rows``: slot-major, slot ``b``'s ``q_lens[b]``
     live rows at ``start[b]``, dead rows at the tail), under the same
@@ -1196,20 +1276,28 @@ def ragged_paged_mla_rows(q, pool, lengths, q_lens, start, block_tables, *,
     that move are the packed rows', ``R / tq`` a call whatever the slots,
     and a tile visits the slots whose rows cross it
     (:func:`row_tile_visits`: each at the full tile, or at the one short
-    window that holds its rows).  Returns o [R, H, value_width]; a row
-    no slot owns, and a slot with lengths 0, return zeros."""
+    window that holds its rows).  ``window`` as :func:`ragged_paged_mla`
+    takes it (``ragged_paged_mla_window`` in a trace).  ``allowed`` ([R,
+    S] of 0 / 1, ``S`` the table's positions) is the SELECTED-rows form,
+    ``ragged_paged_mla_sparse`` in a trace: packed query ``r`` reads the
+    positions of its slot that ``allowed[r]`` marks and no others (a
+    dense walk of the slot's pages under the mask: what a chunk's rows
+    chose is, together, nearly all their slot holds).  Returns o [R, H,
+    value_width]; a row no slot owns, and a slot with lengths 0, return
+    zeros."""
     R, H, W = q.shape
     interpret = _mla_interpret(W, interpret)
-    tq, short = mla_rows_tiling(R, H, q.dtype)
+    tq, short = mla_rows_tiling(R, H, q.dtype, W)
     i32 = lambda x: jnp.asarray(x, jnp.int32)              # noqa: E731
     return _mla_rows_call(
         i32(lengths), i32(q_lens), i32(block_tables), i32(layer).reshape(1),
         i32(start), q, pool, value_width=value_width, scale=float(scale),
-        tq=tq, short=short, interpret=interpret)
+        tq=tq, short=short, interpret=interpret, window=int(window),
+        allowed=allowed)
 
 
 def ragged_paged_mla_reference(q, pool, lengths, q_lens, block_tables, *,
-                               value_width, scale, layer=0):
+                               value_width, scale, layer=0, window=0):
     """Gather-then-mask oracle (f32) for :func:`ragged_paged_mla`, with
     ``ragged_masked_reference``'s conventions (dead rows clip to the
     last live position, where the kernel has zeros past a short tile's
@@ -1222,8 +1310,10 @@ def ragged_paged_mla_reference(q, pool, lengths, q_lens, block_tables, *,
         (lengths - q_lens)[:, None] + jnp.arange(Q)[None, :], 0,
         jnp.maximum(lengths - 1, 0)[:, None])              # [B, Q]
     s = jnp.einsum("bqhc,bsc->bqhs", q.astype(jnp.float32), kv) * scale
-    live = jnp.arange(T * bs)[None, None, None, :] \
-        <= posq[:, :, None, None]
+    kv_pos = jnp.arange(T * bs)[None, None, None, :]
+    live = kv_pos <= posq[:, :, None, None]
+    if window:
+        live &= kv_pos > posq[:, :, None, None] - window
     p = jax.nn.softmax(jnp.where(live, s, NEG_INF), axis=-1)
     out = jnp.einsum("bqhs,bsc->bqhc", p, kv[..., :value_width])
     return out * (lengths > 0)[:, None, None, None]

@@ -344,15 +344,39 @@ def _gelu_tanh(x):
 # refuses any other spec on their paths; folding them is ROADMAP C3/C4).
 
 
+class IndexSpec(NamedTuple):
+    """The learned indexer of a latent attention that reads a SUBSET of
+    its sequence (the DeepSeek-V3.2 family's ``index_*`` keys): ``n_heads``
+    index heads of ``head_dim`` columns, the first ``rope_dim`` of them
+    rotated, ONE index key a cached position; a query row reads the
+    ``topk`` positions of largest ``sum_j w_j relu(q_j . k_s)`` (all of
+    them while it has no more in sight)."""
+
+    n_heads: int
+    head_dim: int
+    topk: int
+    rope_dim: int
+
+
 class LatentSpec(NamedTuple):
     """Multi-head latent attention's five sizes (the source's own
-    ``config.json`` keys)."""
+    ``config.json`` keys) and, of a spec whose latent operators differ BY
+    LAYER (``BlockSpec.latent_by_op``), what else an operator has of its
+    own: ``heads`` (0: the model's), ``gate`` (a head-wise sigmoid gate
+    ``sigmoid(x W_g)`` on the attention's output, before ``W_o``),
+    ``rescale`` (the two low-rank norms' outputs multiplied by
+    ``sqrt(hidden / rank)``) and ``index`` (an ``IndexSpec``: the layer
+    reads the rows its indexer chose).  The defaults trace nothing."""
 
     q_lora_rank: int
     kv_lora_rank: int
     qk_nope_head_dim: int
     qk_rope_head_dim: int
     v_head_dim: int
+    heads: int = 0
+    gate: bool = False
+    rescale: bool = False
+    index: Optional[IndexSpec] = None
 
     @property
     def row_width(self):
@@ -394,12 +418,16 @@ class MuP(NamedTuple):
 # Every operator a layer of ``BlockSpec.ops`` may name, with what such a
 # layer KEEPS from one wave to the next: "pool" (K/V pages of every
 # position), "window" (K/V pages in the window pool), "state" (slot state
-# beside the pool); "none" is a layer with no operator at all (an FFN
-# alone on its one norm), which keeps nothing.  ``BlockSpec.holds`` and
-# ``check_block_spec`` read this table; so does the latter's error text.
+# beside the pool), "index" (an index key a position beside the pool's
+# row, where the layer's latent spec has an indexer); "none" is a layer
+# with no operator at all (an FFN alone on its one norm), which keeps
+# nothing.  ``BlockSpec.holds`` and ``check_block_spec`` read this
+# table; so does the latter's error text.
 OPERATORS = {
     "attention": ("pool",),
     "window_attention": ("window",),
+    "latent_attention": ("pool", "index"),
+    "window_latent_attention": ("window",),
     "conv": ("state",),
     "attention+ssm": ("pool", "state"),
     "retention": ("state",),
@@ -413,6 +441,8 @@ FFN_KINDS = ("gelu", "swiglu", "routed", "none")
 # holds ONE set, so a spec names operators of one kind alone
 STATE_KINDS = {"conv": "conv", "attention+ssm": "ssm", "ssm": "ssm",
                "retention": "retention"}
+# the operators of a latent block (``attention`` "latent" with ``ops``)
+LATENT_OPERATORS = ("latent_attention", "window_latent_attention")
 
 
 class BlockSpec(NamedTuple):
@@ -482,6 +512,16 @@ class BlockSpec(NamedTuple):
     rope_by_op: Optional[tuple] = None
     retention: Optional[tuple] = None
     ffns: Optional[tuple] = None
+    latent_by_op: Optional[tuple] = None
+
+    def latent_of(self, i):
+        """Layer ``i``'s ``LatentSpec``: its operator's entry of
+        ``latent_by_op`` (``((operator, LatentSpec), ...)``), else
+        ``latent``."""
+        for op, la in self.latent_by_op or ():
+            if op == self.op_kind(i):
+                return la
+        return self.latent
 
     def ffn_kind(self, i):
         """Layer ``i``'s FFN: its entry of ``ffns``; else ``ffn``, the
@@ -504,9 +544,11 @@ class BlockSpec(NamedTuple):
         position: a layer with an attention over everything), "window"
         (K/V pages in the window pool: a window layer) or "state" (slot
         state beside the pool: a conv, a state-space mixer or a
-        retention layer).  ``OPERATORS`` says which; a layer with no
-        operator keeps none of them."""
-        return what in OPERATORS[self.op_kind(i)]
+        retention layer) or "index" (index keys beside the pool: a
+        latent layer whose spec has an indexer).  ``OPERATORS`` says
+        which; a layer with no operator keeps none of them."""
+        return what in OPERATORS[self.op_kind(i)] and (
+            what != "index" or self.latent_of(i).index is not None)
 
     def op_index(self, i, what=None):
         """Layer ``i``'s place among the layers that keep what it keeps:
@@ -545,7 +587,7 @@ class BlockSpec(NamedTuple):
     def op_layers(self, L, what):
         """How many of ``L`` layers keep ``what`` ("pool" | "window" |
         "state"; or an operator's name: the layers of that operator)."""
-        if what in ("pool", "window", "state"):
+        if what in ("pool", "window", "state", "index"):
             return sum(1 for i in range(L) if self.holds(i, what))
         return sum(1 for i in range(L) if self.op_kind(i) == what)
 
@@ -610,7 +652,11 @@ def block_spec_of(config):
 def check_block_spec(blk, layers=None):
     """Raise for a spec the mixed wave cannot run.  It runs GPT-2's
     block; latent attention with RMSNorm and RoPE over any FFN kind of
-    ``ffn``; and the grouped-query block: RMSNorm, no biases, an
+    ``ffn``, every layer alike or each one of ``LATENT_OPERATORS`` (a
+    "window_latent_attention" layer over the last ``window`` positions
+    in a latent ring, a "latent_attention" layer over everything or,
+    where its ``LatentSpec`` has an indexer, over the rows that chose);
+    and the grouped-query block: RMSNorm, no biases, an
     optional per-head q/k norm, positions "rope" (over the whole head)
     or "none", every layer ONE of ``OPERATORS`` and one of
     ``FFN_KINDS``, at least one of the two not "none".  What each
@@ -642,35 +688,42 @@ def check_block_spec(blk, layers=None):
             and rt.latent >= 0 and 0 <= rt.held_first
             and 0 <= rt.held
             and rt.held_first + rt.held <= rt.num_experts))
+    ops = blk.ops or ()
+    needs = all(need(blk, ops) for need in _OPERATOR_NEEDS.values()) \
+        and all(o in OPERATORS for o in ops) \
+        and all(op in ops and factor > 0
+                for op, _, factor in blk.rope_by_op or ()) \
+        and (layers is None or not ops or len(ops) == layers)
     if blk.attention == "latent":
+        # one latent spec for every layer, or latent operators BY LAYER
+        # (``LATENT_OPERATORS``), each with a spec, a head count and
+        # rotary parameters of its own
         ok = common and blk.positions == "rope" \
-            and blk.latent is not None and blk.ops is None \
-            and blk.ffns is None \
+            and blk.latent is not None and blk.ffns is None \
             and blk.ssm is None and blk.mup is None and not blk.head_dim \
-            and not blk.window and blk.rope_by_op is None \
-            and blk.retention is None
+            and blk.retention is None and needs \
+            and (bool(ops) or (
+                not blk.window and blk.rope_by_op is None
+                and blk.latent_by_op is None
+                and blk.latent.index is None and not blk.latent.heads))
     else:
-        ops = blk.ops or ()
         n = max(len(ops), len(blk.ffns or ()))
         ok = common and blk.attention == "gqa" and blk.latent is None \
             and blk.positions in ("rope", "none") \
-            and not blk.bias and blk.kv_heads >= 1 \
-            and all(o in OPERATORS for o in ops) \
+            and not blk.bias and blk.kv_heads >= 1 and needs \
             and len({STATE_KINDS[o] for o in ops if o in STATE_KINDS}) <= 1 \
-            and all(need(blk, ops) for need in _OPERATOR_NEEDS.values()) \
             and all(blk.op_kind(i) != "none" or blk.ffn_kind(i) != "none"
                     for i in range(n)) \
-            and all(op in ops and factor > 0
-                    for op, _, factor in blk.rope_by_op or ()) \
-            and (blk.positions == "rope" or blk.rope_by_op is None) \
-            and (layers is None or not ops or len(ops) == layers)
+            and (blk.positions == "rope" or blk.rope_by_op is None)
     if not ok:
         raise ValueError(
             f"the mixed wave runs GPT-2's block, latent attention with "
             f"rmsnorm and rope, or the grouped-query block with rmsnorm "
             f"and positions rope or none, a layer one operator of "
             f"{', '.join(OPERATORS)} (state of one kind a spec; window "
-            f"and retention layers beside plain attention alone) and one "
+            f"and retention layers beside plain attention alone; "
+            f"{' and '.join(LATENT_OPERATORS)} in a latent block alone, "
+            f"an indexer on the first) and one "
             f"FFN of {', '.join(FFN_KINDS)}, not both none; rotary kinds "
             f"{', '.join(ROPE_KINDS)}; routers {', '.join(SCORINGS)}; "
             f"experts {', '.join(EXPERT_FORMS)}: it cannot run {blk}")
@@ -684,6 +737,22 @@ def _retention_fits(blk, ops):
         and blk.head_dim % 2 == 0 and blk.mup is None
 
 
+def _latent_ops_fit(blk, ops):
+    by_op = dict(blk.latent_by_op or ())
+    specs = {op: by_op.get(op, blk.latent) for op in set(ops)}
+    return blk.attention == "latent" \
+        and set(ops) <= set(LATENT_OPERATORS) and set(by_op) <= set(ops) \
+        and ("window_latent_attention" in ops) == (blk.window >= 1) \
+        and all(la is not None and la.heads >= 0
+                and la.qk_rope_head_dim % 2 == 0
+                for la in specs.values()) \
+        and all(la.index is None or (
+                    op == "latent_attention" and la.index.topk >= 1
+                    and la.index.rope_dim % 2 == 0
+                    and la.index.rope_dim <= la.index.head_dim)
+                for op, la in specs.items())
+
+
 # What a spec that names an operator must carry (and one that does not
 # must not), asked of (spec, its ops): ``check_block_spec``'s one table.
 _OPERATOR_NEEDS = {
@@ -691,9 +760,12 @@ _OPERATOR_NEEDS = {
     "ssm": lambda blk, ops: (blk.ssm is not None) == bool(
         {"attention+ssm", "ssm"} & set(ops)),
     "window_attention": lambda blk, ops:
-        ("window_attention" in ops) == (blk.window >= 1)
+        blk.attention == "latent" and "window_attention" not in ops
+        or ("window_attention" in ops) == (blk.window >= 1)
         and ("window_attention" not in ops
              or set(ops) <= {"attention", "window_attention"}),
+    "latent_attention": lambda blk, ops:
+        not set(ops) & set(LATENT_OPERATORS) or _latent_ops_fit(blk, ops),
     "retention": lambda blk, ops:
         ("retention" in ops) == (blk.retention is not None)
         and ("retention" not in ops or _retention_fits(blk, ops)),
@@ -1434,7 +1506,8 @@ def _window_logits(params, name, h, first_row, window, blk=GPT2_BLOCK,
 
 
 def _latent_attention(params, us, blk, H, h, pool, i, wblk, woff, posns,
-                      live, lens, q_len, block_tables, attn, rows=None):
+                      live, lens, q_len, block_tables, attn, rows=None,
+                      layer=0, index_pool=None):
     """One layer's multi-head latent attention over the paged LATENT
     pool ``[L, N_blocks, block, LatentSpec.row_width]``, every row of
     the wave in the ABSORBED form: ``q_nope`` is carried into latent space
@@ -1449,8 +1522,30 @@ def _latent_attention(params, us, blk, H, h, pool, i, wblk, woff, posns,
     (``ragged_paged_mla_rows``, handed the layout's slot starts: no
     ``[B, Q]`` block of the query or of the result exists); the masked
     path alone unpacks the query for its scores and packs the result
-    back.  Returns (h + attention, pool)."""
-    la = blk.latent
+    back.  Returns (h + attention, pool, index_pool).
+
+    ``layer`` is the layer's number (``i`` its place in ITS pool): of a
+    spec with latent operators BY LAYER it takes the layer's own
+    ``LatentSpec`` (``blk.latent_of``: sizes, head count, the rescale
+    of the two low-rank norms, the head-wise gate under ``mla_gate``)
+    and its operator's rotary parameters (``blk.rope_of``).  A
+    "window_latent_attention" layer is handed the latent RING as
+    ``pool`` with its table, write blocks and band (``live``), and the
+    kernels' ``window``.  A layer whose spec has an indexer
+    (``IndexSpec``) writes an index key a row into ``index_pool``
+    (``mla_index``, ``index_write``), scores every row's index queries
+    against the keys its slot has in sight (``index_score``:
+    ``index_decode.index_select``), takes the ``topk`` largest
+    (``index_topk``: ``chosen_mask``) and attends over those rows of
+    the pool alone, a walk of its slots' pages under the chosen rows'
+    mask (``sparse_mla`` inside ``attention``:
+    ``ragged_paged_mla_rows(allowed=)``).  A spec without latent
+    operators traces nothing of this."""
+    la = blk.latent_of(layer)
+    inv, factor = blk.rope_of(layer)
+    H = la.heads or H
+    window = blk.window \
+        if blk.op_kind(layer) == "window_latent_attention" else 0
     B, Q, _ = h.shape
     dn, dr, dv, dc = (la.qk_nope_head_dim, la.qk_rope_head_dim,
                       la.v_head_dim, la.kv_lora_rank)
@@ -1458,13 +1553,18 @@ def _latent_attention(params, us, blk, H, h, pool, i, wblk, woff, posns,
         x = _norm(blk, params, f"{us}_ln1", h)
         cq = _rms(x @ params[f"{us}_attn_q_a_weight"],
                   params[f"{us}_attn_q_a_norm_scale"], blk.norm_eps)
+        if la.rescale:
+            cq = cq * jnp.asarray(
+                (h.shape[-1] / la.q_lora_rank) ** 0.5, cq.dtype)
         q = (cq @ params[f"{us}_attn_q_b_weight"]).reshape(
             B, Q, H, dn + dr)
         kva = x @ params[f"{us}_attn_kv_a_weight"]          # [B, Q, dc+dr]
         ckv = _rms(kva[..., :dc], params[f"{us}_attn_kv_a_norm_scale"],
                    blk.norm_eps)
-        k_r = _rope(kva[..., dc:], posns, blk.rope_theta)
-        q_rope = _rope(q[..., dn:], posns, blk.rope_theta)
+        if la.rescale:
+            ckv = ckv * jnp.asarray((h.shape[-1] / dc) ** 0.5, ckv.dtype)
+        k_r = _rope(kva[..., dc:], posns, blk.rope_theta, inv, factor)
+        q_rope = _rope(q[..., dn:], posns, blk.rope_theta, inv, factor)
         # the pool's rows are padded to the lane tile with zeros, and
         # so is the query: the pad adds nothing to a score
         pad = pool.shape[-1] - dc - dr
@@ -1480,17 +1580,38 @@ def _latent_attention(params, us, blk, H, h, pool, i, wblk, woff, posns,
     with jax.named_scope("kv_write"):
         pool = pool.at[i, wblk, woff].set(row.astype(pool.dtype))
     scale = (dn + dr) ** -0.5
+    allowed = None
+    if la.index is not None:
+        from . import index_decode
+        select, index_pool = index_decode.index_select(
+            params, us, blk, la, x, cq, index_pool, i, wblk, woff, posns,
+            q_len, block_tables, rows, (inv, factor))
+        # a walk of the slot's pages under the chosen rows' mask (what
+        # a chunk's rows chose is nearly all their slot holds); the
+        # masked path's ``live`` is narrowed to it
+        allowed = index_decode.chosen_mask(select, la.index.topk)
+        live = live & ((allowed.reshape(B, Q, -1) if rows is None
+                        else rows.unpack(allowed[None])) > 0.5)
     with jax.named_scope("attention"):
-        if attn == "ragged" and rows is not None:
+        if attn == "ragged" and allowed is not None:
+            from ..kernels.ragged_attention import ragged_paged_mla_rows
+            with jax.named_scope("sparse_mla"):
+                o_lat = ragged_paged_mla_rows(
+                    qf.reshape((B * Q,) + qf.shape[2:]), pool, lens, q_len,
+                    jnp.arange(len(q_len)) * Q if rows is None
+                    else rows.start, block_tables, value_width=dc,
+                    scale=scale, layer=i, allowed=allowed).reshape(
+                        B, Q, H, dc)
+        elif attn == "ragged" and rows is not None:
             from ..kernels.ragged_attention import ragged_paged_mla_rows
             o_lat = ragged_paged_mla_rows(
                 qf[0], pool, lens, q_len, rows.start, block_tables,
-                value_width=dc, scale=scale, layer=i)[None]
+                value_width=dc, scale=scale, layer=i, window=window)[None]
         elif attn == "ragged":
             from ..kernels.ragged_attention import ragged_paged_mla
             o_lat = ragged_paged_mla(qf, pool, lens, q_len,
                                      block_tables, value_width=dc,
-                                     scale=scale, layer=i)
+                                     scale=scale, layer=i, window=window)
         else:
             if rows is not None:
                 qf = rows.unpack(qf)
@@ -1505,9 +1626,15 @@ def _latent_attention(params, us, blk, H, h, pool, i, wblk, woff, posns,
     with jax.named_scope("mla_absorb"):
         o = jnp.einsum("bqhc,chv->bqhv", o_lat.astype(h.dtype),
                        w_kvb[:, :, dn:]).reshape(B, Q, H * dv)
+    if la.gate:
+        with jax.named_scope("mla_gate"):
+            g = jax.nn.sigmoid((x @ params[f"{us}_attn_gate_weight"]
+                                ).astype(jnp.float32))      # [B, Q, H]
+            o = (o.reshape(B, Q, H, dv).astype(jnp.float32)
+                 * g[..., None]).astype(h.dtype).reshape(B, Q, H * dv)
     with jax.named_scope("attn_out"):
         h = h + o @ params[f"{us}_attn_proj_weight"]
-    return h, pool
+    return h, pool, index_pool
 
 
 def _ffn_of_kind(params, us, blk, h, i, valid, stats, moe=None):
@@ -1768,6 +1895,15 @@ def _mixed_step(params, cfg_tuple, cache_k, cache_v, pos, tokens,
     sight.  Rotary frequencies follow the layer's operator
     (``BlockSpec.rope_of``).  Without ``win`` nothing here changes.
 
+    A latent block with operators BY LAYER (``blk.ops`` of
+    ``LATENT_OPERATORS``): a "window_latent_attention" layer is
+    ``_latent_attention`` over the latent RING (``win`` = (ring pool,
+    None), its own ``LatentSpec`` and head count), a "latent_attention"
+    layer the same over the pool, where its spec has an indexer with
+    ``cache_v`` as the index keys' pool (scopes ``mla_index``,
+    ``index_write``, ``index_score``, ``index_topk``, ``sparse_mla``
+    inside ``attention``) and, where gated, ``mla_gate``.
+
     ONE outer scope names the wave's PROGRAM in the device trace, by the
     static facts the body branches on: ``wave_chunk`` (``has_fresh``),
     ``wave_verify`` (``window > 1``: every wave of an engine that
@@ -1896,9 +2032,20 @@ def _mixed_wave(params, cfg_tuple, cache_k, cache_v, pos, tokens, q_len,
             # latent attention over ONE pool (``cache_v`` is None);
             # ``check_block_spec`` keeps out the combinations this wave
             # does not run
-            h, cache_k = _latent_attention(
-                params, us, blk, H, h, cache_k, i, wblk_r, woff_r,
-                posns_r, live, lens, q_len, block_tables, attn, rows)
+            if blk.holds(i, "window"):
+                # the window layers' latent rows lie in the ring, under
+                # its table and the band
+                h, win_k, _ = _latent_attention(
+                    params, us, blk, H, h, win_k, blk.op_index(i), wblk_w,
+                    woff_r, posns_r, live & near, lens, q_len, win_tables,
+                    attn, rows, layer=i)
+            else:
+                # ``cache_v`` is the index keys' pool of a spec whose
+                # full layers choose what they read (else None)
+                h, cache_k, cache_v = _latent_attention(
+                    params, us, blk, H, h, cache_k, blk.op_index(i),
+                    wblk_r, woff_r, posns_r, live, lens, q_len,
+                    block_tables, attn, rows, layer=i, index_pool=cache_v)
             h = _ffn_of_kind(params, us, blk, h, i, valid_r, moe_stats)
             continue
         # the pool holds the layers with an attention alone (all of

@@ -252,6 +252,10 @@ class ServingEngine:
             block=block, pool_blocks=pool_blocks,
             prefix_share=prefix_share,
             row_shape=(latent.row_width,) if latent else None,
+            # a spec whose full layers choose what they read keeps an
+            # index key a position beside the latent rows
+            **({"index_shape": (latent.index.head_dim,)}
+               if latent and latent.index else {}),
             state_shapes=blk.state_shapes(L, c.hidden_size),
             **self._window_pool(blk, L, want, self.chunk))
         self.cfg_tuple = (self._name, c.num_hidden_layers,
@@ -287,6 +291,10 @@ class ServingEngine:
             self.block_spec.op_layers(c.num_hidden_layers, pages)
             for pages in ("pool", "window"))
         self._window_recycled_seen = 0
+        # layers that read the rows a learned indexer chose, and how
+        # many it keeps a row: their waves count ``serve.sparse.*``
+        self._index_layers = self.block_spec.op_layers(
+            c.num_hidden_layers, "index")
         if self.moe is not None:
             self.cfg_tuple = self.cfg_tuple + (self.moe,)
             E = self.moe.num_experts
@@ -433,8 +441,12 @@ class ServingEngine:
         n = blk.op_layers(layers, "window")
         if not n:
             return {}
+        # a latent block's window layers keep latent rows of their own
+        # width in the ring
+        rows = {"window_row_shape": (dict(blk.latent_by_op)[
+            "window_latent_attention"].row_width,)} if blk.latent else {}
         return {"window_layers": n, "window": blk.window,
-                "window_chunk": chunk or int(max_seq_len)}
+                "window_chunk": chunk or int(max_seq_len), **rows}
 
     # ------------------------------------------------------------- #
     # live weight sync (serving/weight_sync.py)
@@ -560,6 +572,17 @@ class ServingEngine:
             ctx, pairs, window,
             self._attn_tiles(ql, int(wave["q"]), rows_computed)
             if attends else None)
+        if self._index_layers:
+            # a row reads ``min(pos + j + 1, K)`` cached rows: the first
+            # ``K - pos`` rows of a q-block (if any) everything they see
+            K = self.block_spec.latent.index.topk
+            grow = np.clip(K - pos, 0, ql)
+            read = grow * pos + grow * (grow + 1) // 2 + (ql - grow) * K
+            self.metrics.record_sparse(*(
+                int(v) * self._index_layers for v in (
+                    ql.sum(), (ql - grow).sum(), pairs, read.sum(),
+                    np.minimum(read, np.where(ql > 0, pos + ql, 0)).sum(),
+                    ctx)))
         out = {}
         wide = np.where(ql > 1, ql, 0)
         for kind, layers, spec in (
@@ -598,7 +621,10 @@ class ServingEngine:
         counts a tile's VISITS to the slots whose rows cross it.  None
         where the engine's waves run no such kernel (the masked path,
         the int8 pool)."""
-        if not self.fast_path or self.kv_quant:
+        if not self.fast_path or self.kv_quant or (
+                self.block_spec.latent and self.block_spec.ops):
+            # (latent operators by layer: two head counts, two kernels
+            # and no page loop in the layers that choose their rows)
             return None
         H, Dh = self.cfg_tuple[2:4]
         if self.block_spec.latent and rows < len(q_len) * Q:

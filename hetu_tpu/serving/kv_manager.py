@@ -304,7 +304,13 @@ class PagedKVManager:
     ``prefix_share=True``, ``truncate`` below the ring's oldest
     position, the wire, an int8 pool.  A manager without window layers
     has none of this: its arrays, tables and programs are what they
-    were.
+    were.  Beside LATENT rows (``row_shape``) the window layers' ring is
+    latent too: ONE pool ``win_k`` of rows ``window_row_shape`` wide (the
+    window layers' own latent width), ``win_v`` None.  ``index_shape``
+    (with ``row_shape``) adds the index keys of a spec whose full layers
+    choose what they read: ``cache_v`` is then ``[layers, N_blocks,
+    block, *index_shape]`` on the latent pool's own blocks and tables
+    (gauge ``serve.kv.index_bytes``).
 
     NO pool layer (``layers=0``: a model whose every layer keeps slot
     state and no page, ``state_shapes`` then being all it holds):
@@ -322,7 +328,8 @@ class PagedKVManager:
                  pos_cap=None, dtype=jnp.float32, bucket=True,
                  block=16, pool_blocks=None, prefix_share=None,
                  row_shape=None, state_shape=None, state_shapes=None,
-                 window_layers=0, window=0, window_chunk=0):
+                 window_layers=0, window=0, window_chunk=0,
+                 window_row_shape=None, index_shape=None):
         self.pool_layers = int(layers)
         if self.pool_layers < 0 or not (self.pool_layers or state_shapes
                                         or state_shape):
@@ -400,11 +407,19 @@ class PagedKVManager:
                 and envvars.get_bool("HETU_KV_PREFIX_SHARE")
         self.prefix_share = bool(prefix_share)
         self.quant = "int8" if _is_int8(dtype) else None
-        if self.window_layers and (self.quant or row_shape is not None):
+        if self.window_layers and (self.quant or (
+                row_shape is None) != (window_row_shape is None)):
             raise ValueError(
-                "PagedKVManager: window layers beside an int8 pool or "
-                "latent rows: the window kernel reads float K/V rows "
-                "(kv_quant or a latent spec with window layers)")
+                "PagedKVManager: window layers beside an int8 pool, or "
+                "latent rows in one pool and K/V heads in the other: the "
+                "window kernels read float rows of the full pool's kind "
+                "(kv_quant with window layers; row_shape without "
+                "window_row_shape)")
+        if index_shape is not None and row_shape is None:
+            raise ValueError(
+                "PagedKVManager: index_shape goes with latent rows "
+                "(row_shape): the index keys lie where a K/V pool has "
+                "its values")
         if self.stateful and self.quant:
             raise ValueError(
                 "PagedKVManager: an int8 pool beside slot-indexed state: "
@@ -426,7 +441,15 @@ class PagedKVManager:
                     "row has no heads (kv_quant with a latent spec)")
             shape = (layers, self.n_blocks, self.block) + tuple(row_shape)
             self.cache_k = _alloc_cache(shape, dtype, None)
-            self.cache_v = None
+            # a spec with an indexer keeps ONE index key a position a
+            # layer on the same blocks and tables, where a K/V pool has
+            # its values: allocated, shared, forked and freed with the
+            # latent rows
+            self.cache_v = None if index_shape is None else _alloc_cache(
+                shape[:3] + tuple(index_shape), dtype, None)
+            if index_shape is not None:
+                telemetry.set_gauge("serve.kv.index_bytes",
+                                    self.index_bytes)
         else:
             self.heads, self.head_dim = int(heads), int(head_dim)
             row = ((heads, head_dim) if self.quant
@@ -456,10 +479,14 @@ class PagedKVManager:
                 self.table_width)
             # every slot's ring + the scratch block 0
             n_win = self.n_slots * self.ring + 1
-            shape = (self.window_layers, n_win, self.block,
-                     kv_row_width(heads, head_dim))
+            # a latent ring holds ONE row a position a layer (of the
+            # window layers' own width) and has no second pool
+            row = (kv_row_width(heads, head_dim),) \
+                if window_row_shape is None else tuple(window_row_shape)
+            shape = (self.window_layers, n_win, self.block) + row
             self.win_k = _alloc_cache(shape, dtype, None)
-            self.win_v = _alloc_cache(shape, dtype, None)
+            self.win_v = None if self.latent \
+                else _alloc_cache(shape, dtype, None)
             self._win_free = list(range(1, n_win))
             self.win_tables = np.zeros((self.n_slots, self.ring), np.int32)
             telemetry.set_gauge("serve.kv.window_bytes", self.window_bytes)
@@ -530,6 +557,11 @@ class PagedKVManager:
         """HBM bytes of the pool pair that holds every position (scales
         included when quantized): all there is without window layers."""
         return cache_nbytes(self.cache_k) + cache_nbytes(self.cache_v)
+
+    @property
+    def index_bytes(self):
+        """HBM bytes of a latent pool's index keys (0 without any)."""
+        return cache_nbytes(self.cache_v) if self.latent else 0
 
     @property
     def window_bytes(self):
@@ -1071,6 +1103,7 @@ class PagedKVManager:
             "window_blocks_free": self.free_window_blocks,
             "window_blocks_recycled": self.window_blocks_recycled,
             "latent": self.latent,
+            "index_bytes": self.index_bytes,
             "state_bytes": self.state_bytes,
             "state_resets": self.state_resets,
         }

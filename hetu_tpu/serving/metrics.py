@@ -216,6 +216,10 @@ class ServingMetrics(MetricsCore):
         self.attn_window_ctx_tokens = 0
         self.attn_window_score_pairs = 0
         self.window_blocks_recycled = 0
+        # an engine whose full layers read the rows an indexer chose
+        # (``record_sparse``)
+        for key in self._SPARSE_COUNTS:
+            setattr(self, key, 0)
         self.ssm_slot_steps = 0
         self.ssm_rows = 0
         self.ssm_chunk_pairs = 0
@@ -311,6 +315,38 @@ class ServingMetrics(MetricsCore):
             self.window_blocks_recycled += recycled
             telemetry.inc("serve.attn.window_ctx_tokens", ctx)
             telemetry.inc("serve.attn.window_score_pairs", pairs)
+
+    # ``record_sparse``'s running sums, in its arguments' order
+    _SPARSE_COUNTS = ("sparse_rows", "sparse_rows_selecting",
+                      "sparse_keys_in_sight", "sparse_keys_read",
+                      "sparse_keys_needed", "index_ctx_tokens")
+
+    def record_sparse(self, rows, selecting, in_sight, read, needed,
+                      index_ctx):
+        """One wave of an engine with layers that read the rows a
+        learned indexer chose, every count summed over those layers:
+        the wave's live rows (``sparse_rows``), those of them with more
+        positions in sight than the indexer keeps
+        (``sparse_rows_selecting``), the positions the rows had in sight
+        (``sparse_keys_in_sight``), the cached rows they read, ``min(in
+        sight, topk)`` a row (``sparse_keys_read``), the cached rows the
+        wave could not do without, a slot's rows read or, where fewer,
+        its positions in sight once (``sparse_keys_needed``), and the
+        index keys the live slots held after the wave's writes, once a
+        slot (``index_ctx_tokens``).  Running sums here and the counters
+        ``serve.sparse.*`` / ``serve.index.ctx_tokens``."""
+        self.sparse_rows += int(rows)
+        self.sparse_rows_selecting += int(selecting)
+        self.sparse_keys_in_sight += int(in_sight)
+        self.sparse_keys_read += int(read)
+        self.sparse_keys_needed += int(needed)
+        self.index_ctx_tokens += int(index_ctx)
+        telemetry.inc("serve.sparse.rows", int(rows))
+        telemetry.inc("serve.sparse.rows_selecting", int(selecting))
+        telemetry.inc("serve.sparse.keys_in_sight", int(in_sight))
+        telemetry.inc("serve.sparse.keys_read", int(read))
+        telemetry.inc("serve.sparse.keys_needed", int(needed))
+        telemetry.inc("serve.index.ctx_tokens", int(index_ctx))
 
     def record_state_scan(self, kind, live_slots, rows, chunk_pairs,
                           layers, kernel_slots=0):
@@ -675,7 +711,7 @@ class ServingMetrics(MetricsCore):
                     "attn_tiles_live", "attn_tiles_short",
                     "attn_q_tiles_moved",
                     "attn_window_ctx_tokens", "attn_window_score_pairs",
-                    "window_blocks_recycled",
+                    "window_blocks_recycled", *_SPARSE_COUNTS,
                     "ssm_slot_steps", "ssm_rows", "ssm_chunk_pairs",
                     "ssm_kernel_slot_steps",
                     "ret_slot_steps", "ret_rows", "ret_chunk_pairs",
@@ -764,6 +800,7 @@ class ServingMetrics(MetricsCore):
             "attn_window_ctx_tokens": count("attn_window_ctx_tokens"),
             "attn_window_score_pairs": count("attn_window_score_pairs"),
             "window_blocks_recycled": count("window_blocks_recycled"),
+            **{key: count(key) for key in self._SPARSE_COUNTS},
             "ssm_slot_steps": count("ssm_slot_steps"),
             "ssm_rows": count("ssm_rows"),
             "ssm_chunk_pairs": count("ssm_chunk_pairs"),

@@ -1,0 +1,546 @@
+"""CPU tests of what ISSUE 51 adds to the benchmark: the runner
+``serve_sparse_latent`` end to end at a small size with every part of its
+comparison, each control coming out not correct, the configuration, cell,
+traffic and metric entries and their files, ``opcount_sparse_latent``
+against numbers worked by hand, and the new reader on a hand-made trace.
+
+They live here and not in ``tests/benchmark/``: that directory's own test
+pins its listing to one file, and a PR may not edit a file the benchmark
+already has.  A CPU run shows control flow and counts; no time, rate or
+share read here is a device number.
+"""
+
+import os
+
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+
+import gc
+import io
+import json
+import sys
+
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+
+from benchmarks import opcount_sparse_latent, run as bench_run  # noqa: E402
+
+BENCH = bench_run.load_json(os.path.join(ROOT, "BENCHMARK.json"))
+PEAK = bench_run.load_json(os.path.join(ROOT, "benchmarks", "peaks.json"))[
+    "TPU v5 lite"]
+CELL = "serve-dots3-note-notes-closed"
+CONFIG = "dots3-note-prev"
+SOURCE = ("https://huggingface.co/dots-studio/dots3-note-prev/blob/main/"
+          "config.json")
+REDUCED = ["num_hidden_layers", "layer_types", "n_routed_experts",
+           "vocab_size"]
+CONTROLS = ["nearest", "no_selection", "window_minus", "window_plus",
+            "window_half", "window_double", "no_gate", "no_rescale",
+            "no_index_rope", "float8", "norm_held"]
+# the file's way of saying it: ``n_routed_experts`` and ``vocab_size`` are
+# what is HELD, the router's width and the vocabulary are ``published``'s
+SMALL = dict(vocab_size=96, hidden_size=64, num_attention_heads=4,
+             num_key_value_heads=4, q_lora_rank=32, kv_lora_rank=16,
+             qk_nope_head_dim=8, qk_rope_head_dim=8, v_head_dim=8,
+             swa_num_attention_heads=2, swa_num_key_value_heads=2,
+             swa_q_lora_rank=32, swa_kv_lora_rank=32,
+             swa_qk_nope_head_dim=16, swa_qk_rope_head_dim=8,
+             swa_v_head_dim=8, sliding_window_size=9, index_n_heads=4,
+             index_head_dim=16, index_topk=16, intermediate_size=128,
+             moe_intermediate_size=32, n_routed_experts=2,
+             num_experts_per_tok=2, max_position_embeddings=256)
+SMALL_MIX = dict(clients=4, request_pool=64, ramp_seconds=0.3,
+                 prompt_len={"median": 32, "sigma": 0.5, "lo": 16, "hi": 96,
+                             "round_to": 8},
+                 output_len={"median": 6, "sigma": 0.5, "lo": 2, "hi": 12})
+
+
+def harness(seconds=2.0, **args_over):
+    resolved = bench_run.resolve_cell(BENCH, CELL)
+    # float32 weights at a width of 64: the order of the sums is all that
+    # differs, so the limits are float32's; a prompt of 6 chunks of 8 is
+    # "long" here and ``index_topk`` is 16
+    args = dict(resolved["config"]["runner_args"], slots=4, pool_blocks=33,
+                prefill_chunk=8, max_seq_len=128, check_requests=3,
+                long_prompt_chunks=6, selecting_rows_min=10,
+                logit_margin=2e-4, tie_margin=1e-5, index_tie_margin=1e-6,
+                held_over_share_max=0.0, over_margin_share_max=0.0,
+                held_rows_min=10, tie_share_max=0.5)
+    args.update(args_over)
+    config = dict(resolved["config"], **SMALL, dtype="float32",
+                  runner_args=args)
+    config["published"] = dict(config["published"], n_routed_experts=8,
+                               vocab_size=192)
+    config["deployment"] = dict(config["deployment"], experts_held=[2, 2],
+                                vocab_rows_held=[96, 96])
+    resolved["config"] = config
+    resolved["traffic"] = dict(resolved["traffic"], **SMALL_MIX)
+    h = bench_run.Harness(resolved, seed=3_000_000_019, seconds=seconds,
+                          trace=False, peak=PEAK, root=ROOT,
+                          out=io.StringIO())
+    h.count_compiles()
+    return h
+
+
+@pytest.fixture(scope="module")
+def runner():
+    return bench_run.load_module("runners", "serve_sparse_latent")
+
+
+@pytest.fixture(scope="module")
+def window(runner):
+    """One served window at the small size, shared by the tests that
+    read it again under a control."""
+    gc.collect()
+    h = harness()
+    return h, runner.serve_window(h)
+
+
+def agree(runner, window, control=None, **limits):
+    h, w = window
+    args = dict(h.config["runner_args"], **limits)
+    return args, runner.agree(h, w["params"], w["ref_config"], w["held"],
+                              w["out"]["done"], args, control=control)
+
+
+def test_runner_end_to_end_at_a_small_size(window, runner):
+    # (``run`` is ``report`` of ``serve_window``: the window the other
+    # tests read again is served once)
+    h, w = window
+    out = runner.report(h, w)
+    log = h.out.getvalue()
+    assert out["correct"], log
+    assert out["attempted"] > 0 and out["failed"] == 0
+    assert out["end_to_end"]["serve_tokens_per_s"] > 0
+    lines = {json.loads(l)["line"]: json.loads(l) for l in log.splitlines()}
+    eng = lines["serve"]["engine"]
+    assert eng["ragged"] and eng["paged"] and eng["drained"]
+    assert eng["warmed_buckets"] == [8]
+    setup = lines["setup"]
+    assert setup["experts_held"] == [2, 2] and setup["router_experts"] == 8
+    assert setup["vocab_rows_held"] == [96, 96]
+    assert setup["index_bytes"] > 0 and setup["window_bytes"] > 0
+    un = out["data"]["counters"]["untraced"]
+    assert un["sparse_rows"] == 2 * un["wave_rows_live"]
+    assert 0 < un["sparse_keys_needed"] <= un["sparse_keys_read"] \
+        < un["sparse_keys_in_sight"]
+    assert 0 < un["sparse_rows_selecting"] < un["sparse_rows"]
+    assert un["index_ctx_tokens"] == 2 * un["attn_ctx_tokens"]
+    assert 0 < un["moe_assignments"] < un["moe_assignments_routed"]
+    assert 0 < out["data"]["snapshot"]["window_ctx_share"] < 1
+    names = {c["name"] for c in out["compared"]}
+    assert names == {"held_over_share", "over_margin_share",
+                     "near_tie_share", "held_rows", "selecting_rows",
+                     "longest_checked_prompt_chunks", "exact_lengths"}
+    assert all(c["within"] for c in out["compared"])
+    # the new per-layer metrics that read counters read them here too
+    data = dict(out["data"], harness=h)
+    for name, lo, hi in (("sparse_ctx_share.serve", 0, 100),
+                         ("selecting_row_share.serve", 0, 100),
+                         ("held_assignment_share.serve", 5, 60),
+                         ("window_ctx_share.serve", 0, 100)):
+        got = bench_run.per_layer_metrics([{"name": name, "unit": "%"}],
+                                          data)
+        assert lo < got[name]["value"] < hi, name
+
+
+def test_the_probe_and_the_reference_name_the_same_controls():
+    from benchmarks import probe_dots3_check, reference_dots3_note
+    assert list(reference_dots3_note.CONTROLS) == CONTROLS
+    from hetu_tpu.models import reference_sparse_latent
+    assert list(reference_sparse_latent.CONTROLS) == CONTROLS
+    # the probe holds the cell's check to all but the two windows of one
+    # position, which it does not resolve
+    assert list(probe_dots3_check.CONTROLS) == [
+        c for c in CONTROLS if c not in ("window_minus", "window_plus")]
+
+
+def test_the_probe_fails_where_the_check_tells_nothing_apart():
+    from benchmarks.probe_dots3_check import unsound
+    sound = {"float32": {"correct": True}, "nearest": {"correct": False}}
+    assert unsound(sound) == []
+    assert unsound(dict(sound, no_gate={"correct": True})) == ["no_gate"]
+    assert unsound({"float32": {"correct": False}}) == ["float32"]
+
+
+# a window one position shorter or longer moves the reference's logits (by
+# 100 tolerances: tests/test_sparse_latent.py) and no served token's rank
+# in four small requests: the two are the controls the chip does not
+# resolve either (PERF.md section 6, PR 51)
+# (five of them marked ``slow``: 7-10 s each of reference compiles; every
+# control's effect on the logits is held in tests/test_sparse_latent.py)
+@pytest.mark.parametrize("control", [None] + [
+    pytest.param(c, marks=pytest.mark.slow) if c in (
+        "window_half", "window_double", "no_gate", "no_rescale",
+        "no_index_rope") else c
+    for c in CONTROLS if c not in ("window_minus", "window_plus")])
+def test_each_control_comes_out_not_correct(window, runner, control):
+    """The same served window read against the reference computed another
+    way: every control is outside one of the limits.  At this width the
+    limits are float32's; the cell's are set between what the bfloat16
+    engine reads and what each control reads on the chip (PERF.md
+    section 6, PR 51)."""
+    args, (ok, record) = agree(runner, window, control)
+    if control is None:
+        assert ok, record
+        assert record["selecting_rows"] >= args["selecting_rows_min"]
+        return
+    assert not ok, record
+    assert record["over_margin_share"] > args["over_margin_share_max"]
+
+
+@pytest.mark.parametrize("limit,value", [
+    ("held_over_share_max", -1.0), ("over_margin_share_max", -1.0),
+    ("tie_share_max", -1.0), ("held_rows_min", 10 ** 9),
+    ("selecting_rows_min", 10 ** 9), ("long_prompt_chunks", 99)])
+def test_each_limit_alone_refuses(window, runner, limit, value):
+    _, (ok, _) = agree(runner, window, **{limit: value})
+    assert not ok
+
+
+def test_near_ties_of_either_kind_are_counted_apart(window, runner):
+    _, (_, sound) = agree(runner, window)
+    _, (ok, record) = agree(runner, window, index_tie_margin=1e9)
+    # every row past ``index_topk`` is then a near tie
+    assert record["near_index_tie_rows"] == record["selecting_rows"] > 0
+    assert record["held_rows"] < sound["held_rows"]
+    _, (_, routed) = agree(runner, window, tie_margin=1e9)
+    assert routed["near_tie_rows"] == routed["rows_checked"]
+
+
+def test_the_file_and_the_deployment_must_agree_on_what_is_held(runner):
+    config = bench_run.resolve_cell(BENCH, CELL)["config"]
+    source, held, rows = runner.published_source(config)
+    assert source["n_routed_experts"] == 256 and held == (0, 32)
+    assert source["vocab_size"] == 152064 and rows == (0, 19008)
+    assert source["num_hidden_layers"] == 5
+    assert not set(runner.OWN_KEYS) & set(source)
+    for part, value in (("experts_held", [0, 16]),
+                        ("vocab_rows_held", [0, 512])):
+        bad = dict(config, deployment=dict(config["deployment"],
+                                           **{part: value}))
+        with pytest.raises(SystemExit, match="disagree"):
+            runner.published_source(bad)
+
+
+# ------------------------------------------------------------------ #
+# entries and their files
+# ------------------------------------------------------------------ #
+
+def catalog_row():
+    path = "/opt/skills/guides/model-configs/architectures.jsonl"
+    if not os.path.isfile(path):
+        pytest.skip("no catalog beside the guides here")
+    with open(path) as f:
+        return next(r for r in map(json.loads, f) if r["name"] == CONFIG)
+
+
+def test_configuration_holds_the_published_widths():
+    entry = next(c for c in BENCH["configs"] if c["name"] == CONFIG)
+    assert entry["source"] == SOURCE and entry["reduced"] == REDUCED
+    assert entry["file"] == f"benchmarks/configs/{CONFIG}.json"
+    assert len(entry["why"]) <= 200
+    conf = bench_run.load_json(os.path.join(ROOT, entry["file"]))
+    assert conf["source"] == SOURCE and conf["reduced"] == REDUCED
+    # ``reduced`` names exactly the keys that differ from ``published``
+    assert sorted(conf["published"]) == sorted(REDUCED)
+    assert all(conf[k] != conf["published"][k] for k in REDUCED)
+    assert sorted(conf["reduced_why"]) == sorted(REDUCED)
+    assert (conf["hidden_size"], conf["num_attention_heads"],
+            conf["swa_num_attention_heads"]) == (5120, 128, 64)
+    assert (conf["q_lora_rank"], conf["kv_lora_rank"],
+            conf["swa_kv_lora_rank"]) == (1024, 512, 1024)
+    assert (conf["qk_nope_head_dim"], conf["qk_rope_head_dim"],
+            conf["v_head_dim"]) == (128, 64, 128)
+    assert (conf["swa_qk_nope_head_dim"], conf["swa_qk_rope_head_dim"],
+            conf["swa_v_head_dim"]) == (192, 64, 128)
+    assert (conf["index_n_heads"], conf["index_head_dim"],
+            conf["index_topk"]) == (64, 128, 2048)
+    assert (conf["sliding_window_size"], conf["moe_intermediate_size"],
+            conf["num_experts_per_tok"], conf["intermediate_size"]) == (
+                513, 1536, 8, 13824)
+    assert conf["layer_types"] == ["full_attention"] \
+        + ["sliding_attention"] * 3 + ["full_attention"]
+    assert conf["num_hidden_layers"] == 5
+    assert conf["deployment"]["chips_a_layer"] == 8
+    assert conf["deployment"]["experts_held"] == [0, 32]
+    assert conf["deployment"]["vocab_rows_held"] == [0, 19008]
+    assert {"apply_mla_qkv_lora_rescale", "attention_gate_type"} <= set(
+        conf["assumed"])
+    assert "memory_analysis" in conf
+    # every key of the catalog row's config, as published, but the four
+    row = catalog_row()
+    assert row["source_url"] == SOURCE
+    for k, v in row["config"].items():
+        assert conf[k] == (v if k not in REDUCED else conf[k]), k
+        if k in REDUCED:
+            assert conf["published"][k] == v, k
+
+
+def test_the_weights_held_are_eight_gigabytes():
+    runner = bench_run.load_module("runners", "serve_sparse_latent")
+    conf = bench_run.resolve_cell(BENCH, CELL)["config"]
+    cfg = runner.model_config(conf)
+    count = 0
+    for name, shape in cfg.param_shapes("d3n").items():
+        n = 1
+        for d in shape:
+            n *= d
+        count += n
+    assert 4.08e9 < count < 4.10e9               # 8.17 GB in bfloat16
+    args = conf["runner_args"]
+    assert args["pool_blocks"] == args["slots"] * (
+        args["max_seq_len"] // 16) + 1
+    from hetu_tpu.models.gpt_decode import check_block_spec
+    check_block_spec(cfg.block_spec(), 5)
+
+
+def test_traffic_file_holds_the_issues_table():
+    mix = bench_run.resolve_cell(BENCH, CELL)["traffic"]
+    assert (mix["loop"], mix["clients"], mix["base_seed"]) == (
+        "closed", 32, 51)
+    assert mix["prompt_len"]["sigma"] == 0.5
+    assert (mix["prompt_len"]["lo"], mix["prompt_len"]["round_to"]) == (
+        2048, 256)
+    # the issue's table, or its ONE stated fallback
+    assert (mix["prompt_len"]["median"], mix["prompt_len"]["hi"]) in (
+        (4096, 16384), (3072, 12288))
+    assert mix["output_len"] == {"median": 160, "sigma": 0.5, "lo": 48,
+                                 "hi": 512}
+    assert (mix["ramp_seconds"], mix["drain_limit_seconds"],
+            mix["trace_seconds"]) == (12.0, 60.0, 6.0)
+    assert mix["request_pool"] % 64 == 0
+    conf = bench_run.resolve_cell(BENCH, CELL)["config"]
+    assert mix["clients"] == conf["runner_args"]["slots"]
+    assert mix["prompt_len"]["hi"] + mix["output_len"]["hi"] \
+        <= conf["runner_args"]["max_seq_len"]
+    assert mix["prompt_len"]["lo"] >= conf["index_topk"]
+
+
+NEW_METRICS = [
+    "index_share.serve", "index_topk_share.serve",
+    "sparse_mla_kernel_share.serve", "sparse_mla_kernel_roofline.serve",
+    "window_mla_kernel_share.serve", "window_mla_kernel_roofline.serve",
+    "sparse_ctx_share.serve", "selecting_row_share.serve",
+    "mla_gate_share.serve"]
+SHARED_METRICS = [
+    "wave_occupancy", "mixed_step_device_ms", "device_idle_share.serve",
+    "kv_write_share.serve", "wave_host_ms", "moe_route_share.serve",
+    "moe_experts_share.serve", "mla_absorb_share.serve",
+    "expert_load_imbalance.serve", "lm_head_share.serve",
+    "chunk_wave_device_ms", "window_ctx_share.serve",
+    "moe_shared_share.serve", "held_assignment_share.serve"]
+
+
+@pytest.mark.parametrize("name", NEW_METRICS + SHARED_METRICS)
+def test_the_cell_reports_the_metric_and_its_files_are_there(name):
+    resolved = bench_run.resolve_cell(BENCH, CELL)
+    entry = next(m for m in resolved["per_layer"] if m["name"] == name)
+    assert entry["moves"] == "serve_tokens_per_s"
+    if name in NEW_METRICS:
+        assert entry["workloads"] == [CELL]
+    else:
+        # appended after the cells accepted before it
+        assert entry["workloads"][-1] == CELL and len(entry["workloads"]) > 1
+    spec = bench_run.load_json(os.path.join(
+        ROOT, "benchmarks", "metrics", name + ".json"))
+    assert os.path.isfile(os.path.join(
+        ROOT, "benchmarks", "readers", spec["reader"] + ".py"))
+
+
+def test_the_cell_is_one_chip_and_the_old_entries_stand():
+    cell = next(w for w in BENCH["workloads"] if w["name"] == CELL)
+    assert cell == dict(cell, config=CONFIG, traffic="notes-closed", chips=1)
+    assert len(cell["why"]) <= 200
+    assert [w["name"] for w in BENCH["workloads"]] == [
+        "train-gpt2-medium-s1024", "serve-gpt2-xl-batch-closed",
+        "serve-glm47flash-reason-closed", "serve-lfm2-8b-a1b-rag-closed",
+        "serve-falcon-h1-34b-chat-closed", "serve-mellum2-12b-code-closed",
+        "serve-brumby-14b-docs-closed", "serve-nemotron3-super-agent-closed",
+        CELL]
+    assert [c["name"] for c in BENCH["configs"]][8] == CONFIG
+    assert BENCH["run_seconds"] == 51
+    assert all(w["chips"] == 1 for w in BENCH["workloads"])
+    resolved = bench_run.resolve_cell(BENCH, CELL)
+    assert {m["name"] for m in resolved["end_to_end"]} == {
+        "serve_tokens_per_s", "setup_s"}
+    # one head count and all experts held are not this model's
+    assert not {m["name"] for m in resolved["per_layer"]} & {
+        "mla_kernel_roofline.serve", "moe_experts_roofline.serve",
+        "mla_kernel_share.serve", "window_kernel_roofline.serve",
+        "ragged_kernel_share.serve", "prefill_wave_ms"}
+    for old in [w["name"] for w in BENCH["workloads"]][:8]:
+        names = {m["name"] for m in bench_run.resolve_cell(
+            BENCH, old)["per_layer"]}
+        assert not set(NEW_METRICS) & names
+
+
+def test_the_parent_exits_cleanly_on_the_cell(runner, monkeypatch):
+    """A program without ``sparse_latent`` (the parent of this PR under
+    this PR's benchmark files) stops before anything is built."""
+    monkeypatch.setitem(sys.modules, "hetu_tpu.models.sparse_latent", None)
+    with pytest.raises(SystemExit, match="no SparseLatentConfig"):
+        runner.model_config({})
+
+
+# ------------------------------------------------------------------ #
+# operations and bytes, against numbers worked by hand
+# ------------------------------------------------------------------ #
+
+D3N = {"num_hidden_layers": 5, "num_attention_heads": 128,
+       "kv_lora_rank": 512, "qk_rope_head_dim": 64,
+       "swa_num_attention_heads": 64, "swa_kv_lora_rank": 1024,
+       "swa_qk_rope_head_dim": 64,
+       "layer_types": ["full_attention"] + ["sliding_attention"] * 3
+       + ["full_attention"]}
+
+
+def test_one_tiny_wave_by_hand():
+    """One wave: a chunk of 256 rows at positions 3,840-4,095 of one slot
+    beside 31 decode rows at 5,000 positions, two full layers, three
+    window layers."""
+    rows = 256 + 31
+    read = (256 * 2048 + 31 * 2048) * 2            # every row past 2,048
+    needed = (4096 + 31 * 2048) * 2                # the chunk: in sight once
+    counters = {"sparse_keys_read": read, "sparse_keys_needed": needed,
+                "sparse_rows": rows * 2, "wave_rows_live": rows,
+                "attn_window_score_pairs": rows * 513,
+                "attn_window_ctx_tokens": (513 + 255) + 31 * 513}
+    ops, nbytes = opcount_sparse_latent.sparse_latent_attention(counters,
+                                                                D3N)
+    assert ops == read * 128 * (576 + 512) * 2 == 327_424_147_456
+    assert nbytes == 2 * (needed * 576 + rows * 2 * 128 * 1088) \
+        == 315_588_608
+    # bound by operations on a v5e: 1.66 ms against 0.39 ms of bytes
+    # (327.4 GFLOP at 197 TFLOP/s; 315.6 MB at 819 GB/s)
+    assert ops / PEAK["bf16_flops_per_s"] > nbytes / PEAK["hbm_bytes_per_s"]
+    ops, nbytes = opcount_sparse_latent.window_latent_attention(counters,
+                                                                D3N)
+    assert ops == rows * 513 * 3 * 64 * (1088 + 1024) * 2 == 119_405_518_848
+    assert nbytes == 2 * 3 * ((768 + 31 * 513) * 1088
+                              + rows * 64 * 2112) == 341_587_584
+    assert opcount_sparse_latent.layers_of(D3N, "full_attention") == 2
+
+
+def _trace():
+    """A hand-made trace: 40 ms window; the gather 3 ms and the sparse
+    kernel 2 + 2 ms inside one ``while`` of 8 ms under ``sparse_mla``,
+    the window kernel 3 x 1 ms, the indexer 2 + 4 + 1 ms, the top-k 5
+    ms, the gate 1 ms, another operation 2 ms: 26 ms busy."""
+    ms = 1e6
+    at = "jit(f)/wave_chunk/"
+    stacks = [at + "attention/sparse_mla/while",
+              at + "attention/sparse_mla/while/body",
+              at + "attention/sparse_mla/while/body",
+              at + "attention/sparse_mla/while/body",
+              at + "attention", at + "attention", at + "attention",
+              at + "mla_index", at + "index_score", at + "index_write",
+              at + "index_topk", at + "mla_gate", at + "mla_qkv"]
+    call = " = bf16[] custom-call()"
+    ops = [["%while.1 = () while()", 0 * ms, 8 * ms],
+           ["%fusion.1 = bf16[] fusion()", 0.5 * ms, 3 * ms],
+           ["%ragged_paged_mla_sparse.1" + call, 3.6 * ms, 2 * ms],
+           ["%ragged_paged_mla_sparse.1" + call, 5.8 * ms, 2 * ms],
+           ["%ragged_paged_mla_window.1" + call, 9 * ms, 1 * ms],
+           ["%ragged_paged_mla_window.2" + call, 11 * ms, 1 * ms],
+           ["%ragged_paged_mla_window.3" + call, 13 * ms, 1 * ms],
+           ["%fusion.2 = f32[] fusion()", 15 * ms, 2 * ms],
+           ["%fusion.3 = f32[] fusion()", 18 * ms, 4 * ms],
+           ["%fusion.4 = f32[] fusion()", 23 * ms, 1 * ms],
+           ["%sort.5 = f32[] sort()", 25 * ms, 5 * ms],
+           ["%fusion.6 = f32[] fusion()", 31 * ms, 1 * ms],
+           ["%fusion.7 = f32[] fusion()", 33 * ms, 2 * ms]]
+    return {"planes": [
+        {"name": "/device:TPU:0",
+         "lines": [{"name": "XLA Ops", "events": ops}]},
+        {"name": "/host:CPU",
+         "lines": [{"name": "python3",
+                    "events": [["bench.engine_step", 0.0, 40 * ms]]}]}],
+        "op_scopes": {"table": stacks, "index": list(range(13))}}
+
+
+class _H:
+    peak = PEAK
+    config = D3N
+
+    def __init__(self, config=None):
+        self.lines = []
+        if config is not None:
+            self.config = config
+
+    def log(self, **record):
+        self.lines.append(record)
+
+
+def metric_args(name):
+    return bench_run.load_json(os.path.join(
+        ROOT, "benchmarks", "metrics", name + ".json"))["args"]
+
+
+def test_the_two_rooflines_on_a_hand_made_trace():
+    reader = bench_run.load_module("readers",
+                                   "kernel_roofline_sparse_latent")
+    counters = {"sparse_keys_read": 1_175_552, "sparse_keys_needed": 135_168,
+                "sparse_rows": 574, "wave_rows_live": 287,
+                "attn_window_score_pairs": 147_231,
+                "attn_window_ctx_tokens": 16_671}
+    h = _H()
+    data = {"trace": _trace(), "harness": h,
+            "counters": {"traced": counters}}
+    # 327.4 GFLOP / 197e12 = 1.662 ms over the ``while`` of 8 ms (the
+    # gather and both kernel calls inside it, once)
+    got = reader.read(data, **metric_args("sparse_mla_kernel_roofline.serve"))
+    assert got == pytest.approx(
+        100 * (327_424_147_456 / PEAK["bf16_flops_per_s"]) / 8e-3)
+    assert 0 < got < 100 and h.lines[-1]["bound"] == "operations"
+    assert h.lines[-1]["kernel_s"] == pytest.approx(8e-3)
+    got = reader.read(data, **metric_args("window_mla_kernel_roofline.serve"))
+    assert got == pytest.approx(
+        100 * (119_405_518_848 / PEAK["bf16_flops_per_s"]) / 3e-3)
+    assert 0 < got < 100
+    # the parent (no counters), another configuration, no such scope
+    args = metric_args("sparse_mla_kernel_roofline.serve")
+    assert reader.read({"trace": _trace(), "harness": h}, **args) is None
+    assert reader.read(dict(data, counters={"traced": {}}), **args) is None
+    assert reader.read(dict(data, harness=_H({"n_layer": 48})),
+                       **args) is None
+    assert reader.read(data, model="sparse_latent_attention",
+                       scopes=["nothing"]) is None
+    assert h.lines[-1]["line"] == "metric_missing"
+
+
+@pytest.mark.parametrize("name,share", [
+    ("index_share.serve", 7 / 26), ("index_topk_share.serve", 5 / 26),
+    ("sparse_mla_kernel_share.serve", 4 / 26),
+    ("window_mla_kernel_share.serve", 3 / 26),
+    ("mla_gate_share.serve", 1 / 26)])
+def test_the_new_shares_on_a_hand_made_trace(name, share):
+    spec = bench_run.load_json(os.path.join(
+        ROOT, "benchmarks", "metrics", name + ".json"))
+    reader = bench_run.load_module("readers", spec["reader"])
+    got = reader.read({"trace": _trace(), "harness": _H()}, **spec["args"])
+    assert got == pytest.approx(100 * share)
+    # a program with none of these scopes or kernels (the parent):
+    # nothing, no raise
+    bare = _trace()
+    bare["op_scopes"]["table"] = ["jit(f)/mlp"] * 13
+    for e in bare["planes"][0]["lines"][0]["events"]:
+        e[0] = "%fusion.9 = f32[] fusion()"
+    assert reader.read({"trace": bare, "harness": _H()},
+                       **spec["args"]) is None
+
+
+@pytest.mark.parametrize("name,counters,want", [
+    ("sparse_ctx_share.serve",
+     {"sparse_keys_read": 600, "sparse_keys_in_sight": 1500}, 40.0),
+    ("selecting_row_share.serve",
+     {"sparse_rows_selecting": 30, "sparse_rows": 40}, 75.0),
+    ("sparse_ctx_share.serve", {}, None),
+    ("selecting_row_share.serve", {"sparse_rows": 0}, None)])
+def test_the_counter_shares(name, counters, want):
+    spec = bench_run.load_json(os.path.join(
+        ROOT, "benchmarks", "metrics", name + ".json"))
+    reader = bench_run.load_module("readers", spec["reader"])
+    got = reader.read({"counters": {"untraced": counters}}, **spec["args"])
+    assert got == (pytest.approx(want) if want is not None else None)

@@ -919,3 +919,73 @@ def test_ssm_step_at_the_cells_sizes(sds, H, P, N, G):
     state = B * H * P * N * 4
     assert mem.alias_size_in_bytes >= state
     assert mem.temp_size_in_bytes < state // 16
+
+
+# ------------------------------------------------------------------- #
+# ISSUE 51: latent attention by layer at the notes cell's sizes
+# ------------------------------------------------------------------- #
+
+def test_sparse_latent_chunk_program_at_the_published_widths(sds,
+                                                             monkeypatch):
+    """The notes cell's ONE chunk program (Q 256; its prompts are
+    multiples of the chunk) at the published widths, all five layers, 32
+    slots and the cell's pools through ``serve_mixed_paged_fn``: the
+    selected-rows kernel once a full layer (the dense walk under the
+    chosen rows' mask, 128 heads, rows of 640), the window kernel once a sliding layer (64 heads, rows
+    of 1,152: the tile is cut in proportion of the width), the 32 held
+    experts through ``moe_grouped_matmul``, the head over 19,008 columns
+    (148.5 lane tiles); the latent pool, the index keys' pool and the
+    latent ring updated in place.  The configuration's
+    ``memory_analysis`` states this compile and the decode program's."""
+    import json
+    import os
+    from hetu_tpu.kernels import grouped_matmul as gm
+    from hetu_tpu.kernels import ragged_attention as ra
+    from hetu_tpu.models import sparse_latent as sl
+    for module in (ra, gm):
+        monkeypatch.setattr(module, "_use_interpret", lambda: False)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "configs",
+                           "dots3-note-prev.json")) as f:
+        conf = json.load(f)
+    args, dep = conf["runner_args"], conf["deployment"]
+    cfg = sl.SparseLatentConfig.from_hf(
+        dict(conf, n_routed_experts=conf["published"]["n_routed_experts"],
+             vocab_size=conf["published"]["vocab_size"]),
+        held_experts=tuple(dep["experts_held"]),
+        vocab_rows=tuple(dep["vocab_rows_held"]))
+    blk = cfg.block_spec()
+    L, B, S = cfg.num_hidden_layers, args["slots"], args["max_seq_len"]
+    T, N, Q = S // BLOCK, args["pool_blocks"], args["prefill_chunk"]
+    params = {k: sds(s, jnp.float32 if "_moe_router_" in k else jnp.bfloat16)
+              for k, s in cfg.param_shapes("d3n").items()}
+    pool = sds((2, N, BLOCK, 640), jnp.bfloat16)
+    keys = sds((2, N, BLOCK, 128), jnp.bfloat16)
+    ring = -(-(blk.window + Q) // BLOCK) + 1
+    win = (sds((3, B * ring + 1, BLOCK, 1152), jnp.bfloat16), None)
+    i32 = lambda *shape: sds(shape, jnp.int32)  # noqa: E731
+    fn = gd.serve_mixed_paged_fn(donate=True, attn="ragged", window=1)
+    compiled = fn.func.lower(
+        params, ("d3n", L, 128, 40, S, blk), pool, keys, i32(B, T), i32(B),
+        i32(B, Q), i32(B), i32(B), sds((B,), jnp.bool_),
+        sds((B,), jnp.float32), i32(B), sds((B, 2), jnp.uint32),
+        attn="ragged", window=1, has_fresh=True, win=win,
+        ring=i32(B, ring)).compile()
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines() if "custom-call(" in line]
+    assert sum("ragged_paged_mla_sparse" in c for c in calls) == 2
+    assert sum("ragged_paged_mla_window" in c for c in calls) == 3
+    assert sum("moe_grouped_matmul" in c for c in calls) == 2 * 4
+    assert "ragged-dot" not in text
+    mem = compiled.memory_analysis()
+    pools = 2 * (2 * N * BLOCK * (640 + 128)
+                 + 3 * (B * ring + 1) * BLOCK * 1152)
+    assert mem.alias_size_in_bytes >= pools
+    # the widest temporaries are the index scores and the chosen rows'
+    # mask (1,024 x 12,800 float32 each): they grow with neither pool
+    assert mem.temp_size_in_bytes < 1.0e9
+    peak = mem.argument_size_in_bytes + mem.output_size_in_bytes \
+        - mem.alias_size_in_bytes + mem.temp_size_in_bytes
+    assert peak < 12e9
+    stated = conf["memory_analysis"][f"slots_{B}_Q_{Q}_pool_{N}"]
+    assert abs(peak / 1e9 - stated["peak_GB"]) < 0.3

@@ -129,7 +129,7 @@ def test_absorbed_equals_expanded_attention(params, cfg):
     live = jnp.arange(12)[None, None, :] <= posns[:, :, None]
     p1 = {k.replace("glm_h1", "glm_h0"): v for k, v in params.items()
           if k.startswith("glm_h1_")}
-    out, _ = gd._latent_attention(
+    out, _, _ = gd._latent_attention(
         p1, "glm_h0", blk, H, h, pool, 0, wblk, posns % 4, posns, live,
         jnp.full((B,), Q), jnp.full((B,), Q), tables, "masked")
     dn, dr, dv, dc = 8, 8, 16, 16

@@ -34,6 +34,7 @@ from test_chip_compile import (  # noqa: E402,F401
 from test_hybrid_moe import digest, wave_programs
 from test_nemotron_h import nemotron_programs
 from test_retention import retention_programs, window_programs
+from test_sparse_latent import sparse_latent_programs
 from test_window_moe import falcon_case, hybrid_programs, lower_cases
 
 
@@ -69,6 +70,10 @@ FAMILIES = {
     "NEMOTRON_DECODE_TILES_RAGGED": (
         lambda s: nemotron_programs(s, "ragged", qs=(1,), slots=32), True),
     "SSM_STEP_RAGGED": (ssm_kernel_programs, True),
+    "SPARSE_LATENT_MASKED": (
+        lambda s: sparse_latent_programs(s, "masked"), False),
+    "SPARSE_LATENT_RAGGED": (
+        lambda s: sparse_latent_programs(s, "ragged"), True),
 }
 
 PARENT = {
@@ -197,6 +202,27 @@ PARENT = {
         "falcon.Q1.fresh1": "737fe178722df78a",
         "falcon.Q32.fresh0": "7209be57d34aca4a",
         "falcon.Q32.fresh1": "7209be57d34aca4a"},
+    # PR 51's own, no parent's: tests/test_sparse_latent.py's small
+    # five-layer model (latent operators by layer: two full layers with
+    # an indexer of top 16, three window layers over a latent ring of
+    # their own width, the gate, the rescale, 2 of 8 experts held).  4
+    # slots x 32 rows stay padded, so the window layers take the dense
+    # entry ``ragged_paged_mla`` under ``window`` (named
+    # ``ragged_paged_mla_window``); the full layers walk their pages
+    # under the chosen rows' mask in either program
+    # (``ragged_paged_mla_rows(allowed=)``, ``ragged_paged_mla_sparse``);
+    # a latent wave has no fresh variant, so a bucket's two programs are
+    # one text.
+    "SPARSE_LATENT_MASKED": {
+        "sparse_latent.Q1.fresh0": "1176acbbb3579b74",
+        "sparse_latent.Q1.fresh1": "1176acbbb3579b74",
+        "sparse_latent.Q32.fresh0": "3e54814565ed48e7",
+        "sparse_latent.Q32.fresh1": "3e54814565ed48e7"},
+    "SPARSE_LATENT_RAGGED": {
+        "sparse_latent.Q1.fresh0": "47efd75d5a24e418",
+        "sparse_latent.Q1.fresh1": "47efd75d5a24e418",
+        "sparse_latent.Q32.fresh0": "984b1ff568454a30",
+        "sparse_latent.Q32.fresh1": "984b1ff568454a30"},
 }
 
 

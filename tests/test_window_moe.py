@@ -567,8 +567,28 @@ def test_a_manager_with_window_layers_refuses_by_name(what):
             manager(dtype="int8")
         return
     if what == "latent":
+        # float latent rows HOLD a latent ring of the window layers' own
+        # width (one pool, no second); mixed kinds and int8 still raise
+        kv = manager(row_shape=(128,), window_row_shape=(256,),
+                     index_shape=(16,))
+        assert kv.win_k.shape[2:] == (4, 256) and kv.win_v is None
+        assert kv.cache_k.shape[2:] == (4, 128)
+        assert kv.cache_v.shape == kv.cache_k.shape[:3] + (16,)
+        assert kv.index_bytes == kv.cache_v.nbytes
+        assert kv.window_bytes == kv.win_k.nbytes
+        s, _ = kv.alloc("a", np.arange(40), 60)
+        kv.advance(s, 40)
+        assert kv.window_blocks_held(s) == kv.ring
+        with pytest.raises(ValueError, match="export_blocks.*latent rows"):
+            kv.export_blocks(s)
+        kv.release(s)
+        assert kv.free_window_blocks == kv.n_slots * kv.ring
         with pytest.raises(ValueError, match="window layers beside"):
             manager(row_shape=(128,))
+        with pytest.raises(ValueError, match="window layers beside an int8"):
+            manager(row_shape=(128,), window_row_shape=(256,), dtype="int8")
+        with pytest.raises(ValueError, match="index_shape goes with latent"):
+            manager(index_shape=(16,))
         return
     kv = manager()
     assert not kv.prefix_share
