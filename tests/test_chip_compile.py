@@ -243,13 +243,17 @@ def test_mixed_step_reads_the_donated_pool_in_place(sds, monkeypatch,
     assert mem.temp_size_in_bytes < pool_bytes // 2
 
 
+@pytest.mark.slow
 @pytest.mark.parametrize("window", [1, 5], ids=["W1", "W5-spec_k4"])
 def test_mixed_wave_tail(sds, window):
     """What follows the last block of a chunk wave of the gpt2-xl cell
     (16 slots, a 256-row q-block, 1600 wide, vocabulary 50257): the
     window's gather, the final LN, the tied head and ``_spec_sample``,
     alone.  The q-block never meets the vocabulary: no [16, 256, 50257]
-    tensor, and temporaries far under its 0.82 GB."""
+    tensor, and temporaries far under its 0.82 GB.  (``slow``: the head
+    and the sampler over a real vocabulary are 30-50 s of the chip
+    compiler's time, which is why the whole-wave programs of this file
+    that are in tier-1 have a vocabulary of 512.)"""
     B, Q, D, V = 16, 256, 1600, 50257
 
     def tail(h, wte, scale, bias, first_row, q_len, temp, top_k, keys):
@@ -404,12 +408,17 @@ def test_grouped_rows_at_the_lfm2_cell_sizes(sds, q_len):
     assert "ragged_paged_mixed" in text
 
 
-@pytest.mark.parametrize("q_len", [1, 256], ids=["Q1", "Q256"])
+@pytest.mark.parametrize("q_len,vocab", [
+    (1, 512), (256, 512),
+    pytest.param(1, None, marks=pytest.mark.slow),
+    pytest.param(256, None, marks=pytest.mark.slow)],
+    ids=["Q1", "Q256", "Q1-own_vocab", "Q256-own_vocab"])
 def test_hybrid_mixed_step_updates_pool_and_state_in_place(sds, monkeypatch,
-                                                           q_len):
-    """The first four layers of the cell (c c A c: both dense FFNs, two
-    routed ones, one attention layer) at the published widths through
-    ``serve_mixed_paged_fn``: ONE kernel call (the pool holds the
+                                                           q_len, vocab):
+    """The first three layers of the cell (c c A: both dense FFNs, a
+    routed one, one attention layer) at the published widths through
+    ``serve_mixed_paged_fn``, under a vocabulary of 512 or ``slow`` the
+    configuration's own 65,536: ONE kernel call (the pool holds the
     attention layer alone), the pool pair and the conv state donated and
     aliased, temporaries that do not grow with the pool."""
     import json
@@ -421,15 +430,16 @@ def test_hybrid_mixed_step_updates_pool_and_state_in_place(sds, monkeypatch,
     with open(os.path.join(root, "benchmarks", "configs",
                            "lfm2-8b-a1b.json")) as f:
         conf = json.load(f)
-    L = 4
+    L = 3
     cfg = HybridMoEConfig.from_hf(dict(
-        conf, num_hidden_layers=L, layer_types=conf["layer_types"][:L]))
+        conf, num_hidden_layers=L, layer_types=conf["layer_types"][:L],
+        vocab_size=vocab or conf["vocab_size"]))
     blk = cfg.block_spec()
     B, T = LFM["slots"], LFM["table"]
     params = {k: sds(s, jnp.float32 if "_moe_router_" in k else jnp.bfloat16)
               for k, s in cfg.param_shapes("lfm").items()}
     pool = sds((1, LFM["blocks"], BLOCK, 512), jnp.bfloat16)
-    state = sds((3, B, 2, cfg.hidden_size), jnp.bfloat16)
+    state = sds((2, B, 2, cfg.hidden_size), jnp.bfloat16)
     i32 = lambda *shape: sds(shape, jnp.int32)  # noqa: E731
     fn = gd.serve_mixed_paged_fn(donate=True, attn="ragged", window=1)
     compiled = fn.func.lower(
@@ -443,7 +453,7 @@ def test_hybrid_mixed_step_updates_pool_and_state_in_place(sds, monkeypatch,
     assert calls[0].count(f"bf16[1,{LFM['blocks']},{BLOCK},512]") >= 2
     mem = compiled.memory_analysis()
     pool_bytes = 2 * LFM["blocks"] * BLOCK * 512 * 2
-    state_bytes = 3 * B * 2 * cfg.hidden_size * 2
+    state_bytes = 2 * B * 2 * cfg.hidden_size * 2
     assert mem.alias_size_in_bytes >= pool_bytes + state_bytes
     assert mem.temp_size_in_bytes < pool_bytes
 
@@ -460,10 +470,11 @@ def _cell_config(name):
         return json.load(f)
 
 
-def _chunk_wave_case(sds, cell):
+def _chunk_wave_case(sds, cell, own_vocab=False):
     """(params, cfg_tuple, pool_k, pool_v, state, slots, table, kernel
     name, kernel calls) of a few layers of a serving cell at its
-    published widths, slots, table and pool."""
+    published widths, slots, table and pool, and a vocabulary of 512
+    (``own_vocab``: the LFM2 cell's own 65,536)."""
     from hetu_tpu.models.moe_decode import HybridMoEConfig, LatentMoEConfig
     if cell == "gpt2-xl":
         L, H = 2, 25
@@ -487,7 +498,8 @@ def _chunk_wave_case(sds, cell):
     L, B, T = 3, LFM["slots"], LFM["table"]   # c c A: dense, dense, routed
     conf = _cell_config("lfm2-8b-a1b.json")
     cfg = HybridMoEConfig.from_hf(dict(
-        conf, num_hidden_layers=L, layer_types=conf["layer_types"][:L]))
+        conf, num_hidden_layers=L, layer_types=conf["layer_types"][:L],
+        vocab_size=conf["vocab_size"] if own_vocab else 512))
     params = {k: sds(v, jnp.float32 if "_moe_router_" in k else jnp.bfloat16)
               for k, v in cfg.param_shapes("lfm").items()}
     pool = sds((1, LFM["blocks"], BLOCK, 512), jnp.bfloat16)
@@ -501,21 +513,28 @@ def _chunk_wave_case(sds, cell):
 LATENT_BLOCKS = ("[32,256,20,640]", "[32,5120,640]", "[32,5120,512]")
 
 
+@pytest.mark.parametrize("against_padded", [
+    False, pytest.param(True, marks=pytest.mark.slow)],
+    ids=["alone", "against-padded"])
 @pytest.mark.parametrize("cell", ["gpt2-xl", "glm-4.7-flash", "lfm2-8b-a1b"])
-def test_packed_chunk_wave_at_the_cells_sizes(sds, monkeypatch, cell):
+def test_packed_chunk_wave_at_the_cells_sizes(sds, monkeypatch, cell,
+                                              against_padded):
     """The chunk program of each serving cell (a 256-row bucket on 16 or
     32 slots) runs its row-wise operators over 1,024 packed rows: the
     kernels are the ones the padded program calls and see the padded
     q-block (the latent cell's, ISSUE 46, the packed rows: its program
     holds no 8,192-row block of the query or of the result), the pool
     (and the conv state) are still updated in place, and the compiler's
-    peak is no higher than the padded program's."""
+    peak is no higher than the padded program's.  What is read off the
+    padded program costs a second compile of the same wave and is
+    ``slow`` (``against-padded``: there the LFM2 cell's two programs
+    have its own vocabulary of 65,536)."""
     from hetu_tpu.kernels import grouped_matmul as gm
     from hetu_tpu.kernels import ragged_attention as ra
     monkeypatch.setattr(ra, "_use_interpret", lambda: False)
     monkeypatch.setattr(gm, "_use_interpret", lambda: False)
     params, cfg_tuple, pk, pv, state, B, T, kernel, n_calls = \
-        _chunk_wave_case(sds, cell)
+        _chunk_wave_case(sds, cell, own_vocab=against_padded)
     Q = 256
     assert gd.wave_rows(cfg_tuple, B, 1, Q) == 1024 < B * Q
     i32 = lambda *shape: sds(shape, jnp.int32)  # noqa: E731
@@ -535,32 +554,18 @@ def test_packed_chunk_wave_at_the_cells_sizes(sds, monkeypatch, cell):
             has_fresh=True, state=state).compile()
 
     packed = compile_wave()
-    with monkeypatch.context() as m:
-        m.setattr(gd, "wave_rows",
-                  lambda cfg, slots, window, q, *a, **k: slots * q)
-        padded = compile_wave()
     text = packed.as_text()
     calls = [line for line in text.splitlines()
              if "custom-call(" in line and kernel in line]
     assert len(calls) == n_calls
     assert all("tpu_custom_call" in c for c in calls)
-    # the rows kernel is handed the padded q-block, as in the padded
-    # program (the wrapper lays it out for the kernel: the same call
-    # line); the latent kernel the packed rows, as they lie
-    padded_calls = [line for line in padded.as_text().splitlines()
-                    if "custom-call(" in line and kernel in line]
     shape_of = lambda line: line.split(  # noqa: E731
         " custom-call(")[0].split("= ")[-1].split("{")[0]
     if cell == "glm-4.7-flash":
+        # the latent kernel is handed the packed rows, as they lie
         H, dc = cfg_tuple[2], cfg_tuple[5].latent.kv_lora_rank
         assert {shape_of(c) for c in calls} == {f"bf16[{1024 * H},{dc}]"}
-        assert {shape_of(c) for c in padded_calls} == {
-            f"bf16[{B},{Q * H},{dc}]"}
         assert not any(block in text for block in LATENT_BLOCKS)
-        assert all(block in padded.as_text() for block in LATENT_BLOCKS[1:])
-    else:
-        assert [shape_of(c) for c in calls] == [shape_of(c)
-                                                for c in padded_calls]
     # 4,096 assignment rows over 64 or 32 experts: the routed products
     # are the chunk wave's own kernel (ISSUE 41), not the compiler's
     assert ("moe_grouped_matmul" in text) == (cell != "gpt2-xl")
@@ -574,10 +579,29 @@ def test_packed_chunk_wave_at_the_cells_sizes(sds, monkeypatch, cell):
     pools = [a for a in (pk, pv) if a is not None]
     donated = sum(int(np.prod(a.shape)) * 2 for a in pools + (
         [state] if state is not None else []))
-    mem, mem0 = packed.memory_analysis(), padded.memory_analysis()
+    mem = packed.memory_analysis()
     assert mem.alias_size_in_bytes >= donated
+    if not against_padded:
+        return
+    with monkeypatch.context() as m:
+        m.setattr(gd, "wave_rows",
+                  lambda cfg, slots, window, q, *a, **k: slots * q)
+        padded = compile_wave()
+    # the rows kernel is handed the padded q-block, as in the padded
+    # program (the wrapper lays it out for the kernel: the same call
+    # line)
+    padded_calls = [line for line in padded.as_text().splitlines()
+                    if "custom-call(" in line and kernel in line]
+    if cell == "glm-4.7-flash":
+        assert {shape_of(c) for c in padded_calls} == {
+            f"bf16[{B},{Q * H},{dc}]"}
+        assert all(block in padded.as_text() for block in LATENT_BLOCKS[1:])
+    else:
+        assert [shape_of(c) for c in calls] == [shape_of(c)
+                                                for c in padded_calls]
     # no higher than the padded program's
-    assert mem.temp_size_in_bytes <= mem0.temp_size_in_bytes
+    assert mem.temp_size_in_bytes <= padded.memory_analysis(
+        ).temp_size_in_bytes
 
 
 def strip_kernel_locations(text):
@@ -810,18 +834,26 @@ def test_retention_mixed_step_rewrites_the_states_where_they_lie(
 # ISSUE 48: the one-part layers and the held experts at the cell's sizes
 # ------------------------------------------------------------------- #
 
-@pytest.mark.parametrize("q_len,pattern", [
-    (1, "M*E"), (64, "M*E"), (128, "M*E"), (256, None)],
-    ids=["Q1-one_of_each", "Q64-one_of_each", "Q128-one_of_each", "Q256"])
+@pytest.mark.parametrize("q_len,pattern,vocab", [
+    (1, "M*E", 512), (64, "M*E", 512), (128, "M*E", 512), (256, "M*E", 512),
+    *(pytest.param(q, "M*E", None, marks=pytest.mark.slow)
+      for q in (1, 64, 128)),
+    pytest.param(256, None, None, marks=pytest.mark.slow)],
+    ids=["Q1-one_of_each", "Q64-one_of_each", "Q128-one_of_each",
+         "Q256-one_of_each", "Q1-one_of_each-own_vocab",
+         "Q64-one_of_each-own_vocab", "Q128-one_of_each-own_vocab", "Q256"])
 def test_nemotron_wave_programs_at_the_published_widths(sds, monkeypatch,
-                                                        q_len, pattern):
+                                                        q_len, pattern,
+                                                        vocab):
     """The agent cell's four wave programs at the published widths, 64
-    slots and the cell's pool through ``serve_mixed_paged_fn``.  The
-    widest chunk program holds all eleven layers (5 mixers, 1 attention,
-    5 expert layers of 128 held experts of 512); the other three one
-    layer of each kind (the same kernels at their own tiles; the whole
-    depth of all four is in the configuration's ``memory_analysis``: a
-    whole-depth compile is 40-70 s here, and the suite has a limit).  The
+    slots and the cell's pool through ``serve_mixed_paged_fn``: one
+    layer of each kind and a vocabulary of 512 (the same kernels at
+    their own tiles; the whole depth of all four is in the
+    configuration's ``memory_analysis``), and ``slow`` the same three
+    layers under the 32,768 rows the cell holds and the widest chunk
+    program with all eleven layers (5 mixers, 1 attention, 5 expert
+    layers of 128 held experts of 512) under them: a whole-depth compile
+    is 40-70 s here, and the suite has a limit.  The
     pool pair and every state array updated in place, no temporary of a
     state array's size (the compiler, short of memory, once recomputed a
     recurrence: PR 37), ONE attention kernel call, the held experts'
@@ -848,6 +880,8 @@ def test_nemotron_wave_programs_at_the_published_widths(sds, monkeypatch,
     if pattern:
         source.update(hybrid_override_pattern=pattern,
                       num_hidden_layers=len(pattern))
+    if vocab:
+        source.update(vocab_size=vocab)
     cfg = nh.NemotronHConfig.from_hf(
         source, held_experts=tuple(conf["deployment"]["experts_held"]))
     blk = cfg.block_spec()
@@ -925,16 +959,23 @@ def test_ssm_step_at_the_cells_sizes(sds, H, P, N, G):
 # ISSUE 51: latent attention by layer at the notes cell's sizes
 # ------------------------------------------------------------------- #
 
+@pytest.mark.parametrize("kinds", [
+    ["full_attention", "sliding_attention"],
+    pytest.param(None, marks=pytest.mark.slow)],
+    ids=["one_of_each", "all_five"])
 def test_sparse_latent_chunk_program_at_the_published_widths(sds,
-                                                             monkeypatch):
+                                                             monkeypatch,
+                                                             kinds):
     """The notes cell's ONE chunk program (Q 256; its prompts are
-    multiples of the chunk) at the published widths, all five layers, 32
-    slots and the cell's pools through ``serve_mixed_paged_fn``: the
-    selected-rows kernel once a full layer (the dense walk under the
-    chosen rows' mask, 128 heads, rows of 640), the window kernel once a sliding layer (64 heads, rows
+    multiples of the chunk) at the published widths, 32 slots and the
+    cell's pools through ``serve_mixed_paged_fn``, a full layer (with
+    the dense FFN) and a sliding one (with the experts) under a
+    vocabulary of 512, or ``slow`` all five layers and the head over
+    19,008 columns (148.5 lane tiles): the selected-rows kernel once a
+    full layer (the dense walk under the chosen rows' mask, 128 heads,
+    rows of 640), the window kernel once a sliding layer (64 heads, rows
     of 1,152: the tile is cut in proportion of the width), the 32 held
-    experts through ``moe_grouped_matmul``, the head over 19,008 columns
-    (148.5 lane tiles); the latent pool, the index keys' pool and the
+    experts through ``moe_grouped_matmul``; the latent pool, the index keys' pool and the
     latent ring updated in place.  The configuration's
     ``memory_analysis`` states this compile and the decode program's."""
     import json
@@ -949,20 +990,26 @@ def test_sparse_latent_chunk_program_at_the_published_widths(sds,
                            "dots3-note-prev.json")) as f:
         conf = json.load(f)
     args, dep = conf["runner_args"], conf["deployment"]
+    vocab, vocab_rows = conf["published"]["vocab_size"], tuple(
+        dep["vocab_rows_held"])
+    if kinds:
+        conf = dict(conf, layer_types=kinds, num_hidden_layers=len(kinds))
+        vocab, vocab_rows = 512, None
+    full = conf["layer_types"].count("full_attention")
+    sliding = len(conf["layer_types"]) - full
     cfg = sl.SparseLatentConfig.from_hf(
         dict(conf, n_routed_experts=conf["published"]["n_routed_experts"],
-             vocab_size=conf["published"]["vocab_size"]),
-        held_experts=tuple(dep["experts_held"]),
-        vocab_rows=tuple(dep["vocab_rows_held"]))
+             vocab_size=vocab),
+        held_experts=tuple(dep["experts_held"]), vocab_rows=vocab_rows)
     blk = cfg.block_spec()
     L, B, S = cfg.num_hidden_layers, args["slots"], args["max_seq_len"]
     T, N, Q = S // BLOCK, args["pool_blocks"], args["prefill_chunk"]
     params = {k: sds(s, jnp.float32 if "_moe_router_" in k else jnp.bfloat16)
               for k, s in cfg.param_shapes("d3n").items()}
-    pool = sds((2, N, BLOCK, 640), jnp.bfloat16)
-    keys = sds((2, N, BLOCK, 128), jnp.bfloat16)
+    pool = sds((full, N, BLOCK, 640), jnp.bfloat16)
+    keys = sds((full, N, BLOCK, 128), jnp.bfloat16)
     ring = -(-(blk.window + Q) // BLOCK) + 1
-    win = (sds((3, B * ring + 1, BLOCK, 1152), jnp.bfloat16), None)
+    win = (sds((sliding, B * ring + 1, BLOCK, 1152), jnp.bfloat16), None)
     i32 = lambda *shape: sds(shape, jnp.int32)  # noqa: E731
     fn = gd.serve_mixed_paged_fn(donate=True, attn="ragged", window=1)
     compiled = fn.func.lower(
@@ -973,13 +1020,13 @@ def test_sparse_latent_chunk_program_at_the_published_widths(sds,
         ring=i32(B, ring)).compile()
     text = compiled.as_text()
     calls = [line for line in text.splitlines() if "custom-call(" in line]
-    assert sum("ragged_paged_mla_sparse" in c for c in calls) == 2
-    assert sum("ragged_paged_mla_window" in c for c in calls) == 3
-    assert sum("moe_grouped_matmul" in c for c in calls) == 2 * 4
+    assert sum("ragged_paged_mla_sparse" in c for c in calls) == full
+    assert sum("ragged_paged_mla_window" in c for c in calls) == sliding
+    assert sum("moe_grouped_matmul" in c for c in calls) == 2 * (L - 1)
     assert "ragged-dot" not in text
     mem = compiled.memory_analysis()
-    pools = 2 * (2 * N * BLOCK * (640 + 128)
-                 + 3 * (B * ring + 1) * BLOCK * 1152)
+    pools = 2 * (full * N * BLOCK * (640 + 128)
+                 + sliding * (B * ring + 1) * BLOCK * 1152)
     assert mem.alias_size_in_bytes >= pools
     # the widest temporaries are the index scores and the chosen rows'
     # mask (1,024 x 12,800 float32 each): they grow with neither pool
@@ -987,5 +1034,6 @@ def test_sparse_latent_chunk_program_at_the_published_widths(sds,
     peak = mem.argument_size_in_bytes + mem.output_size_in_bytes \
         - mem.alias_size_in_bytes + mem.temp_size_in_bytes
     assert peak < 12e9
-    stated = conf["memory_analysis"][f"slots_{B}_Q_{Q}_pool_{N}"]
-    assert abs(peak / 1e9 - stated["peak_GB"]) < 0.3
+    if not kinds:
+        stated = conf["memory_analysis"][f"slots_{B}_Q_{Q}_pool_{N}"]
+        assert abs(peak / 1e9 - stated["peak_GB"]) < 0.3

@@ -6,7 +6,8 @@ shuffles, (2) overlap host-side batch assembly with the consumer, (3)
 hand the executor device-resident (sharded) batches, (4) surface producer
 errors, and (5) leave PS-embedding-feeding loaders host-side."""
 
-import time
+import itertools
+import threading
 
 import numpy as np
 import pytest
@@ -43,34 +44,29 @@ class TestRing:
         dl.stop_prefetch()
 
     def test_overlaps_producer_work(self):
-        """With a slow transform (stand-in for host slicing + device_put),
-        the ring hides most of the producer latency behind consumer
-        compute."""
-        delay = 0.01
-        X = _data(256)
+        """The producer's transform (stand-in for host slicing +
+        device_put) for batch n + 1 has been called before the consumer
+        asks for it: it ran beside the consumer's compute on batch n."""
+        events, count = [], itertools.count()
+        made = [threading.Event() for _ in range(16)]
 
         def slow(batch):
-            time.sleep(delay)
+            n = next(count)
+            events.append(("made", n))
+            made[n].set()
             return batch
 
-        serial = Dataloader(X, 8, "train")
-        t0 = time.perf_counter()
-        for _ in range(10):
-            slow(serial.get_arr())
-            time.sleep(delay)          # consumer "compute"
-        t_serial = time.perf_counter() - t0
-
-        ringed = Dataloader(X, 8, "train")
+        ringed = Dataloader(_data(256), 8, "train")
         ringed.start_prefetch(transform=slow)
-        ringed.peek_arr()              # warm the ring
-        t0 = time.perf_counter()
-        for _ in range(10):
+        for n in range(10):
+            events.append(("asked", n))
             ringed.get_arr()
-            time.sleep(delay)          # consumer "compute"
-        t_ring = time.perf_counter() - t0
+            # consumer "compute", for as long as the producer takes: a
+            # loader that made a batch only when asked would never end it
+            assert made[n + 1].wait(60), f"batch {n + 1} waits to be asked"
         ringed.stop_prefetch()
-        # serial pays producer+consumer; ring pays ~max of the two
-        assert t_ring < t_serial * 0.8, (t_ring, t_serial)
+        for n in range(1, 10):
+            assert events.index(("made", n)) < events.index(("asked", n))
 
     def test_producer_error_surfaces(self):
         dl = Dataloader(_data(16), 8, "train")

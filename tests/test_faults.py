@@ -349,8 +349,10 @@ class TestShardFailoverSIGKILL:
             _start_ps_process(ports[1], {"HETU_CHAOS_ROLE": "server:1"}),
         ]
         try:
+            # (a spawned server imports the package first: seconds on an
+            # idle machine, and nobody knows how many beside six workers)
             for p in ports:
-                _wait_ps("localhost", p)
+                _wait_ps("localhost", p, timeout=120)
             # fast failure detection: short timeouts, generous retries
             # (chaos losses retry without backoff)
             monkeypatch.setenv("HETU_PS_TIMEOUT", "5")
@@ -371,7 +373,7 @@ class TestShardFailoverSIGKILL:
             # restart the dead primary (no kill this time) + resync
             procs.append(_start_ps_process(
                 ports[0], {"HETU_CHAOS_ROLE": "server:0"}))
-            _wait_ps("localhost", ports[0])
+            _wait_ps("localhost", ports[0], timeout=120)
             restored = c.resync_shard(0)
             assert "t" in restored
             assert c.failed_shards() == []

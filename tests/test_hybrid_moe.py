@@ -25,6 +25,8 @@ from hetu_tpu.serving import Request, ServingEngine
 from hetu_tpu.serving.kv_manager import PagedKVManager
 from hetu_tpu.serving.kv_tiers import TieredKVStore
 
+from jitted import mixed_wave, reference
+
 SMALL = dict(
     vocab_size=257, hidden_size=64, num_hidden_layers=6,
     num_attention_heads=8, num_key_value_heads=2,
@@ -69,7 +71,7 @@ def gap(params, cfg, result, omit=()):
     """The widest gap between a row's largest reference logit and the
     reference logit of the token the engine chose."""
     seq = np.asarray(result.tokens, np.int32)
-    lg, _ = ref.forward(params, cfg, seq[:-1], omit=omit)
+    lg, _ = reference(ref.forward, params, cfg, seq[:-1], omit=omit)
     rows = np.asarray(lg)[result.prompt_len - 1:]
     chosen = rows[np.arange(len(rows)), seq[result.prompt_len:]]
     return float((rows.max(-1) - chosen).max())
@@ -196,7 +198,7 @@ def test_engine_logits_match_reference_row_for_row(params, cfg):
         pos = np.zeros(2, np.int32)
         q_len = np.zeros(2, np.int32)
         pos[slot], q_len[slot] = off, n
-        logits, ck, cv, state = gd._mixed_step(
+        logits, ck, cv, state = mixed_wave(
             params, cfg_tuple, ck, cv, pos, tokens, q_len,
             np.zeros(2, np.int32), np.zeros(2, bool),
             window=tokens.shape[1], block_tables=jnp.asarray(kv.tables),

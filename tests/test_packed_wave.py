@@ -26,6 +26,8 @@ from hetu_tpu.models.moe_decode import (
     init_latent_moe_params)
 from hetu_tpu.serving import Request, ServingEngine
 
+from jitted import reference
+
 HYBRID = dict(
     vocab_size=257, hidden_size=64, num_hidden_layers=6,
     num_attention_heads=8, num_key_value_heads=2,
@@ -193,17 +195,24 @@ def model_case(kind, gpt, hybrid, latent, quant=False):
 def run_step(case, desc, attn, padded, monkeypatch):
     params, cfg_tuple, ck, cv, state, _ = case
     tokens, pos, q_len, first, fresh, tables = desc
+
+    def wave(params, ck, cv, state, tables, *desc):
+        # jitted as the engine's own step is, and a function of its own:
+        # the row count is read while tracing
+        stats = {}
+        out = gd._mixed_step(
+            params, cfg_tuple, ck, cv, *desc, window=W, attn=attn,
+            block_tables=tables, has_fresh=True, moe_stats=stats,
+            state=state)
+        return out, stats
     with monkeypatch.context() as m:
         if padded:
             m.setattr(gd, "wave_rows",
                       lambda cfg, slots, window, q, *a, **k: slots * q)
-        stats = {}
-        out = gd._mixed_step(
-            params, cfg_tuple, ck, cv, jnp.asarray(pos), jnp.asarray(tokens),
-            jnp.asarray(q_len), jnp.asarray(first), jnp.asarray(fresh),
-            window=W, attn=attn, block_tables=jnp.asarray(tables),
-            has_fresh=True, moe_stats=stats, state=state)
-    return out, stats
+        return jax.jit(wave)(
+            params, ck, cv, state, jnp.asarray(tables), jnp.asarray(pos),
+            jnp.asarray(tokens), jnp.asarray(q_len), jnp.asarray(first),
+            jnp.asarray(fresh))
 
 
 def pool_body(cache):
@@ -481,7 +490,7 @@ def test_hybrid_engine_matches_reference_with_packing_engaged(hybrid, fast):
     out = eng.run(requests(257, sizes))
     for r in out.values():
         seq = np.asarray(r.tokens, np.int32)
-        lg, _ = ref_hybrid.forward(params, cfg, seq[:-1])
+        lg, _ = reference(ref_hybrid.forward, params, cfg, seq[:-1])
         rows = np.asarray(lg)[r.prompt_len - 1:]
         chosen = rows[np.arange(len(rows)), seq[r.prompt_len:]]
         assert float((rows.max(-1) - chosen).max()) <= TOL, r.request_id
@@ -521,7 +530,7 @@ def test_latent_engine_matches_reference_with_packing_engaged(
     out = eng.run(requests(257, sizes))
     for r in out.values():
         seq = np.asarray(r.tokens, np.int32)
-        lg, margin = ref_latent.forward(params, cfg, seq[:-1])
+        lg, margin = reference(ref_latent.forward, params, cfg, seq[:-1])
         rows = np.asarray(lg)[r.prompt_len - 1:]
         chosen = rows[np.arange(len(rows)), seq[r.prompt_len:]]
         assert float((rows.max(-1) - chosen).max()) <= TOL, r.request_id
@@ -646,7 +655,7 @@ def test_a_deferred_slots_state_is_untouched(hybrid):
     assert len(out) == 8
     for r in out.values():
         seq = np.asarray(r.tokens, np.int32)
-        lg, _ = ref_hybrid.forward(params, cfg, seq[:-1])
+        lg, _ = reference(ref_hybrid.forward, params, cfg, seq[:-1])
         rows = np.asarray(lg)[r.prompt_len - 1:]
         chosen = rows[np.arange(len(rows)), seq[r.prompt_len:]]
         assert float((rows.max(-1) - chosen).max()) <= TOL, r.request_id

@@ -30,6 +30,8 @@ from hetu_tpu.telemetry import top
 from hetu_tpu.telemetry.trace import (
     check_ret_attribution, check_ssm_attribution)
 
+from jitted import mixed_wave, reference
+
 TOL = 2e-4
 NAME = "bru"
 V = 256
@@ -92,7 +94,7 @@ def mixed_step(params, cfg_tuple, kv, plan, last_only=False):
         tokens[s, :len(t)] = t
         pos[s], q_len[s] = p, len(t)
     first = np.maximum(q_len - 1, 0) if last_only else np.zeros(B, np.int32)
-    logits, ck, cv, kv.state = gd._mixed_step(
+    logits, ck, cv, kv.state = mixed_wave(
         params, cfg_tuple, kv.cache_k, kv.cache_v, pos, tokens, q_len,
         first, np.zeros(B, bool), window=1 if last_only else Q,
         block_tables=jnp.asarray(kv.tables), has_fresh=Q > 1,
@@ -398,7 +400,8 @@ def gap(params, cfg, result, omit=None):
     """The widest (largest logit - served token's logit) over the
     answer's rows, in units of the logits' spread."""
     seq = np.asarray(result.tokens, np.int32)
-    lg = np.asarray(ref.forward(params, cfg, seq[:-1], NAME, omit=omit))
+    lg = np.asarray(reference(ref.forward, params, cfg, seq[:-1], NAME,
+                              omit=omit))
     rows = lg[result.prompt_len - 1:]
     chosen = rows[np.arange(len(rows)), seq[result.prompt_len:]]
     return float((rows.max(-1) - chosen).max() / lg.std())
@@ -540,7 +543,7 @@ def test_beside_attention_layers_chunking_changes_nothing(params, cfg):
             tokens[slot, :n] = seq[off:off + n]
             pos, q_len = np.zeros(B, np.int32), np.zeros(B, np.int32)
             pos[slot], q_len[slot] = off, n
-            lg, kv.cache_k, kv.cache_v, kv.state = gd._mixed_step(
+            lg, kv.cache_k, kv.cache_v, kv.state = mixed_wave(
                 params, t, kv.cache_k, kv.cache_v, pos, tokens, q_len,
                 np.zeros(B, np.int32), np.zeros(B, bool), window=Q,
                 block_tables=jnp.asarray(kv.tables), has_fresh=Q > 1,

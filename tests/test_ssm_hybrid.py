@@ -21,6 +21,8 @@ from hetu_tpu.models import ssm_decode as sd
 from hetu_tpu.serving import Request, ServingEngine
 from hetu_tpu.serving.kv_manager import PagedKVManager
 
+from jitted import mixed_wave, reference
+
 TOL = 1e-4
 NAME = "fh1"
 
@@ -72,7 +74,7 @@ def mixed_step(params, cfg_tuple, kv, plan, last_only=False):
         tokens[s, :len(t)] = t
         pos[s], q_len[s] = p, len(t)
     first = np.maximum(q_len - 1, 0) if last_only else np.zeros(B, np.int32)
-    logits, kv.cache_k, kv.cache_v, kv.state = gd._mixed_step(
+    logits, kv.cache_k, kv.cache_v, kv.state = mixed_wave(
         params, cfg_tuple, kv.cache_k, kv.cache_v, pos, tokens, q_len,
         first, np.zeros(B, bool), window=1 if last_only else Q,
         block_tables=jnp.asarray(kv.tables), has_fresh=Q > 1,
@@ -338,7 +340,7 @@ def gap(params, cfg, result):
     """The widest (largest logit - served token's logit) over the
     answer's rows, in units of the logits' spread."""
     seq = np.asarray(result.tokens, np.int32)
-    lg = np.asarray(ref.forward(params, cfg, seq[:-1], NAME))
+    lg = np.asarray(reference(ref.forward, params, cfg, seq[:-1], NAME))
     rows = lg[result.prompt_len - 1:]
     chosen = rows[np.arange(len(rows)), seq[result.prompt_len:]]
     return float((rows.max(-1) - chosen).max() / lg.std())

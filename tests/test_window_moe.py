@@ -8,6 +8,7 @@ comparison is of LOGITS against the plain reference's full forward
 (``models/reference_window_moe.py``), never of tokens alone.
 """
 
+import functools
 import math
 
 import numpy as np
@@ -28,6 +29,7 @@ from hetu_tpu.serving.kv_tiers import TieredKVStore
 # the described v5e chip and its shape-with-sharding factory
 from test_chip_compile import (  # noqa: E402,F401
     no_compile_cache, sds, topo)
+from jitted import mixed_wave, reference  # noqa: E402
 
 NAME = "mel"
 PUBLISHED_YARN = {
@@ -85,7 +87,12 @@ def gap(params, result, wrong=()):
     """The widest gap between a row's largest reference logit and the
     reference logit of the token the engine chose."""
     seq = np.asarray(result.tokens, np.int32)
-    lg, _ = ref.forward(params, SMALL, seq[:-1], name=NAME, wrong=wrong)
+    # the sound reference as one program a length; a fault's operator by
+    # operator, which the faults share (a program a fault and a length
+    # would be thirty of them)
+    forward = ref.forward if wrong else functools.partial(reference,
+                                                          ref.forward)
+    lg, _ = forward(params, SMALL, seq[:-1], name=NAME, wrong=wrong)
     rows = np.asarray(lg)[result.prompt_len - 1:]
     chosen = rows[np.arange(len(rows)), seq[result.prompt_len:]]
     return float((rows.max(-1) - chosen).max())
@@ -455,7 +462,7 @@ def test_engine_logits_match_reference_row_for_row(params, cfg):
                 off, n = w
                 tokens[slots[who], :n] = seqs[who][off:off + n]
                 pos[slots[who]], q_len[slots[who]] = off, n
-        logits, ck, cv, _, win = gd._mixed_step(
+        logits, ck, cv, _, win = mixed_wave(
             params, cfg_tuple, ck, cv, pos, tokens, q_len,
             np.zeros(2, np.int32), np.zeros(2, bool), window=Q,
             block_tables=kv.tables.copy(), has_fresh=Q > 1, win=win,
@@ -474,7 +481,10 @@ def test_engine_logits_match_reference_row_for_row(params, cfg):
 def test_the_comparison_notices(params, served, wrong):
     _, out = served
     sound = max(gap(params, r) for r in out.values())
-    faulty = max(gap(params, r, wrong=(wrong,)) for r in out.values())
+    # the fault must show in the two longest alone (several rings past
+    # the window): fewer sequences to find it in, and two lengths for
+    # the faulty reference to compile
+    faulty = max(gap(params, out[r], wrong=(wrong,)) for r in ("r3", "r5"))
     assert sound <= TOL
     assert faulty > 500 * TOL, (wrong, faulty)
 
