@@ -350,10 +350,8 @@ def main(argv=None):
 
     import jax
     import hetu_tpu.native
-    from hetu_tpu.compile_cache import (count_cache_events,
-                                        enable_compile_cache)
-    cache_dir = enable_compile_cache()
-    cache = count_cache_events()
+    from hetu_tpu import compile_cache
+    cache_dir = compile_cache.enable_compile_cache()
     t_start = time.perf_counter()
 
     cfg = gpt2_small(BATCH)
@@ -381,11 +379,12 @@ def main(argv=None):
                                f"mixed step (found {rec['kernels']})")
         emit(rec)
 
+    cache = compile_cache.summary()
     emit({"phase": "process",
           "seconds": round(time.perf_counter() - t_start, 1),
           "compile_cache_dir": cache_dir,
-          "compile_cache_hits": cache["hits"],
-          "compile_cache_misses": cache["misses"],
+          "compile_cache_hits": cache["cache_hits"],
+          "compile_cache_misses": cache["cache_misses"],
           "native_libraries": hetu_tpu.native.loaded,
           "peak_bytes_in_use": (jax.devices()[0].memory_stats() or {}).get(
               "peak_bytes_in_use")})

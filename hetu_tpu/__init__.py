@@ -12,6 +12,10 @@ so code written against `import hetu as ht` works with
 `import hetu_tpu as ht`.
 """
 
+import time as _time
+
+_IMPORT_T0 = _time.perf_counter()
+
 __version__ = "0.1.0"
 
 from .context import (
@@ -66,3 +70,11 @@ from .graph.ops_comm import (
     parameterServerCommunicate_op, parameterServerSparsePull_op,
     datah2d_op, datad2h_op, quantized_allreduce_op,
 )
+
+from . import telemetry
+
+# what a process pays before its first line of work, as the program sees
+# it (the interpreter's own start and ``import jax`` by a caller that
+# came first are outside)
+telemetry.set_gauge("process.import_ms",
+                    (_time.perf_counter() - _IMPORT_T0) * 1e3)

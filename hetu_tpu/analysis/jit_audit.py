@@ -18,10 +18,7 @@ This module makes the claim checkable in milliseconds on CPU:
   (``jitted._cache_size()``); :func:`assert_no_recompile` diffs two
   snapshots and raises :class:`JitAuditError` naming every function
   whose cache GREW — serving the same traffic twice, or swapping
-  weights, must be a no-op diff;
-- when the running jax exposes ``jax.monitoring`` event listeners, a
-  process-wide compile counter (``jit.compiles`` in the metrics
-  registry) is kept as corroborating telemetry.
+  weights, must be a no-op diff.
 
 ``tests/test_jit_audit.py`` is the regression gate; suite stage 00k
 runs the same check before chip time.
@@ -34,15 +31,13 @@ import weakref
 from .. import envvars
 
 __all__ = ["JitAuditError", "register_engine", "registered",
-           "snapshot", "assert_no_recompile", "install_monitor",
-           "compiles", "reset"]
+           "snapshot", "assert_no_recompile", "reset"]
 
 # the jitted-step attributes an engine may carry (absent/None skipped)
 _ENGINE_FNS = ("_mixed", "_propose", "_draft_prefill")
 
 _ENGINES: list = []       # [(label, weakref-to-engine)]
 _N_REGISTERED = 0
-_MONITOR = {"installed": False, "compiles": 0}
 
 
 class JitAuditError(RuntimeError):
@@ -114,38 +109,8 @@ def assert_no_recompile(before, after=None, context=""):
     return after
 
 
-def install_monitor():
-    """Best-effort process-wide compile counter via ``jax.monitoring``
-    (newer jax only; silently absent elsewhere).  Idempotent."""
-    if _MONITOR["installed"]:
-        return True
-    try:
-        from jax import monitoring
-
-        def _on_event(event, **kw):
-            if "compil" in str(event):
-                _MONITOR["compiles"] += 1
-                try:
-                    from ..telemetry.metrics import REGISTRY
-                    REGISTRY.counter("jit.compiles").inc()
-                except Exception:
-                    pass
-
-        monitoring.register_event_listener(_on_event)
-        _MONITOR["installed"] = True
-        return True
-    except Exception:
-        return False
-
-
-def compiles() -> int:
-    """Compiles seen by the monitor since install (0 if unavailable)."""
-    return _MONITOR["compiles"]
-
-
 def reset():
-    """Forget registered engines (test isolation; the monitor and its
-    counter persist — listeners cannot be unregistered)."""
+    """Forget registered engines (test isolation)."""
     global _N_REGISTERED
     _ENGINES.clear()
     _N_REGISTERED = 0

@@ -34,6 +34,7 @@ from .graph.autodiff import find_topo_sort
 from .graph.ops_misc import Backward, PlaceholderOp
 from .graph.ops_embed import IndexedSlicesOp
 from .optimizer import OptimizerOp
+from . import telemetry
 
 
 class _ParamView:
@@ -515,7 +516,6 @@ class SubExecutor:
         returns: it is ENQUEUE time, not step time; the step's device
         time is in the profiler's trace, under the same span names with
         the ``hetu.`` prefix."""
-        from . import telemetry
         self._runs += 1
         with telemetry.span("exec.step", subgraph=self.name,
                             step=self._runs):
@@ -523,7 +523,6 @@ class SubExecutor:
                              self._runs)
 
     def _run(self, feed_dict, convert_to_numpy_ret_vals, step):
-        from . import telemetry
         ex = self.executor
         with telemetry.span("exec.feed", subgraph=self.name, step=step):
             feeds = gather_feeds(self, feed_dict)
@@ -707,6 +706,7 @@ def _opt_sharding_like(ex, opt_states):
 class Executor:
     """Multi-subgraph driver (reference executor.py:365-541)."""
 
+    @telemetry.spanned("exec.build")
     def __init__(self, eval_node_dict, config=None, **kargs):
         if isinstance(eval_node_dict, list):
             eval_node_dict = {"default": eval_node_dict}

@@ -187,6 +187,7 @@ class ServingEngine:
     ragged = True
     paged = True
 
+    @telemetry.spanned("serve.engine.build")
     def __init__(self, params, config, *, slots=8, queue_limit=64,
                  max_seq_len=None, name=None, dtype=None, log_path=None,
                  donate=True, fast_path=None, kv_block=None,

@@ -324,6 +324,7 @@ class PagedKVManager:
     refuses stays refused (``_refuse_state``).
     """
 
+    @telemetry.spanned("serve.kv.build")
     def __init__(self, *, layers, heads, head_dim, slots, max_seq_len,
                  pos_cap=None, dtype=jnp.float32, bucket=True,
                  block=16, pool_blocks=None, prefix_share=None,

@@ -20,21 +20,29 @@ Every layer emits into this subsystem and every tool reads from it:
   (``bin/hetu_trace.py``); request-lifecycle tracks + counter tracks.
 - :mod:`.top` — the live terminal dashboard (``bin/hetu_top.py``).
 
-``HETU_TELEMETRY=0`` turns spans and metric recording into no-ops.
+``HETU_TELEMETRY=0`` turns spans and metric recording into no-ops, and
+leaves the compile watch (``hetu_tpu/compile_cache.py``: what JAX
+traced, lowered, compiled or loaded, as ``compile`` records and
+``compile.*`` counters) uninstalled.
 """
 
 from . import flight, health, metrics, slo, top, trace  # noqa: F401
 from .events import (  # noqa: F401
     REQUIRED_FIELDS, STREAMS, TelemetrySink, counter, emit, enabled,
-    gauge, get_sink, histogram, inc, make_record, observe, reset,
-    set_gauge, snapshot, span, validate_record,
+    gauge, get_sink, histogram, inc, make_record, observe, open_spans,
+    reset, set_gauge, snapshot, span, spanned, spanned_calls,
+    validate_record,
 )
 from .metrics import REGISTRY, percentile  # noqa: F401
+from .. import compile_cache  # noqa: E402  (it records through .events)
+
+compile_cache.watch()
 
 __all__ = [
     "REQUIRED_FIELDS", "STREAMS", "REGISTRY", "TelemetrySink",
     "counter", "emit", "enabled", "flight", "gauge", "get_sink",
     "health", "histogram", "inc", "make_record", "metrics", "observe",
-    "percentile", "reset", "set_gauge", "slo", "snapshot", "span",
-    "top", "trace", "validate_record",
+    "open_spans", "percentile", "reset", "set_gauge", "slo", "snapshot",
+    "span", "spanned", "spanned_calls", "top", "trace",
+    "validate_record",
 ]

@@ -40,6 +40,7 @@ near-zero overhead is the contract (asserted as a <2% smoke-tier bound).
 from __future__ import annotations
 
 import collections
+import functools
 import json
 import os
 import threading
@@ -170,6 +171,9 @@ REQUIRED_FIELDS = {
     "kvtier_ps_killed": ("reason",),
     # flight recorder dump header (telemetry/flight.py)
     "flight_dump": ("reason",),
+    # the compile watch (hetu_tpu/compile_cache.py): one record a
+    # program and phase (trace / lower / backend / cache_load)
+    "compile": ("phase", "fun", "ms"),
     # telemetry core + bench
     "span": ("name", "ms"),
     "gauge": ("name", "value"),
@@ -308,11 +312,12 @@ def emit(event, _stream="telemetry", _path=None, _t=None, **fields):
 # the one name prefix of the program's spans in the profiler's trace
 # (the benchmark's own are ``bench.``)
 TRACE_PREFIX = "hetu."
-_OPEN = threading.local()      # .stack: names of this thread's open spans
+_OPEN = threading.local()      # .stack: this thread's open spans, outermost first
 
 
 class _Span:
-    __slots__ = ("name", "fields", "parent", "_t0", "_epoch", "_ann")
+    __slots__ = ("name", "fields", "parent", "ms", "end_perf", "_t0",
+                 "_epoch", "_ann")
 
     def __init__(self, name, fields):
         self.name = name
@@ -326,8 +331,8 @@ class _Span:
         stack = getattr(_OPEN, "stack", None)
         if stack is None:
             stack = _OPEN.stack = []
-        self.parent = stack[-1] if stack else None
-        stack.append(self.name)
+        self.parent = stack[-1].name if stack else None
+        stack.append(self)
         # the entry fields ride the annotation as the host event's stats;
         # the event's NAME stays ``hetu.<name>``
         self._ann = TraceAnnotation(TRACE_PREFIX + self.name, **self.fields)
@@ -337,7 +342,8 @@ class _Span:
         return self
 
     def __exit__(self, exc_type, exc, tb):
-        ms = (time.perf_counter() - self._t0) * 1e3
+        self.end_perf = time.perf_counter()
+        ms = self.ms = (self.end_perf - self._t0) * 1e3
         self._ann.__exit__(exc_type, exc, tb)
         _OPEN.stack.pop()
         REGISTRY.histogram("span." + self.name).observe(ms)
@@ -374,6 +380,44 @@ def span(name, **fields):
     if not enabled():
         return _NOOP_SPAN
     return _Span(name, fields)
+
+
+# the calls ``spanned`` timed, newest last: a histogram has no clock, and
+# a reader of set-up wants only what was built before its window opened
+_SPANNED = collections.deque(maxlen=256)
+
+
+def spanned(name):
+    """``span(name)`` round every call of the decorated function: a
+    constructor's body keeps its indentation and its signature.  Such
+    calls are few a process, so each also leaves ``{"name", "ms",
+    "end_perf"}`` (``time.perf_counter`` at its end) in
+    ``spanned_calls()``."""
+    def wrap(fn):
+        @functools.wraps(fn)
+        def under_span(*args, **kwargs):
+            with span(name) as s:
+                out = fn(*args, **kwargs)
+            if s is not _NOOP_SPAN:
+                _SPANNED.append({"name": name, "ms": s.ms,
+                                 "end_perf": s.end_perf})
+            return out
+        return under_span
+    return wrap
+
+
+def spanned_calls():
+    """What ``spanned`` functions this process has run, oldest first."""
+    return list(_SPANNED)
+
+
+def open_spans():
+    """This thread's open spans, outermost first, each as ``{"name":
+    <name>, **<its fields so far>}`` (the entry fields, and whatever
+    ``set()`` added since): what a record made inside them names as its
+    cause (``compile_cache``'s ``compile`` records)."""
+    return [{**s.fields, "name": s.name}
+            for s in getattr(_OPEN, "stack", ())]
 
 
 # ------------------------------------------------------------------- #
@@ -429,4 +473,5 @@ def reset():
     isolation)."""
     REGISTRY.reset()
     _SINK.reset()
+    _SPANNED.clear()
     flight.RECORDER.reset()

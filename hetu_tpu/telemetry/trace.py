@@ -10,8 +10,12 @@ read.  Output:
   one ``tail | jq`` pipeline, now across all streams at once);
 - ``--export trace.json``: a Chrome/Perfetto-loadable trace —
   duration-carrying records (``span``/``serve_step``/``serve_prefill``/
-  ``req_span``) become ``"X"`` complete events laid out per pid/thread
-  track, ``gauge`` records become ``"C"`` counter tracks (occupancy,
+  ``req_span``/``compile``) become ``"X"`` complete events laid out per
+  pid/thread track (``compile`` records, what JAX traced, lowered,
+  compiled or loaded, on a track of their own beside their thread's,
+  ``compile:<thread>``: the phase is the event's name, ``fun`` and the
+  span it was built under, ``parent``, its arguments, so a cold start
+  shows which dispatch built what), ``gauge`` records become ``"C"`` counter tracks (occupancy,
   queue depth, blocks_free render as time series; ``serve_step``
   records contribute ``serve.queue_depth``/``serve.live`` counters
   too), everything else an ``"i"`` instant — plus a one-line summary
@@ -127,6 +131,7 @@ from .events import STREAMS, validate_record
 _DUR_FIELDS = {
     "span": ("ms", None),              # name comes from the record
     "req_span": ("ms", None),          # name = the lifecycle phase
+    "compile": ("ms", None),           # name = the phase (trace, lower, ..)
     "serve_prefill": ("prefill_ms", "serve.prefill"),
     "serve_step": ("decode_ms", "serve.decode"),
 }
@@ -206,15 +211,26 @@ def to_chrome_trace(events):
         if kind == "req_span":
             # lifecycle phases live on the request's own track
             track = f"req:{rec.get('request')}"
+        elif kind == "compile":
+            # beside the thread's spans, not among them: a program's
+            # phases overlap the dispatch that built it
+            track = f"compile:{rec.get('tid', 'events')}"
         else:
             track = rec.get("tid", rec.get("_src", "events"))
         tid = tid_for(pid, track)
         ts_us = float(rec.get("t", 0.0)) * 1e6
-        if kind == "span" and isinstance(rec.get("us"), (int, float)):
+        if kind in ("span", "compile") \
+                and isinstance(rec.get("us"), (int, float)):
             ts_us = float(rec["us"])
         args = {k: v for k, v in rec.items()
                 if k not in ("t", "us", "event", "pid", "tid", "_src")
                 and isinstance(v, (int, float, str, bool))}
+        if kind == "compile" and isinstance(rec.get("parent"), dict):
+            # serve.wave.dispatch(wave=3, kind=chunk, q=256)
+            fields = dict(rec["parent"])
+            args["parent"] = "{}({})".format(
+                fields.pop("name", None),
+                ", ".join(f"{k}={v}" for k, v in fields.items()))
         if kind == "gauge":
             out.append({"name": str(rec.get("name")), "cat": "gauge",
                         "ph": "C", "ts": ts_us, "pid": pid,

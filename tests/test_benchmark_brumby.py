@@ -415,7 +415,10 @@ def test_the_cell_reports_the_metric_and_its_files_are_there(name):
         ROOT, "benchmarks", "metrics", name + ".json"))
     assert os.path.isfile(os.path.join(
         ROOT, "benchmarks", "readers", spec["reader"] + ".py"))
-    assert len(resolved["per_layer"]) == len(NEW_METRICS + SHARED_METRICS)
+    # (set-up's seven, PR 53, move ``setup_s`` and list every cell)
+    assert len([m for m in resolved["per_layer"]
+                if m["moves"] == "serve_tokens_per_s"]) \
+        == len(NEW_METRICS + SHARED_METRICS)
 
 
 def test_the_traffic_is_the_issues():

@@ -736,22 +736,15 @@ def test_one_program_a_bucket_after_a_burst_of_32():
     for n in (8, 16):
         eng.run([Request(((np.arange(n) + n) % 67).tolist(), 2)])
     assert programs() - before == 3      # (1, decode), (8, chunk), (16, ..)
-    built = []
-
-    def on_duration(event, _secs, **_):
-        if event == "/jax/core/compile/backend_compile_duration":
-            built.append(event)
-
     rng = np.random.RandomState(5)
     reqs = [Request(rng.randint(1, 67, 8 * rng.randint(1, 9)).tolist(),
                     int(rng.randint(2, 9)), request_id=f"p{i}")
             for i in range(32)]
-    jax.monitoring.register_event_duration_secs_listener(on_duration)
-    try:
-        out = eng.run(reqs)
-    finally:
-        jax.monitoring.unregister_event_duration_listener(on_duration)
+    built = telemetry.counter("compile.programs")    # the program's watch
+    built_before = built.get()
+    out = eng.run(reqs)
     assert len(out) == 32 and eng.peak_live == 32
+    assert built.get() == built_before
     assert programs() - before == 3
     assert eng.metrics.snapshot()["chunks_deferred"] > 0
     for r in out.values():

@@ -493,7 +493,7 @@ class TestMergedStream:
 
 def _instrumented_names():
     """(call, name, "file:line") for every name handed to
-    ``telemetry.span/inc/observe/set_gauge`` under the serving stack, the
+    ``telemetry.span/spanned/inc/observe/set_gauge`` under the serving stack, the
     executor and the gradient nodes; ``name`` is None where it is not a
     literal (or a choice between literals)."""
     import ast
@@ -511,8 +511,8 @@ def _instrumented_names():
         for node in ast.walk(tree):
             if not (isinstance(node, ast.Call)
                     and isinstance(node.func, ast.Attribute)
-                    and node.func.attr in ("span", "inc", "observe",
-                                           "set_gauge")
+                    and node.func.attr in ("span", "spanned", "inc",
+                                           "observe", "set_gauge")
                     and isinstance(node.func.value, ast.Name)
                     and node.func.value.id == "telemetry" and node.args):
                 continue
@@ -589,6 +589,64 @@ class TestTraceExport:
         # instants for the point events
         assert any(e.get("ph") == "i" and e["name"] == "worker_exit"
                    for e in trace["traceEvents"])
+
+    def test_compile_records_draw_on_a_track_of_their_own(self, tmp_path):
+        """A canned merged log of a cold start: the first chunk wave's
+        dispatch span and the program it built.  The phases sit on
+        ``compile:<thread>``, named by phase, with ``fun`` and the span
+        that built them as arguments; the span stays on its thread."""
+        log = tmp_path / "cold.jsonl"
+        base = {"pid": 7, "tid": "MainThread"}
+        parent = {"wave": 3, "kind": "chunk", "q": 256, "ahead": False,
+                  "name": "serve.wave.dispatch"}
+        recs = [
+            dict(base, t=100.0, event="span", name="serve.wave.dispatch",
+                 ms=9000.0, us=100_000_000, parent="serve.wave", wave=3,
+                 kind="chunk", q=256),
+            dict(base, t=100.5, event="compile", phase="trace",
+                 fun="_serve_mixed_paged", ms=4000.0, us=100_500_000,
+                 t0=100.5, t1=104.5, parent=parent,
+                 under=["serve.wave", "serve.wave.dispatch"]),
+            dict(base, t=101.0, event="compile", phase="trace",
+                 fun="_kv_rows_kernel", ms=2000.0, us=101_000_000,
+                 t0=101.0, t1=103.0, parent=parent,
+                 under=["serve.wave", "serve.wave.dispatch"]),
+            dict(base, t=104.5, event="compile", phase="lower",
+                 fun="jit(_serve_mixed_paged)", ms=1500.0,
+                 us=104_500_000, t0=104.5, t1=106.0, parent=parent,
+                 under=["serve.wave", "serve.wave.dispatch"]),
+            dict(base, t=106.0, event="compile", phase="backend",
+                 fun="jit(_serve_mixed_paged)", ms=3000.0, cache="miss",
+                 us=106_000_000, t0=106.0, t1=109.0, parent=None,
+                 under=[]),
+        ]
+        log.write_text("".join(json.dumps(r) + "\n" for r in recs))
+        events, bad = read_events([str(log)])
+        assert bad == 0
+        assert all(telemetry.validate_record(e) == [] for e in events)
+        trace, n_spans = to_chrome_trace(events)
+        assert n_spans == 5
+        names = {e["tid"]: e["args"]["name"] for e in trace["traceEvents"]
+                 if e.get("ph") == "M"}
+        assert sorted(names.values()) == ["MainThread",
+                                          "compile:MainThread"]
+        drawn = [e for e in trace["traceEvents"]
+                 if e.get("cat") == "compile"]
+        assert [(e["name"], e["args"]["fun"], e["ts"], e["dur"])
+                for e in drawn] == [
+            ("trace", "_serve_mixed_paged", 100.5e6, 4e6),
+            ("trace", "_kv_rows_kernel", 101e6, 2e6),
+            ("lower", "jit(_serve_mixed_paged)", 104.5e6, 1.5e6),
+            ("backend", "jit(_serve_mixed_paged)", 106e6, 3e6)]
+        assert {names[e["tid"]] for e in drawn} == {"compile:MainThread"}
+        assert drawn[0]["args"]["parent"] == \
+            "serve.wave.dispatch(wave=3, kind=chunk, q=256, ahead=False)"
+        assert drawn[3]["args"]["cache"] == "miss" \
+            and "parent" not in drawn[3]["args"]
+        span = next(e for e in trace["traceEvents"]
+                    if e.get("cat") == "span")
+        assert names[span["tid"]] == "MainThread"
+        assert trace_main([str(log), "--check"]) == 0
 
     def test_cli_export_loadable(self, merged_log, tmp_path, capsys):
         self._populate(merged_log)

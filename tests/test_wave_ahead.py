@@ -10,7 +10,6 @@ first chunk the same wave number.
 
 import time
 
-import jax
 import numpy as np
 import pytest
 
@@ -478,20 +477,12 @@ def test_a_burst_after_a_lone_warm_up_builds_no_program(kind):
     for n in (8, 16):
         eng.run([Request(((np.arange(n) + n) % vocab).tolist(), 2)])
     assert eng.metrics.snapshot()["waves_ahead"] == 0
-    built = []
-
-    def on_duration(event, _secs, **_):
-        if event == "/jax/core/compile/backend_compile_duration":
-            built.append(event)
-
     rng = np.random.default_rng(18)
     reqs = [Request(rng.integers(1, vocab, 8 * int(rng.integers(1, 5))
                                  ).tolist(), int(rng.integers(3, 9)),
                     request_id=f"b{i}") for i in range(12)]
-    jax.monitoring.register_event_duration_secs_listener(on_duration)
-    try:
-        out = closed_loop(eng, reqs, clients=4)
-    finally:
-        jax.monitoring.unregister_event_duration_listener(on_duration)
-    assert len(out) == 12 and not built
+    built = telemetry.counter("compile.programs")    # the program's watch
+    before = built.get()
+    out = closed_loop(eng, reqs, clients=4)
+    assert len(out) == 12 and built.get() == before
     assert eng.metrics.snapshot()["waves_ahead"] > 0
