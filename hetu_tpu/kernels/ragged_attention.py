@@ -38,9 +38,18 @@ under the same wrapper and the same HLO name: pool rows of whole lane
 tiles left in HBM, grid (slot, q-tile), pages copied by hand a group at
 a time with the layer in the copy, the same per-slot data (q_len,
 kv_len, tables), mask, dead-tile rule and f32 online softmax (see the
-comment block over it), and a (slot, q-tile) step scored at the height
-its live rows need (``tile_heights``: a decoding slot's one row beside
-a prompt chunk costs one sublane tile of queries, not the tile's 64).
+comment block over it), every live (slot, q-tile) step scored whole.
+That is the DENSE entry (``ragged_paged_attention``), for a wave whose
+rows lie as q-blocks ``[B, Q]``: every decode and verify wave, and a
+chunk wave too small to pack.  A PACKED chunk wave's rows
+(``gpt_decode._Rows``) go to ``ragged_paged_attention_rows`` as they
+lie (ISSUE 54: ``_kv_rows_packed_kernel``, the same page pipeline,
+mask and softmax step over a grid of the packed rows' tiles, each
+visiting the slots whose rows cross it, under the same HLO names; a
+visit is scored at the height its rows need, ``row_tile_visits``: a
+decoding slot's one row beside a prompt chunk costs one sublane tile of
+queries, not the tile's 64): which entry a wave takes is the caller's
+``rows is not None``, nothing else.
 ONE masked-gather reference (``ragged_masked_reference``) serves them
 all for off-TPU interpret-mode parity.
 
@@ -324,25 +333,27 @@ def _page_group(T, bs, W, dtype):
 
 
 def tile_heights(q_len, t, tq, short):
-    """THE rule of a q-tile's height in the hand-paged kernels: of q-tile
-    ``t`` (``tq`` queries) of a q-block with ``q_len`` live rows, whether
-    it is LIVE (holds a live query) and whether it is scored at its FULL
-    height, all ``tq`` queries; a live tile whose live rows fit the
-    program's ``short`` height (one sublane tile of queries, ``< tq``)
-    is scored at that height: a decoding slot's one row beside a prompt
-    chunk's 256 costs 16 queries of scores, not 64.  Plain operators, so
-    the kernels ask it of a prefetched scalar and the engine's counters
-    (``serve.attn.tiles_live``, ``serve.attn.tiles_short``) of a wave's
-    ``q_len`` array.  A program with one height (``short`` 0) never asks:
+    """THE rule of a q-tile's height in the DENSE hand-paged kernels: of
+    q-tile ``t`` (``tq`` queries) of a q-block with ``q_len`` live rows,
+    whether it is LIVE (holds a live query) and whether it is scored at
+    its FULL height, all ``tq`` queries; a live tile whose live rows fit
+    the program's ``short`` height (``< tq``) is scored at that height.
+    Plain operators, so the latent kernel asks it of a prefetched scalar
+    (:func:`mla_tiling`: a decoding slot's one row beside a prompt
+    chunk's 256 costs 8 queries of scores, not 64) and the engine's
+    counters (``serve.attn.tiles_live``, ``serve.attn.tiles_short``) of
+    a wave's ``q_len`` array.  A program with one height (``short`` 0:
+    every dense program of the K/V rows kernel, whose second height is
+    the PACKED entry's, :func:`row_tile_visits`) never asks:
     :func:`_tile_in_sight`."""
     rows = q_len - t * tq
     return rows > 0, rows > short
 
 
 def row_tile_visits(start, q_len, t, tq, short):
-    """THE rule of a VISIT in the packed latent kernel, whose q-tiles are
-    tiles of ``tq`` PACKED query rows and not of one slot's q-block: row
-    tile ``t`` visits every slot whose rows cross it.  Of a slot whose
+    """THE rule of a VISIT in the packed kernels, whose q-tiles are tiles
+    of ``tq`` PACKED query rows and not of one slot's q-block: row tile
+    ``t`` visits every slot whose rows cross it.  Of a slot whose
     q-block is the packed rows ``[start, start + q_len)``: (``lo``,
     ``hi``) its rows inside the tile, counted from the tile's first;
     whether there are any (LIVE); and whether the visit is scored at the
@@ -350,9 +361,9 @@ def row_tile_visits(start, q_len, t, tq, short):
     ``short`` queries (a tile is a whole number of them, so the windows
     are the packed rows' own) is scored at that window: a decoding
     slot's one row costs ``short`` queries of scores wherever it lies.
-    Plain operators, as :func:`tile_heights`: the kernel asks it of
-    prefetched scalars, its wrapper of the wave's arrays (which slots a
-    tile's loop runs over) and the engine's counters of a wave's
+    Plain operators, as :func:`tile_heights`: the kernels ask it of
+    prefetched scalars, their wrappers of the wave's arrays (which slots
+    a tile's loop runs over) and the engine's counters of a wave's
     ``q_len`` (``serve.attn.tiles_live``, ``tiles_short``)."""
     lo = start - t * tq
     hi = lo + q_len
@@ -366,10 +377,22 @@ def row_tile_visits(start, q_len, t, tq, short):
 
 def _short_height(tq, sub):
     """The short height of a program whose q-tiles are ``tq`` queries:
-    ``sub`` where that is less than a tile, else 0 (one height: the Q 1
-    decode programs and the verify programs, whose lowered text is the
-    one-height kernel's)."""
+    ``sub`` where that is less than a tile, else 0 (one height)."""
     return sub if sub < tq else 0
+
+
+def rows_tiling(Q, H, dtype):
+    """(padded Q, queries a q-tile) of the K/V rows kernel's DENSE
+    program for a q-block of ``Q`` queries of ``H`` query heads in
+    ``dtype``: whole sublane tiles of query rows (8 of f32, 16 of bf16)
+    in tiles of at most ``_MAX_ROWS`` (head, query) rows, each scored
+    whole (ONE height: the second was built for decoding rows beside a
+    chunk's, ISSUE 43, and every such wave of 16 slots or more is packed
+    since ISSUE 54; an unpacked chunk wave, 8 slots or fewer or a
+    capacity router's, scores its live tiles whole)."""
+    sub = 32 // jnp.dtype(dtype).itemsize
+    Qp = -(-Q // sub) * sub
+    return Qp, _fit_block(max(_MAX_ROWS // H, 1), Qp)
 
 
 # Rows a lane chunk's product must have at the SHORT height for a second
@@ -383,21 +406,22 @@ def _short_height(tq, sub):
 _SHORT_MIN_ROWS = 64
 
 
-def rows_tiling(Q, H, head_dim, groups, dtype):
-    """(padded Q, queries a q-tile, short height) of the K/V rows
-    kernel's program for a q-block of ``Q`` queries of ``H`` query heads
-    of ``head_dim``, ``groups`` a K/V head, in ``dtype``: whole sublane
-    tiles of query rows (8 of f32, 16 of bf16), tiles of at most
-    ``_MAX_ROWS`` (head, query) rows, and a second height of one sublane
-    tile of queries where a tile is taller than that and a lane chunk's
+def rows_packed_tiling(R, H, head_dim, groups, dtype):
+    """(padded R, packed queries a row tile, short window) of the K/V
+    rows kernel's PACKED program for ``R`` packed rows of ``H`` query
+    heads of ``head_dim``, ``groups`` a K/V head: :func:`rows_tiling`
+    asked of the packed rows as of one q-block (the dense entry's tile
+    at the same widths), and a second height of one sublane tile of
+    queries where a tile is a whole number of them (the window starts
+    where a slot's rows lie: a traced, aligned start) and a lane chunk's
     product at it still has ``_SHORT_MIN_ROWS`` rows."""
-    sub = 32 // jnp.dtype(dtype).itemsize
-    Qp = -(-Q // sub) * sub
-    tq = _fit_block(max(_MAX_ROWS // H, 1), Qp)
+    Rp, tq = rows_tiling(R, H, dtype)
+    short = _short_height(tq, 32 // jnp.dtype(dtype).itemsize)
     cw = _lane_chunk(kv_row_width(H // groups, head_dim), head_dim)
-    if min(cw // head_dim, H // groups) * groups * sub < _SHORT_MIN_ROWS:
-        return Qp, tq, 0
-    return Qp, tq, _short_height(tq, sub)
+    rows = min(cw // head_dim, H // groups) * groups * short
+    if not short or tq % short or rows < _SHORT_MIN_ROWS:
+        return Rp, tq, 0
+    return Rp, tq, short
 
 
 def _tile_in_sight(lens_ref, qlens_ref, tq, bs, group, short=0):
@@ -447,6 +471,47 @@ def _reset(m_ref, l_ref, acc_ref, at=(Ellipsis,)):
     """Empty accumulators in the rows ``at`` (all of them by default)."""
     for ref, fill in ((m_ref, NEG_INF), (l_ref, 0.0), (acc_ref, 0.0)):
         ref[at] = jnp.full(ref.at[at].shape, fill, ref.dtype)
+
+
+def _visited_slots(start, q_lens, tiles, tq, short):
+    """(first, last) slot whose rows cross each of a packed wave's
+    ``tiles`` row tiles (:func:`row_tile_visits`): the layout is
+    slot-major, so the slots a tile visits are a range (empty, first >
+    last, where nobody's rows lie in it)."""
+    live = row_tile_visits(start[:, None], q_lens[:, None],
+                           jnp.arange(tiles)[None, :], tq, short)[2]
+    slot = jnp.arange(len(start))[:, None]
+    first = jnp.min(jnp.where(live, slot, len(start)), axis=0)
+    last = jnp.max(jnp.where(live, slot, -1), axis=0)
+    return first.astype(jnp.int32), last.astype(jnp.int32)
+
+
+def _visit_in_sight(lens_ref, qlens_ref, start_ref, b, t, tq, bs, group,
+                     short, window):
+    """Row tile ``t``'s visit to slot ``b`` in the packed kernels, as
+    :func:`_tile_in_sight` is a grid step's in the dense ones: (filled,
+    q_len, the slot's first packed row, its first row inside the tile,
+    whether it is scored at the full tile, groups of ``group`` pages it
+    can see, last page in sight, ``grp(gi)`` the group the page loop's
+    step ``gi`` copies and scores).  The visit's rows are the rule's
+    (:func:`row_tile_visits`); it sees up to the slot's last row in the
+    tile (causality: what lies above it is neither copied nor scored)
+    and, under a ``window``, from the first group its EARLIEST row in
+    the tile admits."""
+    span = group * bs
+    filled, qlen, start = lens_ref[b], qlens_ref[b], start_ref[b]
+    lo, hi, live, full = row_tile_visits(start, qlen, t, tq, short)
+    end = filled - qlen + (t * tq + hi - start)
+    live &= end > 0
+    n_groups = jnp.where(live, (end + span - 1) // span, 0)
+    last = jnp.maximum(end - 1, 0) // bs
+    grp = lambda gi: gi                                    # noqa: E731
+    if window:
+        first_q = filled - qlen + jnp.maximum(t * tq + lo - start, 0)
+        g0 = jnp.maximum(first_q - window + 1, 0) // span
+        n_groups = jnp.maximum(n_groups - g0, 0)
+        grp = lambda gi: g0 + gi                           # noqa: E731
+    return filled, qlen, start, lo, full, n_groups, last, grp
 
 
 def _page_loop(n_groups, copies, score):
@@ -515,32 +580,13 @@ def _softmax_step(s, v, m_ref, l_ref, acc_ref, at=(slice(None),),
     l_ref[at] = jnp.broadcast_to(l_new, l_ref[at].shape)
 
 
-def _kv_rows_kernel(lens_ref, qlens_ref, bt_ref, layer_ref, q_ref, k_pool,
-                    v_pool, o_ref, k_buf, v_buf, sem, m_ref, l_ref, acc_ref,
-                    *, scale, bs, group, tq, heads, dh, cw, precision,
-                    qpk=1, window=0, short=0):
-    b, t, n_groups, last, full = _tile_in_sight(lens_ref, qlens_ref, tq, bs,
-                                                group, short)
-    # under a window the page loop starts at the first group in sight:
-    # ``g0`` groups are passed over, and ``at(gi)`` is the group the
-    # loop's step ``gi`` copies and scores
-    at = lambda gi: gi                                     # noqa: E731
-    if window:
-        g0 = _first_group(lens_ref, qlens_ref, b, t, tq, group * bs, window)
-        n_groups = jnp.maximum(n_groups - g0, 0)
-        at = lambda gi: g0 + gi                            # noqa: E731
-    layer = layer_ref[0]
-    # (chunk, K/V heads in it): the row's last chunk may hold fewer
-    # (GPT-2 XL: head 24 alone beside 64 pad lanes, which are never
-    # scored)
-    per = cw // dh
-    chunks = [(c, min(per, heads - c * per))
-              for c in range(q_ref.shape[2] // cw)]
-
+def _kv_copies(k_pool, v_pool, bt_ref, k_buf, v_buf, sem, layer, b, last,
+               group, bs, at=lambda gi: gi):
+    """``copies(gi, buf)`` of :func:`_page_loop` for slot ``b`` of the K/V
+    pool pair: group ``at(gi)``'s K and V page copies into buffer ``buf``
+    (``at``: past the groups a window passes over); pages past the
+    ``last`` in sight copy that one again (their positions are masked)."""
     def copies(gi, buf):
-        """Group ``gi``'s K and V page copies into buffer ``buf``; pages
-        past the last in sight copy that one again (their positions are
-        masked)."""
         out = []
         for g in range(group):
             page = bt_ref[b, jnp.minimum(at(gi) * group + g, last)]
@@ -550,101 +596,134 @@ def _kv_rows_kernel(lens_ref, qlens_ref, bt_ref, layer_ref, q_ref, k_pool,
             out.append(pltpu.make_async_copy(
                 v_pool.at[layer, page], v_buf.at[buf, dst], sem.at[1, buf]))
         return out
+    return copies
 
-    def own_lanes(shape, g):
-        """Lanes of a chunk that are head ``g``'s (of the chunk)."""
-        return jax.lax.broadcasted_iota(jnp.int32, shape, 1) // dh == g
 
-    def member(hq, r):
-        """Rows of the q (or o) tile that hold the first ``hq`` queries
-        of member ``r`` of every K/V head's ``qpk`` query heads (all of
-        the tile where ``qpk`` is 1 at the full height: a query head
-        then IS its K/V head)."""
-        if qpk == 1 and hq == tq:
-            return slice(None)
-        return slice(r * tq, r * tq + hq)
+def _lane_chunks(W, cw, dh, heads):
+    """[(lane chunk, K/V heads in it)] of a row ``W`` wide: the row's
+    last chunk may hold fewer (GPT-2 XL: head 24 alone beside 64 pad
+    lanes, which are never scored)."""
+    per = cw // dh
+    return [(c, min(per, heads - c * per)) for c in range(W // cw)]
 
-    def over_chunks(hq, part, *args):
-        """``part(hq, c, n, *args)`` for every lane chunk ``c`` of ``n``
-        K/V heads: one after another at the full height; at the short
-        one a LOOP over the chunks of ``per`` heads, so that a chunk
-        program lowers the short height's body once and not once a lane
-        chunk (a program is traced and lowered at every warm set-up:
-        1.43 s at GPT-2 XL's 13 chunks with both heights unrolled where
-        the one height took 0.71, sandbox, PR 43)."""
-        looped = sum(n == per for _, n in chunks) if hq < tq else 0
-        if looped > 1:
-            def body(c, carry):
-                part(hq, c, per, *args)
-                return carry
-            jax.lax.fori_loop(0, looped, body, 0)
-        else:
-            looped = 0
-        for c, n in chunks[looped:]:
-            part(hq, c, n, *args)
 
-    def lanes_of(c):
-        """Lane chunk ``c``'s lanes (``c`` the loop's index, or static)."""
-        if isinstance(c, int):
-            return slice(c * cw, (c + 1) * cw)
-        return pl.ds(pl.multiple_of(c * cw, cw), cw)
+def _own_lanes(shape, g, dh):
+    """Lanes of a chunk that are head ``g``'s (of the chunk)."""
+    return jax.lax.broadcasted_iota(jnp.int32, shape, 1) // dh == g
 
-    def reset(hq):
-        """Empty accumulators for ``hq`` queries; a short tile's rows
-        past them come back zero."""
-        _reset(m_ref, l_ref, acc_ref, (Ellipsis,) if hq == tq else
-               (slice(None), slice(0, per * qpk * hq)))
-        if hq < tq:
-            o_ref[...] = jnp.zeros_like(o_ref)
 
-    def score(hq, c, n, gi, buf, filled, qlen):
-        """Group ``gi`` scored by the tile's first ``hq`` queries of
-        lane chunk ``c``."""
-        lanes = lanes_of(c)
+def _lanes_of(c, cw):
+    """Lane chunk ``c``'s lanes (``c`` a loop's index, or static)."""
+    if isinstance(c, int):
+        return slice(c * cw, (c + 1) * cw)
+    return pl.ds(pl.multiple_of(c * cw, cw), cw)
+
+
+def _over_chunks(chunks, per, loop, part, *args):
+    """``part(c, n, *args)`` for every lane chunk ``c`` of ``n`` K/V
+    heads: one after another, or (``loop``: the short height) a LOOP
+    over the chunks of ``per`` heads, so that a chunk program lowers the
+    short height's body once and not once a lane chunk (a program is
+    traced and lowered at every warm set-up: 1.43 s at GPT-2 XL's 13
+    chunks with both heights unrolled where the one height took 0.71,
+    sandbox, PR 43)."""
+    looped = sum(n == per for _, n in chunks) if loop else 0
+    if looped > 1:
+        def body(c, carry):
+            part(c, per, *args)
+            return carry
+        jax.lax.fori_loop(0, looped, body, 0)
+    else:
+        looped = 0
+    for c, n in chunks[looped:]:
+        part(c, n, *args)
+
+
+def _kv_rows_kernel(lens_ref, qlens_ref, bt_ref, layer_ref, q_ref, k_pool,
+                    v_pool, o_ref, k_buf, v_buf, sem, m_ref, l_ref, acc_ref,
+                    *, scale, bs, group, tq, heads, dh, cw, precision,
+                    qpk=1, window=0):
+    b, t, n_groups, last, _ = _tile_in_sight(lens_ref, qlens_ref, tq, bs,
+                                             group)
+    # under a window the page loop starts at the first group in sight:
+    # ``g0`` groups are passed over, and ``at(gi)`` is the group the
+    # loop's step ``gi`` copies and scores
+    at = lambda gi: gi                                     # noqa: E731
+    if window:
+        g0 = _first_group(lens_ref, qlens_ref, b, t, tq, group * bs, window)
+        n_groups = jnp.maximum(n_groups - g0, 0)
+        at = lambda gi: g0 + gi                            # noqa: E731
+    layer = layer_ref[0]
+    chunks = _lane_chunks(q_ref.shape[2], cw, dh, heads)
+    copies = _kv_copies(k_pool, v_pool, bt_ref, k_buf, v_buf, sem, layer, b,
+                        last, group, bs, at)
+
+    def member(r):
+        """Rows of the q (or o) tile that hold member ``r`` of every K/V
+        head's ``qpk`` query heads (all of the tile where ``qpk`` is 1:
+        a query head then IS its K/V head)."""
+        return slice(None) if qpk == 1 else slice(r * tq, (r + 1) * tq)
+
+    def score(c, n, gi, buf, filled, qlen):
+        """Group ``gi`` scored by the tile's queries of lane chunk
+        ``c``."""
+        lanes = _lanes_of(c, cw)
         # the chunk's query heads stacked along the rows (K/V head g's
         # ``qpk`` members one after another), each seeing its own K/V
         # head's lanes of the query alone
-        qcs = [q_ref[0, member(hq, r), lanes] for r in range(qpk)]
+        qcs = [q_ref[0, member(r), lanes] for r in range(qpk)]
         q2 = jnp.concatenate(
-            [jnp.where(own_lanes(qc.shape, g), qc, 0)
+            [jnp.where(_own_lanes(qc.shape, g, dh), qc, 0)
              for g in range(n) for qc in qcs], axis=0)
         s = jax.lax.dot_general(
             q2, k_buf[buf, :, lanes], (((1,), (1,)), ((), ())),
             precision=precision,
             preferred_element_type=jnp.float32) * scale
         qi = t * tq + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 0) % hq
+            jnp.int32, s.shape, 0) % tq
         _softmax_step(
             _mask_scores(s, qi, at(gi), filled, qlen, window),
             v_buf[buf, :, lanes], m_ref, l_ref, acc_ref,
-            at=(c, slice(0, n * qpk * hq)), precision=precision)
+            at=(c, slice(0, n * qpk * tq)), precision=precision)
 
-    def finalize(hq, c, n):
-        rows = slice(0, n * qpk * hq)
+    def finalize(c, n):
+        rows = slice(0, n * qpk * tq)
         l = l_ref[c, rows, 0:1]
         o2 = acc_ref[c, rows] / jnp.where(l == 0.0, 1.0, l)
         for r in range(qpk):
-            oc = jnp.zeros((hq, cw), jnp.float32)
+            oc = jnp.zeros((tq, cw), jnp.float32)
             for g in range(n):
-                row = (g * qpk + r) * hq
-                oc = jnp.where(own_lanes(oc.shape, g), o2[row:row + hq], oc)
-            o_ref[0, member(hq, r), lanes_of(c)] = oc.astype(o_ref.dtype)
+                row = (g * qpk + r) * tq
+                oc = jnp.where(_own_lanes(oc.shape, g, dh),
+                               o2[row:row + tq], oc)
+            o_ref[0, member(r), _lanes_of(c, cw)] = oc.astype(o_ref.dtype)
 
-    # the page copies are one text for both heights; what is scored of a
-    # group follows the tile's height
-    heights = (tq, short, full)
-    _at_heights(heights, reset)
-    _page_loop(n_groups, copies, lambda gi, buf: _at_heights(
-        heights, over_chunks, score, gi, buf, lens_ref[b], qlens_ref[b]))
-    _at_heights(heights, over_chunks, finalize)
+    _reset(m_ref, l_ref, acc_ref)
+    _page_loop(n_groups, copies, lambda gi, buf: _over_chunks(
+        chunks, cw // dh, False, score, gi, buf, lens_ref[b], qlens_ref[b]))
+    _over_chunks(chunks, cw // dh, False, finalize)
+
+
+def _kv_rows_scratch(span, W, cw, rows, pool_k, pool_v):
+    """The rows kernels' scratch: two buffers each of a group's K and V
+    pages, their semaphores, and a lane chunk's (m, l, acc) for the
+    ``rows`` stacked rows of a q-tile."""
+    return [
+        pltpu.VMEM((2, span, W), pool_k.dtype),            # K pages
+        pltpu.VMEM((2, span, W), pool_v.dtype),            # V pages
+        pltpu.SemaphoreType.DMA((2, 2)),
+        pltpu.VMEM((W // cw, rows, _LANES), jnp.float32),  # max
+        pltpu.VMEM((W // cw, rows, _LANES), jnp.float32),  # denom
+        pltpu.VMEM((W // cw, rows, cw), jnp.float32),      # acc
+    ]
 
 
 @functools.partial(jax.jit,
                    static_argnames=("heads", "head_dim", "tq", "interpret",
-                                    "qpk", "window", "short"))
+                                    "qpk", "window"))
 def _paged_rows_call(lengths, q_lens, block_tables, layer, qr, pool_k,
                      pool_v, *, heads, head_dim, tq, interpret, qpk=1,
-                     window=0, short=0):
+                     window=0):
     """``_kv_rows_kernel`` over query rows ``qr`` [B, Q, W] (``Q`` whole
     sublane tiles) and the pool pair; with ``qpk`` query heads a K/V
     head, ``qr`` is [B, qpk * Q, W], tile by tile the ``qpk`` members'
@@ -656,8 +735,7 @@ def _paged_rows_call(lengths, q_lens, block_tables, layer, qr, pool_k,
     program against the blocked kernel's 3.4 (sandbox, PR 31), which the
     warm set-up of a cell pays for each of its 19 programs before the
     compile cache can be asked.  ``window`` > 0 is the banded kernel,
-    named ``ragged_paged_window`` in the trace; ``short`` > 0 is the
-    program's second height (:func:`rows_tiling`)."""
+    named ``ragged_paged_window`` in the trace."""
     B, Q, W = qr.shape
     bs = pool_k.shape[2]
     cw = _lane_chunk(W, head_dim)
@@ -670,20 +748,14 @@ def _paged_rows_call(lengths, q_lens, block_tables, layer, qr, pool_k,
         in_specs=[tile, pl.BlockSpec(memory_space=pl.ANY),
                   pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=tile,
-        scratch_shapes=[
-            pltpu.VMEM((2, group * bs, W), pool_k.dtype),   # K pages
-            pltpu.VMEM((2, group * bs, W), pool_v.dtype),   # V pages
-            pltpu.SemaphoreType.DMA((2, 2)),
-            pltpu.VMEM((W // cw, rows, _LANES), jnp.float32),  # max
-            pltpu.VMEM((W // cw, rows, _LANES), jnp.float32),  # denom
-            pltpu.VMEM((W // cw, rows, cw), jnp.float32),      # acc
-        ],
+        scratch_shapes=_kv_rows_scratch(group * bs, W, cw, rows, pool_k,
+                                        pool_v),
     )
     return pl.pallas_call(
         functools.partial(_kv_rows_kernel, scale=head_dim ** -0.5, bs=bs,
                           group=group, tq=tq, heads=heads, dh=head_dim,
                           cw=cw, precision=_prec(qr.dtype), qpk=qpk,
-                          window=window, short=short),
+                          window=window),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Q, W), qr.dtype),
         name="ragged_paged_window" if window else "ragged_paged_mixed",
@@ -711,6 +783,17 @@ def _ungrouped_rows(o, heads, head_dim, tq, groups):
     m = kv_heads(o.reshape(B, Q // tq, groups, tq, -1),
                  heads // groups, head_dim)
     return m.transpose(0, 1, 3, 4, 2, 5).reshape(B, Q, heads, head_dim)
+
+
+def _pool_row_width(pool, kv_heads_, head_dim):
+    """The float pool's row width, which must be the heads' own."""
+    W = pool.shape[3]
+    if W != kv_row_width(kv_heads_, head_dim):
+        raise ValueError(
+            f"ragged_paged_attention reads rows of "
+            f"{kv_row_width(kv_heads_, head_dim)} lanes for {kv_heads_} K/V "
+            f"heads of {head_dim}; the pool's are {W} wide")
+    return W
 
 
 def ragged_paged_attention(q, pool_k, pool_v, lengths, q_lens,
@@ -743,20 +826,19 @@ def ragged_paged_attention(q, pool_k, pool_v, lengths, q_lens,
     are fetched per slot but stored once.  Returns o [B, Q, H, Dh] in
     q's dtype (f32 accumulators).
 
-    A q-tile is scored at the HEIGHT its live rows need
-    (:func:`tile_heights`, asked of the prefetched ``q_len``): where a
-    q-block is cut into tiles taller than one sublane tile of queries
-    (:func:`rows_tiling`: 16 of bf16, 8 of f32), a live tile whose live
-    rows fit that many copies the same pages and scores, masks,
-    accumulates and finalizes those queries alone, and its rows past
-    them come back ZERO (no reader takes a dead row: ``_Rows.pack``
-    gathers live rows and every reader masks by ``q_len``); a slot with
-    ``q_len`` 0 is dead there whatever it has filled.  A taller tile is
-    scored whole, its dead rows clipped to the last live position.  Live
-    rows are the same to the bit at either height (the same products
-    row by row, the same order of page groups).  A q-block of one short
-    tile (a decode wave, a verify wave) has ONE height and the kernel
-    there was, operation for operation.
+    This is the DENSE entry, for a wave whose rows lie as ``[B, Q]``
+    (every decode and verify wave, a chunk wave too small to pack, and
+    every wave of the int8 pool); a packed chunk wave's rows over the
+    float pool go to :func:`ragged_paged_attention_rows` as they lie.
+    Both run under the same names in a trace.
+
+    Every live q-tile (:func:`rows_tiling`) is scored whole, its dead
+    rows clipped to the last live position; a dead tile copies and
+    scores nothing and returns zeros (no reader takes a dead row:
+    ``_Rows.pack`` gathers live rows and every reader masks by
+    ``q_len``).  ONE height: the second, a decoding slot's one row
+    beside a prompt chunk scored at one sublane tile of queries, is the
+    packed entry's (:func:`row_tile_visits`), where such waves go.
 
     ``window`` > 0 (static) scores a SLIDING WINDOW: a query at position
     ``p`` admits ``p - window < kv <= p``, itself and the ``window - 1``
@@ -790,15 +872,10 @@ def ragged_paged_attention(q, pool_k, pool_v, lengths, q_lens,
         return _ragged_paged_blocked(
             q, pool_k[layer], pool_v[layer], lengths, q_lens,
             block_tables, k_scale[layer], v_scale[layer], interpret)
-    bs, W = pool_k.shape[2:]
-    if W != kv_row_width(H // groups, Dh):
-        raise ValueError(
-            f"ragged_paged_attention reads rows of "
-            f"{kv_row_width(H // groups, Dh)} lanes for {H // groups} K/V "
-            f"heads of {Dh}; the pool's are {W} wide")
+    W = _pool_row_width(pool_k, H // groups, Dh)
     # whole sublane tiles of query rows (8 of f32, 16 of bf16): a decode
     # wave's single row rides in one, its dead rows scored by no one
-    Qp, tq, short = rows_tiling(Q, H, Dh, groups, q.dtype)
+    Qp, tq = rows_tiling(Q, H, q.dtype)
     if groups == 1:
         qr = kv_rows(q, W)
         if Qp > Q:
@@ -812,10 +889,198 @@ def ragged_paged_attention(q, pool_k, pool_v, lengths, q_lens,
         block_tables.astype(jnp.int32),
         jnp.asarray(layer, jnp.int32).reshape(1), qr, pool_k, pool_v,
         heads=H // groups, head_dim=Dh, interpret=interpret, tq=tq,
-        qpk=groups, window=int(window), short=short)
+        qpk=groups, window=int(window))
     if groups == 1:
         return kv_heads(o[:, :Q], H, Dh)
     return _ungrouped_rows(o, H, Dh, tq, groups)[:, :Q]
+
+
+# The PACKED entry (ISSUE 54): a chunk wave's query rows as they lie
+# (``gpt_decode._Rows``), the sibling of ``_mla_rows_kernel`` below.  The
+# grid is the packed rows' tiles alone; a tile visits the slots whose rows
+# cross it (``row_tile_visits``), each visit the slot's page pipeline
+# (``_page_loop`` over ``_kv_copies``), ``_mask_scores`` with every row
+# that is not the slot's at query index -2^30 and ``_softmax_step``, which
+# leaves a wholly masked row as it was: ONE tile-wide (m, l, acc) a lane
+# chunk serves every slot of the tile, reset once and finalized once.
+#
+# The heads stay on the lanes and a K/V head's ``qpk`` members a stack of
+# rows, but WINDOW by window: ``_grouped_rows(q[None], W, wq, qpk)`` with
+# ``wq`` the short window's queries (the whole tile where the program has
+# one height), so the rows of a tile are (window, member, query) and those
+# of a lane chunk's accumulators (window, head of the chunk, member,
+# query).  A visit at the short height is then ONE slice of q, of the
+# scores' rows and of (m, l, acc), at a traced start that is whole sublane
+# tiles; a visit at the full height takes all of the tile, and a row's
+# products are the dense entry's row by row whatever the order they are
+# stacked in.
+
+
+def _kv_rows_packed_kernel(lens_ref, qlens_ref, bt_ref, layer_ref, start_ref,
+                           first_ref, last_ref, q_ref, k_pool, v_pool, o_ref,
+                           k_buf, v_buf, sem, m_ref, l_ref, acc_ref, *,
+                           scale, bs, group, tq, heads, dh, cw, precision,
+                           qpk=1, window=0, short=0):
+    """One row tile of the PACKED wave: ``tq`` packed queries of every
+    member, whichever slots they belong to (the layout is slot-major, so
+    the slots ``first_ref[t] .. last_ref[t]`` that cross it are a
+    range).  A row nobody owns comes back zero."""
+    t = pl.program_id(0)
+    layer = layer_ref[0]
+    per = cw // dh
+    chunks = _lane_chunks(q_ref.shape[1], cw, dh, heads)
+    wq = short or tq            # queries a window
+    win = qpk * wq              # rows of q and o a window
+    _reset(m_ref, l_ref, acc_ref)
+
+    def visit(b, carry):
+        filled, qlen, start, lo, full, n_groups, last, grp = \
+            _visit_in_sight(lens_ref, qlens_ref, start_ref, b, t, tq, bs,
+                            group, short, window)
+        # the short window that holds the slot's rows
+        w = lo // short if short else 0
+
+        def score(c, n, hq, gi, buf):
+            """Group ``gi`` scored by lane chunk ``c`` of the whole tile
+            (``hq`` = ``tq``) or of window ``w``."""
+            lanes = _lanes_of(c, cw)
+            if hq == tq:
+                wins = [slice(i * win, (i + 1) * win)
+                        for i in range(tq // wq)]
+                first, at = t * tq, slice(0, n * qpk * tq)
+            else:
+                wins = [pl.ds(pl.multiple_of(w * win, win), win)]
+                first = t * tq + w * wq
+                at = pl.ds(pl.multiple_of(w * (n * win), n * win), n * win)
+            # a window's rows once a K/V head of the chunk, each seeing
+            # its own head's lanes of the query alone
+            q2 = jnp.concatenate(
+                [jnp.where(_own_lanes((win, cw), g, dh), q_ref[rows, lanes],
+                           0) for rows in wins for g in range(n)], axis=0)
+            s = jax.lax.dot_general(
+                q2, k_buf[buf, :, lanes], (((1,), (1,)), ((), ())),
+                precision=precision,
+                preferred_element_type=jnp.float32) * scale
+            # row r of what is scored is packed query ``first + r // (n *
+            # win) * wq + r % wq``: query ``qi`` of the slot's q-block,
+            # or another slot's row, which sees nothing
+            r = jax.lax.broadcasted_iota(jnp.int32, (s.shape[0], 1), 0)
+            qi = first - start + r // (n * win) * wq + r % wq
+            qi = jnp.where((qi >= 0) & (qi < qlen), qi, -(1 << 30))
+            _softmax_step(
+                _mask_scores(s, qi, grp(gi), filled, qlen, window),
+                v_buf[buf, :, lanes], m_ref, l_ref, acc_ref, at=(c, at),
+                precision=precision)
+
+        def over_chunks(hq, gi, buf):
+            _over_chunks(chunks, per, hq < tq, score, hq, gi, buf)
+
+        _page_loop(n_groups,
+                   _kv_copies(k_pool, v_pool, bt_ref, k_buf, v_buf, sem,
+                              layer, b, last, group, bs, grp),
+                   lambda gi, buf: _at_heights((tq, short, full),
+                                               over_chunks, gi, buf))
+        return carry
+
+    jax.lax.fori_loop(first_ref[t], last_ref[t] + 1, visit, 0)
+
+    def finalize(c, n):
+        rows = slice(0, n * qpk * tq)
+        l = l_ref[c, rows, 0:1]
+        o2 = acc_ref[c, rows] / jnp.where(l == 0.0, 1.0, l)
+        for i in range(tq // wq):
+            oc = jnp.zeros((win, cw), jnp.float32)
+            for g in range(n):
+                row = (i * n + g) * win
+                oc = jnp.where(_own_lanes(oc.shape, g, dh),
+                               o2[row:row + win], oc)
+            o_ref[i * win:(i + 1) * win, _lanes_of(c, cw)] = oc.astype(
+                o_ref.dtype)
+
+    _over_chunks(chunks, per, False, finalize)
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("heads", "head_dim", "tq", "interpret",
+                                    "qpk", "window", "short"))
+def _packed_rows_call(lengths, q_lens, block_tables, layer, start, qr,
+                      pool_k, pool_v, *, heads, head_dim, tq, interpret,
+                      qpk=1, window=0, short=0):
+    """``_kv_rows_packed_kernel`` over the packed query rows ``qr``
+    [qpk * R, W], window by window the ``qpk`` members' rows one after
+    another.  Jitted, with the layer a traced scalar, as
+    :func:`_paged_rows_call`, and under its names in a trace."""
+    GR, W = qr.shape
+    bs = pool_k.shape[2]
+    cw = _lane_chunk(W, head_dim)
+    group = _page_group(block_tables.shape[1], bs, W, pool_k.dtype)
+    rows = cw // head_dim * qpk * tq
+    tiles = GR // (qpk * tq)
+    first, last = _visited_slots(start, q_lens, tiles, tq, short)
+    tile = pl.BlockSpec((qpk * tq, W), lambda t, *_: (t, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=7,
+        grid=(tiles,),
+        in_specs=[tile, pl.BlockSpec(memory_space=pl.ANY),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=tile,
+        scratch_shapes=_kv_rows_scratch(group * bs, W, cw, rows, pool_k,
+                                        pool_v),
+    )
+    return pl.pallas_call(
+        functools.partial(_kv_rows_packed_kernel, scale=head_dim ** -0.5,
+                          bs=bs, group=group, tq=tq, heads=heads,
+                          dh=head_dim, cw=cw, precision=_prec(qr.dtype),
+                          qpk=qpk, window=window, short=short),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((GR, W), qr.dtype),
+        name="ragged_paged_window" if window else "ragged_paged_mixed",
+        interpret=interpret,
+    )(lengths, q_lens, block_tables, layer, start, first, last, qr, pool_k,
+      pool_v)
+
+
+def ragged_paged_attention_rows(q, pool_k, pool_v, lengths, q_lens, start,
+                                block_tables, *, layer=0, interpret=None,
+                                groups=1, window=0):
+    """:func:`ragged_paged_attention` over a PACKED wave's query rows as
+    they lie (``gpt_decode._Rows``: slot-major, slot ``b``'s
+    ``q_lens[b]`` live rows at ``start[b]``, dead rows at the tail), the
+    float pool's kernel under the same names in a trace.
+
+    q: [R, H, Dh]; the pool pair, lengths, q_lens, block_tables,
+    ``groups``, ``window`` and the arithmetic as there; ``layer`` may be
+    traced.  Its q-tiles are tiles of packed rows
+    (:func:`rows_packed_tiling`): the q and o tiles that move are the
+    packed rows', ``R / tq`` a call whatever the slots, and a tile visits
+    the slots whose rows cross it (:func:`row_tile_visits`: each at the
+    full tile, or at the one short window that holds its rows).  A live
+    row's result is the dense entry's (the same products row by row, the
+    same order of page groups).  Returns o [R, H * Dh] in q's dtype; a
+    row no slot owns, and a slot with lengths 0, return zeros."""
+    if interpret is None:
+        interpret = _use_interpret()
+    R, H, Dh = q.shape
+    if H % groups:
+        raise ValueError(f"{H} query heads are not {groups} a K/V head")
+    W = _pool_row_width(pool_k, H // groups, Dh)
+    Rp, tq, short = rows_packed_tiling(R, H, Dh, groups, q.dtype)
+    if Rp > R:
+        q = jnp.pad(q, ((0, Rp - R), (0, 0), (0, 0)))
+    wq = short or tq
+    # one query head a K/V head: the rows are the heads side by side
+    qr = kv_rows(q, W) if groups == 1 else \
+        _grouped_rows(q[None], W, wq, groups)[0]
+    i32 = lambda x: jnp.asarray(x, jnp.int32)              # noqa: E731
+    o = _packed_rows_call(
+        i32(lengths), i32(q_lens), i32(block_tables), i32(layer).reshape(1),
+        i32(start), qr, pool_k, pool_v, heads=H // groups, head_dim=Dh,
+        interpret=bool(interpret), tq=tq, qpk=groups, window=int(window),
+        short=short)
+    if groups == 1:
+        return o[:R, :H * Dh]
+    return _ungrouped_rows(o[None], H, Dh, wq, groups)[0, :R].reshape(
+        R, H * Dh)
 
 
 def ragged_masked_reference(q, k, v, lengths, q_lens=None, k_scale=None,
@@ -1117,23 +1382,9 @@ def _mla_rows_kernel(lens_ref, qlens_ref, bt_ref, layer_ref, start_ref,
     _reset(m_ref, l_ref, acc_ref)
 
     def visit(b, carry):
-        filled, qlen, start = lens_ref[b], qlens_ref[b], start_ref[b]
-        lo, hi, live, full = row_tile_visits(start, qlen, t, tq, short)
-        # one past the last position the slot's last row in this tile
-        # sees (causality: what lies above it is neither copied nor
-        # scored)
-        end = filled - qlen + (t * tq + hi - start)
-        live &= end > 0
-        n_groups = jnp.where(live, (end + span - 1) // span, 0)
-        last = jnp.maximum(end - 1, 0) // bs
-        grp = lambda gi: gi                                # noqa: E731
-        if window:
-            # the first group the slot's EARLIEST row in this tile
-            # admits: the groups before it are neither copied nor scored
-            first_q = filled - qlen + jnp.maximum(t * tq + lo - start, 0)
-            g0 = jnp.maximum(first_q - window + 1, 0) // span
-            n_groups = jnp.maximum(n_groups - g0, 0)
-            grp = lambda gi: g0 + gi                       # noqa: E731
+        filled, qlen, start, lo, full, n_groups, last, grp = \
+            _visit_in_sight(lens_ref, qlens_ref, start_ref, b, t, tq, bs,
+                            group, short, window)
         # the window's first query, counted from the tile's first
         w = lo // short * short if short else 0
 
@@ -1217,12 +1468,7 @@ def _mla_rows_call(lengths, q_lens, block_tables, layer, start, qr, pool, *,
     bs = pool.shape[2]
     group = min(_PAGE_GROUP, block_tables.shape[1])
     rows = tq * H
-    # the slots whose rows cross each tile: slot-major, so a range
-    live = row_tile_visits(start[:, None], q_lens[:, None],
-                           jnp.arange(R // tq)[None, :], tq, short)[2]
-    slot = jnp.arange(len(start))[:, None]
-    first = jnp.min(jnp.where(live, slot, len(start)), axis=0)
-    last = jnp.max(jnp.where(live, slot, -1), axis=0)
+    first, last = _visited_slots(start, q_lens, R // tq, tq, short)
     selected = allowed is not None
     span = group * bs
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -1256,8 +1502,8 @@ def _mla_rows_call(lengths, q_lens, block_tables, layer, start, qr, pool, *,
         name="ragged_paged_mla_sparse" if selected
         else "ragged_paged_mla_window" if window else "ragged_paged_mla",
         interpret=interpret,
-    )(lengths, q_lens, block_tables, layer, start, first.astype(jnp.int32),
-      last.astype(jnp.int32), qr.reshape(R * H, W), pool, *more)
+    )(lengths, q_lens, block_tables, layer, start, first, last,
+      qr.reshape(R * H, W), pool, *more)
     return o.reshape(R, H, value_width)
 
 

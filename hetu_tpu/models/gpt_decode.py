@@ -1819,12 +1819,17 @@ def _mixed_step(params, cfg_tuple, cache_k, cache_v, pos, tokens,
     embedding, norms, projections, per-head norm and RoPE, the latent
     projections and absorbs, the conv operator, every FFN and the
     router (``T x k`` assignments of R rows, not of B x Q).  Only what
-    needs a slot's rows as a block unpacks to ``[B, Q]``: the page
-    write, the attention of every kind (kernels and masked paths see
-    what they saw) and the fresh-self softmax; the attention's result
-    is packed back, and ``_window_logits`` gathers the windows straight
-    from the packed rows.  Where R is ``B x Q`` packing is the identity
-    and is skipped.
+    needs a slot's rows as a block unpacks to ``[B, Q]``: k and v for
+    the page write (``_kv_write_pages``), and, off the float pool's
+    kernels, q for the masked path's scoring and fresh-self softmax and
+    for the int8 pool's kernel (``_ragged_paged_blocked``), whose result
+    is packed back.  The float pool's kernels take the packed query rows
+    AS THEY LIE and hand their result back so (the K/V rows kernel's
+    ``ragged_paged_attention_rows``, ISSUE 54; the latent kernel's
+    ``ragged_paged_mla_rows``, ISSUE 46): no q-block of the query or of
+    the result is there.  ``_window_logits`` gathers the windows
+    straight from the packed rows.  Where R is ``B x Q`` packing is the
+    identity and is skipped.
 
     The masked path's DEFAULT attention is ``_verify_step``'s full
     causal mask over the just-written cache, bit for bit — so decode
@@ -1955,7 +1960,8 @@ def _mixed_wave(params, cfg_tuple, cache_k, cache_v, pos, tokens, q_len,
             wpe = params[f"{name}_wpe"]
             h = h + wpe[jnp.clip(posns_r, 0, wpe.shape[0] - 1)]
     if attn == "ragged":
-        from ..kernels.ragged_attention import ragged_paged_attention
+        from ..kernels.ragged_attention import (
+            ragged_paged_attention, ragged_paged_attention_rows)
     # a spec none of whose layers holds a page has no pool, and its wave
     # none of what follows up to the layers
     pooled = blk.op_layers(L, "pool") + blk.op_layers(L, "window") > 0
@@ -2072,11 +2078,17 @@ def _mixed_wave(params, cfg_tuple, cache_k, cache_v, pos, tokens, q_len,
         with jax.named_scope("attn_qkv"):
             q, k, v = _qkv_heads(params, us, blk, i, x, H, Hkv, Dh, posns_r)
         k_r, v_r = k, v
+        # the float pool's kernel takes a packed wave's query rows as
+        # they lie and hands its result back so
+        # (``ragged_paged_attention_rows``)
+        as_rows = attn == "ragged" and not quant and rows is not None
         if rows is not None:
-            # the page write, the scoring and the fresh-self softmax
-            # take a slot's rows as a block
-            with jax.named_scope("attention"):
-                q = rows.unpack(q)
+            # the page write takes a slot's rows as a block, and so do
+            # the masked path's scoring and fresh-self softmax and the
+            # int8 pool's kernel
+            if not as_rows:
+                with jax.named_scope("attention"):
+                    q = rows.unpack(q)
             with jax.named_scope("kv_write"):
                 k, v = rows.unpack(k), rows.unpack(v)
         with jax.named_scope("kv_write"):
@@ -2088,7 +2100,12 @@ def _mixed_wave(params, cfg_tuple, cache_k, cache_v, pos, tokens, q_len,
                 ck = _kv_scatter(ck, (pi, wb, woff_r), k_r)
                 cv = _kv_scatter(cv, (pi, wb, woff_r), v_r)
         with jax.named_scope("attention"):
-            if attn == "ragged":
+            if as_rows:
+                o = ragged_paged_attention_rows(
+                    q[0], ck, cv, lens, q_len, rows.start, tables, layer=pi,
+                    groups=group,
+                    window=blk.window if windowed else 0)[None]
+            elif attn == "ragged":
                 # the pool pair whole, the layer an index in the page
                 # copy: no ``cache_k[i]`` is materialised (an int8 pair
                 # hands its scale planes over beside its payload)
@@ -2137,7 +2154,7 @@ def _mixed_wave(params, cfg_tuple, cache_k, cache_v, pos, tokens, q_len,
                     o = jnp.where(self_fresh[:, None, None, None],
                                   o_fresh, o)
                 o = o.reshape(B, Q, hdim)
-            if rows is not None:
+            if rows is not None and not as_rows:
                 o = rows.pack(o)
         if windowed:
             win_k, win_v = ck, cv

@@ -49,7 +49,8 @@ from ..models.gpt_decode import (
     spec_propose_fn, wave_rows,
 )
 from ..kernels.ragged_attention import (
-    mla_rows_tiling, mla_tiling, row_tile_visits, rows_tiling, tile_heights)
+    mla_rows_tiling, mla_tiling, row_tile_visits, rows_packed_tiling,
+    rows_tiling, tile_heights)
 from ..models.moe_decode import takes_kernel
 from ..models.retention_decode import takes_kernel as retention_takes_kernel
 from ..models.ssm_decode import takes_kernel as ssm_takes_kernel
@@ -617,19 +618,24 @@ class ServingEngine:
         q-tiles moved) of one call of the hand-paged attention kernel in
         a wave of q-blocks ``Q`` wide computed over ``rows`` rows: the
         kernel's own rule asked of the wave's ``q_len`` and the tile the
-        program for ``Q`` has.  A packed wave of the latent kernel
-        (``rows`` under slots x ``Q``) moves the packed rows' tiles and
-        counts a tile's VISITS to the slots whose rows cross it.  None
-        where the engine's waves run no such kernel (the masked path,
-        the int8 pool)."""
+        program for ``Q`` has.  A packed wave (``rows`` under slots x
+        ``Q``), of the latent kernel or of the K/V rows kernel, moves the
+        packed rows' tiles and counts a tile's VISITS to the slots whose
+        rows cross it.  None where the engine's waves run no such kernel
+        (the masked path, the int8 pool)."""
         if not self.fast_path or self.kv_quant or (
                 self.block_spec.latent and self.block_spec.ops):
             # (latent operators by layer: two head counts, two kernels
             # and no page loop in the layers that choose their rows)
             return None
         H, Dh = self.cfg_tuple[2:4]
-        if self.block_spec.latent and rows < len(q_len) * Q:
-            tq, short = mla_rows_tiling(rows, H, self._cdtype)
+        groups = H // (self.block_spec.kv_heads or H)
+        if rows < len(q_len) * Q:
+            if self.block_spec.latent:
+                tq, short = mla_rows_tiling(rows, H, self._cdtype)
+            else:
+                rows, tq, short = rows_packed_tiling(
+                    rows, H, Dh, groups, self._cdtype)
             start = np.cumsum(q_len) - q_len
             _, _, live, full = row_tile_visits(
                 start[:, None], q_len[:, None],
@@ -638,8 +644,8 @@ class ServingEngine:
         if self.block_spec.latent:
             tq, short = mla_tiling(Q, H)
         else:
-            _, tq, short = rows_tiling(
-                Q, H, Dh, H // (self.block_spec.kv_heads or H), self._cdtype)
+            # the K/V rows kernel's dense program has one height
+            (_, tq), short = rows_tiling(Q, H, self._cdtype), 0
         live, full = tile_heights(q_len[:, None],
                                   np.arange(-(-Q // tq))[None, :], tq, short)
         return int(live.sum()), int((live & ~full).sum()), live.size

@@ -290,9 +290,9 @@ class ServingMetrics(MetricsCore):
         those of them it scored at the short height, the q-tiles (and o
         tiles) the call moved, live or dead:
         ``ragged_attention.tile_heights`` of the wave's ``q_len``; of a
-        packed wave of the latent kernel a row tile's visits to the
-        slots whose rows cross it, ``row_tile_visits``, and the packed
-        rows' tiles): ``attn_tiles_live``, ``attn_tiles_short``,
+        packed wave a row tile's visits to the slots whose rows cross
+        it, ``row_tile_visits``, and the packed rows' tiles):
+        ``attn_tiles_live``, ``attn_tiles_short``,
         ``attn_q_tiles_moved`` and the counters
         ``serve.attn.tiles_live``, ``serve.attn.tiles_short``,
         ``serve.attn.q_tiles_moved``."""

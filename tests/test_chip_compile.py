@@ -472,13 +472,56 @@ def _cell_config(name):
 
 def _chunk_wave_case(sds, cell, own_vocab=False):
     """(params, cfg_tuple, pool_k, pool_v, state, slots, table, kernel
-    name, kernel calls) of a few layers of a serving cell at its
-    published widths, slots, table and pool, and a vocabulary of 512
-    (``own_vocab``: the LFM2 cell's own 65,536)."""
+    name, kernel calls[, what else the wave is handed]) of a few layers
+    of a serving cell at its published widths, slots, table and pool,
+    and a vocabulary of 512 (``own_vocab``: the LFM2 cell's own
+    65,536)."""
     from hetu_tpu.models.moe_decode import HybridMoEConfig, LatentMoEConfig
+    if cell == "mellum2-12b-a2.5b":
+        # a window layer and a full one: two pools, two kernels
+        conf = _cell_config("mellum2-12b-a2.5b.json")
+        L, B, T = 2, 32, 12800 // BLOCK
+        cfg = HybridMoEConfig.from_hf(dict(
+            conf, num_hidden_layers=L, vocab_size=512,
+            layer_types=["sliding_attention", "full_attention"],
+            mlp_layer_types=["sparse"] * L))
+        params = {k: sds(v, jnp.float32 if "_moe_router_" in k
+                         else jnp.bfloat16)
+                  for k, v in cfg.param_shapes("mel").items()}
+        pool = sds((1, 16385, BLOCK, 512), jnp.bfloat16)
+        ring = sds((1, B * 81 + 1, BLOCK, 512), jnp.bfloat16)
+        return (params, ("mel", L, 32, 128, T * BLOCK, cfg.block_spec()),
+                pool, pool, None, B, T, "ragged_paged_", 2,
+                dict(win=(ring, ring), ring=sds((B, 81), jnp.int32)))
+    if cell == "falcon-h1-34b":
+        from hetu_tpu.models.ssm_decode import SSMHybridConfig
+        L, B, T = 1, 64, 2048 // BLOCK
+        cfg = SSMHybridConfig.from_hf(dict(
+            _cell_config("falcon-h1-34b.json"), num_hidden_layers=L,
+            vocab_size=512))
+        blk = cfg.block_spec()
+        params = {k: sds(v, jnp.bfloat16)
+                  for k, v in cfg.param_shapes("fh1").items()}
+        pool = sds((L, 8193, BLOCK, 512), jnp.bfloat16)
+        state = tuple(
+            sds((sh[0], B) + tuple(sh[1:]),
+                jnp.bfloat16 if dt is None else dt)
+            for sh, dt in blk.state_shapes(L, cfg.hidden_size))
+        return (params, ("fh1", L, 20, 128, T * BLOCK, blk), pool, pool,
+                state, B, T, "ragged_paged_mixed", L)
     if cell == "gpt2-xl":
+        # two layers over the cell's WHOLE pool (48 layers' pages, 1.1 GB
+        # a side, the layer an index in the page copy).  Two layers'
+        # pages alone are 45.6 MiB a side: they fit the chip's 128 MiB
+        # of VMEM, the compiler stages them there and evicts the page
+        # write's rows to HBM to make room, and ``temp_size_in_bytes``
+        # (HBM alone) then reads that choice, not the program (sandbox
+        # AOT, PR 54: packed 24.6 MB on its parent, 66.8 MB since, 58.4
+        # padded with two layers' pages; 6.2, 5.9 and 58.2 with the
+        # cell's: PERF.md section 7 (ay) has the buffers)
         L, H = 2, 25
-        pool = sds((L, CELL["blocks"], BLOCK, kv_row_width(H, DH)),
+        pool = sds((CELL["layers"], CELL["blocks"], BLOCK,
+                    kv_row_width(H, DH)),
                    jnp.bfloat16)
         return (_gpt_shapes(sds, "gpt", L, H * DH, 512, 1024),
                 ("gpt", L, H, DH, 1024), pool, pool, None, CELL["slots"],
@@ -516,25 +559,32 @@ LATENT_BLOCKS = ("[32,256,20,640]", "[32,5120,640]", "[32,5120,512]")
 @pytest.mark.parametrize("against_padded", [
     False, pytest.param(True, marks=pytest.mark.slow)],
     ids=["alone", "against-padded"])
-@pytest.mark.parametrize("cell", ["gpt2-xl", "glm-4.7-flash", "lfm2-8b-a1b"])
+@pytest.mark.parametrize("cell", [
+    "gpt2-xl", "glm-4.7-flash", "lfm2-8b-a1b",
+    pytest.param("mellum2-12b-a2.5b", marks=pytest.mark.slow),
+    pytest.param("falcon-h1-34b", marks=pytest.mark.slow)])
 def test_packed_chunk_wave_at_the_cells_sizes(sds, monkeypatch, cell,
                                               against_padded):
-    """The chunk program of each serving cell (a 256-row bucket on 16 or
-    32 slots) runs its row-wise operators over 1,024 packed rows: the
-    kernels are the ones the padded program calls and see the padded
-    q-block (the latent cell's, ISSUE 46, the packed rows: its program
-    holds no 8,192-row block of the query or of the result), the pool
-    (and the conv state) are still updated in place, and the compiler's
-    peak is no higher than the padded program's.  What is read off the
-    padded program costs a second compile of the same wave and is
-    ``slow`` (``against-padded``: there the LFM2 cell's two programs
-    have its own vocabulary of 65,536)."""
+    """The chunk program of each serving cell (a 256-row bucket on 16,
+    32 or 64 slots) runs its row-wise operators over 1,024 packed rows:
+    the kernels are the ones the padded program calls, handed the PACKED
+    query rows as they lie (the latent kernel since ISSUE 46, the K/V
+    rows kernel since ISSUE 54: the program holds no block of the query
+    or of the result that is slots x 256 rows; k and v still unpack for
+    the page write), the pool (and the conv state) are still updated in
+    place, and the compiler's peak is no higher than the padded
+    program's.  What is read off the padded program costs a second
+    compile of the same wave and is ``slow`` (``against-padded``: there
+    the LFM2 cell's two programs have its own vocabulary of 65,536), as
+    are the code and short-chat cells' waves."""
     from hetu_tpu.kernels import grouped_matmul as gm
     from hetu_tpu.kernels import ragged_attention as ra
-    monkeypatch.setattr(ra, "_use_interpret", lambda: False)
-    monkeypatch.setattr(gm, "_use_interpret", lambda: False)
-    params, cfg_tuple, pk, pv, state, B, T, kernel, n_calls = \
+    from hetu_tpu.kernels import ssm_step as ss
+    for module in (ra, gm, ss):
+        monkeypatch.setattr(module, "_use_interpret", lambda: False)
+    params, cfg_tuple, pk, pv, state, B, T, kernel, n_calls, *more = \
         _chunk_wave_case(sds, cell, own_vocab=against_padded)
+    more = more[0] if more else {}
     Q = 256
     assert gd.wave_rows(cfg_tuple, B, 1, Q) == 1024 < B * Q
     i32 = lambda *shape: sds(shape, jnp.int32)  # noqa: E731
@@ -546,12 +596,13 @@ def test_packed_chunk_wave_at_the_cells_sizes(sds, monkeypatch, cell,
             return gd._serve_mixed_paged(*args, **kw)
         fn = jax.jit(wave, static_argnums=(1,),
                      static_argnames=("attn", "has_fresh", "window"),
-                     donate_argnums=(2, 3), donate_argnames=("state",))
+                     donate_argnums=(2, 3),
+                     donate_argnames=("state", "win"))
         return fn.lower(
             params, cfg_tuple, pk, pv, i32(B, T), i32(B), i32(B, Q), i32(B),
             i32(B), sds((B,), jnp.bool_), sds((B,), jnp.float32), i32(B),
             sds((B, 2), jnp.uint32), attn="ragged", window=1,
-            has_fresh=True, state=state).compile()
+            has_fresh=True, state=state, **more).compile()
 
     packed = compile_wave()
     text = packed.as_text()
@@ -561,14 +612,29 @@ def test_packed_chunk_wave_at_the_cells_sizes(sds, monkeypatch, cell,
     assert all("tpu_custom_call" in c for c in calls)
     shape_of = lambda line: line.split(  # noqa: E731
         " custom-call(")[0].split("= ")[-1].split("{")[0]
+    H = cfg_tuple[2]
     if cell == "glm-4.7-flash":
         # the latent kernel is handed the packed rows, as they lie
-        H, dc = cfg_tuple[2], cfg_tuple[5].latent.kv_lora_rank
+        dc = cfg_tuple[5].latent.kv_lora_rank
         assert {shape_of(c) for c in calls} == {f"bf16[{1024 * H},{dc}]"}
         assert not any(block in text for block in LATENT_BLOCKS)
+    else:
+        # and so is the K/V rows kernel: the members' rows of the 1,024
+        # packed queries, one pool row wide
+        G = H // (cfg_tuple[5].kv_heads or H) if len(cfg_tuple) > 5 else 1
+        assert {shape_of(c) for c in calls} == {
+            f"bf16[{1024 * G},{pk.shape[3]}]"}
+        # nothing under the attention's scope is a q-block (the page
+        # write's k and v unpack still, under ``kv_write``)
+        scored = [line for line in text.splitlines()
+                  if "/attention/" in line]
+        assert scored and not any(f"[{B},{Q}," in line or
+                                  f"[{B},{Q * G}," in line
+                                  for line in scored)
     # 4,096 assignment rows over 64 or 32 experts: the routed products
     # are the chunk wave's own kernel (ISSUE 41), not the compiler's
-    assert ("moe_grouped_matmul" in text) == (cell != "gpt2-xl")
+    assert ("moe_grouped_matmul" in text) == (
+        cell not in ("gpt2-xl", "falcon-h1-34b"))
     assert "ragged-dot" not in text
     # the weight products run over the packed rows, not over B x Q
     products = [line for line in text.splitlines()
@@ -577,8 +643,11 @@ def test_packed_chunk_wave_at_the_cells_sizes(sds, monkeypatch, cell,
                                 in line.split(" = ")[0]
                                 for line in products)
     pools = [a for a in (pk, pv) if a is not None]
-    donated = sum(int(np.prod(a.shape)) * 2 for a in pools + (
-        [state] if state is not None else []))
+    pools += list(more.get("win", ()))
+    held = [] if state is None else \
+        list(state) if isinstance(state, tuple) else [state]
+    donated = sum(int(np.prod(a.shape)) * a.dtype.itemsize
+                  for a in pools + held)
     mem = packed.memory_analysis()
     assert mem.alias_size_in_bytes >= donated
     if not against_padded:
@@ -587,9 +656,7 @@ def test_packed_chunk_wave_at_the_cells_sizes(sds, monkeypatch, cell,
         m.setattr(gd, "wave_rows",
                   lambda cfg, slots, window, q, *a, **k: slots * q)
         padded = compile_wave()
-    # the rows kernel is handed the padded q-block, as in the padded
-    # program (the wrapper lays it out for the kernel: the same call
-    # line)
+    # the padded program's kernels take the dense entries: q-blocks
     padded_calls = [line for line in padded.as_text().splitlines()
                     if "custom-call(" in line and kernel in line]
     if cell == "glm-4.7-flash":
@@ -597,8 +664,8 @@ def test_packed_chunk_wave_at_the_cells_sizes(sds, monkeypatch, cell,
             f"bf16[{B},{Q * H},{dc}]"}
         assert all(block in padded.as_text() for block in LATENT_BLOCKS[1:])
     else:
-        assert [shape_of(c) for c in calls] == [shape_of(c)
-                                                for c in padded_calls]
+        assert {shape_of(c) for c in padded_calls} == {
+            f"bf16[{B},{Q * G},{pk.shape[3]}]"}
     # no higher than the padded program's
     assert mem.temp_size_in_bytes <= padded.memory_analysis(
         ).temp_size_in_bytes
@@ -639,38 +706,55 @@ def matmul_rows(lowered):
 
 # name: (slots, query heads, K/V heads (None: the latent kernel), head,
 # table, pool blocks, window, rows of a product at the full and at the
-# short height).  The three cells' chunk shapes, and GPT-2 XL's, whose
-# short product would have 32 rows: ONE height (128 rows, and 64 in the
-# last lane chunk's one head), ``_SHORT_MIN_ROWS`` says why.
+# short height).  The K/V rows kernel's DENSE programs have one height
+# (64 queries of every member: 512 rows; GPT-2 XL's 128, and 64 in the
+# last lane chunk's one head); its PACKED programs, and the latent
+# kernel's, two, but GPT-2 XL's, whose short product would have 32 rows
+# (``_SHORT_MIN_ROWS`` says why).
 CHUNK_KERNELS = {
-    "lfm2-groups4-head64": (32, 32, 8, 64, 1024, 25601, 0, {512, 128}),
+    "lfm2-groups4-head64": (32, 32, 8, 64, 1024, 25601, 0, {512}),
     "mellum2-groups8-head128-window1024": (
-        32, 32, 4, 128, 1024, 32 * 81 + 1, 1024, {512, 128}),
-    "mellum2-groups8-head128-full": (32, 32, 4, 128, 1024, 16385, 0,
-                                     {512, 128}),
+        32, 32, 4, 128, 1024, 32 * 81 + 1, 1024, {512}),
+    "mellum2-groups8-head128-full": (32, 32, 4, 128, 1024, 16385, 0, {512}),
     "glm-latent-20x640": (32, 20, None, 640, 512, 10241, 0, {1280, 160}),
     # the same widths over the cell's 1,024 PACKED rows (ISSUE 46)
     "glm-latent-20x640-packed": (32, 20, None, 640, 512, 10241, 0,
                                  {1280, 160}),
     "gpt2-xl-25x64": (16, 25, 25, 64, 64, 449, 0, {128, 64}),
+    # the K/V rows kernel over 1,024 PACKED rows (ISSUE 54): the dense
+    # entry's tile and short window, so its products' rows
+    "lfm2-groups4-head64-packed": (32, 32, 8, 64, 1024, 25601, 0,
+                                   {512, 128}),
+    "mellum2-groups8-head128-window1024-packed": (
+        32, 32, 4, 128, 1024, 32 * 81 + 1, 1024, {512, 128}),
+    "mellum2-groups8-head128-full-packed": (32, 32, 4, 128, 1024, 16385, 0,
+                                            {512, 128}),
+    "falcon-groups5-head128-packed": (64, 20, 4, 128, 128, 8193, 0,
+                                      {320, 80}),
+    "gpt2-xl-25x64-packed": (16, 25, 25, 64, 64, 449, 0, {128, 64}),
 }
 
 
 @pytest.mark.parametrize("name", list(CHUNK_KERNELS))
 def test_a_chunk_programs_kernel_compiles_with_its_heights(sds, monkeypatch,
                                                            name):
-    """Q 256 at the cells' widths: a q-tile is 64 queries, the short
-    height 16 (the latent kernel's 8), and the kernel's products are
-    there at both; the Q 1 program of the same widths has one height,
-    one sublane tile of queries, and no product of the tall one's rows.
+    """Q 256 at the cells' widths: a q-tile is 64 queries, and where a
+    program has a short height (16 in the K/V rows kernel's packed
+    programs, 8 in the latent kernel's) the kernel's products are there
+    at both; the Q 1 program of the same widths has one height, one
+    sublane tile of queries, and no product of the tall one's rows.
     Compiled for the described v5e: two bodies' worth of scratch and
     code fit the scoped VMEM."""
     from hetu_tpu.kernels import ragged_attention as ra
     B, H, Hkv, Dh, T, blocks, window, rows = CHUNK_KERNELS[name]
     lens = sds((B,), jnp.int32)
-    if name.endswith("-packed"):
+    if name.endswith("-packed") and Hkv is None:
         monkeypatch.setattr(ra, "_use_interpret", lambda: False)
         _packed_latent_kernel_compiles(sds, B, H, Dh, T, blocks, rows)
+        return
+    if name.endswith("-packed"):
+        _packed_rows_kernel_compiles(sds, B, H, Hkv, Dh, T, blocks, window,
+                                     rows)
         return
     if Hkv is None:
         assert ra.mla_tiling(256, H) == (64, 8)
@@ -682,9 +766,8 @@ def test_a_chunk_programs_kernel_compiles_with_its_heights(sds, monkeypatch,
                                     value_width=512, scale=1 / 16, layer=3,
                                     interpret=False)
     else:
-        assert ra.rows_tiling(256, H, Dh, H // Hkv, jnp.bfloat16) == (
-            256, 64, 16 if H > Hkv else 0)
-        assert ra.rows_tiling(1, H, Dh, H // Hkv, jnp.bfloat16) == (16, 16, 0)
+        assert ra.rows_tiling(256, H, jnp.bfloat16) == (256, 64)
+        assert ra.rows_tiling(1, H, jnp.bfloat16) == (16, 16)
         pool = (sds((3, blocks, BLOCK, kv_row_width(Hkv, Dh)),
                     jnp.bfloat16),) * 2
 
@@ -695,11 +778,43 @@ def test_a_chunk_programs_kernel_compiles_with_its_heights(sds, monkeypatch,
     lowered = jax.jit(fn).lower(sds((B, 256, H, Dh), jnp.bfloat16), *pool,
                                 lens, lens, sds((B, T), jnp.int32))
     assert rows <= matmul_rows(lowered) and (
-        H > (Hkv or 0) or rows == matmul_rows(lowered))
+        Hkv is None or rows == matmul_rows(lowered))
     assert "tpu_custom_call" in lowered.compile().as_text()
     q1 = jax.jit(fn).lower(sds((B, 1, H, Dh), jnp.bfloat16), *pool,
                            lens, lens, sds((B, T), jnp.int32))
     assert matmul_rows(q1) and max(rows) not in matmul_rows(q1)
+
+
+def _packed_rows_kernel_compiles(sds, B, H, Hkv, Dh, T, blocks, window,
+                                 rows):
+    """The K/V rows kernel's packed entry at a cell's widths (1,024
+    packed rows, 3 layers' pool): row tiles of 64 packed queries and
+    (but at GPT-2 XL's one query head a K/V head) a short window of 16,
+    the dense entry's products row for row, compiled for the described
+    v5e inside the scoped VMEM under the dense entry's name; its call is
+    handed ``[members x 1,024, W]`` rows and no q-block."""
+    from hetu_tpu.kernels import ragged_attention as ra
+    R, bf, G = 1024, jnp.bfloat16, H // Hkv
+    W = kv_row_width(Hkv, Dh)
+    assert ra.rows_packed_tiling(R, H, Dh, G, bf) == (
+        R, 64, 16 if G > 1 else 0)
+    lens, bt = sds((B,), jnp.int32), sds((B, T), jnp.int32)
+    pool = sds((3, blocks, BLOCK, W), bf)
+
+    def call(q, pk, pv, lengths, q_lens, start, bt):
+        return ra.ragged_paged_attention_rows(
+            q, pk, pv, lengths, q_lens, start, bt, layer=2, interpret=False,
+            groups=G, window=window)
+    lowered = jax.jit(call).lower(sds((R, H, Dh), bf), pool, pool, lens,
+                                  lens, lens, bt)
+    assert matmul_rows(lowered) == rows
+    text = lowered.compile().as_text()
+    name = "ragged_paged_window" if window else "ragged_paged_mixed"
+    calls = [line for line in text.splitlines()
+             if "custom-call(" in line and name in line]
+    assert len(calls) == 1 and "tpu_custom_call" in calls[0]
+    assert f"bf16[{R * G},{W}]" in calls[0].split(" custom-call(")[0]
+    assert f"[{B},256," not in text
 
 
 def _packed_latent_kernel_compiles(sds, B, H, W, T, blocks, rows):
@@ -751,12 +866,12 @@ def _packed_latent_kernel_compiles(sds, B, H, W, T, blocks, rows):
 
 
 def test_gpt2_xl_chunk_program_keeps_one_height():
-    """GPT-2 XL's chunk programs have the one height: with two, the
-    short products' 32 rows cost the cell 4 % of its rate (PERF.md,
+    """GPT-2 XL's packed chunk programs have the one height: with two,
+    the short products' 32 rows cost the cell 4 % of its rate (PERF.md,
     PR 43)."""
     from hetu_tpu.kernels import ragged_attention as ra
-    for q in (32, 64, 128, 256):
-        assert ra.rows_tiling(q, 25, DH, 1, jnp.bfloat16)[2] == 0
+    for rows in (256, 512, 1024):
+        assert ra.rows_packed_tiling(rows, 25, DH, 1, jnp.bfloat16)[2] == 0
 
 
 # ------------------------------------------------------------------- #

@@ -364,20 +364,25 @@ def tiles_of(waves, tile, short_queries):
     return live, short
 
 
-def visits_of(waves, tq, short_queries):
-    """(visits, those at the short window, q-tiles moved) of the latent
-    kernel over the waves, row by row from the packed kernel's contract:
+def visits_of(waves, tq, short_queries, tile=None):
+    """(visits, those at the short window, q-tiles moved) of a hand-paged
+    kernel over the waves, row by row from the packed entries' contract:
     a PACKED wave (computed over fewer rows than slots x Q) is cut into
     row tiles of ``tq`` packed queries, a tile visits every slot that
     has a row in it, and a visit all of whose rows lie in one aligned
     window of ``short_queries`` is short; any other wave is the dense
-    entry's, one tile a slot (``tiles_of``)."""
+    entry's (``tiles_of``): the K/V rows kernel's ``tile(Q)`` queries a
+    q-tile at ONE height, or (no ``tile``) the latent kernel's one tile
+    a slot with its short height."""
     from test_latent_moe import brute_force_visits
+    dense_short = 0 if tile else short_queries
+    tile = tile or (lambda Q: Q)
     live = short = moved = 0
     for q_lens, Q, rows in waves:
         if rows == len(q_lens) * Q:
-            a, b = tiles_of([(q_lens, Q, rows)], lambda Q: Q, short_queries)
-            live, short, moved = live + a, short + b, moved + len(q_lens)
+            a, b = tiles_of([(q_lens, Q, rows)], tile, dense_short)
+            live, short = live + a, short + b
+            moved += len(q_lens) * -(-Q // tile(Q))
             continue
         visits = brute_force_visits(q_lens, tq, short_queries)
         live += len(visits)
@@ -388,11 +393,12 @@ def visits_of(waves, tq, short_queries):
 
 def test_attention_tile_counters_follow_the_waves_q_lens(gpt, monkeypatch):
     """``serve.attn.tiles_live`` / ``tiles_short`` of an engine on the
-    kernel path: a chunk of 64 float32 queries of 2 heads is one tile of
-    64, its short height 8, so a decoding slot beside a chunk is a short
-    tile and the chunk a full one; a decode wave's program (Q 1) has one
-    height and nothing in it is short.  An engine on the masked path
-    runs no kernel and counts none."""
+    kernel path: a packed chunk wave is one row tile of 256 float32
+    queries, its short window 8, so a decoding slot beside a chunk is a
+    short visit and the chunk a full one; a dense program (a decode
+    wave's Q 1, a chunk wave too small to pack) has one height and
+    nothing in it is short.  An engine on the masked path runs no kernel
+    and counts none."""
     from hetu_tpu import telemetry
     from hetu_tpu.kernels import ragged_attention as ra
     params, cfg = gpt
@@ -406,8 +412,11 @@ def test_attention_tile_counters_follow_the_waves_q_lens(gpt, monkeypatch):
     eng.run(requests(61, SMALL_SIZES))
     mark, first = eng.metrics.mark(), len(waves)
     whole = eng.metrics.snapshot()
-    assert ra.rows_tiling(64, 2, 8, 1, jnp.float32) == (64, 64, 8)
-    want = tiles_of(waves, f32_rows_tile, 8)
+    assert ra.rows_tiling(64, 2, jnp.float32) == (64, 64)
+    # a chunk wave is packed (256 rows of the block's 512) and goes to
+    # the kernel as it lies: ONE row tile of 256 queries visits its slots
+    assert ra.rows_packed_tiling(256, 2, 8, 1, jnp.float32) == (256, 256, 8)
+    want = visits_of(waves, 256, 8, f32_rows_tile)[:2]
     assert (whole["attn_tiles_live"], whole["attn_tiles_short"]) == want
     # decoding slots rode beside chunks, chunks were scored whole, and
     # the decode waves added live tiles alone
@@ -420,8 +429,8 @@ def test_attention_tile_counters_follow_the_waves_q_lens(gpt, monkeypatch):
     assert counters["serve.attn.tiles_short"] == want[1]
     eng.run(requests(61, SMALL_SIZES[:3], seed=5))
     tail = eng.metrics.snapshot(since=mark)
-    assert (tail["attn_tiles_live"], tail["attn_tiles_short"]) == tiles_of(
-        waves[first:], f32_rows_tile, 8)
+    assert (tail["attn_tiles_live"], tail["attn_tiles_short"]) == visits_of(
+        waves[first:], 256, 8, f32_rows_tile)[:2]
     masked = ServingEngine(params, cfg, slots=8, kv_block=16,
                            prefill_chunk=64, fast_path=False)
     masked.run(requests(61, SMALL_SIZES[:3]))
@@ -506,9 +515,11 @@ def test_hybrid_engine_matches_reference_with_packing_engaged(hybrid, fast):
     assert eng.kv.state_resets == len(sizes)
     assert eng.kv.free_blocks == eng.kv.capacity_blocks
     # the grouped rows kernel's tiles (float32: a short height of 8
-    # queries under tiles of 64); the masked path runs no kernel
+    # queries under tiles of 64, a packed chunk wave one row tile of
+    # 256); the masked path runs no kernel
     tiles = (snap["attn_tiles_live"], snap["attn_tiles_short"])
-    assert tiles == (tiles_of(waves, f32_rows_tile, 8) if fast else (0, 0))
+    assert tiles == (visits_of(waves, 256, 8, f32_rows_tile)[:2]
+                     if fast else (0, 0))
     assert not fast or 0 < tiles[1] < tiles[0]
 
 
@@ -556,6 +567,53 @@ def test_latent_engine_matches_reference_with_packing_engaged(
         counters = telemetry.snapshot()["counters"]
         assert tiles == tuple(counters[f"serve.attn.{name}"] for name in (
             "tiles_live", "tiles_short", "q_tiles_moved"))
+    telemetry.reset()
+
+
+def test_packed_rows_wave_counters_are_the_kernels_visits(hybrid,
+                                                          monkeypatch):
+    """``engine._attn_tiles`` of a packed wave of the K/V rows kernel
+    (ISSUE 54) is ``row_tile_visits`` asked of the same ``q_len``: live
+    visits, short visits and ``R / tq`` q-tiles moved, whatever the slots
+    hold; and an engine's run counts them wave by wave.  Row tiles of 16
+    packed queries x 8 heads: a chunk of 64 crosses tile edges wherever
+    it starts."""
+    from hetu_tpu.kernels import ragged_attention as ra
+    from test_latent_moe import brute_force_visits
+    monkeypatch.setattr(ra, "_MAX_ROWS", 16 * 8)
+    assert ra.rows_packed_tiling(256, 8, 8, 4, jnp.float32) == (256, 16, 8)
+    telemetry.reset()
+    params, cfg = hybrid
+    eng = ServingEngine(params, cfg, slots=8, max_seq_len=256, kv_block=4,
+                        prefill_chunk=64, fast_path=True, prefix_share=False)
+    q_len = np.array([64, 1, 0, 7, 64, 1, 1, 30])
+    start = np.cumsum(q_len) - q_len
+    _, _, live, full = ra.row_tile_visits(
+        start[:, None], q_len[:, None], np.arange(16)[None, :], 16, 8)
+    got = eng._attn_tiles(q_len, 64, 256)
+    assert got == (int(live.sum()), int((live & ~full).sum()), 256 // 16)
+    visits = brute_force_visits(q_len, 16, 8)
+    assert got[:2] == (len(visits), sum(visits.values())) == (16, 8)
+    # the same wave padded is the dense entry's: (slot, q-tile) steps
+    # at one height
+    assert eng._attn_tiles(q_len, 64, 512) == (
+        *tiles_of([(q_len, 64, 512)], lambda Q: 16, 0), 8 * 4) == (
+        14, 0, 32)
+    waves = watch_waves(eng)
+    eng.run(requests(257, SMALL_SIZES))
+    snap = eng.metrics.snapshot()
+    tiles = (snap["attn_tiles_live"], snap["attn_tiles_short"],
+             snap["attn_q_tiles_moved"])
+    assert tiles == visits_of(waves, 16, 8, lambda Q: min(Q, 16))
+    chunk = [w for w in waves if w[2] < 8 * w[1]]
+    assert chunk and all(w[2] == 256 for w in chunk)
+    assert 0 < tiles[1] < tiles[0]
+    # a chunk that crosses a tile's edge is visited from both sides
+    assert tiles[0] > sum(int((w[0] > 0).sum()) for w in waves)
+    assert tiles[2] == 16 * len(chunk) + 8 * (len(waves) - len(chunk))
+    counters = telemetry.snapshot()["counters"]
+    assert tiles == tuple(counters[f"serve.attn.{name}"] for name in (
+        "tiles_live", "tiles_short", "q_tiles_moved"))
     telemetry.reset()
 
 

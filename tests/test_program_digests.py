@@ -44,6 +44,15 @@ def packed_programs(sds, attn):
             if k.endswith("Q32.fresh1")}
 
 
+def packed_rows_programs(sds, attn):
+    """The grouped-query (``lfm2``, ``falcon``) and sliding-window
+    (``mellum2``) chunk programs of 16 slots: 16 x 32 packed into 256
+    rows, whose K/V rows kernel takes the packed rows (ISSUE 54)."""
+    out = hybrid_programs(sds, attn, slots=16, qs=(32,))
+    out.update(window_programs(sds, attn, qs=(32,), slots=16))
+    return {k: low for k, low in out.items() if k.endswith("fresh1")}
+
+
 def ssm_kernel_programs(sds):
     """The small ``nemotron_h`` and ``falcon_h1`` models with a state of
     128 columns: every program's one-row slots through ``ssm_step``."""
@@ -65,6 +74,9 @@ FAMILIES = {
     "PARENT_RETENTION_MASKED": (retention_programs, False),
     "PARENT_PACKED_MASKED": (lambda s: packed_programs(s, "masked"), False),
     "PARENT_PACKED_RAGGED": (lambda s: packed_programs(s, "ragged"), True),
+    "PACKED_ROWS_MASKED": (
+        lambda s: packed_rows_programs(s, "masked"), False),
+    "PACKED_ROWS_RAGGED": (lambda s: packed_rows_programs(s, "ragged"), True),
     "NEMOTRON_MASKED": (lambda s: nemotron_programs(s, "masked"), False),
     "NEMOTRON_RAGGED": (lambda s: nemotron_programs(s, "ragged"), True),
     "NEMOTRON_DECODE_TILES_RAGGED": (
@@ -120,20 +132,24 @@ PARENT = {
         "falcon.Q1.fresh1": "471086ba72e5dab2",
         "falcon.Q32.fresh0": "7a593dcee24143b8",
         "falcon.Q32.fresh1": "e1c7e7369a2d3dbb"},
-    # The four Q 32 entries are PR 43's: with 4 and 2 query heads a K/V
-    # head the rows kernel of a chunk program holds its step at two
-    # heights, ``ragged_attention.tile_heights`` and ``rows_tiling``; the
-    # parent of PR 43 lowered them to 4305fadbb584f1c8 and
-    # c49aeded6dc2f4d8.  No ``ragged_paged_window`` in any of them.
+    # The four Q 32 entries (4 slots x 32 rows stay padded: the DENSE
+    # entry) are the PARENT of PR 43's again: PR 43 gave the rows kernel
+    # of a chunk program a second height (1c3dd14502830c9d and
+    # 0aee2c839734b68c), and PR 54, whose packed entry takes every chunk
+    # wave of 16 slots or more with its heights, took it out of the dense
+    # kernel (``_kv_rows_kernel`` scores every live tile whole; what
+    # differs is the kernel's body alone: no second ``scf.if`` branch of
+    # 8 or 16 queries, no ``tile_heights`` of the prefetched ``q_len``).
+    # No ``ragged_paged_window`` in any of them.
     "PARENT_HYBRID_RAGGED": {
         "lfm2.Q1.fresh0": "d8e968f0ef99f889",
         "lfm2.Q1.fresh1": "d8e968f0ef99f889",
-        "lfm2.Q32.fresh0": "1c3dd14502830c9d",
-        "lfm2.Q32.fresh1": "1c3dd14502830c9d",
+        "lfm2.Q32.fresh0": "4305fadbb584f1c8",
+        "lfm2.Q32.fresh1": "4305fadbb584f1c8",
         "falcon.Q1.fresh0": "b452b65dabd73846",
         "falcon.Q1.fresh1": "b452b65dabd73846",
-        "falcon.Q32.fresh0": "0aee2c839734b68c",
-        "falcon.Q32.fresh1": "0aee2c839734b68c"},
+        "falcon.Q32.fresh0": "c49aeded6dc2f4d8",
+        "falcon.Q32.fresh1": "c49aeded6dc2f4d8"},
     # tests/test_window_moe.py's small sliding-window / full model, as
     # the PARENT of PR 44 lowered it (commit 56a5ee3).
     "PARENT_WINDOW_MASKED": {
@@ -143,11 +159,18 @@ PARENT = {
         "mellum2.Q8.fresh1": "293ab0d1cdcca346"},
     # The families below were taken on the PARENT of PR 47 (commit
     # 2accd4a, in a scratch checkout, before that PR's first deletion).
+    # But every Q 32 entry of a K/V rows kernel with several query heads
+    # a K/V head, which is PR 54's: the dense kernel's one height (see
+    # ``PARENT_HYBRID_RAGGED``; the body of ``ragged_paged_mixed`` /
+    # ``ragged_paged_window`` alone differs).  The parent of PR 54
+    # lowered them to: mellum2 598db0ab0ecb210c, nemotron
+    # 7f0e176ec1f6c772, and in ``SSM_STEP_RAGGED`` nemotron
+    # 69fe6c6b2ab88112, falcon 7209be57d34aca4a.
     "PARENT_WINDOW_RAGGED": {
         "mellum2.Q1.fresh0": "071a841212bd9ec1",
         "mellum2.Q1.fresh1": "071a841212bd9ec1",
-        "mellum2.Q32.fresh0": "598db0ab0ecb210c",
-        "mellum2.Q32.fresh1": "598db0ab0ecb210c"},
+        "mellum2.Q32.fresh0": "57d8e12e8808ce77",
+        "mellum2.Q32.fresh1": "57d8e12e8808ce77"},
     "PARENT_RETENTION_MASKED": {
         "brumby.Q1.fresh0": "e33a3369dfd0dd1b",
         "brumby.Q1.fresh1": "e33a3369dfd0dd1b",
@@ -156,9 +179,34 @@ PARENT = {
     "PARENT_PACKED_MASKED": {
         "gpt2.Q32.fresh1": "6fd67af84c39d578",
         "latent.Q32.fresh1": "9b275df0e7c91882"},
+    # The GPT-2 entry is PR 54's: the K/V rows kernel is handed the 256
+    # packed rows as they lie (``ragged_paged_attention_rows``: its call's
+    # operand and result are ``[256, 256]`` rows where the parent's were
+    # ``[16, 32, 256]``, the grid ``(row tile)`` with the slots' visits
+    # inside, and the query's ``rows.unpack`` gather and the result's
+    # ``rows.pack`` gather are gone; k and v still unpack for the page
+    # write); the parent of PR 54 lowered it to ee10736bb0265878.  The
+    # latent entry is the parent's.
     "PARENT_PACKED_RAGGED": {
-        "gpt2.Q32.fresh1": "ee10736bb0265878",
+        "gpt2.Q32.fresh1": "d56b6d40bd76fb37",
         "latent.Q32.fresh1": "89ef03c3aad79ffc"},
+    # The grouped-query and sliding-window chunk programs of 16 slots
+    # (``packed_rows_programs``).  MASKED: as the PARENT of PR 54 lowered
+    # them (commit 2bf7f3c; the masked path still unpacks q and packs o).
+    # RAGGED: PR 54's own: every pool and window layer's kernel
+    # (``ragged_paged_mixed``, ``ragged_paged_window``) takes the packed
+    # rows, window by window the members' rows; the parent lowered them
+    # to cfece650ee5bc240, 41c74b76a0765db4 and 6a71e6743f415082 (q
+    # unpacked to ``[16, 32, H, Dh]``, relaid by ``_grouped_rows``, the
+    # dense grid ``(slot, q-tile)``, the result relaid and packed).
+    "PACKED_ROWS_MASKED": {
+        "lfm2.Q32.fresh1": "af661b9170a114df",
+        "falcon.Q32.fresh1": "e3194d430ad60fda",
+        "mellum2.Q32.fresh1": "186e8bc80e57df70"},
+    "PACKED_ROWS_RAGGED": {
+        "lfm2.Q32.fresh1": "15d4407705d449f8",
+        "falcon.Q32.fresh1": "3515c214ca1f81d5",
+        "mellum2.Q32.fresh1": "847f077464895fb5"},
     # PR 48's own, no parent's: tests/test_nemotron_h.py's small
     # ``nemotron_h`` model (eleven one-part layers, positions "none",
     # expert layers that hold experts [4, 8) of 16 at a latent width, the
@@ -174,8 +222,8 @@ PARENT = {
     "NEMOTRON_RAGGED": {
         "nemotron.Q1.fresh0": "acb5db193cd96995",
         "nemotron.Q1.fresh1": "acb5db193cd96995",
-        "nemotron.Q32.fresh0": "7f0e176ec1f6c772",
-        "nemotron.Q32.fresh1": "7f0e176ec1f6c772"},
+        "nemotron.Q32.fresh0": "d6103b62da292653",
+        "nemotron.Q32.fresh1": "d6103b62da292653"},
     # PR 49's own, no parent's: the same model at 32 slots, whose DECODE
     # wave's 32 x top-4 sorted rows are one whole row tile (32 can land
     # on the 4 held experts): since PR 49 the rule hands such a wave's
@@ -196,12 +244,12 @@ PARENT = {
     "SSM_STEP_RAGGED": {
         "nemotron.Q1.fresh0": "c3a7775fd1b7f6c8",
         "nemotron.Q1.fresh1": "c3a7775fd1b7f6c8",
-        "nemotron.Q32.fresh0": "69fe6c6b2ab88112",
-        "nemotron.Q32.fresh1": "69fe6c6b2ab88112",
+        "nemotron.Q32.fresh0": "453c8f1ffd43c2f9",
+        "nemotron.Q32.fresh1": "453c8f1ffd43c2f9",
         "falcon.Q1.fresh0": "737fe178722df78a",
         "falcon.Q1.fresh1": "737fe178722df78a",
-        "falcon.Q32.fresh0": "7209be57d34aca4a",
-        "falcon.Q32.fresh1": "7209be57d34aca4a"},
+        "falcon.Q32.fresh0": "87ef4a527badb7db",
+        "falcon.Q32.fresh1": "87ef4a527badb7db"},
     # PR 51's own, no parent's: tests/test_sparse_latent.py's small
     # five-layer model (latent operators by layer: two full layers with
     # an indexer of top 16, three window layers over a latent ring of

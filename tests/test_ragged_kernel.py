@@ -248,13 +248,16 @@ ROW_CASES = {
                                 dict(garbage=1e3)),
 }
 
-# chunk waves with DECODING slots beside the chunks (ISSUE 43): tiles of
-# 32 queries, so a program with two heights; a slot's one row, a tile
-# whose live rows are exactly one sublane tile (8 of f32, 16 of bf16)
-# and one more, a later tile with a short tail (17 of 16 + 1, 33 of 32 +
-# 1), a dead slot, and a slot with ``q_len`` 0 whose pages are filled (a
-# chunk deferred for a wave).  2 K/V heads of 64 (rows of 128 lanes)
-# under ``groups`` query heads each, with and without a window.
+# chunk waves with DECODING slots beside the chunks (ISSUE 43), as the
+# dense entry sees them where they are too small to pack: tiles of 32
+# queries, each live tile scored whole; a slot's one row, a tile whose
+# live rows are exactly one sublane tile (8 of f32, 16 of bf16) and one
+# more, a later tile with a short tail (17 of 16 + 1, 33 of 32 + 1), a
+# dead slot, and a slot with ``q_len`` 0 whose pages are filled (a chunk
+# deferred for a wave).  2 K/V heads of 64 (rows of 128 lanes) under
+# ``groups`` query heads each, with and without a window.  (The second
+# height such waves were given is the PACKED entry's since ISSUE 54:
+# ``PACKED_LAYOUTS`` below.)
 _F32 = dict(q_lens=((64, 1, 1, 17, 0, 0), (128, 8, 9, 1, 33, 0)), tol=2e-5)
 _BF16 = dict(q_lens=((64, 1, 16, 17, 0, 0), (128, 16, 17, 1, 33, 0)),
              tol=3e-2, dtype=jnp.bfloat16)
@@ -262,11 +265,8 @@ for _k, (_g, _win) in enumerate((g, w) for g in (1, 4, 8) for w in (0, 40)):
     # each (groups, window) with both dtypes, one at Q 64 and one at Q 128
     for _i, (_name, _d) in enumerate((("f32", _F32), ("bf16", _BF16))):
         _ql = _d["q_lens"][(_k + _i) % 2]
-        # (one query head a K/V head is 16 or 32 rows a short product,
-        # under the row count at which the rule gives a program two
-        # heights: the count is lowered, the code path is the same)
         _kw = dict(groups=_g, window=_win, max_rows=2 * _g * 32,
-                   tol=_d["tol"], min_short_rows=1)
+                   tol=_d["tol"])
         if "dtype" in _d:
             _kw["dtype"] = _d["dtype"]
         # every slot decodes from (or prefills onto) a prefix; the last
@@ -278,19 +278,17 @@ for _k, (_g, _win) in enumerate((g, w) for g in (1, 4, 8) for w in (0, 40)):
         ROW_CASES[f"decoding-beside-Q{_ql[0]}-g{_g}-w{_win}-{_name}"] = (
             2 * _g, _ql[0], _lens, _ql, _kw)
 
-# rows of SEVERAL lane chunks under two heights (the short height loops
-# over the chunks of whole heads, the full one takes them one by one):
-# GPT-2 XL's 13 chunks, the last with one head beside the pad, and 2
-# chunks of 2 K/V heads x 4 query heads under a window
+# rows of SEVERAL lane chunks in tiles of 32 queries: GPT-2 XL's 13
+# chunks, the last with one head beside the pad, and 2 chunks of 2 K/V
+# heads x 4 query heads under a window
 _LENS = (300, 257, 100, 290, 310, 40)
 ROW_CASES.update({
     "decoding-beside-w1664-f32": (
         25, 64, _LENS, (64, 1, 8, 9, 33, 0),
-        dict(max_rows=25 * 32, garbage=1e3, min_short_rows=1)),
+        dict(max_rows=25 * 32, garbage=1e3)),
     "decoding-beside-w1664-bf16": (
         25, 64, _LENS, (64, 1, 16, 17, 33, 0),
-        dict(max_rows=25 * 32, dtype=jnp.bfloat16, tol=3e-2,
-             min_short_rows=1)),
+        dict(max_rows=25 * 32, dtype=jnp.bfloat16, tol=3e-2)),
     "decoding-beside-w256-g4-w40-f32": (
         16, 64, _LENS, (64, 1, 8, 9, 33, 0),
         dict(max_rows=16 * 32, groups=4, window=40)),
@@ -324,22 +322,12 @@ def _rows_wave(H, Q, lens, q_lens, *, Dh=64, bs=16, T=20, L=3, layer=1,
             np.asarray(q_lens, np.int32), tables.astype(np.int32), layer)
 
 
-def _scored_rows(n, Q, tq, short):
+def _scored_rows(n, Q, tq):
     """[(first row, rows scored)] of a q-block's tiles with ``n`` live
-    rows, written out from the kernel's contract and not through its
-    rule: a program with two heights (``short`` > 0) scores a live tile
-    whose live rows fit ``short`` at ``short`` and a dead one not at
-    all; a program with one scores every live tile whole, and tile 0
-    always."""
-    out = []
-    for t in range(-(-Q // tq)):
-        live = min(max(n - t * tq, 0), tq)
-        if short:
-            h = 0 if live == 0 else short if live <= short else tq
-        else:
-            h = tq if live or t == 0 else 0
-        out.append((t * tq, h))
-    return out
+    rows, written out from the dense kernel's contract and not through
+    its rule: every live tile is scored whole, and tile 0 always."""
+    return [(t * tq, tq if n > t * tq or t == 0 else 0)
+            for t in range(-(-Q // tq))]
 
 
 @pytest.mark.smoke
@@ -352,12 +340,10 @@ class TestPoolRowsKernel:
         kw = dict(kw)
         tol = kw.pop("tol", 2e-5)
         max_rows = kw.pop("max_rows", None)
-        min_short_rows = kw.pop("min_short_rows", ra._SHORT_MIN_ROWS)
         window = kw.pop("window", 0)
         groups = kw.get("groups", 1)
         if max_rows:
             monkeypatch.setattr(ra, "_MAX_ROWS", max_rows)
-        monkeypatch.setattr(ra, "_SHORT_MIN_ROWS", min_short_rows)
         q, pk, pv, lens, q_lens, tables, layer = _rows_wave(
             H, Q, lens, q_lens, **kw)
         got = np.asarray(ragged_paged_attention(
@@ -378,18 +364,15 @@ class TestPoolRowsKernel:
         assert got.shape == want.shape == q.shape
         sub = 8 if q.dtype == np.float32 else 16
         tq = ra._fit_block(max(ra._MAX_ROWS // H, 1), -(-Q // sub) * sub)
-        # two K/V heads of 64 a lane chunk, ``groups`` query heads each
-        short = sub if sub < tq and 2 * groups * sub >= min_short_rows else 0
-        assert ra.rows_tiling(Q, H, 64, groups, q.dtype) == (
-            -(-Q // sub) * sub, tq, short)
+        assert ra.rows_tiling(Q, H, q.dtype) == (-(-Q // sub) * sub, tq)
         for b, n in enumerate(q_lens):
             if lens[b] == 0:
                 assert not got[b].any()          # a dead slot: zeros
                 continue
             # live rows, and a scored tile's dead rows (clipped to the
-            # last live position), are the reference's; the rows past a
-            # tile's scored height, and dead tiles, are zeros
-            for at, h in _scored_rows(int(n), Q, tq, short):
+            # last live position), are the reference's; dead tiles are
+            # zeros
+            for at, h in _scored_rows(int(n), Q, tq):
                 np.testing.assert_allclose(got[b, at:at + h],
                                            want[b, at:at + h],
                                            atol=tol, rtol=tol)
@@ -398,10 +381,11 @@ class TestPoolRowsKernel:
     @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
                              ids=["f32", "bf16"])
     def test_the_rule_says_what_the_kernel_did(self, dtype, monkeypatch):
-        """``tile_heights`` (what the engine's counters ask) against
-        the kernel's output: a live tile's rows past the short height
-        are zero exactly where the rule says it was not scored at the
-        full one."""
+        """``tile_heights`` at one height (what the engine's counters ask
+        of a dense wave of this kernel) against the kernel's output: a
+        live tile is scored WHOLE, its dead rows too (clipped to the
+        last live position), whatever its live rows; a dead tile comes
+        back zero, but tile 0 of a slot that has filled pages."""
         from hetu_tpu.kernels import ragged_attention as ra
         monkeypatch.setattr(ra, "_MAX_ROWS", 4 * 32)
         sub = 8 if dtype == jnp.float32 else 16
@@ -409,40 +393,41 @@ class TestPoolRowsKernel:
         q, pk, pv, lens, q_lens, tables, layer = _rows_wave(
             4, 64, (300, 257, 100, 290, 310, 200, 40), q_lens, groups=2,
             dtype=dtype)
-        monkeypatch.setattr(ra, "_SHORT_MIN_ROWS", 32)
-        Qp, tq, short = ra.rows_tiling(64, 4, 64, 2, dtype)
-        assert (Qp, tq, short) == (64, 32, sub)
+        Qp, tq = ra.rows_tiling(64, 4, dtype)
+        assert (Qp, tq) == (64, 32)
         got = np.asarray(ragged_paged_attention(
             q, pk, pv, lens, q_lens, tables, layer=layer, groups=2,
             interpret=True), np.float32)
         live, full = ra.tile_heights(q_lens[:, None], np.arange(2)[None, :],
-                                     tq, short)
-        assert live.tolist() == [[1, 1], [1, 0], [1, 0], [1, 0], [1, 1],
-                                 [1, 1], [0, 0]]
-        assert full.tolist() == [[1, 1], [0, 0], [0, 0], [1, 0], [1, 0],
-                                 [1, 0], [0, 0]]
+                                     tq, 0)
+        assert live.tolist() == full.tolist() == [
+            [1, 1], [1, 0], [1, 0], [1, 0], [1, 1], [1, 1], [0, 0]]
         for b in range(len(q_lens)):
             for t in range(2):
                 tile = got[b, t * tq:(t + 1) * tq]
-                assert tile[:short].any() == bool(live[b, t])
-                assert tile[short:].any() == bool(full[b, t])
-        # a program with one height asks no rule: nothing is short
-        assert ra.rows_tiling(1, 4, 64, 2, dtype) == (sub, sub, 0)
-        assert ra.rows_tiling(5, 4, 64, 2, dtype) == (sub, sub, 0)
+                scored = bool(live[b, t]) or (t == 0 and lens[b] > 0)
+                assert tile[:sub].any() == tile[sub:].any() == scored
+        assert ra.rows_tiling(1, 4, dtype) == (sub, sub)
+        assert ra.rows_tiling(5, 4, dtype) == (sub, sub)
 
     def test_two_heights_where_the_short_product_has_rows_enough(self):
-        """The cells' chunk programs (Q 256, bf16): 4 to 8 query heads a
-        K/V head stack 80-128 rows a short product and have two
-        heights; GPT-2's one query head a K/V head stacks 32, which the
-        MXU's weight loads bound as they bound the full height's 128:
-        one height, the kernel there was."""
+        """The cells' packed chunk programs (1,024 rows, bf16): 4 to 8
+        query heads a K/V head stack 80-128 rows a short product and
+        have two heights; GPT-2's one query head a K/V head stacks 32,
+        which the MXU's weight loads bound as they bound the full
+        height's 128: one height.  The dense programs' tiles are the
+        same queries, each scored whole."""
         from hetu_tpu.kernels import ragged_attention as ra
         bf16 = jnp.bfloat16
-        assert ra.rows_tiling(256, 32, 64, 4, bf16) == (256, 64, 16)   # lfm2
-        assert ra.rows_tiling(256, 32, 128, 8, bf16) == (256, 64, 16)  # mellum
-        assert ra.rows_tiling(256, 20, 128, 5, bf16) == (256, 64, 16)  # falcon
-        assert ra.rows_tiling(256, 25, 64, 1, bf16) == (256, 64, 0)    # XL
-        assert ra.rows_tiling(256, 12, 64, 1, bf16) == (256, 128, 0)
+        for H, Dh, groups, tq, short in (
+                (32, 64, 4, 64, 16),        # lfm2
+                (32, 128, 8, 64, 16),       # mellum
+                (20, 128, 5, 64, 16),       # falcon
+                (25, 64, 1, 64, 0),         # XL
+                (12, 64, 1, 128, 0)):
+            assert ra.rows_packed_tiling(1024, H, Dh, groups, bf16) == (
+                1024, tq, short)
+            assert ra.rows_tiling(256, H, bf16) == (256, tq)
 
     def test_a_row_that_is_not_the_heads_width_is_refused(self):
         q, pk, pv, lens, q_lens, tables, _ = _rows_wave(
@@ -459,6 +444,109 @@ class TestPoolRowsKernel:
         rows = np.asarray(kv_rows(jnp.asarray(x), 1664))
         assert rows.shape == (2, 3, 1664) and not rows[..., 1600:].any()
         np.testing.assert_array_equal(kv_heads(rows, 25, 64), x)
+
+
+# ------------------------------------------------------------------- #
+# ISSUE 54: the rows kernel's PACKED entry (a chunk wave's rows as they
+# lie: ``ragged_paged_attention_rows``)
+# ------------------------------------------------------------------- #
+
+# layout: (q_lens, lengths after the wave, what it is there for).  128
+# packed float32 rows in row tiles of 32 queries, short windows of 8;
+# a page group is 16 pages = 256 positions, the table 20 pages.
+PACKED_LAYOUTS = {
+    # tile 0 holds slots 0, 1 and 2 whole and the head of slot 3
+    "a-tile-crossed-by-four-slots": ((5, 9, 6, 40), (37, 300, 41, 290)),
+    # the chunk starts at packed row 3 and crosses one tile more than it
+    # fills
+    "a-chunk-at-an-unaligned-row": ((1, 1, 1, 64), (257, 18, 300, 310)),
+    # slots 1 and 2 are rows 18 and 19: window 2 of tile 0, a traced start
+    "a-decode-row-in-a-later-window": ((18, 1, 1), (140, 258, 17)),
+    "an-empty-slot-between-two-live": ((9, 0, 12), (100, 77, 270)),
+    # tile 1 holds one row, tiles 2 and 3 nobody's
+    "a-dead-tail": ((33,), (301,)),
+    # under a window of 40 the first group in sight is group 1
+    "a-first-group-past-page-0": ((9, 3, 1), (310, 300, 320)),
+}
+
+
+@pytest.mark.smoke
+@pytest.mark.parametrize("layout", list(PACKED_LAYOUTS))
+@pytest.mark.parametrize("groups", [1, 4, 5, 8])
+@pytest.mark.parametrize("window", [0, 40], ids=["full", "window40"])
+def test_packed_rows_match_the_reference_and_the_dense_entry(
+        monkeypatch, layout, groups, window):
+    """Interpret mode, float32: the packed entry against the banded
+    oracle and, live row for live row and to the bit, against the dense
+    entry over the same slots' q-blocks (the same tile height, the same
+    order of page groups); a row nobody owns comes back zero whatever
+    the query held.  (Every layout is four slots, the last ones dead, and
+    the layouts of one width follow one another: they share its
+    programs.)"""
+    q_lens, lens = (v + (0,) * (4 - len(v)) for v in PACKED_LAYOUTS[layout])
+    _check_packed_rows(monkeypatch, groups * (2 if groups == 1 else 1),
+                       groups, window, q_lens, lens)
+
+
+def _check_packed_rows(monkeypatch, H, groups, window, q_lens, lens):
+    from hetu_tpu.kernels import ragged_attention as ra
+    from test_window_moe import banded_reference
+    Q, R = 64, 128
+    monkeypatch.setattr(ra, "_MAX_ROWS", 32 * H)
+    monkeypatch.setattr(ra, "_SHORT_MIN_ROWS", 8)
+    assert ra.rows_packed_tiling(R, H, 64, groups, jnp.float32) == (R, 32, 8)
+    assert ra.rows_tiling(Q, H, jnp.float32) == (Q, 32)
+    q, pk, pv, lens, q_lens, tables, layer = _rows_wave(
+        H, Q, lens, q_lens, groups=groups, garbage=3.0)
+    start = np.cumsum(q_lens) - q_lens
+    packed = np.full((R, H, 64), 5.0, np.float32)     # the dead tail too
+    for b, n in enumerate(q_lens):
+        packed[start[b]:start[b] + n] = np.asarray(q)[b, :n]
+    got = np.asarray(ra.ragged_paged_attention_rows(
+        jnp.asarray(packed), pk, pv, lens, q_lens, start, tables,
+        layer=layer, groups=groups, window=window, interpret=True))
+    assert got.shape == (R, H * 64)
+    want = np.asarray(banded_reference(q, pk, pv, lens, q_lens, tables,
+                                       layer, groups, window))
+    dense = np.asarray(ragged_paged_attention(
+        q, pk, pv, lens, q_lens, tables, layer=layer, groups=groups,
+        window=window, interpret=True))
+    for b, n in enumerate(q_lens):
+        mine = got[start[b]:start[b] + n].reshape(n, H, 64)
+        np.testing.assert_allclose(mine, want[b, :n], atol=2e-5, rtol=2e-5)
+        np.testing.assert_array_equal(mine, dense[b, :n])
+    assert not got[q_lens.sum():].any()
+
+
+@pytest.mark.parametrize("layout", ["a-tile-crossed-by-four-slots",
+                                    "a-decode-row-in-a-later-window"])
+@pytest.mark.parametrize("H,groups,window", [(25, 1, 0), (16, 4, 40)],
+                         ids=["w1664", "w256-g4-window40"])
+def test_packed_rows_of_several_lane_chunks(monkeypatch, layout, H, groups,
+                                            window):
+    """Rows of SEVERAL lane chunks in the packed entry (the short window
+    loops over the chunks of whole heads, the full tile takes them one by
+    one): GPT-2 XL's 13 chunks, the last with one head beside the pad
+    (its second height lowered here: ``_SHORT_MIN_ROWS``), and 2 chunks
+    of 2 K/V heads x 4 query heads under a window."""
+    q_lens, lens = (v + (0,) * (4 - len(v)) for v in PACKED_LAYOUTS[layout])
+    _check_packed_rows(monkeypatch, H, groups, window, q_lens, lens)
+
+
+def test_packed_rows_tiling_is_the_dense_entrys_at_the_cells_widths():
+    """1,024 packed rows at the five cells' widths: the tile is the Q 256
+    dense program's, so a live row's products are the dense entry's, and
+    the short window one sublane tile of queries (GPT-2 XL: one
+    height)."""
+    from hetu_tpu.kernels import ragged_attention as ra
+    bf16 = jnp.bfloat16
+    for H, Dh, groups in ((32, 64, 4), (32, 128, 8), (20, 128, 5),
+                          (25, 64, 1), (32, 128, 16)):
+        assert ra.rows_packed_tiling(1024, H, Dh, groups, bf16) == (
+            1024, ra.rows_tiling(256, H, bf16)[1], 16 if groups > 1 else 0)
+    # a tile that is no whole number of short windows has one height
+    assert ra.rows_tiling(40, 64, jnp.float32) == (40, 20)
+    assert ra.rows_packed_tiling(40, 64, 64, 4, jnp.float32) == (40, 20, 0)
 
 
 # ------------------------------------------------------------------- #

@@ -671,12 +671,12 @@ def test_record_state_scan_counts_the_slots_the_kernel_took(kind,
 # the accepted cells' programs (tests/test_program_digests.py pins them)
 # ------------------------------------------------------------------ #
 
-def window_programs(sds, attn="masked", qs=(1, 8)):
+def window_programs(sds, attn="masked", qs=(1, 8), slots=4):
     from hetu_tpu.models.moe_decode import HybridMoEConfig
     from test_window_moe import NAME as MEL, SMALL as MELLUM
     i32 = lambda *s: sds(s, jnp.int32)                     # noqa: E731
     c = HybridMoEConfig.from_hf(MELLUM)
-    B, T, BS = 4, 16, 4
+    B, T, BS = slots, 16, 4
     p = {k: sds(s, jnp.float32) for k, s in c.param_shapes(MEL).items()}
     pool = sds((1, 33, BS, 128), jnp.float32)
     win = sds((3, 25, BS, 128), jnp.float32)

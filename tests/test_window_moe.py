@@ -757,11 +757,12 @@ def test_the_engines_steps_pass_the_ring_check_and_top_shows_the_pool(
 # the accepted cells' programs (tests/test_program_digests.py pins them)
 # ------------------------------------------------------------------ #
 
-def hybrid_programs(sds, attn):
+def hybrid_programs(sds, attn, slots=4, qs=(1, 32)):
     """{name: lowered mixed step} of a small ``lfm2_moe`` and a small
     ``falcon_h1`` configuration; ``sds(shape, dtype)`` makes the abstract
-    arguments."""
-    B, T, N, BS = 4, 8, 33, 16
+    arguments.  At 16 ``slots`` the Q 32 x has_fresh programs are packed
+    (``gd.wave_rows``)."""
+    B, T, N, BS = slots, 8, 33, 16
     c = HybridMoEConfig.from_hf(dict(
         vocab_size=512, hidden_size=256, num_hidden_layers=4,
         num_attention_heads=8, num_key_value_heads=2,
@@ -777,7 +778,7 @@ def hybrid_programs(sds, attn):
                       sds((2, N, BS, kv_row_width(2, 32)), jnp.bfloat16),
                       sds((2, B, 2, 256), jnp.bfloat16))}
     cases["falcon"] = falcon_case(sds, B, N, BS)
-    return lower_cases(sds, attn, cases, B, T)
+    return lower_cases(sds, attn, cases, B, T, qs)
 
 
 def falcon_case(sds, B, N, BS, ssm_state=16):
