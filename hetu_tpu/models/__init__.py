@@ -40,3 +40,4 @@ from .moe_decode import (
     convert_dense_to_moe, resolve_moe_capacity, resolve_moe_quant,
     LatentMoEConfig, RoutedSpec, routed_ffn, init_latent_moe_params,
 )
+from .parallel_moe import ParallelMoEConfig, init_parallel_moe_params

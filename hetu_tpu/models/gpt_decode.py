@@ -305,8 +305,9 @@ def _ln(x, scale, bias, eps=1e-5):
     x32 = x.astype(jnp.float32)
     m = x32.mean(axis=-1, keepdims=True)
     v = ((x32 - m) ** 2).mean(axis=-1, keepdims=True)
-    y = (x32 - m) * jax.lax.rsqrt(v + eps) * scale.astype(jnp.float32) \
-        + bias.astype(jnp.float32)
+    y = (x32 - m) * jax.lax.rsqrt(v + eps) * scale.astype(jnp.float32)
+    if bias is not None:
+        y = y + bias.astype(jnp.float32)
     return y.astype(x.dtype)
 
 
@@ -446,15 +447,18 @@ LATENT_OPERATORS = ("latent_attention", "window_latent_attention")
 
 
 class BlockSpec(NamedTuple):
-    """norm: "layernorm" (scale and bias) | "rmsnorm"; positions:
+    """norm: "layernorm" (scale and bias) | "rmsnorm" | "layernorm_nobias"
+    (mean-centred, a scale alone, ``norm_eps``: the ``cohere2`` family's);
+    positions:
     "learned" (a table added to the embedding) | "none" (nothing added
     and nothing rotated: the ``nemotron_h`` family's attention) | "rope" (rotate-half
     with ``rope_theta``: over ``latent.qk_rope_head_dim``, or over the
     whole head of a K/V attention; ``rope_by_op`` gives the rotary
     parameters BY OPERATOR instead, ``((operator, inv_freq, factor),
     ...)`` as ``rope_frequencies`` makes them on the host: the kinds
-    "default" and "yarn" exist, and a layer whose operator is not named
-    rotates with ``rope_theta``); attention: "mha" (as many K/V heads
+    "default" and "yarn" exist, a layer whose operator is not named
+    rotates with ``rope_theta``, and an entry whose ``inv_freq`` is
+    "none" says that operator's layers rotate NOTHING); attention: "mha" (as many K/V heads
     as query heads) | "gqa" (``kv_heads`` K/V heads, query head ``n``
     reading K/V head ``n // (H / kv_heads)``) | "latent"; bias: the
     K/V attention's projections carry biases (GPT-2's do); qk_norm:
@@ -485,10 +489,12 @@ class BlockSpec(NamedTuple):
     dense SwiGLU); ffns: the FFN kind BY LAYER instead, a tuple of
     ``FFN_KINDS``' names, "none" being a layer that is its operator
     alone (None: ``ffn`` as above; with it ``ffn`` says whether any
-    layer routes); a layer has ONE norm for each part it has: ``ln1``
-    before its operator, ``ln2`` before its FFN; head: "tied" (the
-    embedding table) | "untied" (``{name}_lm_head_weight`` [hidden,
-    vocab])."""
+    layer routes); residual: "sequential" (``h <- h + op(ln1(h))``, then
+    ``h <- h + ffn(ln2(h))``: a norm for each part a layer has) |
+    "parallel" (``x = ln1(h)``, ``h <- h + op(x) + ffn(x)``: ONE norm a
+    layer, the operator and the FFN side by side on its rows, one
+    addition into the residual); head: "tied" (the embedding table) |
+    "untied" (``{name}_lm_head_weight`` [hidden, vocab])."""
 
     norm: str = "layernorm"
     norm_eps: float = 1e-5
@@ -513,6 +519,7 @@ class BlockSpec(NamedTuple):
     retention: Optional[tuple] = None
     ffns: Optional[tuple] = None
     latent_by_op: Optional[tuple] = None
+    residual: str = "sequential"
 
     def latent_of(self, i):
         """Layer ``i``'s ``LatentSpec``: its operator's entry of
@@ -563,7 +570,8 @@ class BlockSpec(NamedTuple):
 
     def rope_of(self, i):
         """Layer ``i``'s (inv_freq or None, factor): its operator's entry
-        of ``rope_by_op``, else ``rope_theta``'s own frequencies."""
+        of ``rope_by_op`` ("none" in ``inv_freq``'s place: the layer
+        rotates nothing), else ``rope_theta``'s own frequencies."""
         for op, inv, factor in self.rope_by_op or ():
             if op == self.op_kind(i):
                 return inv, factor
@@ -649,6 +657,11 @@ def block_spec_of(config):
     return make() if callable(make) else GPT2_BLOCK
 
 
+# the norms and the residual forms a block may have
+NORMS = ("layernorm", "rmsnorm", "layernorm_nobias")
+RESIDUALS = ("sequential", "parallel")
+
+
 def check_block_spec(blk, layers=None):
     """Raise for a spec the mixed wave cannot run.  It runs GPT-2's
     block; latent attention with RMSNorm and RoPE over any FFN kind of
@@ -656,16 +669,23 @@ def check_block_spec(blk, layers=None):
     "window_latent_attention" layer over the last ``window`` positions
     in a latent ring, a "latent_attention" layer over everything or,
     where its ``LatentSpec`` has an indexer, over the rows that chose);
-    and the grouped-query block: RMSNorm, no biases, an
+    and the grouped-query block: RMSNorm or the bias-free LayerNorm
+    ("layernorm_nobias"), no biases, an
     optional per-head q/k norm, positions "rope" (over the whole head)
     or "none", every layer ONE of ``OPERATORS`` and one of
-    ``FFN_KINDS``, at least one of the two not "none".  What each
+    ``FFN_KINDS``, at least one of the two not "none".  Its residual is
+    one of ``RESIDUALS``: "sequential", or "parallel" (one norm a layer,
+    operator and FFN side by side) where every layer is a K/V attention
+    ("attention" or "window_attention") beside a SwiGLU or a routed FFN;
+    a parallel block over a latent or a state operator is not run.
+    What each
     operator needs is ``_OPERATOR_NEEDS``' entry; operators that keep
     slot state are of ONE kind a spec (``STATE_KINDS``: the manager
     holds one set), and a window or a retention layer is mixed with
     plain "attention" layers alone.  Multipliers (``mup``), a head size
     of the configuration's own and rotary parameters by operator
-    (``rope_by_op``, of ``ROPE_KINDS``) go with the grouped-query block
+    (``rope_by_op``, of ``ROPE_KINDS``, or "none" for an operator whose
+    layers rotate nothing) go with the grouped-query block
     alone; a routed FFN scores by one of ``moe_decode.SCORINGS``, has
     experts of one of ``moe_decode.EXPERT_FORMS`` and holds all its
     experts or a contiguous share of them.  ``layers``: the model's
@@ -675,8 +695,7 @@ def check_block_spec(blk, layers=None):
     from .moe_decode import EXPERT_FORMS, SCORINGS
     rt = blk.routed
     kinds = set(blk.ffns or (blk.ffn,))
-    common = blk.norm == "rmsnorm" \
-        and (blk.ffn == "routed") == (rt is not None) \
+    common = (blk.ffn == "routed") == (rt is not None) \
         and kinds <= set(FFN_KINDS) \
         and (blk.ffns is None or (
             ("routed" in kinds) == (rt is not None)
@@ -686,7 +705,7 @@ def check_block_spec(blk, layers=None):
         and (rt is None or (
             rt.scoring in SCORINGS and rt.expert in EXPERT_FORMS
             and rt.latent >= 0 and 0 <= rt.held_first
-            and 0 <= rt.held
+            and 0 <= rt.held and rt.shared_scale > 0
             and rt.held_first + rt.held <= rt.num_experts))
     ops = blk.ops or ()
     needs = all(need(blk, ops) for need in _OPERATOR_NEEDS.values()) \
@@ -698,10 +717,12 @@ def check_block_spec(blk, layers=None):
         # one latent spec for every layer, or latent operators BY LAYER
         # (``LATENT_OPERATORS``), each with a spec, a head count and
         # rotary parameters of its own
-        ok = common and blk.positions == "rope" \
+        ok = common and blk.norm == "rmsnorm" \
+            and blk.residual == "sequential" and blk.positions == "rope" \
             and blk.latent is not None and blk.ffns is None \
             and blk.ssm is None and blk.mup is None and not blk.head_dim \
             and blk.retention is None and needs \
+            and all(inv != "none" for _, inv, _ in blk.rope_by_op or ()) \
             and (bool(ops) or (
                 not blk.window and blk.rope_by_op is None
                 and blk.latent_by_op is None
@@ -709,23 +730,33 @@ def check_block_spec(blk, layers=None):
     else:
         n = max(len(ops), len(blk.ffns or ()))
         ok = common and blk.attention == "gqa" and blk.latent is None \
+            and blk.norm in ("rmsnorm", "layernorm_nobias") \
             and blk.positions in ("rope", "none") \
             and not blk.bias and blk.kv_heads >= 1 and needs \
             and len({STATE_KINDS[o] for o in ops if o in STATE_KINDS}) <= 1 \
             and all(blk.op_kind(i) != "none" or blk.ffn_kind(i) != "none"
                     for i in range(n)) \
-            and (blk.positions == "rope" or blk.rope_by_op is None)
+            and (blk.positions == "rope" or blk.rope_by_op is None) \
+            and (blk.residual == "sequential" or (
+                blk.residual == "parallel"
+                and set(ops) <= {"attention", "window_attention"}
+                and kinds <= {"swiglu", "routed"}))
     if not ok:
         raise ValueError(
             f"the mixed wave runs GPT-2's block, latent attention with "
             f"rmsnorm and rope, or the grouped-query block with rmsnorm "
+            f"or layernorm_nobias (of the norms {', '.join(NORMS)}) "
             f"and positions rope or none, a layer one operator of "
             f"{', '.join(OPERATORS)} (state of one kind a spec; window "
             f"and retention layers beside plain attention alone; "
             f"{' and '.join(LATENT_OPERATORS)} in a latent block alone, "
             f"an indexer on the first) and one "
-            f"FFN of {', '.join(FFN_KINDS)}, not both none; rotary kinds "
-            f"{', '.join(ROPE_KINDS)}; routers {', '.join(SCORINGS)}; "
+            f"FFN of {', '.join(FFN_KINDS)}, not both none; residuals "
+            f"{', '.join(RESIDUALS)}, parallel in the grouped-query block "
+            f"where every layer is attention or window_attention beside "
+            f"a swiglu or routed FFN; rotary kinds "
+            f"{', '.join(ROPE_KINDS)}, or none by operator in the "
+            f"grouped-query block; routers {', '.join(SCORINGS)}; "
             f"experts {', '.join(EXPERT_FORMS)}: it cannot run {blk}")
 
 
@@ -788,9 +819,12 @@ def _rms(x, scale, eps):
 
 def _norm(blk, params, prefix, x):
     """The block's norm over ``x`` with the leaves ``{prefix}_scale``
-    (and ``_bias`` for LayerNorm)."""
+    (and ``_bias`` for LayerNorm; "layernorm_nobias" has the scale
+    alone and ``blk.norm_eps``)."""
     if blk.norm == "rmsnorm":
         return _rms(x, params[f"{prefix}_scale"], blk.norm_eps)
+    if blk.norm == "layernorm_nobias":
+        return _ln(x, params[f"{prefix}_scale"], None, blk.norm_eps)
     return _ln(x, params[f"{prefix}_scale"], params[f"{prefix}_bias"])
 
 
@@ -1500,9 +1534,12 @@ def _window_logits(params, name, h, first_row, window, blk=GPT2_BLOCK,
                       ).astype(jnp.float32)
             return logits if blk.mup is None \
                 else logits * blk.mup.lm_head
-        return (hw @ params[f"{name}_wte_table"].T
-                ).astype(jnp.float32) \
+        logits = (hw @ params[f"{name}_wte_table"].T
+                  ).astype(jnp.float32) \
             + params.get(f"{name}_head_bias", 0.0)
+        # a tied head's ``logit_scale``, where the spec has one
+        return logits if blk.mup is None or blk.mup.lm_head == 1.0 \
+            else logits * blk.mup.lm_head
 
 
 def _latent_attention(params, us, blk, H, h, pool, i, wblk, woff, posns,
@@ -1637,7 +1674,7 @@ def _latent_attention(params, us, blk, H, h, pool, i, wblk, woff, posns,
     return h, pool, index_pool
 
 
-def _ffn_of_kind(params, us, blk, h, i, valid, stats, moe=None):
+def _ffn_of_kind(params, us, blk, h, i, valid, stats, moe=None, x=None):
     """The FFN sublayer by ``blk.ffn_kind(i)``: GPT-2's GELU FFN (or,
     under a ``MoESpec``, its capacity-routed experts) or a dense SwiGLU
     under the scope ``mlp``, or the dropless routed FFN with its shared
@@ -1645,7 +1682,9 @@ def _ffn_of_kind(params, us, blk, h, i, valid, stats, moe=None):
     ``moe_experts``, ``moe_shared``, and ``moe_latent_in`` /
     ``moe_latent_out`` where the experts work at a latent width), or, of
     a layer that is its operator alone ("none"), nothing: ``h`` as it
-    came."""
+    came.  A parallel block hands in ``x``, the rows its one norm made:
+    the FFN reads them as they are and its output ALONE is returned (the
+    caller adds it to the residual beside the operator's)."""
     kind = blk.ffn_kind(i)
     if kind == "none":
         return h
@@ -1653,18 +1692,21 @@ def _ffn_of_kind(params, us, blk, h, i, valid, stats, moe=None):
         with jax.named_scope("mlp"):
             return _ffn_block(params, us, h, i, moe=moe, valid=valid,
                               stats=stats)
-    x = _norm(blk, params, f"{us}_ln2", h)
+    parallel = x is not None
+    if not parallel:
+        x = _norm(blk, params, f"{us}_ln2", h)
     if kind == "swiglu":
         with jax.named_scope("mlp"):
-            return h + swiglu(x, params[f"{us}_ffn_gate_weight"],
-                              params[f"{us}_ffn_up_weight"],
-                              params[f"{us}_ffn_down_weight"], blk.mup)
+            y = swiglu(x, params[f"{us}_ffn_gate_weight"],
+                       params[f"{us}_ffn_up_weight"],
+                       params[f"{us}_ffn_down_weight"], blk.mup)
+            return y if parallel else h + y
     from .moe_decode import routed_ffn
     shp = x.shape
     y = routed_ffn(params, us, x.reshape(-1, shp[-1]), blk.routed,
                    valid=jnp.broadcast_to(valid, shp[:-1]).reshape(-1),
                    stats=stats)
-    return h + y.reshape(shp)
+    return y.reshape(shp) if parallel else h + y.reshape(shp)
 
 
 def _causal_conv(z, hist, w, q_len, rows=None, mix="conv_mix",
@@ -1763,8 +1805,8 @@ def _qkv_heads(params, us, blk, i, x, H, Hkv, Dh, posns):
     if blk.qk_norm:
         q = _rms(q, params[f"{us}_attn_q_norm_scale"], blk.norm_eps)
         k = _rms(k, params[f"{us}_attn_k_norm_scale"], blk.norm_eps)
-    if blk.positions == "rope":
-        inv, factor = blk.rope_of(i)
+    inv, factor = blk.rope_of(i)
+    if blk.positions == "rope" and inv != "none":
         q = _rope(q, posns, blk.rope_theta, inv, factor)
         k = _rope(k, posns, blk.rope_theta, inv, factor)
     return q, k, v
@@ -1898,7 +1940,15 @@ def _mixed_step(params, cfg_tuple, cache_k, cache_v, pos, tokens,
     ``p - window < kv <= p`` (the masked path's band, the kernel's
     ``window``), so a page that a later one has overwritten is never in
     sight.  Rotary frequencies follow the layer's operator
-    (``BlockSpec.rope_of``).  Without ``win`` nothing here changes.
+    (``BlockSpec.rope_of``; an operator whose entry says "none" rotates
+    nothing).  Without ``win`` nothing here changes.
+
+    A PARALLEL block (``blk.residual`` "parallel": K/V attention layers
+    beside a SwiGLU or a routed FFN) makes a layer's ONE norm once under
+    the scope ``par_norm``; the attention and ``_ffn_of_kind(x=)`` both
+    read its rows (packed rows stay packed through both) and the
+    residual takes ``a + f`` in one addition.  A sequential spec traces
+    none of it.
 
     A latent block with operators BY LAYER (``blk.ops`` of
     ``LATENT_OPERATORS``): a "window_latent_attention" layer is
@@ -1934,6 +1984,7 @@ def _mixed_wave(params, cfg_tuple, cache_k, cache_v, pos, tokens, q_len,
     moe = _moe_of(cfg_tuple)
     blk = _block_of(cfg_tuple)
     mup = blk.mup
+    parallel = blk.residual == "parallel"
     B, Q = tokens.shape
     hdim = H * Dh
     Hkv = blk.kv_heads or H
@@ -2066,7 +2117,9 @@ def _mixed_wave(params, cfg_tuple, cache_k, cache_v, pos, tokens, q_len,
             pi = blk.op_index(i, "pool")
             ck, cv, tables, wb = cache_k, cache_v, block_tables, wblk_r
             live_i = live
-        with jax.named_scope("attn_qkv"):
+        # a parallel layer's ONE norm: the operator and the FFN both
+        # read its rows
+        with jax.named_scope("par_norm" if parallel else "attn_qkv"):
             x = _norm(blk, params, f"{us}_ln1", h)
         y_ssm = None
         if blk.op_kind(i) == "attention+ssm":
@@ -2162,7 +2215,15 @@ def _mixed_wave(params, cfg_tuple, cache_k, cache_v, pos, tokens, q_len,
             cache_k, cache_v = ck, cv
         with jax.named_scope("attn_out"):
             o = _proj(params, f"{us}_attn_proj", o, blk.bias)
-            h = h + (o if mup is None else o * mup.attention_out)
+            if mup is not None:
+                o = o * mup.attention_out
+            if not parallel:
+                h = h + o
+        if parallel:
+            # the FFN of the SAME normed rows; one addition
+            h = h + (o + _ffn_of_kind(params, us, blk, h, i, valid_r,
+                                      moe_stats, x=x))
+            continue
         if y_ssm is not None:
             h = h + y_ssm
         h = _ffn_of_kind(params, us, blk, h, i, valid_r, moe_stats, moe)

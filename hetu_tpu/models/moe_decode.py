@@ -260,12 +260,17 @@ class RoutedSpec(NamedTuple):
     whose FFN kind is "routed"; the capacity router's spec is
     ``MoESpec``, which holds every expert always).
     ``scoring`` "sigmoid": sigmoid scores, the ``top_k`` largest of
-    ``score + bias`` chosen; "softmax": a softmax over all the experts,
+    ``score + bias`` chosen (of the scores alone where the layer has no
+    ``_moe_router_bias`` leaf: the bias-free sigmoid router); "softmax":
+    a softmax over all the experts,
     the ``top_k`` largest chosen, no selection bias.  Either way the
     weights are the scores at the chosen, normalised when ``norm_topk``
     and scaled by ``scale``; ``n_shared`` shared experts' width is
     ``n_shared`` times an expert's (the leaves carry the width, so a
-    configuration whose shared width is its own key says 1).
+    configuration whose shared width is its own key says 1): the widened
+    expert is their SUM, and ``shared_scale`` multiplies it before it
+    joins the routed part (``1 / n_shared``: their average; 1.0 traces
+    nothing).
 
     What the layer is told beside that.  ``held`` / ``held_first``: the
     experts ``[held_first, held_first + held)`` are the ones this layer
@@ -290,6 +295,7 @@ class RoutedSpec(NamedTuple):
     held: int = 0
     latent: int = 0
     expert: str = "gated_silu"
+    shared_scale: float = 1.0
 
     @property
     def held_experts(self):
@@ -305,7 +311,9 @@ def route(x, w_router, bias, spec):
     """(chosen experts [T, k] int32, their weights [T, k] f32) for the
     rows ``x`` [T, D]: ``s = sigmoid(float32(x) W_g)``; the ``k`` largest
     of ``s + b`` are CHOSEN; the weights are ``s`` at the chosen,
-    normalised to sum 1 (``norm_topk``) and scaled.  With
+    normalised to sum 1 (``norm_topk``) and scaled.  A sigmoid router
+    with no selection bias hands ``bias`` None and the ``k`` largest of
+    ``s`` itself are chosen.  With
     ``spec.scoring`` "softmax" ``s`` is the softmax over all the experts
     and its ``k`` largest are chosen (``bias`` is not read: None)."""
     logits = jnp.dot(
@@ -316,7 +324,8 @@ def route(x, w_router, bias, spec):
         _, sel = jax.lax.top_k(s, spec.top_k)
     else:
         s = jax.nn.sigmoid(logits)
-        _, sel = jax.lax.top_k(s + bias.astype(jnp.float32), spec.top_k)
+        _, sel = jax.lax.top_k(
+            s if bias is None else s + bias.astype(jnp.float32), spec.top_k)
     # s at the chosen, by comparison and not by gather (a gather of
     # T x k scalars is 0.33 ms a layer at 8192 rows on a v5e)
     hit = sel[:, :, None] == jnp.arange(s.shape[1])[None, None, :]
@@ -405,7 +414,8 @@ def routed_ffn(params, us, x, spec, valid=None, stats=None):
     chosen experts is held gets the shared expert alone).  Nothing here
     stands in for the chips that hold the others.  Leaves:
     ``{us}_moe_router_weight`` [D, E], ``{us}_moe_router_bias`` [E] (the
-    selection bias; a softmax router has none),
+    selection bias; a softmax router has none, and a sigmoid router
+    without the leaf chooses by its scores alone),
     ``{us}_moe_experts_gate``/``_up`` [held, W, F],
     ``{us}_moe_experts_down`` [held, F, W] (``W`` the latent width, else
     D; a "relu2" expert has no gate), ``{us}_moe_latent_in_weight`` [D,
@@ -472,13 +482,16 @@ def routed_ffn(params, us, x, spec, valid=None, stats=None):
     if spec.n_shared:
         with jax.named_scope("moe_shared"):
             if relu2:
-                y = y + _relu2(x @ params[f"{us}_moe_shared_up_weight"]) \
+                sh = _relu2(x @ params[f"{us}_moe_shared_up_weight"]) \
                     @ params[f"{us}_moe_shared_down_weight"]
             else:
                 from .gpt_decode import swiglu
-                y = y + swiglu(x, params[f"{us}_moe_shared_gate_weight"],
-                               params[f"{us}_moe_shared_up_weight"],
-                               params[f"{us}_moe_shared_down_weight"])
+                sh = swiglu(x, params[f"{us}_moe_shared_gate_weight"],
+                            params[f"{us}_moe_shared_up_weight"],
+                            params[f"{us}_moe_shared_down_weight"])
+            if spec.shared_scale != 1.0:
+                sh = sh * spec.shared_scale
+            y = y + sh
     if stats is not None:
         stats["load"] = stats.get("load", 0) + load
         stats["touched"] = stats.get("touched", 0) + jnp.sum(load > 0)

@@ -568,7 +568,9 @@ class ServingEngine:
                              0).sum()),
                 int((grow * pos + grow * (grow + 1) // 2
                      + (ql - grow) * W).sum()),
-                recycled - self._window_recycled_seen)
+                recycled - self._window_recycled_seen,
+                # the rows at a position of ``W`` or more: the band binds
+                int((ql - grow).sum()))
             self._window_recycled_seen = recycled
         self.metrics.record_attention(
             ctx, pairs, window,

@@ -215,6 +215,7 @@ class ServingMetrics(MetricsCore):
         # an engine with window layers (``record_attention``)
         self.attn_window_ctx_tokens = 0
         self.attn_window_score_pairs = 0
+        self.attn_window_bound_rows = 0
         self.window_blocks_recycled = 0
         # an engine whose full layers read the rows an indexer chose
         # (``record_sparse``)
@@ -280,10 +281,13 @@ class ServingMetrics(MetricsCore):
         ``serve.attn.score_pairs`` in ``telemetry``.  An engine with
         window layers adds ``window`` = (the positions a WINDOW layer
         had in sight, the pairs it scored, the window-pool blocks the
-        wave's writes recycled): ``attn_window_ctx_tokens``,
-        ``attn_window_score_pairs``, ``window_blocks_recycled`` and the
+        wave's writes recycled, the live rows at a position of the
+        window or more: those the band binds on):
+        ``attn_window_ctx_tokens``, ``attn_window_score_pairs``,
+        ``window_blocks_recycled``, ``attn_window_bound_rows`` and the
         counters ``serve.attn.window_ctx_tokens``,
-        ``serve.attn.window_score_pairs`` (``serve.kv.
+        ``serve.attn.window_score_pairs``,
+        ``serve.attn.window_bound_rows`` (``serve.kv.
         window_blocks_recycled`` is the manager's own).  An engine whose
         waves run a hand-paged attention kernel adds ``tiles`` = (the
         wave's live (slot, q-tile) steps of one call of the kernel,
@@ -309,12 +313,14 @@ class ServingMetrics(MetricsCore):
             telemetry.inc("serve.attn.tiles_short", short)
             telemetry.inc("serve.attn.q_tiles_moved", moved)
         if window is not None:
-            ctx, pairs, recycled = (int(v) for v in window)
+            ctx, pairs, recycled, bound = (int(v) for v in window)
             self.attn_window_ctx_tokens += ctx
             self.attn_window_score_pairs += pairs
             self.window_blocks_recycled += recycled
+            self.attn_window_bound_rows += bound
             telemetry.inc("serve.attn.window_ctx_tokens", ctx)
             telemetry.inc("serve.attn.window_score_pairs", pairs)
+            telemetry.inc("serve.attn.window_bound_rows", bound)
 
     # ``record_sparse``'s running sums, in its arguments' order
     _SPARSE_COUNTS = ("sparse_rows", "sparse_rows_selecting",
@@ -711,6 +717,7 @@ class ServingMetrics(MetricsCore):
                     "attn_tiles_live", "attn_tiles_short",
                     "attn_q_tiles_moved",
                     "attn_window_ctx_tokens", "attn_window_score_pairs",
+                    "attn_window_bound_rows",
                     "window_blocks_recycled", *_SPARSE_COUNTS,
                     "ssm_slot_steps", "ssm_rows", "ssm_chunk_pairs",
                     "ssm_kernel_slot_steps",
@@ -799,6 +806,7 @@ class ServingMetrics(MetricsCore):
             "attn_q_tiles_moved": count("attn_q_tiles_moved"),
             "attn_window_ctx_tokens": count("attn_window_ctx_tokens"),
             "attn_window_score_pairs": count("attn_window_score_pairs"),
+            "attn_window_bound_rows": count("attn_window_bound_rows"),
             "window_blocks_recycled": count("window_blocks_recycled"),
             **{key: count(key) for key in self._SPARSE_COUNTS},
             "ssm_slot_steps": count("ssm_slot_steps"),

@@ -344,8 +344,9 @@ def test_the_cell_reports_the_metric_and_its_files_are_there(name):
     if name in NEW_METRICS:
         assert entry["workloads"] == [CELL]
     else:
-        # appended after the cells accepted before it
-        assert entry["workloads"][-1] == CELL and len(entry["workloads"]) > 1
+        # appended after the cells accepted before it (a later PR's
+        # cell may follow)
+        assert entry["workloads"].index(CELL) >= 1
     spec = bench_run.load_json(os.path.join(
         ROOT, "benchmarks", "metrics", name + ".json"))
     assert os.path.isfile(os.path.join(
@@ -356,7 +357,7 @@ def test_the_cell_is_one_chip_and_the_old_entries_stand():
     cell = next(w for w in BENCH["workloads"] if w["name"] == CELL)
     assert cell == dict(cell, config=CONFIG, traffic="notes-closed", chips=1)
     assert len(cell["why"]) <= 200
-    assert [w["name"] for w in BENCH["workloads"]] == [
+    assert [w["name"] for w in BENCH["workloads"]][:9] == [
         "train-gpt2-medium-s1024", "serve-gpt2-xl-batch-closed",
         "serve-glm47flash-reason-closed", "serve-lfm2-8b-a1b-rag-closed",
         "serve-falcon-h1-34b-chat-closed", "serve-mellum2-12b-code-closed",

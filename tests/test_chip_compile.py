@@ -1152,3 +1152,89 @@ def test_sparse_latent_chunk_program_at_the_published_widths(sds,
     if not kinds:
         stated = conf["memory_analysis"][f"slots_{B}_Q_{Q}_pool_{N}"]
         assert abs(peak / 1e9 - stated["peak_GB"]) < 0.3
+
+
+# ------------------------------------------------------------------- #
+# ISSUE 55: the parallel block at the grounded cell's sizes
+# ------------------------------------------------------------------- #
+
+@pytest.mark.parametrize("q_len,kinds", [
+    (256, ["sliding_attention", "full_attention"]),
+    pytest.param(1, ["sliding_attention", "full_attention"],
+                 marks=pytest.mark.slow),
+    pytest.param(256, None, marks=pytest.mark.slow),
+    pytest.param(1, None, marks=pytest.mark.slow)],
+    ids=["Q256-one_of_each", "Q1-one_of_each", "Q256-period", "Q1-period"])
+def test_parallel_moe_wave_programs_at_the_published_widths(sds, monkeypatch,
+                                                            q_len, kinds):
+    """The grounded cell's two programs (its prompts are multiples of
+    the chunk: ONE chunk program, Q 256, beside the decode program) at
+    the published widths, 32 slots and the cell's pools through
+    ``serve_mixed_paged_fn``: a sliding layer beside a full one, or
+    ``slow`` the whole period of four.  128 query heads over 8 K/V heads
+    of 128 (group 16, a query row of 16,384 lanes, a pool row of 1,024):
+    ``ragged_paged_window`` once a sliding layer over a ring of 273
+    blocks a slot, ``ragged_paged_mixed`` once a full layer, the 16 held
+    experts through ``moe_grouped_matmul`` in BOTH programs (a decode
+    wave's 256 assignment rows are two row tiles), both pool pairs
+    updated in place.  The configuration's ``memory_analysis`` states
+    the period's compiles."""
+    import json
+    import os
+    from hetu_tpu.kernels import grouped_matmul as gm
+    from hetu_tpu.kernels import ragged_attention as ra
+    from hetu_tpu.models.parallel_moe import ParallelMoEConfig
+    for module in (ra, gm):
+        monkeypatch.setattr(module, "_use_interpret", lambda: False)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "configs",
+                           "command-a-plus-05-2026.json")) as f:
+        conf = json.load(f)
+    args, dep, pub = (conf["runner_args"], conf["deployment"],
+                      conf["published"])
+    kinds = kinds or conf["layer_types"]
+    cfg = ParallelMoEConfig.from_hf(
+        dict(conf, num_experts=pub["num_experts"],
+             vocab_size=pub["vocab_size"], layer_types=kinds,
+             num_hidden_layers=len(kinds)),
+        held_experts=tuple(dep["experts_held"]),
+        vocab_rows=tuple(dep["vocab_rows_held"]))
+    blk = cfg.block_spec()
+    full = kinds.count("full_attention")
+    sliding = len(kinds) - full
+    L, B, S = len(kinds), args["slots"], args["max_seq_len"]
+    T, N = S // BLOCK, args["pool_blocks"]
+    W = kv_row_width(8, 128)
+    assert W == 1024
+    assert ra.rows_packed_tiling(1024, 128, 128, 16, jnp.bfloat16) == (
+        1024, 16, 0)
+    params = {k: sds(s, jnp.float32 if "_moe_router_" in k else jnp.bfloat16)
+              for k, s in cfg.param_shapes("cmd").items()}
+    pool = sds((full, N, BLOCK, W), jnp.bfloat16)
+    ring = -(-(blk.window + args["prefill_chunk"]) // BLOCK) + 1
+    assert ring == 273
+    win = (sds((sliding, B * ring + 1, BLOCK, W), jnp.bfloat16),) * 2
+    i32 = lambda *shape: sds(shape, jnp.int32)  # noqa: E731
+    fn = gd.serve_mixed_paged_fn(donate=True, attn="ragged", window=1)
+    compiled = fn.func.lower(
+        params, ("cmd", L, 128, 128, S, blk), pool, pool, i32(B, T), i32(B),
+        i32(B, q_len), i32(B), i32(B), sds((B,), jnp.bool_),
+        sds((B,), jnp.float32), i32(B), sds((B, 2), jnp.uint32),
+        attn="ragged", window=1, has_fresh=q_len > 1, win=win,
+        ring=i32(B, ring)).compile()
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines() if "custom-call(" in line]
+    assert sum("ragged_paged_window" in c for c in calls) == sliding
+    assert sum("ragged_paged_mixed" in c for c in calls) == full
+    assert sum("moe_grouped_matmul" in c for c in calls) == 2 * L
+    assert "ragged-dot" not in text
+    mem = compiled.memory_analysis()
+    pools = 2 * 2 * BLOCK * W * (full * N + sliding * (B * ring + 1))
+    assert mem.alias_size_in_bytes >= pools
+    assert mem.temp_size_in_bytes < 0.5e9
+    peak = mem.argument_size_in_bytes + mem.output_size_in_bytes \
+        - mem.alias_size_in_bytes + mem.temp_size_in_bytes
+    assert peak < 13.0e9
+    if L == conf["num_hidden_layers"]:
+        stated = conf["memory_analysis"][f"slots_{B}_Q_{q_len}"]
+        assert abs(peak / 1e9 - stated["peak_GB"]) < 0.3

@@ -33,6 +33,7 @@ from test_chip_compile import (  # noqa: E402,F401
     no_compile_cache, sds, strip_kernel_locations, topo)
 from test_hybrid_moe import digest, wave_programs
 from test_nemotron_h import nemotron_programs
+from test_parallel_moe import parallel_moe_programs
 from test_retention import retention_programs, window_programs
 from test_sparse_latent import sparse_latent_programs
 from test_window_moe import falcon_case, hybrid_programs, lower_cases
@@ -86,6 +87,14 @@ FAMILIES = {
         lambda s: sparse_latent_programs(s, "masked"), False),
     "SPARSE_LATENT_RAGGED": (
         lambda s: sparse_latent_programs(s, "ragged"), True),
+    "PARALLEL_MOE_MASKED": (
+        lambda s: parallel_moe_programs(s, "masked"), False),
+    "PARALLEL_MOE_RAGGED": (
+        lambda s: parallel_moe_programs(s, "ragged"), True),
+    "PARALLEL_MOE_PACKED_RAGGED": (
+        lambda s: {k: low for k, low in parallel_moe_programs(
+            s, "ragged", qs=(32,), slots=16).items()
+            if k.endswith("fresh1")}, True),
 }
 
 PARENT = {
@@ -271,6 +280,28 @@ PARENT = {
         "sparse_latent.Q1.fresh1": "47efd75d5a24e418",
         "sparse_latent.Q32.fresh0": "984b1ff568454a30",
         "sparse_latent.Q32.fresh1": "984b1ff568454a30"},
+    # PR 55's own, no parent's: tests/test_parallel_moe.py's small period
+    # of four (the ``cohere2_moe`` family: a PARALLEL block on one
+    # bias-free LayerNorm under the scope ``par_norm``, three sliding
+    # layers rotated over a ring and one full layer that rotates
+    # nothing, 32 query heads over 2: group 16, a bias-free sigmoid
+    # router over 2 of 8 experts held, four shared experts averaged, a
+    # tied head).  4 slots x 32 rows stay padded and take the dense entry
+    # of the rows kernel; 16 slots x 32 are packed into 256 rows and take
+    # its packed entry (``ragged_paged_attention_rows``); the Q 1 pair's
+    # 12 sorted rows keep ``ragged_dot``.
+    "PARALLEL_MOE_MASKED": {
+        "parallel_moe.Q1.fresh0": "53e460fb51d02684",
+        "parallel_moe.Q1.fresh1": "4246d5f557aa5890",
+        "parallel_moe.Q32.fresh0": "b116b5a760b749bb",
+        "parallel_moe.Q32.fresh1": "fcb5ecd9d4365e95"},
+    "PARALLEL_MOE_RAGGED": {
+        "parallel_moe.Q1.fresh0": "d72b430cfe086633",
+        "parallel_moe.Q1.fresh1": "d72b430cfe086633",
+        "parallel_moe.Q32.fresh0": "525e260c074e3c7d",
+        "parallel_moe.Q32.fresh1": "525e260c074e3c7d"},
+    "PARALLEL_MOE_PACKED_RAGGED": {
+        "parallel_moe.Q32.fresh1": "7c048e6a6959d14d"},
 }
 
 
