@@ -61,8 +61,10 @@ NEG_INF = -1e30
 #
 # The PAGED pool of a float dtype holds ROWS, ``[L, N_blocks, block, W]``
 # (``kv_layout``: heads side by side, padded to the lane tile, the
-# layout the mixed ragged kernel reads in place): writes go through
-# ``_kv_scatter``, which lays a ``[.., H, Dh]`` slab out as rows, and
+# layout the mixed ragged kernel reads in place): a q-block narrower
+# than a page is written through ``_kv_scatter``, which lays a ``[.., H,
+# Dh]`` slab out as rows, a wider one as pages
+# (``kernels/paged_kv_write.py``), and
 # every reader but that kernel takes ``_kv_layer``'s ``[.., H, Dh]`` view.
 # The contiguous cache ``[L, B, S_max, H, Dh]`` and the int8 pool keep
 # their head axes.
@@ -91,43 +93,6 @@ def _kv_scatter(cache, idx, val):
     if cache.ndim == 4:                # the paged pool's rows
         val = kv_rows(val, cache.shape[-1])
     return cache.at[idx].set(val.astype(cache.dtype))
-
-
-@jax.jit
-def _kv_write_pages(cache, i, val, pos, q_len, block_tables):
-    """The paged float pool's write of a WIDE q-block, a page at a time:
-    slot b's ``val[b, :q_len[b]]`` ([B, Q, H, Dh]) lands at positions
-    ``pos[b] ..`` of layer ``i``, as ``_kv_scatter`` at ``(i, wblk,
-    woff)`` leaves it, but each of the ``ceil(Q / block) + 1`` pages a
-    q-block can touch is read, overlaid with the rows that fall in it
-    and written back WHOLE; pages with no live row are dropped (an
-    out-of-bounds index), so dead rows go nowhere instead of to scratch
-    block 0.  Why: a position is one row of a (16, 128) tile, and the
-    TPU runs a scatter an update at a time whatever it writes, dead
-    rows too: 16 x 256 rows cost 0.70 ms a pool a layer as rows, 0.12
-    as 272 pages (64 rows: 0.18 | 0.05; ONE row: 0.023 | 0.033, so a
-    narrow q-block keeps the row scatter; my chip run, PR 31).  Jitted
-    with ``i`` traced, so a model's layers share one trace and one
-    lowering a program (as ``ragged_attention._paged_rows_call``)."""
-    B, Q = val.shape[:2]
-    N, bs, W = cache.shape[1:]
-    K = -(-Q // bs) + 1
-    rows = kv_rows(val, W).astype(cache.dtype)
-    # shifted[b, m] = rows[b, m - pos[b] % bs]: the q-block laid over
-    # whole pages from the one its first row falls in
-    start = pos % bs
-    padded = jnp.pad(rows, ((0, 0), (bs, K * bs - Q), (0, 0)))
-    shifted = jax.vmap(
-        lambda x, at: jax.lax.dynamic_slice_in_dim(x, at, K * bs, 0))(
-            padded, bs - start)
-    j = jnp.arange(K * bs)[None, :] - start[:, None]       # q-block row
-    live = ((j >= 0) & (j < q_len[:, None])).reshape(B, K, bs)
-    page = pos[:, None] // bs + jnp.arange(K)[None, :]
-    blk = block_tables[jnp.arange(B)[:, None],
-                       jnp.clip(page, 0, block_tables.shape[1] - 1)]
-    new = jnp.where(live[..., None], shifted.reshape(B, K, bs, W),
-                    cache[i, blk])
-    return cache.at[i, jnp.where(live.any(-1), blk, N)].set(new)
 
 
 def _kv_layer(cache, i, H, Dh):
@@ -213,6 +178,18 @@ def wave_rows(cfg_tuple, slots, window, q, has_fresh=True):
         return dense
     return min(dense, max(_PACKED_ROWS_FLOOR,
                           _pow2(slots * window + 2 * q)))
+
+
+def writes_pages(blk, quant, q, block):
+    """Whether a wave of q-blocks ``q`` wide writes its K/V rows as
+    PAGES (``kernels/paged_kv_write``) and not as rows (``_kv_scatter``):
+    a q-block a page or more wide over a float K/V pool.  The one place
+    that says it, read by ``_mixed_wave`` and by the engine's count of
+    the pages written.  (PR 31 measured one row a slot at 0.023 ms as
+    rows against 0.033 as pages; the int8 pool's scale planes have no
+    page a kernel can copy; the latent pool's rows are
+    ``_latent_attention``'s own to write.)"""
+    return not quant and q >= block and blk.attention != "latent"
 
 
 class _Rows(NamedTuple):
@@ -1861,17 +1838,20 @@ def _mixed_step(params, cfg_tuple, cache_k, cache_v, pos, tokens,
     embedding, norms, projections, per-head norm and RoPE, the latent
     projections and absorbs, the conv operator, every FFN and the
     router (``T x k`` assignments of R rows, not of B x Q).  Only what
-    needs a slot's rows as a block unpacks to ``[B, Q]``: k and v for
-    the page write (``_kv_write_pages``), and, off the float pool's
-    kernels, q for the masked path's scoring and fresh-self softmax and
-    for the int8 pool's kernel (``_ragged_paged_blocked``), whose result
-    is packed back.  The float pool's kernels take the packed query rows
-    AS THEY LIE and hand their result back so (the K/V rows kernel's
-    ``ragged_paged_attention_rows``, ISSUE 54; the latent kernel's
-    ``ragged_paged_mla_rows``, ISSUE 46): no q-block of the query or of
-    the result is there.  ``_window_logits`` gathers the windows
-    straight from the packed rows.  Where R is ``B x Q`` packing is the
-    identity and is skipped.
+    needs a slot's rows as a block unpacks to ``[B, Q]``, and only off
+    the float pool's kernels: q, k and v for the masked path's scoring
+    and fresh-self softmax, q for the int8 pool's kernel
+    (``_ragged_paged_blocked``), whose result is packed back.  The float
+    pool's kernels take the packed rows AS THEY LIE: the page write
+    (``paged_kv_write`` over ``touched_pages``, ISSUE 56: the pages the
+    live rows touch, K and V in one call a layer; a q-block narrower
+    than a page, and the int8 pool, keep ``_kv_scatter``'s row scatter,
+    of the rows as they lie too), and the query rows, whose result comes
+    back so (the K/V rows kernel's ``ragged_paged_attention_rows``,
+    ISSUE 54; the latent kernel's ``ragged_paged_mla_rows``, ISSUE 46):
+    no q-block of the query, of K, of V or of the result is there.
+    ``_window_logits`` gathers the windows straight from the packed
+    rows.  Where R is ``B x Q`` packing is the identity and is skipped.
 
     The masked path's DEFAULT attention is ``_verify_step``'s full
     causal mask over the just-written cache, bit for bit — so decode
@@ -2039,6 +2019,19 @@ def _mixed_wave(params, cfg_tuple, cache_k, cache_v, pos, tokens, q_len,
                                win_tables[bidx[:, None], posc // bs_blk], 0)
             if rows is not None:
                 wblk_w = jnp.where(valid_r, rows.pack(wblk_w), 0)
+        # a q-block a page or more wide is written to the float pool as
+        # the PAGES its live rows touch, from the rows as they lie (a
+        # padded wave's slot b at row ``b Q``): the list once a table
+        wide_write = writes_pages(blk, _kv_q(cache_k), Q, bs_blk)
+        if wide_write:
+            from ..kernels.paged_kv_write import paged_kv_write, touched_pages
+            with jax.named_scope("kv_write"):
+                row0 = bidx * Q if rows is None else rows.start
+                touched = touched_pages(pos, q_len, row0, block_tables,
+                                        bs_blk, Br * Qr, Q)
+                if win is not None:
+                    touched_w = touched_pages(pos, q_len, row0, win_tables,
+                                              bs_blk, Br * Qr, Q)
         ctx = jnp.arange(span)[None, None, :]
         live = ctx <= posns[:, :, None]                    # [B, Q, S]
         # fresh-self variant: context strictly below the write window
@@ -2130,28 +2123,29 @@ def _mixed_wave(params, cfg_tuple, cache_k, cache_v, pos, tokens, q_len,
                                      blk.op_index(i, "state"), q_len, rows)
         with jax.named_scope("attn_qkv"):
             q, k, v = _qkv_heads(params, us, blk, i, x, H, Hkv, Dh, posns_r)
-        k_r, v_r = k, v
+        with jax.named_scope("kv_write"):
+            # both writes take the rows as they lie
+            if wide_write:
+                W = ck.shape[-1]
+                ck, cv = paged_kv_write(
+                    ck, cv, pi, kv_rows(k, W).reshape(-1, W),
+                    kv_rows(v, W).reshape(-1, W),
+                    touched_w if windowed else touched)
+            else:
+                ck = _kv_scatter(ck, (pi, wb, woff_r), k)
+                cv = _kv_scatter(cv, (pi, wb, woff_r), v)
         # the float pool's kernel takes a packed wave's query rows as
         # they lie and hands its result back so
         # (``ragged_paged_attention_rows``)
         as_rows = attn == "ragged" and not quant and rows is not None
-        if rows is not None:
-            # the page write takes a slot's rows as a block, and so do
-            # the masked path's scoring and fresh-self softmax and the
-            # int8 pool's kernel
-            if not as_rows:
-                with jax.named_scope("attention"):
-                    q = rows.unpack(q)
-            with jax.named_scope("kv_write"):
-                k, v = rows.unpack(k), rows.unpack(v)
-        with jax.named_scope("kv_write"):
-            if not quant and Q >= bs_blk:
-                # a q-block a page or more wide: whole pages
-                ck = _kv_write_pages(ck, pi, k, pos, q_len, tables)
-                cv = _kv_write_pages(cv, pi, v, pos, q_len, tables)
-            else:
-                ck = _kv_scatter(ck, (pi, wb, woff_r), k_r)
-                cv = _kv_scatter(cv, (pi, wb, woff_r), v_r)
+        if rows is not None and not as_rows:
+            # a slot's rows as a block: what the masked path's scoring
+            # and fresh-self softmax and the int8 pool's kernel take
+            with jax.named_scope("attention"):
+                q = rows.unpack(q)
+                if attn != "ragged":
+                    # (the chunk's own fresh K/V)
+                    k, v = rows.unpack(k), rows.unpack(v)
         with jax.named_scope("attention"):
             if as_rows:
                 o = ragged_paged_attention_rows(

@@ -46,7 +46,7 @@ from ..models.gpt_decode import (
     GPT2_BLOCK, block_spec_of, check_block_spec, head_dim_of,
     _infer_name, _prep_param, _pow2, _resolve_fast, resolve_draft_layers,
     resolve_spec_k, serve_mixed_paged_fn, serve_prefill_fn,
-    spec_propose_fn, wave_rows,
+    spec_propose_fn, wave_rows, writes_pages,
 )
 from ..kernels.ragged_attention import (
     mla_rows_tiling, mla_tiling, row_tile_visits, rows_packed_tiling,
@@ -545,7 +545,9 @@ class ServingEngine:
         program ran over: whether it was packed).  Slot b's ``q_len``
         rows at positions ``pos .. pos
         + q_len - 1`` see ``pos + j + 1`` positions each, and the slot
-        holds ``pos + q_len`` positions after the wave's writes; a live
+        holds ``pos + q_len`` positions after the wave's writes (and, a
+        q-block a page or more wide, lies in the pages
+        ``record_kv_write`` counts); a live
         slot's state moves once a state-space layer.  An engine with
         window layers counts what those really read beside it
         (``record_attention``'s ``window``)."""
@@ -576,6 +578,14 @@ class ServingEngine:
             ctx, pairs, window,
             self._attn_tiles(ql, int(wave["q"]), rows_computed)
             if attends else None)
+        bs = self.kv.block
+        if attends and writes_pages(self.block_spec, self.kv_quant,
+                                    int(wave["q"]), bs):
+            # the float K/V pool's wide write: the pages the live rows
+            # touch (``paged_kv_write.touched_pages``'s count)
+            self.metrics.record_kv_write(
+                int(np.where(ql > 0, (pos + ql - 1) // bs - pos // bs + 1,
+                             0).sum()), int(ql.sum()))
         if self._index_layers:
             # a row reads ``min(pos + j + 1, K)`` cached rows: the first
             # ``K - pos`` rows of a q-block (if any) everything they see

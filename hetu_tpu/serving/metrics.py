@@ -234,6 +234,9 @@ class ServingMetrics(MetricsCore):
         self.chunks_deferred = 0
         self.waves_ahead = 0
         self.rows_dead_ahead = 0
+        # the float pool's wide write (``record_kv_write``)
+        self.kv_write_pages = 0
+        self.kv_write_rows = 0
 
     def _make_lc(self, t_submit):
         return _Lifecycle(t_submit)
@@ -269,6 +272,20 @@ class ServingMetrics(MetricsCore):
             telemetry.inc("serve.wave.ahead")
         if rows_dead:
             telemetry.inc("serve.wave.rows_dead_ahead", int(rows_dead))
+
+    def record_kv_write(self, pages, rows):
+        """One wave whose K/V rows went to the float pool as PAGES
+        (``kernels/paged_kv_write``: a q-block a page or more wide):
+        ``pages``, the pages the wave's live rows touch under one table
+        (one call's steps: a layer's, of either pool), and ``rows``, the
+        live rows they hold.  Rows over pages x the block is how full
+        the pages written are; pages a wave how much the write has to
+        do.  ``kv_write_pages`` / ``kv_write_rows`` here and the counters
+        ``serve.kv.write_pages`` / ``serve.kv.write_rows``."""
+        self.kv_write_pages += int(pages)
+        self.kv_write_rows += int(rows)
+        telemetry.inc("serve.kv.write_pages", int(pages))
+        telemetry.inc("serve.kv.write_rows", int(rows))
 
     def record_attention(self, ctx_tokens, score_pairs, window=None,
                          tiles=None):
@@ -724,7 +741,8 @@ class ServingMetrics(MetricsCore):
                     "ret_slot_steps", "ret_rows", "ret_chunk_pairs",
                     "ret_kernel_slot_steps",
                     "wave_rows_live", "wave_rows_computed",
-                    "chunks_deferred", "waves_ahead", "rows_dead_ahead")
+                    "chunks_deferred", "waves_ahead", "rows_dead_ahead",
+                    "kv_write_pages", "kv_write_rows")
 
     def mark(self):
         """A position in this engine's history for ``snapshot(since=)``:
@@ -822,6 +840,8 @@ class ServingMetrics(MetricsCore):
             "chunks_deferred": count("chunks_deferred"),
             "waves_ahead": count("waves_ahead"),
             "rows_dead_ahead": count("rows_dead_ahead"),
+            "kv_write_pages": count("kv_write_pages"),
+            "kv_write_rows": count("kv_write_rows"),
             "requests_submitted": count("submitted"),
             "requests_rejected": count("rejected"),
             "requests_finished": count("finished"),

@@ -184,6 +184,85 @@ def test_ragged_paged_rows_at_the_cell_sizes(sds, heads, q_len):
     assert "ragged_paged_mixed" in text
 
 
+# the chunk wave's write at ISSUE 56's probe shapes: (slots, row width,
+# pages a table, blocks of the pool, layers, blocks of the ring pool)
+WRITES = {"gpt2-xl": (16, 1664, 64, 449, 48, 0),
+          "mellum2-code": (32, 1024, 1024, 3001, 3, 2593),
+          "falcon-chat": (64, 512, 64, 2049, 2, 0)}
+
+
+@pytest.mark.parametrize("cell", list(WRITES))
+def test_paged_kv_write_at_the_cells_sizes(sds, cell):
+    """ISSUE 56: the float pool's wide write as ONE call a layer for K
+    and V, the pool pair left in HBM and aliased to the results, the
+    layer traced, the 1,024 packed rows of a 256-row bucket as they lie
+    (32 bits a lane, a window of 24 rows turned by a dynamic sublane
+    roll: Mosaic refuses a 16-row load at an offset it cannot prove a
+    multiple of 8).  A window layer's call takes the ring pool under the
+    ring repeated.  No copy of a pool: what the program holds beside
+    the donated pools is the rows."""
+    from hetu_tpu.kernels.paged_kv_write import paged_kv_write, touched_pages
+    B, W, T, N, L, ring = WRITES[cell]
+    R, Q = 1024, 256
+    pools = [sds((L, N, BLOCK, W), jnp.bfloat16)] * 2
+    if ring:
+        pools += [sds((9, ring, BLOCK, W), jnp.bfloat16)] * 2
+    rows = sds((R, W), jnp.bfloat16)
+    i32 = lambda *shape: sds(shape, jnp.int32)  # noqa: E731
+
+    def write(pools, k, v, pos, q_len, start, tables, rings, layer):
+        tabs = [tables]
+        if ring:
+            tabs.append(rings[:, jnp.arange(T) % rings.shape[1]])
+        out = []
+        for n, t in enumerate(tabs):
+            ck, cv = pools[2 * n:2 * n + 2]
+            touched = touched_pages(pos, q_len, start, t, BLOCK, R, Q)
+            for i in range(2):      # two layers: one lowering
+                ck, cv = paged_kv_write(ck, cv, layer + i, k, v, touched,
+                                        interpret=False)
+            out += [ck, cv]
+        return out
+
+    compiled = jax.jit(write, donate_argnums=(0,)).lower(
+        pools, rows, rows, i32(B), i32(B), i32(B), i32(B, T),
+        i32(B, (ring - 1) // B if ring else 1), i32()).compile()
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines()
+             if "custom-call(" in line and "paged_kv_write" in line]
+    assert len(calls) == (4 if ring else 2)
+    assert all("tpu_custom_call" in c for c in calls)
+    _no_copy_of_a_pool(compiled, pools)
+    # beside the pools: the rows, 32 bits a lane (2 x 7 MB at 1,664)
+    assert compiled.memory_analysis().temp_size_in_bytes < 32 << 20
+
+
+def _one_page_write_a_kv_layer(compiled, layers):
+    """A program a page or more wide writes each of its ``layers`` K/V
+    layers through ONE Mosaic call named ``paged_kv_write`` (ISSUE 56);
+    a narrower one (``layers`` 0) scatters rows and names none.  The
+    caller steers ``paged_kv_write._use_interpret`` as it does the
+    attention kernel's, or the write it compiles is the interpreted
+    one, which the chip never runs."""
+    writes = [line for line in compiled.as_text().splitlines()
+              if "custom-call(" in line and "paged_kv_write" in line]
+    assert len(writes) == layers
+    assert all("tpu_custom_call" in c for c in writes)
+
+
+def _no_copy_of_a_pool(compiled, pools):
+    """The donated ``pools`` are rewritten where they lie: aliased, and
+    no ``copy`` makes one of their shape."""
+    shapes = {f"bf16[{','.join(map(str, p.shape))}]" for p in pools}
+    copies = [line for line in compiled.as_text().splitlines()
+              if " copy(" in line and any(
+                  line.split(" copy(")[0].split("= ")[-1].startswith(sh)
+                  for sh in shapes)]
+    assert not copies, copies[:2]
+    held = sum(int(np.prod(p.shape)) * p.dtype.itemsize for p in pools)
+    assert compiled.memory_analysis().alias_size_in_bytes >= held
+
+
 def _gpt_shapes(sds, name, layers, hidden, vocab, positions):
     """The parameter dict of a GPT-2 of these sizes, as bf16 shapes."""
     def w(*shape):
@@ -216,10 +295,12 @@ def test_mixed_step_reads_the_donated_pool_in_place(sds, monkeypatch,
     Rows of whole lane tiles are aliased in place: what is left does
     not grow with the pool (a small vocabulary here, so that the head's
     transposed table, 0.16 GB at 50257, is not what is measured)."""
+    from hetu_tpu.kernels import paged_kv_write as pw
     from hetu_tpu.kernels import ragged_attention as ra
     # the step asks the backend, which is the CPU here: steer it in the
-    # test, or the kernel is interpreted and nothing is proven
-    monkeypatch.setattr(ra, "_use_interpret", lambda: False)
+    # test, or the kernels are interpreted and nothing is proven
+    for module in (ra, pw):
+        monkeypatch.setattr(module, "_use_interpret", lambda: False)
     L, H, B = 8, 25, CELL["slots"]
     W = kv_row_width(H, DH)
     pool = sds((L, CELL["blocks"], BLOCK, W), jnp.bfloat16)
@@ -237,6 +318,7 @@ def test_mixed_step_reads_the_donated_pool_in_place(sds, monkeypatch,
     # each call takes the WHOLE pool pair, no layer sliced out of it
     assert all(c.count(f"bf16[{L},{CELL['blocks']},{BLOCK},{W}]") >= 2
                for c in calls)
+    _one_page_write_a_kv_layer(compiled, L if q_len >= BLOCK else 0)
     mem = compiled.memory_analysis()
     pool_bytes = 2 * L * CELL["blocks"] * BLOCK * W * 2
     assert mem.alias_size_in_bytes >= pool_bytes      # donated, aliased
@@ -423,9 +505,11 @@ def test_hybrid_mixed_step_updates_pool_and_state_in_place(sds, monkeypatch,
     aliased, temporaries that do not grow with the pool."""
     import json
     import os
+    from hetu_tpu.kernels import paged_kv_write as pw
     from hetu_tpu.kernels import ragged_attention as ra
     from hetu_tpu.models.moe_decode import HybridMoEConfig
-    monkeypatch.setattr(ra, "_use_interpret", lambda: False)
+    for module in (ra, pw):
+        monkeypatch.setattr(module, "_use_interpret", lambda: False)
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     with open(os.path.join(root, "benchmarks", "configs",
                            "lfm2-8b-a1b.json")) as f:
@@ -451,6 +535,7 @@ def test_hybrid_mixed_step_updates_pool_and_state_in_place(sds, monkeypatch,
              if "custom-call(" in line and "ragged_paged_mixed" in line]
     assert len(calls) == 1 and "tpu_custom_call" in calls[0]
     assert calls[0].count(f"bf16[1,{LFM['blocks']},{BLOCK},512]") >= 2
+    _one_page_write_a_kv_layer(compiled, 1 if q_len >= BLOCK else 0)
     mem = compiled.memory_analysis()
     pool_bytes = 2 * LFM["blocks"] * BLOCK * 512 * 2
     state_bytes = 2 * B * 2 * cfg.hidden_size * 2
@@ -570,17 +655,20 @@ def test_packed_chunk_wave_at_the_cells_sizes(sds, monkeypatch, cell,
     the kernels are the ones the padded program calls, handed the PACKED
     query rows as they lie (the latent kernel since ISSUE 46, the K/V
     rows kernel since ISSUE 54: the program holds no block of the query
-    or of the result that is slots x 256 rows; k and v still unpack for
-    the page write), the pool (and the conv state) are still updated in
-    place, and the compiler's peak is no higher than the padded
-    program's.  What is read off the padded program costs a second
+    or of the result that is slots x 256 rows; and since ISSUE 56 none
+    of K or V either: the page write, ``paged_kv_write``, one call a
+    K/V layer, takes the packed rows too), the pool (and the conv
+    state) are still updated in place with no copy of a pool, and the
+    compiler's peak is no higher than the padded program's.  What is
+    read off the padded program costs a second
     compile of the same wave and is ``slow`` (``against-padded``: there
     the LFM2 cell's two programs have its own vocabulary of 65,536), as
     are the code and short-chat cells' waves."""
     from hetu_tpu.kernels import grouped_matmul as gm
+    from hetu_tpu.kernels import paged_kv_write as pw
     from hetu_tpu.kernels import ragged_attention as ra
     from hetu_tpu.kernels import ssm_step as ss
-    for module in (ra, gm, ss):
+    for module in (ra, gm, ss, pw):
         monkeypatch.setattr(module, "_use_interpret", lambda: False)
     params, cfg_tuple, pk, pv, state, B, T, kernel, n_calls, *more = \
         _chunk_wave_case(sds, cell, own_vocab=against_padded)
@@ -624,13 +712,21 @@ def test_packed_chunk_wave_at_the_cells_sizes(sds, monkeypatch, cell,
         G = H // (cfg_tuple[5].kv_heads or H) if len(cfg_tuple) > 5 else 1
         assert {shape_of(c) for c in calls} == {
             f"bf16[{1024 * G},{pk.shape[3]}]"}
-        # nothing under the attention's scope is a q-block (the page
-        # write's k and v unpack still, under ``kv_write``)
-        scored = [line for line in text.splitlines()
-                  if "/attention/" in line]
-        assert scored and not any(f"[{B},{Q}," in line or
-                                  f"[{B},{Q * G}," in line
-                                  for line in scored)
+        # nothing under the attention's scope or the page write's is a
+        # q-block: both take the packed rows as they lie
+        for scope in ("/attention/", "/kv_write/"):
+            under = [line for line in text.splitlines() if scope in line]
+            assert under and not any(f"[{B},{Q}," in line or
+                                     f"[{B},{Q * G}," in line
+                                     for line in under)
+        # the write is one call a K/V layer (a window layer's beside a
+        # full layer's), the pool pair whole
+        attends = [line for line in text.splitlines()
+                   if "custom-call(" in line and (
+                       "ragged_paged_mixed" in line
+                       or "ragged_paged_window" in line)]
+        assert attends
+        _one_page_write_a_kv_layer(packed, len(attends))
     # 4,096 assignment rows over 64 or 32 experts: the routed products
     # are the chunk wave's own kernel (ISSUE 41), not the compiler's
     assert ("moe_grouped_matmul" in text) == (
@@ -650,6 +746,7 @@ def test_packed_chunk_wave_at_the_cells_sizes(sds, monkeypatch, cell,
                   for a in pools + held)
     mem = packed.memory_analysis()
     assert mem.alias_size_in_bytes >= donated
+    _no_copy_of_a_pool(packed, pools)
     if not against_padded:
         return
     with monkeypatch.context() as m:
@@ -980,10 +1077,11 @@ def test_nemotron_wave_programs_at_the_published_widths(sds, monkeypatch,
     import json
     import os
     from hetu_tpu.kernels import grouped_matmul as gm
+    from hetu_tpu.kernels import paged_kv_write as pw
     from hetu_tpu.kernels import ragged_attention as ra
     from hetu_tpu.kernels import ssm_step as ss
     from hetu_tpu.models import nemotron_h as nh
-    for module in (ra, gm, ss):
+    for module in (ra, gm, ss, pw):
         monkeypatch.setattr(module, "_use_interpret", lambda: False)
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     with open(os.path.join(root, "benchmarks", "configs",
@@ -1020,6 +1118,7 @@ def test_nemotron_wave_programs_at_the_published_widths(sds, monkeypatch,
     text = compiled.as_text()
     calls = [line for line in text.splitlines() if "custom-call(" in line]
     assert sum("ragged_paged_mixed" in c for c in calls) == 1
+    _one_page_write_a_kv_layer(compiled, 1 if q_len >= BLOCK else 0)
     experts = sum("moe_grouped_matmul" in c for c in calls)
     assert experts == 2 * cfg.pattern.count("E")
     assert "ragged-dot" not in text
@@ -1182,9 +1281,10 @@ def test_parallel_moe_wave_programs_at_the_published_widths(sds, monkeypatch,
     import json
     import os
     from hetu_tpu.kernels import grouped_matmul as gm
+    from hetu_tpu.kernels import paged_kv_write as pw
     from hetu_tpu.kernels import ragged_attention as ra
     from hetu_tpu.models.parallel_moe import ParallelMoEConfig
-    for module in (ra, gm):
+    for module in (ra, gm, pw):
         monkeypatch.setattr(module, "_use_interpret", lambda: False)
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     with open(os.path.join(root, "benchmarks", "configs",
@@ -1226,6 +1326,8 @@ def test_parallel_moe_wave_programs_at_the_published_widths(sds, monkeypatch,
     calls = [line for line in text.splitlines() if "custom-call(" in line]
     assert sum("ragged_paged_window" in c for c in calls) == sliding
     assert sum("ragged_paged_mixed" in c for c in calls) == full
+    # the ring pool's write beside the full pool's, each a Mosaic call
+    _one_page_write_a_kv_layer(compiled, L if q_len >= BLOCK else 0)
     assert sum("moe_grouped_matmul" in c for c in calls) == 2 * L
     assert "ragged-dot" not in text
     mem = compiled.memory_analysis()

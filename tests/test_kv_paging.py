@@ -291,43 +291,101 @@ class TestPoolRows:
                                           np.asarray(c[:, src]))
 
 
+# (Q, pos, q_len, layout, ring entries, traced layer): ``layout`` "padded"
+# hands the wave in as q-blocks (slot b at row ``b Q``), "packed" as
+# ``_Rows`` lays it (live rows first, five dead rows behind them)
+_PAGE_WRITES = {
+    "aligned": (16, (0, 5, 16, 3), (16, 1, 7, 0), "padded", 0, False),
+    "straddle": (16, (13, 8, 31, 0), (16, 16, 1, 16), "padded", 0, False),
+    "wide": (32, (0, 40, 7, 24), (32, 24, 32, 0), "padded", 0, False),
+    "one-page": (8, (0, 9, 16, 2), (8, 3, 0, 8), "padded", 0, False),
+    "full": (64, (0, 0, 0, 0), (64, 1, 64, 33), "padded", 0, False),
+    # what a packed chunk wave looks like: chunks, decoding rows between
+    "two-chunks": (16, (8, 21, 3, 40), (16, 1, 14, 1), "packed", 0, False),
+    "dead-middle": (16, (5, 9, 0, 30), (13, 1, 0, 16), "packed", 0, False),
+    "last-row-of-a-page": (16, (7, 15, 23, 47), (16, 16, 1, 9), "packed",
+                           0, False),
+    # a ring of five entries under a table of eight pages, positions
+    # past its 40: the logical pages wrap onto the ring's blocks
+    "ring-turned": (16, (44, 37, 57, 41), (16, 1, 7, 16), "packed", 5,
+                    False),
+    "traced-layer": (16, (13, 8, 31, 0), (16, 16, 1, 16), "packed", 0, True),
+    "padded-as-rows": (16, (7, 15, 23, 47), (3, 16, 0, 12), "padded", 0,
+                       True),
+    # the pool in bfloat16: its rows go in as float32 and come back exact
+    "bf16-pool": (16, (8, 21, 3, 40), (16, 1, 14, 1), "packed", 0, False),
+}
+
+
 @pytest.mark.smoke
 class TestPageWrite:
-    """``_kv_write_pages`` (a wide q-block written a page at a time)
-    leaves every block but scratch block 0 exactly as the row scatter
-    does."""
+    """``paged_kv_write`` (a wide q-block written as the pages its live
+    rows touch, from the rows as they lie; the kernel interpreted) leaves
+    every block but scratch block 0 exactly as the row scatter does, and
+    scratch block 0 and every block it does not list as they were."""
 
-    @pytest.mark.parametrize("Q,pos,q_len", [
-        (16, (0, 5, 16, 3), (16, 1, 7, 0)),      # aligned chunk, decode
-        (16, (13, 8, 31, 0), (16, 16, 1, 16)),   # straddling pages
-        (32, (0, 40, 7, 24), (32, 24, 32, 0)),   # two pages and more
-        (8, (0, 9, 16, 2), (8, 3, 0, 8)),        # a q-block of one page
-        (64, (0, 0, 0, 0), (64, 1, 64, 33)),     # to the table's width
-    ], ids=["aligned", "straddle", "wide", "one-page", "full"])
-    def test_same_pool_as_the_row_scatter(self, Q, pos, q_len):
-        from hetu_tpu.models.gpt_decode import (_kv_scatter,
-                                                _kv_write_pages)
+    @pytest.mark.parametrize("case", list(_PAGE_WRITES))
+    def test_same_pool_as_the_row_scatter(self, case):
+        import jax
+        from hetu_tpu.kernels.paged_kv_write import (paged_kv_write,
+                                                     touched_pages)
+        from hetu_tpu.kv_layout import kv_rows
+        from hetu_tpu.models.gpt_decode import _Rows, _kv_scatter
+        Q, pos, q_len, layout, ring, traced = _PAGE_WRITES[case]
         B, T, bs, H, Dh, L = 4, 8, 8, 3, 8, 2
-        rng = np.random.RandomState(Q)
+        rng = np.random.RandomState(Q + len(case))
         m = _mgr(layers=L, heads=H, head_dim=Dh, slots=B,
                  max_seq_len=T * bs, block=bs)
-        pool = jnp.asarray(rng.randn(*m.cache_k.shape).astype(np.float32))
+        W = m.cache_k.shape[-1]
+        dtype = jnp.bfloat16 if case == "bf16-pool" else jnp.float32
+        pools = [jnp.asarray(rng.randn(*m.cache_k.shape), dtype)
+                 for _ in range(2)]
         tables = jnp.asarray(
             1 + rng.permutation(m.n_blocks - 1)[:B * T].reshape(B, T))
-        val = jnp.asarray(rng.randn(B, Q, H, Dh).astype(np.float32))
+        if ring:
+            tables = tables[:, :ring][:, np.arange(T) % ring]
+        vals = [jnp.asarray(rng.randn(B, Q, H, Dh).astype(np.float32))
+                for _ in range(2)]
         pos, q_len = jnp.asarray(pos), jnp.asarray(q_len)
         posns = jnp.clip(pos[:, None] + jnp.arange(Q)[None, :], 0,
                          T * bs - 1)
         valid = jnp.arange(Q)[None, :] < q_len[:, None]
         wblk = jnp.where(
             valid, tables[jnp.arange(B)[:, None], posns // bs], 0)
-        want = _kv_scatter(pool, (1, wblk, posns % bs), val)
-        got = _kv_write_pages(pool, 1, val, pos, q_len, tables)
-        np.testing.assert_array_equal(np.asarray(got[:, 1:]),
-                                      np.asarray(want[:, 1:]))
-        # dead rows go nowhere: scratch block 0 is as it was
-        np.testing.assert_array_equal(np.asarray(got[:, 0]),
-                                      np.asarray(pool[:, 0]))
+        woff = posns % bs
+        if layout == "packed":
+            R = int(q_len.sum()) + 5
+            rows = _Rows.of(q_len, Q, R)
+            start = rows.start
+            vals = [rows.pack(v) for v in vals]
+            wblk = jnp.where(rows.live[None], rows.pack(wblk), 0)
+            woff = rows.pack(woff)
+        else:
+            R, start = B * Q, jnp.arange(B) * Q
+        want = [_kv_scatter(c, (1, wblk, woff), v)
+                for c, v in zip(pools, vals)]
+
+        def write(layer, ck, cv, k, v):
+            t = touched_pages(pos, q_len, start, tables, bs, R, Q)
+            return paged_kv_write(ck, cv, layer, kv_rows(k, W).reshape(R, W),
+                                  kv_rows(v, W).reshape(R, W), t), t
+        if traced:
+            write = jax.jit(write)
+        got, t = write(jnp.asarray(1) if traced else 1, *pools, *vals)
+        listed = set(np.asarray(t.block)[:int(t.count[0])].tolist())
+        assert 0 not in listed
+        for g, w, c in zip(got, want, pools):
+            assert g.dtype == dtype
+            np.testing.assert_array_equal(np.asarray(g[:, 1:]),
+                                          np.asarray(w[:, 1:]))
+            # dead rows go nowhere: scratch block 0 is as it was, and so
+            # is every block the wave does not list, in every layer
+            np.testing.assert_array_equal(np.asarray(g[:, 0]),
+                                          np.asarray(c[:, 0]))
+            moved = np.flatnonzero(
+                (np.asarray(g) != np.asarray(c)).any(axis=(0, 2, 3)))
+            assert set(moved.tolist()) <= listed
+            np.testing.assert_array_equal(np.asarray(g[0]), np.asarray(c[0]))
 
 
 @pytest.mark.smoke

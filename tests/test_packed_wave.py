@@ -760,6 +760,61 @@ def test_counters_count_what_happened(gpt):
         "chunks_deferred"] == 0
 
 
+def test_kv_write_pages_are_the_pages_whose_bytes_changed(gpt):
+    """``serve.kv.write_pages``: what the host counts from a wave's
+    ``pos`` and ``q_len`` is the number of pool blocks the wave's launch
+    changed, wave by wave, in every wave with a q-block a page or more
+    wide (packed or too small to pack), and scratch block 0 is not among
+    them; a narrower wave (its rows go as rows, the dead ones to scratch
+    block 0) counts nothing."""
+    telemetry.reset()
+    params, cfg = gpt
+    # free slots: the engine never runs ahead, so a step lands the wave
+    # before it and launches one
+    eng = ServingEngine(params, cfg, slots=8, kv_block=16, prefill_chunk=64,
+                        fast_path=False)
+    waves = watch_waves(eng)
+    for r in requests(61, SMALL_SIZES[:5]):
+        eng.submit(r)
+
+    def pools():
+        return [np.asarray(c) for c in (eng.kv.cache_k, eng.kv.cache_v)]
+    counts, changed, last, seen = [], [], pools(), 0
+    before = 0
+    while eng.pending:
+        eng.step()
+        now = pools()
+        if eng._launched != seen:
+            seen = eng._launched
+            moved = [(a != b).any(axis=(0, 2, 3)) for a, b in zip(now, last)]
+            assert (moved[0] == moved[1]).all()
+            assert not (moved[0][0] and eng._flying.wave["q"] >= 16)
+            changed.append(int(moved[0].sum()))
+        last = now
+        if len(waves) > len(counts):   # a wave landed in this step
+            count = eng.metrics.snapshot()["kv_write_pages"]
+            counts.append(count - before)
+            before = count
+    assert len(counts) == len(changed) == len(waves) == eng.steps
+    wide = [Q >= 16 for _, Q, _ in waves]
+    assert any(wide) and not all(wide)
+    for count, pages, (q_len, Q, _), w in zip(counts, changed, waves, wide):
+        assert count == (pages if w else 0)
+        # a page holds at most 16 of the wave's rows, a slot's run of
+        # rows at most two pages more than its rows fill
+        if w:
+            assert q_len.sum() / 16 <= pages <= q_len.sum() // 16 \
+                + 2 * (q_len > 0).sum()
+    snap = eng.metrics.snapshot()
+    assert snap["kv_write_pages"] == sum(counts)
+    assert snap["kv_write_rows"] == sum(
+        int(q_len.sum()) for (q_len, _, _), w in zip(waves, wide) if w)
+    counters = telemetry.snapshot()["counters"]
+    assert counters["serve.kv.write_pages"] == snap["kv_write_pages"]
+    assert counters["serve.kv.write_rows"] == snap["kv_write_rows"]
+    telemetry.reset()
+
+
 def test_capacity_router_waves_stay_padded_and_defer_nothing():
     """A ``MoESpec`` sizes its experts' slots from the rows it is
     handed: its engine keeps the padded wave and its schedule."""

@@ -98,6 +98,49 @@ FAMILIES = {
 }
 
 PARENT = {
+    # PR 56 changed, on purpose, every entry whose q-block is a page or more
+    # wide (Q 32, and Q 8 where the block is 8) over a float K/V pool,
+    # ``fresh0`` and ``fresh1``, 37 in all: the ONE operation that differs
+    # is the K/V write under ``kv_write``, which was ``_kv_write_pages`` (a
+    # pad, a shifted slice under ``vmap``, a gather of ``B x K`` pages, a
+    # select and a scatter, once for K and once for V, on k and v unpacked
+    # to ``[B, Q]`` in a packed program) and is the ``pallas_call``
+    # ``paged_kv_write`` (``kernels/paged_kv_write.py``: K and V in one call
+    # a layer on the rows as they lie, after ``touched_pages``' list once a
+    # table; interpreted in the masked families, Mosaic in the kernel ones);
+    # in the MASKED packed programs k and v still unpack, under
+    # ``attention`` now, for the chunk's own fresh K/V.  Every Q 1 entry,
+    # and every latent, sparse-latent, retention and training entry, is the
+    # parent's.  The parent of PR 56 (commit dcdc3e4) lowered the changed
+    # ones to: PARENT_MASKED: gpt2.Q32.fresh0 fe44f8933c85a0a3,
+    # gpt2.Q32.fresh1 910e84f83fe7e6d4; PARENT_RAGGED: gpt2.Q32.fresh0
+    # a7c64ccb01632823, gpt2.Q32.fresh1 a7c64ccb01632823;
+    # PARENT_HYBRID_MASKED: lfm2.Q32.fresh0 34501c1582f67ac1,
+    # lfm2.Q32.fresh1 90e43d41275f0717, falcon.Q32.fresh0 7a593dcee24143b8,
+    # falcon.Q32.fresh1 e1c7e7369a2d3dbb; PARENT_HYBRID_RAGGED:
+    # lfm2.Q32.fresh0 4305fadbb584f1c8, lfm2.Q32.fresh1 4305fadbb584f1c8,
+    # falcon.Q32.fresh0 c49aeded6dc2f4d8, falcon.Q32.fresh1
+    # c49aeded6dc2f4d8; PARENT_WINDOW_MASKED: mellum2.Q8.fresh0
+    # 4e448bc7c3ca2927, mellum2.Q8.fresh1 293ab0d1cdcca346;
+    # PARENT_WINDOW_RAGGED: mellum2.Q32.fresh0 57d8e12e8808ce77,
+    # mellum2.Q32.fresh1 57d8e12e8808ce77; PARENT_PACKED_MASKED:
+    # gpt2.Q32.fresh1 6fd67af84c39d578; PARENT_PACKED_RAGGED:
+    # gpt2.Q32.fresh1 d56b6d40bd76fb37; PACKED_ROWS_MASKED: lfm2.Q32.fresh1
+    # af661b9170a114df, falcon.Q32.fresh1 e3194d430ad60fda,
+    # mellum2.Q32.fresh1 186e8bc80e57df70; PACKED_ROWS_RAGGED:
+    # lfm2.Q32.fresh1 15d4407705d449f8, falcon.Q32.fresh1 3515c214ca1f81d5,
+    # mellum2.Q32.fresh1 847f077464895fb5; NEMOTRON_MASKED:
+    # nemotron.Q32.fresh0 7a29ba66835c6b6a, nemotron.Q32.fresh1
+    # 01313047839bb51b; NEMOTRON_RAGGED: nemotron.Q32.fresh0
+    # d6103b62da292653, nemotron.Q32.fresh1 d6103b62da292653;
+    # SSM_STEP_RAGGED: nemotron.Q32.fresh0 453c8f1ffd43c2f9,
+    # nemotron.Q32.fresh1 453c8f1ffd43c2f9, falcon.Q32.fresh0
+    # 87ef4a527badb7db, falcon.Q32.fresh1 87ef4a527badb7db;
+    # PARALLEL_MOE_MASKED: parallel_moe.Q32.fresh0 b116b5a760b749bb,
+    # parallel_moe.Q32.fresh1 fcb5ecd9d4365e95; PARALLEL_MOE_RAGGED:
+    # parallel_moe.Q32.fresh0 525e260c074e3c7d, parallel_moe.Q32.fresh1
+    # 525e260c074e3c7d; PARALLEL_MOE_PACKED_RAGGED: parallel_moe.Q32.fresh1
+    # 7c048e6a6959d14d.
     # GPT-2 (2 layers, 4 heads of 64, bf16) and the latent block, as the
     # PARENT of PR 34 lowered them (commit cdadf90).  PR 41 changed the
     # latent Q 32 pair on purpose: 128 rows x top-2 over 8 experts are 32
@@ -108,8 +151,8 @@ PARENT = {
     "PARENT_MASKED": {
         "gpt2.Q1.fresh0": "9691db83be028caf",
         "gpt2.Q1.fresh1": "7beca803d1ca3f4f",
-        "gpt2.Q32.fresh0": "fe44f8933c85a0a3",
-        "gpt2.Q32.fresh1": "910e84f83fe7e6d4",
+        "gpt2.Q32.fresh0": "5de13c824f077dfb",
+        "gpt2.Q32.fresh1": "280302dbfcf81e0a",
         "latent.Q1.fresh0": "7af25cbea0694a84",
         "latent.Q1.fresh1": "7af25cbea0694a84",
         "latent.Q32.fresh0": "57bb0151f876695a",
@@ -124,8 +167,8 @@ PARENT = {
     "PARENT_RAGGED": {
         "gpt2.Q1.fresh0": "5919310cf2517705",
         "gpt2.Q1.fresh1": "5919310cf2517705",
-        "gpt2.Q32.fresh0": "a7c64ccb01632823",
-        "gpt2.Q32.fresh1": "a7c64ccb01632823",
+        "gpt2.Q32.fresh0": "ac15699e0f20c049",
+        "gpt2.Q32.fresh1": "ac15699e0f20c049",
         "latent.Q1.fresh0": "3e2ddf60b91b860d",
         "latent.Q1.fresh1": "3e2ddf60b91b860d",
         "latent.Q32.fresh0": "eef22714386f596c",
@@ -135,12 +178,12 @@ PARENT = {
     "PARENT_HYBRID_MASKED": {
         "lfm2.Q1.fresh0": "d5335fb3237bcd18",
         "lfm2.Q1.fresh1": "43b61d3f0c614ec1",
-        "lfm2.Q32.fresh0": "34501c1582f67ac1",
-        "lfm2.Q32.fresh1": "90e43d41275f0717",
+        "lfm2.Q32.fresh0": "c46894dad3a30ac4",
+        "lfm2.Q32.fresh1": "dfb682136f835490",
         "falcon.Q1.fresh0": "5ed3ea5756c9fb13",
         "falcon.Q1.fresh1": "471086ba72e5dab2",
-        "falcon.Q32.fresh0": "7a593dcee24143b8",
-        "falcon.Q32.fresh1": "e1c7e7369a2d3dbb"},
+        "falcon.Q32.fresh0": "3d63c3dc685e1893",
+        "falcon.Q32.fresh1": "f04b1a8499c041a8"},
     # The four Q 32 entries (4 slots x 32 rows stay padded: the DENSE
     # entry) are the PARENT of PR 43's again: PR 43 gave the rows kernel
     # of a chunk program a second height (1c3dd14502830c9d and
@@ -153,19 +196,19 @@ PARENT = {
     "PARENT_HYBRID_RAGGED": {
         "lfm2.Q1.fresh0": "d8e968f0ef99f889",
         "lfm2.Q1.fresh1": "d8e968f0ef99f889",
-        "lfm2.Q32.fresh0": "4305fadbb584f1c8",
-        "lfm2.Q32.fresh1": "4305fadbb584f1c8",
+        "lfm2.Q32.fresh0": "a7d43a2b7b33081d",
+        "lfm2.Q32.fresh1": "a7d43a2b7b33081d",
         "falcon.Q1.fresh0": "b452b65dabd73846",
         "falcon.Q1.fresh1": "b452b65dabd73846",
-        "falcon.Q32.fresh0": "c49aeded6dc2f4d8",
-        "falcon.Q32.fresh1": "c49aeded6dc2f4d8"},
+        "falcon.Q32.fresh0": "f310f336e0d1e441",
+        "falcon.Q32.fresh1": "f310f336e0d1e441"},
     # tests/test_window_moe.py's small sliding-window / full model, as
     # the PARENT of PR 44 lowered it (commit 56a5ee3).
     "PARENT_WINDOW_MASKED": {
         "mellum2.Q1.fresh0": "353f217c54a48780",
         "mellum2.Q1.fresh1": "6c5a3286d660bb18",
-        "mellum2.Q8.fresh0": "4e448bc7c3ca2927",
-        "mellum2.Q8.fresh1": "293ab0d1cdcca346"},
+        "mellum2.Q8.fresh0": "968f72e606dea2ed",
+        "mellum2.Q8.fresh1": "535d5dc67edf3c98"},
     # The families below were taken on the PARENT of PR 47 (commit
     # 2accd4a, in a scratch checkout, before that PR's first deletion).
     # But every Q 32 entry of a K/V rows kernel with several query heads
@@ -178,26 +221,26 @@ PARENT = {
     "PARENT_WINDOW_RAGGED": {
         "mellum2.Q1.fresh0": "071a841212bd9ec1",
         "mellum2.Q1.fresh1": "071a841212bd9ec1",
-        "mellum2.Q32.fresh0": "57d8e12e8808ce77",
-        "mellum2.Q32.fresh1": "57d8e12e8808ce77"},
+        "mellum2.Q32.fresh0": "c8ec1503c38d1cdf",
+        "mellum2.Q32.fresh1": "c8ec1503c38d1cdf"},
     "PARENT_RETENTION_MASKED": {
         "brumby.Q1.fresh0": "e33a3369dfd0dd1b",
         "brumby.Q1.fresh1": "e33a3369dfd0dd1b",
         "brumby.Q32.fresh0": "addbf0ad5c1461c1",
         "brumby.Q32.fresh1": "addbf0ad5c1461c1"},
     "PARENT_PACKED_MASKED": {
-        "gpt2.Q32.fresh1": "6fd67af84c39d578",
+        "gpt2.Q32.fresh1": "2106c857b9db5a61",
         "latent.Q32.fresh1": "9b275df0e7c91882"},
     # The GPT-2 entry is PR 54's: the K/V rows kernel is handed the 256
     # packed rows as they lie (``ragged_paged_attention_rows``: its call's
     # operand and result are ``[256, 256]`` rows where the parent's were
     # ``[16, 32, 256]``, the grid ``(row tile)`` with the slots' visits
     # inside, and the query's ``rows.unpack`` gather and the result's
-    # ``rows.pack`` gather are gone; k and v still unpack for the page
-    # write); the parent of PR 54 lowered it to ee10736bb0265878.  The
+    # ``rows.pack`` gather are gone; k and v unpacked for the page write
+    # until PR 56); the parent of PR 54 lowered it to ee10736bb0265878.  The
     # latent entry is the parent's.
     "PARENT_PACKED_RAGGED": {
-        "gpt2.Q32.fresh1": "d56b6d40bd76fb37",
+        "gpt2.Q32.fresh1": "81bdfb51db67cfc7",
         "latent.Q32.fresh1": "89ef03c3aad79ffc"},
     # The grouped-query and sliding-window chunk programs of 16 slots
     # (``packed_rows_programs``).  MASKED: as the PARENT of PR 54 lowered
@@ -209,13 +252,13 @@ PARENT = {
     # unpacked to ``[16, 32, H, Dh]``, relaid by ``_grouped_rows``, the
     # dense grid ``(slot, q-tile)``, the result relaid and packed).
     "PACKED_ROWS_MASKED": {
-        "lfm2.Q32.fresh1": "af661b9170a114df",
-        "falcon.Q32.fresh1": "e3194d430ad60fda",
-        "mellum2.Q32.fresh1": "186e8bc80e57df70"},
+        "lfm2.Q32.fresh1": "c6972a63bc27aa90",
+        "falcon.Q32.fresh1": "91e5679c51633f9e",
+        "mellum2.Q32.fresh1": "bc774e50bedcf550"},
     "PACKED_ROWS_RAGGED": {
-        "lfm2.Q32.fresh1": "15d4407705d449f8",
-        "falcon.Q32.fresh1": "3515c214ca1f81d5",
-        "mellum2.Q32.fresh1": "847f077464895fb5"},
+        "lfm2.Q32.fresh1": "1b926479d35eedc5",
+        "falcon.Q32.fresh1": "2302f4d7ee7f3f4b",
+        "mellum2.Q32.fresh1": "c64ceea853768c1d"},
     # PR 48's own, no parent's: tests/test_nemotron_h.py's small
     # ``nemotron_h`` model (eleven one-part layers, positions "none",
     # expert layers that hold experts [4, 8) of 16 at a latent width, the
@@ -226,13 +269,13 @@ PARENT = {
     "NEMOTRON_MASKED": {
         "nemotron.Q1.fresh0": "c1af25b0c15abce7",
         "nemotron.Q1.fresh1": "d3a20ecdcb55212b",
-        "nemotron.Q32.fresh0": "7a29ba66835c6b6a",
-        "nemotron.Q32.fresh1": "01313047839bb51b"},
+        "nemotron.Q32.fresh0": "e498085023f16a50",
+        "nemotron.Q32.fresh1": "1e3d7c6b8b8996b6"},
     "NEMOTRON_RAGGED": {
         "nemotron.Q1.fresh0": "acb5db193cd96995",
         "nemotron.Q1.fresh1": "acb5db193cd96995",
-        "nemotron.Q32.fresh0": "d6103b62da292653",
-        "nemotron.Q32.fresh1": "d6103b62da292653"},
+        "nemotron.Q32.fresh0": "c894fccb98518524",
+        "nemotron.Q32.fresh1": "c894fccb98518524"},
     # PR 49's own, no parent's: the same model at 32 slots, whose DECODE
     # wave's 32 x top-4 sorted rows are one whole row tile (32 can land
     # on the 4 held experts): since PR 49 the rule hands such a wave's
@@ -253,12 +296,12 @@ PARENT = {
     "SSM_STEP_RAGGED": {
         "nemotron.Q1.fresh0": "c3a7775fd1b7f6c8",
         "nemotron.Q1.fresh1": "c3a7775fd1b7f6c8",
-        "nemotron.Q32.fresh0": "453c8f1ffd43c2f9",
-        "nemotron.Q32.fresh1": "453c8f1ffd43c2f9",
+        "nemotron.Q32.fresh0": "7e212d754c5f7b0d",
+        "nemotron.Q32.fresh1": "7e212d754c5f7b0d",
         "falcon.Q1.fresh0": "737fe178722df78a",
         "falcon.Q1.fresh1": "737fe178722df78a",
-        "falcon.Q32.fresh0": "87ef4a527badb7db",
-        "falcon.Q32.fresh1": "87ef4a527badb7db"},
+        "falcon.Q32.fresh0": "ed80456adcc6e497",
+        "falcon.Q32.fresh1": "ed80456adcc6e497"},
     # PR 51's own, no parent's: tests/test_sparse_latent.py's small
     # five-layer model (latent operators by layer: two full layers with
     # an indexer of top 16, three window layers over a latent ring of
@@ -293,15 +336,15 @@ PARENT = {
     "PARALLEL_MOE_MASKED": {
         "parallel_moe.Q1.fresh0": "53e460fb51d02684",
         "parallel_moe.Q1.fresh1": "4246d5f557aa5890",
-        "parallel_moe.Q32.fresh0": "b116b5a760b749bb",
-        "parallel_moe.Q32.fresh1": "fcb5ecd9d4365e95"},
+        "parallel_moe.Q32.fresh0": "6c2d876f2aaf1449",
+        "parallel_moe.Q32.fresh1": "4c75570d123b7c09"},
     "PARALLEL_MOE_RAGGED": {
         "parallel_moe.Q1.fresh0": "d72b430cfe086633",
         "parallel_moe.Q1.fresh1": "d72b430cfe086633",
-        "parallel_moe.Q32.fresh0": "525e260c074e3c7d",
-        "parallel_moe.Q32.fresh1": "525e260c074e3c7d"},
+        "parallel_moe.Q32.fresh0": "9c73deba737ec37f",
+        "parallel_moe.Q32.fresh1": "9c73deba737ec37f"},
     "PARALLEL_MOE_PACKED_RAGGED": {
-        "parallel_moe.Q32.fresh1": "7c048e6a6959d14d"},
+        "parallel_moe.Q32.fresh1": "d16b0b872de79b9a"},
 }
 
 
@@ -318,11 +361,12 @@ def digests(request):
                                 build(jax.ShapeDtypeStruct).items()}
                 return done[family]
             from hetu_tpu.kernels import grouped_matmul as gm
+            from hetu_tpu.kernels import paged_kv_write as pw
             from hetu_tpu.kernels import ragged_attention as ra
             from hetu_tpu.kernels import ssm_step as ss
             on_chip = request.getfixturevalue("sds")
             with pytest.MonkeyPatch.context() as m:
-                for module in (ra, gm, ss):
+                for module in (ra, gm, ss, pw):
                     m.setattr(module, "_use_interpret", lambda: False)
                 texts = {k: low.as_text()
                          for k, low in build(on_chip).items()}
