@@ -41,3 +41,4 @@ from .moe_decode import (
     LatentMoEConfig, RoutedSpec, routed_ffn, init_latent_moe_params,
 )
 from .parallel_moe import ParallelMoEConfig, init_parallel_moe_params
+from .kda_latent import KDALatentConfig, init_kda_latent_params

@@ -344,7 +344,11 @@ class LatentSpec(NamedTuple):
     ``sigmoid(x W_g)`` on the attention's output, before ``W_o``),
     ``rescale`` (the two low-rank norms' outputs multiplied by
     ``sqrt(hidden / rank)``) and ``index`` (an ``IndexSpec``: the layer
-    reads the rows its indexer chose).  The defaults trace nothing."""
+    reads the rows its indexer chose).  ``q_lora_rank`` 0: the query has
+    no low-rank step (the source's ``q_lora_rank`` null): ONE projection
+    ``{us}_attn_q_weight`` [hidden, heads x (nope + rope)] and no query
+    norm (no indexer either: its queries are made from the low-rank
+    query).  The defaults trace nothing."""
 
     q_lora_rank: int
     kv_lora_rank: int
@@ -410,6 +414,7 @@ OPERATORS = {
     "attention+ssm": ("pool", "state"),
     "retention": ("state",),
     "ssm": ("state",),
+    "kda": ("state",),
     "none": (),
 }
 # every kind of FFN a layer may have; "none": a layer that is its
@@ -418,9 +423,12 @@ FFN_KINDS = ("gelu", "swiglu", "routed", "none")
 # the operators that keep slot state, by the kind of state: one manager
 # holds ONE set, so a spec names operators of one kind alone
 STATE_KINDS = {"conv": "conv", "attention+ssm": "ssm", "ssm": "ssm",
-               "retention": "retention"}
+               "retention": "retention", "kda": "kda"}
 # the operators of a latent block (``attention`` "latent" with ``ops``)
+# that read latent rows, and the state operators that may stand BESIDE
+# them, layer by layer, in such a block
 LATENT_OPERATORS = ("latent_attention", "window_latent_attention")
+LATENT_STATE_OPERATORS = ("kda",)
 
 
 class BlockSpec(NamedTuple):
@@ -456,7 +464,10 @@ class BlockSpec(NamedTuple):
     (``retention``, a ``retention_decode.RetentionSpec``) in place of
     the softmax over pages, so that it holds slot state and NO page (a
     spec of such layers alone has no pool), an "ssm" layer running the
-    state-space mixer ALONE on the layer's one norm (state, no page) and
+    state-space mixer ALONE on the layer's one norm (state, no page), a
+    "kda" layer running the gated delta rule ``kda`` (a
+    ``kda_decode.KDASpec``: a conv tail and a matrix state a slot, no
+    page; it stands beside latent operators in a latent block) and
     a "none" layer running no operator (it keeps nothing); head_dim: the head size where it is the
     configuration's own key and not ``hidden / heads`` (0: that
     quotient); mup: the block's forward multipliers (``MuP``; None: no
@@ -497,6 +508,7 @@ class BlockSpec(NamedTuple):
     ffns: Optional[tuple] = None
     latent_by_op: Optional[tuple] = None
     residual: str = "sequential"
+    kda: Optional[tuple] = None
 
     def latent_of(self, i):
         """Layer ``i``'s ``LatentSpec``: its operator's entry of
@@ -559,14 +571,14 @@ class BlockSpec(NamedTuple):
         the pool, as ``PagedKVManager(state_shapes=)`` takes it (None:
         none): a conv layer's last ``conv_kernel - 1`` inputs, or a
         state-space mixer's set (``SSMSpec.state_shapes``), or the
-        retention layers' (``RetentionSpec.state_shapes``)."""
+        retention layers' (``RetentionSpec.state_shapes``), or the
+        delta-rule layers' (``KDASpec.state_shapes``)."""
         n = self.op_layers(L, "state")
         if not n:
             return None
-        if self.ssm is not None:
-            return self.ssm.state_shapes(n)
-        if self.retention is not None:
-            return self.retention.state_shapes(n)
+        for spec in (self.ssm, self.retention, self.kda):
+            if spec is not None:
+                return spec.state_shapes(n)
         return (((n, self.conv_kernel - 1, hidden), None),)
 
     def op_layers(self, L, what):
@@ -641,15 +653,21 @@ RESIDUALS = ("sequential", "parallel")
 
 def check_block_spec(blk, layers=None):
     """Raise for a spec the mixed wave cannot run.  It runs GPT-2's
-    block; latent attention with RMSNorm and RoPE over any FFN kind of
-    ``ffn``, every layer alike or each one of ``LATENT_OPERATORS`` (a
-    "window_latent_attention" layer over the last ``window`` positions
-    in a latent ring, a "latent_attention" layer over everything or,
-    where its ``LatentSpec`` has an indexer, over the rows that chose);
+    block; the LATENT block: latent attention with RMSNorm and RoPE over
+    any FFN kind of ``ffn``, every layer alike or each one operator of
+    ``LATENT_OPERATORS`` (a "window_latent_attention" layer over the
+    last ``window`` positions in a latent ring, a "latent_attention"
+    layer over everything or, where its ``LatentSpec`` has an indexer,
+    over the rows that chose; a ``LatentSpec`` whose ``q_lora_rank`` is 0
+    projects its query in one step) or of ``LATENT_STATE_OPERATORS`` (a
+    "kda" layer: the gated delta rule ``kda``, a conv tail and a matrix
+    state a slot BESIDE the latent pool, in one manager; such a spec
+    names at least one latent operator too);
     and the grouped-query block: RMSNorm or the bias-free LayerNorm
     ("layernorm_nobias"), no biases, an
     optional per-head q/k norm, positions "rope" (over the whole head)
-    or "none", every layer ONE of ``OPERATORS`` and one of
+    or "none", every layer ONE of ``OPERATORS`` but the latent block's
+    and one of
     ``FFN_KINDS``, at least one of the two not "none".  Its residual is
     one of ``RESIDUALS``: "sequential", or "parallel" (one norm a layer,
     operator and FFN side by side) where every layer is a K/V attention
@@ -664,8 +682,10 @@ def check_block_spec(blk, layers=None):
     (``rope_by_op``, of ``ROPE_KINDS``, or "none" for an operator whose
     layers rotate nothing) go with the grouped-query block
     alone; a routed FFN scores by one of ``moe_decode.SCORINGS``, has
-    experts of one of ``moe_decode.EXPERT_FORMS`` and holds all its
-    experts or a contiguous share of them.  ``layers``: the model's
+    experts of one of ``moe_decode.EXPERT_FORMS``, holds all its
+    experts or a contiguous share of them and chooses among all of them
+    or among the ``topk_group`` best of ``n_group`` equal groups.
+    ``layers``: the model's
     depth, which ``ops`` and ``ffns`` then name layer by layer."""
     if blk == GPT2_BLOCK:
         return
@@ -683,7 +703,11 @@ def check_block_spec(blk, layers=None):
             rt.scoring in SCORINGS and rt.expert in EXPERT_FORMS
             and rt.latent >= 0 and 0 <= rt.held_first
             and 0 <= rt.held and rt.shared_scale > 0
-            and rt.held_first + rt.held <= rt.num_experts))
+            and rt.held_first + rt.held <= rt.num_experts
+            and 1 <= rt.topk_group <= rt.n_group
+            and (rt.n_group == 1 or rt.scoring == "sigmoid")
+            and rt.num_experts % rt.n_group == 0
+            and rt.top_k <= rt.topk_group * (rt.num_experts // rt.n_group)))
     ops = blk.ops or ()
     needs = all(need(blk, ops) for need in _OPERATOR_NEEDS.values()) \
         and all(o in OPERATORS for o in ops) \
@@ -693,7 +717,8 @@ def check_block_spec(blk, layers=None):
     if blk.attention == "latent":
         # one latent spec for every layer, or latent operators BY LAYER
         # (``LATENT_OPERATORS``), each with a spec, a head count and
-        # rotary parameters of its own
+        # rotary parameters of its own, and delta-rule layers beside
+        # them (``LATENT_STATE_OPERATORS``)
         ok = common and blk.norm == "rmsnorm" \
             and blk.residual == "sequential" and blk.positions == "rope" \
             and blk.latent is not None and blk.ffns is None \
@@ -703,7 +728,8 @@ def check_block_spec(blk, layers=None):
             and (bool(ops) or (
                 not blk.window and blk.rope_by_op is None
                 and blk.latent_by_op is None
-                and blk.latent.index is None and not blk.latent.heads))
+                and blk.latent.index is None and not blk.latent.heads)) \
+            and blk.latent.q_lora_rank >= 0
     else:
         n = max(len(ops), len(blk.ffns or ()))
         ok = common and blk.attention == "gqa" and blk.latent is None \
@@ -719,21 +745,28 @@ def check_block_spec(blk, layers=None):
                 and set(ops) <= {"attention", "window_attention"}
                 and kinds <= {"swiglu", "routed"}))
     if not ok:
+        gqa_ops = [o for o in OPERATORS
+                   if o not in LATENT_OPERATORS + LATENT_STATE_OPERATORS]
+        states = sorted(set(STATE_KINDS.values()))
         raise ValueError(
-            f"the mixed wave runs GPT-2's block, latent attention with "
-            f"rmsnorm and rope, or the grouped-query block with rmsnorm "
-            f"or layernorm_nobias (of the norms {', '.join(NORMS)}) "
-            f"and positions rope or none, a layer one operator of "
-            f"{', '.join(OPERATORS)} (state of one kind a spec; window "
-            f"and retention layers beside plain attention alone; "
-            f"{' and '.join(LATENT_OPERATORS)} in a latent block alone, "
-            f"an indexer on the first) and one "
-            f"FFN of {', '.join(FFN_KINDS)}, not both none; residuals "
-            f"{', '.join(RESIDUALS)}, parallel in the grouped-query block "
-            f"where every layer is attention or window_attention beside "
-            f"a swiglu or routed FFN; rotary kinds "
+            f"the mixed wave runs GPT-2's block; the latent block with "
+            f"rmsnorm and rope, every layer alike or a layer one operator "
+            f"of {', '.join(LATENT_OPERATORS)} (an indexer on the first; "
+            f"q_lora_rank 0: the query in one projection, no indexer) or "
+            f"of {', '.join(LATENT_STATE_OPERATORS)} (slot state beside "
+            f"the latent pool; at least one latent operator with it); or "
+            f"the grouped-query block with rmsnorm or layernorm_nobias "
+            f"(of the norms {', '.join(NORMS)}) and positions rope or "
+            f"none, a layer one operator of {', '.join(gqa_ops)} (state "
+            f"of one kind a spec, of {', '.join(states)}; "
+            f"window and retention layers beside plain attention alone) "
+            f"and one FFN of {', '.join(FFN_KINDS)}, not both none; "
+            f"residuals {', '.join(RESIDUALS)}, parallel in the "
+            f"grouped-query block where every layer is attention or "
+            f"window_attention beside a swiglu or routed FFN; rotary kinds "
             f"{', '.join(ROPE_KINDS)}, or none by operator in the "
-            f"grouped-query block; routers {', '.join(SCORINGS)}; "
+            f"grouped-query block; routers {', '.join(SCORINGS)}, over "
+            f"all the experts or the topk_group best of n_group groups; "
             f"experts {', '.join(EXPERT_FORMS)}: it cannot run {blk}")
 
 
@@ -747,15 +780,18 @@ def _retention_fits(blk, ops):
 
 def _latent_ops_fit(blk, ops):
     by_op = dict(blk.latent_by_op or ())
-    specs = {op: by_op.get(op, blk.latent) for op in set(ops)}
+    specs = {op: by_op.get(op, blk.latent)
+             for op in set(ops) & set(LATENT_OPERATORS)}
     return blk.attention == "latent" \
-        and set(ops) <= set(LATENT_OPERATORS) and set(by_op) <= set(ops) \
+        and set(ops) <= set(LATENT_OPERATORS + LATENT_STATE_OPERATORS) \
+        and set(by_op) <= set(specs) \
         and ("window_latent_attention" in ops) == (blk.window >= 1) \
         and all(la is not None and la.heads >= 0
                 and la.qk_rope_head_dim % 2 == 0
                 for la in specs.values()) \
         and all(la.index is None or (
                     op == "latent_attention" and la.index.topk >= 1
+                    and la.q_lora_rank > 0
                     and la.index.rope_dim % 2 == 0
                     and la.index.rope_dim <= la.index.head_dim)
                 for op, la in specs.items())
@@ -777,6 +813,11 @@ _OPERATOR_NEEDS = {
     "retention": lambda blk, ops:
         ("retention" in ops) == (blk.retention is not None)
         and ("retention" not in ops or _retention_fits(blk, ops)),
+    "kda": lambda blk, ops:
+        ("kda" in ops) == (blk.kda is not None)
+        and ("kda" not in ops or (
+            blk.kda.fits() and blk.attention == "latent"
+            and bool(set(ops) & set(LATENT_OPERATORS)))),
 }
 
 
@@ -1554,7 +1595,9 @@ def _latent_attention(params, us, blk, H, h, pool, i, wblk, woff, posns,
     the pool alone, a walk of its slots' pages under the chosen rows'
     mask (``sparse_mla`` inside ``attention``:
     ``ragged_paged_mla_rows(allowed=)``).  A spec without latent
-    operators traces nothing of this."""
+    operators traces nothing of this.  A ``LatentSpec`` whose
+    ``q_lora_rank`` is 0 makes its query in ONE projection
+    (``{us}_attn_q_weight``), with no query norm."""
     la = blk.latent_of(layer)
     inv, factor = blk.rope_of(layer)
     H = la.heads or H
@@ -1565,13 +1608,17 @@ def _latent_attention(params, us, blk, H, h, pool, i, wblk, woff, posns,
                       la.v_head_dim, la.kv_lora_rank)
     with jax.named_scope("mla_qkv"):
         x = _norm(blk, params, f"{us}_ln1", h)
-        cq = _rms(x @ params[f"{us}_attn_q_a_weight"],
-                  params[f"{us}_attn_q_a_norm_scale"], blk.norm_eps)
-        if la.rescale:
-            cq = cq * jnp.asarray(
-                (h.shape[-1] / la.q_lora_rank) ** 0.5, cq.dtype)
-        q = (cq @ params[f"{us}_attn_q_b_weight"]).reshape(
-            B, Q, H, dn + dr)
+        if la.q_lora_rank:
+            cq = _rms(x @ params[f"{us}_attn_q_a_weight"],
+                      params[f"{us}_attn_q_a_norm_scale"], blk.norm_eps)
+            if la.rescale:
+                cq = cq * jnp.asarray(
+                    (h.shape[-1] / la.q_lora_rank) ** 0.5, cq.dtype)
+            q = cq @ params[f"{us}_attn_q_b_weight"]
+        else:
+            # no low-rank step: one projection, no query norm
+            q = x @ params[f"{us}_attn_q_weight"]
+        q = q.reshape(B, Q, H, dn + dr)
         kva = x @ params[f"{us}_attn_kv_a_weight"]          # [B, Q, dc+dr]
         ckv = _rms(kva[..., :dc], params[f"{us}_attn_kv_a_norm_scale"],
                    blk.norm_eps)
@@ -1937,7 +1984,12 @@ def _mixed_step(params, cfg_tuple, cache_k, cache_v, pos, tokens,
     layer the same over the pool, where its spec has an indexer with
     ``cache_v`` as the index keys' pool (scopes ``mla_index``,
     ``index_write``, ``index_score``, ``index_topk``, ``sparse_mla``
-    inside ``attention``) and, where gated, ``mla_gate``.
+    inside ``attention``) and, where gated, ``mla_gate``.  A "kda" layer
+    of such a block is ``kda_decode.kda_operator`` (``kda_qkvg``,
+    ``kda_conv``, ``kda_scan``, ``state_write``, ``kda_out``) over
+    ``state``, the manager's set BESIDE the latent pool: it writes and
+    reads no page, and the latent layers find their place in the pool by
+    ``blk.op_index`` as ever.
 
     ONE outer scope names the wave's PROGRAM in the device trace, by the
     static facts the body branches on: ``wave_chunk`` (``has_fresh``),
@@ -2076,6 +2128,13 @@ def _mixed_wave(params, cfg_tuple, cache_k, cache_v, pos, tokens, q_len,
             h, state = _retention_operator(
                 params, us, blk, i, h, H, Hkv, Dh, posns_r, state,
                 blk.op_index(i), q_len, rows)
+            h = _ffn_of_kind(params, us, blk, h, i, valid_r, moe_stats)
+            continue
+        if blk.op_kind(i) == "kda":
+            # the gated delta rule over its slot state: no page
+            from .kda_decode import kda_operator
+            h, state = kda_operator(params, us, blk, h, state,
+                                    blk.op_index(i), q_len, rows)
             h = _ffn_of_kind(params, us, blk, h, i, valid_r, moe_stats)
             continue
         if blk.attention == "latent":

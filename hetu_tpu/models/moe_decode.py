@@ -296,6 +296,8 @@ class RoutedSpec(NamedTuple):
     latent: int = 0
     expert: str = "gated_silu"
     shared_scale: float = 1.0
+    n_group: int = 1
+    topk_group: int = 1
 
     @property
     def held_experts(self):
@@ -315,7 +317,10 @@ def route(x, w_router, bias, spec):
     with no selection bias hands ``bias`` None and the ``k`` largest of
     ``s`` itself are chosen.  With
     ``spec.scoring`` "softmax" ``s`` is the softmax over all the experts
-    and its ``k`` largest are chosen (``bias`` is not read: None)."""
+    and its ``k`` largest are chosen (``bias`` is not read: None).
+    With ``spec.n_group`` over 1 the sigmoid router chooses among the
+    kept groups' experts alone (``group_limited``, scope
+    ``moe_group_select``)."""
     logits = jnp.dot(
         x.astype(jnp.float32), w_router.astype(jnp.float32),
         precision=jax.lax.Precision.HIGHEST)
@@ -324,8 +329,10 @@ def route(x, w_router, bias, spec):
         _, sel = jax.lax.top_k(s, spec.top_k)
     else:
         s = jax.nn.sigmoid(logits)
-        _, sel = jax.lax.top_k(
-            s if bias is None else s + bias.astype(jnp.float32), spec.top_k)
+        pick = s if bias is None else s + bias.astype(jnp.float32)
+        if spec.n_group > 1:
+            pick = group_limited(pick, spec.n_group, spec.topk_group)
+        _, sel = jax.lax.top_k(pick, spec.top_k)
     # s at the chosen, by comparison and not by gather (a gather of
     # T x k scalars is 0.33 ms a layer at 8192 rows on a v5e)
     hit = sel[:, :, None] == jnp.arange(s.shape[1])[None, None, :]
@@ -333,6 +340,22 @@ def route(x, w_router, bias, spec):
     if spec.norm_topk:
         w = w / (w.sum(-1, keepdims=True) + 1e-20)
     return sel.astype(jnp.int32), w * spec.scale
+
+
+def group_limited(pick, n_group, topk_group):
+    """The choice scores ``pick`` [T, E] with every expert outside the
+    kept groups at ``-inf``: the experts in ``n_group`` equal groups in
+    their order, a group's score the SUM OF ITS TWO LARGEST choice
+    scores, the ``topk_group`` largest groups kept (ties by the lower
+    group, as ``top_k`` breaks them)."""
+    with jax.named_scope("moe_group_select"):
+        T, E = pick.shape
+        grouped = pick.reshape(T, n_group, E // n_group)
+        score = jax.lax.top_k(grouped, min(2, E // n_group))[0].sum(-1)
+        _, best = jax.lax.top_k(score, topk_group)          # [T, kept]
+        keep = jnp.any(best[:, :, None] == jnp.arange(n_group)[None, None],
+                       axis=1)                              # [T, n_group]
+        return jnp.where(keep[:, :, None], grouped, -jnp.inf).reshape(T, E)
 
 
 def takes_kernel(rows):

@@ -289,6 +289,9 @@ class ServingEngine:
         # the engine's ``serve.attn.*`` stay 0 and no kernel runs)
         self._ret_layers = self.block_spec.op_layers(
             c.num_hidden_layers, "retention")
+        # ... and with the delta rule: ``serve.kda.*`` (``record_kda``)
+        self._kda_layers = self.block_spec.op_layers(
+            c.num_hidden_layers, "kda")
         self._attn_layers = sum(
             self.block_spec.op_layers(c.num_hidden_layers, pages)
             for pages in ("pool", "window"))
@@ -599,6 +602,9 @@ class ServingEngine:
                     ctx)))
         out = {}
         wide = np.where(ql > 1, ql, 0)
+        if self._kda_layers:
+            self.metrics.record_kda(int((ql == 1).sum()), int(wide.sum()),
+                                    self._kda_layers)
         for kind, layers, spec in (
                 ("ssm", self._ssm_layers, self.block_spec.ssm),
                 ("ret", self._ret_layers, self.block_spec.retention)):

@@ -317,11 +317,12 @@ class PagedKVManager:
     ``cache_k`` and ``cache_v`` are None, a token takes no block
     (``blocks_needed`` is 0, the tables one zero column wide), admission
     is by SLOT alone and ``max_seq_len`` is bounded by ``pos_cap`` only.
-    The slot count is then EXACT, not rounded up to a power of two: a
-    slot of such a manager costs its whole state whether or not a
-    request is in it (34 MB a layer for the retention state), where a
-    pooled manager's idle slot costs a table row.  Everything the state
-    refuses stays refused (``_refuse_state``).
+    The slot count of a manager with state, with pool layers or without,
+    is EXACT, not rounded up to a power of two: a slot of such a manager
+    costs its whole state whether or not a request is in it (34 MB a
+    layer for the retention state, 2.1 MB a layer for the delta rule's),
+    where a stateless pooled manager's idle slot costs a table row.
+    Everything the state refuses stays refused (``_refuse_state``).
     """
 
     @telemetry.spanned("serve.kv.build")
@@ -343,7 +344,10 @@ class PagedKVManager:
                 "PagedKVManager: layers=0 holds no block: pool_blocks and "
                 "window_layers go with pool layers")
         if bucket:
-            if self.pool_layers:
+            # (a slot that carries state costs it whether or not a
+            # request is in it: the count is then exact, with a pool or
+            # without)
+            if self.pool_layers and not (state_shapes or state_shape):
                 slots = round_up_pow2(slots)
             s = round_up_pow2(max_seq_len, floor=16)
         else:

@@ -310,19 +310,19 @@ def test_the_configuration_holds_every_published_number():
 
 def test_the_cell_and_its_metrics_are_appended_entries():
     cells = [w["name"] for w in BENCH["workloads"]]
-    assert cells.index(CELL) == 9 and len(cells) == 10
+    assert cells.index(CELL) == 9 and len(cells) >= 10
     cell = BENCH["workloads"][9]
     assert (cell["config"], cell["traffic"], cell["chips"]) == (
         CONFIG, "grounded-closed", 1)
     e2e = {m["name"]: m for m in BENCH["end_to_end"]}
-    assert e2e["serve_tokens_per_s"]["workloads"][-1] == CELL
+    assert e2e["serve_tokens_per_s"]["workloads"][8] == CELL
     assert e2e["serve_tokens_per_s"]["bound"] == 0.1
     assert CELL not in e2e["ttft_p95_ms"]["workloads"]
     metrics = {m["name"]: m for m in BENCH["per_layer"]}
     new = ["wide_window_kernel_roofline.serve",
            "wide_full_kernel_roofline.serve", "held_experts_roofline.serve",
            "par_norm_share.serve", "window_bound_row_share.serve"]
-    assert [m["name"] for m in BENCH["per_layer"]][-5:] == new
+    assert [m["name"] for m in BENCH["per_layer"]][73:78] == new
     for name in new:
         m = metrics[name]
         assert m["workloads"] == [CELL]
@@ -337,7 +337,8 @@ def test_the_cell_and_its_metrics_are_appended_entries():
                  "held_assignment_share.serve", "lm_head_share.serve",
                  "moe_experts_chunk_wave_ms", "attention_chunk_wave_ms",
                  "device_idle_share.serve", "setup_build_s"):
-        assert metrics[name]["workloads"][-1] == CELL, name
+        # (a later PR's cell may follow)
+        assert metrics[name]["workloads"].index(CELL) >= 1, name
     # ... and the three shares whose counts read other keys do not
     for name in ("window_kernel_roofline.serve", "gqa_kernel_roofline.serve",
                  "moe_experts_roofline.serve"):

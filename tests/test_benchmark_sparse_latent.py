@@ -342,7 +342,8 @@ def test_the_cell_reports_the_metric_and_its_files_are_there(name):
     entry = next(m for m in resolved["per_layer"] if m["name"] == name)
     assert entry["moves"] == "serve_tokens_per_s"
     if name in NEW_METRICS:
-        assert entry["workloads"] == [CELL]
+        # (a later PR's cell may follow where the reader counts it right)
+        assert entry["workloads"][0] == CELL
     else:
         # appended after the cells accepted before it (a later PR's
         # cell may follow)

@@ -1340,3 +1340,93 @@ def test_parallel_moe_wave_programs_at_the_published_widths(sds, monkeypatch,
     if L == conf["num_hidden_layers"]:
         stated = conf["memory_analysis"][f"slots_{B}_Q_{q_len}"]
         assert abs(peak / 1e9 - stated["peak_GB"]) < 0.3
+
+
+# ------------------------------------------------------------------- #
+# ISSUE 58: delta-rule layers beside latent attention at the digest
+# cell's sizes
+# ------------------------------------------------------------------- #
+
+@pytest.mark.parametrize("q_len,group", [
+    (256, 2),
+    pytest.param(1, 2, marks=pytest.mark.slow),
+    pytest.param(256, None, marks=pytest.mark.slow),
+    pytest.param(1, None, marks=pytest.mark.slow)],
+    ids=["Q256-one_of_each", "Q1-one_of_each", "Q256-period", "Q1-period"])
+def test_kda_latent_wave_programs_at_the_published_widths(sds, monkeypatch,
+                                                          q_len, group):
+    """The digest cell's two programs (its prompts are multiples of the
+    chunk: ONE chunk program, Q 256, beside the decode program) at the
+    published widths, the cell's 48 slots (exact: a manager with state
+    does not round them) and its latent pool through ``serve_mixed_paged_fn``: one KDA
+    layer (with a dense FFN) beside the MLA layer (with the experts)
+    under a vocabulary of 512, or ``slow`` the whole period of six and
+    the head over 39,296 columns.  The chunked delta rule's ``while`` and
+    its triangular solve compile for the chip; ``ragged_paged_mla`` once
+    (the packed rows' entry in the chunk program) over rows of 640, the
+    128 held experts through ``moe_grouped_matmul`` (every Pallas call a
+    Mosaic one: ``_use_interpret`` off in every kernel module the wave
+    can reach); the latent pool and the twelve (or two) states updated in
+    place.  The configuration's ``memory_analysis`` states the period's
+    compiles."""
+    import json
+    import os
+    from hetu_tpu.kernels import grouped_matmul as gm
+    from hetu_tpu.kernels import paged_kv_write as pw
+    from hetu_tpu.kernels import ragged_attention as ra
+    from hetu_tpu.kernels import ssm_step as ss
+    from hetu_tpu.models.kda_latent import F32_LEAVES, KDALatentConfig
+    for module in (ra, gm, pw, ss):
+        monkeypatch.setattr(module, "_use_interpret", lambda: False)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "configs",
+                           "ling-3.0-flash-vl.json")) as f:
+        conf = json.load(f)
+    args, dep, pub = (conf["runner_args"], conf["deployment"],
+                      conf["published"])
+    vocab, vocab_rows = pub["vocab_size"], tuple(dep["vocab_rows_held"])
+    over = {}
+    if group:
+        over = dict(layer_group_size=group, num_hidden_layers=group,
+                    first_k_dense_replace=1)
+        vocab, vocab_rows = 512, None
+    cfg = KDALatentConfig.from_hf(
+        dict(conf, num_experts=pub["num_experts"], vocab_size=vocab, **over),
+        held_experts=tuple(dep["experts_held"]), vocab_rows=vocab_rows)
+    blk = cfg.block_spec()
+    L, S = cfg.num_hidden_layers, args["max_seq_len"]
+    B, T, N = args["slots"], S // BLOCK, args["pool_blocks"]
+    kda = blk.op_layers(L, "kda")
+    assert (B, blk.op_layers(L, "pool"), kda) == (48, 1, L - 1)
+    params = {k: sds(s, jnp.float32 if k.endswith(F32_LEAVES)
+                     else jnp.bfloat16)
+              for k, s in cfg.param_shapes("lng").items()}
+    pool = sds((1, N, BLOCK, 640), jnp.bfloat16)
+    state = tuple(sds((shape[0], B) + shape[1:], dtype or jnp.bfloat16)
+                  for shape, dtype in blk.state_shapes(L, cfg.hidden_size))
+    assert state[-1].shape == (1, B, 32, 128, 128) \
+        and state[-1].dtype == jnp.float32
+    i32 = lambda *shape: sds(shape, jnp.int32)  # noqa: E731
+    fn = gd.serve_mixed_paged_fn(donate=True, attn="ragged", window=1)
+    compiled = fn.func.lower(
+        params, ("lng", L, 32, 128, S, blk), pool, None, i32(B, T), i32(B),
+        i32(B, q_len), i32(B), i32(B), sds((B,), jnp.bool_),
+        sds((B,), jnp.float32), i32(B), sds((B, 2), jnp.uint32),
+        attn="ragged", window=1, has_fresh=q_len > 1, state=state).compile()
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines() if "custom-call(" in line]
+    assert sum("ragged_paged_mla" in c for c in calls) == 1
+    routed = L - cfg.first_k_dense_replace
+    assert sum("moe_grouped_matmul" in c for c in calls) == 2 * routed
+    assert "ragged-dot" not in text
+    mem = compiled.memory_analysis()
+    held = N * BLOCK * 640 * 2 + sum(
+        int(np.prod(s.shape)) * s.dtype.itemsize for s in state)
+    assert mem.alias_size_in_bytes >= held
+    assert mem.temp_size_in_bytes < 1.5e9
+    peak = mem.argument_size_in_bytes + mem.output_size_in_bytes \
+        - mem.alias_size_in_bytes + mem.temp_size_in_bytes
+    assert peak < 12.0e9
+    if not group:
+        stated = conf["memory_analysis"][f"slots_{B}_Q_{q_len}"]
+        assert abs(peak / 1e9 - stated["peak_GB"]) < 0.3

@@ -32,6 +32,7 @@ import pytest
 from test_chip_compile import (  # noqa: E402,F401
     no_compile_cache, sds, strip_kernel_locations, topo)
 from test_hybrid_moe import digest, wave_programs
+from test_kda_latent import kda_latent_programs
 from test_nemotron_h import nemotron_programs
 from test_parallel_moe import parallel_moe_programs
 from test_retention import retention_programs, window_programs
@@ -95,6 +96,8 @@ FAMILIES = {
         lambda s: {k: low for k, low in parallel_moe_programs(
             s, "ragged", qs=(32,), slots=16).items()
             if k.endswith("fresh1")}, True),
+    "KDA_LATENT_MASKED": (lambda s: kda_latent_programs(s, "masked"), False),
+    "KDA_LATENT_RAGGED": (lambda s: kda_latent_programs(s, "ragged"), True),
 }
 
 PARENT = {
@@ -345,6 +348,28 @@ PARENT = {
         "parallel_moe.Q32.fresh1": "9c73deba737ec37f"},
     "PARALLEL_MOE_PACKED_RAGGED": {
         "parallel_moe.Q32.fresh1": "d16b0b872de79b9a"},
+    # PR 58's own, no parent's: tests/test_kda_latent.py's small model of
+    # four layers (the ``Ling-3.0-flash`` family: KDA, KDA, MLA, KDA in ONE
+    # latent block: the gated delta rule's step and, in the Q 32 bucket,
+    # its chunked form's ``while`` and ``triangular_solve`` over the
+    # manager's six states beside ONE latent pool layer whose query has no
+    # low-rank step; a group-limited sigmoid router, ``moe_group_select``,
+    # over 4 of 16 experts held).  4 slots x 32 rows stay padded and take
+    # ``ragged_paged_mla``; a latent wave has no fresh variant, so a
+    # bucket's two programs are one text.  Every entry above is the
+    # parent's: the new ``BlockSpec.kda``, ``LatentSpec.q_lora_rank`` 0 and
+    # ``RoutedSpec.n_group`` / ``topk_group`` trace nothing at their
+    # defaults.
+    "KDA_LATENT_MASKED": {
+        "kda_latent.Q1.fresh0": "a4214555e594b6bb",
+        "kda_latent.Q1.fresh1": "a4214555e594b6bb",
+        "kda_latent.Q32.fresh0": "03d16ee894843498",
+        "kda_latent.Q32.fresh1": "03d16ee894843498"},
+    "KDA_LATENT_RAGGED": {
+        "kda_latent.Q1.fresh0": "610eca4a3607a5ef",
+        "kda_latent.Q1.fresh1": "610eca4a3607a5ef",
+        "kda_latent.Q32.fresh0": "d7d792f69ba556c3",
+        "kda_latent.Q32.fresh1": "d7d792f69ba556c3"},
 }
 
 
