@@ -57,8 +57,19 @@ at a time.  A dead row and a dead slot have ``g`` 0, ``beta`` 0 and ``k``
 matrix products take their operands in the activations' dtype and
 accumulate in float32, but for the two that READ the carried state (``W
 S`` and ``(q e^G) S``: float32 operands at precision highest, as the
-one-row step reads it); the state is read, decayed, corrected and stored
-in float32.
+one-row step reads it); the solve is float32; the state is read, decayed,
+corrected and stored in float32.
+
+What runs the chunked form follows the program's shape alone
+(``takes_kernel``): where the head is a whole number of lane tiles (the
+published model's 128) and the q-block whole chunks, the Pallas kernel
+``kernels/kda_scan.kda_chunk_scan`` (ISSUE 59) takes the lanes' rows as
+gathered and reads and rewrites the lanes' states where they lie in the
+manager's array, the same mathematics at the same precisions with the
+decays, both score matrices and the solve in VMEM; everywhere else
+(narrow heads, the CPU's small models, a q-block of a few rows)
+``kda_chunked`` as XLA's own operations round slices of the state, which
+is also the form the kernel is held to (``tests/test_kda_kernel.py``).
 
 Scopes: ``kda_qkvg`` (the norm, the projections, decay and beta),
 ``kda_conv`` (the three tails' mix and write, SiLU, the L2
@@ -230,6 +241,19 @@ def kda_chunked(q, k, v, g, beta, S):
 WIDE_LANES = 3
 
 
+def takes_kernel(head_dim, q_block):
+    """The shape rule: whether a program whose q-blocks are ``q_block``
+    rows wide runs the wide slots' chunked form through
+    ``kernels/kda_scan`` (else through ``kda_chunked``).  A head of whole
+    lane tiles (a head's rows are a column block of the wave's: the
+    published model's 128) and a q-block wider than one row that is
+    whole chunks.  Static shapes alone decide, so a program is one or
+    the other, and the engine can ask the same question of a wave
+    (``serve.kda.kernel_chunk_rows``)."""
+    from ..kernels._shared import _LANES
+    return head_dim % _LANES == 0 and q_block > 1 and q_block % CHUNK == 0
+
+
 def kda_mixer(sp, q, k, v, g, beta, state, si, q_len, rows=None):
     """One layer's delta rule over the wave's rows: ``q`` / ``k`` / ``v``
     [B, Q, H, D] after the conv and the normalisation, ``g`` [B, Q, H, D]
@@ -242,8 +266,10 @@ def kda_mixer(sp, q, k, v, g, beta, state, si, q_len, rows=None):
     discipline).  Every slot with ONE live row takes ``kda_step`` at its
     row, the whole batch at once.  The slots with a wider q-block are
     taken ``WIDE_LANES`` at a time, widest first, by a ``while_loop`` that
-    gathers their rows and states, runs ``kda_chunked`` and writes both
-    back.  Returns (o [.., H D] float32 laid out as ``q``, state)."""
+    gathers their rows and runs ``kda_chunk_scan`` on the states where
+    they lie or, where ``takes_kernel`` says no, ``kda_chunked`` on
+    slices of them, written back.  Returns (o [.., H D] float32 laid out
+    as ``q``, state)."""
     H, D = sp.heads, sp.head_dim
     n_state = len(state) // 2
     mats = state[n_state + si]
@@ -281,6 +307,7 @@ def kda_mixer(sp, q, k, v, g, beta, state, si, q_len, rows=None):
             lanes = math.gcd(WIDE_LANES, B_)     # divides the slots
             order = jnp.argsort(-q_len)                    # widest first
             n_wide = jnp.sum(q_len > 1)
+            kernel = takes_kernel(D, Q)
 
             def wide(carry):
                 j0, mats, y_f = carry
@@ -292,23 +319,34 @@ def kda_mixer(sp, q, k, v, g, beta, state, si, q_len, rows=None):
                 at = start[slot][:, None] + jnp.arange(Q)[None, :]
                 live = jnp.arange(Q)[None, :] < ql[:, None]  # [lanes, Q]
                 got = jnp.minimum(at, R - 1)
-                kc = jnp.where(live[..., None, None], k_f[got], 0)
-                gc = jnp.where(live[..., None, None], g_f[got], 0.0)
-                bc = jnp.where(live[..., None], b_f[got], 0.0)
-                # a lane's state by a slice of its own: a gather over
-                # the slots makes the compiler copy the whole state
-                S0 = jnp.concatenate([jax.lax.dynamic_slice(
-                    mats, (0, slot[j], 0, 0, 0), (1, 1, H, D, D))[0]
-                    for j in range(lanes)]).astype(f32)
-                yc, Sc = kda_chunked(q_f[got], kc, v_f[got], gc, bc, S0)
-                # every read of the lanes' old states ends here, before
-                # the writes below overwrite them in place
-                yc, Sc = jax.lax.optimization_barrier((yc, Sc))
-                with jax.named_scope("state_write"):
-                    for j in range(lanes):
-                        mats = jax.lax.dynamic_update_slice(
-                            mats, Sc[j].astype(kept)[None, None],
-                            (0, slot[j], 0, 0, 0))
+                if kernel:
+                    # the lanes' states read and rewritten where they
+                    # lie, once, from the rows as gathered (the kernel
+                    # masks those past ``ql``: ``kernels/kda_scan``)
+                    from ..kernels.kda_scan import kda_chunk_scan
+                    yc, mats = kda_chunk_scan(
+                        slot, ql, *(a.reshape(R, H * D)[got]
+                                    for a in (q, k, v, g)), b_f[got], mats,
+                        chunk=CHUNK, sub=SUB)
+                else:
+                    kc = jnp.where(live[..., None, None], k_f[got], 0)
+                    gc = jnp.where(live[..., None, None], g_f[got], 0.0)
+                    bc = jnp.where(live[..., None], b_f[got], 0.0)
+                    # a lane's state by a slice of its own: a gather
+                    # over the slots makes the compiler copy the whole
+                    # state
+                    S0 = jnp.concatenate([jax.lax.dynamic_slice(
+                        mats, (0, slot[j], 0, 0, 0), (1, 1, H, D, D))[0]
+                        for j in range(lanes)]).astype(f32)
+                    yc, Sc = kda_chunked(q_f[got], kc, v_f[got], gc, bc, S0)
+                    # every read of the lanes' old states ends here,
+                    # before the writes below overwrite them in place
+                    yc, Sc = jax.lax.optimization_barrier((yc, Sc))
+                    with jax.named_scope("state_write"):
+                        for j in range(lanes):
+                            mats = jax.lax.dynamic_update_slice(
+                                mats, Sc[j].astype(kept)[None, None],
+                                (0, slot[j], 0, 0, 0))
                 # a lane's live rows into the wave's, by a slice of its
                 # own (``retention_mixer``)
                 for j in range(lanes):

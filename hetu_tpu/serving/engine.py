@@ -52,6 +52,7 @@ from ..kernels.ragged_attention import (
     mla_rows_tiling, mla_tiling, row_tile_visits, rows_packed_tiling,
     rows_tiling, tile_heights)
 from ..models.moe_decode import takes_kernel
+from ..models.kda_decode import takes_kernel as kda_takes_kernel
 from ..models.retention_decode import takes_kernel as retention_takes_kernel
 from ..models.ssm_decode import takes_kernel as ssm_takes_kernel
 from .kv_manager import (PagedKVManager, assemble_mixed_wave,
@@ -603,8 +604,10 @@ class ServingEngine:
         out = {}
         wide = np.where(ql > 1, ql, 0)
         if self._kda_layers:
-            self.metrics.record_kda(int((ql == 1).sum()), int(wide.sum()),
-                                    self._kda_layers)
+            self.metrics.record_kda(
+                int((ql == 1).sum()), int(wide.sum()), self._kda_layers,
+                kda_takes_kernel(self.block_spec.kda.head_dim,
+                                 int(wave["q"])))
         for kind, layers, spec in (
                 ("ssm", self._ssm_layers, self.block_spec.ssm),
                 ("ret", self._ret_layers, self.block_spec.retention)):

@@ -232,6 +232,7 @@ class ServingMetrics(MetricsCore):
         # an engine with delta-rule layers (``record_kda``)
         self.kda_slot_steps = 0
         self.kda_chunk_rows = 0
+        self.kda_kernel_chunk_rows = 0
         self.wave_rows_live = 0
         self.wave_rows_computed = 0
         self.chunks_deferred = 0
@@ -420,21 +421,27 @@ class ServingMetrics(MetricsCore):
         return {"slot_steps": steps, "rows": rows,
                 "live_slots": int(live_slots), "layers": int(layers)}
 
-    def record_kda(self, one_row_slots, chunk_rows, layers):
+    def record_kda(self, one_row_slots, chunk_rows, layers, kernel=False):
         """One wave of an engine with ``layers`` delta-rule layers
         (``kda_decode``): ``one_row_slots`` (slots with ONE live row: each
         one's state takes one step of the recurrence a layer, read and
         written once) and ``chunk_rows`` (the live rows of the q-blocks
-        wider than one row: what the chunked form runs over).  Running
-        sums ``kda_slot_steps`` and ``kda_chunk_rows`` (each x layers)
-        here, the counters ``serve.kda.slot_steps`` and
-        ``serve.kda.chunk_rows`` in ``telemetry``."""
+        wider than one row: what the chunked form runs over); ``kernel``
+        whether the wave's program runs that form through
+        ``kernels/kda_scan`` (``kda_decode.takes_kernel`` of its head and
+        q-block).  Running sums ``kda_slot_steps``, ``kda_chunk_rows``
+        and ``kda_kernel_chunk_rows`` (each x layers) here, the counters
+        ``serve.kda.slot_steps``, ``serve.kda.chunk_rows`` and
+        ``serve.kda.kernel_chunk_rows`` in ``telemetry``."""
         steps = int(one_row_slots) * int(layers)
         rows = int(chunk_rows) * int(layers)
         self.kda_slot_steps += steps
         self.kda_chunk_rows += rows
         telemetry.inc("serve.kda.slot_steps", steps)
         telemetry.inc("serve.kda.chunk_rows", rows)
+        if kernel and rows:
+            self.kda_kernel_chunk_rows += rows
+            telemetry.inc("serve.kda.kernel_chunk_rows", rows)
 
     def record_routed(self, load, touched, kernel=False, routed=None):
         """One wave of a dropless routed engine: ``load`` [held experts]
@@ -760,6 +767,7 @@ class ServingMetrics(MetricsCore):
                     "ret_slot_steps", "ret_rows", "ret_chunk_pairs",
                     "ret_kernel_slot_steps",
                     "kda_slot_steps", "kda_chunk_rows",
+                    "kda_kernel_chunk_rows",
                     "wave_rows_live", "wave_rows_computed",
                     "chunks_deferred", "waves_ahead", "rows_dead_ahead",
                     "kv_write_pages", "kv_write_rows")
@@ -857,6 +865,7 @@ class ServingMetrics(MetricsCore):
             "ret_kernel_slot_steps": count("ret_kernel_slot_steps"),
             "kda_slot_steps": count("kda_slot_steps"),
             "kda_chunk_rows": count("kda_chunk_rows"),
+            "kda_kernel_chunk_rows": count("kda_kernel_chunk_rows"),
             "wave_rows_live": count("wave_rows_live"),
             "wave_rows_computed": count("wave_rows_computed"),
             "chunks_deferred": count("chunks_deferred"),

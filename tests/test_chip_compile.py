@@ -1347,6 +1347,33 @@ def test_parallel_moe_wave_programs_at_the_published_widths(sds, monkeypatch,
 # cell's sizes
 # ------------------------------------------------------------------- #
 
+@pytest.mark.parametrize("kept", ["float32", "bfloat16"])
+def test_kda_chunk_scan_at_the_cells_sizes(sds, kept):
+    """Three lanes of 256 rows x 32 heads of 128 over the state of 48
+    slots (float32 as the configuration keeps it, bfloat16 as the control
+    the comparison refuses does): the kernel compiles for the chip, the
+    state aliased, no temporary (``S`` is carried across a q-block's
+    chunks in 64 KB of VMEM scratch, which is no temporary of the
+    program's)."""
+    from hetu_tpu.kernels import kda_scan as ks
+    from hetu_tpu.models import kda_decode as kd
+    lanes, Q, H, D, slots = kd.WIDE_LANES, 256, 32, 128, 48
+    bf, f32, kept = jnp.bfloat16, jnp.float32, jnp.dtype(kept)
+    assert kd.takes_kernel(D, Q)
+    rows = sds((lanes, Q, H * D), bf)
+    args = (sds((lanes,), jnp.int32), sds((lanes,), jnp.int32), rows, rows,
+            rows, sds((lanes, Q, H * D), f32), sds((lanes, Q, H), f32),
+            sds((1, slots, H, D, D), kept))
+    compiled = jax.jit(
+        lambda *a: ks._kda_chunk_scan_call(
+            *a, chunk=kd.CHUNK, sub=kd.SUB, interpret=False),
+        donate_argnums=(7,)).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= slots * H * D * D * kept.itemsize
+    assert mem.temp_size_in_bytes == 0
+
+
 @pytest.mark.parametrize("q_len,group", [
     (256, 2),
     pytest.param(1, 2, marks=pytest.mark.slow),
@@ -1361,8 +1388,10 @@ def test_kda_latent_wave_programs_at_the_published_widths(sds, monkeypatch,
     does not round them) and its latent pool through ``serve_mixed_paged_fn``: one KDA
     layer (with a dense FFN) beside the MLA layer (with the experts)
     under a vocabulary of 512, or ``slow`` the whole period of six and
-    the head over 39,296 columns.  The chunked delta rule's ``while`` and
-    its triangular solve compile for the chip; ``ragged_paged_mla`` once
+    the head over 39,296 columns.  The chunk program runs the chunked
+    delta rule through ``kda_chunk_scan`` (ISSUE 59: ONE lowering, a call
+    a KDA layer, no triangular solve left in the program); the decode
+    program holds neither; ``ragged_paged_mla`` once
     (the packed rows' entry in the chunk program) over rows of 640, the
     128 held experts through ``moe_grouped_matmul`` (every Pallas call a
     Mosaic one: ``_use_interpret`` off in every kernel module the wave
@@ -1372,11 +1401,12 @@ def test_kda_latent_wave_programs_at_the_published_widths(sds, monkeypatch,
     import json
     import os
     from hetu_tpu.kernels import grouped_matmul as gm
+    from hetu_tpu.kernels import kda_scan as ks
     from hetu_tpu.kernels import paged_kv_write as pw
     from hetu_tpu.kernels import ragged_attention as ra
     from hetu_tpu.kernels import ssm_step as ss
     from hetu_tpu.models.kda_latent import F32_LEAVES, KDALatentConfig
-    for module in (ra, gm, pw, ss):
+    for module in (ra, gm, pw, ss, ks):
         monkeypatch.setattr(module, "_use_interpret", lambda: False)
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     with open(os.path.join(root, "benchmarks", "configs",
@@ -1408,13 +1438,22 @@ def test_kda_latent_wave_programs_at_the_published_widths(sds, monkeypatch,
         and state[-1].dtype == jnp.float32
     i32 = lambda *shape: sds(shape, jnp.int32)  # noqa: E731
     fn = gd.serve_mixed_paged_fn(donate=True, attn="ragged", window=1)
-    compiled = fn.func.lower(
+    lowered = fn.func.lower(
         params, ("lng", L, 32, 128, S, blk), pool, None, i32(B, T), i32(B),
         i32(B, q_len), i32(B), i32(B), sds((B,), jnp.bool_),
         sds((B,), jnp.float32), i32(B), sds((B, 2), jnp.uint32),
-        attn="ragged", window=1, has_fresh=q_len > 1, state=state).compile()
+        attn="ragged", window=1, has_fresh=q_len > 1, state=state)
+    # the KDA layers share one trace and one Mosaic lowering of the scan
+    assert lowered.as_text().count(
+        "func.func private @_kda_chunk_scan_call") == (1 if q_len > 1 else 0)
+    compiled = lowered.compile()
     text = compiled.as_text()
     calls = [line for line in text.splitlines() if "custom-call(" in line]
+    scans = [c for c in calls if "kda_chunk_scan" in c]
+    assert len(scans) == (kda if q_len > 1 else 0)
+    assert all("tpu_custom_call" in c for c in scans)
+    assert not any("triangular" in c.lower() or "InvertDiagBlocks" in c
+                   for c in calls)
     assert sum("ragged_paged_mla" in c for c in calls) == 1
     routed = L - cfg.first_k_dense_replace
     assert sum("moe_grouped_matmul" in c for c in calls) == 2 * routed

@@ -359,7 +359,11 @@ PARENT = {
     # bucket's two programs are one text.  Every entry above is the
     # parent's: the new ``BlockSpec.kda``, ``LatentSpec.q_lora_rank`` 0 and
     # ``RoutedSpec.n_group`` / ``topk_group`` trace nothing at their
-    # defaults.
+    # defaults.  PR 59 (the chunked form as the kernel ``kda_chunk_scan``
+    # wherever ``kda_decode.takes_kernel`` says so): these eight STAND as
+    # PR 58 wrote them, because the small model's heads are 16 wide (and
+    # a q-block of 32 is no whole chunk of 64), so its programs keep
+    # ``kda_chunked``, whose trace ``kda_mixer`` leaves as it was.
     "KDA_LATENT_MASKED": {
         "kda_latent.Q1.fresh0": "a4214555e594b6bb",
         "kda_latent.Q1.fresh1": "a4214555e594b6bb",
