@@ -1,12 +1,11 @@
 #!/usr/bin/env python
 """Repo lint gate CLI (hetu_tpu/analysis/lint.py rules).
 
-    python bin/hetu_lint.py hetu_tpu/ bench.py      # lint, exit != 0 on findings
+    python bin/hetu_lint.py hetu_tpu/ bin/  # lint, exit != 0 on findings
     python bin/hetu_lint.py --env-table             # HETU_* doc table (markdown)
     python bin/hetu_lint.py --rules env-registry hetu_tpu/
 
-Runs without jax/device initialization: the rules are pure-AST, so this
-is safe (and fast) as the first stage of the on-chip suite.
+Runs without jax/device initialization: the rules are pure-AST.
 """
 
 import os

@@ -4,8 +4,8 @@ Reference counterpart: ps-lite's multi-worker keyed RPC throughput
 (tests/pstests/test_bandwidth.py pattern).  One TCP PSServer on
 localhost, N worker PROCESSES each hammering sd_pushpull on a shared
 embedding table (zipf-skewed ids, the CTR regime); reports aggregate
-embedding rows/s per worker count and writes BENCH_PS_SCALING.json next
-to this script (the artifact the round records).
+embedding rows/s per worker count and writes BENCH_PS_SCALING.json into
+the directory it is run from.
 
 Run: python examples/ctr/bench_ps_scaling.py [--rows 1000000]
 """
@@ -24,6 +24,9 @@ import numpy as np
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.dirname(os.path.abspath(__file__)))))
+
+
+_OUT = "BENCH_PS_SCALING.json"     # written where the bench is run
 
 
 def _free_port():
@@ -181,7 +184,7 @@ def quant_ab(iters=20, dense_shape=(512, 1024), sparse_batch=4096,
         "config": {"dense_shape": list(dense_shape),
                    "sparse_batch": sparse_batch, "dim": dim,
                    "rows": rows, "iters": iters,
-                   "chunk": quant.wire_chunk(),
+                   "chunk": quant.DEFAULT_CHUNK,
                    "traffic": "dense push + dense pull + sparse "
                               "sd_pushpull per round",
                    "counters": "ps.rpc.bytes_sent/recv (PR 5), "
@@ -197,9 +200,7 @@ def quant_ab(iters=20, dense_shape=(512, 1024), sparse_batch=4096,
     assert ratio >= 3.5, (
         f"int8 PS wire reduction {ratio}x below the 3.5x acceptance "
         f"floor: {exact} vs {int8}")
-    path = os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                        "..", "..", "BENCH_PS_SCALING.json")
-    path = os.path.abspath(path)
+    path = _OUT
     try:
         with open(path) as f:
             art = json.load(f)
@@ -349,9 +350,7 @@ def main():
         "scaling_vs_base": {k: round(r["aggregate_rows_per_sec"] / base, 2)
                             for k, r in results.items()},
     }
-    path = os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                        "..", "..", "BENCH_PS_SCALING.json")
-    with open(os.path.abspath(path), "w") as f:
+    with open(_OUT, "w") as f:
         json.dump(out, f, indent=1)
     print(json.dumps(out["scaling_vs_base"]))
 

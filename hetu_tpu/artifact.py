@@ -1,11 +1,9 @@
-"""Shared persistence discipline for measurement artifacts.
+"""How a measured record reaches its file.
 
-One rule, applied by bench.py's sweep modes AND the planner's chip
-calibration: a degraded run (reduced scale, or not on real TPU) never
-overwrites a full-scale TPU record, and a run that produced no data
-never overwrites a record that has some.  Centralized here so the two
-consumers cannot drift (review r5: chip_calibration's hand copy had
-already lost the reduced-scale half).
+Two rules: a record is written whole or not at all, and a degraded run
+(reduced scale, or not on a real TPU) never overwrites a full-scale TPU
+record.  The planner's chip calibration and environment profile write
+through here.
 """
 
 from __future__ import annotations
@@ -17,9 +15,9 @@ import tempfile
 
 def atomic_json_dump(path, obj, indent=1):
     """Write JSON via a same-directory temp file + os.replace: a
-    process killed mid-write (the suite's per-stage timeouts SIGTERM
-    bench.py wherever it is) must never leave a truncated record that
-    a later run silently discards and overwrites."""
+    process killed mid-write, or a dump that raises, must never leave a
+    truncated record that a later run silently discards and
+    overwrites."""
     d = os.path.dirname(os.path.abspath(path)) or "."
     fd, tmp = tempfile.mkstemp(dir=d, suffix=".tmp")
     try:
@@ -34,14 +32,10 @@ def atomic_json_dump(path, obj, indent=1):
         raise
 
 
-def persist_artifact(path, art, reduced, has_data=True):
+def persist_artifact(path, art, reduced):
     """Write ``art`` (a JSON-able dict) to ``path`` unless doing so
-    would degrade the record:
-
-    * ``reduced`` runs (small shapes, or a non-TPU backend) never
-      replace an existing full-scale TPU record;
-    * an all-error run (``has_data=False``) never replaces a record
-      that has data.
+    would degrade the record: a ``reduced`` run (small shapes, or a
+    non-TPU backend) never replaces an existing full-scale TPU record.
 
     When skipped, sets ``art['not_written']`` with the reason and
     returns False; otherwise writes and returns True.
@@ -52,15 +46,11 @@ def persist_artifact(path, art, reduced, has_data=True):
             existing = json.load(f)
     except (OSError, ValueError):
         pass
-    if isinstance(existing, dict):
-        if (not existing.get("reduced_scale")
-                and existing.get("platform") == "tpu" and reduced):
-            art["not_written"] = ("full-scale TPU record already "
-                                  "present; reduced run not persisted")
-            return False
-        if not has_data:
-            art["not_written"] = ("run produced no measured data; "
-                                  "keeping the existing record")
-            return False
+    if (isinstance(existing, dict) and reduced
+            and not existing.get("reduced_scale")
+            and existing.get("platform") == "tpu"):
+        art["not_written"] = ("full-scale TPU record already "
+                              "present; reduced run not persisted")
+        return False
     atomic_json_dump(path, art)
     return True

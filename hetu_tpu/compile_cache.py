@@ -3,9 +3,9 @@ building them cost this one.
 
 Two things, one module.  ``enable_compile_cache()`` is the one helper
 for every entry point that compiles on the chip (``chip_smoke.py``,
-``bench.py``, the examples): JAX's persistent compilation cache, placed
-from outside when the environment says where and otherwise at one fixed
-place inside the checkout.  The directory is part of the cache key, so
+``benchmarks/run.py``, the examples): JAX's persistent compilation
+cache, placed from outside when the environment says where and
+otherwise at one fixed place inside the checkout.  The directory is part of the cache key, so
 it must not move between runs: never ``/tmp``, a pid or a timestamp.
 
 ``watch()`` is the one place ``hetu_tpu`` listens to ``jax.monitoring``

@@ -1,13 +1,10 @@
 """Central typed registry for every ``HETU_*`` environment variable.
 
-Before this module the repo had ~60 scattered ``os.environ`` reads with
-per-site defaults and per-site parsing (``!= "0"`` here, ``bool(get())``
-there, ``.lower() not in (...)`` elsewhere) — undocumented drift the
-README could not keep up with.  Now every knob is REGISTERED once with a
-type, default, and help string, and every read goes through a typed
-getter; ``bin/hetu_lint.py`` (rule ``env-registry``) rejects any new raw
-``os.environ['HETU_*']`` read outside this file, and ``--env-table``
-regenerates the README's knob table from the registry.
+Every knob is REGISTERED once with a type, default, and help string,
+and every read goes through a typed getter; ``bin/hetu_lint.py`` (rule
+``env-registry``) rejects any new raw ``os.environ['HETU_*']`` read
+outside this file, and ``--env-table`` regenerates the README's knob
+table from the registry.
 
 Getters re-read ``os.environ`` on every call (no import-time caching):
 tests and the chaos harness toggle vars at runtime and must observe the
@@ -15,8 +12,9 @@ change.  Reading an UNREGISTERED name raises — adding the registry row
 (one line, with help text) is the price of a new knob.
 
 Boolean parsing is uniform: unset → default; ``"" / 0 / false / no /
-off`` (case-insensitive) → False; anything else → True.  This subsumes
-the three ad-hoc spellings the call sites used to have.
+off`` (case-insensitive) → False; anything else → True.
+
+What may be a knob at all: see ``DEPLOYMENT`` below the registry.
 """
 
 from __future__ import annotations
@@ -45,7 +43,7 @@ def _reg(name, type_, default, help_, section):
 
 
 # --------------------------------------------------------------------- #
-# static checks (this PR's subsystem)
+# static checks (hetu_tpu/analysis/)
 # --------------------------------------------------------------------- #
 _reg("HETU_VALIDATE", "bool", False,
      "Run the pre-trace graph verifier + parallelism checker at executor/"
@@ -115,12 +113,6 @@ _reg("HETU_SLO_TPS", "float", None,
      "Throughput-bound SLO: each finished request's per-stream decode "
      "rate (tokens/second after the first token) must be at least "
      "this.  Unset = no throughput SLO.", "slo")
-_reg("HETU_SLO_OBJECTIVE", "float", 0.99,
-     "Fraction of requests that must meet each SLO target (the error "
-     "budget is 1 - objective).", "slo")
-_reg("HETU_SLO_WINDOW", "int", 256,
-     "Sliding-window size (finished requests) for SLO burn-rate "
-     "tracking.", "slo")
 
 # --------------------------------------------------------------------- #
 # multi-process / TPU bring-up
@@ -159,9 +151,6 @@ _reg("HETU_PS_CONNECT_TIMEOUT", "float", 10.0,
      "TCP connect timeout (seconds).", "ps")
 _reg("HETU_PS_RETRIES", "int", 3,
      "Resend attempts before PSConnectionError surfaces.", "ps")
-_reg("HETU_PS_BACKLOG_STEPS", "int", 32,
-     "Max training steps of push traffic buffered through a PS outage "
-     "(direct hybrid path) before the run fails.", "ps")
 _reg("HETU_PS_REPLICATE", "bool", False,
      "Ring-replicate every key to its backup server ((s+1) % N) and "
      "fail over on primary loss (sharded client, N > 1).", "ps")
@@ -253,32 +242,17 @@ _reg("HETU_KV_BLOCK", "str", "auto",
      "Paged KV cache: the block-table pool's block size (tokens per "
      "block), a positive integer; auto = 16.  0 or less is refused "
      "(the slot-contiguous layout it selected is gone).", "serving")
-_reg("HETU_KV_PREFIX_SHARE", "bool", True,
-     "Paged KV: refcounted copy-on-write sharing of common prompt "
-     "prefixes — N requests with the same system prompt store its KV "
-     "blocks once (registered prefixes are LRU-evicted under pool "
-     "pressure).", "serving")
 _reg("HETU_SPEC_K", "int", 0,
      "Speculative decoding: a truncated-layer draft proposes up to this "
      "many tokens per slot per wave and the target verifies all k+1 "
      "positions in ONE batched step (longest-prefix acceptance + bonus "
      "token; outputs token-identical to plain decoding).  0 = off; "
      "ServingEngine(spec=)/generate_fast(spec=) override.", "serving")
-_reg("HETU_SPEC_ADAPT", "bool", True,
-     "Adaptive speculation depth: a sliding acceptance-rate window "
-     "moves the per-wave draft length through the pow2 ladder "
-     "1..HETU_SPEC_K (raise on sustained high acceptance, back off on "
-     "low).  0 pins the configured k.", "serving")
 _reg("HETU_SPEC_DRAFT_LAYERS", "int", 0,
      "Truncated-layer draft depth: the draft model is the target's "
      "first N blocks plus the shared final LN and tied embedding head "
      "(no separate weights or tokenizer).  0 = auto: max(1, L // 4).",
      "serving")
-_reg("HETU_KV_CHUNK", "int", 0,
-     "Paged KV chunked prefill: prompts fill their blocks in chunks of "
-     "this many tokens interleaved with decode waves, so a long prompt "
-     "does not stall running generations (0 = whole prompt in one "
-     "pass).", "serving")
 _reg("HETU_KV_HOST_BYTES", "int", 0,
      "Tiered KV: host-RAM ring capacity in bytes for refcount-zero "
      "prefix blocks spilled out of the HBM pool (LRU; oldest entries "
@@ -302,14 +276,6 @@ _reg("HETU_MOE_QUANT", "str", None,
      "expert exchange, the HETU_COMM_QUANT codec; empty/0/off = full "
      "precision).  Applies to the explicit shard_map EP reference "
      "path.", "serving")
-_reg("HETU_EMBED_WAVE", "int", 8,
-     "Embedding serving: max requests the engine claims per scoring "
-     "wave (one embedding gather + one jitted tower forward per wave; "
-     "EmbedServingEngine(wave=) overrides).", "serving")
-_reg("HETU_EMBED_QUEUE", "int", 64,
-     "Embedding serving: bounded admission-queue depth — submit "
-     "raises QueueFull past it (EmbedServingEngine(queue_limit=) "
-     "overrides).", "serving")
 
 # --------------------------------------------------------------------- #
 # serving fleet router (serving/router.py)
@@ -318,45 +284,6 @@ _reg("HETU_REPLICAS", "int", 2,
      "Default fleet size for ServingRouter: how many supervised "
      "ServingEngine replicas the router builds from its factory "
      "(constructor replicas= overrides).", "router")
-_reg("HETU_ROUTER_AFFINITY", "bool", True,
-     "Session affinity: hash Request.session_id to a stable home "
-     "replica so a returning session's shared-prefix KV blocks stay "
-     "hot (remapped with a prefix_misses count when the home replica "
-     "is unroutable).", "router")
-_reg("HETU_ROUTER_STALE", "float", 0.0,
-     "> 0: the router kills, drains, and requeues a replica whose "
-     "step heartbeat is staler than this many seconds — wedged-replica "
-     "detection, the serving analog of HETU_LIVENESS_STALE.", "router")
-_reg("HETU_ROUTER_BREAKER", "int", 3,
-     "Per-replica circuit breaker: consecutive failures "
-     "(deaths/wedge kills) that eject the replica from routing; a "
-     "half-open probe request readmits it after the cooldown.",
-     "router")
-_reg("HETU_ROUTER_BREAKER_COOLDOWN", "float", 0.5,
-     "Base seconds an open circuit breaker holds before the half-open "
-     "probe (doubles per failure past the threshold).", "router")
-_reg("HETU_ROUTER_RETRY_LIMIT", "int", 5,
-     "Placement retries the router grants a request it holds "
-     "(requeued off a dead replica / fleet full) before declaring it "
-     "lost — a terminal failure with a flight dump.", "router")
-_reg("HETU_ROUTER_RETRY_BACKOFF", "float", 0.02,
-     "Base seconds of exponential backoff between a held request's "
-     "placement retries.", "router")
-_reg("HETU_ROUTER_SHED_QUEUE", "float", 0.75,
-     "Fleet queue-fill fraction at which SLO-class load shedding "
-     "starts: throughput-class submissions are shed (RouterShed) while "
-     "latency-class requests keep admitting until hard-full.", "router")
-_reg("HETU_ROUTER_SHED_ON_SLO", "bool", True,
-     "Also shed throughput-class traffic while any replica's SLO "
-     "health is at breach (frees capacity to pull latency-class TTFT "
-     "back inside budget).", "router")
-_reg("HETU_ROUTER_DIRECTORY", "bool", True,
-     "Fleet prefix-cache directory: route a request whose prompt "
-     "prefix is resident on replica R to R (a directory hit) before "
-     "falling back to the session-affinity hash.  Entries are hints — "
-     "a stale hit degrades to a cold admission, and disabling (or "
-     "chaos-killing) the directory degrades the fleet to exact "
-     "affinity-only routing.", "router")
 _reg("HETU_ROUTER_ROLES", "str", None,
      "Prefill/decode disaggregation: comma-separated role per replica "
      "index ('prefill', 'decode', or 'mixed'; unlisted replicas are "
@@ -364,28 +291,6 @@ _reg("HETU_ROUTER_ROLES", "str", None,
      "prefill-heavy replica and their KV blocks are handed off to a "
      "decode-heavy one (export_blocks/import_blocks).  Unset = every "
      "replica mixed, no handoffs.", "router")
-_reg("HETU_DIRECTORY_TTL", "float", 0.0,
-     "> 0: seconds an un-refreshed directory entry stays routable; "
-     "expired entries are skipped (counted stale) until re-registered. "
-     "0 = hints never expire (the replica's token-verified match still "
-     "catches every lie).", "router")
-
-# --------------------------------------------------------------------- #
-# live weight sync (serving/weight_sync.py — rolling zero-downtime swaps)
-# --------------------------------------------------------------------- #
-_reg("HETU_SWAP_PROBE_TOKENS", "int", 4,
-     "Greedy probe-decode length (tokens) a freshly swapped replica "
-     "must retire on the NEW weight version before the rollout "
-     "readmits it — the half-open check of a rolling swap.", "swap")
-_reg("HETU_SWAP_DRAIN_STEPS", "int", 2000,
-     "Max router steps a quiesced replica may take to drain its "
-     "in-flight requests before the rollout is marked failed (and the "
-     "fleet auto-rolls back).", "swap")
-_reg("HETU_SWAP_ROLLBACK", "bool", True,
-     "Auto-roll already-swapped replicas back to the last COMMITTED "
-     "version when a rollout fails mid-swap.  0 leaves them on the new "
-     "version (the rollout is still marked failed); dead replicas "
-     "respawn on the committed version either way.", "swap")
 
 # --------------------------------------------------------------------- #
 # elastic fleet (serving/autoscaler.py — SLO-burn-driven autoscaling)
@@ -396,39 +301,7 @@ _reg("HETU_FLEET_MIN", "int", 1,
      "replica regardless).", "fleet")
 _reg("HETU_FLEET_MAX", "int", 4,
      "Most replicas the autoscaler may run: scale-up stops at this "
-     "ceiling (the equal-peak-capacity bound the autoscale_ab bench "
-     "sizes its static arm to).", "fleet")
-_reg("HETU_AUTOSCALE_UP_BURN", "float", 1.0,
-     "Worst-replica SLO burn rate at or above which a tick counts as "
-     "hot (burn >= 1 = an error budget spending faster than it "
-     "refills); HETU_AUTOSCALE_UP_TICKS consecutive hot ticks trigger "
-     "a scale-up.", "fleet")
-_reg("HETU_AUTOSCALE_UP_PRESSURE", "float", 0.75,
-     "Aggregate queue-fill fraction at or above which a tick counts "
-     "as hot even without an SLO burn signal — queue pressure leads "
-     "latency, so the fleet grows before the breach.", "fleet")
-_reg("HETU_AUTOSCALE_UP_TICKS", "int", 3,
-     "Consecutive hot ticks (one tick per router step) required to "
-     "scale up — the hysteresis that keeps a one-step spike from "
-     "spawning a replica.", "fleet")
-_reg("HETU_AUTOSCALE_DOWN_PRESSURE", "float", 0.15,
-     "Aggregate queue-fill fraction at or below which a tick counts "
-     "as idle (with burn < 1 and nothing router-held); "
-     "HETU_AUTOSCALE_DOWN_TICKS consecutive idle ticks trigger a "
-     "scale-down.", "fleet")
-_reg("HETU_AUTOSCALE_DOWN_TICKS", "int", 50,
-     "Consecutive idle ticks required to scale down — deliberately "
-     "much slower than scale-up (growing late sheds traffic; "
-     "shrinking late only burns replica-seconds).", "fleet")
-_reg("HETU_AUTOSCALE_COOLDOWN", "int", 20,
-     "Refractory ticks after ANY scale action during which the "
-     "autoscaler only observes — a bursty signal cannot flap the "
-     "fleet.", "fleet")
-_reg("HETU_AUTOSCALE_WARM_PREFIXES", "int", 4,
-     "Hottest directory-known prefixes moved per membership change: "
-     "imported into a joining replica before it takes traffic "
-     "(scale-up warming) and exported from a retiring replica to its "
-     "best peer (scale-down).  0 disables prefix movement.", "fleet")
+     "ceiling.", "fleet")
 
 # --------------------------------------------------------------------- #
 # quantization (hetu_tpu/quant.py — one layer, three seams)
@@ -452,9 +325,6 @@ _reg("HETU_KV_QUANT", "str", None,
      "byte; dequantized inside the decode kernels' online-softmax "
      "loop).  Unset/0 = the cache follows the weight dtype (default).",
      "quant")
-_reg("HETU_QUANT_CHUNK", "int", 256,
-     "Elements per f32 scale for the flat (PS wire / comm pair) int8 "
-     "codec; the KV cache always scales per (position, head).", "quant")
 _reg("HETU_HANDOFF_QUANT", "str", "auto",
      "Replica-to-replica KV handoff wire (export_blocks/import_blocks): "
      "'auto' ships the pool's native bytes (an int8 pool's payload + "
@@ -463,54 +333,45 @@ _reg("HETU_HANDOFF_QUANT", "str", "auto",
      "bytes), '0'/'off' pins the exact wire.", "quant")
 
 # --------------------------------------------------------------------- #
-# graph/ops knobs
-# --------------------------------------------------------------------- #
-_reg("HETU_MOE_SCATTER_DISPATCH", "bool", False,
-     "MoE dispatch formulation: row scatter-add instead of the GShard "
-     "one-hot matmul (read ONCE at op construction).", "ops")
-
-# --------------------------------------------------------------------- #
-# data / planner
+# data
 # --------------------------------------------------------------------- #
 _reg("HETU_DATA_HOME", "path", "~/.hetu_data",
      "Dataset download/cache directory.", "data")
-_reg("HETU_CALIB_SMALL", "bool", False,
-     "Chip-calibration: reduced ladder for smoke runs.", "planner")
 
 # --------------------------------------------------------------------- #
-# bench.py
+# which knobs stay
 # --------------------------------------------------------------------- #
-_reg("HETU_BENCH_SMALL", "bool", False,
-     "Force the reduced (CPU-scale) bench configs.", "bench")
-_reg("HETU_BENCH_CONFIGS", "str", None,
-     "Comma-separated subset of bench matrix configs to run.", "bench")
-_reg("HETU_BENCH_SWEEP", "bool", False,
-     "Run the (batch x attention x head) ablation sweep.", "bench")
-_reg("HETU_BENCH_DECODE", "bool", False,
-     "Run the KV-cached decode benchmark.", "bench")
-_reg("HETU_BENCH_EMBED_SERVE", "bool", False,
-     "Run the embedding-cache recommendation-serving benchmark "
-     "(zipf cache-limit ladder, int8-pull A/B, PS-kill chaos).",
-     "bench")
-_reg("HETU_BENCH_CTR_ROWS", "bool", False,
-     "Run the max-embedding-rows-per-chip ladder.", "bench")
-_reg("HETU_BENCH_CTR_FP32", "bool", False,
-     "CTR hybrid: pin full-width fp32 host-link transfers (default "
-     "ships bf16).", "bench")
-_reg("HETU_BENCH_FORCE_FLASH", "str", None,
-     "Pin the attention impl for sweeps: 1 = flash kernel, 0 = XLA "
-     "batched attention (unset = size-based crossover).", "bench")
-_reg("HETU_BENCH_FUSED_HEAD", "bool", False,
-     "A/B the chunked fused LM head (memory tool) against the "
-     "materialized-logits default.", "bench")
-_reg("HETU_BENCH_BERT_BATCH", "int", None,
-     "Pin the BERT-base per-chip batch instead of probing.", "bench")
-_reg("HETU_BENCH_MOE_BATCH", "int", None,
-     "Override the MoE bench batch (chip-fill tuning).", "bench")
-_reg("HETU_BENCH_MOE_TOKENS", "int", None,
-     "Override the MoE bench tokens-per-sample.", "bench")
-_reg("HETU_BENCH_LC_BLOCKS", "str", None,
-     "Long-context flash tile override, 'bq,bk'.", "bench")
+# A knob stays in the registry if a test or an example names it, or if
+# it is a DEPLOYMENT setting: something a cluster's operator sets from
+# outside the program (an address, a port, a rank, a path, a fleet's
+# size, how it is supervised).  A policy value with one value in use is
+# a parameter's default or a constant beside its reader, not a knob.
+# tests/test_lint_clean.py reads this tuple; nothing selects on it at
+# run time.
+DEPLOYMENT = (
+    "HETU_TPU_COORDINATOR",     # address of jax.distributed's coordinator
+    "HETU_TPU_NUM_PROCS",       # world size of a multi-host bring-up
+    "HETU_TPU_PROC_ID",         # this host's index in it
+    "HETU_PS_NRANK",            # worker count, stamped by the launcher
+    "HETU_PS_INDEX",            # a server's index, stamped by the launcher
+    "HETU_PS_ADVERTISE",        # the address a server announces
+    "HETU_PS_VAN_PORT",         # a port
+    "HETU_PS_VAN_BIND_ALL",     # which interfaces the van listens on
+    "HETU_PS_USE_VAN",          # the transport a site allows (ROADMAP C9)
+    "HETU_SCHEDULER_PORT",      # a port
+    "HETU_DATA_HOME",           # a path
+    "HETU_ROUTER_ROLES",        # the fleet's layout, a role a replica
+    "HETU_FLEET_MIN",           # the fleet's size: what a site pays for
+    "HETU_FLEET_MAX",
+    "HETU_SUPERVISE",           # whether heturun respawns its children
+    "HETU_HEARTBEAT_INTERVAL",  # liveness beats, tuned to the network
+    # the two below are the launcher's ONLY switch for a behaviour that
+    # is off by default (no parameter of launch() reaches it and no
+    # test turns it on there): kept with the supervision settings, and
+    # named under ROADMAP C10 as behaviours to decide
+    "HETU_PS_REPLICATE",        # the group's replication guarantee
+    "HETU_LIVENESS_STALE",      # wedge detection over the heartbeats
+)
 
 
 # --------------------------------------------------------------------- #

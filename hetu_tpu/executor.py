@@ -165,6 +165,10 @@ class HetuConfig:
 # today's host link)
 _RING_DEVICE_PUT_MIN_BYTES = 4 << 20
 
+# most training steps of push traffic the direct (cache-less) hybrid
+# path buffers through a PS outage before the run fails
+_PS_BACKLOG_STEPS = 32
+
 
 def _wire_prefetch(sub):
     """Wire this subgraph's dataloaders: multi-host batch sharding, then
@@ -758,7 +762,7 @@ class Executor:
         self._ps_push_future = None   # pending async phase B (one step)
         # outage handling for the direct (cache-less) hybrid path:
         # pushes that cannot reach the PS buffer here and replay on the
-        # next successful contact, bounded by HETU_PS_BACKLOG_STEPS
+        # next successful contact, bounded by _PS_BACKLOG_STEPS
         self._ps_push_backlog = []
         if self.config.comm_mode in ("PS", "Hybrid"):
             self._setup_ps(all_nodes)
@@ -987,13 +991,11 @@ class Executor:
             else:
                 self.ps_comm.push(name, rows)
         except ConnectionError as e:
-            from .envvars import get_int
-            limit = get_int("HETU_PS_BACKLOG_STEPS")
             self._ps_push_backlog.append((kind, name, ids, rows))
-            if len(self._ps_push_backlog) > limit:
+            if len(self._ps_push_backlog) > _PS_BACKLOG_STEPS:
                 raise PSConnectionError(
                     f"PS outage: push backlog exceeded "
-                    f"HETU_PS_BACKLOG_STEPS={limit} buffered steps "
+                    f"{_PS_BACKLOG_STEPS} buffered steps "
                     f"(last failure: {e})") from e
 
     def ps_step_sync(self):

@@ -150,7 +150,7 @@ class QuantizeCommOp(Op):
         super().__init__(node, name="QuantizeComm", ctx=ctx)
         self.axis = axis or "dp"
         from .. import quant as _quant
-        self.chunk = int(chunk or _quant.wire_chunk())
+        self.chunk = int(chunk or _quant.DEFAULT_CHUNK)
 
     def compute(self, input_vals, tc: TraceContext):
         from .. import quant as _quant
@@ -195,7 +195,7 @@ class DequantizeCommOp(Op):
         self.axis = axis or "dp"
         self.shape = tuple(int(d) for d in shape)
         from .. import quant as _quant
-        self.chunk = int(chunk or _quant.wire_chunk())
+        self.chunk = int(chunk or _quant.DEFAULT_CHUNK)
 
     def compute(self, input_vals, tc: TraceContext):
         from .. import quant as _quant

@@ -7,15 +7,13 @@ src/communication/mpi_nccl_communication.cu:152-243), BalanceAssignment.py
 (auction assignment), SamGroupSum.cu / SamMax.cu / GroupTopKIdx.cu (SAM
 gate), Dispatch.py (model-parallel annotation).
 
-TPU-native: dispatch/combine default to the GShard-style one-hot-matmul
+TPU-native: dispatch/combine take the GShard-style one-hot-matmul
 formulation (_scatter_rows) — MXU work with no data-dependent writes —
-with the row-scatter form behind HETU_MOE_SCATTER_DISPATCH=1; the MoE
-bench A/Bs both on-chip (an earlier round measured scatter dispatch at
-3.5 ms of a 67 ms step on the v5e; a fused Pallas dispatch kernel
-remains not worth it either way).  Combine stays a gather (fast on
-TPU).  All-to-all is ``jax.lax.all_to_all`` over the 'ep' mesh axis
-inside shard_map; hierarchical A2A decomposes over ('dcn', 'ici') axes —
-the natural mapping of the reference's gather→exchange→scatter staging.
+and the row-scatter form where the one-hot mask would outgrow
+_ONEHOT_DISPATCH_MAX_ELEMS.  Combine stays a gather (fast on TPU).
+All-to-all is ``jax.lax.all_to_all`` over the 'ep' mesh axis inside
+shard_map; hierarchical A2A decomposes over ('dcn', 'ici') axes — the
+natural mapping of the reference's gather→exchange→scatter staging.
 """
 
 from __future__ import annotations
@@ -53,25 +51,18 @@ def _slot_weights(pos_valid_weight, n_slots, dtype):
 _ONEHOT_DISPATCH_MAX_ELEMS = 1 << 27
 
 
-def _force_scatter_dispatch():
-    from ..envvars import get_bool
-    return get_bool("HETU_MOE_SCATTER_DISPATCH")
-
-
-def _scatter_rows(terms, n_slots, src, dtype, force_scatter=False):
+def _scatter_rows(terms, n_slots, src, dtype):
     """Rows of ``src`` summed into ``n_slots`` buckets.
 
-    Default: one-hot MXU matmul (sum_i onehot(pos_i, weighted)^T @ src)
-    — row scatter-adds can lower to a serialized scatter on TPU, while
-    this formulation is pure matmul work.  The .at[].add scatter form is
-    used instead when (a) the caller forces it (the op reads
-    ``HETU_MOE_SCATTER_DISPATCH=1`` ONCE at construction — the MoE bench
-    A/Bs both on-chip), or (b) the [N, n_slots] mask would exceed
+    One-hot MXU matmul (sum_i onehot(pos_i, weighted)^T @ src) — row
+    scatter-adds can lower to a serialized scatter on TPU, while this
+    formulation is pure matmul work.  The .at[].add scatter form is used
+    instead when the [N, n_slots] mask would exceed
     _ONEHOT_DISPATCH_MAX_ELEMS, past which the mask's memory/FLOPs
     dominate the experts themselves (at top-k capacity, mask elements
     grow as k*N^2)."""
     N = src.shape[0]
-    if force_scatter or N * n_slots > _ONEHOT_DISPATCH_MAX_ELEMS:
+    if N * n_slots > _ONEHOT_DISPATCH_MAX_ELEMS:
         out = jnp.zeros((n_slots, src.shape[-1]), dtype)
         for pos, valid, w in terms:
             rows = src if w is None else w.reshape(-1, 1).astype(dtype) * src
@@ -98,7 +89,6 @@ class LayoutTransformOp(Op):
         self.capacity = int(capacity)
         self.topK = len(indices_s)
         self.total_experts = int(total_experts)
-        self.force_scatter = _force_scatter_dispatch()
 
     def jax_fn(self, x, *idx_loc):
         k, cap = self.topK, self.capacity
@@ -107,8 +97,7 @@ class LayoutTransformOp(Op):
             idx = _flat_int(idx_loc[i])
             loc = _flat_int(idx_loc[k + i])
             terms.append((idx * cap + loc, loc < cap, None))
-        return _scatter_rows(terms, self.total_experts * cap, x, x.dtype,
-                             force_scatter=self.force_scatter)
+        return _scatter_rows(terms, self.total_experts * cap, x, x.dtype)
 
     def gradient(self, output_grad):
         k = self.topK
@@ -210,7 +199,6 @@ class ReverseLayoutTransformGradientDataOp(Op):
         self.capacity = int(capacity)
         self.topK = len(indices_s)
         self.num_experts = int(num_experts)
-        self.force_scatter = _force_scatter_dispatch()
 
     def jax_fn(self, g, *rest):
         k, cap = self.topK, self.capacity
@@ -223,8 +211,7 @@ class ReverseLayoutTransformGradientDataOp(Op):
             loc = _flat_int(locations[i])
             terms.append((idx * cap + loc, loc < cap,
                           gates[i].reshape(-1)))
-        return _scatter_rows(terms, self.num_experts * cap, g, g.dtype,
-                             force_scatter=self.force_scatter)
+        return _scatter_rows(terms, self.num_experts * cap, g, g.dtype)
 
     def gradient(self, output_grad):
         raise NotImplementedError

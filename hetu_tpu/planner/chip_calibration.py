@@ -34,7 +34,6 @@ import json
 import os
 import time
 
-from .. import envvars
 
 import numpy as np
 import jax
@@ -129,8 +128,8 @@ def measure_matmul_curve(dims=(1024, 2048, 4096, 8192),
 
 # bf16 spec-sheet peak TFLOP/s keyed by the exact ``device_kind`` JAX
 # reports (source: Google Cloud TPU documentation, the system-architecture
-# page of each generation).  The single source of truth — bench.py's MFU
-# denominator and chip_smoke.py's device check read it too.  A kind that is
+# page of each generation).  The single source of truth: chip_smoke.py's
+# device check reads it too.  A kind that is
 # not here is an error, not a default.
 SPEC_PEAKS = {
     "TPU v2": 45.0,         # "TPU v2": 45 TFLOP/s per chip
@@ -351,11 +350,9 @@ def load_calibration(path=CALIBRATION_FILE, n_devices=None):
 
 def main():
     from ..artifact import persist_artifact
-    small = envvars.get_bool("HETU_CALIB_SMALL")
-    # cheap pre-check: a degraded run (small probes, or not on real
-    # TPU) that would be refused anyway must not burn minutes of
-    # matmul sweeps first
-    reduced_now = small or jax.default_backend() != "tpu"
+    # cheap pre-check: a degraded run (not on real TPU) that would be
+    # refused anyway must not burn minutes of matmul sweeps first
+    reduced_now = jax.default_backend() != "tpu"
     try:
         with open(CALIBRATION_FILE) as f:
             existing = json.load(f)
@@ -365,15 +362,14 @@ def main():
             and not existing.get("reduced_scale")
             and existing.get("platform") == "tpu"):
         print(json.dumps({
-            "platform": jax.default_backend(), "small": small,
+            "platform": jax.default_backend(),
             "not_written": "full-scale TPU calibration record already "
                            "present; degraded run skipped"}))
         return
-    art = calibrate_chip(small=small)
-    # degraded = small probes or a non-TPU backend; either must never
-    # clobber a full-scale TPU calibration record (shared discipline
-    # with bench.py's sweep artifacts)
-    art["reduced_scale"] = small or art.get("platform") != "tpu"
+    art = calibrate_chip()
+    # a non-TPU backend must never clobber a full-scale TPU
+    # calibration record
+    art["reduced_scale"] = art.get("platform") != "tpu"
     if not persist_artifact(CALIBRATION_FILE, art,
                             reduced=art["reduced_scale"]):
         print(json.dumps({"platform": art["platform"],

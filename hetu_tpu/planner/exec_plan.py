@@ -6,8 +6,8 @@ the cost model rank FULL configurations it never ran (reference
 tools/Galvatron/utils/cost_model.py:38-60 consumes per-component
 profiled coefficients; bert/profile_forward.py produces them).  The
 multi-device half of that loop lives in cost_model.py/search.py; this
-module closes the SINGLE-CHIP half over the knobs the on-chip ablation
-sweep measures (bench.py HETU_BENCH_SWEEP): per-chip batch, attention
+module closes the SINGLE-CHIP half over the knobs an on-chip ablation
+sweep measures: per-chip batch, attention
 implementation (XLA batched vs fused flash), and LM-head variant
 (materialized vs fused chunked).
 
@@ -29,8 +29,6 @@ to pick a config that IS fast, not to break measurement-noise ties).
 """
 
 from __future__ import annotations
-
-import json
 
 import numpy as np
 
@@ -94,8 +92,8 @@ class ExecConfigModel:
 def validate_against_sweep(sweep, fit_keys=None, regret_tol=0.02):
     """Fit on a subset, rank the FULL grid, compare against measured.
 
-    ``sweep``: the SWEEP_BERT_BASE.json dict ({"configs": [...]}) or the
-    list of config rows directly.  Each row: {batch, attention, head,
+    ``sweep``: a dict ``{"configs": [...]}`` or the list of config rows
+    directly.  Each row: {batch, attention, head,
     step_time_ms}.  ``fit_keys``: optional iterable of (batch, attn,
     head) keys to calibrate on; default = every row EXCEPT the measured
     best (the strictest honest split: the model must predict the winner
@@ -163,8 +161,3 @@ def validate_against_sweep(sweep, fit_keys=None, regret_tol=0.02):
             for k in sorted(thr, key=thr.get, reverse=True)
         ],
     }
-
-
-def validate_sweep_file(path, fit_keys=None):
-    with open(path) as f:
-        return validate_against_sweep(json.load(f), fit_keys=fit_keys)

@@ -53,7 +53,7 @@ def _q_encode(arr):
     unchanged.  Counts the saved bytes."""
     if quant.ps_quant() != "int8" or not quant.should_quantize(arr):
         return arr
-    qa = quant.QuantArray.encode(arr, quant.wire_chunk())
+    qa = quant.QuantArray.encode(arr)
     from .. import telemetry
     if telemetry.enabled():
         telemetry.inc("ps.rpc.bytes_saved", quant.wire_savings(qa))

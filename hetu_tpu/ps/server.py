@@ -32,7 +32,7 @@ import numpy as np
 
 from . import faults, wire
 from .. import envvars, locks
-from ..quant import QuantArray, maybe_decode, should_quantize, wire_chunk
+from ..quant import QuantArray, maybe_decode, should_quantize
 
 
 # ----------------------------------------------------------------- #
@@ -652,7 +652,7 @@ class PSServer:
         pull half of the HETU_PS_QUANT pair); qualifying values only —
         tiny/integer payloads stay exact."""
         if quant == "int8" and should_quantize(value):
-            return QuantArray.encode(value, wire_chunk())
+            return QuantArray.encode(value)
         return value
 
     def pull(self, key, quant=None):

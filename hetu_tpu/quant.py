@@ -63,32 +63,12 @@ def resolve_quant(mode, env_name):
         f"supported: {_Q_MODES}")
 
 
-def wire_chunk():
-    """Chunk size for the flat wire codec (``HETU_QUANT_CHUNK``)."""
-    return int(envvars.get_int("HETU_QUANT_CHUNK") or DEFAULT_CHUNK)
-
-
 def ps_quant():
     return resolve_quant(None, "HETU_PS_QUANT")
 
 
 def comm_quant():
     return resolve_quant(None, "HETU_COMM_QUANT")
-
-
-def kv_quant():
-    return resolve_quant(None, "HETU_KV_QUANT")
-
-
-def active_modes():
-    """Compact provenance string of the quantization knobs in effect —
-    stamped on bench rows/headlines so quantized and unquantized
-    measurements can never be compared silently ("off" when everything
-    is default)."""
-    on = [f"{k}={v}" for k, v in (("ps", ps_quant()),
-                                  ("comm", comm_quant()),
-                                  ("kv", kv_quant())) if v]
-    return ",".join(on) if on else "off"
 
 
 # --------------------------------------------------------------------- #

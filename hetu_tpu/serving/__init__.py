@@ -102,7 +102,7 @@ all k+1 positions as a q-block of the wave, longest-prefix acceptance +
 a bonus token emit 1..k+1 tokens per wave, and rejected positions roll
 back via ``kv.truncate`` — outputs stay token-identical to plain decoding
 (greedy AND sampled), with an adaptive-k controller riding a sliding
-acceptance-rate window (``$HETU_SPEC_ADAPT``).
+acceptance-rate window (``spec_adapt=``, on by default).
 
 What scores the wave follows the platform (``fast_path=``/
 ``$HETU_SERVE_FAST``): the Pallas ragged kernel

@@ -13,20 +13,21 @@ peak minute.
 Control loop (one :meth:`tick` per ``router.step()``, exactly like the
 weight-sync coordinator — no second thread, no lock):
 
-- **scale up** after ``HETU_AUTOSCALE_UP_TICKS`` consecutive hot ticks
-  (burn >= ``HETU_AUTOSCALE_UP_BURN`` or pressure >=
-  ``HETU_AUTOSCALE_UP_PRESSURE``): ``router.add_replica()`` spawns a
+- **scale up** after ``up_ticks`` (3) consecutive hot ticks (burn >=
+  ``up_burn``, 1.0, or pressure >= ``up_pressure``, 0.75: queue
+  pressure leads latency): ``router.add_replica()`` spawns a
   fresh supervised replica that admits on the COMMITTED weight version,
   prefix-warms from its peers, and probe-decodes before taking traffic.
-- **scale down** after ``HETU_AUTOSCALE_DOWN_TICKS`` consecutive idle
-  ticks (burn < 1 and pressure <= ``HETU_AUTOSCALE_DOWN_PRESSURE`` and
-  nothing router-held): ``router.retire_replica()`` drains the
+- **scale down** after ``down_ticks`` (50: shrinking late only burns
+  replica-seconds, growing late sheds traffic) consecutive idle ticks
+  (burn < 1 and pressure <= ``down_pressure``, 0.15, and nothing
+  router-held): ``router.retire_replica()`` drains the
   least-loaded replica onto its peers with zero request loss.  Never
   fires mid-rollout (the version-committed quorum must hold) and never
   targets a quiesced replica.
-- **hysteresis**: both streaks reset on any action and a
-  ``HETU_AUTOSCALE_COOLDOWN``-tick refractory window follows, so a
-  bursty signal cannot flap the fleet.
+- **hysteresis**: both streaks reset on any action and a refractory
+  window of ``cooldown`` (20) ticks follows, so a bursty signal cannot
+  flap the fleet.
 
 Tick-counted (not wall-clock) hysteresis keeps chaos runs and the
 virtual-time traffic replay (serving/traffic.py) seed-deterministic.
@@ -47,21 +48,23 @@ import time
 from .. import envvars, telemetry
 from ..telemetry import flight
 from .replica import RETIRED, UP
+from .router import WARM_PREFIXES
 
 __all__ = ["FleetAutoscaler"]
 
 
 class FleetAutoscaler:
     """Rides ``router.step()`` and resizes the fleet (see module
-    docstring for the control contract).  Constructor knobs default to
-    the ``HETU_FLEET_*`` / ``HETU_AUTOSCALE_*`` registry entries;
-    attaching sets ``router.autoscaler`` so the router ticks it once
-    per step, after supervision and placement."""
+    docstring for the control contract).  The fleet's bounds default to
+    the ``HETU_FLEET_*`` registry entries, the control loop's thresholds
+    to the signature's values; attaching sets ``router.autoscaler`` so
+    the router ticks it once per step, after supervision and
+    placement."""
 
     def __init__(self, router, *, fleet_min=None, fleet_max=None,
-                 up_burn=None, up_pressure=None, up_ticks=None,
-                 down_pressure=None, down_ticks=None, cooldown=None,
-                 warm_prefixes=None, enabled=True):
+                 up_burn=1.0, up_pressure=0.75, up_ticks=3,
+                 down_pressure=0.15, down_ticks=50, cooldown=20,
+                 warm_prefixes=WARM_PREFIXES, enabled=True):
         self.router = router
         self.fleet_min = int(fleet_min if fleet_min is not None
                              else envvars.get_int("HETU_FLEET_MIN"))
@@ -71,27 +74,13 @@ class FleetAutoscaler:
             raise ValueError(
                 f"need 1 <= fleet_min <= fleet_max, got "
                 f"{self.fleet_min}..{self.fleet_max}")
-        self.up_burn = float(
-            up_burn if up_burn is not None
-            else envvars.get_float("HETU_AUTOSCALE_UP_BURN"))
-        self.up_pressure = float(
-            up_pressure if up_pressure is not None
-            else envvars.get_float("HETU_AUTOSCALE_UP_PRESSURE"))
-        self.up_ticks = int(
-            up_ticks if up_ticks is not None
-            else envvars.get_int("HETU_AUTOSCALE_UP_TICKS"))
-        self.down_pressure = float(
-            down_pressure if down_pressure is not None
-            else envvars.get_float("HETU_AUTOSCALE_DOWN_PRESSURE"))
-        self.down_ticks = int(
-            down_ticks if down_ticks is not None
-            else envvars.get_int("HETU_AUTOSCALE_DOWN_TICKS"))
-        self.cooldown = int(
-            cooldown if cooldown is not None
-            else envvars.get_int("HETU_AUTOSCALE_COOLDOWN"))
-        self.warm_prefixes = int(
-            warm_prefixes if warm_prefixes is not None
-            else envvars.get_int("HETU_AUTOSCALE_WARM_PREFIXES"))
+        self.up_burn = float(up_burn)
+        self.up_pressure = float(up_pressure)
+        self.up_ticks = int(up_ticks)
+        self.down_pressure = float(down_pressure)
+        self.down_ticks = int(down_ticks)
+        self.cooldown = int(cooldown)
+        self.warm_prefixes = int(warm_prefixes)
         self.enabled = bool(enabled)
         self.ticks = 0
         self.scale_ups = 0

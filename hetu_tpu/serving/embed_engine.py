@@ -47,7 +47,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .. import envvars
 from ..telemetry import flight
 from ..telemetry import slo as slo_mod
 from .engine import QueueFull, _STORM_REJECTS
@@ -131,15 +130,17 @@ class EmbedServingEngine:
     graph builders (W1..W4 + cross{i}_* for CTR, W1..Wn for NCF).
     ``tables``: name -> CacheSparseTable; ``"snd_order_embedding"``
     for wdl/dcn, ``"user_embed"`` + ``"item_embed"`` for ncf.
-    ``model``: "wdl" | "dcn" | "ncf".  ``wave``/``queue_limit`` default
-    from ``HETU_EMBED_WAVE``/``HETU_EMBED_QUEUE``; ``slo`` wires an
-    SLOMonitor exactly like ServingEngine (env-declared by default).
+    ``model``: "wdl" | "dcn" | "ncf".  ``wave``: most requests claimed
+    per scoring wave (one embedding gather + one jitted tower forward);
+    ``queue_limit``: admission-queue depth past which ``submit`` raises
+    QueueFull; ``slo`` wires an SLOMonitor exactly like ServingEngine
+    (env-declared by default).
     """
 
     def __init__(self, params, tables, model="wdl", *,
                  embedding_size=None, embed_dim=8,
                  mlp_layers=(64, 32, 16, 8), num_cross_layers=3,
-                 wave=None, queue_limit=None, slo=None, tags=None,
+                 wave=8, queue_limit=64, slo=None, tags=None,
                  log_path=None):
         if model not in ("wdl", "dcn", "ncf"):
             raise ValueError(
@@ -163,11 +164,8 @@ class EmbedServingEngine:
                 embedding_size if embedding_size is not None
                 else self.tables["snd_order_embedding"].width)
             self.num_cross_layers = int(num_cross_layers)
-        self.wave = int(wave if wave is not None
-                        else envvars.get_int("HETU_EMBED_WAVE"))
-        self.queue_limit = int(
-            queue_limit if queue_limit is not None
-            else envvars.get_int("HETU_EMBED_QUEUE"))
+        self.wave = int(wave)
+        self.queue_limit = int(queue_limit)
         self._queue = collections.deque()
         self.metrics = EmbedServingMetrics(log_path, tags=tags)
         # optional fn(request, slot) called at retirement — same seam

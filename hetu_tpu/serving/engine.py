@@ -126,7 +126,7 @@ class ServingEngine:
     outputs stay TOKEN-IDENTICAL to the non-speculative engine (greedy
     and sampled alike: accepted tokens are the target's own sequential
     samples from each request's rng stream), rejected positions roll
-    back via ``kv.truncate``; spec_adapt (``$HETU_SPEC_ADAPT``, default
+    back via ``kv.truncate``; spec_adapt (default
     on) moves the per-wave draft length through the pow2 ladder
     1..spec on a sliding acceptance-rate window.  Speculation composes
     with paged/prefix-shared/chunked/int8 KV, the fast path, TP, and
@@ -193,9 +193,9 @@ class ServingEngine:
     def __init__(self, params, config, *, slots=8, queue_limit=64,
                  max_seq_len=None, name=None, dtype=None, log_path=None,
                  donate=True, fast_path=None, kv_block=None,
-                 pool_blocks=None, prefix_share=None, prefill_chunk=None,
+                 pool_blocks=None, prefix_share=None, prefill_chunk=0,
                  kv_quant=None, slo=None, tags=None, spec=None,
-                 spec_adapt=None, spec_draft_layers=None):
+                 spec_adapt=True, spec_draft_layers=None):
         c = config
         self._name = _infer_name(params, name)
         # dtype=None FOLLOWS the params: bf16 weights stay bf16 and the
@@ -239,9 +239,9 @@ class ServingEngine:
         blk = self.block_spec
         latent = blk.latent
         L = c.num_hidden_layers
-        chunk = (prefill_chunk if prefill_chunk is not None
-                 else envvars.get_int("HETU_KV_CHUNK"))
-        self.chunk = max(int(chunk or 0), 0)
+        # prompts fill their blocks in chunks of this many tokens beside
+        # the decode slots of the same wave (0: a whole prompt at once)
+        self.chunk = max(int(prefill_chunk or 0), 0)
         self.kv = PagedKVManager(
             # the pool holds the layers with an attention; the layers
             # with a conv, a state-space mixer or retention keep slot
@@ -395,9 +395,7 @@ class ServingEngine:
                 # and its wrappers append no stats element
                 self.cfg_tuple_draft = self.cfg_tuple_draft + (
                     self.moe._replace(draft=True),)
-            adapt = (spec_adapt if spec_adapt is not None
-                     else envvars.get_bool("HETU_SPEC_ADAPT"))
-            self.spec_adapt = bool(adapt) and self.spec_k > 1
+            self.spec_adapt = bool(spec_adapt) and self.spec_k > 1
             # adaptive runs ramp up from mid-ladder; pinned runs start
             # (and stay) at the configured k
             self._spec_kcur = (max(1, self.spec_k // 2)

@@ -408,8 +408,7 @@ class PagedKVManager:
                 "prefix in the window layers' ring, which holds the "
                 "slot's own last positions alone")
         if prefix_share is None:
-            prefix_share = not self.stateful and not self.window_layers \
-                and envvars.get_bool("HETU_KV_PREFIX_SHARE")
+            prefix_share = not self.stateful and not self.window_layers
         self.prefix_share = bool(prefix_share)
         self.quant = "int8" if _is_int8(dtype) else None
         if self.window_layers and (self.quant or (

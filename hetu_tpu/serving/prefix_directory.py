@@ -44,7 +44,7 @@ import time
 
 import numpy as np
 
-from .. import envvars, locks
+from .. import locks
 
 
 def prefix_hash(tokens):
@@ -73,14 +73,12 @@ class _DirEntry:
 
 class PrefixDirectory:
     """The fleet map.  ``ttl`` seconds bound how long an un-refreshed
-    entry stays routable (``$HETU_DIRECTORY_TTL``; 0 = hints never
-    expire — the token-verified degradation path still catches every
-    lie, TTL just caps how often it has to)."""
+    entry stays routable (0, the default = hints never expire — the
+    token-verified degradation path still catches every lie, TTL just
+    caps how often it has to)."""
 
-    def __init__(self, *, ttl=None, now=None):
-        if ttl is None:
-            ttl = envvars.get_float("HETU_DIRECTORY_TTL")
-        self.ttl = float(ttl or 0.0)
+    def __init__(self, *, ttl=0.0, now=None):
+        self.ttl = float(ttl)
         self._now = now or time.perf_counter
         self._mu = locks.TracedLock("prefix.dir")
         self._entries = {}               # hash -> _DirEntry

@@ -30,7 +30,7 @@ behind this router, which owns everything a fleet adds to the problem:
   ``match_prefix`` is the only thing that attaches KV), and killing
   the directory (``kill_directory()`` / chaos role "directory")
   degrades the fleet to exact affinity-only behavior —
-  ``HETU_ROUTER_DIRECTORY=0`` pins that mode.
+  ``directory=False`` pins that mode.
 
 - **Prefill/decode disaggregation with KV handoff.**  With
   ``HETU_ROUTER_ROLES`` marking replicas prefill-heavy or
@@ -51,7 +51,7 @@ behind this router, which owns everything a fleet adds to the problem:
 
 - **Supervised replicas with drain + requeue.**  Replicas die (chaos
   kill, scheduler exception) and wedge (alive, silent).  Death is
-  detected by state, wedge by stale heartbeat (``HETU_ROUTER_STALE``,
+  detected by state, wedge by stale heartbeat (``stale=`` seconds,
   the serving analog of ``HETU_LIVENESS_STALE``) — either way the
   router DRAINS the corpse from its own assignment records (a dead
   process cannot be introspected) and requeues every unretired request
@@ -65,7 +65,7 @@ behind this router, which owns everything a fleet adds to the problem:
   ``HETU_RESTART_BACKOFF``); a spent budget is terminal
   (``replica_failed`` + flight dump).
 
-- **Per-replica circuit breaker.**  ``HETU_ROUTER_BREAKER`` consecutive
+- **Per-replica circuit breaker.**  ``breaker_threshold`` consecutive
   failures eject the replica from routing (state "open"); after a
   cooldown one half-open PROBE request is let through — retiring it
   closes the breaker, another failure reopens it with a doubled
@@ -74,14 +74,14 @@ behind this router, which owns everything a fleet adds to the problem:
 
 - **Bounded retry + deadlines.**  A request the router holds (requeued
   off a corpse, or unplaceable) retries with exponential backoff
-  (``HETU_ROUTER_RETRY_BACKOFF``) up to ``HETU_ROUTER_RETRY_LIMIT``
+  (``retry_backoff``) up to ``retry_limit``
   times; exhaustion is a router terminal failure (event + flight dump).
   ``Request.deadline_s`` bounds how long the router may hold it before
   expiring it (``router_deadline``) instead of serving uselessly late.
 
 - **SLO-class load shedding + backpressure.**  Under pressure (fleet
-  queue fill >= ``HETU_ROUTER_SHED_QUEUE``, or any replica's SLO state
-  at breach with ``HETU_ROUTER_SHED_ON_SLO``) throughput-class
+  queue fill >= ``shed_queue``, or any replica's SLO state
+  at breach with ``shed_on_slo``) throughput-class
   submissions are shed (:class:`RouterShed`) while latency-class
   requests keep admitting until the fleet is hard-full — keeping
   latency-class TTFT inside budget by sacrificing the traffic that
@@ -152,6 +152,11 @@ _ROLE_RANK = {
 
 _ROLES = ("prefill", "decode", "mixed")
 
+# hottest directory-known prefixes moved per membership change: imported
+# into a joining replica before it takes traffic, exported from a
+# retiring one to its best peer (0 moves none)
+WARM_PREFIXES = 4
+
 
 class RouterShed(QueueFull):
     """SLO-class load shed: the fleet is under pressure and this
@@ -204,52 +209,41 @@ class ServingRouter:
     ``factory(index)`` builds one replica's engine — every incarnation,
     including post-death respawns, comes from it.  All engines must
     share one config (the router pre-validates prompt lengths against
-    the first incarnation's ``s_max``).  Knobs default to the
-    ``HETU_ROUTER_*`` / launcher env registry entries; constructor
-    arguments override.
+    the first incarnation's ``s_max``).  The fleet's size and roles
+    and the respawn budget default to the ``HETU_REPLICAS`` /
+    ``HETU_ROUTER_ROLES`` / launcher env registry entries; the routing
+    policy's defaults are the signature's: session affinity and the
+    prefix directory on (entries never expire), no wedge detection
+    (``stale=0``), a breaker that opens at 3 consecutive failures for
+    0.5 s doubling, 5 placement retries 0.02 s doubling apart,
+    throughput-class shedding from a queue 0.75 full and while any
+    replica's SLO is at breach.
     """
 
-    def __init__(self, factory, replicas=None, *, session_affinity=None,
-                 stale=None, breaker_threshold=None,
-                 breaker_cooldown=None, retry_limit=None,
-                 retry_backoff=None, shed_queue=None, shed_on_slo=None,
+    def __init__(self, factory, replicas=None, *, session_affinity=True,
+                 stale=0.0, breaker_threshold=3,
+                 breaker_cooldown=0.5, retry_limit=5,
+                 retry_backoff=0.02, shed_queue=0.75, shed_on_slo=True,
                  restart_limit=None, restart_backoff=None,
-                 directory=None, directory_ttl=None, roles=None,
+                 directory=True, directory_ttl=0.0, roles=None,
                  handoff_quant=None, kv_tiers=None, log_path=None):
         n = int(replicas if replicas is not None
                 else envvars.get_int("HETU_REPLICAS"))
         if n < 1:
             raise ValueError(f"a fleet needs >= 1 replica, got {n}")
-        self.session_affinity = (
-            session_affinity if session_affinity is not None
-            else envvars.get_bool("HETU_ROUTER_AFFINITY"))
-        self.stale = float(stale if stale is not None
-                           else envvars.get_float("HETU_ROUTER_STALE"))
-        self.breaker_threshold = int(
-            breaker_threshold if breaker_threshold is not None
-            else envvars.get_int("HETU_ROUTER_BREAKER"))
-        self.breaker_cooldown = float(
-            breaker_cooldown if breaker_cooldown is not None
-            else envvars.get_float("HETU_ROUTER_BREAKER_COOLDOWN"))
-        self.retry_limit = int(
-            retry_limit if retry_limit is not None
-            else envvars.get_int("HETU_ROUTER_RETRY_LIMIT"))
-        self.retry_backoff = float(
-            retry_backoff if retry_backoff is not None
-            else envvars.get_float("HETU_ROUTER_RETRY_BACKOFF"))
-        self.shed_queue = float(
-            shed_queue if shed_queue is not None
-            else envvars.get_float("HETU_ROUTER_SHED_QUEUE"))
-        self.shed_on_slo = (
-            shed_on_slo if shed_on_slo is not None
-            else envvars.get_bool("HETU_ROUTER_SHED_ON_SLO"))
+        self.session_affinity = bool(session_affinity)
+        self.stale = float(stale)
+        self.breaker_threshold = int(breaker_threshold)
+        self.breaker_cooldown = float(breaker_cooldown)
+        self.retry_limit = int(retry_limit)
+        self.retry_backoff = float(retry_backoff)
+        self.shed_queue = float(shed_queue)
+        self.shed_on_slo = bool(shed_on_slo)
         self.log_path = log_path
         # fleet prefix-cache directory (must exist before the replicas:
         # each incarnation wires itself in via _wire_replica)
-        use_dir = (directory if directory is not None
-                   else envvars.get_bool("HETU_ROUTER_DIRECTORY"))
         self.directory = (PrefixDirectory(ttl=directory_ttl)
-                          if use_dir else None)
+                          if directory else None)
         self.directory_killed = False
         # tiered KV (ISSUE 17): one fleet-shared spill/fetch ladder
         # behind every replica's pool — evicted prefix blocks tier to
@@ -849,7 +843,7 @@ class ServingRouter:
     # elastic fleet membership (live add / retire)
     # ------------------------------------------------------------- #
 
-    def add_replica(self, kind="mixed", *, warm_prefixes=None,
+    def add_replica(self, kind="mixed", *, warm_prefixes=WARM_PREFIXES,
                     probe=True):
         """Grow the fleet live: spawn a fresh supervised replica under
         the same factory/respawn budget the constructor fleet got, at
@@ -1048,13 +1042,11 @@ class ServingRouter:
         kv = getattr(eng, "kv", None) if eng is not None else None
         return kv is not None and getattr(kv, "prefix_share", False)
 
-    def _warm_replica(self, rep, budget=None):
+    def _warm_replica(self, rep, budget):
         """Prefix-warm a joining replica BEFORE it takes traffic:
         import its peers' hottest DIRECTORY-KNOWN prefixes (a prefix no
         directory entry names attracts no routed traffic — not worth
         the wire bytes).  Returns how many prefixes landed."""
-        if budget is None:
-            budget = envvars.get_int("HETU_AUTOSCALE_WARM_PREFIXES")
         if budget <= 0 or not self._warm_prefix_ok(rep):
             return 0
         block = rep.engine.kv.block
@@ -1086,7 +1078,7 @@ class ServingRouter:
                 warmed += 1
         return warmed
 
-    def _export_hot_prefixes(self, rep, budget=None):
+    def _export_hot_prefixes(self, rep, budget=WARM_PREFIXES):
         """A retiring replica's warmth must not die with it: export its
         hottest directory-known prefixes to the best-scoring UP peer
         through the same codec warming uses.  Runs BEFORE the directory
@@ -1096,8 +1088,6 @@ class ServingRouter:
         SPILLS to the tier ladder instead of dying with the pool
         (pre-tier behavior dropped it outright).  Returns
         ``(exported, spilled)``."""
-        if budget is None:
-            budget = envvars.get_int("HETU_AUTOSCALE_WARM_PREFIXES")
         if budget <= 0 or not self._warm_prefix_ok(rep):
             return 0, 0
         kv = rep.engine.kv

@@ -12,12 +12,12 @@ TIME with zero request loss.  Per replica the cycle is
   it; in-flight work keeps stepping).
 - **drain**: every request the router assigned to the replica retires
   (or requeues off it if it dies) and the engine's own queue empties.
-  Draining is bounded by ``HETU_SWAP_DRAIN_STEPS`` router steps.
+  Draining is bounded by ``drain_steps`` (2,000) router steps.
 - **swap**: ``engine.swap_params`` replaces the param dict between
   steps — no recompile (the jitted step takes params as arguments),
   and the spec-decode truncated-layer draft inherits the swap for free
   because it shares the target's param dict.
-- **probe**: a version-tagged greedy decode (``HETU_SWAP_PROBE_TOKENS``
+- **probe**: a version-tagged greedy decode (``probe_tokens``, 4
   tokens) must retire on the NEW version before the replica serves
   traffic again — the half-open readmission check of the breaker,
   applied to weights.
@@ -33,7 +33,7 @@ version-push seam models a corrupt/stale version read.  Every failure
 degrades cleanly: the dead replica respawns on the LAST COMMITTED
 version (the coordinator wraps the replica factories), the coordinator
 marks the rollout failed, auto-rolls any already-swapped replicas back
-(``HETU_SWAP_ROLLBACK``), and the flight recorder dumps the swap
+(``rollback=``, on by default), and the flight recorder dumps the swap
 timeline.  A stale push (incoming version <= committed) never touches
 an engine.
 
@@ -48,7 +48,6 @@ from __future__ import annotations
 
 import time
 
-from .. import envvars
 from ..ps import faults
 from ..telemetry import flight
 from .request import Request
@@ -77,21 +76,15 @@ class WeightSyncCoordinator:
     and no lock.
     """
 
-    def __init__(self, router, params, version, *, probe_tokens=None,
-                 drain_steps=None, rollback=None, probe_prompt=None,
+    def __init__(self, router, params, version, *, probe_tokens=4,
+                 drain_steps=2000, rollback=True, probe_prompt=None,
                  probe_factory=None):
         self.router = router
         self.committed_params = dict(params)
         self.committed_version = int(version)
-        self.probe_tokens = int(
-            probe_tokens if probe_tokens is not None
-            else envvars.get_int("HETU_SWAP_PROBE_TOKENS"))
-        self.drain_steps = int(
-            drain_steps if drain_steps is not None
-            else envvars.get_int("HETU_SWAP_DRAIN_STEPS"))
-        self.rollback = bool(
-            rollback if rollback is not None
-            else envvars.get_bool("HETU_SWAP_ROLLBACK"))
+        self.probe_tokens = int(probe_tokens)
+        self.drain_steps = int(drain_steps)
+        self.rollback = bool(rollback)
         self.probe_prompt = list(probe_prompt or _DEFAULT_PROBE_PROMPT)
         # fn(replica_index, version) -> Request/EmbedRequest: overrides
         # the default greedy-GPT probe (an embed fleet's probe payload

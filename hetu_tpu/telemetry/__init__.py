@@ -1,5 +1,5 @@
-"""Unified telemetry: run-wide spans, a metrics registry, ONE event
-pipeline, and measurement health gates.
+"""Unified telemetry: run-wide spans, a metrics registry and ONE event
+pipeline.
 
 Every layer emits into this subsystem and every tool reads from it:
 
@@ -10,8 +10,6 @@ Every layer emits into this subsystem and every tool reads from it:
   event-shape contract.
 - :mod:`.metrics` — thread-safe counters/gauges/histograms behind
   ``snapshot()``.
-- :mod:`.health` — banking gates: sibling-consistency, physics
-  ceiling, live-vs-banked provenance stamps (bench.py wires them).
 - :mod:`.slo` — declarative serving SLOs (TTFT / per-stream tok/s)
   with sliding-window burn rates behind the engine's ``health()``.
 - :mod:`.flight` — the chaos flight recorder: an always-on bounded
@@ -26,7 +24,7 @@ traced, lowered, compiled or loaded, as ``compile`` records and
 ``compile.*`` counters) uninstalled.
 """
 
-from . import flight, health, metrics, slo, top, trace  # noqa: F401
+from . import flight, metrics, slo, top, trace  # noqa: F401
 from .events import (  # noqa: F401
     REQUIRED_FIELDS, STREAMS, TelemetrySink, counter, emit, enabled,
     gauge, get_sink, histogram, inc, make_record, observe, open_spans,
@@ -41,7 +39,7 @@ compile_cache.watch()
 __all__ = [
     "REQUIRED_FIELDS", "STREAMS", "REGISTRY", "TelemetrySink",
     "counter", "emit", "enabled", "flight", "gauge", "get_sink",
-    "health", "histogram", "inc", "make_record", "metrics", "observe",
+    "histogram", "inc", "make_record", "metrics", "observe",
     "open_spans", "percentile", "reset", "set_gauge", "slo", "snapshot",
     "span", "spanned", "spanned_calls", "top", "trace",
     "validate_record",

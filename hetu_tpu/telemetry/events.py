@@ -174,11 +174,9 @@ REQUIRED_FIELDS = {
     # the compile watch (hetu_tpu/compile_cache.py): one record a
     # program and phase (trace / lower / backend / cache_load)
     "compile": ("phase", "fun", "ms"),
-    # telemetry core + bench
+    # telemetry core
     "span": ("name", "ms"),
     "gauge": ("name", "value"),
-    "bench_row": ("config",),
-    "bench_probe_health": ("ok",),
 }
 
 

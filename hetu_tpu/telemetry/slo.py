@@ -29,9 +29,10 @@ violations land in the serve stream next to the request records that
 caused them (``bin/hetu_top.py`` tails both).
 
 Env construction (``SLOMonitor.from_env``): ``HETU_SLO_TTFT_MS`` /
-``HETU_SLO_TPS`` declare the two SLO kinds, ``HETU_SLO_OBJECTIVE`` the
-shared objective, ``HETU_SLO_WINDOW`` the window size.  With neither
-target set the monitor is empty and ``health()`` is always ``ok``.
+``HETU_SLO_TPS`` declare the two SLO kinds, each at the default
+objective (0.99) over the default window (256 finished requests).  With
+neither target set the monitor is empty and ``health()`` is always
+``ok``.
 """
 
 from __future__ import annotations
@@ -90,10 +91,10 @@ class SLOMonitor:
     legacy log) alongside the request records.  Default: the merged
     telemetry stream."""
 
-    def __init__(self, slos=(), window=None, breach_burn=2.0,
+    def __init__(self, slos=(), window=256, breach_burn=2.0,
                  emit_fn=None):
         self.slos = list(slos)
-        self.window = int(window or envvars.get_int("HETU_SLO_WINDOW"))
+        self.window = int(window)
         self.breach_burn = float(breach_burn)
         self.emit_fn = emit_fn or (
             lambda kind, **f: events.emit(kind, _stream="serve", **f))
@@ -107,14 +108,13 @@ class SLOMonitor:
     def from_env(cls, emit_fn=None):
         """The env-declared monitor (``HETU_SLO_*``); empty (always ok)
         when no target is set."""
-        objective = envvars.get_float("HETU_SLO_OBJECTIVE")
         slos = []
         ttft = envvars.get_float("HETU_SLO_TTFT_MS")
         if ttft is not None:
-            slos.append(SLO("ttft", "latency", ttft, objective))
+            slos.append(SLO("ttft", "latency", ttft))
         tps = envvars.get_float("HETU_SLO_TPS")
         if tps is not None:
-            slos.append(SLO("stream_tok_s", "throughput", tps, objective))
+            slos.append(SLO("stream_tok_s", "throughput", tps))
         return cls(slos, emit_fn=emit_fn)
 
     # ------------------------------------------------------------- #

@@ -55,11 +55,6 @@ REPLICA, with requests the router requeued off a dead replica
 Balance is skipped when the input contains a ``flight_dump`` header —
 a flight recording is by definition a mid-flight snapshot.
 
-``--check`` also enforces the mixed-quantization rule: every
-``bench_row`` in the stream must carry the same ``quant`` stamp
-(``hetu_tpu.quant.active_modes()``) — quantized and exact measurements
-can never be compared silently.
-
 ``--check`` also enforces the speculative-attribution rule: a
 ``req_retire`` record carrying spec fields must satisfy
 ``spec_accepted + spec_bonus + 1 == n_generated`` — every retired
@@ -662,29 +657,6 @@ def check_tier_balance(events):
     return problems
 
 
-def check_quant_consistency(events):
-    """The mixed-quantization rule: every ``bench_row`` record in one
-    stream must carry the SAME ``quant`` stamp (rows predating the
-    stamp count as "off" — they were measured exact).  A stream mixing
-    int8-wire/int8-KV rows with exact rows is not comparable: the
-    quantized run moves ~4x fewer bytes, so ranking them side by side
-    silently rewards the lossy configuration.  Returns problem strings;
-    empty when consistent (or when there are no bench rows)."""
-    by_quant = {}
-    for e in events:
-        if e.get("event") != "bench_row":
-            continue
-        by_quant.setdefault(str(e.get("quant") or "off"), []).append(
-            str(e.get("config")))
-    if len(by_quant) <= 1:
-        return []
-    detail = "; ".join(f"{q}: {sorted(set(c))}"
-                       for q, c in sorted(by_quant.items()))
-    return [f"quant-mix: bench rows were measured under different "
-            f"quantization modes and cannot be compared ({detail}) — "
-            f"re-run one side or split the streams"]
-
-
 def check_spec_attribution(events):
     """The speculative-attribution rule: per retired request, accepted
     draft tokens + bonus samples + the prefill token must equal the
@@ -959,7 +931,7 @@ def main(argv=None):
                     help="validate every record against the event "
                          "contract AND the request span-balance rule "
                          "(every serve_admit has a serve_finish), the "
-                         "quant-mix rule, the speculative-attribution "
+                         "speculative-attribution "
                          "rule (accepted + bonus + 1 == n_generated "
                          "per retired request), and the KV-handoff "
                          "pairing rule (every kv_handoff_out has a "
@@ -1010,8 +982,6 @@ def main(argv=None):
                                 f"{json.dumps(rec)[:160]}")
         balance = check_span_balance(events)
         problems.extend(balance)
-        qmix = check_quant_consistency(events)
-        problems.extend(qmix)
         spec = check_spec_attribution(events)
         problems.extend(spec)
         handoff = check_handoff_balance(events)
@@ -1045,7 +1015,6 @@ def main(argv=None):
         print(json.dumps({"records": len(events), "bad_lines": bad,
                           "contract_violations": len(problems),
                           "span_balance_violations": len(balance),
-                          "quant_mix_violations": len(qmix),
                           "spec_attribution_violations": len(spec),
                           "handoff_violations": len(handoff),
                           "gather_violations": len(gather),

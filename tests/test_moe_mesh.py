@@ -218,9 +218,10 @@ class TestShardMapA2A:
             assert np.isfinite(float(np.asarray(out_v[0])))
 
 
-def test_dispatch_formulations_agree():
+def test_dispatch_formulations_agree(monkeypatch):
     """The one-hot-matmul and row-scatter dispatch forms must produce
     identical expert buffers and identical combine-data gradients."""
+    from hetu_tpu.graph import ops_moe
     from hetu_tpu.graph.ops_moe import _scatter_rows
 
     rng = np.random.RandomState(5)
@@ -234,12 +235,39 @@ def test_dispatch_formulations_agree():
                   [(pos, valid, gates)],
                   [(pos, valid, None), ((pos + 3) % slots,
                                         jnp.ones_like(valid), gates)]):
-        a = _scatter_rows(terms, slots, src, jnp.float32,
-                          force_scatter=False)
-        b = _scatter_rows(terms, slots, src, jnp.float32,
-                          force_scatter=True)
+        a = _scatter_rows(terms, slots, src, jnp.float32)
+        with monkeypatch.context() as m:
+            m.setattr(ops_moe, "_ONEHOT_DISPATCH_MAX_ELEMS", 0)
+            b = _scatter_rows(terms, slots, src, jnp.float32)
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=1e-5, atol=1e-5)
+
+
+def test_dispatch_form_follows_the_mask_size(monkeypatch):
+    """``_scatter_rows`` chooses its form from ``N * n_slots`` alone:
+    the one-hot matmul up to ``_ONEHOT_DISPATCH_MAX_ELEMS`` mask
+    elements, the row scatter-add over it, with equal results at the
+    boundary."""
+    import jax
+    from hetu_tpu.graph import ops_moe
+
+    rng = np.random.RandomState(7)
+    N, D, slots = 32, 8, 12
+    src = jnp.asarray(rng.randn(N, D).astype(np.float32))
+    pos = jnp.asarray(rng.randint(0, slots, N).astype(np.int32))
+    terms = [(pos, pos < slots, None)]
+
+    def run(limit):
+        monkeypatch.setattr(ops_moe, "_ONEHOT_DISPATCH_MAX_ELEMS", limit)
+        fn = lambda s: ops_moe._scatter_rows(terms, slots, s, jnp.float32)
+        prims = {e.primitive.name for e in jax.make_jaxpr(fn)(src).eqns}
+        return prims, np.asarray(fn(src))
+
+    at, out_at = run(N * slots)          # the mask fits: one-hot matmul
+    over, out_over = run(N * slots - 1)  # one element over: scatter-add
+    assert "dot_general" in at and "scatter-add" not in at
+    assert "scatter-add" in over and "dot_general" not in over
+    np.testing.assert_allclose(out_at, out_over, rtol=1e-5, atol=1e-5)
 
 
 class TestBertMoEFlagship:

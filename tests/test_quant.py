@@ -117,7 +117,6 @@ class TestCodec:
         assert quant.resolve_quant(None, "HETU_PS_QUANT") is None
         monkeypatch.setenv("HETU_PS_QUANT", "int8")
         assert quant.ps_quant() == "int8"
-        assert quant.active_modes() == "ps=int8"
         with pytest.raises(ValueError):
             quant.resolve_quant("int3", "HETU_PS_QUANT")
 
@@ -660,30 +659,3 @@ class TestKVQuantEngine:
         assert not isinstance(eng.kv.cache_k, tuple)
         assert eng.kv.cache_k.dtype == jnp.float32
         assert eng.kv.quant is None
-
-
-# --------------------------------------------------------------------- #
-# provenance
-# --------------------------------------------------------------------- #
-
-class TestQuantProvenance:
-    def test_active_modes_composes(self, monkeypatch):
-        assert quant.active_modes() == "off"
-        monkeypatch.setenv("HETU_KV_QUANT", "int8")
-        monkeypatch.setenv("HETU_PS_QUANT", "int8")
-        assert quant.active_modes() == "ps=int8,kv=int8"
-
-    def test_trace_check_rejects_mixed_bench_rows(self):
-        from hetu_tpu.telemetry.trace import check_quant_consistency
-        rows = [{"event": "bench_row", "config": "a", "quant": "off"},
-                {"event": "bench_row", "config": "b",
-                 "quant": "kv=int8"}]
-        assert check_quant_consistency(rows)
-        assert not check_quant_consistency(rows[:1])
-        # a legacy row with no stamp counts as "off" and clashes with
-        # a quantized row — never compared silently
-        legacy = [{"event": "bench_row", "config": "old"},
-                  rows[1]]
-        assert check_quant_consistency(legacy)
-        assert not check_quant_consistency(
-            [{"event": "bench_row", "config": "old"}, rows[0]])

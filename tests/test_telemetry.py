@@ -1,12 +1,11 @@
 """Unified telemetry subsystem (hetu_tpu/telemetry): the one event
-pipeline, spans/metrics, health gates, and trace export.
+pipeline, spans/metrics, and trace export.
 
 The acceptance spine (ISSUE 5): a training step, a serving request, and
 a validate failure all land in ONE merged JSONL stream via the sink;
 ``bin/hetu_trace.py`` exports a loadable Perfetto trace from it; with
-``HETU_TELEMETRY=0`` the instrumentation is a no-op; and the health
-gate rejects a synthetic wedged probe (>2x off siblings) while passing
-a clean one.  Plus the shared EVENT CONTRACT test covering all four
+``HETU_TELEMETRY=0`` the instrumentation is a no-op.  Plus the shared
+EVENT CONTRACT test covering all four
 streams — ``{"t", "event"}`` + per-kind required fields as a single
 schema instead of four conventions.
 """
@@ -21,7 +20,6 @@ import pytest
 
 import hetu_tpu as ht
 from hetu_tpu import telemetry
-from hetu_tpu.telemetry import health
 from hetu_tpu.telemetry.trace import (
     main as trace_main, read_events, to_chrome_trace,
 )
@@ -192,128 +190,6 @@ class TestMetrics:
                 pass
         dt = time.perf_counter() - t0
         assert dt < 0.05, f"1000 disabled spans took {dt * 1e3:.1f} ms"
-
-
-# --------------------------------------------------------------------- #
-# health gates (the ISSUE's acceptance pair: reject wedged, pass clean)
-# --------------------------------------------------------------------- #
-
-class TestHealthGates:
-    def test_rejects_synthetic_wedged_probe(self):
-        # the observed Aug-2 window: batch 48 wedged at 64.6 against
-        # 216.5/223 neighbors
-        v = health.check_sibling_consistency({32: 216.5, 48: 64.6,
-                                              64: 223.0})
-        assert v["ok"] is False
-        assert list(v["wedged"]) == ["48"]
-        assert v["wedged"]["48"]["ratio"] > 2.0
-        assert set(v["clean"]) == {"32", "64"}
-
-    def test_passes_clean_probe_set(self):
-        v = health.check_sibling_consistency({32: 258.5, 48: 252.0,
-                                              64: 251.0})
-        assert v["ok"] is True and v["wedged"] == {}
-
-    def test_two_probe_low_outlier(self):
-        v = health.check_sibling_consistency({32: 100.0, 64: 40.0})
-        assert list(v["wedged"]) == ["64"]
-
-    def test_gate_emits_event(self):
-        health.check_sibling_consistency({1: 1.0, 2: 1.0})
-        recs = telemetry.get_sink().recent(kind="bench_probe_health")
-        assert recs and recs[-1]["ok"] is True
-
-    def test_physics_ceiling_rejects_impossible_mfu(self):
-        v = health.check_physics_ceiling(mfu=1.2, platform="tpu")
-        assert v["ok"] is False and "MFU" in v["violations"][0]
-
-    def test_physics_ceiling_rejects_above_calibrated_peak(self):
-        peak = health._calibrated_peak_tflops()
-        if peak is None:
-            pytest.skip("no CALIBRATION_TPU.json in tree")
-        v = health.check_physics_ceiling(tflops_chip=peak * 2,
-                                         platform="tpu")
-        assert v["ok"] is False and "calibrated" in v["violations"][0]
-
-    def test_physics_ceiling_passes_sane_and_cpu(self):
-        assert health.check_physics_ceiling(mfu=0.48, tflops_chip=95.0,
-                                            platform="tpu")["ok"]
-        assert health.check_physics_ceiling(mfu=None,
-                                            platform="cpu")["ok"]
-
-    def test_provenance_stamp(self):
-        live = health.stamp_provenance({"value": 1.0}, live=True)
-        assert live["provenance"] == "live" and "measured_at" not in live
-        banked = health.stamp_provenance({"value": 1.0}, live=False,
-                                         measured_at="2026-07-30")
-        assert banked["provenance"] == "banked"
-        assert banked["measured_at"] == "2026-07-30"
-
-
-# --------------------------------------------------------------------- #
-# bench wiring (satellite #1: headline semantics + probe gate)
-# --------------------------------------------------------------------- #
-
-class TestBenchWiring:
-    def test_probe_health_drops_wedged_from_selection(self):
-        import bench
-        numeric = {32: 216.5, 48: 64.6, 64: 223.0}
-        v = bench._probe_health(numeric)
-        assert v["ok"] is False and 48 not in numeric
-        assert max(numeric, key=numeric.get) == 64
-
-    def test_probe_health_keeps_clean(self):
-        import bench
-        numeric = {32: 258.5, 48: 252.0}
-        v = bench._probe_health(numeric)
-        assert v["ok"] is True and set(numeric) == {32, 48}
-
-    def test_no_chip_fails_and_banked_rows_keep_their_platform(
-            self, monkeypatch, capsys):
-        """A bench run that finds no chip FAILS and prints nothing — no
-        CPU fallback, no re-emitted row; ``JAX_PLATFORMS=cpu`` is the
-        one explicit way to a CPU run.  Rows merged from an earlier
-        run's matrix file say whose platform they were measured on."""
-        import bench
-        monkeypatch.delenv("JAX_PLATFORMS", raising=False)
-        with pytest.raises(SystemExit) as e:
-            bench.main()                 # the suite's backend is the CPU
-        assert e.value.code not in (0, None)
-        assert capsys.readouterr().out == ""
-        monkeypatch.setenv("JAX_PLATFORMS", "cpu")
-        assert bench._require_backend() == "cpu"
-        results = {
-            "bert_base": {"value": 221.7, "mfu": 0.407,
-                          "platform": "tpu",
-                          "measured_at": "2026-08-02 10:00 UTC"},
-            "bert4l": {"value": 630.0, "measured_at":
-                       "2026-08-02 10:30 UTC"},
-        }
-        f = bench._provenance_fields(results, ran=set(),
-                                     head_name="bert_base",
-                                     run_platform="cpu",
-                                     prev_platform="tpu")
-        assert f["platform"] == "tpu"
-        assert f["run_platform"] == "cpu"
-        assert f["headline_provenance"] == "banked"
-        assert f["rows_live"] == []
-        assert f["rows_banked"]["bert_base"]["measured_at"] == \
-            "2026-08-02 10:00 UTC"
-        # rows without a per-row platform stamp inherit the previous
-        # capture's platform, not the current run's
-        assert f["rows_banked"]["bert4l"]["platform"] == "tpu"
-
-    def test_headline_live_rows(self):
-        import bench
-        results = {"bert_base": {"value": 9.0, "platform": "cpu",
-                                 "measured_at": "now"}}
-        f = bench._provenance_fields(results, ran={"bert_base"},
-                                     head_name="bert_base",
-                                     run_platform="cpu")
-        assert f["platform"] == "cpu"
-        assert f["headline_provenance"] == "live"
-        assert f["rows_live"] == ["bert_base"]
-        assert f["rows_banked"] == {}
 
 
 # --------------------------------------------------------------------- #
