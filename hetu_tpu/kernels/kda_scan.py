@@ -39,6 +39,17 @@ the published widths) and both score matrices to memory, and solves the
     the mixer's order) copies one head's ``S`` back as it was and
     computes nothing.
 
+``exact`` (a decay with no lower bound, ``KDASpec.unbounded``) forms the
+pairs inside a chunk LEVEL BY LEVEL instead (``kda_decode._levels``, built
+here from iotas and shifts): at the level of half-blocks of ``s`` rows a
+row in the second half of its block of ``2 s`` and a column in the first
+half take the first half's last row as their reference, so that both
+exponents are sums of ``g`` (one product of 0/1 matrices with the chunk's
+``g`` gives every level's) and at most 0 whatever ``g`` is; six levels a
+chunk of 64, each one product on the MXU as a sub-block's row is, and a
+seventh for the diagonal of ``P``.  Everything after the two score
+matrices is the same code.
+
 Precision is the XLA form's, nowhere lower: the state read, decayed,
 corrected and stored in float32 (stored in the state's own dtype, once a
 q-block); the running sums, the exponentials and the solve in float32;
@@ -82,9 +93,57 @@ def _solve_unit_lower(A, rhs):
     return X
 
 
+def _level_sums(ii, jj, c):
+    """``kda_decode._levels`` for a chunk of ``c`` rows from the iotas
+    ``ii`` / ``jj`` [c, c]: (the 0/1 matrices stacked down the rows
+    [(1 + 2 levels) c, c] float32: the inclusive running sum first, then
+    every level's row sums, then every level's column sums; the levels'
+    pair masks)."""
+    levels = (c - 1).bit_length()
+    second = [((ii >> lv) & 1) == 1 for lv in range(levels)]
+    parts = [jj <= ii]
+    parts += [second[lv] & (jj >= ((ii >> lv) << lv)) & (jj <= ii)
+              for lv in range(levels)]
+    parts += [~second[lv] & (jj > ii) & (jj < (((ii >> lv) + 1) << lv))
+              for lv in range(levels)]
+    pairs = [second[lv] & (((jj >> lv) & 1) == 0)
+             & ((ii >> (lv + 1)) == (jj >> (lv + 1)))
+             for lv in range(levels)]
+    return jnp.concatenate(
+        [jnp.where(p, 1.0, 0.0) for p in parts], axis=0).astype(
+            jnp.float32), pairs
+
+
+def _scores_by_level(qz, kz, gz, bz, sums, pairs, cd):
+    """(A [c, c] strictly lower and times its row's beta, P [c, c] lower
+    with its diagonal, the inclusive running sum G [c, D]) of one chunk
+    by ``_level_sums``' pairing: every exponent a sum of ``g``."""
+    f32 = jnp.float32
+    c, levels = qz.shape[0], len(pairs)
+    s_all = jnp.dot(sums, gz, precision=_HI, preferred_element_type=f32)
+    cross = (((1,), (1,)), ((), ()))
+    A = P = jnp.zeros((c, c), f32)
+    for lv in range(levels):
+        er = jnp.exp(s_all[(1 + lv) * c:(2 + lv) * c])              # <= 1
+        ec = jnp.exp(s_all[(1 + levels + lv) * c:(2 + levels + lv) * c])
+        got = jax.lax.dot_general(
+            jnp.concatenate([(kz * er).astype(cd), (qz * er).astype(cd)],
+                            axis=0), (kz * ec).astype(cd), cross,
+            preferred_element_type=f32)                        # [2 c, c]
+        A = A + jnp.where(pairs[lv], got[:c], 0.0)
+        P = P + jnp.where(pairs[lv], got[c:], 0.0)
+    ii = jax.lax.broadcasted_iota(jnp.int32, (c, c), 0)
+    jj = jax.lax.broadcasted_iota(jnp.int32, (c, c), 1)
+    # a row's own column decays by nothing
+    P = P + jnp.where(ii == jj, jax.lax.dot_general(
+        qz.astype(cd), kz.astype(cd), cross, preferred_element_type=f32),
+        0.0)
+    return A * bz, P, s_all[:c]
+
+
 def _kda_chunk_scan_kernel(slot_ref, ql_ref, q_ref, k_ref, v_ref, g_ref,
                            b_ref, s_ref, y_ref, so_ref, sf_ref, *, chunk,
-                           sub):
+                           sub, exact=False):
     """Grid (lane, head).  ``q_ref`` / ``k_ref`` / ``v_ref`` [Q, D] in
     the rows' dtype, ``g_ref`` [Q, D] float32, ``b_ref`` [Q, H] float32
     (the lane's beta, every head's), ``s_ref`` / ``so_ref`` [D, D] in the
@@ -109,14 +168,19 @@ def _kda_chunk_scan_kernel(slot_ref, ql_ref, q_ref, k_ref, v_ref, g_ref,
         sf_ref[...] = s_ref[...].astype(f32)
         ii = jax.lax.broadcasted_iota(jnp.int32, (c, c), 0)
         jj = jax.lax.broadcasted_iota(jnp.int32, (c, c), 1)
-        # a row's sub-block by comparisons (no vector division)
-        blk_i = sum((ii >= b * sub).astype(jnp.int32) for b in range(1, m))
-        blk_j = sum((jj >= b * sub).astype(jnp.int32) for b in range(1, m))
-        # [sums inside a row's sub-block up to it; sums over the
-        # sub-blocks before it]: the running sum of g in two parts
-        sums = jnp.concatenate(
-            [jnp.where((blk_i == blk_j) & (jj <= ii), 1.0, 0.0),
-             jnp.where(blk_j < blk_i, 1.0, 0.0)], axis=0).astype(f32)
+        if exact:
+            sums, pairs = _level_sums(ii, jj, c)
+        else:
+            # a row's sub-block by comparisons (no vector division)
+            blk_i = sum((ii >= b * sub).astype(jnp.int32)
+                        for b in range(1, m))
+            blk_j = sum((jj >= b * sub).astype(jnp.int32)
+                        for b in range(1, m))
+            # [sums inside a row's sub-block up to it; sums over the
+            # sub-blocks before it]: the running sum of g in two parts
+            sums = jnp.concatenate(
+                [jnp.where((blk_i == blk_j) & (jj <= ii), 1.0, 0.0),
+                 jnp.where(blk_j < blk_i, 1.0, 0.0)], axis=0).astype(f32)
         ones = jnp.ones((c, D), f32)
         row = jax.lax.broadcasted_iota(jnp.int32, (c, 1), 0)
         head = jax.lax.broadcasted_iota(jnp.int32, (c, b_ref.shape[1]), 1)
@@ -132,29 +196,32 @@ def _kda_chunk_scan_kernel(slot_ref, ql_ref, q_ref, k_ref, v_ref, g_ref,
             bz = jnp.where(live, jnp.sum(
                 jnp.where(head == h, b_ref[at, :], 0.0), axis=1,
                 keepdims=True), 0.0)                           # [c, 1]
-            two = jnp.dot(sums, gz, precision=_HI,
-                          preferred_element_type=f32)          # [2 c, D]
-            Gl, Rf = two[:c], two[c:]
-            G = Gl + Rf
-            up = jnp.exp(Gl)                                   # <= 1
-            ku, qu = (kz * up).astype(cd), (qz * up).astype(cd)
-            A, P = [], []
-            for i in range(m):
-                # column j seen from sub-block i: exp(R_i - G_j) for j's
-                # sub-block at or before i (masked BEFORE the
-                # exponential: a later one's is e^320)
-                R = Rf[i * sub:i * sub + 1]                    # [1, D]
-                down = jnp.exp(jnp.where(row < (i + 1) * sub, R - G,
-                                         -jnp.inf))
-                at_i = slice(i * sub, (i + 1) * sub)
-                both = jax.lax.dot_general(
-                    jnp.concatenate([ku[at_i], qu[at_i]], axis=0),
-                    (kz * down).astype(cd), (((1,), (1,)), ((), ())),
-                    preferred_element_type=f32)                # [2 sub, c]
-                A.append(both[:sub])
-                P.append(both[sub:])
-            A = jnp.where(jj < ii, jnp.concatenate(A, axis=0) * bz, 0.0)
-            P = jnp.where(jj <= ii, jnp.concatenate(P, axis=0), 0.0)
+            if exact:
+                A, P, G = _scores_by_level(qz, kz, gz, bz, sums, pairs, cd)
+            else:
+                two = jnp.dot(sums, gz, precision=_HI,
+                              preferred_element_type=f32)      # [2 c, D]
+                Gl, Rf = two[:c], two[c:]
+                G = Gl + Rf
+                up = jnp.exp(Gl)                               # <= 1
+                ku, qu = (kz * up).astype(cd), (qz * up).astype(cd)
+                A, P = [], []
+                for i in range(m):
+                    # column j seen from sub-block i: exp(R_i - G_j)
+                    # for j's sub-block at or before i (masked BEFORE
+                    # the exponential: a later one's is e^320)
+                    R = Rf[i * sub:i * sub + 1]                # [1, D]
+                    down = jnp.exp(jnp.where(row < (i + 1) * sub, R - G,
+                                             -jnp.inf))
+                    at_i = slice(i * sub, (i + 1) * sub)
+                    both = jax.lax.dot_general(
+                        jnp.concatenate([ku[at_i], qu[at_i]], axis=0),
+                        (kz * down).astype(cd), (((1,), (1,)), ((), ())),
+                        preferred_element_type=f32)            # [2 sub, c]
+                    A.append(both[:sub])
+                    P.append(both[sub:])
+                A = jnp.where(jj < ii, jnp.concatenate(A, axis=0) * bz, 0.0)
+                P = jnp.where(jj <= ii, jnp.concatenate(P, axis=0), 0.0)
             eG = jnp.exp(G)
             WU = _solve_unit_lower(
                 A, jnp.concatenate([kz * eG, vz], axis=1) * bz)
@@ -191,9 +258,10 @@ def _kda_chunk_scan_kernel(slot_ref, ql_ref, q_ref, k_ref, v_ref, g_ref,
         so_ref[...] = sf_ref[...].astype(so_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("chunk", "sub", "interpret"))
+@functools.partial(jax.jit, static_argnames=("chunk", "sub", "interpret",
+                                             "exact"))
 def _kda_chunk_scan_call(slot, q_len, q, k, v, g, beta, mats, *, chunk,
-                         sub, interpret):
+                         sub, interpret, exact=False):
     """``_kda_chunk_scan_kernel`` over ``q`` / ``k`` / ``v`` / ``g``
     [lanes, Q, H D], ``beta`` [lanes, Q, H] and the manager's state."""
     lanes, Q, H = beta.shape
@@ -211,7 +279,8 @@ def _kda_chunk_scan_call(slot, q_len, q, k, v, g, beta, mats, *, chunk,
     row = pl.BlockSpec((None, Q, D), rows)
     s_spec = pl.BlockSpec((None, None, None, D, D), state)
     return pl.pallas_call(
-        functools.partial(_kda_chunk_scan_kernel, chunk=chunk, sub=sub),
+        functools.partial(_kda_chunk_scan_kernel, chunk=chunk, sub=sub,
+                          exact=exact),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2, grid=(lanes, H),
             in_specs=[row, row, row, row,
@@ -231,7 +300,7 @@ def _kda_chunk_scan_call(slot, q_len, q, k, v, g, beta, mats, *, chunk,
 
 
 def kda_chunk_scan(slot, q_len, q, k, v, g, beta, mats, *, chunk, sub,
-                   interpret=None):
+                   exact=False, interpret=None):
     """The lanes' q-blocks through the chunked delta rule, on the
     manager's state where it lies.
 
@@ -244,9 +313,10 @@ def kda_chunk_scan(slot, q_len, q, k, v, g, beta, mats, *, chunk, sub,
     H D] float32, ``beta`` [lanes, Q, H] float32, ``mats`` [1, slots, H,
     D, D].  Returns (o [lanes, Q, H D] float32, mats after the
     q-blocks): ``kda_chunked``'s, from and into the lanes' slots; an
-    idle lane's rows are 0 and its state keeps its bits."""
+    idle lane's rows are 0 and its state keeps its bits.  ``exact``: the
+    pairs level by level, for a ``g`` with no lower bound."""
     if interpret is None:
         interpret = _use_interpret()
     return _kda_chunk_scan_call(
         slot.astype(jnp.int32), q_len.astype(jnp.int32), q, k, v, g, beta,
-        mats, chunk=chunk, sub=sub, interpret=interpret)
+        mats, chunk=chunk, sub=sub, interpret=interpret, exact=exact)

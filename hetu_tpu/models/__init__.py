@@ -42,3 +42,4 @@ from .moe_decode import (
 )
 from .parallel_moe import ParallelMoEConfig, init_parallel_moe_params
 from .kda_latent import KDALatentConfig, init_kda_latent_params
+from .kda_gqa import KDAGQAConfig, init_kda_gqa_params

@@ -467,7 +467,9 @@ class BlockSpec(NamedTuple):
     state-space mixer ALONE on the layer's one norm (state, no page), a
     "kda" layer running the gated delta rule ``kda`` (a
     ``kda_decode.KDASpec``: a conv tail and a matrix state a slot, no
-    page; it stands beside latent operators in a latent block) and
+    page; it stands beside latent operators in a latent block, or beside
+    plain "attention" layers in the grouped-query block, whose K/V pool
+    the one manager then holds beside the delta rule's states) and
     a "none" layer running no operator (it keeps nothing); head_dim: the head size where it is the
     configuration's own key and not ``hidden / heads`` (0: that
     quotient); mup: the block's forward multipliers (``MuP``; None: no
@@ -482,7 +484,11 @@ class BlockSpec(NamedTuple):
     "parallel" (``x = ln1(h)``, ``h <- h + op(x) + ffn(x)``: ONE norm a
     layer, the operator and the FFN side by side on its rows, one
     addition into the residual); head: "tied" (the embedding table) |
-    "untied" (``{name}_lm_head_weight`` [hidden, vocab])."""
+    "untied" (``{name}_lm_head_weight`` [hidden, vocab]); attn_gate: the
+    K/V attention's output times ``sigmoid(x W_gate)``, one a COLUMN
+    (``{us}_attn_gate_weight`` [hidden, H Dh], of the layer's normed
+    rows), before ``W_o`` (the ``solar_open2`` family's
+    ``use_gqa_gate``)."""
 
     norm: str = "layernorm"
     norm_eps: float = 1e-5
@@ -509,6 +515,7 @@ class BlockSpec(NamedTuple):
     latent_by_op: Optional[tuple] = None
     residual: str = "sequential"
     kda: Optional[tuple] = None
+    attn_gate: bool = False
 
     def latent_of(self, i):
         """Layer ``i``'s ``LatentSpec``: its operator's entry of
@@ -672,7 +679,10 @@ def check_block_spec(blk, layers=None):
     one of ``RESIDUALS``: "sequential", or "parallel" (one norm a layer,
     operator and FFN side by side) where every layer is a K/V attention
     ("attention" or "window_attention") beside a SwiGLU or a routed FFN;
-    a parallel block over a latent or a state operator is not run.
+    a parallel block over a latent or a state operator is not run.  A
+    "kda" layer stands beside plain "attention" layers alone (at least
+    one), and an output gate a column (``attn_gate``) goes with the
+    sequential grouped-query block without multipliers.
     What each
     operator needs is ``_OPERATOR_NEEDS``' entry; operators that keep
     slot state are of ONE kind a spec (``STATE_KINDS``: the manager
@@ -723,7 +733,7 @@ def check_block_spec(blk, layers=None):
             and blk.residual == "sequential" and blk.positions == "rope" \
             and blk.latent is not None and blk.ffns is None \
             and blk.ssm is None and blk.mup is None and not blk.head_dim \
-            and blk.retention is None and needs \
+            and blk.retention is None and needs and not blk.attn_gate \
             and all(inv != "none" for _, inv, _ in blk.rope_by_op or ()) \
             and (bool(ops) or (
                 not blk.window and blk.rope_by_op is None
@@ -740,13 +750,14 @@ def check_block_spec(blk, layers=None):
             and all(blk.op_kind(i) != "none" or blk.ffn_kind(i) != "none"
                     for i in range(n)) \
             and (blk.positions == "rope" or blk.rope_by_op is None) \
+            and (not blk.attn_gate or (
+                blk.residual == "sequential" and blk.mup is None)) \
             and (blk.residual == "sequential" or (
                 blk.residual == "parallel"
                 and set(ops) <= {"attention", "window_attention"}
                 and kinds <= {"swiglu", "routed"}))
     if not ok:
-        gqa_ops = [o for o in OPERATORS
-                   if o not in LATENT_OPERATORS + LATENT_STATE_OPERATORS]
+        gqa_ops = [o for o in OPERATORS if o not in LATENT_OPERATORS]
         states = sorted(set(STATE_KINDS.values()))
         raise ValueError(
             f"the mixed wave runs GPT-2's block; the latent block with "
@@ -815,9 +826,11 @@ _OPERATOR_NEEDS = {
         and ("retention" not in ops or _retention_fits(blk, ops)),
     "kda": lambda blk, ops:
         ("kda" in ops) == (blk.kda is not None)
-        and ("kda" not in ops or (
-            blk.kda.fits() and blk.attention == "latent"
-            and bool(set(ops) & set(LATENT_OPERATORS)))),
+        and ("kda" not in ops or (blk.kda.fits() and (
+            blk.attention == "latent"
+            and bool(set(ops) & set(LATENT_OPERATORS))
+            or blk.attention == "gqa"
+            and "attention" in ops and set(ops) <= {"attention", "kda"}))),
 }
 
 
@@ -1989,7 +2002,10 @@ def _mixed_step(params, cfg_tuple, cache_k, cache_v, pos, tokens,
     ``kda_conv``, ``kda_scan``, ``state_write``, ``kda_out``) over
     ``state``, the manager's set BESIDE the latent pool: it writes and
     reads no page, and the latent layers find their place in the pool by
-    ``blk.op_index`` as ever.
+    ``blk.op_index`` as ever.  In the grouped-query block a "kda" layer
+    is the same operator beside "attention" layers, whose pages are the
+    K/V pool's; a spec with ``attn_gate`` multiplies the attention's
+    output by a sigmoid gate a column under the scope ``gqa_gate``.
 
     ONE outer scope names the wave's PROGRAM in the device trace, by the
     static facts the body branches on: ``wave_chunk`` (``has_fresh``),
@@ -2266,6 +2282,11 @@ def _mixed_wave(params, cfg_tuple, cache_k, cache_v, pos, tokens, q_len,
             win_k, win_v = ck, cv
         else:
             cache_k, cache_v = ck, cv
+        if blk.attn_gate:
+            with jax.named_scope("gqa_gate"):
+                gate = jax.nn.sigmoid((x @ params[f"{us}_attn_gate_weight"]
+                                       ).astype(jnp.float32))
+                o = (o.astype(jnp.float32) * gate).astype(h.dtype)
         with jax.named_scope("attn_out"):
             o = _proj(params, f"{us}_attn_proj", o, blk.bias)
             if mup is not None:

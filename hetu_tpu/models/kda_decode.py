@@ -25,6 +25,22 @@ columns (key and value alike), a head at a time:
   out     y = RMSNorm_D(o_t) * scale_h (a scale a head's column) times
           sigmoid(u W_g)_h (one gate a head); concat(y) W_o
 
+That is the SAFE gate's layer, ``KDASpec``'s defaults.  The layer as
+published (arXiv:2510.26692; the ``solar_open2`` family's, three to every
+grouped-query layer) is the same recurrence under other values of the
+spec, no other code path but where said:
+
+  decay   "softplus": g = -exp(A_log_h) softplus(u W_f + dt_bias), which
+          has NO lower bound (one step at the family's constants reaches
+          -16 and below), so the chunked form takes its EXACT pairing
+          (below); ``rank`` r > 0: ``W_f`` and ``W_g`` are low-rank, ``d
+          x r`` then ``r x H D``, the gate's second with a bias
+  beta    ``beta_scale`` 2: beta in (0, 2), so that ``I - beta k k^T``
+          has the eigenvalue ``1 - beta`` down to -1 (arXiv:2411.12537)
+          and the state's part along ``k`` changes SIGN; the step, the
+          solve and the update are the same expressions
+  out     ``gate_by`` "channel": sigmoid(u W_g + b_g) one a COLUMN
+
 What a sequence carries from one q-block to its next, a slot a layer:
 the conv's last ``K - 1`` inputs ``[K - 1, 3 H D]`` in the pool's dtype
 (the three tails side by side) and ``S`` ``[H, D, D]`` in FLOAT32 (a
@@ -54,6 +70,18 @@ inside the row's own: that is what the bound is for), masked before the
 exponential elsewhere.  A wave's few wide slots are taken ``WIDE_LANES``
 at a time.  A dead row and a dead slot have ``g`` 0, ``beta`` 0 and ``k``
 0: decay 1, correction 0, the state stays where it was, bit for bit.  The
+With a decay that has no bound (``KDASpec.unbounded``) NO reference
+inside a row's own sub-block is safe (sixteen rows of -17.5 are e^280), and
+the pairs are formed level by level instead (``_levels``): at the level of
+half-blocks of ``s`` rows (1, 2, 4, .. up to half the chunk), a row ``i`` in
+the SECOND half of its block of ``2 s`` and a column ``j`` in the FIRST
+take the last row ``m`` of that first half as their reference, ``exp(G_i -
+G_m) exp(G_m - G_j)``: both exponents are sums of ``g`` over the rows
+between, so BOTH are at most 0 whatever ``g`` is, every pair ``j < i``
+meets at exactly one level, and a level is one product on the matrix unit
+as the sub-blocks' were (six levels a chunk of 64 in place of four
+sub-block rows; a factor that underflows belongs to a pair under e^-87).
+The
 matrix products take their operands in the activations' dtype and
 accumulate in float32, but for the two that READ the carried state (``W
 S`` and ``(q e^G) S``: float32 operands at precision highest, as the
@@ -85,6 +113,7 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 
 # the chunked form: ``CHUNK`` rows a state update, in sub-blocks of
@@ -92,6 +121,9 @@ import jax.numpy as jnp
 # exponent: ``KDASpec.fits``)
 CHUNK = 64
 SUB = 16
+# the decays and the output gate's granularities ``KDASpec`` names
+DECAYS = ("safe", "softplus")
+GATES_BY = ("head", "channel")
 
 
 class KDASpec(NamedTuple):
@@ -99,13 +131,28 @@ class KDASpec(NamedTuple):
     a layer a slot, ``conv_kernel`` taps (``short_conv_kernel_size``),
     ``lower_bound`` the safe gate's (``kda_lower_bound``),
     ``state_dtype`` the dtype ``S`` is KEPT in ("float32"; "bfloat16" is
-    the control the comparison has to refuse)."""
+    the control the comparison has to refuse).  ``decay`` "safe" (the
+    bounded gate above) | "softplus" (the published one, unbounded:
+    ``lower_bound`` is then not read); ``rank`` the low rank of the decay's
+    and the output gate's projections (0: full rank, no gate bias);
+    ``gate_by`` "head" | "channel"; ``beta_scale`` 1 or 2 (beta in (0,
+    2): negative eigenvalues)."""
 
     heads: int
     head_dim: int
     conv_kernel: int = 4
     lower_bound: float = -5.0
     state_dtype: str = "float32"
+    decay: str = "safe"
+    rank: int = 0
+    gate_by: str = "head"
+    beta_scale: float = 1.0
+
+    @property
+    def unbounded(self):
+        """Whether ``g`` has no lower bound: the chunked form then pairs
+        its rows level by level (every exponent at most 0)."""
+        return self.decay != "safe"
 
     @property
     def width(self):
@@ -113,10 +160,15 @@ class KDASpec(NamedTuple):
         return self.heads * self.head_dim
 
     def fits(self):
-        """Whether the chunked form's exponents stay inside float32."""
+        """Whether the mixer runs these values: sizes, a decay of
+        ``DECAYS`` (the safe gate's bound keeping the chunked form's
+        sub-block exponents inside float32; the unbounded one needs no
+        bound), a gate of ``GATES_BY``, beta's scale in (0, 2]."""
         return self.heads >= 1 and self.head_dim >= 1 \
-            and self.conv_kernel >= 2 \
-            and 0 < -self.lower_bound * SUB <= 85.0
+            and self.conv_kernel >= 2 and self.rank >= 0 \
+            and self.decay in DECAYS and self.gate_by in GATES_BY \
+            and 0 < self.beta_scale <= 2 \
+            and (self.unbounded or 0 < -self.lower_bound * SUB <= 85.0)
 
     def state_shapes(self, layers):
         """The manager's set of slot states for ``layers`` such layers:
@@ -153,11 +205,90 @@ def kda_step(q, k, v, g, beta, S):
                       preferred_element_type=f32), S
 
 
-def kda_chunked(q, k, v, g, beta, S):
+def _levels(c):
+    """The level-by-level pairing of a chunk of ``c`` rows, as 0/1
+    matrices over (row, row): for the level of half-blocks of ``s`` =
+    ``2^l`` rows, ``rows[l, i, t]`` marks the rows ``t`` whose ``g`` sums
+    to ``G_i - G_m`` for a row ``i`` in the second half of its block of
+    ``2 s`` (``m`` the last row of the first half), ``cols[l, j, t]``
+    those that sum to ``G_m - G_j`` for a column ``j`` in the first half,
+    and ``pairs[l, i, j]`` the pairs the level owns.  Every ``j < i`` is
+    in exactly one level's ``pairs``."""
+    r, t = np.arange(c)[:, None], np.arange(c)[None, :]
+    rows, cols, pairs = [], [], []
+    s = 1
+    while s < c:
+        second = (r // s) % 2 == 1
+        rows.append(second & (t >= r // s * s) & (t <= r))
+        cols.append(~second & (t > r) & (t < (r // s + 1) * s))
+        pairs.append(second & ((t // s) % 2 == 0)
+                     & (r // (2 * s) == t // (2 * s)))
+        s *= 2
+    return np.stack(rows), np.stack(cols), np.stack(pairs)
+
+
+def _scores_by_level(qz, kz, gz, bz, cd):
+    """(A, P) [.., c, c] float32 of one chunk, ``A`` strictly lower and
+    times its row's beta ``bz`` [.., c], ``P`` lower with its diagonal,
+    from ``qz`` / ``kz`` / ``gz`` [.., c, D] float32 by ``_levels``'
+    pairing: every exponent a sum of ``g``, at most 0; the operands
+    rounded to ``cd`` as the sub-blocks' are."""
+    f32 = jnp.float32
+    hi = jax.lax.Precision.HIGHEST
+    c = gz.shape[-2]
+    rows, cols, pairs = _levels(c)
+    er = jnp.exp(jnp.einsum("lrt,...td->...lrd", rows.astype(np.float32),
+                            gz, precision=hi))              # <= 1
+    ec = jnp.exp(jnp.einsum("lrt,...td->...lrd", cols.astype(np.float32),
+                            gz, precision=hi))              # <= 1
+    kk = (kz[..., None, :, :] * ec).astype(cd)
+    A, P = (jnp.where(pairs, jnp.einsum(
+        "...lid,...ljd->...lij", (x[..., None, :, :] * er).astype(cd), kk,
+        preferred_element_type=f32), 0.0).sum(-3) for x in (kz, qz))
+    # a row's own column decays by nothing
+    return A * bz[..., None], P + jnp.where(
+        np.eye(c, dtype=bool), jnp.einsum(
+            "...id,...jd->...ij", qz.astype(cd), kz.astype(cd),
+            preferred_element_type=f32), 0.0)
+
+
+def _scores_by_sub_block(qz, kz, G, bz, cd):
+    """(A, P) as ``_scores_by_level`` gives them, from the running sums
+    ``G`` [n, B, H, c, D] of a BOUNDED ``g``: row ``i`` by its decay since
+    its sub-block of ``SUB`` began, column ``j`` by its decay up to that
+    point (at most ``e^(-bound SUB)`` inside the row's own sub-block)."""
+    f32 = jnp.float32
+    lead, (c, D) = G.shape[:-2], G.shape[-2:]
+    m = c // SUB
+    Gb = G.reshape(lead + (m, SUB, D))
+    # a sub-block's reference: the running sum where it begins
+    R = jnp.concatenate([jnp.zeros(lead + (1, D), f32), Gb[..., :-1, -1, :]],
+                        axis=-2)                            # [.., m, D]
+    up = jnp.exp(Gb - R[..., None, :])                      # <= 1
+    # column j seen from sub-block I: exp(R_I - G_j) for j's sub-block
+    # J <= I (masked BEFORE the exponential: a later one's is e^320)
+    ok = (jnp.arange(m)[:, None] >= jnp.arange(m)[None, :])[:, :, None, None]
+    down = jnp.exp(jnp.where(
+        ok, R[..., :, None, None, :] - Gb[..., None, :, :, :], -jnp.inf))
+    kk = (kz.reshape(lead + (1, m, SUB, D)) * down).astype(cd).reshape(
+        lead + (m, c, D))
+    kb, qb = kz.reshape(lead + (m, SUB, D)), qz.reshape(lead + (m, SUB, D))
+    A = jnp.einsum("nbhIid,nbhIjd->nbhIij", (kb * up).astype(cd), kk,
+                   preferred_element_type=f32).reshape(lead + (c, c))
+    P = jnp.einsum("nbhIid,nbhIjd->nbhIij", (qb * up).astype(cd), kk,
+                   preferred_element_type=f32).reshape(lead + (c, c))
+    ii, jj = jnp.arange(c)[:, None], jnp.arange(c)[None, :]
+    return jnp.where(jj < ii, A * bz[..., None], 0.0), \
+        jnp.where(jj <= ii, P, 0.0)
+
+
+def kda_chunked(q, k, v, g, beta, S, exact=False):
     """The chunked form over every lane's q-block: ``q`` / ``k`` / ``v``
     [B, Q, H, D], ``g`` [B, Q, H, D] float32 (0 on dead rows), ``beta``
     [B, Q, H] float32 (0 with ``k`` 0 on dead rows), ``S`` [B, H, D, D]
     float32 (the lane's carry).  Equal to ``kda_step`` row after row.
+    ``exact``: the pairs inside a chunk level by level (``_levels``),
+    for a ``g`` with no lower bound (``KDASpec.unbounded``).
     Returns (o [B, Q, H, D] float32, S after the q-block)."""
     B_, Q, H, D = q.shape
     f32 = jnp.float32
@@ -170,7 +301,7 @@ def kda_chunked(q, k, v, g, beta, S):
         q, k, v, g, beta = (jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),)
                                     * (a.ndim - 2))
                             for a in (q, k, v, g, beta))
-    n, m = (Q + pad) // c, c // sub
+    n = (Q + pad) // c
 
     def cut(a):
         """[B, Q, H, ...] as chunks [n, B, H, c, ...]."""
@@ -180,27 +311,8 @@ def kda_chunked(q, k, v, g, beta, S):
     qz, kz, vz = cut(q).astype(f32), cut(k).astype(f32), cut(v).astype(f32)
     bz = cut(beta)                                          # [n, B, H, c]
     G = jnp.cumsum(cut(g), axis=-2)                         # inclusive
-    lead = (n, B_, H)
-    Gb = G.reshape(lead + (m, sub, D))
-    # a sub-block's reference: the running sum where it begins
-    R = jnp.concatenate([jnp.zeros(lead + (1, D), f32), Gb[..., :-1, -1, :]],
-                        axis=-2)                            # [.., m, D]
-    up = jnp.exp(Gb - R[..., None, :])                      # <= 1
-    # column j seen from sub-block I: exp(R_I - G_j) for j's sub-block
-    # J <= I (masked BEFORE the exponential: a later one's is e^320)
-    ok = (jnp.arange(m)[:, None] >= jnp.arange(m)[None, :])[:, :, None, None]
-    down = jnp.exp(jnp.where(
-        ok, R[..., :, None, None, :] - Gb[..., None, :, :, :], -jnp.inf))
-    kk = (kz.reshape(lead + (1, m, sub, D)) * down).astype(cd).reshape(
-        lead + (m, c, D))
-    kb, qb = kz.reshape(lead + (m, sub, D)), qz.reshape(lead + (m, sub, D))
-    A = jnp.einsum("nbhIid,nbhIjd->nbhIij", (kb * up).astype(cd), kk,
-                   preferred_element_type=f32).reshape(lead + (c, c))
-    P = jnp.einsum("nbhIid,nbhIjd->nbhIij", (qb * up).astype(cd), kk,
-                   preferred_element_type=f32).reshape(lead + (c, c))
-    ii, jj = jnp.arange(c)[:, None], jnp.arange(c)[None, :]
-    A = jnp.where(jj < ii, A * bz[..., None], 0.0)
-    P = jnp.where(jj <= ii, P, 0.0)
+    A, P = (_scores_by_level(qz, kz, cut(g), bz, cd) if exact
+            else _scores_by_sub_block(qz, kz, G, bz, cd))
     # (I + A) [W | U] = [beta k exp(G) | beta v]: forward substitution
     eG = jnp.exp(G)
     rhs = jnp.concatenate([kz * eG, vz], axis=-1) * bz[..., None]
@@ -327,7 +439,7 @@ def kda_mixer(sp, q, k, v, g, beta, state, si, q_len, rows=None):
                     yc, mats = kda_chunk_scan(
                         slot, ql, *(a.reshape(R, H * D)[got]
                                     for a in (q, k, v, g)), b_f[got], mats,
-                        chunk=CHUNK, sub=SUB)
+                        chunk=CHUNK, sub=SUB, exact=sp.unbounded)
                 else:
                     kc = jnp.where(live[..., None, None], k_f[got], 0)
                     gc = jnp.where(live[..., None, None], g_f[got], 0.0)
@@ -338,7 +450,8 @@ def kda_mixer(sp, q, k, v, g, beta, state, si, q_len, rows=None):
                     S0 = jnp.concatenate([jax.lax.dynamic_slice(
                         mats, (0, slot[j], 0, 0, 0), (1, 1, H, D, D))[0]
                         for j in range(lanes)]).astype(f32)
-                    yc, Sc = kda_chunked(q_f[got], kc, v_f[got], gc, bc, S0)
+                    yc, Sc = kda_chunked(q_f[got], kc, v_f[got], gc, bc, S0,
+                                         exact=sp.unbounded)
                     # every read of the lanes' old states ends here,
                     # before the writes below overwrite them in place
                     yc, Sc = jax.lax.optimization_barrier((yc, Sc))
@@ -364,6 +477,14 @@ def kda_mixer(sp, q, k, v, g, beta, state, si, q_len, rows=None):
     return y, state[:n_state + si] + (mats,) + state[n_state + si + 1:]
 
 
+def _low_rank(params, prefix, u, rank):
+    """``u W``: ``{prefix}_weight``, or with ``rank`` the two steps
+    ``{prefix}_a_weight`` [d, r] then ``{prefix}_b_weight`` [r, ..]."""
+    if not rank:
+        return u @ params[f"{prefix}_weight"]
+    return (u @ params[f"{prefix}_a_weight"]) @ params[f"{prefix}_b_weight"]
+
+
 def kda_operator(params, us, blk, h, state, si, q_len, rows=None):
     """One layer's KDA over the wave's rows ``h`` ([B, Q, d], or a packed
     wave's [1, R, d] with ``rows``): the front end under ``kda_qkvg`` and
@@ -381,12 +502,17 @@ def kda_operator(params, us, blk, h, state, si, q_len, rows=None):
     with jax.named_scope("kda_qkvg"):
         u = _norm(blk, params, f"{us}_ln1", h)
         qkv = u @ params[f"{us}_kda_qkv_weight"]            # [.., 3 H D]
-        f = (u @ params[f"{us}_kda_f_weight"]).astype(f32) \
+        f = _low_rank(params, f"{us}_kda_f", u, sp.rank).astype(f32) \
             + params[f"{us}_kda_dt_bias"].astype(f32)
         a = jnp.repeat(jnp.exp(params[f"{us}_kda_A_log"].astype(f32)), D)
-        g = sp.lower_bound * jax.nn.sigmoid(a * f)          # [.., H D] <= 0
+        if sp.unbounded:
+            g = -a * jax.nn.softplus(f)                     # [.., H D] <= 0
+        else:
+            g = sp.lower_bound * jax.nn.sigmoid(a * f)      # [.., H D] <= 0
         beta = jax.nn.sigmoid(
             (u @ params[f"{us}_kda_beta_weight"]).astype(f32))   # [.., H]
+        if sp.beta_scale != 1.0:
+            beta = sp.beta_scale * beta
     qkv, last = _causal_conv(qkv, tails[0], params[f"{us}_kda_conv_weight"],
                              q_len, rows, mix="kda_conv", write="kda_conv")
     with jax.named_scope("kda_conv"):
@@ -401,8 +527,13 @@ def kda_operator(params, us, blk, h, state, si, q_len, rows=None):
         y = y.reshape(Br, Qr, H, D)
         y = y * jax.lax.rsqrt((y * y).mean(-1, keepdims=True) + blk.norm_eps) \
             * params[f"{us}_kda_norm_scale"].astype(f32).reshape(H, D)
-        gate = jax.nn.sigmoid(
-            (u @ params[f"{us}_kda_gate_weight"]).astype(f32))
-        y = (y * gate[..., None]).astype(h.dtype).reshape(Br, Qr, H * D)
+        z = _low_rank(params, f"{us}_kda_gate", u, sp.rank).astype(f32)
+        if sp.rank:
+            z = z + params[f"{us}_kda_gate_bias"].astype(f32)
+        gate = jax.nn.sigmoid(z)
+        # one gate a head, or one a column of every head
+        gate = gate.reshape(Br, Qr, H, D) if sp.gate_by == "channel" \
+            else gate[..., None]
+        y = (y * gate).astype(h.dtype).reshape(Br, Qr, H * D)
         h = h + y @ params[f"{us}_kda_out_weight"]
     return h, state

@@ -302,7 +302,8 @@ F32_LEAVES = ("_kda_dt_bias", "_kda_A_log", "_moe_router_weight",
 
 def init_kda_latent_params(config, name="lng", seed=0, gains=None,
                            dtype=jnp.float32, a_range=(1.0, 16.0),
-                           dt_range=(0.001, 0.1)):
+                           dt_range=(0.001, 0.1), defaults=None,
+                           gain_of=None):
     """Seeded random serving params for a ``KDALatentConfig``, made on
     the device in one jitted call.  Every weight matrix is ``normal(gain
     / sqrt(fan_in))`` (``DEFAULT_GAINS``; ``gains`` overrides entries;
@@ -314,8 +315,13 @@ def init_kda_latent_params(config, name="lng", seed=0, gains=None,
     log-uniform in ``dt_range`` a channel (a zero decay input then gives
     ``g`` from about -0.05 a step, a memory of some twenty tokens, up to
     nothing at all).  The router's weight and bias and the decay's
-    constants are float32 whatever ``dtype`` is."""
-    g = dict(DEFAULT_GAINS, **(gains or {}))
+    constants are float32 whatever ``dtype`` is.  ``defaults`` /
+    ``gain_of``: another configuration class's gains and leaf table
+    (``kda_gqa``'s, whose low-rank output gate has a bias,
+    ``normal(kda_gate_bias)``)."""
+    g = dict(DEFAULT_GAINS if defaults is None else defaults,
+             **(gains or {}))
+    gain_of = _GAIN_OF if gain_of is None else gain_of
     shapes = config.param_shapes(name)
 
     def make(key):
@@ -335,11 +341,14 @@ def init_kda_latent_params(config, name="lng", seed=0, gains=None,
             elif n.endswith("_moe_router_bias"):
                 out[n] = g["router_bias"] * jax.random.normal(
                     k, shape, jnp.float32)
+            elif n.endswith("_kda_gate_bias"):
+                out[n] = (g["kda_gate_bias"] * jax.random.normal(
+                    k, shape, jnp.float32)).astype(dtype)
             elif n.endswith("_wte_table"):
                 out[n] = (g["embedding"] * jax.random.normal(
                     k, shape, jnp.float32)).astype(dtype)
             else:
-                gain = g[next(v for s, v in _GAIN_OF.items()
+                gain = g[next(v for s, v in gain_of.items()
                               if n.endswith(s))]
                 out[n] = (gain / math.sqrt(shape[-2]) * jax.random.normal(
                     k, shape, jnp.float32)).astype(

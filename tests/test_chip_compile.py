@@ -1487,3 +1487,122 @@ def test_kda_latent_wave_programs_at_the_published_widths(sds, monkeypatch,
     if not group:
         stated = conf["memory_analysis"][f"slots_{B}_Q_{q_len}"]
         assert abs(peak / 1e9 - stated["peak_GB"]) < 0.3
+
+
+# ------------------------------------------------------------------- #
+# ISSUE 62: the published delta rule beside gated grouped-query
+# attention at the longdoc cell's sizes
+# ------------------------------------------------------------------- #
+
+def test_kda_chunk_scan_level_by_level_at_the_cells_sizes(sds):
+    """Three lanes of 256 rows x 64 heads of 128 over the state of 48
+    slots with ``exact`` (a decay free of any bound: the pairs level by
+    level, the levels' 0/1 matrices from iotas and shifts): the kernel
+    compiles for the chip, the state aliased, no temporary."""
+    from hetu_tpu.kernels import kda_scan as ks
+    from hetu_tpu.models import kda_decode as kd
+    lanes, Q, H, D, slots = kd.WIDE_LANES, 256, 64, 128, 48
+    bf, f32 = jnp.bfloat16, jnp.float32
+    rows = sds((lanes, Q, H * D), bf)
+    args = (sds((lanes,), jnp.int32), sds((lanes,), jnp.int32), rows, rows,
+            rows, sds((lanes, Q, H * D), f32), sds((lanes, Q, H), f32),
+            sds((1, slots, H, D, D), f32))
+    compiled = jax.jit(
+        lambda *a: ks._kda_chunk_scan_call(
+            *a, chunk=kd.CHUNK, sub=kd.SUB, interpret=False, exact=True),
+        donate_argnums=(7,)).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= slots * H * D * D * 4
+    assert mem.temp_size_in_bytes == 0
+
+
+@pytest.mark.parametrize("q_len,cut", [
+    (256, True),
+    pytest.param(1, True, marks=pytest.mark.slow),
+    pytest.param(256, False, marks=pytest.mark.slow),
+    pytest.param(1, False, marks=pytest.mark.slow)],
+    ids=["Q256-one_of_each", "Q1-one_of_each", "Q256-period", "Q1-period"])
+def test_kda_gqa_wave_programs_at_the_published_widths(sds, monkeypatch,
+                                                       q_len, cut):
+    """The longdoc cell's two programs (its prompts are multiples of the
+    chunk: ONE chunk program, Q 256, beside the decode program) at the
+    published widths, the cell's 48 slots (exact) and its K/V pool of
+    75,265 blocks through ``serve_mixed_paged_fn``: the GQA layer and
+    one KDA layer under a vocabulary of 512, or ``slow`` the whole
+    period of four and the head over 24,576 columns.  The chunk program
+    runs the chunked delta rule through ``kda_chunk_scan`` (ONE lowering,
+    a call a KDA layer, ``exact``); the decode program holds none;
+    ``ragged_paged_mixed`` once at 64 over 8; the 40 held experts of
+    EVERY layer through ``moe_grouped_matmul``; the pool pair and the
+    six (or two) states updated in place.  The configuration's
+    ``memory_analysis`` states the period's compiles."""
+    import json
+    import os
+    from hetu_tpu.kernels import grouped_matmul as gm
+    from hetu_tpu.kernels import kda_scan as ks
+    from hetu_tpu.kernels import paged_kv_write as pw
+    from hetu_tpu.kernels import ragged_attention as ra
+    from hetu_tpu.models.kda_gqa import KDAGQAConfig
+    from hetu_tpu.models.kda_latent import F32_LEAVES
+    for module in (ra, gm, pw, ks):
+        monkeypatch.setattr(module, "_use_interpret", lambda: False)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "configs",
+                           "solar-open2-250b.json")) as f:
+        conf = json.load(f)
+    args, dep, pub = (conf["runner_args"], conf["deployment"],
+                      conf["published"])
+    vocab, vocab_rows = pub["vocab_size"], tuple(dep["vocab_rows_held"])
+    over = {}
+    if cut:
+        over = dict(num_hidden_layers=2)
+        vocab, vocab_rows = 512, None
+    cfg = KDAGQAConfig.from_hf(
+        dict(conf, n_routed_experts=pub["n_routed_experts"],
+             vocab_size=vocab, **over),
+        held_experts=tuple(dep["experts_held"]), vocab_rows=vocab_rows)
+    blk = cfg.block_spec()
+    L, S = cfg.num_hidden_layers, args["max_seq_len"]
+    B, T, N = args["slots"], S // BLOCK, args["pool_blocks"]
+    kda = blk.op_layers(L, "kda")
+    assert (B, blk.op_layers(L, "pool"), kda) == (48, 1, L - 1)
+    assert blk.kda.unbounded and blk.attn_gate
+    params = {k: sds(s, jnp.float32 if k.endswith(F32_LEAVES)
+                     else jnp.bfloat16)
+              for k, s in cfg.param_shapes("slr").items()}
+    W = kv_row_width(8, 128)
+    pool = sds((1, N, BLOCK, W), jnp.bfloat16)
+    state = tuple(sds((shape[0], B) + shape[1:], dtype or jnp.bfloat16)
+                  for shape, dtype in blk.state_shapes(L, cfg.hidden_size))
+    assert state[-1].shape == (1, B, 64, 128, 128) \
+        and state[-1].dtype == jnp.float32
+    i32 = lambda *shape: sds(shape, jnp.int32)  # noqa: E731
+    fn = gd.serve_mixed_paged_fn(donate=True, attn="ragged", window=1)
+    lowered = fn.func.lower(
+        params, ("slr", L, 64, 128, S, blk), pool, pool, i32(B, T), i32(B),
+        i32(B, q_len), i32(B), i32(B), sds((B,), jnp.bool_),
+        sds((B,), jnp.float32), i32(B), sds((B, 2), jnp.uint32),
+        attn="ragged", window=1, has_fresh=q_len > 1, state=state)
+    assert lowered.as_text().count(
+        "func.func private @_kda_chunk_scan_call") == (1 if q_len > 1 else 0)
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines() if "custom-call(" in line]
+    scans = [c for c in calls if "kda_chunk_scan" in c]
+    assert len(scans) == (kda if q_len > 1 else 0)
+    assert all("tpu_custom_call" in c for c in scans)
+    assert sum("ragged_paged_mixed" in c for c in calls) == 1
+    assert sum("moe_grouped_matmul" in c for c in calls) == 2 * L
+    assert "ragged-dot" not in text
+    mem = compiled.memory_analysis()
+    held = 2 * N * BLOCK * W * 2 + sum(
+        int(np.prod(s.shape)) * s.dtype.itemsize for s in state)
+    assert mem.alias_size_in_bytes >= held
+    peak = mem.argument_size_in_bytes + mem.output_size_in_bytes \
+        - mem.alias_size_in_bytes + mem.temp_size_in_bytes
+    assert mem.temp_size_in_bytes < 1.5e9
+    assert peak < 14.5e9
+    if not cut:
+        stated = conf["memory_analysis"][f"slots_{B}_Q_{q_len}"]
+        assert abs(peak / 1e9 - stated["peak_GB"]) < 0.3

@@ -25,8 +25,11 @@ TOL = 2e-4
 def draw(Q, lanes=3, seed=0, decay="mixed"):
     """(q, k, v, g, beta, mats) of ``lanes`` q-blocks ``Q`` wide: ``g``
     at the bound on every channel of whole sub-blocks ("bound": the
-    exponents' worst case, e^80 inside a sub-block), near 0 ("near0") or
-    at the bound on half the channels and near 0 on the others."""
+    exponents' worst case, e^80 inside a sub-block), near 0 ("near0"),
+    at the bound on half the channels and near 0 on the others, or FREE
+    of any bound ("free", ISSUE 62: -30 a step on every channel of whole
+    sub-blocks, e^480 where a sub-block's own reference would put it,
+    with beta up to 2)."""
     rng = np.random.default_rng(seed)
     q, k, v = (rng.normal(size=(lanes, Q, H, D)).astype(np.float32)
                for _ in range(3))
@@ -35,11 +38,15 @@ def draw(Q, lanes=3, seed=0, decay="mixed"):
     if decay == "bound":
         whole = rng.uniform(size=(lanes, -(-Q // kd.SUB), H, 1)) < 0.5
         g = np.where(np.repeat(whole, kd.SUB, axis=1)[:, :Q], -5.0, g * 0.02)
+    elif decay == "free":
+        whole = rng.uniform(size=(lanes, -(-Q // kd.SUB), H, 1)) < 0.5
+        g = np.where(np.repeat(whole, kd.SUB, axis=1)[:, :Q], -30.0, g)
     elif decay == "near0":
         g = g * 0.002
     else:
         g = np.where(rng.uniform(size=g.shape) < 0.5, -5.0, g * 0.02)
-    beta = rng.uniform(0.1, 1.0, size=(lanes, Q, H)).astype(np.float32)
+    beta = rng.uniform(0.1, 2.0 if decay == "free" else 1.0,
+                       size=(lanes, Q, H)).astype(np.float32)
     mats = rng.normal(size=(1, SLOTS, H, D, D)).astype(np.float32)
     return tuple(jnp.asarray(a, jnp.float32)
                  for a in (q, k, v, g, beta, mats))
@@ -75,22 +82,26 @@ def bits(a):
     return np.asarray(a).view(np.uint32)
 
 
-@pytest.mark.parametrize("decay", ["mixed", "bound", "near0"])
+@pytest.mark.parametrize("decay", ["mixed", "bound", "near0", "free"])
 @pytest.mark.parametrize("Q", [64, 128, 256])
 def test_the_kernel_is_the_chunked_form_and_the_recurrence(Q, decay):
     """Three lanes on slots 3, 0 and 4 with ``Q``, 80 (or ``Q``) and 1
     live rows, from a nonzero carried state: one chunk, two (the second
     reads the state the first left) and four, a lane that ends inside a
-    chunk, a lane of one row."""
+    chunk, a lane of one row.  A decay free of any bound goes through
+    the pairing level by level (``exact``), in the kernel and in the XLA
+    form alike."""
+    exact = {"exact": True} if decay == "free" else {}
     q, k, v, g, beta, mats = draw(Q, seed=Q, decay=decay)
     slot = jnp.array([3, 0, 4])
     q_len = np.array([Q, min(80, Q), 1])
     kc, gc, bc = dead(q_len, Q, k, g, beta)
     S0 = mats[0][slot]
-    want_y, want_S = jax.jit(kd.kda_chunked)(q, kc, v, gc, bc, S0)
+    want_y, want_S = jax.jit(lambda *a: kd.kda_chunked(*a, **exact))(
+        q, kc, v, gc, bc, S0)
     step_y, step_S = stepwise(q, kc, v, gc, bc, S0)
     y, mats2 = kda_chunk_scan(slot, jnp.asarray(q_len), q, k, v, g, beta,
-                              mats, chunk=kd.CHUNK, sub=kd.SUB)
+                              mats, chunk=kd.CHUNK, sub=kd.SUB, **exact)
     y = np.asarray(y).reshape(3, Q, H, D)
     assert np.isfinite(y).all()
     for b, n in enumerate(q_len):

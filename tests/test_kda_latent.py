@@ -186,9 +186,11 @@ def spec_with(**over):
 
 
 @pytest.mark.parametrize("blk", [
-    # a delta-rule layer in the grouped-query block
+    # a delta-rule layer of the grouped-query block beside anything but
+    # plain attention (beside it: ``tests/test_kda_gqa.py``, ISSUE 62)
     lambda: gd.BlockSpec(norm="rmsnorm", positions="rope", attention="gqa",
-                         bias=False, kv_heads=2, ops=("kda", "attention"),
+                         bias=False, kv_heads=2, window=4,
+                         ops=("kda", "window_attention"),
                          kda=kd.KDASpec(2, 16), ffn="swiglu"),
     # ... named without its spec, and a spec without its layers
     lambda: spec_with(kda=None),

@@ -32,6 +32,7 @@ import pytest
 from test_chip_compile import (  # noqa: E402,F401
     no_compile_cache, sds, strip_kernel_locations, topo)
 from test_hybrid_moe import digest, wave_programs
+from test_kda_gqa import kda_gqa_programs
 from test_kda_latent import kda_latent_programs
 from test_nemotron_h import nemotron_programs
 from test_parallel_moe import parallel_moe_programs
@@ -127,6 +128,8 @@ FAMILIES = {
             if k.endswith("fresh1")}, True),
     "KDA_LATENT_MASKED": (lambda s: kda_latent_programs(s, "masked"), False),
     "KDA_LATENT_RAGGED": (lambda s: kda_latent_programs(s, "ragged"), True),
+    "KDA_GQA_MASKED": (lambda s: kda_gqa_programs(s, "masked"), False),
+    "KDA_GQA_RAGGED": (lambda s: kda_gqa_programs(s, "ragged"), True),
 }
 
 PARENT = {
@@ -416,6 +419,26 @@ PARENT = {
         "kda_latent.Q1.fresh1": "610eca4a3607a5ef",
         "kda_latent.Q32.fresh0": "d7d792f69ba556c3",
         "kda_latent.Q32.fresh1": "d7d792f69ba556c3"},
+    # PR 62's own, no parent's: tests/test_kda_gqa.py's small model of the
+    # ``solar_open2`` block (GQA, KDA, KDA, KDA; heads of 128, so that the
+    # Q 64 programs take ``kda_chunk_scan`` with ``exact``: the kernel's
+    # level-by-level pairing is in the text, interpreted in the masked
+    # family and as Mosaic's assembly in the other).  PR 62 changed NO
+    # accepted program: the 97 entries above stand as PR 61 left them (the
+    # new ``BlockSpec.attn_gate`` and ``KDASpec.decay`` / ``rank`` /
+    # ``gate_by`` / ``beta_scale`` trace nothing at their defaults, and
+    # ``kda_chunked``'s sub-block scoring moved into a helper of the same
+    # operations in the same order).
+    "KDA_GQA_MASKED": {
+        "kda_gqa.Q1.fresh0": "81c477bd9d182f78",
+        "kda_gqa.Q1.fresh1": "be3e10a67c8aef92",
+        "kda_gqa.Q64.fresh0": "ccbae64e8352ec7b",
+        "kda_gqa.Q64.fresh1": "16ac7062bd635530"},
+    "KDA_GQA_RAGGED": {
+        "kda_gqa.Q1.fresh0": "c412ddf6db5f419c",
+        "kda_gqa.Q1.fresh1": "c412ddf6db5f419c",
+        "kda_gqa.Q64.fresh0": "00c0715dd0c411a6",
+        "kda_gqa.Q64.fresh1": "00c0715dd0c411a6"},
 }
 
 
@@ -432,12 +455,13 @@ def digests(request):
                                 build(jax.ShapeDtypeStruct).items()}
                 return done[family]
             from hetu_tpu.kernels import grouped_matmul as gm
+            from hetu_tpu.kernels import kda_scan as ks
             from hetu_tpu.kernels import paged_kv_write as pw
             from hetu_tpu.kernels import ragged_attention as ra
             from hetu_tpu.kernels import ssm_step as ss
             on_chip = request.getfixturevalue("sds")
             with pytest.MonkeyPatch.context() as m:
-                for module in (ra, gm, ss, pw):
+                for module in (ra, gm, ss, pw, ks):
                     m.setattr(module, "_use_interpret", lambda: False)
                 texts = {k: low.as_text()
                          for k, low in build(on_chip).items()}
