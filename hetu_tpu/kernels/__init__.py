@@ -3,7 +3,8 @@ and prefill), the ragged kernel that scores the serving engine's wave
 on a TPU, the page write of a wide q-block's K/V rows
 (``paged_kv_write``), the grouped matmul of a wave's routed experts, and
 the state operators' scans on the manager's state where it lies: the
-power-retention layer's chunked form (``retention_scan``), the Mamba-2
+power-retention layer's one-row step and chunked form (two kernels in
+``retention_scan``), the Mamba-2
 mixer's one-row step (``ssm_step``) and the gated delta rule's chunked
 form (``kda_scan``)."""
 

@@ -34,8 +34,8 @@ zeroed when a slot is claimed and handed through the donated step; a
 model of such layers alone holds no pool at all (``layers=0``).
 
 One program a bucket serves every kind of row, as ``ssm_decode.ssm_mixer``
-does.  A slot with ONE live row takes one step of the recurrence,
-elementwise in float32, the whole batch at once.  A slot with a wider
+does.  A slot with ONE live row takes one step of the recurrence, in
+float32.  A slot with a wider
 q-block takes the CHUNKED form over chunks of ``RetentionSpec.chunk``
 rows: inside a chunk the scores ``(q_t . k_j)^2`` directly (products
 ``d`` wide, never through ``phi``) under the gates' decay, masked before
@@ -46,24 +46,30 @@ increment 0, the state stays where it was, bit for bit.  The matrix
 products take their operands in the activations' dtype and accumulate in
 float32; the state is read, decayed, added to and stored in float32.
 
-What goes through ``phi`` in the chunked form (the carry's read, the
-normaliser's read, the state's update) has two bodies, chosen by static
-shape alone (``takes_kernel``): where the head is a whole number of lane
-tiles (the published 128) and the q-block wider than one row,
-``kernels/retention_scan`` builds ``phi(q)`` and ``phi(k)`` a stripe at
-a time in VMEM and never writes them, and reads and rewrites a lane's
-state once, where it lies in the manager's arrays
-(``retention_chunked_inplace``); anywhere else ``retention_chunked``, in
-XLA's own operations, on states sliced out and written back, which is
-also the form the kernel is held to.
+Both forms have two bodies, chosen by static shape alone
+(``takes_kernel``).  Where the head is a whole number of lane tiles (the
+published 128), ``kernels/retention_scan`` has a kernel for each, on the
+manager's arrays where they lie, the two kernels' slots disjoint and
+ordered through the arrays: ``retention_step_scan`` takes the slots
+with ONE row, compacted to the front of its grid, and reads a head's
+``S`` once, decays it, adds to it, reads it out and writes it once
+(``retention_step_inplace``); ``retention_chunk_scan`` takes what goes
+through ``phi`` in the chunked form (the carry's read, the normaliser's
+read, the state's update) of a q-block wider than one row, building
+``phi(q)`` and ``phi(k)`` a stripe at a time in VMEM and never writing
+them (``retention_chunked_inplace``).  A slot with no row is touched by
+neither.  Anywhere else ``retention_step`` over every slot at once (a
+slot that does not move decayed by 1 and added 0) and
+``retention_chunked`` on states sliced out and written back, in XLA's
+own operations: the forms the kernels are held to.
 
 Scopes: ``ret_qkvg`` (``gpt_decode``: projections, q/k norm, rotation,
 gate), ``ret_expand`` (``phi`` of q and k where XLA forms them),
 ``ret_scan`` (step and chunked forms, numerator and denominator, the
-kernel ``retention_chunk_scan`` and with it the wide slots' state
-store), ``state_write`` (the shared name: the one-step pass's store, and
-the slices' where the chunked form runs in XLA), ``ret_out``
-(``gpt_decode``: ``W_o``).
+kernels ``retention_step_scan`` and ``retention_chunk_scan`` and with
+them their slots' state stores), ``state_write`` (the shared name: the
+store of the one-step pass and the slices of the chunked form where
+they run in XLA), ``ret_out`` (``gpt_decode``: ``W_o``).
 """
 
 from __future__ import annotations
@@ -239,17 +245,43 @@ def retention_chunked(q, k, v, lg, S, z, chunk):
     return _rows_first(ys)[:, :Q], S, z
 
 
-def takes_kernel(head_dim, q_block):
+def takes_kernel(head_dim, q_block=None):
     """The shape rule: whether a program whose q-blocks are ``q_block``
     rows wide runs the wide slots' chunked form through
-    ``kernels/retention_scan`` (else through ``retention_chunked``).  A
-    head of whole lane tiles (the kernel's stripes are the head's width:
-    the published model's 128) and a q-block wider than one row.  Static
-    shapes alone decide, so a program is one or the other, and the
-    engine can ask the same question of a wave
-    (``serve.ret.kernel_slot_steps``)."""
+    ``kernels/retention_scan`` (else through ``retention_chunked``): a
+    head of whole lane tiles (the kernels' stripes are the head's width:
+    the published model's 128) and a q-block wider than one row.  Asked
+    of the head alone (no ``q_block``): whether the one-row slots' step
+    goes through the one-step kernel (else through ``retention_step``),
+    which every program of such a head has.  Static shapes alone decide,
+    so a program is one or the other, and the engine can ask the same
+    questions of a wave (``serve.ret.kernel_slot_steps``)."""
     from ..kernels._shared import _LANES
-    return head_dim % _LANES == 0 and q_block > 1
+    return head_dim % _LANES == 0 and (q_block is None or q_block > 1)
+
+
+def retention_step_inplace(q, k, v, lg, mats, norms, one):
+    """``retention_step`` for the slots ``one`` [B] marks (those with ONE
+    live row), on the manager's ``mats`` [1, slots, g, D, d] / ``norms``
+    [1, slots, g, D]: ``q`` [B, g, n/g, d], ``k`` / ``v`` [B, g, d],
+    ``lg`` [B, g] float32, a slot's row each.  The marked slots go
+    through ``kernels.retention_scan.retention_step_scan`` in slot
+    order; every other slot's state is neither read nor written.
+    Returns (y [B, g, n/g, d] float32, 0 on the slots not marked, mats,
+    norms)."""
+    from ..kernels.retention_scan import retention_step_scan
+    B = one.shape[0]
+    order = jnp.argsort(jnp.logical_not(one), stable=True)
+    n = jnp.sum(one)
+    live = jnp.arange(B) < n
+    # (the lanes past the last marked slot name it again: the kernel
+    # stays on its last block)
+    slot = jnp.where(live, order, order[jnp.maximum(n - 1, 0)])
+    num, den, mats, norms = retention_step_scan(
+        slot, n, q[slot], k[slot], v[slot], jnp.exp(lg[slot]), mats, norms)
+    y = _read_out(num, den)
+    return jnp.zeros_like(y).at[jnp.where(live, slot, B)].set(
+        y, mode="drop"), mats, norms
 
 
 def retention_chunked_inplace(q, k, v, lg, mats, norms, slot, q_len,
@@ -299,12 +331,13 @@ def retention_mixer(sp, q, k, v, lg, state, si, q_len, rows=None):
     and rewritten whole.
 
     The scan never unpacks the wave (``ssm_decode.ssm_mixer``'s
-    discipline).  Every slot with ONE live row takes ``retention_step``
-    at its row, the whole batch at once.  The slots with a wider q-block
-    are taken ``WIDE_LANES`` at a time, widest first, by a
-    ``while_loop`` that gathers their rows and states, runs
-    ``retention_chunked`` and scatters both back.  Returns (y laid out
-    as ``q`` in its dtype, state)."""
+    discipline).  Every slot with ONE live row takes one step at its
+    row: ``retention_step_inplace`` by ``takes_kernel`` of the head,
+    else ``retention_step`` over the whole batch at once.  The slots
+    with a wider q-block are taken ``WIDE_LANES`` at a time, widest
+    first, by a ``while_loop`` that gathers their rows and runs the
+    chunked form on their states.  Returns (y laid out as ``q`` in its
+    dtype, state)."""
     g, d, D = sp.kv_heads, sp.head_dim, sp.state
     n = q.shape[2]
     m = n // g
@@ -323,17 +356,26 @@ def retention_mixer(sp, q, k, v, lg, state, si, q_len, rows=None):
         k_f, v_f = k.reshape(-1, g, d), v.reshape(-1, g, d)
         lg_f = lg.reshape(-1, g)
         R = q_f.shape[0]
-        # the slots with one live row: one step of the recurrence (a
-        # slot with none, or with more, has lg 0 and k 0 here and stays)
+        # the slots with one live row: one step of the recurrence (in
+        # XLA's form a slot with none, or with more, has lg 0 and k 0
+        # here and stays)
         first = jnp.minimum(start, R - 1)
-        one = (q_len == 1)[:, None]
-        k1 = jnp.where(one[..., None], k_f[first], 0)
-        lg1 = jnp.where(one, lg_f[first], 0.0)
-        y1, S, z = retention_step(q_f[first], k1, v_f[first], lg1,
-                                  mats[0].astype(f32), norms[0].astype(f32))
+        step_kernel = takes_kernel(d)
+        if step_kernel:
+            y1, mats, norms = retention_step_inplace(
+                q_f[first], k_f[first], v_f[first], lg_f[first], mats,
+                norms, q_len == 1)
+        else:
+            one = (q_len == 1)[:, None]
+            k1 = jnp.where(one[..., None], k_f[first], 0)
+            lg1 = jnp.where(one, lg_f[first], 0.0)
+            y1, S, z = retention_step(
+                q_f[first], k1, v_f[first], lg1, mats[0].astype(f32),
+                norms[0].astype(f32))
         y1 = y1.reshape(B_, n * d)
-    with jax.named_scope("state_write"):
-        mats, norms = S.astype(kept)[None], z.astype(kept)[None]
+    if not step_kernel:
+        with jax.named_scope("state_write"):
+            mats, norms = S.astype(kept)[None], z.astype(kept)[None]
     if Q == 1:
         y = y1.reshape(Br, Qr, n * d)
     else:

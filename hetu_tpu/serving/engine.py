@@ -619,12 +619,14 @@ class ServingEngine:
             chunk_pairs = int((full * (c * (c + 1) // 2)
                                + rest * (rest + 1) // 2).sum())
             # the slots the wave's program took through a kernel, by
-            # the program's own rule: the wide slots' chunked form
-            # through ``kernels/retention_scan``, the one-row slots'
-            # step through ``kernels/ssm_step``
+            # the program's own rule: the wide slots' chunked form and
+            # the one-row slots' step through the two kernels of
+            # ``kernels/retention_scan``, the one-row slots' step
+            # through ``kernels/ssm_step``
             if kind == "ret":
-                by_kernel = (ql > 1) & retention_takes_kernel(
-                    spec.head_dim, int(wave["q"]))
+                by_kernel = ((ql > 1) & retention_takes_kernel(
+                    spec.head_dim, int(wave["q"]))) | (
+                    (ql == 1) & retention_takes_kernel(spec.head_dim))
             else:
                 by_kernel = (ql == 1) & ssm_takes_kernel(spec)
             out[kind] = self.metrics.record_state_scan(

@@ -387,8 +387,12 @@ class ServingMetrics(MetricsCore):
         slots the wave's program took through a Pallas kernel by its own
         shape rule: of a retention wave those with a q-block wider than
         one row, whose chunked form ran through
-        ``kernels/retention_scan``, ``retention_decode.takes_kernel`` of
-        its head and q-block; of a state-space wave those with ONE row,
+        ``kernels/retention_scan``'s ``retention_chunk_scan``,
+        ``retention_decode.takes_kernel`` of its head and q-block, and
+        those with ONE row, whose step ran through the same file's
+        ``retention_step_scan``, ``takes_kernel`` of its head alone: of
+        a head of whole lane tiles every live slot; of a state-space
+        wave those with ONE row,
         whose step ran through ``kernels/ssm_step``,
         ``ssm_decode.takes_kernel`` of its mixer's sizes; x layers the
         sum ``<kind>_kernel_slot_steps`` and the counter

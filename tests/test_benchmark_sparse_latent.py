@@ -98,8 +98,13 @@ def window(runner):
     # (alone on the machine: 1 of 17 held rows in four seconds, 5 of 29
     # in eight).  Two seconds failed one whole run in two (PR 53); four
     # failed both whole runs of PR 59's tree (15 and 21 rows held, none
-    # over), whose new test file only moved what runs beside this one
-    h = harness(8.0)
+    # over), whose new test file only moved what runs beside this one;
+    # eight failed PR 63's whole run the same way (12 rows held under
+    # ``norm_held``, none over, where the tree alone reads 1 of 21).
+    # Eight checked requests, not three: 59-63 rows held, of which
+    # ``norm_held`` moves 10 over and float8 5, whichever requests the
+    # clock samples
+    h = harness(8.0, check_requests=8)
     return h, runner.serve_window(h)
 
 

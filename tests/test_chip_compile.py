@@ -1022,12 +1022,38 @@ def test_retention_chunk_scan_at_the_cells_sizes(sds, kept):
     assert mem.temp_size_in_bytes == 0
 
 
+@pytest.mark.parametrize("kept", ["float32", "bfloat16"])
+def test_retention_step_scan_at_the_cells_sizes(sds, kept):
+    """The one-step kernel (ISSUE 63) over 24 lanes of 5 query heads on
+    8 K/V heads of 128, the state of 24 slots (float32, and the
+    bfloat16 control): compiles for the chip, both states aliased, no
+    temporary."""
+    from hetu_tpu.kernels import retention_scan as rs
+    slots = lanes = BRUMBY["slots"]
+    g, d = 8, 128
+    D = d * (d + 1) // 2
+    f32, kept = jnp.float32, jnp.dtype(kept)
+    row = sds((lanes, g, 8, d), f32)
+    args = (sds((lanes,), jnp.int32), sds((1,), jnp.int32),
+            sds((lanes, g, rs.STEP_ROWS, d), f32), row, row, row,
+            sds((1, slots, g, D, d), kept), sds((1, slots, g, D), kept))
+    compiled = jax.jit(
+        lambda *a: rs._step_scan_call(*a, read="bfloat16", interpret=False),
+        donate_argnums=(6, 7)).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= slots * g * D * (d + 1) * kept.itemsize
+    assert mem.temp_size_in_bytes == 0
+
+
 def test_retention_mixed_step_rewrites_the_states_where_they_lie(
         sds, monkeypatch):
     """Two layers of the documents cell at its published widths and 24
-    slots, the Q 256 program: ONE lowering of the kernel for both layers,
-    a call a layer, the four states aliased and no temporary of a
-    state's size (the un-aliased copy PR 44's first build paid for)."""
+    slots, the Q 256 program: ONE lowering of each kernel for both
+    layers, a call of each a layer (the one-step kernel, then the chunk
+    kernel in the wide slots' loop), the four states aliased and no
+    temporary of a state's size (the un-aliased copy PR 44's first build
+    paid for)."""
     from hetu_tpu.kernels import retention_scan as rs
     from hetu_tpu.models import retention_decode as rd
     monkeypatch.setattr(rs, "_use_interpret", lambda: False)
@@ -1049,11 +1075,12 @@ def test_retention_mixed_step_rewrites_the_states_where_they_lie(
         i32(B), sds((B,), jnp.bool_), sds((B,), jnp.float32), i32(B),
         sds((B, 2), jnp.uint32), attn="ragged", window=1, has_fresh=True,
         state=state)
-    assert lowered.as_text().count("tpu_custom_call") == 1
+    assert lowered.as_text().count("tpu_custom_call") == 2
     compiled = lowered.compile()
-    calls = [line for line in compiled.as_text().splitlines()
-             if "custom-call(" in line and "retention_chunk_scan" in line]
-    assert len(calls) == L and all("tpu_custom_call" in c for c in calls)
+    for kernel in ("retention_step_scan", "retention_chunk_scan"):
+        calls = [line for line in compiled.as_text().splitlines()
+                 if "custom-call(" in line and kernel in line]
+        assert len(calls) == L and all("tpu_custom_call" in c for c in calls)
     one_state = B * 8 * 8256 * 128 * 4
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= L * B * 8 * 8256 * 129 * 4

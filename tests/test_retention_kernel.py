@@ -1,7 +1,9 @@
 """``kernels/retention_scan`` (ISSUE 45): the power-retention layer's
 chunked form through ``phi`` as one Pallas kernel, interpreted on the
 CPU, against ``retention_decode.retention_chunked`` (the form in XLA's
-own operations, which heads narrower than a lane tile keep running).
+own operations, which heads narrower than a lane tile keep running);
+and (ISSUE 63) the one-step form as another, against
+``retention_decode.retention_step``.
 
 Head 128 so that the kernel is what runs (its stripes are the head's
 width: ``D`` 8,256), few rows and K/V heads so that it is quick.  Both
@@ -13,7 +15,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from hetu_tpu.kernels.retention_scan import retention_chunk_scan
+from hetu_tpu.kernels.retention_scan import (
+    retention_chunk_scan, retention_step_scan)
 from hetu_tpu.models import retention_decode as rd
 
 D_HEAD = 128
@@ -47,6 +50,11 @@ def states(rng, scale=1.0):
 def close(got, want, rel):
     got, want = np.asarray(got), np.asarray(want)
     assert np.abs(got - want).max() <= rel * np.abs(want).max()
+
+
+def same_bits(a, b):
+    return np.array_equal(np.asarray(a).view(np.uint32),
+                          np.asarray(b).view(np.uint32))
 
 
 @pytest.mark.parametrize("m", [1, 5], ids=["m1", "m5"])
@@ -121,17 +129,86 @@ def test_an_idle_lane_and_an_untouched_slot_keep_their_bits(q_len):
     assert np.isfinite(np.asarray(y)).all()
     moved = {int(slot[b]) for b in range(3) if q_len[b]}
     for s in range(SLOTS):
-        same = np.array_equal(
-            np.asarray(mats2[0, s]).view(np.uint32),
-            np.asarray(mats[0, s]).view(np.uint32)) and np.array_equal(
-            np.asarray(norms2[0, s]).view(np.uint32),
-            np.asarray(norms[0, s]).view(np.uint32))
+        same = same_bits(mats2[0, s], mats[0, s]) \
+            and same_bits(norms2[0, s], norms[0, s])
         assert same == (s not in moved), s
+
+
+# the one-step kernel's slot sets, a q_len a slot: every slot one row;
+# none (wide, dead: the kernel's grid is all idle); one-row slots mixed
+# with a wide one and dead ones, the last slot and the first among the
+# ones that move
+Q_LENS = {"all_one": (1, 1, 1, 1, 1), "none": (8, 0, 0, 5, 0),
+          "mixed": (1, 8, 0, 0, 1), "wide_first": (4, 1, 1, 0, 1)}
+
+
+def step_rows(rng, m):
+    q = rng.normal(size=(SLOTS, G, m, D_HEAD)).astype(np.float32)
+    k = rng.normal(size=(SLOTS, G, D_HEAD)).astype(np.float32)
+    v = rng.normal(size=(SLOTS, G, D_HEAD)).astype(np.float32)
+    lg = np.log(rng.uniform(0.6, 0.999, size=(SLOTS, G))).astype(np.float32)
+    return tuple(map(jnp.asarray, (q + k[:, :, None, :], k, v, lg)))
+
+
+@pytest.mark.parametrize("m", [1, 5, 2], ids=["m1", "m5", "m2"])
+@pytest.mark.parametrize("slots", ["all_one", "none", "mixed"])
+def test_the_step_kernel_is_the_xla_step(slots, m):
+    """``retention_step_inplace`` against ``retention_step`` as the
+    mixer hands it the wave (``k`` 0 and ``lg`` 0 on the slots that do
+    not move): ``y``, ``S`` and ``z`` of the one-row slots in float32,
+    every other slot's state bit for bit and its ``y`` 0."""
+    rng = np.random.default_rng(len(slots) * 10 + m)
+    one = np.asarray(Q_LENS[slots]) == 1
+    q, k, v, lg = step_rows(rng, m)
+    mats, norms = states(rng)
+    want_y, want_S, want_z = rd.retention_step(
+        q, jnp.where(one[:, None, None], k, 0), v,
+        jnp.where(one[:, None], lg, 0.0), mats[0], norms[0])
+    y, mats2, norms2 = rd.retention_step_inplace(
+        q, k, v, lg, mats, norms, jnp.asarray(one))
+    for s in range(SLOTS):
+        if one[s]:
+            close(y[s], want_y[s], 1e-5)
+            close(mats2[0, s], want_S[s], 1e-6)
+            close(norms2[0, s], want_z[s], 1e-6)
+        else:
+            assert not np.asarray(y[s]).any()
+            assert same_bits(mats2[0, s], mats[0, s])
+            assert same_bits(norms2[0, s], norms[0, s])
+
+
+@pytest.mark.parametrize("slots", ["none", "mixed", "wide_first"])
+def test_a_slot_the_step_kernel_does_not_take_keeps_its_bits(slots):
+    """A wide slot, a dead one and the wave with no one-row slot at all,
+    states of 1e3 with negative zeros among them (a decay of 1 and an
+    increment of +0 would turn them over); the kernel called as it
+    stands, its lanes past the last one-row slot naming that slot
+    again."""
+    rng = np.random.default_rng(sum(Q_LENS[slots]))
+    one = np.asarray(Q_LENS[slots]) == 1
+    q, k, v, lg = step_rows(rng, 5)
+    mats, norms = states(rng, scale=1e3)
+    mats = mats.at[0, :, 0, :64].set(-0.0)
+    n = int(one.sum())
+    order = np.argsort(~one, kind="stable")
+    slot = np.where(np.arange(SLOTS) < n, order, order[max(n - 1, 0)])
+    num, den, mats2, norms2 = retention_step_scan(
+        jnp.asarray(slot), jnp.asarray(n), q[slot], k[slot], v[slot],
+        jnp.exp(lg[slot]), mats, norms)
+    assert np.isfinite(np.asarray(num)).all()
+    assert not np.asarray(num[n:]).any() and not np.asarray(den[n:]).any()
+    for s in range(SLOTS):
+        same = same_bits(mats2[0, s], mats[0, s]) \
+            and same_bits(norms2[0, s], norms[0, s])
+        assert same == (not one[s]), s
 
 
 @pytest.mark.parametrize("head_dim,q_block,kernel", [
     (16, 8, False), (2, 256, False), (128, 1, False), (64, 256, False),
-    (128, 2, True), (128, 256, True), (256, 8, True)])
+    (128, 2, True), (128, 256, True), (256, 8, True),
+    # asked of the head alone: the one-row slots' step
+    (128, None, True), (256, None, True), (16, None, False),
+    (64, None, False)])
 def test_the_shape_rule(head_dim, q_block, kernel):
     assert rd.takes_kernel(head_dim, q_block) is kernel
 
@@ -155,19 +232,28 @@ def mixer_case(d, Q):
     return sp, q, k, v, lg, state, q_len
 
 
-@pytest.mark.parametrize("d,Q,kernel", [(128, 8, True), (128, 1, False),
-                                        (16, 8, False)])
-def test_the_mixer_takes_the_kernel_by_the_rule(d, Q, kernel):
+@pytest.mark.parametrize("d,Q,kernels", [(128, 8, 2), (128, 1, 1),
+                                         (16, 8, 0), (16, 1, 0)])
+def test_the_mixer_takes_the_kernel_by_the_rule(d, Q, kernels):
+    """A head of 128: the one-step kernel in every program, the chunk
+    kernel beside it where the q-block is wider than one row; a head of
+    16: neither."""
     sp, q, k, v, lg, state, q_len = mixer_case(d, Q)
-    jaxpr = jax.make_jaxpr(
-        lambda *a: rd.retention_mixer(sp, *a, 0, q_len))(q, k, v, lg, state)
-    assert ("pallas_call" in str(jaxpr)) is kernel
+    jaxpr = str(jax.make_jaxpr(
+        lambda *a: rd.retention_mixer(sp, *a, 0, q_len))(q, k, v, lg, state))
+    assert ("pallas_call" in jaxpr) is bool(kernels)
+    assert ("retention_step_scan" in jaxpr) is (kernels > 0)
+    assert ("retention_chunk_scan" in jaxpr) is (kernels > 1)
 
 
-def test_the_mixers_wave_is_the_same_wave_through_the_kernel(monkeypatch):
-    sp, q, k, v, lg, state, q_len = mixer_case(128, 8)
+@pytest.mark.parametrize("Q", [8, 1])
+def test_the_mixers_wave_is_the_same_wave_through_the_kernel(monkeypatch, Q):
+    """The one-row slot through ``retention_step_scan`` and (``Q`` 8) the
+    wide ones through ``retention_chunk_scan``, against the same wave in
+    XLA's operations."""
+    sp, q, k, v, lg, state, q_len = mixer_case(128, Q)
     y, (S, z) = rd.retention_mixer(sp, q, k, v, lg, state, 0, q_len)
-    monkeypatch.setattr(rd, "takes_kernel", lambda d, Q: False)
+    monkeypatch.setattr(rd, "takes_kernel", lambda d, Q=None: False)
     want_y, (want_S, want_z) = rd.retention_mixer(sp, q, k, v, lg, state, 0,
                                                   q_len)
     for b, n in enumerate(np.asarray(q_len)):
@@ -193,9 +279,10 @@ WIDE = dict(
 
 def test_an_engine_of_wide_heads_serves_through_the_kernel():
     """Chunks of 16 over chunks of 8 in the scan: every chunk wave's
-    wide slots go through the kernel (twice a q-block), the decode waves
-    through the one-step form; the logits against the plain reference
-    as ``tests/test_retention.py`` holds its engine, and the counter."""
+    wide slots go through the chunk kernel (twice a q-block), its
+    one-row slots and the decode waves' through the one-step kernel; the
+    logits against the plain reference as ``tests/test_retention.py``
+    holds its engine, and the counter: every live slot of every wave."""
     from hetu_tpu.models import reference_retention as ref
     from hetu_tpu.serving import Request, ServingEngine
     cfg = rd.RetentionConfig.from_hf(WIDE)
@@ -217,6 +304,8 @@ def test_an_engine_of_wide_heads_serves_through_the_kernel():
         assert float((at.max(-1) - chosen).max() / lg.std()) <= 2e-4
     snap = eng.metrics.snapshot()
     # every prompt chunk is wider than one row here (21 = 16 + 5, 9,
-    # 34 = 16 + 16 + 2): six wide slot steps a layer
-    assert snap["ret_kernel_slot_steps"] == 6 * 2
-    assert snap["ret_kernel_slot_steps"] < snap["ret_slot_steps"]
+    # 34 = 16 + 16 + 2: six wide slot steps a layer), and the waves that
+    # carry them carry the other slots' single rows
+    assert snap["ret_slot_steps"] > 6 * 2
+    assert snap["ret_kernel_slot_steps"] == snap["ret_slot_steps"]
+    assert snap["ssm_kernel_slot_steps"] == snap["ssm_slot_steps"] == 0
